@@ -3,14 +3,13 @@
 
 The reference publishes per-model K80 img/s at batch 32
 (/root/reference/example/image-classification/README.md:147-157), which
-BASELINE.md calls the per-chip throughput *shape*. bench.py covers
-resnet-50 only; this sweep measures the rest of the table with the same
-fused-step + K-scan-dispatch technique and reports per-model
-vs_baseline multiples.
+BASELINE.md calls the per-chip throughput *shape*. This sweep measures
+the table with a fused-step + K-scan-dispatch technique and reports
+per-model vs_baseline multiples.
 
-Wedge-resilient like the other sweeps: MODEL_ONLY=name runs one model
-per process/claim; rows merge by model into the shared result file
-(same regime + platform only, atomic replace).
+MODEL_ONLY=name runs one model per process (one compile per chip
+call); rows merge by model into the shared result file (same regime +
+platform only, atomic replace).
 
 Rows per model: f32 batch-32 scan-K device rate (reference dtype and
 batch — comparable to the K80 column) and bf16 scan-K (the TPU-native

@@ -1,10 +1,10 @@
 """Side-effect-free helpers shared by the benchmark scripts.
 
-Kept free of jax/config imports on purpose: bench.py imports pack_rec
-mid-run on the live TPU backend, so this module must not touch backend
-or platform configuration at import time (the input_pipeline SCRIPT
-forces the CPU platform for itself; that belongs in its __main__, not
-here).
+Kept free of jax/config imports on purpose: a caller may import
+pack_rec mid-run on a live TPU backend, so this module must not touch
+backend or platform configuration at import time (the input_pipeline
+SCRIPT forces the CPU platform for itself; that belongs in its
+__main__, not here).
 """
 import os
 

@@ -4,9 +4,13 @@
 north-star workload, BASELINE.md resnet-50 109 img/s on K80).
 
 Data: pack ImageNet with ``tools/im2rec.py`` into train.rec/val.rec and
-point --data-train/--data-val at them. Runs on TPU by default; the whole
-forward+backward+update step compiles to ONE XLA program, and with
---num-devices > 1 gradients sync via psum over ICI inside the step.
+point --data-train/--data-val at them. Runs on TPU by default. With
+--num-devices > 1 the whole forward+backward+update step compiles to ONE
+XLA program (kvstore 'device', the fused ShardedTrainStep) and gradients
+sync via psum over ICI inside the step. With ONE device this script
+takes the per-key executor path with a 'local' kvstore, not the fused
+step (routing fact recorded in PERF.md / ROADMAP.md; a dp=1 mesh is
+what reaches the fused step on one chip).
 
 ``--dtype bfloat16`` selects the reference's fp16 path analog (cast-in/
 cast-out symbol; MXU-native reduced precision).

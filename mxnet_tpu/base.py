@@ -156,27 +156,27 @@ def bucket_bytes_env():
         return _DEFAULT_BUCKET_BYTES
 
 
-def _init_compile_cache():
-    """MXTPU_COMPILE_CACHE=<dir>: turn on JAX's persistent compilation
-    cache at import, so benchmark re-runs and preemption-resumed jobs
-    (resilience/checkpoint.py auto-resume) skip XLA recompiles. The
+def compile_cache_dir():
+    """Turn on JAX's persistent compilation cache and return where it
+    lives. ``JAX_COMPILATION_CACHE_DIR`` places it from outside: when
+    that is set JAX has already read it and no directory is set in
+    code. Otherwise the cache sits at ``<checkout>/.jax_cache``,
+    resolved from this package's own path — the path is part of the
+    cache key, so it must not move between runs. The admission
     thresholds drop to 0 because our programs are many small jit bodies
-    (per-key ops, fused steps) that the default 1s/too-small gates would
-    mostly skip."""
-    cache_dir = os.environ.get("MXTPU_COMPILE_CACHE")
-    if not cache_dir:
-        return
+    (per-key ops, fused steps) that the default 1s/too-small gates
+    would mostly skip."""
     import jax
 
-    for knob, value in (
-        ("jax_compilation_cache_dir", cache_dir),
-        ("jax_persistent_cache_min_compile_time_secs", 0),
-        ("jax_persistent_cache_min_entry_size_bytes", 0),
-    ):
-        try:
-            jax.config.update(knob, value)
-        except (AttributeError, ValueError):  # knob absent in this jax
-            pass
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return cache_dir
 
 
-_init_compile_cache()
+compile_cache_dir()

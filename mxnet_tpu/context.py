@@ -62,11 +62,14 @@ class Context:
                 except RuntimeError:
                     continue
             if devs is None:
-                # No accelerator present (unit-test environment): fall back
-                # to CPU devices so multi-"device" tests run anywhere, the
-                # same trick the reference plays with mx.cpu(1)/mx.cpu(2) in
-                # tests/python/unittest/test_multi_device_exec.py.
-                devs = jax.local_devices(backend="cpu")
+                # asking for the chip must never hand back the host CPU:
+                # a run that silently lands there looks like a chip run
+                raise MXNetError(
+                    "context %s: JAX has no tpu or gpu backend here "
+                    "(platforms present: %s); use mx.cpu() to run on "
+                    "the host" % (
+                        self,
+                        sorted({d.platform for d in jax.devices()})))
         if self.device_id >= len(devs):
             raise MXNetError(
                 "context %s: device_id %d out of range (%d %s devices visible)"

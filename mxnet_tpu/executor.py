@@ -70,6 +70,7 @@ def _instrument_jit(fn, key):
     disabled."""
     state = {"compiled": False}
 
+    @functools.wraps(fn)
     def wrapper(*args, **kwargs):
         if not _tm.enabled():
             state["compiled"] = True

@@ -285,7 +285,18 @@ class KVStore(object):
             def _apply(merged, k, upd_key):
                 with self._update_lock:
                     if self._updater is not None:
-                        self._updater(upd_key, merged, self._store[k])
+                        stored = self._store[k]
+                        if stored.context != merged.context:
+                            # parity kvstore_local.h Push ("if merged is
+                            # on gpu, we may need copy weight from cpu
+                            # to gpu"): init() stores the weight where
+                            # arg_params live (the host), the reduce
+                            # lands on the chip; the stored weight
+                            # follows it there, once, so the updater
+                            # never mixes backends
+                            stored = stored.as_in_context(merged.context)
+                            self._store[k] = stored
+                        self._updater(upd_key, merged, stored)
                     else:
                         merged.copyto(self._store[k])
 

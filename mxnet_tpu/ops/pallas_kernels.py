@@ -9,9 +9,12 @@ whose VMEM working set is O(block²+block·D) per grid step (the K/V axis
 is walked by the innermost grid dimension, not loaded whole), forward and
 backward both as MXU-tiled kernels.
 
-Everything degrades gracefully off-TPU: ``interpret=True`` runs the same
-kernels through the Pallas interpreter (tests), and callers can always
-use the pure-jnp reference path (``reference_attention``).
+Mosaic or interpreter is decided per call site by the platform the
+enclosing computation is LOWERED for (``_by_platform``), never by the
+process default backend: a step placed on a TPU device compiles the
+Mosaic kernels, the same step placed on a host device runs them through
+the Pallas interpreter (tests). The pure-jnp references
+(``reference_attention``, ``slab_update_reference``) are the oracles.
 
 Layout convention matches ``parallel/ring_attention``: [B, T, H, D].
 """
@@ -29,34 +32,37 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _use_interpret():
-    return jax.default_backend() != "tpu"
+def _by_platform(call, *args):
+    """Run ``call(*args, interpret=...)`` as a Mosaic kernel where the
+    enclosing computation is lowered for TPU and through the Pallas
+    interpreter on every other platform. The choice is made at lowering
+    from the platform of the device the step runs on
+    (``lax.platform_dependent``), so only the matching branch reaches
+    the compiler."""
+    return jax.lax.platform_dependent(
+        *args,
+        tpu=functools.partial(call, interpret=False),
+        default=functools.partial(call, interpret=True))
 
 
 def _no_x64():
     """Context manager forcing 32-bit tracing: the framework enables
     jax_enable_x64 globally (reference float64 NDArray parity) but
-    Mosaic kernels must stay 32-bit. `jax.enable_x64` was removed in
-    jax 0.4.x; `jax.experimental.disable_x64` is the stable spelling."""
-    try:
-        return jax.experimental.disable_x64()
-    except AttributeError:  # pragma: no cover — future jax renames
-        import contextlib
-
-        return contextlib.nullcontext()
+    Mosaic kernels must stay 32-bit."""
+    return jax.enable_x64(False)
 
 
-def fused_update_enabled():
+def fused_update_enabled(platform):
     """Whether the fused optimizer-slab kernel replaces the jnp update
-    chain. ``MXTPU_FUSED_UPDATE_KERNEL``: "1" forces it on everywhere
-    (interpret mode off-TPU — the parity tests), "0" forces the jnp
-    reference, unset enables it on TPU only."""
+    chain on a mesh of ``platform`` devices. ``MXTPU_FUSED_UPDATE_KERNEL``:
+    "1" forces it on everywhere (interpret mode off-TPU — the parity
+    tests), "0" forces the jnp reference, unset enables it on TPU only."""
     v = os.environ.get("MXTPU_FUSED_UPDATE_KERNEL", "")
     if v == "0":
         return False
     if v == "1":
         return True
-    return jax.default_backend() == "tpu"
+    return platform == "tpu"
 
 
 def _pad_to(x, axis, mult):
@@ -250,7 +256,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
 # host-side wrappers
 # ---------------------------------------------------------------------------
 
-def _fwd_call(q3, k3, v3, t_real, scale, causal, block_q, block_k,
+def _fwd_call(q3, k3, v3, *, t_real, scale, causal, block_q, block_k,
               interpret):
     bh, t_pad, d = q3.shape
     nq = t_pad // block_q
@@ -286,7 +292,7 @@ def _fwd_call(q3, k3, v3, t_real, scale, causal, block_q, block_k,
     return out, lse
 
 
-def _bwd_call(q3, k3, v3, do3, lse, delta, t_real, scale, causal,
+def _bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
               block_q, block_k, interpret):
     bh, t_pad, d = q3.shape
     nq = t_pad // block_q
@@ -348,30 +354,31 @@ def _bwd_call(q3, k3, v3, do3, lse, delta, t_real, scale, causal,
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
 )
 def _flash(q3, k3, v3, t_real, scale, causal, block_q, block_k):
-    interp = _use_interpret()
-    out, _ = _fwd_call(q3, k3, v3, t_real, scale, causal, block_q,
-                       block_k, interp)
+    out, _ = _flash_fwd(q3, k3, v3, t_real, scale, causal, block_q,
+                        block_k)
     return out
 
 
 def _flash_fwd(q3, k3, v3, t_real, scale, causal, block_q, block_k):
-    interp = _use_interpret()
-    out, lse = _fwd_call(q3, k3, v3, t_real, scale, causal, block_q,
-                         block_k, interp)
+    out, lse = _by_platform(
+        functools.partial(
+            _fwd_call, t_real=t_real, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k),
+        q3, k3, v3)
     return out, (q3, k3, v3, out, lse)
 
 
 def _flash_bwd(t_real, scale, causal, block_q, block_k, res, g):
     q3, k3, v3, out, lse = res
-    interp = _use_interpret()
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
         keepdims=True,
     )  # [BH, T, 1]
-    dq, dk, dv = _bwd_call(
-        q3, k3, v3, g.astype(q3.dtype), lse, delta, t_real, scale,
-        causal, block_q, block_k, interp,
-    )
+    dq, dk, dv = _by_platform(
+        functools.partial(
+            _bwd_call, t_real=t_real, scale=scale, causal=causal,
+            block_q=block_q, block_k=block_k),
+        q3, k3, v3, g.astype(q3.dtype), lse, delta)
     return dq, dk, dv
 
 
@@ -415,7 +422,7 @@ def attention(q, k, v, causal=False, scale=None, mesh=None):
     """Shared attention dispatch for every model that wants fused
     attention without hand-picking a kernel: sequence-parallel ring
     attention when the mesh shards the sequence axis, the Pallas flash
-    kernel when it pays (TPU and T >= 128, or forced via
+    kernel when it pays (lowered for TPU and T >= 128, or forced via
     ``MXNET_TPU_FORCE_FLASH=1``), the materialized reference otherwise.
     q/k/v: [B, T, H, D] -> [B, T, H, D]."""
     t = q.shape[1]
@@ -423,11 +430,15 @@ def attention(q, k, v, causal=False, scale=None, mesh=None):
         from ..parallel.ring_attention import sequence_parallel_attention
 
         return sequence_parallel_attention(q, k, v, mesh, causal=causal)
-    force = os.environ.get("MXNET_TPU_FORCE_FLASH") == "1"
-    on_tpu = jax.default_backend() == "tpu"
-    if mesh is None and (force or (on_tpu and t >= 128)):
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    return reference_attention(q, k, v, causal=causal, scale=scale)
+    flash = functools.partial(flash_attention, causal=causal, scale=scale)
+    reference = functools.partial(
+        reference_attention, causal=causal, scale=scale)
+    if mesh is None and os.environ.get("MXNET_TPU_FORCE_FLASH") == "1":
+        return flash(q, k, v)
+    if mesh is None and t >= 128:
+        return jax.lax.platform_dependent(
+            q, k, v, tpu=flash, default=reference)
+    return reference(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -537,7 +548,7 @@ def slab_update_reference(kind, w, g, states, lr, inv_scale, finite, *,
 
 def fused_slab_update(kind, w, g, states, lr, inv_scale, finite, *, wd,
                       rescale_grad, clip_gradient, momentum=0.0, beta1=0.9,
-                      beta2=0.999, epsilon=1e-8, interpret=None):
+                      beta2=0.999, epsilon=1e-8):
     """AMP optimizer step over a flat slab in one Pallas VMEM pass.
 
     w: (S,) f32 master shard; g: (S,) grad shard (bf16 under AMP);
@@ -552,8 +563,6 @@ def fused_slab_update(kind, w, g, states, lr, inv_scale, finite, *, wd,
     s = w.shape[0]
     rows = -(-s // _SLAB_LANES)
     block_rows = 256 if rows >= 256 else (-(-rows // 16) * 16)
-    if interpret is None:
-        interpret = _use_interpret()
     kern = functools.partial(
         _slab_kernel, kind, n_state, wd=float(wd),
         rescale_grad=float(rescale_grad),
@@ -562,7 +571,7 @@ def fused_slab_update(kind, w, g, states, lr, inv_scale, finite, *, wd,
         epsilon=float(epsilon))
     # pads/stacks stay OUTSIDE the 32-bit context: under the global
     # jax_enable_x64 an outer trace caches their lowered subfunctions
-    # with i64 scalar operands, and re-tracing them under disable_x64
+    # with i64 scalar operands, and re-tracing them under _no_x64
     # emits i32 signatures for the same cache key — mixed-width
     # func.call verifier errors. Only the pallas_call itself (whose
     # Mosaic grid indexing must be 32-bit) runs under _no_x64.
@@ -577,20 +586,24 @@ def fused_slab_update(kind, w, g, states, lr, inv_scale, finite, *, wd,
     rp = w2.shape[0]
     grid = (rp // block_rows,)
     blk = pl.BlockSpec((block_rows, _SLAB_LANES), lambda i: (i, 0))
-    blk16 = pl.BlockSpec((block_rows, _SLAB_LANES), lambda i: (i, 0))
-    with _no_x64():
-        outs = pl.pallas_call(
-            kern,
-            grid=grid,
-            in_specs=[pl.BlockSpec((1, 3), lambda i: (0, 0),
-                                   memory_space=pltpu.SMEM),
-                      blk, blk] + [blk] * n_state,
-            out_specs=[blk] + [blk] * n_state + [blk16],
-            out_shape=[jax.ShapeDtypeStruct((rp, _SLAB_LANES),
-                                            jnp.float32)] * (n_state + 1)
-            + [jax.ShapeDtypeStruct((rp, _SLAB_LANES), jnp.bfloat16)],
-            interpret=interpret,
-        )(scalars, w2, g2, *st2)
+
+    def call(*operands, interpret):
+        with _no_x64():
+            return pl.pallas_call(
+                kern,
+                grid=grid,
+                in_specs=[pl.BlockSpec((1, 3), lambda i: (0, 0),
+                                       memory_space=pltpu.SMEM),
+                          blk, blk] + [blk] * n_state,
+                out_specs=[blk] * (n_state + 2),
+                out_shape=[jax.ShapeDtypeStruct((rp, _SLAB_LANES),
+                                                jnp.float32)]
+                * (n_state + 1)
+                + [jax.ShapeDtypeStruct((rp, _SLAB_LANES), jnp.bfloat16)],
+                interpret=interpret,
+            )(*operands)
+
+    outs = _by_platform(call, scalars, w2, g2, *st2)
     new_w = outs[0].reshape(-1)[:s]
     new_states = tuple(o.reshape(-1)[:s] for o in outs[1:n_state + 1])
     w16 = outs[n_state + 1].reshape(-1)[:s]
@@ -600,11 +613,14 @@ def fused_slab_update(kind, w, g, states, lr, inv_scale, finite, *, wd,
 # ---------------------------------------------------------------------------
 # conv-backward pair (ROADMAP item 3: the MFU climb).
 #
-# ResNet's dominant FLOP sink is conv backward, and the banked probes
-# (conv_bwd_experiments / NOTES_r5 §8) showed XLA's native
-# conv-backprop-filter can lose badly to an explicit tap decomposition.
+# ResNet's dominant FLOP sink is conv backward, and an explicit tap
+# decomposition is a candidate against XLA's native
+# conv-backprop-filter (neither side measured on the chip; PERF.md).
 # The kernels below productize that decomposition WITHOUT the im2col
-# patches slab:
+# patches slab. OPT-IN and staying so: on the v5e Mosaic refuses the
+# pair at most ResNet-50 shapes (scoped-VMEM stack overflow — the
+# 12 MiB plan budget undercounts what the 4-D blocks take once tiled);
+# see PERF.md, PR 13.
 #
 #   wgrad:  gw[o,c,kh,kw] = sum_{n,oh,ow} g[n,o,oh,ow]
 #                           * xpad[n,c,oh+kh,ow+kw]
@@ -714,7 +730,7 @@ def _conv_wgrad_kernel(x_ref, g_ref, out_ref, *, bn, oh, ow, kh, kw):
                     preferred_element_type=jnp.float32)  # (O, C)
 
 
-def conv_bwd_filter(data, grad, wshape, pad, block_n=None, interpret=None):
+def conv_bwd_filter(data, grad, wshape, pad, block_n=None):
     """Pallas filter gradient of a stride-1/dilation-1/groups-1 2-D conv.
 
     data: (N, C, H, W); grad: (N, O, OH, OW) cotangent; wshape:
@@ -724,8 +740,6 @@ def conv_bwd_filter(data, grad, wshape, pad, block_n=None, interpret=None):
     n, c, h, w = data.shape
     o, _, kh, kw = wshape
     oh, ow = grad.shape[2], grad.shape[3]
-    if interpret is None:
-        interpret = _use_interpret()
     if block_n is None:
         plan = conv_bwd_plan(data.shape, wshape, (1, 1), pad, (1, 1),
                              data.dtype)
@@ -741,20 +755,28 @@ def conv_bwd_filter(data, grad, wshape, pad, block_n=None, interpret=None):
     hp, wp = x_t.shape[1], x_t.shape[2]
     kern = functools.partial(_conv_wgrad_kernel, bn=block_n, oh=oh, ow=ow,
                              kh=kh, kw=kw)
-    with _no_x64():
-        gw = pl.pallas_call(
-            kern,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_n, hp, wp, c), lambda i: (i, 0, 0, 0)),
-                pl.BlockSpec((block_n, oh, ow, o), lambda i: (i, 0, 0, 0)),
-            ],
-            # constant index map: the accumulator block stays
-            # VMEM-resident across the whole N-block grid
-            out_specs=pl.BlockSpec((kh * kw, o, c), lambda i: (0, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((kh * kw, o, c), jnp.float32),
-            interpret=interpret,
-        )(x_t, g_t)
+
+    def call(x_t, g_t, interpret):
+        with _no_x64():
+            return pl.pallas_call(
+                kern,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((block_n, hp, wp, c),
+                                 lambda i: (i, 0, 0, 0)),
+                    pl.BlockSpec((block_n, oh, ow, o),
+                                 lambda i: (i, 0, 0, 0)),
+                ],
+                # constant index map: the accumulator block stays
+                # VMEM-resident across the whole N-block grid
+                out_specs=pl.BlockSpec((kh * kw, o, c),
+                                       lambda i: (0, 0, 0)),
+                out_shape=jax.ShapeDtypeStruct((kh * kw, o, c),
+                                               jnp.float32),
+                interpret=interpret,
+            )(x_t, g_t)
+
+    gw = _by_platform(call, x_t, g_t)
     return jnp.transpose(gw, (1, 2, 0)).reshape(o, c, kh, kw)
 
 
@@ -772,8 +794,7 @@ def _conv_dgrad_kernel(g_ref, w_ref, out_ref, *, bn, h, w, kh, kw):
     out_ref[...] = acc.reshape(out_ref.shape).astype(out_ref.dtype)
 
 
-def conv_bwd_input(grad, weight, dshape, pad, block_n=None,
-                   interpret=None):
+def conv_bwd_input(grad, weight, dshape, pad, block_n=None):
     """Pallas data gradient of a stride-1/dilation-1/groups-1 2-D conv.
 
     grad: (N, O, OH, OW) cotangent; weight: (O, C, kh, kw); dshape:
@@ -783,8 +804,6 @@ def conv_bwd_input(grad, weight, dshape, pad, block_n=None,
     in-register f32 tap accumulation. Returns f32 (N, C, H, W)."""
     n, c, h, w = dshape
     o, _, kh, kw = weight.shape
-    if interpret is None:
-        interpret = _use_interpret()
     if block_n is None:
         plan = conv_bwd_plan(dshape, weight.shape, (1, 1), pad, (1, 1),
                              grad.dtype)
@@ -800,21 +819,25 @@ def conv_bwd_input(grad, weight, dshape, pad, block_n=None,
     hgp, wgp = g_t.shape[1], g_t.shape[2]
     kern = functools.partial(_conv_dgrad_kernel, bn=block_n, h=h, w=w,
                              kh=kh, kw=kw)
-    with _no_x64():
-        gd = pl.pallas_call(
-            kern,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((block_n, hgp, wgp, o),
-                             lambda i: (i, 0, 0, 0)),
-                pl.BlockSpec((kh, kw, o, c), lambda i: (0, 0, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((block_n, h, w, c),
-                                   lambda i: (i, 0, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct(
-                (g_t.shape[0], h, w, c), jnp.float32),
-            interpret=interpret,
-        )(g_t, w_rot)
+
+    def call(g_t, w_rot, interpret):
+        with _no_x64():
+            return pl.pallas_call(
+                kern,
+                grid=grid,
+                in_specs=[
+                    pl.BlockSpec((block_n, hgp, wgp, o),
+                                 lambda i: (i, 0, 0, 0)),
+                    pl.BlockSpec((kh, kw, o, c), lambda i: (0, 0, 0, 0)),
+                ],
+                out_specs=pl.BlockSpec((block_n, h, w, c),
+                                       lambda i: (i, 0, 0, 0)),
+                out_shape=jax.ShapeDtypeStruct(
+                    (g_t.shape[0], h, w, c), jnp.float32),
+                interpret=interpret,
+            )(g_t, w_rot)
+
+    gd = _by_platform(call, g_t, w_rot)
     return jnp.transpose(gd[:n], (0, 3, 1, 2))
 
 

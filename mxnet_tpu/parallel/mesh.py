@@ -409,22 +409,18 @@ def init_distributed(coordinator_address=None, num_processes=None,
         return False
     if jax.distributed.is_initialized():
         return True
-    try:
-        # The CPU backend needs an explicit collectives implementation
-        # for cross-process psum/allgather (without it they silently
-        # reduce over local devices only — tested, not hypothetical).
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except AttributeError:
-        pass  # older jax: option absent, CPU multi-process unsupported
+    # The CPU backend needs an explicit collectives implementation
+    # for cross-process psum/allgather (without it they silently
+    # reduce over local devices only — tested, not hypothetical).
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes, process_id=process_id)
     except RuntimeError as e:
-        # jax 0.9 raises "distributed.initialize should only be called
-        # once."; older versions say "already initialized"
-        msg = str(e).lower()
-        if "already" in msg or "once" in msg:
+        # a second call raises "distributed.initialize should only be
+        # called once."
+        if "once" in str(e).lower():
             return True
         raise
     return True

@@ -12,9 +12,10 @@ previously-bound executor in an LRU pool keyed on the input-shape
 signature (the reference re-creates; here a bucket flip is a dict
 lookup), and all executors share one set of parameter buffers via
 ``shared_exec`` binding. ``compile()`` lowers and compiles the serving
-fast path per bucket up front — warm-started through
-``MXTPU_COMPILE_CACHE`` — with the streaming input buffers donated, so
-the steady-state request loop never traces (proven by the
+fast path per bucket up front — warm-started through the persistent
+compile cache (``base.compile_cache_dir``) — with the streaming input
+buffers donated, so the steady-state request loop never traces (proven
+by the
 telemetry.anatomy recompile detector: every dispatch routes through
 ``_GraphProgram.dispatch_plan``).
 
@@ -213,9 +214,9 @@ class Predictor(object):
         """AOT-lower and compile the serving fast path for each shape
         bucket up front (default: the currently-bound shapes). After
         this, ``predict_batch`` for any compiled bucket is a single
-        donated-buffer device call with zero tracing; with
-        ``MXTPU_COMPILE_CACHE`` set, the XLA executables warm-start
-        from the persistent cache across process restarts."""
+        donated-buffer device call with zero tracing; the XLA
+        executables warm-start from the persistent compile cache
+        across process restarts."""
         if input_shapes_list is None:
             input_shapes_list = [dict(self._input_shapes)]
         for shapes in input_shapes_list:
@@ -253,13 +254,18 @@ class Predictor(object):
 
 
 class _ServeFn(object):
-    """One AOT-compiled forward for one input-shape bucket: parameters
-    closed over as executable constants, streaming inputs donated."""
+    """One AOT-compiled forward for one input-shape bucket, pinned to
+    the executor's context device: parameters closed over as executable
+    constants, streaming inputs donated."""
 
     def __init__(self, exec_, input_shapes):
         import jax
 
         self._exec = exec_
+        # the avals name the context's device, so the executable lands
+        # there whatever the process default backend is
+        self._device = exec_._ctx.jax_device
+        sharding = jax.sharding.SingleDeviceSharding(self._device)
         self._program = exec_._program
         self._data_names = tuple(sorted(input_shapes))
         self._output_names = list(exec_._output_names)
@@ -286,11 +292,12 @@ class _ServeFn(object):
         self._avals = [
             jax.ShapeDtypeStruct(
                 tuple(input_shapes[n]),
-                exec_.arg_dict[n]._data.dtype)
+                exec_.arg_dict[n]._data.dtype, sharding=sharding)
             for n in data_names
         ]
-        # AOT: lower + compile now (MXTPU_COMPILE_CACHE warm-starts
-        # this), so the first request pays zero trace/compile time.
+        # AOT: lower + compile now (the persistent compile cache
+        # warm-starts this), so the first request pays zero
+        # trace/compile time.
         # CPU XLA cannot honor donation — silence that warning, the
         # request stays meaningful on TPU.
         import warnings
@@ -311,7 +318,7 @@ class _ServeFn(object):
     def __call__(self, inputs):
         import time
 
-        import jax.numpy as jnp
+        import jax
 
         overrides = self._program.shape_overrides
         self._program.dispatch_plan(self._sig, lambda: overrides)
@@ -324,7 +331,8 @@ class _ServeFn(object):
                     % (name, tuple(data.shape), tuple(aval.shape)))
             # fresh device array per call: its buffer is donated to the
             # executable, so the output can alias it in place
-            data_vals.append(jnp.asarray(data, dtype=aval.dtype))
+            data_vals.append(jax.device_put(
+                data.astype(aval.dtype, copy=False), self._device))
         t0 = time.perf_counter()
         outs = self._compiled(*data_vals)
         outs = [np.asarray(o) for o in outs]

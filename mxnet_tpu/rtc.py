@@ -62,11 +62,15 @@ class Rtc(object):
         self._kernel = namespace["_rtc_kernel"]
         self._pl = pl
 
-    def _compiled(self, in_shapes, in_dtypes, out_shapes, out_dtypes, grid):
-        key = (in_shapes, in_dtypes, out_shapes, out_dtypes, grid)
+    def _compiled(self, in_shapes, in_dtypes, out_shapes, out_dtypes, grid,
+                  platform):
+        key = (in_shapes, in_dtypes, out_shapes, out_dtypes, grid,
+               platform)
         fn = self._cache.get(key)
         if fn is None:
-            interpret = jax.default_backend() != "tpu"
+            # Mosaic on the chip, the Pallas interpreter elsewhere —
+            # decided by where the inputs live, not the process default
+            interpret = platform != "tpu"
             kwargs = {} if grid is None else {"grid": grid}
             call = self._pl.pallas_call(
                 self._kernel,
@@ -100,6 +104,8 @@ class Rtc(object):
             tuple(tuple(o.shape) for o in outs),
             tuple(str(np.dtype(o.dtype)) for o in outs),
             grid,
+            next(iter((in_vals[0] if in_vals else outs[0]._data)
+                      .devices())).platform,
         )
         results = fn(*in_vals)
         for o, r in zip(outs, results):

@@ -145,9 +145,9 @@ class GenerationEngine(object):
     # -- compile-ahead -------------------------------------------------
     def compile(self, prompt_lengths=None):
         """Warm every (count-bucket × length-bucket) prefill program and
-        the decode step, so the serving loop never traces. With
-        MXTPU_COMPILE_CACHE set the XLA executables come from the
-        persistent cache."""
+        the decode step, so the serving loop never traces. The XLA
+        executables come from the persistent compile cache when it is
+        warm."""
         import jax.numpy as jnp
 
         lengths = prompt_lengths or self.len_buckets

@@ -123,7 +123,7 @@ class ServingEngine(object):
     def start(self, precompile=True):
         """Spawn the dispatcher. ``precompile`` AOT-compiles every batch
         bucket first so the request path never traces (warm via
-        MXTPU_COMPILE_CACHE)."""
+        the persistent compile cache)."""
         if self._thread is not None:
             return self
         if precompile:

@@ -80,8 +80,7 @@ _C_COST_MISSES = _registry.counter(
 _G_MFU = _registry.gauge(
     "anatomy.mfu",
     "Model FLOPs utilization over the last anatomy interval: "
-    "flops_per_step * steps / wall / peak_flops (wall-rate based, same "
-    "convention as benchmarks/bench.py)")
+    "flops_per_step * steps / wall / peak_flops (wall-rate based)")
 _G_MODEL_FLOPS = _registry.gauge(
     "anatomy.model_flops",
     "Per-step FLOPs of the active compiled program (XLA cost analysis)")
@@ -375,7 +374,7 @@ def emit_interval(force=False):
                 record["mfu"] = mfu
                 _G_MFU.set(mfu)
             else:
-                # bench.py's sanity gate: >100% means the peak table or
+                # sanity gate: >100% means the peak table or
                 # the cost model is wrong for this device — say so
                 # instead of reporting a nonsense utilization
                 record["mfu_error"] = (
@@ -402,12 +401,9 @@ _kind_cache = None
 def _device_kind():
     global _kind_cache
     if _kind_cache is None:
-        try:
-            import jax
+        import jax
 
-            _kind_cache = str(getattr(jax.devices()[0], "device_kind", ""))
-        except Exception:
-            _kind_cache = ""
+        _kind_cache = str(jax.devices()[0].device_kind)
     return _kind_cache
 
 

@@ -9,8 +9,8 @@ Two independent sources of truth, cross-checked in benchmark_score.py:
   conv/FC MACs by hand — the classical "2*N*K*OH*OW*C/g*kh*kw" number
   papers quote MFU against, independent of XLA's fusion decisions.
 
-Peak-rate tables mirror ``benchmarks/bench.py`` (per-chip dense
-bf16/f32 peaks from public TPU specs); ``MXTPU_ANATOMY_PEAK_TFLOPS`` /
+Peak-rate tables hold per-chip dense bf16 peaks from public TPU specs;
+``MXTPU_ANATOMY_PEAK_TFLOPS`` /
 ``MXTPU_ANATOMY_PEAK_GBPS`` override both for unlisted hardware and for
 deterministic CPU tests. Stdlib-only at import (jax stays lazy) so
 telemetry keeps its no-cycle guarantee.

@@ -3,13 +3,26 @@
 Mirrors the reference's trick of using multiple CPU contexts as fake
 devices (tests/python/unittest/test_multi_device_exec.py) — here via
 XLA's host-platform device-count flag, set BEFORE jax initializes.
-The jax.config update routes around any accelerator plugin so the suite
-never depends on TPU availability.
+The jax.config update pins the suite to the CPU so it never claims (or
+depends on) a chip.
 """
+import atexit
 import os
+import shutil
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# The suite's compile cache is placed through the variable, BEFORE jax is
+# imported: a fresh directory per session that the subprocesses tests
+# spawn inherit. XLA:CPU entries written under another jax configuration
+# have aborted fresh interpreters at load, so the suite never reads a
+# cache that outlives it (base.compile_cache_dir).
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _cache_dir = tempfile.mkdtemp(prefix="mxtpu-test-jax-cache-")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _cache_dir
+    atexit.register(shutil.rmtree, _cache_dir, ignore_errors=True)
 
 from __graft_entry__ import _force_cpu_mesh_platform
 

@@ -139,24 +139,35 @@ def test_amp_engages_and_master_invariant(monkeypatch):
     assert scale >= 1.0
 
 
-def test_amp_requires_flat_path(monkeypatch):
-    """dp=1 has no flat path: AMP must decline (warning) and run fp32."""
-    monkeypatch.setenv("MXTPU_AMP", "bf16")
-    np.random.seed(0)
-    mx.random.seed(0)
+@pytest.mark.parametrize("mesh", [False, True])
+def test_amp_that_cannot_engage_raises(monkeypatch, mesh):
+    """dp=1 has no flat path — through the per-key executor path (one
+    context) or the fused step on a dp=1 mesh. AMP must refuse: it used
+    to log a warning and train fp32, a silent wrong answer. An unknown
+    value is refused the same way."""
+    from mxnet_tpu.parallel import make_mesh
+
     rng = np.random.RandomState(42)
     X = rng.randn(64, 8).astype(np.float32)
     y = rng.randint(0, 4, 64).astype(np.float32)
-    it = mx.io.NDArrayIter(X, y, batch_size=16)
-    mod = mx.mod.Module(_mlp_net(), context=[mx.cpu(0)])
-    metric = mx.metric.create("acc")
-    mod.fit(it, eval_metric=metric, kvstore="device", optimizer="sgd",
-            optimizer_params={"learning_rate": 0.1,
-                              "rescale_grad": 1.0 / 16},
-            initializer=mx.init.Uniform(0.1), num_epoch=1)
-    if mod._fused_trainer is not None:
-        assert not mod._fused_owner._fused_trainer.amp
-    assert np.isfinite(metric.get()[1])
+
+    def fit():
+        it = mx.io.NDArrayIter(X, y, batch_size=16)
+        mod = mx.mod.Module(_mlp_net(), context=[mx.cpu(0)],
+                            mesh=make_mesh(dp=1) if mesh else None)
+        mod.fit(it, kvstore="device", optimizer="sgd",
+                initializer=mx.init.Uniform(0.1), num_epoch=1)
+        return mod
+
+    monkeypatch.setenv("MXTPU_AMP", "bf16")
+    with pytest.raises(mx.MXNetError, match="MXTPU_AMP=bf16 cannot engage"):
+        fit()
+    if mesh:
+        monkeypatch.setenv("MXTPU_AMP", "fp8")
+        with pytest.raises(mx.MXNetError, match="not understood"):
+            fit()
+    monkeypatch.delenv("MXTPU_AMP")
+    assert (fit()._fused_trainer is not None) == mesh
 
 
 def test_amp_lenet_convergence_gate(monkeypatch):
@@ -309,8 +320,7 @@ def test_slab_kernel_matches_reference(kind, size):
         ref_w, ref_st, ref_w16 = slab_update_reference(
             kind, w, g, states, 0.05, 1.0 / 128, finite, **kw)
         got_w, got_st, got_w16 = fused_slab_update(
-            kind, w, g, states, 0.05, 1.0 / 128, finite,
-            interpret=True, **kw)
+            kind, w, g, states, 0.05, 1.0 / 128, finite, **kw)
         np.testing.assert_allclose(np.asarray(got_w), np.asarray(ref_w),
                                    rtol=1e-6, atol=1e-7)
         for a, b in zip(got_st, ref_st):

@@ -1,9 +1,11 @@
-"""MXTPU_COMPILE_CACHE: persistent XLA compilation cache wiring.
+"""base.compile_cache_dir: where JAX's persistent compilation cache lives.
 
-base._init_compile_cache() runs at import and points JAX's persistent
-compilation cache at the given directory with the size/time thresholds
-dropped to 0 (our programs are many small jit bodies). Verified in a
-subprocess because the knob must be set before any compilation.
+The helper runs at import. ``JAX_COMPILATION_CACHE_DIR`` places the
+cache from outside and is left untouched; without it the cache sits at
+``<checkout>/.jax_cache``, resolved from the package's own path. The
+admission thresholds are 0 either way (our programs are many small jit
+bodies). Verified in subprocesses because jax reads the variable at
+import and the cache must be placed before any compilation.
 """
 import json
 import os
@@ -12,53 +14,71 @@ import sys
 
 import pytest
 
-_PROBE = r"""
-import json, os, sys
-os.environ["JAX_PLATFORMS"] = "cpu"
-import mxnet_tpu  # triggers _init_compile_cache()
-import jax, jax.numpy as jnp
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-cfg_dir = jax.config.jax_compilation_cache_dir
-out = jax.jit(lambda x: x * 2.0 + 1.0)(jnp.arange(8, dtype=jnp.float32))
-out.block_until_ready()
-cache_dir = os.environ["MXTPU_COMPILE_CACHE"]
-entries = []
-for root, _, files in os.walk(cache_dir):
-    entries.extend(files)
-print(json.dumps({"cfg_dir": cfg_dir, "entries": entries}))
+_PROBE = r"""
+import json, os
+import mxnet_tpu  # places the cache at import
+import jax, jax.numpy as jnp
+from mxnet_tpu import base
+
+helper_dir = base.compile_cache_dir()
+out = {"helper_dir": helper_dir,
+       "cfg_dir": jax.config.jax_compilation_cache_dir,
+       "min_secs": jax.config.jax_persistent_cache_min_compile_time_secs,
+       "min_bytes": jax.config.jax_persistent_cache_min_entry_size_bytes}
+if os.environ.get("PROBE_COMPILE") == "1":
+    jax.jit(lambda x: x * 2.0 + 1.0)(
+        jnp.arange(8, dtype=jnp.float32)).block_until_ready()
+    out["entries"] = sorted(
+        f for _, _, files in os.walk(helper_dir) for f in files)
+print(json.dumps(out))
 """
 
 
-def _run_probe(env):
-    full_env = dict(os.environ)
-    full_env.update(env)
-    full_env.pop("XLA_FLAGS", None)  # single device is fine here
+def _run_probe(env_updates, cwd):
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)  # single device is fine here
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = REPO
+    for k, v in env_updates.items():
+        if v is None:
+            env.pop(k, None)
+        else:
+            env[k] = v
     proc = subprocess.run(
-        [sys.executable, "-c", _PROBE], env=full_env,
+        [sys.executable, "-c", _PROBE], env=env, cwd=cwd,
         capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stderr
     return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-def test_compile_cache_populates_dir(tmp_path):
+def _checkout_cache_entries():
+    default = os.path.join(REPO, ".jax_cache")
+    return sorted(os.listdir(default)) if os.path.isdir(default) else None
+
+
+def test_env_var_places_the_cache_untouched(tmp_path):
     cache = tmp_path / "xla_cache"
     cache.mkdir()
-    res = _run_probe({"MXTPU_COMPILE_CACHE": str(cache),
-                      "PYTHONPATH": os.path.dirname(
-                          os.path.dirname(os.path.abspath(__file__)))})
+    before = _checkout_cache_entries()
+    res = _run_probe({"JAX_COMPILATION_CACHE_DIR": str(cache),
+                      "PROBE_COMPILE": "1"}, cwd=str(tmp_path))
+    assert res["helper_dir"] == str(cache)
     assert res["cfg_dir"] == str(cache)
+    assert res["min_secs"] == 0 and res["min_bytes"] == 0
+    # with the variable set nothing is written under the checkout default
+    assert _checkout_cache_entries() == before
     if not res["entries"]:  # some jax builds can't cache CPU executables
         pytest.skip("jax persistent cache wrote no CPU entries here")
-    assert res["entries"]
 
 
-def test_compile_cache_off_by_default():
-    from mxnet_tpu import base
-
-    env_backup = os.environ.pop("MXTPU_COMPILE_CACHE", None)
-    try:
-        # no env -> no-op, must not raise or touch jax config
-        base._init_compile_cache()
-    finally:
-        if env_backup is not None:
-            os.environ["MXTPU_COMPILE_CACHE"] = env_backup
+def test_default_is_checkout_jax_cache(tmp_path):
+    # resolved from the package path, not the working directory; no
+    # compile here, so the checkout's directory is never written
+    res = _run_probe({"JAX_COMPILATION_CACHE_DIR": None},
+                     cwd=str(tmp_path))
+    want = os.path.join(REPO, ".jax_cache")
+    assert res["helper_dir"] == want
+    assert res["cfg_dir"] == want
+    assert res["min_secs"] == 0 and res["min_bytes"] == 0

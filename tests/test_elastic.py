@@ -236,10 +236,6 @@ def _run_elastic(script_dir, ckpt_dir, out, extra_env, timeout=300):
     env.pop(fault.ENV, None)
     env.pop("MXTPU_WORLD_SIZE", None)
     env.pop("MXTPU_ELASTIC", None)
-    # bench (imported by earlier test files) exports a shared persistent
-    # compile-cache dir; a stale entry from another jax config can abort
-    # the fresh interpreter during deserialization — stay hermetic
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(extra_env)
     return subprocess.run(
         [sys.executable, script, ckpt_dir, out],
@@ -355,9 +351,6 @@ def test_watchdog_elastic_shrink_and_continue(tmp_path, monkeypatch):
     with open(script, "w") as f:
         f.write(ELASTIC_SCRIPT)
     monkeypatch.delenv("XLA_FLAGS", raising=False)
-    # supervise() passes a straight os.environ copy to the child: scrub
-    # the shared compile cache here (see _run_elastic)
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.setenv(fault.ENV, "replica_lost=3@5")
     monkeypatch.setenv(ck.ENV_INTERVAL, "3")
     monkeypatch.setenv("MXTPU_ELASTIC_POLL", "0")

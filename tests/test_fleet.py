@@ -345,8 +345,7 @@ def _run_rank(script_dir, run_dir, rank, extra_env=None, timeout=300):
             f.write(FLEET_SCRIPT)
     env = os.environ.copy()
     for var in ("XLA_FLAGS", fault.ENV, "MXTPU_TELEMETRY_FILE",
-                "MXTPU_WORLD_SIZE", "MXTPU_ELASTIC", "MXTPU_METRICS_PORT",
-                "JAX_COMPILATION_CACHE_DIR"):
+                "MXTPU_WORLD_SIZE", "MXTPU_ELASTIC", "MXTPU_METRICS_PORT"):
         env.pop(var, None)
     env.update({
         "MXTPU_RUN_DIR": run_dir,

@@ -71,6 +71,24 @@ def test_optimizer_on_kvstore():
     assert_almost_equal(w.asnumpy(), -0.1 * np.ones(SHAPE), rtol=1e-5)
 
 
+def test_optimizer_on_kvstore_follows_the_gradient_device():
+    """The weight is init()ed where arg_params live (the host) while the
+    gradients arrive from the training device. The stored weight moves
+    to the reduce's device (parity kvstore_local.h Push) instead of the
+    updater mixing devices — what stopped examples/train_imagenet.py
+    --ctx tpu on the chip, reproduced here with two host devices."""
+    kv = mx.kvstore.create("local")
+    kv.set_optimizer(mx.optimizer.SGD(learning_rate=0.5, momentum=0.9))
+    kv.init(0, nd.ones(SHAPE, ctx=mx.cpu(0)))
+    for _ in range(2):
+        kv.push(0, nd.ones(SHAPE, ctx=mx.cpu(1)))
+    out = nd.empty(SHAPE, ctx=mx.cpu(1))
+    kv.pull(0, out=out)
+    # step 1: mom=-0.5, w=0.5; step 2: mom=-0.95, w=-0.45
+    assert_almost_equal(out.asnumpy(), -0.45 * np.ones(SHAPE))
+    assert out.context == mx.cpu(1)
+
+
 def test_string_keys_stable():
     kv = mx.kvstore.create("local")
     kv.init("weight", nd.zeros(SHAPE))

@@ -158,3 +158,39 @@ def test_fused_checkpoint_roundtrip(tmp_path):
         not np.allclose(before[k], after[k].asnumpy()) for k in before
     )
     assert changed
+
+
+@pytest.mark.parametrize("ndev", [1, 2])
+def test_fused_keeps_reduced_precision_weights(ndev):
+    """A bf16 symbol (cast-in/cast-out, bf16 weights — resnet's
+    dtype="bfloat16") through the fused step: lr and t enter the program
+    as traced f32 scalars, so the update computes in f32 and must cast
+    back. It used to hand f32 weights to the step's own second call — a
+    retrace, then a dtype clash in the first bf16 op (what stopped
+    ResNet-50 bf16 on the chip). ndev=1 is the dp=1 mesh (legacy per-key
+    update), ndev=2 the flat sharded update."""
+    from mxnet_tpu.parallel import make_mesh
+
+    data = mx.sym.Variable("data")
+    net = mx.sym.Cast(data, dtype="bfloat16")
+    net = mx.sym.FullyConnected(net, num_hidden=16, name="fc1")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
+    net = mx.sym.Cast(net, dtype="float32")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    rng = np.random.RandomState(0)
+    X = rng.randn(16, 8).astype(np.float32)
+    y = rng.randint(0, 4, 16).astype(np.float32)
+    it = mx.io.ResizeIter(mx.io.NDArrayIter(X, y, batch_size=16), 4)
+    mod = mx.mod.Module(
+        net, context=[mx.cpu(i) for i in range(ndev)],
+        mesh=make_mesh(dp=1) if ndev == 1 else None)
+    metric = mx.metric.create("ce")
+    mod.fit(it, eval_metric=metric, kvstore="device", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+            initializer=mx.init.Uniform(0.1), num_epoch=1)
+    trainer = mod._fused_trainer
+    assert trainer is not None
+    assert (trainer.flat_mode is None) == (ndev == 1)
+    assert {str(v.dtype) for v in mod._fused_params.values()} == {"bfloat16"}
+    assert np.isfinite(metric.get()[1])

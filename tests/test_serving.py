@@ -385,7 +385,8 @@ def test_sigterm_drains_and_exits_zero(tmp_path):
     serve_tool._build_toy_bundle(bundle)
     proc = subprocess.Popen(
         [sys.executable, os.path.join(REPO, "tools", "serve.py"),
-         "--bundle", bundle, "--input", "data=1x28x28", "--port", "0"],
+         "--ctx", "cpu", "--bundle", bundle, "--input", "data=1x28x28",
+         "--port", "0"],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
         env=_serve_env(), cwd=REPO)
     try:
@@ -411,12 +412,11 @@ def test_sigterm_drains_and_exits_zero(tmp_path):
             proc.kill()
 
 
-@pytest.mark.slow
 @pytest.mark.timeout(300)
 def test_serve_self_test_subprocess():
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "serve.py"),
-         "--self-test"],
+         "--ctx", "cpu", "--self-test"],
         capture_output=True, text=True, timeout=280, env=_serve_env(),
         cwd=REPO)
     assert r.returncode == 0, r.stdout + r.stderr

@@ -18,12 +18,16 @@ Usage::
     python -m tools.serve --bundle model.pred --input data=1x28x28
     python -m tools.serve --checkpoint runs/exp1/ckpts/ckpt-100 \
         --symbol model.json --input data=1x28x28 --port 9000
-    python -m tools.serve --self-test
+    python -m tools.serve --self-test [--ctx cpu]
+
+The model serves from ``--ctx`` (default ``tpu``, as the examples; a
+host without the chip must say ``--ctx cpu``, it is never picked
+silently).
 
 Knobs: ``--max-batch`` / MXTPU_SERVE_MAX_BATCH, ``--timeout-ms`` /
 MXTPU_SERVE_BATCH_TIMEOUT_MS, ``--metrics-port`` / MXTPU_METRICS_PORT
 (Prometheus /metrics via telemetry.fleet.MetricsServer),
-MXTPU_SERVE_QUANT=int8, MXTPU_SERVE_EXEC_CACHE, MXTPU_COMPILE_CACHE.
+MXTPU_SERVE_QUANT=int8, MXTPU_SERVE_EXEC_CACHE.
 """
 from __future__ import annotations
 
@@ -55,19 +59,34 @@ def _parse_input_specs(specs):
     return shapes
 
 
+def get_context(args):
+    """The serving device. ``--ctx cpu`` pins jax to the host BEFORE the
+    first backend touch, so a CPU server on a chip host never claims
+    the chip (one process per chip)."""
+    if args.ctx == "cpu":
+        import jax
+
+        jax.config.update("jax_platforms", "cpu")
+    import mxnet_tpu as mx
+
+    return mx.Context(args.ctx, 0)
+
+
 def load_predictor(args, feature_shapes):
     from mxnet_tpu import predict
 
+    ctx = get_context(args)
     input_shapes = {n: (1,) + s for n, s in feature_shapes.items()}
     if args.bundle:
-        return predict.load_bundle(args.bundle, input_shapes)
+        return predict.load_bundle(args.bundle, input_shapes, ctx=ctx)
     if args.checkpoint:
         if not args.symbol:
             raise SystemExit("--checkpoint needs --symbol <symbol.json>")
         with open(args.symbol) as f:
             symbol_json = f.read()
         params = predict.params_from_checkpoint(args.checkpoint)
-        return predict.Predictor(symbol_json, params, input_shapes)
+        return predict.Predictor(symbol_json, params, input_shapes,
+                                 ctx=ctx)
     raise SystemExit("one of --bundle / --checkpoint is required")
 
 
@@ -171,8 +190,10 @@ def _build_toy_bundle(path):
     return sym
 
 
-def _self_test():
+def _self_test(args):
     import tempfile
+
+    ctx = get_context(args)
 
     from mxnet_tpu import telemetry
     from mxnet_tpu.serving.engine import ServeClosed, ServingEngine
@@ -184,7 +205,8 @@ def _self_test():
 
     from mxnet_tpu import predict
 
-    predictor = predict.load_bundle(bundle, {"data": (1, 1, 28, 28)})
+    predictor = predict.load_bundle(bundle, {"data": (1, 1, 28, 28)},
+                                    ctx=ctx)
     engine = ServingEngine(predictor, max_batch=4, batch_timeout_ms=2.0)
     engine.start()
     server = ServeServer(("127.0.0.1", 0), engine)
@@ -249,7 +271,7 @@ def _self_test():
     except ServeClosed:
         pass
     print("self-test: graceful drain rejects new work")
-    print("serve self-test PASSED")
+    print("serve self-test PASSED on %s" % ctx.jax_device)
     return 0
 
 
@@ -263,6 +285,8 @@ def main(argv=None):
     ap.add_argument("--input", action="append", default=[],
                     metavar="name=DxDxD",
                     help="per-example input shape (repeatable)")
+    ap.add_argument("--ctx", default="tpu", choices=["tpu", "cpu", "gpu"],
+                    help="device the model serves from (default tpu)")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int,
                     default=int(os.environ.get("MXTPU_SERVE_PORT", "9000")))
@@ -276,7 +300,7 @@ def main(argv=None):
     ap.add_argument("--self-test", action="store_true")
     args = ap.parse_args(argv)
     if args.self_test:
-        return _self_test()
+        return _self_test(args)
     return run_server(args)
 
 

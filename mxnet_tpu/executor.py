@@ -116,15 +116,45 @@ def _force_mirrored(node):
     return node.attrs.get("__force_mirroring__") in ("True", "true", "1")
 
 
+_OP_CLASS = {
+    "Convolution": "conv", "Deconvolution": "conv",
+    "FullyConnected": "fc", "BatchNorm": "bn", "Pooling": "pool",
+    "Activation": "act", "LeakyReLU": "act", "relu": "act",
+    "sigmoid": "act", "tanh": "act", "add_n": "act", "clip": "act",
+    "MakeLoss": "loss",
+}
+
+
+def op_class(op_name):
+    """conv | fc | bn | pool | act | loss | other: the class a node's
+    device ops are filed under (the first part of its named scope)."""
+    cls = _OP_CLASS.get(op_name)
+    if cls is not None:
+        return cls
+    if op_name.endswith("Output"):
+        return "loss"
+    if op_name.startswith(("elemwise_", "broadcast_", "_plus", "_Plus",
+                           "_add", "_minus", "_Minus", "_sub", "_mul",
+                           "_Mul", "_div", "_Div")):
+        return "act"
+    return "other"
+
+
 def _compute_node(node, attrs, in_vals, is_train):
     """Run one node's fcompute; a node carrying __force_mirroring__
     recomputes (only) itself in backward via jax.checkpoint — the
-    per-node escape hatch the reference's need_mirror honors first."""
-    if is_train and _force_mirrored(node):
-        fn = jax.checkpoint(
-            lambda *iv: node.op.fcompute(attrs, list(iv), is_train))
-        return fn(*in_vals)
-    return node.op.fcompute(attrs, in_vals, is_train)
+    per-node escape hatch the reference's need_mirror honors first.
+
+    The node's ops are traced under the scope ``<op class>/<node
+    name>`` (metadata only): backward ops inherit it inside JAX's
+    ``transpose(jvp(...))`` wrapper, so a device op in a profiler trace
+    says which layer and which kind of layer it belongs to."""
+    with jax.named_scope("%s/%s" % (op_class(node.op.name), node.name)):
+        if is_train and _force_mirrored(node):
+            fn = jax.checkpoint(
+                lambda *iv: node.op.fcompute(attrs, list(iv), is_train))
+            return fn(*in_vals)
+        return node.op.fcompute(attrs, in_vals, is_train)
 
 
 _MIRROR_SAVE_DEFAULT = "dot_general,conv_general_dilated"

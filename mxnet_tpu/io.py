@@ -371,8 +371,9 @@ class DeviceFeedIter(DataIter):
         return True
 
     def _fill(self):
-        while len(self._staged) < self.depth and self._stage_one():
-            pass
+        with _tm.span("io.feed_fill"):
+            while len(self._staged) < self.depth and self._stage_one():
+                pass
 
     def reset(self):
         # staged transfers are abandoned, not awaited: jax arrays are

@@ -633,6 +633,10 @@ class BaseModule(object):
             return blob
 
         def _after_steps(epoch, done, n_new):
+            with _tm.span("fit.after_steps"):
+                _bookkeep(epoch, done, n_new)
+
+        def _bookkeep(epoch, done, n_new):
             """Bookkeeping after ``n_new`` batches finished training
             (``done`` = batches of this epoch now fully trained). Fires
             the fault harness per optimizer step, honors a pending
@@ -912,8 +916,9 @@ class BaseModule(object):
                     for (nbatch, db), outs in zip(pending, steps):
                         self._install_step_outputs(outs)
                         _queue_metric(db)
-                        _fire(batch_end_callback, epoch, nbatch,
-                              eval_metric, _cb_locals(nbatch, db))
+                        with _tm.span("fit.callbacks"):
+                            _fire(batch_end_callback, epoch, nbatch,
+                                  eval_metric, _cb_locals(nbatch, db))
                     # the K-group is atomic (one XLA dispatch applied all
                     # K updates), so step bookkeeping — and any interval
                     # / preemption checkpoint — lands on its boundary
@@ -925,32 +930,47 @@ class BaseModule(object):
                     # compiled; a one-off K'-step compile isn't worth it)
                     for nbatch, db in pending:
                         with _tm.span("fit.step", epoch=epoch,
-                                      nbatch=nbatch):
+                                      nbatch=nbatch, step=loop["gs"] + 1):
                             t0 = time.perf_counter()
                             self.forward_backward(db)
                             self.update()
                             _H_STEP_SECONDS.observe(
                                 time.perf_counter() - t0, epoch=str(epoch))
-                        _queue_metric(db)
-                        _fire(batch_end_callback, epoch, nbatch,
-                              eval_metric, _cb_locals(nbatch, db))
-                        _after_steps(epoch, nbatch + 1, 1)
+                            _queue_metric(db)
+                            with _tm.span("fit.callbacks"):
+                                _fire(batch_end_callback, epoch, nbatch,
+                                      eval_metric, _cb_locals(nbatch, db))
+                            _after_steps(epoch, nbatch + 1, 1)
 
-            for nbatch, data_batch in enumerate(fit_data, start=skip):
-                if _fault.configured():
+            batches = iter(fit_data)
+
+            def _next_batch():
+                """The next batch, or None at the end of the epoch; the
+                wait for it is ``fit.input``."""
+                with _tm.span("fit.input"):
+                    batch = next(batches, None)
+                if batch is not None and _fault.configured():
                     # poison-batch injection (nan_grad_at_step /
                     # loss_spike_at_step): this batch will feed
                     # optimizer step gs + len(pending) + 1
                     _mode = _fault.batch_poison(
                         loop["gs"] + len(pending) + 1)
                     if _mode:
-                        data_batch = _poison_batch(data_batch, _mode)
+                        batch = _poison_batch(batch, _mode)
+                return batch
+
+            nbatch = skip - 1
+            while True:
+                nbatch += 1
                 use_multi = (
                     _k() > 1 and monitor is None
                     and getattr(self, "_fused_trainer", None) is not None
                     and hasattr(self, "update_multi")
                 )
                 if use_multi:
+                    data_batch = _next_batch()
+                    if data_batch is None:
+                        break
                     if (pending and any(
                             tuple(p.shape) != tuple(d.shape)
                             for p, d in zip(pending[0][1].data,
@@ -964,22 +984,31 @@ class BaseModule(object):
                         _flush_group(pending, epoch, eval_metric)
                         pending = []
                     continue
-                if monitor is not None:
-                    monitor.tic()
-                with _tm.span("fit.step", epoch=epoch, nbatch=nbatch):
+                # one span holds the whole step period, from the wait
+                # for the batch to the bookkeeping after it: what the
+                # spans inside it do not cover is the loop's own time
+                with _tm.span("fit.step", epoch=epoch, nbatch=nbatch,
+                              step=loop["gs"] + 1) as step_span:
+                    data_batch = _next_batch()
+                    if data_batch is None:
+                        step_span.discard()
+                        break
+                    if monitor is not None:
+                        monitor.tic()
                     t0 = time.perf_counter()
                     self.forward_backward(data_batch)
                     self.update()
                     _H_STEP_SECONDS.observe(
                         time.perf_counter() - t0, epoch=str(epoch))
-                if _tm.enabled():
-                    _tm.sample_device_memory()
-                _queue_metric(data_batch)
-                if monitor is not None:
-                    monitor.toc_print()
-                _fire(batch_end_callback, epoch, nbatch, eval_metric,
-                      locals())
-                _after_steps(epoch, nbatch + 1, 1)
+                    if _tm.enabled():
+                        _tm.sample_device_memory()
+                    _queue_metric(data_batch)
+                    if monitor is not None:
+                        monitor.toc_print()
+                    with _tm.span("fit.callbacks"):
+                        _fire(batch_end_callback, epoch, nbatch,
+                              eval_metric, locals())
+                    _after_steps(epoch, nbatch + 1, 1)
             if pending:
                 _flush_group(pending, epoch, eval_metric)
                 pending = []

@@ -47,10 +47,11 @@ _M_FEED_HITS = _tm.counter(
     "device_put)")
 _H_OUTPUT_SYNC = _tm.histogram(
     "module.output_sync_seconds",
-    "Host wall time blocked pulling fused-step outputs to host "
-    "(update_metric / deferred metric drain). Under the async pipeline "
-    "this is where device compute surfaces on the host thread — the "
-    "device-sync leg of the step anatomy (telemetry/anatomy.py)")
+    "Host wall time of the metric's update over a fused step's outputs "
+    "(update_metric / deferred metric drain): the blocking fetch inside "
+    "eval_metric.update and the metric's arithmetic. Under the async "
+    "pipeline this is where device compute surfaces on the host thread "
+    "— the device-sync leg of the step anatomy (telemetry/anatomy.py)")
 
 
 def _local_rows(arr):
@@ -662,7 +663,8 @@ class Module(BaseModule):
                 # staging + enqueue together are the step's host-side
                 # cost: the trainer call returns before the device runs
                 t0 = time.perf_counter()
-                batch = self._make_fused_batch(self._fused_batch)
+                with _tm.span("module.stage"):
+                    batch = self._make_fused_batch(self._fused_batch)
                 _H_STAGE_HOST.observe(time.perf_counter() - t0)
                 p, a, s, outs = self._fused_trainer(
                     owner._fused_params, owner._fused_aux, owner._fused_opt,
@@ -759,12 +761,14 @@ class Module(BaseModule):
             return jax.device_put(stacked, sharding)
 
         batches = {}
-        for i, name in enumerate(self._data_names):
-            batches[name] = _put_stack([b.data[i] for b in data_batches])
-        if self._label_names and data_batches[0].label:
-            for i, name in enumerate(self._label_names):
+        with _tm.span("module.stage"):
+            for i, name in enumerate(self._data_names):
                 batches[name] = _put_stack(
-                    [b.label[i] for b in data_batches])
+                    [b.data[i] for b in data_batches])
+            if self._label_names and data_batches[0].label:
+                for i, name in enumerate(self._label_names):
+                    batches[name] = _put_stack(
+                        [b.label[i] for b in data_batches])
         if _tm.enabled():
             per_stage = (time.perf_counter() - t0_host) / k
             for _ in range(k):
@@ -829,10 +833,8 @@ class Module(BaseModule):
 
     def _materialized_fused_outputs(self):
         if self._fused_outputs is None and self._fused_outs_raw is not None:
-            t0 = time.perf_counter()
             self._fused_outputs = [
                 nd.NDArray(_local_rows(o)) for o in self._fused_outs_raw]
-            _H_OUTPUT_SYNC.observe(time.perf_counter() - t0)
         return self._fused_outputs
 
     def get_outputs(self, merge_multi_context=True):
@@ -856,12 +858,27 @@ class Module(BaseModule):
         return self._exec_group.get_input_grads(merge_multi_context=merge_multi_context)
 
     def update_metric(self, eval_metric, labels):
-        if self._fused_trainer is not None:
-            outs = self._materialized_fused_outputs()
-            if outs is not None:
-                eval_metric.update(labels, outs)
-                return
-        self._exec_group.update_metric(eval_metric, labels)
+        if self._fused_trainer is not None and (
+                self._fused_outputs is not None
+                or self._fused_outs_raw is not None):
+            self._update_metric_fused(
+                eval_metric, labels, self._materialized_fused_outputs)
+            return
+        with _tm.span("module.update_metric"):
+            self._exec_group.update_metric(eval_metric, labels)
+
+    def _update_metric_fused(self, eval_metric, labels, make_outs):
+        """The one place a fused step's outputs reach the metric, now
+        or ``MXTPU_METRIC_INTERVAL`` steps late. ``make_outs()`` wraps
+        the device arrays (a host transfer only in multi-process runs);
+        the blocking fetch happens inside ``eval_metric.update``
+        (``NDArray.asnumpy``). This interval — fetch and the metric's
+        arithmetic together — is what ``module.output_sync_seconds``
+        holds."""
+        with _tm.span("module.update_metric"):
+            t0 = time.perf_counter()
+            eval_metric.update(labels, make_outs())
+            _H_OUTPUT_SYNC.observe(time.perf_counter() - t0)
 
     def _metric_snapshot(self):
         """Deferred-metric hook (BaseModule.fit, MXTPU_METRIC_INTERVAL):
@@ -879,10 +896,9 @@ class Module(BaseModule):
         """Drain one deferred step: the blocking host transfer happens
         HERE, k steps behind the dispatch frontier; accumulation math
         and order match an immediate update_metric exactly."""
-        t0 = time.perf_counter()
-        eval_metric.update(
-            labels, [nd.NDArray(_local_rows(o)) for o in snapshot])
-        _H_OUTPUT_SYNC.observe(time.perf_counter() - t0)
+        self._update_metric_fused(
+            eval_metric, labels,
+            lambda: [nd.NDArray(_local_rows(o)) for o in snapshot])
 
     def _sync_params_from_devices(self):
         """Parity module.py:666."""

@@ -254,10 +254,16 @@ def _conv2d_bwd_nhwc(data, weight, stride, pad, dilate, groups):
                 dimension_numbers=_conv_nhwc_dn(),
                 feature_group_count=groups)
 
-        _, vjp_fn = jax.vjp(f_nhwc, data_t, weight_t)
-        gd_t, gw_t = vjp_fn(jnp.transpose(g, (0, 2, 3, 1)))
-        return (jnp.transpose(gd_t, (0, 3, 1, 2)),
-                jnp.transpose(gw_t, (3, 2, 0, 1)))
+        g_t = jnp.transpose(g, (0, 2, 3, 1))
+        # one vjp per gradient conv, so that each carries its own scope
+        # into a device trace (the unused primal convs are dead code)
+        with jax.named_scope("dgrad"):
+            gd_t, = jax.vjp(lambda dt: f_nhwc(dt, weight_t), data_t)[1](g_t)
+            gd = jnp.transpose(gd_t, (0, 3, 1, 2))
+        with jax.named_scope("wgrad"):
+            gw_t, = jax.vjp(lambda wt: f_nhwc(data_t, wt), weight_t)[1](g_t)
+            gw = jnp.transpose(gw_t, (3, 2, 0, 1))
+        return gd, gw
 
     conv.defvjp(fwd, bwd)
     return conv(data, weight)
@@ -286,10 +292,12 @@ def _conv2d_wgrad_custom(data, weight, stride, pad, dilate, wgrad_fn):
 
     def bwd(res, g):
         d, w = res
-        _, dgrad_vjp = jax.vjp(lambda dd: plain(dd, w), d)
-        gd, = dgrad_vjp(g)
-        gw = wgrad_fn(d, g, w)
-        return gd, gw.astype(w.dtype).reshape(w.shape)
+        with jax.named_scope("dgrad"):
+            _, dgrad_vjp = jax.vjp(lambda dd: plain(dd, w), d)
+            gd, = dgrad_vjp(g)
+        with jax.named_scope("wgrad"):
+            gw = wgrad_fn(d, g, w).astype(w.dtype).reshape(w.shape)
+        return gd, gw
 
     conv.defvjp(fwd, bwd)
     return conv(data, weight)
@@ -457,9 +465,11 @@ def _conv2d_pallas_bwd(data, weight, pad):
 
     def bwd(res, g):
         d, w = res
-        gd = _pk.conv_bwd_input(g, w, d.shape, pad)
-        gw = _pk.conv_bwd_filter(d, g, w.shape, pad)
-        return gd.astype(d.dtype), gw.astype(w.dtype)
+        with jax.named_scope("dgrad"):
+            gd = _pk.conv_bwd_input(g, w, d.shape, pad).astype(d.dtype)
+        with jax.named_scope("wgrad"):
+            gw = _pk.conv_bwd_filter(d, g, w.shape, pad).astype(w.dtype)
+        return gd, gw
 
     conv.defvjp(fwd, bwd)
     return conv(data, weight)

@@ -170,6 +170,21 @@ def _unwrap_state(state):
     return state._data
 
 
+def _abstract(args):
+    """Shape, dtype and sharding of every array in ``args``: what a
+    lowering needs, and all that is left of donated arguments once they
+    are dispatched. An uncommitted array keeps no sharding, as in the
+    dispatch itself."""
+    import jax
+
+    def one(x):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=x.sharding if getattr(x, "committed", True) else None)
+
+    return jax.tree_util.tree_map(one, args)
+
+
 def _keep_dtype(new, old):
     """Cast an updated weight / state tree back to the dtype it is
     stored in. lr and t enter the step as traced f32 scalars, so the
@@ -1087,17 +1102,21 @@ class ShardedTrainStep:
         amp_cast = set(self.data_names) if (amp and self.amp_cast_data) \
             else set()
 
+        # jax.named_scope below is metadata only (trace time, no op): a
+        # device op in a profiler trace carries the phase it belongs to
+        # (bench/reduce_scopes.py reads fwd_bwd, update, guard, amp_cast)
         def step(params, aux, opt_state, batch, rng, lr, t, gthr):
             if amp_cast:
                 # bf16 activations from the first op: cast floating DATA
                 # feeds (never labels — loss heads compare against them
                 # exactly). MXTPU_AMP_CAST_DATA=0 keeps feeds untouched.
-                batch = {
-                    n: (v.astype(jnp.bfloat16)
-                        if (n in amp_cast
-                            and jnp.issubdtype(v.dtype, jnp.floating))
-                        else v)
-                    for n, v in batch.items()}
+                with jax.named_scope("amp_cast"):
+                    batch = {
+                        n: (v.astype(jnp.bfloat16)
+                            if (n in amp_cast
+                                and jnp.issubdtype(v.dtype, jnp.floating))
+                            else v)
+                        for n, v in batch.items()}
 
             def loss_fn(ps):
                 args = dict(ps)
@@ -1114,14 +1133,16 @@ class ShardedTrainStep:
                 # backward, keep dot/conv residuals (executor._mirror_policy)
                 loss_fn = jax.checkpoint(loss_fn, policy=_mirror_policy)
 
-            if guard:
-                # value_and_grad instead of grad: the diag head needs
-                # the loss VALUE; the gradient computation is identical.
-                (loss_val, (outs, new_aux)), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(params)
-            else:
-                grads, (outs, new_aux) = jax.grad(
-                    loss_fn, has_aux=True)(params)
+            with jax.named_scope("fwd_bwd"):
+                if guard:
+                    # value_and_grad instead of grad: the diag head
+                    # needs the loss VALUE; the gradient computation is
+                    # identical.
+                    (loss_val, (outs, new_aux)), grads = \
+                        jax.value_and_grad(loss_fn, has_aux=True)(params)
+                else:
+                    grads, (outs, new_aux) = jax.grad(
+                        loss_fn, has_aux=True)(params)
             if amp:
                 # Loss scaling rides the GRADIENT stream, not the loss
                 # value: every loss head here ignores its incoming
@@ -1160,7 +1181,8 @@ class ShardedTrainStep:
                 apply = self._apply_optimizer_flat
             else:
                 apply = self._apply_optimizer
-            new_params, new_opt = apply(params, grads, opt_state, lr, t)
+            with jax.named_scope("update"):
+                new_params, new_opt = apply(params, grads, opt_state, lr, t)
             new_aux = {**aux, **new_aux}  # carry shared-owner extras through
             if amp:
                 # aux state (BN moving stats) keeps its fp32 dtype across
@@ -1172,44 +1194,45 @@ class ShardedTrainStep:
                             and v.dtype != aux[k].dtype) else v)
                     for k, v in new_aux.items()}
             if guard:
-                # Global grad-norm² from the SAME gradient stream the
-                # optimizer just consumed — replicated already, so this
-                # adds local reductions but no new collective. AMP grads
-                # arrive pre-multiplied by the loss scale; unscale the
-                # squared norm so the gate threshold and the host
-                # detector both see true magnitudes.
-                gn2 = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                          for g in grads.values())
-                if amp:
-                    inv = 1.0 / opt_state[self.AMP_SCALE_KEY].astype(
-                        jnp.float32)
-                    gn2 = gn2 * inv * inv
-                ok = jnp.logical_and(jnp.isfinite(gn2), gn2 <= gthr)
+                with jax.named_scope("guard"):
+                    # Global grad-norm² from the SAME gradient stream the
+                    # optimizer just consumed — replicated already, so this
+                    # adds local reductions but no new collective. AMP grads
+                    # arrive pre-multiplied by the loss scale; unscale the
+                    # squared norm so the gate threshold and the host
+                    # detector both see true magnitudes.
+                    gn2 = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                              for g in grads.values())
+                    if amp:
+                        inv = 1.0 / opt_state[self.AMP_SCALE_KEY].astype(
+                            jnp.float32)
+                        gn2 = gn2 * inv * inv
+                    ok = jnp.logical_and(jnp.isfinite(gn2), gn2 <= gthr)
 
-                def _sel(new, old):
-                    # branchless select over a (possibly nested) state
-                    # entry: select(True, new, old) is bitwise `new`, so
-                    # a clean step is untouched by the gate
-                    return jax.tree_util.tree_map(
-                        lambda n_, o_: jnp.where(ok, n_, o_), new, old)
+                    def _sel(new, old):
+                        # branchless select over a (possibly nested) state
+                        # entry: select(True, new, old) is bitwise `new`, so
+                        # a clean step is untouched by the gate
+                        return jax.tree_util.tree_map(
+                            lambda n_, o_: jnp.where(ok, n_, o_), new, old)
 
-                # AMP's scaler bookkeeping stays LIVE through a skip:
-                # reverting the scale would undo the backoff that makes
-                # the next attempt finite (same contract as the inner
-                # AMP gate, which also exempts these two keys).
-                passthru = ({self.AMP_SCALE_KEY, self.AMP_GOOD_KEY}
-                            if amp else ())
-                new_params = {k: (_sel(v, params[k]) if k in params else v)
-                              for k, v in new_params.items()}
-                new_opt = {k: (v if (k in passthru or k not in opt_state)
-                               else _sel(v, opt_state[k]))
-                           for k, v in new_opt.items()}
-                new_aux = {k: (_sel(v, aux[k]) if k in aux else v)
-                           for k, v in new_aux.items()}
-                diag = jnp.stack([
-                    jnp.asarray(loss_val, jnp.float32), gn2,
-                    ok.astype(jnp.float32)])
-                outs = list(outs) + [diag]
+                    # AMP's scaler bookkeeping stays LIVE through a skip:
+                    # reverting the scale would undo the backoff that makes
+                    # the next attempt finite (same contract as the inner
+                    # AMP gate, which also exempts these two keys).
+                    passthru = ({self.AMP_SCALE_KEY, self.AMP_GOOD_KEY}
+                                if amp else ())
+                    new_params = {k: (_sel(v, params[k]) if k in params else v)
+                                  for k, v in new_params.items()}
+                    new_opt = {k: (v if (k in passthru or k not in opt_state)
+                                   else _sel(v, opt_state[k]))
+                               for k, v in new_opt.items()}
+                    new_aux = {k: (_sel(v, aux[k]) if k in aux else v)
+                               for k, v in new_aux.items()}
+                    diag = jnp.stack([
+                        jnp.asarray(loss_val, jnp.float32), gn2,
+                        ok.astype(jnp.float32)])
+                    outs = list(outs) + [diag]
             return new_params, new_aux, new_opt, outs
 
         return step
@@ -1324,22 +1347,46 @@ class ShardedTrainStep:
         lrs_arr = jnp.asarray(lrs, jnp.float32)
         ts_arr = jnp.asarray(ts, jnp.float32)
         gthr_arr = jnp.asarray(self.guard_threshold, jnp.float32)
-        if _tm.anatomy.wants_cost():
-            # AOT lower+compile BEFORE the donating dispatch (lower does
-            # not consume buffers); cached per signature, so the steady
-            # state pays a dict lookup. No steps=k division: XLA's cost
-            # analysis sums the scan BODY once (trip count is not
-            # multiplied in), so the K-step program already reports
-            # per-step cost
-            _tm.anatomy.capture_cost(
-                self.program._program_uid, ("multi", k) + sig,
-                lambda: fn.lower(params, aux, opt_state, batches, rngs,
-                                 lrs_arr, ts_arr, gthr_arr).compile(),
-                dtype="bf16" if self.amp else "f32")
+        args = (params, aux, opt_state, batches, rngs, lrs_arr, ts_arr,
+                gthr_arr)
+        # No steps=k division in the cost: XLA's cost analysis sums the
+        # scan BODY once (trip count is not multiplied in), so the
+        # K-step program already reports per-step cost
+        cost_key = ("multi", k) + sig
+        specs = (_abstract(args) if _tm.anatomy.cost_pending(
+            self.program._program_uid, cost_key) else None)
         _M_STEPS.inc(k, path="multi")
         with _tm.span("train_step.dispatch", k=k):
-            return fn(params, aux, opt_state, batches, rngs,
-                      lrs_arr, ts_arr, gthr_arr)
+            out = fn(*args)
+        if specs is not None:
+            self._capture_cost(cost_key, fn, specs, {
+                n: tuple(v.shape[1:]) for n, v in batches.items()})
+        return out
+
+    def _capture_cost(self, cost_key, fn, specs, batch_shapes):
+        """Price the program just dispatched for the step anatomy, once
+        per signature and AFTER its dispatch: ``fn`` is lowered again
+        from abstract arguments (the donated ones are gone) and never
+        compiled, so telemetry builds no executable the untraced run
+        does not build, and the jit's own lowering came first."""
+        devices = self.mesh.size
+
+        def analytic():
+            # the TPU's client analyses no lowering: count by hand
+            from ..telemetry import costmodel
+
+            ops = costmodel.analytic_op_costs(
+                self.symbol, dtype_bytes=2 if self.amp else 4,
+                **batch_shapes)
+            # a training step is ~3x the forward (forward + 2x backward)
+            return {"flops": 3.0 * sum(o["flops"] for o in ops) / devices,
+                    "bytes_accessed":
+                        3.0 * sum(o["bytes"] for o in ops) / devices}
+
+        _tm.anatomy.capture_cost(
+            self.program._program_uid, cost_key,
+            lambda: fn.lower(*specs), devices=devices, analytic=analytic,
+            dtype="bf16" if self.amp else "f32")
 
     def __call__(self, params, aux, opt_state, batch, rng=None, lr=None, t=1):
         assert self._step is not None, "call compile() first"
@@ -1383,14 +1430,14 @@ class ShardedTrainStep:
         lr_arr = jnp.asarray(lr, jnp.float32)
         t_arr = jnp.asarray(t, jnp.float32)
         gthr_arr = jnp.asarray(self.guard_threshold, jnp.float32)
-        if _tm.anatomy.wants_cost():
-            _tm.anatomy.capture_cost(
-                self.program._program_uid, ("single",) + sig,
-                lambda: self._step.lower(params, aux, opt_state, batch,
-                                         rng, lr_arr, t_arr,
-                                         gthr_arr).compile(),
-                dtype="bf16" if self.amp else "f32")
+        args = (params, aux, opt_state, batch, rng, lr_arr, t_arr, gthr_arr)
+        cost_key = ("single",) + sig
+        specs = (_abstract(args) if _tm.anatomy.cost_pending(
+            self.program._program_uid, cost_key) else None)
         _M_STEPS.inc(path="single")
         with _tm.span("train_step.dispatch", t=t):
-            return self._step(params, aux, opt_state, batch, rng,
-                              lr_arr, t_arr, gthr_arr)
+            out = self._step(*args)
+        if specs is not None:
+            self._capture_cost(cost_key, self._step, specs, {
+                n: tuple(v.shape) for n, v in batch.items()})
+        return out

@@ -2,11 +2,15 @@
 
 Turns PR 1's raw spans/metrics into an answer to "why is MFU 14%?":
 
-- **Cost capture** — at compile time the fused trainer hands this module
-  an AOT compile thunk per dispatch-plan signature;
-  :func:`capture_cost` runs it once per signature, reads XLA's
-  ``cost_analysis()`` (costmodel.extract_cost), and exports live
-  ``anatomy.model_flops`` / ``anatomy.model_bytes_accessed`` gauges.
+- **Cost capture** — after the first dispatch of each dispatch-plan
+  signature the fused trainer hands this module a thunk that LOWERS the
+  same program (no backend compile: telemetry builds no executable the
+  untraced run does not build); :func:`capture_cost` runs it once per
+  signature, reads XLA's ``cost_analysis()`` of the lowering
+  (costmodel.extract_cost) or, where the backend offers none before
+  compilation (the TPU's), the hand count from the symbol's shapes, and
+  exports live ``anatomy.model_flops`` / ``anatomy.model_bytes_accessed``
+  gauges.
 - **Phase decomposition** — the fit loop calls :func:`begin_loop` /
   :func:`on_steps`; every MXTPU_ANATOMY_INTERVAL steps (and at epoch
   end) :func:`emit_interval` takes registry deltas of the phase-time
@@ -50,13 +54,22 @@ def enabled():
             and os.environ.get("MXTPU_ANATOMY", "1") not in ("", "0"))
 
 
-def wants_cost():
-    """Whether the trainers should run the extra AOT compile for XLA
-    cost analysis (MXTPU_ANATOMY_COSTS=0 skips it — the analysis itself
-    is free, but AOT lowering compiles the program a second time on
-    backends whose jit cache ignores the AOT path)."""
-    return (enabled()
-            and os.environ.get("MXTPU_ANATOMY_COSTS", "1") not in ("", "0"))
+def cost_pending(program_uid, key):
+    """Whether :func:`capture_cost` has yet to run for this program and
+    signature: the trainers build the abstract arguments of a lowering
+    only then. Once captured, asking makes that program's cost the
+    current one (what a cache hit of ``capture_cost`` does)."""
+    global _current_cost
+    if not enabled():
+        return False
+    with _lock:
+        if (program_uid, key) not in _cost_cache:
+            return True
+        cost = _cost_cache[(program_uid, key)]
+        if cost:
+            _current_cost = cost
+    _C_COST_HITS.inc()
+    return False
 
 
 def _interval_steps():
@@ -76,7 +89,7 @@ _C_COST_HITS = _registry.counter(
     "Cost-model lookups served from the per-signature cache")
 _C_COST_MISSES = _registry.counter(
     "anatomy.cost_cache_misses",
-    "Cost-model lookups that ran an AOT compile + cost_analysis()")
+    "Cost-model lookups that lowered the program for cost_analysis()")
 _G_MFU = _registry.gauge(
     "anatomy.mfu",
     "Model FLOPs utilization over the last anatomy interval: "
@@ -98,12 +111,18 @@ _cost_cache = {}  # (program_uid, key) -> {"flops", "bytes_accessed"} | None
 _current_cost = None  # the cost dict of the most recently dispatched program
 
 
-def capture_cost(program_uid, key, compile_thunk, steps=1, dtype=None):
-    """Resolve the per-step device cost of one compiled program.
+def capture_cost(program_uid, key, lower_thunk, steps=1, dtype=None,
+                 devices=1, analytic=None):
+    """Resolve the per-step, per-device cost of one program.
 
-    ``compile_thunk`` must return a jax AOT ``Compiled`` (built from the
-    SAME abstract args the dispatch will use); it runs at most once per
-    (program, signature). ``steps`` divides multi-step (scan-K) program
+    ``lower_thunk`` must return a jax stage with ``cost_analysis()``
+    built from the SAME abstract args the dispatch used — a ``Lowered``,
+    whose analysis needs no backend compile (its numbers are of the
+    program before XLA's optimizations and partitioning: operations
+    within a few percent of the executable's, bytes an upper bound, both
+    for all ``devices`` together). It runs at most once per (program,
+    signature). Where it yields nothing, ``analytic()`` gives the cost
+    per device instead. ``steps`` divides multi-step (scan-K) program
     totals back to per-step. ``dtype`` tags the program's compute dtype
     ("bf16"/"f32") so MFU is computed against the right roofline — fp32
     compute can never reach the bf16 peak the tables quote. Failures
@@ -121,12 +140,14 @@ def capture_cost(program_uid, key, compile_thunk, steps=1, dtype=None):
     _C_COST_MISSES.inc()
     cost = None
     try:
-        raw = costmodel.extract_cost(compile_thunk())
+        raw = costmodel.extract_cost(lower_thunk())
+        per = max(steps, 1) * max(devices, 1)
+        if not (raw["flops"] or raw["bytes_accessed"]) and analytic:
+            raw, per = analytic(), 1
         if raw["flops"] or raw["bytes_accessed"]:
             cost = {
-                "flops": (raw["flops"] or 0.0) / max(steps, 1),
-                "bytes_accessed":
-                    (raw["bytes_accessed"] or 0.0) / max(steps, 1),
+                "flops": (raw["flops"] or 0.0) / per,
+                "bytes_accessed": (raw["bytes_accessed"] or 0.0) / per,
             }
             if dtype:
                 cost["compute_dtype"] = str(dtype)

@@ -104,7 +104,8 @@ def peak_bytes_for_kind(kind):
 
 
 def extract_cost(compiled):
-    """Pull {"flops", "bytes_accessed"} out of a jax AOT ``Compiled``.
+    """Pull {"flops", "bytes_accessed"} out of a jax AOT stage
+    (``Compiled``, or a ``Lowered`` where the backend analyses one).
 
     ``cost_analysis()`` has returned a dict, a list of one dict per
     partition, and None across jax versions; any shape degrades to None

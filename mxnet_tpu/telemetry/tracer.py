@@ -7,13 +7,19 @@
 - aggregate into the ``mxtpu.span_seconds`` histogram (labelled by span
   name) — the per-phase totals ``tools/trace_summary.py`` and the
   Prometheus dump report,
+- enter a ``jax.profiler.TraceAnnotation`` of the same name, so that
+  while a ``jax.profiler`` trace is being taken every span lands in the
+  host plane of the same ``.xplane.pb`` as the device ops, on the
+  profiler's clock (bench/reduce_trace.py names idle gaps by them),
+- carry the step number as the shared identifier: a span opened with
+  ``step=`` hands it to every span opened inside it,
 - emit a complete chrome-trace ``"X"`` event into the profiler's event
   buffer when the profiler is running, so one ``profile.json`` shows
   framework spans alongside jax.profiler device traces,
 - append a JSONL record when ``MXTPU_TELEMETRY_FILE`` export is active.
 
 When telemetry is disabled ``span()`` returns a shared no-op context
-manager — no allocation, no clock read.
+manager — no allocation, no clock read, no annotation constructed.
 """
 from __future__ import annotations
 
@@ -42,8 +48,28 @@ class _NullSpan:
     def set_attrs(self, **attrs):
         pass
 
+    def discard(self):
+        pass
+
 
 _NULL = _NullSpan()
+
+# what an open span reports to, resolved at the first enabled span and
+# not at import: profiler pulls in jax at call sites and must never
+# become a hard dependency of the metrics layer
+_sinks = None
+
+
+def _resolve_sinks():
+    global _sinks
+    from jax.profiler import TraceAnnotation
+
+    from .. import profiler as _profiler
+    from . import export as _export
+
+    _sinks = (TraceAnnotation, _profiler.record_event_complete,
+              _export.emit_span)
+    return _sinks
 
 
 def _stack():
@@ -55,7 +81,7 @@ def _stack():
 
 class Span:
     __slots__ = ("name", "attrs", "parent", "depth", "_t0", "_ts_us",
-                 "duration")
+                 "duration", "_annotation", "_discarded")
 
     def __init__(self, name, attrs):
         self.name = name
@@ -63,16 +89,32 @@ class Span:
         self.parent = None
         self.depth = 0
         self.duration = None
+        self._discarded = False
 
     def set_attrs(self, **attrs):
         self.attrs.update(attrs)
+
+    def discard(self):
+        """Close without a record (the fit loop opens ``fit.step`` before
+        it knows whether the iterator holds another batch)."""
+        self._discarded = True
 
     def __enter__(self):
         st = _stack()
         if st:
             self.parent = st[-1]
             self.depth = self.parent.depth + 1
+            step = self.parent.attrs.get("step")
+            if step is not None:
+                self.attrs.setdefault("step", step)
         st.append(self)
+        # the same region on the profiler's clock (costs a flag test
+        # while no jax.profiler trace is being taken)
+        annotate = (_sinks or _resolve_sinks())[0]
+        step = self.attrs.get("step")
+        self._annotation = (annotate(self.name) if step is None
+                            else annotate(self.name, step=step))
+        self._annotation.__enter__()
         # wall clock for the trace timeline, monotonic for the duration
         self._ts_us = time.time() * 1e6
         self._t0 = time.perf_counter()
@@ -80,27 +122,25 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         self.duration = dur
         st = _stack()
         if st and st[-1] is self:
             st.pop()
+        if self._discarded:
+            return False
         SPAN_SECONDS.observe(dur, span=self.name)
         args = dict(self.attrs)
         if self.parent is not None:
             args["parent"] = self.parent.name
         if exc_type is not None:
             args["error"] = exc_type.__name__
-        # profiler buffer (no-op unless profiler_set_state("run"));
-        # deferred import: profiler pulls in jax at call sites and must
-        # never become a hard dependency of the metrics layer
-        from .. import profiler as _profiler
-
-        _profiler.record_event_complete(
+        _, record_event_complete, emit_span = _sinks
+        # profiler buffer (no-op unless profiler_set_state("run"))
+        record_event_complete(
             self.name, self._ts_us, dur * 1e6, category="framework",
             args=args or None)
-        from . import export as _export
-
-        _export.emit_span({
+        emit_span({
             "type": "span", "name": self.name, "ts": self._ts_us / 1e6,
             "dur": dur, "depth": self.depth,
             "thread": threading.get_ident() % 10000, "attrs": args,

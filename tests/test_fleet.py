@@ -352,7 +352,6 @@ def _run_rank(script_dir, run_dir, rank, extra_env=None, timeout=300):
         "DMLC_RANK": str(rank),
         "MXTPU_TELEMETRY": "1",
         "MXTPU_ANATOMY_INTERVAL": "4",
-        "MXTPU_ANATOMY_COSTS": "0",
     })
     env.update(extra_env or {})
     return subprocess.run([sys.executable, script], capture_output=True,

@@ -1,0 +1,351 @@
+"""The traced run is the untraced program seen from inside (ISSUE 24):
+spans cost nothing when telemetry is off, land on the profiler's clock
+when it is on, carry the step id down the tree; the fused step's named
+scopes are metadata only; and telemetry compiles nothing of its own.
+"""
+import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
+import time
+
+import jax
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import executor
+from mxnet_tpu import telemetry as tm
+from mxnet_tpu.parallel import train_step
+from mxnet_tpu.telemetry import anatomy, tracer
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+# table B of docs/observability.md: span -> parent inside one fit step
+SPAN_PARENT = {
+    "fit.step": None,
+    "fit.input": "fit.step",
+    "io.feed_fill": "fit.input",
+    "module.update": "fit.step",
+    "module.stage": "module.update",
+    "train_step.dispatch": "module.update",
+    "module.update_metric": "fit.step",
+    "fit.callbacks": "fit.step",
+    "fit.after_steps": "fit.step",
+}
+
+
+@pytest.fixture(autouse=True)
+def _isolate():
+    tm.reset()
+    tm.disable()
+    yield
+    tm.reset()
+    tm.disable()
+
+
+class _CountingAnnotation:
+    made = 0
+
+    def __init__(self, *args, **kwargs):
+        type(self).made += 1
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Counts every TraceAnnotation the tracer constructs."""
+    _CountingAnnotation.made = 0
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _CountingAnnotation)
+    monkeypatch.setattr(tracer, "_sinks", None)
+    return _CountingAnnotation
+
+
+def _toy_symbol():
+    net = mx.sym.Variable("data")
+    net = mx.sym.Convolution(net, num_filter=4, kernel=(3, 3), pad=(1, 1),
+                             name="conv0")
+    net = mx.sym.BatchNorm(net, name="bn0")
+    net = mx.sym.Activation(net, act_type="relu", name="relu0")
+    net = mx.sym.Pooling(net, kernel=(2, 2), stride=(2, 2), pool_type="max",
+                         name="pool0")
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=4,
+                                name="fc0")
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+def _toy_fit(batches=4, eval_metric="acc", **fit_kwargs):
+    """A fused fit of ``batches`` steps on two virtual devices."""
+    rng = np.random.RandomState(0)
+    n = 8 * batches
+    it = mx.io.NDArrayIter(rng.rand(n, 3, 8, 8).astype("f"),
+                           rng.randint(0, 4, n).astype("f"), batch_size=8)
+    mod = mx.mod.Module(_toy_symbol(), context=[mx.cpu(0), mx.cpu(1)])
+    mod.fit(it, eval_metric=eval_metric, optimizer="sgd", kvstore="device",
+            num_epoch=1, **fit_kwargs)
+    assert mod._fused_trainer is not None
+    return mod
+
+
+# ---------------------------------------------------------------------------
+# D. what it costs when off
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", sorted(SPAN_PARENT))
+def test_disabled_span_is_the_shared_null(name, annotations):
+    sp = tm.span(name, step=3)
+    assert sp is tracer._NULL
+    with sp as inner:
+        assert inner is tracer._NULL
+        inner.discard()
+    assert annotations.made == 0
+
+
+def test_disabled_fit_builds_no_span_and_no_annotation(annotations,
+                                                       monkeypatch):
+    built = []
+    init = tracer.Span.__init__
+    monkeypatch.setattr(tracer.Span, "__init__",
+                        lambda self, *a: (built.append(a), init(self, *a))[1])
+    _toy_fit()
+    assert built == [] and annotations.made == 0
+
+
+# ---------------------------------------------------------------------------
+# B. the span tree of one step
+# ---------------------------------------------------------------------------
+
+def test_fit_span_tree_parents_and_step_ids(tmp_path):
+    jsonl = str(tmp_path / "t.jsonl")
+    tm.enable(jsonl=jsonl)
+    _toy_fit(batches=4)
+    tm.flush()
+    spans = [json.loads(ln) for ln in open(jsonl)]
+    spans = [s for s in spans if s["type"] == "span"]
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["name"], []).append(s)
+    # one fit.step per batch: the fifth, opened to find the iterator
+    # empty, is discarded
+    assert len(by_name["fit.step"]) == 4
+    assert sorted(s["attrs"]["step"] for s in by_name["fit.step"]) == \
+        [1, 2, 3, 4]
+    for name, parent in SPAN_PARENT.items():
+        assert name in by_name, name
+        inside = [s for s in by_name[name]
+                  if s["attrs"].get("parent") == parent]
+        assert inside, (name, parent)
+        if parent is not None:
+            # children inherit the step id of the fit.step they are in
+            # (the wait that found the iterator empty belongs to a fifth,
+            # discarded, step)
+            ids = {s["attrs"]["step"] for s in inside}
+            assert {1, 2, 3, 4} <= ids <= {1, 2, 3, 4, 5}, (name, ids)
+    # a step's children lie inside it on the clock
+    step = by_name["fit.step"][1]
+    for name in ("fit.input", "module.update", "module.update_metric",
+                 "fit.callbacks", "fit.after_steps"):
+        kid = [s for s in by_name[name]
+               if s["attrs"].get("step") == step["attrs"]["step"]][0]
+        assert kid["ts"] >= step["ts"] - 1e-3
+        assert kid["ts"] + kid["dur"] <= step["ts"] + step["dur"] + 1e-3
+
+
+class _SleepyMetric(mx.metric.Accuracy):
+    def update(self, labels, preds):
+        time.sleep(0.02)
+        super().update(labels, preds)
+
+
+@pytest.mark.parametrize("interval", ["1", "2"])
+def test_output_sync_holds_the_metric_update(interval, monkeypatch):
+    """One timing site for both metric-interval paths: the histogram
+    holds what eval_metric.update takes, the blocking fetch included."""
+    monkeypatch.setenv("MXTPU_METRIC_INTERVAL", interval)
+    tm.enable()
+    _toy_fit(batches=4, eval_metric=_SleepyMetric())
+    sync = tm.snapshot()["module.output_sync_seconds"]["streams"]
+    assert sum(s["count"] for s in sync) == 4
+    assert sum(s["sum"] for s in sync) >= 4 * 0.02
+
+
+def test_spans_land_in_the_profilers_trace(tmp_path):
+    """Same clock as the device trace: a span is a TraceAnnotation in
+    the host plane of the .xplane.pb the profiler writes."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tm.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(3):
+            with tm.span("fit.step", step=i):
+                with tm.span("module.update"):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                         / "*.xplane.pb"))[0]
+    events = [e for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    steps = [e for e in events if e.name == "fit.step"]
+    updates = [e for e in events if e.name == "module.update"]
+    assert len(steps) == 3 and len(updates) == 3
+    for s, u in zip(sorted(steps, key=lambda e: e.start_ns),
+                    sorted(updates, key=lambda e: e.start_ns)):
+        assert s.start_ns <= u.start_ns
+        assert u.start_ns + u.duration_ns <= s.start_ns + s.duration_ns
+
+
+# ---------------------------------------------------------------------------
+# C. scopes inside the fused step are metadata only
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("op,cls", [
+    ("Convolution", "conv"), ("Deconvolution", "conv"),
+    ("FullyConnected", "fc"), ("BatchNorm", "bn"), ("Pooling", "pool"),
+    ("Activation", "act"), ("LeakyReLU", "act"), ("elemwise_add", "act"),
+    ("_Plus", "act"), ("SoftmaxOutput", "loss"), ("MakeLoss", "loss"),
+    ("LinearRegressionOutput", "loss"), ("Concat", "other"),
+    ("Flatten", "other"),
+])
+def test_op_class(op, cls):
+    assert executor.op_class(op) == cls
+
+
+def _lowered_step(monkeypatch):
+    """Lower the toy fit's fused step from what the trainer hands to the
+    cost capture (the abstract arguments of its first dispatch)."""
+    grabbed = {}
+    monkeypatch.setattr(
+        train_step.ShardedTrainStep, "_capture_cost",
+        lambda self, key, fn, specs, shapes: grabbed.update(
+            fn=fn, specs=specs))
+    tm.enable()
+    _toy_fit(batches=1)
+    tm.disable()
+    return grabbed["fn"].lower(*grabbed["specs"])
+
+
+def test_lowered_step_carries_the_scopes(monkeypatch):
+    text = _lowered_step(monkeypatch).as_text(debug_info=True)
+    for scope in ("fwd_bwd/jvp(conv/conv0)", "fwd_bwd/jvp(bn/bn0)",
+                  "jvp(pool/pool0)", "jvp(fc/fc0)", "jvp(act/relu0)",
+                  "jvp(loss/softmax)", "transpose(jvp(conv/conv0))",
+                  "jit(step)/update/"):
+        assert scope in text, scope
+
+
+def test_scopes_change_no_executable(monkeypatch):
+    import contextlib
+
+    def optimized(lowered):
+        return re.sub(r", metadata=\{[^}]*\}", "",
+                      lowered.compile().as_text())
+
+    with_scopes = optimized(_lowered_step(monkeypatch))
+    assert "jvp(conv/conv0)" not in with_scopes  # metadata is stripped
+    tm.reset()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert optimized(_lowered_step(monkeypatch)) == with_scopes
+
+
+# ---------------------------------------------------------------------------
+# A. telemetry builds no program of its own
+# ---------------------------------------------------------------------------
+
+def test_cost_capture_falls_back_to_the_hand_count():
+    class _NoAnalysis:  # a Lowered on the TPU's client
+        def cost_analysis(self):
+            return None
+
+    tm.enable()
+    assert anatomy.cost_pending(7, ("single", "sig"))
+    cost = anatomy.capture_cost(
+        7, ("single", "sig"), _NoAnalysis, devices=4,
+        analytic=lambda: {"flops": 30.0, "bytes_accessed": 12.0})
+    assert cost == {"flops": 30.0, "bytes_accessed": 12.0}
+    assert not anatomy.cost_pending(7, ("single", "sig"))
+
+    class _Global:  # the un-partitioned program: all devices together
+        def cost_analysis(self):
+            return {"flops": 80.0, "bytes accessed": 40.0}
+
+    cost = anatomy.capture_cost(8, ("single",), _Global, devices=4)
+    assert cost == {"flops": 20.0, "bytes_accessed": 10.0}
+
+
+_COUNT_COMPILES = textwrap.dedent("""
+    import sys
+    sys.path.insert(0, %r)
+    from __graft_entry__ import _force_cpu_mesh_platform
+    _force_cpu_mesh_platform(8)
+    import jax
+    import numpy as np
+    import mxnet_tpu as mx
+    from mxnet_tpu import telemetry as tm
+    from mxnet_tpu.models import mlp
+
+    seen = {"compiles": 0, "hits": 0, "misses": 0}
+
+    def on_event(event, **_):
+        if event.endswith("/cache_hits"):
+            seen["hits"] += 1
+        elif event.endswith("/cache_misses"):
+            seen["misses"] += 1
+
+    def on_duration(event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen["compiles"] += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    if sys.argv[1] == "on":
+        tm.enable()
+        jax.profiler.start_trace(sys.argv[2])
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.randn(64, 8).astype("f"),
+                           (rng.rand(64) > 0.5).astype("f"), batch_size=16)
+    mod = mx.mod.Module(mlp(num_classes=2, hidden=(8,)),
+                        context=[mx.cpu(i) for i in range(4)])
+    mod.fit(it, optimizer="sgd", kvstore="device", num_epoch=2)
+    if sys.argv[1] == "on":
+        jax.profiler.stop_trace()
+        assert tm.snapshot()["anatomy.model_flops"]["streams"][0]["value"] > 0
+    assert mod._fused_trainer.amp
+    print("COMPILES %%(compiles)d %%(hits)d %%(misses)d" %% seen)
+""") % REPO
+
+
+def test_telemetry_compiles_what_the_untraced_run_compiles(tmp_path):
+    """dp=4 with AMP (flat sharded update), a fresh cache directory for
+    each run: with telemetry on and a profiler slice running, the
+    backend compiles exactly the programs of the untraced run."""
+    script = tmp_path / "count_compiles.py"
+    script.write_text(_COUNT_COMPILES)
+    counts = {}
+    for mode in ("off", "on"):
+        cache = tmp_path / ("cache_" + mode)
+        cache.mkdir()
+        env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache),
+                   MXTPU_AMP="bf16", TF_CPP_MIN_LOG_LEVEL="3")
+        env.pop("MXTPU_TELEMETRY", None)
+        res = subprocess.run(
+            [sys.executable, str(script), mode, str(tmp_path / "trace")],
+            capture_output=True, text=True, timeout=300, env=env)
+        assert res.returncode == 0, res.stderr[-2000:]
+        line = [ln for ln in res.stdout.splitlines()
+                if ln.startswith("COMPILES")][-1]
+        counts[mode] = tuple(int(x) for x in line.split()[1:])
+    assert counts["off"][0] > 0
+    assert counts["on"] == counts["off"], counts

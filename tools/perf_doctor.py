@@ -129,9 +129,8 @@ def format_mfu_trajectory(records):
     pts = [(int(r.get("interval", i)), r["mfu"])
            for i, r in enumerate(records) if r.get("mfu") is not None]
     if not pts:
-        return ("no MFU values (cost model unresolved: check "
-                "MXTPU_ANATOMY_COSTS and the peak-rate table / "
-                "MXTPU_ANATOMY_PEAK_TFLOPS)")
+        return ("no MFU values (cost model unresolved: check the "
+                "peak-rate table / MXTPU_ANATOMY_PEAK_TFLOPS)")
     traj = " -> ".join("%.3f" % m for _, m in pts)
     vals = [m for _, m in pts]
     return "%s   (min %.3f, max %.3f, last %.3f over %d intervals)" % (

@@ -11,8 +11,8 @@ both from the hot loop:
   current step computes (MXTPU_DEVICE_FEED, default on), and
   Module._make_fused_batch adopts the staged buffers by sharding
   equality — no per-step asnumpy/device_put;
-- MXTPU_METRIC_INTERVAL=k drains metric fetches k steps behind the
-  dispatch frontier, so np.asarray never blocks the loop;
+- fit keeps one fused step in flight (both legs: it has no switch), so
+  the metric's np.asarray waits with the next step already enqueued;
 - _GraphProgram.dispatch_plan caches the per-(shape,dtype,sharding)
   canonicalization, so steady-state steps skip the dict churn.
 
@@ -35,7 +35,7 @@ Asserts (exit 1 on failure, DOB_NO_ASSERT=1 to only record):
 
 Run:    JAX_PLATFORMS=cpu python benchmarks/dispatch_overlap_bench.py
 Smoke:  DOB_SMOKE=1 ... (tiny sizes; asserts skipped)
-Env:    DOB_BATCH (256) DOB_STEPS (20 measured/epoch) DOB_IV (8)
+Env:    DOB_BATCH (256) DOB_STEPS (20 measured/epoch)
         DOB_TAG DOB_NO_ASSERT
 """
 from __future__ import annotations
@@ -53,7 +53,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 SMOKE = os.environ.get("DOB_SMOKE") == "1"
 BATCH = int(os.environ.get("DOB_BATCH", "32" if SMOKE else "256"))
 STEPS = int(os.environ.get("DOB_STEPS", "6" if SMOKE else "20"))
-METRIC_IV = int(os.environ.get("DOB_IV", "2" if SMOKE else "8"))
 NDEV = int(os.environ.get("DOB_DEVICES", "4"))
 
 # the fused mesh path needs a multi-device context; on CPU use the test
@@ -62,8 +61,7 @@ if os.environ.get("JAX_PLATFORMS", "cpu") == "cpu":
     from __graft_entry__ import _force_cpu_mesh_platform  # noqa: E402
 
     _force_cpu_mesh_platform(NDEV)
-_ENV_KNOBS = ("MXTPU_DEVICE_FEED", "MXTPU_METRIC_INTERVAL",
-              "MXNET_FIT_MULTISTEP")
+_ENV_KNOBS = ("MXTPU_DEVICE_FEED", "MXNET_FIT_MULTISTEP")
 
 
 def _convnet():
@@ -150,7 +148,6 @@ def measure(mode):
         os.environ["MXTPU_DEVICE_FEED"] = "0"
     else:
         os.environ["MXTPU_DEVICE_FEED"] = "1"
-        os.environ["MXTPU_METRIC_INTERVAL"] = str(METRIC_IV)
     try:
         telemetry.reset()
         # JSONL sink so the anatomy layer's per-interval step records
@@ -217,7 +214,6 @@ def main():
         "platform": dev.platform,
         "device_kind": getattr(dev, "device_kind", "?"),
         "batch": BATCH, "steps_per_epoch": STEPS,
-        "metric_interval": METRIC_IV,
         "rows": rows,
         "host_overhead_reduction_x": round(sync_ms / async_ms, 2)
         if async_ms else None,

@@ -34,9 +34,12 @@ _H_EPOCH_SECONDS = _tm.histogram(
     "fit.epoch_seconds", "Wall time of one training epoch")
 _G_DISPATCH_DEPTH = _tm.gauge(
     "fit.dispatch_depth",
-    "Steps the fit loop's dispatch frontier is ahead of the deferred "
-    "metric drain (0 = synchronous per-batch metric fetch; bounded by "
-    "MXTPU_METRIC_INTERVAL)")
+    "Enqueued steps whose metric fetch and batch-end callbacks have not "
+    "run yet (1 in the steady state of a fused fit, 0 on the executor "
+    "path and after every drain)")
+_C_LOOKAHEAD = _tm.counter(
+    "fit.lookahead_steps",
+    "Steps whose metric fetch began with a later step already enqueued")
 _C_RESUME_LOADED = _tm.counter(
     "resume.loaded", "fit() calls that restored state from a checkpoint")
 _C_RESUME_NONE = _tm.counter(
@@ -352,12 +355,11 @@ class BaseModule(object):
             validation_metric = eval_metric
 
         # -- async dispatch pipeline (docs/performance.md) -------------
-        # Both knobs act on the fused mesh path only, defaults = parity:
-        # MXTPU_DEVICE_FEED (on) wraps train_data in a DeviceFeedIter so
-        # the next batch's host->device transfer is in flight during
-        # compute; MXTPU_METRIC_INTERVAL=k defers the blocking per-batch
-        # metric fetch k steps behind the dispatch frontier (same
-        # accumulation order — the final metric is bitwise-identical).
+        # On the fused mesh path MXTPU_DEVICE_FEED (on) wraps train_data
+        # in a DeviceFeedIter so the next batch's host->device transfer
+        # is in flight during compute, and the loop keeps one step in
+        # flight: a step's metric fetch and batch-end callbacks run
+        # after the NEXT step has been enqueued (_post_step below).
         fit_data = train_data
         _trainer = getattr(self, "_fused_trainer", None)
         if (_trainer is not None
@@ -366,30 +368,47 @@ class BaseModule(object):
             from ..io import DeviceFeedIter
 
             fit_data = DeviceFeedIter(train_data, _trainer.batch_sharding())
-        try:
-            metric_iv = max(1, int(os.environ.get(
-                "MXTPU_METRIC_INTERVAL", "1")))
-        except ValueError:
-            metric_iv = 1
-        deferred_metrics = collections.deque()
+        # post-step work (labels, outputs, callback arguments) of the
+        # newest enqueued step; never more than one entry
+        in_flight = collections.deque()
 
-        def _queue_metric(data_batch):
-            snap = self._metric_snapshot() if metric_iv > 1 else None
-            if snap is None:
-                # cadence 1, or a path whose outputs can't be deferred
-                self.update_metric(eval_metric, data_batch.label)
+        def _run_post(labels, outs, cb_args):
+            if outs is not None:
+                # the step may no longer be the newest: serve ITS outputs
+                # to the metric and to get_outputs() in its callbacks
+                self._install_step_outputs(outs)
+            self.update_metric(eval_metric, labels)
+            with _tm.span("fit.callbacks"):
+                _fire(batch_end_callback, *cb_args)
+
+        def _post_step(epoch, nbatch, data_batch, cb_locals):
+            """The step of ``data_batch`` has just been enqueued. Where
+            its outputs stay valid while later steps dispatch (the fused
+            path: ``_metric_snapshot``), its metric fetch and callbacks
+            wait until the next step is enqueued and the PREVIOUS step's
+            run now, so the device always has a step queued behind the
+            one the host blocks on. Elsewhere (executor path, monitor)
+            the outputs are reused across steps: synchronous order."""
+            outs = self._metric_snapshot() if monitor is None else None
+            post = (data_batch.label, outs,
+                    (epoch, nbatch, eval_metric, cb_locals))
+            if outs is None:
+                _run_post(*post)
                 return
-            deferred_metrics.append((data_batch.label, snap))
-            while len(deferred_metrics) >= metric_iv:
-                labels, s = deferred_metrics.popleft()
-                self._apply_metric_snapshot(eval_metric, labels, s)
-            _G_DISPATCH_DEPTH.set(len(deferred_metrics))
+            if in_flight:
+                _C_LOOKAHEAD.inc()
+                _run_post(*in_flight.popleft())
+                # outside a callback get_outputs() serves the newest step
+                self._install_step_outputs(outs)
+            in_flight.append(post)
+            _G_DISPATCH_DEPTH.set(1)
 
-        def _drain_metrics():
-            while deferred_metrics:
-                labels, s = deferred_metrics.popleft()
-                self._apply_metric_snapshot(eval_metric, labels, s)
-            _G_DISPATCH_DEPTH.set(0)
+        def _drain_post():
+            """Catch the loop up: called wherever state must be that of
+            one step (epoch end, checkpoint capture, preemption)."""
+            _G_DISPATCH_DEPTH.set(0)  # nothing is enqueued behind it
+            while in_flight:
+                _run_post(*in_flight.popleft())
 
         # MXNET_FIT_MULTISTEP=K: group K batches into ONE XLA dispatch
         # (lax.scan over the fused step — Module.update_multi), amortizing
@@ -672,9 +691,9 @@ class BaseModule(object):
                 return
             if preempt["flag"]:
                 # grace path: dispatch frontier already behind us (the
-                # group completed), deferred metric fetches drain, and
+                # group completed), its pending post-step work runs, and
                 # the final checkpoint is written synchronously
-                _drain_metrics()
+                _drain_post()
                 ckpt_mgr.save(_capture(epoch, done), loop["gs"])
                 _C_PREEMPTED.inc()
                 self.logger.info(
@@ -694,7 +713,7 @@ class BaseModule(object):
                         # preemption path: the dispatch frontier is
                         # behind us, so the snapshot and the iterator
                         # position agree
-                        _drain_metrics()
+                        _drain_post()
                         ckpt_mgr.save(_capture(epoch, done), loop["gs"])
                         self.logger.info(
                             "elastic: replica(s) %s declared lost — "
@@ -705,7 +724,7 @@ class BaseModule(object):
             if (ckpt_interval
                     and loop["gs"] - loop["last_saved"] >= ckpt_interval):
                 loop["last_saved"] = loop["gs"]
-                _drain_metrics()
+                _drain_post()
                 ckpt_mgr.save_async(_capture(epoch, done), loop["gs"])
 
         old_handlers = {}
@@ -731,16 +750,16 @@ class BaseModule(object):
                         validation_metric, begin_epoch, num_epoch, monitor,
                         batch_end_callback, epoch_end_callback,
                         eval_end_callback, eval_batch_end_callback, fit_k,
-                        _queue_metric, _drain_metrics, _after_steps,
+                        _post_step, _drain_post, _after_steps,
                         ckpt_mgr, loop, _capture, resume_skip,
                         resume_metric, auto_tuner)
                     break
                 except _guard.GuardrailRewind as rw:
                     # -- rewind-to-last-good (docs/robustness.md) ------
                     # The dispatch frontier is at a group boundary (the
-                    # monitor only votes there); deferred metric
-                    # fetches are for steps about to be discarded.
-                    deferred_metrics.clear()
+                    # monitor only votes there); the pending post-step
+                    # work is for a step about to be discarded.
+                    in_flight.clear()
                     _G_DISPATCH_DEPTH.set(0)
                     self._drain_guard_diag()
                     ckpt_mgr.wait()  # in-flight async save must land
@@ -859,7 +878,7 @@ class BaseModule(object):
                     validation_metric, begin_epoch, num_epoch, monitor,
                     batch_end_callback, epoch_end_callback,
                     eval_end_callback, eval_batch_end_callback, fit_k,
-                    _queue_metric, _drain_metrics, _after_steps, ckpt_mgr,
+                    _post_step, _drain_post, _after_steps, ckpt_mgr,
                     loop, _capture, resume_skip, resume_metric,
                     auto_tuner=None):
         """Epoch loop body of :meth:`fit` (split out so the signal-window
@@ -915,10 +934,7 @@ class BaseModule(object):
                             _H_STEP_SECONDS.observe(per, epoch=str(epoch))
                     for (nbatch, db), outs in zip(pending, steps):
                         self._install_step_outputs(outs)
-                        _queue_metric(db)
-                        with _tm.span("fit.callbacks"):
-                            _fire(batch_end_callback, epoch, nbatch,
-                                  eval_metric, _cb_locals(nbatch, db))
+                        _post_step(epoch, nbatch, db, _cb_locals(nbatch, db))
                     # the K-group is atomic (one XLA dispatch applied all
                     # K updates), so step bookkeeping — and any interval
                     # / preemption checkpoint — lands on its boundary
@@ -936,10 +952,8 @@ class BaseModule(object):
                             self.update()
                             _H_STEP_SECONDS.observe(
                                 time.perf_counter() - t0, epoch=str(epoch))
-                            _queue_metric(db)
-                            with _tm.span("fit.callbacks"):
-                                _fire(batch_end_callback, epoch, nbatch,
-                                      eval_metric, _cb_locals(nbatch, db))
+                            _post_step(epoch, nbatch, db,
+                                       _cb_locals(nbatch, db))
                             _after_steps(epoch, nbatch + 1, 1)
 
             batches = iter(fit_data)
@@ -1002,17 +1016,17 @@ class BaseModule(object):
                         time.perf_counter() - t0, epoch=str(epoch))
                     if _tm.enabled():
                         _tm.sample_device_memory()
-                    _queue_metric(data_batch)
                     if monitor is not None:
                         monitor.toc_print()
-                    with _tm.span("fit.callbacks"):
-                        _fire(batch_end_callback, epoch, nbatch,
-                              eval_metric, locals())
+                    # a copy: locals() hands out one dict a frame, and
+                    # these may be read one step later
+                    _post_step(epoch, nbatch, data_batch, dict(locals()))
                     _after_steps(epoch, nbatch + 1, 1)
             if pending:
                 _flush_group(pending, epoch, eval_metric)
                 pending = []
-            _drain_metrics()  # deferred fetches land before epoch stats
+            _drain_post()  # the last step's metric and callbacks
+            # land before the epoch's statistics
             # close the partial anatomy interval on the epoch boundary so
             # its phase deltas land in the same JSONL flush below
             _tm.anatomy.emit_interval(force=True)
@@ -1158,14 +1172,16 @@ class BaseModule(object):
         return None
 
     def _metric_snapshot(self):
-        """Deferred-metric hook for fit()'s MXTPU_METRIC_INTERVAL path:
-        return per-step output state that stays valid k steps later
-        (Module's fused path returns its raw jax outputs), or None to
-        force the immediate update_metric path."""
+        """fit()'s lookahead hook: the outputs of the step just enqueued
+        in a form that stays valid while later steps dispatch (Module's
+        fused path returns its raw jax outputs), or None where outputs
+        are reused across steps, which keeps fit's post-step work
+        synchronous."""
         return None
 
-    def _apply_metric_snapshot(self, eval_metric, labels, snapshot):
-        """Accumulate one deferred step captured by _metric_snapshot."""
+    def _install_step_outputs(self, outs_raw):
+        """Publish what _metric_snapshot returned for one step as the
+        current outputs (update_metric and get_outputs serve them)."""
         raise NotImplementedError()
 
     def bind(self, data_shapes, label_shapes=None, for_training=True,

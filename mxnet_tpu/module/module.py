@@ -811,10 +811,11 @@ class Module(BaseModule):
         return steps
 
     def _install_step_outputs(self, outs_raw):
-        """Publish one micro-step's raw outputs as the current fused
-        outputs (fit's multi-step flush uses this per step so
-        update_metric/get_outputs serve that step's results — the
-        ONLY sanctioned way for callers to set fused-output state)."""
+        """Publish one step's raw outputs as the current fused outputs
+        (fit uses this per step, under its one-step lookahead and in the
+        multi-step flush, so update_metric/get_outputs serve that step's
+        results — the ONLY sanctioned way for callers to set
+        fused-output state)."""
         self._fused_outs_raw = outs_raw
         self._fused_outputs = None
 
@@ -861,44 +862,33 @@ class Module(BaseModule):
         if self._fused_trainer is not None and (
                 self._fused_outputs is not None
                 or self._fused_outs_raw is not None):
-            self._update_metric_fused(
-                eval_metric, labels, self._materialized_fused_outputs)
+            # The one place a fused step's outputs reach the metric
+            # (under fit's lookahead with the next step already
+            # enqueued behind it). Wrapping the device arrays is a host
+            # transfer only in multi-process runs; the blocking fetch
+            # happens inside eval_metric.update (NDArray.asnumpy). This
+            # interval, fetch and the metric's arithmetic together, is
+            # what module.output_sync_seconds holds.
+            with _tm.span("module.update_metric"):
+                t0 = time.perf_counter()
+                eval_metric.update(labels,
+                                   self._materialized_fused_outputs())
+                _H_OUTPUT_SYNC.observe(time.perf_counter() - t0)
             return
         with _tm.span("module.update_metric"):
             self._exec_group.update_metric(eval_metric, labels)
 
-    def _update_metric_fused(self, eval_metric, labels, make_outs):
-        """The one place a fused step's outputs reach the metric, now
-        or ``MXTPU_METRIC_INTERVAL`` steps late. ``make_outs()`` wraps
-        the device arrays (a host transfer only in multi-process runs);
-        the blocking fetch happens inside ``eval_metric.update``
-        (``NDArray.asnumpy``). This interval — fetch and the metric's
-        arithmetic together — is what ``module.output_sync_seconds``
-        holds."""
-        with _tm.span("module.update_metric"):
-            t0 = time.perf_counter()
-            eval_metric.update(labels, make_outs())
-            _H_OUTPUT_SYNC.observe(time.perf_counter() - t0)
-
     def _metric_snapshot(self):
-        """Deferred-metric hook (BaseModule.fit, MXTPU_METRIC_INTERVAL):
-        the fused path's raw per-step outputs are freshly allocated jax
-        arrays, so holding references keeps them valid while later steps
-        dispatch. Returns None on the executor path — its output
-        NDArrays are REUSED across steps, so a deferred read would see
-        a later step's values."""
+        """fit()'s lookahead hook: the fused path's raw per-step outputs
+        are freshly allocated jax arrays (never donated, never written
+        again), so holding references keeps them valid while later steps
+        dispatch. Returns None on the executor path: its output NDArrays
+        are REUSED across steps, so a read after the next dispatch would
+        see the later step's values, and fit stays synchronous there."""
         if (self._fused_trainer is not None
                 and self._fused_outs_raw is not None):
             return list(self._fused_outs_raw)
         return None
-
-    def _apply_metric_snapshot(self, eval_metric, labels, snapshot):
-        """Drain one deferred step: the blocking host transfer happens
-        HERE, k steps behind the dispatch frontier; accumulation math
-        and order match an immediate update_metric exactly."""
-        self._update_metric_fused(
-            eval_metric, labels,
-            lambda: [nd.NDArray(_local_rows(o)) for o in snapshot])
 
     def _sync_params_from_devices(self):
         """Parity module.py:666."""

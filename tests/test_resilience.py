@@ -9,6 +9,7 @@ resume has to fall back to the previous valid one.
 """
 import errno
 import os
+import pickle
 import signal
 import subprocess
 import sys
@@ -431,7 +432,8 @@ def _blob_iter(batch_size=8, n=64):
     return mx.io.NDArrayIter(x, y, batch_size=batch_size)
 
 
-def _fused_fit(ckpt_dir, metric, resume=None, num_epoch=1):
+def _fused_fit(ckpt_dir, metric, resume=None, num_epoch=1,
+               batch_end_callback=None):
     np.random.seed(0)
     mx.random.seed(0)
     mod = mx.mod.Module(_mlp(), context=FOUR_DEV)
@@ -439,7 +441,8 @@ def _fused_fit(ckpt_dir, metric, resume=None, num_epoch=1):
             optimizer="sgd",
             optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
             initializer=mx.init.Uniform(0.1), num_epoch=num_epoch,
-            checkpoint_dir=ckpt_dir, resume=resume)
+            checkpoint_dir=ckpt_dir, resume=resume,
+            batch_end_callback=batch_end_callback)
     assert mod._fused_trainer is not None
     return mod
 
@@ -478,6 +481,47 @@ def test_sigterm_preempts_with_final_checkpoint_and_exact_resume(
         np.testing.assert_array_equal(res_params[key], ref_params[key],
                                       err_msg="param %s drifted" % key)
     assert res_metric.get() == ref_metric.get()
+
+
+def test_preempt_with_a_post_step_pending_runs_it_before_the_checkpoint(
+        tmp_path, monkeypatch):
+    """fit keeps one step in flight: when the preemption is honoured
+    after step 3's dispatch, step 3's metric fetch and callback have
+    not run yet. The drain before the capture runs them, so the
+    checkpoint's metric covers step 3, every batch's callback fires
+    exactly once across the two runs with the uninterrupted run's
+    metric, and the resumed run ends on its final metric."""
+    monkeypatch.delenv(ck.ENV_INTERVAL, raising=False)
+    monkeypatch.delenv(fault.ENV, raising=False)
+
+    def recorder(seen):
+        return lambda p: seen.append((p.nbatch, p.eval_metric.get()))
+
+    ref_seen, ref_metric = [], mx.metric.create("acc")
+    ref = _fused_fit(str(tmp_path / "ref"), ref_metric,
+                     batch_end_callback=recorder(ref_seen))
+    assert [n for n, _ in ref_seen] == list(range(8))
+
+    pre_dir, pre_seen = str(tmp_path / "pre"), []
+    monkeypatch.setenv(fault.ENV, "preempt_at_step=3")
+    with pytest.raises(SystemExit) as exc:
+        _fused_fit(pre_dir, mx.metric.create("acc"),
+                   batch_end_callback=recorder(pre_seen))
+    assert exc.value.code == resilience.EXIT_PREEMPTED
+    monkeypatch.delenv(fault.ENV)
+    assert pre_seen == ref_seen[:3]
+    saved = pickle.loads(ck.CheckpointManager(pre_dir).load()["metric"])
+    assert saved.get() == ref_seen[2][1]
+
+    res_seen, res_metric = [], mx.metric.create("acc")
+    res = _fused_fit(pre_dir, res_metric, resume="auto",
+                     batch_end_callback=recorder(res_seen))
+    assert pre_seen + res_seen == ref_seen
+    assert res_metric.get() == ref_metric.get()
+    ref_params, res_params = _params_of(ref), _params_of(res)
+    for key in ref_params:
+        np.testing.assert_array_equal(res_params[key], ref_params[key],
+                                      err_msg="param %s drifted" % key)
 
 
 def test_async_interval_snapshots_survive_donation(tmp_path, monkeypatch):

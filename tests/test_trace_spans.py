@@ -137,6 +137,10 @@ def test_fit_span_tree_parents_and_step_ids(tmp_path):
     assert len(by_name["fit.step"]) == 4
     assert sorted(s["attrs"]["step"] for s in by_name["fit.step"]) == \
         [1, 2, 3, 4]
+    # fit keeps one step in flight: the metric fetch and the callbacks
+    # of step N run inside fit.step N+1, after its module.update, and
+    # those of the last step in the drain at the epoch's end
+    lagging = ("module.update_metric", "fit.callbacks")
     for name, parent in SPAN_PARENT.items():
         assert name in by_name, name
         inside = [s for s in by_name[name]
@@ -147,15 +151,27 @@ def test_fit_span_tree_parents_and_step_ids(tmp_path):
             # (the wait that found the iterator empty belongs to a fifth,
             # discarded, step)
             ids = {s["attrs"]["step"] for s in inside}
-            assert {1, 2, 3, 4} <= ids <= {1, 2, 3, 4, 5}, (name, ids)
-    # a step's children lie inside it on the clock
+            first = {2} if name in lagging else {1, 2}
+            assert first | {3, 4} <= ids <= {1, 2, 3, 4, 5}, (name, ids)
+    for name in lagging:
+        assert len(by_name[name]) == 4
+        drained = [s for s in by_name[name] if "parent" not in s["attrs"]]
+        assert len(drained) == 1 and drained[0]["ts"] >= max(
+            s["ts"] + s["dur"] for s in by_name["fit.step"]) - 1e-3
+    # a step's children lie inside it on the clock, the previous step's
+    # post-step work after this step's enqueue
     step = by_name["fit.step"][1]
+    kids = {}
     for name in ("fit.input", "module.update", "module.update_metric",
                  "fit.callbacks", "fit.after_steps"):
-        kid = [s for s in by_name[name]
-               if s["attrs"].get("step") == step["attrs"]["step"]][0]
+        kid = kids[name] = [
+            s for s in by_name[name]
+            if s["attrs"].get("step") == step["attrs"]["step"]][0]
         assert kid["ts"] >= step["ts"] - 1e-3
         assert kid["ts"] + kid["dur"] <= step["ts"] + step["dur"] + 1e-3
+    order = ["fit.input", "module.update", "module.update_metric",
+             "fit.callbacks", "fit.after_steps"]
+    assert sorted(order, key=lambda n: kids[n]["ts"]) == order
 
 
 class _SleepyMetric(mx.metric.Accuracy):
@@ -164,16 +180,32 @@ class _SleepyMetric(mx.metric.Accuracy):
         super().update(labels, preds)
 
 
-@pytest.mark.parametrize("interval", ["1", "2"])
-def test_output_sync_holds_the_metric_update(interval, monkeypatch):
-    """One timing site for both metric-interval paths: the histogram
-    holds what eval_metric.update takes, the blocking fetch included."""
-    monkeypatch.setenv("MXTPU_METRIC_INTERVAL", interval)
-    tm.enable()
-    _toy_fit(batches=4, eval_metric=_SleepyMetric())
-    sync = tm.snapshot()["module.output_sync_seconds"]["streams"]
-    assert sum(s["count"] for s in sync) == 4
-    assert sum(s["sum"] for s in sync) >= 4 * 0.02
+@pytest.mark.parametrize("kvstore,fused", [("device", True),
+                                           ("local", False)])
+def test_output_sync_holds_the_metric_update(kvstore, fused, tmp_path):
+    """One timing site per path: on the fused path, where the metric's
+    update runs one dispatch late, the histogram holds what
+    eval_metric.update takes, the blocking fetch included, once a step;
+    the executor path's update is the span alone."""
+    jsonl = str(tmp_path / "t.jsonl")
+    tm.enable(jsonl=jsonl)
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.rand(32, 3, 8, 8).astype("f"),
+                           rng.randint(0, 4, 32).astype("f"), batch_size=8)
+    mod = mx.mod.Module(_toy_symbol(), context=[mx.cpu(0), mx.cpu(1)])
+    mod.fit(it, eval_metric=_SleepyMetric(), optimizer="sgd",
+            kvstore=kvstore, num_epoch=1)
+    assert (mod._fused_trainer is not None) == fused
+    tm.flush()
+    spans = [json.loads(ln) for ln in open(jsonl)]
+    spans = [s for s in spans if s["type"] == "span"
+             and s["name"] == "module.update_metric"]
+    assert len(spans) == 4 and sum(s["dur"] for s in spans) >= 4 * 0.02
+    sync = tm.snapshot().get("module.output_sync_seconds",
+                             {"streams": []})["streams"]
+    assert sum(s["count"] for s in sync) == (4 if fused else 0)
+    if fused:
+        assert sum(s["sum"] for s in sync) >= 4 * 0.02
 
 
 def test_spans_land_in_the_profilers_trace(tmp_path):

@@ -14,6 +14,9 @@ Runs on a real TPU by default; --cpu routes onto the virtual host mesh
 (same trick as tests/conftest.py) so the sharded program is runnable
 anywhere. Data is a synthetic char-level corpus so the example is
 offline-complete (swap in a token file per the README for real text).
+
+For a transformer that is an ``mx.sym`` graph trained by ``Module.fit``
+(OLMoE: top-k sparse experts), see ``examples/train_olmoe_lm.py``.
 """
 from __future__ import annotations
 

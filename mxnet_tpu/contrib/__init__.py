@@ -4,3 +4,7 @@ from .. import autograd
 from . import autograd as _autograd_alias  # noqa: F401
 from . import ndarray
 from . import symbol
+
+# the reference's short names
+nd = ndarray
+sym = symbol

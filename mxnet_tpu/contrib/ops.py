@@ -806,4 +806,6 @@ CONTRIB_OP_EXPORTS = (
     "MultiBoxPrior", "MultiBoxTarget", "MultiBoxDetection", "Proposal",
     "ROIPooling", "CTCLoss", "ctc_loss", "fft", "ifft", "quantize",
     "dequantize", "count_sketch", "SwitchMoE",
+    # ops/transformer.py
+    "RMSNorm", "RoPE", "Attention", "TopKMoE",
 )

@@ -121,13 +121,17 @@ _OP_CLASS = {
     "FullyConnected": "fc", "BatchNorm": "bn", "Pooling": "pool",
     "Activation": "act", "LeakyReLU": "act", "relu": "act",
     "sigmoid": "act", "tanh": "act", "add_n": "act", "clip": "act",
-    "MakeLoss": "loss",
+    "MakeLoss": "loss", "softmax_cross_entropy": "loss",
+    "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
+    "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
+    "Embedding": "embed",
 }
 
 
 def op_class(op_name):
-    """conv | fc | bn | pool | act | loss | other: the class a node's
-    device ops are filed under (the first part of its named scope)."""
+    """conv | fc | bn | pool | act | loss | attn | moe | norm | embed |
+    other: the class a node's device ops are filed under (the first part
+    of its named scope)."""
     cls = _OP_CLASS.get(op_name)
     if cls is not None:
         return cls

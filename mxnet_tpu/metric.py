@@ -252,6 +252,25 @@ class CrossEntropy(EvalMetric):
         return float(-numpy.log(probs + self.eps).sum()), lab.size
 
 
+class Loss(EvalMetric):
+    """Mean of a loss the network computes itself (a ``MakeLoss`` head):
+    the name later MXNet gave this metric. Labels are ignored. Only the
+    outputs named by ``outputs`` (indices; default the first) are read,
+    so a symbol may carry side outputs behind ``BlockGrad`` after its
+    loss, and the fetch is the size of the loss, not of a probability
+    table."""
+
+    def __init__(self, name="loss", outputs=(0,)):
+        super().__init__(name)
+        self._outputs = tuple(outputs)
+
+    def update(self, _, preds):
+        for i in self._outputs:
+            loss = _host(preds[i])
+            self.sum_metric += float(loss.sum())
+            self.num_inst += loss.size
+
+
 class CustomMetric(EvalMetric):
     """Adapter for a user eval fn of (label_np, pred_np); the fn may
     return a bare score (counted per batch) or a (sum, count) pair."""
@@ -317,6 +336,7 @@ _REGISTRY = {
     "accuracy": Accuracy,
     "ce": CrossEntropy,
     "f1": F1,
+    "loss": Loss,
     "mae": MAE,
     "mse": MSE,
     "rmse": RMSE,

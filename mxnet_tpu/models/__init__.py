@@ -4,7 +4,10 @@ Capability parity targets (SURVEY.md §7 / BASELINE.md): MLP + LeNet
 (MNIST), ResNet-18/34/50/101/152 + ResNeXt, Inception-v3/BN, AlexNet,
 VGG (ImageNet), LSTM language models (PTB), and a transformer with ring
 attention (the TPU-native long-context flagship — beyond reference
-parity, standing in for its model-parallel LSTM).
+parity, standing in for its model-parallel LSTM). ``olmoe`` is
+OLMoE-1B-7B (64 SwiGLU experts, top-8, dropless) as an ``mx.sym`` graph
+of the ``RMSNorm`` / ``RoPE`` / ``Attention`` / ``TopKMoE`` ops, trained
+by ``Module.fit``; ``olmoe_reference`` is its plain float32 reference.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -18,3 +21,4 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
+from . import olmoe, olmoe_reference

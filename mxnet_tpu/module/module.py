@@ -54,6 +54,23 @@ _H_OUTPUT_SYNC = _tm.histogram(
     "— the device-sync leg of the step anatomy (telemetry/anatomy.py)")
 
 
+class _Released:
+    """What an executor array holds in place of its buffer while the
+    fused step owns the parameters: where a buffer of which shape and
+    type belongs (all ``NDArray.copyto`` and ``shape`` ask for)."""
+
+    __slots__ = ("shape", "dtype", "device")
+
+    def __init__(self, data):
+        self.shape, self.dtype, self.device = (
+            data.shape, data.dtype, data.device)
+
+    def __array__(self, *args, **kwargs):
+        raise MXNetError(
+            "this executor array gave its buffer up to the fused step: "
+            "read Module.get_params(), or run a forward first")
+
+
 def _local_rows(arr):
     """This process's rows of a (possibly multi-process) jax.Array.
     Single-process arrays pass through untouched; for a process-spanning
@@ -267,7 +284,10 @@ class Module(BaseModule):
             _impl(name, arr, aux_params)
         self.params_initialized = True
         self._params_dirty = False
-        self._exec_group.set_params(self._arg_params, self._aux_params)
+        if self._fused_trainer is None or not self._fused_exec_stale:
+            # (released under the fused step, as at fit's epoch end:
+            # _ensure_exec_params fills them when an executor runs)
+            self._exec_group.set_params(self._arg_params, self._aux_params)
 
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,
@@ -338,6 +358,9 @@ class Module(BaseModule):
         self._exec_group.reshape(self._data_shapes, self._label_shapes)
         if self.params_initialized:
             self._exec_group.set_params(self._arg_params, self._aux_params)
+            # fresh executors with fresh weights: the next fused update
+            # releases them again
+            self._fused_exec_stale = False
 
     def init_optimizer(self, kvstore="local", optimizer="sgd",
                        optimizer_params=(("learning_rate", 0.01),),
@@ -505,6 +528,8 @@ class Module(BaseModule):
             data_names=self._data_names, label_names=self._label_names,
         ).compile()
         self._fused_owner = self
+        # before the fused copies are placed: both at once do not fit
+        self._release_exec_arrays()
         if multiworker:
             # ranks may have initialized params independently; adopt the
             # kvstore's root-broadcast values (kv.init stored rank 0's)
@@ -524,7 +549,6 @@ class Module(BaseModule):
             self._fused_params = self._fused_trainer.amp_cast_params(
                 self._fused_params)
         self._fused_t = 0
-        self._fused_exec_stale = False
 
     def _make_fused_batch(self, data_batch):
         import jax
@@ -556,6 +580,35 @@ class Module(BaseModule):
             for name, arr in zip(self._label_names, data_batch.label):
                 batch[name] = _put(arr)
         return batch
+
+    def _release_exec_arrays(self):
+        """The fused step keeps parameters, gradients and optimizer state
+        on the mesh itself, so while it trains the executor group's own
+        weight and gradient buffers are dead weight (the gradients are
+        never written on this path, the weights are stale after one
+        step): two parameter-sized buffers per device, at 1.5 G
+        parameters the difference between fitting a 16 GB chip and not.
+        They are dropped here, each array keeping where a buffer of
+        which shape and type belongs; ``_ensure_exec_params`` fills the
+        weights again before any executor-path forward, and an executor
+        backward writes its gradients anew (``grad_req`` ``write``; an
+        accumulating gradient is left alone)."""
+        for exe in self._exec_group.execs:
+            for name in self._param_names:
+                arrays = [exe.arg_dict[name]]
+                if exe._grad_req.get(name) == "write":
+                    arrays.append(exe.grad_dict[name])
+                for arr in arrays:
+                    if not isinstance(arr._data, _Released):
+                        arr._data = _Released(arr._data)
+        self._fused_exec_stale = True
+
+    def _exec_arrays_went_stale(self, owner):
+        """A fused update ran: what an eval since the last one put into
+        the executor group is out of date, and goes."""
+        if not self._fused_exec_stale:
+            self._release_exec_arrays()
+        owner._fused_exec_stale = True
 
     def _ensure_exec_params(self):
         """Refresh executor-group weight copies after fused updates (the
@@ -603,6 +656,9 @@ class Module(BaseModule):
                 data_names=self._data_names, label_names=self._label_names,
                 flat_update=False,
             ).compile()
+            # the weight arrays shared with the owner are released or out
+            # of date, and this module's own gradient buffers as unused
+            self._release_exec_arrays()
         self.optimizer_initialized = True
 
     def forward(self, data_batch, is_train=None):
@@ -685,8 +741,7 @@ class Module(BaseModule):
             self._fused_outs_raw = outs
             self._fused_outputs = None
             self._fused_batch = None
-            owner._fused_exec_stale = True
-            self._fused_exec_stale = True
+            self._exec_arrays_went_stale(owner)
             return
         if self._update_on_kvstore:
             with _tm.span("module.update", path="kvstore"):
@@ -797,8 +852,7 @@ class Module(BaseModule):
             for _ in range(k):
                 _H_DISPATCH_HOST.observe(per)
         owner._fused_params, owner._fused_aux, owner._fused_opt = p, a, s
-        owner._fused_exec_stale = True
-        self._fused_exec_stale = True
+        self._exec_arrays_went_stale(owner)
         self._fused_batch = None
         # outs: stacked (K, rows, ...) per head; slice lazily per step
         steps = [[o[i] for o in outs] for i in range(k)]

@@ -18,3 +18,4 @@ from . import nn  # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import rnn_op  # noqa: F401
 from . import spatial  # noqa: F401
+from . import transformer  # noqa: F401

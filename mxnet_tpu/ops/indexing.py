@@ -80,13 +80,28 @@ def _embedding_infer(attrs, in_shapes):
     return [tuple(dshape), wshape], [tuple(dshape) + (out,)], []
 
 
+def _embedding_infer_type(attrs, in_types):
+    """The output has the TABLE's dtype, never the indices': ``dtype``
+    (later MXNet's parameter, default float32) unless the weight's type
+    is already known. Without this a bf16 model fed integer or float32
+    token ids would infer every downstream weight in the ids' type."""
+    from ..base import np_dtype
+
+    data_t, weight_t = in_types
+    if weight_t is None:
+        weight_t = np_dtype(attrs.get("dtype", "float32"))
+    return ([data_t if data_t is not None else np.float32, weight_t],
+            [weight_t], [])
+
+
 register(
     OpDef(
         "Embedding",
         _embedding,
         arguments=("data", "weight"),
-        defaults={"input_dim": 0, "output_dim": 0},
+        defaults={"input_dim": 0, "output_dim": 0, "dtype": "float32"},
         infer_shape=_embedding_infer,
+        infer_type=_embedding_infer_type,
     )
 )
 

@@ -96,3 +96,61 @@ def switch_moe(params, x, capacity_factor=1.25):
     return y.astype(x.dtype), aux_loss
 
 
+
+
+def topk_moe(params, x, top_k, norm_topk_prob=False):
+    """Dropless top-k MoE FFN with SwiGLU experts (the OLMoE / Mixtral
+    block). x: [tokens, d_model] -> ([tokens, d_model], counts [E]).
+
+    ``params``: ``gate_w`` [d, E] (router), ``w_gate_up`` [E, d, 2h]
+    (each expert's gate projection in the first ``h`` columns, its up
+    projection in the last), ``w_down`` [E, h, d]. Expert tensors lead
+    with E, so ``moe_partition_specs`` applies to them too.
+
+    No capacity: every (token, expert) pair of the routing is computed,
+    whatever the load. The ``tokens * top_k`` rows are sorted by expert
+    and the three expert matmuls run as two grouped matmuls over the
+    sorted rows (``jax.lax.ragged_dot``), so the work is that of the
+    routing and not ``O(T * E * C * d)`` as in ``switch_moe``'s dense
+    dispatch. The router (matmul at full float32 precision, softmax,
+    top-k) stays in float32 whatever the activations' dtype; routing
+    weights are not renormalised unless ``norm_topk_prob``. ``counts``
+    is the number of rows each expert received (int32, no gradient).
+    """
+    tokens, d_model = x.shape
+    num_experts = params["gate_w"].shape[1]
+    hidden = params["w_down"].shape[1]
+
+    with jax.named_scope("router"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), params["gate_w"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)               # [T, E]
+        weights, experts = jax.lax.top_k(probs, top_k)        # [T, k]
+        if norm_topk_prob:
+            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+
+    with jax.named_scope("dispatch"):
+        flat_expert = experts.reshape(-1)                     # [T*k]
+        order = jnp.argsort(flat_expert, stable=True)         # by expert
+        inverse = jnp.argsort(order)
+        counts = jnp.bincount(
+            flat_expert, length=num_experts).astype(jnp.int32)
+        rows = jnp.take(x, order // top_k, axis=0)            # [T*k, d]
+
+    with jax.named_scope("experts"):
+        gate_up = jax.lax.ragged_dot(
+            rows, params["w_gate_up"].astype(x.dtype), counts)
+        act = jax.nn.silu(gate_up[:, :hidden]) * gate_up[:, hidden:]
+        out_rows = jax.lax.ragged_dot(
+            act, params["w_down"].astype(x.dtype), counts)    # [T*k, d]
+
+    with jax.named_scope("combine"):
+        # back to token order by the inverse permutation (a gather, not
+        # a scatter-add), then the k weighted rows of a token summed in
+        # float32
+        per_token = jnp.take(out_rows, inverse, axis=0).reshape(
+            tokens, top_k, d_model)
+        y = jnp.einsum("tkd,tk->td", per_token.astype(jnp.float32),
+                       weights)
+    return y.astype(x.dtype), jax.lax.stop_gradient(counts)

@@ -40,6 +40,17 @@ def test_char_lstm_trains_and_samples():
     assert len(sampled) >= 20, repr(sampled)
 
 
+def test_olmoe_lm_trains_through_module_fit():
+    """examples/train_olmoe_lm.py: the tiny preset through Module.fit's
+    fused step learns its deterministic corpus and reports the expert
+    rows of the last step."""
+    out = _run_example("train_olmoe_lm.py", "--num-epochs", "3")
+    first, last = (float(x) for x in out.split("loss ")[1].split(" (")[0]
+                   .split(" -> "))
+    assert last < first - 2.0, out
+    assert "rows per expert" in out
+
+
 def test_adversary_fgsm_drops_accuracy():
     """examples/adversary_fgsm.py (reference example/adversary): the
     inputs_need_grad Module path must deliver real dLoss/dData — FGSM

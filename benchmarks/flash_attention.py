@@ -3,9 +3,14 @@
 Proves the hand-written MXU kernel (ops/pallas_kernels.py) compiles and
 runs on hardware (the test suite exercises interpret mode only), matches
 dense numerics, and unlocks sequence lengths whose O(T^2) score matrix
-cannot fit in HBM. Measured v5e r3: T=2048 flash 7.0 ms vs dense 35.6 ms
-(5.1x); flash alone runs to T=16384 on one chip (dense would need ~8.6GB
-of scores). Prints ONE JSON line.
+cannot fit in HBM (flash alone runs to T=16384 on one chip; dense would
+need ~8.6GB of scores). The kernel picks its tiles from (T, D, dtype)
+(`flash_tiles`; pass `block_q=` / `block_k=` to pin one) and feeds the MXU
+the type it is given: the float32 inputs here stay float32 operands, so
+this script times the float32 caller; the bf16 numbers at the OLMoE
+cell's shape are in PERF.md (PR 28). Measured on the v5e (chip run,
+PR 28): T=2048 flash 0.32 ms vs dense 29.25 ms; T=8192 1.72 ms; T=16384
+5.5 ms. Prints ONE JSON line.
 """
 import json
 import os

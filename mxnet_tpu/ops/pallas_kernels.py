@@ -29,6 +29,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import telemetry as _tm
+
 _NEG_INF = -1e30
 
 
@@ -76,29 +78,145 @@ def _pad_to(x, axis, mult):
 
 
 # ---------------------------------------------------------------------------
-# forward kernel: grid (B*H, nq, nk), k innermost. The output block index
-# map ignores the k dimension, so Mosaic keeps o_ref resident in VMEM
-# while the k loop accumulates into scratch; only one (block_q, block_k)
-# tile of each operand is on-chip at a time.
+# flash attention. What a (q tile, k tile) pair costs is decided in three
+# places, all below:
+#
+#   operands  the blocks go to the MXU in the type they arrive in (bf16
+#             stays bf16, one pass; float32 stays float32) and every
+#             product accumulates in float32. Scores, mask, running max,
+#             exp, row sums, lse, delta and the accumulators are float32;
+#             p and dS are rounded to the operand type before the four
+#             products that consume them — the rounding the kernel's own
+#             output takes anyway.
+#   tiles     ``flash_tiles`` picks (block_q, block_k) from (T, D, dtype)
+#             and a VMEM budget: a grid step costs ~0.3 us whatever it
+#             holds, so a 128 x 128 tile (0.04 us of bf16 work) is all
+#             overhead — so much so that the operand type changes
+#             nothing there (PERF.md section 6, PR 28).
+#   causal    a dead tile (above the diagonal) is skipped by ``pl.when``
+#             AND its index map names the tile already resident, so no
+#             DMA is issued for it; the iota/compare/select mask runs only
+#             on tiles the diagonal crosses or that hold padding.
+#
+# forward / dq: grid (B*H, nq, nk), k innermost; dkv: grid (B*H, nk, nq).
+# The output block index map ignores the innermost dimension, so Mosaic
+# keeps the output resident in VMEM while the inner loop accumulates into
+# scratch; one (block_q, block_k) tile pair is on-chip at a time.
+# dS = P * (dP - delta), P = exp(S - L), dP = dO V^T,
+# delta_i = sum_d dO_id * O_id.
 # ---------------------------------------------------------------------------
+
+_M_FLASH_LOWERINGS = _tm.counter(
+    "attention.flash_lowerings", "Traces of a flash_attention call site "
+    "(one per lowering, nothing per step); labels: operands (the type "
+    "the MXU is fed), block_q, block_k")
+
+# What one grid step may hold in VMEM as ``_flash_vmem_bytes`` counts it:
+# Mosaic's default scoped limit on the v5e. The calls ask for no more
+# (no ``vmem_limit_bytes``), so XLA keeps the rest of VMEM for its own
+# placement exactly as it did round the 128 x 128 kernel.
+_FLASH_VMEM_BUDGET = 16 * 1024 * 1024
+# Largest tiles worth taking, by measurement on the v5e (T 2048-8192,
+# D 64-256, bf16 and float32, causal and not: 1024 x 1024 is the fastest
+# or within 1% of it everywhere, 2048 is slower again; PERF.md section 7).
+_FLASH_MAX_BLOCK_Q = 1024
+_FLASH_MAX_BLOCK_K = 1024
+_FLASH_MIN_BLOCK = 128
+
+
+def _flash_vmem_bytes(block_q, block_k, d, itemsize):
+    """Upper bound on the VMEM one grid step of the widest kernel (dkv)
+    holds: double-buffered operand and result tiles, the float32
+    accumulators and two float32 score-shaped temporaries. Against the
+    smallest limit Mosaic compiles each shape under (v5e, 2 MiB steps):
+    12 MiB counted / 10 needed at 1024 x 1024, D = 128, bf16; 15 / 12 in
+    float32; 22 / 16 at D = 256 float32; 40 / 40 at 2048 x 2048."""
+    lanes = -(-d // 128) * 128
+    row_tiles = 2 * 2 * block_q * lanes * itemsize      # q, dO
+    col_tiles = 2 * 4 * block_k * lanes * itemsize      # k, v, dk, dv
+    acc = 2 * block_k * lanes * 4
+    scores = 2 * block_q * block_k * 4
+    return row_tiles + col_tiles + acc + scores
+
+
+def _one_tile(t):
+    """The tile that holds a whole short sequence: the next power of
+    two, 8 at the least."""
+    return max(8, 1 << (t - 1).bit_length())
+
+
+def flash_tiles(t, d, dtype):
+    """(block_q, block_k) for a sequence of ``t`` positions, head size
+    ``d``, operands of ``dtype``: the largest powers of two up to the
+    measured caps whose working set fits ``_FLASH_VMEM_BUDGET`` and that
+    pad ``t`` by no more than an eighth over what 128-wide tiles would.
+    A sequence shorter than the smallest tile gets one tile of its own
+    size (the next power of two, 8 at the least)."""
+    if t < _FLASH_MIN_BLOCK:
+        return _one_tile(t), _one_tile(t)
+    itemsize = jnp.dtype(dtype).itemsize
+    t_min = -(-t // _FLASH_MIN_BLOCK) * _FLASH_MIN_BLOCK
+
+    def pads_little(blk):
+        return -(-t // blk) * blk * 8 <= t_min * 9
+
+    block_q = block_k = _FLASH_MIN_BLOCK
+    # k first: a wider k tile amortises the per-step cost without
+    # lengthening the accumulators
+    while (block_k * 2 <= _FLASH_MAX_BLOCK_K and pads_little(block_k * 2)
+           and _flash_vmem_bytes(block_q, block_k * 2, d, itemsize)
+           <= _FLASH_VMEM_BUDGET):
+        block_k *= 2
+    while (block_q * 2 <= _FLASH_MAX_BLOCK_Q and pads_little(block_q * 2)
+           and _flash_vmem_bytes(block_q * 2, block_k, d, itemsize)
+           <= _FLASH_VMEM_BUDGET):
+        block_q *= 2
+    return block_q, block_k
+
+
+# Tile-index arithmetic goes through ``jax.lax`` directly: every jnp
+# call or operator on a tracer is a nested jit to trace, a millisecond
+# or two apiece inside a deep training step, and these run once per
+# index map per kernel per layer per platform branch.
+
+def _affine(i, mult, plus=0):
+    """i * mult + plus on an int32 index."""
+    out = jax.lax.mul(i, np.int32(mult))
+    return jax.lax.add(out, np.int32(plus)) if plus else out
+
 
 def _causal_block_live(qi, ki, block_q, block_k):
     """Whether k block ki intersects the causal triangle of q block qi."""
-    return ki * jnp.int32(block_k) <= qi * jnp.int32(block_q) + jnp.int32(
-        block_q - 1
-    )
+    return jax.lax.le(_affine(ki, block_k),
+                      _affine(qi, block_q, block_q - 1))
+
+
+def _last_live_k(qi, block_q, block_k):
+    """The last k block that ``_causal_block_live`` admits for q block
+    qi: what the k/v index maps of forward and dq clamp to."""
+    return jax.lax.div(_affine(qi, block_q, block_q - 1),
+                       np.int32(block_k))
+
+
+def _first_live_q(ki, block_q, block_k):
+    """The first q block that ``_causal_block_live`` admits for k block
+    ki: what the q/dO/lse/delta index maps of dkv clamp to."""
+    return jax.lax.div(_affine(ki, block_k), np.int32(block_q))
 
 
 def _masked_scores(q, k_blk, qi, ki, *, block_q, block_k, t_real, scale,
-                   causal):
+                   causal, masked=True):
     """The shared score/mask invariant of all three kernels:
     s = scale·q@kᵀ on the MXU plus the (padding, causal) keep-mask for
-    this (qi, ki) block pair. Kept in ONE place so forward and backward
-    can never disagree on masking."""
+    this (qi, ki) block pair — None for a tile ``_tile_cases`` found to
+    need none. Kept in ONE place so forward and backward can never
+    disagree on masking."""
     s = jnp.float32(scale) * jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [bq, bk]
+    if not masked:
+        return s, None
     q_pos = qi * jnp.int32(block_q) + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
@@ -111,150 +229,200 @@ def _masked_scores(q, k_blk, qi, ki, *, block_q, block_k, t_real, scale,
     return s, mask
 
 
+def _tile_cases(body, qi, ki, *, block_q, block_k, t_real, t_pad, causal):
+    """Run ``body(masked)`` for this tile pair at what it holds: not at
+    all for a dead tile, with the mask where the diagonal crosses it or
+    it holds padding keys, without it everywhere else."""
+    live = needs_mask = None  # None: statically "always" / "never"
+    if causal:
+        live = _causal_block_live(qi, ki, block_q, block_k)
+        needs_mask = jax.lax.gt(_affine(ki, block_k, block_k - 1),
+                                _affine(qi, block_q))
+    if t_real < t_pad:
+        pads = jax.lax.gt(_affine(ki, block_k, block_k), np.int32(t_real))
+        needs_mask = (pads if needs_mask is None
+                      else jax.lax.bitwise_or(needs_mask, pads))
+    if needs_mask is None:
+        body(False)
+        return
+    unmasked = jax.lax.bitwise_not(needs_mask)
+    if live is not None:
+        needs_mask = jax.lax.bitwise_and(live, needs_mask)
+        unmasked = jax.lax.bitwise_and(live, unmasked)
+    pl.when(needs_mask)(lambda: body(True))
+    pl.when(unmasked)(lambda: body(False))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
-                *, block_q, block_k, t_real, scale, causal):
+                *, block_q, block_k, t_real, t_pad, scale, causal):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _():
-        acc[:] = jnp.zeros_like(acc)
-        m_s[:] = jnp.full_like(m_s, jnp.float32(_NEG_INF))
-        l_s[:] = jnp.zeros_like(l_s)
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, jnp.float32(_NEG_INF))
+        l_s[...] = jnp.zeros_like(l_s)
 
-    live = True
-    if causal:
-        live = _causal_block_live(qi, ki, block_q, block_k)
-
-    @pl.when(live)
-    def _():
-        q = q_ref[0].astype(jnp.float32)  # [bq, D]
-        k_blk = k_ref[0].astype(jnp.float32)  # [bk, D]
-        v_blk = v_ref[0].astype(jnp.float32)
+    def body(masked):
+        v_blk = v_ref[0]  # [bk, D]
         s, mask = _masked_scores(
-            q, k_blk, qi, ki, block_q=block_q, block_k=block_k,
-            t_real=t_real, scale=scale, causal=causal)
-        s = jnp.where(mask, s, jnp.float32(_NEG_INF))
-
-        m_prev = m_s[:, 0]
-        m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(s - m_cur[:, None])
-        l_s[:, 0] = l_s[:, 0] * alpha + jnp.sum(p, axis=1)
-        m_s[:, 0] = m_cur
-        acc[:] = acc[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
+            q_ref[0], k_ref[0], qi, ki, block_q=block_q, block_k=block_k,
+            t_real=t_real, scale=scale, causal=causal, masked=masked)
+        if masked:
+            s = jnp.where(mask, s, jnp.float32(_NEG_INF))
+        m_prev = m_s[...]  # [bq, 1]
+        m_cur = jax.lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jax.lax.exp(m_prev - m_cur)
+        p = jax.lax.exp(s - m_cur)
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_s[...] = m_cur
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+                t_real=t_real, t_pad=t_pad, causal=causal)
 
     @pl.when(ki == nk - 1)
     def _():
-        l_fin = l_s[:, 0]
+        l_fin = l_s[...]
         safe_l = jnp.where(l_fin > 0, l_fin, jnp.float32(1.0))
-        o_ref[0] = (acc[:] / safe_l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
         # logsumexp residual for backward
-        l_ref[0, :, 0] = (m_s[:, 0] + jnp.log(safe_l)).astype(jnp.float32)
+        l_ref[0] = m_s[...] + jnp.log(safe_l)
 
 
-# ---------------------------------------------------------------------------
-# backward kernels. dq: grid (bh, nq, nk); dkv: grid (bh, nk, nq).
-# dS = P * (dP - delta), P = exp(S - L), dP = dO V^T,
-# delta_i = sum_d dO_id * O_id.
-# ---------------------------------------------------------------------------
+def _bwd_p_ds(q, k_blk, v_blk, do, lse, delta, qi, ki, masked, **tile):
+    """P and dS of one tile pair, rounded to the operand type: what dq
+    and dkv both rebuild from the residuals."""
+    s, mask = _masked_scores(q, k_blk, qi, ki, masked=masked, **tile)
+    p = jax.lax.exp(s - lse)
+    if masked:
+        p = jnp.where(mask, p, jnp.float32(0.0))
+    dp = jax.lax.dot_general(
+        do, v_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds = p * (dp - delta)
+    return p.astype(do.dtype), ds.astype(q.dtype)
+
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
-                   dq_acc, *, block_q, block_k, t_real, scale, causal):
+                   dq_acc, *, block_q, block_k, t_real, t_pad, scale,
+                   causal):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    live = True
-    if causal:
-        live = _causal_block_live(qi, ki, block_q, block_k)
-
-    @pl.when(live)
-    def _():
-        q = q_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = l_ref[0, :, 0]
-        delta = d_ref[0, :, 0]
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s, mask = _masked_scores(
-            q, k_blk, qi, ki, block_q=block_q, block_k=block_k,
+    def body(masked):
+        k_blk = k_ref[0]
+        _, ds = _bwd_p_ds(
+            q_ref[0], k_blk, v_ref[0], do_ref[0], l_ref[0], d_ref[0],
+            qi, ki, masked, block_q=block_q, block_k=block_k,
             t_real=t_real, scale=scale, causal=causal)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), jnp.float32(0.0))
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None])
-        dq_acc[:] = dq_acc[:] + jax.lax.dot_general(
+        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
+    _tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+                t_real=t_real, t_pad=t_pad, causal=causal)
+
     @pl.when(ki == nk - 1)
     def _():
-        dq_ref[0] = (jnp.float32(scale) * dq_acc[:]).astype(dq_ref.dtype)
+        dq_ref[0] = (jnp.float32(scale) * dq_acc[...]).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                    t_real, scale, causal):
+                    t_real, t_pad, scale, causal):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nq = pl.num_programs(2)
 
     @pl.when(qi == 0)
     def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    live = True
-    if causal:
-        live = _causal_block_live(qi, ki, block_q, block_k)
-
-    @pl.when(live)
-    def _():
-        k_blk = k_ref[0].astype(jnp.float32)  # [bk, D]
-        v_blk = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32)  # [bq, D]
-        do = do_ref[0].astype(jnp.float32)
-        lse = l_ref[0, :, 0]
-        delta = d_ref[0, :, 0]
-        s, mask = _masked_scores(
-            q, k_blk, qi, ki, block_q=block_q, block_k=block_k,
-            t_real=t_real, scale=scale, causal=causal)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), jnp.float32(0.0))
-        dv_acc[:] = dv_acc[:] + jax.lax.dot_general(
+    def body(masked):
+        q = q_ref[0]  # [bq, D]
+        do = do_ref[0]
+        p, ds = _bwd_p_ds(
+            q, k_ref[0], v_ref[0], do, l_ref[0], d_ref[0], qi, ki,
+            masked, block_q=block_q, block_k=block_k, t_real=t_real,
+            scale=scale, causal=causal)
+        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta[:, None])
-        dk_acc[:] = dk_acc[:] + jax.lax.dot_general(
+        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
+    _tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+                t_real=t_real, t_pad=t_pad, causal=causal)
+
     @pl.when(qi == nq - 1)
     def _():
-        dk_ref[0] = (jnp.float32(scale) * dk_acc[:]).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[0] = (jnp.float32(scale) * dk_acc[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
 # host-side wrappers
 # ---------------------------------------------------------------------------
+
+def _operand_label(dtype):
+    return {"bfloat16": "bf16", "float16": "f16",
+            "float32": "f32"}.get(jnp.dtype(dtype).name,
+                                  jnp.dtype(dtype).name)
+
+
+def _kernel_name(which, dtype, block_q, block_k):
+    return "flash_%s_%s_q%d_k%d" % (
+        which, _operand_label(dtype), block_q, block_k)
+
+
+_FLASH_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _tile_specs(block_q, block_k, d, causal, inner):
+    """Block specs of a q-shaped tile, a k-shaped tile and a per-row
+    statistic for a grid whose innermost dimension walks ``inner``
+    ("k": forward and dq, grid (bh, nq, nk); "q": dkv, grid
+    (bh, nk, nq)). Under ``causal`` the streamed operand's index clamps
+    to the row's (column's) live range: a dead step names the tile
+    already resident and fetches nothing."""
+    if inner == "k":
+        def q_idx(b, i, j):
+            return (b, i, 0)
+
+        def k_idx(b, i, j):
+            if causal:
+                j = jax.lax.min(j, _last_live_k(i, block_q, block_k))
+            return (b, j, 0)
+    else:
+        def q_idx(b, i, j):
+            if causal:
+                j = jax.lax.max(j, _first_live_q(i, block_q, block_k))
+            return (b, j, 0)
+
+        def k_idx(b, i, j):
+            return (b, i, 0)
+    return (pl.BlockSpec((1, block_q, d), q_idx),
+            pl.BlockSpec((1, block_k, d), k_idx),
+            pl.BlockSpec((1, block_q, 1), q_idx))
+
 
 def _fwd_call(q3, k3, v3, *, t_real, scale, causal, block_q, block_k,
               interpret):
@@ -263,21 +431,15 @@ def _fwd_call(q3, k3, v3, *, t_real, scale, causal, block_q, block_k,
     nk = t_pad // block_k
     kern = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, t_real=t_real,
-        scale=scale, causal=causal,
+        t_pad=t_pad, scale=scale, causal=causal,
     )
+    q_spec, k_spec, row_spec = _tile_specs(block_q, block_k, d, causal, "k")
     with _no_x64():
         out, lse = pl.pallas_call(
             kern,
             grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            ],
+            in_specs=[q_spec, k_spec, k_spec],
+            out_specs=[q_spec, row_spec],
             out_shape=[
                 jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
                 jax.ShapeDtypeStruct((bh, t_pad, 1), jnp.float32),
@@ -287,6 +449,8 @@ def _fwd_call(q3, k3, v3, *, t_real, scale, causal, block_q, block_k,
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
+            compiler_params=_FLASH_PARAMS,
+            name=_kernel_name("fwd", q3.dtype, block_q, block_k),
             interpret=interpret,
         )(q3, k3, v3)
     return out, lse
@@ -297,46 +461,29 @@ def _bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
     bh, t_pad, d = q3.shape
     nq = t_pad // block_q
     nk = t_pad // block_k
+    tile = dict(block_q=block_q, block_k=block_k, t_real=t_real,
+                t_pad=t_pad, scale=scale, causal=causal)
     with _no_x64():
+        q_spec, k_spec, row_spec = _tile_specs(
+            block_q, block_k, d, causal, "k")
         dq = pl.pallas_call(
-            functools.partial(
-                _bwd_dq_kernel, block_q=block_q, block_k=block_k,
-                t_real=t_real, scale=scale, causal=causal,
-            ),
+            functools.partial(_bwd_dq_kernel, **tile),
             grid=(bh, nq, nk),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, block_q, d), lambda b, i, j: (b, i, 0)
-            ),
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=_FLASH_PARAMS,
+            name=_kernel_name("dq", q3.dtype, block_q, block_k),
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
+        q_spec, k_spec, row_spec = _tile_specs(
+            block_q, block_k, d, causal, "q")
         dk, dv = pl.pallas_call(
-            functools.partial(
-                _bwd_dkv_kernel, block_q=block_q, block_k=block_k,
-                t_real=t_real, scale=scale, causal=causal,
-            ),
+            functools.partial(_bwd_dkv_kernel, **tile),
             grid=(bh, nk, nq),
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, j, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0)),
-            ],
+            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            out_specs=[k_spec, k_spec],
             out_shape=[
                 jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
                 jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
@@ -345,6 +492,8 @@ def _bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
             ],
+            compiler_params=_FLASH_PARAMS,
+            name=_kernel_name("dkv", q3.dtype, block_q, block_k),
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
@@ -385,8 +534,8 @@ def _flash_bwd(t_real, scale, causal, block_q, block_k, res, g):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
-def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
-                    block_k=128):
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None):
     """Blockwise (flash) attention. q/k/v: [B, T, H, D] -> [B, T, H, D].
 
     Pallas MXU kernels on TPU; the same kernels run under the Pallas
@@ -395,6 +544,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     (cudnn_rnn-inl.h being the closest 2017 analog of a fused
     sequence kernel).
 
+    The MXU is fed the type the inputs arrive in, accumulating in
+    float32; the softmax arithmetic is float32 whatever the inputs.
+    ``block_q`` / ``block_k`` default to ``flash_tiles(T, D, dtype)``;
+    pass them only to pin a tiling (tests, benchmarks).
+
     NOTE: pallas_call has no GSPMD partitioning rules — inside pjit over a
     sharded mesh, wrap calls in shard_map (see parallel/ring_attention for
     the sp-sharded composition) or keep attention inputs replicated.
@@ -402,9 +556,14 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=128,
     b, t, h, d = q.shape
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
-    blk = min(block_q, block_k)
-    if t < blk:
-        block_q = block_k = max(8, 1 << (t - 1).bit_length())
+    if block_q is None or block_k is None:
+        auto_q, auto_k = flash_tiles(t, d, q.dtype)
+        block_q = block_q or auto_q
+        block_k = block_k or auto_k
+    if t < min(block_q, block_k):
+        block_q = block_k = _one_tile(t)
+    _M_FLASH_LOWERINGS.inc(operands=_operand_label(q.dtype),
+                           block_q=int(block_q), block_k=int(block_k))
     q3 = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     k3 = k.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     v3 = v.transpose(0, 2, 1, 3).reshape(b * h, t, d)

@@ -1,0 +1,65 @@
+"""The flash kernels compile for the chip at real widths, without the
+chip: the TPU's compiler is installed here and compiles for a described
+v5e (interpret mode cannot see a tile Mosaic refuses, or a working set
+over the scoped-VMEM limit the calls leave at its default). The
+topology is described inside a fixture, never at import: only the worker
+that is handed this file loads the TPU's library. Keep every such test
+in THIS file."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mxnet_tpu.ops.pallas_kernels import flash_attention, flash_tiles
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no compiler here: skip
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# the OLMoE cell's call; the float32 caller at the widest head the
+# budget admits 1024-wide k tiles for; padding inside a large tile,
+# non-causal; a head narrower than a lane row
+SHAPES = [
+    (4096, 16, 128, jnp.bfloat16, True),
+    (4096, 4, 256, jnp.float32, True),
+    (1000, 4, 64, jnp.bfloat16, False),
+    (2176, 2, 32, jnp.float32, True),
+]
+
+
+@pytest.mark.parametrize("t,h,d,dtype,causal", SHAPES)
+def test_flash_chosen_tiles_compile_for_v5e(one_chip, t, h, d, dtype,
+                                            causal):
+    x = jax.ShapeDtypeStruct((1, t, h, d), dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=causal).astype(
+            jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile().as_text()
+    bq, bk = flash_tiles(t, d, dtype)
+    operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    for which in ("fwd", "dq", "dkv"):
+        # the kernel's name is the device op's name: what a trace shows
+        assert "flash_%s_%s_q%d_k%d" % (which, operands, bq, bk) in text

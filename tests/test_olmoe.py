@@ -283,8 +283,9 @@ def test_attention_t256_runs_the_flash_kernel(monkeypatch):
 
     got = jax.value_and_grad(system, argnums=(0, 1, 2))(q, k, v)
     want = jax.value_and_grad(reference, argnums=(0, 1, 2))(q, k, v)
-    # online softmax in 128-blocks against one softmax per row: same
-    # float32 arithmetic in another order, over up to 256 keys
+    # the flash kernel's online softmax (one 256 x 256 tile here, the
+    # chosen tiling) against one softmax per row: same float32
+    # arithmetic in another order, over up to 256 keys
     _close(got[0], want[0], "value", rtol=1e-5)
     for g, w_, name in zip(got[1], want[1], "qkv"):
         _close(g, w_, "d/d" + name, rtol=1e-4, ulps=64)

@@ -136,7 +136,7 @@ def score(jax, jnp, name, batch, bf16):
     # (the jitted call returns before the device computes; blocking
     # happens at the float() read above) — the same host component the
     # async-pipeline telemetry tracks for training
-    # (module.dispatch_host_seconds / dispatch_overlap_bench.py)
+    # (module.dispatch_host_seconds)
     disp = []
     for _ in range(max(3, REPS)):
         d0 = time.perf_counter()

@@ -70,9 +70,6 @@ _H_ALLREDUCE_SECONDS = _tm.histogram(
 _H_BUCKET_BYTES = _tm.histogram(
     "kvstore.bucket_bytes", "Payload bytes per coalesced gradient bucket "
     "(kvstore GradBucketer flushes and fused flat-update plan buckets)")
-_M_BUCKET_FLUSHES = _tm.counter(
-    "kvstore.bucket_flushes", "GradBucketer flushes (one count per "
-    "collective issued on the dist deferred-reduce queue)")
 # same name mesh.py uses for cross-process collectives — the registry
 # dedupes by name, so local reduces and gloo/jax collectives land in one
 # anatomy 'collective' phase
@@ -431,7 +428,6 @@ class KVStore(object):
                     if rdt != dtype:
                         flat = flat.astype(rdt)
                 _H_BUCKET_BYTES.observe(flat.nbytes, path="dist")
-                _M_BUCKET_FLUSHES.inc()
                 if two_phase:
                     # explicit reduce-scatter + all-gather round trip
                     # (the sharded-update decomposition) instead of one

@@ -96,130 +96,6 @@ def _check_input_names(symbol, names, typename, throw):
         logging.warning(msg)
 
 
-class _MultistepAutoTuner:
-    """``MXNET_FIT_MULTISTEP=auto``: grow the fused-step scan depth K
-    until host dispatch is invisible next to device time.
-
-    After each full K-group the tuner reads the async-pipeline phase
-    totals (``module.dispatch_host_seconds`` et al — the same counters
-    the anatomy record reports) and estimates the dispatch share of the
-    group's wall time using the anatomy's disjointness rule (dispatch
-    minus its staging sub-window, clamped at zero; device time is the
-    wall remainder after every host phase). While the share exceeds
-    ``MXTPU_DISPATCH_TARGET_FRAC`` (default 0.05) and K <
-    ``MXNET_FIT_MULTISTEP_MAX`` (default 32), K doubles. Each doubling
-    costs exactly one recompile, and the first group at each depth is
-    excluded from measurement so compile time never pollutes the
-    estimate. Once the target is met (or the cap is hit) the tuner
-    settles: K is frozen, every later group re-dispatches the same
-    compiled K-scan, and the steady state recompiles zero times.
-
-    Decisions land in the telemetry JSONL as ``type=multistep_auto``
-    records, and the current depth is stamped onto every anatomy
-    interval record via :func:`telemetry.anatomy.note_multistep`."""
-
-    _KEYS = {"dispatch": "module.dispatch_host_seconds",
-             "stage": "module.stage_host_seconds",
-             "input": "io.feed_wait_seconds"}
-
-    def __init__(self, logger=None):
-        def _env(name, default, cast):
-            try:
-                return cast(os.environ.get(name, default))
-            except ValueError:
-                return cast(default)
-
-        self.target = _env("MXTPU_DISPATCH_TARGET_FRAC", "0.05", float)
-        self.k_max = max(1, _env("MXNET_FIT_MULTISTEP_MAX", "32", int))
-        # measure at least this many steps per decision so one noisy
-        # group can't trigger a doubling
-        self.min_steps = max(
-            1, _env("MXTPU_MULTISTEP_AUTO_STEPS", "8", int))
-        self.k = min(2, self.k_max)
-        self.settled = self.k >= self.k_max
-        self.logger = logger
-        self.last_frac = None
-        self._skip = True
-        self._steps = 0
-        self._base = None
-        self._t0 = None
-        _tm.anatomy.note_multistep(self.k, settled=self.settled)
-
-    def _totals(self):
-        from ..telemetry import registry as _reg
-
-        return {k: _reg.REGISTRY.total(v) for k, v in self._KEYS.items()}
-
-    def _arm(self):
-        self._base = self._totals()
-        self._t0 = time.perf_counter()
-        self._steps = 0
-
-    def after_group(self, k_done):
-        """Called by the fit loop after each full K-group dispatch."""
-        if self.settled or k_done != self.k:
-            return
-        if not _tm.enabled():
-            # no phase counters to steer by: freeze at the initial depth
-            self._settle(None, "telemetry disabled")
-            return
-        if self._skip:
-            # the first group at this depth carries the K-scan compile;
-            # start measuring from the next one
-            self._skip = False
-            self._arm()
-            return
-        self._steps += k_done
-        if self._steps < self.min_steps:
-            return
-        now = self._totals()
-        wall = max(time.perf_counter() - self._t0, 1e-9)
-        disp = now["dispatch"] - self._base["dispatch"]
-        stage = now["stage"] - self._base["stage"]
-        feed = now["input"] - self._base["input"]
-        # anatomy's disjointness rule: the dispatch measurement window
-        # includes staging, so subtract it; device-side time is what is
-        # left of wall after every host phase
-        disp_adj = max(disp - stage, 0.0)
-        device = max(wall - feed - stage - disp_adj, 1e-9)
-        frac = disp_adj / device
-        self.last_frac = frac
-        if frac <= self.target:
-            self._settle(frac, "target met")
-        elif self.k >= self.k_max:
-            self._settle(frac, "depth cap")
-        else:
-            self.k = min(self.k * 2, self.k_max)
-            self._skip = True
-            self._record(frac, grown=True)
-            if self.logger is not None:
-                self.logger.info(
-                    "fit multistep auto: dispatch %.1f%% of device time "
-                    "> %.1f%% target, growing K to %d",
-                    100 * frac, 100 * self.target, self.k)
-
-    def _settle(self, frac, why):
-        self.settled = True
-        self._record(frac, grown=False, why=why)
-        if self.logger is not None:
-            self.logger.info(
-                "fit multistep auto: settled at K=%d (%s%s)", self.k, why,
-                "" if frac is None
-                else ", dispatch at %.1f%% of device time" % (100 * frac))
-
-    def _record(self, frac, grown, why=None):
-        _tm.anatomy.note_multistep(self.k, settled=self.settled,
-                                   dispatch_frac=frac)
-        rec = {"type": "multistep_auto", "k": self.k,
-               "settled": self.settled, "grown": grown,
-               "target_frac": self.target}
-        if frac is not None:
-            rec["dispatch_frac"] = round(frac, 4)
-        if why:
-            rec["why"] = why
-        _tm.anatomy.emit_decision(rec)
-
-
 class BaseModule(object):
     def __init__(self, logger=logging):
         self.logger = logger
@@ -410,25 +286,6 @@ class BaseModule(object):
             while in_flight:
                 _run_post(*in_flight.popleft())
 
-        # MXNET_FIT_MULTISTEP=K: group K batches into ONE XLA dispatch
-        # (lax.scan over the fused step — Module.update_multi), amortizing
-        # host dispatch overhead the way the reference's threaded engine
-        # hides it (threaded_engine_perdevice.cc:26-136). Metric updates
-        # and batch callbacks still fire once per batch, after the group.
-        # MXNET_FIT_MULTISTEP=auto hands depth selection to the tuner:
-        # K starts at 2 and doubles until dispatch_host is below
-        # MXTPU_DISPATCH_TARGET_FRAC of device time, then freezes.
-        auto_tuner = None
-        _fit_k_raw = os.environ.get("MXNET_FIT_MULTISTEP", "1")
-        if _fit_k_raw.strip().lower() == "auto":
-            auto_tuner = _MultistepAutoTuner(self.logger)
-            fit_k = auto_tuner.k
-        else:
-            try:
-                fit_k = int(_fit_k_raw)
-            except ValueError:
-                fit_k = 1
-
         # -- preemption-safe checkpointing (resilience/) ---------------
         from ..resilience import checkpoint as _ckpt
         from ..resilience import fault as _fault
@@ -563,7 +420,7 @@ class BaseModule(object):
         # MXTPU_ELASTIC=1 promotes heartbeat liveness from a reporter to
         # a driver: when a peer replica is declared lost mid-fit
         # (lost_ tombstone, or a heartbeat that went silent past
-        # MXTPU_ELASTIC_TIMEOUT), drain at the next group boundary,
+        # MXTPU_ELASTIC_TIMEOUT), drain at the next step boundary,
         # write a final synchronous checkpoint, and exit EXIT_RESHAPE —
         # the supervisor (tools/watchdog.py --elastic) relaunches at the
         # surviving world size, where resume="auto" re-binds the same
@@ -651,30 +508,30 @@ class BaseModule(object):
                 blob["health"] = guard_mon.health_blob(loop["gs"])
             return blob
 
-        def _after_steps(epoch, done, n_new):
+        def _after_step(epoch, done):
             with _tm.span("fit.after_steps"):
-                _bookkeep(epoch, done, n_new)
+                _bookkeep(epoch, done)
 
-        def _bookkeep(epoch, done, n_new):
-            """Bookkeeping after ``n_new`` batches finished training
-            (``done`` = batches of this epoch now fully trained). Fires
-            the fault harness per optimizer step, honors a pending
-            preemption, and takes interval snapshots — always on a group
-            boundary, so the captured params exactly match the recorded
-            iterator position."""
+        def _bookkeep(epoch, done):
+            """Bookkeeping at a step boundary: one more optimizer step
+            has been dispatched (``done`` = batches of this epoch now
+            trained). Fires the fault harness, honors a pending
+            preemption, and takes interval snapshots. Every capture
+            first drains the lookahead's pending post-step work
+            (``_drain_post``), so the captured params, metric and
+            iterator position are those of the same step."""
             if _fault.configured():
-                for s in range(loop["gs"] + 1, loop["gs"] + n_new + 1):
-                    _fault.fire("step", step=s)
-            loop["gs"] += n_new
+                _fault.fire("step", step=loop["gs"] + 1)
+            loop["gs"] += 1
             loop["done"] = done
             loop["epoch"] = epoch
-            _tm.anatomy.on_steps(n_new)
+            _tm.anatomy.on_steps(1)
             if fleet_hb is not None:
-                fleet_hb.progress(n_new)
+                fleet_hb.progress()
             if guard_mon is not None:
-                # fold the group's diag stream into the detector (one
-                # tiny host transfer per step, at the group boundary —
-                # never ahead of the dispatch frontier)
+                # fold the step's diag sample into the detector (one
+                # tiny host transfer per step, never ahead of the
+                # dispatch frontier)
                 rewind = False
                 for t, diag in self._drain_guard_diag():
                     verdict = guard_mon.observe(
@@ -690,9 +547,9 @@ class BaseModule(object):
             if ckpt_mgr is None:
                 return
             if preempt["flag"]:
-                # grace path: dispatch frontier already behind us (the
-                # group completed), its pending post-step work runs, and
-                # the final checkpoint is written synchronously
+                # grace path: the step has been dispatched, its pending
+                # post-step work runs, and the final checkpoint is
+                # written synchronously
                 _drain_post()
                 ckpt_mgr.save(_capture(epoch, done), loop["gs"])
                 _C_PREEMPTED.inc()
@@ -709,10 +566,9 @@ class BaseModule(object):
                                 timeout=elastic["timeout"])
                             if r != elastic["rank"]]
                     if lost:
-                        # drain-at-group-boundary, exactly like the
-                        # preemption path: the dispatch frontier is
-                        # behind us, so the snapshot and the iterator
-                        # position agree
+                        # drain at the step boundary, exactly like the
+                        # preemption path, so the snapshot and the
+                        # iterator position agree
                         _drain_post()
                         ckpt_mgr.save(_capture(epoch, done), loop["gs"])
                         self.logger.info(
@@ -730,10 +586,10 @@ class BaseModule(object):
         old_handlers = {}
         if ckpt_mgr is not None:
             def _on_preempt(signum, frame):
-                # flag only — the loop checkpoints at the next group
+                # flag only — the loop checkpoints at the next step
                 # boundary, where captured state and iterator position
-                # agree (checkpointing from the handler could tear a
-                # multi-step dispatch)
+                # agree (a handler can run between a step's dispatch and
+                # its bookkeeping)
                 preempt["flag"] = True
 
             for _sig in (signal.SIGTERM, signal.SIGINT):
@@ -749,16 +605,15 @@ class BaseModule(object):
                         fit_data, train_data, eval_data, eval_metric,
                         validation_metric, begin_epoch, num_epoch, monitor,
                         batch_end_callback, epoch_end_callback,
-                        eval_end_callback, eval_batch_end_callback, fit_k,
-                        _post_step, _drain_post, _after_steps,
+                        eval_end_callback, eval_batch_end_callback,
+                        _post_step, _drain_post, _after_step,
                         ckpt_mgr, loop, _capture, resume_skip,
-                        resume_metric, auto_tuner)
+                        resume_metric)
                     break
                 except _guard.GuardrailRewind as rw:
                     # -- rewind-to-last-good (docs/robustness.md) ------
-                    # The dispatch frontier is at a group boundary (the
-                    # monitor only votes there); the pending post-step
-                    # work is for a step about to be discarded.
+                    # The monitor votes at a step boundary; the pending
+                    # post-step work is for a step about to be discarded.
                     in_flight.clear()
                     _G_DISPATCH_DEPTH.set(0)
                     self._drain_guard_diag()
@@ -877,21 +732,15 @@ class BaseModule(object):
     def _fit_epochs(self, fit_data, train_data, eval_data, eval_metric,
                     validation_metric, begin_epoch, num_epoch, monitor,
                     batch_end_callback, epoch_end_callback,
-                    eval_end_callback, eval_batch_end_callback, fit_k,
-                    _post_step, _drain_post, _after_steps, ckpt_mgr,
-                    loop, _capture, resume_skip, resume_metric,
-                    auto_tuner=None):
+                    eval_end_callback, eval_batch_end_callback,
+                    _post_step, _drain_post, _after_step, ckpt_mgr,
+                    loop, _capture, resume_skip, resume_metric):
         """Epoch loop body of :meth:`fit` (split out so the signal-window
         try/finally in fit stays readable)."""
         from ..resilience import fault as _fault
 
         _tm.anatomy.begin_loop()
         self._note_op_costs(train_data)
-
-        def _k():
-            # the auto tuner's depth is live (it can grow between
-            # groups); a fixed MXNET_FIT_MULTISTEP=K never changes
-            return auto_tuner.k if auto_tuner is not None else fit_k
         for epoch in range(begin_epoch, num_epoch):
             tic = time.time()
             eval_metric.reset()
@@ -908,54 +757,6 @@ class BaseModule(object):
                 # already-trained batches are skipped, never re-fed:
                 # they consumed no RNG and must consume none on resume
                 fit_data.skip(skip)
-            pending = []  # (nbatch, data_batch) awaiting a K-group flush
-
-            def _flush_group(pending, epoch, eval_metric):
-                def _cb_locals(nbatch, data_batch):
-                    # match the normal path's BatchEndParam.locals keys
-                    # (callbacks reading locals['self']/['data_batch']
-                    # must keep working under MXNET_FIT_MULTISTEP)
-                    return dict(self=self, train_data=train_data,
-                                data_batch=data_batch, epoch=epoch,
-                                nbatch=nbatch, eval_metric=eval_metric,
-                                monitor=monitor)
-
-                if len(pending) == _k():
-                    with _tm.span("fit.step_group", epoch=epoch,
-                                  k=len(pending)):
-                        t0 = time.perf_counter()
-                        steps = self.update_multi([b for _, b in pending])
-                        dt = time.perf_counter() - t0
-                    if _tm.enabled():
-                        # amortized per-step cost so the histogram stays
-                        # comparable with the single-step path
-                        per = dt / len(pending)
-                        for _ in pending:
-                            _H_STEP_SECONDS.observe(per, epoch=str(epoch))
-                    for (nbatch, db), outs in zip(pending, steps):
-                        self._install_step_outputs(outs)
-                        _post_step(epoch, nbatch, db, _cb_locals(nbatch, db))
-                    # the K-group is atomic (one XLA dispatch applied all
-                    # K updates), so step bookkeeping — and any interval
-                    # / preemption checkpoint — lands on its boundary
-                    _after_steps(epoch, pending[-1][0] + 1, len(pending))
-                    if auto_tuner is not None:
-                        auto_tuner.after_group(len(pending))
-                else:
-                    # partial trailing group: single-step path (already
-                    # compiled; a one-off K'-step compile isn't worth it)
-                    for nbatch, db in pending:
-                        with _tm.span("fit.step", epoch=epoch,
-                                      nbatch=nbatch, step=loop["gs"] + 1):
-                            t0 = time.perf_counter()
-                            self.forward_backward(db)
-                            self.update()
-                            _H_STEP_SECONDS.observe(
-                                time.perf_counter() - t0, epoch=str(epoch))
-                            _post_step(epoch, nbatch, db,
-                                       _cb_locals(nbatch, db))
-                            _after_steps(epoch, nbatch + 1, 1)
-
             batches = iter(fit_data)
 
             def _next_batch():
@@ -966,9 +767,8 @@ class BaseModule(object):
                 if batch is not None and _fault.configured():
                     # poison-batch injection (nan_grad_at_step /
                     # loss_spike_at_step): this batch will feed
-                    # optimizer step gs + len(pending) + 1
-                    _mode = _fault.batch_poison(
-                        loop["gs"] + len(pending) + 1)
+                    # optimizer step gs + 1
+                    _mode = _fault.batch_poison(loop["gs"] + 1)
                     if _mode:
                         batch = _poison_batch(batch, _mode)
                 return batch
@@ -976,28 +776,6 @@ class BaseModule(object):
             nbatch = skip - 1
             while True:
                 nbatch += 1
-                use_multi = (
-                    _k() > 1 and monitor is None
-                    and getattr(self, "_fused_trainer", None) is not None
-                    and hasattr(self, "update_multi")
-                )
-                if use_multi:
-                    data_batch = _next_batch()
-                    if data_batch is None:
-                        break
-                    if (pending and any(
-                            tuple(p.shape) != tuple(d.shape)
-                            for p, d in zip(pending[0][1].data,
-                                            data_batch.data))):
-                        # shape break (e.g. last partial batch): flush
-                        # what we have before starting a new group
-                        _flush_group(pending, epoch, eval_metric)
-                        pending = []
-                    pending.append((nbatch, data_batch))
-                    if len(pending) == _k():
-                        _flush_group(pending, epoch, eval_metric)
-                        pending = []
-                    continue
                 # one span holds the whole step period, from the wait
                 # for the batch to the bookkeeping after it: what the
                 # spans inside it do not cover is the loop's own time
@@ -1021,10 +799,7 @@ class BaseModule(object):
                     # a copy: locals() hands out one dict a frame, and
                     # these may be read one step later
                     _post_step(epoch, nbatch, data_batch, dict(locals()))
-                    _after_steps(epoch, nbatch + 1, 1)
-            if pending:
-                _flush_group(pending, epoch, eval_metric)
-                pending = []
+                    _after_step(epoch, nbatch + 1)
             _drain_post()  # the last step's metric and callbacks
             # land before the epoch's statistics
             # close the partial anatomy interval on the epoch boundary so

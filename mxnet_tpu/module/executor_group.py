@@ -17,15 +17,8 @@ import numpy as np
 from .. import context as ctx_mod
 from .. import ndarray as nd
 from ..base import MXNetError
-from .. import telemetry as _tm
 from ..executor import Executor
 from ..io import DataDesc
-
-_M_LOAD_FASTPATH = _tm.counter(
-    "executor_group.load_fastpath",
-    "Whole-batch input loads served by aliasing the (immutable) source "
-    "buffer instead of slice + copyto (single target slice, matching "
-    "shape/dtype/sharding)")
 
 
 def _split_input_slice(batch_size, work_load_list):
@@ -71,7 +64,6 @@ def _load_general(data, targets):
                     and dst.dtype == src.dtype
                     and getattr(src, "sharding", None)
                     == getattr(dst, "sharding", None)):
-                _M_LOAD_FASTPATH.inc()
                 d_dst._data = src
                 continue
         for slice_idx, d_dst in d_targets:
@@ -264,6 +256,10 @@ class DataParallelExecutorGroup(object):
         """Weighted merge back to CPU params (parity executor_group.py:317:
         the reference averages weight copies across devices)."""
         for name, block in zip(self.param_names, self.param_arrays):
+            for w in block:
+                # copyto reads its source as it stands: a kvstore pull
+                # into this weight may still be in flight
+                w.wait_to_read()
             weight = sum(w.copyto(ctx_mod.cpu()) for w in block) / len(block)
             weight.astype(arg_params[name].dtype).copyto(arg_params[name])
         for name, block in zip(self.aux_names, self.aux_arrays):

@@ -30,8 +30,7 @@ _H_DISPATCH_HOST = _tm.histogram(
     "Host wall time to stage inputs + enqueue one fused train step "
     "(the dispatch returns before the device finishes, so this is the "
     "pure per-step host overhead — executor.step_seconds' host "
-    "component; multi-step dispatches record the amortized per-step "
-    "cost)")
+    "component)")
 _H_STAGE_HOST = _tm.histogram(
     "module.stage_host_seconds",
     "Host wall time of the input-STAGING slice of a fused step "
@@ -758,118 +757,11 @@ class Module(BaseModule):
                     kvstore=self._kvstore
                 )
 
-    def update_multi(self, data_batches):
-        """Run len(data_batches) fused training steps in ONE XLA dispatch
-        (lax.scan over the fused step; ShardedTrainStep.compile_multi).
-
-        Used by fit() under MXNET_FIT_MULTISTEP=K to amortize the
-        per-dispatch host overhead (its share of a step on the chip is
-        not measured; PERF.md); the reference hides the same overhead
-        with its threaded engine
-        (threaded_engine_perdevice.cc:26-136). Per-step math, lr
-        schedule, and num_update advance identically to K update()
-        calls. Returns a list of per-step raw output lists so the
-        caller can update metrics per micro-step (Speedometer
-        semantics). Requires the fused path and identically-shaped
-        batches."""
-        assert self._fused_trainer is not None, "fused path required"
-        assert self._fused_batch is None, \
-            "pending forward(); use update() for it first"
-        owner = self._fused_owner
-        trainer = self._fused_trainer
-        optm = self._optimizer
-        k = len(data_batches)
-        if (self._kvstore is not None
-                and getattr(self._kvstore, "_heartbeat", None) is not None):
-            # one dispatch = K optimizer steps: credit all K ticks so a
-            # progress watchdog tuned to per-batch cadence doesn't
-            # false-trip mid-dispatch (ADVICE r5)
-            self._kvstore._heartbeat.progress(ticks=k)
-        self._params_dirty = True
-
-        t0_host = time.perf_counter()
-        sharding = trainer.batch_sharding_stacked()
-        per_batch_sharding = trainer.batch_sharding()
-        multiproc = getattr(self, "_fused_multiproc", False) or getattr(
-            owner, "_fused_multiproc", False)
-
-        def _put_stack(arrs):
-            import jax
-
-            if not multiproc:
-                datas = [getattr(a, "_data", None) for a in arrs]
-                if all(d is not None
-                       and getattr(d, "sharding", None) == per_batch_sharding
-                       for d in datas):
-                    # DeviceFeedIter already staged every micro-batch on
-                    # the mesh: stack device-side instead of bouncing K
-                    # batches through the host (composes the K-step scan
-                    # path with the double-buffered feed)
-                    import jax.numpy as jnp
-
-                    _M_FEED_HITS.inc(len(arrs))
-                    return jax.device_put(jnp.stack(datas), sharding)
-            stacked = np.stack([a.asnumpy() for a in arrs])
-            if multiproc:
-                return jax.make_array_from_process_local_data(
-                    sharding, stacked)
-            return jax.device_put(stacked, sharding)
-
-        batches = {}
-        with _tm.span("module.stage"):
-            for i, name in enumerate(self._data_names):
-                batches[name] = _put_stack(
-                    [b.data[i] for b in data_batches])
-            if self._label_names and data_batches[0].label:
-                for i, name in enumerate(self._label_names):
-                    batches[name] = _put_stack(
-                        [b.label[i] for b in data_batches])
-        if _tm.enabled():
-            per_stage = (time.perf_counter() - t0_host) / k
-            for _ in range(k):
-                _H_STAGE_HOST.observe(per_stage)
-
-        # advance the schedule exactly as K update() calls would
-        lrs, ts = [], []
-        for _ in range(k):
-            owner._fused_t += 1
-            optm.num_update = max(owner._fused_t, optm.num_update)
-            lrs.append(optm.lr_scheduler(optm.num_update)
-                       if optm.lr_scheduler is not None else optm.lr)
-            ts.append(owner._fused_t)
-
-        if self is not owner and self._fused_params is None:
-            self._fused_params = owner._fused_params
-            self._fused_aux = owner._fused_aux
-            self._fused_opt = owner._fused_opt
-        p, a, s, outs = trainer.call_multi(
-            owner._fused_params, owner._fused_aux, owner._fused_opt,
-            batches, lrs, ts)
-        if _tm.enabled():
-            # amortized per-step host cost, recorded once per micro-step
-            # so the histogram stays comparable with update()'s samples
-            per = (time.perf_counter() - t0_host) / k
-            for _ in range(k):
-                _H_DISPATCH_HOST.observe(per)
-        owner._fused_params, owner._fused_aux, owner._fused_opt = p, a, s
-        self._exec_arrays_went_stale(owner)
-        self._fused_batch = None
-        # outs: stacked (K, rows, ...) per head; slice lazily per step
-        steps = [[o[i] for o in outs] for i in range(k)]
-        if getattr(trainer, "guard", False):
-            owner._guard_pending = getattr(owner, "_guard_pending", [])
-            for i in range(k):
-                owner._guard_pending.append((ts[i], steps[i].pop()))
-        # leave the LAST step's outputs readable via get_outputs()
-        self._install_step_outputs(steps[-1])
-        return steps
-
     def _install_step_outputs(self, outs_raw):
         """Publish one step's raw outputs as the current fused outputs
-        (fit uses this per step, under its one-step lookahead and in the
-        multi-step flush, so update_metric/get_outputs serve that step's
-        results — the ONLY sanctioned way for callers to set
-        fused-output state)."""
+        (fit uses this per step, under its one-step lookahead, so
+        update_metric/get_outputs serve that step's results — the ONLY
+        sanctioned way for callers to set fused-output state)."""
         self._fused_outs_raw = outs_raw
         self._fused_outputs = None
 
@@ -877,7 +769,7 @@ class Module(BaseModule):
         """Return queued (step_t, diag) guardrail samples and clear the
         queue.  diag is a length-3 float32 vector (loss, grad-norm²,
         gate_ok); materialising it here is the only host sync the
-        guardrail adds, one tiny transfer per step group."""
+        guardrail adds, one tiny transfer per step."""
         owner = self._fused_owner or self
         pending = getattr(owner, "_guard_pending", None)
         if not pending:

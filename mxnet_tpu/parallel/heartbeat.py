@@ -137,7 +137,6 @@ class HeartbeatWriter:
         self._stop = threading.Event()
         self._thread = None
         self._last_prog = 0.0
-        self._last_ticks = 0
         self._lost = False  # sticky once the tombstone is seen
         os.makedirs(directory, exist_ok=True)
 
@@ -191,34 +190,19 @@ class HeartbeatWriter:
                 return
             self._thread = None
 
-    def progress(self, ticks=1):
+    def progress(self):
         """Mark forward progress from the worker's OWN thread (kvstore
         push/pull/barrier; fused update). Rate-limited to one touch per
-        interval so per-key push loops don't turn into an utime storm.
-
-        ``ticks`` > 1 reports a multi-batch dispatch (Module.update_multi
-        runs K optimizer steps per host call, so the next report is K
-        batch-times away). The K-1 extra ticks bank FUTURE mtime credit
-        — estimated from the previous inter-report gap — so
-        ``tools/watchdog.py --progress-timeout`` tuned to per-batch
-        cadence doesn't false-trip mid-dispatch (ADVICE r5)."""
+        interval so per-key push loops don't turn into an utime storm."""
         now = time.monotonic()
-        if ticks <= 1 and now - self._last_prog < self._interval:
+        if now - self._last_prog < self._interval:
             return
         if self._is_lost() or os.path.exists(
                 _tombstone(self._dir, _STALL_PREFIX, self.rank)):
             return  # tombstoned: the rank must LOOK wedged to pollers
-        per_tick = 0.0
-        if self._last_prog > 0.0 and self._last_ticks > 0:
-            per_tick = max(0.0, now - self._last_prog) / self._last_ticks
         self._last_prog = now
-        self._last_ticks = ticks
         try:
             _touch(self._prog_path)
-            credit = (ticks - 1) * per_tick
-            if credit > 0.0:
-                t = time.time() + credit
-                os.utime(self._prog_path, (t, t))
         except OSError:
             pass  # progress is advisory; liveness beat handles teardown
 

@@ -38,9 +38,6 @@ from ..base import bucket_bytes_env as _env_bucket_bytes
 _M_STEPS = _tm.counter(
     "train_step.steps", "Optimizer steps dispatched through the fused "
     "ShardedTrainStep path")
-_M_FLAT_BUCKETS = _tm.counter(
-    "train_step.flat_buckets", "Flat update buckets planned by the "
-    "sharded/bucketed fused-update path (one count per bucket per plan)")
 _H_BUCKET_BYTES = _tm.histogram(
     "kvstore.bucket_bytes", "Payload bytes per coalesced gradient bucket "
     "(kvstore GradBucketer flushes and fused flat-update plan buckets)")
@@ -109,7 +106,6 @@ class _FlatUpdatePlan:
             for (i, name, off, size, shape) in b.views:
                 self.by_name[name] = (bi, off, size, shape)
         for b in self.buckets:
-            _M_FLAT_BUCKETS.inc()
             _H_BUCKET_BYTES.observe(
                 b.size * np.dtype(b.dtype).itemsize, path="flat_update")
 
@@ -238,7 +234,6 @@ class ShardedTrainStep:
         self.param_specs = dict(param_specs or {})
         self._batch_spec = P("dp")
         self._step = None
-        self._step_multi = {}  # K -> jitted K-step scan program
         self._needs_rng = any(
             (not n.is_variable) and n.op.needs_rng
             for n in self.program.nodes
@@ -315,11 +310,11 @@ class ShardedTrainStep:
         # branchless select — generalized to fp32 — so a non-finite or
         # out-of-threshold gradient updates NOTHING, bitwise.
         # guard_threshold is the host-side grad-norm² bound the
-        # GuardrailMonitor refreshes at group boundaries; it rides into
+        # GuardrailMonitor refreshes at step boundaries; it rides into
         # the compiled program as a traced scalar (no recompiles), inf
         # means "gate on non-finite only" (detector warmup). fit() arms
         # this AFTER construction (guardrails="auto"), re-jitting the
-        # already-lazy step wrappers.
+        # already-lazy step wrapper.
         self.guard = False
         self.guard_threshold = float("inf")
 
@@ -584,21 +579,12 @@ class ShardedTrainStep:
         # BEFORE this conversion drops the master/scale keys
         self.amp = False
         self._step = None
-        self._step_multi = {}
         return placed
 
     def batch_sharding(self):
         from jax.sharding import NamedSharding
 
         return NamedSharding(self.mesh, self._batch_spec)
-
-    def batch_sharding_stacked(self):
-        """Sharding for a (K, batch, ...) stack of K step batches: the
-        scan axis is unsharded, rows shard over dp like batch_sharding."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        return NamedSharding(
-            self.mesh, P(*((None,) + tuple(self._batch_spec))))
 
     # ------------------------------------------------------------------
     def place_params(self, arg_arrays_by_name, aux_arrays_by_name):
@@ -1259,109 +1245,13 @@ class ShardedTrainStep:
     def arm_guard(self):
         """Turn the guardrail gate + diag head on (fit(guardrails=...)).
 
-        Re-wraps the step jits; jax.jit traces lazily, so arming before
+        Re-wraps the step jit; jax.jit traces lazily, so arming before
         the first dispatch costs nothing extra, and arming later in a
         trainer's life retraces once at the next call. Idempotent."""
         if not self.guard:
             self.guard = True
-            self._step_multi.clear()
             self.compile()
         return self
-
-    def compile_multi(self, k):
-        """Jit a K-step program: lax.scan of the fused step over stacked
-        batches — ONE host dispatch per K optimizer steps.
-
-        Motivation: a small-batch step can cost more host dispatch
-        than device time (share on the chip not measured; PERF.md);
-        scanning K steps inside one XLA program amortizes the dispatch
-        to 1/K per step, the in-graph analog of the reference's
-        dispatch-hiding
-        threaded engine (threaded_engine_perdevice.cc:26-136 — its
-        python thread never waits on the device). Exact same per-step
-        math: the scan body IS the single-step body; lr/t/rng arrive as
-        (K,)-stacked xs so schedules advance per micro-step.
-
-        Returns the jitted fn (params, aux, opt, batches[K,...],
-        rngs[K,2], lrs[K], ts[K]) -> (params, aux, opt, outs[K, ...]);
-        cached per K."""
-        import jax
-
-        fn = self._step_multi.get(k)
-        if fn is not None:
-            return fn
-        step = self._make_step_fn()
-
-        def multi(params, aux, opt_state, batches, rngs, lrs, ts, gthr):
-            def body(carry, xs):
-                p, a, s = carry
-                batch_k, rng_k, lr_k, t_k = xs
-                # gthr is a loop constant: the monitor refreshes it at
-                # group boundaries, never inside a K-group
-                np_, na, ns, outs = step(p, a, s, batch_k, rng_k,
-                                         lr_k, t_k, gthr)
-                return (np_, na, ns), outs
-
-            (p, a, s), outs = jax.lax.scan(
-                body, (params, aux, opt_state), (batches, rngs, lrs, ts))
-            return p, a, s, outs
-
-        fn = jax.jit(multi, donate_argnums=(0, 1, 2))
-        self._step_multi[k] = fn
-        return fn
-
-    def call_multi(self, params, aux, opt_state, batches, lrs, ts):
-        """Run K fused steps in one dispatch (see compile_multi).
-
-        `batches`: dict name -> (K, batch, ...) arrays already placed
-        with batch_sharding_stacked(); `lrs`/`ts`: length-K sequences
-        (per-micro-step schedule values, host-computed)."""
-        import jax.numpy as jnp
-
-        k = len(lrs)
-        fn = self.compile_multi(k)
-        # dispatch fast path (_GraphProgram.dispatch_plan): key on the
-        # batch entries alone — param shapes are fixed per trainer, and
-        # creation-shape overrides depend only on the PER-STEP shapes
-        # (scan axis dropped)
-        sig = tuple(
-            (n, tuple(v.shape[1:]), str(v.dtype),
-             getattr(v, "sharding", None))
-            for n, v in batches.items())
-
-        def _build():
-            from ..executor import resolve_creation_shapes
-
-            shapes = {n: tuple(v.shape) for n, v in params.items()}
-            shapes.update(
-                {n: tuple(v.shape[1:]) for n, v in batches.items()})
-            return resolve_creation_shapes(self.symbol, shapes)
-
-        self.program.dispatch_plan(sig, _build)
-        if self._needs_rng:
-            from .. import random as _random
-
-            rngs = jnp.stack([_random.next_key() for _ in range(k)])
-        else:
-            rngs = jnp.zeros((k, 2), jnp.uint32)
-        lrs_arr = jnp.asarray(lrs, jnp.float32)
-        ts_arr = jnp.asarray(ts, jnp.float32)
-        gthr_arr = jnp.asarray(self.guard_threshold, jnp.float32)
-        args = (params, aux, opt_state, batches, rngs, lrs_arr, ts_arr,
-                gthr_arr)
-        # No steps=k division in the cost: XLA's cost analysis sums the
-        # scan BODY once (trip count is not multiplied in), so the
-        # K-step program already reports per-step cost
-        cost_key = ("multi", k) + sig
-        specs = (_abstract(args) if _tm.anatomy.cost_pending(
-            self.program._program_uid, cost_key) else None)
-        _M_STEPS.inc(k, path="multi")
-        with _tm.span("train_step.dispatch", k=k):
-            out = fn(*args)
-        if specs is not None:
-            self._capture_cost(cost_key, fn, specs, {
-                n: tuple(v.shape[1:]) for n, v in batches.items()})
-        return out
 
     def _capture_cost(self, cost_key, fn, specs, batch_shapes):
         """Price the program just dispatched for the step anatomy, once

@@ -43,17 +43,8 @@ import numpy as np
 
 from . import ndarray as nd
 from . import symbol as sym_mod
-from . import telemetry as _tm
 from .base import MXNetError
 from .context import Context, cpu
-
-_H_DISPATCH_SECONDS = _tm.histogram(
-    "predict.dispatch_seconds",
-    "device time per AOT predict dispatch")
-_C_EXEC_EVICTIONS = _tm.counter(
-    "predict.exec_evictions",
-    "executors dropped from the shape-signature LRU pool")
-
 
 def _exec_cache_cap():
     try:
@@ -148,7 +139,6 @@ class Predictor(object):
         while len(self._exec_cache) > cap:
             old_key, _ = self._exec_cache.popitem(last=False)
             self._serve_cache.pop(old_key, None)
-            _C_EXEC_EVICTIONS.inc()
         return exec_
 
     def _dequant(self, name, arr):
@@ -316,8 +306,6 @@ class _ServeFn(object):
         program.dispatch_plan(self._sig, lambda: overrides)
 
     def __call__(self, inputs):
-        import time
-
         import jax
 
         overrides = self._program.shape_overrides
@@ -333,11 +321,8 @@ class _ServeFn(object):
             # executable, so the output can alias it in place
             data_vals.append(jax.device_put(
                 data.astype(aval.dtype, copy=False), self._device))
-        t0 = time.perf_counter()
         outs = self._compiled(*data_vals)
-        outs = [np.asarray(o) for o in outs]
-        _H_DISPATCH_SECONDS.observe(time.perf_counter() - t0)
-        return outs
+        return [np.asarray(o) for o in outs]
 
 
 # --------------------------------------------------------------------------

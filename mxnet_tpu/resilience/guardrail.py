@@ -92,7 +92,7 @@ def _env_float(name, default):
 
 
 class GuardrailRewind(Exception):
-    """Raised at a group boundary when the monitor votes to rewind.
+    """Raised at a step boundary when the monitor votes to rewind.
 
     Carries where the anomaly run was detected so fit() can skip the
     poison window after restoring the last-good checkpoint.
@@ -183,9 +183,9 @@ class GuardrailMonitor:
     """Streaming anomaly detector over the fused step's diag stream.
 
     One :meth:`observe` call per optimizer step (fit drains them at
-    group boundaries — the detector never blocks the dispatch
-    frontier). Policy ladder: an anomalous step answers ``"skip"``
-    (the in-graph gate already protected the params);
+    step boundaries, after the step's dispatch). Policy ladder: an
+    anomalous step answers ``"skip"`` (the in-graph gate already
+    protected the params);
     ``rewind_after`` CONSECUTIVE anomalies answer ``"rewind"`` — a
     transient glitch self-heals, a persistent divergence does not.
 

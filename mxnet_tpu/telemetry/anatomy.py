@@ -111,8 +111,8 @@ _cost_cache = {}  # (program_uid, key) -> {"flops", "bytes_accessed"} | None
 _current_cost = None  # the cost dict of the most recently dispatched program
 
 
-def capture_cost(program_uid, key, lower_thunk, steps=1, dtype=None,
-                 devices=1, analytic=None):
+def capture_cost(program_uid, key, lower_thunk, dtype=None, devices=1,
+                 analytic=None):
     """Resolve the per-step, per-device cost of one program.
 
     ``lower_thunk`` must return a jax stage with ``cost_analysis()``
@@ -122,8 +122,7 @@ def capture_cost(program_uid, key, lower_thunk, steps=1, dtype=None,
     within a few percent of the executable's, bytes an upper bound, both
     for all ``devices`` together). It runs at most once per (program,
     signature). Where it yields nothing, ``analytic()`` gives the cost
-    per device instead. ``steps`` divides multi-step (scan-K) program
-    totals back to per-step. ``dtype`` tags the program's compute dtype
+    per device instead. ``dtype`` tags the program's compute dtype
     ("bf16"/"f32") so MFU is computed against the right roofline — fp32
     compute can never reach the bf16 peak the tables quote. Failures
     cache as None — never retried, never raised.
@@ -141,7 +140,7 @@ def capture_cost(program_uid, key, lower_thunk, steps=1, dtype=None,
     cost = None
     try:
         raw = costmodel.extract_cost(lower_thunk())
-        per = max(steps, 1) * max(devices, 1)
+        per = max(devices, 1)
         if not (raw["flops"] or raw["bytes_accessed"]) and analytic:
             raw, per = analytic(), 1
         if raw["flops"] or raw["bytes_accessed"]:
@@ -266,32 +265,6 @@ def _phase_totals():
 
 
 _state = None  # active interval accumulator (fit-loop thread only)
-_multistep = None  # last MXNET_FIT_MULTISTEP=auto decision (joins records)
-
-
-def note_multistep(k, settled, dispatch_frac=None):
-    """Record the fit loop's current multi-step scan depth (the
-    MXNET_FIT_MULTISTEP=auto tuner's choice) so every subsequent anatomy
-    interval record carries it — the chosen depth is part of the step's
-    anatomy, not a side channel."""
-    global _multistep
-    ms = {"k": int(k), "auto": True, "settled": bool(settled)}
-    if dispatch_frac is not None:
-        ms["dispatch_frac"] = round(float(dispatch_frac), 4)
-    _multistep = ms
-
-
-def emit_decision(record):
-    """Write one freestanding decision record (e.g. type=multistep_auto)
-    to the telemetry JSONL. No-op when anatomy is off; never raises."""
-    if not enabled():
-        return
-    try:
-        rec = dict(record)
-        rec.setdefault("t", time.time())
-        _export.emit_record(rec)
-    except Exception as exc:  # noqa: BLE001 — observers must not raise
-        _LOG.debug("emit_decision failed: %s", exc)
 
 
 def note_op_costs(ops, device_kind=None, compute_dtype=None):
@@ -374,8 +347,6 @@ def emit_interval(force=False):
         "unattributed_seconds": wall - sum(phases.values()),
         "recompiles": _C_RECOMPILES.value() - st["recompiles0"],
     }
-    if _multistep is not None:
-        record["multistep"] = dict(_multistep)
     cost = _current_cost
     if cost:
         record["flops_per_step"] = cost["flops"]
@@ -431,11 +402,10 @@ def _device_kind():
 def reset_state():
     """Drop caches, fingerprints, and the active interval (telemetry
     reset path — test isolation)."""
-    global _state, _current_cost, _multistep
+    global _state, _current_cost
     with _lock:
         _cost_cache.clear()
         _last_fp.clear()
         _program_meta.clear()
         _state = None
         _current_cost = None
-        _multistep = None

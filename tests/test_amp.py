@@ -553,9 +553,8 @@ def _run_train(script_dir, ckpt_dir, out, extra_env, timeout=300):
     env.pop("XLA_FLAGS", None)
     env.pop(fault.ENV, None)
     for k in ("MXTPU_AMP", "MXTPU_SHARD_UPDATE", "MXTPU_BUCKET_BYTES",
-              "MXNET_FIT_MULTISTEP", "MXTPU_DEVICE_FEED",
-              "MXTPU_FUSED_UPDATE_KERNEL", "MXTPU_LOSS_SCALE",
-              "MXTPU_LOSS_SCALE_WINDOW"):
+              "MXTPU_DEVICE_FEED", "MXTPU_FUSED_UPDATE_KERNEL",
+              "MXTPU_LOSS_SCALE", "MXTPU_LOSS_SCALE_WINDOW"):
         env.pop(k, None)
     env.update(extra_env)
     return subprocess.run(

@@ -172,8 +172,8 @@ def test_capture_cost_cache_hit_miss():
     anatomy.capture_cost(1, ("single", "other"), thunk)
     assert len(calls) == 2
 
-    # multi-step programs divide back to per-step
-    c4 = anatomy.capture_cost(2, ("multi",), thunk, steps=4)
+    # a program over several devices divides back to one device
+    c4 = anatomy.capture_cost(2, ("single",), thunk, devices=4)
     assert c4 == {"flops": 25.0, "bytes_accessed": 10.0}
 
     # failures cache as None and never rerun the thunk

@@ -10,9 +10,7 @@ through step N — so metrics must be EXACTLY equal and parameters
 array-equal between the synchronous loop and fit.
 """
 import logging
-import os
 import re
-import time
 
 import numpy as np
 import pytest
@@ -43,12 +41,8 @@ def _blob_iter(batch_size=32, n=128, seed=0):
 FOUR_DEV = [mx.cpu(i) for i in range(4)]
 
 
-def _set_knobs(monkeypatch, feed, multistep=None):
+def _set_knobs(monkeypatch, feed):
     monkeypatch.setenv("MXTPU_DEVICE_FEED", "1" if feed else "0")
-    if multistep is None:
-        monkeypatch.delenv("MXNET_FIT_MULTISTEP", raising=False)
-    else:
-        monkeypatch.setenv("MXNET_FIT_MULTISTEP", str(multistep))
 
 
 FIT_KW = dict(optimizer="sgd", kvstore="device",
@@ -66,10 +60,10 @@ def _params(mod):
     return {n: v.asnumpy() for n, v in mod.get_params()[0].items()}
 
 
-def _fit(monkeypatch, feed, multistep=None, num_epoch=2,
-         batch_end_callback=None, **fit_kw):
+def _fit(monkeypatch, feed, num_epoch=2, batch_end_callback=None,
+         **fit_kw):
     """Fixed-seed fused fit; returns (final Train metric, params)."""
-    _set_knobs(monkeypatch, feed, multistep)
+    _set_knobs(monkeypatch, feed)
     mod = _seeded_module()
     eval_metric = mx.metric.Accuracy()
     mod.fit(_blob_iter(), eval_metric=eval_metric, num_epoch=num_epoch,
@@ -401,42 +395,8 @@ def test_dispatch_fastpath_counters(monkeypatch):
 
 
 # ---------------------------------------------------------------------
-# composition with MXNET_FIT_MULTISTEP
+# satellite: inject-latency warning
 # ---------------------------------------------------------------------
-def test_composed_with_multistep(monkeypatch):
-    """K-step scan dispatch + device feed + the lookahead together must
-    match the plain K-step run AND the single-step run exactly, in
-    parameters, the metric and what every callback sees."""
-    base, comp = _Recorder(1), _Recorder(1)
-    m_base, p_base = _fit(monkeypatch, feed=False, multistep=4,
-                          batch_end_callback=base)
-    m_comp, p_comp = _fit(monkeypatch, feed=True, multistep=4,
-                          batch_end_callback=comp)
-    np.testing.assert_equal(m_base, m_comp)
-    np.testing.assert_equal(comp.seen, base.seen)
-    for o_comp, o_base in zip(comp.outputs, base.outputs):
-        np.testing.assert_array_equal(o_comp, o_base)
-    for name in p_base:
-        np.testing.assert_array_equal(p_base[name], p_comp[name],
-                                      err_msg=name)
-
-
-# ---------------------------------------------------------------------
-# satellites: heartbeat K-tick credit, inject-latency warning
-# ---------------------------------------------------------------------
-def test_heartbeat_multistep_credit(tmp_path):
-    """progress(ticks=K) banks future mtime credit so a per-batch-tuned
-    watchdog doesn't false-trip across a K-step dispatch (ADVICE r5)."""
-    from mxnet_tpu.parallel.heartbeat import HeartbeatWriter
-
-    hb = HeartbeatWriter(str(tmp_path), 0, interval=0.05)
-    hb.progress()  # establishes the cadence baseline
-    time.sleep(0.2)
-    hb.progress(ticks=4)  # per-tick ~0.2s -> ~0.6s future credit
-    mtime = os.path.getmtime(str(tmp_path / "prog_0"))
-    assert mtime > time.time() + 0.3, (mtime, time.time())
-
-
 def test_inject_latency_warns_once(monkeypatch, caplog):
     from mxnet_tpu.parallel import mesh
 

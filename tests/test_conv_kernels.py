@@ -1,4 +1,4 @@
-"""Pallas conv-backward pair + multistep auto-depth (ISSUE 17).
+"""Pallas conv-backward pair (ISSUE 17).
 
 The acceptance contract under test:
 
@@ -10,9 +10,7 @@ The acceptance contract under test:
   fall back to XLA (or the taps lever) and executor gradients stay
   identical with the flag on or off, including against the NHWC lever;
 - a full lenet-style fit converges the same with the kernels on or off;
-- ``MXNET_FIT_MULTISTEP=auto`` records its chosen depth in the anatomy
-  JSONL (decision records + interval stamps) and recompiles stay zero
-  once the depth settles.
+- a fused fit under telemetry emits one ``op_costs`` record.
 """
 import json
 
@@ -29,8 +27,7 @@ from mxnet_tpu.ops import pallas_kernels as pk
 
 _ENV_VARS = (
     "MXTPU_CONV_KERNEL", "MXNET_CONV_WGRAD", "MXNET_CONV_BWD_LAYOUT",
-    "MXNET_CONV_S2D", "MXNET_FIT_MULTISTEP", "MXNET_FIT_MULTISTEP_MAX",
-    "MXTPU_DISPATCH_TARGET_FRAC", "MXTPU_MULTISTEP_AUTO_STEPS",
+    "MXNET_CONV_S2D",
 )
 
 
@@ -307,117 +304,33 @@ def test_lenet_fit_convergence_kernel_on_vs_off(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# MXNET_FIT_MULTISTEP=auto
+# the op_costs record of a fused fit
 # ---------------------------------------------------------------------------
 
-def _mlp():
+def test_op_costs_record_emitted(tmp_path, monkeypatch):
+    # the fit loop emits one op_costs record (the feed into
+    # perf_doctor's kernel-candidates table)
+    monkeypatch.setenv("MXTPU_ANATOMY_INTERVAL", "8")
+    jl = str(tmp_path / "telemetry.jsonl")
+    tm.enable(jsonl=jl)
     data = mx.sym.Variable("data")
     net = mx.sym.FullyConnected(data, num_hidden=16, name="fc1")
     net = mx.sym.Activation(net, act_type="relu")
     net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
-    return mx.sym.SoftmaxOutput(net, name="softmax")
-
-
-def _blob_iter(batch_size=8, n=256, seed=0):
-    rng = np.random.RandomState(seed)
-    x = rng.randn(n, 8).astype("f")
-    y = rng.randint(0, 4, n).astype("f")
-    return mx.io.NDArrayIter(x, y, batch_size=batch_size)
-
-
-def _records(path, kind):
-    out = []
-    with open(path) as f:
-        for line in f:
-            rec = json.loads(line)
-            if rec.get("type") == kind:
-                out.append(rec)
-    return out
-
-
-def _fit_auto(tmp_path, monkeypatch, env, num_epoch=2):
-    for k, v in env.items():
-        monkeypatch.setenv(k, v)
-    monkeypatch.setenv("MXNET_FIT_MULTISTEP", "auto")
-    monkeypatch.setenv("MXTPU_ANATOMY_INTERVAL", "8")
-    jl = str(tmp_path / "telemetry.jsonl")
-    tm.enable(jsonl=jl)
-    mod = mx.mod.Module(_mlp(), context=FOUR_DEV)
-    mod.fit(_blob_iter(), eval_metric=mx.metric.Accuracy(),
-            optimizer="sgd", optimizer_params={"learning_rate": 0.05},
-            kvstore="device", num_epoch=num_epoch,
-            initializer=mx.init.Uniform(0.05))
-    assert mod._fused_trainer is not None, "fused path did not engage"
-    tm.flush()
-    return jl
-
-
-def test_multistep_auto_grows_to_cap_and_settles(tmp_path, monkeypatch):
-    # target 0 is unreachable: the tuner must double 2 -> 4, hit the
-    # cap, settle there, and then hold K with zero further recompiles
-    jl = _fit_auto(tmp_path, monkeypatch, {
-        "MXNET_FIT_MULTISTEP_MAX": "4",
-        "MXTPU_DISPATCH_TARGET_FRAC": "0",
-        "MXTPU_MULTISTEP_AUTO_STEPS": "1",
-    })
-    decs = _records(jl, "multistep_auto")
-    assert decs, "no multistep_auto decision records"
-    assert [d["k"] for d in decs] == [4, 4], decs
-    assert decs[0]["grown"] and not decs[0]["settled"], decs
-    assert decs[-1]["settled"] and decs[-1]["why"] == "depth cap", decs
-    assert decs[-1]["dispatch_frac"] > 0, decs
-
-    # the chosen depth is stamped on anatomy interval records
-    anat = _records(jl, "anatomy")
-    stamped = [r["multistep"] for r in anat if "multistep" in r]
-    assert stamped, anat
-    assert stamped[-1] == {"k": 4, "auto": True, "settled": True,
-                           "dispatch_frac": decs[-1]["dispatch_frac"]}
-
-    # steady state: the growth recompile (K=2 -> K=4 program) is the
-    # last one ever — intervals closing after the settle report zero
-    settle_t = decs[-1]["t"]
-    assert all(rec["t"] <= settle_t or rec.get("recompiles", 0) == 0
-               for rec in anat), anat
-    recs = _records(jl, "recompile")
-    assert all(r["t"] <= settle_t for r in recs), recs
-
-
-def test_multistep_auto_settles_at_two_when_target_met(tmp_path,
-                                                       monkeypatch):
-    # an easily met target: the first measured group settles at the
-    # initial depth — no growth, no extra recompiles
-    jl = _fit_auto(tmp_path, monkeypatch, {
-        "MXTPU_DISPATCH_TARGET_FRAC": "1000",
-        "MXTPU_MULTISTEP_AUTO_STEPS": "1",
-    }, num_epoch=1)
-    decs = _records(jl, "multistep_auto")
-    assert len(decs) == 1 and decs[0]["settled"], decs
-    assert decs[0]["k"] == 2 and decs[0]["why"] == "target met", decs
-    anat = _records(jl, "anatomy")
-    assert any(r.get("multistep", {}).get("k") == 2 for r in anat), anat
-
-
-def test_multistep_auto_without_telemetry(monkeypatch):
-    # no counters to steer by: auto must freeze at the initial depth
-    # and train normally rather than crash (the old int() parse path
-    # silently fell back to K=1)
-    monkeypatch.setenv("MXNET_FIT_MULTISTEP", "auto")
-    mod = mx.mod.Module(_mlp(), context=FOUR_DEV)
-    mod.fit(_blob_iter(n=64), eval_metric=mx.metric.Accuracy(),
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.randn(256, 8).astype("f"),
+                           rng.randint(0, 4, 256).astype("f"),
+                           batch_size=8)
+    mod = mx.mod.Module(net, context=FOUR_DEV)
+    mod.fit(it, eval_metric=mx.metric.Accuracy(),
             optimizer="sgd", optimizer_params={"learning_rate": 0.05},
             kvstore="device", num_epoch=1,
             initializer=mx.init.Uniform(0.05))
-    assert mod._fused_trainer is not None
-
-
-def test_op_costs_record_emitted(tmp_path, monkeypatch):
-    # the fit loop emits one op_costs record (tentpole C's feed into
-    # perf_doctor's kernel-candidates table)
-    jl = _fit_auto(tmp_path, monkeypatch, {
-        "MXTPU_DISPATCH_TARGET_FRAC": "1000",
-    }, num_epoch=1)
-    recs = _records(jl, "op_costs")
+    assert mod._fused_trainer is not None, "fused path did not engage"
+    tm.flush()
+    with open(jl) as f:
+        recs = [r for r in map(json.loads, f) if r.get("type") == "op_costs"]
     assert recs, "no op_costs record"
     ops = recs[-1]["ops"]
     assert any(o["op"] == "FullyConnected" for o in ops), ops

@@ -194,3 +194,77 @@ def test_fused_keeps_reduced_precision_weights(ndev):
     assert (trainer.flat_mode is None) == (ndev == 1)
     assert {str(v.dtype) for v in mod._fused_params.values()} == {"bfloat16"}
     assert np.isfinite(metric.get()[1])
+
+
+def test_fused_dropout_keys_advance_per_step():
+    """Each fused step draws its own rng key: the same batch through two
+    consecutive steps sees two different Dropout masks (an input-side
+    Dropout exposed as a second, gradient-blocked output), and a Dropout
+    net still trains the blob problem through ``fit``."""
+    data = mx.sym.Variable("data")
+    drop = mx.sym.Dropout(data, p=0.5, name="drop")
+    net = mx.sym.FullyConnected(drop, num_hidden=4, name="fc")
+    net = mx.sym.Group([mx.sym.SoftmaxOutput(net, name="softmax"),
+                        mx.sym.BlockGrad(drop, name="mask")])
+    it = _blob_iter()
+    mod = mx.mod.Module(net, context=FOUR_DEV)
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(mx.init.Uniform(0.1))
+    mod.init_optimizer(kvstore="device", optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1})
+    assert mod._fused_trainer is not None, "fused path not taken"
+    batch = next(iter(it))
+    kept = []
+    for _ in range(2):
+        mod.forward_backward(batch)
+        mod.update()
+        kept.append(mod.get_outputs()[1].asnumpy() != 0)
+    # p=0.5 over 32 x 8 elements: both masks drop something, and differ
+    assert not kept[0].all() and not kept[1].all()
+    assert (kept[0] != kept[1]).any(), "two steps drew the same mask"
+
+    data = mx.sym.Variable("data")
+    net = mx.sym.FullyConnected(data, num_hidden=32, name="fc1")
+    net = mx.sym.Activation(net, act_type="relu")
+    net = mx.sym.Dropout(net, p=0.3)
+    net = mx.sym.FullyConnected(net, num_hidden=4, name="fc2")
+    net = mx.sym.SoftmaxOutput(net, name="softmax")
+    mod = mx.mod.Module(net, context=FOUR_DEV)
+    mod.fit(_blob_iter(), optimizer="sgd",
+            optimizer_params={"learning_rate": 0.2, "momentum": 0.9},
+            kvstore="device", num_epoch=8,
+            initializer=mx.init.Uniform(0.1))
+    assert mod._fused_trainer is not None, "fused path not taken"
+    acc = dict(mod.score(_blob_iter(), mx.metric.Accuracy()))["accuracy"]
+    assert acc >= 0.9, acc
+
+
+def test_fused_partial_last_batch_matches_executor_path():
+    """112 samples in batches of 32: the last batch is padded with 16
+    wrapped-around rows. The fused path trains on it exactly as the
+    executor path on the same devices does: same parameters, same
+    epoch metric."""
+    def run(kvstore):
+        it = _blob_iter(n=112)
+        pads = [b.pad for b in it]
+        assert pads == [0, 0, 0, 16], pads
+        it.reset()
+        mx.random.seed(0)
+        np.random.seed(0)
+        mod = mx.mod.Module(_mlp(), context=FOUR_DEV)
+        metric = mx.metric.Accuracy()
+        mod.fit(it, eval_metric=metric, optimizer="sgd",
+                optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+                kvstore=kvstore, num_epoch=2,
+                initializer=mx.init.Uniform(0.1))
+        return mod, metric.get()[1], {
+            k: v.asnumpy() for k, v in mod.get_params()[0].items()}
+
+    mod_f, acc_f, fused = run("device")
+    assert mod_f._fused_trainer is not None, "fused path not taken"
+    mod_e, acc_e, plain = run("local")
+    assert mod_e._fused_trainer is None
+    assert acc_f == acc_e, (acc_f, acc_e)
+    for k in plain:
+        np.testing.assert_allclose(
+            fused[k], plain[k], rtol=2e-4, atol=2e-5, err_msg=k)

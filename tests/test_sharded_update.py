@@ -11,8 +11,8 @@ would round differently from the sharded one.
 
 These tests pin the acceptance criteria: bitwise-equal params, optimizer
 state, and metrics between the sharded and replicated paths — for
-SGD-momentum and Adam, under MXNET_FIT_MULTISTEP and MXTPU_DEVICE_FEED,
-across 1/2/4 simulated devices — including SIGKILL crash-resume through
+SGD-momentum and Adam, with MXTPU_DEVICE_FEED on and off, across
+1/2/4 simulated devices — including SIGKILL crash-resume through
 resilience checkpoints and checkpoint portability across modes.
 """
 import os
@@ -99,21 +99,19 @@ def _assert_bitwise(got, want):
                                       err_msg="%s differs" % k)
 
 
-@pytest.mark.parametrize("ndev,optname,fit_k,feed,bucket", [
-    (2, "sgd", "1", "1", None),
-    (2, "adam", "2", "0", "256"),   # tiny cap: multiple buckets + padding
-    (4, "sgd", "2", "1", None),
-    (4, "adam", "1", "0", None),
-    (8, "sgd", "1", "1", "256"),
+@pytest.mark.parametrize("ndev,optname,feed,bucket", [
+    (2, "sgd", "1", None),
+    (2, "adam", "0", "256"),   # tiny cap: multiple buckets + padding
+    (4, "sgd", "1", None),
+    (4, "adam", "0", None),
+    (8, "sgd", "1", "256"),
 ])
-def test_sharded_bitwise_parity(monkeypatch, ndev, optname, fit_k, feed,
-                                bucket):
+def test_sharded_bitwise_parity(monkeypatch, ndev, optname, feed, bucket):
     """MXTPU_SHARD_UPDATE=1 vs =0: params, optimizer state, and metric
-    bitwise-equal across device counts, optimizers, multi-step fit, and
-    device-resident feeds; sharded state genuinely at 1/N."""
+    bitwise-equal across device counts, optimizers and device-resident
+    feeds; sharded state genuinely at 1/N."""
     from jax.sharding import PartitionSpec as P
 
-    monkeypatch.setenv("MXNET_FIT_MULTISTEP", fit_k)
     monkeypatch.setenv("MXTPU_DEVICE_FEED", feed)
     if bucket is not None:
         monkeypatch.setenv("MXTPU_BUCKET_BYTES", bucket)
@@ -368,7 +366,7 @@ def _run_train(script_dir, ckpt_dir, out, extra_env, timeout=300):
     env.pop("XLA_FLAGS", None)
     env.pop(fault.ENV, None)
     for k in ("MXTPU_SHARD_UPDATE", "MXTPU_BUCKET_BYTES",
-              "MXNET_FIT_MULTISTEP", "MXTPU_DEVICE_FEED"):
+              "MXTPU_DEVICE_FEED"):
         env.pop(k, None)
     env.update(extra_env)
     return subprocess.run(

@@ -7,8 +7,8 @@ the full stack the reference's train tier exercises: pack a JPEG
 RecordIO file (recordio.pack_img — the same writer im2rec uses), read
 it back through ImageRecordIter (native decode, mean subtract,
 shuffle), and train a small convnet via Module.fit on a multi-device
-mesh with kvstore='device' (the fused ShardedTrainStep path) plus
-MXNET_FIT_MULTISTEP grouping — asserting a real accuracy bar.
+mesh with kvstore='device' (the fused ShardedTrainStep path) —
+asserting a real accuracy bar.
 
 Zero egress makes CIFAR itself unavailable, so the classes are
 synthetic but genuinely visual: each class is an oriented sinusoidal
@@ -59,7 +59,7 @@ def _pack(path_prefix, n, seed):
     return rec
 
 
-def test_recordio_convergence_fused_multistep(tmp_path, monkeypatch):
+def test_recordio_convergence_fused(tmp_path):
     train_rec = _pack(str(tmp_path / "train"), N_TRAIN, 0)
     val_rec = _pack(str(tmp_path / "val"), N_VAL, 1)
 
@@ -90,7 +90,6 @@ def test_recordio_convergence_fused_multistep(tmp_path, monkeypatch):
                                 name="fc")
     net = mx.sym.SoftmaxOutput(net, name="softmax")
 
-    monkeypatch.setenv("MXNET_FIT_MULTISTEP", "2")
     mod = mx.mod.Module(net, context=[mx.cpu(i) for i in range(4)])
     np.random.seed(0)
     mx.random.seed(0)

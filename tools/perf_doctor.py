@@ -53,9 +53,10 @@ _ADVICE = {
                   "decode",
     "stage_host": "host input staging: MXTPU_DEVICE_FEED=1 adopts "
                   "device-resident batches and removes this phase",
-    "dispatch_host": "per-dispatch host overhead: raise "
-                     "MXNET_FIT_MULTISTEP to amortize K steps per "
-                     "dispatch",
+    "dispatch_host": "per-dispatch host overhead: the fused path "
+                     "(kvstore='device' on a mesh) hides it behind "
+                     "fit's one-step lookahead; the executor path, "
+                     "monitors and BucketingModule run without it",
     "device_sync": "blocked on device results: device compute dominates "
                    "— see the roofline bound for which resource to "
                    "attack",
@@ -510,18 +511,6 @@ def report(path, keep_all=False):
         diag += "; device model says the interval is %s-bound" % roof
     out += ["", diag]
 
-    ms = next((r["multistep"] for r in reversed(anatomy)
-               if r.get("multistep")), None)
-    if ms:
-        out.append(
-            "multistep: K=%d%s%s" % (
-                ms.get("k", 0),
-                " (auto, settled)" if ms.get("settled")
-                else " (auto, still growing)" if ms.get("auto") else "",
-                "" if ms.get("dispatch_frac") is None else
-                ", dispatch at %.1f%% of device time"
-                % (100.0 * ms["dispatch_frac"])))
-
     amp = amp_advice(anatomy)
     if amp:
         out.append(amp)
@@ -584,8 +573,6 @@ def _self_test():
                                        mfu=0.12)) + "\n")
         rec2 = anatomy_rec(2, dict(base), 0.01, mfu=0.14,
                            bound="compute", dtype="f32", kind="TPU v5e")
-        rec2["multistep"] = {"k": 8, "auto": True, "settled": True,
-                             "dispatch_frac": 0.031}
         f.write(json.dumps(rec2) + "\n")
         # op_costs record: one clearly memory-bound op (bn) and one
         # clearly compute-bound (conv) — only bn may surface as a
@@ -731,7 +718,6 @@ def _self_test():
     assert "MFU trajectory" in text and "step anatomy" in text, text
     assert "p50=" in text and "p99=" in text, text
     assert "kernel candidates" in text and "stage1_bn1" in text, text
-    assert "multistep: K=8 (auto, settled)" in text, text
 
     # empty / anatomy-free file degrades to a message, not a crash
     empty = os.path.join(d, "empty.jsonl")

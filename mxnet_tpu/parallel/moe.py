@@ -16,6 +16,8 @@ overflow an expert's capacity are dropped (contribute zero), matching
 the published behavior; an auxiliary load-balance loss (Switch
 Transformer eq. 4) keeps the router from collapsing onto one expert.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -98,6 +100,28 @@ def switch_moe(params, x, capacity_factor=1.25):
 
 
 
+# who computes the expert products where the step is not lowered for the
+# TPU (``grouped_matmul``'s ``off_tpu``): XLA's own grouped matmul
+_EXPERTS_OFF_TPU = "ragged_dot"
+
+
+def _expert_dot(counts, rows, dtype):
+    """``dot(lhs, rhs)`` over ``rows`` rows sorted into ``counts``
+    groups: ``ops.pallas_kernels.grouped_matmul``, whose Pallas kernels
+    run where the step is lowered for the TPU and ``jax.lax.ragged_dot``
+    on every other platform (and at shapes the kernels do not take). The
+    kernels' group metadata is made once here and shared by every product
+    (and its two transposes) that the returned function is used for."""
+    from ..ops import pallas_kernels as pk
+
+    groups = counts.shape[0]
+    if not pk.gmm_runs_kernel(rows, dtype):
+        return lambda lhs, rhs: jax.lax.ragged_dot(lhs, rhs, counts)
+    metadata = pk.gmm_metadata(counts, rows, pk.gmm_row_tile(rows, groups))
+    return functools.partial(pk.grouped_matmul, group_sizes=counts,
+                             metadata=metadata, off_tpu=_EXPERTS_OFF_TPU)
+
+
 def topk_moe(params, x, top_k, norm_topk_prob=False):
     """Dropless top-k MoE FFN with SwiGLU experts (the OLMoE / Mixtral
     block). x: [tokens, d_model] -> ([tokens, d_model], counts [E]).
@@ -110,12 +134,17 @@ def topk_moe(params, x, top_k, norm_topk_prob=False):
     No capacity: every (token, expert) pair of the routing is computed,
     whatever the load. The ``tokens * top_k`` rows are sorted by expert
     and the three expert matmuls run as two grouped matmuls over the
-    sorted rows (``jax.lax.ragged_dot``), so the work is that of the
-    routing and not ``O(T * E * C * d)`` as in ``switch_moe``'s dense
-    dispatch. The router (matmul at full float32 precision, softmax,
-    top-k) stays in float32 whatever the activations' dtype; routing
-    weights are not renormalised unless ``norm_topk_prob``. ``counts``
-    is the number of rows each expert received (int32, no gradient).
+    sorted rows, so the work is that of the routing and not
+    ``O(T * E * C * d)`` as in ``switch_moe``'s dense dispatch. Who
+    computes them (``_expert_dot``): the Pallas kernels of
+    ``ops.pallas_kernels.grouped_matmul`` where the step is lowered for
+    the TPU, ``jax.lax.ragged_dot`` everywhere else (by
+    ``lax.platform_dependent``, inside that function); both take
+    operands of ``x.dtype``, accumulate in float32 and round once. The
+    router (matmul at full float32 precision, softmax, top-k) stays in
+    float32 whatever the activations' dtype; routing weights are not
+    renormalised unless ``norm_topk_prob``. ``counts`` is the number of
+    rows each expert received (int32, no gradient).
     """
     tokens, d_model = x.shape
     num_experts = params["gate_w"].shape[1]
@@ -139,11 +168,10 @@ def topk_moe(params, x, top_k, norm_topk_prob=False):
         rows = jnp.take(x, order // top_k, axis=0)            # [T*k, d]
 
     with jax.named_scope("experts"):
-        gate_up = jax.lax.ragged_dot(
-            rows, params["w_gate_up"].astype(x.dtype), counts)
+        dot = _expert_dot(counts, rows.shape[0], x.dtype)
+        gate_up = dot(rows, params["w_gate_up"].astype(x.dtype))
         act = jax.nn.silu(gate_up[:, :hidden]) * gate_up[:, hidden:]
-        out_rows = jax.lax.ragged_dot(
-            act, params["w_down"].astype(x.dtype), counts)    # [T*k, d]
+        out_rows = dot(act, params["w_down"].astype(x.dtype))  # [T*k, d]
 
     with jax.named_scope("combine"):
         # back to token order by the inverse permutation (a gather, not

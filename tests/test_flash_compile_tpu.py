@@ -1,5 +1,5 @@
-"""The flash kernels compile for the chip at real widths, without the
-chip: the TPU's compiler is installed here and compiles for a described
+"""The flash and grouped-matmul kernels compile for the chip at real
+widths, without the chip: the TPU's compiler is installed here and compiles for a described
 v5e (interpret mode cannot see a tile Mosaic refuses, or a working set
 over the scoped-VMEM limit the calls leave at its default). The
 topology is described inside a fixture, never at import: only the worker
@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxnet_tpu.ops.pallas_kernels import flash_attention, flash_tiles
+from mxnet_tpu.ops.pallas_kernels import (
+    flash_attention, flash_tiles, gmm_tiles, grouped_matmul)
 
 
 @pytest.fixture(scope="module")
@@ -63,3 +64,37 @@ def test_flash_chosen_tiles_compile_for_v5e(one_chip, t, h, d, dtype,
     for which in ("fwd", "dq", "dkv"):
         # the kernel's name is the device op's name: what a trace shows
         assert "flash_%s_%s_q%d_k%d" % (which, operands, bq, bk) in text
+
+
+# the OLMoE cell's two expert products (gate/up, down); a float32 caller
+# at the widest result tile its budget admits
+GMM_SHAPES = [
+    (32768, 2048, 2048, 64, jnp.bfloat16),
+    (32768, 1024, 2048, 64, jnp.bfloat16),
+    (8192, 2048, 1024, 16, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("m,k,n,groups,dtype", GMM_SHAPES)
+def test_gmm_chosen_tiles_compile_for_v5e(one_chip, m, k, n, groups, dtype):
+    lhs = jax.ShapeDtypeStruct((m, k), dtype, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((groups, k, n), dtype, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one_chip)
+
+    def loss(lhs, rhs, sizes):
+        # squared: the backward pass needs the forward's result
+        return jnp.sum(
+            grouped_matmul(lhs, rhs, sizes).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        lhs, rhs, sizes).compile().as_text()
+    operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    tm, tk_d, tn_d = gmm_tiles(m, n, k, groups, dtype)
+    for mode, tiles in (
+            ("fwd", gmm_tiles(m, k, n, groups, dtype)),
+            ("dgrad", (tm, tn_d, tk_d)),
+            ("wgrad", gmm_tiles(m, k, n, groups, dtype, wgrad=True))):
+        # the kernel's name is the device op's name: what a trace shows
+        assert "gmm_%s_%s_m%d_k%d_n%d" % ((mode, operands) + tiles) in text
+    # XLA's own grouped matmul is nowhere in the program
+    assert "ragged_dot_tiling" not in text and "ragged-dot" not in text

@@ -200,13 +200,28 @@ def _moe_weights(rng, d, experts, hidden):
                        ).astype(np.float32)}
 
 
+def _force_the_kernel(monkeypatch):
+    """The expert products through ``grouped_matmul``'s kernels off the
+    TPU too: its own platform switch then takes the Pallas interpreter."""
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_EXPERTS_OFF_TPU", "interpret")
+
+
+@pytest.mark.parametrize("experts_by", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("case", ["one_expert_takes_all", "one_takes_none",
                                   "top_k_is_every_expert", "plain"])
-def test_topk_moe_is_dropless(case):
+def test_topk_moe_is_dropless(case, experts_by, monkeypatch):
     """No capacity: whatever the routing, every (token, expert) pair is
-    computed — output, counts and gradients equal the masked loop."""
+    computed — output, counts and gradients equal the masked loop, with
+    XLA's grouped matmul and with the Pallas kernels (interpreted; 144
+    tokens there, so that one row tile of 128 is filled and the last is
+    partial)."""
     rng = np.random.RandomState(7)
     tokens, d, experts, hidden, top_k = 48, 16, 6, 8, 2
+    if experts_by == "kernel":
+        tokens = 144
+        _force_the_kernel(monkeypatch)
     w = _moe_weights(rng, d, experts, hidden)
     x = rng.randn(tokens, d).astype(np.float32)
     if case == "one_expert_takes_all":
@@ -231,6 +246,10 @@ def test_topk_moe_is_dropless(case):
                                top_k, False)
         return jnp.sum(y * y), (y, counts)
 
+    if experts_by == "kernel":
+        jaxpr = str(jax.make_jaxpr(jax.grad(
+            lambda w, x: system(w, x)[0]))(w, jnp.asarray(x)))
+        assert jaxpr.count("gmm_fwd_f32_m128") and "gmm_wgrad_f32" in jaxpr
     (_, (y, counts)), grads = jax.value_and_grad(
         system, argnums=(0, 1), has_aux=True)(w, jnp.asarray(x))
     (_, (y_ref, counts_ref)), grads_ref = jax.value_and_grad(
@@ -293,7 +312,8 @@ def test_attention_t256_runs_the_flash_kernel(monkeypatch):
 
 # -- bf16 --------------------------------------------------------------------
 
-def test_bf16_topk_moe_keeps_its_router_in_float32():
+@pytest.mark.parametrize("experts_by", ["ragged_dot", "kernel"])
+def test_bf16_topk_moe_keeps_its_router_in_float32(experts_by, monkeypatch):
     """bf16 activations and weights, router in float32: on the same
     bf16-rounded inputs the float32 reference takes the same routing
     decision for EVERY token (equal counts; the two float32 routers
@@ -302,7 +322,12 @@ def test_bf16_topk_moe_keeps_its_router_in_float32():
     standard deviations of the output at the worst element. The
     reference computed in bf16 throughout (the nearest precision below:
     a bf16 router) misroutes 26-32 of 8192 rows and reads 0.11-0.23.
-    The limit 0.09 lies between, and the counts must be equal."""
+    The limit 0.09 lies between, and the counts must be equal. The Pallas
+    kernels (interpreted) keep the rounding of XLA's grouped matmul, bf16
+    in, float32 accumulation, bf16 out: the same limit, and within
+    accumulation order of that path."""
+    if experts_by == "kernel":
+        _force_the_kernel(monkeypatch)
     for seed in range(3):
         rng = np.random.RandomState(seed)
         tokens, d, experts, hidden, top_k = 2048, 64, 16, 32, 4
@@ -325,6 +350,14 @@ def test_bf16_topk_moe_keeps_its_router_in_float32():
         np.testing.assert_array_equal(np.asarray(counts),
                                       np.asarray(want_counts))
         assert error(y) < 0.09, (seed, error(y))
+        if experts_by == "kernel":
+            monkeypatch.undo()
+            by_xla, _ = topk_moe(w, x, top_k)
+            _force_the_kernel(monkeypatch)
+            # one bf16 ulp of the largest output where the two float32
+            # sums round to different sides
+            assert float(jnp.abs(f32(y) - f32(by_xla)).max()) <= (
+                2.0 ** -7 * float(jnp.abs(f32(by_xla)).max()))
         low, low_counts, _ = ref.moe(x, w["gate_w"], w["w_gate_up"],
                                      w["w_down"], top_k, False)
         assert int(jnp.abs(low_counts - want_counts).sum()) > 0

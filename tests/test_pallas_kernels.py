@@ -187,12 +187,9 @@ def test_flash_default_tiles_match_explicit_128(dtype, tol):
         assert _rel(a, p_.astype(jnp.float32)) < 5 * tol, name
 
 
-def _kernel_dots(dtype):
-    """(operand dtypes, result dtype) of every dot_general inside the
-    three kernels of a causal flash call on ``dtype`` inputs."""
-    q = jnp.zeros((1, 256, 1, 64), dtype)
-    fn = lambda q, k, v: flash_attention(q, k, v, causal=True)
-    jaxpr = jax.make_jaxpr(jax.grad(_loss(fn), argnums=(0, 1, 2)))(q, q, q)
+def _dots_by_kernel(jaxpr):
+    """kernel name -> (operand dtypes, result dtype) of every dot_general
+    inside a ``pallas_call`` of that name."""
     found = {}
 
     def walk(jp, kernel):
@@ -209,6 +206,15 @@ def _kernel_dots(dtype):
 
     walk(jaxpr.jaxpr, None)
     return found
+
+
+def _kernel_dots(dtype):
+    """``_dots_by_kernel`` of the three kernels of a causal flash call on
+    ``dtype`` inputs."""
+    q = jnp.zeros((1, 256, 1, 64), dtype)
+    fn = lambda q, k, v: flash_attention(q, k, v, causal=True)
+    return _dots_by_kernel(
+        jax.make_jaxpr(jax.grad(_loss(fn), argnums=(0, 1, 2)))(q, q, q))
 
 
 def test_flash_kernels_feed_mxu_operands_as_given():
@@ -283,3 +289,218 @@ def test_flash_lowerings_counter_counts_one_per_lowering():
     finally:
         telemetry.disable()
         telemetry.reset()
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (the expert layer's products): interpret mode against a
+# per-group dense product
+# ---------------------------------------------------------------------------
+
+def _dense_grouped(lhs, rhs, sizes):
+    """Row r of group g times rhs[g], group by group, in float32."""
+    lhs, rhs = lhs.astype(jnp.float32), rhs.astype(jnp.float32)
+    ends = np.cumsum(sizes)
+    rows = np.arange(lhs.shape[0])
+    out = 0
+    for g, (start, end) in enumerate(zip(ends - np.asarray(sizes), ends)):
+        mine = jnp.asarray((rows >= start) & (rows < end))[:, None]
+        out = out + jnp.dot(jnp.where(mine, lhs, 0), rhs[g],
+                            precision=jax.lax.Precision.HIGHEST)
+    return out
+
+
+# group sizes over row tiles of 128: uniform and aligned; the OLMoE
+# cell's skew scaled down (a few fat groups, many of a handful of rows,
+# some of none); one group takes all; empty groups first, last and in a
+# run; every boundary inside a tile; m no multiple of the tile
+GMM_SIZES = {
+    "uniform": [128] * 4,
+    "cell_skew": [3, 0, 410, 7, 1, 0, 395, 2, 0, 190, 12, 4],
+    "one_takes_all": [0, 0, 384, 0],
+    "empty_first_and_last": [0, 0, 200, 0, 0, 56, 0],
+    "straddles_tiles": [100, 130, 27, 255],
+    "m_no_multiple_of_the_tile": [70, 150, 81],
+}
+
+
+def _gmm_inputs(sizes, k, n, dtype, seed=0):
+    rng = np.random.RandomState(seed)
+    lhs = jnp.asarray(rng.randn(sum(sizes), k), dtype)
+    rhs = jnp.asarray(rng.randn(len(sizes), k, n), dtype)
+    return lhs, rhs, jnp.asarray(sizes, jnp.int32)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("load", sorted(GMM_SIZES))
+def test_grouped_matmul_matches_the_dense_product(load, dtype, tol):
+    """Forward and both gradients (and the cotangent's own, through the
+    squared loss) against the per-group float32 product. bf16: the output
+    and the gradients are rounded to bf16 once (half an ulp 2e-3) from a
+    float32 accumulator, the rounding ``ragged_dot`` has."""
+    sizes = GMM_SIZES[load]
+    lhs, rhs, group_sizes = _gmm_inputs(sizes, 48, 160, dtype)
+    assert pk.gmm_runs_kernel(lhs.shape[0], dtype)
+
+    def loss(fn):
+        return lambda l, r: jnp.sum(fn(l, r).astype(jnp.float32) ** 2)
+
+    kernel = lambda l, r: pk.grouped_matmul(l, r, group_sizes)
+    dense = lambda l, r: _dense_grouped(l, r, sizes)
+    out = kernel(lhs, rhs)
+    assert out.dtype == dtype and out.shape == (sum(sizes), 160)
+    assert _rel(out, dense(lhs, rhs)) < tol
+    got = jax.grad(loss(kernel), argnums=(0, 1))(lhs, rhs)
+    want = jax.grad(loss(dense), argnums=(0, 1))(
+        lhs.astype(jnp.float32), rhs.astype(jnp.float32))
+    for name, a, w in zip(("d/dlhs", "d/drhs"), got, want):
+        assert a.dtype == dtype
+        assert _rel(a, w) < 2 * tol, (name, _rel(a, w))
+    # the weight gradient of a group with no rows is exactly zero
+    for g, size in enumerate(sizes):
+        if size == 0:
+            assert not np.asarray(got[1][g], np.float32).any(), g
+
+
+def test_grouped_matmul_splits_k_and_n_like_the_whole():
+    """A contraction walked in several k steps (the float32 scratch
+    accumulator) and several n tiles against the whole-k, whole-n call."""
+    sizes = GMM_SIZES["straddles_tiles"]
+    lhs, rhs, group_sizes = _gmm_inputs(sizes, 256, 384, jnp.float32, 3)
+    meta = pk.gmm_metadata(group_sizes, lhs.shape[0], 128)
+    dout = jnp.asarray(np.random.RandomState(4).randn(lhs.shape[0], 384),
+                       jnp.float32)
+    want = _dense_grouped(lhs, rhs, sizes)
+    got = pk._gmm_call(*meta[:4], lhs, rhs, tiles=(128, 128, 128),
+                       transposed=False, interpret=True)
+    assert _rel(got, want) < 1e-5
+    d_want, w_want = jax.vjp(
+        lambda l, r: _dense_grouped(l, r, sizes), lhs, rhs)[1](dout)
+    d_got = pk._gmm_call(*meta[:4], dout, rhs, tiles=(128, 128, 128),
+                         transposed=True, interpret=True)
+    assert _rel(d_got, d_want) < 1e-5
+    w_got = pk._gmm_wgrad_call(meta[0], *meta[4:], lhs, dout, groups=4,
+                               tiles=(128, 128, 128), interpret=True)
+    assert _rel(w_got, w_want) < 1e-5
+
+
+def test_grouped_matmul_hands_small_or_odd_calls_to_ragged_dot():
+    """Fewer rows than one row tile, or an operand type Mosaic does not
+    take: ``jax.lax.ragged_dot``, chosen from the shapes, no kernel."""
+    for sizes, dtype in (([10, 0, 30], jnp.float32),
+                         ([100, 100], jnp.float16)):
+        lhs, rhs, group_sizes = _gmm_inputs(sizes, 16, 24, dtype)
+        assert not pk.gmm_runs_kernel(lhs.shape[0], dtype)
+        jaxpr = jax.make_jaxpr(pk.grouped_matmul)(lhs, rhs, group_sizes)
+        assert "pallas_call" not in str(jaxpr)
+        assert _rel(pk.grouped_matmul(lhs, rhs, group_sizes),
+                    _dense_grouped(lhs, rhs, sizes)) < 1e-2
+
+
+def test_gmm_metadata_visits_every_tile_a_group_owns():
+    sizes = GMM_SIZES["empty_first_and_last"]      # 256 rows, tm 128
+    offsets, gids, tids, visits, w_gids, w_tids, w_visits = (
+        np.asarray(a) for a in pk.gmm_metadata(
+            jnp.asarray(sizes, jnp.int32), sum(sizes), 128))
+    assert offsets.tolist() == [0, 0, 0, 200, 200, 200, 256, 256]
+    # group 2 owns rows in tiles 0 and 1, group 5 in tile 1
+    assert int(visits) == 3
+    assert gids[:3].tolist() == [2, 2, 5] and tids[:3].tolist() == [0, 1, 1]
+    # wgrad visits the five empty groups too, in order
+    assert int(w_visits) == 8
+    assert w_gids[:8].tolist() == [0, 1, 2, 2, 3, 4, 5, 6]
+    assert w_tids[:8].tolist() == [0, 0, 0, 1, 1, 1, 1, 1]
+    assert len(gids) == 2 + len(sizes) - 1
+
+
+# the OLMoE cell's two products and their transposes; a float32 caller;
+# a narrow n; k and n no multiple of a lane row; few rows a group
+GMM_TILE_SHAPES = [
+    (32768, 2048, 2048, 64, jnp.bfloat16),
+    (32768, 1024, 2048, 64, jnp.bfloat16),
+    (32768, 2048, 1024, 64, jnp.bfloat16),
+    (32768, 2048, 2048, 64, jnp.float32),
+    (8192, 4096, 128, 8, jnp.bfloat16),
+    (1000, 200, 72, 4, jnp.float32),
+    (4096, 14336, 4096, 64, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("m,k,n,groups,dtype", GMM_TILE_SHAPES)
+def test_gmm_tiles_divide_or_mask_and_fit(m, k, n, groups, dtype):
+    itemsize = jnp.dtype(dtype).itemsize
+    for wgrad in (False, True):
+        tm, tk, tn = pk.gmm_tiles(m, k, n, groups, dtype, wgrad=wgrad)
+        # rows: a power of two between the measured bounds, no more than
+        # the mean rows a group where that is above the least tile (the
+        # last tile of an m that is no multiple is masked, not padded)
+        assert tm & (tm - 1) == 0
+        assert pk._GMM_MIN_ROW_TILE <= tm <= pk._GMM_MAX_ROW_TILE
+        assert tm <= max(m // groups, pk._GMM_MIN_ROW_TILE)
+        assert tm == pk.gmm_row_tile(m, groups)
+        # the contraction admits no partial tile; a partial tile of a
+        # result dimension is masked
+        assert tk == k or tk % 128 == 0
+        assert tn == n or tn % 128 == 0
+        if not wgrad:
+            assert k % tk == 0
+        assert pk._gmm_vmem_bytes(
+            tm, tk, tn, itemsize, wgrad) <= pk._FLASH_VMEM_BUDGET
+
+
+def test_gmm_tiles_at_the_cell_shape_keep_k_whole():
+    # forward and dgrad: a row block fetched once per n tile, a weight
+    # block once a group; wgrad: a square result block
+    bf16 = jnp.bfloat16
+    assert pk.gmm_tiles(32768, 2048, 2048, 64, bf16) == (256, 2048, 1024)
+    assert pk.gmm_tiles(32768, 1024, 2048, 64, bf16) == (256, 1024, 2048)
+    assert pk.gmm_tiles(32768, 2048, 1024, 64, bf16) == (256, 2048, 1024)
+    for k in (2048, 1024):
+        assert pk.gmm_tiles(32768, k, 2048, 64, bf16, wgrad=True) == (
+            256, 1024, 1024)
+
+
+def test_gmm_lowerings_counter_counts_one_per_call_site_and_mode():
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        sizes = GMM_SIZES["straddles_tiles"]
+        lhs, rhs, group_sizes = _gmm_inputs(sizes, 32, 64, jnp.bfloat16)
+        fn = lambda l, r: pk.grouped_matmul(l, r, group_sizes)
+        step = jax.jit(jax.grad(
+            lambda l, r: jnp.sum(fn(l, r).astype(jnp.float32) ** 2),
+            argnums=(0, 1)))
+        step(lhs, rhs)
+        step(lhs, rhs)  # a second step of one lowering counts nothing
+        jax.jit(fn)(lhs.astype(jnp.float32), rhs.astype(jnp.float32))
+        c = telemetry.REGISTRY.get("moe.gmm_lowerings")
+        for mode in ("fwd", "dgrad", "wgrad"):
+            assert c.value(mode=mode, operands="bf16", tm=128, tk=32,
+                           tn=64) == 1, mode
+        assert c.value(mode="fwd", operands="f32", tm=128, tk=32,
+                       tn=64) == 1
+        assert telemetry.total("moe.gmm_lowerings") == 4
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+
+
+def test_gmm_kernels_feed_mxu_operands_as_given():
+    """Every product inside the three kernels takes the operands' own
+    type and accumulates in float32; the kernels are named for what they
+    run."""
+    sizes = GMM_SIZES["straddles_tiles"]
+    for dtype, label in ((jnp.bfloat16, "bf16"), (jnp.float32, "f32")):
+        lhs, rhs, group_sizes = _gmm_inputs(sizes, 32, 64, dtype)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda l, r: jnp.sum(pk.grouped_matmul(
+                l, r, group_sizes).astype(jnp.float32) ** 2),
+            argnums=(0, 1)))(lhs, rhs)
+        found = _dots_by_kernel(jaxpr)
+        assert sorted(found) == [
+            "gmm_%s_%s_m128_k32_n64" % (mode, label)
+            for mode in ("dgrad", "fwd", "wgrad")]
+        for dots in found.values():
+            for operands, result in dots:
+                assert operands == (dtype, dtype)
+                assert result == jnp.float32

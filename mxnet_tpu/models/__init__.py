@@ -8,6 +8,9 @@ parity, standing in for its model-parallel LSTM). ``olmoe`` is
 OLMoE-1B-7B (64 SwiGLU experts, top-8, dropless) as an ``mx.sym`` graph
 of the ``RMSNorm`` / ``RoPE`` / ``Attention`` / ``TopKMoE`` ops, trained
 by ``Module.fit``; ``olmoe_reference`` is its plain float32 reference.
+``mimo_v2`` is MiMo-V2-Flash (window-128 and full attention over grouped
+heads, 256 sigmoid-routed experts) from the same ops, whole or as one
+chip's share of its layers, with ``mimo_v2_reference`` beside it.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -21,4 +24,4 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
-from . import olmoe, olmoe_reference
+from . import mimo_v2, mimo_v2_reference, olmoe, olmoe_reference

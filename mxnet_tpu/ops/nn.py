@@ -42,6 +42,7 @@ _ACT = {
     "tanh": jnp.tanh,
     "softrelu": jax.nn.softplus,
     "softsign": jax.nn.soft_sign,
+    "silu": jax.nn.silu,
 }
 
 

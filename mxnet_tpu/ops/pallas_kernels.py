@@ -97,8 +97,24 @@ def _pad_to(x, axis, mult):
 #             AND its index map names the tile already resident, so no
 #             DMA is issued for it; the iota/compare/select mask runs only
 #             on tiles the diagonal crosses or that hold padding.
+#   window    a causal window of w keys (query i sees keys i-w+1 .. i)
+#             shortens the grid's inner dimension to the tiles a band
+#             can touch (``_band_steps``): inner step j visits block
+#             ``first live + j``, so tiles below the band are neither
+#             fetched nor stepped over; the band's lower edge is one more
+#             compare in the mask.
+#   heads     G key/value heads serve H = G * group query heads: q head
+#             b reads k/v head b // group through the index maps; dkv's
+#             inner dimension walks the group's q heads one after
+#             another and sums their dK/dV in the one accumulator. The
+#             value width may differ from the query/key width.
+#   sink      a per-head logit that joins the softmax's denominator and
+#             no value: applied to the forward kernel's (out, lse) by
+#             ``_apply_sink`` outside it; the backward kernels rebuild P
+#             from the lse that holds it and need nothing else.
 #
-# forward / dq: grid (B*H, nq, nk), k innermost; dkv: grid (B*H, nk, nq).
+# forward / dq: grid (B*H, nq, nk), k innermost; dkv: grid (B*G, nk,
+# group * nq).
 # The output block index map ignores the innermost dimension, so Mosaic
 # keeps the output resident in VMEM while the inner loop accumulates into
 # scratch; one (block_q, block_k) tile pair is on-chip at a time.
@@ -109,7 +125,9 @@ def _pad_to(x, axis, mult):
 _M_FLASH_LOWERINGS = _tm.counter(
     "attention.flash_lowerings", "Traces of a flash_attention call site "
     "(one per lowering, nothing per step); labels: operands (the type "
-    "the MXU is fed), block_q, block_k")
+    "the MXU is fed), block_q, block_k and, where the call has them, "
+    "window, kv_heads (fewer than the query heads), dv (a value width "
+    "other than the query's)")
 
 # What one grid step may hold in VMEM as ``_flash_vmem_bytes`` counts it:
 # Mosaic's default scoped limit on the v5e. The calls ask for no more
@@ -145,15 +163,23 @@ def _one_tile(t):
     return max(8, 1 << (t - 1).bit_length())
 
 
-def flash_tiles(t, d, dtype):
+def flash_tiles(t, d, dtype, window=0):
     """(block_q, block_k) for a sequence of ``t`` positions, head size
-    ``d``, operands of ``dtype``: the largest powers of two up to the
-    measured caps whose working set fits ``_FLASH_VMEM_BUDGET`` and that
-    pad ``t`` by no more than an eighth over what 128-wide tiles would.
-    A sequence shorter than the smallest tile gets one tile of its own
-    size (the next power of two, 8 at the least)."""
+    ``d`` (the wider of query and value), operands of ``dtype``: the
+    largest powers of two up to the measured caps whose working set fits
+    ``_FLASH_VMEM_BUDGET`` and that pad ``t`` by no more than an eighth
+    over what 128-wide tiles would. A sequence shorter than the smallest
+    tile gets one tile of its own size (the next power of two, 8 at the
+    least). Under a causal ``window`` the band of a q tile of B rows
+    crosses two k tiles of B >= window keys, B * window of their 2 B^2
+    scores live: square tiles of twice the window, where the products
+    of a step weigh about what the step itself costs."""
     if t < _FLASH_MIN_BLOCK:
         return _one_tile(t), _one_tile(t)
+    if window:
+        block = min(max(2 * _one_tile(window), _FLASH_MIN_BLOCK),
+                    _FLASH_MAX_BLOCK_Q, _one_tile(t))
+        return block, block
     itemsize = jnp.dtype(dtype).itemsize
     t_min = -(-t // _FLASH_MIN_BLOCK) * _FLASH_MIN_BLOCK
 
@@ -204,13 +230,62 @@ def _first_live_q(ki, block_q, block_k):
     return jax.lax.div(_affine(ki, block_k), np.int32(block_q))
 
 
+def _first_live_k(qi, block_q, block_k, window):
+    """The first k block that holds a key inside the window of q block
+    qi's first row."""
+    return jax.lax.div(
+        jax.lax.max(_affine(qi, block_q, 1 - window), np.int32(0)),
+        np.int32(block_k))
+
+
+def _last_live_q(ki, block_q, block_k, window, nq):
+    """The last q block that holds a row whose window reaches k block
+    ki's last key."""
+    return jax.lax.min(
+        jax.lax.div(_affine(ki, block_k, block_k + window - 2),
+                    np.int32(block_q)),
+        np.int32(nq - 1))
+
+
+def _band_steps(nq, nk, block_q, block_k, window, inner):
+    """Extent of the grid's inner dimension under a window: the most
+    inner blocks the band of any one outer block touches."""
+    if inner == "k":
+        return max((i * block_q + block_q - 1) // block_k
+                   - max(i * block_q + 1 - window, 0) // block_k + 1
+                   for i in range(nq))
+    return max(min((i * block_k + block_k + window - 2) // block_q, nq - 1)
+               - (i * block_k) // block_q + 1 for i in range(nk))
+
+
+def _inner_k(qi, j, *, block_q, block_k, window):
+    """The k block that inner step j of q block qi visits (forward,
+    dq): j itself, or the j-th of the band."""
+    if not window:
+        return j
+    return jax.lax.add(_first_live_k(qi, block_q, block_k, window), j)
+
+
+def _inner_q(ki, j, *, block_q, block_k, window, steps, group):
+    """(q head within the group, q block) that inner step j of k block
+    ki visits (dkv): the group's heads one after another, ``steps``
+    blocks each."""
+    head = None
+    if group > 1:
+        head = jax.lax.div(j, np.int32(steps))
+        j = jax.lax.rem(j, np.int32(steps))
+    if window:
+        j = jax.lax.add(_first_live_q(ki, block_q, block_k), j)
+    return head, j
+
+
 def _masked_scores(q, k_blk, qi, ki, *, block_q, block_k, t_real, scale,
-                   causal, masked=True):
+                   causal, window=0, masked=True):
     """The shared score/mask invariant of all three kernels:
-    s = scale·q@kᵀ on the MXU plus the (padding, causal) keep-mask for
-    this (qi, ki) block pair — None for a tile ``_tile_cases`` found to
-    need none. Kept in ONE place so forward and backward can never
-    disagree on masking."""
+    s = scale·q@kᵀ on the MXU plus the (padding, causal, window)
+    keep-mask for this (qi, ki) block pair — None for a tile
+    ``_tile_cases`` found to need none. Kept in ONE place so forward and
+    backward can never disagree on masking."""
     s = jnp.float32(scale) * jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -226,18 +301,33 @@ def _masked_scores(q, k_blk, qi, ki, *, block_q, block_k, t_real, scale,
     mask = k_pos < jnp.int32(t_real)
     if causal:
         mask = mask & (q_pos >= k_pos)
+    if window:
+        mask = mask & (q_pos - k_pos < jnp.int32(window))
     return s, mask
 
 
-def _tile_cases(body, qi, ki, *, block_q, block_k, t_real, t_pad, causal):
+def _tile_cases(body, qi, ki, *, block_q, block_k, t_real, t_pad, causal,
+                window=0):
     """Run ``body(masked)`` for this tile pair at what it holds: not at
-    all for a dead tile, with the mask where the diagonal crosses it or
-    it holds padding keys, without it everywhere else."""
+    all for a dead tile, with the mask where the diagonal or the
+    window's edge crosses it or it holds padding keys, without it
+    everywhere else."""
     live = needs_mask = None  # None: statically "always" / "never"
     if causal:
         live = _causal_block_live(qi, ki, block_q, block_k)
         needs_mask = jax.lax.gt(_affine(ki, block_k, block_k - 1),
                                 _affine(qi, block_q))
+    if window:
+        # the band's inner steps start at its first block, so a dead
+        # tile lies above the diagonal (forward, dq) or below the band
+        # or past the last q block (dkv)
+        live = jax.lax.bitwise_and(live, jax.lax.bitwise_and(
+            jax.lax.ge(_affine(ki, block_k, block_k + window - 2),
+                       _affine(qi, block_q)),
+            jax.lax.lt(qi, np.int32(t_pad // block_q))))
+        needs_mask = jax.lax.bitwise_or(needs_mask, jax.lax.ge(
+            _affine(qi, block_q, block_q - 1),
+            _affine(ki, block_k, window)))
     if t_real < t_pad:
         pads = jax.lax.gt(_affine(ki, block_k, block_k), np.int32(t_real))
         needs_mask = (pads if needs_mask is None
@@ -254,22 +344,23 @@ def _tile_cases(body, qi, ki, *, block_q, block_k, t_real, t_pad, causal):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
-                *, block_q, block_k, t_real, t_pad, scale, causal):
+                *, block_q, block_k, t_real, t_pad, scale, causal, window):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    j = pl.program_id(2)
+    ki = _inner_k(qi, j, block_q=block_q, block_k=block_k, window=window)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _():
         acc[...] = jnp.zeros_like(acc)
         m_s[...] = jnp.full_like(m_s, jnp.float32(_NEG_INF))
         l_s[...] = jnp.zeros_like(l_s)
 
     def body(masked):
-        v_blk = v_ref[0]  # [bk, D]
+        v_blk = v_ref[0]  # [bk, Dv]
         s, mask = _masked_scores(
             q_ref[0], k_ref[0], qi, ki, block_q=block_q, block_k=block_k,
-            t_real=t_real, scale=scale, causal=causal, masked=masked)
+            t_real=t_real, scale=scale, causal=causal, window=window,
+            masked=masked)
         if masked:
             s = jnp.where(mask, s, jnp.float32(_NEG_INF))
         m_prev = m_s[...]  # [bq, 1]
@@ -284,9 +375,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
         )
 
     _tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-                t_real=t_real, t_pad=t_pad, causal=causal)
+                t_real=t_real, t_pad=t_pad, causal=causal, window=window)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _():
         l_fin = l_s[...]
         safe_l = jnp.where(l_fin > 0, l_fin, jnp.float32(1.0))
@@ -312,12 +403,12 @@ def _bwd_p_ds(q, k_blk, v_blk, do, lse, delta, qi, ki, masked, **tile):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
                    dq_acc, *, block_q, block_k, t_real, t_pad, scale,
-                   causal):
+                   causal, window):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    j = pl.program_id(2)
+    ki = _inner_k(qi, j, block_q=block_q, block_k=block_k, window=window)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -326,28 +417,29 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
         _, ds = _bwd_p_ds(
             q_ref[0], k_blk, v_ref[0], do_ref[0], l_ref[0], d_ref[0],
             qi, ki, masked, block_q=block_q, block_k=block_k,
-            t_real=t_real, scale=scale, causal=causal)
+            t_real=t_real, scale=scale, causal=causal, window=window)
         dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     _tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-                t_real=t_real, t_pad=t_pad, causal=causal)
+                t_real=t_real, t_pad=t_pad, causal=causal, window=window)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _():
         dq_ref[0] = (jnp.float32(scale) * dq_acc[...]).astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                    t_real, t_pad, scale, causal):
+                    t_real, t_pad, scale, causal, window, steps, group):
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    j = pl.program_id(2)
+    _, qi = _inner_q(ki, j, block_q=block_q, block_k=block_k,
+                     window=window, steps=steps, group=group)
 
-    @pl.when(qi == 0)
+    @pl.when(j == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -358,20 +450,20 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
         p, ds = _bwd_p_ds(
             q, k_ref[0], v_ref[0], do, l_ref[0], d_ref[0], qi, ki,
             masked, block_q=block_q, block_k=block_k, t_real=t_real,
-            scale=scale, causal=causal)
+            scale=scale, causal=causal, window=window)
         dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [bk, D]
+        )  # [bk, Dv]
         dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     _tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-                t_real=t_real, t_pad=t_pad, causal=causal)
+                t_real=t_real, t_pad=t_pad, causal=causal, window=window)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _():
         dk_ref[0] = (jnp.float32(scale) * dk_acc[...]).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -387,138 +479,175 @@ def _operand_label(dtype):
                                   jnp.dtype(dtype).name)
 
 
-def _kernel_name(which, dtype, block_q, block_k):
-    return "flash_%s_%s_q%d_k%d" % (
-        which, _operand_label(dtype), block_q, block_k)
+def _kernel_name(which, dtype, block_q, block_k, window=0):
+    return "flash_%s_%s_q%d_k%d%s" % (
+        which, _operand_label(dtype), block_q, block_k,
+        "_w%d" % window if window else "")
 
 
 _FLASH_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _tile_specs(block_q, block_k, d, causal, inner):
-    """Block specs of a q-shaped tile, a k-shaped tile and a per-row
-    statistic for a grid whose innermost dimension walks ``inner``
-    ("k": forward and dq, grid (bh, nq, nk); "q": dkv, grid
-    (bh, nk, nq)). Under ``causal`` the streamed operand's index clamps
-    to the row's (column's) live range: a dead step names the tile
-    already resident and fetches nothing."""
+def _tile_specs(block_q, block_k, d, dv, causal, inner, *, window=0,
+                group=1, nq=0, steps=0):
+    """Block specs of a q-shaped tile, a k-shaped tile, their
+    value-width twins and a per-row statistic for a grid whose innermost
+    dimension walks ``inner`` ("k": forward and dq, grid (bh, nq, nk);
+    "q": dkv, grid (bg, nk, group * nq)). Under ``causal`` the streamed
+    operand's index clamps to the row's (column's) live range: a dead
+    step names the tile already resident and fetches nothing. The
+    leading index is a q head for q-shaped tiles and the key/value head
+    it reads for k-shaped ones."""
+    tile = dict(block_q=block_q, block_k=block_k, window=window)
     if inner == "k":
         def q_idx(b, i, j):
             return (b, i, 0)
 
         def k_idx(b, i, j):
+            j = _inner_k(i, j, **tile)
             if causal:
                 j = jax.lax.min(j, _last_live_k(i, block_q, block_k))
+            if group > 1:
+                b = jax.lax.div(b, np.int32(group))
             return (b, j, 0)
     else:
         def q_idx(b, i, j):
-            if causal:
+            head, j = _inner_q(i, j, steps=steps, group=group, **tile)
+            if window:
+                j = jax.lax.min(
+                    j, _last_live_q(i, block_q, block_k, window, nq))
+            elif causal:
                 j = jax.lax.max(j, _first_live_q(i, block_q, block_k))
+            if head is not None:
+                b = jax.lax.add(_affine(b, group), head)
             return (b, j, 0)
 
         def k_idx(b, i, j):
             return (b, i, 0)
     return (pl.BlockSpec((1, block_q, d), q_idx),
             pl.BlockSpec((1, block_k, d), k_idx),
+            pl.BlockSpec((1, block_q, dv), q_idx),
+            pl.BlockSpec((1, block_k, dv), k_idx),
             pl.BlockSpec((1, block_q, 1), q_idx))
 
 
-def _fwd_call(q3, k3, v3, *, t_real, scale, causal, block_q, block_k,
-              interpret):
+def _fwd_call(q3, k3, v3, *, t_real, scale, causal, window, block_q,
+              block_k, interpret):
     bh, t_pad, d = q3.shape
+    dv = v3.shape[2]
+    group = bh // k3.shape[0]
     nq = t_pad // block_q
     nk = t_pad // block_k
     kern = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, t_real=t_real,
-        t_pad=t_pad, scale=scale, causal=causal,
+        t_pad=t_pad, scale=scale, causal=causal, window=window,
     )
-    q_spec, k_spec, row_spec = _tile_specs(block_q, block_k, d, causal, "k")
+    q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+        block_q, block_k, d, dv, causal, "k", window=window, group=group)
+    inner = (_band_steps(nq, nk, block_q, block_k, window, "k")
+             if window else nk)
     with _no_x64():
         out, lse = pl.pallas_call(
             kern,
-            grid=(bh, nq, nk),
-            in_specs=[q_spec, k_spec, k_spec],
-            out_specs=[q_spec, row_spec],
+            grid=(bh, nq, inner),
+            in_specs=[q_spec, k_spec, v_spec],
+            out_specs=[o_spec, row_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
+                jax.ShapeDtypeStruct((bh, t_pad, dv), q3.dtype),
                 jax.ShapeDtypeStruct((bh, t_pad, 1), jnp.float32),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
             compiler_params=_FLASH_PARAMS,
-            name=_kernel_name("fwd", q3.dtype, block_q, block_k),
+            name=_kernel_name("fwd", q3.dtype, block_q, block_k, window),
             interpret=interpret,
         )(q3, k3, v3)
     return out, lse
 
 
 def _bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
-              block_q, block_k, interpret):
+              window, block_q, block_k, interpret):
     bh, t_pad, d = q3.shape
+    bg, dv = k3.shape[0], v3.shape[2]
+    group = bh // bg
     nq = t_pad // block_q
     nk = t_pad // block_k
     tile = dict(block_q=block_q, block_k=block_k, t_real=t_real,
-                t_pad=t_pad, scale=scale, causal=causal)
+                t_pad=t_pad, scale=scale, causal=causal, window=window)
     with _no_x64():
-        q_spec, k_spec, row_spec = _tile_specs(
-            block_q, block_k, d, causal, "k")
+        q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+            block_q, block_k, d, dv, causal, "k", window=window,
+            group=group)
+        inner = (_band_steps(nq, nk, block_q, block_k, window, "k")
+                 if window else nk)
         dq = pl.pallas_call(
             functools.partial(_bwd_dq_kernel, **tile),
-            grid=(bh, nq, nk),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+            grid=(bh, nq, inner),
+            in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             compiler_params=_FLASH_PARAMS,
-            name=_kernel_name("dq", q3.dtype, block_q, block_k),
+            name=_kernel_name("dq", q3.dtype, block_q, block_k, window),
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
-        q_spec, k_spec, row_spec = _tile_specs(
-            block_q, block_k, d, causal, "q")
-        dk, dv = pl.pallas_call(
-            functools.partial(_bwd_dkv_kernel, **tile),
-            grid=(bh, nk, nq),
-            in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-            out_specs=[k_spec, k_spec],
+        steps = (_band_steps(nq, nk, block_q, block_k, window, "q")
+                 if window else nq)
+        q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+            block_q, block_k, d, dv, causal, "q", window=window,
+            group=group, nq=nq, steps=steps)
+        dk, dv_ = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, steps=steps, group=group,
+                              **tile),
+            grid=(bg, nk, group * steps),
+            in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
+            out_specs=[k_spec, v_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
-                jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
+                jax.ShapeDtypeStruct((bg, t_pad, d), q3.dtype),
+                jax.ShapeDtypeStruct((bg, t_pad, dv), q3.dtype),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_k, d), jnp.float32),
-                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, dv), jnp.float32),
             ],
             compiler_params=_FLASH_PARAMS,
-            name=_kernel_name("dkv", q3.dtype, block_q, block_k),
+            name=_kernel_name("dkv", q3.dtype, block_q, block_k, window),
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7)
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9)
 )
-def _flash(q3, k3, v3, t_real, scale, causal, block_q, block_k):
-    out, _ = _flash_fwd(q3, k3, v3, t_real, scale, causal, block_q,
-                        block_k)
+def _flash(q3, k3, v3, sink, t_real, scale, causal, window, block_q,
+           block_k):
+    out, _ = _flash_fwd(q3, k3, v3, sink, t_real, scale, causal, window,
+                        block_q, block_k)
     return out
 
 
-def _flash_fwd(q3, k3, v3, t_real, scale, causal, block_q, block_k):
+def _flash_fwd(q3, k3, v3, sink, t_real, scale, causal, window, block_q,
+               block_k):
     out, lse = _by_platform(
         functools.partial(
             _fwd_call, t_real=t_real, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k),
+            window=window, block_q=block_q, block_k=block_k),
         q3, k3, v3)
-    return out, (q3, k3, v3, out, lse)
+    if sink is not None:
+        with_sink = jnp.logaddexp(lse, sink[:, None, None])
+        out = (out.astype(jnp.float32)
+               * jnp.exp(lse - with_sink)).astype(out.dtype)
+        lse = with_sink
+    return out, (q3, k3, v3, sink, out, lse)
 
 
-def _flash_bwd(t_real, scale, causal, block_q, block_k, res, g):
-    q3, k3, v3, out, lse = res
+def _flash_bwd(t_real, scale, causal, window, block_q, block_k, res, g):
+    q3, k3, v3, sink, out, lse = res
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
         keepdims=True,
@@ -526,17 +655,30 @@ def _flash_bwd(t_real, scale, causal, block_q, block_k, res, g):
     dq, dk, dv = _by_platform(
         functools.partial(
             _bwd_call, t_real=t_real, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k),
+            window=window, block_q=block_q, block_k=block_k),
         q3, k3, v3, g.astype(q3.dtype), lse, delta)
-    return dq, dk, dv
+    dsink = None
+    if sink is not None:
+        # the sink's probability exp(sink - lse) meets a zero value:
+        # d sink = sum_i p_sink,i * (0 - delta_i)
+        dsink = -jnp.sum(jnp.exp(sink[:, None, None] - lse) * delta,
+                         axis=(1, 2))
+    return dq, dk, dv, dsink
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _heads_first(x):
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None):
-    """Blockwise (flash) attention. q/k/v: [B, T, H, D] -> [B, T, H, D].
+                    block_k=None, window=0, sink=None):
+    """Blockwise (flash) attention. q [B, T, H, D], k [B, T, G, D],
+    v [B, T, G, Dv] -> [B, T, H, Dv]; H a multiple of G, query head h
+    reads key/value head ``h // (H / G)``.
 
     Pallas MXU kernels on TPU; the same kernels run under the Pallas
     interpreter elsewhere so tests don't need hardware. The TPU-native
@@ -546,58 +688,84 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     The MXU is fed the type the inputs arrive in, accumulating in
     float32; the softmax arithmetic is float32 whatever the inputs.
-    ``block_q`` / ``block_k`` default to ``flash_tiles(T, D, dtype)``;
-    pass them only to pin a tiling (tests, benchmarks).
+    ``block_q`` / ``block_k`` default to ``flash_tiles(T, max(D, Dv),
+    dtype, window)``; pass them only to pin a tiling (tests,
+    benchmarks). ``window`` w > 0 (with ``causal``): query i sees keys
+    i-w+1 .. i, and no tile outside that band is fetched or computed.
+    ``sink`` [H]: a learnable logit per query head that joins each
+    row's softmax denominator and carries no value (float32 arithmetic;
+    differentiable).
 
     NOTE: pallas_call has no GSPMD partitioning rules — inside pjit over a
     sharded mesh, wrap calls in shard_map (see parallel/ring_attention for
     the sp-sharded composition) or keep attention inputs replicated.
     """
     b, t, h, d = q.shape
+    g, dv = k.shape[2], v.shape[3]
+    if h % g or v.shape[2] != g or k.shape[3] != d:
+        raise ValueError(
+            "flash_attention: query %s, key %s, value %s: key and value "
+            "need one head count that divides the query's, and the key "
+            "the query's width" % (q.shape, k.shape, v.shape))
+    if window and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     if block_q is None or block_k is None:
-        auto_q, auto_k = flash_tiles(t, d, q.dtype)
+        auto_q, auto_k = flash_tiles(t, max(d, dv), q.dtype, window)
         block_q = block_q or auto_q
         block_k = block_k or auto_k
     if t < min(block_q, block_k):
         block_q = block_k = _one_tile(t)
-    _M_FLASH_LOWERINGS.inc(operands=_operand_label(q.dtype),
-                           block_q=int(block_q), block_k=int(block_k))
-    q3 = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    k3 = k.transpose(0, 2, 1, 3).reshape(b * h, t, d)
-    v3 = v.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+    labels = dict(operands=_operand_label(q.dtype), block_q=int(block_q),
+                  block_k=int(block_k))
+    if window or g != h or dv != d:
+        labels.update(window=int(window), kv_heads=int(g), dv=int(dv))
+    _M_FLASH_LOWERINGS.inc(**labels)
     mult = int(np.lcm(block_q, block_k))
-    q3, _ = _pad_to(q3, 1, mult)
-    k3, _ = _pad_to(k3, 1, mult)
-    v3, _ = _pad_to(v3, 1, mult)
-    out = _flash(q3, k3, v3, t, float(scale), bool(causal), int(block_q),
-                 int(block_k))
+    q3, k3, v3 = (_pad_to(_heads_first(x), 1, mult)[0] for x in (q, k, v))
+    if sink is not None:
+        sink = jnp.tile(sink.astype(jnp.float32), b)  # [B*H], as q3's rows
+    out = _flash(q3, k3, v3, sink, t, float(scale), bool(causal),
+                 int(window), int(block_q), int(block_k))
     out = out[:, :t]
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 
 
-def attention(q, k, v, causal=False, scale=None, mesh=None):
+def attention(q, k, v, causal=False, scale=None, mesh=None, window=0,
+              sink=None):
     """Shared attention dispatch for every model that wants fused
     attention without hand-picking a kernel: sequence-parallel ring
     attention when the mesh shards the sequence axis, the Pallas flash
     kernel when it pays (lowered for TPU and T >= 128, or forced via
     ``MXNET_TPU_FORCE_FLASH=1``), the materialized reference otherwise.
-    q/k/v: [B, T, H, D] -> [B, T, H, D]."""
+    q [B, T, H, D], k [B, T, G, D], v [B, T, G, Dv] -> [B, T, H, Dv];
+    ``window`` and ``sink`` as ``flash_attention`` takes them."""
     t = q.shape[1]
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ..parallel.ring_attention import sequence_parallel_attention
 
+        if window or sink is not None or k.shape != q.shape:
+            raise ValueError("attention: window, sink and grouped heads "
+                             "are not implemented over an sp mesh")
         return sequence_parallel_attention(q, k, v, mesh, causal=causal)
-    flash = functools.partial(flash_attention, causal=causal, scale=scale)
-    reference = functools.partial(
-        reference_attention, causal=causal, scale=scale)
+    extra = () if sink is None else (sink,)
+
+    def flash(q, k, v, *sink):
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window, sink=sink[0] if sink else None)
+
+    def reference(q, k, v, *sink):
+        return reference_attention(
+            q, k, v, causal=causal, scale=scale, window=window,
+            sink=sink[0] if sink else None)
+
     if mesh is None and os.environ.get("MXNET_TPU_FORCE_FLASH") == "1":
-        return flash(q, k, v)
+        return flash(q, k, v, *extra)
     if mesh is None and t >= 128:
         return jax.lax.platform_dependent(
-            q, k, v, tpu=flash, default=reference)
-    return reference(q, k, v)
+            q, k, v, *extra, tpu=flash, default=reference)
+    return reference(q, k, v, *extra)
 
 
 # ---------------------------------------------------------------------------
@@ -1000,17 +1168,30 @@ def conv_bwd_input(grad, weight, dshape, pad, block_n=None):
     return jnp.transpose(gd[:n], (0, 3, 1, 2))
 
 
-def reference_attention(q, k, v, causal=False, scale=None):
+def reference_attention(q, k, v, causal=False, scale=None, window=0,
+                        sink=None):
     """Materialized-scores attention, the correctness oracle for the
-    kernels (and the XLA path for tiny sequence lengths)."""
+    kernels (and the XLA path for tiny sequence lengths): shapes,
+    ``window`` and ``sink`` as ``flash_attention`` takes them."""
     b, t, h, d = q.shape
+    group = h // k.shape[2]
+    if group > 1:
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
     if scale is None:
         scale = 1.0 / float(np.sqrt(d))
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
-        mask = jnp.tril(jnp.ones((t, t), bool))
+        pos = np.arange(t)
+        mask = pos[:, None] >= pos[None, :]
+        if window:
+            mask &= pos[:, None] - pos[None, :] < window
         s = jnp.where(mask[None, None], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    if sink is not None:
+        s = jnp.concatenate([s, jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None], (b, h, t, 1))],
+            axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :t]
     return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(
         q.dtype
     )

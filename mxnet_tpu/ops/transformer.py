@@ -69,43 +69,52 @@ register(
 # --------------------------------------------------------------------------
 # RoPE — rotary position embedding, half-rotation convention
 # --------------------------------------------------------------------------
-def rope(x, num_heads, theta):
+def rope(x, num_heads, theta, rotary_dim=0):
     """Rotate ``x`` [B, T, H*D] by its positions 0..T-1. The pairs are
-    (i, i + D/2) within a head — the ``rotate_half`` convention of the
-    published code, not the interleaved (2i, 2i+1) one. Angles, sines
-    and the rotation itself are float32; the result is ``x``'s dtype."""
+    (i, i + R/2) within the first R = ``rotary_dim`` dimensions of a
+    head (0: the whole head) — the ``rotate_half`` convention of the
+    published code, not the interleaved (2i, 2i+1) one; the dimensions
+    past R pass through. Angles, sines and the rotation itself are
+    float32; the result is ``x``'s dtype."""
     b, t, hd = x.shape
     d = hd // num_heads
-    inv_freq = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+    r = rotary_dim or d
+    inv_freq = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
     angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
     cos = jnp.asarray(np.cos(angles), jnp.float32)[None, :, None, :]
     sin = jnp.asarray(np.sin(angles), jnp.float32)[None, :, None, :]
     x4 = x.astype(jnp.float32).reshape(b, t, num_heads, d)
-    x1, x2 = x4[..., : d // 2], x4[..., d // 2:]
+    x1, x2 = x4[..., : r // 2], x4[..., r // 2: r]
     out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x4[..., r:]], axis=-1)
     return out.reshape(b, t, hd).astype(x.dtype)
 
 
 def _rope(attrs, ins, is_train):
     return [rope(ins[0], int(attrs["num_heads"]),
-                 float(attrs.get("theta", 10000.0)))]
+                 float(attrs.get("theta", 10000.0)),
+                 int(attrs.get("rotary_dim", 0)))]
 
 
-def _heads_infer(what, n_in):
-    def infer(attrs, in_shapes):
-        data = _known(in_shapes[0], what)
-        heads = int(attrs["num_heads"])
-        if len(data) != 3 or heads <= 0 or data[2] % (2 * heads):
-            # ValueError: a known-but-wrong shape must survive the infer
-            # fixpoint loop (see SwitchMoE)
-            raise ValueError(
-                "%s: data must be [batch, time, num_heads * head_dim] with "
-                "an even head_dim, got %s for num_heads=%d"
-                % (what, data, heads))
-        return [data] * n_in, [data], []
+def _split_heads(what, name, shape, heads):
+    """``shape`` [batch, time, heads * head_dim] -> head_dim. A
+    ValueError: a known-but-wrong shape must survive the infer fixpoint
+    loop (see SwitchMoE)."""
+    if len(shape) != 3 or heads <= 0 or shape[2] % heads:
+        raise ValueError(
+            "%s: %s must be [batch, time, %d heads * head_dim], got %s"
+            % (what, name, heads, shape))
+    return shape[2] // heads
 
-    return infer
+
+def _rope_infer(attrs, in_shapes):
+    data = _known(in_shapes[0], "RoPE")
+    d = _split_heads("RoPE", "data", data, int(attrs["num_heads"]))
+    r = int(attrs.get("rotary_dim", 0)) or d
+    if r % 2 or r > d:
+        raise ValueError("RoPE: rotary_dim must be even and at most the "
+                         "head_dim %d, got %d" % (d, r))
+    return [data], [data], []
 
 
 register(
@@ -113,8 +122,8 @@ register(
         "_contrib_RoPE",
         _rope,
         arguments=("data",),
-        defaults={"num_heads": 1, "theta": 10000.0},
-        infer_shape=_heads_infer("RoPE", 1),
+        defaults={"num_heads": 1, "theta": 10000.0, "rotary_dim": 0},
+        infer_shape=_rope_infer,
         aliases=("RoPE",),
     )
 )
@@ -123,28 +132,68 @@ register(
 # --------------------------------------------------------------------------
 # Attention — multi-head scaled-dot-product attention
 # --------------------------------------------------------------------------
+def _kv_heads(attrs):
+    return int(attrs.get("num_kv_heads", 0)) or int(attrs["num_heads"])
+
+
 def _attention(attrs, ins, is_train):
     from .pallas_kernels import attention
 
-    q, k, v = ins
-    heads = int(attrs["num_heads"])
-    b, t, hd = q.shape
-    split = (b, t, heads, hd // heads)
-    out = attention(q.reshape(split), k.reshape(split), v.reshape(split),
-                    causal=bool(attrs.get("causal", True)))
-    return [out.reshape(b, t, hd)]
+    q, k, v = ins[:3]
+    heads, kv_heads = int(attrs["num_heads"]), _kv_heads(attrs)
+    window = int(attrs.get("window", 0))
+    b, t, _ = q.shape
+
+    def split(x, n):
+        return x.reshape(b, t, n, x.shape[2] // n)
+
+    with jax.named_scope("window" if window else "full"):
+        out = attention(split(q, heads), split(k, kv_heads),
+                        split(v, kv_heads),
+                        causal=bool(attrs.get("causal", True)),
+                        window=window,
+                        sink=ins[3] if bool(attrs.get("with_sink", False))
+                        else None)
+    return [out.reshape(b, t, -1)]
 
 
-register(
-    OpDef(
-        "_contrib_Attention",
-        _attention,
-        arguments=("query", "key", "value"),
-        defaults={"num_heads": 1, "causal": True},
-        infer_shape=_heads_infer("Attention", 3),
-        aliases=("Attention",),
-    )
+def _attention_infer(attrs, in_shapes):
+    heads, kv_heads = int(attrs["num_heads"]), _kv_heads(attrs)
+    if kv_heads <= 0 or heads % kv_heads:
+        raise ValueError("Attention: num_kv_heads=%d must divide "
+                         "num_heads=%d" % (kv_heads, heads))
+    if int(attrs.get("window", 0)) and not bool(attrs.get("causal", True)):
+        raise ValueError("Attention: a window needs causal=True")
+    q, k, v = (_known(shape, "Attention") for shape in in_shapes[:3])
+    d = _split_heads("Attention", "query", q, heads)
+    dk = _split_heads("Attention", "key", k, kv_heads)
+    dv = _split_heads("Attention", "value", v, kv_heads)
+    for name, shape in (("key", k), ("value", v)):
+        if shape[:2] != q[:2]:
+            raise ValueError(
+                "Attention: %s %s does not share query's batch and time "
+                "%s" % (name, shape, q[:2]))
+    if dk != d:
+        raise ValueError(
+            "Attention: key %s has head_dim %d over %d heads, query %s "
+            "has %d over %d" % (k, dk, kv_heads, q, d, heads))
+    ins = [q, k, v] + [(heads,)] * (len(in_shapes) - 3)
+    return ins, [q[:2] + (heads * dv,)], []
+
+
+_attn = OpDef(
+    "_contrib_Attention",
+    _attention,
+    arguments=("query", "key", "value", "sink"),
+    defaults={"num_heads": 1, "num_kv_heads": 0, "causal": True,
+              "window": 0, "with_sink": False},
+    infer_shape=_attention_infer,
+    aliases=("Attention",),
 )
+_attn.list_arguments = lambda attrs=None: (
+    ["query", "key", "value", "sink"]
+    if (attrs or {}).get("with_sink") else ["query", "key", "value"])
+register(_attn)
 
 
 # --------------------------------------------------------------------------
@@ -152,16 +201,22 @@ register(
 # --------------------------------------------------------------------------
 def _topk_moe(attrs, ins, is_train):
     """``parallel/moe.topk_moe`` as a Symbol op. Two outputs: the routed
-    FFN result and how many (token, expert) rows each expert received —
-    float32 so that it can ride out of a training step beside the loss
-    (behind ``BlockGrad``; it has no gradient)."""
+    FFN result and how many (token, expert) rows each of the
+    ``num_experts`` experts received — float32 so that it can ride out
+    of a training step beside the loss (behind ``BlockGrad``; it has no
+    gradient)."""
     from ..parallel.moe import topk_moe
 
-    data, gate_w, w_gate_up, w_down = ins
+    data, gate_w, w_gate_up, w_down = ins[:4]
+    params = {"gate_w": gate_w, "w_gate_up": w_gate_up, "w_down": w_down}
+    if bool(attrs.get("with_select_bias", False)):
+        params["select_bias"] = ins[4]
     y, counts = topk_moe(
-        {"gate_w": gate_w, "w_gate_up": w_gate_up, "w_down": w_down},
-        data, top_k=int(attrs["top_k"]),
-        norm_topk_prob=bool(attrs.get("norm_topk_prob", False)))
+        params, data, top_k=int(attrs["top_k"]),
+        norm_topk_prob=bool(attrs.get("norm_topk_prob", False)),
+        scoring=str(attrs.get("scoring", "softmax")),
+        expert_offset=int(attrs.get("expert_offset", 0)),
+        share_rows_bound=int(attrs.get("share_rows_bound", 0)))
     return [y, counts.astype(jnp.float32)]
 
 
@@ -172,6 +227,8 @@ def _topk_moe_infer(attrs, in_shapes):
                          "(Reshape (B,T,D) inputs to (B*T, D))")
     d_model = data[1]
     num_experts = int(attrs["num_experts"])
+    held = int(attrs.get("experts_held", 0)) or num_experts
+    offset = int(attrs.get("expert_offset", 0))
     hidden = int(attrs["num_hidden"])
     top_k = int(attrs["top_k"])
     if hidden <= 0:
@@ -179,12 +236,24 @@ def _topk_moe_infer(attrs, in_shapes):
     if not 1 <= top_k <= num_experts:
         raise ValueError("TopKMoE: top_k must lie in 1..num_experts, got "
                          "%d of %d" % (top_k, num_experts))
-    return (
-        [data, (d_model, num_experts),
-         (num_experts, d_model, 2 * hidden), (num_experts, hidden, d_model)],
-        [data, (num_experts,)],
-        [],
-    )
+    if str(attrs.get("scoring", "softmax")) not in ("softmax", "sigmoid"):
+        raise ValueError("TopKMoE: scoring must be softmax or sigmoid, "
+                         "got %r" % (attrs["scoring"],))
+    if not (0 < held <= num_experts and 0 <= offset <= num_experts - held):
+        raise ValueError(
+            "TopKMoE: experts_held=%d from expert_offset=%d are not among "
+            "num_experts=%d" % (held, offset, num_experts))
+    if held < num_experts and not (
+            0 < int(attrs.get("share_rows_bound", 0)) <= data[0] * top_k):
+        raise ValueError(
+            "TopKMoE: a share (experts_held=%d of %d) needs "
+            "share_rows_bound in 1..tokens * top_k (%d), got %s"
+            % (held, num_experts, data[0] * top_k,
+               attrs.get("share_rows_bound", 0)))
+    ins = [data, (d_model, num_experts), (held, d_model, 2 * hidden),
+           (held, hidden, d_model)]
+    return (ins + [(num_experts,)] * (len(in_shapes) - 4),
+            [data, (num_experts,)], [])
 
 
 def _topk_moe_infer_type(attrs, in_types):
@@ -196,16 +265,22 @@ def _topk_moe_infer_type(attrs, in_types):
             [t, np.float32], [])
 
 
-register(
-    OpDef(
-        "_contrib_TopKMoE",
-        _topk_moe,
-        arguments=("data", "gate_weight", "gate_up_weight", "down_weight"),
-        outputs=("output", "expert_count"),
-        defaults={"num_experts": 8, "num_hidden": 0, "top_k": 2,
-                  "norm_topk_prob": False},
-        infer_shape=_topk_moe_infer,
-        infer_type=_topk_moe_infer_type,
-        aliases=("TopKMoE",),
-    )
+_moe = OpDef(
+    "_contrib_TopKMoE",
+    _topk_moe,
+    arguments=("data", "gate_weight", "gate_up_weight", "down_weight",
+               "select_bias"),
+    outputs=("output", "expert_count"),
+    defaults={"num_experts": 8, "num_hidden": 0, "top_k": 2,
+              "norm_topk_prob": False, "scoring": "softmax",
+              "with_select_bias": False, "experts_held": 0,
+              "expert_offset": 0,
+              "share_rows_bound": 0},
+    infer_shape=_topk_moe_infer,
+    infer_type=_topk_moe_infer_type,
+    aliases=("TopKMoE",),
 )
+_moe.list_arguments = lambda attrs=None: (
+    ["data", "gate_weight", "gate_up_weight", "down_weight"]
+    + (["select_bias"] if (attrs or {}).get("with_select_bias") else []))
+register(_moe)

@@ -21,6 +21,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry as _tm
+
 
 def init_moe_params(rng, d_model, d_hidden, num_experts, dtype=jnp.float32):
     """Router + expert weights. Expert-major tensors lead with the E axis
@@ -122,7 +124,47 @@ def _expert_dot(counts, rows, dtype):
                              metadata=metadata, off_tpu=_EXPERTS_OFF_TPU)
 
 
-def topk_moe(params, x, top_k, norm_topk_prob=False):
+_M_SHARE_LOWERINGS = _tm.counter(
+    "moe.share_lowerings", "Traces of a topk_moe call site that holds a "
+    "share of its experts (one per lowering, nothing per step); labels: "
+    "held (experts computed here), of (experts routed over), bound (rows "
+    "of the share's buffer)")
+
+
+def _route(params, x, top_k, norm_topk_prob, scoring):
+    """Float32 routing: (weights [T, k], experts [T, k]). ``softmax``:
+    top-k of the softmax. ``sigmoid``: scores ``sigmoid(logits)``, the
+    choice by score plus ``select_bias`` (which carries no gradient and
+    never reaches the weights), the weights the chosen scores."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), params["gate_w"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        weights, experts = jax.lax.top_k(
+            jax.nn.softmax(logits, axis=-1), top_k)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        select = scores
+        if params.get("select_bias") is not None:
+            select = scores + jax.lax.stop_gradient(
+                params["select_bias"].astype(jnp.float32))
+        _, experts = jax.lax.top_k(select, top_k)
+        # the chosen scores by a mask over the experts, not a gather:
+        # [T, k, E] compares fuse into one pass, forward and backward,
+        # where take_along_axis is a gather and then a scatter-add
+        chosen = experts[:, :, None] == jax.lax.broadcasted_iota(
+            experts.dtype, (1, 1, scores.shape[1]), 2)
+        weights = jnp.sum(jnp.where(chosen, scores[:, None, :], 0), axis=-1)
+    else:
+        raise ValueError("topk_moe: scoring must be softmax or sigmoid, "
+                         "got %r" % (scoring,))
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts
+
+
+def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
+             expert_offset=0, share_rows_bound=0):
     """Dropless top-k MoE FFN with SwiGLU experts (the OLMoE / Mixtral
     block). x: [tokens, d_model] -> ([tokens, d_model], counts [E]).
 
@@ -141,23 +183,35 @@ def topk_moe(params, x, top_k, norm_topk_prob=False):
     the TPU, ``jax.lax.ragged_dot`` everywhere else (by
     ``lax.platform_dependent``, inside that function); both take
     operands of ``x.dtype``, accumulate in float32 and round once. The
-    router (matmul at full float32 precision, softmax, top-k) stays in
-    float32 whatever the activations' dtype; routing weights are not
-    renormalised unless ``norm_topk_prob``. ``counts`` is the number of
-    rows each expert received (int32, no gradient).
+    router (matmul at full float32 precision, scores, top-k) stays in
+    float32 whatever the activations' dtype (``_route``: ``scoring``
+    ``softmax`` or ``sigmoid``, the latter with the optional
+    ``select_bias`` [E]); routing weights are not renormalised unless
+    ``norm_topk_prob``. ``counts`` is the number of rows each of the E
+    experts received (int32, no gradient).
+
+    **A share of the experts.** Where ``w_gate_up`` holds H < E experts,
+    they are the router's experts ``expert_offset`` .. ``expert_offset
+    + H - 1``: the layer routes over all E, and computes the part of
+    the result that the held experts give (what an expert-parallel
+    member computes before the exchange; nothing stands in for the
+    others). The rows routed here are compacted into a buffer of
+    ``share_rows_bound`` rows, and gather, expert products and combine
+    run over that buffer, not over ``tokens * top_k``. Rows past the
+    bound are not computed; ``counts`` still counts every row over all
+    E, so a caller sees that they existed.
     """
     tokens, d_model = x.shape
     num_experts = params["gate_w"].shape[1]
-    hidden = params["w_down"].shape[1]
+    held, hidden = params["w_down"].shape[:2]
 
     with jax.named_scope("router"):
-        logits = jnp.dot(
-            x.astype(jnp.float32), params["gate_w"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST)
-        probs = jax.nn.softmax(logits, axis=-1)               # [T, E]
-        weights, experts = jax.lax.top_k(probs, top_k)        # [T, k]
-        if norm_topk_prob:
-            weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        weights, experts = _route(params, x, top_k, norm_topk_prob,
+                                  scoring)
+
+    if held < num_experts:
+        return _topk_moe_share(params, x, weights, experts, expert_offset,
+                               share_rows_bound)
 
     with jax.named_scope("dispatch"):
         flat_expert = experts.reshape(-1)                     # [T*k]
@@ -181,4 +235,65 @@ def topk_moe(params, x, top_k, norm_topk_prob=False):
             tokens, top_k, d_model)
         y = jnp.einsum("tkd,tk->td", per_token.astype(jnp.float32),
                        weights)
+    return y.astype(x.dtype), jax.lax.stop_gradient(counts)
+
+
+def _topk_moe_share(params, x, weights, experts, offset, bound):
+    """``topk_moe`` where the layer holds experts ``offset`` ..
+    ``offset + H - 1`` of the E it routes over: their part of the
+    result, over a buffer of ``bound`` rows."""
+    tokens, d_model = x.shape
+    top_k = experts.shape[1]
+    num_experts = params["gate_w"].shape[1]
+    held, hidden = params["w_down"].shape[:2]
+    if not 0 < bound <= tokens * top_k:
+        raise ValueError(
+            "topk_moe: a share needs share_rows_bound in 1..tokens * "
+            "top_k (%d), got %d" % (tokens * top_k, bound))
+    if not 0 <= offset <= num_experts - held:
+        raise ValueError(
+            "topk_moe: experts %d..%d are not among the router's %d"
+            % (offset, offset + held - 1, num_experts))
+    _M_SHARE_LOWERINGS.inc(held=held, of=num_experts, bound=bound)
+
+    with jax.named_scope("dispatch"):
+        flat_expert = experts.reshape(-1)                     # [T*k]
+        counts = jnp.sum(jax.nn.one_hot(
+            flat_expert, num_experts, dtype=jnp.int32), axis=0)
+        local = flat_expert - offset
+        here = (local >= 0) & (local < held)
+        # the first ``bound`` pairs routed here, in token order: row r
+        # of the buffer takes the first pair whose running count of
+        # held pairs reaches r + 1 (a fused compare-and-count; what
+        # ``jnp.nonzero(size=)`` does by a scatter-add over every
+        # pair); one past the last pair marks a free row
+        running = jnp.cumsum(here.astype(jnp.int32))
+        pairs = jnp.searchsorted(
+            running, jnp.arange(1, bound + 1, dtype=jnp.int32),
+            side="left", method="compare_all")
+        group = jnp.take(local, pairs, mode="fill", fill_value=held)
+        order = jnp.argsort(group, stable=True)               # by expert
+        pairs, group = pairs[order], group[order]
+        used = group < held                                   # [bound]
+        sizes = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32),
+                        axis=0)
+        token = jnp.where(used, pairs // top_k, 0)
+        # a free row belongs to no group: the grouped products leave it
+        # unwritten, so it is zeroed on the way in and on the way out
+        # (and with it its gradients)
+        rows = jnp.where(used[:, None], jnp.take(x, token, axis=0), 0)
+
+    with jax.named_scope("experts"):
+        dot = _expert_dot(sizes, bound, x.dtype)
+        gate_up = dot(rows, params["w_gate_up"].astype(x.dtype))
+        act = jax.nn.silu(gate_up[:, :hidden]) * gate_up[:, hidden:]
+        out_rows = dot(act, params["w_down"].astype(x.dtype))  # [bound, d]
+
+    with jax.named_scope("combine"):
+        weight = jnp.where(used, jnp.take(
+            weights.reshape(-1), pairs, mode="fill", fill_value=0), 0)
+        weighted = jnp.where(
+            used[:, None],
+            out_rows.astype(jnp.float32) * weight[:, None], 0)
+        y = jax.ops.segment_sum(weighted, token, num_segments=tokens)
     return y.astype(x.dtype), jax.lax.stop_gradient(counts)

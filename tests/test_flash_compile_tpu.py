@@ -66,12 +66,41 @@ def test_flash_chosen_tiles_compile_for_v5e(one_chip, t, h, d, dtype,
         assert "flash_%s_%s_q%d_k%d" % (which, operands, bq, bk) in text
 
 
+# the MiMo-V2-Flash share cell's two calls: 8 query heads of 192 on one
+# key/value head, values of 128; a window of 128 with a sink, and full
+@pytest.mark.parametrize("window,sink", [(128, True), (0, False)])
+def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, window,
+                                                        sink):
+    t, h, d, dv = 4096, 8, 192, 128
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(q, k, v, s):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window,
+            sink=s if sink else None).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3) if sink
+                            else (0, 1, 2))).lower(
+        shape(1, t, h, d), shape(1, t, 1, d), shape(1, t, 1, dv),
+        shape(h, dtype=jnp.float32)).compile().as_text()
+    bq, bk = flash_tiles(t, d, jnp.bfloat16, window)
+    assert (bq, bk) == ((256, 256) if window else (1024, 1024))
+    for which in ("fwd", "dq", "dkv"):
+        assert "flash_%s_bf16_q%d_k%d%s" % (
+            which, bq, bk, "_w128" if window else "") in text
+
+
 # the OLMoE cell's two expert products (gate/up, down); a float32 caller
-# at the widest result tile its budget admits
+# at the widest result tile its budget admits; the MiMo share cell's two
+# (8 held experts over a buffer of 2,048 rows)
 GMM_SHAPES = [
     (32768, 2048, 2048, 64, jnp.bfloat16),
     (32768, 1024, 2048, 64, jnp.bfloat16),
     (8192, 2048, 1024, 16, jnp.float32),
+    (2048, 4096, 4096, 8, jnp.bfloat16),
+    (2048, 2048, 4096, 8, jnp.bfloat16),
 ]
 
 

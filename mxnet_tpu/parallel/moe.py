@@ -124,6 +124,88 @@ def _expert_dot(counts, rows, dtype):
                              metadata=metadata, off_tpu=_EXPERTS_OFF_TPU)
 
 
+_M_PERMUTE_LOWERINGS = _tm.counter(
+    "moe.permute_lowerings", "Traces of a topk_moe call site that moves "
+    "its rows into expert order and back by the permutation pair (one "
+    "per lowering, nothing per step); labels: rows (tokens * top_k), "
+    "top_k, width (d_model)")
+
+
+def _take_rows(a, index):
+    """``a[index]`` along the first axis, every index in range: one
+    ``lax.gather``, without the clamp or fill of ``jnp.take``."""
+    numbers = jax.lax.GatherDimensionNumbers(
+        offset_dims=tuple(range(1, a.ndim)), collapsed_slice_dims=(0,),
+        start_index_map=(0,))
+    return jax.lax.gather(
+        a, index[:, None], numbers, (1,) + a.shape[1:],
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+
+
+# The row moves round the experts. ``order`` [tokens * top_k] sorts the
+# (token, expert) pairs by expert and ``inverse`` [tokens, top_k] is its
+# inverse permutation (the row of a token's j-th expert), which autodiff
+# cannot know: it transposes each gather to a scatter-add over the rows.
+# Here the transpose of a gather by one is a gather by the other, so rows
+# move by gathers only, forward and backward; the indices carry no
+# gradient.
+
+@jax.custom_vjp
+def _dispatch(x, order, inverse):
+    """Token rows into expert order: ``rows[r] = x[order[r] // top_k]``."""
+    return _dispatch_fwd(x, order, inverse)[0]
+
+
+def _dispatch_fwd(x, order, inverse):
+    top_k = inverse.shape[1]
+    _M_PERMUTE_LOWERINGS.inc(rows=order.shape[0], top_k=top_k,
+                             width=x.shape[1])
+    return _take_rows(x, jax.lax.div(order, jnp.int32(top_k))), inverse
+
+
+def _dispatch_bwd(inverse, d_rows):
+    # a token's top_k cotangent rows, summed in float32 and rounded once
+    per_token = _take_rows(d_rows, inverse.reshape(-1)).reshape(
+        inverse.shape + d_rows.shape[1:])
+    dx = jnp.sum(per_token.astype(jnp.float32), axis=1)
+    return dx.astype(d_rows.dtype), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(out_rows, weights, order, inverse):
+    """Expert rows back to their tokens and a token's ``top_k`` rows
+    summed under its routing weights in float32: ``y[t] = sum_j
+    weights[t, j] * out_rows[inverse[t, j]]``."""
+    return _combine_fwd(out_rows, weights, order, inverse)[0]
+
+
+def _combine_fwd(out_rows, weights, order, inverse):
+    per_token = _take_rows(out_rows, inverse.reshape(-1)).reshape(
+        inverse.shape + out_rows.shape[1:])
+    y = jnp.einsum("tkd,tk->td", per_token.astype(jnp.float32), weights)
+    return y.astype(out_rows.dtype), (per_token, weights, order)
+
+
+def _combine_bwd(res, dy):
+    per_token, weights, order = res
+    top_k = weights.shape[1]
+    d_weights = jnp.einsum("td,tkd->tk", dy.astype(jnp.float32),
+                           per_token.astype(jnp.float32))
+    # the [tokens, d] cotangent gathered into expert order and scaled a
+    # row, rounded once: the [tokens, top_k, d] float32 cotangent of
+    # ``per_token`` is never written
+    scale = _take_rows(weights.reshape(-1), order)
+    d_rows = _take_rows(dy, jax.lax.div(order, jnp.int32(top_k)))
+    d_rows = d_rows.astype(jnp.float32) * scale[:, None]
+    return d_rows.astype(per_token.dtype), d_weights, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 _M_SHARE_LOWERINGS = _tm.counter(
     "moe.share_lowerings", "Traces of a topk_moe call site that holds a "
     "share of its experts (one per lowering, nothing per step); labels: "
@@ -177,7 +259,10 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     whatever the load. The ``tokens * top_k`` rows are sorted by expert
     and the three expert matmuls run as two grouped matmuls over the
     sorted rows, so the work is that of the routing and not
-    ``O(T * E * C * d)`` as in ``switch_moe``'s dense dispatch. Who
+    ``O(T * E * C * d)`` as in ``switch_moe``'s dense dispatch. The rows
+    move into expert order and back by gathers only, forward and
+    backward (``_dispatch``, ``_combine``), and nothing on this path is
+    a scatter but the transpose of the router's ``top_k``. Who
     computes them (``_expert_dot``): the Pallas kernels of
     ``ops.pallas_kernels.grouped_matmul`` where the step is lowered for
     the TPU, ``jax.lax.ragged_dot`` everywhere else (by
@@ -201,7 +286,7 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     bound are not computed; ``counts`` still counts every row over all
     E, so a caller sees that they existed.
     """
-    tokens, d_model = x.shape
+    tokens = x.shape[0]
     num_experts = params["gate_w"].shape[1]
     held, hidden = params["w_down"].shape[:2]
 
@@ -215,11 +300,17 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
 
     with jax.named_scope("dispatch"):
         flat_expert = experts.reshape(-1)                     # [T*k]
-        order = jnp.argsort(flat_expert, stable=True)         # by expert
-        inverse = jnp.argsort(order)
-        counts = jnp.bincount(
-            flat_expert, length=num_experts).astype(jnp.int32)
-        rows = jnp.take(x, order // top_k, axis=0)            # [T*k, d]
+        # int32 whatever jax_enable_x64 says: the chip sorts and
+        # gathers by 64-bit indices as pairs of words
+        pairs = jax.lax.iota(jnp.int32, tokens * top_k)
+        _, order = jax.lax.sort((flat_expert, pairs), num_keys=1,
+                                is_stable=True)               # by expert
+        _, inverse = jax.lax.sort((order, pairs), num_keys=1)
+        inverse = inverse.reshape(tokens, top_k)
+        counts = jnp.sum(jax.nn.one_hot(
+            flat_expert, num_experts, dtype=jnp.int32), axis=0,
+            dtype=jnp.int32)
+        rows = _dispatch(x, order, inverse)                   # [T*k, d]
 
     with jax.named_scope("experts"):
         dot = _expert_dot(counts, rows.shape[0], x.dtype)
@@ -228,14 +319,8 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
         out_rows = dot(act, params["w_down"].astype(x.dtype))  # [T*k, d]
 
     with jax.named_scope("combine"):
-        # back to token order by the inverse permutation (a gather, not
-        # a scatter-add), then the k weighted rows of a token summed in
-        # float32
-        per_token = jnp.take(out_rows, inverse, axis=0).reshape(
-            tokens, top_k, d_model)
-        y = jnp.einsum("tkd,tk->td", per_token.astype(jnp.float32),
-                       weights)
-    return y.astype(x.dtype), jax.lax.stop_gradient(counts)
+        y = _combine(out_rows, weights, order, inverse)
+    return y, jax.lax.stop_gradient(counts)
 
 
 def _topk_moe_share(params, x, weights, experts, offset, bound):

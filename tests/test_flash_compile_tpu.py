@@ -127,3 +127,37 @@ def test_gmm_chosen_tiles_compile_for_v5e(one_chip, m, k, n, groups, dtype):
         assert "gmm_%s_%s_m%d_k%d_n%d" % ((mode, operands) + tiles) in text
     # XLA's own grouped matmul is nowhere in the program
     assert "ragged_dot_tiling" not in text and "ragged-dot" not in text
+
+
+def test_expert_layer_moves_rows_by_gathers_on_v5e(one_chip):
+    """One OLMoE expert layer at the cell's shape (4,096 tokens of 2,048,
+    top-8 of 64 experts of width 1,024, bf16), forward and backward, as
+    the TPU's compiler leaves it: the row moves are gathers (XLA turned
+    none back into a scatter), the only scatter is the transpose of the
+    router's ``top_k`` over [tokens, experts], and the index vectors are
+    int32."""
+    import re
+
+    from mxnet_tpu.parallel.moe import topk_moe
+
+    tokens, d, experts, hidden, top_k = 4096, 2048, 64, 1024, 8
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    w = {"gate_w": spec(d, experts),
+         "w_gate_up": spec(experts, d, 2 * hidden),
+         "w_down": spec(experts, hidden, d)}
+
+    def loss(w, x):
+        return jnp.sum(topk_moe(w, x, top_k)[0].astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        w, spec(tokens, d)).compile().as_text()
+    scattered = re.findall(r"= (\S+?)\{[^ ]*\} scatter\(", text)
+    # (the compiler flattens it)
+    assert scattered and set(scattered) <= {
+        "f32[%d,%d]" % (tokens, experts), "f32[%d]" % (tokens * experts)}
+    gathered = re.findall(r"= (\S+?)\{[^ ]*\} gather\(", text)
+    assert gathered.count("bf16[%d,%d]" % (tokens * top_k, d)) == 4
+    assert "s64[%d]" % (tokens * top_k) not in text

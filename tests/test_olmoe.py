@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu.models import olmoe, olmoe_reference as ref
-from mxnet_tpu.parallel import make_mesh
+from mxnet_tpu.parallel import make_mesh, moe
 from mxnet_tpu.parallel.moe import topk_moe
 
 TINY = dict(vocab_size=512, hidden_size=64, num_layers=2, num_heads=4,
@@ -264,6 +264,196 @@ def test_topk_moe_is_dropless(case, experts_by, monkeypatch):
     for name in w:
         _close(grads[0][name], grads_ref[0][name], name, ulps=32)
     _close(grads[1], grads_ref[1], "d/dx", ulps=32)
+
+
+def _take_moe(w, x, top_k, norm_topk_prob=False, scoring="softmax"):
+    """``topk_moe``'s whole-layer path with its row moves as plain
+    ``jnp.take`` under autodiff (the formulation before the permutation
+    pair: the backward of each gather is a scatter-add) and its count as
+    ``jnp.bincount``."""
+    tokens, d = x.shape
+    hidden = w["w_down"].shape[1]
+    weights, experts = moe._route(w, x, top_k, norm_topk_prob, scoring)
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.bincount(flat, length=w["gate_w"].shape[1])
+    rows = jnp.take(x, order // top_k, axis=0)
+    gate_up = jax.lax.ragged_dot(rows, w["w_gate_up"], counts)
+    act = jax.nn.silu(gate_up[:, :hidden]) * gate_up[:, hidden:]
+    out_rows = jax.lax.ragged_dot(act, w["w_down"], counts)
+    per_token = jnp.take(out_rows, jnp.argsort(order), axis=0).reshape(
+        tokens, top_k, d)
+    y = jnp.einsum("tkd,tk->td", per_token.astype(jnp.float32), weights)
+    return y.astype(x.dtype), counts
+
+
+def _routed(rng, routing, tokens, d, experts, hidden):
+    """Weights and tokens whose routing is ``uniform`` (a random
+    router), ``skewed`` (three router columns that nearly always win, as
+    the seeded OLMoE cell's eight) or leaves one expert ``empty``."""
+    w = _moe_weights(rng, d, experts, hidden)
+    x = rng.randn(tokens, d).astype(np.float32)
+    x[:, 0] = np.abs(x[:, 0]) + 1
+    if routing == "skewed":
+        w["gate_w"][0, :3] += 10.0
+    elif routing == "empty":
+        w["gate_w"][0, 4] = -50.0
+    return w, x
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid_norm"])
+@pytest.mark.parametrize("routing", ["uniform", "skewed", "empty"])
+@pytest.mark.parametrize("top_k", [1, 2, 8])
+def test_row_moves_by_gathers_match_autodiff_of_take(top_k, routing,
+                                                     scoring, dtype):
+    """The permutation pair against ``jnp.take`` under autodiff: output,
+    counts and every gradient. float32: equal to summation order. bf16:
+    both round the same float32 products once, except that a token's
+    ``top_k`` cotangent rows are summed in float32 and rounded once here
+    and added one by one in bf16 there, so d/dx may differ by those
+    ``top_k - 1`` roundings (2**-9 of the largest element each)."""
+    tokens, d, experts, hidden = 64, 32, 16, 16
+    w, x = _routed(np.random.RandomState(11), routing, tokens, d, experts,
+                   hidden)
+    w = {n: jnp.asarray(v, dtype) for n, v in w.items()}
+    x = jnp.asarray(x, dtype)
+    kwargs = (dict(norm_topk_prob=True, scoring="sigmoid")
+              if scoring == "sigmoid_norm"
+              else dict(norm_topk_prob=False, scoring="softmax"))
+    mix = jnp.asarray(np.random.RandomState(12).randn(tokens, d),
+                      jnp.float32)
+
+    def loss(layer):
+        def f(w, x):
+            y, counts = layer(w, x, top_k, **kwargs)
+            return jnp.sum(y.astype(jnp.float32) * mix), (y, counts)
+        return jax.value_and_grad(f, argnums=(0, 1), has_aux=True)
+
+    (_, (y, counts)), (dw, dx) = loss(topk_moe)(w, x)
+    (_, (y_ref, counts_ref)), (dw_ref, dx_ref) = loss(_take_moe)(w, x)
+    assert counts.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(counts_ref))
+    flat = np.asarray(moe._route(w, x, top_k, **kwargs)[1]).reshape(-1)
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.bincount(flat, minlength=experts))
+    if routing == "empty":
+        assert int(counts[4]) == 0
+    if routing == "skewed" and top_k > 1:
+        assert int(counts[:3].sum()) > 0.8 * tokens * min(top_k, 3)
+    assert y.dtype == x.dtype and dx.dtype == x.dtype
+    if dtype == "float32":
+        _close(y, y_ref, "output")
+        for name in w:
+            _close(dw[name], dw_ref[name], name, ulps=32)
+        _close(dx, dx_ref, "d/dx", ulps=32)
+        return
+
+    def within(got, want, roundings, what):
+        got, want = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+        assert np.abs(got - want).max() <= (
+            roundings * 2.0 ** -9 * np.abs(want).max()), what
+
+    within(y, y_ref, 2, "output")
+    for name in w:
+        within(dw[name], dw_ref[name], 2, name)
+    within(dx, dx_ref, top_k + 1, "d/dx")
+
+
+@pytest.mark.parametrize("top_k", [2, 8])
+def test_bf16_row_moves_round_once(top_k):
+    """The two backward moves on bf16 cotangents against numpy in
+    float32: a token's ``top_k`` rows summed and rounded once (nearer
+    the float32 sum than the scatter-add of ``jnp.take``'s transpose,
+    which rounds after every row), and ``dy[token] * weight`` rounded
+    once into expert order."""
+    rng = np.random.RandomState(13)
+    tokens, d = 96, 24
+    order = np.argsort(rng.randint(0, 5, tokens * top_k),
+                       kind="stable").astype(np.int32)
+    inverse = np.argsort(order).astype(np.int32).reshape(tokens, top_k)
+    x = jnp.asarray(rng.randn(tokens, d), jnp.bfloat16)
+    d_rows = jnp.asarray(rng.randn(tokens * top_k, d), jnp.bfloat16)
+    weights = jnp.asarray(rng.rand(tokens, top_k), jnp.float32)
+    dy = jnp.asarray(rng.randn(tokens, d), jnp.bfloat16)
+
+    def f32(a):
+        return np.asarray(a.astype(jnp.float32))
+
+    def bf16(a):
+        return f32(jnp.asarray(a, jnp.bfloat16))
+
+    exact = f32(d_rows)[inverse].sum(axis=1)
+    dx, = jax.vjp(lambda x: moe._dispatch(x, order, inverse), x)[1](d_rows)
+    by_take, = jax.vjp(lambda x: jnp.take(x, order // top_k, axis=0),
+                       x)[1](d_rows)
+    assert dx.dtype == jnp.bfloat16
+    # float32 sums of top_k terms differ in their last place by order:
+    # one bf16 ulp where that flips the rounding
+    assert np.abs(f32(dx) - exact).max() <= 2.0 ** -8 * np.abs(exact).max()
+    worse = np.abs(f32(by_take) - exact).mean() - np.abs(f32(dx)
+                                                         - exact).mean()
+    assert worse > 0 if top_k > 2 else worse == 0  # two rows: one rounding
+
+    out_rows = jnp.asarray(rng.randn(tokens * top_k, d), jnp.bfloat16)
+    d_out, d_weights = jax.vjp(
+        lambda o, w_: moe._combine(o, w_, order, inverse),
+        out_rows, weights)[1](dy)
+    want = bf16(f32(dy)[order // top_k]
+                * np.asarray(weights).reshape(-1)[order][:, None])
+    assert d_out.dtype == jnp.bfloat16 and d_weights.dtype == jnp.float32
+    np.testing.assert_array_equal(f32(d_out), want)
+    _close(d_weights, np.einsum(
+        "td,tkd->tk", f32(dy), f32(out_rows)[inverse]), "d/dweights")
+
+
+def _scatter_operands(jaxpr):
+    """Shapes of the operands of every scatter in ``jaxpr`` and in the
+    jaxprs its equations hold."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith("scatter"):
+            found.append(tuple(eqn.invars[0].aval.shape))
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found.extend(_scatter_operands(sub))
+    return found
+
+
+def test_the_whole_layer_path_differentiates_without_row_scatters():
+    """``jax.grad(topk_moe)`` scatters nothing with ``d_model`` columns
+    and nothing over the ``tokens * top_k`` pairs: the one scatter-add
+    left is the transpose of the router's ``top_k``, over [tokens,
+    experts]. The ``jnp.take`` formulation shows that the search finds
+    the others: the count's and the two transposes'. One trace of a
+    call site counts once in ``moe.permute_lowerings``."""
+    from mxnet_tpu import telemetry
+
+    tokens, d, experts, hidden, top_k = 48, 20, 6, 8, 2
+    w, x = _routed(np.random.RandomState(14), "uniform", tokens, d, experts,
+                   hidden)
+
+    def grad_of(layer):
+        return jax.make_jaxpr(jax.grad(
+            lambda w, x: jnp.sum(layer(w, x, top_k)[0] ** 2),
+            argnums=(0, 1)))(w, jnp.asarray(x)).jaxpr
+
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        ours = _scatter_operands(grad_of(topk_moe))
+        counter = telemetry.REGISTRY.get("moe.permute_lowerings")
+        assert counter.value(rows=tokens * top_k, top_k=top_k, width=d) == 1
+        assert telemetry.total("moe.permute_lowerings") == 1
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert ours == [(tokens, experts)]
+    by_take = _scatter_operands(grad_of(_take_moe))
+    assert sorted(by_take) == sorted(
+        [(tokens, experts), (experts,), (tokens, d), (tokens * top_k, d)])
 
 
 def test_topk_moe_symbol_op_infers_and_checks():

@@ -123,6 +123,7 @@ _OP_CLASS = {
     "sigmoid": "act", "tanh": "act", "add_n": "act", "clip": "act",
     "MakeLoss": "loss", "softmax_cross_entropy": "loss",
     "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
+    "_contrib_LatentAttention": "attn",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed",
 }

@@ -11,6 +11,10 @@ by ``Module.fit``; ``olmoe_reference`` is its plain float32 reference.
 ``mimo_v2`` is MiMo-V2-Flash (window-128 and full attention over grouped
 heads, 256 sigmoid-routed experts) from the same ops, whole or as one
 chip's share of its layers, with ``mimo_v2_reference`` beside it.
+``kanana2`` is Kanana-2-30B-A3B (latent attention, shared experts beside
+128 sigmoid-routed ones), whole or as a share, with
+``kanana2_reference``; ``lm_blocks`` holds what the three LM symbols
+share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -24,4 +28,5 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
-from . import mimo_v2, mimo_v2_reference, olmoe, olmoe_reference
+from . import (kanana2, kanana2_reference, mimo_v2, mimo_v2_reference, olmoe,
+               olmoe_reference)

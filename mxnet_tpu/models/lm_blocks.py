@@ -1,0 +1,49 @@
+"""The pieces the decoder-only LM symbols share (``mimo_v2``,
+``kanana2``; the tail also ``olmoe``): a bias-free projection, the dense
+SwiGLU feed-forward, the routed expert layer's call and the head with
+its loss. Each takes the node-name prefix of its layer, so a model's
+argument and scope names are its own."""
+from .. import initializer as init
+from .. import symbol as sym
+from ..contrib import symbol as csym
+
+
+def linear(x, name, num_hidden):
+    return sym.FullyConnected(x, num_hidden=num_hidden, no_bias=True,
+                              name=name)
+
+
+def swiglu(x, prefix, width, hidden_size):
+    """``<prefix>down_proj(silu(<prefix>gate_proj(x)) *
+    <prefix>up_proj(x))`` at ``width`` columns."""
+    gate = sym.Activation(linear(x, prefix + "gate_proj", width),
+                          act_type="silu")
+    return linear(gate * linear(x, prefix + "up_proj", width),
+                  prefix + "down_proj", hidden_size)
+
+
+def expert_layer(x, prefix, **attrs):
+    """``TopKMoE`` named ``<prefix>moe`` with a zero selection bias that
+    the model states itself: (its output, its row counts behind
+    ``BlockGrad`` as ``<prefix>expert_count``)."""
+    moe = csym.TopKMoE(
+        x, with_select_bias=True, select_bias=sym.Variable(
+            prefix + "moe_select_bias", init=init.Zero()),
+        name=prefix + "moe", **attrs)
+    return moe[0], sym.BlockGrad(moe[1], name=prefix + "expert_count")
+
+
+def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps):
+    """``final_norm``, the untied ``lm_head``, float32 logits
+    (``lm_head_f32``) and each sequence's mean next-token cross-entropy
+    behind ``MakeLoss`` (``loss``), grouped with the layers' counts."""
+    logits = linear(csym.RMSNorm(h, eps=rms_eps, name="final_norm"),
+                    "lm_head", vocab_size)
+    logits = sym.Cast(logits, dtype="float32", name="lm_head_f32")
+    nll = 0 - sym.pick(sym.log_softmax(logits, name="lm_head_logp"),
+                       sym.Reshape(label, shape=(-1,)), axis=1,
+                       name="lm_head_pick")
+    per_sequence = sym.mean(sym.Reshape(nll, shape=(-1, seq_len)), axis=1,
+                            name="lm_head_mean")
+    loss = sym.MakeLoss(per_sequence, name="loss")
+    return sym.Group([loss] + counts)

@@ -58,6 +58,7 @@ makes every parameter bf16.
 from .. import initializer as init
 from .. import symbol as sym
 from ..contrib import symbol as csym
+from .lm_blocks import expert_layer, head_and_loss, linear, swiglu
 
 
 def get_symbol(vocab_size=152576, hidden_size=4096, layer_pattern=None,
@@ -80,10 +81,6 @@ def get_symbol(vocab_size=152576, hidden_size=4096, layer_pattern=None,
     if len(moe_pattern) != len(layer_pattern):
         raise ValueError("mimo_v2: layer_pattern and moe_pattern differ "
                          "in length")
-
-    def linear(x, name, num_hidden):
-        return sym.FullyConnected(x, num_hidden=num_hidden, no_bias=True,
-                                  name=name)
 
     def norm(x, name):
         return csym.RMSNorm(x, eps=rms_eps, name=name)
@@ -128,30 +125,17 @@ def get_symbol(vocab_size=152576, hidden_size=4096, layer_pattern=None,
         h = h + linear(attn, p + "o_proj", hidden_size)
         x = norm(h, p + "ffn_norm")
         if not experts:
-            gate = sym.Activation(linear(x, p + "gate_proj", dense_width),
-                                  act_type="silu")
-            h = h + linear(gate * linear(x, p + "up_proj", dense_width),
-                           p + "down_proj", hidden_size)
+            h = h + swiglu(x, p, dense_width, hidden_size)
             continue
-        moe = csym.TopKMoE(
-            x, with_select_bias=True, select_bias=sym.Variable(
-                p + "moe_select_bias", init=init.Zero()),
-            num_experts=num_experts, num_hidden=expert_width,
+        moe, count = expert_layer(
+            x, p, num_experts=num_experts, num_hidden=expert_width,
             top_k=experts_per_token, norm_topk_prob=norm_topk_prob,
             scoring=scoring, experts_held=experts_held,
             expert_offset=expert_offset,
-            share_rows_bound=share_rows_bound, name=p + "moe")
-        h = h + moe[0]
-        counts.append(sym.BlockGrad(moe[1], name=p + "expert_count"))
-    logits = linear(norm(h, "final_norm"), "lm_head", vocab_size)
-    logits = sym.Cast(logits, dtype="float32", name="lm_head_f32")
-    nll = 0 - sym.pick(sym.log_softmax(logits, name="lm_head_logp"),
-                       sym.Reshape(label, shape=(-1,)), axis=1,
-                       name="lm_head_pick")
-    per_sequence = sym.mean(sym.Reshape(nll, shape=(-1, seq_len)), axis=1,
-                            name="lm_head_mean")
-    loss = sym.MakeLoss(per_sequence, name="loss")
-    return sym.Group([loss] + counts)
+            share_rows_bound=share_rows_bound)
+        h = h + moe
+        counts.append(count)
+    return head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps)
 
 
 # keys whose value changes the mathematics and that this builder takes in

@@ -34,16 +34,13 @@ bf16 weights, what one chip runs: ``MXTPU_AMP`` needs dp > 1).
 """
 from .. import symbol as sym
 from ..contrib import symbol as csym
+from .lm_blocks import head_and_loss, linear
 
 
 def get_symbol(vocab_size=50304, hidden_size=2048, num_layers=16,
                num_heads=16, num_experts=64, experts_per_token=8,
                expert_width=1024, seq_len=4096, rope_theta=10000.0,
                rms_eps=1e-5, dtype="float32", norm_topk_prob=False):
-    def linear(x, name, num_hidden):
-        return sym.FullyConnected(x, num_hidden=num_hidden, no_bias=True,
-                                  name=name)
-
     def norm(x, name):
         return csym.RMSNorm(x, eps=rms_eps, name=name)
 
@@ -76,15 +73,7 @@ def get_symbol(vocab_size=50304, hidden_size=2048, num_layers=16,
             norm_topk_prob=norm_topk_prob, name=p + "moe")
         h = h + moe[0]
         counts.append(sym.BlockGrad(moe[1], name=p + "expert_count"))
-    logits = linear(norm(h, "final_norm"), "lm_head", vocab_size)
-    logits = sym.Cast(logits, dtype="float32", name="lm_head_f32")
-    nll = 0 - sym.pick(sym.log_softmax(logits, name="lm_head_logp"),
-                       sym.Reshape(label, shape=(-1,)), axis=1,
-                       name="lm_head_pick")
-    per_sequence = sym.mean(sym.Reshape(nll, shape=(-1, seq_len)), axis=1,
-                            name="lm_head_mean")
-    loss = sym.MakeLoss(per_sequence, name="loss")
-    return sym.Group([loss] + counts)
+    return head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps)
 
 
 def from_config(config, seq_len=None, dtype="float32"):

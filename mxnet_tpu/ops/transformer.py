@@ -1,5 +1,5 @@
 """Transformer-block operators for the Symbol API: RMSNorm, RoPE,
-Attention and TopKMoE.
+Attention, LatentAttention and TopKMoE.
 
 Beyond-reference capability (the 2017 operator set has no attention and
 no sparse-expert layer): what a decoder-only LM with sparse experts
@@ -7,7 +7,9 @@ no sparse-expert layer): what a decoder-only LM with sparse experts
 ``Module.fit`` trains through the fused step. Each op is a thin
 ``OpDef`` over one function kept elsewhere: ``Attention`` over the one
 attention dispatch ``ops/pallas_kernels.attention`` (flash kernel on the
-TPU at T >= 128, the materialised reference elsewhere), ``TopKMoE`` over
+TPU at T >= 128, the materialised reference elsewhere;
+``LatentAttention`` projects its keys and values up from a latent first
+and calls the same dispatch), ``TopKMoE`` over
 ``parallel/moe.topk_moe``. Exported as ``mx.contrib.sym`` /
 ``mx.contrib.nd`` functions through ``contrib.ops.CONTRIB_OP_EXPORTS``.
 
@@ -21,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry as _tm
 from ..base import MXNetError
 from .registry import OpDef, register
 
@@ -67,33 +70,51 @@ register(
 
 
 # --------------------------------------------------------------------------
-# RoPE — rotary position embedding, half-rotation convention
+# RoPE — rotary position embedding, half-rotation or interleaved pairs
 # --------------------------------------------------------------------------
-def rope(x, num_heads, theta, rotary_dim=0):
-    """Rotate ``x`` [B, T, H*D] by its positions 0..T-1. The pairs are
-    (i, i + R/2) within the first R = ``rotary_dim`` dimensions of a
-    head (0: the whole head) — the ``rotate_half`` convention of the
-    published code, not the interleaved (2i, 2i+1) one; the dimensions
-    past R pass through. Angles, sines and the rotation itself are
-    float32; the result is ``x``'s dtype."""
+def rope(x, num_heads, theta, rotary_dim=0, offset=0, interleave=False):
+    """Rotate ``x`` [B, T, H*D] by its positions 0..T-1, in place: the
+    R = ``rotary_dim`` dimensions of a head from ``offset`` on (0: the
+    whole head); the dimensions before and past them pass through.
+    The pairs are (i, i + R/2) — the ``rotate_half`` convention — or,
+    with ``interleave``, (2i, 2i + 1); pair i turns by ``pos *
+    theta^(-2i/R)`` either way. Angles, sines and the rotation itself
+    are float32; the result is ``x``'s dtype."""
     b, t, hd = x.shape
     d = hd // num_heads
-    r = rotary_dim or d
+    r = rotary_dim or d - offset
     inv_freq = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
     angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    if interleave:  # a pair's two lanes share its angle
+        angles = np.repeat(angles, 2, axis=-1)
     cos = jnp.asarray(np.cos(angles), jnp.float32)[None, :, None, :]
     sin = jnp.asarray(np.sin(angles), jnp.float32)[None, :, None, :]
     x4 = x.astype(jnp.float32).reshape(b, t, num_heads, d)
-    x1, x2 = x4[..., : r // 2], x4[..., r // 2: r]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x4[..., r:]], axis=-1)
+    rot = x4[..., offset: offset + r]
+    if interleave:
+        # the pair's other lane by two lane rotations and a select on
+        # the lane's parity (no [.., R/2, 2] reshape: a minor dimension
+        # of 2 is a padded layout on the chip): -x[2i+1] at 2i, x[2i]
+        # at 2i+1
+        even = (np.arange(r) % 2 == 0)[None, None, None, :]
+        other = jnp.where(even, -jnp.roll(rot, -1, axis=-1),
+                          jnp.roll(rot, 1, axis=-1))
+        rotated = [rot * cos + other * sin]
+    else:
+        x1, x2 = rot[..., : r // 2], rot[..., r // 2:]
+        rotated = [x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+    before = [x4[..., :offset]] if offset else []
+    out = jnp.concatenate(before + rotated + [x4[..., offset + r:]],
+                          axis=-1)
     return out.reshape(b, t, hd).astype(x.dtype)
 
 
 def _rope(attrs, ins, is_train):
     return [rope(ins[0], int(attrs["num_heads"]),
                  float(attrs.get("theta", 10000.0)),
-                 int(attrs.get("rotary_dim", 0)))]
+                 int(attrs.get("rotary_dim", 0)),
+                 int(attrs.get("rotary_offset", 0)),
+                 bool(attrs.get("interleave", False)))]
 
 
 def _split_heads(what, name, shape, heads):
@@ -107,13 +128,19 @@ def _split_heads(what, name, shape, heads):
     return shape[2] // heads
 
 
+def _check_rotation(what, d, r, offset):
+    if r % 2 or r <= 0 or offset < 0 or offset + r > d:
+        raise ValueError(
+            "%s: the rotated dimensions must be an even count inside the "
+            "head_dim %d, got %d from %d on" % (what, d, r, offset))
+
+
 def _rope_infer(attrs, in_shapes):
     data = _known(in_shapes[0], "RoPE")
     d = _split_heads("RoPE", "data", data, int(attrs["num_heads"]))
-    r = int(attrs.get("rotary_dim", 0)) or d
-    if r % 2 or r > d:
-        raise ValueError("RoPE: rotary_dim must be even and at most the "
-                         "head_dim %d, got %d" % (d, r))
+    offset = int(attrs.get("rotary_offset", 0))
+    _check_rotation("RoPE", d, int(attrs.get("rotary_dim", 0)) or d - offset,
+                    offset)
     return [data], [data], []
 
 
@@ -122,7 +149,8 @@ register(
         "_contrib_RoPE",
         _rope,
         arguments=("data",),
-        defaults={"num_heads": 1, "theta": 10000.0, "rotary_dim": 0},
+        defaults={"num_heads": 1, "theta": 10000.0, "rotary_dim": 0,
+                  "rotary_offset": 0, "interleave": False},
         infer_shape=_rope_infer,
         aliases=("RoPE",),
     )
@@ -197,6 +225,97 @@ register(_attn)
 
 
 # --------------------------------------------------------------------------
+# LatentAttention — causal attention whose keys and values are projected
+# up from one normalised latent a token (MLA, DeepSeek-V2/V3)
+# --------------------------------------------------------------------------
+_M_LATENT_LOWERINGS = _tm.counter(
+    "attention.latent_lowerings", "Traces of a LatentAttention call site "
+    "(one per lowering, nothing per step); labels: heads, latent (the "
+    "width keys and values are projected up from), rope (the rotary key "
+    "every head shares), nope (a head's own key width), dv")
+
+
+def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
+                     v_head_dim, theta, eps, interleave=True):
+    """query [B, T, H * (N + R)] (a head's N un-rotated dimensions, then
+    its R rotary ones), latent [B, T, L + R] (the compressed key/value
+    latent, then the one rotary key a token), gamma [L], up_weight
+    [H * (N + Dv), L] (a head's N key rows, then its Dv value rows) ->
+    [B, T, H * Dv].
+
+    ``c = RMSNorm(latent[:L])``; ``(k_nope_h, v_h) = up_weight c``;
+    RoPE on each head's ``q_rope`` and on the shared ``k_rope``; head
+    h's key is ``[k_nope_h, k_rope]``; causal softmax attention scaled
+    by ``1 / sqrt(N + R)`` through the one attention dispatch. Norm
+    statistics, rotation and softmax are float32; the up-projection
+    takes operands of ``latent``'s dtype and accumulates in float32."""
+    from .pallas_kernels import attention
+
+    b, t, _ = query.shape
+    width = latent.shape[2] - rope_dim
+    nope = query.shape[2] // num_heads - rope_dim
+    _M_LATENT_LOWERINGS.inc(heads=num_heads, latent=width, rope=rope_dim,
+                            nope=nope, dv=v_head_dim)
+    with jax.named_scope("latent"):
+        c = rms_norm(latent[..., :width], gamma, eps)
+        kv = jax.lax.dot_general(
+            c, up_weight.astype(c.dtype), (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(c.dtype)
+        kv = kv.reshape(b, t, num_heads, nope + v_head_dim)
+        q = rope(query, num_heads, theta, rope_dim, nope, interleave)
+        k_rope = rope(latent[..., width:], 1, theta, rope_dim, 0, interleave)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                k_rope[:, :, None, :], (b, t, num_heads, rope_dim))],
+            axis=-1)
+    with jax.named_scope("full"):
+        out = attention(q.reshape(b, t, num_heads, nope + rope_dim), k,
+                        kv[..., nope:], causal=True)
+    return out.reshape(b, t, num_heads * v_head_dim)
+
+
+def _latent_attention(attrs, ins, is_train):
+    return [latent_attention(
+        *ins, num_heads=int(attrs["num_heads"]),
+        rope_dim=int(attrs["rope_dim"]),
+        v_head_dim=int(attrs["v_head_dim"]),
+        theta=float(attrs.get("theta", 10000.0)),
+        eps=float(attrs.get("eps", 1e-6)),
+        interleave=bool(attrs.get("interleave", True)))]
+
+
+def _latent_attention_infer(attrs, in_shapes):
+    heads, r = int(attrs["num_heads"]), int(attrs["rope_dim"])
+    dv = int(attrs["v_head_dim"])
+    q = _known(in_shapes[0], "LatentAttention")
+    latent = _known(in_shapes[1], "LatentAttention")
+    d = _split_heads("LatentAttention", "query", q, heads)
+    _check_rotation("LatentAttention", d, r, d - r)
+    if dv <= 0:
+        raise ValueError("LatentAttention: v_head_dim must be set (> 0)")
+    if len(latent) != 3 or latent[:2] != q[:2] or latent[2] <= r:
+        raise ValueError(
+            "LatentAttention: latent %s must be query's [batch, time] %s "
+            "by the latent width + rope_dim=%d" % (latent, q[:2], r))
+    width = latent[2] - r
+    return ([q, latent, (width,), (heads * (d - r + dv), width)],
+            [q[:2] + (heads * dv,)], [])
+
+
+register(
+    OpDef(
+        "_contrib_LatentAttention",
+        _latent_attention,
+        arguments=("query", "latent", "latent_gamma", "up_weight"),
+        defaults={"num_heads": 1, "rope_dim": 0, "v_head_dim": 0,
+                  "theta": 10000.0, "eps": 1e-6, "interleave": True},
+        infer_shape=_latent_attention_infer,
+        aliases=("LatentAttention",),
+    )
+)
+
+
+# --------------------------------------------------------------------------
 # TopKMoE — dropless top-k sparse-expert SwiGLU FFN
 # --------------------------------------------------------------------------
 def _topk_moe(attrs, ins, is_train):
@@ -215,6 +334,7 @@ def _topk_moe(attrs, ins, is_train):
         params, data, top_k=int(attrs["top_k"]),
         norm_topk_prob=bool(attrs.get("norm_topk_prob", False)),
         scoring=str(attrs.get("scoring", "softmax")),
+        routed_scale=float(attrs.get("routed_scale", 1.0)),
         expert_offset=int(attrs.get("expert_offset", 0)),
         share_rows_bound=int(attrs.get("share_rows_bound", 0)))
     return [y, counts.astype(jnp.float32)]
@@ -273,7 +393,8 @@ _moe = OpDef(
     outputs=("output", "expert_count"),
     defaults={"num_experts": 8, "num_hidden": 0, "top_k": 2,
               "norm_topk_prob": False, "scoring": "softmax",
-              "with_select_bias": False, "experts_held": 0,
+              "routed_scale": 1.0, "with_select_bias": False,
+              "experts_held": 0,
               "expert_offset": 0,
               "share_rows_bound": 0},
     infer_shape=_topk_moe_infer,

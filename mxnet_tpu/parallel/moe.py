@@ -210,14 +210,16 @@ _M_SHARE_LOWERINGS = _tm.counter(
     "moe.share_lowerings", "Traces of a topk_moe call site that holds a "
     "share of its experts (one per lowering, nothing per step); labels: "
     "held (experts computed here), of (experts routed over), bound (rows "
-    "of the share's buffer)")
+    "of the share's buffer) and, where it is not 1, scale (the factor on "
+    "the routing weights)")
 
 
-def _route(params, x, top_k, norm_topk_prob, scoring):
+def _route(params, x, top_k, norm_topk_prob, scoring, routed_scale=1.0):
     """Float32 routing: (weights [T, k], experts [T, k]). ``softmax``:
     top-k of the softmax. ``sigmoid``: scores ``sigmoid(logits)``, the
     choice by score plus ``select_bias`` (which carries no gradient and
-    never reaches the weights), the weights the chosen scores."""
+    never reaches the weights), the weights the chosen scores. The
+    weights, renormalised or not, times ``routed_scale``."""
     logits = jnp.dot(
         x.astype(jnp.float32), params["gate_w"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)
@@ -242,11 +244,13 @@ def _route(params, x, top_k, norm_topk_prob, scoring):
                          "got %r" % (scoring,))
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if routed_scale != 1.0:
+        weights = weights * routed_scale
     return weights, experts
 
 
 def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
-             expert_offset=0, share_rows_bound=0):
+             expert_offset=0, share_rows_bound=0, routed_scale=1.0):
     """Dropless top-k MoE FFN with SwiGLU experts (the OLMoE / Mixtral
     block). x: [tokens, d_model] -> ([tokens, d_model], counts [E]).
 
@@ -272,8 +276,9 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     float32 whatever the activations' dtype (``_route``: ``scoring``
     ``softmax`` or ``sigmoid``, the latter with the optional
     ``select_bias`` [E]); routing weights are not renormalised unless
-    ``norm_topk_prob``. ``counts`` is the number of rows each of the E
-    experts received (int32, no gradient).
+    ``norm_topk_prob``, and are multiplied by ``routed_scale`` after
+    that (DeepSeek-V3's ``routed_scaling_factor``). ``counts`` is the
+    number of rows each of the E experts received (int32, no gradient).
 
     **A share of the experts.** Where ``w_gate_up`` holds H < E experts,
     they are the router's experts ``expert_offset`` .. ``expert_offset
@@ -292,9 +297,12 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
 
     with jax.named_scope("router"):
         weights, experts = _route(params, x, top_k, norm_topk_prob,
-                                  scoring)
+                                  scoring, routed_scale)
 
     if held < num_experts:
+        scale = {} if routed_scale == 1.0 else {"scale": routed_scale}
+        _M_SHARE_LOWERINGS.inc(held=held, of=num_experts,
+                               bound=share_rows_bound, **scale)
         return _topk_moe_share(params, x, weights, experts, expert_offset,
                                share_rows_bound)
 
@@ -339,7 +347,6 @@ def _topk_moe_share(params, x, weights, experts, offset, bound):
         raise ValueError(
             "topk_moe: experts %d..%d are not among the router's %d"
             % (offset, offset + held - 1, num_experts))
-    _M_SHARE_LOWERINGS.inc(held=held, of=num_experts, bound=bound)
 
     with jax.named_scope("dispatch"):
         flat_expert = experts.reshape(-1)                     # [T*k]

@@ -108,16 +108,23 @@ def _pad_to(x, axis, mult):
 #             inner dimension walks the group's q heads one after
 #             another and sums their dK/dV in the one accumulator. The
 #             value width may differ from the query/key width.
+#   backward  one pass (``_bwd_fused_kernel``: P and dS built once a tile
+#             pair, five products) where dK and dV of a whole key/value
+#             head fit VMEM beside a step's tiles (``_bwd_fuses``: shapes
+#             and operand type alone decide); dq and dkv (seven products,
+#             P and dS twice) for the sequences too long for that.
 #   sink      a per-head logit that joins the softmax's denominator and
 #             no value: applied to the forward kernel's (out, lse) by
 #             ``_apply_sink`` outside it; the backward kernels rebuild P
 #             from the lse that holds it and need nothing else.
 #
-# forward / dq: grid (B*H, nq, nk), k innermost; dkv: grid (B*G, nk,
-# group * nq).
+# forward / dq / the one-pass backward: grid (B*H, nq, nk), k innermost;
+# dkv: grid (B*G, nk, group * nq).
 # The output block index map ignores the innermost dimension, so Mosaic
 # keeps the output resident in VMEM while the inner loop accumulates into
-# scratch; one (block_q, block_k) tile pair is on-chip at a time.
+# scratch; one (block_q, block_k) tile pair is on-chip at a time. The
+# one-pass backward's dK / dV blocks ignore the q tile too (and the q
+# head within its group): they stay for the whole key/value head.
 # dS = P * (dP - delta), P = exp(S - L), dP = dO V^T,
 # delta_i = sum_d dO_id * O_id.
 # ---------------------------------------------------------------------------
@@ -127,13 +134,22 @@ _M_FLASH_LOWERINGS = _tm.counter(
     "(one per lowering, nothing per step); labels: operands (the type "
     "the MXU is fed), block_q, block_k and, where the call has them, "
     "window, kv_heads (fewer than the query heads), dv (a value width "
-    "other than the query's)")
+    "other than the query's). Where a site's backward is traced it "
+    "counts once more under operands, block_q, block_k, window and bwd: "
+    "fused (dq, dk and dv in one pass) or split (dq and dkv)")
 
-# What one grid step may hold in VMEM as ``_flash_vmem_bytes`` counts it:
-# Mosaic's default scoped limit on the v5e. The calls ask for no more
-# (no ``vmem_limit_bytes``), so XLA keeps the rest of VMEM for its own
-# placement exactly as it did round the 128 x 128 kernel.
+# What one grid step of forward, dq and dkv may hold in VMEM as
+# ``_flash_vmem_bytes`` counts it: Mosaic's default scoped limit on the
+# v5e. Those calls ask for no more (no ``vmem_limit_bytes``), and the
+# tiles are chosen under it. The one-pass backward keeps dK and dV of a
+# whole key/value head beside such a step's tiles, so it states its count
+# as its own limit (44 MiB at T 8,192 and widths 192 / 128 in bf16), and
+# is taken only where that count stays under ``_FLASH_BWD_VMEM_LIMIT``.
 _FLASH_VMEM_BUDGET = 16 * 1024 * 1024
+# Three eighths of the v5e's 128 MiB of VMEM (a limit is scoped to its
+# call: what XLA places there round the call is not displaced). T 16,384
+# at 192 / 128 counts 68 MiB and T 32,768 116: those keep dq and dkv.
+_FLASH_BWD_VMEM_LIMIT = 48 * 1024 * 1024
 # Largest tiles worth taking, by measurement on the v5e (T 2048-8192,
 # D 64-256, bf16 and float32, causal and not: 1024 x 1024 is the fastest
 # or within 1% of it everywhere, 2048 is slower again; PERF.md section 7).
@@ -142,19 +158,52 @@ _FLASH_MAX_BLOCK_K = 1024
 _FLASH_MIN_BLOCK = 128
 
 
-def _flash_vmem_bytes(block_q, block_k, d, itemsize):
-    """Upper bound on the VMEM one grid step of the widest kernel (dkv)
-    holds: double-buffered operand and result tiles, the float32
-    accumulators and two float32 score-shaped temporaries. Against the
-    smallest limit Mosaic compiles each shape under (v5e, 2 MiB steps):
-    12 MiB counted / 10 needed at 1024 x 1024, D = 128, bf16; 15 / 12 in
-    float32; 22 / 16 at D = 256 float32; 40 / 40 at 2048 x 2048."""
-    lanes = -(-d // 128) * 128
+def _lanes(width):
+    return -(-width // 128) * 128
+
+
+def _flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None):
+    """Upper bound on the VMEM one grid step of the widest kernel holds:
+    double-buffered operand and result tiles, the float32 accumulators
+    and two float32 score-shaped temporaries, as dkv has them; with
+    ``resident`` = (t_pad, d, dv), plus what the one-pass backward keeps
+    for a whole key/value head: dK and dV in float32 scratch and their
+    double-buffered output blocks, and a third score-shaped temporary.
+    Against the smallest limit Mosaic compiles each shape under (v5e,
+    1-2 MiB steps), counted / needed:
+
+    ====================================================  =======  ======
+    dkv, 1024 x 1024, D 128, bf16                          12 MiB   10
+    dkv, the same in float32                                15       12
+    dkv, D 256, float32                                     22       16
+    dkv, 2048 x 2048                                        40       40
+    one pass, T 8192, 192 / 128, bf16 (Kanana)              44       39
+    one pass, T 4096, 128 / 128, bf16 (OLMoE)               24       18
+    one pass, T 4096, 192 / 128, bf16, 8 heads on 1 (MiMo)  32       18
+    one pass, the same, 256 x 256 under a window of 128     14.75    9
+    one pass, T 4096, 128 / 128, float32                    31       27
+    ====================================================  =======  ======
+    """
+    lanes = _lanes(d)
     row_tiles = 2 * 2 * block_q * lanes * itemsize      # q, dO
     col_tiles = 2 * 4 * block_k * lanes * itemsize      # k, v, dk, dv
     acc = 2 * block_k * lanes * 4
     scores = 2 * block_q * block_k * 4
-    return row_tiles + col_tiles + acc + scores
+    step = row_tiles + col_tiles + acc + scores
+    if resident is None:
+        return step
+    t_pad, d, dv = resident
+    return (step + block_q * block_k * 4    # P and dS both outlive dP
+            + t_pad * (_lanes(d) + _lanes(dv)) * (4 + 2 * itemsize))
+
+
+def _bwd_fuses(t_pad, block_q, block_k, d, dv, dtype):
+    """Whether the backward of a call runs as one pass: decided by the
+    call's shapes and operand type alone, through what the pass would
+    hold in VMEM."""
+    return _flash_vmem_bytes(
+        block_q, block_k, max(d, dv), jnp.dtype(dtype).itemsize,
+        resident=(t_pad, d, dv)) <= _FLASH_BWD_VMEM_LIMIT
 
 
 def _one_tile(t):
@@ -469,6 +518,88 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
+                      dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, block_q,
+                      block_k, t_real, t_pad, scale, causal, window, group):
+    """dq, dk and dv in one pass: P and dS are built once a tile pair and
+    feed all three products. The grid is dq's, (q head, q tile, k step):
+    dq accumulates in tile-sized scratch over the inner steps; dk and dv
+    accumulate in float32 scratch that holds the key/value head whole
+    ([k tile, row, column]) over every q tile of every q head of its
+    group, and are written out at the group's last step. The inner steps
+    walk a row's k tiles from the diagonal down to the first, so a row's
+    dead steps come first and its last step is a live one: the next
+    row's q, dO, lse and delta arrive under a step that computes."""
+    b = pl.program_id(0)
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    ki = _inner_k(qi, jax.lax.sub(pl.num_programs(2) - 1, j),
+                  block_q=block_q, block_k=block_k, window=window)
+    first = jax.lax.bitwise_and(jax.lax.eq(qi, np.int32(0)),
+                                jax.lax.eq(j, np.int32(0)))
+    last = jax.lax.bitwise_and(
+        jax.lax.eq(qi, pl.num_programs(1) - 1),
+        jax.lax.eq(j, pl.num_programs(2) - 1))
+    if group > 1:
+        head = jax.lax.rem(b, np.int32(group))
+        first = jax.lax.bitwise_and(first, jax.lax.eq(head, np.int32(0)))
+        last = jax.lax.bitwise_and(
+            last, jax.lax.eq(head, np.int32(group - 1)))
+
+    def each_k_tile(fn):
+        def step(i, carry):
+            fn(i)
+            return carry
+        jax.lax.fori_loop(0, dk_acc.shape[0], step, 0)
+
+    @pl.when(first)
+    def _():
+        def zero(i):
+            dk_acc[i] = jnp.zeros(dk_acc.shape[1:], jnp.float32)
+            dv_acc[i] = jnp.zeros(dv_acc.shape[1:], jnp.float32)
+        each_k_tile(zero)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def body(masked):
+        q = q_ref[0]
+        k_blk = k_ref[0]
+        do = do_ref[0]
+        p, ds = _bwd_p_ds(
+            q, k_blk, v_ref[0], do, l_ref[0], d_ref[0], qi, ki, masked,
+            block_q=block_q, block_k=block_k, t_real=t_real, scale=scale,
+            causal=causal, window=window)
+        dv_acc[ki] = dv_acc[ki] + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [bk, Dv]
+        dk_acc[ki] = dk_acc[ki] + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+            ds, k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    _tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+                t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = (jnp.float32(scale) * dq_acc[...]).astype(dq_ref.dtype)
+
+    @pl.when(last)
+    def _():
+        def write(i):
+            dk_ref[0, i] = (jnp.float32(scale) * dk_acc[i]).astype(
+                dk_ref.dtype)
+            dv_ref[0, i] = dv_acc[i].astype(dv_ref.dtype)
+        each_k_tile(write)
+
+
 # ---------------------------------------------------------------------------
 # host-side wrappers
 # ---------------------------------------------------------------------------
@@ -490,21 +621,24 @@ _FLASH_PARAMS = pltpu.CompilerParams(
 
 
 def _tile_specs(block_q, block_k, d, dv, causal, inner, *, window=0,
-                group=1, nq=0, steps=0):
+                group=1, nq=0, steps=0, reverse=False):
     """Block specs of a q-shaped tile, a k-shaped tile, their
     value-width twins and a per-row statistic for a grid whose innermost
-    dimension walks ``inner`` ("k": forward and dq, grid (bh, nq, nk);
-    "q": dkv, grid (bg, nk, group * nq)). Under ``causal`` the streamed
-    operand's index clamps to the row's (column's) live range: a dead
-    step names the tile already resident and fetches nothing. The
-    leading index is a q head for q-shaped tiles and the key/value head
-    it reads for k-shaped ones."""
+    dimension walks ``inner`` ("k": forward, dq and the one-pass
+    backward, grid (bh, nq, nk), the last with ``reverse``: its ``steps``
+    inner steps walk downwards; "q": dkv, grid (bg, nk, group * nq)).
+    Under ``causal`` the streamed operand's index clamps to the row's
+    (column's) live range: a dead step names the tile already resident
+    and fetches nothing. The leading index is a q head for q-shaped
+    tiles and the key/value head it reads for k-shaped ones."""
     tile = dict(block_q=block_q, block_k=block_k, window=window)
     if inner == "k":
         def q_idx(b, i, j):
             return (b, i, 0)
 
         def k_idx(b, i, j):
+            if reverse:
+                j = jax.lax.sub(np.int32(steps - 1), j)
             j = _inner_k(i, j, **tile)
             if causal:
                 j = jax.lax.min(j, _last_live_k(i, block_q, block_k))
@@ -569,8 +703,59 @@ def _fwd_call(q3, k3, v3, *, t_real, scale, causal, window, block_q,
     return out, lse
 
 
+def _bwd_fused_call(q3, k3, v3, do3, lse, delta, *, interpret, **tile):
+    bh, t_pad, d = q3.shape
+    bg, dv = k3.shape[0], v3.shape[2]
+    group = bh // bg
+    block_q, block_k = tile["block_q"], tile["block_k"]
+    causal, window = tile["causal"], tile["window"]
+    nq = t_pad // block_q
+    nk = t_pad // block_k
+    steps = (_band_steps(nq, nk, block_q, block_k, window, "k")
+             if window else nk)
+    q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+        block_q, block_k, d, dv, causal, "k", window=window, group=group,
+        steps=steps, reverse=True)
+
+    def whole_head(width):
+        def idx(b, i, j):
+            if group > 1:
+                b = jax.lax.div(b, np.int32(group))
+            return (b, 0, 0, 0)
+        return pl.BlockSpec((1, nk, block_k, width), idx)
+
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, group=group, **tile),
+        grid=(bh, nq, steps),
+        in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
+        out_specs=[q_spec, whole_head(d), whole_head(dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
+            jax.ShapeDtypeStruct((bg, nk, block_k, d), q3.dtype),
+            jax.ShapeDtypeStruct((bg, nk, block_k, dv), q3.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((nk, block_k, d), jnp.float32),
+            pltpu.VMEM((nk, block_k, dv), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            # dk / dv accumulate over the q tiles, and over the q heads
+            # of a group
+            dimension_semantics=(
+                "parallel" if group == 1 else "arbitrary", "arbitrary",
+                "arbitrary"),
+            vmem_limit_bytes=_flash_vmem_bytes(
+                block_q, block_k, max(d, dv), q3.dtype.itemsize,
+                resident=(t_pad, d, dv))),
+        name=_kernel_name("bwd", q3.dtype, block_q, block_k, window),
+        interpret=interpret,
+    )(q3, k3, v3, do3, lse, delta)
+    return dq, dk.reshape(bg, t_pad, d), dv_.reshape(bg, t_pad, dv)
+
+
 def _bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
-              window, block_q, block_k, interpret):
+              window, block_q, block_k, interpret, fused=False):
     bh, t_pad, d = q3.shape
     bg, dv = k3.shape[0], v3.shape[2]
     group = bh // bg
@@ -579,6 +764,9 @@ def _bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
     tile = dict(block_q=block_q, block_k=block_k, t_real=t_real,
                 t_pad=t_pad, scale=scale, causal=causal, window=window)
     with _no_x64():
+        if fused:
+            return _bwd_fused_call(q3, k3, v3, do3, lse, delta,
+                                   interpret=interpret, **tile)
         q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
             block_q, block_k, d, dv, causal, "k", window=window,
             group=group)
@@ -652,10 +840,15 @@ def _flash_bwd(t_real, scale, causal, window, block_q, block_k, res, g):
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
         keepdims=True,
     )  # [BH, T, 1]
+    fused = _bwd_fuses(q3.shape[1], block_q, block_k, q3.shape[2],
+                       v3.shape[2], q3.dtype)
+    _M_FLASH_LOWERINGS.inc(
+        operands=_operand_label(q3.dtype), block_q=block_q,
+        block_k=block_k, window=window, bwd="fused" if fused else "split")
     dq, dk, dv = _by_platform(
         functools.partial(
             _bwd_call, t_real=t_real, scale=scale, causal=causal,
-            window=window, block_q=block_q, block_k=block_k),
+            window=window, block_q=block_q, block_k=block_k, fused=fused),
         q3, k3, v3, g.astype(q3.dtype), lse, delta)
     dsink = None
     if sink is not None:

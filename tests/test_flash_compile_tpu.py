@@ -1,16 +1,19 @@
 """The flash and grouped-matmul kernels compile for the chip at real
 widths, without the chip: the TPU's compiler is installed here and compiles for a described
 v5e (interpret mode cannot see a tile Mosaic refuses, or a working set
-over the scoped-VMEM limit the calls leave at its default). The
+over the scoped-VMEM limit: the default the forward, dq and dkv leave it
+at, the stated one of the one-pass backward). The
 topology is described inside a fixture, never at import: only the worker
 that is handed this file loads the TPU's library. Keep every such test
 in THIS file."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from mxnet_tpu.ops import pallas_kernels as pk
 from mxnet_tpu.ops.pallas_kernels import (
     flash_attention, flash_tiles, gmm_tiles, grouped_matmul)
 
@@ -39,12 +42,15 @@ def one_chip():
 
 # the OLMoE cell's call; the float32 caller at the widest head the
 # budget admits 1024-wide k tiles for; padding inside a large tile,
-# non-causal; a head narrower than a lane row
+# non-causal; a head narrower than a lane row; a sequence whose dK / dV
+# no longer stay in VMEM for a whole head (67 MiB counted): its backward
+# is dq and dkv
 SHAPES = [
     (4096, 16, 128, jnp.bfloat16, True),
     (4096, 4, 256, jnp.float32, True),
     (1000, 4, 64, jnp.bfloat16, False),
     (2176, 2, 32, jnp.float32, True),
+    (16384, 2, 128, jnp.float32, True),
 ]
 
 
@@ -61,9 +67,14 @@ def test_flash_chosen_tiles_compile_for_v5e(one_chip, t, h, d, dtype,
         x, x, x).compile().as_text()
     bq, bk = flash_tiles(t, d, dtype)
     operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
-    for which in ("fwd", "dq", "dkv"):
+    t_pad = -(-t // max(bq, bk)) * max(bq, bk)
+    fuses = pk._bwd_fuses(t_pad, bq, bk, d, d, dtype)
+    assert fuses is (t < 16384)
+    for which, there in (("fwd", True), ("bwd", fuses), ("dq", not fuses),
+                         ("dkv", not fuses)):
         # the kernel's name is the device op's name: what a trace shows
-        assert "flash_%s_%s_q%d_k%d" % (which, operands, bq, bk) in text
+        assert ("flash_%s_%s_q%d_k%d" % (which, operands, bq, bk)
+                in text) is there
 
 
 # the MiMo-V2-Flash share cell's two calls: 8 query heads of 192 on one
@@ -87,9 +98,54 @@ def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, window,
         shape(h, dtype=jnp.float32)).compile().as_text()
     bq, bk = flash_tiles(t, d, jnp.bfloat16, window)
     assert (bq, bk) == ((256, 256) if window else (1024, 1024))
-    for which in ("fwd", "dq", "dkv"):
+    for which in ("fwd", "bwd"):
         assert "flash_%s_bf16_q%d_k%d%s" % (
             which, bq, bk, "_w128" if window else "") in text
+    assert "flash_dq_" not in text and "flash_dkv_" not in text
+
+
+# the three LM cells' attention calls (T, query heads, key/value heads,
+# D, Dv, window): Kanana's latent attention, OLMoE's, MiMo's full and
+# window layers
+CELL_CALLS = {
+    "kanana2_8k": (8192, 32, 32, 192, 128, 0),
+    "olmoe_4k": (4096, 16, 16, 128, 128, 0),
+    "mimo_full_4k": (4096, 8, 1, 192, 128, 0),
+    "mimo_window_4k": (4096, 8, 1, 192, 128, 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_CALLS))
+def test_one_pass_backward_compiles_under_its_stated_limit(one_chip, cell):
+    """The backward of each cell's call is the one kernel, and Mosaic
+    compiles it under exactly the scoped VMEM ``_flash_vmem_bytes``
+    counts for it (a working set over the limit fails the compile)."""
+    t, h, g, d, dv, window = CELL_CALLS[cell]
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(1, t, h, d), shape(1, t, g, d),
+        shape(1, t, g, dv)).compile().as_text()
+    bq, bk = flash_tiles(t, d, jnp.bfloat16, window)
+    assert pk._bwd_fuses(t, bq, bk, d, dv, jnp.bfloat16)
+    name = "flash_bwd_bf16_q%d_k%d%s" % (
+        bq, bk, "_w%d" % window if window else "")
+    calls = [line for line in text.splitlines()
+             if name in line and "custom-call(" in line]
+    assert len(calls) == 1
+    assert "flash_dq_" not in text and "flash_dkv_" not in text
+    limit, used = (
+        int(re.search(r'"%s":\[\{"memory_space":"1","offset":"0",'
+                      r'"size":"(\d+)"' % key, calls[0]).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert limit == pk._flash_vmem_bytes(bq, bk, d, 2, resident=(t, d, dv))
+    assert used <= limit <= pk._FLASH_BWD_VMEM_LIMIT
 
 
 # the OLMoE cell's two expert products (gate/up, down); a float32 caller

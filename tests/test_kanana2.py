@@ -221,6 +221,39 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
     assert "layer0_shared_gate_proj_weight" not in got  # the dense layer
 
 
+def test_every_layer_takes_the_one_pass_backward(monkeypatch):
+    """Forced onto the flash kernel (interpret mode here; on the chip
+    ``attention`` takes it by itself), every layer's attention call site
+    traces the one-pass backward once, and the gradients are the
+    reference's through it."""
+    monkeypatch.setenv("MXNET_TPU_FORCE_FLASH", "1")
+    sym = kanana2.from_config(SHARE, seq_len=T)
+    params = _params(sym, 7)
+    tokens, labels = _batch(8)
+    _, grads = ref.loss_and_grads(params, tokens, labels, SHARE)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        mod = _module(sym, params)
+        batch = mx.io.DataBatch(data=[mx.nd.array(tokens)],
+                                label=[mx.nd.array(labels)])
+        for _ in range(2):  # a second step traces nothing
+            mod.forward(batch, is_train=True)
+            mod.backward()
+        flash = telemetry.REGISTRY.get("attention.flash_lowerings")
+        tile = dict(operands="f32", block_q=T, block_k=T)
+        assert flash.value(window=0, bwd="fused", **tile) == 3
+        assert flash.value(window=0, bwd="split", **tile) == 0
+        assert flash.value(window=0, kv_heads=HEADS, dv=DV, **tile) == 3
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    got = mod._exec_group.execs[0].grad_dict
+    for name, want_g in grads.items():
+        _close(got[name].asnumpy() / BATCH, want_g, name, rtol=1e-4,
+               ulps=64)
+
+
 def test_from_config_refuses_what_it_does_not_implement():
     for key, value in [("q_lora_rank", 1536), ("n_group", 2),
                        ("rope_scaling", {"type": "yarn", "factor": 4}),

@@ -219,22 +219,19 @@ def _kernel_dots(dtype):
 
 def test_flash_kernels_feed_mxu_operands_as_given():
     bf16 = _kernel_dots(jnp.bfloat16)
-    # the Mosaic and the interpreter branch carry the same three kernels
-    assert sorted(bf16) == ["flash_dkv_bf16_q256_k256",
-                            "flash_dq_bf16_q256_k256",
+    # the Mosaic and the interpreter branch carry the same two kernels
+    assert sorted(bf16) == ["flash_bwd_bf16_q256_k256",
                             "flash_fwd_bf16_q256_k256"]
-    # 2 / 3 / 4 products a tile pair, in the masked and the unmasked
-    # body, in both branches
+    # 2 products a tile pair forward and 5 backward (P and dS are built
+    # once), in the masked and the unmasked body, in both branches
     assert {k: len(v) for k, v in bf16.items()} == {
-        "flash_fwd_bf16_q256_k256": 8, "flash_dq_bf16_q256_k256": 12,
-        "flash_dkv_bf16_q256_k256": 16}
+        "flash_fwd_bf16_q256_k256": 8, "flash_bwd_bf16_q256_k256": 20}
     for dots in bf16.values():
         for operands, result in dots:
             assert operands == (jnp.bfloat16, jnp.bfloat16)
             assert result == jnp.float32
     f32 = _kernel_dots(jnp.float32)
-    assert sorted(f32) == ["flash_dkv_f32_q256_k256",
-                           "flash_dq_f32_q256_k256",
+    assert sorted(f32) == ["flash_bwd_f32_q256_k256",
                            "flash_fwd_f32_q256_k256"]
     for dots in f32.values():
         for operands, result in dots:
@@ -285,7 +282,149 @@ def test_flash_lowerings_counter_counts_one_per_lowering():
         c = telemetry.REGISTRY.get("attention.flash_lowerings")
         assert c.value(operands="bf16", block_q=256, block_k=256) == 1
         assert c.value(operands="f32", block_q=128, block_k=128) == 1
-        assert telemetry.total("attention.flash_lowerings") == 2
+        # the differentiated site counts once more, under its backward's
+        # form; the forward-only site has no backward to count
+        assert c.value(operands="bf16", block_q=256, block_k=256,
+                       window=0, bwd="fused") == 1
+        assert telemetry.total("attention.flash_lowerings") == 3
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+
+
+# ---------------------------------------------------------------------------
+# the backward as one pass (dq, dk, dv from one P and dS a tile pair)
+# against the split form (dq and dkv) and the reference's gradients
+# ---------------------------------------------------------------------------
+
+# (b, t, heads, kv heads, d, dv, causal, window, sink, block_q, block_k,
+# dtype): causal and not; T no multiple of the tile (padding keys);
+# several tiles a side, square and not; grouped heads (dK / dV sum over
+# the group in the resident buffer); a value width of its own; a window
+# with a sink (the band's inner steps); bf16 and float32
+FUSED_BWD_CASES = {
+    "causal_several_tiles": (1, 256, 2, 2, 32, 32, True, 0, False, 64, 64,
+                             jnp.float32),
+    "full_padded_keys": (1, 200, 2, 2, 32, 32, False, 0, False, 64, 32,
+                         jnp.float32),
+    "causal_padded_wide_k_tile": (2, 300, 2, 2, 32, 32, True, 0, False,
+                                  32, 64, jnp.float32),
+    "grouped_heads": (2, 300, 4, 2, 32, 32, True, 0, False, 64, 64,
+                      jnp.float32),
+    "grouped_heads_full": (1, 192, 6, 2, 16, 16, False, 0, False, 64, 64,
+                           jnp.float32),
+    "value_width_of_its_own": (1, 256, 2, 2, 48, 32, True, 0, False, 64,
+                               64, jnp.float32),
+    "window_with_sink": (1, 512, 4, 1, 32, 16, True, 48, True, 64, 64,
+                         jnp.float32),
+    "window_wider_than_a_tile": (1, 400, 2, 2, 32, 32, True, 100, True,
+                                 64, 64, jnp.float32),
+    "bf16_causal": (1, 512, 2, 2, 64, 64, True, 0, False, 128, 128,
+                    jnp.bfloat16),
+    "bf16_grouped_window_sink": (1, 512, 4, 1, 48, 32, True, 96, True,
+                                 128, 128, jnp.bfloat16),
+    "bf16_padded_full": (2, 200, 2, 1, 32, 32, False, 0, False, 64, 128,
+                         jnp.bfloat16),
+}
+
+
+def _fused_case(name):
+    (b, t, h, g, d, dv, causal, window, sink, bq, bk,
+     dtype) = FUSED_BWD_CASES[name]
+    rng = np.random.RandomState(sorted(FUSED_BWD_CASES).index(name))
+    q, k, v, do = (jnp.asarray(rng.randn(*shape), dtype) for shape in (
+        (b, t, h, d), (b, t, g, d), (b, t, g, dv), (b, t, h, dv)))
+    sink = jnp.asarray(rng.randn(h), jnp.float32) if sink else None
+    kw = dict(causal=causal, window=window, block_q=bq, block_k=bk)
+    return (q, k, v, do, sink), kw
+
+
+@pytest.mark.parametrize("name", sorted(FUSED_BWD_CASES))
+def test_fused_backward_matches_the_split_form(name, monkeypatch):
+    (q, k, v, do, sink), kw = _fused_case(name)
+
+    def grads(fuses):
+        monkeypatch.setattr(pk, "_bwd_fuses", lambda *a: fuses)
+        _, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, sink=sink, **kw),
+            q, k, v)
+        return vjp(do)
+
+    # the same products on the same operands: only dq's sum over k tiles
+    # runs in another order (float32 accumulation noise, then one
+    # rounding to the operand type)
+    tol = 1e-6 if q.dtype == jnp.float32 else 1e-2
+    for which, a, r in zip("dq dk dv".split(), grads(True), grads(False)):
+        assert a.dtype == r.dtype == q.dtype and a.shape == r.shape
+        assert _rel(a, r.astype(jnp.float32)) < tol, which
+
+
+@pytest.mark.parametrize("name", sorted(FUSED_BWD_CASES))
+def test_fused_backward_matches_the_reference_gradients(name):
+    (q, k, v, do, sink), kw = _fused_case(name)
+    assert pk._bwd_fuses(q.shape[1], kw["block_q"], kw["block_k"],
+                         q.shape[3], v.shape[3], q.dtype)
+    extra = () if sink is None else (sink,)
+
+    def grads(fn, q, k, v, **kw):
+        _, vjp = jax.vjp(lambda q, k, v, *s: fn(
+            q, k, v, sink=s[0] if s else None, **kw), q, k, v, *extra)
+        return vjp(do.astype(q.dtype))
+
+    got = grads(flash_attention, q, k, v, **kw)
+    want = grads(reference_attention,
+                 *(x.astype(jnp.float32) for x in (q, k, v)),
+                 causal=kw["causal"], window=kw["window"])
+    tol = 5e-4 if q.dtype == jnp.float32 else BF16_BWD_TOL
+    for which, a, r in zip("dq dk dv dsink".split(), got, want):
+        assert _rel(a, r) < tol, (which, _rel(a, r))
+
+
+# the benchmark's attention calls (T, d, dv, window), bf16: Kanana's
+# latent attention, OLMoE's, MiMo's full and window layers; then what the
+# resident dK / dV cannot hold
+@pytest.mark.parametrize("t,d,dv,window,dtype,fuses", [
+    (8192, 192, 128, 0, jnp.bfloat16, True),
+    (4096, 128, 128, 0, jnp.bfloat16, True),
+    (4096, 192, 128, 0, jnp.bfloat16, True),
+    (4096, 192, 128, 128, jnp.bfloat16, True),
+    (32768, 192, 128, 0, jnp.bfloat16, False),
+    (16384, 192, 128, 0, jnp.bfloat16, False),
+    (16384, 128, 128, 0, jnp.float32, False),
+])
+def test_backward_form_follows_the_shapes(t, d, dv, window, dtype, fuses):
+    bq, bk = flash_tiles(t, max(d, dv), dtype, window)
+    assert pk._bwd_fuses(t, bq, bk, d, dv, dtype) is fuses
+    counted = pk._flash_vmem_bytes(
+        bq, bk, max(d, dv), jnp.dtype(dtype).itemsize, resident=(t, d, dv))
+    assert (counted <= pk._FLASH_BWD_VMEM_LIMIT) is fuses
+    # the resident part alone: dK and dV in float32 and their two
+    # output buffers, lane-padded
+    assert counted - pk._flash_vmem_bytes(
+        bq, bk, max(d, dv), jnp.dtype(dtype).itemsize) >= t * (
+            d + dv) * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def test_split_backward_is_taken_where_the_rule_says(monkeypatch):
+    # a sequence the rule sends to dq and dkv, scaled down: the limit is
+    # lowered under a small call's count and the two kernels are traced
+    monkeypatch.setattr(pk, "_FLASH_BWD_VMEM_LIMIT", 1024)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        q = jnp.zeros((1, 256, 1, 64), jnp.float32)
+        fn = lambda q, k, v: flash_attention(q, k, v, causal=True)
+        dots = _dots_by_kernel(jax.make_jaxpr(
+            jax.grad(_loss(fn), argnums=(0, 1, 2)))(q, q, q))
+        assert sorted(dots) == ["flash_dkv_f32_q256_k256",
+                                "flash_dq_f32_q256_k256",
+                                "flash_fwd_f32_q256_k256"]
+        # 3 / 4 products a tile pair in dq / dkv: seven where five do
+        assert len(dots["flash_dq_f32_q256_k256"]) == 12
+        assert len(dots["flash_dkv_f32_q256_k256"]) == 16
+        c = telemetry.REGISTRY.get("attention.flash_lowerings")
+        assert c.value(operands="f32", block_q=256, block_k=256, window=0,
+                       bwd="split") == 1
     finally:
         telemetry.disable()
         telemetry.reset()

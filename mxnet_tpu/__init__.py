@@ -10,6 +10,10 @@ Usage mirrors the reference's ``import mxnet as mx``:
     net = mx.sym.FullyConnected(data, num_hidden=10)
     mod = mx.mod.Module(net, context=mx.tpu())
 """
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()  # telemetry.enable() publishes both
+
 import jax as _jax
 
 # float64 NDArrays are part of the reference API surface (mshadow DType
@@ -79,3 +83,7 @@ kv = kvstore
 # Parity __init__.py:37: non-worker DMLC roles get their documented no-op
 # path at import (the PS tier is subsumed by in-step XLA collectives).
 kvstore_server._init_kvstore_server_module()
+
+_IMPORT_SECONDS = _time.perf_counter() - _IMPORT_T0
+if telemetry.enabled():  # MXTPU_TELEMETRY=1: on before the second stamp
+    telemetry.setup.publish_import()

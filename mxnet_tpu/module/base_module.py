@@ -710,6 +710,10 @@ class BaseModule(object):
         less module, shapeless iterator) is silently skipped."""
         if not _tm.anatomy.enabled():
             return
+        with _tm.span(_tm.tracer.COST_CAPTURE):
+            self._emit_op_costs(train_data)
+
+    def _emit_op_costs(self, train_data):
         try:
             from ..telemetry import costmodel as _cm
 

@@ -250,7 +250,14 @@ class Module(BaseModule):
         if self.params_initialized and not force_init:
             return
         assert self.binded, "call bind before initializing the parameters"
+        # a forced refill (set_params at an epoch's end) is no set-up
+        with (_tm.NULL_SPAN if self.params_initialized
+              else _tm.span("module.init_params")):
+            self._init_params(initializer, arg_params, aux_params,
+                              allow_missing)
 
+    def _init_params(self, initializer, arg_params, aux_params,
+                     allow_missing):
         def _impl(name, arr, cache):
             if cache is not None:
                 if name in cache:
@@ -297,6 +304,13 @@ class Module(BaseModule):
         if self.binded:
             self.logger.warning("Already binded, ignoring bind()")
             return
+        with _tm.span("module.bind"):
+            self._bind(data_shapes, label_shapes, for_training,
+                       inputs_need_grad, shared_module, grad_req)
+
+    def _bind(self, data_shapes, label_shapes, for_training,
+              inputs_need_grad, shared_module, grad_req):
+        """Shape and type inference, the executor group and its arrays."""
         self.for_training = for_training
         self.inputs_need_grad = inputs_need_grad
         self.binded = True
@@ -369,6 +383,10 @@ class Module(BaseModule):
         if self.optimizer_initialized and not force_init:
             self.logger.warning("optimizer already initialized, ignoring...")
             return
+        with _tm.span("module.init_optimizer"):
+            self._init_optimizer(kvstore, optimizer, optimizer_params)
+
+    def _init_optimizer(self, kvstore, optimizer, optimizer_params):
         # an explicit mesh IS the device set: its size (not the ctx list,
         # which only hosts the eval executors) decides whether a kvstore
         # is needed at all (reference model.py:40 drops it for 1 device).
@@ -521,14 +539,15 @@ class Module(BaseModule):
         self._fused_multiproc = not all(
             d.process_index == jax.process_index()
             for d in mesh.devices.flat)
-        self._fused_trainer = ShardedTrainStep(
-            self._symbol, mesh, optimizer=self._optimizer,
-            param_specs=self._param_specs,
-            data_names=self._data_names, label_names=self._label_names,
-        ).compile()
-        self._fused_owner = self
-        # before the fused copies are placed: both at once do not fit
-        self._release_exec_arrays()
+        with _tm.span("module.fused_build"):
+            self._fused_trainer = ShardedTrainStep(
+                self._symbol, mesh, optimizer=self._optimizer,
+                param_specs=self._param_specs,
+                data_names=self._data_names, label_names=self._label_names,
+            ).compile()
+            self._fused_owner = self
+            # before the fused copies are placed: both at once do not fit
+            self._release_exec_arrays()
         if multiworker:
             # ranks may have initialized params independently; adopt the
             # kvstore's root-broadcast values (kv.init stored rank 0's)
@@ -562,7 +581,7 @@ class Module(BaseModule):
                 # batch (reference: each dist worker reads its own data
                 # shard; global batch = local batch x num_workers)
                 return jax.make_array_from_process_local_data(
-                    sharding, arr.asnumpy())
+                    sharding, self._fused_trainer.count_h2d(arr.asnumpy()))
             data = getattr(arr, "_data", None)
             if data is not None and getattr(data, "sharding", None) == sharding:
                 # DeviceFeedIter staged this batch on the mesh already —
@@ -570,7 +589,8 @@ class Module(BaseModule):
                 # step: no asnumpy sync, no per-step host->device copy
                 _M_FEED_HITS.inc()
                 return data
-            return jax.device_put(arr.asnumpy(), sharding)
+            return jax.device_put(
+                self._fused_trainer.count_h2d(arr.asnumpy()), sharding)
 
         batch = {}
         for name, arr in zip(self._data_names, data_batch.data):

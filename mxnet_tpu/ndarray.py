@@ -25,6 +25,7 @@ import numpy as np
 
 from . import autograd as _autograd
 from . import random as _random
+from . import telemetry as _tm
 from .base import MXNetError, mx_dtype_code, np_dtype, dtype_name
 from .context import Context, current_context
 from .ops import registry as _registry
@@ -288,9 +289,13 @@ class NDArray:
                 # _data assignment (not copyto) precisely so this drain
                 # can't self-deadlock the op that holds the var.
                 other._drain_engine()
+            if _tm.enabled():
+                _note_crossing(self._data, other._data.device)
             other._data = jax.device_put(self._data, other._data.device)
             return other
         if isinstance(other, Context):
+            if _tm.enabled():
+                _note_crossing(self._data, other.jax_device)
             return NDArray(jax.device_put(self._data, other.jax_device))
         raise MXNetError("copyto: unsupported target %r" % (other,))
 
@@ -498,9 +503,18 @@ _NONCOMMUTATIVE = {"elemwise_sub", "elemwise_div", "_power", "_mod"}
 # --------------------------------------------------------------------------
 # creation API
 # --------------------------------------------------------------------------
+def _note_crossing(data, device):
+    """A buffer held by a host (cpu) device on its way to ``device`` is
+    host traffic like ``_put``'s; one between two chips is not."""
+    if getattr(getattr(data, "device", None), "platform", None) == "cpu":
+        _tm.note_h2d(data.nbytes, device)
+
+
 def _put(arr, ctx):
     jax = _jax()
     ctx = ctx or current_context()
+    if _tm.enabled():
+        _tm.note_h2d(arr.nbytes, ctx.jax_device)
     return jax.device_put(arr, ctx.jax_device)
 
 

@@ -395,13 +395,13 @@ class ShardedTrainStep:
             if pad:
                 parts.append(np.zeros((pad,), np.float32))
             state[self._master_key(bi)] = jax.device_put(
-                np.concatenate(parts), sharding)
+                self.count_h2d(np.concatenate(parts)), sharding)
         rep = NamedSharding(self.mesh, P())
-        state[self.AMP_SCALE_KEY] = jax.device_put(
+        state[self.AMP_SCALE_KEY] = jax.device_put(self.count_h2d(
             np.asarray(self.amp_scale_init if scale is None else scale,
-                       np.float32), rep)
+                       np.float32)), rep)
         state[self.AMP_GOOD_KEY] = jax.device_put(
-            np.asarray(good, np.float32), rep)
+            self.count_h2d(np.asarray(good, np.float32)), rep)
         return state
 
     def master_params_named(self, opt_state):
@@ -422,8 +422,9 @@ class ShardedTrainStep:
         import jax
 
         named = self.master_params_named(opt_state)
-        return {n: jax.device_put(np.asarray(v, np.float32),
-                                  self._sharding_for(n))
+        return {n: jax.device_put(
+                    self.count_h2d(np.asarray(v, np.float32)),
+                    self._sharding_for(n))
                 for n, v in named.items()}
 
     def amp_state_blob(self, opt_state):
@@ -507,7 +508,8 @@ class ShardedTrainStep:
             leaf_dtype = flats[0].dtype
             if pad:
                 flats.append(np.zeros((pad,), leaf_dtype))
-            return jax.device_put(np.concatenate(flats), sharding)
+            return jax.device_put(
+                self.count_h2d(np.concatenate(flats)), sharding)
 
         state = {}
         for bi, b in enumerate(plan.buckets):
@@ -568,7 +570,7 @@ class ShardedTrainStep:
                 return None
             if isinstance(s, tuple):
                 return tuple(_place(name, x) for x in s)
-            host = np.asarray(s)
+            host = self.count_h2d(np.asarray(s))
             return jax.device_put(host,
                                   self._state_sharding_for(name, host))
 
@@ -587,28 +589,37 @@ class ShardedTrainStep:
         return NamedSharding(self.mesh, self._batch_spec)
 
     # ------------------------------------------------------------------
+    def count_h2d(self, host):
+        """``host``, a numpy array on its way to the mesh, counted into
+        ``device.h2d_bytes`` while telemetry is on."""
+        if _tm.enabled():
+            _tm.note_h2d(host.nbytes, self.mesh.devices.flat[0])
+        return host
+
     def place_params(self, arg_arrays_by_name, aux_arrays_by_name):
         """device_put host/NDArray values onto the mesh by spec.
 
         Accepts numpy arrays or NDArrays; returns dict of jax.Arrays."""
         import jax
 
-        def _np(v):
-            return v.asnumpy() if hasattr(v, "asnumpy") else np.asarray(v)
+        def _put(v, name):
+            host = v.asnumpy() if hasattr(v, "asnumpy") else np.asarray(v)
+            return jax.device_put(self.count_h2d(host),
+                                  self._sharding_for(name))
 
-        params = {
-            n: jax.device_put(_np(arg_arrays_by_name[n]), self._sharding_for(n))
-            for n in self.param_names
-        }
-        aux = {
-            n: jax.device_put(_np(aux_arrays_by_name[n]), self._sharding_for(n))
-            for n in self.aux_names
-        }
+        with _tm.span("train_step.place_params"):
+            params = {n: _put(arg_arrays_by_name[n], n)
+                      for n in self.param_names}
+            aux = {n: _put(aux_arrays_by_name[n], n) for n in self.aux_names}
         return params, aux
 
     def make_state(self, params):
         """Build optimizer state via the optimizer's OWN create_state on
         host zeros, then place it on the mesh (ZeRO-1 aware)."""
+        with _tm.span("train_step.make_state"):
+            return self._make_state(params)
+
+    def _make_state(self, params):
         import jax
 
         from .. import ndarray as ndmod
@@ -628,7 +639,8 @@ class ShardedTrainStep:
                         return None
                     if isinstance(s, tuple):
                         return tuple(_place_flat(x) for x in s)
-                    return jax.device_put(s.asnumpy(), sharding)
+                    return jax.device_put(
+                        self.count_h2d(s.asnumpy()), sharding)
 
                 placed = _place_flat(st)
                 if placed is not None:
@@ -651,7 +663,8 @@ class ShardedTrainStep:
                 if isinstance(s, tuple):
                     return tuple(_place(x) for x in s)
                 return jax.device_put(
-                    s.asnumpy(), self._state_sharding_for(name, s)
+                    self.count_h2d(s.asnumpy()),
+                    self._state_sharding_for(name, s)
                 )
 
             state[name] = _place(st)
@@ -1233,6 +1246,9 @@ class ShardedTrainStep:
         import jax
 
         self._step = jax.jit(self._make_step_fn(), donate_argnums=(0, 1, 2))
+        # signatures this jit has dispatched: the first call of each is
+        # the one that traces, lowers and compiles or loads
+        self._dispatched = set()
         try:
             _tm.anatomy.register_program(
                 self.program._program_uid,
@@ -1325,9 +1341,15 @@ class ShardedTrainStep:
         specs = (_abstract(args) if _tm.anatomy.cost_pending(
             self.program._program_uid, cost_key) else None)
         _M_STEPS.inc(path="single")
-        with _tm.span("train_step.dispatch", t=t):
+        first = sig not in self._dispatched
+        if first:
+            self._dispatched.add(sig)
+        with (_tm.span("train_step.first_dispatch") if first
+              else _tm.NULL_SPAN), _tm.span("train_step.dispatch", t=t):
             out = self._step(*args)
         if specs is not None:
-            self._capture_cost(cost_key, self._step, specs, {
-                n: tuple(v.shape) for n, v in batch.items()})
+            # the tracing's own set-up cost, timed as such
+            with _tm.span(_tm.tracer.COST_CAPTURE):
+                self._capture_cost(cost_key, self._step, specs, {
+                    n: tuple(v.shape) for n, v in batch.items()})
         return out

@@ -33,9 +33,13 @@ from . import registry as _registry
 from .registry import (  # noqa: F401
     Counter, Gauge, Histogram, Registry, REGISTRY,
     counter, gauge, histogram, render_prometheus, snapshot, enabled,
-    percentile_from_counts, total,
+    percentile_from_counts, total, snapshots_taken,
 )
-from .tracer import span, current_span, Span  # noqa: F401
+from .tracer import (  # noqa: F401
+    span, current_span, under, Span, NULL_SPAN,
+)
+from . import setup  # noqa: F401  (jax's seconds, bytes to the device)
+from .setup import note_h2d  # noqa: F401
 from .export import (  # noqa: F401
     sample_device_memory, write_prometheus_file, set_prometheus_file,
     jsonl_path,
@@ -62,6 +66,7 @@ def enable(jsonl=None, prometheus=None, prometheus_interval=None,
     if prometheus is not None:
         _export.set_prometheus_file(prometheus, prometheus_interval)
     _registry.set_enabled(True)
+    setup.install()
     _export.ensure_fleet_sink()
     if metrics_port is not None or os.environ.get("MXTPU_METRICS_PORT"):
         fleet.maybe_start_metrics_server(metrics_port)
@@ -82,6 +87,7 @@ def reset():
     """Zero all metric values and detach the JSONL sink — test isolation
     helper. Metric handles held by instrument sites stay registered."""
     _registry.REGISTRY.reset_values()
+    _registry._TAKEN.clear()
     anatomy.reset_state()
     _export.set_jsonl_path(None)
     _export.stop_prom_thread()
@@ -92,6 +98,7 @@ def reset():
 # env-driven enablement at import (MXTPU_TELEMETRY=1): adopt the fleet
 # sink and, if MXTPU_METRICS_PORT asks, serve /metrics right away
 if _registry.enabled():
+    setup.install()
     _export.ensure_fleet_sink()
     if os.environ.get("MXTPU_METRICS_PORT"):
         fleet.maybe_start_metrics_server()

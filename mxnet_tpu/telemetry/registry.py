@@ -19,10 +19,12 @@ the Prometheus renderer sanitizes to ``engine_ops_pushed`` at the edge.
 """
 from __future__ import annotations
 
+import collections
 import logging
 import math
 import os
 import threading
+import time
 
 # enabled at import via env so `MXTPU_TELEMETRY=1 python train.py` needs
 # no code changes; MXTPU_TELEMETRY_FILE implies enablement (an export
@@ -483,5 +485,22 @@ counter = REGISTRY.counter
 gauge = REGISTRY.gauge
 histogram = REGISTRY.histogram
 render_prometheus = REGISTRY.render_prometheus
-snapshot = REGISTRY.snapshot
 total = REGISTRY.total
+
+# the last few dumps handed out, each with the time.perf_counter() it
+# was taken at: the registry is cumulative, so whoever wants a value as
+# of some moment needs the dump taken then, and may not have been there
+_TAKEN = collections.deque(maxlen=4)
+
+
+def snapshot():
+    """``REGISTRY.snapshot()``, kept for :func:`snapshots_taken`."""
+    dump = REGISTRY.snapshot()
+    _TAKEN.append((time.perf_counter(), dump))
+    return dump
+
+
+def snapshots_taken():
+    """``[(perf_counter stamp, dump)]`` of the newest :func:`snapshot`
+    calls, oldest first."""
+    return list(_TAKEN)

@@ -1,0 +1,135 @@
+"""What a process's set-up is made of besides the spans round it: jax's
+own seconds by phase, the host-to-device bytes, and the package's import
+stamps. Each stream carries ``under``, the outermost span open on the
+calling thread (``tracer.under``): a reader takes one root's share
+(``module.bind``, ``fit.step``, ...) and an operator sees which step
+recompiled. See docs/observability.md, "Set-up".
+
+One set of ``jax.monitoring`` callbacks, registered by the first
+``telemetry.enable()``; a flag test each while collection is off.
+"""
+from __future__ import annotations
+
+import sys
+import threading
+
+from . import registry as _reg
+from .tracer import under
+
+JIT_SECONDS = _reg.counter(
+    "jit.seconds",
+    "seconds inside jax's own pipeline, by phase (trace, lower, compile, "
+    "cache_load) and by the outermost span open on the calling thread; "
+    "each phase less what ran nested in it, so the streams add up to "
+    "wall time")
+JIT_CACHE = _reg.counter(
+    "jit.cache",
+    "persistent compile cache lookups by result (hit, miss) and by the "
+    "outermost span open on the calling thread")
+H2D_BYTES = _reg.counter(
+    "device.h2d_bytes",
+    "bytes of host memory handed to jax.device_put (the numpy array's "
+    "nbytes, once however many devices receive it), by the outermost "
+    "span open on the calling thread")
+IMPORT_T0 = _reg.gauge(
+    "process.import_t0",
+    "time.perf_counter() at the first statement of mxnet_tpu/__init__.py")
+IMPORT_SECONDS = _reg.gauge(
+    "process.import_seconds",
+    "seconds from the first to the last statement of mxnet_tpu/__init__.py")
+
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+_tls = threading.local()
+_installed = False
+_accelerated = None
+
+
+def _open_phases():
+    """Per thread, the seconds spent nested in each open phase. jax
+    times a phase from enter to exit, and phases nest: a jit inside a
+    traced function is traced inside the outer trace, a constant folded
+    during tracing compiles inside it."""
+    st = getattr(_tls, "open", None)
+    if st is None:
+        st = _tls.open = []
+    return st
+
+
+def _on_phase_begin(event, _value, **_):
+    # jax records a scalar (the start time) on entering a timed phase
+    if _reg._enabled and event in _PHASES:
+        _open_phases().append(0.0)
+
+
+def _on_duration(event, seconds, **_):
+    if not _reg._enabled:
+        return
+    phase = _PHASES.get(event)
+    if phase is None and event != _CACHE_LOAD:
+        return
+    st = _open_phases()
+    if phase is None:
+        # timed inside backend_compile, with no begin of its own
+        phase, nested = "cache_load", 0.0
+    else:
+        nested = st.pop() if st else 0.0
+    if st:
+        st[-1] += seconds
+    JIT_SECONDS.inc(max(seconds - nested, 0.0), phase=phase, under=under())
+
+
+def _on_event(event, **_):
+    if _reg._enabled and event in _CACHE_RESULTS:
+        JIT_CACHE.inc(result=_CACHE_RESULTS[event], under=under())
+
+
+def install():
+    """Register the callbacks (once a process) and publish the import
+    stamps; ``telemetry.enable()`` and the env-driven enablement call
+    it."""
+    global _installed
+    publish_import()
+    if _installed:
+        return
+    _installed = True
+    from jax import monitoring
+
+    monitoring.register_scalar_listener(_on_phase_begin)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+
+
+def publish_import():
+    """The two stamps mxnet_tpu/__init__.py takes unconditionally, under
+    public names (telemetry is not yet on while the package imports)."""
+    pkg = sys.modules.get(__name__.split(".")[0])
+    t0 = getattr(pkg, "_IMPORT_T0", None)
+    seconds = getattr(pkg, "_IMPORT_SECONDS", None)
+    if t0 is not None and seconds is not None:
+        IMPORT_T0.set(t0)
+        IMPORT_SECONDS.set(seconds)
+
+
+def note_h2d(nbytes, device):
+    """Count ``nbytes`` of host memory handed to ``jax.device_put`` for
+    ``device``. A put to a host (cpu) device while an accelerator is the
+    default backend crosses nothing and is not counted. Callers guard
+    with ``telemetry.enabled()``: one flag test a call when off."""
+    global _accelerated
+    if _accelerated is None:
+        import jax
+
+        _accelerated = jax.default_backend() != "cpu"
+    if _accelerated and device.platform == "cpu":
+        return
+    H2D_BYTES.inc(int(nbytes), under=under())

@@ -52,7 +52,7 @@ class _NullSpan:
         pass
 
 
-_NULL = _NullSpan()
+_NULL = NULL_SPAN = _NullSpan()
 
 # what an open span reports to, resolved at the first enabled span and
 # not at import: profiler pulls in jax at call sites and must never
@@ -163,3 +163,22 @@ def current_span():
     """The innermost active span on this thread, or None."""
     st = getattr(_tls, "stack", None)
     return st[-1] if st else None
+
+
+# the span round what telemetry itself does in set-up when it is on
+COST_CAPTURE = "telemetry.cost_capture"
+
+
+def under():
+    """Name of the OUTERMOST span open on this thread, ``-`` outside
+    any: the label by which ``jit.seconds``, ``jit.cache`` and
+    ``device.h2d_bytes`` say whose work they were (telemetry/setup.py).
+    ``telemetry.cost_capture`` is charged to itself wherever it nests,
+    so that no root's share holds the tracing's own cost."""
+    st = getattr(_tls, "stack", None)
+    if not st:
+        return "-"
+    for sp in st:
+        if sp.name == COST_CAPTURE:
+            return COST_CAPTURE
+    return st[0].name

@@ -20,6 +20,7 @@ from mxnet_tpu import executor
 from mxnet_tpu import telemetry as tm
 from mxnet_tpu.parallel import train_step
 from mxnet_tpu.telemetry import anatomy, tracer
+from mxnet_tpu.telemetry import setup as tm_setup
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -81,13 +82,16 @@ def _toy_symbol():
     return mx.sym.SoftmaxOutput(net, name="softmax")
 
 
-def _toy_fit(batches=4, eval_metric="acc", **fit_kwargs):
+def _toy_fit(batches=4, eval_metric="acc", mod=None, batch_size=8,
+             **fit_kwargs):
     """A fused fit of ``batches`` steps on two virtual devices."""
     rng = np.random.RandomState(0)
-    n = 8 * batches
+    n = batch_size * batches
     it = mx.io.NDArrayIter(rng.rand(n, 3, 8, 8).astype("f"),
-                           rng.randint(0, 4, n).astype("f"), batch_size=8)
-    mod = mx.mod.Module(_toy_symbol(), context=[mx.cpu(0), mx.cpu(1)])
+                           rng.randint(0, 4, n).astype("f"),
+                           batch_size=batch_size)
+    if mod is None:
+        mod = mx.mod.Module(_toy_symbol(), context=[mx.cpu(0), mx.cpu(1)])
     mod.fit(it, eval_metric=eval_metric, optimizer="sgd", kvstore="device",
             num_epoch=1, **fit_kwargs)
     assert mod._fused_trainer is not None
@@ -110,12 +114,22 @@ def test_disabled_span_is_the_shared_null(name, annotations):
 
 def test_disabled_fit_builds_no_span_and_no_annotation(annotations,
                                                        monkeypatch):
-    built = []
+    built, listeners = [], []
     init = tracer.Span.__init__
     monkeypatch.setattr(tracer.Span, "__init__",
                         lambda self, *a: (built.append(a), init(self, *a))[1])
+    # as in a process that never enabled telemetry
+    monkeypatch.setattr(tm_setup, "_installed", False)
+    for register in ("register_event_listener", "register_scalar_listener",
+                     "register_event_duration_secs_listener"):
+        monkeypatch.setattr(jax.monitoring, register, listeners.append)
     _toy_fit()
     assert built == [] and annotations.made == 0
+    assert listeners == [] and not tm_setup._installed
+    for metric in (tm_setup.JIT_SECONDS, tm_setup.JIT_CACHE,
+                   tm_setup.H2D_BYTES, tm_setup.IMPORT_T0,
+                   tm_setup.IMPORT_SECONDS):
+        assert metric.label_sets() == [], metric.name
 
 
 # ---------------------------------------------------------------------------
@@ -151,7 +165,10 @@ def test_fit_span_tree_parents_and_step_ids(tmp_path):
             # (the wait that found the iterator empty belongs to a fifth,
             # discarded, step)
             ids = {s["attrs"]["step"] for s in inside}
-            first = {2} if name in lagging else {1, 2}
+            # (the first step's dispatch lies one level down, inside
+            # train_step.first_dispatch)
+            late = name in lagging or name == "train_step.dispatch"
+            first = {2} if late else {1, 2}
             assert first | {3, 4} <= ids <= {1, 2, 3, 4, 5}, (name, ids)
     for name in lagging:
         assert len(by_name[name]) == 4
@@ -172,6 +189,167 @@ def test_fit_span_tree_parents_and_step_ids(tmp_path):
     order = ["fit.input", "module.update", "module.update_metric",
              "fit.callbacks", "fit.after_steps"]
     assert sorted(order, key=lambda n: kids[n]["ts"]) == order
+
+
+# ---------------------------------------------------------------------------
+# E. set-up from inside (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+SETUP_ROOTS = ("module.bind", "module.init_params", "module.init_optimizer",
+               "train_step.first_dispatch")
+SETUP_CHILDREN = ("module.fused_build", "train_step.place_params",
+                  "train_step.make_state")
+
+
+def _spans_of(jsonl):
+    tm.flush()
+    spans = [json.loads(ln) for ln in open(jsonl)]
+    return [s for s in spans if s["type"] == "span"]
+
+
+def _streams(metric):
+    return tm.snapshot().get(metric, {"streams": []})["streams"]
+
+
+@pytest.fixture
+def traced_fit(tmp_path):
+    jsonl = str(tmp_path / "t.jsonl")
+    tm.enable(jsonl=jsonl)
+    return _toy_fit(batches=3), jsonl
+
+
+def test_setup_spans_come_once_and_in_order(traced_fit):
+    spans = _spans_of(traced_fit[1])
+    roots = sorted((s for s in spans if s["name"] in SETUP_ROOTS),
+                   key=lambda s: s["ts"])
+    assert [s["name"] for s in roots] == list(SETUP_ROOTS)
+    # the dispatch that traces and compiles is the first step's, and the
+    # steady-state span stays inside it
+    first = roots[-1]
+    assert first["attrs"]["parent"] == "module.update"
+    inner = [s for s in spans if s["name"] == "train_step.dispatch"
+             and s["attrs"].get("parent") == "train_step.first_dispatch"]
+    assert len(inner) == 1
+    assert len([s for s in spans
+                if s["name"] == "train_step.dispatch"]) == 3
+
+
+@pytest.mark.parametrize("child", SETUP_CHILDREN)
+def test_setup_children_lie_under_init_optimizer(child, traced_fit):
+    found = [s for s in _spans_of(traced_fit[1]) if s["name"] == child]
+    assert len(found) == 1
+    assert found[0]["attrs"]["parent"] == "module.init_optimizer"
+
+
+def test_a_second_fit_is_no_second_setup(traced_fit):
+    mod, jsonl = traced_fit
+    _toy_fit(batches=3, mod=mod)
+    names = [s["name"] for s in _spans_of(jsonl)]
+    for name in SETUP_ROOTS + SETUP_CHILDREN:
+        assert names.count(name) == 1, name
+    assert names.count("train_step.dispatch") == 6
+
+
+def test_a_new_batch_size_is_a_new_first_dispatch(traced_fit):
+    mod, jsonl = traced_fit
+    mod.reshape([("data", (4, 3, 8, 8))], [("softmax_label", (4,))])
+    _toy_fit(batches=3, mod=mod, batch_size=4)
+    names = [s["name"] for s in _spans_of(jsonl)]
+    assert names.count("train_step.first_dispatch") == 2
+    for name in SETUP_ROOTS[:-1] + SETUP_CHILDREN:
+        assert names.count(name) == 1, name
+
+
+@pytest.mark.parametrize("phase", ["trace", "lower", "compile"])
+def test_jit_seconds_say_under_which_root(phase, traced_fit):
+    streams = [s for s in _streams("jit.seconds")
+               if s["labels"]["phase"] == phase]
+    by_root = {s["labels"]["under"]: s["value"] for s in streams}
+    # the fused step's own program, whatever else ran
+    assert by_root.get("fit.step", 0) > 0, by_root
+    # nothing of the program's runs outside every span
+    assert "-" not in by_root, by_root
+    assert all(v >= 0 for v in by_root.values())
+
+
+def test_cost_capture_is_charged_to_itself(traced_fit):
+    """What telemetry itself does in set-up is inside a span of its own
+    (the symbol's cost table before the loop, the step's second lowering
+    after its first dispatch), and what jax spends there is not the
+    enclosing root's."""
+    spans = [s for s in _spans_of(traced_fit[1])
+             if s["name"] == "telemetry.cost_capture"]
+    assert {s["attrs"].get("parent") for s in spans} == {None,
+                                                         "module.update"}
+    before = {s["labels"]["under"]: s["value"]
+              for s in _streams("jit.seconds")
+              if s["labels"]["phase"] == "trace"}
+    with tm.span("fit.step"), tm.span("telemetry.cost_capture"):
+        jax.jit(lambda x: x * 3 + 1).lower(np.ones((5,), "f"))
+    after = {s["labels"]["under"]: s["value"]
+             for s in _streams("jit.seconds")
+             if s["labels"]["phase"] == "trace"}
+    assert after["telemetry.cost_capture"] > before.get(
+        "telemetry.cost_capture", 0)
+    assert after["fit.step"] == before["fit.step"]
+
+
+def test_jit_phases_do_not_count_nested_seconds_twice():
+    """jax times a phase from enter to exit and phases nest; each stream
+    holds its phase less what ran inside it."""
+    tm.enable()
+    begin, done = tm_setup._on_phase_begin, tm_setup._on_duration
+    trace, compile_ = (
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/backend_compile_duration")
+    with tm.span("module.bind"):
+        begin(trace, 0.0)
+        begin(trace, 0.0)
+        done(trace, 0.25)          # a jit traced inside the outer trace
+        begin(compile_, 0.0)
+        done("/jax/compilation_cache/cache_retrieval_time_sec", 0.5)
+        done(compile_, 0.75)       # a constant folded while tracing
+        done(trace, 2.0)
+    got = {s["labels"]["phase"]: s["value"]
+           for s in _streams("jit.seconds")}
+    assert got == {"trace": 0.25 + 1.0, "compile": 0.25, "cache_load": 0.5}
+    assert {s["labels"]["under"] for s in _streams("jit.seconds")} == {
+        "module.bind"}
+
+
+def test_h2d_bytes_of_a_bind_are_its_arrays():
+    tm.enable()
+    mod = mx.mod.Module(_toy_symbol(), context=mx.cpu(0))
+    mod.bind([("data", (8, 3, 8, 8))], [("softmax_label", (8,))])
+    exe = mod._exec_group.execs[0]
+    arrays = (list(exe.arg_arrays) + list(exe.aux_arrays)
+              + [g for g in exe.grad_arrays if g is not None])
+    want = sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
+               for a in arrays)
+    got = {s["labels"]["under"]: s["value"]
+           for s in _streams("device.h2d_bytes")}
+    assert got == {"module.bind": want} and want > 0
+
+
+def test_enable_publishes_the_import_stamps():
+    tm.enable()
+    t0 = _streams("process.import_t0")[0]["value"]
+    seconds = _streams("process.import_seconds")[0]["value"]
+    assert (t0, seconds) == (mx._IMPORT_T0, mx._IMPORT_SECONDS)
+    assert 0 < seconds and t0 + seconds <= time.perf_counter()
+
+
+def test_snapshots_are_kept_with_their_stamp():
+    tm.enable()
+    before = time.perf_counter()
+    tm.counter("t.kept").inc(2)
+    first = tm.snapshot()
+    tm.counter("t.kept").inc(3)
+    tm.snapshot()
+    taken = tm.snapshots_taken()
+    assert [d["t.kept"]["streams"][0]["value"] for _, d in taken] == [2, 5]
+    assert taken[0][1] is first
+    assert before <= taken[0][0] <= taken[1][0] <= time.perf_counter()
 
 
 class _SleepyMetric(mx.metric.Accuracy):
@@ -353,7 +531,16 @@ _COUNT_COMPILES = textwrap.dedent("""
     mod.fit(it, optimizer="sgd", kvstore="device", num_epoch=2)
     if sys.argv[1] == "on":
         jax.profiler.stop_trace()
-        assert tm.snapshot()["anatomy.model_flops"]["streams"][0]["value"] > 0
+        snap = tm.snapshot()
+        assert snap["anatomy.model_flops"]["streams"][0]["value"] > 0
+        # the second lowering is the tracing's own, timed as such, and
+        # compiles nothing
+        captured = [s for s in snap["mxtpu.span_seconds"]["streams"]
+                    if s["labels"] == {"span": "telemetry.cost_capture"}]
+        assert captured and captured[0]["count"] >= 2, captured
+        own = {s["labels"]["phase"] for s in snap["jit.seconds"]["streams"]
+               if s["labels"]["under"] == "telemetry.cost_capture"}
+        assert own and own <= {"trace", "lower"}, snap["jit.seconds"]
     assert mod._fused_trainer.amp
     print("COMPILES %%(compiles)d %%(hits)d %%(misses)d" %% seen)
 """) % REPO

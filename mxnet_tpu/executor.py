@@ -914,9 +914,11 @@ class Executor:
             self._arg_names, self.arg_arrays, self.grad_arrays, arg_shapes
         ):
             if name in kwargs or tuple(arr.shape) != tuple(shp):
-                new_args.append(nd.zeros(shp, ctx=self._ctx, dtype=arr.dtype))
+                new_args.append(
+                    nd.deferred_full(shp, 0, ctx=self._ctx, dtype=arr.dtype))
                 new_grads.append(
-                    None if garr is None else nd.zeros(shp, ctx=self._ctx, dtype=arr.dtype)
+                    None if garr is None else
+                    nd.deferred_full(shp, 0, ctx=self._ctx, dtype=arr.dtype)
                 )
             else:
                 new_args.append(arr)
@@ -924,7 +926,8 @@ class Executor:
         new_aux = []
         for arr, shp in zip(self.aux_arrays, aux_shapes):
             if tuple(arr.shape) != tuple(shp):
-                new_aux.append(nd.zeros(shp, ctx=self._ctx, dtype=arr.dtype))
+                new_aux.append(
+                    nd.deferred_full(shp, 0, ctx=self._ctx, dtype=arr.dtype))
             else:
                 new_aux.append(arr)
         return Executor(
@@ -1009,10 +1012,14 @@ class Executor:
     @staticmethod
     def simple_bind(symbol, ctx, grad_req="write", type_dict=None,
                     group2ctx=None, shared_exec=None, **kwargs):
-        """Infer shapes/types, allocate arg/grad/aux arrays, bind.
-        Parity: symbol.py:1114. With group2ctx, params/grads allocate on
+        """Infer shapes/types, declare arg/grad/aux arrays, bind.
+        Parity: symbol.py:1114. With group2ctx, params/grads belong to
         their group's device (reference simple_bind honors AssignContext
-        when allocating, symbol.py:1114-1210)."""
+        when allocating, symbol.py:1114-1210). Every array is born
+        deferred (``nd.deferred_full``): a zero that is made on its
+        device when something first reads it, and never if it is
+        written whole first (``set_params``, a batch, a ``write``
+        gradient)."""
         if isinstance(ctx, (list, tuple)):
             ctx = ctx[0]
         if not isinstance(ctx, Context):
@@ -1028,8 +1035,8 @@ class Executor:
             if name in shared and tuple(shared[name].shape) == tuple(shape):
                 arg_arrays.append(shared[name])
             else:
-                arg_arrays.append(
-                    nd.zeros(shape, ctx=var_ctx.get(name, ctx), dtype=dtype))
+                arg_arrays.append(nd.deferred_full(
+                    shape, 0, ctx=var_ctx.get(name, ctx), dtype=dtype))
         req_of = (
             (lambda n: grad_req)
             if isinstance(grad_req, str)
@@ -1038,7 +1045,7 @@ class Executor:
             else (lambda n: dict(zip(arg_names, grad_req)).get(n, "null"))
         )
         grad_arrays = [
-            nd.zeros(shape, ctx=var_ctx.get(name, ctx), dtype=dtype)
+            nd.deferred_full(shape, 0, ctx=var_ctx.get(name, ctx), dtype=dtype)
             if req_of(name) != "null" else None
             for name, shape, dtype in zip(arg_names, arg_shapes, arg_types)
         ]
@@ -1052,8 +1059,8 @@ class Executor:
                 # aux states (BN moving stats) live with their owning
                 # node's group too — _var_contexts covers them because
                 # aux vars appear among consumer-node inputs
-                aux_arrays.append(
-                    nd.zeros(shape, ctx=var_ctx.get(name, ctx), dtype=dtype))
+                aux_arrays.append(nd.deferred_full(
+                    shape, 0, ctx=var_ctx.get(name, ctx), dtype=dtype))
         return Executor(
             symbol, ctx, arg_arrays, grad_arrays, grad_req, aux_arrays, group2ctx
         )

@@ -208,7 +208,7 @@ class Normal(Initializer):
         import jax
         import jax.numpy as jnp
 
-        with jax.default_device(arr._data.device):
+        with jax.default_device(arr._placement):
             arr[:] = self.sigma * jax.random.normal(
                 random.next_key(), arr.shape, jnp.float32)
 

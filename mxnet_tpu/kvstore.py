@@ -496,8 +496,10 @@ class KVStore(object):
                         # direct _data write, NOT copyto: copyto drains
                         # the target's engine var, which is held by THIS
                         # op — calling it here would self-deadlock
+                        if _tm.enabled():
+                            nd._note_crossing(stored._data, o._placement)
                         o._data = jax.device_put(stored._data,
-                                                 o._data.device)
+                                                 o._placement)
                     _H_PULL_SECONDS.observe(time.perf_counter() - t0)
 
                 # device_put is idempotent (pure read of the stored

@@ -55,7 +55,8 @@ def _load_general(data, targets):
             # DeviceFeedIter-staged batch needs no transfer at all
             slice_idx, d_dst = d_targets[0]
             src = getattr(d_src, "_data", None)
-            dst = getattr(d_dst, "_data", None)
+            # the target as it stands, made or not: it is only replaced
+            dst = getattr(d_dst, "_buf", None)
             if (src is not None and dst is not None
                     and getattr(d_src, "_engine_dep", None) is None
                     and getattr(d_dst, "_engine_dep", None) is None
@@ -95,6 +96,20 @@ def _merge_multi_context(outputs):
     return [_gather(tensors) for tensors in outputs]
 
 
+class _Weights:
+    """Whether a group's weight arrays are out of date (``stale``): the
+    truth is then the module's host parameters or its fused state, and
+    ``Module._ensure_exec_params`` fills the arrays before an executor
+    runs. One object for every group that shares the arrays by identity
+    (``shared_group``: bucketing), since what one of them fills or
+    outdates it does for all."""
+
+    __slots__ = ("stale",)
+
+    def __init__(self):
+        self.stale = False
+
+
 class DataParallelExecutorGroup(object):
     """Parity: executor_group.py:77 DataParallelExecutorGroup."""
 
@@ -117,6 +132,8 @@ class DataParallelExecutorGroup(object):
         else:
             self.shared_data_arrays = shared_group.shared_data_arrays
         self.shared_group = shared_group
+        self.weights = _Weights() if shared_group is None \
+            else shared_group.weights
 
         data_names = [x[0] for x in data_shapes]
         if isinstance(grad_req, str):
@@ -185,6 +202,8 @@ class DataParallelExecutorGroup(object):
     def reshape(self, data_shapes, label_shapes):
         if data_shapes == self.data_shapes and label_shapes == self.label_shapes:
             return
+        # fresh executors and arrays, shared with nobody
+        self.weights = _Weights()
         self.bind_exec(data_shapes, label_shapes, reshape=True)
 
     def _collect_arrays(self):

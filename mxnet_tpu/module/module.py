@@ -53,23 +53,6 @@ _H_OUTPUT_SYNC = _tm.histogram(
     "— the device-sync leg of the step anatomy (telemetry/anatomy.py)")
 
 
-class _Released:
-    """What an executor array holds in place of its buffer while the
-    fused step owns the parameters: where a buffer of which shape and
-    type belongs (all ``NDArray.copyto`` and ``shape`` ask for)."""
-
-    __slots__ = ("shape", "dtype", "device")
-
-    def __init__(self, data):
-        self.shape, self.dtype, self.device = (
-            data.shape, data.dtype, data.device)
-
-    def __array__(self, *args, **kwargs):
-        raise MXNetError(
-            "this executor array gave its buffer up to the fused step: "
-            "read Module.get_params(), or run a forward first")
-
-
 def _local_rows(arr):
     """This process's rows of a (possibly multi-process) jax.Array.
     Single-process arrays pass through untouched; for a process-spanning
@@ -163,7 +146,18 @@ class Module(BaseModule):
         self._fused_outs_raw = None
         self._monitor = None
         self._fused_t = 0
-        self._fused_exec_stale = False
+
+    @property
+    def _exec_params_stale(self):
+        """The executor group's weights are out of date (the truth is
+        the fused state, else ``_arg_params``): ``_ensure_exec_params``
+        fills them before an executor runs. Kept with the arrays, so
+        modules that share them share it (``executor_group._Weights``)."""
+        return self._exec_group.weights.stale
+
+    @_exec_params_stale.setter
+    def _exec_params_stale(self, value):
+        self._exec_group.weights.stale = value
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -277,11 +271,12 @@ class Module(BaseModule):
         self._arg_attrs = self._symbol.attr_dict()
         attrs = self._arg_attrs
         if self._arg_params is None:
-            param_arrays = [nd.zeros(x[0].shape, dtype=x[0].dtype)
+            # the initializer writes each whole: no zeros are made first
+            param_arrays = [nd.deferred_full(x[0].shape, 0, dtype=x[0].dtype)
                             for x in self._exec_group.param_arrays]
             self._arg_params = dict(zip(self._param_names, param_arrays))
         if self._aux_params is None:
-            aux_arrays = [nd.zeros(x[0].shape, dtype=x[0].dtype)
+            aux_arrays = [nd.deferred_full(x[0].shape, 0, dtype=x[0].dtype)
                           for x in self._exec_group.aux_arrays]
             self._aux_params = dict(zip(self._aux_names, aux_arrays))
         for name, arr in self._arg_params.items():
@@ -290,10 +285,10 @@ class Module(BaseModule):
             _impl(name, arr, aux_params)
         self.params_initialized = True
         self._params_dirty = False
-        if self._fused_trainer is None or not self._fused_exec_stale:
-            # (released under the fused step, as at fit's epoch end:
-            # _ensure_exec_params fills them when an executor runs)
-            self._exec_group.set_params(self._arg_params, self._aux_params)
+        # the executor group gets them when an executor first runs
+        # (_ensure_exec_params): a fused fit that never evaluates
+        # through the executors never sends them
+        self._outdate_exec_params()
 
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,
@@ -345,7 +340,7 @@ class Module(BaseModule):
             self._arg_params = shared_module._arg_params
             self._aux_params = shared_module._aux_params
         elif self.params_initialized:
-            self._exec_group.set_params(self._arg_params, self._aux_params)
+            self._exec_params_stale = True
         if shared_module is not None and shared_module.optimizer_initialized:
             self.borrow_optimizer(shared_module)
 
@@ -373,7 +368,7 @@ class Module(BaseModule):
             self._exec_group.set_params(self._arg_params, self._aux_params)
             # fresh executors with fresh weights: the next fused update
             # releases them again
-            self._fused_exec_stale = False
+            self._exec_params_stale = False
 
     def init_optimizer(self, kvstore="local", optimizer="sgd",
                        optimizer_params=(("learning_rate", 0.01),),
@@ -431,12 +426,21 @@ class Module(BaseModule):
         self._kvstore = kvstore
         self._update_on_kvstore = update_on_kvstore
         self._updater = None
+        fused = self._fusable(kvstore)
+        if not fused:
+            # training runs the executors: they get their weights now,
+            # and the kvstore's pull below lands on them as the
+            # reference's does (rank 0's values over each worker's own)
+            self._ensure_exec_params()
         if kvstore:
             _initialize_kvstore(
                 kvstore=kvstore, param_arrays=self._exec_group.param_arrays,
                 arg_params=self._arg_params,
                 param_names=self._param_names,
-                update_on_kvstore=update_on_kvstore
+                # the fused step never reads the executor group: nothing
+                # is pulled into it (_ensure_exec_params fills it from
+                # the fused state if an executor ever runs)
+                update_on_kvstore=update_on_kvstore and not fused
             )
         if update_on_kvstore:
             kvstore.set_optimizer(self._optimizer)
@@ -444,7 +448,7 @@ class Module(BaseModule):
             self._updater = opt.get_updater(optimizer)
         from ..parallel.train_step import amp_requested
 
-        if self._fusable(kvstore):
+        if fused:
             self._init_fused()
         elif self._mesh is not None:
             # the user explicitly asked for a mesh; quietly training
@@ -546,7 +550,8 @@ class Module(BaseModule):
                 data_names=self._data_names, label_names=self._label_names,
             ).compile()
             self._fused_owner = self
-            # before the fused copies are placed: both at once do not fit
+            # before the fused copies are placed: both at once do not
+            # fit (nothing to drop unless an executor has already run)
             self._release_exec_arrays()
         if multiworker:
             # ranks may have initialized params independently; adopt the
@@ -607,35 +612,40 @@ class Module(BaseModule):
         never written on this path, the weights are stale after one
         step): two parameter-sized buffers per device, at 1.5 G
         parameters the difference between fitting a 16 GB chip and not.
-        They are dropped here, each array keeping where a buffer of
-        which shape and type belongs; ``_ensure_exec_params`` fills the
-        weights again before any executor-path forward, and an executor
-        backward writes its gradients anew (``grad_req`` ``write``; an
-        accumulating gradient is left alone)."""
+        From bind on they hold no buffer at all (``nd.deferred_full``);
+        what an executor-path forward or backward has made since goes
+        here, each array back to the deferred zero it was born as.
+        ``_ensure_exec_params`` fills the weights again before any
+        executor-path forward, and an executor backward writes its
+        gradients anew (``grad_req`` ``write``; an accumulating
+        gradient is left alone)."""
         for exe in self._exec_group.execs:
+            args, grads = exe.arg_dict, exe.grad_dict
             for name in self._param_names:
-                arrays = [exe.arg_dict[name]]
+                args[name]._drop_buffer()
                 if exe._grad_req.get(name) == "write":
-                    arrays.append(exe.grad_dict[name])
-                for arr in arrays:
-                    if not isinstance(arr._data, _Released):
-                        arr._data = _Released(arr._data)
-        self._fused_exec_stale = True
+                    grads[name]._drop_buffer()
+        self._exec_params_stale = True
 
-    def _exec_arrays_went_stale(self, owner):
-        """A fused update ran: what an eval since the last one put into
-        the executor group is out of date, and goes."""
-        if not self._fused_exec_stale:
+    def _outdate_exec_params(self):
+        """The truth moved (host parameters were set, a fused update
+        ran): what the executor group holds is out of date, and under
+        the fused step it goes."""
+        if self._fused_trainer is None:
+            self._exec_params_stale = True
+        elif not self._exec_params_stale:
             self._release_exec_arrays()
-        owner._fused_exec_stale = True
 
     def _ensure_exec_params(self):
-        """Refresh executor-group weight copies after fused updates (the
-        eval/predict path still runs per-device executors)."""
-        if self._fused_trainer is not None and self._fused_exec_stale:
-            self._sync_params_from_devices()
+        """Give the executor group its weights before an executor runs:
+        from the fused state after fused updates (the eval/predict path
+        still runs per-device executors), else the host parameters that
+        ``init_params`` / ``set_params`` left for this moment."""
+        if self._exec_params_stale:
+            if self._fused_trainer is not None:
+                self._sync_params_from_devices()
             self._exec_group.set_params(self._arg_params, self._aux_params)
-            self._fused_exec_stale = False
+            self._exec_params_stale = False
 
     def borrow_optimizer(self, shared_module):
         """Parity module.py:529. When the shared module runs the fused
@@ -760,7 +770,7 @@ class Module(BaseModule):
             self._fused_outs_raw = outs
             self._fused_outputs = None
             self._fused_batch = None
-            self._exec_arrays_went_stale(owner)
+            self._outdate_exec_params()
             return
         if self._update_on_kvstore:
             with _tm.span("module.update", path="kvstore"):
@@ -877,7 +887,9 @@ class Module(BaseModule):
                         self._aux_params[name][:] = np.asarray(arr)
                 self._params_dirty = False
                 return
-            self._exec_group.get_params(self._arg_params, self._aux_params)
+            if not self._exec_params_stale:  # else the host's are newer
+                self._exec_group.get_params(self._arg_params,
+                                            self._aux_params)
             self._params_dirty = False
 
     def _topology(self):
@@ -1008,8 +1020,6 @@ class Module(BaseModule):
             if self is not owner:
                 self._fused_params = owner._fused_params
                 self._fused_aux = owner._fused_aux
-            owner._fused_exec_stale = True
-            self._fused_exec_stale = True
         opt = blob.get("opt") or {"kind": "none"}
         kind = opt.get("kind", "none")
         if kind == "fused":

@@ -169,24 +169,121 @@ def imperative_invoke(opdef, inputs, attrs, out=None):
     return ret
 
 
+class _Deferred:
+    """What an NDArray holds in place of a buffer nobody has read or
+    written yet: where a constant of which shape, type and value belongs.
+    The buffer comes into being on the first read of ``NDArray._data``,
+    made on its own device by a program (no host array, no crossing); a
+    whole write replaces it without the constant ever being made. Also
+    what an executor array keeps once the fused step owns the
+    parameters (``NDArray._drop_buffer``): then ``value`` is None, there
+    is nothing to read, and a read says so."""
+
+    __slots__ = ("shape", "dtype", "value", "device")
+
+    def __init__(self, shape, dtype, value, device):
+        self.shape = tuple(int(d) for d in shape)
+        self.dtype = np.dtype(dtype)
+        self.value = value.item() if isinstance(value, np.generic) else value
+        self.device = device
+
+    @property
+    def ndim(self):
+        return len(self.shape)
+
+    @property
+    def nbytes(self):
+        return int(np.prod(self.shape, dtype=np.int64)) * self.dtype.itemsize
+
+    @property
+    def sharding(self):
+        """``device`` is a Device or, as ``jax.Array.device`` gives for an
+        array over several, a Sharding; a program's ``out_shardings``
+        wants the latter."""
+        from jax.sharding import Sharding, SingleDeviceSharding
+
+        if isinstance(self.device, Sharding):
+            return self.device
+        return SingleDeviceSharding(self.device)
+
+
+@functools.lru_cache(maxsize=1024)
+def _constants_program(specs, shardings):
+    """One jitted program, kept for the process, that makes every
+    constant of ``specs`` ((shape, dtype, value), ...) where
+    ``shardings`` says."""
+    import jax.numpy as jnp
+
+    def make():
+        return tuple(jnp.full(shape, value, dtype)
+                     for shape, dtype, value in specs)
+
+    return _jax().jit(make, out_shardings=shardings)
+
+
+def make_constants(deferred):
+    """The buffers of a sequence of ``_Deferred``, made on their devices
+    by ONE program and without a host array: a set-up that needs a
+    state tree pays one cache load, not one per key."""
+    deferred = tuple(deferred)
+    if not deferred:
+        return ()
+    if any(d.value is None for d in deferred):
+        raise MXNetError(
+            "this executor array gave its buffer up to the fused step: "
+            "read Module.get_params(), or run a forward first")
+    if _tm.enabled():
+        for d in deferred:
+            _tm.note_const(d.nbytes)
+    return _constants_program(
+        tuple((d.shape, d.dtype, d.value) for d in deferred),
+        tuple(d.sharding for d in deferred))()
+
+
 class NDArray:
     """An n-dimensional array on a device, with async-op semantics."""
 
-    __slots__ = ("_data", "_engine_dep")
+    __slots__ = ("_buf", "_engine_dep")
     # prefer our operators over numpy's in mixed expressions
     __array_priority__ = 1000.0
 
     def __init__(self, data):
-        self._data = data
+        # a jax.Array, or a _Deferred until something reads or writes it
+        self._buf = data
         # (engine, Var) when a host-side engine op (KVStore push/pull)
         # has claimed this array; None for the overwhelmingly common
         # case where jax's value tracking is the only discipline needed
         self._engine_dep = None
 
+    # -- the buffer ---------------------------------------------------------
+    @property
+    def _data(self):
+        """The jax.Array; a deferred constant is made here, once."""
+        buf = self._buf
+        if type(buf) is _Deferred:
+            buf = self._buf = make_constants((buf,))[0]
+        return buf
+
+    @_data.setter
+    def _data(self, value):
+        self._buf = value
+
+    @property
+    def _placement(self):
+        """Where the buffer lives (a Device; a Sharding for an array
+        over several), whether or not it has been made."""
+        return self._buf.device
+
+    def _drop_buffer(self):
+        """Give the buffer up: the array keeps shape, type and device,
+        and has nothing to read until something writes it whole."""
+        buf = self._buf
+        self._buf = _Deferred(buf.shape, buf.dtype, None, buf.device)
+
     # -- basic properties ---------------------------------------------------
     @property
     def shape(self):
-        return tuple(self._data.shape)
+        return tuple(self._buf.shape)
 
     @property
     def size(self):
@@ -194,20 +291,18 @@ class NDArray:
 
     @property
     def ndim(self):
-        return self._data.ndim
+        return self._buf.ndim
 
     @property
     def dtype(self):
-        return np_dtype(self._data.dtype)
+        return np_dtype(self._buf.dtype)
 
     @property
     def context(self):
-        jax = _jax()
-        dev = self._data.device
+        dev = self._buf.device
         if hasattr(dev, "platform"):
             return _ctx_of_jax_device(dev)
-        devs = list(self._data.devices())
-        return _ctx_of_jax_device(devs[0])
+        return _ctx_of_jax_device(sorted(dev.device_set, key=lambda d: d.id)[0])
 
     ctx = context
 
@@ -240,7 +335,8 @@ class NDArray:
 
     def wait_to_read(self):
         self._drain_engine()
-        self._data.block_until_ready()
+        if type(self._buf) is not _Deferred:  # nothing in flight otherwise
+            self._buf.block_until_ready()
 
     wait_to_write = wait_to_read
 
@@ -290,8 +386,8 @@ class NDArray:
                 # can't self-deadlock the op that holds the var.
                 other._drain_engine()
             if _tm.enabled():
-                _note_crossing(self._data, other._data.device)
-            other._data = jax.device_put(self._data, other._data.device)
+                _note_crossing(self._data, other._placement)
+            other._data = jax.device_put(self._data, other._placement)
             return other
         if isinstance(other, Context):
             if _tm.enabled():
@@ -342,12 +438,25 @@ class NDArray:
         else:
             v = value
         if isinstance(key, _py_slice) and key.start is None and key.stop is None:
+            # a whole write: what the array held is not read
             if np.isscalar(v):
-                self._data = jnp.full_like(self._data, v)
+                if type(self._buf) is _Deferred:
+                    self._buf = _Deferred(self.shape, self._buf.dtype, v,
+                                          self._buf.device)
+                else:
+                    self._data = jnp.full_like(self._buf, v)
+            elif isinstance(v, _jax().Array):
+                v = jnp.broadcast_to(
+                    jnp.asarray(v, dtype=self.dtype), self.shape)
+                if not isinstance(self._buf, _jax().core.Tracer):
+                    v = _jax().device_put(v, self._buf.sharding)
+                self._data = v
             else:
-                self._data = jnp.broadcast_to(
-                    jnp.asarray(v, dtype=self.dtype), self.shape
-                ) + jnp.zeros_like(self._data)
+                # host values go straight to where the array lives, not
+                # by way of the default device
+                self._data = _jax().device_put(
+                    np.broadcast_to(np.asarray(v, dtype=self.dtype),
+                                    self.shape), self._buf.sharding)
             return
         self._data = self._data.at[key].set(v)
 
@@ -480,7 +589,8 @@ class NDArray:
     def __setstate__(self, state):
         import jax.numpy as jnp
 
-        self._data = jnp.asarray(state["data"])
+        self._buf = jnp.asarray(state["data"])
+        self._engine_dep = None
 
 
 _BCAST_NAME = {
@@ -518,26 +628,43 @@ def _put(arr, ctx):
     return jax.device_put(arr, ctx.jax_device)
 
 
+def deferred_full(shape, val, ctx=None, dtype=np.float32):
+    """An array that reads as ``full(shape, val)`` on ``ctx`` and holds
+    no buffer until something reads it (``_Deferred``): what bind's
+    arguments, gradients and auxiliary states and an optimizer's fresh
+    state are born as, on every context."""
+    if isinstance(shape, int):
+        shape = (shape,)
+    ctx = ctx or current_context()
+    return NDArray(_Deferred(shape, np_dtype(dtype), val, ctx.jax_device))
+
+
+def _created(host, shape, val, ctx, dtype):
+    """On the cpu context ``host``'s numpy array handed to the host
+    device; on an accelerator no host array and no crossing
+    (``deferred_full``)."""
+    if isinstance(shape, int):
+        shape = (shape,)
+    ctx = ctx or current_context()
+    if ctx.device_type != "cpu":
+        return deferred_full(shape, val, ctx, dtype)
+    return NDArray(_put(host(shape, np_dtype(dtype)), ctx))
+
+
 def empty(shape, ctx=None, dtype=np.float32):
     return zeros(shape, ctx, dtype)
 
 
 def zeros(shape, ctx=None, dtype=np.float32):
-    if isinstance(shape, int):
-        shape = (shape,)
-    return NDArray(_put(np.zeros(shape, np_dtype(dtype)), ctx))
+    return _created(np.zeros, shape, 0, ctx, dtype)
 
 
 def ones(shape, ctx=None, dtype=np.float32):
-    if isinstance(shape, int):
-        shape = (shape,)
-    return NDArray(_put(np.ones(shape, np_dtype(dtype)), ctx))
+    return _created(np.ones, shape, 1, ctx, dtype)
 
 
 def full(shape, val, ctx=None, dtype=np.float32):
-    if isinstance(shape, int):
-        shape = (shape,)
-    return NDArray(_put(np.full(shape, val, np_dtype(dtype)), ctx))
+    return _created(lambda s, d: np.full(s, val, d), shape, val, ctx, dtype)
 
 
 def array(source_array, ctx=None, dtype=None):

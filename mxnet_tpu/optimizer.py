@@ -162,7 +162,8 @@ class Optimizer:
         assert self.elementwise_update, (
             "%s cannot create flat sharded state (elementwise_update is "
             "False)" % type(self).__name__)
-        return self.create_state(index, nd.zeros((size,), dtype=dtype))
+        return self.create_state(
+            index, nd.deferred_full((size,), 0, dtype=dtype))
 
     def update(self, index, weight, grad, state):
         raise NotImplementedError()
@@ -175,8 +176,12 @@ register = Optimizer.register
 
 
 def _zeros_like_weight(weight, dtype=None):
-    return nd.zeros(weight.shape, ctx=weight.context,
-                    dtype=dtype or weight.dtype)
+    """Fresh state is born deferred: made on the weight's device by the
+    first update that reads it, or on the mesh in one program with the
+    rest of the tree (``ShardedTrainStep.make_state``); never on the
+    host."""
+    return nd.deferred_full(weight.shape, 0, ctx=weight.context,
+                            dtype=dtype or weight.dtype)
 
 
 @register

@@ -381,21 +381,47 @@ class ShardedTrainStep:
         scalars. `params_by_name` must be fp32 truth (host or device);
         `scale`/`good` seed the loss scaler (fresh init by default)."""
         import jax
+        import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         plan = self._flat_plan
         assert plan is not None, "flat plan not built yet"
         sharding = self._flat_state_sharding()
+        names = [[name for (_i, name, _o, _s, _sh) in b.views]
+                 for b in plan.buckets]
+        pads = [b.padded - b.size for b in plan.buckets]
         state = {}
-        for bi, b in enumerate(plan.buckets):
-            parts = [np.asarray(params_by_name[name],
-                                np.float32).reshape(-1)
-                     for (_i, name, _o, _s, _sh) in b.views]
-            pad = b.padded - b.size
-            if pad:
-                parts.append(np.zeros((pad,), np.float32))
-            state[self._master_key(bi)] = jax.device_put(
-                self.count_h2d(np.concatenate(parts)), sharding)
+        mesh_devices = set(self.mesh.devices.flat)
+        if all(isinstance(params_by_name[n], jax.Array)
+               and params_by_name[n].sharding.device_set <= mesh_devices
+               for bucket in names for n in bucket):
+            # already on the mesh (make_state's placed params): packed
+            # there by one program, nothing fetched and sent back
+            def pack(buckets):
+                slabs = []
+                for parts, pad in zip(buckets, pads):
+                    flats = [p.astype(jnp.float32).reshape(-1)
+                             for p in parts]
+                    if pad:
+                        flats.append(jnp.zeros((pad,), jnp.float32))
+                    slabs.append(jnp.concatenate(flats))
+                return slabs
+
+            slabs = jax.jit(pack, out_shardings=[sharding] * len(names))(
+                [[params_by_name[n] for n in bucket] for bucket in names])
+            if _tm.enabled():
+                _tm.note_const(4 * sum(pads))
+            for bi, slab in enumerate(slabs):
+                state[self._master_key(bi)] = slab
+        else:
+            for bi, bucket in enumerate(names):
+                parts = [np.asarray(params_by_name[name],
+                                    np.float32).reshape(-1)
+                         for name in bucket]
+                if pads[bi]:
+                    parts.append(np.zeros((pads[bi],), np.float32))
+                state[self._master_key(bi)] = jax.device_put(
+                    self.count_h2d(np.concatenate(parts)), sharding)
         rep = NamedSharding(self.mesh, P())
         state[self.AMP_SCALE_KEY] = jax.device_put(self.count_h2d(
             np.asarray(self.amp_scale_init if scale is None else scale,
@@ -615,7 +641,11 @@ class ShardedTrainStep:
 
     def make_state(self, params):
         """Build optimizer state via the optimizer's OWN create_state on
-        host zeros, then place it on the mesh (ZeRO-1 aware)."""
+        a float32 stand-in of each weight (a deferred zero: shape, type
+        and device, no buffer), made where it lives (ZeRO-1 aware):
+        what create_state declares as a constant is made on the mesh,
+        the whole tree by one program and without a host array; what it
+        computed is already on a device and moves from there."""
         with _tm.span("train_step.make_state"):
             return self._make_state(params)
 
@@ -629,46 +659,65 @@ class ShardedTrainStep:
         if self.flat_mode is not None:
             plan = self._ensure_flat_plan(params)
             sharding = self._flat_state_sharding()
-            state = {}
-            for bi, b in enumerate(plan.buckets):
-                st = self.optimizer.create_state_flat(
+            state = self._place_fresh_state(
+                {self._flat_key(bi): self.optimizer.create_state_flat(
                     b.rep_index, b.padded, dtype=b.dtype)
-
-                def _place_flat(s):
-                    if s is None:
-                        return None
-                    if isinstance(s, tuple):
-                        return tuple(_place_flat(x) for x in s)
-                    return jax.device_put(
-                        self.count_h2d(s.asnumpy()), sharding)
-
-                placed = _place_flat(st)
-                if placed is not None:
-                    state[self._flat_key(bi)] = placed
+                 for bi, b in enumerate(plan.buckets)},
+                lambda key, s: sharding)
+            state = {k: v for k, v in state.items() if v is not None}
             if self.amp:
                 # params here must be fp32 truth (callers pass the placed
                 # fp32 params BEFORE amp_cast_params) — they become the
                 # master slabs
                 state.update(self.build_amp_master_state(params))
             return state
-        state = {}
-        for i, name in enumerate(self.param_names):
-            p = params[name]
-            host_w = ndmod.zeros(p.shape)
-            st = self.optimizer.create_state(i, host_w)
+        # the stand-ins belong to one of this process's mesh devices, so
+        # whatever a create_state computes from one is made there
+        home = ndmod._ctx_of_jax_device(next(
+            d for d in self.mesh.devices.flat
+            if d.process_index == jax.process_index()))
+        return self._place_fresh_state(
+            {name: self.optimizer.create_state(
+                i, ndmod.deferred_full(params[name].shape, 0, ctx=home))
+             for i, name in enumerate(self.param_names)},
+            self._state_sharding_for)
 
-            def _place(s):
-                if s is None:
-                    return None
-                if isinstance(s, tuple):
-                    return tuple(_place(x) for x in s)
-                return jax.device_put(
-                    self.count_h2d(s.asnumpy()),
-                    self._state_sharding_for(name, s)
-                )
+    def _place_fresh_state(self, tree, sharding_for):
+        """``tree`` (key -> None, NDArray or nested tuples, as
+        create_state returns them) on the mesh, each leaf where
+        ``sharding_for(key, leaf)`` says. Leaves nobody has read are
+        constants: all of them come out of ONE program on the mesh
+        (``ndarray.make_constants``), none crosses from the host."""
+        import jax
 
-            state[name] = _place(st)
-        return state
+        from .. import ndarray as ndmod
+
+        consts = []
+
+        def _place(key, s):
+            if s is None:
+                return None
+            if isinstance(s, tuple):
+                return tuple(_place(key, x) for x in s)
+            sharding = sharding_for(key, s)
+            buf = s._buf
+            if isinstance(buf, ndmod._Deferred):
+                consts.append(ndmod._Deferred(
+                    buf.shape, buf.dtype, buf.value, sharding))
+                return len(consts) - 1  # filled in below
+            if _tm.enabled():
+                ndmod._note_crossing(buf, self.mesh.devices.flat[0])
+            return jax.device_put(buf, sharding)
+
+        placed = {key: _place(key, s) for key, s in tree.items()}
+        made = ndmod.make_constants(consts)
+
+        def _fill(s):
+            if isinstance(s, tuple):
+                return tuple(_fill(x) for x in s)
+            return made[s] if isinstance(s, int) else s
+
+        return {key: _fill(s) for key, s in placed.items()}
 
     def init(self, arg_shapes_by_name, initializer, seed=0):
         """Allocate + initialize sharded params/aux/opt-state on the mesh."""

@@ -282,7 +282,7 @@ class _ServeFn(object):
         self._avals = [
             jax.ShapeDtypeStruct(
                 tuple(input_shapes[n]),
-                exec_.arg_dict[n]._data.dtype, sharding=sharding)
+                exec_.arg_dict[n].dtype, sharding=sharding)
             for n in data_names
         ]
         # AOT: lower + compile now (the persistent compile cache

@@ -31,6 +31,12 @@ H2D_BYTES = _reg.counter(
     "bytes of host memory handed to jax.device_put (the numpy array's "
     "nbytes, once however many devices receive it), by the outermost "
     "span open on the calling thread")
+CONST_BYTES = _reg.counter(
+    "device.const_bytes",
+    "bytes of constants made on a device by a program, without a host "
+    "array or a crossing (a deferred buffer's first read, fresh optimizer "
+    "state), by the outermost span open on the calling thread: with "
+    "device.h2d_bytes, what a span allocated")
 IMPORT_T0 = _reg.gauge(
     "process.import_t0",
     "time.perf_counter() at the first statement of mxnet_tpu/__init__.py")
@@ -133,3 +139,10 @@ def note_h2d(nbytes, device):
     if _accelerated and device.platform == "cpu":
         return
     H2D_BYTES.inc(int(nbytes), under=under())
+
+
+def note_const(nbytes):
+    """Count ``nbytes`` of a constant a program made on a device. Same
+    guard (the caller's) and same ``under`` as ``note_h2d``, so a span's
+    allocations are the sum of the two."""
+    CONST_BYTES.inc(int(nbytes), under=under())

@@ -609,9 +609,9 @@ def _blobs():
 
 
 def _released(mod, name="fc_weight"):
-    from mxnet_tpu.module.module import _Released
+    from mxnet_tpu.ndarray import _Deferred
 
-    held = {isinstance(arr._data, _Released)
+    held = {isinstance(arr._buf, _Deferred)
             for exe in mod._exec_group.execs
             for arr in (exe.arg_dict[name], exe.grad_dict[name])}
     assert len(held) == 1, "weights and gradients go and come together"
@@ -619,14 +619,41 @@ def _released(mod, name="fc_weight"):
 
 
 def test_fused_fit_releases_the_executors_buffers_and_eval_fills_them():
-    """While the fused step trains, the executor group holds no buffer
-    for the weights or for their gradients (two parameter-sized buffers
-    per device otherwise); an executor-path forward finds the trained
-    weights on its devices, and the next fused update drops them
-    again."""
+    """From bind on and while the fused step trains, the executor group
+    holds no buffer for the weights or for their gradients (two
+    parameter-sized buffers per device otherwise); an executor-path
+    forward finds the trained weights on its devices, and the next
+    fused update drops them again."""
     net, x, y = _blobs()
     ctx = [mx.cpu(1), mx.cpu(2)]
     mod = mx.mod.Module(net, context=ctx)
+    it = mx.io.NDArrayIter(x, y, batch_size=16)
+    # absent from bind on, not only after the fused step was built
+    mod.bind(it.provide_data, it.provide_label)
+    assert _released(mod)
+    mod.init_params(mx.init.Uniform(0.1))
+    assert _released(mod)
+    mod.init_optimizer(kvstore="device",
+                       optimizer_params={"learning_rate": 0.5})
+    assert mod._fused_trainer is not None and _released(mod)
+    # an eval between two fused updates finds the weights the first left
+    batches = list(it)
+    it.reset()
+    mod.forward(batches[0], is_train=True)
+    mod.update()
+    after_one = {n: v.asnumpy() for n, v in mod.get_params()[0].items()}
+    assert _released(mod)
+    mod.forward(batches[1], is_train=False)
+    for exe, c in zip(mod._exec_group.execs, ctx):
+        weight = exe.arg_dict["fc_weight"]
+        assert weight._data.device == c.jax_device
+        np.testing.assert_array_equal(weight.asnumpy(),
+                                      after_one["fc_weight"])
+    mod.forward(batches[1], is_train=True)
+    mod.update()
+    assert _released(mod)
+    assert not np.array_equal(mod.get_params()[0]["fc_weight"].asnumpy(),
+                              after_one["fc_weight"])
     released = []
     mod.fit(mx.io.NDArrayIter(x, y, batch_size=16), num_epoch=4,
             eval_data=mx.io.NDArrayIter(x, y, batch_size=16),

@@ -807,5 +807,5 @@ CONTRIB_OP_EXPORTS = (
     "ROIPooling", "CTCLoss", "ctc_loss", "fft", "ifft", "quantize",
     "dequantize", "count_sketch", "SwitchMoE",
     # ops/transformer.py
-    "RMSNorm", "RoPE", "Attention", "LatentAttention", "TopKMoE",
+    "RMSNorm", "RoPE", "Attention", "LatentAttention", "Mamba2", "TopKMoE",
 )

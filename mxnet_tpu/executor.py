@@ -123,15 +123,15 @@ _OP_CLASS = {
     "sigmoid": "act", "tanh": "act", "add_n": "act", "clip": "act",
     "MakeLoss": "loss", "softmax_cross_entropy": "loss",
     "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
-    "_contrib_LatentAttention": "attn",
+    "_contrib_LatentAttention": "attn", "_contrib_Mamba2": "ssm",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed",
 }
 
 
 def op_class(op_name):
-    """conv | fc | bn | pool | act | loss | attn | moe | norm | embed |
-    other: the class a node's device ops are filed under (the first part
+    """conv | fc | bn | pool | act | loss | attn | ssm | moe | norm |
+    embed | other: the class a node's device ops are filed under (the first part
     of its named scope)."""
     cls = _OP_CLASS.get(op_name)
     if cls is not None:

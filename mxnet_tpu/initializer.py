@@ -2,7 +2,9 @@
 
 Parity: reference ``python/mxnet/initializer.py`` (InitDesc, name-pattern
 dispatch, Uniform/Normal/Orthogonal/Xavier/MSRAPrelu/Bilinear/LSTMBias/
-Load/Mixed/Constant).
+Load/Mixed/Constant). Beyond it: ``LogOfUniform`` and
+``InverseSoftplus``, the two rules a state-space layer's dynamics are
+initialised by.
 """
 from __future__ import annotations
 
@@ -193,6 +195,44 @@ class Uniform(Initializer):
         arr[:] = np.random.uniform(-self.scale, self.scale, arr.shape).astype(
             np.float32
         )
+
+
+@register
+class LogOfUniform(Initializer):
+    """``log(u)``, ``u`` uniform in [low, high]: a state-space layer's
+    ``A_log``, whose ``-exp(.)`` is a head's decay rate (the published
+    Mamba-2 rule is ``log(U(1, 16))``)."""
+
+    def __init__(self, low=1.0, high=16.0):
+        super().__init__(low=low, high=high)
+        self.low, self.high = low, high
+
+    def _init_weight(self, _, arr):
+        arr[:] = np.log(np.random.uniform(
+            self.low, self.high, arr.shape)).astype(np.float32)
+
+    _init_default = _init_weight
+
+
+@register
+class InverseSoftplus(Initializer):
+    """``softplus^-1(v) = v + log(1 - exp(-v))``, ``v`` log-uniform in
+    [low, high] and not under ``floor``: a state-space layer's
+    ``dt_bias``, so that the step size ``softplus(dt_bias)`` starts
+    log-uniform (the published Mamba-2 rule: ``time_step_min`` 0.001,
+    ``time_step_max`` 0.1, ``time_step_floor`` 1e-4)."""
+
+    def __init__(self, low=0.001, high=0.1, floor=1e-4):
+        super().__init__(low=low, high=high, floor=floor)
+        self.low, self.high, self.floor = low, high, floor
+
+    def _init_weight(self, _, arr):
+        v = np.exp(np.random.uniform(
+            np.log(self.low), np.log(self.high), arr.shape))
+        v = np.maximum(v, self.floor)
+        arr[:] = (v + np.log(-np.expm1(-v))).astype(np.float32)
+
+    _init_default = _init_weight
 
 
 @register

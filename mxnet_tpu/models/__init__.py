@@ -13,8 +13,10 @@ heads, 256 sigmoid-routed experts) from the same ops, whole or as one
 chip's share of its layers, with ``mimo_v2_reference`` beside it.
 ``kanana2`` is Kanana-2-30B-A3B (latent attention, shared experts beside
 128 sigmoid-routed ones), whole or as a share, with
-``kanana2_reference``; ``lm_blocks`` holds what the three LM symbols
-share.
+``kanana2_reference``. ``nemotron_h`` is Nemotron-3-Nano-30B-A3B
+(Mamba-2 state-space layers, un-gated relu² experts and NoPE grouped
+attention in one-mixer blocks), whole or as a share, with
+``nemotron_h_reference``; ``lm_blocks`` holds what the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -28,5 +30,5 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
-from . import (kanana2, kanana2_reference, mimo_v2, mimo_v2_reference, olmoe,
-               olmoe_reference)
+from . import (kanana2, kanana2_reference, mimo_v2, mimo_v2_reference,
+               nemotron_h, nemotron_h_reference, olmoe, olmoe_reference)

@@ -1,7 +1,8 @@
 """The pieces the decoder-only LM symbols share (``mimo_v2``,
-``kanana2``; the tail also ``olmoe``): a bias-free projection, the dense
-SwiGLU feed-forward, the routed expert layer's call and the head with
-its loss. Each takes the node-name prefix of its layer, so a model's
+``kanana2``, ``nemotron_h``; the tail also ``olmoe``): a bias-free
+projection, the dense SwiGLU and the un-gated relu² feed-forward, the
+one-mixer residual block, the routed expert layer's call and the head
+with its loss. Each takes the node-name prefix of its layer, so a model's
 argument and scope names are its own."""
 from .. import initializer as init
 from .. import symbol as sym
@@ -20,6 +21,21 @@ def swiglu(x, prefix, width, hidden_size):
                           act_type="silu")
     return linear(gate * linear(x, prefix + "up_proj", width),
                   prefix + "down_proj", hidden_size)
+
+
+def relu2_mlp(x, prefix, width, hidden_size):
+    """``<prefix>down_proj(relu(<prefix>up_proj(x))^2)`` at ``width``
+    columns: the un-gated feed-forward."""
+    up = sym.Activation(linear(x, prefix + "up_proj", width),
+                        act_type="relu")
+    return linear(sym.square(up), prefix + "down_proj", hidden_size)
+
+
+def mixer_block(h, prefix, rms_eps, mixer):
+    """``h + mixer(RMSNorm(h), prefix)``, the norm named
+    ``<prefix>norm``: a block of one norm and one mixer."""
+    return h + mixer(csym.RMSNorm(h, eps=rms_eps, name=prefix + "norm"),
+                     prefix)
 
 
 def expert_layer(x, prefix, **attrs):

@@ -1,5 +1,5 @@
 """Transformer-block operators for the Symbol API: RMSNorm, RoPE,
-Attention, LatentAttention and TopKMoE.
+Attention, LatentAttention, Mamba2 and TopKMoE.
 
 Beyond-reference capability (the 2017 operator set has no attention and
 no sparse-expert layer): what a decoder-only LM with sparse experts
@@ -10,7 +10,9 @@ attention dispatch ``ops/pallas_kernels.attention`` (flash kernel on the
 TPU at T >= 128, the materialised reference elsewhere;
 ``LatentAttention`` projects its keys and values up from a latent first
 and calls the same dispatch), ``TopKMoE`` over
-``parallel/moe.topk_moe``. Exported as ``mx.contrib.sym`` /
+``parallel/moe.topk_moe``; ``Mamba2`` (a state-space mixer's core: the
+convolution, the chunked scan and the gated norm) is ``jax.numpy`` here,
+with no kernel behind it. Exported as ``mx.contrib.sym`` /
 ``mx.contrib.nd`` functions through ``contrib.ops.CONTRIB_OP_EXPORTS``.
 
 Layout: activations are ``[batch, time, heads * head_dim]`` between ops
@@ -18,6 +20,8 @@ Layout: activations are ``[batch, time, heads * head_dim]`` between ops
 takes ``[tokens, d_model]``.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -316,7 +320,182 @@ register(
 
 
 # --------------------------------------------------------------------------
-# TopKMoE — dropless top-k sparse-expert SwiGLU FFN
+# Mamba2 — the state-space mixer's core between its two projections
+# (Mamba-2 / SSD, Dao & Gu, arXiv:2405.21060)
+# --------------------------------------------------------------------------
+_M_SCAN_LOWERINGS = _tm.counter(
+    "ssm.scan_lowerings", "Traces of a Mamba2 call site (one per "
+    "lowering, nothing per step); labels: heads, head_dim, state, groups, "
+    "chunk, conv (the convolution's taps)")
+
+
+def ssd_scan(x, bmat, cmat, dt, a, chunk):
+    """The state-space recurrence ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t
+    B_t^T``, ``y_t = S_t C_t`` (``S`` [H, P, N], zero before the first
+    token) in its chunked (SSD) form. x [B, T, H, P], bmat and cmat
+    [B, T, G, N] (head h reads group ``h // (H / G)``), dt [B, T, H]
+    float32 and positive, a [H] float32 and negative -> y [B, T, H, P]
+    float32.
+
+    Inside a chunk of ``chunk`` tokens the masked ``(C B^T) * decay``
+    product against ``dt x``; a chunk's end state; the recurrence over
+    the chunks (a ``lax.scan``, the state entering each chunk kept); and
+    the carried state read through ``C``. Log decays, their running sums
+    and the carried state are float32; the four products take operands of
+    ``x``'s dtype and accumulate in float32. T is padded to whole chunks
+    with ``dt`` 0 (no decay, no input) and the padding cut off."""
+    f32 = jnp.float32
+    b, t, h, p = x.shape
+    g, n = bmat.shape[2:]
+    e = h // g                                    # heads a group
+    pad = -t % chunk
+    if pad:
+        x, bmat, cmat, dt = (
+            jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+            for v in (x, bmat, cmat, dt))
+    nc = (t + pad) // chunk
+    x = x.reshape(b, nc, chunk, g, e, p)
+    bmat = bmat.reshape(b, nc, chunk, g, n)
+    cmat = cmat.reshape(b, nc, chunk, g, n)
+    dt = dt.reshape(b, nc, chunk, g, e)
+    cum = jnp.cumsum(dt * a.reshape(g, e), axis=2)    # log decay to here
+    total = cum[:, :, -1]                             # [B, nc, G, E]
+    x32 = x.astype(f32)
+
+    def dot(spec, lhs, rhs):
+        return jnp.einsum(spec, lhs, rhs, preferred_element_type=f32)
+
+    # inside a chunk: token i reads token j <= i through C_i . B_j,
+    # decayed by exp(cum_i - cum_j)
+    cb = dot("bcign,bcjgn->bcgij", cmat, bmat)
+    at = jnp.moveaxis(cum, 2, -1)                     # [B, nc, G, E, Q]
+    causal = np.tril(np.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, at[..., :, None] - at[..., None, :],
+                              -jnp.inf))
+    mixed = (cb[:, :, :, None] * decay).astype(x.dtype)
+    y = dot("bcgeij,bcjgep->bcigep", mixed,
+            (x32 * dt[..., None]).astype(x.dtype))
+    # a chunk's end state, had it started from zero
+    to_end = jnp.exp(total[:, :, None] - cum) * dt
+    states = dot("bcjgep,bcjgn->cbgepn",
+                 (x32 * to_end[..., None]).astype(x.dtype), bmat)
+
+    def carry(state, chunk_in):
+        ended, decayed = chunk_in
+        return state * jnp.exp(decayed)[..., None, None] + ended, state
+
+    _, entering = jax.lax.scan(
+        carry, jnp.zeros(states.shape[1:], f32),
+        (states, jnp.moveaxis(total, 1, 0)))
+    # the state a chunk entered with, read through C and decayed to here
+    y = y + dot("bcign,cbgepn->bcigep", cmat,
+                entering.astype(x.dtype)) * jnp.exp(cum)[..., None]
+    return y.reshape(b, t + pad, h, p)[:, :t]
+
+
+def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
+           num_heads, head_dim, state_size, num_groups, chunk_size, eps):
+    """proj [B, T, 2 H P + 2 G N + H] (``in_proj``'s output: the gate
+    ``z``, then ``x | B | C``, then a step size a head), conv_weight
+    [taps, H P + 2 G N] (tap ``taps - 1`` meets the current token),
+    conv_bias [H P + 2 G N], dt_bias, a_log and d_skip [H], norm_gamma
+    [H P] -> [B, T, H P] (``out_proj``'s input).
+
+    ``x | B | C = silu(conv(.))``, a causal depthwise convolution over
+    time as ``taps`` shifted multiply-adds (scope ``conv1d``: ``conv`` is
+    the class of the ``Convolution`` nodes in a trace); ``dt =
+    softplus(dt + dt_bias)``, ``a = -exp(a_log)``, ``y = ssd_scan(...) +
+    d_skip x`` (scope ``scan``); ``RMSNorm(y * silu(z))`` with the
+    statistics over each of the G groups of columns, times ``norm_gamma``
+    (scope ``gate_norm``: the gate first, then the norm). The
+    convolution's sum, step sizes, decays, the carried state, the gate
+    and the norm's statistics are float32 whatever ``proj``'s dtype."""
+    f32 = jnp.float32
+    b, t, _ = proj.shape
+    h, p, n, g = num_heads, head_dim, state_size, num_groups
+    d_in, taps = h * p, conv_weight.shape[0]
+    conv_dim = d_in + 2 * g * n
+    _M_SCAN_LOWERINGS.inc(heads=h, head_dim=p, state=n, groups=g,
+                          chunk=chunk_size, conv=taps)
+    with jax.named_scope("conv1d"):
+        padded = jnp.pad(proj[..., d_in:d_in + conv_dim],
+                         ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
+        w = conv_weight.astype(f32)
+        acc = conv_bias.astype(f32)
+        for j in range(taps):
+            acc = acc + padded[:, j:j + t] * w[j]
+        xbc = jax.nn.silu(acc).astype(proj.dtype)
+    with jax.named_scope("scan"):
+        x = xbc[..., :d_in].reshape(b, t, h, p)
+        dt = jax.nn.softplus(proj[..., d_in + conv_dim:].astype(f32)
+                             + dt_bias.astype(f32))
+        y = ssd_scan(x, xbc[..., d_in:d_in + g * n].reshape(b, t, g, n),
+                     xbc[..., d_in + g * n:].reshape(b, t, g, n), dt,
+                     -jnp.exp(a_log.astype(f32)), chunk_size)
+        y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
+    with jax.named_scope("gate_norm"):
+        gated = (y.reshape(b, t, d_in)
+                 * jax.nn.silu(proj[..., :d_in].astype(f32)))
+        groups = gated.reshape(b, t, g, d_in // g)
+        var = jnp.mean(jnp.square(groups), axis=-1, keepdims=True)
+        normed = (groups * jax.lax.rsqrt(var + eps)).reshape(b, t, d_in)
+        return norm_gamma.astype(proj.dtype) * normed.astype(proj.dtype)
+
+
+def _mamba2_sizes(attrs):
+    return tuple(int(attrs[k]) for k in (
+        "num_heads", "head_dim", "state_size", "num_groups"))
+
+
+def _mamba2(attrs, ins, is_train):
+    h, p, n, g = _mamba2_sizes(attrs)
+    core = functools.partial(
+        mamba2, num_heads=h, head_dim=p, state_size=n, num_groups=g,
+        chunk_size=int(attrs["chunk_size"]),
+        eps=float(attrs.get("eps", 1e-5)))
+    if is_train:
+        core = jax.checkpoint(
+            core, policy=jax.checkpoint_policies.dots_saveable)
+    return [core(*ins)]
+
+
+def _mamba2_infer(attrs, in_shapes):
+    h, p, n, g = _mamba2_sizes(attrs)
+    taps, chunk = int(attrs["conv_kernel"]), int(attrs["chunk_size"])
+    if min(h, p, n, g, taps, chunk) <= 0 or h % g:
+        raise ValueError(
+            "Mamba2: num_heads=%d, head_dim=%d, state_size=%d, "
+            "num_groups=%d, conv_kernel=%d and chunk_size=%d must be "
+            "positive and the groups divide the heads"
+            % (h, p, n, g, taps, chunk))
+    data = _known(in_shapes[0], "Mamba2")
+    d_in, conv_dim = h * p, h * p + 2 * g * n
+    if len(data) != 3 or data[2] != d_in + conv_dim + h:
+        raise ValueError(
+            "Mamba2: data must be [batch, time, %d] (z %d | x B C %d | "
+            "dt %d), got %s" % (d_in + conv_dim + h, d_in, conv_dim, h,
+                                data))
+    return ([data, (taps, conv_dim), (conv_dim,), (h,), (h,), (h,),
+             (d_in,)], [data[:2] + (d_in,)], [])
+
+
+register(
+    OpDef(
+        "_contrib_Mamba2",
+        _mamba2,
+        arguments=("data", "conv_weight", "conv_bias", "dt_bias", "a_log",
+                   "d", "norm_gamma"),
+        defaults={"num_heads": 1, "head_dim": 0, "state_size": 0,
+                  "num_groups": 1, "conv_kernel": 4, "chunk_size": 128,
+                  "eps": 1e-5},
+        infer_shape=_mamba2_infer,
+        aliases=("Mamba2",),
+    )
+)
+
+
+# --------------------------------------------------------------------------
+# TopKMoE — dropless top-k sparse-expert FFN
 # --------------------------------------------------------------------------
 def _topk_moe(attrs, ins, is_train):
     """``parallel/moe.topk_moe`` as a Symbol op. Two outputs: the routed
@@ -335,6 +514,7 @@ def _topk_moe(attrs, ins, is_train):
         norm_topk_prob=bool(attrs.get("norm_topk_prob", False)),
         scoring=str(attrs.get("scoring", "softmax")),
         routed_scale=float(attrs.get("routed_scale", 1.0)),
+        activation=str(attrs.get("activation", "swiglu")),
         expert_offset=int(attrs.get("expert_offset", 0)),
         share_rows_bound=int(attrs.get("share_rows_bound", 0)))
     return [y, counts.astype(jnp.float32)]
@@ -359,6 +539,10 @@ def _topk_moe_infer(attrs, in_shapes):
     if str(attrs.get("scoring", "softmax")) not in ("softmax", "sigmoid"):
         raise ValueError("TopKMoE: scoring must be softmax or sigmoid, "
                          "got %r" % (attrs["scoring"],))
+    activation = str(attrs.get("activation", "swiglu"))
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError("TopKMoE: activation must be swiglu or relu2, "
+                         "got %r" % (activation,))
     if not (0 < held <= num_experts and 0 <= offset <= num_experts - held):
         raise ValueError(
             "TopKMoE: experts_held=%d from expert_offset=%d are not among "
@@ -370,7 +554,9 @@ def _topk_moe_infer(attrs, in_shapes):
             "share_rows_bound in 1..tokens * top_k (%d), got %s"
             % (held, num_experts, data[0] * top_k,
                attrs.get("share_rows_bound", 0)))
-    ins = [data, (d_model, num_experts), (held, d_model, 2 * hidden),
+    # un-gated, ``gate_up_weight`` is the up projection alone
+    up = hidden if activation == "relu2" else 2 * hidden
+    ins = [data, (d_model, num_experts), (held, d_model, up),
            (held, hidden, d_model)]
     return (ins + [(num_experts,)] * (len(in_shapes) - 4),
             [data, (num_experts,)], [])
@@ -393,7 +579,8 @@ _moe = OpDef(
     outputs=("output", "expert_count"),
     defaults={"num_experts": 8, "num_hidden": 0, "top_k": 2,
               "norm_topk_prob": False, "scoring": "softmax",
-              "routed_scale": 1.0, "with_select_bias": False,
+              "routed_scale": 1.0, "activation": "swiglu",
+              "with_select_bias": False,
               "experts_held": 0,
               "expert_offset": 0,
               "share_rows_bound": 0},

@@ -124,6 +124,26 @@ def _expert_dot(counts, rows, dtype):
                              metadata=metadata, off_tpu=_EXPERTS_OFF_TPU)
 
 
+def _experts(params, rows, counts, activation):
+    """The experts' feed-forward over ``rows`` sorted into ``counts``
+    groups. ``swiglu``: ``down(silu(gate) * up)``, gate and up one
+    product over ``w_gate_up`` [E, d, 2h]; ``relu2``: ``down(relu(up)^2)``
+    with ``w_gate_up`` [E, d, h] the up projection alone, two grouped
+    products a pass where SwiGLU does three."""
+    dtype = rows.dtype
+    hidden = params["w_down"].shape[1]
+    dot = _expert_dot(counts, rows.shape[0], dtype)
+    up = dot(rows, params["w_gate_up"].astype(dtype))
+    if activation == "relu2":
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
+    elif activation == "swiglu":
+        act = jax.nn.silu(up[:, :hidden]) * up[:, hidden:]
+    else:
+        raise ValueError("topk_moe: activation must be swiglu or relu2, "
+                         "got %r" % (activation,))
+    return dot(act, params["w_down"].astype(dtype))
+
+
 _M_PERMUTE_LOWERINGS = _tm.counter(
     "moe.permute_lowerings", "Traces of a topk_moe call site that moves "
     "its rows into expert order and back by the permutation pair (one "
@@ -250,14 +270,18 @@ def _route(params, x, top_k, norm_topk_prob, scoring, routed_scale=1.0):
 
 
 def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
-             expert_offset=0, share_rows_bound=0, routed_scale=1.0):
+             expert_offset=0, share_rows_bound=0, routed_scale=1.0,
+             activation="swiglu"):
     """Dropless top-k MoE FFN with SwiGLU experts (the OLMoE / Mixtral
-    block). x: [tokens, d_model] -> ([tokens, d_model], counts [E]).
+    block) or, ``activation="relu2"``, un-gated ``relu(.)^2`` ones
+    (Nemotron-H). x: [tokens, d_model] -> ([tokens, d_model], counts
+    [E]).
 
     ``params``: ``gate_w`` [d, E] (router), ``w_gate_up`` [E, d, 2h]
     (each expert's gate projection in the first ``h`` columns, its up
-    projection in the last), ``w_down`` [E, h, d]. Expert tensors lead
-    with E, so ``moe_partition_specs`` applies to them too.
+    projection in the last; un-gated, [E, d, h]: the up projection
+    alone), ``w_down`` [E, h, d]. Expert tensors lead with E, so
+    ``moe_partition_specs`` applies to them too.
 
     No capacity: every (token, expert) pair of the routing is computed,
     whatever the load. The ``tokens * top_k`` rows are sorted by expert
@@ -293,18 +317,20 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     """
     tokens = x.shape[0]
     num_experts = params["gate_w"].shape[1]
-    held, hidden = params["w_down"].shape[:2]
+    held = params["w_down"].shape[0]
 
     with jax.named_scope("router"):
         weights, experts = _route(params, x, top_k, norm_topk_prob,
                                   scoring, routed_scale)
 
     if held < num_experts:
-        scale = {} if routed_scale == 1.0 else {"scale": routed_scale}
+        labels = {} if routed_scale == 1.0 else {"scale": routed_scale}
+        if activation != "swiglu":
+            labels["act"] = activation
         _M_SHARE_LOWERINGS.inc(held=held, of=num_experts,
-                               bound=share_rows_bound, **scale)
+                               bound=share_rows_bound, **labels)
         return _topk_moe_share(params, x, weights, experts, expert_offset,
-                               share_rows_bound)
+                               share_rows_bound, activation)
 
     with jax.named_scope("dispatch"):
         flat_expert = experts.reshape(-1)                     # [T*k]
@@ -321,24 +347,21 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
         rows = _dispatch(x, order, inverse)                   # [T*k, d]
 
     with jax.named_scope("experts"):
-        dot = _expert_dot(counts, rows.shape[0], x.dtype)
-        gate_up = dot(rows, params["w_gate_up"].astype(x.dtype))
-        act = jax.nn.silu(gate_up[:, :hidden]) * gate_up[:, hidden:]
-        out_rows = dot(act, params["w_down"].astype(x.dtype))  # [T*k, d]
+        out_rows = _experts(params, rows, counts, activation)  # [T*k, d]
 
     with jax.named_scope("combine"):
         y = _combine(out_rows, weights, order, inverse)
     return y, jax.lax.stop_gradient(counts)
 
 
-def _topk_moe_share(params, x, weights, experts, offset, bound):
+def _topk_moe_share(params, x, weights, experts, offset, bound, activation):
     """``topk_moe`` where the layer holds experts ``offset`` ..
     ``offset + H - 1`` of the E it routes over: their part of the
     result, over a buffer of ``bound`` rows."""
     tokens, d_model = x.shape
     top_k = experts.shape[1]
     num_experts = params["gate_w"].shape[1]
-    held, hidden = params["w_down"].shape[:2]
+    held = params["w_down"].shape[0]
     if not 0 < bound <= tokens * top_k:
         raise ValueError(
             "topk_moe: a share needs share_rows_bound in 1..tokens * "
@@ -376,10 +399,7 @@ def _topk_moe_share(params, x, weights, experts, offset, bound):
         rows = jnp.where(used[:, None], jnp.take(x, token, axis=0), 0)
 
     with jax.named_scope("experts"):
-        dot = _expert_dot(sizes, bound, x.dtype)
-        gate_up = dot(rows, params["w_gate_up"].astype(x.dtype))
-        act = jax.nn.silu(gate_up[:, :hidden]) * gate_up[:, hidden:]
-        out_rows = dot(act, params["w_down"].astype(x.dtype))  # [bound, d]
+        out_rows = _experts(params, rows, sizes, activation)  # [bound, d]
 
     with jax.named_scope("combine"):
         weight = jnp.where(used, jnp.take(

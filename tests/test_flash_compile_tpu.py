@@ -150,14 +150,19 @@ def test_one_pass_backward_compiles_under_its_stated_limit(one_chip, cell):
 
 # the OLMoE cell's two expert products (gate/up, down); a float32 caller
 # at the widest result tile its budget admits; the MiMo share cell's two
-# (8 held experts over a buffer of 2,048 rows)
+# (8 held experts over a buffer of 2,048 rows); the Nemotron-3-Nano
+# share cell's two (un-gated experts of 1,856 = 14.5 x 128 columns, which
+# no multiple of 128 divides: every product holds the whole 1,856 in one
+# block) at the rows 8 and 16 held experts expect and at their buffers
 GMM_SHAPES = [
     (32768, 2048, 2048, 64, jnp.bfloat16),
     (32768, 1024, 2048, 64, jnp.bfloat16),
     (8192, 2048, 1024, 16, jnp.float32),
     (2048, 4096, 4096, 8, jnp.bfloat16),
     (2048, 2048, 4096, 8, jnp.bfloat16),
-]
+] + [(m, k, n, groups, jnp.bfloat16)
+     for m, groups in ((3072, 8), (6144, 8), (6144, 16), (12288, 16))
+     for k, n in ((2688, 1856), (1856, 2688))]
 
 
 @pytest.mark.parametrize("m,k,n,groups,dtype", GMM_SHAPES)

@@ -326,7 +326,7 @@ register(
 _M_SCAN_LOWERINGS = _tm.counter(
     "ssm.scan_lowerings", "Traces of a Mamba2 call site (one per "
     "lowering, nothing per step); labels: heads, head_dim, state, groups, "
-    "chunk, conv (the convolution's taps)")
+    "chunk, conv (the convolution's taps), impl (kernel / einsum)")
 
 
 def ssd_scan(x, bmat, cmat, dt, a, chunk):
@@ -343,7 +343,9 @@ def ssd_scan(x, bmat, cmat, dt, a, chunk):
     the carried state read through ``C``. Log decays, their running sums
     and the carried state are float32; the four products take operands of
     ``x``'s dtype and accumulate in float32. T is padded to whole chunks
-    with ``dt`` 0 (no decay, no input) and the padding cut off."""
+    with ``dt`` 0 (no decay, no input) and the padding cut off. The form
+    for the shapes ``pallas_kernels.ssd_scan`` has no tiles for, and what
+    its tests hold it to."""
     f32 = jnp.float32
     b, t, h, p = x.shape
     g, n = bmat.shape[2:]
@@ -394,7 +396,8 @@ def ssd_scan(x, bmat, cmat, dt, a, chunk):
 
 
 def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
-           num_heads, head_dim, state_size, num_groups, chunk_size, eps):
+           num_heads, head_dim, state_size, num_groups, chunk_size, eps,
+           remat=False):
     """proj [B, T, 2 H P + 2 G N + H] (``in_proj``'s output: the gate
     ``z``, then ``x | B | C``, then a step size a head), conv_weight
     [taps, H P + 2 G N] (tap ``taps - 1`` meets the current token),
@@ -409,37 +412,87 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     statistics over each of the G groups of columns, times ``norm_gamma``
     (scope ``gate_norm``: the gate first, then the norm). The
     convolution's sum, step sizes, decays, the carried state, the gate
-    and the norm's statistics are float32 whatever ``proj``'s dtype."""
+    and the norm's statistics are float32 whatever ``proj``'s dtype. The
+    scan is the Pallas kernel pair where the shapes have tiles for it and
+    the step is lowered for the TPU (``pallas_kernels.ssd_takes`` /
+    ``ssd_scan``; the skip inside it), the ``jnp.einsum`` form elsewhere.
+    ``remat`` (training): the float32 tables of the convolution, of the
+    gate and norm and of the einsum form are computed again in the
+    backward pass, not kept (``jax.checkpoint`` round each; plain
+    autodiff kept 9.6 GB of them at the Nemotron cell's shape); the
+    kernel pair keeps its own residuals (its output and the states) and
+    runs once each way.
+
+    The call site counts itself here (``ssm.scan_lowerings``); the block
+    itself is ``_mamba2_block``, one ``jax.jit`` for every node of one
+    signature: a model's layers trace, differentiate and lower it once
+    (XLA inlines the calls, each under its own node's scope)."""
+    from . import pallas_kernels
+
+    kernel = bool(pallas_kernels.ssd_takes(
+        num_heads, head_dim, state_size, num_groups, chunk_size, proj.dtype))
+    _M_SCAN_LOWERINGS.inc(heads=num_heads, head_dim=head_dim,
+                          state=state_size, groups=num_groups,
+                          chunk=chunk_size, conv=conv_weight.shape[0],
+                          impl="kernel" if kernel else "einsum")
+    return _mamba2_block(
+        proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
+        sizes=(num_heads, head_dim, state_size, num_groups, chunk_size),
+        eps=float(eps), remat=bool(remat), kernel=kernel)
+
+
+@functools.partial(jax.jit, static_argnames=("sizes", "eps", "remat",
+                                             "kernel"))
+def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
+                  norm_gamma, *, sizes, eps, remat, kernel):
+    """``mamba2`` for one signature (``sizes``: heads, head width, state,
+    groups, chunk)."""
+    from . import pallas_kernels
+
     f32 = jnp.float32
     b, t, _ = proj.shape
-    h, p, n, g = num_heads, head_dim, state_size, num_groups
+    h, p, n, g, chunk = sizes
     d_in, taps = h * p, conv_weight.shape[0]
     conv_dim = d_in + 2 * g * n
-    _M_SCAN_LOWERINGS.inc(heads=h, head_dim=p, state=n, groups=g,
-                          chunk=chunk_size, conv=taps)
-    with jax.named_scope("conv1d"):
+
+    def again(f, **policy):
+        return jax.checkpoint(f, **policy) if remat else f
+
+    def conv1d(proj, conv_weight, conv_bias):
         padded = jnp.pad(proj[..., d_in:d_in + conv_dim],
                          ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
         w = conv_weight.astype(f32)
         acc = conv_bias.astype(f32)
         for j in range(taps):
             acc = acc + padded[:, j:j + t] * w[j]
-        xbc = jax.nn.silu(acc).astype(proj.dtype)
-    with jax.named_scope("scan"):
-        x = xbc[..., :d_in].reshape(b, t, h, p)
-        dt = jax.nn.softplus(proj[..., d_in + conv_dim:].astype(f32)
-                             + dt_bias.astype(f32))
-        y = ssd_scan(x, xbc[..., d_in:d_in + g * n].reshape(b, t, g, n),
-                     xbc[..., d_in + g * n:].reshape(b, t, g, n), dt,
-                     -jnp.exp(a_log.astype(f32)), chunk_size)
-        y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
-    with jax.named_scope("gate_norm"):
+        return jax.nn.silu(acc).astype(proj.dtype)
+
+    def gate_norm(y, proj, norm_gamma):
         gated = (y.reshape(b, t, d_in)
                  * jax.nn.silu(proj[..., :d_in].astype(f32)))
         groups = gated.reshape(b, t, g, d_in // g)
         var = jnp.mean(jnp.square(groups), axis=-1, keepdims=True)
         normed = (groups * jax.lax.rsqrt(var + eps)).reshape(b, t, d_in)
         return norm_gamma.astype(proj.dtype) * normed.astype(proj.dtype)
+
+    with jax.named_scope("conv1d"):
+        xbc = again(conv1d)(proj, conv_weight, conv_bias)
+    with jax.named_scope("scan"):
+        x = xbc[..., :d_in].reshape(b, t, h, p)
+        bc = (xbc[..., d_in:d_in + g * n].reshape(b, t, g, n),
+              xbc[..., d_in + g * n:].reshape(b, t, g, n))
+        dt = jax.nn.softplus(proj[..., d_in + conv_dim:].astype(f32)
+                             + dt_bias.astype(f32))
+        a = -jnp.exp(a_log.astype(f32))
+        if kernel:
+            y = pallas_kernels.ssd_scan(x, *bc, dt, a, d_skip, chunk)
+        else:
+            y = again(functools.partial(ssd_scan, chunk=chunk),
+                      policy=jax.checkpoint_policies.dots_saveable)(
+                          x, *bc, dt, a)
+            y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
+    with jax.named_scope("gate_norm"):
+        return again(gate_norm)(y, proj, norm_gamma)
 
 
 def _mamba2_sizes(attrs):
@@ -449,14 +502,9 @@ def _mamba2_sizes(attrs):
 
 def _mamba2(attrs, ins, is_train):
     h, p, n, g = _mamba2_sizes(attrs)
-    core = functools.partial(
-        mamba2, num_heads=h, head_dim=p, state_size=n, num_groups=g,
-        chunk_size=int(attrs["chunk_size"]),
-        eps=float(attrs.get("eps", 1e-5)))
-    if is_train:
-        core = jax.checkpoint(
-            core, policy=jax.checkpoint_policies.dots_saveable)
-    return [core(*ins)]
+    return [mamba2(*ins, num_heads=h, head_dim=p, state_size=n, num_groups=g,
+                   chunk_size=int(attrs["chunk_size"]),
+                   eps=float(attrs.get("eps", 1e-5)), remat=is_train)]
 
 
 def _mamba2_infer(attrs, in_shapes):

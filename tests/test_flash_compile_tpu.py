@@ -222,3 +222,44 @@ def test_expert_layer_moves_rows_by_gathers_on_v5e(one_chip):
     gathered = re.findall(r"= (\S+?)\{[^ ]*\} gather\(", text)
     assert gathered.count("bf16[%d,%d]" % (tokens * top_k, d)) == 4
     assert "s64[%d]" % (tokens * top_k) not in text
+
+
+# the Nemotron-3-Nano cell's scan (one sequence of 8,192 tokens, 64 heads
+# of 64 on 8 groups, state 128, chunks of 128, bf16); a float32 caller
+# whose heads are whole lane rows, in chunks of 256 over a ragged length
+SSD_SHAPES = [
+    (8192, 64, 64, 8, 128, 128, jnp.bfloat16),
+    (1000, 4, 128, 2, 256, 256, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("t,heads,p,groups,n,chunk,dtype", SSD_SHAPES)
+def test_ssd_scan_kernels_compile_for_v5e(one_chip, t, heads, p, groups, n,
+                                          chunk, dtype):
+    """The scan's forward and backward kernels at real widths: Mosaic
+    takes every slice and product of both bodies and a step's working set
+    is under the scoped VMEM the calls state."""
+    assert pk.ssd_takes(heads, p, n, groups, chunk, dtype)
+
+    def spec(*shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*ins):
+        return jnp.sum(pk.ssd_scan(*ins, chunk))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        spec(1, t, heads, p), spec(1, t, groups, n), spec(1, t, groups, n),
+        spec(1, t, heads, dtype=jnp.float32), spec(heads, dtype=jnp.float32),
+        spec(heads, dtype=jnp.float32)).compile().as_text()
+    operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    for which in ("fwd", "bwd"):
+        name = "ssd_%s_%s_q%d_p%d_n%d" % (which, operands, chunk, p, n)
+        calls = [line for line in text.splitlines()
+                 if name in line and "custom-call(" in line]
+        assert len(calls) == 1, name
+        limit, used = (
+            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"0",'
+                          r'"size":"(\d+)"' % key, calls[0]).group(1))
+            for key in ("scoped_memory_configs",
+                        "used_scoped_memory_configs"))
+        assert used <= limit <= pk._SSD_VMEM_LIMIT, (name, used, limit)

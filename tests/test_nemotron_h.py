@@ -81,14 +81,14 @@ def _dynamics(rng, heads):
             (dt + np.log(-np.expm1(-dt))).astype(np.float32))
 
 
-def _params(sym, seed, sigma=0.08):
+def _params(sym, seed, sigma=0.08, data=(BATCH, T)):
     """Seeded weights under the symbol's argument names: Normal(sigma),
     a unit embedding as the model states it, gammas and skips near 1,
     taps of the published spread, ``a_log`` and ``dt_bias`` by the
     published rule, selection biases away from 0 (so that their part is
     tested)."""
     rng = np.random.RandomState(seed)
-    shapes, _, _ = sym.infer_shape(data=(BATCH, T), softmax_label=(BATCH, T))
+    shapes, _, _ = sym.infer_shape(data=data, softmax_label=data)
     out = {}
     for name, shape in zip(sym.list_arguments(), shapes):
         if name in ("data", "softmax_label"):
@@ -223,8 +223,10 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
         mod.forward(batch, is_train=False)
         # one per layer's call site, nothing per step
         scan = telemetry.REGISTRY.get("ssm.scan_lowerings")
+        # state 16 in chunks of 8: no tiles for the kernel pair
         assert scan.value(heads=64, head_dim=P, state=N, groups=8,
-                          chunk=CHUNK, conv=TAPS) == MAMBA_LAYERS
+                          chunk=CHUNK, conv=TAPS,
+                          impl="einsum") == MAMBA_LAYERS
         share = telemetry.REGISTRY.get("moe.share_lowerings")
         assert share.value(held=4, of=16, bound=BATCH * T * 3, scale=2.5,
                            act="relu2") == EXPERT_LAYERS
@@ -256,6 +258,103 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
     kept = np.exp(-128 * rate * step)
     assert kept.max() > 0.1 and kept.min() < 1e-3, (kept.min(), kept.max())
     assert got["layer2_ssm_a_log"].tolist() != got["layer0_ssm_a_log"].tolist()
+
+
+def test_the_cells_widths_take_the_kernel_pair():
+    """Trace only, at the Nemotron cell's widths (64 heads of 64, state
+    128, 8 groups, chunks of 128, bf16): the call site counts itself
+    under ``impl="kernel"``, and the einsum form at any width the kernels
+    have no tiles for."""
+    heads, p, n, g, chunk = 64, 64, 128, 8, 128
+    conv_dim = heads * p + 2 * g * n
+
+    def trace(chunk, dtype):
+        spec = lambda *shape: jax.ShapeDtypeStruct(shape, dtype)
+        jax.eval_shape(
+            lambda *ins: mamba2(*ins, num_heads=heads, head_dim=p,
+                                state_size=n, num_groups=g,
+                                chunk_size=chunk, eps=1e-5),
+            spec(1, 2 * chunk, heads * p + conv_dim + heads),
+            spec(TAPS, conv_dim), spec(conv_dim), spec(heads), spec(heads),
+            spec(heads), spec(heads * p))
+
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        trace(chunk, jnp.bfloat16)
+        trace(chunk, jnp.float32)
+        trace(64, jnp.bfloat16)
+        trace(chunk, jnp.float16)
+        scan = telemetry.REGISTRY.get("ssm.scan_lowerings")
+        labels = dict(heads=heads, head_dim=p, state=n, groups=g, conv=TAPS)
+        assert scan.value(chunk=chunk, impl="kernel", **labels) == 2
+        assert scan.value(chunk=chunk, impl="einsum", **labels) == 1
+        assert scan.value(chunk=64, impl="einsum", **labels) == 1
+        assert telemetry.total("ssm.scan_lowerings") == 4
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+
+
+def test_three_blocks_of_one_shape_trace_the_block_and_the_kernels_once():
+    """Three ``Mamba2`` nodes of one shape the kernel pair takes (4 heads
+    of 32 in one lane row, state 128, chunks of 128) through the fused
+    step: each node counts its call site, the block behind them is one
+    ``jax.jit`` and each kernel's ``pallas_call`` is traced once, not once
+    a node nor once a branch; and the step (off the TPU: the einsum
+    branch inside the kernels' ``custom_vjp``) follows the reference."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops import transformer as tr
+
+    t, heads, p, n, chunk = 256, 4, 32, 128, 128
+    cfg = dict(CFG, num_hidden_layers=4, hybrid_override_pattern="MMME",
+               mamba_num_heads=heads, mamba_head_dim=p, ssm_state_size=n,
+               n_groups=1, chunk_size=chunk, max_position_embeddings=t)
+    assert pk.ssd_takes(heads, p, n, 1, chunk, jnp.float32)
+    sym = nemotron_h.from_config(cfg, seq_len=t)
+    params = _params(sym, 7, data=(1, t))
+    tokens = np.random.RandomState(8).randint(0, CFG["vocab_size"],
+                                              (1, t + 1))
+    tokens, labels = (tokens[:, :-1].astype(np.float32),
+                      tokens[:, 1:].astype(np.float32))
+    lr, momentum, steps = 0.05, 0.9, 3
+    want = {k: jnp.asarray(v) for k, v in params.items()}
+    moms = {k: jnp.zeros_like(v) for k, v in want.items()}
+    losses = []
+    for _ in range(2):
+        loss, grads = ref.loss_and_grads(want, tokens, labels, cfg)
+        losses.append(float(loss))
+        want, moms = ref.sgd_momentum_step(want, moms, grads, lr, momentum)
+
+    for jitted in (tr._mamba2_block, pk._ssd_fwd_call, pk._ssd_bwd_call):
+        jitted.clear_cache()    # another test's trace is not this one's
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        seen = []
+        mod = mx.mod.Module(sym, context=mx.cpu(0), mesh=make_mesh(dp=1))
+        mod.fit(mx.io.NDArrayIter(np.tile(tokens, (steps, 1)),
+                                  np.tile(labels, (steps, 1)), batch_size=1),
+                num_epoch=1, eval_metric="loss", optimizer="sgd",
+                optimizer_params={"learning_rate": lr, "momentum": momentum},
+                kvstore="device",
+                arg_params={k: mx.nd.array(v) for k, v in params.items()},
+                aux_params={}, initializer=None,
+                batch_end_callback=lambda b: (
+                    seen.append(b.eval_metric.get()[1]),
+                    b.eval_metric.reset()))
+        assert mod._fused_trainer is not None
+        scan = telemetry.REGISTRY.get("ssm.scan_lowerings")
+        assert scan.value(heads=heads, head_dim=p, state=n, groups=1,
+                          chunk=chunk, conv=TAPS, impl="kernel") == 3
+        assert telemetry.total("ssm.scan_lowerings") == 3
+        traces = telemetry.REGISTRY.get("ssm.scan_kernel_traces")
+        assert (traces.value(mode="fwd"), traces.value(mode="bwd")) == (1, 1)
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    _close(seen[:2], losses, "loss of the first two steps")
+    assert seen[-1] < seen[0], seen
 
 
 def test_from_config_refuses_what_it_does_not_implement():

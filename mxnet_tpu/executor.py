@@ -125,12 +125,12 @@ _OP_CLASS = {
     "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
     "_contrib_LatentAttention": "attn", "_contrib_Mamba2": "ssm",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
-    "Embedding": "embed",
+    "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
 }
 
 
 def op_class(op_name):
-    """conv | fc | bn | pool | act | loss | attn | ssm | moe | norm |
+    """conv | fc | bn | pool | act | loss | attn | ssm | gdn | moe | norm |
     embed | other: the class a node's device ops are filed under (the first part
     of its named scope)."""
     cls = _OP_CLASS.get(op_name)

@@ -16,7 +16,11 @@ chip's share of its layers, with ``mimo_v2_reference`` beside it.
 ``kanana2_reference``. ``nemotron_h`` is Nemotron-3-Nano-30B-A3B
 (Mamba-2 state-space layers, un-gated relu² experts and NoPE grouped
 attention in one-mixer blocks), whole or as a share, with
-``nemotron_h_reference``; ``lm_blocks`` holds what the LM symbols share.
+``nemotron_h_reference``. ``olmo_hybrid`` is Olmo-Hybrid-7B (gated
+delta-rule linear attention three to one beside full attention, dense
+SwiGLU, blocks that norm a sub-layer's output), whole or as a pipeline
+stage, with ``olmo_hybrid_reference``; ``lm_blocks`` holds what the LM
+symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -31,4 +35,5 @@ from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
 from . import (kanana2, kanana2_reference, mimo_v2, mimo_v2_reference,
-               nemotron_h, nemotron_h_reference, olmoe, olmoe_reference)
+               nemotron_h, nemotron_h_reference, olmo_hybrid,
+               olmo_hybrid_reference, olmoe, olmoe_reference)

@@ -1,8 +1,8 @@
 """The pieces the decoder-only LM symbols share (``mimo_v2``,
-``kanana2``, ``nemotron_h``; the tail also ``olmoe``): a bias-free
-projection, the dense SwiGLU and the un-gated relu² feed-forward, the
-one-mixer residual block, the routed expert layer's call and the head
-with its loss. Each takes the node-name prefix of its layer, so a model's
+``kanana2``, ``nemotron_h``, ``olmo_hybrid``; the tail also ``olmoe``): a
+bias-free projection, the dense SwiGLU and the un-gated relu² feed-forward,
+the one-mixer residual block and the block that norms a sub-layer's output,
+the routed expert layer's call and the head with its loss. Each takes the node-name prefix of its layer, so a model's
 argument and scope names are its own."""
 from .. import initializer as init
 from .. import symbol as sym
@@ -63,3 +63,11 @@ def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps):
                             name="lm_head_mean")
     loss = sym.MakeLoss(per_sequence, name="loss")
     return sym.Group([loss] + counts)
+
+
+def post_norm_block(h, prefix, norm, rms_eps, sublayer):
+    """``h + RMSNorm(sublayer(h, prefix))``, the norm named
+    ``<prefix><norm>``: the norm sits on the sub-layer's OUTPUT (the
+    Olmo 2 / Olmo 3 order); ``mixer_block`` norms its input."""
+    return h + csym.RMSNorm(sublayer(h, prefix), eps=rms_eps,
+                            name=prefix + norm)

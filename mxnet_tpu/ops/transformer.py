@@ -1,5 +1,5 @@
 """Transformer-block operators for the Symbol API: RMSNorm, RoPE,
-Attention, LatentAttention, Mamba2 and TopKMoE.
+Attention, LatentAttention, Mamba2, TopKMoE and GatedDeltaNet.
 
 Beyond-reference capability (the 2017 operator set has no attention and
 no sparse-expert layer): what a decoder-only LM with sparse experts
@@ -640,3 +640,224 @@ _moe.list_arguments = lambda attrs=None: (
     ["data", "gate_weight", "gate_up_weight", "down_weight"]
     + (["select_bias"] if (attrs or {}).get("with_select_bias") else []))
 register(_moe)
+
+
+# --------------------------------------------------------------------------
+# GatedDeltaNet — a linear-attention mixer's core between its projections:
+# the gated delta rule (Yang, Kautz & Hatamizadeh, arXiv:2412.06464; write
+# strengths up to 2, Grazzi et al., arXiv:2411.12537)
+# --------------------------------------------------------------------------
+_M_LINEAR_ATTN_LOWERINGS = _tm.counter(
+    "linear_attn.lowerings", "Traces of a GatedDeltaNet call site (one per "
+    "lowering, nothing per step); labels: heads, key_dim, value_dim (a "
+    "head's widths), chunk (tokens a chunk of the delta rule), conv (the "
+    "convolution's taps), impl (chunked: the jax.numpy chunk form)")
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk):
+    """The gated delta rule ``S_t = a_t S_{t-1} + k_t u_t^T`` with ``u_t =
+    beta_t (v_t - a_t S_{t-1}^T k_t)``, ``a_t = exp(g_t)``, ``o_t = S_t^T
+    q_t`` (``S`` [H, K, V] float32, zero before the first token) in its
+    chunk form. q and k [B, T, H, K], v [B, T, H, V], g (log decay, <= 0)
+    and beta (write strength) [B, T, H] float32 -> o [B, T, H, V]
+    float32.
+
+    With ``b_i`` the running sum of ``g`` inside a chunk, ``c_i =
+    exp(b_i)`` and ``D_ij = exp(b_i - b_j)`` (the exponential of a masked
+    non-positive difference): ``L_ij = beta_i D_ij (k_i . k_j)`` below the
+    diagonal; one unit-triangular system a chunk and head, ``(I + L) [W |
+    Y] = [beta v | beta c k]`` (forward substitution: ``L`` is nilpotent,
+    but the powers of a product form cancel badly once keys repeat); ``M
+    = tril((q k^T) * D)``. All of that for every chunk at once; then, a
+    ``lax.scan`` over the chunks whose carry is the state, three
+    products with the state ``S`` a chunk enters with: ``o = M W + (c q -
+    M Y) S``, ``u = W - Y S`` and ``S' = c_C S + (k c_C / c)^T u``.
+    Decays, ``D``, the triangular solve and the state are float32; the
+    products take operands of ``v``'s dtype and accumulate in float32. T
+    is padded to whole chunks with ``k`` 0, ``beta`` 0 and ``g`` 0 (no
+    write, no decay) and the padding cut off."""
+    f32 = jnp.float32
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    pad = -t % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    nc = (t + pad) // chunk
+    dtype = v.dtype
+
+    def chunks(x):  # [B, T, H, ...] -> [B, nc, H, C, ...]
+        return jnp.moveaxis(x.reshape((b, nc, chunk) + x.shape[2:]), 3, 2)
+
+    def dot(spec, lhs, rhs):
+        return jnp.einsum(spec, lhs.astype(dtype), rhs.astype(dtype),
+                          preferred_element_type=f32)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    beta = chunks(beta.astype(f32))[..., None]        # [B, nc, H, C, 1]
+    cum = jnp.cumsum(chunks(g.astype(f32)), axis=-1)  # b_i
+    c = jnp.exp(cum)[..., None]                       # decay from the
+    to_end = jnp.exp(cum[..., -1:] - cum)[..., None]  # start; to the end
+    lower = np.tril(np.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(lower, cum[..., :, None] - cum[..., None, :],
+                              -jnp.inf))              # D, 0 above the diag
+    system = beta * decay * dot("bchid,bchjd->bchij", k, k)
+    solved = jax.scipy.linalg.solve_triangular(
+        system, jnp.concatenate([beta * v.astype(f32),
+                                 beta * c * k.astype(f32)], axis=-1),
+        lower=True, unit_diagonal=True)               # the diagonal unread
+    w, y = solved[..., :dv], solved[..., dv:]
+    m = decay * dot("bchid,bchjd->bchij", q, k)
+    out0 = dot("bchij,bchjv->bchiv", m, w)
+    q_in = c * q.astype(f32) - dot("bchij,bchjd->bchid", m, y)
+    k_out = to_end * k.astype(f32)
+
+    def step(state, at):                              # [B, H, K, V]
+        out0, q_in, w, y, k_out, kept = at
+        u = w - dot("bhid,bhdv->bhiv", y, state)
+        out = out0 + dot("bhid,bhdv->bhiv", q_in, state)
+        state = kept[..., None, None] * state + dot("bhid,bhiv->bhdv",
+                                                    k_out, u)
+        return state, out
+
+    _, out = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, dv), f32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (
+            out0, q_in.astype(dtype), w.astype(dtype), y.astype(dtype),
+            k_out.astype(dtype), jnp.exp(cum[..., -1]))))
+    out = jnp.moveaxis(out, 0, 1)                     # [B, nc, H, C, V]
+    return jnp.moveaxis(out, 2, 3).reshape(b, t + pad, h, dv)[:, :t]
+
+
+def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
+                    dt_bias, norm_gamma, num_heads, chunk_size, eps,
+                    allow_neg_eigval=True, remat=False):
+    """query and key [B, T, H K], value and gate [B, T, H V], a and b [B,
+    T, H] (the six projections of the block's input), conv_weight [taps,
+    2 H K + H V] (the taps of ``query | key | value``; tap ``taps - 1``
+    meets the current token), a_log and dt_bias [H], norm_gamma [V] ->
+    [B, T, H V] (``o_proj``'s input).
+
+    ``q, k, v = silu(conv(.))``, a causal depthwise convolution over time
+    without bias (scope ``conv1d``); ``q = q / |q| / sqrt(K)`` and ``k =
+    k / |k|`` a head (``|x|`` = sqrt(sum x^2 + 1e-6)), ``beta = 2
+    sigmoid(b)`` (``allow_neg_eigval``; without it the 2 goes), ``g =
+    -exp(a_log) softplus(a + dt_bias)``, ``o = gated_delta_rule(...)``
+    (scope ``delta_rule``); ``RMSNorm(o) norm_gamma silu(gate)`` with the
+    statistics over each head's V columns (scope ``gate_norm``: the norm
+    first, then the gate; ``Mamba2`` gates first). The convolution's
+    sum, the two norms, write strengths, decays, the triangular solve,
+    the state and the gate are float32 whatever the inputs' dtype.
+    ``remat`` (training): each of the three scopes is computed again in
+    the backward pass from its inputs, nothing inside it is kept
+    (``jax.checkpoint``).
+
+    The call site counts itself here (``linear_attn.lowerings``); the
+    block itself is ``_gated_delta_block``, one ``jax.jit`` for every node
+    of one signature, the same form on every platform."""
+    _M_LINEAR_ATTN_LOWERINGS.inc(
+        heads=num_heads, key_dim=query.shape[2] // num_heads,
+        value_dim=value.shape[2] // num_heads, chunk=chunk_size,
+        conv=conv_weight.shape[0], impl="chunked")
+    return _gated_delta_block(
+        query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
+        norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
+        eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
+        remat=bool(remat))
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "chunk", "eps",
+                                             "beta_scale", "remat"))
+def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
+                       dt_bias, norm_gamma, *, heads, chunk, eps,
+                       beta_scale, remat):
+    """``gated_delta_net`` for one signature."""
+    f32 = jnp.float32
+    bsz, t, _ = query.shape
+    dk, dv = query.shape[2] // heads, value.shape[2] // heads
+    taps = conv_weight.shape[0]
+
+    def again(f):
+        return jax.checkpoint(f) if remat else f
+
+    def conv1d(x, w):
+        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
+        w = w.astype(f32)
+        acc = padded[:, :t] * w[0]
+        for j in range(1, taps):
+            acc = acc + padded[:, j:j + t] * w[j]
+        return jax.nn.silu(acc).astype(x.dtype)
+
+    def unit(x):  # each head's vector over its length, float32
+        x = x.astype(f32).reshape(bsz, t, heads, -1)
+        return x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    def delta_rule(q, k, v, a, b, a_log, dt_bias):
+        q = (unit(q) * dk ** -0.5).astype(v.dtype)
+        k = unit(k).astype(v.dtype)
+        beta = beta_scale * jax.nn.sigmoid(b.astype(f32))
+        g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
+            a.astype(f32) + dt_bias.astype(f32))
+        return gated_delta_rule(q, k, v.reshape(bsz, t, heads, dv), g, beta,
+                                chunk)
+
+    def gate_norm(o, gate, norm_gamma):
+        var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+        normed = o * jax.lax.rsqrt(var + eps) * norm_gamma.astype(f32)
+        gated = normed.reshape(bsz, t, heads * dv) * jax.nn.silu(
+            gate.astype(f32))
+        return gated.astype(gate.dtype)
+
+    with jax.named_scope("conv1d"):
+        edges = (0, heads * dk, 2 * heads * dk, 2 * heads * dk + heads * dv)
+        q, k, v = (again(conv1d)(x, conv_weight[:, lo:hi])
+                   for x, lo, hi in zip((query, key, value), edges,
+                                        edges[1:]))
+    with jax.named_scope("delta_rule"):
+        o = again(delta_rule)(q, k, v, a, b, a_log, dt_bias)
+    with jax.named_scope("gate_norm"):
+        return again(gate_norm)(o, gate, norm_gamma)
+
+
+def _gated_delta_net(attrs, ins, is_train):
+    return [gated_delta_net(
+        *ins, num_heads=int(attrs["num_heads"]),
+        chunk_size=int(attrs.get("chunk_size", 64)),
+        eps=float(attrs.get("eps", 1e-6)),
+        allow_neg_eigval=bool(attrs.get("allow_neg_eigval", True)),
+        remat=is_train)]
+
+
+def _gated_delta_net_infer(attrs, in_shapes):
+    heads, taps = int(attrs["num_heads"]), int(attrs.get("conv_kernel", 4))
+    chunk = int(attrs.get("chunk_size", 64))
+    if min(heads, taps, chunk) <= 0:
+        raise ValueError(
+            "GatedDeltaNet: num_heads=%d, conv_kernel=%d and chunk_size=%d "
+            "must be positive" % (heads, taps, chunk))
+    q = _known(in_shapes[0], "GatedDeltaNet")
+    v = _known(in_shapes[2], "GatedDeltaNet")
+    dk = _split_heads("GatedDeltaNet", "query", q, heads)
+    dv = _split_heads("GatedDeltaNet", "value", v, heads)
+    if v[:2] != q[:2]:
+        raise ValueError("GatedDeltaNet: value %s does not share query's "
+                         "batch and time %s" % (v, q[:2]))
+    scalars = q[:2] + (heads,)
+    return ([q, q, v, v, scalars, scalars, (taps, 2 * heads * dk + heads * dv),
+             (heads,), (heads,), (dv,)], [v], [])
+
+
+register(
+    OpDef(
+        "_contrib_GatedDeltaNet",
+        _gated_delta_net,
+        arguments=("query", "key", "value", "gate", "a", "b", "conv_weight",
+                   "a_log", "dt_bias", "norm_gamma"),
+        defaults={"num_heads": 1, "conv_kernel": 4, "chunk_size": 64,
+                  "eps": 1e-6, "allow_neg_eigval": True},
+        infer_shape=_gated_delta_net_infer,
+        aliases=("GatedDeltaNet",),
+    )
+)

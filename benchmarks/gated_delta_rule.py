@@ -1,0 +1,198 @@
+"""The gated delta rule's chunk core on the chip: `ops/transformer.py::
+gated_delta_rule` (the `jax.numpy` chunk form, under `jax.checkpoint` with
+the unit norms, write strengths and decays before it, as the GatedDeltaNet
+op ran its `delta_rule` stage before the kernels) against
+`ops/pallas_kernels.py::gated_delta_rule` (the `gdn_fwd_` / `gdn_bwd_`
+kernel pair, the same prologue under its own `jax.checkpoint`) at the
+Olmo-Hybrid cell's shape (one sequence of 4,096 tokens, 30 heads with keys
+of 96 and values of 192, chunks of 64, bf16), forward and forward +
+backward, the two forms alternating. Host clock over 20 calls closed by a
+fetch; the arrays cross the jit boundary as the op holds them
+(``[B, T, H K]``, ``[B, T, H V]``, ``[B, T, H]``). The kernels' own device
+time is read from a profiler trace by their names.
+
+Also prints how far each form's output and gradients are, on the chip,
+from the chunk form in float32 with every product at the highest
+precision (largest difference over that one's largest magnitude), for
+bf16 and for float32 operands. `--heads-a-step 1,3,6` times the kernel
+pair at other head groups than the rule's own. Prints one JSON line a row
+and writes `chiprun_out/gated_delta_rule_table.json`; PERF.md section 7
+holds the table (PR 42).
+
+    chiprun -- python3 benchmarks/gated_delta_rule.py
+"""
+import argparse
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
+from mxnet_tpu.ops.transformer import gated_delta_rule  # noqa: E402
+
+B, T, H, K, V, CHUNK = 1, 4096, 30, 96, 192, 64
+INPUTS = ("q", "k", "v", "a", "b")
+
+
+def _time(f, *args, reps=20):
+    jax.block_until_ready(f(*args))
+    jax.block_until_ready(f(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        r = f(*args)
+    jax.block_until_ready(r)
+    np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[:1])  # a fetch
+    return (time.perf_counter() - t0) / reps * 1e3
+
+
+def _kernel_device_ms(g, *args, reps=10):
+    """Device ms a call of each ``gdn_`` kernel and of everything else in
+    the program, from a profiler trace of ``reps`` calls."""
+    import collections
+    import glob
+    import tempfile
+
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(g(*args))
+    where = tempfile.mkdtemp()
+    with jax.profiler.trace(where):
+        for _ in range(reps):
+            r = g(*args)
+        jax.block_until_ready(r)
+    trace, = glob.glob(where + "/plugins/profile/*/*.xplane.pb")
+    ms = collections.Counter()
+    for plane in ProfileData.from_file(trace).planes:
+        if plane.name != "/device:TPU:0":
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for e in line.events:
+                name = e.name.split(" = ")[0].lstrip("%")
+                ms[name.split(".")[0] if name.startswith("gdn_")
+                   else "everything else"] += e.duration_ns / 1e6 / reps
+    return dict(ms)
+
+
+def inputs(seed, dtype, t=T):
+    """q, k, v as the op's convolution leaves them (unit scale, heads side
+    by side in the last dimension), a and b as their projections do, decay
+    rates by the published rule."""
+    rng = np.random.RandomState(seed)
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), H))
+    q, k, v = (jnp.asarray(rng.randn(B, t, H * w), dtype)
+               for w in (K, K, V))
+    a = jnp.asarray(rng.randn(B, t, H)
+                    + step + np.log(-np.expm1(-step)), dtype)
+    b = jnp.asarray(rng.randn(B, t, H), dtype)
+    return ((q, k, v, a, b, jnp.asarray(np.log(rng.uniform(1, 16, H)),
+                                        jnp.float32)),
+            jnp.asarray(rng.randn(B, t, H, V), jnp.float32))
+
+
+def forms():
+    """The ``delta_rule`` stage of ``_gated_delta_block`` both ways."""
+    f32 = jnp.float32
+
+    def unit(x):
+        x = x.astype(f32).reshape(x.shape[:2] + (H, -1))
+        return x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    def before(q, k, v, a, b, a_log):
+        g = -jnp.exp(a_log) * jax.nn.softplus(a.astype(f32))
+        return ((unit(q) * K ** -0.5).astype(v.dtype),
+                unit(k).astype(v.dtype), g,
+                2.0 * jax.nn.sigmoid(b.astype(f32)))
+
+    def heads(v):
+        return v.reshape(v.shape[:2] + (H, V))
+
+    @jax.checkpoint
+    def chunked(q, k, v, a, b, a_log):
+        q, k, g, beta = before(q, k, v, a, b, a_log)
+        return gated_delta_rule(q, k, heads(v), g, beta, CHUNK)
+
+    def kernel(q, k, v, a, b, a_log):
+        q, k, g, beta = jax.checkpoint(before)(q, k, v, a, b, a_log)
+        return pk.gated_delta_rule(q, k, heads(v), g, beta, CHUNK)
+
+    def both(f):
+        def loss(cot, *ins):
+            return jnp.sum(f(*ins) * cot)
+        return (jax.jit(f),
+                jax.jit(jax.value_and_grad(loss, argnums=(1, 2, 3, 4, 5))))
+    return {"chunked": both(chunked), "kernel": both(kernel)}
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--heads-a-step", default="")
+    ap.add_argument("--rounds", type=int, default=3)
+    args_ = ap.parse_args()
+    dev = jax.devices()[0]
+    res = {"device": str(dev.device_kind), "platform": dev.platform,
+           "shape": dict(b=B, t=T, heads=H, key_dim=K, value_dim=V,
+                         chunk=CHUNK), "rows": []}
+
+    def row(**kw):
+        print(json.dumps(kw), flush=True)
+        res["rows"].append(kw)
+
+    both = forms()
+    for dtype, t in ((jnp.bfloat16, T), (jnp.float32, 1024)):
+        args, cot = inputs(0, dtype, t)
+        outs = {name: (f(*args), g(cot, *args)[1])
+                for name, (f, g) in both.items()}
+
+        def rel(got, want):
+            got, want = (v.astype(jnp.float32) for v in (got, want))
+            return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+        # the yardstick: the chunk form on the same values in float32
+        # with every product at the highest precision
+        with jax.default_matmul_precision("highest"):
+            f, g = forms()["chunked"]
+            exact = tuple(v.astype(jnp.float32) for v in args)
+            o_x, g_x = f(*exact), g(cot, *exact)[1]
+        for name, (o, grads) in outs.items():
+            row(check=name + "_against_float32_highest",
+                dtype=jnp.dtype(dtype).name, t=t, o=rel(o, o_x),
+                **{"d" + n: rel(k, e)
+                   for n, k, e in zip(INPUTS, grads, g_x)})
+
+    args, cot = inputs(1, jnp.bfloat16)
+    for _ in range(args_.rounds):
+        for name in ("chunked", "kernel"):
+            f, g = both[name]
+            fwd = _time(f, *args)
+            row(form=name, fwd_ms=fwd, fwd_bwd_ms=_time(g, cot, *args))
+    row(kernels_device_ms=_kernel_device_ms(both["kernel"][1], cot, *args),
+        heads_a_step=pk._gdn_group(H), steps=B * H // pk._gdn_group(H)
+        * (T // CHUNK))
+    def retimed(**label):
+        for f in (pk._gdn_fwd_call, pk._gdn_bwd_call, pk._gdn_forward):
+            f.clear_cache()
+        f, g = forms()["kernel"]
+        row(fwd_ms=_time(f, *args), fwd_bwd_ms=_time(g, cot, *args),
+            kernels_device_ms=_kernel_device_ms(g, cot, *args), **label)
+
+    for per in [int(p) for p in args_.heads_a_step.split(",") if p]:
+        own, pk._GDN_HEADS_A_STEP = pk._GDN_HEADS_A_STEP, per
+        retimed(heads_a_step=pk._gdn_group(H))
+        pk._GDN_HEADS_A_STEP = own
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/gated_delta_rule_table.json", "w") as f:
+        json.dump(res, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

@@ -651,7 +651,9 @@ _M_LINEAR_ATTN_LOWERINGS = _tm.counter(
     "linear_attn.lowerings", "Traces of a GatedDeltaNet call site (one per "
     "lowering, nothing per step); labels: heads, key_dim, value_dim (a "
     "head's widths), chunk (tokens a chunk of the delta rule), conv (the "
-    "convolution's taps), impl (chunked: the jax.numpy chunk form)")
+    "convolution's taps), impl (kernel: the Pallas pair where the step is "
+    "lowered for the TPU, the chunk form elsewhere; chunked: the jax.numpy "
+    "chunk form everywhere)")
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk):
@@ -675,7 +677,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk):
     Decays, ``D``, the triangular solve and the state are float32; the
     products take operands of ``v``'s dtype and accumulate in float32. T
     is padded to whole chunks with ``k`` 0, ``beta`` 0 and ``g`` 0 (no
-    write, no decay) and the padding cut off."""
+    write, no decay) and the padding cut off. The form for the shapes
+    ``pallas_kernels.gated_delta_rule`` has no tiles for and for every
+    platform but the TPU, and what its tests hold it to."""
     f32 = jnp.float32
     b, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -748,31 +752,45 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     statistics over each head's V columns (scope ``gate_norm``: the norm
     first, then the gate; ``Mamba2`` gates first). The convolution's
     sum, the two norms, write strengths, decays, the triangular solve,
-    the state and the gate are float32 whatever the inputs' dtype.
+    the state and the gate are float32 whatever the inputs' dtype. The
+    rule is the Pallas kernel pair where the shapes have tiles for it and
+    the step is lowered for the TPU (``pallas_kernels.gdn_takes`` /
+    ``gated_delta_rule``), the ``jax.numpy`` chunk form elsewhere.
     ``remat`` (training): each of the three scopes is computed again in
     the backward pass from its inputs, nothing inside it is kept
-    (``jax.checkpoint``).
+    (``jax.checkpoint``); of ``delta_rule`` on the kernel path that is
+    the unit norms, ``beta`` and ``g`` only: the kernel pair keeps its
+    own residuals (the state each chunk entered with and its system's
+    inverse) and runs once each way.
 
     The call site counts itself here (``linear_attn.lowerings``); the
     block itself is ``_gated_delta_block``, one ``jax.jit`` for every node
-    of one signature, the same form on every platform."""
+    of one signature."""
+    from . import pallas_kernels
+
+    key_dim, value_dim = (x.shape[2] // num_heads for x in (query, value))
+    kernel = pallas_kernels.gdn_takes(
+        num_heads, key_dim, value_dim, chunk_size, value.dtype)
     _M_LINEAR_ATTN_LOWERINGS.inc(
-        heads=num_heads, key_dim=query.shape[2] // num_heads,
-        value_dim=value.shape[2] // num_heads, chunk=chunk_size,
-        conv=conv_weight.shape[0], impl="chunked")
+        heads=num_heads, key_dim=key_dim, value_dim=value_dim,
+        chunk=chunk_size, conv=conv_weight.shape[0],
+        impl="kernel" if kernel else "chunked")
     return _gated_delta_block(
         query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
         norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
         eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
-        remat=bool(remat))
+        remat=bool(remat), kernel=kernel)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "chunk", "eps",
-                                             "beta_scale", "remat"))
+                                             "beta_scale", "remat",
+                                             "kernel"))
 def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                        dt_bias, norm_gamma, *, heads, chunk, eps,
-                       beta_scale, remat):
+                       beta_scale, remat, kernel=False):
     """``gated_delta_net`` for one signature."""
+    from . import pallas_kernels
+
     f32 = jnp.float32
     bsz, t, _ = query.shape
     dk, dv = query.shape[2] // heads, value.shape[2] // heads
@@ -794,12 +812,15 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
         return x * jax.lax.rsqrt(
             jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
-    def delta_rule(q, k, v, a, b, a_log, dt_bias):
-        q = (unit(q) * dk ** -0.5).astype(v.dtype)
-        k = unit(k).astype(v.dtype)
+    def unit_and_strengths(q, k, a, b, a_log, dt_bias):
         beta = beta_scale * jax.nn.sigmoid(b.astype(f32))
         g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
             a.astype(f32) + dt_bias.astype(f32))
+        return ((unit(q) * dk ** -0.5).astype(value.dtype),
+                unit(k).astype(value.dtype), g, beta)
+
+    def delta_rule(q, k, v, a, b, a_log, dt_bias):
+        q, k, g, beta = unit_and_strengths(q, k, a, b, a_log, dt_bias)
         return gated_delta_rule(q, k, v.reshape(bsz, t, heads, dv), g, beta,
                                 chunk)
 
@@ -816,7 +837,13 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                    for x, lo, hi in zip((query, key, value), edges,
                                         edges[1:]))
     with jax.named_scope("delta_rule"):
-        o = again(delta_rule)(q, k, v, a, b, a_log, dt_bias)
+        if kernel:
+            q, k, g, beta = again(unit_and_strengths)(q, k, a, b, a_log,
+                                                      dt_bias)
+            o = pallas_kernels.gated_delta_rule(
+                q, k, v.reshape(bsz, t, heads, dv), g, beta, chunk)
+        else:
+            o = again(delta_rule)(q, k, v, a, b, a_log, dt_bias)
     with jax.named_scope("gate_norm"):
         return again(gate_norm)(o, gate, norm_gamma)
 

@@ -263,3 +263,48 @@ def test_ssd_scan_kernels_compile_for_v5e(one_chip, t, heads, p, groups, n,
             for key in ("scoped_memory_configs",
                         "used_scoped_memory_configs"))
         assert used <= limit <= pk._SSD_VMEM_LIMIT, (name, used, limit)
+
+
+# the Olmo-Hybrid cell's delta rule (one sequence of 4,096 tokens, 30
+# heads with keys of 96 and values of 192: neither a whole number of lane
+# rows; chunks of 64, bf16, fifteen heads a step); a float32 caller whose
+# heads are whole lane rows, seven heads a step, chunks of 128 over a
+# ragged length
+GDN_SHAPES = [
+    (4096, 30, 96, 192, 64, jnp.bfloat16),
+    (300, 7, 128, 128, 128, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("t,heads,dk,dv,chunk,dtype", GDN_SHAPES)
+def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, t, heads, dk, dv,
+                                                  chunk, dtype):
+    """The delta rule's forward and backward kernels at real widths:
+    Mosaic takes every slice, broadcast and product of both bodies (the
+    substitution's row and column reads, keys of 96 padded to a lane row
+    in VMEM) and a step's working set is under the scoped VMEM the calls
+    state."""
+    assert pk.gdn_takes(heads, dk, dv, chunk, dtype)
+
+    def spec(*shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*ins):
+        return jnp.sum(pk.gated_delta_rule(*ins, chunk))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        spec(1, t, heads, dk), spec(1, t, heads, dk), spec(1, t, heads, dv),
+        spec(1, t, heads, dtype=jnp.float32),
+        spec(1, t, heads, dtype=jnp.float32)).compile().as_text()
+    operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    for which in ("fwd", "bwd"):
+        name = "gdn_%s_%s_c%d_k%d_v%d" % (which, operands, chunk, dk, dv)
+        calls = [line for line in text.splitlines()
+                 if name in line and "custom-call(" in line]
+        assert len(calls) == 1, name
+        limit, used = (
+            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"0",'
+                          r'"size":"(\d+)"' % key, calls[0]).group(1))
+            for key in ("scoped_memory_configs",
+                        "used_scoped_memory_configs"))
+        assert used <= limit <= pk._GDN_VMEM_LIMIT, (name, used, limit)

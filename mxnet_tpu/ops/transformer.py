@@ -8,8 +8,8 @@ no sparse-expert layer): what a decoder-only LM with sparse experts
 ``OpDef`` over one function kept elsewhere: ``Attention`` over the one
 attention dispatch ``ops/pallas_kernels.attention`` (flash kernel on the
 TPU at T >= 128, the materialised reference elsewhere;
-``LatentAttention`` projects its keys and values up from a latent first
-and calls the same dispatch), ``TopKMoE`` over
+``LatentAttention`` projects its keys and values up from a latent first,
+for its own flash pair or the same dispatch), ``TopKMoE`` over
 ``parallel/moe.topk_moe``; ``Mamba2`` (a state-space mixer's core: the
 convolution, the chunked scan and the gated norm) is ``jax.numpy`` here,
 with no kernel behind it. Exported as ``mx.contrib.sym`` /
@@ -236,7 +236,7 @@ _M_LATENT_LOWERINGS = _tm.counter(
     "attention.latent_lowerings", "Traces of a LatentAttention call site "
     "(one per lowering, nothing per step); labels: heads, latent (the "
     "width keys and values are projected up from), rope (the rotary key "
-    "every head shares), nope (a head's own key width), dv")
+    "every head shares), nope (a head's own key), dv, impl (see below)")
 
 
 def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
@@ -250,32 +250,32 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
     ``c = RMSNorm(latent[:L])``; ``(k_nope_h, v_h) = up_weight c``;
     RoPE on each head's ``q_rope`` and on the shared ``k_rope``; head
     h's key is ``[k_nope_h, k_rope]``; causal softmax attention scaled
-    by ``1 / sqrt(N + R)`` through the one attention dispatch. Norm
-    statistics, rotation and softmax are float32; the up-projection
-    takes operands of ``latent``'s dtype and accumulates in float32."""
-    from .pallas_kernels import attention
+    by ``1 / sqrt(N + R)``. Norm statistics, rotation and softmax are
+    float32; the up-projection takes operands of ``latent``'s dtype and
+    accumulates in float32. The attention itself has two forms, chosen
+    by the shapes alone and counted under ``impl``: ``kernel`` where
+    ``pallas_kernels.latent_flash_takes`` admits them
+    (``_latent_kernel_path`` below: the flash pair of two key operands
+    where the step is lowered for the TPU), ``composed`` everywhere else
+    (``_latent_composed_path``: the concatenated key through the one
+    attention dispatch)."""
+    from .pallas_kernels import latent_flash_takes
 
-    b, t, _ = query.shape
     width = latent.shape[2] - rope_dim
     nope = query.shape[2] // num_heads - rope_dim
+    kernel = latent_flash_takes(query.shape[1], nope, rope_dim, v_head_dim,
+                                query.dtype)
     _M_LATENT_LOWERINGS.inc(heads=num_heads, latent=width, rope=rope_dim,
-                            nope=nope, dv=v_head_dim)
+                            nope=nope, dv=v_head_dim,
+                            impl="kernel" if kernel else "composed")
     with jax.named_scope("latent"):
         c = rms_norm(latent[..., :width], gamma, eps)
         kv = jax.lax.dot_general(
             c, up_weight.astype(c.dtype), (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32).astype(c.dtype)
-        kv = kv.reshape(b, t, num_heads, nope + v_head_dim)
-        q = rope(query, num_heads, theta, rope_dim, nope, interleave)
         k_rope = rope(latent[..., width:], 1, theta, rope_dim, 0, interleave)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(
-                k_rope[:, :, None, :], (b, t, num_heads, rope_dim))],
-            axis=-1)
-    with jax.named_scope("full"):
-        out = attention(q.reshape(b, t, num_heads, nope + rope_dim), k,
-                        kv[..., nope:], causal=True)
-    return out.reshape(b, t, num_heads * v_head_dim)
+    path = _latent_kernel_path if kernel else _latent_composed_path
+    return path(query, kv, k_rope, num_heads, v_head_dim, theta, interleave)
 
 
 def _latent_attention(attrs, ins, is_train):
@@ -888,3 +888,133 @@ register(
         aliases=("GatedDeltaNet",),
     )
 )
+
+
+# --------------------------------------------------------------------------
+# LatentAttention's two forms of the attention itself (``latent_attention``
+# chooses; down here so that no line above moves: see GatedDeltaNet's note)
+# --------------------------------------------------------------------------
+# what the kernel path runs where the step is not lowered for the TPU:
+# "composed" (``reference_attention`` over the concatenated key, on the
+# kernels' operands) or, for the kernels' tests, "interpret"
+_LATENT_OFF_TPU = "composed"
+
+
+def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
+                          interleave):
+    """Every head's key materialised: the rotation over the whole query,
+    the shared rotary key broadcast and concatenated behind each head's
+    slice of ``kv`` [B, T, H (N + Dv)], the values sliced out of it, and
+    ``pallas_kernels.attention`` (the flash kernel on the TPU at T >= 128,
+    the materialised reference elsewhere)."""
+    from .pallas_kernels import attention
+
+    b, t, _ = query.shape
+    rope_dim = k_rope.shape[2]
+    nope = query.shape[2] // num_heads - rope_dim
+    with jax.named_scope("latent"):
+        kv = kv.reshape(b, t, num_heads, nope + v_head_dim)
+        q = rope(query, num_heads, theta, rope_dim, nope, interleave)
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                k_rope[:, :, None, :], (b, t, num_heads, rope_dim))],
+            axis=-1)
+    with jax.named_scope("full"):
+        out = attention(q.reshape(b, t, num_heads, nope + rope_dim), k,
+                        kv[..., nope:], causal=True)
+    return out.reshape(b, t, num_heads * v_head_dim)
+
+
+def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
+                        interleave):
+    """Nothing of [T, H, N + R] built for the keys: one pass over the
+    query (``_kernel_query``) and ``pallas_kernels.latent_flash`` on
+    ``kv`` and ``k_rope`` where the up-projection and the rotation left
+    them; its output is the output projection's input as it stands."""
+    from .pallas_kernels import latent_flash
+
+    rope_dim = k_rope.shape[2]
+    width = query.shape[2] // num_heads
+    interpret = _LATENT_OFF_TPU == "interpret"
+    with jax.named_scope("latent"):
+        q = _kernel_query(query, num_heads, rope_dim, theta, interleave,
+                          interpret)
+        k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, -rope_dim % 128)))
+    with jax.named_scope("full"):
+        return latent_flash(q, kv, k_rope, num_heads, width - rope_dim,
+                            scale=width ** -0.5, interpret=interpret)
+
+
+def _rotated_lanes(x, rope_dim, theta, interleave):
+    """x [B, T, H, N + R] -> its R last lanes a head rotated, [B, T, H, R]
+    in x's type: ``rope`` on those lanes alone, the only ones lifted to
+    float32."""
+    b, t, h, d = x.shape
+    rot = rope(x[..., d - rope_dim:].reshape(b, t, h * rope_dim), h, theta,
+               interleave=interleave)
+    return rot.reshape(b, t, h, rope_dim)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_heads", "rope_dim", "theta", "interleave", "inverse", "interpret"))
+def _query_pass(x, *, num_heads, rope_dim, theta, interleave, inverse,
+                interpret):
+    """x [B, T, H (N + R)] -> [B, T, H (N + Rp)]: each head's R last lanes
+    rotated by their positions and zero lanes behind them to a whole lane
+    row, Rp; under ``inverse`` the transpose, on the cotangent (the
+    rotation's transpose on its R lanes, the N others through, the zero
+    lanes' unread). ``pallas_kernels.latent_query`` where it has blocks
+    for the shapes and the step is lowered for the TPU, ``jax.numpy``
+    everywhere else; one ``jax.jit`` a signature."""
+    from . import pallas_kernels as pk
+
+    b, t, _ = x.shape
+    pad = -rope_dim % 128
+    d = x.shape[2] // num_heads - (pad if inverse else 0)   # N + R
+
+    def composed(x):
+        x = x.reshape(b, t, num_heads, -1)
+        if inverse:
+            turned, = jax.linear_transpose(
+                lambda r: _rotated_lanes(r, rope_dim, theta, interleave),
+                jax.ShapeDtypeStruct((b, t, num_heads, rope_dim), x.dtype))(
+                    x[..., d - rope_dim:d])
+            parts = [x[..., :d - rope_dim], turned]
+        else:
+            parts = [x[..., :d - rope_dim],
+                     _rotated_lanes(x, rope_dim, theta, interleave),
+                     jnp.zeros((b, t, num_heads, pad), x.dtype)]
+        return jnp.concatenate(parts, axis=-1).reshape(b, t, -1)
+
+    if not pk.latent_query_takes(t, num_heads, d - rope_dim, rope_dim):
+        return composed(x)
+    return pk._ssd_by_platform(
+        functools.partial(
+            pk.latent_query, heads=num_heads, nope=d - rope_dim,
+            rope=rope_dim, theta=theta, interleave=interleave,
+            inverse=inverse),
+        composed, interpret, x)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _kernel_query(query, num_heads, rope_dim, theta, interleave, interpret):
+    """The query as ``latent_flash`` reads it (``_query_pass``): one pass
+    that reads it once; the backward is the same pass backwards, no pad
+    and no sum of two."""
+    return _query_pass(query, num_heads=num_heads, rope_dim=rope_dim,
+                       theta=theta, interleave=interleave, inverse=False,
+                       interpret=interpret)
+
+
+def _kernel_query_fwd(query, *static):
+    return _kernel_query(query, *static), None
+
+
+def _kernel_query_bwd(num_heads, rope_dim, theta, interleave, interpret, _,
+                      g):
+    return (_query_pass(g, num_heads=num_heads, rope_dim=rope_dim,
+                        theta=theta, interleave=interleave, inverse=True,
+                        interpret=interpret),)
+
+
+_kernel_query.defvjp(_kernel_query_fwd, _kernel_query_bwd)

@@ -148,6 +148,52 @@ def test_one_pass_backward_compiles_under_its_stated_limit(one_chip, cell):
     assert used <= limit <= pk._FLASH_BWD_VMEM_LIMIT
 
 
+def test_the_latent_pair_compiles_at_the_kanana_cells_shape(one_chip):
+    """``LatentAttention`` as the Kanana cell calls it (T 8,192, 32 heads
+    of 128 + 64 query/key and 128 value dimensions from a latent of 512,
+    bf16), forward and backward: the op takes the latent pair, Mosaic
+    compiles the forward under the default scoped limit and the one-pass
+    backward under exactly the VMEM ``_flash_vmem_bytes`` counts for it
+    (the padded widths 256 / 128: what 192 / 128 counted), once each, and
+    no single-key flash kernel is left in the program."""
+    from mxnet_tpu.ops.transformer import latent_attention
+
+    t, h, nope, rope, dv, latent = 8192, 32, 128, 64, 128, 512
+    assert pk.latent_flash_takes(t, nope, rope, dv, jnp.bfloat16)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(*ins):
+        return jnp.sum(latent_attention(
+            *ins, num_heads=h, rope_dim=rope, v_head_dim=dv, theta=1e6,
+            eps=1e-6, interleave=True).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shape(1, t, h * (nope + rope)), shape(1, t, latent + rope),
+        shape(latent), shape(h * (nope + dv), latent)).compile().as_text()
+    width = nope + 128                  # the rotary lanes to a lane row
+    bq, bk = flash_tiles(t, width, jnp.bfloat16)
+    assert (bq, bk) == (1024, 1024)
+    calls = {which: [line for line in text.splitlines()
+                     if "flash2_%s_bf16_q%d_k%d" % (which, bq, bk) in line
+                     and "custom-call(" in line]
+             for which in ("fwd", "bwd")}
+    assert [len(c) for c in calls.values()] == [1, 1]
+    assert "flash_fwd_" not in text and "flash_bwd_" not in text
+    # the pass over the query round the pair, each way
+    for which in ("fwd", "bwd"):
+        assert len(re.findall(r"(?m)^\s*%%latent_query_%s_bf16[.\d]* = "
+                              % which, text)) == 1
+    limit, used = (
+        int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
+                      r'"size":"(\d+)"' % key, calls["bwd"][0]).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
+    assert limit == pk._flash_vmem_bytes(bq, bk, width, 2,
+                                         resident=(t, width, dv))
+    assert used <= limit <= pk._FLASH_BWD_VMEM_LIMIT
+
+
 # the OLMoE cell's two expert products (gate/up, down); a float32 caller
 # at the widest result tile its budget admits; the MiMo share cell's two
 # (8 held experts over a buffer of 2,048 rows); the Nemotron-3-Nano

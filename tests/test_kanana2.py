@@ -66,12 +66,12 @@ def _close(got, want, what, rtol=1e-5, ulps=8):
     np.testing.assert_allclose(got, want, rtol=rtol, atol=atol, err_msg=what)
 
 
-def _params(sym, seed, sigma=0.08):
+def _params(sym, seed, sigma=0.08, t=T):
     """Seeded weights under the symbol's argument names: Normal(sigma),
     a unit embedding as the model states it, gammas near 1 and selection
     biases away from 0 (so that their part is tested)."""
     rng = np.random.RandomState(seed)
-    shapes, _, _ = sym.infer_shape(data=(BATCH, T), softmax_label=(BATCH, T))
+    shapes, _, _ = sym.infer_shape(data=(BATCH, t), softmax_label=(BATCH, t))
     out = {}
     for name, shape in zip(sym.list_arguments(), shapes):
         if name in ("data", "softmax_label"):
@@ -84,16 +84,16 @@ def _params(sym, seed, sigma=0.08):
     return out
 
 
-def _batch(seed):
+def _batch(seed, t=T):
     rng = np.random.RandomState(seed)
-    tokens = rng.randint(0, CFG["vocab_size"], (BATCH, T + 1))
+    tokens = rng.randint(0, CFG["vocab_size"], (BATCH, t + 1))
     return tokens[:, :-1].astype(np.float32), tokens[:, 1:].astype(np.float32)
 
 
-def _module(sym, params):
+def _module(sym, params, t=T):
     mod = mx.mod.Module(sym, context=mx.cpu(0))
-    mod.bind(data_shapes=[("data", (BATCH, T))],
-             label_shapes=[("softmax_label", (BATCH, T))])
+    mod.bind(data_shapes=[("data", (BATCH, t))],
+             label_shapes=[("softmax_label", (BATCH, t))])
     mod.init_params(arg_params={k: mx.nd.array(v) for k, v in params.items()},
                     aux_params={})
     return mod
@@ -198,7 +198,7 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
         # one per layer's call site, nothing per step
         latent = telemetry.REGISTRY.get("attention.latent_lowerings")
         assert latent.value(heads=HEADS, latent=LATENT, rope=ROPE,
-                            nope=NOPE, dv=DV) == 3
+                            nope=NOPE, dv=DV, impl="composed") == 3
         assert telemetry.total("attention.latent_lowerings") == 3
         share = telemetry.REGISTRY.get("moe.share_lowerings")
         assert share.value(held=4, of=16, bound=BATCH * T * 3,
@@ -221,30 +221,67 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
     assert "layer0_shared_gate_proj_weight" not in got  # the dense layer
 
 
-def test_every_layer_takes_the_one_pass_backward(monkeypatch):
-    """Forced onto the flash kernel (interpret mode here; on the chip
-    ``attention`` takes it by itself), every layer's attention call site
-    traces the one-pass backward once, and the gradients are the
-    reference's through it."""
-    monkeypatch.setenv("MXNET_TPU_FORCE_FLASH", "1")
-    sym = kanana2.from_config(SHARE, seq_len=T)
-    params = _params(sym, 7)
-    tokens, labels = _batch(8)
-    _, grads = ref.loss_and_grads(params, tokens, labels, SHARE)
+# heads whose own keys and values are whole lane rows, as the cell's are
+# (128 + 64 / 128): the shapes ``pallas_kernels.latent_flash_takes`` admits
+LANE_WHOLE = dict(
+    SHARE, num_attention_heads=2, num_key_value_heads=2,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, qk_head_dim=192, head_dim=64,
+    v_head_dim=128, max_position_embeddings=128,
+    share=dict(SHARE["share"], share_rows_bound=BATCH * 128 * 3))
+
+
+@pytest.mark.parametrize("path", ["flash_over_the_composition",
+                                  "latent_pair"])
+def test_every_layer_takes_the_one_pass_backward(monkeypatch, path):
+    """Forced onto the kernels (interpret mode here; on the chip the op
+    takes them by itself), every layer's attention traces the one-pass
+    backward, and the gradients are the reference's through it. On the
+    tiny widths the composition's ``attention`` call takes the flash
+    kernel, a call site a layer; on lane-whole heads, the path the cell
+    runs, the latent pair: three call sites, ONE trace of its forward and
+    of its one-pass backward (there is no other), none of ``flash_*``."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops import transformer as tr
+
+    pair = path == "latent_pair"
+    cfg, t = (LANE_WHOLE, 128) if pair else (SHARE, T)
+    if pair:
+        monkeypatch.setattr(tr, "_LATENT_OFF_TPU", "interpret")
+        for name in ("_latent_fwd_call", "_latent_bwd_call",
+                     "_latent_forward", "_latent_backward"):
+            getattr(pk, name).clear_cache()
+    else:
+        monkeypatch.setenv("MXNET_TPU_FORCE_FLASH", "1")
+    sym = kanana2.from_config(cfg, seq_len=t)
+    params = _params(sym, 7, t=t)
+    tokens, labels = _batch(8, t)
+    _, grads = ref.loss_and_grads(params, tokens, labels, cfg)
     telemetry.reset()
     telemetry.enable()
     try:
-        mod = _module(sym, params)
+        mod = _module(sym, params, t)
         batch = mx.io.DataBatch(data=[mx.nd.array(tokens)],
                                 label=[mx.nd.array(labels)])
         for _ in range(2):  # a second step traces nothing
             mod.forward(batch, is_train=True)
             mod.backward()
         flash = telemetry.REGISTRY.get("attention.flash_lowerings")
-        tile = dict(operands="f32", block_q=T, block_k=T)
-        assert flash.value(window=0, bwd="fused", **tile) == 3
-        assert flash.value(window=0, bwd="split", **tile) == 0
-        assert flash.value(window=0, kv_heads=HEADS, dv=DV, **tile) == 3
+        sites = telemetry.REGISTRY.get("attention.latent_lowerings")
+        traces = telemetry.REGISTRY.get("attention.latent_kernel_traces")
+        if pair:
+            assert sites.value(heads=2, latent=LATENT, rope=64, nope=128,
+                               dv=128, impl="kernel") == 3
+            assert traces.value(**{"pass": "fwd"}) == 1
+            assert traces.value(**{"pass": "bwd"}) == 1
+            assert telemetry.total("attention.flash_lowerings") == 0
+        else:
+            tile = dict(operands="f32", block_q=T, block_k=T)
+            assert flash.value(window=0, bwd="fused", **tile) == 3
+            assert flash.value(window=0, bwd="split", **tile) == 0
+            assert flash.value(window=0, kv_heads=HEADS, dv=DV, **tile) == 3
+            assert sites.value(heads=HEADS, latent=LATENT, rope=ROPE,
+                               nope=NOPE, dv=DV, impl="composed") == 3
+            assert telemetry.total("attention.latent_kernel_traces") == 0
     finally:
         telemetry.disable()
         telemetry.reset()

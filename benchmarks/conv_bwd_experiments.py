@@ -96,10 +96,10 @@ def _time_conv_bwd(jax, jnp, dshape, wshape, stride, pad, reps, dtype):
 def run_conv_score(jax, jnp, smoke=None, reps=None, dtype=None):
     """The per-shape XLA-vs-Pallas-vs-taps conv-backward table.
 
-    Returns {"dtype", "platform", "interpret", "rows": [...]} where each
+    Returns {"dtype", "platform", "kernels_ran", "rows": [...]} where each
     row carries per-leg backward ms, the dispatch plan for the shape
     (None = fell back to XLA), and speedups vs the XLA leg."""
-    from mxnet_tpu.ops import pallas_kernels as _pk
+    from mxnet_tpu.ops import kernels as _pk
 
     if smoke is None:
         smoke = jax.default_backend() != "tpu"
@@ -108,7 +108,6 @@ def run_conv_score(jax, jnp, smoke=None, reps=None, dtype=None):
     dtype = dtype or (jnp.float32 if jax.default_backend() != "tpu"
                       else jnp.bfloat16)
     shapes = _SCORE_SHAPES_SMOKE if smoke else _SCORE_SHAPES
-    interpret = jax.default_backend() != "tpu"
     rows = []
     for name, dshape, wshape, stride, pad in shapes:
         plan = _pk.conv_bwd_plan(dshape, wshape, stride, pad, (1, 1),
@@ -144,10 +143,10 @@ def run_conv_score(jax, jnp, smoke=None, reps=None, dtype=None):
         print(json.dumps(row), file=sys.stderr)
     return {"dtype": jnp.dtype(dtype).name,
             "platform": jax.default_backend(),
-            # interpret=True legs measure the Pallas kernels through the
-            # pallas interpreter — valid for dispatch/parity evidence;
-            # TPU rows are the perf numbers the acceptance tracks
-            "interpret": interpret,
+            # off the TPU the pallas leg is the pair's plain form, XLA's
+            # gradient convs again (ops/kernels/conv.py): such rows prove
+            # the dispatch table; TPU rows are the perf numbers
+            "kernels_ran": jax.default_backend() == "tpu",
             "reps": reps,
             "rows": rows}
 
@@ -157,8 +156,8 @@ def main():
     import jax.numpy as jnp
 
     # SCORE_CONV_FULL=1 forces the real ResNet shapes even off-TPU
-    # (interpret-mode legs; slow but the dispatch table and speedup
-    # table cover the tuned envelope, not the smoke stand-ins)
+    # (the dispatch table and speedup table then cover the tuned
+    # envelope, not the smoke stand-ins)
     score = run_conv_score(
         jax, jnp,
         smoke=(False if os.environ.get("SCORE_CONV_FULL") == "1"

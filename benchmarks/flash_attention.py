@@ -1,6 +1,6 @@
 """Pallas flash-attention kernel on real TPU vs dense attention.
 
-Proves the hand-written MXU kernel (ops/pallas_kernels.py) compiles and
+Proves the hand-written MXU kernel (ops/kernels/flash.py) compiles and
 runs on hardware (the test suite exercises interpret mode only), matches
 dense numerics, and unlocks sequence lengths whose O(T^2) score matrix
 cannot fit in HBM (flash alone runs to T=16384 on one chip; dense would
@@ -27,8 +27,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
-from mxnet_tpu.ops.pallas_kernels import flash_attention  # noqa: E402
+from mxnet_tpu.ops import kernels as pk  # noqa: E402
+from mxnet_tpu.ops.kernels import flash_attention  # noqa: E402
 
 
 def dense(q, k, v):
@@ -85,14 +85,14 @@ def cell_kernels():
         bq, bk = pk.flash_tiles(t, max(d, dv), q.dtype, window)
         kw = dict(t_real=t, scale=d ** -0.5, causal=True, window=window,
                   block_q=bq, block_k=bk, interpret=False)
-        fwd = jax.jit(functools.partial(pk._fwd_call, **kw))
+        fwd = jax.jit(functools.partial(pk.flash.fwd_call, **kw))
         o, lse = fwd(q, k, v)
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1, keepdims=True)
         row = {"tiles": [bq, bk], "fwd_ms": _ms(fwd, q, k, v)}
         ref = None
         for form, fused in BWD_FORMS.items():
-            bwd = jax.jit(functools.partial(pk._bwd_call, fused=fused,
+            bwd = jax.jit(functools.partial(pk.flash.bwd_call, fused=fused,
                                             **kw))
             row["bwd_%s_ms" % form] = _ms(bwd, q, k, v, do, lse, delta)
             row["bwd_%s_us_a_pair" % form] = round(

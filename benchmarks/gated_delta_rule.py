@@ -2,7 +2,7 @@
 gated_delta_rule` (the `jax.numpy` chunk form, under `jax.checkpoint` with
 the unit norms, write strengths and decays before it, as the GatedDeltaNet
 op ran its `delta_rule` stage before the kernels) against
-`ops/pallas_kernels.py::gated_delta_rule` (the `gdn_fwd_` / `gdn_bwd_`
+`ops/kernels/gdn.py::gated_delta_rule` (the `gdn_fwd_` / `gdn_bwd_`
 kernel pair, the same prologue under its own `jax.checkpoint`) at the
 Olmo-Hybrid cell's shape (one sequence of 4,096 tokens, 30 heads with keys
 of 96 and values of 192, chunks of 64, bf16), forward and forward +
@@ -34,7 +34,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
+from mxnet_tpu.ops import kernels as pk  # noqa: E402
 from mxnet_tpu.ops.transformer import gated_delta_rule  # noqa: E402
 
 B, T, H, K, V, CHUNK = 1, 4096, 30, 96, 192, 64
@@ -176,19 +176,20 @@ def main():
             fwd = _time(f, *args)
             row(form=name, fwd_ms=fwd, fwd_bwd_ms=_time(g, cot, *args))
     row(kernels_device_ms=_kernel_device_ms(both["kernel"][1], cot, *args),
-        heads_a_step=pk._gdn_group(H), steps=B * H // pk._gdn_group(H)
+        heads_a_step=pk.gdn.gdn_group(H), steps=B * H // pk.gdn.gdn_group(H)
         * (T // CHUNK))
     def retimed(**label):
-        for f in (pk._gdn_fwd_call, pk._gdn_bwd_call, pk._gdn_forward):
+        for f in (pk.gdn.gdn_fwd_call, pk.gdn.gdn_bwd_call,
+                  pk.gdn.gdn_forward):
             f.clear_cache()
         f, g = forms()["kernel"]
         row(fwd_ms=_time(f, *args), fwd_bwd_ms=_time(g, cot, *args),
             kernels_device_ms=_kernel_device_ms(g, cot, *args), **label)
 
     for per in [int(p) for p in args_.heads_a_step.split(",") if p]:
-        own, pk._GDN_HEADS_A_STEP = pk._GDN_HEADS_A_STEP, per
-        retimed(heads_a_step=pk._gdn_group(H))
-        pk._GDN_HEADS_A_STEP = own
+        own, pk.gdn.GDN_HEADS_A_STEP = pk.gdn.GDN_HEADS_A_STEP, per
+        retimed(heads_a_step=pk.gdn.gdn_group(H))
+        pk.gdn.GDN_HEADS_A_STEP = own
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/gated_delta_rule_table.json", "w") as f:
         json.dump(res, f, indent=1)

@@ -1,5 +1,5 @@
 """The expert layer's grouped matmul on the chip: `jax.lax.ragged_dot` as
-XLA lowers it against `ops/pallas_kernels.py::grouped_matmul`'s three
+XLA lowers it against `ops/kernels/gmm.py::grouped_matmul`'s three
 kernels over a table of tiles, forward, dgrad and wgrad apart, at the
 OLMoE cell's two shapes (`[32768, 2048] x [64, 2048, 2048]` and
 `[32768, 1024] x [64, 1024, 2048]`, bf16) under four loads: the cell's own
@@ -25,7 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
+from mxnet_tpu.ops import kernels as pk  # noqa: E402
 
 M, GROUPS = 32768, 64
 CELL_LAYER1 = [
@@ -76,10 +76,10 @@ def kernel(mode, tiles):
     def f(l, r, d, s):
         meta = pk.gmm_metadata(s, M, tm)
         if mode == "wgrad":
-            return pk._gmm_wgrad_call(
+            return pk.gmm.gmm_wgrad_call(
                 meta[0], *meta[4:], l, d, groups=GROUPS, tiles=tiles,
                 interpret=False)
-        return pk._gmm_call(
+        return pk.gmm.gmm_call(
             *meta[:4], d if mode == "dgrad" else l, r, tiles=tiles,
             transposed=mode == "dgrad", interpret=False)
     return jax.jit(f)

@@ -4,7 +4,7 @@ dimensions from a latent of 512, interleaved RoPE, bf16): the composition
 the op ran before PR 44 (`ops/transformer.py::_latent_composed_path`: the
 rotation over the whole query, the rotary key broadcast and concatenated,
 `flash_attention` on head-major copies) against the kernel path
-(`_latent_kernel_path`: `pallas_kernels.latent_flash`, two key operands on
+(`_latent_kernel_path`: `kernels.latent_flash`, two key operands on
 the arrays the neighbouring matmuls leave), the two alternating.
 
 Three tables, one JSON line a row, all written to
@@ -51,7 +51,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import reduce_scopes  # noqa: E402  (bench/: the trace's scopes)
 import reduce_trace  # noqa: E402
-from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
+from mxnet_tpu.ops import kernels as pk  # noqa: E402
 from mxnet_tpu.ops import transformer as tr  # noqa: E402
 
 B, T, HIDDEN, H, N, R, DV, L = 1, 8192, 2048, 32, 128, 64, 128, 512

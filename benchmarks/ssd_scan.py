@@ -1,7 +1,7 @@
 """The chunked state-space scan on the chip: `ops/transformer.py::ssd_scan`
 (the `jnp.einsum` form, under `jax.checkpoint(policy=dots_saveable)` as
 the Mamba2 op ran it before the kernels, plus the skip) against
-`ops/pallas_kernels.py::ssd_scan` (the `ssd_fwd_` / `ssd_bwd_` kernel pair)
+`ops/kernels/ssd.py::ssd_scan` (the `ssd_fwd_` / `ssd_bwd_` kernel pair)
 at the Nemotron cell's shape (one sequence of 8,192 tokens, 64 heads of 64,
 state 128, 8 groups, chunks of 128, bf16), forward and forward + backward,
 the two forms alternating. Host clock over 20 calls closed by a fetch;
@@ -34,7 +34,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
+from mxnet_tpu.ops import kernels as pk  # noqa: E402
 from mxnet_tpu.ops.transformer import ssd_scan  # noqa: E402
 
 B, T, H, P, G, N, CHUNK = 1, 8192, 64, 64, 8, 128, 128

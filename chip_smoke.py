@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import argparse
 import collections
+import functools
 import json
 import os
 import sys
@@ -131,7 +132,8 @@ class Smoke:
 
     def expect_mosaic(self, text, what):
         """On the chip a Pallas kernel must be a Mosaic custom call in the
-        lowered step; the rehearsal runs the interpreter and has none."""
+        lowered step; the rehearsal runs the interpreter or the kernel's
+        plain form and has none."""
         n = text.count(MOSAIC_CALL)
         if self.rehearsal:
             check(n == 0, "%s: Mosaic call in a CPU rehearsal" % what)
@@ -181,9 +183,12 @@ def leg_kernels(smoke):
     import jax
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops import kernels as pk
 
     rng = np.random.RandomState(0)
+    # in the rehearsal the interpreter stands in for Mosaic
+    flash = functools.partial(pk.flash_attention,
+                              interpret=smoke.rehearsal)
     # the shapes examples/train_transformer_lm.py uses by default
     B, T, H, D = 8, 256, 8, 32
     # the kernels feed the MXU at default precision, so f32 inputs see
@@ -197,9 +202,8 @@ def leg_kernels(smoke):
             return lambda q, k, v: jnp.sum(
                 attn(q, k, v, causal=True).astype(jnp.float32) * w)
 
-        fwd = jax.jit(lambda q, k, v: pk.flash_attention(
-            q, k, v, causal=True))
-        bwd = jax.jit(jax.grad(loss(pk.flash_attention), argnums=(0, 1, 2)))
+        fwd = jax.jit(lambda q, k, v: flash(q, k, v, causal=True))
+        bwd = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))
         n_fwd = smoke.expect_mosaic(fwd.lower(q, k, v).as_text(),
                                     "flash forward")
         n_bwd = smoke.expect_mosaic(bwd.lower(q, k, v).as_text(),
@@ -233,10 +237,10 @@ def leg_kernels(smoke):
             g = jnp.asarray(rng.randn(size) * 4, jnp.bfloat16)
             states = tuple(
                 jnp.asarray(np.abs(rng.randn(size)).astype(np.float32) * .1)
-                for _ in range(pk._SLAB_STATE_SLOTS[kind]))
+                for _ in range(pk.SLAB_STATE_SLOTS[kind]))
             fused = jax.jit(lambda w, g, st, fin, _k=kind: (
                 pk.fused_slab_update(_k, w, g, st, 0.05, 1.0 / 128, fin,
-                                     **kw)))
+                                     interpret=smoke.rehearsal, **kw)))
             plain = jax.jit(lambda w, g, st, fin, _k=kind: (
                 pk.slab_update_reference(_k, w, g, st, 0.05, 1.0 / 128,
                                          fin, **kw)))
@@ -500,7 +504,7 @@ def leg_generate(smoke):
               requests=len(prompts), tokens=sum(len(t) for t in outs),
               prefill_T=long_T,
               prefill_attention="pallas x%d" % n_long if n_long
-              else "reference (interpreter off the chip)",
+              else "plain form (no Mosaic off the chip)",
               cache_on=sorted(cache_platforms),
               worst_logit_gap_vs_full_forward="%.2e" % worst_gap)
     check(worst_gap <= 0.05,
@@ -520,8 +524,6 @@ def leg_train_dpn(smoke):
     n = len(smoke.devices)
     sym = _resnet(smoke, "float32")
     os.environ["MXTPU_AMP"] = "bf16"
-    if smoke.rehearsal:  # the interpreter stands in for Mosaic
-        os.environ["MXTPU_FUSED_UPDATE_KERNEL"] = "1"
     try:
         mod = mx.mod.Module(
             sym, context=[smoke.ctx(i) for i in range(n)])
@@ -532,7 +534,6 @@ def leg_train_dpn(smoke):
         text = _inspect_step(smoke, mod, "train_dp%d" % n)
     finally:
         os.environ.pop("MXTPU_AMP", None)
-        os.environ.pop("MXTPU_FUSED_UPDATE_KERNEL", None)
     tag = "train_dp%d" % n
     n_mosaic = smoke.expect_mosaic(text, "slab kernel in the dp=%d step" % n)
     total, resident = trainer.opt_state_shard_info(mod._fused_opt)

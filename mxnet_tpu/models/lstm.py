@@ -9,7 +9,7 @@ reference:
 Plus a TPU-native variant, ``lstm_attention_lm``: a pure-JAX
 recurrence (lax.scan) with a causal self-attention readout over the
 hidden-state sequence, routed through the same attention dispatcher the
-transformer uses (ops.pallas_kernels.attention — reference / Pallas
+transformer uses (ops.kernels.attention — reference / Pallas
 flash / ring by mesh+length).
 """
 import numpy as np
@@ -116,7 +116,7 @@ def lstm_attention_lm(vocab=10000, num_hidden=256, num_embed=256,
                                                       head_dim)
         v = (hs @ params["wv"].astype(dtype)).reshape(B, T, n_heads,
                                                       head_dim)
-        from ..ops.pallas_kernels import attention as attn_dispatch
+        from ..ops.kernels import attention as attn_dispatch
 
         o = attn_dispatch(q, k, v, causal=True, mesh=mesh)
         ctx = o.reshape(B, T, num_hidden) @ params["wo"].astype(dtype)

@@ -76,9 +76,9 @@ def transformer_lm(vocab=32000, d_model=512, n_heads=8, n_layers=4,
         k = (x @ p["wk"].astype(dtype)).reshape(B, T, n_heads, head_dim)
         v = (x @ p["wv"].astype(dtype)).reshape(B, T, n_heads, head_dim)
         # ring (sp>1 mesh) / Pallas flash / reference selection lives in
-        # one place now — ops.pallas_kernels.attention — shared with the
+        # one place now — ops.kernels.attention — shared with the
         # LSTM attention readout (models/lstm.py)
-        from ..ops.pallas_kernels import attention as attn_dispatch
+        from ..ops.kernels import attention as attn_dispatch
 
         o = attn_dispatch(q, k, v, causal=True, mesh=mesh)
         return o.reshape(B, T, D) @ p["wo"].astype(dtype)
@@ -143,7 +143,7 @@ def transformer_lm_serving(vocab=32000, d_model=512, n_heads=8, n_layers=4,
       handling per-slot lengths, ring wraparound, and slot reuse.
     - ``prefill(params, cache, tokens[n, T], slots[n], lengths[n],
       mesh=None)`` → ``(cache, last_logits[n, vocab])``: a normal
-      causal forward (ops.pallas_kernels.attention dispatch, so an
+      causal forward (ops.kernels.attention dispatch, so an
       'sp' mesh routes long prompts through parallel/ring_attention)
       whose per-layer K/V scatter into the cache rows of ``slots`` —
       new sequences join a running batch mid-flight without touching
@@ -190,7 +190,7 @@ def transformer_lm_serving(vocab=32000, d_model=512, n_heads=8, n_layers=4,
                 "prefill bucket %d exceeds KV window %d" % (T, max_len))
         pe = jnp.asarray(pe_np[:T], dtype)
         x = jnp.take(params["embed"], tokens, axis=0).astype(dtype) + pe[None]
-        from ..ops.pallas_kernels import attention as attn_dispatch
+        from ..ops.kernels import attention as attn_dispatch
 
         ck, cv = cache["k"], cache["v"]
         for i in range(n_layers):

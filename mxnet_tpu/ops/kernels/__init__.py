@@ -1,0 +1,56 @@
+"""Pallas TPU kernels for the hot ops, one module a kernel family.
+
+The reference reaches for hand-written CUDA / cuDNN where the stock ops
+are too slow (SURVEY.md §2 N6 cudnn_*-inl.h, N18 mshadow). The TPU-native
+equivalent is Pallas: kernels that XLA cannot produce from jnp alone
+because they need explicit on-chip (VMEM) accumulation patterns.
+
+  flash        single-key flash attention (forward, dq / dkv, the
+               one-pass backward), ``attention`` (the ops' one dispatch)
+               and ``reference_attention`` (the oracle)
+  latent       latent attention's two-key flash pair and the pass over
+               its query
+  gmm          the expert layer's grouped matmul
+  ssd          the chunked state-space scan (Mamba-2)
+  gdn          the gated delta rule's chunk core
+  slab_update  the AMP optimizer step over a flat slab
+  conv         the conv-backward pair
+  common       what they share
+
+An op calls an entry exported here; the entry asks its family's
+``*_takes`` / plan whether it has tiles for the shapes and
+``common.on_tpu`` for the branch: the Mosaic kernels where the enclosing
+computation is LOWERED for the TPU (never the process default backend),
+the plain ``jax.lax`` / ``jax.numpy`` form of the same signature on every
+other platform, and the Pallas interpreter only where the caller says
+``interpret=True`` (the kernels' tests; ``common.INTERPRET`` is what the
+op-level callers pass, for the tests that reach a kernel through a
+model). Whatever else a test or a benchmark needs of a family (its
+``pallas_call`` wrappers, its VMEM count) it imports from the family's
+module.
+
+Layout convention matches ``parallel/ring_attention``: [B, T, H, D].
+"""
+from . import common
+from .conv import (
+    conv_bwd_filter, conv_bwd_input, conv_bwd_plan, conv_kernel_enabled)
+from .flash import (
+    attention, flash_attention, flash_tiles, reference_attention)
+from .gdn import gated_delta_rule, gdn_takes
+from .gmm import (
+    gmm_metadata, gmm_row_tile, gmm_runs_kernel, gmm_tiles, grouped_matmul)
+from .latent import (
+    latent_flash, latent_flash_takes, latent_query, latent_query_takes)
+from .slab_update import (
+    SLAB_STATE_SLOTS, fused_slab_update, slab_update_reference)
+from .ssd import ssd_scan, ssd_takes
+
+__all__ = [
+    "attention", "common", "conv_bwd_filter", "conv_bwd_input",
+    "conv_bwd_plan", "conv_kernel_enabled", "flash_attention",
+    "flash_tiles", "fused_slab_update", "gated_delta_rule", "gdn_takes",
+    "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
+    "grouped_matmul", "latent_flash", "latent_flash_takes", "latent_query",
+    "latent_query_takes", "reference_attention", "SLAB_STATE_SLOTS",
+    "slab_update_reference", "ssd_scan", "ssd_takes",
+]

@@ -1,0 +1,956 @@
+"""Flash attention. What a (q tile, k tile) pair costs is decided in three
+places, all below:
+
+  operands  the blocks go to the MXU in the type they arrive in (bf16
+            stays bf16, one pass; float32 stays float32) and every
+            product accumulates in float32. Scores, mask, running max,
+            exp, row sums, lse, delta and the accumulators are float32;
+            p and dS are rounded to the operand type before the four
+            products that consume them — the rounding the kernel's own
+            output takes anyway.
+  tiles     ``flash_tiles`` picks (block_q, block_k) from (T, D, dtype)
+            and a VMEM budget: a grid step costs ~0.3 us whatever it
+            holds, so a 128 x 128 tile (0.04 us of bf16 work) is all
+            overhead — so much so that the operand type changes
+            nothing there (PERF.md section 6, PR 28).
+  causal    a dead tile (above the diagonal) is skipped by ``pl.when``
+            AND its index map names the tile already resident, so no
+            DMA is issued for it; the iota/compare/select mask runs only
+            on tiles the diagonal crosses or that hold padding.
+  window    a causal window of w keys (query i sees keys i-w+1 .. i)
+            shortens the grid's inner dimension to the tiles a band
+            can touch (``_band_steps``): inner step j visits block
+            ``first live + j``, so tiles below the band are neither
+            fetched nor stepped over; the band's lower edge is one more
+            compare in the mask.
+  heads     G key/value heads serve H = G * group query heads: q head
+            b reads k/v head b // group through the index maps; dkv's
+            inner dimension walks the group's q heads one after
+            another and sums their dK/dV in the one accumulator. The
+            value width may differ from the query/key width.
+  backward  one pass (``_bwd_fused_kernel``: P and dS built once a tile
+            pair, five products) where dK and dV of a whole key/value
+            head fit VMEM beside a step's tiles (``bwd_fuses``: shapes
+            and operand type alone decide); dq and dkv (seven products,
+            P and dS twice) for the sequences too long for that.
+  sink      a per-head logit that joins the softmax's denominator and
+            no value: applied to the forward kernel's (out, lse) by
+            ``_apply_sink`` outside it; the backward kernels rebuild P
+            from the lse that holds it and need nothing else.
+
+forward / dq / the one-pass backward: grid (B*H, nq, nk), k innermost;
+dkv: grid (B*G, nk, group * nq).
+The output block index map ignores the innermost dimension, so Mosaic
+keeps the output resident in VMEM while the inner loop accumulates into
+scratch; one (block_q, block_k) tile pair is on-chip at a time. The
+one-pass backward's dK / dV blocks ignore the q tile too (and the q
+head within its group): they stay for the whole key/value head.
+dS = P * (dP - delta), P = exp(S - L), dP = dO V^T,
+delta_i = sum_d dO_id * O_id.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ... import telemetry as _tm
+from . import common
+from .common import (
+    NEG_INF, VMEM_RAISED_LIMIT, VMEM_SCOPED_DEFAULT, affine, no_x64, on_tpu,
+    operand_label, pad_to, whole_lanes)
+
+_M_FLASH_LOWERINGS = _tm.counter(
+    "attention.flash_lowerings", "Traces of a flash_attention call site "
+    "(one per lowering, nothing per step); labels: operands (the type "
+    "the MXU is fed), block_q, block_k and, where the call has them, "
+    "window, kv_heads (fewer than the query heads), dv (a value width "
+    "other than the query's). Where a site's backward is traced it "
+    "counts once more under operands, block_q, block_k, window and bwd: "
+    "fused (dq, dk and dv in one pass) or split (dq and dkv)")
+
+# Forward, dq and dkv ask Mosaic for no more than its scoped default
+# (``VMEM_SCOPED_DEFAULT``), and their tiles are chosen under it as
+# ``flash_vmem_bytes`` counts a grid step. The one-pass backward keeps dK
+# and dV of a whole key/value head beside such a step's tiles, so it
+# states its count as its own limit (44 MiB at T 8,192 and widths 192 /
+# 128 in bf16) and is taken only where that count stays under
+# ``VMEM_RAISED_LIMIT``: T 16,384 at 192 / 128 counts 68 MiB and T 32,768
+# 116, those keep dq and dkv.
+# Largest tiles worth taking, by measurement on the v5e (T 2048-8192,
+# D 64-256, bf16 and float32, causal and not: 1024 x 1024 is the fastest
+# or within 1% of it everywhere, 2048 is slower again; PERF.md section 7).
+FLASH_MAX_BLOCK_Q = 1024
+FLASH_MAX_BLOCK_K = 1024
+FLASH_MIN_BLOCK = 128
+
+
+def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None):
+    """Upper bound on the VMEM one grid step of the widest kernel holds:
+    double-buffered operand and result tiles, the float32 accumulators
+    and two float32 score-shaped temporaries, as dkv has them; with
+    ``resident`` = (t_pad, d, dv), plus what the one-pass backward keeps
+    for a whole key/value head: dK and dV in float32 scratch and their
+    double-buffered output blocks, and a third score-shaped temporary.
+    Against the smallest limit Mosaic compiles each shape under (v5e,
+    1-2 MiB steps), counted / needed:
+
+    ====================================================  =======  ======
+    dkv, 1024 x 1024, D 128, bf16                          12 MiB   10
+    dkv, the same in float32                                15       12
+    dkv, D 256, float32                                     22       16
+    dkv, 2048 x 2048                                        40       40
+    one pass, T 8192, 192 / 128, bf16 (Kanana)              44       39
+    one pass, T 4096, 128 / 128, bf16 (OLMoE)               24       18
+    one pass, T 4096, 192 / 128, bf16, 8 heads on 1 (MiMo)  32       18
+    one pass, the same, 256 x 256 under a window of 128     14.75    9
+    one pass, T 4096, 128 / 128, float32                    31       27
+    ====================================================  =======  ======
+    """
+    lanes = whole_lanes(d)
+    row_tiles = 2 * 2 * block_q * lanes * itemsize      # q, dO
+    col_tiles = 2 * 4 * block_k * lanes * itemsize      # k, v, dk, dv
+    acc = 2 * block_k * lanes * 4
+    scores = 2 * block_q * block_k * 4
+    step = row_tiles + col_tiles + acc + scores
+    if resident is None:
+        return step
+    t_pad, d, dv = resident
+    return (step + block_q * block_k * 4    # P and dS both outlive dP
+            + t_pad * (whole_lanes(d) + whole_lanes(dv)) * (4 + 2 * itemsize))
+
+
+def bwd_fuses(t_pad, block_q, block_k, d, dv, dtype):
+    """Whether the backward of a call runs as one pass: decided by the
+    call's shapes and operand type alone, through what the pass would
+    hold in VMEM."""
+    return flash_vmem_bytes(
+        block_q, block_k, max(d, dv), jnp.dtype(dtype).itemsize,
+        resident=(t_pad, d, dv)) <= VMEM_RAISED_LIMIT
+
+
+def _one_tile(t):
+    """The tile that holds a whole short sequence: the next power of
+    two, 8 at the least."""
+    return max(8, 1 << (t - 1).bit_length())
+
+
+def flash_tiles(t, d, dtype, window=0):
+    """(block_q, block_k) for a sequence of ``t`` positions, head size
+    ``d`` (the wider of query and value), operands of ``dtype``: the
+    largest powers of two up to the measured caps whose working set fits
+    ``VMEM_SCOPED_DEFAULT`` and that pad ``t`` by no more than an eighth
+    over what 128-wide tiles would. A sequence shorter than the smallest
+    tile gets one tile of its own size (the next power of two, 8 at the
+    least). Under a causal ``window`` the band of a q tile of B rows
+    crosses two k tiles of B >= window keys, B * window of their 2 B^2
+    scores live: square tiles of twice the window, where the products
+    of a step weigh about what the step itself costs."""
+    if t < FLASH_MIN_BLOCK:
+        return _one_tile(t), _one_tile(t)
+    if window:
+        block = min(max(2 * _one_tile(window), FLASH_MIN_BLOCK),
+                    FLASH_MAX_BLOCK_Q, _one_tile(t))
+        return block, block
+    itemsize = jnp.dtype(dtype).itemsize
+    t_min = -(-t // FLASH_MIN_BLOCK) * FLASH_MIN_BLOCK
+
+    def pads_little(blk):
+        return -(-t // blk) * blk * 8 <= t_min * 9
+
+    block_q = block_k = FLASH_MIN_BLOCK
+    # k first: a wider k tile amortises the per-step cost without
+    # lengthening the accumulators
+    while (block_k * 2 <= FLASH_MAX_BLOCK_K and pads_little(block_k * 2)
+           and flash_vmem_bytes(block_q, block_k * 2, d, itemsize)
+           <= VMEM_SCOPED_DEFAULT):
+        block_k *= 2
+    while (block_q * 2 <= FLASH_MAX_BLOCK_Q and pads_little(block_q * 2)
+           and flash_vmem_bytes(block_q * 2, block_k, d, itemsize)
+           <= VMEM_SCOPED_DEFAULT):
+        block_q *= 2
+    return block_q, block_k
+
+
+def _causal_block_live(qi, ki, block_q, block_k):
+    """Whether k block ki intersects the causal triangle of q block qi."""
+    return jax.lax.le(affine(ki, block_k),
+                      affine(qi, block_q, block_q - 1))
+
+
+def last_live_k(qi, block_q, block_k):
+    """The last k block that ``_causal_block_live`` admits for q block
+    qi: what the k/v index maps of forward and dq clamp to."""
+    return jax.lax.div(affine(qi, block_q, block_q - 1),
+                       np.int32(block_k))
+
+
+def _first_live_q(ki, block_q, block_k):
+    """The first q block that ``_causal_block_live`` admits for k block
+    ki: what the q/dO/lse/delta index maps of dkv clamp to."""
+    return jax.lax.div(affine(ki, block_k), np.int32(block_q))
+
+
+def _first_live_k(qi, block_q, block_k, window):
+    """The first k block that holds a key inside the window of q block
+    qi's first row."""
+    return jax.lax.div(
+        jax.lax.max(affine(qi, block_q, 1 - window), np.int32(0)),
+        np.int32(block_k))
+
+
+def _last_live_q(ki, block_q, block_k, window, nq):
+    """The last q block that holds a row whose window reaches k block
+    ki's last key."""
+    return jax.lax.min(
+        jax.lax.div(affine(ki, block_k, block_k + window - 2),
+                    np.int32(block_q)),
+        np.int32(nq - 1))
+
+
+def _band_steps(nq, nk, block_q, block_k, window, inner):
+    """Extent of the grid's inner dimension under a window: the most
+    inner blocks the band of any one outer block touches."""
+    if inner == "k":
+        return max((i * block_q + block_q - 1) // block_k
+                   - max(i * block_q + 1 - window, 0) // block_k + 1
+                   for i in range(nq))
+    return max(min((i * block_k + block_k + window - 2) // block_q, nq - 1)
+               - (i * block_k) // block_q + 1 for i in range(nk))
+
+
+def _inner_k(qi, j, *, block_q, block_k, window):
+    """The k block that inner step j of q block qi visits (forward,
+    dq): j itself, or the j-th of the band."""
+    if not window:
+        return j
+    return jax.lax.add(_first_live_k(qi, block_q, block_k, window), j)
+
+
+def _inner_q(ki, j, *, block_q, block_k, window, steps, group):
+    """(q head within the group, q block) that inner step j of k block
+    ki visits (dkv): the group's heads one after another, ``steps``
+    blocks each."""
+    head = None
+    if group > 1:
+        head = jax.lax.div(j, np.int32(steps))
+        j = jax.lax.rem(j, np.int32(steps))
+    if window:
+        j = jax.lax.add(_first_live_q(ki, block_q, block_k), j)
+    return head, j
+
+
+def _masked_scores(q, k_blk, qi, ki, *, block_q, block_k, t_real, scale,
+                   causal, window=0, masked=True):
+    """The shared score/mask invariant of all three kernels:
+    s = scale·q@kᵀ on the MXU plus the (padding, causal, window)
+    keep-mask for this (qi, ki) block pair — None for a tile
+    ``tile_cases`` found to need none. Kept in ONE place so forward and
+    backward can never disagree on masking."""
+    s = jnp.float32(scale) * jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )  # [bq, bk]
+    if not masked:
+        return s, None
+    q_pos = qi * jnp.int32(block_q) + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0
+    )
+    k_pos = ki * jnp.int32(block_k) + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1
+    )
+    mask = k_pos < jnp.int32(t_real)
+    if causal:
+        mask = mask & (q_pos >= k_pos)
+    if window:
+        mask = mask & (q_pos - k_pos < jnp.int32(window))
+    return s, mask
+
+
+def tile_cases(body, qi, ki, *, block_q, block_k, t_real, t_pad, causal,
+               window=0):
+    """Run ``body(masked)`` for this tile pair at what it holds: not at
+    all for a dead tile, with the mask where the diagonal or the
+    window's edge crosses it or it holds padding keys, without it
+    everywhere else."""
+    live = needs_mask = None  # None: statically "always" / "never"
+    if causal:
+        live = _causal_block_live(qi, ki, block_q, block_k)
+        needs_mask = jax.lax.gt(affine(ki, block_k, block_k - 1),
+                                affine(qi, block_q))
+    if window:
+        # the band's inner steps start at its first block, so a dead
+        # tile lies above the diagonal (forward, dq) or below the band
+        # or past the last q block (dkv)
+        live = jax.lax.bitwise_and(live, jax.lax.bitwise_and(
+            jax.lax.ge(affine(ki, block_k, block_k + window - 2),
+                       affine(qi, block_q)),
+            jax.lax.lt(qi, np.int32(t_pad // block_q))))
+        needs_mask = jax.lax.bitwise_or(needs_mask, jax.lax.ge(
+            affine(qi, block_q, block_q - 1),
+            affine(ki, block_k, window)))
+    if t_real < t_pad:
+        pads = jax.lax.gt(affine(ki, block_k, block_k), np.int32(t_real))
+        needs_mask = (pads if needs_mask is None
+                      else jax.lax.bitwise_or(needs_mask, pads))
+    if needs_mask is None:
+        body(False)
+        return
+    unmasked = jax.lax.bitwise_not(needs_mask)
+    if live is not None:
+        needs_mask = jax.lax.bitwise_and(live, needs_mask)
+        unmasked = jax.lax.bitwise_and(live, unmasked)
+    pl.when(needs_mask)(lambda: body(True))
+    pl.when(unmasked)(lambda: body(False))
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
+                *, block_q, block_k, t_real, t_pad, scale, causal, window):
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    ki = _inner_k(qi, j, block_q=block_q, block_k=block_k, window=window)
+
+    @pl.when(j == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, jnp.float32(NEG_INF))
+        l_s[...] = jnp.zeros_like(l_s)
+
+    def body(masked):
+        v_blk = v_ref[0]  # [bk, Dv]
+        s, mask = _masked_scores(
+            q_ref[0], k_ref[0], qi, ki, block_q=block_q, block_k=block_k,
+            t_real=t_real, scale=scale, causal=causal, window=window,
+            masked=masked)
+        if masked:
+            s = jnp.where(mask, s, jnp.float32(NEG_INF))
+        m_prev = m_s[...]  # [bq, 1]
+        m_cur = jax.lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jax.lax.exp(m_prev - m_cur)
+        p = jax.lax.exp(s - m_cur)
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_s[...] = m_cur
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        l_fin = l_s[...]
+        safe_l = jnp.where(l_fin > 0, l_fin, jnp.float32(1.0))
+        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
+        # logsumexp residual for backward
+        l_ref[0] = m_s[...] + jnp.log(safe_l)
+
+
+def _bwd_p_ds(q, k_blk, v_blk, do, lse, delta, qi, ki, masked, **tile):
+    """P and dS of one tile pair, rounded to the operand type: what dq
+    and dkv both rebuild from the residuals."""
+    s, mask = _masked_scores(q, k_blk, qi, ki, masked=masked, **tile)
+    p = jax.lax.exp(s - lse)
+    if masked:
+        p = jnp.where(mask, p, jnp.float32(0.0))
+    dp = jax.lax.dot_general(
+        do, v_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    ds = p * (dp - delta)
+    return p.astype(do.dtype), ds.astype(q.dtype)
+
+
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
+                   dq_acc, *, block_q, block_k, t_real, t_pad, scale,
+                   causal, window):
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    ki = _inner_k(qi, j, block_q=block_q, block_k=block_k, window=window)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def body(masked):
+        k_blk = k_ref[0]
+        _, ds = _bwd_p_ds(
+            q_ref[0], k_blk, v_ref[0], do_ref[0], l_ref[0], d_ref[0],
+            qi, ki, masked, block_q=block_q, block_k=block_k,
+            t_real=t_real, scale=scale, causal=causal, window=window)
+        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+            ds, k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = (jnp.float32(scale) * dq_acc[...]).astype(dq_ref.dtype)
+
+
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
+                    t_real, t_pad, scale, causal, window, steps, group):
+    ki = pl.program_id(1)
+    j = pl.program_id(2)
+    _, qi = _inner_q(ki, j, block_q=block_q, block_k=block_k,
+                     window=window, steps=steps, group=group)
+
+    @pl.when(j == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def body(masked):
+        q = q_ref[0]  # [bq, D]
+        do = do_ref[0]
+        p, ds = _bwd_p_ds(
+            q, k_ref[0], v_ref[0], do, l_ref[0], d_ref[0], qi, ki,
+            masked, block_q=block_q, block_k=block_k, t_real=t_real,
+            scale=scale, causal=causal, window=window)
+        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [bk, Dv]
+        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[0] = (jnp.float32(scale) * dk_acc[...]).astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
+                      dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, block_q,
+                      block_k, t_real, t_pad, scale, causal, window, group):
+    """dq, dk and dv in one pass: P and dS are built once a tile pair and
+    feed all three products. The grid is dq's, (q head, q tile, k step):
+    dq accumulates in tile-sized scratch over the inner steps; dk and dv
+    accumulate in float32 scratch that holds the key/value head whole
+    ([k tile, row, column]) over every q tile of every q head of its
+    group, and are written out at the group's last step. The inner steps
+    walk a row's k tiles from the diagonal down to the first, so a row's
+    dead steps come first and its last step is a live one: the next
+    row's q, dO, lse and delta arrive under a step that computes."""
+    b = pl.program_id(0)
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    ki = _inner_k(qi, jax.lax.sub(pl.num_programs(2) - 1, j),
+                  block_q=block_q, block_k=block_k, window=window)
+    first = jax.lax.bitwise_and(jax.lax.eq(qi, np.int32(0)),
+                                jax.lax.eq(j, np.int32(0)))
+    last = jax.lax.bitwise_and(
+        jax.lax.eq(qi, pl.num_programs(1) - 1),
+        jax.lax.eq(j, pl.num_programs(2) - 1))
+    if group > 1:
+        head = jax.lax.rem(b, np.int32(group))
+        first = jax.lax.bitwise_and(first, jax.lax.eq(head, np.int32(0)))
+        last = jax.lax.bitwise_and(
+            last, jax.lax.eq(head, np.int32(group - 1)))
+
+    def each_k_tile(fn):
+        def step(i, carry):
+            fn(i)
+            return carry
+        jax.lax.fori_loop(0, dk_acc.shape[0], step, 0)
+
+    @pl.when(first)
+    def _():
+        def zero(i):
+            dk_acc[i] = jnp.zeros(dk_acc.shape[1:], jnp.float32)
+            dv_acc[i] = jnp.zeros(dv_acc.shape[1:], jnp.float32)
+        each_k_tile(zero)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def body(masked):
+        q = q_ref[0]
+        k_blk = k_ref[0]
+        do = do_ref[0]
+        p, ds = _bwd_p_ds(
+            q, k_blk, v_ref[0], do, l_ref[0], d_ref[0], qi, ki, masked,
+            block_q=block_q, block_k=block_k, t_real=t_real, scale=scale,
+            causal=causal, window=window)
+        dv_acc[ki] = dv_acc[ki] + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [bk, Dv]
+        dk_acc[ki] = dk_acc[ki] + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+            ds, k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = (jnp.float32(scale) * dq_acc[...]).astype(dq_ref.dtype)
+
+    @pl.when(last)
+    def _():
+        def write(i):
+            dk_ref[0, i] = (jnp.float32(scale) * dk_acc[i]).astype(
+                dk_ref.dtype)
+            dv_ref[0, i] = dv_acc[i].astype(dv_ref.dtype)
+        each_k_tile(write)
+
+
+# ---------------------------------------------------------------------------
+# host-side wrappers
+# ---------------------------------------------------------------------------
+
+def _kernel_name(which, dtype, block_q, block_k, window=0):
+    return "flash_%s_%s_q%d_k%d%s" % (
+        which, operand_label(dtype), block_q, block_k,
+        "_w%d" % window if window else "")
+
+
+_FLASH_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _tile_specs(block_q, block_k, d, dv, causal, inner, *, window=0,
+                group=1, nq=0, steps=0, reverse=False):
+    """Block specs of a q-shaped tile, a k-shaped tile, their
+    value-width twins and a per-row statistic for a grid whose innermost
+    dimension walks ``inner`` ("k": forward, dq and the one-pass
+    backward, grid (bh, nq, nk), the last with ``reverse``: its ``steps``
+    inner steps walk downwards; "q": dkv, grid (bg, nk, group * nq)).
+    Under ``causal`` the streamed operand's index clamps to the row's
+    (column's) live range: a dead step names the tile already resident
+    and fetches nothing. The leading index is a q head for q-shaped
+    tiles and the key/value head it reads for k-shaped ones."""
+    tile = dict(block_q=block_q, block_k=block_k, window=window)
+    if inner == "k":
+        def q_idx(b, i, j):
+            return (b, i, 0)
+
+        def k_idx(b, i, j):
+            if reverse:
+                j = jax.lax.sub(np.int32(steps - 1), j)
+            j = _inner_k(i, j, **tile)
+            if causal:
+                j = jax.lax.min(j, last_live_k(i, block_q, block_k))
+            if group > 1:
+                b = jax.lax.div(b, np.int32(group))
+            return (b, j, 0)
+    else:
+        def q_idx(b, i, j):
+            head, j = _inner_q(i, j, steps=steps, group=group, **tile)
+            if window:
+                j = jax.lax.min(
+                    j, _last_live_q(i, block_q, block_k, window, nq))
+            elif causal:
+                j = jax.lax.max(j, _first_live_q(i, block_q, block_k))
+            if head is not None:
+                b = jax.lax.add(affine(b, group), head)
+            return (b, j, 0)
+
+        def k_idx(b, i, j):
+            return (b, i, 0)
+    return (pl.BlockSpec((1, block_q, d), q_idx),
+            pl.BlockSpec((1, block_k, d), k_idx),
+            pl.BlockSpec((1, block_q, dv), q_idx),
+            pl.BlockSpec((1, block_k, dv), k_idx),
+            pl.BlockSpec((1, block_q, 1), q_idx))
+
+
+def fwd_call(q3, k3, v3, *, t_real, scale, causal, window, block_q,
+             block_k, interpret):
+    bh, t_pad, d = q3.shape
+    dv = v3.shape[2]
+    group = bh // k3.shape[0]
+    nq = t_pad // block_q
+    nk = t_pad // block_k
+    kern = functools.partial(
+        _fwd_kernel, block_q=block_q, block_k=block_k, t_real=t_real,
+        t_pad=t_pad, scale=scale, causal=causal, window=window,
+    )
+    q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+        block_q, block_k, d, dv, causal, "k", window=window, group=group)
+    inner = (_band_steps(nq, nk, block_q, block_k, window, "k")
+             if window else nk)
+    with no_x64():
+        out, lse = pl.pallas_call(
+            kern,
+            grid=(bh, nq, inner),
+            in_specs=[q_spec, k_spec, v_spec],
+            out_specs=[o_spec, row_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, t_pad, dv), q3.dtype),
+                jax.ShapeDtypeStruct((bh, t_pad, 1), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+            ],
+            compiler_params=_FLASH_PARAMS,
+            name=_kernel_name("fwd", q3.dtype, block_q, block_k, window),
+            interpret=interpret,
+        )(q3, k3, v3)
+    return out, lse
+
+
+def _bwd_fused_call(q3, k3, v3, do3, lse, delta, *, interpret, **tile):
+    bh, t_pad, d = q3.shape
+    bg, dv = k3.shape[0], v3.shape[2]
+    group = bh // bg
+    block_q, block_k = tile["block_q"], tile["block_k"]
+    causal, window = tile["causal"], tile["window"]
+    nq = t_pad // block_q
+    nk = t_pad // block_k
+    steps = (_band_steps(nq, nk, block_q, block_k, window, "k")
+             if window else nk)
+    q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+        block_q, block_k, d, dv, causal, "k", window=window, group=group,
+        steps=steps, reverse=True)
+
+    def whole_head(width):
+        def idx(b, i, j):
+            if group > 1:
+                b = jax.lax.div(b, np.int32(group))
+            return (b, 0, 0, 0)
+        return pl.BlockSpec((1, nk, block_k, width), idx)
+
+    dq, dk, dv_ = pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, group=group, **tile),
+        grid=(bh, nq, steps),
+        in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
+        out_specs=[q_spec, whole_head(d), whole_head(dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
+            jax.ShapeDtypeStruct((bg, nk, block_k, d), q3.dtype),
+            jax.ShapeDtypeStruct((bg, nk, block_k, dv), q3.dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((nk, block_k, d), jnp.float32),
+            pltpu.VMEM((nk, block_k, dv), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            # dk / dv accumulate over the q tiles, and over the q heads
+            # of a group
+            dimension_semantics=(
+                "parallel" if group == 1 else "arbitrary", "arbitrary",
+                "arbitrary"),
+            vmem_limit_bytes=flash_vmem_bytes(
+                block_q, block_k, max(d, dv), q3.dtype.itemsize,
+                resident=(t_pad, d, dv))),
+        name=_kernel_name("bwd", q3.dtype, block_q, block_k, window),
+        interpret=interpret,
+    )(q3, k3, v3, do3, lse, delta)
+    return dq, dk.reshape(bg, t_pad, d), dv_.reshape(bg, t_pad, dv)
+
+
+def bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
+             window, block_q, block_k, interpret, fused=False):
+    bh, t_pad, d = q3.shape
+    bg, dv = k3.shape[0], v3.shape[2]
+    group = bh // bg
+    nq = t_pad // block_q
+    nk = t_pad // block_k
+    tile = dict(block_q=block_q, block_k=block_k, t_real=t_real,
+                t_pad=t_pad, scale=scale, causal=causal, window=window)
+    with no_x64():
+        if fused:
+            return _bwd_fused_call(q3, k3, v3, do3, lse, delta,
+                                   interpret=interpret, **tile)
+        q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+            block_q, block_k, d, dv, causal, "k", window=window,
+            group=group)
+        inner = (_band_steps(nq, nk, block_q, block_k, window, "k")
+                 if window else nk)
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, **tile),
+            grid=(bh, nq, inner),
+            in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=_FLASH_PARAMS,
+            name=_kernel_name("dq", q3.dtype, block_q, block_k, window),
+            interpret=interpret,
+        )(q3, k3, v3, do3, lse, delta)
+        steps = (_band_steps(nq, nk, block_q, block_k, window, "q")
+                 if window else nq)
+        q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
+            block_q, block_k, d, dv, causal, "q", window=window,
+            group=group, nq=nq, steps=steps)
+        dk, dv_ = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, steps=steps, group=group,
+                              **tile),
+            grid=(bg, nk, group * steps),
+            in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
+            out_specs=[k_spec, v_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((bg, t_pad, d), q3.dtype),
+                jax.ShapeDtypeStruct((bg, t_pad, dv), q3.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, dv), jnp.float32),
+            ],
+            compiler_params=_FLASH_PARAMS,
+            name=_kernel_name("dkv", q3.dtype, block_q, block_k, window),
+            interpret=interpret,
+        )(q3, k3, v3, do3, lse, delta)
+    return dq, dk, dv_
+
+
+def _keep(t, causal, window):
+    """The [T, T] keep-mask of a causal call (a query sees the keys up to
+    its own, the last ``window`` of them under a window), None of a call
+    that is not: ``reference_attention``'s and the plain pair's."""
+    if not causal:
+        return None
+    pos = np.arange(t)
+    mask = pos[:, None] >= pos[None, :]
+    if window:
+        mask &= pos[:, None] - pos[None, :] < window
+    return mask
+
+
+def _plain_scores(q3, k3, v3, t_real, scale, causal, window):
+    """The real rows of the padded [BH, T, d] operands, key and value
+    heads repeated over their group, and their masked float32 scores."""
+    group = q3.shape[0] // k3.shape[0]
+    q, k, v = (x[:, :t_real] for x in (q3, k3, v3))
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=0) for x in (k, v))
+    s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
+    mask = _keep(t_real, causal, window)
+    if mask is not None:
+        s = jnp.where(mask[None], s, NEG_INF)
+    return q, k, v, s
+
+
+def plain_fwd(q3, k3, v3, *, t_real, scale, causal, window, **tiles):
+    """``fwd_call`` in ``jax.numpy``: ``reference_attention``'s arithmetic
+    on the kernel's operands -> (out, lse), padded as the kernel's. The
+    branch for every platform but the TPU."""
+    _, _, v, s = _plain_scores(q3, k3, v3, t_real, scale, causal, window)
+    out = jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, axis=-1),
+                     v.astype(jnp.float32)).astype(q3.dtype)
+    lse = jax.nn.logsumexp(s, axis=-1, keepdims=True)
+    pad = ((0, 0), (0, q3.shape[1] - t_real), (0, 0))
+    return jnp.pad(out, pad), jnp.pad(lse, pad)
+
+
+def plain_bwd(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
+               window, **tiles):
+    """``bwd_call`` in ``jax.numpy``, float32 throughout: ``p = exp(s -
+    lse)``, ``ds = p * (dp - delta)`` -> (dq, dk, dv), the key and value
+    heads' summed over their group, padded and typed as the kernel's."""
+    f32 = jnp.float32
+    bg = k3.shape[0]
+    q, k, v, s = _plain_scores(q3, k3, v3, t_real, scale, causal, window)
+    q, k, v, do = (x.astype(f32) for x in (q, k, v, do3[:, :t_real]))
+    p = jnp.exp(s - lse[:, :t_real])
+    ds = p * (jnp.einsum("bqd,bkd->bqk", do, v) - delta[:, :t_real])
+    pad = ((0, 0), (0, q3.shape[1] - t_real), (0, 0))
+
+    def shaped(x, heads):
+        x = x.reshape(heads, -1, *x.shape[1:]).sum(axis=1)
+        return jnp.pad(x, pad).astype(q3.dtype)
+
+    return (shaped(scale * jnp.einsum("bqk,bkd->bqd", ds, k), q3.shape[0]),
+            shaped(scale * jnp.einsum("bqk,bqd->bkd", ds, q), bg),
+            shaped(jnp.einsum("bqk,bqd->bkd", p, do), bg))
+
+
+@functools.partial(
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10)
+)
+def _flash(q3, k3, v3, sink, t_real, scale, causal, window, block_q,
+           block_k, interpret):
+    out, _ = _flash_fwd(q3, k3, v3, sink, t_real, scale, causal, window,
+                        block_q, block_k, interpret)
+    return out
+
+
+def _static(t_real, scale, causal, window, block_q, block_k):
+    """A call's static arguments, as both of a pair's forms take them."""
+    return dict(t_real=t_real, scale=scale, causal=causal, window=window,
+                block_q=block_q, block_k=block_k)
+
+
+def _flash_fwd(q3, k3, v3, sink, t_real, scale, causal, window, block_q,
+               block_k, interpret):
+    call = _static(t_real, scale, causal, window, block_q, block_k)
+    out, lse = on_tpu(functools.partial(fwd_call, **call),
+                      functools.partial(plain_fwd, **call), interpret,
+                      q3, k3, v3)
+    if sink is not None:
+        with_sink = jnp.logaddexp(lse, sink[:, None, None])
+        out = (out.astype(jnp.float32)
+               * jnp.exp(lse - with_sink)).astype(out.dtype)
+        lse = with_sink
+    return out, (q3, k3, v3, sink, out, lse)
+
+
+def _flash_bwd(t_real, scale, causal, window, block_q, block_k, interpret,
+               res, g):
+    q3, k3, v3, sink, out, lse = res
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+        keepdims=True,
+    )  # [BH, T, 1]
+    fused = bwd_fuses(q3.shape[1], block_q, block_k, q3.shape[2],
+                      v3.shape[2], q3.dtype)
+    _M_FLASH_LOWERINGS.inc(
+        operands=operand_label(q3.dtype), block_q=block_q,
+        block_k=block_k, window=window, bwd="fused" if fused else "split")
+    call = _static(t_real, scale, causal, window, block_q, block_k)
+    dq, dk, dv = on_tpu(
+        functools.partial(bwd_call, fused=fused, **call),
+        functools.partial(plain_bwd, **call), interpret,
+        q3, k3, v3, g.astype(q3.dtype), lse, delta)
+    dsink = None
+    if sink is not None:
+        # the sink's probability exp(sink - lse) meets a zero value:
+        # d sink = sum_i p_sink,i * (0 - delta_i)
+        dsink = -jnp.sum(jnp.exp(sink[:, None, None] - lse) * delta,
+                         axis=(1, 2))
+    return dq, dk, dv, dsink
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _heads_first(x):
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
+def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
+                    block_k=None, window=0, sink=None, interpret=False):
+    """Blockwise (flash) attention. q [B, T, H, D], k [B, T, G, D],
+    v [B, T, G, Dv] -> [B, T, H, Dv]; H a multiple of G, query head h
+    reads key/value head ``h // (H / G)``.
+
+    Pallas MXU kernels where the computation is lowered for the TPU and
+    ``reference_attention``'s arithmetic in ``jax.numpy`` on every other
+    platform, the choice made inside the ``custom_vjp``
+    (``common.on_tpu``); ``interpret=True`` (the kernels' tests) runs the
+    kernels through the Pallas interpreter wherever the computation is
+    lowered. The TPU-native replacement for what the reference delegates
+    to cuDNN fused kernels (cudnn_rnn-inl.h being the closest 2017 analog
+    of a fused sequence kernel).
+
+    The MXU is fed the type the inputs arrive in, accumulating in
+    float32; the softmax arithmetic is float32 whatever the inputs.
+    ``block_q`` / ``block_k`` default to ``flash_tiles(T, max(D, Dv),
+    dtype, window)``; pass them only to pin a tiling (tests,
+    benchmarks). ``window`` w > 0 (with ``causal``): query i sees keys
+    i-w+1 .. i, and no tile outside that band is fetched or computed.
+    ``sink`` [H]: a learnable logit per query head that joins each
+    row's softmax denominator and carries no value (float32 arithmetic;
+    differentiable).
+
+    NOTE: pallas_call has no GSPMD partitioning rules — inside pjit over a
+    sharded mesh, wrap calls in shard_map (see parallel/ring_attention for
+    the sp-sharded composition) or keep attention inputs replicated.
+    """
+    b, t, h, d = q.shape
+    g, dv = k.shape[2], v.shape[3]
+    if h % g or v.shape[2] != g or k.shape[3] != d:
+        raise ValueError(
+            "flash_attention: query %s, key %s, value %s: key and value "
+            "need one head count that divides the query's, and the key "
+            "the query's width" % (q.shape, k.shape, v.shape))
+    if window and not causal:
+        raise ValueError("flash_attention: a window needs causal=True")
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(d))
+    if block_q is None or block_k is None:
+        auto_q, auto_k = flash_tiles(t, max(d, dv), q.dtype, window)
+        block_q = block_q or auto_q
+        block_k = block_k or auto_k
+    if t < min(block_q, block_k):
+        block_q = block_k = _one_tile(t)
+    labels = dict(operands=operand_label(q.dtype), block_q=int(block_q),
+                  block_k=int(block_k))
+    if window or g != h or dv != d:
+        labels.update(window=int(window), kv_heads=int(g), dv=int(dv))
+    _M_FLASH_LOWERINGS.inc(**labels)
+    mult = int(np.lcm(block_q, block_k))
+    q3, k3, v3 = (pad_to(_heads_first(x), 1, mult)[0] for x in (q, k, v))
+    if sink is not None:
+        sink = jnp.tile(sink.astype(jnp.float32), b)  # [B*H], as q3's rows
+    out = _flash(q3, k3, v3, sink, t, float(scale), bool(causal),
+                 int(window), int(block_q), int(block_k), bool(interpret))
+    out = out[:, :t]
+    return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
+
+
+def reference_attention(q, k, v, causal=False, scale=None, window=0,
+                        sink=None):
+    """Materialized-scores attention, the correctness oracle for the
+    kernels (and the XLA path for tiny sequence lengths): shapes,
+    ``window`` and ``sink`` as ``flash_attention`` takes them."""
+    b, t, h, d = q.shape
+    group = h // k.shape[2]
+    if group > 1:
+        k = jnp.repeat(k, group, axis=2)
+        v = jnp.repeat(v, group, axis=2)
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(d))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    mask = _keep(t, causal, window)
+    if mask is not None:
+        s = jnp.where(mask[None, None], s, NEG_INF)
+    if sink is not None:
+        s = jnp.concatenate([s, jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None, None], (b, h, t, 1))],
+            axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :t]
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(
+        q.dtype
+    )
+
+
+def attention(q, k, v, causal=False, scale=None, mesh=None, window=0,
+              sink=None):
+    """Shared attention dispatch for every model that wants fused
+    attention without hand-picking a kernel: sequence-parallel ring
+    attention when the mesh shards the sequence axis, ``flash_attention``
+    at T >= 128 (the Pallas kernels where the step is lowered for the
+    TPU, the reference's arithmetic elsewhere), the materialized
+    reference otherwise.
+    q [B, T, H, D], k [B, T, G, D], v [B, T, G, Dv] -> [B, T, H, Dv];
+    ``window`` and ``sink`` as ``flash_attention`` takes them."""
+    if mesh is not None and mesh.shape.get("sp", 1) > 1:
+        from ...parallel.ring_attention import sequence_parallel_attention
+
+        if window or sink is not None or k.shape != q.shape:
+            raise ValueError("attention: window, sink and grouped heads "
+                             "are not implemented over an sp mesh")
+        return sequence_parallel_attention(q, k, v, mesh, causal=causal)
+    if mesh is None and q.shape[1] >= FLASH_MIN_BLOCK:
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window, sink=sink,
+                               interpret=common.INTERPRET)
+    return reference_attention(q, k, v, causal=causal, scale=scale,
+                               window=window, sink=sink)

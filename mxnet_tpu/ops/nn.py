@@ -424,32 +424,34 @@ def _conv2d_wgrad_taps(data, weight, stride, pad, dilate):
 def _pallas_conv_plan(data, weight, stride, pad, dilate, groups):
     """Dispatch-table lookup for the Pallas conv-backward pair.
 
-    Cheap env check first; the pallas_kernels import and the per-shape
-    envelope decision (memoized there) only run when
-    MXTPU_CONV_KERNEL=pallas is set. Returns the plan dict or None —
-    None falls through to the taps lever / XLA default below."""
+    Cheap env check first; the per-shape envelope decision (memoized in
+    ops/kernels/conv.py) only runs when MXTPU_CONV_KERNEL=pallas is set.
+    Returns the plan dict or None — None falls through to the taps lever /
+    XLA default below."""
     if groups != 1:
         return None
     try:
-        from . import pallas_kernels as _pk
+        from . import kernels
     except Exception:  # noqa: BLE001 — pallas unavailable: fall back
         return None
-    if not _pk.conv_kernel_enabled():
+    if not kernels.conv_kernel_enabled():
         return None
-    return _pk.conv_bwd_plan(tuple(data.shape), tuple(weight.shape),
-                             tuple(stride), tuple(pad), tuple(dilate),
-                             data.dtype)
+    return kernels.conv_bwd_plan(tuple(data.shape), tuple(weight.shape),
+                                 tuple(stride), tuple(pad), tuple(dilate),
+                                 data.dtype)
 
 
 def _conv2d_pallas_bwd(data, weight, pad):
     """Stride-1 2-D conv whose BOTH gradient convs are the Pallas
-    conv-backward pair (ops/pallas_kernels.conv_bwd_input/_filter):
+    conv-backward pair (ops/kernels.conv_bwd_input/_filter):
     im2col-free in-register tap accumulation, f32 accumulators, no
     lhs-dilated dgrad conv. Forward stays XLA's own lowering (it is
     already MXU-shaped). Only called for shapes inside the tuned
     envelope (_pallas_conv_plan); numerics pinned in
     tests/test_conv_kernels.py."""
-    from . import pallas_kernels as _pk
+    from . import kernels
+
+    interpret = kernels.common.INTERPRET
 
     def plain(d, w):
         return jax.lax.conv_general_dilated(
@@ -467,9 +469,11 @@ def _conv2d_pallas_bwd(data, weight, pad):
     def bwd(res, g):
         d, w = res
         with jax.named_scope("dgrad"):
-            gd = _pk.conv_bwd_input(g, w, d.shape, pad).astype(d.dtype)
+            gd = kernels.conv_bwd_input(
+                g, w, d.shape, pad, interpret=interpret).astype(d.dtype)
         with jax.named_scope("wgrad"):
-            gw = _pk.conv_bwd_filter(d, g, w.shape, pad).astype(w.dtype)
+            gw = kernels.conv_bwd_filter(
+                d, g, w.shape, pad, interpret=interpret).astype(w.dtype)
         return gd, gw
 
     conv.defvjp(fwd, bwd)

@@ -6,7 +6,7 @@ no sparse-expert layer): what a decoder-only LM with sparse experts
 (``models/olmoe.py``) needs to be an ``mx.sym`` graph that
 ``Module.fit`` trains through the fused step. Each op is a thin
 ``OpDef`` over one function kept elsewhere: ``Attention`` over the one
-attention dispatch ``ops/pallas_kernels.attention`` (flash kernel on the
+attention dispatch ``ops/kernels.attention`` (flash kernel on the
 TPU at T >= 128, the materialised reference elsewhere;
 ``LatentAttention`` projects its keys and values up from a latent first,
 for its own flash pair or the same dispatch), ``TopKMoE`` over
@@ -169,7 +169,7 @@ def _kv_heads(attrs):
 
 
 def _attention(attrs, ins, is_train):
-    from .pallas_kernels import attention
+    from .kernels import attention
 
     q, k, v = ins[:3]
     heads, kv_heads = int(attrs["num_heads"]), _kv_heads(attrs)
@@ -254,12 +254,12 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
     float32; the up-projection takes operands of ``latent``'s dtype and
     accumulates in float32. The attention itself has two forms, chosen
     by the shapes alone and counted under ``impl``: ``kernel`` where
-    ``pallas_kernels.latent_flash_takes`` admits them
+    ``kernels.latent_flash_takes`` admits them
     (``_latent_kernel_path`` below: the flash pair of two key operands
     where the step is lowered for the TPU), ``composed`` everywhere else
     (``_latent_composed_path``: the concatenated key through the one
     attention dispatch)."""
-    from .pallas_kernels import latent_flash_takes
+    from .kernels import latent_flash_takes
 
     width = latent.shape[2] - rope_dim
     nope = query.shape[2] // num_heads - rope_dim
@@ -344,7 +344,7 @@ def ssd_scan(x, bmat, cmat, dt, a, chunk):
     and the carried state are float32; the four products take operands of
     ``x``'s dtype and accumulate in float32. T is padded to whole chunks
     with ``dt`` 0 (no decay, no input) and the padding cut off. The form
-    for the shapes ``pallas_kernels.ssd_scan`` has no tiles for, and what
+    for the shapes ``kernels.ssd_scan`` has no tiles for, and what
     its tests hold it to."""
     f32 = jnp.float32
     b, t, h, p = x.shape
@@ -414,7 +414,7 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     convolution's sum, step sizes, decays, the carried state, the gate
     and the norm's statistics are float32 whatever ``proj``'s dtype. The
     scan is the Pallas kernel pair where the shapes have tiles for it and
-    the step is lowered for the TPU (``pallas_kernels.ssd_takes`` /
+    the step is lowered for the TPU (``kernels.ssd_takes`` /
     ``ssd_scan``; the skip inside it), the ``jnp.einsum`` form elsewhere.
     ``remat`` (training): the float32 tables of the convolution, of the
     gate and norm and of the einsum form are computed again in the
@@ -427,9 +427,9 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     itself is ``_mamba2_block``, one ``jax.jit`` for every node of one
     signature: a model's layers trace, differentiate and lower it once
     (XLA inlines the calls, each under its own node's scope)."""
-    from . import pallas_kernels
+    from . import kernels
 
-    kernel = bool(pallas_kernels.ssd_takes(
+    kernel = bool(kernels.ssd_takes(
         num_heads, head_dim, state_size, num_groups, chunk_size, proj.dtype))
     _M_SCAN_LOWERINGS.inc(heads=num_heads, head_dim=head_dim,
                           state=state_size, groups=num_groups,
@@ -438,16 +438,17 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     return _mamba2_block(
         proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
         sizes=(num_heads, head_dim, state_size, num_groups, chunk_size),
-        eps=float(eps), remat=bool(remat), kernel=kernel)
+        eps=float(eps), remat=bool(remat), kernel=kernel,
+        interpret=kernels.common.INTERPRET)
 
 
 @functools.partial(jax.jit, static_argnames=("sizes", "eps", "remat",
-                                             "kernel"))
+                                             "kernel", "interpret"))
 def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
-                  norm_gamma, *, sizes, eps, remat, kernel):
+                  norm_gamma, *, sizes, eps, remat, kernel, interpret):
     """``mamba2`` for one signature (``sizes``: heads, head width, state,
     groups, chunk)."""
-    from . import pallas_kernels
+    from . import kernels
 
     f32 = jnp.float32
     b, t, _ = proj.shape
@@ -485,7 +486,8 @@ def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
                              + dt_bias.astype(f32))
         a = -jnp.exp(a_log.astype(f32))
         if kernel:
-            y = pallas_kernels.ssd_scan(x, *bc, dt, a, d_skip, chunk)
+            y = kernels.ssd_scan(x, *bc, dt, a, d_skip, chunk,
+                                 interpret=interpret)
         else:
             y = again(functools.partial(ssd_scan, chunk=chunk),
                       policy=jax.checkpoint_policies.dots_saveable)(
@@ -678,7 +680,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk):
     products take operands of ``v``'s dtype and accumulate in float32. T
     is padded to whole chunks with ``k`` 0, ``beta`` 0 and ``g`` 0 (no
     write, no decay) and the padding cut off. The form for the shapes
-    ``pallas_kernels.gated_delta_rule`` has no tiles for and for every
+    ``kernels.gated_delta_rule`` has no tiles for and for every
     platform but the TPU, and what its tests hold it to."""
     f32 = jnp.float32
     b, t, h, dk = q.shape
@@ -754,7 +756,7 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     sum, the two norms, write strengths, decays, the triangular solve,
     the state and the gate are float32 whatever the inputs' dtype. The
     rule is the Pallas kernel pair where the shapes have tiles for it and
-    the step is lowered for the TPU (``pallas_kernels.gdn_takes`` /
+    the step is lowered for the TPU (``kernels.gdn_takes`` /
     ``gated_delta_rule``), the ``jax.numpy`` chunk form elsewhere.
     ``remat`` (training): each of the three scopes is computed again in
     the backward pass from its inputs, nothing inside it is kept
@@ -766,10 +768,10 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     The call site counts itself here (``linear_attn.lowerings``); the
     block itself is ``_gated_delta_block``, one ``jax.jit`` for every node
     of one signature."""
-    from . import pallas_kernels
+    from . import kernels
 
     key_dim, value_dim = (x.shape[2] // num_heads for x in (query, value))
-    kernel = pallas_kernels.gdn_takes(
+    kernel = kernels.gdn_takes(
         num_heads, key_dim, value_dim, chunk_size, value.dtype)
     _M_LINEAR_ATTN_LOWERINGS.inc(
         heads=num_heads, key_dim=key_dim, value_dim=value_dim,
@@ -779,17 +781,18 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
         query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
         norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
         eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
-        remat=bool(remat), kernel=kernel)
+        remat=bool(remat), kernel=kernel,
+        interpret=kernels.common.INTERPRET)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "chunk", "eps",
                                              "beta_scale", "remat",
-                                             "kernel"))
+                                             "kernel", "interpret"))
 def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                        dt_bias, norm_gamma, *, heads, chunk, eps,
-                       beta_scale, remat, kernel=False):
+                       beta_scale, remat, kernel, interpret):
     """``gated_delta_net`` for one signature."""
-    from . import pallas_kernels
+    from . import kernels
 
     f32 = jnp.float32
     bsz, t, _ = query.shape
@@ -840,8 +843,9 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
         if kernel:
             q, k, g, beta = again(unit_and_strengths)(q, k, a, b, a_log,
                                                       dt_bias)
-            o = pallas_kernels.gated_delta_rule(
-                q, k, v.reshape(bsz, t, heads, dv), g, beta, chunk)
+            o = kernels.gated_delta_rule(
+                q, k, v.reshape(bsz, t, heads, dv), g, beta, chunk,
+                interpret=interpret)
         else:
             o = again(delta_rule)(q, k, v, a, b, a_log, dt_bias)
     with jax.named_scope("gate_norm"):
@@ -894,20 +898,14 @@ register(
 # LatentAttention's two forms of the attention itself (``latent_attention``
 # chooses; down here so that no line above moves: see GatedDeltaNet's note)
 # --------------------------------------------------------------------------
-# what the kernel path runs where the step is not lowered for the TPU:
-# "composed" (``reference_attention`` over the concatenated key, on the
-# kernels' operands) or, for the kernels' tests, "interpret"
-_LATENT_OFF_TPU = "composed"
-
-
 def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
                           interleave):
     """Every head's key materialised: the rotation over the whole query,
     the shared rotary key broadcast and concatenated behind each head's
     slice of ``kv`` [B, T, H (N + Dv)], the values sliced out of it, and
-    ``pallas_kernels.attention`` (the flash kernel on the TPU at T >= 128,
+    ``kernels.attention`` (the flash kernel on the TPU at T >= 128,
     the materialised reference elsewhere)."""
-    from .pallas_kernels import attention
+    from .kernels import attention
 
     b, t, _ = query.shape
     rope_dim = k_rope.shape[2]
@@ -928,14 +926,14 @@ def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
 def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
                         interleave):
     """Nothing of [T, H, N + R] built for the keys: one pass over the
-    query (``_kernel_query``) and ``pallas_kernels.latent_flash`` on
+    query (``_kernel_query``) and ``kernels.latent_flash`` on
     ``kv`` and ``k_rope`` where the up-projection and the rotation left
     them; its output is the output projection's input as it stands."""
-    from .pallas_kernels import latent_flash
+    from .kernels import common, latent_flash
 
     rope_dim = k_rope.shape[2]
     width = query.shape[2] // num_heads
-    interpret = _LATENT_OFF_TPU == "interpret"
+    interpret = common.INTERPRET
     with jax.named_scope("latent"):
         q = _kernel_query(query, num_heads, rope_dim, theta, interleave,
                           interpret)
@@ -963,10 +961,10 @@ def _query_pass(x, *, num_heads, rope_dim, theta, interleave, inverse,
     rotated by their positions and zero lanes behind them to a whole lane
     row, Rp; under ``inverse`` the transpose, on the cotangent (the
     rotation's transpose on its R lanes, the N others through, the zero
-    lanes' unread). ``pallas_kernels.latent_query`` where it has blocks
+    lanes' unread). ``kernels.latent_query`` where it has blocks
     for the shapes and the step is lowered for the TPU, ``jax.numpy``
     everywhere else; one ``jax.jit`` a signature."""
-    from . import pallas_kernels as pk
+    from . import kernels
 
     b, t, _ = x.shape
     pad = -rope_dim % 128
@@ -986,11 +984,11 @@ def _query_pass(x, *, num_heads, rope_dim, theta, interleave, inverse,
                      jnp.zeros((b, t, num_heads, pad), x.dtype)]
         return jnp.concatenate(parts, axis=-1).reshape(b, t, -1)
 
-    if not pk.latent_query_takes(t, num_heads, d - rope_dim, rope_dim):
+    if not kernels.latent_query_takes(t, num_heads, d - rope_dim, rope_dim):
         return composed(x)
-    return pk._ssd_by_platform(
+    return kernels.common.on_tpu(
         functools.partial(
-            pk.latent_query, heads=num_heads, nope=d - rope_dim,
+            kernels.latent_query, heads=num_heads, nope=d - rope_dim,
             rope=rope_dim, theta=theta, interleave=interleave,
             inverse=inverse),
         composed, interpret, x)

@@ -142,7 +142,7 @@ class Optimizer:
     elementwise_update = False
 
     # Name of the fused Pallas slab-update kernel variant
-    # (ops/pallas_kernels.fused_slab_update) the AMP flat-update path may
+    # (ops/kernels.fused_slab_update) the AMP flat-update path may
     # use for this optimizer: "sgd" (momentum attr picks the mom
     # variant), "adam", or None to always take the jnp reference path.
     # Only meaningful when elementwise_update is True.

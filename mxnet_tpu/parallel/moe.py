@@ -102,26 +102,23 @@ def switch_moe(params, x, capacity_factor=1.25):
 
 
 
-# who computes the expert products where the step is not lowered for the
-# TPU (``grouped_matmul``'s ``off_tpu``): XLA's own grouped matmul
-_EXPERTS_OFF_TPU = "ragged_dot"
-
-
 def _expert_dot(counts, rows, dtype):
     """``dot(lhs, rhs)`` over ``rows`` rows sorted into ``counts``
-    groups: ``ops.pallas_kernels.grouped_matmul``, whose Pallas kernels
+    groups: ``ops.kernels.grouped_matmul``, whose Pallas kernels
     run where the step is lowered for the TPU and ``jax.lax.ragged_dot``
     on every other platform (and at shapes the kernels do not take). The
     kernels' group metadata is made once here and shared by every product
     (and its two transposes) that the returned function is used for."""
-    from ..ops import pallas_kernels as pk
+    from ..ops import kernels
 
     groups = counts.shape[0]
-    if not pk.gmm_runs_kernel(rows, dtype):
+    if not kernels.gmm_runs_kernel(rows, dtype):
         return lambda lhs, rhs: jax.lax.ragged_dot(lhs, rhs, counts)
-    metadata = pk.gmm_metadata(counts, rows, pk.gmm_row_tile(rows, groups))
-    return functools.partial(pk.grouped_matmul, group_sizes=counts,
-                             metadata=metadata, off_tpu=_EXPERTS_OFF_TPU)
+    metadata = kernels.gmm_metadata(
+        counts, rows, kernels.gmm_row_tile(rows, groups))
+    return functools.partial(kernels.grouped_matmul, group_sizes=counts,
+                             metadata=metadata,
+                             interpret=kernels.common.INTERPRET)
 
 
 def _experts(params, rows, counts, activation):
@@ -292,9 +289,9 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     backward (``_dispatch``, ``_combine``), and nothing on this path is
     a scatter but the transpose of the router's ``top_k``. Who
     computes them (``_expert_dot``): the Pallas kernels of
-    ``ops.pallas_kernels.grouped_matmul`` where the step is lowered for
+    ``ops.kernels.grouped_matmul`` where the step is lowered for
     the TPU, ``jax.lax.ragged_dot`` everywhere else (by
-    ``lax.platform_dependent``, inside that function); both take
+    ``kernels.common.on_tpu``, inside that function); both take
     operands of ``x.dtype``, accumulate in float32 and round once. The
     router (matmul at full float32 precision, scores, top-k) stays in
     float32 whatever the activations' dtype (``_route``: ``scoring``

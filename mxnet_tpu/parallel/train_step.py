@@ -845,11 +845,11 @@ class ShardedTrainStep:
         master + state updated, bf16 weight copy out; non-finite steps
         pass old values through bitwise (branchless select).
 
-        Optimizers that declare a `fused_slab_kernel` run the Pallas
-        kernel (ops/pallas_kernels.fused_slab_update) when
-        MXTPU_FUSED_UPDATE_KERNEL allows — one VMEM pass for the whole
-        unscale/update/cast chain — or its shared-math jnp reference
-        otherwise (same `_slab_update_math`, so toggling the kernel
+        Optimizers that declare a `fused_slab_kernel` go through
+        ops/kernels.fused_slab_update: the Pallas kernel where the step is
+        lowered for the TPU — one VMEM pass for the whole
+        unscale/update/cast chain — and its shared-math jnp reference on
+        every other platform (same `_slab_update_math`, so the platform
         changes codegen, not formulas). Other elementwise optimizers
         trace through their own Optimizer.update on the unscaled fp32
         gradient exactly like `_flat_body`."""
@@ -857,7 +857,7 @@ class ShardedTrainStep:
         import jax.numpy as jnp
 
         from ..ndarray import NDArray
-        from ..ops import pallas_kernels as pk
+        from ..ops import kernels
 
         opt = self.optimizer
         opt.lr = lr
@@ -876,18 +876,15 @@ class ShardedTrainStep:
             states = ()
             if st_c is not None:
                 states = st_c if isinstance(st_c, tuple) else (st_c,)
-            platform = self.mesh.devices.flat[0].platform
-            fn = (pk.fused_slab_update
-                  if pk.fused_update_enabled(platform)
-                  else pk.slab_update_reference)
-            nm, nst, w16 = fn(
+            nm, nst, w16 = kernels.fused_slab_update(
                 kind, m_c, g_c, states, lr_eff, inv_scale, finite,
                 wd=kwargs["wd"], rescale_grad=kwargs["rescale_grad"],
                 clip_gradient=kwargs["clip_gradient"],
                 momentum=getattr(opt, "momentum", 0.0),
                 beta1=getattr(opt, "beta1", 0.9),
                 beta2=getattr(opt, "beta2", 0.999),
-                epsilon=getattr(opt, "epsilon", 1e-8))
+                epsilon=getattr(opt, "epsilon", 1e-8),
+                interpret=kernels.common.INTERPRET)
             if st_c is None:
                 new_st = None
             elif isinstance(st_c, tuple):

@@ -10,7 +10,7 @@ fused Pallas optimizer-slab kernel. These tests pin the contracts:
   training continues;
 - the scale doubles after MXTPU_LOSS_SCALE_WINDOW consecutive finite
   steps;
-- the Pallas slab kernel (interpret mode off-TPU) matches the jnp
+- the Pallas slab kernel (interpret mode) matches the jnp
   reference chain across device counts and optimizers;
 - kvstore gradient buckets group by dtype, the byte cap counts actual
   itemsize, and MXTPU_BUCKET_REDUCE_DTYPE upcasts only the sum;
@@ -304,8 +304,8 @@ def test_slab_kernel_matches_reference(kind, size):
     odd/padded sizes; finite=0 must return the inputs bitwise."""
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops.pallas_kernels import (
-        _SLAB_STATE_SLOTS, fused_slab_update, slab_update_reference)
+    from mxnet_tpu.ops.kernels import (
+        SLAB_STATE_SLOTS, fused_slab_update, slab_update_reference)
 
     rng = np.random.RandomState(size + len(kind))
     w = jnp.asarray(rng.randn(size).astype(np.float32))
@@ -313,14 +313,15 @@ def test_slab_kernel_matches_reference(kind, size):
                     jnp.bfloat16)
     states = tuple(
         jnp.asarray(rng.randn(size).astype(np.float32) * 0.1)
-        for _ in range(_SLAB_STATE_SLOTS[kind]))
+        for _ in range(SLAB_STATE_SLOTS[kind]))
     kw = dict(wd=0.0001, rescale_grad=1.0 / 32, clip_gradient=None,
               momentum=0.9, beta1=0.9, beta2=0.999, epsilon=1e-8)
     for finite in (1.0, 0.0):
         ref_w, ref_st, ref_w16 = slab_update_reference(
             kind, w, g, states, 0.05, 1.0 / 128, finite, **kw)
         got_w, got_st, got_w16 = fused_slab_update(
-            kind, w, g, states, 0.05, 1.0 / 128, finite, **kw)
+            kind, w, g, states, 0.05, 1.0 / 128, finite, interpret=True,
+            **kw)
         np.testing.assert_allclose(np.asarray(got_w), np.asarray(ref_w),
                                    rtol=1e-6, atol=1e-7)
         for a, b in zip(got_st, ref_st):
@@ -337,17 +338,19 @@ def test_slab_kernel_matches_reference(kind, size):
 @pytest.mark.parametrize("ndev,optname", [(2, "sgd"), (4, "adam"),
                                           (8, "sgd")])
 def test_amp_kernel_vs_reference_fit(monkeypatch, ndev, optname):
-    """End-to-end: MXTPU_FUSED_UPDATE_KERNEL=1 (interpret Pallas) vs =0
-    (jnp chain) across simulated device counts — same masters and
-    working params to float tolerance after a full fit."""
+    """End-to-end: the slab kernel through the Pallas interpreter (the
+    kernel layer's one test seam) vs the jnp chain a CPU step runs, across
+    simulated device counts — same masters and working params to float
+    tolerance after a full fit."""
+    from mxnet_tpu.ops.kernels import common
+
     monkeypatch.setenv("MXTPU_AMP", "bf16")
     monkeypatch.setenv("MXTPU_SHARD_UPDATE", "1")
 
-    monkeypatch.setenv("MXTPU_FUSED_UPDATE_KERNEL", "0")
     mod_r, met_r = _fit_mlp(ndev, optname, num_epoch=1)
     ref = {k: np.asarray(v) for k, v in _masters(mod_r).items()}
 
-    monkeypatch.setenv("MXTPU_FUSED_UPDATE_KERNEL", "1")
+    monkeypatch.setattr(common, "INTERPRET", True)
     mod_k, met_k = _fit_mlp(ndev, optname, num_epoch=1)
     got = {k: np.asarray(v) for k, v in _masters(mod_k).items()}
 
@@ -553,8 +556,8 @@ def _run_train(script_dir, ckpt_dir, out, extra_env, timeout=300):
     env.pop("XLA_FLAGS", None)
     env.pop(fault.ENV, None)
     for k in ("MXTPU_AMP", "MXTPU_SHARD_UPDATE", "MXTPU_BUCKET_BYTES",
-              "MXTPU_DEVICE_FEED", "MXTPU_FUSED_UPDATE_KERNEL",
-              "MXTPU_LOSS_SCALE", "MXTPU_LOSS_SCALE_WINDOW"):
+              "MXTPU_DEVICE_FEED", "MXTPU_LOSS_SCALE",
+              "MXTPU_LOSS_SCALE_WINDOW"):
         env.pop(k, None)
     env.update(extra_env)
     return subprocess.run(

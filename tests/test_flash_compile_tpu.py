@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from mxnet_tpu.ops import pallas_kernels as pk
-from mxnet_tpu.ops.pallas_kernels import (
+from mxnet_tpu.ops import kernels as pk
+from mxnet_tpu.ops.kernels import (
     flash_attention, flash_tiles, gmm_tiles, grouped_matmul)
 
 
@@ -68,7 +68,7 @@ def test_flash_chosen_tiles_compile_for_v5e(one_chip, t, h, d, dtype,
     bq, bk = flash_tiles(t, d, dtype)
     operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
     t_pad = -(-t // max(bq, bk)) * max(bq, bk)
-    fuses = pk._bwd_fuses(t_pad, bq, bk, d, d, dtype)
+    fuses = pk.flash.bwd_fuses(t_pad, bq, bk, d, d, dtype)
     assert fuses is (t < 16384)
     for which, there in (("fwd", True), ("bwd", fuses), ("dq", not fuses),
                          ("dkv", not fuses)):
@@ -133,7 +133,7 @@ def test_one_pass_backward_compiles_under_its_stated_limit(one_chip, cell):
         shape(1, t, h, d), shape(1, t, g, d),
         shape(1, t, g, dv)).compile().as_text()
     bq, bk = flash_tiles(t, d, jnp.bfloat16, window)
-    assert pk._bwd_fuses(t, bq, bk, d, dv, jnp.bfloat16)
+    assert pk.flash.bwd_fuses(t, bq, bk, d, dv, jnp.bfloat16)
     name = "flash_bwd_bf16_q%d_k%d%s" % (
         bq, bk, "_w%d" % window if window else "")
     calls = [line for line in text.splitlines()
@@ -144,8 +144,9 @@ def test_one_pass_backward_compiles_under_its_stated_limit(one_chip, cell):
         int(re.search(r'"%s":\[\{"memory_space":"1","offset":"0",'
                       r'"size":"(\d+)"' % key, calls[0]).group(1))
         for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
-    assert limit == pk._flash_vmem_bytes(bq, bk, d, 2, resident=(t, d, dv))
-    assert used <= limit <= pk._FLASH_BWD_VMEM_LIMIT
+    assert limit == pk.flash.flash_vmem_bytes(bq, bk, d, 2,
+                                              resident=(t, d, dv))
+    assert used <= limit <= pk.common.VMEM_RAISED_LIMIT
 
 
 def test_the_latent_pair_compiles_at_the_kanana_cells_shape(one_chip):
@@ -189,9 +190,9 @@ def test_the_latent_pair_compiles_at_the_kanana_cells_shape(one_chip):
         int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
                       r'"size":"(\d+)"' % key, calls["bwd"][0]).group(1))
         for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
-    assert limit == pk._flash_vmem_bytes(bq, bk, width, 2,
-                                         resident=(t, width, dv))
-    assert used <= limit <= pk._FLASH_BWD_VMEM_LIMIT
+    assert limit == pk.flash.flash_vmem_bytes(bq, bk, width, 2,
+                                              resident=(t, width, dv))
+    assert used <= limit <= pk.common.VMEM_RAISED_LIMIT
 
 
 # the OLMoE cell's two expert products (gate/up, down); a float32 caller
@@ -308,7 +309,8 @@ def test_ssd_scan_kernels_compile_for_v5e(one_chip, t, heads, p, groups, n,
                           r'"size":"(\d+)"' % key, calls[0]).group(1))
             for key in ("scoped_memory_configs",
                         "used_scoped_memory_configs"))
-        assert used <= limit <= pk._SSD_VMEM_LIMIT, (name, used, limit)
+        assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (
+            name, used, limit)
 
 
 # the Olmo-Hybrid cell's delta rule (one sequence of 4,096 tokens, 30
@@ -353,4 +355,5 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, t, heads, dk, dv,
                           r'"size":"(\d+)"' % key, calls[0]).group(1))
             for key in ("scoped_memory_configs",
                         "used_scoped_memory_configs"))
-        assert used <= limit <= pk._GDN_VMEM_LIMIT, (name, used, limit)
+        assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (
+            name, used, limit)

@@ -1,4 +1,4 @@
-"""The gated delta rule's kernel pair (``ops/pallas_kernels.py::
+"""The gated delta rule's kernel pair (``ops/kernels/gdn.py::
 gated_delta_rule``: ``gdn_fwd_`` / ``gdn_bwd_`` behind a ``custom_vjp``)
 through the Pallas interpreter (``interpret=True``: off the TPU the op's
 own branch is the ``jax.numpy`` chunk form), at small shapes the kernels
@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.models import olmo_hybrid, olmo_hybrid_reference as ref
-from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
 from mxnet_tpu.ops.transformer import gated_delta_net, gated_delta_rule
 from mxnet_tpu.parallel import make_mesh
@@ -229,7 +229,7 @@ def test_the_kernels_take_the_cells_shape_and_refuse_what_has_no_tiles():
     assert take(30, 96, 192, 64, jnp.float32)
     assert take(3, 32, 64, 16, jnp.float32)
     assert take(16, 128, 128, 128, jnp.bfloat16)
-    assert pk._gdn_group(30) == 15 and pk._gdn_group(34) == 2
+    assert pk.gdn.gdn_group(30) == 15 and pk.gdn.gdn_group(34) == 2
     for heads, dk, dv, chunk, dtype in [
             (3, 8, 16, 8, jnp.float32),          # the tiny symbol's
             (30, 8, 16, 64, jnp.float32),
@@ -240,7 +240,8 @@ def test_the_kernels_take_the_cells_shape_and_refuse_what_has_no_tiles():
             (6, 2048, 4096, 128, jnp.float32),   # a step over VMEM
             (0, 96, 192, 64, jnp.bfloat16)]:
         assert not take(heads, dk, dv, chunk, dtype), (heads, dk, dv, chunk)
-    assert pk._gdn_vmem_bytes(128, 6, 2048, 4096, 4) > pk._GDN_VMEM_LIMIT
+    assert (pk.gdn.gdn_vmem_bytes(128, 6, 2048, 4096, 4)
+            > pk.common.VMEM_RAISED_LIMIT)
 
 
 # -- the op at a shape the kernels take ---------------------------------------
@@ -280,8 +281,7 @@ def rule_path(request, monkeypatch):
     round the switch."""
     tr._gated_delta_block.clear_cache()
     if request.param == "kernels_interpreted":
-        monkeypatch.setattr(pk, "gated_delta_rule", functools.partial(
-            pk.gated_delta_rule, interpret=True))
+        monkeypatch.setattr(pk.common, "INTERPRET", True)
     yield request.param
     tr._gated_delta_block.clear_cache()
 
@@ -322,7 +322,8 @@ def test_off_the_tpu_the_ops_values_are_the_chunk_forms_bit_for_bit(remat):
 
     def grads(kernel):
         def loss(*a):
-            o = tr._gated_delta_block(*a, kernel=kernel, **kw)
+            o = tr._gated_delta_block(*a, kernel=kernel, interpret=False,
+                                      **kw)
             return jnp.sum(o * cot), o
         return jax.jit(jax.value_and_grad(loss, tuple(range(len(ins))),
                                           has_aux=True))(*ins)
@@ -393,8 +394,8 @@ def test_a_fit_of_three_linear_layers_traces_each_kernel_once():
     rng = np.random.RandomState(3)
     tokens = rng.randint(0, cfg["vocab_size"], (1, t + 1))
     steps = 4
-    for jitted in (tr._gated_delta_block, pk._gdn_fwd_call,
-                   pk._gdn_bwd_call, pk._gdn_forward):
+    for jitted in (tr._gated_delta_block, pk.gdn.gdn_fwd_call,
+                   pk.gdn.gdn_bwd_call, pk.gdn.gdn_forward):
         jitted.clear_cache()    # another test's trace is not this one's
     telemetry.reset()
     telemetry.enable()

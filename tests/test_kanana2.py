@@ -222,36 +222,38 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
 
 
 # heads whose own keys and values are whole lane rows, as the cell's are
-# (128 + 64 / 128): the shapes ``pallas_kernels.latent_flash_takes`` admits
-LANE_WHOLE = dict(
-    SHARE, num_attention_heads=2, num_key_value_heads=2,
-    qk_nope_head_dim=128, qk_rope_head_dim=64, qk_head_dim=192, head_dim=64,
-    v_head_dim=128, max_position_embeddings=128,
+# (128 + 64 / 128): the shapes ``kernels.latent_flash_takes`` admits
+# the share at T 128, the shortest sequence either path sends to its
+# kernels
+SHARE_128 = dict(
+    SHARE, max_position_embeddings=128,
     share=dict(SHARE["share"], share_rows_bound=BATCH * 128 * 3))
+LANE_WHOLE = dict(
+    SHARE_128, num_attention_heads=2, num_key_value_heads=2,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, qk_head_dim=192, head_dim=64,
+    v_head_dim=128)
 
 
 @pytest.mark.parametrize("path", ["flash_over_the_composition",
                                   "latent_pair"])
 def test_every_layer_takes_the_one_pass_backward(monkeypatch, path):
-    """Forced onto the kernels (interpret mode here; on the chip the op
-    takes them by itself), every layer's attention traces the one-pass
+    """Sent through the kernels (the Pallas interpreter here, by the
+    kernel layer's one test seam; on the chip the op takes them by itself), every layer's attention traces the one-pass
     backward, and the gradients are the reference's through it. On the
     tiny widths the composition's ``attention`` call takes the flash
     kernel, a call site a layer; on lane-whole heads, the path the cell
     runs, the latent pair: three call sites, ONE trace of its forward and
     of its one-pass backward (there is no other), none of ``flash_*``."""
-    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops import kernels as pk
     from mxnet_tpu.ops import transformer as tr
 
     pair = path == "latent_pair"
-    cfg, t = (LANE_WHOLE, 128) if pair else (SHARE, T)
+    cfg, t = (LANE_WHOLE if pair else SHARE_128), 128
+    monkeypatch.setattr(pk.common, "INTERPRET", True)
     if pair:
-        monkeypatch.setattr(tr, "_LATENT_OFF_TPU", "interpret")
-        for name in ("_latent_fwd_call", "_latent_bwd_call",
-                     "_latent_forward", "_latent_backward"):
-            getattr(pk, name).clear_cache()
-    else:
-        monkeypatch.setenv("MXNET_TPU_FORCE_FLASH", "1")
+        for jitted in (pk.latent.latent_fwd_call, pk.latent.latent_bwd_call,
+                       pk.latent.latent_forward, pk.latent.latent_backward):
+            jitted.clear_cache()
     sym = kanana2.from_config(cfg, seq_len=t)
     params = _params(sym, 7, t=t)
     tokens, labels = _batch(8, t)
@@ -275,7 +277,7 @@ def test_every_layer_takes_the_one_pass_backward(monkeypatch, path):
             assert traces.value(**{"pass": "bwd"}) == 1
             assert telemetry.total("attention.flash_lowerings") == 0
         else:
-            tile = dict(operands="f32", block_q=T, block_k=T)
+            tile = dict(operands="f32", block_q=t, block_k=t)
             assert flash.value(window=0, bwd="fused", **tile) == 3
             assert flash.value(window=0, bwd="split", **tile) == 0
             assert flash.value(window=0, kv_heads=HEADS, dv=DV, **tile) == 3

@@ -1,4 +1,4 @@
-"""Latent attention's flash pair (``ops/pallas_kernels.py::latent_flash``:
+"""Latent attention's flash pair (``ops/kernels/latent.py::latent_flash``:
 ``flash2_fwd_`` / ``flash2_bwd_`` behind a ``custom_vjp``, two key
 operands on token-major arrays) through the Pallas interpreter (off the
 TPU the pair's own branch is ``reference_attention`` over the concatenated
@@ -24,14 +24,14 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu import telemetry
-from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
 
 BATCH, NOPE, ROPE, DV, LATENT = 2, 128, 64, 128, 32
 THETA, EPS = 1e6, 1e-6
 GRADS = ("dq", "dlatent", "dgamma", "dw_up")
-_JITTED = ("_latent_fwd_call", "_latent_bwd_call", "_latent_forward",
-           "_latent_backward")
+_JITTED = ("latent_fwd_call", "latent_bwd_call", "latent_forward",
+           "latent_backward")
 
 
 def _close(got, want, what, rtol=1e-5, ulps=8):
@@ -64,7 +64,7 @@ def _op(heads, interleave=True):
 @pytest.fixture
 def kernels(monkeypatch):
     """The op's kernel path through the Pallas interpreter."""
-    monkeypatch.setattr(tr, "_LATENT_OFF_TPU", "interpret")
+    monkeypatch.setattr(pk.common, "INTERPRET", True)
 
 
 def _composed(monkeypatch, op, *ins):
@@ -132,7 +132,7 @@ def test_uneven_tiles_walk_the_same_triangle(block_q, block_k):
                                    block_k=block_k, interpret=True),
         (q, kv, kr), cot)
     want, want_g = _out_and_grads(
-        lambda *a: pk._latent_composed(*a, heads, NOPE, scale),
+        lambda *a: pk.latent.latent_composed(*a, heads, NOPE, scale),
         (q, kv, kr), cot)
     assert got.shape == want.shape == (BATCH, 600, heads * DV)
     _close(got, want, "out")
@@ -177,7 +177,7 @@ def test_bf16_operands_are_one_rounding_from_the_composition(
          "r128_rotate_half"])
 def test_the_query_pass_is_the_rotation_and_its_transpose(
         rope, interleave, monkeypatch):
-    """``pallas_kernels.latent_query`` against ``rope`` on the rotary
+    """``kernels.latent_query`` against ``rope`` on the rotary
     lanes (``_query_pass``'s ``jax.numpy`` form): the padded query, and
     the cotangent taken back, lanes behind the rotary ones unread."""
     heads, t, pad = 4, 24, -rope % 128
@@ -220,7 +220,7 @@ def test_a_head_under_a_lane_row_takes_the_composition(kernels):
     ``composed`` and no kernel is traced, even when told to interpret."""
     ins, _ = _inputs(1, 128, 2, nope=64)
     for name in _JITTED:
-        getattr(pk, name).clear_cache()
+        getattr(pk.latent, name).clear_cache()
     telemetry.reset()
     telemetry.enable()
     try:
@@ -306,7 +306,7 @@ def test_three_layers_trace_each_body_once_over_two_steps(kernels):
         return sum(jnp.sum(op(*ins) ** 2) for ins in layers)
 
     for name in _JITTED:
-        getattr(pk, name).clear_cache()  # another test's trace is not ours
+        getattr(pk.latent, name).clear_cache()  # another test's trace is not ours
     telemetry.reset()
     telemetry.enable()
     try:

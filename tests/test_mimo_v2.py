@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu.models import mimo_v2, mimo_v2_reference as ref
-from mxnet_tpu.ops.pallas_kernels import (
+from mxnet_tpu.ops.kernels import (
     flash_attention, reference_attention)
 from mxnet_tpu.ops.transformer import rope
 from mxnet_tpu.parallel import make_mesh
@@ -239,7 +239,7 @@ def _tiled(q, k, v, window, sink):
     # 8 x 8 tiles: the band of a q tile crosses two k tiles, and most of
     # the grid's steps would be dead without the band's own inner extent
     return flash_attention(q, k, v, causal=True, window=window, sink=sink,
-                           block_q=8, block_k=8)
+                           block_q=8, block_k=8, interpret=True)
 
 
 def _materialised(q, k, v, window, sink):
@@ -248,7 +248,7 @@ def _materialised(q, k, v, window, sink):
 
 
 @pytest.mark.parametrize("path", [_tiled, _materialised],
-                         ids=["flash_kernel", "off_tpu"])
+                         ids=["flash_kernel", "materialised"])
 @pytest.mark.parametrize("kv_heads,window,with_sink", [
     (2, WINDOW, True), (1, 0, False), (1, WINDOW, False), (2, 0, True)])
 def test_attention_matches_the_reference(path, kv_heads, window, with_sink):
@@ -274,7 +274,7 @@ def test_attention_matches_the_reference(path, kv_heads, window, with_sink):
 
 
 @pytest.mark.parametrize("path", [_tiled, _materialised],
-                         ids=["flash_kernel", "off_tpu"])
+                         ids=["flash_kernel", "materialised"])
 def test_the_windows_edge_is_exact(path):
     """Equal scores and one-hot values: row i's output IS its
     probabilities, 1 / min(i + 1, window) on the keys i - window + 1 ..

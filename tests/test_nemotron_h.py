@@ -303,7 +303,7 @@ def test_three_blocks_of_one_shape_trace_the_block_and_the_kernels_once():
     ``jax.jit`` and each kernel's ``pallas_call`` is traced once, not once
     a node nor once a branch; and the step (off the TPU: the einsum
     branch inside the kernels' ``custom_vjp``) follows the reference."""
-    from mxnet_tpu.ops import pallas_kernels as pk
+    from mxnet_tpu.ops import kernels as pk
     from mxnet_tpu.ops import transformer as tr
 
     t, heads, p, n, chunk = 256, 4, 32, 128, 128
@@ -326,7 +326,8 @@ def test_three_blocks_of_one_shape_trace_the_block_and_the_kernels_once():
         losses.append(float(loss))
         want, moms = ref.sgd_momentum_step(want, moms, grads, lr, momentum)
 
-    for jitted in (tr._mamba2_block, pk._ssd_fwd_call, pk._ssd_bwd_call):
+    for jitted in (tr._mamba2_block, pk.ssd.ssd_fwd_call,
+                   pk.ssd.ssd_bwd_call):
         jitted.clear_cache()    # another test's trace is not this one's
     telemetry.reset()
     telemetry.enable()

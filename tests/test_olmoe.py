@@ -202,10 +202,10 @@ def _moe_weights(rng, d, experts, hidden):
 
 def _force_the_kernel(monkeypatch):
     """The expert products through ``grouped_matmul``'s kernels off the
-    TPU too: its own platform switch then takes the Pallas interpreter."""
-    from mxnet_tpu.parallel import moe
+    TPU too, in the Pallas interpreter: the kernel layer's one test seam."""
+    from mxnet_tpu.ops.kernels import common
 
-    monkeypatch.setattr(moe, "_EXPERTS_OFF_TPU", "interpret")
+    monkeypatch.setattr(common, "INTERPRET", True)
 
 
 @pytest.mark.parametrize("experts_by", ["ragged_dot", "kernel"])
@@ -471,8 +471,8 @@ def test_topk_moe_symbol_op_infers_and_checks():
 
 def test_attention_t256_runs_the_flash_kernel(monkeypatch):
     """The op's one dispatch, at a T the TPU takes through the flash
-    kernel: forced here, in interpret mode, forward and backward."""
-    monkeypatch.setenv("MXNET_TPU_FORCE_FLASH", "1")
+    kernel: through the Pallas interpreter here, forward and backward."""
+    _force_the_kernel(monkeypatch)
     rng = np.random.RandomState(8)
     b, t, heads, d = 1, 256, 2, 32
     q, k, v = (jnp.asarray(0.5 * rng.randn(b, t, heads * d), jnp.float32)

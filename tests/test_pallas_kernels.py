@@ -2,15 +2,21 @@
 kernel code that compiles via Mosaic on TPU; the backend-equivalence trick
 mirrors the reference's cpu-vs-gpu check_consistency harness,
 tests/python/gpu/test_operator_gpu.py)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from mxnet_tpu import telemetry
-from mxnet_tpu.ops import pallas_kernels as pk
-from mxnet_tpu.ops.pallas_kernels import (
-    flash_attention, flash_tiles, reference_attention)
+from mxnet_tpu.ops import kernels as pk
+from mxnet_tpu.ops.kernels import common, flash_tiles, reference_attention
+
+# off the TPU an entry's own branch is its plain form: these tests mean
+# the kernels, through the Pallas interpreter
+flash_attention = functools.partial(pk.flash_attention, interpret=True)
+grouped_matmul = functools.partial(pk.grouped_matmul, interpret=True)
 
 
 CASES = [
@@ -83,8 +89,9 @@ def test_transformer_uses_flash_shapes_consistent():
 
 
 def test_transformer_flash_branch_matches_reference(monkeypatch):
-    # force the model's flash branch off-TPU (Pallas interpreter) and
-    # check it agrees with the reference-attention branch — this executes
+    # send the model's flash branch through the Pallas interpreter (the
+    # kernel layer's one test seam) and check it agrees with the plain
+    # branch a CPU step runs — this executes
     # the actual flash_attention call site in the transformer, so a
     # swapped q/k/v argument or wrong keyword there fails here, not on
     # hardware
@@ -94,9 +101,10 @@ def test_transformer_flash_branch_matches_reference(monkeypatch):
         vocab=50, d_model=32, n_layers=1, n_heads=2, dtype=jnp.float32,
     )
     params = init_fn(seed=0)
-    toks = jnp.asarray(np.random.RandomState(1).randint(0, 50, (2, 16)))
+    # T 128: the shortest sequence ``attention`` sends to flash_attention
+    toks = jnp.asarray(np.random.RandomState(1).randint(0, 50, (2, 128)))
     ref_logits = apply_fn(params, toks)
-    monkeypatch.setenv("MXNET_TPU_FORCE_FLASH", "1")
+    monkeypatch.setattr(common, "INTERPRET", True)
     flash_logits = apply_fn(params, toks)
     np.testing.assert_allclose(
         np.asarray(flash_logits), np.asarray(ref_logits),
@@ -219,13 +227,12 @@ def _kernel_dots(dtype):
 
 def test_flash_kernels_feed_mxu_operands_as_given():
     bf16 = _kernel_dots(jnp.bfloat16)
-    # the Mosaic and the interpreter branch carry the same two kernels
     assert sorted(bf16) == ["flash_bwd_bf16_q256_k256",
                             "flash_fwd_bf16_q256_k256"]
     # 2 products a tile pair forward and 5 backward (P and dS are built
-    # once), in the masked and the unmasked body, in both branches
+    # once), in the masked and the unmasked body; each body traced once
     assert {k: len(v) for k, v in bf16.items()} == {
-        "flash_fwd_bf16_q256_k256": 8, "flash_bwd_bf16_q256_k256": 20}
+        "flash_fwd_bf16_q256_k256": 4, "flash_bwd_bf16_q256_k256": 10}
     for dots in bf16.values():
         for operands, result in dots:
             assert operands == (jnp.bfloat16, jnp.bfloat16)
@@ -256,8 +263,8 @@ def test_flash_tiles_divide_and_fit(t, d, dtype):
         assert t_pad * 8 <= -(-t // 128) * 128 * 9
     else:
         assert bq == bk and t <= bq < 2 * max(t, 8)
-    assert pk._flash_vmem_bytes(
-        bq, bk, d, jnp.dtype(dtype).itemsize) <= pk._FLASH_VMEM_BUDGET
+    assert pk.flash.flash_vmem_bytes(
+        bq, bk, d, jnp.dtype(dtype).itemsize) <= pk.common.VMEM_SCOPED_DEFAULT
 
 
 def test_flash_tiles_at_the_cell_shape_are_large():
@@ -344,7 +351,7 @@ def test_fused_backward_matches_the_split_form(name, monkeypatch):
     (q, k, v, do, sink), kw = _fused_case(name)
 
     def grads(fuses):
-        monkeypatch.setattr(pk, "_bwd_fuses", lambda *a: fuses)
+        monkeypatch.setattr(pk.flash, "bwd_fuses", lambda *a: fuses)
         _, vjp = jax.vjp(
             lambda q, k, v: flash_attention(q, k, v, sink=sink, **kw),
             q, k, v)
@@ -362,7 +369,7 @@ def test_fused_backward_matches_the_split_form(name, monkeypatch):
 @pytest.mark.parametrize("name", sorted(FUSED_BWD_CASES))
 def test_fused_backward_matches_the_reference_gradients(name):
     (q, k, v, do, sink), kw = _fused_case(name)
-    assert pk._bwd_fuses(q.shape[1], kw["block_q"], kw["block_k"],
+    assert pk.flash.bwd_fuses(q.shape[1], kw["block_q"], kw["block_k"],
                          q.shape[3], v.shape[3], q.dtype)
     extra = () if sink is None else (sink,)
 
@@ -394,13 +401,13 @@ def test_fused_backward_matches_the_reference_gradients(name):
 ])
 def test_backward_form_follows_the_shapes(t, d, dv, window, dtype, fuses):
     bq, bk = flash_tiles(t, max(d, dv), dtype, window)
-    assert pk._bwd_fuses(t, bq, bk, d, dv, dtype) is fuses
-    counted = pk._flash_vmem_bytes(
+    assert pk.flash.bwd_fuses(t, bq, bk, d, dv, dtype) is fuses
+    counted = pk.flash.flash_vmem_bytes(
         bq, bk, max(d, dv), jnp.dtype(dtype).itemsize, resident=(t, d, dv))
-    assert (counted <= pk._FLASH_BWD_VMEM_LIMIT) is fuses
+    assert (counted <= pk.common.VMEM_RAISED_LIMIT) is fuses
     # the resident part alone: dK and dV in float32 and their two
     # output buffers, lane-padded
-    assert counted - pk._flash_vmem_bytes(
+    assert counted - pk.flash.flash_vmem_bytes(
         bq, bk, max(d, dv), jnp.dtype(dtype).itemsize) >= t * (
             d + dv) * (4 + 2 * jnp.dtype(dtype).itemsize)
 
@@ -408,7 +415,7 @@ def test_backward_form_follows_the_shapes(t, d, dv, window, dtype, fuses):
 def test_split_backward_is_taken_where_the_rule_says(monkeypatch):
     # a sequence the rule sends to dq and dkv, scaled down: the limit is
     # lowered under a small call's count and the two kernels are traced
-    monkeypatch.setattr(pk, "_FLASH_BWD_VMEM_LIMIT", 1024)
+    monkeypatch.setattr(pk.flash, "VMEM_RAISED_LIMIT", 1024)
     telemetry.reset()
     telemetry.enable()
     try:
@@ -420,8 +427,8 @@ def test_split_backward_is_taken_where_the_rule_says(monkeypatch):
                                 "flash_dq_f32_q256_k256",
                                 "flash_fwd_f32_q256_k256"]
         # 3 / 4 products a tile pair in dq / dkv: seven where five do
-        assert len(dots["flash_dq_f32_q256_k256"]) == 12
-        assert len(dots["flash_dkv_f32_q256_k256"]) == 16
+        assert len(dots["flash_dq_f32_q256_k256"]) == 6
+        assert len(dots["flash_dkv_f32_q256_k256"]) == 8
         c = telemetry.REGISTRY.get("attention.flash_lowerings")
         assert c.value(operands="f32", block_q=256, block_k=256, window=0,
                        bwd="split") == 1
@@ -484,7 +491,7 @@ def test_grouped_matmul_matches_the_dense_product(load, dtype, tol):
     def loss(fn):
         return lambda l, r: jnp.sum(fn(l, r).astype(jnp.float32) ** 2)
 
-    kernel = lambda l, r: pk.grouped_matmul(l, r, group_sizes)
+    kernel = lambda l, r: grouped_matmul(l, r, group_sizes)
     dense = lambda l, r: _dense_grouped(l, r, sizes)
     out = kernel(lhs, rhs)
     assert out.dtype == dtype and out.shape == (sum(sizes), 160)
@@ -510,15 +517,15 @@ def test_grouped_matmul_splits_k_and_n_like_the_whole():
     dout = jnp.asarray(np.random.RandomState(4).randn(lhs.shape[0], 384),
                        jnp.float32)
     want = _dense_grouped(lhs, rhs, sizes)
-    got = pk._gmm_call(*meta[:4], lhs, rhs, tiles=(128, 128, 128),
+    got = pk.gmm.gmm_call(*meta[:4], lhs, rhs, tiles=(128, 128, 128),
                        transposed=False, interpret=True)
     assert _rel(got, want) < 1e-5
     d_want, w_want = jax.vjp(
         lambda l, r: _dense_grouped(l, r, sizes), lhs, rhs)[1](dout)
-    d_got = pk._gmm_call(*meta[:4], dout, rhs, tiles=(128, 128, 128),
+    d_got = pk.gmm.gmm_call(*meta[:4], dout, rhs, tiles=(128, 128, 128),
                          transposed=True, interpret=True)
     assert _rel(d_got, d_want) < 1e-5
-    w_got = pk._gmm_wgrad_call(meta[0], *meta[4:], lhs, dout, groups=4,
+    w_got = pk.gmm.gmm_wgrad_call(meta[0], *meta[4:], lhs, dout, groups=4,
                                tiles=(128, 128, 128), interpret=True)
     assert _rel(w_got, w_want) < 1e-5
 
@@ -530,9 +537,9 @@ def test_grouped_matmul_hands_small_or_odd_calls_to_ragged_dot():
                          ([100, 100], jnp.float16)):
         lhs, rhs, group_sizes = _gmm_inputs(sizes, 16, 24, dtype)
         assert not pk.gmm_runs_kernel(lhs.shape[0], dtype)
-        jaxpr = jax.make_jaxpr(pk.grouped_matmul)(lhs, rhs, group_sizes)
+        jaxpr = jax.make_jaxpr(grouped_matmul)(lhs, rhs, group_sizes)
         assert "pallas_call" not in str(jaxpr)
-        assert _rel(pk.grouped_matmul(lhs, rhs, group_sizes),
+        assert _rel(grouped_matmul(lhs, rhs, group_sizes),
                     _dense_grouped(lhs, rhs, sizes)) < 1e-2
 
 
@@ -574,8 +581,8 @@ def test_gmm_tiles_divide_or_mask_and_fit(m, k, n, groups, dtype):
         # the mean rows a group where that is above the least tile (the
         # last tile of an m that is no multiple is masked, not padded)
         assert tm & (tm - 1) == 0
-        assert pk._GMM_MIN_ROW_TILE <= tm <= pk._GMM_MAX_ROW_TILE
-        assert tm <= max(m // groups, pk._GMM_MIN_ROW_TILE)
+        assert pk.gmm.GMM_MIN_ROW_TILE <= tm <= pk.gmm.GMM_MAX_ROW_TILE
+        assert tm <= max(m // groups, pk.gmm.GMM_MIN_ROW_TILE)
         assert tm == pk.gmm_row_tile(m, groups)
         # the contraction admits no partial tile; a partial tile of a
         # result dimension is masked
@@ -583,8 +590,8 @@ def test_gmm_tiles_divide_or_mask_and_fit(m, k, n, groups, dtype):
         assert tn == n or tn % 128 == 0
         if not wgrad:
             assert k % tk == 0
-        assert pk._gmm_vmem_bytes(
-            tm, tk, tn, itemsize, wgrad) <= pk._FLASH_VMEM_BUDGET
+        assert pk.gmm.gmm_vmem_bytes(
+            tm, tk, tn, itemsize, wgrad) <= pk.common.VMEM_SCOPED_DEFAULT
 
 
 def test_gmm_tiles_at_the_cell_shape_keep_k_whole():
@@ -605,7 +612,7 @@ def test_gmm_lowerings_counter_counts_one_per_call_site_and_mode():
     try:
         sizes = GMM_SIZES["straddles_tiles"]
         lhs, rhs, group_sizes = _gmm_inputs(sizes, 32, 64, jnp.bfloat16)
-        fn = lambda l, r: pk.grouped_matmul(l, r, group_sizes)
+        fn = lambda l, r: grouped_matmul(l, r, group_sizes)
         step = jax.jit(jax.grad(
             lambda l, r: jnp.sum(fn(l, r).astype(jnp.float32) ** 2),
             argnums=(0, 1)))
@@ -632,7 +639,7 @@ def test_gmm_kernels_feed_mxu_operands_as_given():
     for dtype, label in ((jnp.bfloat16, "bf16"), (jnp.float32, "f32")):
         lhs, rhs, group_sizes = _gmm_inputs(sizes, 32, 64, dtype)
         jaxpr = jax.make_jaxpr(jax.grad(
-            lambda l, r: jnp.sum(pk.grouped_matmul(
+            lambda l, r: jnp.sum(grouped_matmul(
                 l, r, group_sizes).astype(jnp.float32) ** 2),
             argnums=(0, 1)))(lhs, rhs)
         found = _dots_by_kernel(jaxpr)
@@ -643,3 +650,231 @@ def test_gmm_kernels_feed_mxu_operands_as_given():
             for operands, result in dots:
                 assert operands == (dtype, dtype)
                 assert result == jnp.float32
+
+
+# ---------------------------------------------------------------------------
+# the one platform switch (``common.on_tpu``), entry by entry: off the TPU
+# and without ``interpret`` an entry IS its plain form and traces no
+# interpreter copy of a body (what a step lowered for the TPU must not pay
+# for); with ``interpret=True`` it is the kernels alone, no switch
+# ---------------------------------------------------------------------------
+
+def _switch_case(name):
+    """(entry(*args, interpret=...), plain(*args), args, the arguments a
+    gradient is taken in: none where the entry is itself a backward)."""
+    from mxnet_tpu.ops import transformer as tr
+
+    rng = np.random.RandomState(sorted(SWITCH_CASES).index(name))
+
+    def draw(*shape, scale=1.0, dtype=jnp.float32):
+        return jnp.asarray(scale * rng.randn(*shape), dtype)
+
+    def flash_plain(q, k, v, window=0):
+        # the pair's plain form on the operands heads first, as
+        # ``flash_attention`` hands them over (``reference_attention``'s
+        # arithmetic; one product in another layout, so an ulp from it)
+        b, t, h, d = q.shape
+        out, _ = pk.flash.plain_fwd(
+            *(x.transpose(0, 2, 1, 3).reshape(-1, t, x.shape[3])
+              for x in (q, k, v)),
+            t_real=t, scale=1.0 / float(np.sqrt(d)), causal=True,
+            window=window)
+        return out.reshape(b, h, t, -1).transpose(0, 2, 1, 3)
+
+    if name == "flash_attention":
+        return (functools.partial(pk.flash_attention, causal=True,
+                                  window=40),
+                functools.partial(flash_plain, window=40),
+                (draw(1, 200, 4, 32), draw(1, 200, 2, 32),
+                 draw(1, 200, 2, 16)), (0, 1, 2))
+    if name == "attention":
+        def entry(q, k, v, interpret):
+            # ``attention`` reads the seam where it traces
+            with pytest.MonkeyPatch.context() as m:
+                m.setattr(common, "INTERPRET", interpret)
+                return pk.attention(q, k, v, causal=True)
+        return (entry, flash_plain,
+                tuple(draw(2, 128, 2, 32) for _ in range(3)), (0, 1, 2))
+    if name == "grouped_matmul":
+        sizes = GMM_SIZES["straddles_tiles"]
+        lhs, rhs, group_sizes = _gmm_inputs(sizes, 32, 64, jnp.float32)
+        return (lambda l, r, interpret: pk.grouped_matmul(
+                    l, r, group_sizes, interpret=interpret),
+                lambda l, r: jax.lax.ragged_dot(l, r, group_sizes),
+                (lhs, rhs), (0, 1))
+    if name == "ssd_scan":
+        b, t, h, p, n, chunk = 1, 256, 4, 32, 128, 128
+        assert pk.ssd_takes(h, p, n, 1, chunk, jnp.float32)
+        args = (draw(b, t, h, p), draw(b, t, 1, n, scale=0.3),
+                draw(b, t, 1, n, scale=0.3),
+                jnp.abs(draw(b, t, h, scale=0.1)) + 0.01,
+                -jnp.abs(draw(h)) - 0.1, draw(h))
+
+        def plain(x, bm, cm, dt, a, skip):
+            return (tr.ssd_scan(x, bm, cm, dt, a, chunk)
+                    + skip[:, None] * x.astype(jnp.float32))
+        return (lambda *a, interpret: pk.ssd_scan(
+                    *a, chunk, interpret=interpret),
+                plain, args, tuple(range(6)))
+    if name == "gated_delta_rule":
+        b, t, h, dk, dv, chunk = 1, 64, 3, 32, 64, 16
+        assert pk.gdn_takes(h, dk, dv, chunk, jnp.float32)
+        q, k = (draw(b, t, h, dk) for _ in range(2))
+        q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+                for x in (q, k))
+        args = (q, k, draw(b, t, h, dv), -jnp.abs(draw(b, t, h, scale=0.1)),
+                jax.nn.sigmoid(draw(b, t, h)))
+        return (lambda *a, interpret: pk.gated_delta_rule(
+                    *a, chunk, interpret=interpret),
+                lambda *a: tr.gated_delta_rule(*a, chunk), args,
+                tuple(range(5)))
+    if name == "latent_flash":
+        b, t, heads, nope, rp, dv = 1, 128, 2, 128, 128, 128
+        assert pk.latent_flash_takes(t, nope, 64, dv, jnp.float32)
+        scale = (nope + 64) ** -0.5
+        return (lambda *a, interpret: pk.latent_flash(
+                    *a, heads, nope, scale, interpret=interpret),
+                lambda *a: pk.latent.latent_composed(*a, heads, nope, scale),
+                (draw(b, t, heads * (nope + rp), scale=0.3),
+                 draw(b, t, heads * (nope + dv), scale=0.3),
+                 draw(b, t, rp, scale=0.3)), (0, 1, 2))
+    if name == "fused_slab_update":
+        size = 3000
+        kw = dict(wd=1e-4, rescale_grad=1.0 / 32, clip_gradient=None,
+                  momentum=0.9)
+        args = (draw(size), draw(size, scale=4, dtype=jnp.bfloat16),
+                (draw(size, scale=0.1),))
+
+        def entry(w, g, states, interpret):
+            return pk.fused_slab_update(
+                "sgd_mom", w, g, states, 0.05, 1.0 / 128, 1.0,
+                interpret=interpret, **kw)
+        return (entry, lambda w, g, states: pk.slab_update_reference(
+                    "sgd_mom", w, g, states, 0.05, 1.0 / 128, 1.0, **kw),
+                args, ())
+    dshape, wshape, pad = (2, 8, 10, 10), (16, 8, 3, 3), (1, 1)
+    x, w, g = draw(*dshape), draw(*wshape, scale=0.1), draw(2, 16, 10, 10)
+
+    def conv(x, w):
+        return jax.lax.conv_general_dilated(
+            x, w, (1, 1), [(1, 1), (1, 1)],
+            dimension_numbers=("NCHW", "OIHW", "NCHW"))
+    if name == "conv_bwd_filter":
+        return (lambda x, g, interpret: pk.conv_bwd_filter(
+                    x, g, wshape, pad, interpret=interpret),
+                lambda x, g: jax.vjp(conv, x, w)[1](g)[1], (x, g), ())
+    assert name == "conv_bwd_input"
+    return (lambda g, w, interpret: pk.conv_bwd_input(
+                g, w, dshape, pad, interpret=interpret),
+            lambda g, w: jax.vjp(conv, x, w)[1](g)[0], (g, w), ())
+
+
+SWITCH_CASES = ("attention", "conv_bwd_filter", "conv_bwd_input",
+                "flash_attention", "fused_slab_update", "gated_delta_rule",
+                "grouped_matmul", "latent_flash", "ssd_scan")
+
+
+def _primitives(jaxpr):
+    """Every equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+@pytest.mark.parametrize("name", SWITCH_CASES)
+def test_an_entry_off_the_tpu_is_its_plain_form_and_interprets_only_when_told(
+        name):
+    entry, plain, args, argnums = _switch_case(name)
+
+    def traced(interpret):
+        fn = value = functools.partial(entry, interpret=interpret)
+        if argnums:
+            # the backward rule's branches are part of the contract
+            fn = jax.value_and_grad(
+                lambda *a: jnp.sum(jnp.sin(value(*a).astype(jnp.float32))),
+                argnums)
+        eqns = list(_primitives(jax.make_jaxpr(fn)(*args).jaxpr))
+        return ([e for e in eqns if e.primitive.name == "pallas_call"],
+                [e for e in eqns if e.primitive.name == "platform_index"])
+
+    calls, switches = traced(False)
+    # the Mosaic branch is there for a lowering for the TPU to take, and
+    # no interpreter copy of it beside it
+    assert calls and switches
+    assert not any(c.params["interpret"] for c in calls)
+    interpreted, switches = traced(True)
+    assert interpreted and not switches
+    assert all(c.params["interpret"] for c in interpreted)
+    assert (sorted(str(c.params["name"]) for c in interpreted)
+            == sorted(str(c.params["name"]) for c in calls))
+
+    got = jax.jit(functools.partial(entry, interpret=False))(*args)
+    want = jax.jit(plain)(*args)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ---------------------------------------------------------------------------
+# the flash pair's plain form (``flash.plain_fwd`` / ``plain_bwd``: what
+# every platform but the TPU runs inside the ``custom_vjp``) against
+# ``jax.vjp(reference_attention)`` on the kernels' padded operands
+# ---------------------------------------------------------------------------
+
+# (b, t, t_pad, heads, kv heads, d, dv, causal, window)
+PLAIN_PAIR_CASES = {
+    "causal": (2, 64, 64, 2, 2, 16, 16, True, 0),
+    "full_padded": (1, 50, 64, 2, 2, 16, 16, False, 0),
+    "grouped_window_padded": (2, 100, 128, 4, 2, 16, 16, True, 24),
+    "one_kv_head_value_width_of_its_own": (1, 64, 64, 4, 1, 24, 16, True,
+                                           0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLAIN_PAIR_CASES))
+def test_the_plain_flash_pair_matches_the_reference_and_its_vjp(name):
+    b, t, t_pad, h, g, d, dv, causal, window = PLAIN_PAIR_CASES[name]
+    rng = np.random.RandomState(sorted(PLAIN_PAIR_CASES).index(name))
+    q, k, v, do = (jnp.asarray(rng.randn(*shape), jnp.float32)
+                   for shape in ((b, t, h, d), (b, t, g, d), (b, t, g, dv),
+                                 (b, t, h, dv)))
+    scale = d ** -0.5
+    want, vjp = jax.vjp(
+        lambda q, k, v: reference_attention(
+            q, k, v, causal=causal, scale=scale, window=window), q, k, v)
+    want_grads = vjp(do)
+
+    def padded(x):
+        # [B, T, H, D] -> [B H, T_pad, D], as ``flash_attention`` hands
+        # its operands to the pair
+        x = x.transpose(0, 2, 1, 3).reshape(-1, t, x.shape[3])
+        return jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
+
+    def unpadded(x3, heads):
+        return x3[:, :t].reshape(b, heads, t, -1).transpose(0, 2, 1, 3)
+
+    call = dict(t_real=t, scale=scale, causal=causal, window=window,
+                block_q=t_pad, block_k=t_pad)
+    q3, k3, v3, do3 = (padded(x) for x in (q, k, v, do))
+    out, lse = pk.flash.plain_fwd(q3, k3, v3, **call)
+    assert out.shape == (b * h, t_pad, dv) and lse.shape == (b * h, t_pad, 1)
+    assert out.dtype == q.dtype and lse.dtype == jnp.float32
+    assert _rel(unpadded(out, h), want) < 1e-6
+    # a row's lse is the log of its softmax's denominator: the row's
+    # probabilities rebuilt from it sum to one
+    s = scale * jnp.einsum("bqhd,bkhd->bhqk", q,
+                           jnp.repeat(k, h // g, axis=2))
+    p = jnp.exp(s - unpadded(lse, h).transpose(0, 2, 1, 3))
+    if causal:
+        i, j = np.indices((t, t))
+        p = jnp.where((j <= i) & ((i - j < window) if window else True),
+                      p, 0.0)
+    np.testing.assert_allclose(np.asarray(p.sum(-1)), 1.0, rtol=1e-5)
+    delta = jnp.sum(do3 * out, axis=-1, keepdims=True)
+    grads = pk.flash.plain_bwd(q3, k3, v3, do3, lse, delta, **call)
+    for which, got, w, heads in zip(("dq", "dk", "dv"), grads, want_grads,
+                                    (h, g, g)):
+        assert got.dtype == q.dtype and got.shape[1] == t_pad
+        assert not np.asarray(got[:, t:]).any(), which
+        assert _rel(unpadded(got, heads), w) < 1e-5, which

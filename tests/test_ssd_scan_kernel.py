@@ -1,4 +1,4 @@
-"""The state-space scan's kernel pair (``ops/pallas_kernels.py::ssd_scan``:
+"""The state-space scan's kernel pair (``ops/kernels/ssd.py::ssd_scan``:
 ``ssd_fwd_`` / ``ssd_bwd_`` behind a ``custom_vjp``) through the Pallas
 interpreter (``interpret=True``: off the TPU the op's own branch is the
 einsum form), at small shapes the kernels have tiles for (chunks of 128,
@@ -15,7 +15,6 @@ only the order of summation differs (``_close``: rtol 1e-5 and a few
 float32 ulps of the tensor's largest magnitude; more ulps for gradients,
 which are long sums through several chunks); bf16 inside the rms band
 ``test_mamba2_in_bf16_keeps_its_decays_and_state_in_float32`` uses."""
-import functools
 
 import numpy as np
 import pytest
@@ -24,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.models import nemotron_h_reference as ref
-from mxnet_tpu.ops import pallas_kernels as pk
+from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
 from mxnet_tpu.ops.transformer import mamba2, ssd_scan
 
@@ -189,8 +188,7 @@ def scan_path(request, monkeypatch):
     ``jax.jit`` a signature, so its cache is emptied round the switch."""
     tr._mamba2_block.clear_cache()
     if request.param == "kernels_interpreted":
-        monkeypatch.setattr(pk, "ssd_scan", functools.partial(
-            pk.ssd_scan, interpret=True))
+        monkeypatch.setattr(pk.common, "INTERPRET", True)
     yield request.param
     tr._mamba2_block.clear_cache()
 

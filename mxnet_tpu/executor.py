@@ -126,13 +126,14 @@ _OP_CLASS = {
     "_contrib_LatentAttention": "attn", "_contrib_Mamba2": "ssm",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
+    "_contrib_ShortConv": "sconv",
 }
 
 
 def op_class(op_name):
-    """conv | fc | bn | pool | act | loss | attn | ssm | gdn | moe | norm |
-    embed | other: the class a node's device ops are filed under (the first part
-    of its named scope)."""
+    """conv | fc | bn | pool | act | loss | attn | ssm | gdn | sconv | moe |
+    norm | embed | other: the class a node's device ops are filed under
+    (the first part of its named scope)."""
     cls = _OP_CLASS.get(op_name)
     if cls is not None:
         return cls

@@ -19,8 +19,11 @@ attention in one-mixer blocks), whole or as a share, with
 ``nemotron_h_reference``. ``olmo_hybrid`` is Olmo-Hybrid-7B (gated
 delta-rule linear attention three to one beside full attention, dense
 SwiGLU, blocks that norm a sub-layer's output), whole or as a pipeline
-stage, with ``olmo_hybrid_reference``; ``lm_blocks`` holds what the LM
-symbols share.
+stage, with ``olmo_hybrid_reference``. ``lfm2`` is LFM2-24B-A2B (gated
+short-convolution layers three to one beside grouped attention on heads
+of 64, a dense SwiGLU then 64 sigmoid-routed experts, a head tied to the
+embedding), whole or as a share, with ``lfm2_reference``; ``lm_blocks``
+holds what the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -34,6 +37,6 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
-from . import (kanana2, kanana2_reference, mimo_v2, mimo_v2_reference,
-               nemotron_h, nemotron_h_reference, olmo_hybrid,
-               olmo_hybrid_reference, olmoe, olmoe_reference)
+from . import (kanana2, kanana2_reference, lfm2, lfm2_reference, mimo_v2,
+               mimo_v2_reference, nemotron_h, nemotron_h_reference,
+               olmo_hybrid, olmo_hybrid_reference, olmoe, olmoe_reference)

@@ -1,9 +1,11 @@
 """The pieces the decoder-only LM symbols share (``mimo_v2``,
-``kanana2``, ``nemotron_h``, ``olmo_hybrid``; the tail also ``olmoe``): a
-bias-free projection, the dense SwiGLU and the un-gated relu² feed-forward,
-the one-mixer residual block and the block that norms a sub-layer's output,
-the routed expert layer's call and the head with its loss. Each takes the node-name prefix of its layer, so a model's
-argument and scope names are its own."""
+``kanana2``, ``nemotron_h``, ``olmo_hybrid``, ``lfm2``; the tail also
+``olmoe``): a bias-free projection, the dense SwiGLU and the un-gated
+relu² feed-forward, the one-mixer residual block and the block that norms
+a sub-layer's output, the routed expert layer's call and the head, untied
+or reading the embedding's matrix, with its loss. Each takes the
+node-name prefix of its layer, so a model's argument and scope names are
+its own."""
 from .. import initializer as init
 from .. import symbol as sym
 from ..contrib import symbol as csym
@@ -49,12 +51,22 @@ def expert_layer(x, prefix, **attrs):
     return moe[0], sym.BlockGrad(moe[1], name=prefix + "expert_count")
 
 
-def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps):
-    """``final_norm``, the untied ``lm_head``, float32 logits
-    (``lm_head_f32``) and each sequence's mean next-token cross-entropy
-    behind ``MakeLoss`` (``loss``), grouped with the layers' counts."""
-    logits = linear(csym.RMSNorm(h, eps=rms_eps, name="final_norm"),
-                    "lm_head", vocab_size)
+def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
+                  tied_to=None):
+    """``final_norm``, the ``lm_head``, float32 logits (``lm_head_f32``)
+    and each sequence's mean next-token cross-entropy behind ``MakeLoss``
+    (``loss``), grouped with the layers' counts. The head is untied, its
+    own ``lm_head_weight``, unless ``tied_to`` is the embedding's
+    ``Variable``: then it reads that matrix (``[vocab, hidden]`` is an
+    ``Embedding``'s table and a ``FullyConnected``'s weight alike), one
+    parameter whose gradient is the sum of both uses."""
+    normed = csym.RMSNorm(h, eps=rms_eps, name="final_norm")
+    if tied_to is None:
+        logits = linear(normed, "lm_head", vocab_size)
+    else:
+        logits = sym.FullyConnected(normed, weight=tied_to,
+                                    num_hidden=vocab_size, no_bias=True,
+                                    name="lm_head")
     logits = sym.Cast(logits, dtype="float32", name="lm_head_f32")
     nll = 0 - sym.pick(sym.log_softmax(logits, name="lm_head_logp"),
                        sym.Reshape(label, shape=(-1,)), axis=1,

@@ -56,18 +56,24 @@ GMM_MAX_ROW_TILE = 512
 GMM_MAX_COL_TILE = 2048
 
 
-def gmm_vmem_bytes(tm, tk, tn, itemsize, wgrad=False):
+def gmm_vmem_bytes(tm, tk, tn, itemsize, wgrad=False, split=False):
     """Upper bound on the VMEM one grid step holds: double-buffered
     operand and result blocks, the float32 accumulator and a float32
     product-shaped temporary; wgrad also the masked copies of its two
-    row blocks. Against Mosaic on the v5e under its default limit, bf16:
-    forward 13 MiB counted at (256, 2048, 1024), compiles; 18 at
+    row blocks (it contracts the rows and reads no ``split``); ``split``
+    (forward and dgrad walking the contraction in more than one step,
+    ``tk`` under ``k``) a third float32 table of the result's shape. Against Mosaic on the v5e under its default limit,
+    bf16: forward 13 MiB counted at (256, 2048, 1024), compiles; 18 at
     (512, 2048, 1024), refused; wgrad 15 at (256, 1024, 1024), compiles;
-    28 at (256, 2048, 1024), refused."""
+    28 at (256, 2048, 1024), refused; dgrad with the contraction in two
+    steps at (512, 1536, 1024), 15 counted without the third table,
+    refused at 16.22 MiB used (the same tiles with the contraction whole
+    use 13.08)."""
     if wgrad:
         return (3 * (tm * tk + tm * tn) * itemsize
                 + 2 * tk * tn * itemsize + 2 * tk * tn * 4)
-    return 2 * (tm * tk + tk * tn + tm * tn) * itemsize + 2 * tm * tn * 4
+    return (2 * (tm * tk + tk * tn + tm * tn) * itemsize
+            + (3 if split else 2) * tm * tn * 4)
 
 
 def gmm_row_tile(m, groups):
@@ -109,7 +115,7 @@ def gmm_tiles(m, k, n, groups, dtype, wgrad=False):
     tn = _col_tile(n, GMM_MAX_COL_TILE)
 
     def over():
-        return (gmm_vmem_bytes(tm, tk, tn, itemsize, wgrad)
+        return (gmm_vmem_bytes(tm, tk, tn, itemsize, wgrad, split=tk < k)
                 > VMEM_SCOPED_DEFAULT)
 
     def halved(size, tile):

@@ -1,5 +1,5 @@
 """Transformer-block operators for the Symbol API: RMSNorm, RoPE,
-Attention, LatentAttention, Mamba2, TopKMoE and GatedDeltaNet.
+Attention, LatentAttention, Mamba2, TopKMoE, GatedDeltaNet and ShortConv.
 
 Beyond-reference capability (the 2017 operator set has no attention and
 no sparse-expert layer): what a decoder-only LM with sparse experts
@@ -453,19 +453,15 @@ def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
     f32 = jnp.float32
     b, t, _ = proj.shape
     h, p, n, g, chunk = sizes
-    d_in, taps = h * p, conv_weight.shape[0]
+    d_in = h * p
     conv_dim = d_in + 2 * g * n
 
     def again(f, **policy):
         return jax.checkpoint(f, **policy) if remat else f
 
     def conv1d(proj, conv_weight, conv_bias):
-        padded = jnp.pad(proj[..., d_in:d_in + conv_dim],
-                         ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
-        w = conv_weight.astype(f32)
-        acc = conv_bias.astype(f32)
-        for j in range(taps):
-            acc = acc + padded[:, j:j + t] * w[j]
+        acc = causal_taps(proj[..., d_in:d_in + conv_dim], conv_weight,
+                          conv_bias)
         return jax.nn.silu(acc).astype(proj.dtype)
 
     def gate_norm(y, proj, norm_gamma):
@@ -566,7 +562,8 @@ def _topk_moe(attrs, ins, is_train):
         routed_scale=float(attrs.get("routed_scale", 1.0)),
         activation=str(attrs.get("activation", "swiglu")),
         expert_offset=int(attrs.get("expert_offset", 0)),
-        share_rows_bound=int(attrs.get("share_rows_bound", 0)))
+        share_rows_bound=int(attrs.get("share_rows_bound", 0)),
+        renorm_eps=float(attrs.get("renorm_eps", 0.0)))
     return [y, counts.astype(jnp.float32)]
 
 
@@ -630,6 +627,7 @@ _moe = OpDef(
     defaults={"num_experts": 8, "num_hidden": 0, "top_k": 2,
               "norm_topk_prob": False, "scoring": "softmax",
               "routed_scale": 1.0, "activation": "swiglu",
+              "renorm_eps": 0.0,
               "with_select_bias": False,
               "experts_held": 0,
               "expert_offset": 0,
@@ -797,18 +795,12 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
     f32 = jnp.float32
     bsz, t, _ = query.shape
     dk, dv = query.shape[2] // heads, value.shape[2] // heads
-    taps = conv_weight.shape[0]
 
     def again(f):
         return jax.checkpoint(f) if remat else f
 
     def conv1d(x, w):
-        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(f32)
-        w = w.astype(f32)
-        acc = padded[:, :t] * w[0]
-        for j in range(1, taps):
-            acc = acc + padded[:, j:j + t] * w[j]
-        return jax.nn.silu(acc).astype(x.dtype)
+        return jax.nn.silu(causal_taps(x, w)).astype(x.dtype)
 
     def unit(x):  # each head's vector over its length, float32
         x = x.astype(f32).reshape(bsz, t, heads, -1)
@@ -890,6 +882,84 @@ register(
                   "eps": 1e-6, "allow_neg_eigval": True},
         infer_shape=_gated_delta_net_infer,
         aliases=("GatedDeltaNet",),
+    )
+)
+
+
+# --------------------------------------------------------------------------
+# ShortConv — a double-gated short causal convolution between its two
+# projections (the ``conv`` mixer of the LFM2 family), and the taps that
+# ``Mamba2`` and ``GatedDeltaNet`` share with it
+# --------------------------------------------------------------------------
+_M_SCONV_LOWERINGS = _tm.counter(
+    "sconv.lowerings", "Traces of a ShortConv call site (one per lowering, "
+    "nothing per step); labels: channels, taps, impl (jnp: shifted "
+    "multiply-adds that XLA fuses; there is no kernel)")
+
+
+def causal_taps(x, weight, bias=None):
+    """A causal depthwise convolution over time as shifted multiply-adds:
+    x [B, T, C], weight [taps, C] (tap ``taps - 1`` meets the current
+    token, ``x`` is zero before the sequence), bias [C] or None -> float32
+    [B, T, C], ``bias + sum_j weight[j] * x[t - (taps - 1) + j]`` summed
+    in float32 in that order."""
+    taps, t = weight.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
+    w = weight.astype(jnp.float32)
+    acc = None if bias is None else bias.astype(jnp.float32)
+    for j in range(taps):
+        term = padded[:, j:j + t] * w[j]
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def short_conv(proj, conv_weight, remat=False):
+    """proj [B, T, 3 H] (``in_proj``'s output, ``B | C | x`` in that
+    order), conv_weight [taps, H] -> [B, T, H] (``out_proj``'s input):
+    ``C * causal_taps(B * x)``, no bias and no activation anywhere. The
+    two gates and the taps' sum are float32 whatever ``proj``'s dtype
+    (scopes ``gate_in``, ``conv1d``, ``gate_out``), the result ``proj``'s.
+    ``remat`` (training): one ``jax.checkpoint`` round the three, so the
+    backward pass keeps the op's two inputs and computes the float32
+    tables again."""
+    h = conv_weight.shape[1]
+    _M_SCONV_LOWERINGS.inc(channels=h, taps=conv_weight.shape[0], impl="jnp")
+
+    def core(proj, conv_weight):
+        f32 = jnp.float32
+        with jax.named_scope("gate_in"):
+            z = proj[..., :h].astype(f32) * proj[..., 2 * h:].astype(f32)
+        with jax.named_scope("conv1d"):
+            c = causal_taps(z, conv_weight)
+        with jax.named_scope("gate_out"):
+            return (proj[..., h:2 * h].astype(f32) * c).astype(proj.dtype)
+
+    return (jax.checkpoint(core) if remat else core)(proj, conv_weight)
+
+
+def _short_conv(attrs, ins, is_train):
+    return [short_conv(*ins, remat=is_train)]
+
+
+def _short_conv_infer(attrs, in_shapes):
+    taps = int(attrs.get("conv_kernel", 3))
+    data = _known(in_shapes[0], "ShortConv")
+    if taps <= 0 or len(data) != 3 or data[2] % 3:
+        raise ValueError(
+            "ShortConv: conv_kernel=%d must be positive and data [batch, "
+            "time, 3 * channels] (B | C | x), got %s" % (taps, data))
+    h = data[2] // 3
+    return [data, (taps, h)], [data[:2] + (h,)], []
+
+
+register(
+    OpDef(
+        "_contrib_ShortConv",
+        _short_conv,
+        arguments=("data", "conv_weight"),
+        defaults={"conv_kernel": 3},
+        infer_shape=_short_conv_infer,
+        aliases=("ShortConv",),
     )
 )
 

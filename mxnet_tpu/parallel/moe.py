@@ -228,15 +228,18 @@ _M_SHARE_LOWERINGS = _tm.counter(
     "share of its experts (one per lowering, nothing per step); labels: "
     "held (experts computed here), of (experts routed over), bound (rows "
     "of the share's buffer) and, where it is not 1, scale (the factor on "
-    "the routing weights)")
+    "the routing weights); where they are not the defaults, act and "
+    "renorm_eps")
 
 
-def _route(params, x, top_k, norm_topk_prob, scoring, routed_scale=1.0):
+def _route(params, x, top_k, norm_topk_prob, scoring, routed_scale=1.0,
+           renorm_eps=0.0):
     """Float32 routing: (weights [T, k], experts [T, k]). ``softmax``:
     top-k of the softmax. ``sigmoid``: scores ``sigmoid(logits)``, the
     choice by score plus ``select_bias`` (which carries no gradient and
     never reaches the weights), the weights the chosen scores. The
-    weights, renormalised or not, times ``routed_scale``."""
+    weights, renormalised or not (over their sum plus ``renorm_eps``),
+    times ``routed_scale``."""
     logits = jnp.dot(
         x.astype(jnp.float32), params["gate_w"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST)
@@ -260,7 +263,8 @@ def _route(params, x, top_k, norm_topk_prob, scoring, routed_scale=1.0):
         raise ValueError("topk_moe: scoring must be softmax or sigmoid, "
                          "got %r" % (scoring,))
     if norm_topk_prob:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + renorm_eps if renorm_eps else total)
     if routed_scale != 1.0:
         weights = weights * routed_scale
     return weights, experts
@@ -268,7 +272,7 @@ def _route(params, x, top_k, norm_topk_prob, scoring, routed_scale=1.0):
 
 def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
              expert_offset=0, share_rows_bound=0, routed_scale=1.0,
-             activation="swiglu"):
+             activation="swiglu", renorm_eps=0.0):
     """Dropless top-k MoE FFN with SwiGLU experts (the OLMoE / Mixtral
     block) or, ``activation="relu2"``, un-gated ``relu(.)^2`` ones
     (Nemotron-H). x: [tokens, d_model] -> ([tokens, d_model], counts
@@ -297,8 +301,10 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     float32 whatever the activations' dtype (``_route``: ``scoring``
     ``softmax`` or ``sigmoid``, the latter with the optional
     ``select_bias`` [E]); routing weights are not renormalised unless
-    ``norm_topk_prob``, and are multiplied by ``routed_scale`` after
-    that (DeepSeek-V3's ``routed_scaling_factor``). ``counts`` is the
+    ``norm_topk_prob`` (then over their sum plus ``renorm_eps``: the
+    ``+ 1e-6`` of the ``lfm2_moe`` code), and are multiplied by
+    ``routed_scale`` after that (DeepSeek-V3's
+    ``routed_scaling_factor``). ``counts`` is the
     number of rows each of the E experts received (int32, no gradient).
 
     **A share of the experts.** Where ``w_gate_up`` holds H < E experts,
@@ -318,12 +324,14 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
 
     with jax.named_scope("router"):
         weights, experts = _route(params, x, top_k, norm_topk_prob,
-                                  scoring, routed_scale)
+                                  scoring, routed_scale, renorm_eps)
 
     if held < num_experts:
         labels = {} if routed_scale == 1.0 else {"scale": routed_scale}
         if activation != "swiglu":
             labels["act"] = activation
+        if renorm_eps:
+            labels["renorm_eps"] = renorm_eps
         _M_SHARE_LOWERINGS.inc(held=held, of=num_experts,
                                bound=share_rows_bound, **labels)
         return _topk_moe_share(params, x, weights, experts, expert_offset,

@@ -104,11 +104,13 @@ def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, window,
     assert "flash_dq_" not in text and "flash_dkv_" not in text
 
 
-# the three LM cells' attention calls (T, query heads, key/value heads,
-# D, Dv, window): Kanana's latent attention, OLMoE's, MiMo's full and
-# window layers
+# the LM cells' attention calls (T, query heads, key/value heads, D, Dv,
+# window): Kanana's latent attention, OLMoE's, MiMo's full and window
+# layers, LFM2's (heads of 64: half a lane row a head, 4 query heads a
+# key/value head)
 CELL_CALLS = {
     "kanana2_8k": (8192, 32, 32, 192, 128, 0),
+    "lfm2_8k": (8192, 32, 8, 64, 64, 0),
     "olmoe_4k": (4096, 16, 16, 128, 128, 0),
     "mimo_full_4k": (4096, 8, 1, 192, 128, 0),
     "mimo_window_4k": (4096, 8, 1, 192, 128, 128),
@@ -209,7 +211,15 @@ GMM_SHAPES = [
     (2048, 2048, 4096, 8, jnp.bfloat16),
 ] + [(m, k, n, groups, jnp.bfloat16)
      for m, groups in ((3072, 8), (6144, 8), (6144, 16), (12288, 16))
-     for k, n in ((2688, 1856), (1856, 2688))]
+     for k, n in ((2688, 1856), (1856, 2688))
+] + [(m, k, n, 8, jnp.bfloat16)
+     # the LFM2 share cell's: 8 held SwiGLU experts of 1536 (gate and up
+     # one product of 3072 columns, then down; 2048 x 1536 the up
+     # projection alone) at the rows they expect and at the buffer of
+     # 8,192, whose row tile is 512: the dgrad of 3072 columns walks its
+     # contraction in two steps and gives up half its result tile for it
+     for m in (4096, 8192)
+     for k, n in ((2048, 3072), (1536, 2048), (2048, 1536))]
 
 
 @pytest.mark.parametrize("m,k,n,groups,dtype", GMM_SHAPES)

@@ -591,7 +591,8 @@ def test_gmm_tiles_divide_or_mask_and_fit(m, k, n, groups, dtype):
         if not wgrad:
             assert k % tk == 0
         assert pk.gmm.gmm_vmem_bytes(
-            tm, tk, tn, itemsize, wgrad) <= pk.common.VMEM_SCOPED_DEFAULT
+            tm, tk, tn, itemsize, wgrad,
+            split=tk < k) <= pk.common.VMEM_SCOPED_DEFAULT
 
 
 def test_gmm_tiles_at_the_cell_shape_keep_k_whole():
@@ -604,6 +605,30 @@ def test_gmm_tiles_at_the_cell_shape_keep_k_whole():
     for k in (2048, 1024):
         assert pk.gmm_tiles(32768, k, 2048, 64, bf16, wgrad=True) == (
             256, 1024, 1024)
+
+
+def test_gmm_tiles_count_a_third_table_where_the_contraction_is_split():
+    """The LFM2 share cell's buffer of 8,192 rows over 8 experts is the
+    first row tile of 512: the dgrad of the 3,072 gate-and-up columns
+    (the transposed problem, k 3072 n 2048) walks its contraction in two
+    steps of 1536, and Mosaic needs 16.22 MiB for the (512, 1536, 1024)
+    that two float32 tables count at 15.0. With the third table it gives
+    up half its result tile; the same tiles with the contraction whole
+    (the down projection's forward) stay, and so does every tile of the
+    cells that were there (a split contraction at a row tile of 256 or
+    128 counts 14.5 MiB at the most)."""
+    bf16 = jnp.bfloat16
+    count = pk.gmm.gmm_vmem_bytes
+    assert count(512, 1536, 1024, 2) == 15 * 2 ** 20
+    assert count(512, 1536, 1024, 2, split=True) == 17 * 2 ** 20
+    assert pk.gmm_tiles(8192, 3072, 2048, 8, bf16) == (512, 1536, 512)
+    assert pk.gmm_tiles(8192, 1536, 2048, 8, bf16) == (512, 1536, 1024)
+    assert pk.gmm_tiles(8192, 2048, 3072, 8, bf16) == (512, 2048, 768)
+    # Nemotron's, MiMo's and Kanana's: split or whole, unchanged
+    assert pk.gmm_tiles(12288, 2688, 1856, 16, bf16) == (256, 896, 1856)
+    assert pk.gmm_tiles(12288, 1856, 2688, 16, bf16) == (256, 1856, 896)
+    assert pk.gmm_tiles(2048, 4096, 4096, 8, bf16) == (128, 2048, 1024)
+    assert pk.gmm_tiles(12288, 1536, 2048, 16, bf16) == (256, 1536, 1024)
 
 
 def test_gmm_lowerings_counter_counts_one_per_call_site_and_mode():

@@ -14,10 +14,28 @@ discarded pass over the table (a process's first executables ran 50
 times slower for their first calls); `bound_ms` is one move's bytes
 (read and write 134 MB) at the chip's 819 GB/s.
 
-Prints one JSON line a row and writes `chiprun_out/moe_permute.json`;
-PERF.md section 7 holds the table (PR 32).
+**A share's moves** (PR 47), at the three share cells' shapes (`SHARES`:
+Kanana 8,192 x 2,048, top-6, 16 of 128 experts held, a buffer of 12,288
+rows; LFM2 8,192 x 2,048, top-4, 8 of 64, 8,192; MiMo 4,096 x 4,096,
+top-8, 8 of 256, 2,048), uniform routing, three formulations of the same
+two moves, forward and backward ms a move: `take`, what the tree had
+(`jnp.take` and `jax.ops.segment_sum` under autodiff: two row
+scatter-adds and a scalar one); `inverse_gather`, PR 32's cure carried
+over (every (token, k) pair gathers its row through a zero row:
+`tokens * top_k` rows where the buffer holds `bound`); `segment_product`,
+`parallel/moe.py`'s `_share_dispatch` / `_share_combine` (gathers over
+the buffer and `ops.kernels.sorted_segment_sum`), with `_share_weights`
+(the weights' pick and its placement) a row of its own since the weight
+is applied inside the experts there. `plan` is the index arithmetic
+(compaction, sorts) of the tree's formulation and of this one;
+`bound_ms` of a share row is one move's bytes (the buffer read and the
+tokens written, or the reverse) at 819 GB/s.
 
-    chiprun -- python3 benchmarks/moe_permute.py
+Prints one JSON line a row and writes `chiprun_out/moe_permute.json`;
+PERF.md section 7 holds the tables (PR 32, PR 47).
+
+    chiprun -- python3 benchmarks/moe_permute.py [--shares-only]
+    python3 benchmarks/moe_permute.py --rehearse-cpu    (proves the script)
 """
 import json
 import os
@@ -60,6 +78,37 @@ def _time(f, *args, reps=20):
     jax.block_until_ready(r)
     np.asarray(jax.tree_util.tree_leaves(r)[0][:1])  # closed by a fetch
     return (time.perf_counter() - t0) / reps * 1e3
+
+
+def _device_ms(f, *args, reps=10):
+    """Device 0's busy time a call, ms: the union of its ops over a
+    profiled run of ``reps`` calls (``bench/reduce_trace.py`` reads the
+    slice, as the benchmark's readers do). The host clock of ``_time``
+    has a floor of about 0.3 ms a call on the chip's host, above most of
+    a share's moves; None off the TPU."""
+    import glob
+    import tempfile
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import reduce_trace
+
+    jax.block_until_ready(f(*args))
+    with tempfile.TemporaryDirectory() as where:
+        with jax.profiler.trace(where):
+            for _ in range(reps):
+                r = f(*args)
+            jax.block_until_ready(r)
+        found = glob.glob(os.path.join(
+            where, "plugins", "profile", "*", "*.xplane.pb"))
+        devices = reduce_trace.load(found[0])["devices"] if found else {}
+    if 0 not in devices:
+        return None
+    busy = reduce_trace.total(reduce_trace.union(
+        [(s, s + d) for _, s, d in devices[0]["ops"]]))
+    return busy / reps / 1e6
 
 
 def sort_pair(experts):
@@ -122,7 +171,149 @@ def table(name, load, row):
             bwd_ms=_time(*backward(f, diff, rest), cotangent))
 
 
+SHARES = {  # tokens, width, experts, held, top_k, bound
+    "kanana": (8192, 2048, 128, 16, 6, 12288),
+    "lfm2": (8192, 2048, 64, 8, 4, 8192),
+    "mimo": (4096, 4096, 256, 8, 8, 2048),
+}
+
+
+def take_plan(experts, held, bound):
+    """The tree's compaction before PR 47: compare-and-count, a gather,
+    ``jnp.argsort`` (int64 under x64) and two gathers by it."""
+    tokens, top_k = experts.shape
+    local = experts.reshape(-1)
+    here = (local >= 0) & (local < held)
+    running = jnp.cumsum(here.astype(jnp.int32))
+    pairs = jnp.searchsorted(
+        running, jnp.arange(1, bound + 1, dtype=jnp.int32), side="left",
+        method="compare_all")
+    group = jnp.take(local, pairs, mode="fill", fill_value=held)
+    order = jnp.argsort(group, stable=True)
+    pairs, group = pairs[order], group[order]
+    used = group < held
+    return pairs, used, jnp.where(used, pairs // top_k, 0)
+
+
+def segment_plan(experts, held, bound):
+    """This tree's: compare-and-count, a gather, two int32 sorts."""
+    return moe._share_plan(experts, offset=0, held=held, bound=bound)
+
+
+def share_table(name, shape, row, reps=20):
+    tokens, width, experts_n, held, top_k, bound = shape
+    rng = np.random.RandomState(1)
+    x = jnp.asarray(rng.randn(tokens, width), jnp.bfloat16)
+    rows = jnp.asarray(rng.randn(bound, width), jnp.bfloat16)
+    weights = jnp.asarray(rng.rand(tokens, top_k), jnp.float32)
+    experts = jnp.asarray(np.argsort(
+        rng.rand(tokens, experts_n), axis=1)[:, :top_k].astype(np.int32))
+    move_bytes = (bound + tokens) * width * 2
+
+    def put(what, by, fwd, bwd):
+        """``fwd`` / ``bwd``: (jitted function, arguments) or None."""
+        def ms(call, clock):
+            return None if call is None else clock(
+                call[0], *call[1], reps=reps)
+
+        row(share=name, what=what, by=by,
+            fwd_device_ms=ms(fwd, _device_ms), bwd_device_ms=ms(
+                bwd, _device_ms), fwd_ms=ms(fwd, _time),
+            bwd_ms=ms(bwd, _time), bound_ms=1e3 * move_bytes / 819e9)
+
+    for by, plan in (("take", take_plan), ("segment_product", segment_plan)):
+        put("plan", by, (jax.jit(
+            lambda e, plan=plan: plan(e, held, bound)), (experts,)), None)
+    t_pairs, t_used, t_token = jax.jit(
+        lambda e: take_plan(e, held, bound))(experts)
+    _, token, pairs, inverse, segment, slot = segment_plan(
+        experts, held, bound)
+    running = jnp.cumsum(experts.reshape(-1) < held, dtype=jnp.int32
+                         ).reshape(tokens, top_k)
+    held_rows = int(np.asarray(running)[-1, -1])
+    row(share=name, what="held_rows", by="routing", rows=held_rows,
+        bound=bound)
+
+    # -- the tree's formulation, under autodiff
+    def take_dispatch(x):
+        return jnp.where(t_used[:, None], jnp.take(x, t_token, axis=0), 0)
+
+    def take_combine(out_rows, weights):
+        weight = jnp.where(t_used, jnp.take(
+            weights.reshape(-1), t_pairs, mode="fill", fill_value=0), 0)
+        weighted = jnp.where(
+            t_used[:, None],
+            out_rows.astype(jnp.float32) * weight[:, None], 0)
+        return jax.ops.segment_sum(
+            weighted, t_token, num_segments=tokens).astype(out_rows.dtype)
+
+    # -- PR 32's cure carried over: every pair gathers its row of the
+    # buffer, a pair that holds none the zero row after it
+    row_of_pair = jnp.where(
+        (experts < held) & (running <= bound),
+        moe._take_rows(inverse, jnp.clip(running.reshape(-1) - 1, 0,
+                                         bound - 1)).reshape(tokens, top_k),
+        bound)
+
+    def zero_row(a):
+        return jnp.concatenate([a, jnp.zeros((1,) + a.shape[1:], a.dtype)])
+
+    def per_token(rows):
+        return moe._take_rows(zero_row(rows), row_of_pair.reshape(-1)
+                              ).reshape(tokens, top_k, width)
+
+    def inverse_dispatch_bwd(d_rows):
+        return jnp.sum(per_token(d_rows).astype(jnp.float32),
+                       axis=1).astype(d_rows.dtype)
+
+    def inverse_combine(out_rows, weights):
+        return jnp.einsum("tkd,tk->td", per_token(out_rows).astype(
+            jnp.float32), weights).astype(out_rows.dtype)
+
+    def inverse_combine_bwd(out_rows, weights, dy):
+        d_weights = jnp.einsum("td,tkd->tk", dy.astype(jnp.float32),
+                               per_token(out_rows).astype(jnp.float32))
+        scale = jnp.where(pairs < tokens * top_k, moe._take_rows(
+            weights.reshape(-1), jnp.minimum(pairs, tokens * top_k - 1)), 0)
+        d_rows = moe._take_rows(dy, token).astype(jnp.float32)
+        return (d_rows * scale[:, None]).astype(dy.dtype), d_weights
+
+    # -- this tree's
+    def segment_dispatch(x):
+        return moe._share_dispatch(x, token, inverse, segment, tokens)
+
+    def segment_combine(out_rows):
+        return moe._share_combine(out_rows, token, inverse, segment, tokens)
+
+    def segment_weights(weights):
+        return moe._share_weights(weights, pairs, inverse, segment, slot,
+                                  weights.shape)
+
+    def both(f, diff, cotangent):
+        run, transpose = backward(f, diff, ())
+        return (jax.jit(f), diff), (run, (transpose, cotangent))
+
+    put("dispatch", "take", *both(take_dispatch, (x,), rows))
+    put("dispatch", "inverse_gather",
+        (jax.jit(lambda x: moe._take_rows(x, token)), (x,)),
+        (jax.jit(inverse_dispatch_bwd), (rows,)))
+    put("dispatch", "segment_product", *both(segment_dispatch, (x,), rows))
+    put("combine", "take", *both(take_combine, (rows, weights), x))
+    put("combine", "inverse_gather",
+        (jax.jit(inverse_combine), (rows, weights)),
+        (jax.jit(inverse_combine_bwd), (rows, weights, x)))
+    put("combine", "segment_product", *both(segment_combine, (rows,), x))
+    put("weights", "segment_product", *both(
+        segment_weights, (weights,), jnp.asarray(rng.rand(bound),
+                                                 jnp.float32)))
+
+
 def main():
+    if "--rehearse-cpu" in sys.argv:
+        # the script end to end at a toy size; its times mean nothing
+        share_table("toy", (512, 128, 16, 4, 4, 640),
+                    lambda **kw: print(json.dumps(kw), flush=True), reps=1)
+        return
     dev = jax.devices()[0]
     move_bytes = 2 * TOKENS * TOP_K * WIDTH * 2
     res = {"device": str(dev.device_kind), "platform": dev.platform,
@@ -134,9 +325,14 @@ def main():
         print(json.dumps(kw), flush=True)
         res["rows"].append(kw)
 
-    table("discarded", ROUTINGS["multinomial"], lambda **kw: None)
-    for name, load in ROUTINGS.items():
-        table(name, load, row)
+    for name, shape in SHARES.items():
+        share_table(name, shape, lambda **kw: None, reps=2)   # discarded
+    for name, shape in SHARES.items():
+        share_table(name, shape, row)
+    if "--shares-only" not in sys.argv:
+        table("discarded", ROUTINGS["multinomial"], lambda **kw: None)
+        for name, load in ROUTINGS.items():
+            table(name, load, row)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/moe_permute.json", "w") as f:
         json.dump(res, f, indent=1)

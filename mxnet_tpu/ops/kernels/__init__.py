@@ -10,7 +10,8 @@ because they need explicit on-chip (VMEM) accumulation patterns.
                and ``reference_attention`` (the oracle)
   latent       latent attention's two-key flash pair and the pass over
                its query
-  gmm          the expert layer's grouped matmul
+  gmm          the expert layer's grouped matmul, and the sorted
+               segment sum that is its wgrad at another shape
   ssd          the chunked state-space scan (Mamba-2)
   gdn          the gated delta rule's chunk core
   slab_update  the AMP optimizer step over a flat slab
@@ -38,7 +39,8 @@ from .flash import (
     attention, flash_attention, flash_tiles, reference_attention)
 from .gdn import gated_delta_rule, gdn_takes
 from .gmm import (
-    gmm_metadata, gmm_row_tile, gmm_runs_kernel, gmm_tiles, grouped_matmul)
+    gmm_metadata, gmm_row_tile, gmm_runs_kernel, gmm_tiles, grouped_matmul,
+    sorted_segment_sum)
 from .latent import (
     latent_flash, latent_flash_takes, latent_query, latent_query_takes)
 from .slab_update import (
@@ -52,5 +54,5 @@ __all__ = [
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
     "grouped_matmul", "latent_flash", "latent_flash_takes", "latent_query",
     "latent_query_takes", "reference_attention", "SLAB_STATE_SLOTS",
-    "slab_update_reference", "ssd_scan", "ssd_takes",
+    "slab_update_reference", "sorted_segment_sum", "ssd_scan", "ssd_takes",
 ]

@@ -39,7 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ... import telemetry as _tm
 from .common import (
-    VMEM_SCOPED_DEFAULT, affine, no_x64, on_tpu, operand_label)
+    LANES, VMEM_SCOPED_DEFAULT, affine, no_x64, on_tpu, operand_label,
+    pad_to, whole_lanes)
 
 _M_GMM_LOWERINGS = _tm.counter(
     "moe.gmm_lowerings", "Traces of a grouped_matmul kernel call site "
@@ -462,3 +463,107 @@ def grouped_matmul(lhs, rhs, group_sizes, metadata=None, interpret=False):
             gmm_tiles(m, k, n, groups, dtype, wgrad=True))
     return _gmm(lhs.astype(dtype), rhs.astype(dtype), group_sizes,
                 tuple(metadata), plan, bool(interpret))
+
+
+# Tokens a group of ``sorted_segment_sum``: the contraction's ``k``, one
+# MXU pass wide twice over; 12,288 rows of 2,048 into 8,192 tokens are
+# 12.9 GFLOP at it and 8 MiB of VMEM at ``gmm_tiles``' (128, 256, 2048).
+SEGMENT_TILE = 256
+
+
+def _bf16_pieces(rows):
+    """float32 ``rows`` as three float32 tables of bf16-exact values that
+    sum to it, side by side: whatever precision the MXU multiplies float32
+    operands at (its default is one bf16 pass), each piece times the 0 / 1
+    table is exact. ``reduce_precision`` and not a cast there and back,
+    which XLA may drop."""
+    pieces, rest = [], rows
+    for _ in range(3):
+        piece = lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+        pieces.append(piece)
+        rest = lax.sub(rest, piece)
+    return lax.concatenate(pieces, 1)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("num_segments", "tiles", "interpret"))
+def segment_product_call(rows, segment, *, num_segments, tiles, interpret):
+    """``sorted_segment_sum``'s kernel branch: the 0 / 1 table, the
+    groups' sizes and ``gmm_wgrad_call`` over them. One ``jax.jit`` a
+    signature, as the two wrappers above, so that a model's layers trace
+    the table and the metadata once."""
+    m, n = rows.shape
+    groups = -(-num_segments // SEGMENT_TILE)
+    # a row of no segment is a row of no group: the kernel's masks keep
+    # it out, so a NaN there never meets a 0 of the table
+    group = lax.select(lax.lt(segment, np.int32(num_segments)),
+                       lax.div(segment, np.int32(SEGMENT_TILE)),
+                       lax.full_like(segment, groups))
+    sizes = lax.reduce(
+        lax.convert_element_type(lax.eq(
+            lax.broadcast_in_dim(group, (m, groups), (0,)),
+            lax.broadcasted_iota(jnp.int32, (m, groups), 1)), jnp.int32),
+        np.int32(0), lax.add, (0,))
+    table = lax.convert_element_type(lax.eq(
+        lax.broadcast_in_dim(lax.rem(segment, np.int32(SEGMENT_TILE)),
+                             (m, SEGMENT_TILE), (0,)),
+        lax.broadcasted_iota(jnp.int32, (m, SEGMENT_TILE), 1)), rows.dtype)
+    wide = rows.dtype == jnp.float32
+    operand, _ = pad_to(_bf16_pieces(rows) if wide else rows, 1, LANES)
+    offsets, _, _, _, gids, tids, visits = gmm_metadata(sizes, m, tiles[0])
+    out = gmm_wgrad_call(offsets, gids, tids, visits, table, operand,
+                         groups=groups, tiles=tiles, interpret=interpret)
+    out = lax.slice_in_dim(
+        lax.reshape(out, (groups * SEGMENT_TILE, operand.shape[1])),
+        0, num_segments)
+    part = functools.partial(lax.slice_in_dim, out, axis=1)
+    if wide:
+        return lax.add(lax.add(part(0, n), part(n, 2 * n)),
+                       part(2 * n, 3 * n))
+    return part(0, n)
+
+
+@functools.partial(jax.jit, static_argnames=("num_segments",))
+def segment_sum_call(rows, segment, *, num_segments):
+    """``sorted_segment_sum``'s branch off the TPU."""
+    return jax.ops.segment_sum(
+        rows.astype(jnp.float32), segment, num_segments=num_segments,
+        indices_are_sorted=True).astype(rows.dtype)
+
+
+def sorted_segment_sum(rows, segment, num_segments, interpret=False):
+    """``rows[m, n]`` whose ``segment`` ids [m] (int32) never fall, summed
+    by id -> ``[num_segments, n]`` in ``rows.dtype``: float32 accumulation,
+    one rounding. Rows whose id is ``num_segments`` or more (they stand
+    last) belong to no segment and contribute nothing, whatever they
+    hold.
+
+    Where the computation is lowered for the TPU the sum is a product and
+    no scatter: ``SEGMENT_TILE`` consecutive segments are one group, the
+    rows of a group are a slab of ``rows`` (they are sorted), and the
+    group's sums are ``table[slab]^T x rows[slab]`` with ``table[r, c]``
+    1 where row r's id is the group's c-th: the ragged contraction over
+    rows that ``gmm_wgrad_call`` computes, called here at its shapes
+    (lhs the exact 0 / 1 table, the group sizes a count of ids, tiles
+    from ``gmm_tiles``; counted in ``moe.gmm_lowerings`` as a ``wgrad``).
+    bf16 rows are its operand as they are; float32 rows go as three
+    bf16-exact pieces side by side, summed after (``_bf16_pieces``), so
+    the result is float32's whatever the MXU's pass. On every other
+    platform, and where ``gmm_runs_kernel`` refuses the shapes,
+    ``jax.ops.segment_sum(indices_are_sorted=True)``. Not
+    differentiable: its callers are the rules of a ``custom_vjp``
+    (``parallel/moe.py``'s share moves)."""
+    m, n = rows.shape
+    segment = lax.convert_element_type(segment, jnp.int32)
+    plain = functools.partial(segment_sum_call, num_segments=num_segments)
+    if not gmm_runs_kernel(m, rows.dtype):
+        return plain(rows, segment)
+    columns = n * (3 if rows.dtype == jnp.float32 else 1)
+    tiles = gmm_tiles(m, SEGMENT_TILE, whole_lanes(columns),
+                      -(-num_segments // SEGMENT_TILE), rows.dtype,
+                      wgrad=True)
+    _gmm_count("wgrad", rows.dtype, tiles)
+    return on_tpu(
+        functools.partial(segment_product_call, num_segments=num_segments,
+                          tiles=tiles),
+        plain, interpret, rows, segment)

@@ -121,24 +121,77 @@ def _expert_dot(counts, rows, dtype):
                              interpret=kernels.common.INTERPRET)
 
 
-def _experts(params, rows, counts, activation):
+def _experts(params, rows, counts, activation, scale=None):
     """The experts' feed-forward over ``rows`` sorted into ``counts``
     groups. ``swiglu``: ``down(silu(gate) * up)``, gate and up one
     product over ``w_gate_up`` [E, d, 2h]; ``relu2``: ``down(relu(up)^2)``
     with ``w_gate_up`` [E, d, h] the up projection alone, two grouped
-    products a pass where SwiGLU does three."""
+    products a pass where SwiGLU does three. ``scale`` [rows] (float32; a
+    share's routing weights): the activation is computed in float32,
+    multiplied by its row's scale there and rounded once, so the rows
+    leave the down projection already weighted (``down`` is linear)
+    (``_scaled_activation``)."""
     dtype = rows.dtype
     hidden = params["w_down"].shape[1]
-    dot = _expert_dot(counts, rows.shape[0], dtype)
-    up = dot(rows, params["w_gate_up"].astype(dtype))
-    if activation == "relu2":
-        act = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
-    elif activation == "swiglu":
-        act = jax.nn.silu(up[:, :hidden]) * up[:, hidden:]
-    else:
+    if activation not in ("swiglu", "relu2"):
         raise ValueError("topk_moe: activation must be swiglu or relu2, "
                          "got %r" % (activation,))
+    dot = _expert_dot(counts, rows.shape[0], dtype)
+    up = dot(rows, params["w_gate_up"].astype(dtype))
+    if scale is not None:
+        act = _scaled_activation(up, scale, activation)
+    elif activation == "relu2":
+        act = jnp.square(jax.nn.relu(up.astype(jnp.float32))).astype(dtype)
+    else:
+        act = jax.nn.silu(up[:, :hidden]) * up[:, hidden:]
     return dot(act, params["w_down"].astype(dtype))
+
+
+def _activation_parts(up, activation):
+    """float32 (activation of ``up``, its derivatives by ``up``'s column
+    blocks): ``relu2``: ``relu(up)^2`` and (``2 relu(up)``,); ``swiglu``:
+    ``silu(gate) * lin`` over the two halves of ``up``'s columns (sliced
+    before they are widened: XLA writes a widened ``up`` to HBM whole
+    where the slices follow it) and (``silu'(gate) * lin``, ``silu(gate)``)."""
+    if activation == "relu2":
+        positive = jax.nn.relu(up.astype(jnp.float32))
+        return jnp.square(positive), (2 * positive,)
+    hidden = up.shape[1] // 2
+    gate = up[:, :hidden].astype(jnp.float32)
+    lin = up[:, hidden:].astype(jnp.float32)
+    sig = jax.nn.sigmoid(gate)
+    silu = gate * sig
+    return silu * lin, ((sig + silu * (1 - sig)) * lin, silu)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+@functools.partial(jax.jit, static_argnums=(2,))
+def _scaled_activation(up, scale, activation):
+    """``_experts``' activation of ``up`` in float32 times ``scale`` a
+    row, rounded once. The backward keeps ``up`` and ``scale`` and
+    computes the activation again, in two passes over them (the rows'
+    cotangent; the scale's, a sum a row): no float32 table of the
+    activation's shape reaches HBM either way. One ``jax.jit`` a
+    signature each way for a model's layers."""
+    act, _ = _activation_parts(up, activation)
+    return (act * scale[:, None]).astype(up.dtype)
+
+
+def _scaled_activation_fwd(up, scale, activation):
+    return _scaled_activation(up, scale, activation), (up, scale)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _scaled_activation_bwd(activation, res, d_out):
+    up, scale = res
+    act, slopes = _activation_parts(up, activation)
+    d_out = d_out.astype(jnp.float32)
+    d_act = d_out * scale[:, None]
+    d_up = jnp.concatenate([d_act * slope for slope in slopes], axis=1)
+    return d_up.astype(up.dtype), jnp.sum(d_out * act, axis=1)
+
+
+_scaled_activation.defvjp(_scaled_activation_fwd, _scaled_activation_bwd)
 
 
 _M_PERMUTE_LOWERINGS = _tm.counter(
@@ -288,10 +341,12 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     whatever the load. The ``tokens * top_k`` rows are sorted by expert
     and the three expert matmuls run as two grouped matmuls over the
     sorted rows, so the work is that of the routing and not
-    ``O(T * E * C * d)`` as in ``switch_moe``'s dense dispatch. The rows
-    move into expert order and back by gathers only, forward and
-    backward (``_dispatch``, ``_combine``), and nothing on this path is
-    a scatter but the transpose of the router's ``top_k``. Who
+    ``O(T * E * C * d)`` as in ``switch_moe``'s dense dispatch. Where
+    the layer holds every expert the rows move into expert order and
+    back by gathers only, forward and backward (``_dispatch``,
+    ``_combine``: a permutation and its inverse over the ``tokens *
+    top_k`` pairs), and nothing on that path is a scatter but the
+    transpose of the router's ``top_k`` (a share's moves are below). Who
     computes them (``_expert_dot``): the Pallas kernels of
     ``ops.kernels.grouped_matmul`` where the step is lowered for
     the TPU, ``jax.lax.ragged_dot`` everywhere else (by
@@ -316,7 +371,21 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     ``share_rows_bound`` rows, and gather, expert products and combine
     run over that buffer, not over ``tokens * top_k``. Rows past the
     bound are not computed; ``counts`` still counts every row over all
-    E, so a caller sees that they existed.
+    E, so a caller sees that they existed. A share has no inverse
+    permutation (a token holds 0 to ``top_k`` rows of the buffer), but
+    its buffer is made in token order, so a token's rows are a
+    contiguous segment of it: rows reach the buffer by a gather
+    (``_share_dispatch``) and leave it by a gather into token order and
+    a sorted segment sum (``_share_combine``;
+    ``ops.kernels.sorted_segment_sum``: the grouped matmul's wgrad
+    kernel over an exact 0 / 1 table where the step is lowered for the
+    TPU, ``jax.ops.segment_sum`` elsewhere), each the other's transpose,
+    so neither direction scatters; float32 accumulation, one rounding.
+    The routing weight multiplies the experts' activation in float32
+    before that activation's one rounding (``_experts(scale=)``), not
+    the rounded output rows: the down projection is linear, and what
+    the segment sum adds is then the rows themselves. The weights'
+    cotangents come back through the same sum (``_share_weights``).
     """
     tokens = x.shape[0]
     num_experts = params["gate_w"].shape[1]
@@ -332,8 +401,12 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
             labels["act"] = activation
         if renorm_eps:
             labels["renorm_eps"] = renorm_eps
-        _M_SHARE_LOWERINGS.inc(held=held, of=num_experts,
-                               bound=share_rows_bound, **labels)
+        from ..ops import kernels
+
+        _M_SHARE_LOWERINGS.inc(
+            held=held, of=num_experts, bound=share_rows_bound,
+            sum="segment_product" if kernels.gmm_runs_kernel(
+                share_rows_bound, x.dtype) else "segment_sum", **labels)
         return _topk_moe_share(params, x, weights, experts, expert_offset,
                                share_rows_bound, activation)
 
@@ -359,11 +432,161 @@ def topk_moe(params, x, top_k, norm_topk_prob=False, scoring="softmax",
     return y, jax.lax.stop_gradient(counts)
 
 
+# A share's row moves. The compaction makes the buffer in TOKEN order (a
+# token's held rows are consecutive there, the free rows last) and one
+# small sort puts it in expert order; ``inverse`` [bound] is the row of
+# the expert-ordered buffer that token-ordered row r holds and
+# ``segment`` [bound] that row's token (``tokens`` for a free row).
+# Autodiff would transpose ``x[token]`` to a scatter-add of the buffer
+# into the tokens; here the sum of a token's rows is a sorted segment
+# sum (``ops.kernels.sorted_segment_sum``: a product where the step is
+# lowered for the TPU), so the two moves are each other's transposes and
+# rows move by gathers over the buffer only, forward and backward. A free
+# row belongs to no segment and to no expert's group: whatever it holds,
+# nothing reads it. What a layer traces of all this beyond the gathers
+# sits behind module-level ``jax.jit``s: one trace a signature.
+
+@functools.partial(jax.jit, static_argnames=("offset", "held", "bound"))
+def _share_plan(experts, *, offset, held, bound):
+    """Where the rows of a share's buffer come from, from the routing
+    ``experts`` [tokens, top_k] alone: ``sizes`` [held] (rows an expert
+    held), and a row each of the expert-ordered buffer ``token`` (0 for a
+    free row), ``pairs`` (its (token, k) pair; ``tokens * top_k`` for a
+    free row) and of the token-ordered one ``inverse``, ``segment``,
+    ``slot`` (the k of its pair)."""
+    tokens, top_k = experts.shape
+    local = experts.reshape(-1).astype(jnp.int32) - jnp.int32(offset)
+    here = (local >= 0) & (local < held)
+    # the first ``bound`` pairs routed here, in token order: row r of
+    # the buffer takes the first pair whose running count of held pairs
+    # reaches r + 1 (a fused compare-and-count; what
+    # ``jnp.nonzero(size=)`` does by a scatter-add over every pair); one
+    # past the last pair marks a free row
+    running = jnp.cumsum(here.astype(jnp.int32))
+    row = jax.lax.iota(jnp.int32, bound)
+    pairs = jnp.searchsorted(running, row + 1, side="left",
+                             method="compare_all").astype(jnp.int32)
+    free = pairs >= tokens * top_k
+    group = jnp.where(free, held, _take_rows(
+        local, jnp.minimum(pairs, tokens * top_k - 1)))
+    segment = jnp.where(free, tokens, pairs // top_k)
+    slot = pairs % top_k
+    # into expert order (int32 sorts: ``jnp.argsort`` sorts and gathers
+    # by int64 under jax_enable_x64) and back
+    group, pairs, order = jax.lax.sort(
+        (group, pairs, row), num_keys=1, is_stable=True)
+    _, inverse = jax.lax.sort((order, row), num_keys=1)
+    sizes = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32), axis=0)
+    token = jnp.where(group < held, pairs // top_k, 0)
+    return sizes, token, pairs, inverse, segment, slot
+
+
+def _sum_rows(rows, inverse, segment, tokens):
+    """The expert-ordered ``rows`` of each token summed in float32 and
+    rounded once -> [tokens, d]."""
+    from ..ops import kernels
+
+    return kernels.sorted_segment_sum(
+        _take_rows(rows, inverse), segment, tokens,
+        interpret=kernels.common.INTERPRET)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _share_dispatch(x, token, inverse, segment, tokens):
+    """Token rows into the expert-ordered buffer: ``rows[r] =
+    x[token[r]]``."""
+    return _take_rows(x, token)
+
+
+def _share_dispatch_fwd(x, token, inverse, segment, tokens):
+    return _take_rows(x, token), (inverse, segment)
+
+
+def _share_dispatch_bwd(tokens, res, d_rows):
+    return (_sum_rows(d_rows, *res, tokens), None, None, None)
+
+
+_share_dispatch.defvjp(_share_dispatch_fwd, _share_dispatch_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _share_combine(out_rows, token, inverse, segment, tokens):
+    """The buffer's rows back to their tokens: ``y[t]`` the sum of the
+    rows whose token is t."""
+    return _sum_rows(out_rows, inverse, segment, tokens)
+
+
+def _share_combine_fwd(out_rows, token, inverse, segment, tokens):
+    return _sum_rows(out_rows, inverse, segment, tokens), token
+
+
+def _share_combine_bwd(tokens, token, dy):
+    return _take_rows(dy, token), None, None, None
+
+
+_share_combine.defvjp(_share_combine_fwd, _share_combine_bwd)
+
+
+@jax.jit
+def _pick_weights(weights, pairs):
+    """``weights`` [tokens, top_k] at ``pairs`` [bound], 0 where a pair is
+    one past the last (a free row)."""
+    flat = weights.reshape(-1)
+    last = flat.shape[0] - 1
+    return jnp.where(pairs <= last,
+                     _take_rows(flat, jnp.minimum(pairs, last)), 0)
+
+
+@functools.partial(jax.jit, static_argnames=("tokens", "top_k"))
+def _weights_table(d_scale, inverse, segment, slot, *, tokens, top_k):
+    """[bound, top_k] in token order: a row's weight cotangent at the k of
+    its pair, zeros beside it and in a free row."""
+    placed = jnp.where(segment < tokens, _take_rows(d_scale, inverse), 0)
+    return jnp.where(
+        slot[:, None] == jax.lax.iota(jnp.int32, top_k)[None, :],
+        placed[:, None], 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _share_weights(weights, pairs, inverse, segment, slot, shape):
+    """The routing weight [bound] of every row of the expert-ordered
+    buffer, 0 for a free row. Its transpose places a row's cotangent at
+    its pair of [tokens, top_k] as the moves place rows: the [bound,
+    top_k] table that holds it at the pair's k, summed by token (each
+    sum has one addend at most), where the gather's own transpose is a
+    scatter-add. ``shape`` is ``weights.shape``."""
+    return _pick_weights(weights, pairs)
+
+
+def _share_weights_fwd(weights, pairs, inverse, segment, slot, shape):
+    return _pick_weights(weights, pairs), (inverse, segment, slot)
+
+
+def _share_weights_bwd(shape, res, d_scale):
+    from ..ops import kernels
+
+    inverse, segment, slot = res
+    table = _weights_table(d_scale, inverse, segment, slot,
+                           tokens=shape[0], top_k=shape[1])
+    return (kernels.sorted_segment_sum(
+        table, segment, shape[0], interpret=kernels.common.INTERPRET),
+        None, None, None, None)
+
+
+_share_weights.defvjp(_share_weights_fwd, _share_weights_bwd)
+
+
 def _topk_moe_share(params, x, weights, experts, offset, bound, activation):
     """``topk_moe`` where the layer holds experts ``offset`` ..
     ``offset + H - 1`` of the E it routes over: their part of the
-    result, over a buffer of ``bound`` rows."""
-    tokens, d_model = x.shape
+    result, over a buffer of ``bound`` rows: the first ``bound`` pairs
+    routed here, in token order. The rows move by gathers over the buffer
+    and a token's rows are summed as a sorted segment sum
+    (``_share_dispatch``, ``_share_combine``: each the other's
+    transpose); the routing weight is applied in float32 where the
+    experts' activation is rounded (``_experts``), so what is summed is
+    the rows themselves, in float32, rounded once."""
+    tokens = x.shape[0]
     top_k = experts.shape[1]
     num_experts = params["gate_w"].shape[1]
     held = params["w_down"].shape[0]
@@ -377,40 +600,17 @@ def _topk_moe_share(params, x, weights, experts, offset, bound, activation):
             % (offset, offset + held - 1, num_experts))
 
     with jax.named_scope("dispatch"):
-        flat_expert = experts.reshape(-1)                     # [T*k]
         counts = jnp.sum(jax.nn.one_hot(
-            flat_expert, num_experts, dtype=jnp.int32), axis=0)
-        local = flat_expert - offset
-        here = (local >= 0) & (local < held)
-        # the first ``bound`` pairs routed here, in token order: row r
-        # of the buffer takes the first pair whose running count of
-        # held pairs reaches r + 1 (a fused compare-and-count; what
-        # ``jnp.nonzero(size=)`` does by a scatter-add over every
-        # pair); one past the last pair marks a free row
-        running = jnp.cumsum(here.astype(jnp.int32))
-        pairs = jnp.searchsorted(
-            running, jnp.arange(1, bound + 1, dtype=jnp.int32),
-            side="left", method="compare_all")
-        group = jnp.take(local, pairs, mode="fill", fill_value=held)
-        order = jnp.argsort(group, stable=True)               # by expert
-        pairs, group = pairs[order], group[order]
-        used = group < held                                   # [bound]
-        sizes = jnp.sum(jax.nn.one_hot(group, held, dtype=jnp.int32),
-                        axis=0)
-        token = jnp.where(used, pairs // top_k, 0)
-        # a free row belongs to no group: the grouped products leave it
-        # unwritten, so it is zeroed on the way in and on the way out
-        # (and with it its gradients)
-        rows = jnp.where(used[:, None], jnp.take(x, token, axis=0), 0)
+            experts.reshape(-1), num_experts, dtype=jnp.int32), axis=0)
+        sizes, token, pairs, inverse, segment, slot = _share_plan(
+            experts, offset=offset, held=held, bound=bound)
+        rows = _share_dispatch(x, token, inverse, segment, tokens)
+        scale = _share_weights(weights, pairs, inverse, segment, slot,
+                               weights.shape)
 
     with jax.named_scope("experts"):
-        out_rows = _experts(params, rows, sizes, activation)  # [bound, d]
+        out_rows = _experts(params, rows, sizes, activation, scale)
 
     with jax.named_scope("combine"):
-        weight = jnp.where(used, jnp.take(
-            weights.reshape(-1), pairs, mode="fill", fill_value=0), 0)
-        weighted = jnp.where(
-            used[:, None],
-            out_rows.astype(jnp.float32) * weight[:, None], 0)
-        y = jax.ops.segment_sum(weighted, token, num_segments=tokens)
-    return y.astype(x.dtype), jax.lax.stop_gradient(counts)
+        y = _share_combine(out_rows, token, inverse, segment, tokens)
+    return y, jax.lax.stop_gradient(counts)

@@ -281,6 +281,76 @@ def test_expert_layer_moves_rows_by_gathers_on_v5e(one_chip):
     assert "s64[%d]" % (tokens * top_k) not in text
 
 
+# the four share cells' sorted segment sums (rows of the buffer, tokens,
+# width): a token's rows summed as the grouped matmul's wgrad over an
+# exact 0 / 1 table of 256 tokens a group, forward (``combine``) and
+# backward (``dispatch``'s transpose): Kanana, LFM2, Nemotron-3-Nano
+# (2,688 = 21 lane rows: three n tiles of 896), MiMo; and the float32
+# [buffer, top_k] tables that place the routing weights' cotangents
+# (three bf16-exact pieces side by side, padded to a lane row)
+SEGMENT_SHAPES = [
+    (12288, 8192, 2048, jnp.bfloat16, (128, 256, 2048)),
+    (8192, 8192, 2048, jnp.bfloat16, (128, 256, 2048)),
+    (12288, 8192, 2688, jnp.bfloat16, (128, 256, 896)),
+    (2048, 4096, 4096, jnp.bfloat16, (128, 256, 2048)),
+    (12288, 8192, 6, jnp.float32, (128, 256, 128)),
+    (8192, 8192, 4, jnp.float32, (128, 256, 128)),
+    (2048, 4096, 8, jnp.float32, (128, 256, 128)),
+]
+
+
+@pytest.mark.parametrize("m,tokens,n,dtype,tiles", SEGMENT_SHAPES)
+def test_sorted_segment_sum_compiles_for_v5e(one_chip, m, tokens, n, dtype,
+                                             tiles):
+    rows = jax.ShapeDtypeStruct((m, n), dtype, sharding=one_chip)
+    segment = jax.ShapeDtypeStruct((m,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda r, s: pk.sorted_segment_sum(r, s, tokens)).lower(
+        rows, segment).compile().as_text()
+    operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    assert "gmm_wgrad_%s_m%d_k%d_n%d" % ((operands,) + tiles) in text
+    assert pk.gmm.gmm_vmem_bytes(
+        *tiles, jnp.dtype(dtype).itemsize,
+        wgrad=True) <= pk.common.VMEM_SCOPED_DEFAULT
+    assert " scatter(" not in text
+
+
+def test_a_share_layer_moves_rows_without_a_scatter_on_v5e(one_chip):
+    """One share layer at the Kanana cell's shape (8,192 tokens of 2,048,
+    top-6 of 128 sigmoid-routed experts, 16 held of width 768, a buffer
+    of 12,288 rows, bf16), forward and backward, as the TPU's compiler
+    leaves it: no scatter at all (the tree's formulation had two of
+    [8192, 2048] and one of [49152]), the segment product twice, the
+    weights' table once, four gathers of the buffer's rows, int32 index
+    vectors."""
+    from mxnet_tpu.parallel.moe import topk_moe
+
+    tokens, d, experts, held, hidden, top_k, bound = (
+        8192, 2048, 128, 16, 768, 6, 12288)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    w = {"gate_w": spec(d, experts),
+         "w_gate_up": spec(held, d, 2 * hidden),
+         "w_down": spec(held, hidden, d)}
+
+    def loss(w, x):
+        y, _ = topk_moe(w, x, top_k, norm_topk_prob=True, scoring="sigmoid",
+                        share_rows_bound=bound, routed_scale=2.448)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        w, spec(tokens, d)).compile().as_text()
+    assert " scatter(" not in text
+    calls = re.findall(r"= \S+ custom-call\(.*?op_name=\"[^\"]*?/"
+                       r"(gmm_wgrad_\w+)/pallas_call", text)
+    assert calls.count("gmm_wgrad_bf16_m128_k256_n2048") == 2
+    assert calls.count("gmm_wgrad_f32_m128_k256_n128") == 1
+    gathered = re.findall(r"= (\S+?)\{[^ ]*\} gather\(", text)
+    assert gathered.count("bf16[%d,%d]" % (bound, d)) == 4
+    assert "s64[%d]" % bound not in text
+
+
 # the Nemotron-3-Nano cell's scan (one sequence of 8,192 tokens, 64 heads
 # of 64 on 8 groups, state 128, chunks of 128, bf16); a float32 caller
 # whose heads are whole lane rows, in chunks of 256 over a ragged length

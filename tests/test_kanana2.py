@@ -202,7 +202,7 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
         assert telemetry.total("attention.latent_lowerings") == 3
         share = telemetry.REGISTRY.get("moe.share_lowerings")
         assert share.value(held=4, of=16, bound=BATCH * T * 3,
-                           scale=2.448) == 2
+                           sum="segment_product", scale=2.448) == 2
         mod.forward(mx.io.DataBatch(data=[mx.nd.array(tokens)],
                                     label=[mx.nd.array(labels)]),
                     is_train=False)

@@ -233,7 +233,7 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
         assert sconv.value(channels=48, taps=3, impl="jnp") == CONV_LAYERS
         share = telemetry.REGISTRY.get("moe.share_lowerings")
         assert share.value(held=4, of=16, bound=BATCH * T * 3,
-                           renorm_eps=1e-6) == EXPERT_LAYERS
+                           sum="segment_product", renorm_eps=1e-6) == EXPERT_LAYERS
         mod.forward(batch, is_train=False)
         assert telemetry.total("sconv.lowerings") == CONV_LAYERS
     finally:

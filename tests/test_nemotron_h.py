@@ -229,7 +229,7 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
                           impl="einsum") == MAMBA_LAYERS
         share = telemetry.REGISTRY.get("moe.share_lowerings")
         assert share.value(held=4, of=16, bound=BATCH * T * 3, scale=2.5,
-                           act="relu2") == EXPERT_LAYERS
+                           sum="segment_product", act="relu2") == EXPERT_LAYERS
         mod.forward(batch, is_train=False)
         assert telemetry.total("ssm.scan_lowerings") == MAMBA_LAYERS
     finally:

@@ -14,6 +14,8 @@ because they need explicit on-chip (VMEM) accumulation patterns.
                segment sum that is its wgrad at another shape
   ssd          the chunked state-space scan (Mamba-2)
   gdn          the gated delta rule's chunk core
+  taps         the causal taps: the short depthwise convolution over time
+               of Mamba2, GatedDeltaNet and ShortConv, with its epilogue
   slab_update  the AMP optimizer step over a flat slab
   conv         the conv-backward pair
   common       what they share
@@ -46,13 +48,15 @@ from .latent import (
 from .slab_update import (
     SLAB_STATE_SLOTS, fused_slab_update, slab_update_reference)
 from .ssd import ssd_scan, ssd_takes
+from .taps import causal_conv, taps_takes
 
 __all__ = [
-    "attention", "common", "conv_bwd_filter", "conv_bwd_input",
+    "attention", "causal_conv", "common", "conv_bwd_filter", "conv_bwd_input",
     "conv_bwd_plan", "conv_kernel_enabled", "flash_attention",
     "flash_tiles", "fused_slab_update", "gated_delta_rule", "gdn_takes",
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
     "grouped_matmul", "latent_flash", "latent_flash_takes", "latent_query",
     "latent_query_takes", "reference_attention", "SLAB_STATE_SLOTS",
     "slab_update_reference", "sorted_segment_sum", "ssd_scan", "ssd_takes",
+    "taps_takes",
 ]

@@ -405,8 +405,12 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     [H P] -> [B, T, H P] (``out_proj``'s input).
 
     ``x | B | C = silu(conv(.))``, a causal depthwise convolution over
-    time as ``taps`` shifted multiply-adds (scope ``conv1d``: ``conv`` is
-    the class of the ``Convolution`` nodes in a trace); ``dt =
+    time (scope ``conv1d``: ``conv`` is the class of the ``Convolution``
+    nodes in a trace): one Pallas kernel each way over that window of
+    ``proj``'s columns where the taps' family has tiles for the shapes
+    and the step is lowered for the TPU (``kernels.taps_takes`` /
+    ``causal_conv``), ``causal_taps``' shifted multiply-adds elsewhere;
+    ``dt =
     softplus(dt + dt_bias)``, ``a = -exp(a_log)``, ``y = ssd_scan(...) +
     d_skip x`` (scope ``scan``); ``RMSNorm(y * silu(z))`` with the
     statistics over each of the G groups of columns, times ``norm_gamma``
@@ -420,10 +424,12 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     gate and norm and of the einsum form are computed again in the
     backward pass, not kept (``jax.checkpoint`` round each; plain
     autodiff kept 9.6 GB of them at the Nemotron cell's shape); the
-    kernel pair keeps its own residuals (its output and the states) and
-    runs once each way.
+    scan's kernel pair keeps its own residuals (its output and the
+    states) and the taps' its inputs (the backward kernel computes the
+    sum again in VMEM), and each runs once each way.
 
-    The call site counts itself here (``ssm.scan_lowerings``); the block
+    The call site counts itself here (``ssm.scan_lowerings``, and
+    ``causal_taps.lowerings`` for the convolution); the block
     itself is ``_mamba2_block``, one ``jax.jit`` for every node of one
     signature: a model's layers trace, differentiate and lower it once
     (XLA inlines the calls, each under its own node's scope)."""
@@ -431,6 +437,9 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
 
     kernel = bool(kernels.ssd_takes(
         num_heads, head_dim, state_size, num_groups, chunk_size, proj.dtype))
+    d_in = num_heads * head_dim
+    taps_kernel = _taps_site("mamba2", proj, conv_weight, "bias_silu",
+                             offset=d_in)
     _M_SCAN_LOWERINGS.inc(heads=num_heads, head_dim=head_dim,
                           state=state_size, groups=num_groups,
                           chunk=chunk_size, conv=conv_weight.shape[0],
@@ -439,13 +448,15 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
         proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
         sizes=(num_heads, head_dim, state_size, num_groups, chunk_size),
         eps=float(eps), remat=bool(remat), kernel=kernel,
-        interpret=kernels.common.INTERPRET)
+        taps_kernel=taps_kernel, interpret=kernels.common.INTERPRET)
 
 
 @functools.partial(jax.jit, static_argnames=("sizes", "eps", "remat",
-                                             "kernel", "interpret"))
+                                             "kernel", "taps_kernel",
+                                             "interpret"))
 def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
-                  norm_gamma, *, sizes, eps, remat, kernel, interpret):
+                  norm_gamma, *, sizes, eps, remat, kernel, taps_kernel,
+                  interpret):
     """``mamba2`` for one signature (``sizes``: heads, head width, state,
     groups, chunk)."""
     from . import kernels
@@ -473,7 +484,12 @@ def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
         return norm_gamma.astype(proj.dtype) * normed.astype(proj.dtype)
 
     with jax.named_scope("conv1d"):
-        xbc = again(conv1d)(proj, conv_weight, conv_bias)
+        if taps_kernel:
+            xbc = kernels.causal_conv(
+                proj, conv_weight, conv_bias, form="bias_silu", offset=d_in,
+                channels=conv_dim, interpret=interpret)
+        else:
+            xbc = again(conv1d)(proj, conv_weight, conv_bias)
     with jax.named_scope("scan"):
         x = xbc[..., :d_in].reshape(b, t, h, p)
         bc = (xbc[..., d_in:d_in + g * n].reshape(b, t, g, n),
@@ -744,7 +760,11 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     [B, T, H V] (``o_proj``'s input).
 
     ``q, k, v = silu(conv(.))``, a causal depthwise convolution over time
-    without bias (scope ``conv1d``); ``q = q / |q| / sqrt(K)`` and ``k =
+    without bias (scope ``conv1d``; each of the three arrays the taps'
+    Pallas kernel pair where ``kernels.taps_takes`` has tiles for it and
+    the step is lowered for the TPU, a width that is no multiple of 128
+    lanes taken whole, ``causal_taps`` elsewhere); ``q = q / |q| /
+    sqrt(K)`` and ``k =
     k / |k|`` a head (``|x|`` = sqrt(sum x^2 + 1e-6)), ``beta = 2
     sigmoid(b)`` (``allow_neg_eigval``; without it the 2 goes), ``g =
     -exp(a_log) softplus(a + dt_bias)``, ``o = gated_delta_rule(...)``
@@ -761,9 +781,11 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     (``jax.checkpoint``); of ``delta_rule`` on the kernel path that is
     the unit norms, ``beta`` and ``g`` only: the kernel pair keeps its
     own residuals (the state each chunk entered with and its system's
-    inverse) and runs once each way.
+    inverse) and runs once each way, as the taps' pair does on its
+    inputs.
 
-    The call site counts itself here (``linear_attn.lowerings``); the
+    The call site counts itself here (``linear_attn.lowerings``, and
+    ``causal_taps.lowerings`` once a convolved array); the
     block itself is ``_gated_delta_block``, one ``jax.jit`` for every node
     of one signature."""
     from . import kernels
@@ -775,20 +797,24 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
         heads=num_heads, key_dim=key_dim, value_dim=value_dim,
         chunk=chunk_size, conv=conv_weight.shape[0],
         impl="kernel" if kernel else "chunked")
+    taps_kernel = tuple(
+        _taps_site("gated_delta_net", x, conv_weight, "silu",
+                   channels=x.shape[2]) for x in (query, key, value))
     return _gated_delta_block(
         query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
         norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
         eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
-        remat=bool(remat), kernel=kernel,
+        remat=bool(remat), kernel=kernel, taps_kernel=taps_kernel,
         interpret=kernels.common.INTERPRET)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "chunk", "eps",
                                              "beta_scale", "remat",
-                                             "kernel", "interpret"))
+                                             "kernel", "taps_kernel",
+                                             "interpret"))
 def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                        dt_bias, norm_gamma, *, heads, chunk, eps,
-                       beta_scale, remat, kernel, interpret):
+                       beta_scale, remat, kernel, taps_kernel, interpret):
     """``gated_delta_net`` for one signature."""
     from . import kernels
 
@@ -799,8 +825,12 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
     def again(f):
         return jax.checkpoint(f) if remat else f
 
-    def conv1d(x, w):
-        return jax.nn.silu(causal_taps(x, w)).astype(x.dtype)
+    def conv1d(x, w, takes):
+        if takes:
+            return kernels.causal_conv(x, w, form="silu",
+                                       interpret=interpret)
+        return again(lambda x, w: jax.nn.silu(
+            causal_taps(x, w)).astype(x.dtype))(x, w)
 
     def unit(x):  # each head's vector over its length, float32
         x = x.astype(f32).reshape(bsz, t, heads, -1)
@@ -828,9 +858,9 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
 
     with jax.named_scope("conv1d"):
         edges = (0, heads * dk, 2 * heads * dk, 2 * heads * dk + heads * dv)
-        q, k, v = (again(conv1d)(x, conv_weight[:, lo:hi])
-                   for x, lo, hi in zip((query, key, value), edges,
-                                        edges[1:]))
+        q, k, v = (conv1d(x, conv_weight[:, lo:hi], takes)
+                   for x, lo, hi, takes in zip((query, key, value), edges,
+                                               edges[1:], taps_kernel))
     with jax.named_scope("delta_rule"):
         if kernel:
             q, k, g, beta = again(unit_and_strengths)(q, k, a, b, a_log,
@@ -893,8 +923,9 @@ register(
 # --------------------------------------------------------------------------
 _M_SCONV_LOWERINGS = _tm.counter(
     "sconv.lowerings", "Traces of a ShortConv call site (one per lowering, "
-    "nothing per step); labels: channels, taps, impl (jnp: shifted "
-    "multiply-adds that XLA fuses; there is no kernel)")
+    "nothing per step); labels: channels, taps, impl (kernel: the Pallas "
+    "pair of ops/kernels/taps.py where the step is lowered for the TPU; "
+    "jnp: shifted multiply-adds that XLA fuses)")
 
 
 def causal_taps(x, weight, bias=None):
@@ -902,7 +933,11 @@ def causal_taps(x, weight, bias=None):
     x [B, T, C], weight [taps, C] (tap ``taps - 1`` meets the current
     token, ``x`` is zero before the sequence), bias [C] or None -> float32
     [B, T, C], ``bias + sum_j weight[j] * x[t - (taps - 1) + j]`` summed
-    in float32 in that order."""
+    in float32 in that order. The plain form: what ``mamba2``,
+    ``gated_delta_net`` and ``short_conv`` run on every platform but the
+    TPU and for the shapes ``kernels.taps_takes`` refuses, and the oracle
+    of ``kernels.causal_conv``, which sums the same terms in the same
+    order in VMEM."""
     taps, t = weight.shape[0], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0))).astype(jnp.float32)
     w = weight.astype(jnp.float32)
@@ -913,28 +948,45 @@ def causal_taps(x, weight, bias=None):
     return acc
 
 
+def gated_taps(proj, conv_weight):
+    """``C * causal_taps(B * x)`` of proj [B, T, 3 H] = ``B | C | x``,
+    conv_weight [taps, H] -> [B, T, H] in proj's dtype, the gates and the
+    sum float32: ``short_conv`` in ``jax.numpy``."""
+    h = conv_weight.shape[1]
+    f32 = jnp.float32
+    with jax.named_scope("gate_in"):
+        z = proj[..., :h].astype(f32) * proj[..., 2 * h:].astype(f32)
+    with jax.named_scope("conv1d"):
+        c = causal_taps(z, conv_weight)
+    with jax.named_scope("gate_out"):
+        return (proj[..., h:2 * h].astype(f32) * c).astype(proj.dtype)
+
+
 def short_conv(proj, conv_weight, remat=False):
     """proj [B, T, 3 H] (``in_proj``'s output, ``B | C | x`` in that
     order), conv_weight [taps, H] -> [B, T, H] (``out_proj``'s input):
     ``C * causal_taps(B * x)``, no bias and no activation anywhere. The
-    two gates and the taps' sum are float32 whatever ``proj``'s dtype
-    (scopes ``gate_in``, ``conv1d``, ``gate_out``), the result ``proj``'s.
-    ``remat`` (training): one ``jax.checkpoint`` round the three, so the
-    backward pass keeps the op's two inputs and computes the float32
-    tables again."""
-    h = conv_weight.shape[1]
-    _M_SCONV_LOWERINGS.inc(channels=h, taps=conv_weight.shape[0], impl="jnp")
+    two gates and the taps' sum are float32 whatever ``proj``'s dtype,
+    the result ``proj``'s. One Pallas kernel each way where the family
+    has tiles for the shapes and the step is lowered for the TPU
+    (``kernels.taps_takes`` / ``causal_conv``, scope ``conv1d``: the
+    three thirds read where ``proj`` holds them, all of ``dproj`` written
+    by the backward), ``gated_taps`` elsewhere (scopes ``gate_in``,
+    ``conv1d``, ``gate_out``). ``remat`` (training): the backward pass
+    keeps the op's two inputs and computes the float32 tables again, the
+    kernel in VMEM, ``gated_taps`` under one ``jax.checkpoint``."""
+    from . import kernels
 
-    def core(proj, conv_weight):
-        f32 = jnp.float32
-        with jax.named_scope("gate_in"):
-            z = proj[..., :h].astype(f32) * proj[..., 2 * h:].astype(f32)
+    kernel = _taps_site("short_conv", proj, conv_weight, "gates")
+    _M_SCONV_LOWERINGS.inc(channels=conv_weight.shape[1],
+                           taps=conv_weight.shape[0],
+                           impl="kernel" if kernel else "jnp")
+    if kernel:
         with jax.named_scope("conv1d"):
-            c = causal_taps(z, conv_weight)
-        with jax.named_scope("gate_out"):
-            return (proj[..., h:2 * h].astype(f32) * c).astype(proj.dtype)
-
-    return (jax.checkpoint(core) if remat else core)(proj, conv_weight)
+            return kernels.causal_conv(proj, conv_weight, form="gates",
+                                       interpret=kernels.common.INTERPRET)
+    return (jax.checkpoint(gated_taps) if remat else gated_taps)(
+        proj, conv_weight)
 
 
 def _short_conv(attrs, ins, is_train):
@@ -1086,3 +1138,32 @@ def _kernel_query_bwd(num_heads, rope_dim, theta, interleave, interpret, _,
 
 
 _kernel_query.defvjp(_kernel_query_fwd, _kernel_query_bwd)
+
+
+# --------------------------------------------------------------------------
+# The causal taps' call sites (``mamba2``, ``gated_delta_net``,
+# ``short_conv``): which form runs, counted
+# --------------------------------------------------------------------------
+_M_TAPS_LOWERINGS = _tm.counter(
+    "causal_taps.lowerings", "Traces of a causal depthwise convolution's "
+    "call site (one per convolved array, node and lowering, nothing per "
+    "step); labels: site (mamba2 / gated_delta_net / short_conv), "
+    "channels, taps, impl (kernel: the Pallas pair of ops/kernels/taps.py "
+    "where the step is lowered for the TPU, the jax.numpy form of the "
+    "same signature elsewhere; jnp: causal_taps' shifted multiply-adds "
+    "everywhere)")
+
+
+def _taps_site(site, src, conv_weight, form, offset=0, channels=None):
+    """Whether ``kernels.causal_conv`` takes ``channels`` columns of src
+    from ``offset`` (``conv_weight``'s by default); the call site counts
+    itself here, outside its block's ``jax.jit``."""
+    from . import kernels
+
+    taps = conv_weight.shape[0]
+    channels = conv_weight.shape[1] if channels is None else channels
+    kernel = kernels.taps_takes(channels, src.shape[1], taps, src.dtype,
+                                form, offset, src.shape[2])
+    _M_TAPS_LOWERINGS.inc(site=site, channels=channels, taps=taps,
+                          impl="kernel" if kernel else "jnp")
+    return kernel

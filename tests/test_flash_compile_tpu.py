@@ -437,3 +437,63 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, t, heads, dk, dv,
                         "used_scoped_memory_configs"))
         assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (
             name, used, limit)
+
+
+# the three cells' convolved arrays: Nemotron's Mamba-2 window (columns
+# 4,096 to 10,240 of an in_proj output 10,304 wide: 80.5 lane rows),
+# Olmo-Hybrid's query / key (2,880 columns, 22.5 lane rows, taken whole)
+# and value, LFM2's B | C | x; a float32 caller with three taps
+TAPS_SHAPES = {
+    "nemotron_mamba2": ("bias_silu", 4, (1, 8192, 10304), 4096, 6144,
+                        jnp.bfloat16),
+    "olmo_hybrid_query_key": ("silu", 4, (1, 4096, 2880), 0, 2880,
+                              jnp.bfloat16),
+    "olmo_hybrid_value": ("silu", 4, (1, 4096, 5760), 0, 5760, jnp.bfloat16),
+    "lfm2_short_conv": ("gates", 3, (1, 8192, 6144), 0, 2048, jnp.bfloat16),
+    "float32_taps3": ("silu", 3, (2, 512, 640), 0, 640, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("site", sorted(TAPS_SHAPES))
+def test_causal_taps_kernels_compile_for_v5e(one_chip, site):
+    """The taps' forward and backward kernels at real widths: Mosaic takes
+    the sublane rolls, the ragged last lane step of a width taken whole
+    and the three thirds of one block, the window's offset is an index
+    map's and no slice or copy of the operand, and a step's blocks fit
+    the scoped VMEM the calls state."""
+    form, taps, shape, offset, channels, dtype = TAPS_SHAPES[site]
+    assert pk.taps_takes(channels, shape[1], taps, dtype, form, offset,
+                         shape[2])
+
+    def spec(*dims, dtype=dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(src, w, *bias):
+        # the operand an elementwise neighbour's output, as a projection's
+        # is in a step (an entry parameter's default layout is not the
+        # kernel's where the width is no multiple of 128)
+        out = pk.causal_conv(src * 2, w, *bias, form=form, offset=offset,
+                             channels=channels)
+        return jnp.sum(out.astype(jnp.float32))
+
+    ins = [spec(*shape), spec(taps, channels)] + (
+        [spec(channels)] if form == "bias_silu" else [])
+    # the value too: the backward keeps the op's inputs alone, so a program
+    # of gradients only holds no forward kernel at all
+    text = jax.jit(jax.value_and_grad(
+        loss, argnums=tuple(range(len(ins))))).lower(*ins).compile().as_text()
+    operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    for which in ("fwd", "bwd"):
+        calls = [line for line in text.splitlines()
+                 if "taps_%s_%s_" % (which, operands) in line
+                 and "custom-call(" in line]
+        assert len(calls) == 1, (which, len(calls))
+        assert "_k%d_%s" % (taps, form) in calls[0]
+        # Mosaic refuses a body over the limit its call states; what the
+        # line reports as used counts the arrays XLA itself keeps in VMEM
+        limit = int(re.search(
+            r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+            r'"size":"(\d+)"', calls[0]).group(1))
+        assert limit <= pk.common.VMEM_RAISED_LIMIT, (which, limit)
+        operand = re.search(r"custom-call\(%([\w.\-]+)", calls[0]).group(1)
+        assert not operand.startswith(("copy.", "slice")), (which, operand)

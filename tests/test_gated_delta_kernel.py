@@ -318,7 +318,8 @@ def test_off_the_tpu_the_ops_values_are_the_chunk_forms_bit_for_bit(remat):
     autodiffs fuse the decay's gradient differently, to an ulp)."""
     *ins, cot = _op_inputs(1)
     tr._gated_delta_block.clear_cache()
-    kw = dict(heads=H, chunk=CHUNK, eps=1e-6, beta_scale=2.0, remat=remat)
+    kw = dict(heads=H, chunk=CHUNK, eps=1e-6, beta_scale=2.0, remat=remat,
+              taps_kernel=(False,) * 3)
 
     def grads(kernel):
         def loss(*a):

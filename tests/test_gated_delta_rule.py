@@ -279,6 +279,55 @@ def test_the_op_infers_its_parameters_and_checks_its_inputs():
     assert mx.executor.op_class("_contrib_GatedDeltaNet") == "gdn"
 
 
+def test_the_taps_kernel_leaves_the_op_its_outputs_and_gradients(
+        monkeypatch):
+    """``GatedDeltaNet`` in training at a shape the taps' kernel family
+    takes (T 256; query and key 128 wide, value 256), the three
+    convolutions as the kernel pair through the Pallas interpreter (the
+    delta rule's own pair with them), against the op with ``causal_taps``
+    under its checkpoints (``taps_takes`` made to refuse): the output to
+    the last bit, every gradient to summation order."""
+    from mxnet_tpu.ops import kernels as pk
+    from mxnet_tpu.ops import transformer as tr
+
+    heads, t = 2, 256
+    rng = np.random.RandomState(9)
+    a_log, dt_bias = _dynamics(rng, heads)
+
+    def draw(*shape, scale=1.0, shift=0.0):
+        return jnp.asarray(shift + scale * rng.randn(*shape), jnp.float32)
+
+    ins = (draw(1, t, heads * 64), draw(1, t, heads * 64),
+           draw(1, t, heads * 128), draw(1, t, heads * 128),
+           draw(1, t, heads, scale=2.0), draw(1, t, heads),
+           draw(TAPS, 2 * heads * 64 + heads * 128, scale=0.3),
+           jnp.asarray(a_log), jnp.asarray(dt_bias),
+           draw(128, scale=0.1, shift=1.0))
+    cot = draw(1, t, heads * 128)
+    every = tuple(range(len(ins)))
+
+    def op(*a):
+        return gated_delta_net(*a, num_heads=heads, chunk_size=64, eps=1e-6,
+                               remat=True)
+
+    def run():
+        tr._gated_delta_block.clear_cache()
+        return op(*ins), jax.grad(lambda *a: jnp.sum(op(*a) * cot),
+                                  every)(*ins)
+
+    monkeypatch.setattr(pk.common, "INTERPRET", True)
+    assert all(pk.taps_takes(x.shape[2], t, TAPS, x.dtype, "silu", 0,
+                             x.shape[2]) for x in ins[:3])
+    out, grads = run()
+    monkeypatch.setattr(pk, "taps_takes", lambda *a, **k: False)
+    was, were = run()
+    tr._gated_delta_block.clear_cache()
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(was))
+    for name, g, w in zip(NAMES, grads, were):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        _close(g, w, name, ulps=64)
+
+
 def test_a_call_site_counts_its_lowering():
     """``linear_attn.lowerings``: one a trace of a call site, labelled
     with what decides the lowering; nothing a step."""

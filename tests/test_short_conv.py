@@ -158,6 +158,47 @@ def test_in_bf16_the_gates_and_the_sum_stay_float32():
         assert err < 0.0025 < below, (seed, err, below)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_taps_kernel_leaves_the_op_its_outputs_and_gradients(
+        dtype, monkeypatch):
+    """``ShortConv`` in training at a shape the kernel family takes (128
+    channels, three time tiles), the pair through the Pallas interpreter,
+    against ``gated_taps`` under its checkpoint (the op as it was): the
+    output to the last bit, ``dproj`` (all three thirds, written by the
+    backward kernel itself) and the taps' gradient to summation order
+    (bf16: one bf16 ulp of the largest)."""
+    from mxnet_tpu.ops import kernels as pk
+    from mxnet_tpu.ops.transformer import gated_taps
+
+    rng = np.random.RandomState(7)
+    proj = jnp.asarray(rng.randn(B, 384, 3 * 128), dtype)
+    w = jnp.asarray(rng.uniform(-1, 1, (TAPS, 128)) * TAPS ** -0.5, dtype)
+    cot = jnp.asarray(rng.randn(B, 384, 128), jnp.float32)
+    assert pk.taps_takes(128, 384, TAPS, dtype, "gates", 0, 3 * 128)
+    monkeypatch.setattr(pk.common, "INTERPRET", True)
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a).astype(jnp.float32) * cot)
+
+    op = lambda *a: short_conv(*a, remat=True)
+    was = jax.checkpoint(gated_taps)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(op)(proj, w), np.float32),
+        np.asarray(jax.jit(was)(proj, w), np.float32))
+    got = jax.jit(jax.grad(loss(op), (0, 1)))(proj, w)
+    want = jax.jit(jax.grad(loss(was), (0, 1)))(proj, w)
+    for name, g, e in zip(NAMES, got, want):
+        assert g.dtype == e.dtype and g.shape == e.shape, name
+        if dtype == jnp.bfloat16:
+            scale = float(jnp.abs(e.astype(jnp.float32)).max())
+            np.testing.assert_allclose(
+                np.asarray(g, np.float64), np.asarray(e, np.float64),
+                rtol=2.0 ** -7, atol=2.0 ** -8 * scale, err_msg=name)
+        else:
+            _close(g, e, "d " + name, ulps=64)
+
+
 def test_the_scopes_and_the_counter():
     """Through the symbol and the executor: the node's ops are traced
     under ``sconv/<node>`` and, inside it, ``gate_in``, ``conv1d`` and

@@ -251,6 +251,44 @@ def test_mamba2_in_training_has_the_recurrences_gradients(scan_path):
         _close(g, w, name, ulps=64)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_taps_kernel_leaves_mamba2_its_outputs_and_gradients(
+        dtype, monkeypatch):
+    """``Mamba2`` in training with the convolution as the taps kernel pair
+    (through the interpreter, the scan's pair with it) against the op as
+    it was, the convolution ``causal_taps`` under its checkpoint
+    (``taps_takes`` made to refuse): the output to the last bit, every
+    gradient to summation order (bf16: one bf16 ulp of the largest)."""
+    ins = _op_inputs(4, 256, dtype)
+    every = tuple(range(7))
+
+    def run():
+        tr._mamba2_block.clear_cache()
+        out = _op(*ins, remat=True)
+        return out, jax.grad(lambda *a: jnp.sum(
+            _op(*a, remat=True).astype(jnp.float32) ** 2), every)(*ins)
+
+    monkeypatch.setattr(pk.common, "INTERPRET", True)
+    assert pk.taps_takes(HEADS * P + 2 * GROUPS * N, 256, TAPS, dtype,
+                         "bias_silu", HEADS * P, ins[0].shape[2])
+    out, grads = run()
+    monkeypatch.setattr(pk, "taps_takes", lambda *a, **k: False)
+    was, were = run()
+    tr._mamba2_block.clear_cache()
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(was, np.float32))
+    for name, g, w in zip(OP_GRADS, grads, were):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        if dtype == jnp.bfloat16:
+            scale = float(jnp.abs(w.astype(jnp.float32)).max())
+            np.testing.assert_allclose(
+                np.asarray(g, np.float64), np.asarray(w, np.float64),
+                rtol=2.0 ** -6, atol=2.0 ** -7 * scale, err_msg=name)
+        else:
+            _close(g, w, name, ulps=64)
+
+
 def _pallas_calls(jaxpr):
     """Every ``pallas_call`` equation of a jaxpr, nested ones included."""
     for eqn in jaxpr.eqns:
@@ -262,11 +300,12 @@ def _pallas_calls(jaxpr):
 
 def test_a_training_step_holds_each_kernel_once_and_never_interpreted():
     """The gradient's program of the ``Mamba2`` op: ONE forward and one
-    backward kernel (the kernel pair keeps its own residuals: no second
-    forward under a checkpoint), both for Mosaic: the branch for every
-    other platform is the einsum form, so a step lowered for the TPU
-    traces no interpreter copy of the bodies, and one lowered for the
-    CPU holds no kernel at all and runs."""
+    backward kernel of the scan and of the causal taps (each pair keeps
+    its own residuals: no second forward under a checkpoint), all for
+    Mosaic: the branch for every other platform is the einsum form and
+    ``causal_taps``, so a step lowered for the TPU traces no interpreter
+    copy of the bodies, and one lowered for the CPU holds no kernel at
+    all and runs."""
     tr._mamba2_block.clear_cache()
     ins = _op_inputs(2, 256, jnp.float32)
     attrs = dict(num_heads=HEADS, head_dim=P, state_size=N,
@@ -279,7 +318,9 @@ def test_a_training_step_holds_each_kernel_once_and_never_interpreted():
     calls = list(_pallas_calls(grad.trace(*ins).jaxpr.jaxpr))
     names = sorted(str(c.params["name"]) for c in calls)
     assert names == ["ssd_bwd_f32_q128_p64_n128",
-                     "ssd_fwd_f32_q128_p64_n128"], names
+                     "ssd_fwd_f32_q128_p64_n128",
+                     "taps_bwd_f32_t256_c512_k4_bias_silu",
+                     "taps_fwd_f32_t256_c512_k4_bias_silu"], names
     assert not any(c.params["interpret"] for c in calls)
     lowered = grad.lower(*ins)
     text = lowered.as_text()

@@ -20,7 +20,14 @@ MXU work. Prints one JSON line a row and writes
 (PR 39; the kernels are PR 38's, which the ledger holds as refused
 for its set-up).
 
-    chiprun -- python3 benchmarks/ssd_scan.py
+Then the pair alone by shape (``SHAPES``: the Nemotron cell's and the
+Falcon-H1 cell's, 4,096 tokens, 16 heads of 128 in one group, state 256):
+device ms a call forward and backward from the trace, us a grid step, and
+the share of the bytes bound (``x``, ``B``, ``C``, ``dt`` in and ``y`` out
+once forward, three times that for forward + backward, over 819 GB/s).
+``--shapes-only`` prints that table alone.
+
+    chiprun -- python3 benchmarks/ssd_scan.py [--shapes-only]
 """
 import json
 import os
@@ -39,6 +46,10 @@ from mxnet_tpu.ops.transformer import ssd_scan  # noqa: E402
 
 B, T, H, P, G, N, CHUNK = 1, 8192, 64, 64, 8, 128, 128
 PEAK_TFLOPS = 197.0     # bf16, one v5e chip (Google Cloud documentation)
+PEAK_GBS = 819.0        # HBM, the same source
+# (t, heads, head_dim, groups, state) of the cells that run the pair
+SHAPES = {"nemotron3_nano_fit_share_8k": (8192, 64, 64, 8, 128),
+          "falcon_h1_fit_share_4k": (4096, 16, 128, 1, 256)}
 
 
 def _time(f, *args, reps=20):
@@ -82,21 +93,21 @@ def _kernel_device_ms(g, *args, reps=10):
     return dict(ms)
 
 
-def inputs(seed, dtype, t=T):
+def inputs(seed, dtype, t=T, h=H, p=P, g=G, n=N):
     """x, B, C as the op's convolution leaves them (unit scale, heads and
     groups side by side in the last dimension), step sizes and rates by
     the published rule."""
     rng = np.random.RandomState(seed)
-    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), H))
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), h))
     dt_bias = step + np.log(-np.expm1(-step))
     x, bm, cm = (jnp.asarray(rng.randn(B, t, width), dtype)
-                 for width in (H * P, G * N, G * N))
+                 for width in (h * p, g * n, g * n))
     dt = jax.nn.softplus(
-        jnp.asarray(rng.randn(B, t, H) + dt_bias, jnp.float32))
-    a = -jnp.asarray(rng.uniform(1, 16, H), jnp.float32)
-    skip = jnp.asarray(1 + 0.2 * rng.randn(H), jnp.float32)
+        jnp.asarray(rng.randn(B, t, h) + dt_bias, jnp.float32))
+    a = -jnp.asarray(rng.uniform(1, 16, h), jnp.float32)
+    skip = jnp.asarray(1 + 0.2 * rng.randn(h), jnp.float32)
     return ((x, bm, cm, dt, a, skip),
-            jnp.asarray(rng.randn(B, t, H * P), jnp.float32))
+            jnp.asarray(rng.randn(B, t, h * p), jnp.float32))
 
 
 def forms():
@@ -124,6 +135,31 @@ def forms():
     return {"einsum": both(einsum), "kernel": both(kernel)}
 
 
+def pair_by_shape(row):
+    """The kernel pair alone at each of ``SHAPES``, bf16."""
+    for cell, (t, h, p, g, n) in SHAPES.items():
+        args, cot = inputs(2, jnp.bfloat16, t, h, p, g, n)
+
+        def loss(cot, x, bm, cm, *rest, h=h, p=p, g=g, n=n, t=t):
+            y = pk.ssd_scan(x.reshape(B, t, h, p), bm.reshape(B, t, g, n),
+                            cm.reshape(B, t, g, n), *rest, CHUNK)
+            return jnp.sum(y.reshape(B, t, h * p) * cot)
+
+        grad = jax.jit(jax.value_and_grad(loss, argnums=(1, 2, 3, 4, 5, 6)))
+        ms = _kernel_device_ms(grad, cot, *args)
+        fwd = sum(v for k, v in ms.items() if k.startswith("ssd_fwd"))
+        bwd = sum(v for k, v in ms.items() if k.startswith("ssd_bwd"))
+        steps = B * g * (t // CHUNK)
+        bound_ms = 2.0 * t * (2 * h * p + 2 * g * n + h) / PEAK_GBS / 1e6
+        row(pair_alone=cell, t=t, heads=h, head_dim=p, groups=g, state=n,
+            grid_steps=steps, fwd_ms=fwd, bwd_ms=bwd,
+            fwd_us_step=1e3 * fwd / steps, bwd_us_step=1e3 * bwd / steps,
+            rest_ms=ms.get("everything else"), bytes_bound_fwd_ms=bound_ms,
+            share_of_bytes_bound_fwd=100 * bound_ms / fwd,
+            share_of_bytes_bound_fwd_bwd=100 * 3 * bound_ms / (fwd + bwd),
+            kernels=sorted(k for k in ms if k.startswith("ssd_")))
+
+
 def main():
     dev = jax.devices()[0]
     res = {"device": str(dev.device_kind), "platform": dev.platform,
@@ -134,6 +170,14 @@ def main():
         print(json.dumps(kw), flush=True)
         res["rows"].append(kw)
 
+    def save():
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/ssd_scan_table.json", "w") as f:
+            json.dump(res, f, indent=1)
+
+    if "--shapes-only" in sys.argv:
+        pair_by_shape(row)
+        return save()
     both = forms()
     # how far apart the two forms are, on the chip
     for dtype, t in ((jnp.bfloat16, T), (jnp.float32, 1024)):
@@ -182,9 +226,8 @@ def main():
             row(form=name, fwd_ms=fwd, fwd_bwd_ms=fwd_bwd, **extra)
     row(kernels_device_ms=_kernel_device_ms(both["kernel"][1], cot, *args),
         steps=steps)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/ssd_scan_table.json", "w") as f:
-        json.dump(res, f, indent=1)
+    pair_by_shape(row)
+    save()
 
 
 if __name__ == "__main__":

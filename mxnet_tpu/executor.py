@@ -126,7 +126,7 @@ _OP_CLASS = {
     "_contrib_LatentAttention": "attn", "_contrib_Mamba2": "ssm",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
-    "_contrib_ShortConv": "sconv",
+    "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",
 }
 
 

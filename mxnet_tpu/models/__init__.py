@@ -22,8 +22,11 @@ SwiGLU, blocks that norm a sub-layer's output), whole or as a pipeline
 stage, with ``olmo_hybrid_reference``. ``lfm2`` is LFM2-24B-A2B (gated
 short-convolution layers three to one beside grouped attention on heads
 of 64, a dense SwiGLU then 64 sigmoid-routed experts, a head tied to the
-embedding), whole or as a share, with ``lfm2_reference``; ``lm_blocks``
-holds what the LM symbols share.
+embedding), whole or as a share, with ``lfm2_reference``. ``falcon_h1``
+is Falcon-H1-34B (Mamba-2 and grouped attention side by side in every
+block off one norm, fourteen fixed multipliers, a dense SwiGLU), whole or
+as one of the chips that share a layer by tensor parallelism, with
+``falcon_h1_reference``; ``lm_blocks`` holds what the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -37,6 +40,7 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
-from . import (kanana2, kanana2_reference, lfm2, lfm2_reference, mimo_v2,
-               mimo_v2_reference, nemotron_h, nemotron_h_reference,
-               olmo_hybrid, olmo_hybrid_reference, olmoe, olmoe_reference)
+from . import (falcon_h1, falcon_h1_reference, kanana2, kanana2_reference,
+               lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
+               nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
+               olmoe, olmoe_reference)

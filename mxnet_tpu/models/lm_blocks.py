@@ -1,28 +1,40 @@
 """The pieces the decoder-only LM symbols share (``mimo_v2``,
-``kanana2``, ``nemotron_h``, ``olmo_hybrid``, ``lfm2``; the tail also
-``olmoe``): a bias-free projection, the dense SwiGLU and the un-gated
-relu² feed-forward, the one-mixer residual block and the block that norms
-a sub-layer's output, the routed expert layer's call and the head, untied
-or reading the embedding's matrix, with its loss. Each takes the
-node-name prefix of its layer, so a model's argument and scope names are
-its own."""
+``kanana2``, ``nemotron_h``, ``olmo_hybrid``, ``lfm2``, ``falcon_h1``;
+the tail also ``olmoe``): a bias-free projection, the dense SwiGLU and
+the un-gated relu² feed-forward, the one-mixer residual block, the block
+that norms a sub-layer's output and the block whose mixers read one
+normed input side by side, a fixed scalar on a node's output, the routed
+expert layer's call and the head, untied or reading the embedding's
+matrix, with its loss. Each takes the node-name prefix of its layer, so a
+model's argument and scope names are its own."""
 from .. import initializer as init
 from .. import symbol as sym
 from ..contrib import symbol as csym
 
 
-def linear(x, name, num_hidden):
+def linear(x, name, num_hidden, init=None):
+    """``FullyConnected`` without a bias; ``init`` is the weight's own
+    rule where the model states one."""
+    extra = {} if init is None else {
+        "weight": sym.Variable(name + "_weight", init=init)}
     return sym.FullyConnected(x, num_hidden=num_hidden, no_bias=True,
-                              name=name)
+                              name=name, **extra)
 
 
-def swiglu(x, prefix, width, hidden_size):
+def swiglu(x, prefix, width, hidden_size, gate_scale=1.0, out_scale=1.0,
+           inits=(None, None, None)):
     """``<prefix>down_proj(silu(<prefix>gate_proj(x)) *
-    <prefix>up_proj(x))`` at ``width`` columns."""
-    gate = sym.Activation(linear(x, prefix + "gate_proj", width),
-                          act_type="silu")
-    return linear(gate * linear(x, prefix + "up_proj", width),
-                  prefix + "down_proj", hidden_size)
+    <prefix>up_proj(x))`` at ``width`` columns; with the two fixed
+    scalars, ``gate_scale`` on the gate's pre-activation and
+    ``out_scale`` on the result (``scaled``). ``inits``: the rules of the
+    gate's, the up and the down projection's weights."""
+    gate = sym.Activation(
+        scaled(linear(x, prefix + "gate_proj", width, inits[0]),
+               prefix + "gate_proj_scale", gate_scale), act_type="silu")
+    return scaled(
+        linear(gate * linear(x, prefix + "up_proj", width, inits[1]),
+               prefix + "down_proj", hidden_size, inits[2]),
+        prefix + "down_proj_scale", out_scale)
 
 
 def relu2_mlp(x, prefix, width, hidden_size):
@@ -52,22 +64,28 @@ def expert_layer(x, prefix, **attrs):
 
 
 def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
-                  tied_to=None):
+                  tied_to=None, logit_scale=1.0, init=None):
     """``final_norm``, the ``lm_head``, float32 logits (``lm_head_f32``)
     and each sequence's mean next-token cross-entropy behind ``MakeLoss``
     (``loss``), grouped with the layers' counts. The head is untied, its
-    own ``lm_head_weight``, unless ``tied_to`` is the embedding's
-    ``Variable``: then it reads that matrix (``[vocab, hidden]`` is an
-    ``Embedding``'s table and a ``FullyConnected``'s weight alike), one
-    parameter whose gradient is the sum of both uses."""
+    own ``lm_head_weight`` (drawn by ``init`` where given), unless
+    ``tied_to`` is the embedding's ``Variable``: then it reads that matrix
+    (``[vocab, hidden]`` is an ``Embedding``'s table and a
+    ``FullyConnected``'s weight alike), one parameter whose gradient is
+    the sum of both uses. ``logit_scale``: a fixed scalar on the float32
+    logits (the cast is ``lm_head_cast`` then, the scaled logits
+    ``lm_head_f32``)."""
     normed = csym.RMSNorm(h, eps=rms_eps, name="final_norm")
     if tied_to is None:
-        logits = linear(normed, "lm_head", vocab_size)
+        logits = linear(normed, "lm_head", vocab_size, init)
     else:
         logits = sym.FullyConnected(normed, weight=tied_to,
                                     num_hidden=vocab_size, no_bias=True,
                                     name="lm_head")
-    logits = sym.Cast(logits, dtype="float32", name="lm_head_f32")
+    logits = scaled(sym.Cast(
+        logits, dtype="float32",
+        name="lm_head_f32" if logit_scale == 1 else "lm_head_cast"),
+        "lm_head_f32", logit_scale)
     nll = 0 - sym.pick(sym.log_softmax(logits, name="lm_head_logp"),
                        sym.Reshape(label, shape=(-1,)), axis=1,
                        name="lm_head_pick")
@@ -83,3 +101,28 @@ def post_norm_block(h, prefix, norm, rms_eps, sublayer):
     Olmo 2 / Olmo 3 order); ``mixer_block`` norms its input."""
     return h + csym.RMSNorm(sublayer(h, prefix), eps=rms_eps,
                             name=prefix + norm)
+
+
+def scaled(x, name, scale):
+    """``scale * x`` under a fixed Python scalar as the node ``name``
+    (``ScaledSum`` of one input: the product float32, one rounding to
+    ``x``'s dtype); ``x`` itself where the scalar is 1."""
+    if scale == 1:
+        return x
+    return csym.ScaledSum(x, scales=(float(scale),), name=name)
+
+
+def parallel_block(h, prefix, rms_eps, mixers):
+    """``h + sum_i scale_i * mixer_i(RMSNorm(h), prefix)``: ONE norm
+    (``<prefix>norm``) read by every mixer side by side, their outputs
+    scaled and summed by one node (``<prefix>mixer_sum``, which counts
+    the block in ``lm.parallel_blocks`` where it is traced) before the
+    residual add (``<prefix>mixer_add``: named, so that a trace files the
+    fusion the two end in with the block whichever is its root).
+    ``mixers`` is ``[(kind, scale, mixer)]``."""
+    normed = csym.RMSNorm(h, eps=rms_eps, name=prefix + "norm")
+    return sym.elemwise_add(h, csym.ScaledSum(
+        *[mixer(normed, prefix) for _, _, mixer in mixers],
+        scales=tuple(float(scale) for _, scale, _ in mixers),
+        kinds="+".join(kind for kind, _, _ in mixers),
+        name=prefix + "mixer_sum"), name=prefix + "mixer_add")

@@ -1,5 +1,6 @@
 """Transformer-block operators for the Symbol API: RMSNorm, RoPE,
-Attention, LatentAttention, Mamba2, TopKMoE, GatedDeltaNet and ShortConv.
+Attention, LatentAttention, Mamba2, TopKMoE, GatedDeltaNet, ShortConv and
+ScaledSum.
 
 Beyond-reference capability (the 2017 operator set has no attention and
 no sparse-expert layer): what a decoder-only LM with sparse experts
@@ -30,6 +31,7 @@ import numpy as np
 from .. import telemetry as _tm
 from ..base import MXNetError
 from .registry import OpDef, register
+from .utils import same_shape_infer
 
 
 def _known(shape, what):
@@ -326,7 +328,8 @@ register(
 _M_SCAN_LOWERINGS = _tm.counter(
     "ssm.scan_lowerings", "Traces of a Mamba2 call site (one per "
     "lowering, nothing per step); labels: heads, head_dim, state, groups, "
-    "chunk, conv (the convolution's taps), impl (kernel / einsum)")
+    "chunk, conv (the convolution's taps), impl (kernel / einsum) and, "
+    "where the projection's five segments are scaled, scaled=1")
 
 
 def ssd_scan(x, bmat, cmat, dt, a, chunk):
@@ -397,7 +400,7 @@ def ssd_scan(x, bmat, cmat, dt, a, chunk):
 
 def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
            num_heads, head_dim, state_size, num_groups, chunk_size, eps,
-           remat=False):
+           remat=False, multipliers=None):
     """proj [B, T, 2 H P + 2 G N + H] (``in_proj``'s output: the gate
     ``z``, then ``x | B | C``, then a step size a head), conv_weight
     [taps, H P + 2 G N] (tap ``taps - 1`` meets the current token),
@@ -428,6 +431,16 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     states) and the taps' its inputs (the backward kernel computes the
     sum again in VMEM), and each runs once each way.
 
+    ``multipliers`` (Falcon-H1's ``ssm_multipliers``, with whatever
+    scalar the projection's input carried folded in): five fixed scalars
+    over ``proj``'s segments ``z | x | B | C | dt``, ``proj * m`` a
+    segment in the mathematics. No scaled copy of ``proj`` is made (the
+    taps' kernel reads its window where ``in_proj`` left it): ``x``'s,
+    ``B``'s and ``C``'s scale the taps' float32 weights a column (a
+    depthwise tap is linear in its column; the bias is not scaled),
+    ``z``'s sits inside the gate's ``silu`` and ``dt``'s in front of
+    ``dt_bias``, each float32.
+
     The call site counts itself here (``ssm.scan_lowerings``, and
     ``causal_taps.lowerings`` for the convolution); the block
     itself is ``_mamba2_block``, one ``jax.jit`` for every node of one
@@ -440,23 +453,31 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     d_in = num_heads * head_dim
     taps_kernel = _taps_site("mamba2", proj, conv_weight, "bias_silu",
                              offset=d_in)
+    if multipliers is not None:
+        multipliers = tuple(float(m) for m in multipliers)
+        if len(multipliers) != 5:
+            raise ValueError("Mamba2: multipliers=%r must be five scalars, "
+                             "one a segment of z | x | B | C | dt"
+                             % (multipliers,))
     _M_SCAN_LOWERINGS.inc(heads=num_heads, head_dim=head_dim,
                           state=state_size, groups=num_groups,
                           chunk=chunk_size, conv=conv_weight.shape[0],
-                          impl="kernel" if kernel else "einsum")
+                          impl="kernel" if kernel else "einsum",
+                          **({} if multipliers is None else {"scaled": 1}))
     return _mamba2_block(
         proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
         sizes=(num_heads, head_dim, state_size, num_groups, chunk_size),
         eps=float(eps), remat=bool(remat), kernel=kernel,
-        taps_kernel=taps_kernel, interpret=kernels.common.INTERPRET)
+        taps_kernel=taps_kernel, interpret=kernels.common.INTERPRET,
+        multipliers=multipliers)
 
 
 @functools.partial(jax.jit, static_argnames=("sizes", "eps", "remat",
                                              "kernel", "taps_kernel",
-                                             "interpret"))
+                                             "interpret", "multipliers"))
 def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
                   norm_gamma, *, sizes, eps, remat, kernel, taps_kernel,
-                  interpret):
+                  interpret, multipliers=None):
     """``mamba2`` for one signature (``sizes``: heads, head width, state,
     groups, chunk)."""
     from . import kernels
@@ -466,6 +487,11 @@ def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
     h, p, n, g, chunk = sizes
     d_in = h * p
     conv_dim = d_in + 2 * g * n
+    m_z = m_dt = None
+    if multipliers is not None:
+        m_z, m_x, m_b, m_c, m_dt = multipliers
+        conv_weight = conv_weight.astype(f32) * np.repeat(
+            np.asarray([m_x, m_b, m_c], np.float32), (d_in, g * n, g * n))
 
     def again(f, **policy):
         return jax.checkpoint(f, **policy) if remat else f
@@ -476,8 +502,9 @@ def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
         return jax.nn.silu(acc).astype(proj.dtype)
 
     def gate_norm(y, proj, norm_gamma):
-        gated = (y.reshape(b, t, d_in)
-                 * jax.nn.silu(proj[..., :d_in].astype(f32)))
+        z = proj[..., :d_in].astype(f32)
+        gated = y.reshape(b, t, d_in) * jax.nn.silu(
+            z if m_z is None else z * m_z)
         groups = gated.reshape(b, t, g, d_in // g)
         var = jnp.mean(jnp.square(groups), axis=-1, keepdims=True)
         normed = (groups * jax.lax.rsqrt(var + eps)).reshape(b, t, d_in)
@@ -494,7 +521,8 @@ def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
         x = xbc[..., :d_in].reshape(b, t, h, p)
         bc = (xbc[..., d_in:d_in + g * n].reshape(b, t, g, n),
               xbc[..., d_in + g * n:].reshape(b, t, g, n))
-        dt = jax.nn.softplus(proj[..., d_in + conv_dim:].astype(f32)
+        dt = proj[..., d_in + conv_dim:].astype(f32)
+        dt = jax.nn.softplus((dt if m_dt is None else dt * m_dt)
                              + dt_bias.astype(f32))
         a = -jnp.exp(a_log.astype(f32))
         if kernel:
@@ -518,7 +546,8 @@ def _mamba2(attrs, ins, is_train):
     h, p, n, g = _mamba2_sizes(attrs)
     return [mamba2(*ins, num_heads=h, head_dim=p, state_size=n, num_groups=g,
                    chunk_size=int(attrs["chunk_size"]),
-                   eps=float(attrs.get("eps", 1e-5)), remat=is_train)]
+                   eps=float(attrs.get("eps", 1e-5)), remat=is_train,
+                   multipliers=attrs.get("multipliers"))]
 
 
 def _mamba2_infer(attrs, in_shapes):
@@ -549,7 +578,7 @@ register(
                    "d", "norm_gamma"),
         defaults={"num_heads": 1, "head_dim": 0, "state_size": 0,
                   "num_groups": 1, "conv_kernel": 4, "chunk_size": 128,
-                  "eps": 1e-5},
+                  "eps": 1e-5, "multipliers": None},
         infer_shape=_mamba2_infer,
         aliases=("Mamba2",),
     )
@@ -1167,3 +1196,50 @@ def _taps_site(site, src, conv_weight, form, offset=0, channels=None):
     _M_TAPS_LOWERINGS.inc(site=site, channels=channels, taps=taps,
                           impl="kernel" if kernel else "jnp")
     return kernel
+
+
+# --------------------------------------------------------------------------
+# ScaledSum — sub-layer outputs under fixed scalars (Falcon-H1's
+# multipliers): one scaled, or several scaled and summed off one input
+# --------------------------------------------------------------------------
+_M_PARALLEL_BLOCKS = _tm.counter(
+    "lm.parallel_blocks", "Traces of a ScaledSum node that sums the "
+    "mixers of one parallel block (one per node and lowering, nothing per "
+    "step); labels: mixers (their kinds, '+'-joined), count")
+
+
+def scaled_sum(xs, scales):
+    """``sum_i scales[i] * xs[i]`` of arrays of one shape and dtype under
+    fixed Python scalars: each product and the sum float32, one rounding
+    to the inputs' dtype (a scalar rounded to bf16 first would be off by
+    up to 0.4%, and 0.0375 is by 0.26%)."""
+    acc = None
+    for x, scale in zip(xs, scales):
+        term = x.astype(jnp.float32) * float(scale)
+        acc = term if acc is None else acc + term
+    return acc.astype(xs[0].dtype)
+
+
+def _scaled_sum(attrs, ins, is_train):
+    scales = tuple(attrs["scales"])
+    if len(scales) != len(ins):
+        raise ValueError("ScaledSum: %d inputs under scales=%r"
+                         % (len(ins), scales))
+    kinds = attrs.get("kinds")
+    if kinds:
+        _M_PARALLEL_BLOCKS.inc(mixers=str(kinds), count=len(ins))
+    return [scaled_sum(ins, scales)]
+
+
+register(
+    OpDef(
+        "_contrib_ScaledSum",
+        _scaled_sum,
+        arguments=("args",),
+        key_var_num_args="num_args",
+        defaults={"scales": (1.0,), "kinds": None},
+        infer_shape=lambda attrs, in_shapes: same_shape_infer(
+            len(in_shapes))(attrs, in_shapes),
+        aliases=("ScaledSum",),
+    )
+)

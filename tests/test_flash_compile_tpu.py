@@ -107,8 +107,10 @@ def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, window,
 # the LM cells' attention calls (T, query heads, key/value heads, D, Dv,
 # window): Kanana's latent attention, OLMoE's, MiMo's full and window
 # layers, LFM2's (heads of 64: half a lane row a head, 4 query heads a
+# key/value head), Falcon-H1's share (10 query heads on 2: five a
 # key/value head)
 CELL_CALLS = {
+    "falcon_h1_4k": (4096, 10, 2, 128, 128, 0),
     "kanana2_8k": (8192, 32, 32, 192, 128, 0),
     "lfm2_8k": (8192, 32, 8, 64, 64, 0),
     "olmoe_4k": (4096, 16, 16, 128, 128, 0),
@@ -352,10 +354,13 @@ def test_a_share_layer_moves_rows_without_a_scatter_on_v5e(one_chip):
 
 
 # the Nemotron-3-Nano cell's scan (one sequence of 8,192 tokens, 64 heads
-# of 64 on 8 groups, state 128, chunks of 128, bf16); a float32 caller
+# of 64 on 8 groups, state 128, chunks of 128, bf16); the Falcon-H1
+# share's (4,096 tokens, 16 heads of 128 in ONE group, state 256: a step
+# is sixteen lane tiles and a [256, 2048] float32 state); a float32 caller
 # whose heads are whole lane rows, in chunks of 256 over a ragged length
 SSD_SHAPES = [
     (8192, 64, 64, 8, 128, 128, jnp.bfloat16),
+    (4096, 16, 128, 1, 256, 128, jnp.bfloat16),
     (1000, 4, 128, 2, 256, 256, jnp.float32),
 ]
 
@@ -384,8 +389,9 @@ def test_ssd_scan_kernels_compile_for_v5e(one_chip, t, heads, p, groups, n,
         calls = [line for line in text.splitlines()
                  if name in line and "custom-call(" in line]
         assert len(calls) == 1, name
+        # the stated limit starts past what XLA itself keeps in VMEM
         limit, used = (
-            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"0",'
+            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
                           r'"size":"(\d+)"' % key, calls[0]).group(1))
             for key in ("scoped_memory_configs",
                         "used_scoped_memory_configs"))
@@ -442,8 +448,13 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, t, heads, dk, dv,
 # the three cells' convolved arrays: Nemotron's Mamba-2 window (columns
 # 4,096 to 10,240 of an in_proj output 10,304 wide: 80.5 lane rows),
 # Olmo-Hybrid's query / key (2,880 columns, 22.5 lane rows, taken whole)
-# and value, LFM2's B | C | x; a float32 caller with three taps
+# and value, LFM2's B | C | x; the Falcon-H1 share's window (columns
+# 2,048 to 4,608 of an in_proj output 4,624 wide, 36.125 lane rows: a
+# column tile of four lane rows, a time tile of 1,024); a float32 caller
+# with three taps
 TAPS_SHAPES = {
+    "falcon_h1_mamba2": ("bias_silu", 4, (1, 4096, 4624), 2048, 2560,
+                         jnp.bfloat16),
     "nemotron_mamba2": ("bias_silu", 4, (1, 8192, 10304), 4096, 6144,
                         jnp.bfloat16),
     "olmo_hybrid_query_key": ("silu", 4, (1, 4096, 2880), 0, 2880,

@@ -80,12 +80,11 @@ def _time(f, *args, reps=20):
     return (time.perf_counter() - t0) / reps * 1e3
 
 
-def _device_ms(f, *args, reps=10):
-    """Device 0's busy time a call, ms: the union of its ops over a
-    profiled run of ``reps`` calls (``bench/reduce_trace.py`` reads the
-    slice, as the benchmark's readers do). The host clock of ``_time``
-    has a floor of about 0.3 ms a call on the chip's host, above most of
-    a share's moves; None off the TPU."""
+def _device_ops(f, *args, reps=10):
+    """Device 0's ops over a profiled run of ``reps`` calls, as
+    ``bench/reduce_trace.py`` reads them ((name, start, duration) in ns;
+    the benchmark's readers read a slice the same way); None off the
+    TPU."""
     import glob
     import tempfile
 
@@ -104,11 +103,24 @@ def _device_ms(f, *args, reps=10):
         found = glob.glob(os.path.join(
             where, "plugins", "profile", "*", "*.xplane.pb"))
         devices = reduce_trace.load(found[0])["devices"] if found else {}
-    if 0 not in devices:
+    return devices[0]["ops"] if 0 in devices else None
+
+
+def _busy_ms(ops, reps):
+    """The union of ``ops`` (``_device_ops``'s) a call, ms."""
+    import reduce_trace
+
+    if ops is None:
         return None
-    busy = reduce_trace.total(reduce_trace.union(
-        [(s, s + d) for _, s, d in devices[0]["ops"]]))
-    return busy / reps / 1e6
+    return reduce_trace.total(reduce_trace.union(
+        [(s, s + d) for _, s, d in ops])) / reps / 1e6
+
+
+def _device_ms(f, *args, reps=10):
+    """Device 0's busy time a call, ms. The host clock of ``_time`` has a
+    floor of about 0.3 ms a call on the chip's host, above most of a
+    share's moves; None off the TPU."""
+    return _busy_ms(_device_ops(f, *args, reps=reps), reps)
 
 
 def sort_pair(experts):

@@ -7,10 +7,13 @@ XLA's variadic sort replaces that here.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry as _tm
 from ..base import MXNetError
 from .registry import OpDef, register
 
@@ -64,10 +67,71 @@ register(
 )
 
 
+_M_EMBED_GRAD_LOWERINGS = _tm.counter(
+    "embedding.grad_lowerings", "Traces of Embedding's backward rule (one "
+    "per lowering, nothing per step); labels: rows (ids looked up), vocab, "
+    "width, dtype (the table's), impl (segment_product / segment_sum / "
+    "scatter)")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _lookup(weight, idx, vocab):
+    """``weight[idx]`` along the first axis as ``jnp.take`` reads it
+    (a negative id counts from the end, one out of range reads NaN).
+    Autodiff would transpose the gather to a scatter-add of the
+    cotangent's rows into the table, which on the TPU costs up to 14 us
+    a row (PERF.md section 5); the rule below sorts the ids and sums each
+    id's rows as a sorted segment sum
+    (``ops.kernels.sorted_segment_sum``: a product where the step is
+    lowered for the TPU): float32 accumulation, one rounding to the
+    table's type, every row of the table written, exact zeros where no
+    id points."""
+    return jnp.take(weight, idx, axis=0)
+
+
+def _lookup_fwd(weight, idx, vocab):
+    return jnp.take(weight, idx, axis=0), idx
+
+
+def _lookup_bwd(vocab, idx, g):
+    from . import kernels
+
+    width = g.shape[-1]
+    rows = g.reshape(-1, width)
+    m = rows.shape[0]
+    if kernels.common.trace_is_partitioned():
+        # the kernels have no partitioning rule: a program split over
+        # devices keeps the scatter-add, which the partitioner knows
+        impl = "scatter"
+    elif kernels.gmm_runs_kernel(m, g.dtype):
+        impl = "segment_product"
+    else:
+        impl = "segment_sum"
+    _M_EMBED_GRAD_LOWERINGS.inc(rows=m, vocab=vocab, width=width,
+                                dtype=jnp.dtype(g.dtype).name, impl=impl)
+    if impl == "scatter":
+        take = functools.partial(jnp.take, indices=idx, axis=0)
+        return jax.linear_transpose(
+            take, jax.ShapeDtypeStruct((vocab, width), g.dtype))(g) + (None,)
+    # what ``jnp.take`` does with an id: below zero it counts from the
+    # end, and one still outside the table reads no row, so its
+    # cotangent belongs to no segment (and sorts last)
+    ids = idx.reshape(m)
+    ids = jnp.where(ids < 0, ids + vocab, ids)
+    ids = jnp.where((ids < 0) | (ids >= vocab), vocab, ids)
+    ids, order = jax.lax.sort((ids, jax.lax.iota(jnp.int32, m)), num_keys=1)
+    rows = rows.at[order].get(mode="promise_in_bounds")
+    return (kernels.sorted_segment_sum(
+        rows, ids, vocab, interpret=kernels.common.INTERPRET), None)
+
+
+_lookup.defvjp(_lookup_fwd, _lookup_bwd)
+
+
 def _embedding(attrs, ins, is_train):
     data, weight = ins
     idx = data.astype(jnp.int32)
-    return [jnp.take(weight, idx, axis=0)]
+    return [_lookup(weight, idx, weight.shape[0])]
 
 
 def _embedding_infer(attrs, in_shapes):
@@ -102,6 +166,17 @@ register(
         defaults={"input_dim": 0, "output_dim": 0, "dtype": "float32"},
         infer_shape=_embedding_infer,
         infer_type=_embedding_infer_type,
+        doc="""weight[data] along the first axis: data (any shape, cast to int32)
+-> data.shape + (output_dim,) in the table's type. An id below zero counts
+from the table's end; one outside the table reads NaN and has no gradient.
+The table's gradient is dense, in the table's type: each id's cotangent
+rows summed in float32 and rounded once, exact zeros where no id points.
+It is built without a scatter: the ids are sorted and the rows summed as a
+sorted segment sum (ops/kernels/gmm.py sorted_segment_sum: a product over
+a 0 / 1 table through the grouped matmul's wgrad kernel where the step is
+lowered for the TPU, jax.ops.segment_sum elsewhere); a step partitioned
+over more than one device keeps the scatter-add that autodiff gives, which
+accumulates in the table's type.""",
     )
 )
 

@@ -4,6 +4,8 @@ the Pallas interpreter from inside a model, the two VMEM figures, and the
 helpers more than one family uses. Nothing here imports a family."""
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
@@ -32,6 +34,30 @@ VMEM_RAISED_LIMIT = 48 * 1024 * 1024
 # when it traces. A test reaches a kernel through a model with
 # ``monkeypatch.setattr(common, "INTERPRET", True)``; nothing else sets it.
 INTERPRET = False
+
+
+# Whether the program being traced is partitioned over more than one
+# device. The kernels have no partitioning rule (``grouped_matmul``'s
+# docstring), and a trace under ``jax.jit`` does not see the mesh its
+# operands are placed on: ``ShardedTrainStep`` says it round the trace of
+# its step, the one program of ops that the partitioner splits.
+_PARTITIONED = contextvars.ContextVar("mxtpu_partitioned", default=False)
+
+
+@contextlib.contextmanager
+def partitioned_trace(devices):
+    """Round the trace of a program laid over ``devices`` devices."""
+    token = _PARTITIONED.set(devices > 1)
+    try:
+        yield
+    finally:
+        _PARTITIONED.reset(token)
+
+
+def trace_is_partitioned():
+    """What an op asks that has a form the partitioner can split beside
+    its kernels (``Embedding``'s backward rule)."""
+    return _PARTITIONED.get()
 
 
 def on_tpu(kernels, plain, interpret, *args):

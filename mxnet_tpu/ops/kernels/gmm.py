@@ -551,8 +551,8 @@ def sorted_segment_sum(rows, segment, num_segments, interpret=False):
     the result is float32's whatever the MXU's pass. On every other
     platform, and where ``gmm_runs_kernel`` refuses the shapes,
     ``jax.ops.segment_sum(indices_are_sorted=True)``. Not
-    differentiable: its callers are the rules of a ``custom_vjp``
-    (``parallel/moe.py``'s share moves)."""
+    differentiable: its callers are ``custom_vjp`` rules (a share's
+    moves in ``parallel/moe.py``, ``Embedding`` in ``ops/indexing.py``)."""
     m, n = rows.shape
     segment = lax.convert_element_type(segment, jnp.int32)
     plain = functools.partial(segment_sum_call, num_segments=num_segments)

@@ -44,6 +44,7 @@ class OpDef:
         needs_rng=False,
         mutate_inputs=(),
         open_attrs=False,
+        doc=None,
     ):
         self.name = name
         self.fcompute = fcompute
@@ -78,6 +79,8 @@ class OpDef:
         # ops forwarding arbitrary kwargs to user code (Custom): the
         # typo net cannot know their parameter space
         self.open_attrs = open_attrs
+        # what the generated docstring says of the op beyond its signature
+        self.doc = doc
 
     # -- attr handling ------------------------------------------------------
     def canon_attrs(self, raw_attrs):
@@ -168,6 +171,8 @@ class OpDef:
         dmlc::Parameter docgen feeding the python op factories)."""
         lines = ["%s(%s, **params)" % (
             self.name, ", ".join(self._arguments)), ""]
+        if self.doc:
+            lines += [self.doc, ""]
         if self.defaults:
             lines.append("Parameters (with defaults):")
             for k in sorted(self.defaults):

@@ -1139,6 +1139,7 @@ class ShardedTrainStep:
         import jax.numpy as jnp
 
         from ..executor import _mirror_enabled, _mirror_policy
+        from ..ops import kernels
 
         program = self.program
         do_mirror = _mirror_enabled()
@@ -1178,7 +1179,8 @@ class ShardedTrainStep:
                 # backward, keep dot/conv residuals (executor._mirror_policy)
                 loss_fn = jax.checkpoint(loss_fn, policy=_mirror_policy)
 
-            with jax.named_scope("fwd_bwd"):
+            with jax.named_scope("fwd_bwd"), \
+                    kernels.common.partitioned_trace(self.mesh.size):
                 if guard:
                     # value_and_grad instead of grad: the diag head
                     # needs the loss VALUE; the gradient computation is

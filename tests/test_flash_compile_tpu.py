@@ -316,6 +316,51 @@ def test_sorted_segment_sum_compiles_for_v5e(one_chip, m, tokens, n, dtype,
     assert " scatter(" not in text
 
 
+# the seven LM cells' embedding gradients (ids a step, the table's width,
+# its rows; bf16): Falcon-H1 (32,640 rows are 127.5 groups of 256: the
+# padded sums are sliced), Olmo-Hybrid, OLMoE (196.5 groups), MiMo-V2-Flash,
+# Kanana-2, Nemotron-3-Nano (2,688 = 21 lane rows), LFM2
+EMBED_SHAPES = [
+    (4096, 5120, 32640, (128, 256, 1280)),
+    (4096, 3840, 12544, (128, 256, 1920)),
+    (4096, 2048, 50304, (128, 256, 2048)),
+    (4096, 4096, 19072, (128, 256, 2048)),
+    (8192, 2048, 16032, (128, 256, 2048)),
+    (8192, 2688, 16384, (128, 256, 896)),
+    (8192, 2048, 8192, (128, 256, 2048)),
+]
+
+
+@pytest.mark.parametrize("m,width,vocab,tiles", EMBED_SHAPES)
+def test_the_embeddings_gradient_compiles_for_v5e(one_chip, m, width, vocab,
+                                                  tiles):
+    """``Embedding``'s backward rule at a cell's shape as the TPU's
+    compiler leaves it: one sort, the segment product at the tiles
+    ``gmm_tiles`` gives (within the scoped VMEM the call asks for), no
+    scatter, the whole table's rows out."""
+    from mxnet_tpu.ops import indexing
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def grad(table, ids, cot):
+        return jax.vjp(lambda w: indexing._lookup(w, ids, vocab),
+                       table)[1](cot)[0]
+
+    compiled = jax.jit(grad).lower(
+        spec((vocab, width)), spec((m,), jnp.int32),
+        spec((m, width))).compile()
+    text = compiled.as_text()
+    assert pk.gmm_tiles(m, pk.gmm.SEGMENT_TILE, width,
+                        -(-vocab // pk.gmm.SEGMENT_TILE), jnp.bfloat16,
+                        wgrad=True) == tiles
+    assert "gmm_wgrad_bf16_m%d_k%d_n%d" % tiles in text
+    assert pk.gmm.gmm_vmem_bytes(
+        *tiles, 2, wgrad=True) <= pk.common.VMEM_SCOPED_DEFAULT
+    assert " scatter(" not in text and text.count(" sort(") == 1
+    assert "-> bf16[%d,%d]" % (vocab, width) in text
+
+
 def test_a_share_layer_moves_rows_without_a_scatter_on_v5e(one_chip):
     """One share layer at the Kanana cell's shape (8,192 tokens of 2,048,
     top-6 of 128 sigmoid-routed experts, 16 held of width 768, a buffer

@@ -302,11 +302,12 @@ def test_a_share_models_step_scatters_nothing_in_its_expert_layers(
         monkeypatch, seam):
     """The LFM2 share symbol at T 128 (two expert layers, a buffer of 768
     rows each), forward and backward as a step traces it. With the
-    kernels' branch taken (the seam) the program's only scatters are the
-    loss's pick over the vocabulary and the embedding's gradient: none
-    has the tokens' shape, where the tree's formulation had two a layer
-    (and a scalar one). On the CPU's own branch the segment sums are the
-    scatters: two a layer of that shape and one of [tokens, top_k], each
+    kernels' branch taken (the seam) the program's only scatter is the
+    loss's pick over the vocabulary (the embedding's gradient is a sorted
+    segment sum too, since PR 50): none has the tokens' shape, where the
+    tree's formulation had two a layer (and a scalar one). On the CPU's
+    own branch the segment sums are the scatters: two a layer of that
+    shape, one of [tokens, top_k] and the embedding's over the table, each
     over sorted indices."""
     from mxnet_tpu.executor import _GraphProgram
     from mxnet_tpu.models import lfm2
@@ -340,10 +341,11 @@ def test_a_share_models_step_scatters_nothing_in_its_expert_layers(
     text = jax.jit(jax.grad(loss)).lower(args, feeds).compiler_ir(
         dialect="hlo").as_hlo_text()
     scatters = re.findall(r"= (\S+?)\{[^ ]*\} scatter\((.*)", text)
-    others = ["f32[%d,%d]" % (batch * t, vocab),
+    others = ["f32[%d,%d]" % (batch * t, vocab)]
+    # a token's rows, the weights' cotangents placed the same way, and the
+    # table's rows
+    summed = ["f32[%d,%d]" % (batch * t, hidden), "f32[%d,3]" % (batch * t),
               "f32[%d,%d]" % (vocab, hidden)]
-    # a token's rows, and the weights' cotangents placed the same way
-    summed = ["f32[%d,%d]" % (batch * t, hidden), "f32[%d,3]" % (batch * t)]
     assert sorted(s for s, _ in scatters if s not in summed) == sorted(others)
     sums = [(s, rest) for s, rest in scatters if s in summed]
     if seam:

@@ -155,12 +155,21 @@ def _compute_node(node, attrs, in_vals, is_train):
     name>`` (metadata only): backward ops inherit it inside JAX's
     ``transpose(jvp(...))`` wrapper, so a device op in a profiler trace
     says which layer and which kind of layer it belongs to."""
-    with jax.named_scope("%s/%s" % (op_class(node.op.name), node.name)):
+    cls = op_class(node.op.name)
+    # while jax traces (and telemetry is on) the host seconds a node's
+    # fcompute takes are observed by class: jit.node_trace_seconds, the
+    # host's table with the device table's rows
+    t0 = _tm.setup.trace_clock() if _tm.setup.tracing() else None
+    with jax.named_scope("%s/%s" % (cls, node.name)):
         if is_train and _force_mirrored(node):
             fn = jax.checkpoint(
                 lambda *iv: node.op.fcompute(attrs, list(iv), is_train))
-            return fn(*in_vals)
-        return node.op.fcompute(attrs, in_vals, is_train)
+            results = fn(*in_vals)
+        else:
+            results = node.op.fcompute(attrs, in_vals, is_train)
+    if t0 is not None:
+        _tm.setup.note_node_trace(cls, t0)
+    return results
 
 
 _MIRROR_SAVE_DEFAULT = "dot_general,conv_general_dilated"

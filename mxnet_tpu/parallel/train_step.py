@@ -1142,6 +1142,7 @@ class ShardedTrainStep:
         from ..ops import kernels
 
         program = self.program
+        trace_part = _tm.setup.trace_part
         do_mirror = _mirror_enabled()
         amp = self.amp
         guard = self.guard
@@ -1150,7 +1151,10 @@ class ShardedTrainStep:
 
         # jax.named_scope below is metadata only (trace time, no op): a
         # device op in a profiler trace carries the phase it belongs to
-        # (bench/reduce_scopes.py reads fwd_bwd, update, guard, amp_cast)
+        # (bench/reduce_scopes.py reads fwd_bwd, update, guard, amp_cast).
+        # trace_part says where the trace's own host seconds went
+        # (jit.trace_seconds: forward, backward, update); the body runs
+        # only while jax traces it
         def step(params, aux, opt_state, batch, rng, lr, t, gthr):
             if amp_cast:
                 # bf16 activations from the first op: cast floating DATA
@@ -1165,21 +1169,23 @@ class ShardedTrainStep:
                         for n, v in batch.items()}
 
             def loss_fn(ps):
-                args = dict(ps)
-                args.update(batch)
-                outs, new_aux = program(args, aux, rng, True)
-                # *Output heads: drive vjp with ones (Executor.backward
-                # convention — the loss op bakes its own gradient)
-                loss = sum(jnp.sum(o.astype(jnp.float32) if amp else o)
-                           for o in outs)
-                return loss, (outs, new_aux)
+                with trace_part("forward"):
+                    args = dict(ps)
+                    args.update(batch)
+                    outs, new_aux = program(args, aux, rng, True)
+                    # *Output heads: drive vjp with ones
+                    # (Executor.backward convention — the loss op bakes
+                    # its own gradient)
+                    loss = sum(jnp.sum(o.astype(jnp.float32) if amp else o)
+                               for o in outs)
+                    return loss, (outs, new_aux)
 
             if do_mirror:
                 # MXNET_BACKWARD_DO_MIRROR: rematerialize cheap ops in
                 # backward, keep dot/conv residuals (executor._mirror_policy)
                 loss_fn = jax.checkpoint(loss_fn, policy=_mirror_policy)
 
-            with jax.named_scope("fwd_bwd"), \
+            with jax.named_scope("fwd_bwd"), trace_part("backward"), \
                     kernels.common.partitioned_trace(self.mesh.size):
                 if guard:
                     # value_and_grad instead of grad: the diag head
@@ -1228,7 +1234,7 @@ class ShardedTrainStep:
                 apply = self._apply_optimizer_flat
             else:
                 apply = self._apply_optimizer
-            with jax.named_scope("update"):
+            with jax.named_scope("update"), trace_part("update"):
                 new_params, new_opt = apply(params, grads, opt_state, lr, t)
             new_aux = {**aux, **new_aux}  # carry shared-owner extras through
             if amp:

@@ -12,20 +12,34 @@ from __future__ import annotations
 
 import sys
 import threading
+import time
 
 from . import registry as _reg
-from .tracer import under
+from .tracer import NULL_SPAN, under
 
 JIT_SECONDS = _reg.counter(
     "jit.seconds",
     "seconds inside jax's own pipeline, by phase (trace, lower, compile, "
-    "cache_load) and by the outermost span open on the calling thread; "
-    "each phase less what ran nested in it, so the streams add up to "
-    "wall time")
+    "cache_load), by the jitted function's name and by the outermost "
+    "span open on the calling thread; each phase less what ran nested in "
+    "it, so the streams add up to wall time")
 JIT_CACHE = _reg.counter(
     "jit.cache",
-    "persistent compile cache lookups by result (hit, miss) and by the "
-    "outermost span open on the calling thread")
+    "persistent compile cache lookups by result (hit, miss), by the "
+    "function being compiled and by the outermost span open on the "
+    "calling thread")
+TRACE_SECONDS = _reg.counter(
+    "jit.trace_seconds",
+    "host seconds of the fused step's trace by part of its body "
+    "(forward, backward, update) and by the outermost span open on the "
+    "calling thread; a part less the parts nested in it and less "
+    "what jax timed inside it as a phase other than trace, so the parts "
+    "add up with jit.seconds{phase=trace}")
+NODE_TRACE_SECONDS = _reg.histogram(
+    "jit.node_trace_seconds",
+    "host seconds of one symbol node's fcompute while jax traces it, by "
+    "the node's op class and by the outermost span open on the calling "
+    "thread; on the same clock as jit.trace_seconds")
 H2D_BYTES = _reg.counter(
     "device.h2d_bytes",
     "bytes of host memory handed to jax.device_put (the numpy array's "
@@ -61,42 +75,120 @@ _accelerated = None
 
 
 def _open_phases():
-    """Per thread, the seconds spent nested in each open phase. jax
-    times a phase from enter to exit, and phases nest: a jit inside a
-    traced function is traced inside the outer trace, a constant folded
-    during tracing compiles inside it."""
+    """Per thread, ``[seconds nested in it, fun]`` of each open phase.
+    jax times a phase from enter to exit, and phases nest: a jit inside
+    a traced function is traced inside the outer trace, a constant
+    folded during tracing compiles inside it."""
     st = getattr(_tls, "open", None)
     if st is None:
         st = _tls.open = []
     return st
 
 
-def _on_phase_begin(event, _value, **_):
+def _fun(fun_name):
+    """One function under one name in every phase: jax names the trace
+    ``step`` and the lowering and the compile ``jit(step)``."""
+    if not fun_name:
+        return "-"
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+def _on_phase_begin(event, _value, fun_name=None, **_):
     # jax records a scalar (the start time) on entering a timed phase
     if _reg._enabled and event in _PHASES:
-        _open_phases().append(0.0)
+        _open_phases().append([0.0, _fun(fun_name)])
 
 
-def _on_duration(event, seconds, **_):
+def _on_duration(event, seconds, fun_name=None, **_):
     if not _reg._enabled:
         return
     phase = _PHASES.get(event)
     if phase is None and event != _CACHE_LOAD:
         return
     st = _open_phases()
+    fun = _fun(fun_name)
     if phase is None:
-        # timed inside backend_compile, with no begin of its own
+        # timed inside backend_compile, with no begin and no name of
+        # its own
         phase, nested = "cache_load", 0.0
+        if st:
+            fun = st[-1][1]
     else:
-        nested = st.pop() if st else 0.0
+        nested = st.pop()[0] if st else 0.0
     if st:
-        st[-1] += seconds
-    JIT_SECONDS.inc(max(seconds - nested, 0.0), phase=phase, under=under())
+        st[-1][0] += seconds
+    own = max(seconds - nested, 0.0)
+    if phase != "trace":
+        _tls.untraced = getattr(_tls, "untraced", 0.0) + own
+    JIT_SECONDS.inc(own, phase=phase, fun=fun, under=under())
 
 
 def _on_event(event, **_):
     if _reg._enabled and event in _CACHE_RESULTS:
-        JIT_CACHE.inc(result=_CACHE_RESULTS[event], under=under())
+        st = _open_phases()
+        JIT_CACHE.inc(result=_CACHE_RESULTS[event],
+                      fun=st[-1][1] if st else "-", under=under())
+
+
+def tracing():
+    """Whether jax is inside a timed phase on this thread, which for a
+    caller that is Python run by jax means a trace; False while
+    collection is off (the phases are then not followed)."""
+    return _reg._enabled and bool(getattr(_tls, "open", None))
+
+
+def trace_clock():
+    """``perf_counter`` less the seconds jax has timed on this thread as
+    a phase other than trace (a constant compiled or loaded while
+    tracing, an eager op lowered): an interval on this clock is what
+    ``jit.seconds{phase=trace}`` holds of it, nested traces included."""
+    return time.perf_counter() - getattr(_tls, "untraced", 0.0)
+
+
+class _TracePart:
+    """One part of a traced body (``jit.trace_seconds``): parts nest,
+    and each holds its interval on ``trace_clock`` less the parts
+    nested in it, so a body's parts partition it."""
+
+    __slots__ = ("part", "t0", "nested")
+
+    def __init__(self, part):
+        self.part = part
+
+    def __enter__(self):
+        st = getattr(_tls, "parts", None)
+        if st is None:
+            st = _tls.parts = []
+        st.append(self)
+        self.nested = 0.0
+        self.t0 = trace_clock()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = trace_clock() - self.t0
+        st = _tls.parts
+        st.pop()
+        if st:
+            st[-1].nested += seconds
+        TRACE_SECONDS.inc(max(seconds - self.nested, 0.0), part=self.part,
+                          under=under())
+        return False
+
+
+def trace_part(part):
+    """Context manager round one part of a function's body that runs
+    only while jax traces it; the shared null while collection is off
+    (one flag test a part at trace time)."""
+    return _TracePart(part) if _reg._enabled else NULL_SPAN
+
+
+def note_node_trace(op_class, t0):
+    """One node's ``fcompute`` from ``t0`` (a ``trace_clock`` reading)
+    to now. Callers guard with ``tracing()``."""
+    NODE_TRACE_SECONDS.observe(
+        trace_clock() - t0, **{"class": op_class, "under": under()})
 
 
 def install():

@@ -128,7 +128,8 @@ def test_disabled_fit_builds_no_span_and_no_annotation(annotations,
     assert listeners == [] and not tm_setup._installed
     for metric in (tm_setup.JIT_SECONDS, tm_setup.JIT_CACHE,
                    tm_setup.H2D_BYTES, tm_setup.IMPORT_T0,
-                   tm_setup.IMPORT_SECONDS):
+                   tm_setup.IMPORT_SECONDS, tm_setup.TRACE_SECONDS,
+                   tm_setup.NODE_TRACE_SECONDS):
         assert metric.label_sets() == [], metric.name
 
 
@@ -303,18 +304,210 @@ def test_jit_phases_do_not_count_nested_seconds_twice():
         "/jax/core/compile/jaxpr_trace_duration",
         "/jax/core/compile/backend_compile_duration")
     with tm.span("module.bind"):
-        begin(trace, 0.0)
-        begin(trace, 0.0)
-        done(trace, 0.25)          # a jit traced inside the outer trace
-        begin(compile_, 0.0)
+        begin(trace, 0.0, fun_name="step")
+        begin(trace, 0.0, fun_name="inner")
+        done(trace, 0.25, fun_name="inner")   # a jit traced inside the
+        begin(compile_, 0.0, fun_name="jit(fold)")        # outer trace
+        tm_setup._on_event("/jax/compilation_cache/cache_hits")
         done("/jax/compilation_cache/cache_retrieval_time_sec", 0.5)
-        done(compile_, 0.75)       # a constant folded while tracing
-        done(trace, 2.0)
-    got = {s["labels"]["phase"]: s["value"]
-           for s in _streams("jit.seconds")}
+        done(compile_, 0.75, fun_name="jit(fold)")   # a constant folded
+        done(trace, 2.0, fun_name="step")            # while tracing
+    got = {}
+    for s in _streams("jit.seconds"):
+        phase = s["labels"]["phase"]
+        got[phase] = got.get(phase, 0.0) + s["value"]
     assert got == {"trace": 0.25 + 1.0, "compile": 0.25, "cache_load": 0.5}
     assert {s["labels"]["under"] for s in _streams("jit.seconds")} == {
         "module.bind"}
+    # one function under one name in every phase; a cache lookup and
+    # its load carry the name of the compile they happened in
+    assert {(s["labels"]["phase"], s["labels"]["fun"]): s["value"]
+            for s in _streams("jit.seconds")} == {
+        ("trace", "step"): 1.0, ("trace", "inner"): 0.25,
+        ("compile", "fold"): 0.25, ("cache_load", "fold"): 0.5}
+    assert [s["labels"] for s in _streams("jit.cache")] == [
+        {"result": "hit", "fun": "fold", "under": "module.bind"}]
+
+
+# ---------------------------------------------------------------------------
+# the first dispatch from inside (ISSUE 51): which function, which part
+# of the step's body, which class of node
+# ---------------------------------------------------------------------------
+
+TOY_CLASSES = {"conv": 1, "bn": 1, "act": 1, "pool": 1, "other": 1,
+               "fc": 1, "loss": 1}
+
+
+def _under(metric, root="fit.step"):
+    return [s for s in _streams(metric) if s["labels"]["under"] == root]
+
+
+def _parts(root="fit.step"):
+    return {s["labels"]["part"]: s["value"]
+            for s in _under("jit.trace_seconds", root)}
+
+
+def _nodes(root="fit.step"):
+    return {s["labels"]["class"]: (s["count"], s["sum"])
+            for s in _under("jit.node_trace_seconds", root)}
+
+
+@pytest.mark.parametrize("metric", ["jit.seconds", "jit.cache"])
+def test_jit_streams_say_which_function(metric, traced_fit):
+    streams = _streams(metric)
+    assert streams and all("fun" in s["labels"] for s in streams)
+    funs = {s["labels"]["fun"] for s in streams}
+    assert not [f for f in funs if f.startswith("jit(")], funs
+    # the fused step's program by its name, in every phase it has
+    own = {s["labels"].get("phase", s["labels"].get("result"))
+           for s in _under(metric) if s["labels"]["fun"] == "step"}
+    if metric == "jit.seconds":
+        assert own >= {"trace", "lower", "compile"}, own
+    else:
+        assert own and own <= {"hit", "miss"}, own
+
+
+def test_trace_parts_partition_the_steps_trace(traced_fit):
+    parts = _parts()
+    assert set(parts) == {"forward", "backward", "update"}
+    assert all(v > 0 for v in parts.values())
+    # the step's own trace and what nested in it: every function traced
+    # under fit.step (the eager scalars round the call are its dust)
+    traced = sum(s["value"] for s in _under("jit.seconds")
+                 if s["labels"]["phase"] == "trace")
+    assert sum(parts.values()) == pytest.approx(traced, rel=0.05, abs=0.01)
+    assert sum(parts.values()) <= traced + 1e-3
+    # every root that traces a step, and no other
+    assert {s["labels"]["under"]
+            for s in _streams("jit.trace_seconds")} == {"fit.step"}
+
+
+def test_forward_holds_the_nodes(traced_fit):
+    nodes = _nodes()
+    assert _parts()["forward"] >= sum(v for _, v in nodes.values())
+    assert all(v > 0 for _, v in nodes.values())
+
+
+def test_a_node_is_observed_once_under_its_class(traced_fit):
+    nodes = _nodes()
+    program = traced_fit[0]._fused_trainer.program
+    wanted = [executor.op_class(n.op.name) for n in program.nodes
+              if not n.is_variable]
+    assert {c: n for c, (n, _) in nodes.items()} == TOY_CLASSES
+    assert {c: wanted.count(c) for c in set(wanted)} == TOY_CLASSES
+
+
+def test_a_traced_signature_is_traced_once(traced_fit):
+    """A second fit and its steady-state steps run no Python of the
+    step: neither stream moves."""
+    mod, _ = traced_fit
+    before = _parts(), _nodes()
+    _toy_fit(batches=3, mod=mod)
+    assert (_parts(), _nodes()) == before
+
+
+def test_a_new_batch_size_is_one_more_trace(traced_fit):
+    mod, _ = traced_fit
+    before = _parts()
+    mod.reshape([("data", (4, 3, 8, 8))], [("softmax_label", (4,))])
+    _toy_fit(batches=3, mod=mod, batch_size=4)
+    assert {c: n for c, (n, _) in _nodes().items()} == {
+        c: 2 * n for c, n in TOY_CLASSES.items()}
+    after = _parts()
+    assert all(after[p] > before[p] for p in ("forward", "backward",
+                                              "update"))
+
+
+def test_an_eager_forward_observes_no_node():
+    """Outside a trace a node's seconds are asynchronous dispatch, which
+    is not this: the executor path observes its nodes where jax traces
+    them and nowhere else."""
+    tm.enable()
+    mod = mx.mod.Module(_toy_symbol(), context=mx.cpu(0))
+    mod.bind([("data", (8, 3, 8, 8))], [("softmax_label", (8,))])
+    mod.init_params()
+    batch = mx.io.DataBatch(data=[mx.nd.ones((8, 3, 8, 8))],
+                            label=[mx.nd.zeros((8,))])
+    with jax.disable_jit():
+        mod.forward(batch, is_train=False)
+        mod.get_outputs()[0].asnumpy()
+    assert _streams("jit.node_trace_seconds") == []
+    assert _streams("jit.trace_seconds") == []
+    mod.forward(batch, is_train=False)
+    assert sum(s["count"]
+               for s in _streams("jit.node_trace_seconds")) == len(
+        TOY_CLASSES)
+    # no fused step was traced: no part
+    assert _streams("jit.trace_seconds") == []
+
+
+def test_cost_capture_keeps_its_own_parts(traced_fit):
+    """Should telemetry's second lowering ever trace the body again, the
+    seconds are its own and no root's."""
+    assert _parts("telemetry.cost_capture") == {}
+    assert _nodes("telemetry.cost_capture") == {}
+    before = _parts()
+
+    def body(x):
+        with tm_setup.trace_part("forward"):
+            return x * 3 + 1
+
+    with tm.span("fit.step"), tm.span("telemetry.cost_capture"):
+        jax.jit(body).lower(np.ones((5,), "f"))
+    assert set(_parts("telemetry.cost_capture")) == {"forward"}
+    assert _parts() == before
+
+
+def test_a_part_is_its_clock_less_the_other_phases(monkeypatch):
+    """A part holds what jit.seconds{phase=trace} holds of its interval:
+    a constant compiled or loaded while tracing and an eager op lowered
+    are other streams' seconds, a nested trace is the part's own work;
+    parts nest, and an inner part's seconds are not the outer's."""
+    tm.enable()
+    now = [100.0]
+    monkeypatch.setattr(tm_setup, "time", type("clock", (), {
+        "perf_counter": staticmethod(lambda: now[0])}))
+    begin, done = tm_setup._on_phase_begin, tm_setup._on_duration
+    trace, lower, compile_ = (
+        "/jax/core/compile/jaxpr_trace_duration",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration",
+        "/jax/core/compile/backend_compile_duration")
+    assert not tm_setup.tracing()
+    with tm.span("fit.step"):
+        begin(trace, 0.0, fun_name="step")
+        assert tm_setup.tracing()
+        now[0] += 0.125                    # the body's rest
+        with tm_setup.trace_part("backward"):
+            now[0] += 1.0
+            with tm_setup.trace_part("forward"):
+                begin(trace, 0.0, fun_name="inner")
+                now[0] += 0.25
+                done(trace, 0.25, fun_name="inner")
+                begin(lower, 0.0, fun_name="jit(const)")
+                now[0] += 0.5
+                done(lower, 0.5, fun_name="jit(const)")
+                begin(compile_, 0.0, fun_name="jit(const)")
+                now[0] += 0.75
+                done("/jax/compilation_cache/cache_retrieval_time_sec",
+                     0.5)
+                done(compile_, 0.75, fun_name="jit(const)")
+                now[0] += 2.0
+        now[0] += 0.5                      # jax closes the jaxpr
+        done(trace, 5.125, fun_name="step")
+    assert not tm_setup.tracing()
+    assert _parts() == {"forward": 2.25, "backward": 1.0}
+    traced = sum(s["value"] for s in _streams("jit.seconds")
+                 if s["labels"]["phase"] == "trace")
+    assert traced == 5.125 - 0.5 - 0.75
+    assert traced - sum(_parts().values()) == 0.125 + 0.5
+
+
+def test_parts_and_nodes_cost_a_flag_test_when_off():
+    assert tm_setup.trace_part("forward") is tm.NULL_SPAN
+    assert not tm_setup.tracing()
+    tm_setup._on_phase_begin("/jax/core/compile/jaxpr_trace_duration", 0.0,
+                             fun_name="step")
+    assert not tm_setup.tracing()
 
 
 def test_h2d_bytes_of_a_bind_are_its_arrays():

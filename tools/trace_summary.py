@@ -7,7 +7,10 @@ Reads either artifact the framework's observability stack produces —
   by name;
 * a telemetry JSONL stream (``MXTPU_TELEMETRY_FILE`` /
   ``telemetry.enable(jsonl=...)``) — ``span`` lines are grouped by
-  name, and the LAST ``metrics`` snapshot is rendered below the table.
+  name, and the LAST ``metrics`` snapshot is rendered below the table,
+  with one table "first dispatch" where it holds a traced step: the
+  trace's host seconds by part of the step, by op class and by jitted
+  function.
 
 For each span/event name: count, total ms, mean ms, and share of wall
 time (first start to last end). Usage::
@@ -195,6 +198,70 @@ def _format_bucket_hist(metrics):
     return "\n".join(lines) if len(lines) > 2 else None
 
 
+# parts of the fused step's body in the order it runs them
+# (telemetry/setup.py: jit.trace_seconds)
+TRACE_PARTS = ("forward", "backward", "update")
+
+
+def format_first_dispatch(metrics, top=10):
+    """Where the first dispatch's host seconds went, from one snapshot:
+    the step's trace by part of its body (``jit.trace_seconds``), the
+    forward by op class (``jit.node_trace_seconds``: seconds, nodes, ms
+    a node), and the heaviest jitted functions by trace seconds
+    (``jit.seconds{phase="trace", fun}``). None where no step was
+    traced while telemetry was on."""
+    def streams(name):
+        return (metrics or {}).get(name, {}).get("streams", [])
+
+    parts, roots = {}, set()
+    for st in streams("jit.trace_seconds"):
+        part = st["labels"].get("part", "?")
+        parts[part] = parts.get(part, 0.0) + st.get("value", 0.0)
+        roots.add(st["labels"].get("under"))
+    if not parts:
+        return None
+    phases, funs, rest = {}, {}, -sum(parts.values())
+    for st in streams("jit.seconds"):
+        phase = st["labels"].get("phase", "?")
+        phases[phase] = phases.get(phase, 0.0) + st.get("value", 0.0)
+        if phase == "trace":
+            fun = st["labels"].get("fun", "?")
+            funs[fun] = funs.get(fun, 0.0) + st.get("value", 0.0)
+            if st["labels"].get("under") in roots:
+                rest += st.get("value", 0.0)
+    out = ["", "first dispatch (host seconds while jax traced):",
+           "  %-28s %10s" % ("part of the step", "seconds")]
+    for part in TRACE_PARTS + tuple(sorted(set(parts) - set(TRACE_PARTS))):
+        if part in parts:
+            out.append("  %-28s %10.3f" % (part, parts[part]))
+    # the body outside the three and jax's own work round it: the
+    # trace under the roots that traced a step, less the parts
+    out.append("  %-28s %10.3f" % ("rest", rest))
+    out.append("  %-28s %10.3f  (every root; lower %.3f)" % (
+        "jit.seconds trace, all", phases.get("trace", 0.0),
+        phases.get("lower", 0.0)))
+    classes = {}
+    for st in streams("jit.node_trace_seconds"):
+        cls = st["labels"].get("class", "?")
+        tot, cnt = classes.get(cls, (0.0, 0))
+        classes[cls] = (tot + st.get("sum", 0.0), cnt + st.get("count", 0))
+    if classes:
+        out.append("  %-28s %10s %6s %9s" % (
+            "by op class (forward)", "seconds", "nodes", "ms/node"))
+        rows = sorted(classes.items(), key=lambda kv: -kv[1][0])
+        rows.append(("all", (sum(t for t, _ in classes.values()),
+                             sum(c for _, c in classes.values()))))
+        for cls, (tot, cnt) in rows:
+            out.append("  %-28s %10.3f %6d %9.2f" % (
+                cls, tot, cnt, 1e3 * tot / cnt if cnt else 0.0))
+    if funs:
+        out.append("  %-28s %10s" % ("traced function (top %d)" % top,
+                                     "seconds"))
+        for fun, tot in sorted(funs.items(), key=lambda kv: -kv[1])[:top]:
+            out.append("  %-28s %10.3f" % (fun[:28], tot))
+    return "\n".join(out)
+
+
 # phase columns of an anatomy record, in fit-loop order (matches
 # telemetry/anatomy.py _PHASES)
 ANATOMY_PHASES = ("input_wait", "stage_host", "dispatch_host",
@@ -265,6 +332,9 @@ def summarize(path, top=0):
     bucket = _format_bucket_hist(metrics)
     if bucket:
         text += "\n" + bucket
+    first = format_first_dispatch(metrics)
+    if first:
+        text += "\n" + first
     if metrics:
         text += "\n" + format_metrics(metrics)
     return text
@@ -454,6 +524,41 @@ def _self_test():
     assert "collectives:" in text and "mesh.all_gather" in text, text
     assert "gradient buckets" in text and "mean bucket 2.0 KiB" in text, \
         text
+    assert "first dispatch" not in text, text  # no step was traced
+
+    # the first dispatch's table from the set-up streams
+    def _c(value, **labels):
+        return {"labels": labels, "value": value}
+
+    def _h(total, count, **labels):
+        return {"labels": labels, "sum": total, "count": count}
+
+    first = format_first_dispatch({
+        "jit.trace_seconds": {"kind": "counter", "streams": [
+            _c(3.0, part="forward", under="fit.step"),
+            _c(2.0, part="backward", under="fit.step"),
+            _c(0.5, part="update", under="fit.step")]},
+        "jit.node_trace_seconds": {"kind": "histogram", "streams": [
+            _h(2.0, 4, **{"class": "attn", "under": "fit.step"}),
+            _h(0.5, 50, **{"class": "fc", "under": "fit.step"})]},
+        "jit.seconds": {"kind": "counter", "streams": [
+            _c(4.5, phase="trace", fun="step", under="fit.step"),
+            _c(1.5, phase="trace", fun="gmm_call", under="fit.step"),
+            _c(0.25, phase="trace", fun="multiply",
+               under="module.init_params"),
+            _c(1.25, phase="lower", fun="step", under="fit.step"),
+            _c(9.0, phase="compile", fun="step", under="fit.step")]},
+    }, top=2).splitlines()
+    got = {ln.split()[0]: ln.split()[1:] for ln in first[3:]}
+    # under fit.step 6.0 s of trace less the three parts' 5.5
+    assert got["forward"] == ["3.000"] and got["rest"] == ["0.500"], first
+    assert got["attn"] == ["2.000", "4", "500.00"], first
+    assert got["fc"] == ["0.500", "50", "10.00"], first
+    assert got["all"] == ["2.500", "54", "46.30"], first
+    assert got["step"] == ["4.500"] and got["gmm_call"] == ["1.500"], first
+    assert "multiply" not in got, first      # top=2
+    assert any("6.250" in ln and "lower 1.250" in ln for ln in first), first
+    assert format_first_dispatch({}) is None
 
     # anatomy intervals: appended to the same JSONL; the span/metrics
     # readers must keep ignoring them and --anatomy must render them

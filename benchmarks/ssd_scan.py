@@ -27,8 +27,19 @@ the share of the bytes bound (``x``, ``B``, ``C``, ``dt`` in and ``y`` out
 once forward, three times that for forward + backward, over 819 GB/s).
 ``--shapes-only`` prints that table alone.
 
-    chiprun -- python3 benchmarks/ssd_scan.py [--shapes-only]
+``--gate-norm`` prints the table of what follows the scan instead (the
+gate and the grouped RMSNorm, ``ops/kernels/gate_norm.py``, PR 52): at
+each of ``GATE_NORM_SHAPES`` (the Nemotron, Falcon-H1 and Olmo-Hybrid
+cells') the ``jax.numpy`` form alone under its ``jax.checkpoint`` (with
+the transposing copy of a head-major ``o``), the kernel pair at the tiles
+``gate_norm_tiles`` picks and at every other (row tile, column tile) that
+fits, device ms forward and backward from a trace, GB/s over the bytes the
+op must move, and how far the pair's results are from the form's on the
+chip. Writes ``chiprun_out/gate_norm_table.json``.
+
+    chiprun -- python3 benchmarks/ssd_scan.py [--shapes-only | --gate-norm]
 """
+import functools
 import json
 import os
 import sys
@@ -63,9 +74,10 @@ def _time(f, *args, reps=20):
     return (time.perf_counter() - t0) / reps * 1e3
 
 
-def _kernel_device_ms(g, *args, reps=10):
-    """Device ms a call of each ``ssd_`` kernel and of everything in the
-    program, from a profiler trace of ``reps`` calls."""
+def _kernel_device_ms(g, *args, reps=10, prefix="ssd_"):
+    """Device ms a call of each kernel named ``prefix``... and of
+    everything else in the program, from a profiler trace of ``reps``
+    calls."""
     import collections
     import glob
     import tempfile
@@ -88,7 +100,7 @@ def _kernel_device_ms(g, *args, reps=10):
                 continue
             for e in line.events:
                 name = e.name.split(" = ")[0].lstrip("%")
-                ms[name.split(".")[0] if name.startswith("ssd_")
+                ms[name.split(".")[0] if name.startswith(prefix)
                    else "everything else"] += e.duration_ns / 1e6 / reps
     return dict(ms)
 
@@ -160,6 +172,113 @@ def pair_by_shape(row):
             kernels=sorted(k for k in ms if k.startswith("ssd_")))
 
 
+# form, groups, group width, T, the gate's array's width, the gate's scale
+GATE_NORM_SHAPES = {
+    "nemotron3_nano_fit_share_8k": ("gate_first", 8, 512, 8192, 10304, None),
+    "falcon_h1_fit_share_4k": ("gate_first", 1, 2048, 4096, 4624, 0.7),
+    "olmo_hybrid_fit_stage_4k": ("norm_first", 30, 192, 4096, 5760, None)}
+
+
+def gate_norm_table(row, rows_tiles=(128, 256, 512, 1024, 2048)):
+    """The gate and norm alone at each of ``GATE_NORM_SHAPES``, bf16."""
+    from mxnet_tpu.ops.kernels import gate_norm as gn
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+
+    def gbs(mb, ms):
+        return mb / ms if ms else None
+
+    def each_way(f):
+        # the result and the three cotangents, the result's cotangent an
+        # operand: a loss summed here would fuse into the form
+        def both(y, src, gamma, cot):
+            out, back = jax.vjp(f, y, src, gamma)
+            return (out,) + back(cot)
+        return jax.jit(both)
+
+    def fwd_bwd(ms):
+        return tuple(sum(v for k, v in ms.items()
+                         if k.startswith("gate_norm_" + which))
+                     for which in ("fwd", "bwd"))
+
+    for cell, (form, groups, width, t, src_width, scale) in (
+            GATE_NORM_SHAPES.items()):
+        columns = groups * width
+        rng = np.random.RandomState(3)
+        y = jnp.asarray(rng.randn(*(
+            (B, t, columns) if form == "gate_first"
+            else (B, groups, t, width))), f32)
+        src = jnp.asarray(rng.randn(B, t, src_width), bf16)
+        gamma = jnp.asarray(
+            1 + 0.1 * rng.randn(columns if form == "gate_first" else width),
+            bf16)
+        cot = jnp.asarray(rng.randn(B, t, columns), bf16)
+        static = dict(form=form, width=width, eps=1e-5, scale=scale, offset=0)
+        # what the op must move: y and the gate in, the result out; then
+        # those two and the cotangent in, two cotangents out
+        fwd_mb = t * columns * (4 + 2 + 2) / 1e6
+        bwd_mb = t * columns * (4 + 2 + 2 + 4 + 2) / 1e6
+
+        plain = jax.checkpoint(functools.partial(gn.plain_form, **static))
+        plain_both = each_way(plain)
+        ms_fwd = _kernel_device_ms(jax.jit(plain), y, src, gamma,
+                                   prefix="gate_norm_")["everything else"]
+        ms_both = _kernel_device_ms(plain_both, y, src, gamma, cot,
+                                    prefix="gate_norm_")["everything else"]
+        row(gate_norm=cell, impl="jnp", form=form, groups=groups, width=width,
+            t=t, fwd_ms=ms_fwd, fwd_bwd_ms=ms_both,
+            bytes_bound_fwd_ms=fwd_mb / PEAK_GBS,
+            bytes_bound_fwd_bwd_ms=(fwd_mb + bwd_mb) / PEAK_GBS,
+            fwd_gbs=gbs(fwd_mb, ms_fwd),
+            fwd_bwd_gbs=gbs(fwd_mb + bwd_mb, ms_both))
+        # the pair through its entry, as the block calls it
+        entry = functools.partial(
+            pk.gated_rms_norm, form=form, eps=1e-5, groups=groups,
+            scale=scale)
+        chosen = gn.gate_norm_tiles(form, groups, width, t, bf16, 0,
+                                    src_width)
+        entry_both = each_way(entry)
+        ms = _kernel_device_ms(entry_both, y, src, gamma, cot,
+                               prefix="gate_norm_")
+        fwd, bwd = fwd_bwd(ms)
+        far = {name: float(
+            jnp.abs(g.astype(f32) - w.astype(f32)).max()
+            / jnp.abs(w.astype(f32)).max())
+            for name, g, w in zip(
+                ("out", "dy", "dsrc", "dgamma"),
+                entry_both(y, src, gamma, cot),
+                plain_both(y, src, gamma, cot))}
+        row(gate_norm=cell, impl="kernel", tiles=chosen, fwd_ms=fwd,
+            bwd_ms=bwd, rest_ms=ms.get("everything else"),
+            fwd_gbs=gbs(fwd_mb, fwd), bwd_gbs=gbs(bwd_mb, bwd),
+            share_of_bytes_bound=100 * (fwd_mb + bwd_mb) / PEAK_GBS
+            / (fwd + bwd), far_from_jnp=far,
+            kernels=sorted(k for k in ms if k.startswith("gate_norm_")))
+        # every other tile that fits, the two calls alone
+        tiles = [(r, c) for r in rows_tiles if t % r == 0
+                 for c in ([n * width for n in (1, 2, 4, 8)
+                            if groups % n == 0] if form == "gate_first"
+                           else [chosen[1]])
+                 if gn.gate_norm_vmem_bytes(r, c, width, 2, form)
+                 <= pk.common.VMEM_RAISED_LIMIT]
+        for tile in tiles:
+            def both(y, src, gamma_row, cot, tile=tile):
+                kw = dict(tiles=tile, interpret=False, **static)
+                return (gn.gate_norm_fwd_call(y, src, gamma_row, **kw),
+                        gn.gate_norm_bwd_call(y, src, gamma_row, cot, **kw))
+            try:
+                ms = _kernel_device_ms(
+                    jax.jit(both), y, src,
+                    gn._gamma_row(gamma, bf16, form, tile), cot,
+                    prefix="gate_norm_")
+            except Exception as e:  # noqa: BLE001 — Mosaic refused the tile
+                row(gate_norm=cell, tiles=tile, refused=str(e)[:300])
+                continue
+            fwd, bwd = fwd_bwd(ms)
+            row(gate_norm=cell, tiles=tile, fwd_ms=fwd, bwd_ms=bwd,
+                fwd_gbs=gbs(fwd_mb, fwd), bwd_gbs=gbs(bwd_mb, bwd))
+
+
 def main():
     dev = jax.devices()[0]
     res = {"device": str(dev.device_kind), "platform": dev.platform,
@@ -170,14 +289,17 @@ def main():
         print(json.dumps(kw), flush=True)
         res["rows"].append(kw)
 
-    def save():
+    def save(name="ssd_scan_table.json"):
         os.makedirs("chiprun_out", exist_ok=True)
-        with open("chiprun_out/ssd_scan_table.json", "w") as f:
+        with open("chiprun_out/" + name, "w") as f:
             json.dump(res, f, indent=1)
 
     if "--shapes-only" in sys.argv:
         pair_by_shape(row)
         return save()
+    if "--gate-norm" in sys.argv:
+        gate_norm_table(row)
+        return save("gate_norm_table.json")
     both = forms()
     # how far apart the two forms are, on the chip
     for dtype, t in ((jnp.bfloat16, T), (jnp.float32, 1024)):

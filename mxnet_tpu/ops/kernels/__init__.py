@@ -16,6 +16,8 @@ because they need explicit on-chip (VMEM) accumulation patterns.
   gdn          the gated delta rule's chunk core
   taps         the causal taps: the short depthwise convolution over time
                of Mamba2, GatedDeltaNet and ShortConv, with its epilogue
+  gate_norm    the gate and the grouped RMSNorm behind the scan (Mamba2)
+               and the delta rule (GatedDeltaNet), one pass each way
   slab_update  the AMP optimizer step over a flat slab
   conv         the conv-backward pair
   common       what they share
@@ -39,6 +41,7 @@ from .conv import (
     conv_bwd_filter, conv_bwd_input, conv_bwd_plan, conv_kernel_enabled)
 from .flash import (
     attention, flash_attention, flash_tiles, reference_attention)
+from .gate_norm import gate_norm_takes, gated_rms_norm
 from .gdn import gated_delta_rule, gdn_takes
 from .gmm import (
     gmm_metadata, gmm_row_tile, gmm_runs_kernel, gmm_tiles, grouped_matmul,
@@ -53,7 +56,8 @@ from .taps import causal_conv, taps_takes
 __all__ = [
     "attention", "causal_conv", "common", "conv_bwd_filter", "conv_bwd_input",
     "conv_bwd_plan", "conv_kernel_enabled", "flash_attention",
-    "flash_tiles", "fused_slab_update", "gated_delta_rule", "gdn_takes",
+    "flash_tiles", "fused_slab_update", "gate_norm_takes",
+    "gated_delta_rule", "gated_rms_norm", "gdn_takes",
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
     "grouped_matmul", "latent_flash", "latent_flash_takes", "latent_query",
     "latent_query_takes", "reference_attention", "SLAB_STATE_SLOTS",

@@ -522,7 +522,8 @@ def _gdn_bwd(chunk, interpret, res, do):
 _gdn.defvjp(_gdn_fwd, _gdn_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk, interpret=False):
+def gated_delta_rule(q, k, v, g, beta, chunk, interpret=False,
+                     head_major=False):
     """``ops/transformer.py::gated_delta_rule`` (q and k [B, T, H, K], v
     [B, T, H, V] in one type, g and beta [B, T, H] -> o [B, T, H, V]
     float32) as a Pallas kernel pair, differentiable in all five, for the
@@ -535,7 +536,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk, interpret=False):
     makes it; ``interpret=True`` (the kernels' tests) runs the kernels
     through the Pallas interpreter wherever the computation is lowered.
     No partitioning rule: inside a sharded ``jit``, call under
-    ``shard_map``."""
+    ``shard_map``. ``head_major`` (T whole chunks): o as the kernel wrote
+    it, [B, H, T, V], for a reader that takes it so (``gated_rms_norm``):
+    no move behind the forward and none in front of the backward."""
     t = q.shape[1]
     pad = -t % chunk
     if pad:
@@ -545,4 +548,9 @@ def gated_delta_rule(q, k, v, g, beta, chunk, interpret=False):
     f32 = jnp.float32
     o = _gdn(*(jnp.moveaxis(x, 1, 2) for x in (q, k, v)), g.astype(f32),
              beta.astype(f32), int(chunk), bool(interpret))
+    if head_major:
+        if pad:
+            raise ValueError("gated_delta_rule: head_major with %d tokens "
+                             "in chunks of %d" % (t, chunk))
+        return o
     return jnp.moveaxis(o, 1, 2)[:, :t]

@@ -423,13 +423,16 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     scan is the Pallas kernel pair where the shapes have tiles for it and
     the step is lowered for the TPU (``kernels.ssd_takes`` /
     ``ssd_scan``; the skip inside it), the ``jnp.einsum`` form elsewhere.
-    ``remat`` (training): the float32 tables of the convolution, of the
-    gate and norm and of the einsum form are computed again in the
-    backward pass, not kept (``jax.checkpoint`` round each; plain
-    autodiff kept 9.6 GB of them at the Nemotron cell's shape); the
-    scan's kernel pair keeps its own residuals (its output and the
-    states) and the taps' its inputs (the backward kernel computes the
-    sum again in VMEM), and each runs once each way.
+    The gate and norm are one Pallas kernel each way where
+    ``kernels.gate_norm_takes`` has tiles (``gated_rms_norm``,
+    ``gate_first``), the ``gate_norm`` closure below elsewhere.
+    ``remat`` (training): the float32 tables of the ``jax.numpy`` forms
+    are computed again in the backward pass, not kept (``jax.checkpoint``
+    round each; plain autodiff kept 9.6 GB of them at the Nemotron
+    cell's shape); the scan's kernel pair keeps its own residuals (its
+    output and the states), the taps' and the gate and norm's their
+    inputs (the backward kernel computes the sums again in VMEM), and
+    each runs once each way.
 
     ``multipliers`` (Falcon-H1's ``ssm_multipliers``, with whatever
     scalar the projection's input carried folded in): five fixed scalars
@@ -441,8 +444,8 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     ``z``'s sits inside the gate's ``silu`` and ``dt``'s in front of
     ``dt_bias``, each float32.
 
-    The call site counts itself here (``ssm.scan_lowerings``, and
-    ``causal_taps.lowerings`` for the convolution); the block
+    The call site counts itself here (``ssm.scan_lowerings``,
+    ``causal_taps.lowerings`` and ``gate_norm.lowerings``); the block
     itself is ``_mamba2_block``, one ``jax.jit`` for every node of one
     signature: a model's layers trace, differentiate and lower it once
     (XLA inlines the calls, each under its own node's scope)."""
@@ -453,6 +456,8 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     d_in = num_heads * head_dim
     taps_kernel = _taps_site("mamba2", proj, conv_weight, "bias_silu",
                              offset=d_in)
+    norm_kernel = _gate_norm_site("mamba2", "gate_first", num_groups,
+                                  d_in // num_groups, proj)
     if multipliers is not None:
         multipliers = tuple(float(m) for m in multipliers)
         if len(multipliers) != 5:
@@ -469,15 +474,15 @@ def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
         sizes=(num_heads, head_dim, state_size, num_groups, chunk_size),
         eps=float(eps), remat=bool(remat), kernel=kernel,
         taps_kernel=taps_kernel, interpret=kernels.common.INTERPRET,
-        multipliers=multipliers)
+        multipliers=multipliers, norm_kernel=norm_kernel)
 
 
-@functools.partial(jax.jit, static_argnames=("sizes", "eps", "remat",
-                                             "kernel", "taps_kernel",
-                                             "interpret", "multipliers"))
+@functools.partial(jax.jit, static_argnames=(
+    "sizes", "eps", "remat", "kernel", "taps_kernel", "interpret",
+    "multipliers", "norm_kernel"))
 def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
                   norm_gamma, *, sizes, eps, remat, kernel, taps_kernel,
-                  interpret, multipliers=None):
+                  interpret, multipliers=None, norm_kernel=False):
     """``mamba2`` for one signature (``sizes``: heads, head width, state,
     groups, chunk)."""
     from . import kernels
@@ -534,6 +539,10 @@ def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
                           x, *bc, dt, a)
             y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
     with jax.named_scope("gate_norm"):
+        if norm_kernel:
+            return kernels.gated_rms_norm(
+                y.reshape(b, t, d_in), proj, norm_gamma, form="gate_first",
+                groups=g, eps=eps, scale=m_z, interpret=interpret)
         return again(gate_norm)(y, proj, norm_gamma)
 
 
@@ -811,10 +820,13 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     the unit norms, ``beta`` and ``g`` only: the kernel pair keeps its
     own residuals (the state each chunk entered with and its system's
     inverse) and runs once each way, as the taps' pair does on its
-    inputs.
+    inputs. Where ``kernels.gate_norm_takes`` has tiles the gate and
+    norm are ``gated_rms_norm`` (``norm_first``), one kernel each way on
+    ``o`` head-major as the rule's kernel wrote it: no move between them.
 
-    The call site counts itself here (``linear_attn.lowerings``, and
-    ``causal_taps.lowerings`` once a convolved array); the
+    The call site counts itself here (``linear_attn.lowerings``,
+    ``gate_norm.lowerings``, ``causal_taps.lowerings`` once a convolved
+    array); the
     block itself is ``_gated_delta_block``, one ``jax.jit`` for every node
     of one signature."""
     from . import kernels
@@ -829,21 +841,25 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     taps_kernel = tuple(
         _taps_site("gated_delta_net", x, conv_weight, "silu",
                    channels=x.shape[2]) for x in (query, key, value))
+    # the norm's kernel reads o where the rule's kernel left it, head-major
+    norm_kernel = _gate_norm_site(
+        "gated_delta_net", "norm_first", num_heads, value_dim, gate,
+        core=kernel and query.shape[1] % chunk_size == 0)
     return _gated_delta_block(
         query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
         norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
         eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
         remat=bool(remat), kernel=kernel, taps_kernel=taps_kernel,
-        interpret=kernels.common.INTERPRET)
+        interpret=kernels.common.INTERPRET, norm_kernel=norm_kernel)
 
 
-@functools.partial(jax.jit, static_argnames=("heads", "chunk", "eps",
-                                             "beta_scale", "remat",
-                                             "kernel", "taps_kernel",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "chunk", "eps", "beta_scale", "remat", "kernel", "taps_kernel",
+    "interpret", "norm_kernel"))
 def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                        dt_bias, norm_gamma, *, heads, chunk, eps,
-                       beta_scale, remat, kernel, taps_kernel, interpret):
+                       beta_scale, remat, kernel, taps_kernel, interpret,
+                       norm_kernel=False):
     """``gated_delta_net`` for one signature."""
     from . import kernels
 
@@ -896,10 +912,14 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                                                       dt_bias)
             o = kernels.gated_delta_rule(
                 q, k, v.reshape(bsz, t, heads, dv), g, beta, chunk,
-                interpret=interpret)
+                interpret=interpret, head_major=norm_kernel)
         else:
             o = again(delta_rule)(q, k, v, a, b, a_log, dt_bias)
     with jax.named_scope("gate_norm"):
+        if norm_kernel:
+            return kernels.gated_rms_norm(o, gate, norm_gamma, eps=eps,
+                                          form="norm_first",
+                                          interpret=interpret)
         return again(gate_norm)(o, gate, norm_gamma)
 
 
@@ -1195,6 +1215,29 @@ def _taps_site(site, src, conv_weight, form, offset=0, channels=None):
                                 form, offset, src.shape[2])
     _M_TAPS_LOWERINGS.inc(site=site, channels=channels, taps=taps,
                           impl="kernel" if kernel else "jnp")
+    return kernel
+
+
+_M_GATE_NORM_LOWERINGS = _tm.counter(
+    "gate_norm.lowerings", "Traces of the gate and grouped RMSNorm of a "
+    "Mamba2 or GatedDeltaNet call site (one per node and lowering, nothing "
+    "per step); labels: site, groups, width, impl (kernel: the Pallas pair "
+    "of ops/kernels/gate_norm.py where the step is lowered for the TPU, the "
+    "jax.numpy form of the same signature elsewhere; jnp: the block's "
+    "gate_norm closure everywhere)")
+
+
+def _gate_norm_site(site, form, groups, width, src, core=True):
+    """Whether ``kernels.gated_rms_norm`` takes ``groups`` groups of
+    ``width`` columns gated by the first of src's (``core``: whether what
+    feeds it is laid out as the kernels read it); the call site counts
+    itself here, outside its block's ``jax.jit``."""
+    from . import kernels
+
+    kernel = bool(core) and kernels.gate_norm_takes(
+        form, groups, width, src.shape[1], src.dtype, 0, src.shape[2])
+    _M_GATE_NORM_LOWERINGS.inc(site=site, groups=groups, width=width,
+                               impl="kernel" if kernel else "jnp")
     return kernel
 
 

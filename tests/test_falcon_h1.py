@@ -567,6 +567,10 @@ def test_the_blocks_count_themselves_where_they_are_traced():
         taps = telemetry.REGISTRY.get("causal_taps.lowerings")
         assert taps.value(site="mamba2", channels=HEADS * P + 2 * N,
                           taps=TAPS, impl="kernel") == 2
+        norm = telemetry.REGISTRY.get("gate_norm.lowerings")
+        assert norm.value(site="mamba2", groups=1, width=HEADS * P,
+                          impl="kernel") == 2
+        assert telemetry.total("gate_norm.lowerings") == 2
         blocks = telemetry.REGISTRY.get("lm.parallel_blocks")
         assert blocks.value(mixers="mamba2+attention", count=2) == 2
         assert telemetry.total("lm.parallel_blocks") == 2

@@ -553,3 +553,92 @@ def test_causal_taps_kernels_compile_for_v5e(one_chip, site):
         assert limit <= pk.common.VMEM_RAISED_LIMIT, (which, limit)
         operand = re.search(r"custom-call\(%([\w.\-]+)", calls[0]).group(1)
         assert not operand.startswith(("copy.", "slice")), (which, operand)
+
+
+# the gate and grouped norm behind the three state-space cells' cores, as
+# the blocks call it: Nemotron's Mamba-2 (8 groups of 512 columns, the gate
+# the first 4,096 of an in_proj output 10,304 wide), the Falcon-H1 share's
+# (ONE group of 2,048 under the gate's multiplier, in_proj 4,624 wide) and
+# Olmo-Hybrid's delta net (30 heads of 192, 1.5 lane rows: two a block,
+# ``o`` head-major from the rule's kernel)
+GATE_NORM_BLOCKS = {
+    "nemotron_mamba2": ("gate_first", 8192, 256, 512,
+                        dict(heads=64, p=64, n=128, groups=8)),
+    "falcon_h1_mamba2": ("gate_first", 4096, 256, 2048,
+                         dict(heads=16, p=128, n=256, groups=1,
+                              multipliers=(0.7, 1.1, 0.9, 1.2, 0.8))),
+    "olmo_hybrid_delta_net": ("norm_first", 4096, 1024, 192,
+                              dict(heads=30, dk=96, dv=192)),
+}
+
+
+@pytest.mark.parametrize("site", sorted(GATE_NORM_BLOCKS))
+def test_gate_norm_kernels_compile_inside_their_blocks_for_v5e(one_chip,
+                                                               site):
+    """A block's value and gradients at the cell's shape: Mosaic takes
+    both bodies (the paired heads' lane shifts and masked sums among
+    them), each runs once, what each call reads is what the projection or
+    the core's kernel wrote (no copy, slice or transpose in front of
+    either, and ``do`` goes to the rule's backward as written), and a
+    step's blocks fit the scoped VMEM the calls state."""
+    from mxnet_tpu.ops import transformer as tr
+
+    form, t, rows, width, dims = GATE_NORM_BLOCKS[site]
+    f32 = jnp.float32
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    if form == "gate_first":
+        h, p, n, g = (dims[k] for k in ("heads", "p", "n", "groups"))
+        conv = h * p + 2 * g * n
+        ins = [spec(1, t, 2 * h * p + 2 * g * n + h), spec(4, conv),
+               spec(conv), spec(h), spec(h), spec(h), spec(h * p)]
+
+        def op(proj, *rest):
+            # the operand an elementwise neighbour's output, as a
+            # projection's is in a step
+            return tr.mamba2(proj * 2, *rest, h, p, n, g, 128, 1e-5,
+                             remat=True,
+                             multipliers=dims.get("multipliers"))
+    else:
+        h, dk, dv = (dims[k] for k in ("heads", "dk", "dv"))
+        ins = [spec(1, t, h * dk), spec(1, t, h * dk), spec(1, t, h * dv),
+               spec(1, t, h * dv), spec(1, t, h), spec(1, t, h),
+               spec(4, 2 * h * dk + h * dv), spec(h), spec(h), spec(dv)]
+
+        def op(q, k, v, gate, *rest):
+            return tr.gated_delta_net(q * 2, k * 2, v * 2, gate * 2, *rest,
+                                      h, 64, 1e-6, remat=True)
+
+    tr._mamba2_block.clear_cache()
+    tr._gated_delta_block.clear_cache()
+    text = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(op(*a).astype(f32)),
+        argnums=tuple(range(len(ins))))).lower(*ins).compile().as_text()
+    made_by = {m.group(1): m.group(2) for m in re.finditer(
+        r"%([\w.\-]+) = \S+ ([\w\-]+)\(", text)}
+    core = {"gate_first": "ssd_", "norm_first": "gdn_"}[form]
+    for which in ("fwd", "bwd"):
+        name = "gate_norm_%s_bf16_r%d_g%d_%s" % (which, rows, width, form)
+        calls = [line for line in text.splitlines()
+                 if name in line and "custom-call(" in line]
+        assert len(calls) == 1, (name, len(calls))
+        limit = int(re.search(
+            r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",'
+            r'"size":"(\d+)"', calls[0]).group(1))
+        assert limit <= pk.common.VMEM_RAISED_LIMIT, (name, limit)
+        operands = re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
+        kinds = [made_by.get(o.strip().lstrip("%"))
+                 for o in operands.split(",")]
+        # (a ``copy-done`` is XLA's prefetch of an operand into VMEM, not
+        # a pass that writes HBM)
+        assert not {"copy", "slice", "transpose"} & set(kinds), (name, kinds)
+        # the core's output straight from its kernel's tuple
+        assert kinds[0] == "get-tuple-element", (name, kinds)
+    # the core's backward reads the cotangent where this one wrote it
+    bwd = [line for line in text.splitlines()
+           if core + "bwd_" in line and "custom-call(" in line]
+    assert len(bwd) == 1
+    last = re.search(r"custom-call\(([^)]*)\)", bwd[0]).group(1).split(",")[-1]
+    assert made_by[last.strip().lstrip("%")] == "get-tuple-element"

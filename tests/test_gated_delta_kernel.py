@@ -319,7 +319,7 @@ def test_off_the_tpu_the_ops_values_are_the_chunk_forms_bit_for_bit(remat):
     *ins, cot = _op_inputs(1)
     tr._gated_delta_block.clear_cache()
     kw = dict(heads=H, chunk=CHUNK, eps=1e-6, beta_scale=2.0, remat=remat,
-              taps_kernel=(False,) * 3)
+              taps_kernel=(False,) * 3, norm_kernel=False)
 
     def grads(kernel):
         def loss(*a):
@@ -422,6 +422,11 @@ def test_a_fit_of_three_linear_layers_traces_each_kernel_once():
         assert telemetry.total("linear_attn.lowerings") == 3
         traces = telemetry.REGISTRY.get("linear_attn.kernel_traces")
         assert (traces.value(mode="fwd"), traces.value(mode="bwd")) == (1, 1)
+        # 32 tokens: no row tile of the gate and norm's kernel divides them
+        norm = telemetry.REGISTRY.get("gate_norm.lowerings")
+        assert norm.value(site="gated_delta_net", groups=2, width=32,
+                          impl="jnp") == 3
+        assert telemetry.total("gate_norm.lowerings") == 3
     finally:
         telemetry.disable()
         telemetry.reset()

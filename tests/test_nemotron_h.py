@@ -351,6 +351,11 @@ def test_three_blocks_of_one_shape_trace_the_block_and_the_kernels_once():
         assert telemetry.total("ssm.scan_lowerings") == 3
         traces = telemetry.REGISTRY.get("ssm.scan_kernel_traces")
         assert (traces.value(mode="fwd"), traces.value(mode="bwd")) == (1, 1)
+        # the gate and norm behind each scan: a node and lowering, not a step
+        norm = telemetry.REGISTRY.get("gate_norm.lowerings")
+        assert norm.value(site="mamba2", groups=1, width=heads * p,
+                          impl="kernel") == 3
+        assert telemetry.total("gate_norm.lowerings") == 3
     finally:
         telemetry.disable()
         telemetry.reset()

@@ -210,8 +210,14 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
         count = telemetry.REGISTRY.get("linear_attn.lowerings")
         assert count.value(heads=30, key_dim=8, value_dim=16, chunk=64,
                            conv=4, impl="chunked") == LINEAR_LAYERS
+        # the gate and norm behind each rule: heads of 16 columns are no
+        # lane rows, so the ``jax.numpy`` closure everywhere
+        norm = telemetry.REGISTRY.get("gate_norm.lowerings")
+        assert norm.value(site="gated_delta_net", groups=30, width=16,
+                          impl="jnp") == LINEAR_LAYERS
         mod.forward(batch, is_train=False)
         assert telemetry.total("linear_attn.lowerings") == LINEAR_LAYERS
+        assert telemetry.total("gate_norm.lowerings") == LINEAR_LAYERS
     finally:
         telemetry.disable()
         telemetry.reset()

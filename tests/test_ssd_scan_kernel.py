@@ -300,8 +300,9 @@ def _pallas_calls(jaxpr):
 
 def test_a_training_step_holds_each_kernel_once_and_never_interpreted():
     """The gradient's program of the ``Mamba2`` op: ONE forward and one
-    backward kernel of the scan and of the causal taps (each pair keeps
-    its own residuals: no second forward under a checkpoint), all for
+    backward kernel of the scan, of the causal taps and of the gate and
+    norm (each pair keeps its own residuals: no second forward under a
+    checkpoint), all for
     Mosaic: the branch for every other platform is the einsum form and
     ``causal_taps``, so a step lowered for the TPU traces no interpreter
     copy of the bodies, and one lowered for the CPU holds no kernel at
@@ -317,7 +318,9 @@ def test_a_training_step_holds_each_kernel_once_and_never_interpreted():
     grad = jax.jit(jax.grad(loss, tuple(range(7))))
     calls = list(_pallas_calls(grad.trace(*ins).jaxpr.jaxpr))
     names = sorted(str(c.params["name"]) for c in calls)
-    assert names == ["ssd_bwd_f32_q128_p64_n128",
+    assert names == ["gate_norm_bwd_f32_r256_g256_gate_first",
+                     "gate_norm_fwd_f32_r256_g256_gate_first",
+                     "ssd_bwd_f32_q128_p64_n128",
                      "ssd_fwd_f32_q128_p64_n128",
                      "taps_bwd_f32_t256_c512_k4_bias_silu",
                      "taps_fwd_f32_t256_c512_k4_bias_silu"], names

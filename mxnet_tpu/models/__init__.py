@@ -26,7 +26,12 @@ embedding), whole or as a share, with ``lfm2_reference``. ``falcon_h1``
 is Falcon-H1-34B (Mamba-2 and grouped attention side by side in every
 block off one norm, fourteen fixed multipliers, a dense SwiGLU), whole or
 as one of the chips that share a layer by tensor parallelism, with
-``falcon_h1_reference``; ``lm_blocks`` holds what the LM symbols share.
+``falcon_h1_reference``. ``kimi_linear`` is Kimi-Linear-48B-A3B (Kimi
+Delta Attention, a delta rule whose decay is a vector a head, three to
+one beside latent attention without a rotary embedding, a dense SwiGLU
+then one shared and 256 sigmoid-routed experts), whole or as a share,
+with ``kimi_linear_reference``; ``lm_blocks`` holds what the LM symbols
+share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -41,6 +46,7 @@ from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
 from . import (falcon_h1, falcon_h1_reference, kanana2, kanana2_reference,
+               kimi_linear, kimi_linear_reference,
                lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
                nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
                olmoe, olmoe_reference)

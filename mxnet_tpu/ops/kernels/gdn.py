@@ -96,14 +96,14 @@ def gdn_vmem_bytes(chunk, per, key_dim, value_dim, itemsize):
             + 24 * max(chunk * max(k, v) * 4, state))
 
 
-def gdn_takes(heads, key_dim, value_dim, chunk, dtype):
+def gdn_takes(heads, key_dim, value_dim, chunk, dtype, decay="scalar"):
     """Whether ``gated_delta_rule`` has tiles for these shapes: chunks of
-    whole bf16 sublane tiles up to a lane row, a head's keys and values in
-    multiples of 32 (a quarter of a lane row; the rest of the row is
-    padding in VMEM), an operand type Mosaic takes and a step that fits
-    VMEM. Everything else is the ``jax.numpy`` chunk form's
-    (``ops/transformer.py::gated_delta_rule``)."""
-    if min(heads, key_dim, value_dim, chunk) <= 0:
+    whole bf16 sublane tiles up to a lane row, keys and values of a head in
+    multiples of 32 (a quarter of a lane row, padded in VMEM), an operand
+    type Mosaic takes, a step that fits VMEM, and ONE decay a head and token
+    (``decay="channel"``: refused). Everything else is the ``jax.numpy``
+    chunk forms' (``ops/transformer.py::gated_delta_rule``)."""
+    if min(heads, key_dim, value_dim, chunk) <= 0 or decay != "scalar":
         return False
     return (chunk % 16 == 0 and chunk <= LANES
             and key_dim % 32 == 0 and value_dim % 32 == 0

@@ -242,7 +242,7 @@ _M_LATENT_LOWERINGS = _tm.counter(
 
 
 def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
-                     v_head_dim, theta, eps, interleave=True):
+                     v_head_dim, theta, eps, interleave=True, rotary=True):
     """query [B, T, H * (N + R)] (a head's N un-rotated dimensions, then
     its R rotary ones), latent [B, T, L + R] (the compressed key/value
     latent, then the one rotary key a token), gamma [L], up_weight
@@ -260,7 +260,10 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
     (``_latent_kernel_path`` below: the flash pair of two key operands
     where the step is lowered for the TPU), ``composed`` everywhere else
     (``_latent_composed_path``: the concatenated key through the one
-    attention dispatch)."""
+    attention dispatch). ``rotary=False`` (NoPE latent attention): neither
+    the query's R last dimensions nor the shared key is rotated, ``theta``
+    and ``interleave`` are read by nothing; the same two forms on the same
+    shapes (the kernels never rotated), counted with ``rotary=0``."""
     from .kernels import latent_flash_takes
 
     width = latent.shape[2] - rope_dim
@@ -269,15 +272,19 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
                                 query.dtype)
     _M_LATENT_LOWERINGS.inc(heads=num_heads, latent=width, rope=rope_dim,
                             nope=nope, dv=v_head_dim,
-                            impl="kernel" if kernel else "composed")
+                            impl="kernel" if kernel else "composed",
+                            **({} if rotary else {"rotary": 0}))
     with jax.named_scope("latent"):
         c = rms_norm(latent[..., :width], gamma, eps)
         kv = jax.lax.dot_general(
             c, up_weight.astype(c.dtype), (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32).astype(c.dtype)
-        k_rope = rope(latent[..., width:], 1, theta, rope_dim, 0, interleave)
+        k_rope = latent[..., width:]
+        if rotary:
+            k_rope = rope(k_rope, 1, theta, rope_dim, 0, interleave)
     path = _latent_kernel_path if kernel else _latent_composed_path
-    return path(query, kv, k_rope, num_heads, v_head_dim, theta, interleave)
+    return path(query, kv, k_rope, num_heads, v_head_dim, theta, interleave,
+                rotary)
 
 
 def _latent_attention(attrs, ins, is_train):
@@ -287,7 +294,8 @@ def _latent_attention(attrs, ins, is_train):
         v_head_dim=int(attrs["v_head_dim"]),
         theta=float(attrs.get("theta", 10000.0)),
         eps=float(attrs.get("eps", 1e-6)),
-        interleave=bool(attrs.get("interleave", True)))]
+        interleave=bool(attrs.get("interleave", True)),
+        rotary=bool(attrs.get("rotary", True)))]
 
 
 def _latent_attention_infer(attrs, in_shapes):
@@ -314,7 +322,8 @@ register(
         _latent_attention,
         arguments=("query", "latent", "latent_gamma", "up_weight"),
         defaults={"num_heads": 1, "rope_dim": 0, "v_head_dim": 0,
-                  "theta": 10000.0, "eps": 1e-6, "interleave": True},
+                  "theta": 10000.0, "eps": 1e-6, "interleave": True,
+                  "rotary": True},
         infer_shape=_latent_attention_infer,
         aliases=("LatentAttention",),
     )
@@ -790,7 +799,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk):
 
 def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
                     dt_bias, norm_gamma, num_heads, chunk_size, eps,
-                    allow_neg_eigval=True, remat=False):
+                    allow_neg_eigval=True, remat=False, gate_act="silu"):
     """query and key [B, T, H K], value and gate [B, T, H V], a and b [B,
     T, H] (the six projections of the block's input), conv_weight [taps,
     2 H K + H V] (the taps of ``query | key | value``; tap ``taps - 1``
@@ -824,6 +833,17 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     norm are ``gated_rms_norm`` (``norm_first``), one kernel each way on
     ``o`` head-major as the rule's kernel wrote it: no move between them.
 
+    **A decay a channel** (Kimi Delta Attention, arXiv:2510.26692), taken
+    by the shape of ``a``: [B, T, H K] with ``dt_bias`` [H K] (``a_log``
+    stays [H]) gives ``g = -exp(a_log_h) softplus(a + dt_bias)`` a key
+    channel, and the rule is ``channel_delta_rule`` below, the
+    ``jax.numpy`` chunk form on every platform (``kernels.gdn_takes``
+    refuses the form). ``gate_act="sigmoid"``: the gate behind the norm
+    is a sigmoid, in the ``gate_norm`` closure everywhere
+    (``gated_rms_norm`` knows ``silu``). Both are counted where they are
+    not the default (``decay="channel"``, ``gate="sigmoid"``); the scalar
+    signature traces what it always did.
+
     The call site counts itself here (``linear_attn.lowerings``,
     ``gate_norm.lowerings``, ``causal_taps.lowerings`` once a convolved
     array); the
@@ -832,40 +852,52 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     from . import kernels
 
     key_dim, value_dim = (x.shape[2] // num_heads for x in (query, value))
+    channel = a.shape[2] != num_heads
+    if gate_act not in ("silu", "sigmoid"):
+        raise ValueError("GatedDeltaNet: gate_act=%r (silu or sigmoid)"
+                         % (gate_act,))
     kernel = kernels.gdn_takes(
-        num_heads, key_dim, value_dim, chunk_size, value.dtype)
+        num_heads, key_dim, value_dim, chunk_size, value.dtype,
+        "channel" if channel else "scalar")
+    labels = {"decay": "channel"} if channel else {}
+    if gate_act != "silu":
+        labels["gate"] = gate_act
     _M_LINEAR_ATTN_LOWERINGS.inc(
         heads=num_heads, key_dim=key_dim, value_dim=value_dim,
         chunk=chunk_size, conv=conv_weight.shape[0],
-        impl="kernel" if kernel else "chunked")
+        impl="kernel" if kernel else "chunked", **labels)
     taps_kernel = tuple(
         _taps_site("gated_delta_net", x, conv_weight, "silu",
                    channels=x.shape[2]) for x in (query, key, value))
     # the norm's kernel reads o where the rule's kernel left it, head-major
     norm_kernel = _gate_norm_site(
         "gated_delta_net", "norm_first", num_heads, value_dim, gate,
-        core=kernel and query.shape[1] % chunk_size == 0)
+        core=kernel and query.shape[1] % chunk_size == 0
+        and gate_act == "silu")
     return _gated_delta_block(
         query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
         norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
         eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
         remat=bool(remat), kernel=kernel, taps_kernel=taps_kernel,
-        interpret=kernels.common.INTERPRET, norm_kernel=norm_kernel)
+        interpret=kernels.common.INTERPRET, norm_kernel=norm_kernel,
+        gate_act=gate_act)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "chunk", "eps", "beta_scale", "remat", "kernel", "taps_kernel",
-    "interpret", "norm_kernel"))
+    "interpret", "norm_kernel", "gate_act"))
 def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                        dt_bias, norm_gamma, *, heads, chunk, eps,
                        beta_scale, remat, kernel, taps_kernel, interpret,
-                       norm_kernel=False):
-    """``gated_delta_net`` for one signature."""
+                       norm_kernel=False, gate_act="silu"):
+    """``gated_delta_net`` for one signature (``a`` [B, T, H K]: the
+    channel form)."""
     from . import kernels
 
     f32 = jnp.float32
     bsz, t, _ = query.shape
     dk, dv = query.shape[2] // heads, value.shape[2] // heads
+    channel = a.shape[2] != heads
 
     def again(f):
         return jax.checkpoint(f) if remat else f
@@ -884,6 +916,10 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
 
     def unit_and_strengths(q, k, a, b, a_log, dt_bias):
         beta = beta_scale * jax.nn.sigmoid(b.astype(f32))
+        if channel:  # a rate a head, a step size a key channel
+            a_log = a_log[:, None]
+            a = a.reshape(bsz, t, heads, dk)
+            dt_bias = dt_bias.reshape(heads, dk)
         g = -jnp.exp(a_log.astype(f32)) * jax.nn.softplus(
             a.astype(f32) + dt_bias.astype(f32))
         return ((unit(q) * dk ** -0.5).astype(value.dtype),
@@ -891,14 +927,14 @@ def _gated_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
 
     def delta_rule(q, k, v, a, b, a_log, dt_bias):
         q, k, g, beta = unit_and_strengths(q, k, a, b, a_log, dt_bias)
-        return gated_delta_rule(q, k, v.reshape(bsz, t, heads, dv), g, beta,
-                                chunk)
+        rule = channel_delta_rule if channel else gated_delta_rule
+        return rule(q, k, v.reshape(bsz, t, heads, dv), g, beta, chunk)
 
     def gate_norm(o, gate, norm_gamma):
         var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
         normed = o * jax.lax.rsqrt(var + eps) * norm_gamma.astype(f32)
-        gated = normed.reshape(bsz, t, heads * dv) * jax.nn.silu(
-            gate.astype(f32))
+        gated = normed.reshape(bsz, t, heads * dv) * getattr(
+            jax.nn, gate_act)(gate.astype(f32))
         return gated.astype(gate.dtype)
 
     with jax.named_scope("conv1d"):
@@ -929,7 +965,7 @@ def _gated_delta_net(attrs, ins, is_train):
         chunk_size=int(attrs.get("chunk_size", 64)),
         eps=float(attrs.get("eps", 1e-6)),
         allow_neg_eigval=bool(attrs.get("allow_neg_eigval", True)),
-        remat=is_train)]
+        remat=is_train, gate_act=str(attrs.get("gate_act", "silu")))]
 
 
 def _gated_delta_net_infer(attrs, in_shapes):
@@ -947,8 +983,12 @@ def _gated_delta_net_infer(attrs, in_shapes):
         raise ValueError("GatedDeltaNet: value %s does not share query's "
                          "batch and time %s" % (v, q[:2]))
     scalars = q[:2] + (heads,)
-    return ([q, q, v, v, scalars, scalars, (taps, 2 * heads * dk + heads * dv),
-             (heads,), (heads,), (dv,)], [v], [])
+    # a decay a channel where ``a`` is as wide as the keys (KDA)
+    channel = dk > 1 and in_shapes[4] is not None and tuple(
+        in_shapes[4]) == tuple(q)
+    return ([q, q, v, v, q if channel else scalars, scalars,
+             (taps, 2 * heads * dk + heads * dv), (heads,),
+             (heads * dk,) if channel else (heads,), (dv,)], [v], [])
 
 
 register(
@@ -958,7 +998,7 @@ register(
         arguments=("query", "key", "value", "gate", "a", "b", "conv_weight",
                    "a_log", "dt_bias", "norm_gamma"),
         defaults={"num_heads": 1, "conv_kernel": 4, "chunk_size": 64,
-                  "eps": 1e-6, "allow_neg_eigval": True},
+                  "eps": 1e-6, "allow_neg_eigval": True, "gate_act": "silu"},
         infer_shape=_gated_delta_net_infer,
         aliases=("GatedDeltaNet",),
     )
@@ -1070,7 +1110,7 @@ register(
 # chooses; down here so that no line above moves: see GatedDeltaNet's note)
 # --------------------------------------------------------------------------
 def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
-                          interleave):
+                          interleave, rotary=True):
     """Every head's key materialised: the rotation over the whole query,
     the shared rotary key broadcast and concatenated behind each head's
     slice of ``kv`` [B, T, H (N + Dv)], the values sliced out of it, and
@@ -1083,7 +1123,8 @@ def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
     nope = query.shape[2] // num_heads - rope_dim
     with jax.named_scope("latent"):
         kv = kv.reshape(b, t, num_heads, nope + v_head_dim)
-        q = rope(query, num_heads, theta, rope_dim, nope, interleave)
+        q = rope(query, num_heads, theta, rope_dim, nope,
+                 interleave) if rotary else query
         k = jnp.concatenate(
             [kv[..., :nope], jnp.broadcast_to(
                 k_rope[:, :, None, :], (b, t, num_heads, rope_dim))],
@@ -1095,7 +1136,7 @@ def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
 
 
 def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
-                        interleave):
+                        interleave, rotary=True):
     """Nothing of [T, H, N + R] built for the keys: one pass over the
     query (``_kernel_query``) and ``kernels.latent_flash`` on
     ``kv`` and ``k_rope`` where the up-projection and the rotation left
@@ -1106,8 +1147,13 @@ def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
     width = query.shape[2] // num_heads
     interpret = common.INTERPRET
     with jax.named_scope("latent"):
-        q = _kernel_query(query, num_heads, rope_dim, theta, interleave,
-                          interpret)
+        if rotary:
+            q = _kernel_query(query, num_heads, rope_dim, theta, interleave,
+                              interpret)
+        else:  # a head's R lanes as they are, zeros to a whole lane row
+            q = jnp.pad(query.reshape(query.shape[:2] + (num_heads, width)),
+                        ((0, 0),) * 3 + ((0, -rope_dim % 128),)).reshape(
+                            query.shape[:2] + (-1,))
         k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, -rope_dim % 128)))
     with jax.named_scope("full"):
         return latent_flash(q, kv, k_rope, num_heads, width - rope_dim,
@@ -1286,3 +1332,136 @@ register(
         aliases=("ScaledSum",),
     )
 )
+
+
+# --------------------------------------------------------------------------
+# The delta rule with a decay a CHANNEL (Kimi Delta Attention, Kimi Linear,
+# arXiv:2510.26692): ``GatedDeltaNet``'s rule where ``a`` is as wide as
+# the keys (down here so that no line above moves: see GatedDeltaNet's note)
+# --------------------------------------------------------------------------
+KDA_SUB_BLOCK = 16  # tokens a sub-block of a chunk (fla's chunk_kda)
+
+
+def channel_delta_rule(q, k, v, g, beta, chunk):
+    """The delta rule ``S_t = Diag(a_t) S_{t-1} + k_t u_t^T`` with ``u_t =
+    beta_t (v_t - (Diag(a_t) S_{t-1})^T k_t)``, ``a_t = exp(g_t)`` a vector
+    over the K key channels, ``o_t = S_t^T q_t`` (``S`` [H, K, V] float32,
+    zero before the first token) in its chunk form. q and k [B, T, H, K],
+    v [B, T, H, V], g [B, T, H, K] (log decay, <= 0) and beta [B, T, H]
+    float32 -> o [B, T, H, V] float32.
+
+    With ``b_i`` in R^K the running sum of ``g`` inside a chunk, ``A(x)_ij
+    = sum_d x_id k_jd exp(b_id - b_jd)``: the decay sits INSIDE the
+    contraction, and the factored ``(x_i exp(b_i)) . (k_j exp(-b_j))``
+    raises e to a positive power that overflows float32 after a few
+    strongly decayed tokens. So a chunk is cut into sub-blocks of
+    ``KDA_SUB_BLOCK`` tokens (one sub-block where that does not divide it). A pair in different sub-blocks factors through the first token
+    ``n`` of the later one, ``exp(b_i - b_n)`` and ``exp(b_n - b_j)`` both
+    at most 1, and is a product on the MXU (``_decayed_products``); a pair
+    in one sub-block is summed from ``exp(b_i - b_j)`` itself, masked
+    before the exponential. No exponential of a positive number anywhere.
+    Then ``gated_delta_rule``'s steps with a vector where it has a scalar:
+    ``L = beta * strict_lower(A(k))``; one unit-triangular system a chunk
+    and head, ``(I + L) [W | Y] = [beta v | beta (exp(b) * k)]``; ``M =
+    lower(A(q))``; a ``lax.scan`` over the chunks whose carry is the
+    state: ``o = M W + (exp(b) * q - M Y) S``, ``u = W - Y S``, ``S' =
+    Diag(exp(b_C)) S + (exp(b_C - b) * k)^T u``. Decays, their sums, the
+    tables, the solve and the state are float32; the products take
+    operands of ``v``'s dtype and accumulate in float32. T is padded to
+    whole chunks as ``gated_delta_rule`` pads it. The one form on every
+    platform: ``kernels.gdn_takes`` refuses a decay a channel."""
+    f32 = jnp.float32
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    # one sub-block where 16 does not divide the chunk: every pair from the
+    # difference itself
+    sub = chunk if chunk % KDA_SUB_BLOCK else KDA_SUB_BLOCK
+    pad = -t % chunk
+    if pad:
+        q, k, v, g, beta = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, g, beta))
+    nc = (t + pad) // chunk
+    dtype = v.dtype
+
+    def chunks(x):  # [B, T, H, ...] -> [B, nc, H, C, ...]
+        return jnp.moveaxis(x.reshape((b, nc, chunk) + x.shape[2:]), 3, 2)
+
+    def dot(spec, lhs, rhs):
+        return jnp.einsum(spec, lhs.astype(dtype), rhs.astype(dtype),
+                          preferred_element_type=f32)
+
+    q, k, v = chunks(q), chunks(k), chunks(v)
+    beta = chunks(beta.astype(f32))[..., None]        # [B, nc, H, C, 1]
+    cum = jnp.cumsum(chunks(g.astype(f32)), axis=3)   # b_i [B, nc, H, C, K]
+    c = jnp.exp(cum)                                  # decay from the
+    to_end = jnp.exp(cum[..., -1:, :] - cum)          # start; to the end
+    kk, qk = _decayed_products(q, k, cum, sub, dot)
+    solved = jax.scipy.linalg.solve_triangular(
+        beta * kk, jnp.concatenate(
+            [beta * v.astype(f32), beta * c * k.astype(f32)], axis=-1),
+        lower=True, unit_diagonal=True)               # the diagonal unread
+    w, y = solved[..., :dv], solved[..., dv:]
+    out0 = dot("bchij,bchjv->bchiv", qk, w)
+    q_in = c * q.astype(f32) - dot("bchij,bchjd->bchid", qk, y)
+    k_out = to_end * k.astype(f32)
+
+    def step(state, at):                              # [B, H, K, V]
+        out0, q_in, w, y, k_out, kept = at
+        u = w - dot("bhid,bhdv->bhiv", y, state)
+        out = out0 + dot("bhid,bhdv->bhiv", q_in, state)
+        state = kept[..., None] * state + dot("bhid,bhiv->bhdv", k_out, u)
+        return state, out
+
+    _, out = jax.lax.scan(
+        step, jnp.zeros((b, h, dk, dv), f32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (
+            out0, q_in.astype(dtype), w.astype(dtype), y.astype(dtype),
+            k_out.astype(dtype), c[..., -1, :])))
+    out = jnp.moveaxis(out, 0, 1)                     # [B, nc, H, C, V]
+    return jnp.moveaxis(out, 2, 3).reshape(b, t + pad, h, dv)[:, :t]
+
+
+def _decayed_products(q, k, cum, sub, dot):
+    """``A(k)`` and ``A(q)`` of ``channel_delta_rule``, [B, nc, H, C, C]
+    float32, zero above the diagonal: q, k [B, nc, H, C, K], ``cum`` their
+    running log decays (float32, falling along C). Sub-block ``I``'s rows
+    against the columns of the sub-blocks before it: ``(x_i exp(b_i -
+    b_n)) . (k_j exp(b_n - b_j))`` with ``n`` the sub-block's first token,
+    one batched product a kind of row over every sub-block but the first;
+    the ``sub x sub`` blocks on the diagonal: the sum over K of ``x_id k_jd
+    exp(b_id - b_jd)``, the difference masked to ``j <= i`` before the
+    exponential (a reduction XLA fuses: nothing [.., sub, sub, K] is
+    written)."""
+    f32 = jnp.float32
+    lead, (chunk, dk) = q.shape[:3], q.shape[3:]
+    ns = chunk // sub
+
+    def subs(x):  # [.., C, K] -> [.., ns, sub, K]
+        return x.reshape(lead + (ns, sub, dk))
+
+    kf = k.astype(f32)
+    qs, ks, cs = subs(q.astype(f32)), subs(kf), subs(cum)
+    lower = np.tril(np.ones((sub, sub), bool))[..., None]
+    within = jnp.exp(jnp.where(
+        lower, cs[..., :, None, :] - cs[..., None, :, :], -jnp.inf))
+    cols = ks[..., None, :, :] * within               # k_j exp(b_i - b_j)
+    diag = [jnp.sum(x[..., :, None, :] * cols, axis=-1) for x in (ks, qs)]
+    if ns == 1:
+        return tuple(d.reshape(lead + (chunk, chunk)) for d in diag)
+    before = (ns - 1) * sub                           # columns with a later
+    first = cs[..., 1:, :1, :]                        # sub-block; b_n
+    earlier = (np.arange(before)[None, :]
+               < sub * np.arange(1, ns)[:, None])[..., None]
+    k_to = kf[..., None, :before, :] * jnp.exp(jnp.where(
+        earlier, first - cum[..., None, :before, :], -jnp.inf))
+    from_n = jnp.exp(cs[..., 1:, :, :] - first)       # [.., ns - 1, sub, K]
+    own = np.eye(ns, dtype=np.float32)[:, None, :, None]
+    out = []
+    for x, d in zip((ks, qs), diag):
+        off = dot("bchnid,bchnjd->bchnij", x[..., 1:, :, :] * from_n, k_to)
+        full = jnp.pad(off.reshape(lead + (before, before)),
+                       ((0, 0),) * 3 + ((sub, 0), (0, sub)))
+        blocks = d[..., :, :, None, :] * own          # block-diagonal
+        out.append(full + blocks.reshape(lead + (chunk, chunk)))
+    return tuple(out)

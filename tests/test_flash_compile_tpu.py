@@ -199,6 +199,77 @@ def test_the_latent_pair_compiles_at_the_kanana_cells_shape(one_chip):
     assert used <= limit <= pk.common.VMEM_RAISED_LIMIT
 
 
+def test_the_latent_pair_takes_the_call_that_rotates_nothing(one_chip):
+    """``LatentAttention(rotary=False)`` at the Kimi Linear cell's shape
+    (Kanana's signature on a hidden size of 2304: T 8,192, 32 heads of
+    128 + 64 / 128 from a latent of 512): the same flash pair, once each
+    way, and no pass of ``latent_query`` over the query (its 64 lanes are
+    padded to a lane row by XLA: nothing is rotated)."""
+    from mxnet_tpu.ops.transformer import latent_attention
+
+    t, h, nope, rope, dv, latent = 8192, 32, 128, 64, 128, 512
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(*ins):
+        return jnp.sum(latent_attention(
+            *ins, num_heads=h, rope_dim=rope, v_head_dim=dv, theta=1e4,
+            eps=1e-5, rotary=False).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shape(1, t, h * (nope + rope)), shape(1, t, latent + rope),
+        shape(latent), shape(h * (nope + dv), latent)).compile().as_text()
+    for which in ("fwd", "bwd"):
+        assert len([line for line in text.splitlines()
+                    if "flash2_%s_bf16_q1024_k1024" % which in line
+                    and "custom-call(" in line]) == 1
+    assert "latent_query_" not in text
+    assert "flash_fwd_" not in text and "flash_bwd_" not in text
+    assert " cosine(" not in text and " sine(" not in text
+
+
+def test_the_channel_delta_block_compiles_within_its_memory(one_chip):
+    """``GatedDeltaNet`` in its channel form at the Kimi Linear cell's
+    shape (T 8,192, 32 heads of 128 / 128, chunks of 64, bf16), value and
+    gradients under ``remat`` as a training step runs it: the three
+    convolutions are the taps' pair, the rule is no Pallas kernel (the
+    scalar rule's pair refuses a decay a channel) and neither is the
+    sigmoid-gated norm, and the compiled block's temporaries stay under
+    4.5 GB (4.02 as written): the diagonal sub-blocks' [T, 16, H, 128]
+    float32 (2.1 GB each, several in the backward) are fused reductions'
+    operands, never written."""
+    from mxnet_tpu.ops import transformer as tr
+
+    t, h, d = 8192, 32, 128
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ins = [spec(1, t, h * d)] * 5 + [
+        spec(1, t, h), spec(4, 3 * h * d), spec(h), spec(h * d), spec(d)]
+
+    def op(q, k, v, gate, a, *rest):
+        # the operands an elementwise neighbour's output, as a
+        # projection's is in a step
+        return tr.gated_delta_net(
+            q * 2, k * 2, v * 2, gate * 2, a * 2, *rest, h, 64, 1e-5,
+            allow_neg_eigval=False, remat=True, gate_act="sigmoid")
+
+    tr._gated_delta_block.clear_cache()
+    compiled = jax.jit(jax.value_and_grad(
+        lambda *a: jnp.sum(op(*a).astype(jnp.float32)),
+        argnums=tuple(range(len(ins))))).lower(*ins).compile()
+    text = compiled.as_text()
+    for which in ("fwd", "bwd"):
+        assert len([line for line in text.splitlines()
+                    if "taps_%s_bf16_t512_c2048_k4_silu" % which in line
+                    and "custom-call(" in line]) == 3, which
+    assert "gdn_fwd_" not in text and "gdn_bwd_" not in text
+    assert "gate_norm_fwd_" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4.5e9
+
+
 # the OLMoE cell's two expert products (gate/up, down); a float32 caller
 # at the widest result tile its budget admits; the MiMo share cell's two
 # (8 held experts over a buffer of 2,048 rows); the Nemotron-3-Nano
@@ -221,7 +292,16 @@ GMM_SHAPES = [
      # 8,192, whose row tile is 512: the dgrad of 3072 columns walks its
      # contraction in two steps and gives up half its result tile for it
      for m in (4096, 8192)
-     for k, n in ((2048, 3072), (1536, 2048), (2048, 1536))]
+     for k, n in ((2048, 3072), (1536, 2048), (2048, 1536))
+] + [(m, k, n, groups, jnp.bfloat16)
+     # the Kimi Linear share cell's: SwiGLU experts of 1024 on a hidden
+     # size of 2304 = 18 lane rows (gate and up one product of 2048
+     # columns, then down) at the rows 8 and 16 held experts expect of
+     # 8,192 x 8 pairs over 256 and at their buffers (twice the expected
+     # rows, and the three times the 8-held cell took)
+     for m, groups in ((2048, 8), (4096, 8), (6144, 8), (4096, 16),
+                       (8192, 16))
+     for k, n in ((2304, 2048), (1024, 2304))]
 
 
 @pytest.mark.parametrize("m,k,n,groups,dtype", GMM_SHAPES)
@@ -506,6 +586,9 @@ TAPS_SHAPES = {
                               jnp.bfloat16),
     "olmo_hybrid_value": ("silu", 4, (1, 4096, 5760), 0, 5760, jnp.bfloat16),
     "lfm2_short_conv": ("gates", 3, (1, 8192, 6144), 0, 2048, jnp.bfloat16),
+    # Kimi Linear's KDA query, key and value: 4,096 columns each, a
+    # projection's whole output
+    "kimi_kda_qkv": ("silu", 4, (1, 8192, 4096), 0, 4096, jnp.bfloat16),
     "float32_taps3": ("silu", 3, (2, 512, 640), 0, 640, jnp.float32),
 }
 

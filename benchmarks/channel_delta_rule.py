@@ -1,0 +1,220 @@
+"""The delta rule with a decay a channel (Kimi Delta Attention) on the chip:
+`ops/transformer.py::channel_delta_rule` (the `jax.numpy` chunk form, under
+`jax.checkpoint` with the unit norms, write strengths and decays before it,
+as the GatedDeltaNet op ran its `delta_rule` stage before the kernels)
+against `ops/kernels/gdn.py::channel_delta_rule` (the `kda_fwd_` /
+`kda_bwd_` kernel pair, the same prologue under its own `jax.checkpoint`:
+`kernel`), against `channel_delta_net` (`fused`: what the op calls, the
+pair with the prologue made in VMEM, `kda_*_pre`) and against the scalar
+rule's pair (`gdn_fwd_` / `gdn_bwd_`, every channel of a head given the
+first one's decay: a floor, the channel rule does strictly more) at the
+Kimi Linear cell's shape (one sequence of 8,192 tokens, 32 heads with keys
+and values of 128, chunks of 64, bf16), forward and forward + backward,
+the forms alternating. Host clock over 10 calls
+closed by a fetch; the arrays cross the jit boundary as the op holds them
+(``[B, T, H K]``, ``[B, T, H V]``, ``[B, T, H]``). The kernels' own device
+time is read from a profiler trace by their names.
+
+Also prints how far each form's output and gradients are, on the chip,
+from the chunk form in float32 with every product at the highest precision
+(largest difference over that one's largest magnitude), and each pair's
+VMEM a step by the accounting. `--heads-a-step=1,4,16` times the fused
+pair at other head groups than its own. Prints one JSON line a row and
+writes `chiprun_out/channel_delta_rule_table.json`; PERF.md section 7 holds
+the table (PR 54).
+
+    chiprun -- python3 benchmarks/channel_delta_rule.py
+
+`--rehearse-cpu` runs the same flow at a toy size here (the pairs' branch
+for other platforms, no trace): it proves the script, not a number.
+"""
+import collections
+import glob
+import json
+import os
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from mxnet_tpu.ops import kernels as pk  # noqa: E402
+from mxnet_tpu.ops.transformer import channel_delta_rule  # noqa: E402
+
+B, T, H, K, V, CHUNK = 1, 8192, 32, 128, 128, 64
+INPUTS = ("q", "k", "v", "a", "b")
+
+
+def _time(f, *args, reps=10):
+    jax.block_until_ready(f(*args))
+    jax.block_until_ready(f(*args))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        r = f(*args)
+    jax.block_until_ready(r)
+    np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[:1])  # a fetch
+    return (time.perf_counter() - t0) / reps * 1e3
+
+
+def _device_ms(g, *args, reps=5):
+    """Device ms a call of each ``kda_`` / ``gdn_`` kernel and of
+    everything else in the program, from a profiler trace of ``reps``
+    calls."""
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(g(*args))
+    where = tempfile.mkdtemp()
+    with jax.profiler.trace(where):
+        for _ in range(reps):
+            r = g(*args)
+        jax.block_until_ready(r)
+    trace, = glob.glob(where + "/plugins/profile/*/*.xplane.pb")
+    ms = collections.Counter()
+    for plane in ProfileData.from_file(trace).planes:
+        if plane.name != "/device:TPU:0":
+            continue
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            for e in line.events:
+                name = e.name.split(" = ")[0].lstrip("%")
+                ms[name.split(".")[0] if name.startswith(("kda_", "gdn_"))
+                   else "everything else"] += e.duration_ns / 1e6 / reps
+    return dict(ms)
+
+
+def inputs(seed, dtype, t):
+    """q, k, v as the op's convolution leaves them (unit scale, heads side
+    by side in the last dimension), a and b as their projections do, a
+    decay rate a head and a step size a channel by the published rule."""
+    rng = np.random.RandomState(seed)
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), H * K))
+    q, k, v, a = (jnp.asarray(rng.randn(B, t, H * w), dtype)
+                  for w in (K, K, V, K))
+    b = jnp.asarray(rng.randn(B, t, H), dtype)
+    return ((q, k, v, a, b,
+             jnp.asarray(np.log(rng.uniform(1, 16, H)), jnp.float32),
+             jnp.asarray(step + np.log(-np.expm1(-step)), jnp.float32)),
+            jnp.asarray(rng.randn(B, t, H, V), jnp.float32))
+
+
+def forms():
+    """The ``delta_rule`` stage of ``GatedDeltaNet`` four ways."""
+    f32 = jnp.float32
+
+    def unit(x):
+        x = x.astype(f32).reshape(x.shape[:2] + (H, -1))
+        return x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    def before(q, k, v, a, b, a_log, dt_bias):
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            a.astype(f32).reshape(a.shape[:2] + (H, K))
+            + dt_bias.reshape(H, K))
+        return ((unit(q) * K ** -0.5).astype(v.dtype),
+                unit(k).astype(v.dtype), g, jax.nn.sigmoid(b.astype(f32)))
+
+    def heads(v):
+        return v.reshape(v.shape[:2] + (H, V))
+
+    @jax.checkpoint
+    def chunked(*ins):
+        q, k, g, beta = before(*ins)
+        return channel_delta_rule(q, k, heads(ins[2]), g, beta, CHUNK)
+
+    def kernel(*ins):
+        q, k, g, beta = jax.checkpoint(before)(*ins)
+        return pk.gdn.channel_delta_rule(q, k, heads(ins[2]), g, beta, CHUNK)
+
+    def fused(q, k, v, a, b, a_log, dt_bias):
+        beta = jax.checkpoint(
+            lambda b: jax.nn.sigmoid(b.astype(f32)))(b)
+        return heads(pk.channel_delta_net(q, k, v, a, beta, a_log, dt_bias,
+                                          CHUNK))
+
+    def scalar_kernel(*ins):
+        q, k, g, beta = jax.checkpoint(before)(*ins)
+        return pk.gated_delta_rule(q, k, heads(ins[2]), g[..., 0], beta,
+                                   CHUNK)
+
+    def both(f):
+        def loss(cot, *ins):
+            return jnp.sum(f(*ins) * cot)
+        return (jax.jit(f),
+                jax.jit(jax.value_and_grad(loss, argnums=(1, 2, 3, 4, 5))))
+    return {"chunked": both(chunked), "kernel": both(kernel),
+            "fused": both(fused), "scalar_kernel": both(scalar_kernel)}
+
+
+def main():
+    global T, H
+    rehearse = "--rehearse-cpu" in sys.argv
+    steps = [a.split("=", 1)[1] for a in sys.argv
+             if a.startswith("--heads-a-step=")]
+    if rehearse:
+        T, H = 128, 2
+    dev = jax.devices()[0]
+    res = {"device": str(dev.device_kind), "platform": dev.platform,
+           "shape": dict(b=B, t=T, heads=H, key_dim=K, value_dim=V,
+                         chunk=CHUNK), "rows": []}
+
+    def row(**kw):
+        print(json.dumps(kw), flush=True)
+        res["rows"].append(kw)
+
+    assert pk.gdn_takes(H, K, V, CHUNK, jnp.bfloat16, "channel")
+    both = forms()
+    args, cot = inputs(0, jnp.bfloat16, T if rehearse else 2048)
+    outs = {name: (f(*args), g(cot, *args)[1])
+            for name, (f, g) in both.items() if name != "scalar_kernel"}
+
+    def rel(got, want):
+        got, want = (v.astype(jnp.float32) for v in (got, want))
+        return float(jnp.abs(got - want).max() / jnp.abs(want).max())
+
+    # the yardstick: the chunk form on the same values in float32 with
+    # every product at the highest precision
+    with jax.default_matmul_precision("highest"):
+        f, g = forms()["chunked"]
+        exact = tuple(v.astype(jnp.float32) for v in args)
+        o_x, g_x = f(*exact), g(cot, *exact)[1]
+    for name, (o, grads) in outs.items():
+        row(check=name + "_against_float32_highest", dtype="bfloat16",
+            t=args[0].shape[1], o=rel(o, o_x),
+            **{"d" + n: rel(k, e) for n, k, e in zip(INPUTS, grads, g_x)})
+
+    args, cot = inputs(1, jnp.bfloat16, T)
+    for _ in range(1 if rehearse else 2):
+        for name, (f, g) in both.items():
+            fwd = _time(f, *args)
+            row(form=name, fwd_ms=fwd, fwd_bwd_ms=_time(g, cot, *args))
+    item = jnp.dtype(jnp.bfloat16).itemsize
+    row(vmem_by_the_accounting=dict(
+        kda=pk.gdn.kda_vmem_bytes(CHUNK, pk.gdn.kda_group(H), H, K, V, item),
+        gdn=pk.gdn.gdn_vmem_bytes(CHUNK, pk.gdn.gdn_group(H), K, V, item)))
+    for name in () if rehearse else ("kernel", "fused", "scalar_kernel"):
+        row(form=name, kernels_device_ms=_device_ms(both[name][1], cot,
+                                                    *args))
+
+    for per in [int(p) for s in steps for p in s.split(",") if p]:
+        own, pk.gdn.KDA_HEADS_A_STEP = pk.gdn.KDA_HEADS_A_STEP, per
+        for f in (pk.gdn.kda_fwd_call, pk.gdn.kda_bwd_call,
+                  pk.gdn.kda_net_forward):
+            f.clear_cache()
+        f, g = forms()["fused"]
+        row(heads_a_step=pk.gdn.kda_group(H), fwd_ms=_time(f, *args),
+            fwd_bwd_ms=_time(g, cot, *args),
+            kernels_device_ms=None if rehearse else _device_ms(g, cot, *args))
+        pk.gdn.KDA_HEADS_A_STEP = own
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/channel_delta_rule_table.json", "w") as f:
+        json.dump(res, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

@@ -13,7 +13,8 @@ because they need explicit on-chip (VMEM) accumulation patterns.
   gmm          the expert layer's grouped matmul, and the sorted
                segment sum that is its wgrad at another shape
   ssd          the chunked state-space scan (Mamba-2)
-  gdn          the gated delta rule's chunk core
+  gdn          the gated delta rule's chunk core, its decay a head's
+               (gdn_) or a key channel's (kda_, Kimi Delta Attention)
   taps         the causal taps: the short depthwise convolution over time
                of Mamba2, GatedDeltaNet and ShortConv, with its epilogue
   gate_norm    the gate and the grouped RMSNorm behind the scan (Mamba2)
@@ -42,7 +43,7 @@ from .conv import (
 from .flash import (
     attention, flash_attention, flash_tiles, reference_attention)
 from .gate_norm import gate_norm_takes, gated_rms_norm
-from .gdn import gated_delta_rule, gdn_takes
+from .gdn import channel_delta_net, gated_delta_rule, gdn_takes
 from .gmm import (
     gmm_metadata, gmm_row_tile, gmm_runs_kernel, gmm_tiles, grouped_matmul,
     sorted_segment_sum)
@@ -54,7 +55,8 @@ from .ssd import ssd_scan, ssd_takes
 from .taps import causal_conv, taps_takes
 
 __all__ = [
-    "attention", "causal_conv", "common", "conv_bwd_filter", "conv_bwd_input",
+    "attention", "causal_conv", "channel_delta_net", "common",
+    "conv_bwd_filter", "conv_bwd_input",
     "conv_bwd_plan", "conv_kernel_enabled", "flash_attention",
     "flash_tiles", "fused_slab_update", "gate_norm_takes",
     "gated_delta_rule", "gated_rms_norm", "gdn_takes",

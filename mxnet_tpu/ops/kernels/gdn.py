@@ -45,9 +45,9 @@ HBM.
             (``linear_attn.kernel_traces``), the ``jax.numpy`` chunk
             form on every platform but the TPU.
 
-Log decays, their sums and exponentials, ``L``, the substitution, the
-inverse's products, the state and every accumulator float32; the MXU's
-other operands in v's type.
+Decays, their sums and exponentials, ``L``, the substitution, the state
+and every accumulator float32; the MXU's other operands in v's type. The
+rule with a decay a CHANNEL (``kda_``) is the second pair, at the end.
 """
 from __future__ import annotations
 
@@ -97,13 +97,13 @@ def gdn_vmem_bytes(chunk, per, key_dim, value_dim, itemsize):
 
 
 def gdn_takes(heads, key_dim, value_dim, chunk, dtype, decay="scalar"):
-    """Whether ``gated_delta_rule`` has tiles for these shapes: chunks of
-    whole bf16 sublane tiles up to a lane row, keys and values of a head in
-    multiples of 32 (a quarter of a lane row, padded in VMEM), an operand
-    type Mosaic takes, a step that fits VMEM, and ONE decay a head and token
-    (``decay="channel"``: refused). Everything else is the ``jax.numpy``
-    chunk forms' (``ops/transformer.py::gated_delta_rule``)."""
-    if min(heads, key_dim, value_dim, chunk) <= 0 or decay != "scalar":
+    """Whether the delta rule has tiles: chunks of whole bf16 sublane tiles
+    up to a lane row, keys and values in multiples of 32, Mosaic's operand
+    types, a step within VMEM; ``decay="channel"``: ``kda_takes`` below."""
+    if decay != "scalar":
+        return decay == "channel" and kda_takes(heads, key_dim, value_dim,
+                                                chunk, dtype)
+    if min(heads, key_dim, value_dim, chunk) <= 0:
         return False
     return (chunk % 16 == 0 and chunk <= LANES
             and key_dim % 32 == 0 and value_dim % 32 == 0
@@ -554,3 +554,712 @@ def gated_delta_rule(q, k, v, g, beta, chunk, interpret=False,
                              "in chunks of %d" % (t, chunk))
         return o
     return jnp.moveaxis(o, 1, 2)[:, :t]
+
+
+# --------------------------------------------------------------------------
+# The rule with a decay a CHANNEL (Kimi Delta Attention, arXiv:2510.26692):
+# ``ops/transformer.py::channel_delta_rule`` as a second pair of bodies on
+# the same plan (down here so that no line of the scalar pair moves: a
+# kernel's lowering carries its file and line into the compile cache's key)
+#
+#   operands  token-major, as the projections and the taps' pair leave them:
+#             a head is whole lane rows of [B, T, H K] (K and V multiples of
+#             128), so a step's block is [C, G K] and a head a lane slice of
+#             it at a traced offset; nothing is moved round the pair.
+#   prologue  what the op computes between the taps and the rule (the unit
+#             norms of q and k a head, ``g = -exp(a_log) softplus(a +
+#             dt_bias)``) is made HERE where the op calls
+#             (``channel_delta_net``, kernels ``kda_*_pre``) and transposed
+#             in the backward kernel: as XLA's passes over [B, T, H, K] (a
+#             tile eight heads of a token, where [B, T, H K]'s is eight
+#             tokens of a head) they cost 10 ms a layer in moves at the
+#             cell's shape. ``channel_delta_rule`` is the pair on q, k, g
+#             as given (``kda_*``), what the tests hold to the chunk form.
+#   decay     ``b`` [C, K], the running sum of ``g`` inside the chunk, is a
+#             float32 product with a triangle of ones here; it sits INSIDE
+#             the contraction, ``A(x)_ij = sum_d x_id k_jd exp(b_id - b_jd)``.
+#             Sub-blocks of 16 tokens: rows of a later sub-block against the
+#             columns before it factor through the sub-block's first token
+#             ``n`` (``x_i exp(b_i - b_n)`` times ``k_j exp(b_n - b_j)``, both
+#             factors at most 1, one product on the MXU for q and k's rows
+#             together); inside a sub-block column j is the lane sum of
+#             ``x_i * k_j exp(b_i - b_j)`` over an 8-row tile, the difference
+#             masked to j <= i BEFORE the exponential. No exponential of a
+#             positive number.
+#   state     carried transposed, [V, K]: the chunk's decay ``exp(b_C)`` is
+#             a row along lanes there, and the three products with it are
+#             the MXU's plain, NT and TN forms.
+#   residuals the state each chunk entered with, ``(I + L)^-1`` and the two
+#             tables ``A(k) | A(q)``: the backward rebuilds neither the
+#             substitution nor a lane sum.
+#   backward  the tables' cotangents ``P`` (of ``A(k)``, strictly lower) and
+#             ``Q`` (of ``A(q)``) reach k's and q's rows as ``R_i = sum_j P_ij
+#             k_j exp(b_i - b_j)`` and k's columns as ``C_j = sum_i (P_ij k_i +
+#             Q_ij q_i) exp(b_i - b_j)``, the same sub-blocks: products through
+#             ``n`` across them, an 8-row tile a column inside one. The log
+#             decay's cotangent is a [C, K] table: ``q dq + k (dk_rows -
+#             dk_columns)``, the last token taking what the chunk's whole
+#             decay carries, summed backwards by the triangle's transpose.
+# --------------------------------------------------------------------------
+KDA_HEADS_A_STEP = 8
+KDA_SUB_BLOCK = 16   # ops/transformer.py::KDA_SUB_BLOCK (fla's chunk_kda)
+
+
+def kda_group(heads):
+    """Heads a grid step of the channel pair: the largest divisor of
+    ``heads`` up to ``KDA_HEADS_A_STEP`` (a block's rows are then that
+    many lane rows long; the body is one head's, a ``fori_loop``)."""
+    return max(g for g in range(1, KDA_HEADS_A_STEP + 1) if heads % g == 0)
+
+
+def kda_vmem_bytes(chunk, per, heads, key_dim, value_dim, itemsize):
+    """What a backward step of the channel pair holds, counted generously:
+    the double-buffered blocks (q, k, dq, dk; v, dv; g, dg; do; the
+    entering state; the inverse; the two tables; beta and its cotangent),
+    the carried cotangents and the three tables in scratch, and four dozen
+    float32 temporaries of the one head in hand."""
+    c = whole_lanes(chunk)
+    head = (4 * chunk * key_dim * itemsize + 2 * chunk * value_dim * itemsize
+            + 2 * chunk * key_dim * 4 + chunk * value_dim * 4
+            + key_dim * value_dim * 4 + 3 * chunk * c * 4)
+    small = chunk * (whole_lanes(heads) + whole_lanes(per)) * 4
+    return (2 * (per * head + small) + per * key_dim * value_dim * 4
+            + 2 * chunk * c * 4 + chunk * key_dim * 4
+            + 48 * max(chunk * max(key_dim, value_dim) * 4,
+                       key_dim * value_dim * 4))
+
+
+def kda_takes(heads, key_dim, value_dim, chunk, dtype):
+    """Whether the channel pair has tiles: a head whole lane rows of keys
+    and of values, chunks of whole sub-blocks up to a lane row, an operand
+    type Mosaic takes, a step that fits VMEM."""
+    return (min(heads, key_dim, value_dim, chunk) > 0
+            and chunk % KDA_SUB_BLOCK == 0 and chunk <= LANES
+            and key_dim % LANES == 0 and value_dim % LANES == 0
+            and jnp.dtype(dtype).name in ("bfloat16", "float32")
+            and kda_vmem_bytes(chunk, kda_group(heads), heads, key_dim,
+                               value_dim, jnp.dtype(dtype).itemsize)
+            <= VMEM_RAISED_LIMIT)
+
+
+def _rows(x, lo, hi):
+    return lax.slice(x, (lo, 0), (hi, x.shape[1]))
+
+
+def _along(row, rows):
+    """[1, W] -> [rows, W]."""
+    return lax.broadcast_in_dim(row, (rows, row.shape[1]), (0, 1))
+
+
+def _across(col, width):
+    """[R, 1] -> [R, width]."""
+    return lax.broadcast_in_dim(col, (col.shape[0], width), (0, 1))
+
+
+def _kda_masks(c):
+    """``_gdn_masks`` and the triangle of ones [C, C] float32 that sums a
+    chunk's log decays forwards and, transposed, their cotangents back."""
+    masks = _gdn_masks(c)
+    return dict(masks, ones=lax.select(
+        masks["causal"], lax.full((c, c), 1, jnp.float32),
+        lax.full((c, c), 0, jnp.float32)))
+
+
+def _kda_chunk(q_ref, k_ref, g_ref, beta_ref, pre, h, first, key_dim, masks):
+    """What both kernels make of head h's chunk (h a traced index) before
+    the tables: q and k in float32, ``beta`` [C, 1],
+    ``b`` and its exponentials from the chunk's start (``c``), to its end
+    (``to_end``) and over the whole of it (``whole`` [1, K]), q and k
+    decayed from the start and k to the end in the operands' type, and a
+    sub-block's two factors: ``from_n`` [C, K] (a row's decay from its
+    sub-block's first token) and, a later sub-block I, ``to_n[I]`` (the
+    decay from a column before it to that token, [16 I, K]) with k times
+    it in the operands' type, zero rows below.
+
+    ``pre`` (the op's call: ``(rate_ref, bias_ref)``, rows [1, G K]): q_ref
+    and k_ref hold the convolution's output and g_ref the decay's
+    pre-activation ``a``, and what ``_gated_delta_block`` computes before
+    the rule is computed here, a chunk at a time: ``q = x / |x| / sqrt(K)``
+    and ``k = x / |x|`` a head, rounded to the operands' type as XLA's pass
+    rounded them, ``g = rate * softplus(a + bias)`` (``rate`` is ``-exp(
+    a_log)`` along a head's lanes). Kept for the backward: the unit vectors
+    and their scales, the pre-activation's sigmoid, ``g``."""
+    c = q_ref.shape[0]
+    op, f32 = q_ref.dtype, jnp.float32
+    cast, mul, sub = lax.convert_element_type, lax.mul, lax.sub
+    at = pl.multiple_of(lax.mul(h, np.int32(key_dim)), LANES)
+    q, k = (cast(x[:, pl.ds(at, key_dim)], f32) for x in (q_ref, k_ref))
+    g = g_ref[:, pl.ds(at, key_dim)]
+    kept = {}
+    if pre is not None:
+        for name, x, scale in (("q", q, key_dim ** -0.5), ("k", k, 1.0)):
+            inv = lax.rsqrt(lax.add(sum_keepdims(mul(x, x), 1),
+                                    np.float32(1e-6)))
+            kept["unit_" + name] = mul(x, _across(inv, key_dim))
+            kept["scale_" + name] = mul(inv, np.float32(scale))
+        q = cast(cast(mul(kept["unit_q"], np.float32(key_dim ** -0.5)), op),
+                 f32)
+        k = cast(cast(kept["unit_k"], op), f32)
+        rate, bias = (_along(x[:, pl.ds(at, key_dim)], c) for x in pre)
+        x = lax.add(cast(g, f32), bias)
+        # jax.nn.softplus: max(x, 0) + log1p(exp(-|x|))
+        g = mul(rate, lax.add(
+            lax.max(x, np.float32(0)),
+            lax.log1p(lax.exp(lax.neg(lax.abs(x))))))
+        kept.update(g=g, slope=mul(rate, lax.logistic(x)))
+    cols = beta_ref[...]
+    beta = _gdn_column(cols, lax.broadcasted_iota(jnp.int32, cols.shape, 1),
+                       lax.add(first, h))
+    b = dot_highest(masks["ones"], g, (1, 0))
+    total = _rows(b, c - 1, c)
+    decay, to_end = lax.exp(b), lax.exp(sub(_along(total, c), b))
+    sb = KDA_SUB_BLOCK
+    firsts = [_rows(b, n, n + 1) for n in range(0, c, sb)]
+    from_n = lax.exp(sub(b, lax.concatenate(
+        [_along(n, sb) for n in firsts], 0)))
+    to_n, k_to = {}, {}
+    for i in range(1, c // sb):
+        to_n[i] = lax.exp(sub(_along(firsts[i], sb * i), _rows(b, 0, sb * i)))
+        k_to[i] = lax.concatenate(
+            [cast(mul(_rows(k, 0, sb * i), to_n[i]), op),
+             lax.full((c - sb * i, key_dim), 0, op)], 0)
+    return dict(
+        kept, q=q, k=k, beta=beta, b=b, c=decay, to_end=to_end,
+        whole=lax.exp(total), from_n=from_n, to_n=to_n, k_to=k_to,
+        qc=cast(mul(decay, q), op), kc=cast(mul(decay, k), op),
+        k_out=cast(mul(to_end, k), op),
+        rows_n=lax.concatenate([cast(mul(from_n, k), op),
+                                cast(mul(from_n, q), op)], 0))
+
+
+def _kda_pair(t, i):
+    """Sub-block i's rows of k over those of q, each decayed from the
+    sub-block's first token, [32, K] in the operands' type."""
+    c, sb = t["b"].shape[0], KDA_SUB_BLOCK
+    return lax.concatenate([_rows(t["rows_n"], sb * i, sb * i + sb),
+                            _rows(t["rows_n"], c + sb * i, c + sb * i + sb)],
+                           0)
+
+
+def _kda_tiles(t):
+    """The 8-row tiles of a chunk's diagonal sub-blocks, one (tile, column)
+    after another: yields ``(i, half, j, e, k_j)`` with ``e`` [8, K] the
+    decay ``exp(b_r - b_j)`` of rows ``16 i + 8 half ...`` from column j of
+    the same sub-block (0 where r < j: masked before the exponential) and
+    ``k_j`` that column's key along the tile's rows. A column in a
+    sub-block's lower half meets the lower tile only."""
+    b, k = t["b"], t["k"]
+    c, width = b.shape
+    sb = KDA_SUB_BLOCK
+    row = lax.broadcasted_iota(jnp.int32, (8, width), 0)
+    masked = lax.full((8, width), NEG_INF, jnp.float32)
+    for i in range(c // sb):
+        for jj in range(sb):
+            j = sb * i + jj
+            b_j, k_j = (_along(_rows(x, j, j + 1), 8) for x in (b, k))
+            for half in range(jj // 8, 2):
+                at = sb * i + 8 * half
+                d = lax.sub(_rows(b, at, at + 8), b_j)
+                if jj > 8 * half:
+                    d = lax.select(lax.ge(row, np.int32(jj - 8 * half)), d,
+                                   masked)
+                yield i, half, j, lax.exp(d), k_j
+
+
+def _kda_products(t):
+    """``A(k)`` and ``A(q)`` [C, C] float32, zero above the diagonal."""
+    c, key_dim = t["b"].shape
+    sb = KDA_SUB_BLOCK
+    lane = lax.broadcasted_iota(jnp.int32, (8, c), 1)
+    tiles = {}
+    for i in range(c // sb):
+        both = (dot_highest(_kda_pair(t, i), t["k_to"][i], (1, 1)) if i
+                else lax.full((2 * sb, c), 0, jnp.float32))
+        for half in range(2):
+            tiles[i, half] = [_rows(both, x + 8 * half, x + 8 * half + 8)
+                              for x in (0, sb)]
+    for i, half, j, e, k_j in _kda_tiles(t):
+        at = sb * i + 8 * half
+        z = lax.mul(e, k_j)
+        here = lax.eq(lane, np.int32(j))
+        tiles[i, half] = [
+            lax.select(here, _across(sum_keepdims(
+                lax.mul(_rows(t[x], at, at + 8), z), 1), c), acc)
+            for x, acc in zip("kq", tiles[i, half])]
+    return tuple(
+        lax.concatenate([tiles[i, half][x] for i in range(c // sb)
+                         for half in range(2)], 0) for x in range(2))
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, heads,
+                    key_dim, value_dim, fused):
+    """One chunk of ``heads`` heads. q, k [C, G K]; v [C, G V]; g [C, G K]
+    float32; beta [C, H] (``fused``: q, k and ``a`` before the rule's
+    prologue, then ``rate`` and ``bias`` [1, G K]: ``_kda_chunk``) -> o
+    [C, G V] float32, the state each head's chunk entered with [G, V, K],
+    ``(I + L)^-1`` [G, C, C] and ``A(k)`` over ``A(q)`` [G, 2 C, C],
+    float32."""
+    pre, rest = (rest[:2], rest[2:]) if fused else (None, rest)
+    o_ref, ent_ref, inv_ref, tab_ref, state, low_s = rest
+    c = q_ref.shape[0]
+    op, f32 = v_ref.dtype, jnp.float32
+    cast, mul, add, sub = (lax.convert_element_type, lax.mul, lax.add,
+                           lax.sub)
+    masks = _kda_masks(c)
+    zero = lax.full((c, c), 0, f32)
+    first = lax.mul(pl.program_id(1), np.int32(heads))
+
+    @pl.when(first_chunk())
+    def _():
+        state[...] = lax.full(state.shape, 0, f32)
+
+    def head(h, carry):
+        entered = state[h]
+        ent_ref[h] = entered
+        entered_op = cast(entered, op)
+        t = _kda_chunk(q_ref, k_ref, g_ref, beta_ref, pre, h, first,
+                       key_dim, masks)
+        kk, qk = _kda_products(t)
+        tab_ref[h, :c] = kk
+        tab_ref[h, c:] = qk
+        low_s[...] = lax.select(masks["strict"], mul(t["beta"], kk), zero)
+        inv = _gdn_inverse(low_s, masks["eye"])
+        inv_ref[h] = inv
+        at = pl.multiple_of(lax.mul(h, np.int32(value_dim)), LANES)
+        v = cast(v_ref[:, pl.ds(at, value_dim)], f32)
+        kept = sub(v, dot_highest(t["kc"], entered_op, (1, 1)))
+        u = cast(dot_highest(inv, mul(t["beta"], kept), (1, 0)), op)
+        o_ref[:, pl.ds(at, value_dim)] = add(
+            dot_highest(cast(qk, op), u, (1, 0)),
+            dot_highest(t["qc"], entered_op, (1, 1)))
+        state[h] = add(mul(_along(t["whole"], value_dim), entered),
+                       dot_highest(u, t["k_out"], (0, 0)))
+        return carry
+
+    lax.fori_loop(0, heads, head, np.int32(0))
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, *rest, heads,
+                    key_dim, value_dim, fused):
+    """The same chunk with do [C, G V] float32 and the cotangent of the
+    state each head leaves (carried, [G, V, K]) -> dq, dk, dv in the
+    operands' type, dg [C, G K] float32 and beta's cotangent [C, G] (a
+    head a column). ``fused``: the cotangents of q, k and ``a`` BEFORE
+    the prologue (through the unit norms and the softplus), and ``sums``
+    [2, G K]: the chunk's sums over its tokens of ``dg * g`` (a head's
+    lanes summed are its ``a_log``'s cotangent) and of ``a``'s cotangent
+    in float32 (the bias's)."""
+    pre, rest = (rest[:2], rest[2:]) if fused else (None, rest)
+    (ent_ref, inv_ref, tab_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+     dbeta_ref, *sums_ref, dstate, p_s, q_s, col_s) = rest
+    c = q_ref.shape[0]
+    sb = KDA_SUB_BLOCK
+    op, f32 = v_ref.dtype, jnp.float32
+    cast, mul, add, sub = (lax.convert_element_type, lax.mul, lax.add,
+                           lax.sub)
+    masks = _kda_masks(c)
+    zero = lax.full((c, c), 0, f32)
+    first = lax.mul(pl.program_id(1), np.int32(heads))
+    last = lax.eq(lax.broadcasted_iota(jnp.int32, (c, key_dim), 0),
+                  np.int32(c - 1))
+    lane = lax.broadcasted_iota(jnp.int32, (c, heads), 1)
+
+    @pl.when(first_chunk())
+    def _():
+        dstate[...] = lax.full(dstate.shape, 0, f32)
+
+    def head(h, small):
+        entered, dleft = ent_ref[h], dstate[h]
+        entered_op, dleft_op = cast(entered, op), cast(dleft, op)
+        t = _kda_chunk(q_ref, k_ref, g_ref, beta_ref, pre, h, first,
+                       key_dim, masks)
+        q, k, beta = t["q"], t["k"], t["beta"]
+        kk, qk, inv = tab_ref[h, :c], tab_ref[h, c:], inv_ref[h]
+        at = pl.multiple_of(lax.mul(h, np.int32(value_dim)), LANES)
+        v = cast(v_ref[:, pl.ds(at, value_dim)], f32)
+        kept = sub(v, dot_highest(t["kc"], entered_op, (1, 1)))
+        u = dot_highest(inv, mul(beta, kept), (1, 0))
+        u_op = cast(u, op)
+        do_op = cast(do_ref[:, pl.ds(at, value_dim)], op)
+        du = add(dot_highest(cast(qk, op), do_op, (0, 0)),
+                 dot_highest(t["k_out"], dleft_op, (1, 1)))
+        dr = dot_highest(inv, du, (0, 0))                  # T^T du
+        dqk = lax.select(masks["causal"], dot_highest(do_op, u_op, (1, 1)),
+                         zero)
+        dlow = lax.select(masks["strict"],
+                          lax.neg(dot_highest(dr, u, (1, 1))), zero)
+        dkk = mul(beta, dlow)
+        p_s[...] = dkk
+        q_s[...] = dqk
+        beta_dr = mul(beta, dr)
+        dks = cast(lax.neg(beta_dr), op)
+        dqc = dot_highest(do_op, entered_op, (1, 0))       # [C, K]
+        dkc = dot_highest(dks, entered_op, (1, 0))
+        dk_out = dot_highest(u_op, dleft_op, (1, 0))
+        # the tables' cotangents: across sub-blocks through the first token
+        # of the later one, inside one an 8-row tile a column
+        rows, below = {}, lax.full((c, key_dim), 0, f32)
+        for i in range(c // sb):
+            if i:
+                both = cast(lax.concatenate(
+                    [_rows(x, sb * i, sb * i + sb) for x in (dkk, dqk)], 0),
+                    op)
+                from_n = _rows(t["from_n"], sb * i, sb * i + sb)
+                onto = dot_highest(both, t["k_to"][i], (1, 0))  # [32, K]
+                under = dot_highest(both, _kda_pair(t, i), (0, 0))
+                below = add(below, lax.concatenate(
+                    [mul(t["to_n"][i], _rows(under, 0, sb * i)),
+                     lax.full((c - sb * i, key_dim), 0, f32)], 0))
+            for half in range(2):
+                rows[i, half] = [
+                    mul(_rows(from_n, 8 * half, 8 * half + 8),
+                        _rows(onto, x + 8 * half, x + 8 * half + 8))
+                    if i else lax.full((8, key_dim), 0, f32)
+                    for x in (0, sb)]
+        column = None
+        for i, half, j, e, k_j in _kda_tiles(t):
+            lo = sb * i + 8 * half
+            z = mul(e, k_j)
+            p_j, q_j = (_across(ref[lo:lo + 8, j:j + 1], key_dim)
+                        for ref in (p_s, q_s))
+            rows[i, half] = [add(acc, mul(w, z))
+                             for acc, w in zip(rows[i, half], (p_j, q_j))]
+            y = mul(add(mul(p_j, _rows(k, lo, lo + 8)),
+                        mul(q_j, _rows(q, lo, lo + 8))), e)
+            column = y if column is None else add(column, y)
+            if half == 1:
+                col_s[j:j + 1, :] = sum_keepdims(column, 0)
+                column = None
+        dk_rows, dq_rows = (
+            lax.concatenate([rows[i, half][x] for i in range(c // sb)
+                             for half in range(2)], 0) for x in range(2))
+        dk_columns = add(add(col_s[...], below), mul(t["to_end"], dk_out))
+        at_k = pl.multiple_of(lax.mul(h, np.int32(key_dim)), LANES)
+        dq = add(mul(t["c"], dqc), dq_rows)
+        dk_rows = add(mul(t["c"], dkc), dk_rows)
+        dk = add(dk_rows, dk_columns)
+        dv_ref[:, pl.ds(at, value_dim)] = cast(beta_dr, dv_ref.dtype)
+        # b_i multiplies row i of the tables and divides column i, and so
+        # with the decays from the start and to the end; the last token's
+        # carries the whole chunk's decay of the entering state and of
+        # every key
+        leaves = add(
+            sum_keepdims(mul(mul(t["to_end"], k), dk_out), 0),
+            mul(t["whole"], sum_keepdims(mul(dleft, entered), 0)))
+        db = add(add(mul(q, dq), mul(k, sub(dk_rows, dk_columns))),
+                 lax.select(last, _along(leaves, c),
+                            lax.full((c, key_dim), 0, f32)))
+        dg = dot_highest(masks["ones"], db, (0, 0))
+        if fused:  # through the unit norms and the softplus
+            dq, dk = (
+                mul(_across(t["scale_" + x], key_dim), sub(
+                    d, mul(t["unit_" + x], _across(
+                        sum_keepdims(mul(t["unit_" + x], d), 1), key_dim))))
+                for x, d in (("q", dq), ("k", dk)))
+            da = mul(dg, t["slope"])
+            sums_ref[0][:, pl.ds(at_k, key_dim)] = lax.concatenate(
+                [sum_keepdims(mul(dg, t["g"]), 0), sum_keepdims(da, 0)], 0)
+            dg = da
+        dq_ref[:, pl.ds(at_k, key_dim)] = cast(dq, dq_ref.dtype)
+        dk_ref[:, pl.ds(at_k, key_dim)] = cast(dk, dk_ref.dtype)
+        dg_ref[:, pl.ds(at_k, key_dim)] = cast(dg, dg_ref.dtype)
+        dbeta = add(sum_keepdims(mul(dr, kept), 1),
+                    sum_keepdims(mul(dlow, kk), 1))
+        dstate[h] = add(
+            mul(_along(t["whole"], value_dim), dleft),
+            add(dot_highest(do_op, t["qc"], (0, 0)),
+                dot_highest(dks, t["kc"], (0, 0))))
+        return add(small, lax.select(lax.eq(lane, h), _across(dbeta, heads),
+                                     lax.full(lane.shape, 0, f32)))
+
+    dbeta_ref[...] = lax.fori_loop(
+        0, heads, head, lax.full(dbeta_ref.shape, 0, f32))
+
+
+def _kda_name(which, dtype, chunk, key_dim, value_dim, fused):
+    return "kda_%s_%s_c%d_k%d_v%d%s" % (
+        which, operand_label(dtype), chunk, key_dim, value_dim,
+        "_pre" if fused else "")
+
+
+def _kda_specs(chunk, per, heads, key_dim, value_dim, nc, reverse):
+    """Block specs of (a group's columns of q, k and g; of v; beta; its
+    cotangent; the entering states; the inverses; the tables; a row of a
+    group's columns; two rows a chunk of them) at grid step (batch, group,
+    chunk), the chunks walked downwards under ``reverse``."""
+    def at(c):
+        return lax.sub(np.int32(nc - 1), c) if reverse else c
+
+    def columns(width):
+        return pl.BlockSpec((None, chunk, per * width),
+                            lambda b, g, c: (b, at(c), g))
+
+    def by_chunk(rows, width):
+        return pl.BlockSpec((None, None, per, rows, width),
+                            lambda b, g, c: (b, at(c), g, 0, 0))
+
+    return (columns(key_dim), columns(value_dim),
+            pl.BlockSpec((None, chunk, heads), lambda b, g, c: (b, at(c), 0)),
+            pl.BlockSpec((None, None, chunk, per),
+                         lambda b, g, c: (b, g, at(c), 0)),
+            by_chunk(value_dim, key_dim), by_chunk(chunk, chunk),
+            by_chunk(2 * chunk, chunk),
+            pl.BlockSpec((1, per * key_dim), lambda b, g, c: (0, g)),
+            pl.BlockSpec((None, None, 2, per * key_dim),
+                         lambda b, g, c: (b, at(c), 0, g)))
+
+
+def _kda_params(chunk, per, heads, key_dim, value_dim, dtype):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=max(
+            VMEM_SCOPED_DEFAULT,
+            kda_vmem_bytes(chunk, per, heads, key_dim, value_dim,
+                           jnp.dtype(dtype).itemsize)))
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def kda_fwd_call(q, k, v, g, beta, *pre, chunk, interpret):
+    """q, k [B, T, H K], v [B, T, H V], g [B, T, H K] and beta [B, T, H]
+    float32 (with ``pre``, rate and bias [1, H K] float32: q, k and ``a``
+    before the rule's prologue, ``_kda_chunk``) -> o [B, T, H V], the
+    entering states [B, T / C, H, V, K], the systems' inverses [B, T / C,
+    H, C, C] and the tables [B, T / C, H, 2 C, C], float32."""
+    _M_GDN_TRACES.inc(mode="fwd")
+    b, t, h = beta.shape
+    key_dim, value_dim = q.shape[2] // h, v.shape[2] // h
+    per, nc = kda_group(h), t // chunk
+    (narrow, wide, beta_spec, _, state_spec, inv_spec, tab_spec, row_spec,
+     _) = _kda_specs(chunk, per, h, key_dim, value_dim, nc, False)
+    with no_x64():
+        return pl.pallas_call(
+            functools.partial(_kda_fwd_kernel, heads=per, key_dim=key_dim,
+                              value_dim=value_dim, fused=bool(pre)),
+            grid=(b, h // per, nc),
+            in_specs=[narrow, narrow, wide, narrow, beta_spec]
+            + [row_spec] * len(pre),
+            out_specs=[wide, state_spec, inv_spec, tab_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct(v.shape, jnp.float32),
+                jax.ShapeDtypeStruct((b, nc, h, value_dim, key_dim),
+                                     jnp.float32),
+                jax.ShapeDtypeStruct((b, nc, h, chunk, chunk), jnp.float32),
+                jax.ShapeDtypeStruct((b, nc, h, 2 * chunk, chunk),
+                                     jnp.float32)],
+            scratch_shapes=[
+                pltpu.VMEM((per, value_dim, key_dim), jnp.float32),
+                pltpu.VMEM((chunk, chunk), jnp.float32)],
+            compiler_params=_kda_params(chunk, per, h, key_dim, value_dim,
+                                        v.dtype),
+            name=_kda_name("fwd", v.dtype, chunk, key_dim, value_dim,
+                           bool(pre)),
+            interpret=interpret,
+        )(q, k, v, g, beta, *pre)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def kda_bwd_call(q, k, v, g, beta, *rest, chunk, interpret):
+    """``rest``: (rate, bias,) the entering states, the inverses, the
+    tables, do -> dq, dk [B, T, H K] and dv [B, T, H V] in the operands'
+    type, dg [B, T, H K] float32 (with rate and bias: ``a``'s cotangent in
+    its type) and beta's cotangent [B, H / G, T, G], float32; with rate
+    and bias also ``sums`` [B, T / C, 2, H K] (``_kda_bwd_kernel``)."""
+    _M_GDN_TRACES.inc(mode="bwd")
+    b, t, h = beta.shape
+    key_dim, value_dim = q.shape[2] // h, v.shape[2] // h
+    per, nc = kda_group(h), t // chunk
+    fused = len(rest) == 6
+    (narrow, wide, beta_spec, dbeta_spec, state_spec, inv_spec, tab_spec,
+     row_spec, sums_spec) = _kda_specs(chunk, per, h, key_dim, value_dim,
+                                       nc, True)
+    with no_x64():
+        return pl.pallas_call(
+            functools.partial(_kda_bwd_kernel, heads=per, key_dim=key_dim,
+                              value_dim=value_dim, fused=fused),
+            grid=(b, h // per, nc),
+            in_specs=[narrow, narrow, wide, narrow, beta_spec]
+            + [row_spec] * (2 * fused)
+            + [state_spec, inv_spec, tab_spec, wide],
+            out_specs=[narrow, narrow, wide, narrow, dbeta_spec]
+            + [sums_spec] * fused,
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+                jax.ShapeDtypeStruct(g.shape, g.dtype),
+                jax.ShapeDtypeStruct((b, h // per, t, per), jnp.float32)]
+            + [jax.ShapeDtypeStruct((b, nc, 2, h * key_dim), jnp.float32)]
+            * fused,
+            scratch_shapes=[
+                pltpu.VMEM((per, value_dim, key_dim), jnp.float32),
+                pltpu.VMEM((chunk, chunk), jnp.float32),
+                pltpu.VMEM((chunk, chunk), jnp.float32),
+                pltpu.VMEM((chunk, key_dim), jnp.float32)],
+            compiler_params=_kda_params(chunk, per, h, key_dim, value_dim,
+                                        v.dtype),
+            name=_kda_name("bwd", v.dtype, chunk, key_dim, value_dim, fused),
+            interpret=interpret,
+        )(q, k, v, g, beta, *rest)
+
+
+def _kda_chunked(q, k, v, g, beta, chunk):
+    """The rule in the ``jax.numpy`` chunk form on the kernels' operands
+    (a head's columns side by side), o as they give it ([B, T, H V]
+    float32): the branch for every platform but the TPU."""
+    from ..transformer import channel_delta_rule as chunk_form
+
+    b, t, h = beta.shape
+    q, k, v, g = (x.reshape(b, t, h, -1) for x in (q, k, v, g))
+    return chunk_form(q, k, v, g, beta, chunk).reshape(b, t, -1)
+
+
+def _kda_net_chunked(q, k, v, a, beta, a_log, dt_bias, chunk):
+    """``_gated_delta_block``'s ``delta_rule`` stage in its channel form as
+    it stands there (the unit norms, the decays a channel, the ``jax.numpy``
+    chunk form), on the fused pair's operands."""
+    f32 = jnp.float32
+    b, t, h = beta.shape
+    key_dim = q.shape[2] // h
+
+    def unit(x):  # each head's vector over its length, float32
+        x = x.astype(f32).reshape(b, t, h, -1)
+        return x * lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    g = -jnp.exp(a_log[:, None].astype(f32)) * jax.nn.softplus(
+        a.reshape(b, t, h, key_dim).astype(f32)
+        + dt_bias.reshape(h, key_dim).astype(f32))
+    return _kda_chunked(
+        (unit(q) * key_dim ** -0.5).astype(v.dtype).reshape(q.shape),
+        unit(k).astype(v.dtype).reshape(k.shape), v,
+        g.reshape(b, t, -1), beta, chunk)
+
+
+def _kda_rule(fused):
+    """The ``custom_vjp`` over (q, k, v, g, beta) and, ``fused``, over (q,
+    k, v, a, beta, a_log, dt_bias): one plan, the platform's branch chosen
+    inside the forward and inside the rule."""
+    plain = _kda_net_chunked if fused else _kda_chunked
+    n = 7 if fused else 5
+
+    def rows(*pre):
+        """``-exp(a_log)`` along each head's lanes and the bias, [1, H K]
+        float32."""
+        if not fused:
+            return ()
+        a_log, dt_bias = pre
+        key_dim = dt_bias.shape[0] // a_log.shape[0]
+        return (jnp.repeat(-jnp.exp(a_log.astype(jnp.float32)),
+                           key_dim)[None],
+                dt_bias.astype(jnp.float32)[None])
+
+    def whole_chunks(chunk, *xs):
+        """[B, T, ...] padded with zeros to whole chunks: no write (k 0,
+        beta 0), and nothing reads the state behind the last token."""
+        pad = -xs[0].shape[1] % chunk
+        return tuple(jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+                     for x in xs) if pad else xs
+
+    @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+    def forward(*ins, chunk, interpret):
+        """The inputs, o [B, T, H V] float32 and the backward's other
+        residuals: the state each chunk entered with, its system's
+        inverse and its two tables (zeros off the TPU, where the chunk
+        form's own transpose is the backward)."""
+        b, t, h = ins[4].shape
+        key_dim, value_dim = ins[0].shape[2] // h, ins[2].shape[2] // h
+
+        def kernels(*ins, interpret):
+            o, *kept = kda_fwd_call(
+                *whole_chunks(chunk, *ins[:5]), *rows(*ins[5:]), chunk=chunk,
+                interpret=interpret)
+            return (o[:, :t],) + tuple(kept)
+
+        def chunked(*ins):
+            return (plain(*ins, chunk),) + tuple(
+                jnp.zeros((b, -(-t // chunk), h) + tail, jnp.float32)
+                for tail in ((value_dim, key_dim), (chunk, chunk),
+                             (2 * chunk, chunk)))
+
+        return ins + tuple(on_tpu(kernels, chunked, interpret, *ins))
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(n, n + 1))
+    def rule(*args):
+        return rule_fwd(*args)[0]
+
+    def rule_fwd(*args):
+        # one trace of the forward for the primal and the rule: see _ssd_fwd
+        with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+            res = forward(*args[:n], chunk=args[n], interpret=args[n + 1])
+        return res[n], res[:n] + res[n + 1:]
+
+    def rule_bwd(chunk, interpret, res, do):
+        b, t, h = res[4].shape
+
+        def kernels(*ins, interpret):
+            dq, dk, dv, dg, dbeta, *sums = kda_bwd_call(
+                *whole_chunks(chunk, *ins[:5]), *rows(*ins[5:n]), *ins[n:-1],
+                *whole_chunks(chunk, ins[-1]), chunk=chunk,
+                interpret=interpret)
+            grads = tuple(x[:, :t] for x in (
+                dq, dk, dv, dg, jnp.moveaxis(dbeta, 1, 2).reshape(b, -1, h)))
+            if not fused:
+                return grads
+            # a head's lanes of the first row are its a_log's cotangent
+            by_lane = jnp.sum(sums[0], axis=(0, 1))
+            return grads + (
+                jnp.sum(by_lane[0].reshape(h, -1), 1).astype(ins[5].dtype),
+                by_lane[1].astype(ins[6].dtype))
+
+        def chunked(*ins):
+            return jax.vjp(lambda *x: plain(*x, chunk), *ins[:n])[1](ins[-1])
+
+        return on_tpu(kernels, chunked, interpret, *res,
+                      do.astype(jnp.float32))
+
+    rule.defvjp(rule_fwd, rule_bwd)
+    return rule, forward
+
+
+_kda, kda_forward = _kda_rule(False)
+_kda_net, kda_net_forward = _kda_rule(True)
+
+
+def channel_delta_rule(q, k, v, g, beta, chunk, interpret=False):
+    """``ops/transformer.py::channel_delta_rule`` (q and k [B, T, H, K], v
+    [B, T, H, V] in one type, g [B, T, H, K] and beta [B, T, H] -> o [B, T,
+    H, V] float32) as a Pallas kernel pair, differentiable in all five, for
+    the shapes ``gdn_takes(..., "channel")`` admits. T is padded to whole
+    chunks as ``gated_delta_rule`` pads it (on the kernels' branch: the
+    chunk form pads itself); the kernels read and write token-major, a
+    head's lane rows a column block of [B, T, H K]. Mosaic where the
+    computation is lowered for the TPU, the ``jax.numpy`` chunk form itself
+    on every other platform, ``interpret=True`` the kernels through the
+    Pallas interpreter wherever; no partitioning rule
+    (``gated_delta_rule``'s notes). What the op calls is
+    ``channel_delta_net`` below: these arrays [B, T, H, K] are not laid out
+    as the kernels' [B, T, H K] are (a tile is eight heads of one token,
+    not eight tokens of one head), so round this entry XLA moves them."""
+    b, t, h, _ = q.shape
+    f32 = jnp.float32
+    o = _kda(*(x.reshape(b, t, -1) for x in (q, k, v, g.astype(f32))),
+             beta.astype(f32), int(chunk), bool(interpret))
+    return o.reshape(b, t, h, -1)
+
+
+def channel_delta_net(q, k, v, a, beta, a_log, dt_bias, chunk,
+                      interpret=False):
+    """``GatedDeltaNet``'s ``delta_rule`` stage in its channel form, from
+    the convolution's outputs to the rule's: q, k and ``a`` [B, T, H K], v
+    [B, T, H V] as the projections and the taps' pair leave them, beta [B,
+    T, H] float32, a_log [H], dt_bias [H K] -> o [B, T, H V] float32,
+    differentiable in all seven. The unit norms, ``g = -exp(a_log)
+    softplus(a + dt_bias)`` and its running sums are made in VMEM a chunk
+    at a time (``_kda_chunk``) and their transposes in the backward
+    kernel, so no array [B, T, H, K] exists and XLA moves nothing round
+    the pair; what is kept for the backward is the inputs and the pair's
+    three residuals. Branches and padding as ``channel_delta_rule``'s (a
+    padded token: q, k, v and ``a`` 0, beta 0; its decay is not 1, and
+    nothing reads the state behind the last token)."""
+    return _kda_net(q, k, v, a, beta.astype(jnp.float32), a_log, dt_bias,
+                    int(chunk), bool(interpret))

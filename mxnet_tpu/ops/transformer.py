@@ -716,7 +716,7 @@ _M_LINEAR_ATTN_LOWERINGS = _tm.counter(
     "head's widths), chunk (tokens a chunk of the delta rule), conv (the "
     "convolution's taps), impl (kernel: the Pallas pair where the step is "
     "lowered for the TPU, the chunk form elsewhere; chunked: the jax.numpy "
-    "chunk form everywhere)")
+    "chunk form everywhere), decay=channel (gdn.py's kda_ pair), gate")
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk):
@@ -809,46 +809,39 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     ``q, k, v = silu(conv(.))``, a causal depthwise convolution over time
     without bias (scope ``conv1d``; each of the three arrays the taps'
     Pallas kernel pair where ``kernels.taps_takes`` has tiles for it and
-    the step is lowered for the TPU, a width that is no multiple of 128
-    lanes taken whole, ``causal_taps`` elsewhere); ``q = q / |q| /
-    sqrt(K)`` and ``k =
-    k / |k|`` a head (``|x|`` = sqrt(sum x^2 + 1e-6)), ``beta = 2
-    sigmoid(b)`` (``allow_neg_eigval``; without it the 2 goes), ``g =
-    -exp(a_log) softplus(a + dt_bias)``, ``o = gated_delta_rule(...)``
-    (scope ``delta_rule``); ``RMSNorm(o) norm_gamma silu(gate)`` with the
-    statistics over each head's V columns (scope ``gate_norm``: the norm
-    first, then the gate; ``Mamba2`` gates first). The convolution's
-    sum, the two norms, write strengths, decays, the triangular solve,
-    the state and the gate are float32 whatever the inputs' dtype. The
-    rule is the Pallas kernel pair where the shapes have tiles for it and
-    the step is lowered for the TPU (``kernels.gdn_takes`` /
-    ``gated_delta_rule``), the ``jax.numpy`` chunk form elsewhere.
-    ``remat`` (training): each of the three scopes is computed again in
-    the backward pass from its inputs, nothing inside it is kept
-    (``jax.checkpoint``); of ``delta_rule`` on the kernel path that is
-    the unit norms, ``beta`` and ``g`` only: the kernel pair keeps its
-    own residuals (the state each chunk entered with and its system's
-    inverse) and runs once each way, as the taps' pair does on its
-    inputs. Where ``kernels.gate_norm_takes`` has tiles the gate and
-    norm are ``gated_rms_norm`` (``norm_first``), one kernel each way on
-    ``o`` head-major as the rule's kernel wrote it: no move between them.
+    the step is lowered for the TPU, ``causal_taps`` elsewhere); ``q = q /
+    |q| / sqrt(K)`` and ``k = k / |k|`` a head (``|x|`` = sqrt(sum x^2 +
+    1e-6)), ``beta = 2 sigmoid(b)`` (``allow_neg_eigval``; without it the 2
+    goes), ``g = -exp(a_log) softplus(a + dt_bias)``, ``o =
+    gated_delta_rule(...)`` (scope ``delta_rule``); ``RMSNorm(o) norm_gamma
+    silu(gate)`` with the statistics over each head's V columns (scope
+    ``gate_norm``: the norm first, then the gate). The convolution's sum,
+    the two norms, write strengths, decays, the triangular solve, the
+    state and the gate are float32 whatever the inputs' dtype. The rule is
+    the Pallas kernel pair where the shapes have tiles for it and the step
+    is lowered for the TPU (``kernels.gdn_takes``), the ``jax.numpy``
+    chunk form elsewhere. ``remat`` (training): each of the three scopes
+    is computed again in the backward pass from its inputs
+    (``jax.checkpoint``); of ``delta_rule`` on the kernel path that is the
+    unit norms, ``beta`` and ``g`` only: the kernel pair keeps its own
+    residuals and runs once each way. Where ``kernels.gate_norm_takes``
+    has tiles the gate and norm are ``gated_rms_norm`` (``norm_first``) on
+    ``o`` head-major as the rule's kernel wrote it.
 
     **A decay a channel** (Kimi Delta Attention, arXiv:2510.26692), taken
     by the shape of ``a``: [B, T, H K] with ``dt_bias`` [H K] (``a_log``
     stays [H]) gives ``g = -exp(a_log_h) softplus(a + dt_bias)`` a key
-    channel, and the rule is ``channel_delta_rule`` below, the
-    ``jax.numpy`` chunk form on every platform (``kernels.gdn_takes``
-    refuses the form). ``gate_act="sigmoid"``: the gate behind the norm
-    is a sigmoid, in the ``gate_norm`` closure everywhere
-    (``gated_rms_norm`` knows ``silu``). Both are counted where they are
-    not the default (``decay="channel"``, ``gate="sigmoid"``); the scalar
-    signature traces what it always did.
+    channel and the rule ``channel_delta_rule`` below. Where
+    ``kernels.gdn_takes(..., "channel")`` has tiles (a head whole lane
+    rows) the block is ``_channel_delta_block``: the pair ``kda_fwd_`` /
+    ``kda_bwd_`` from the taps' outputs on, the unit norms and decays made
+    in VMEM. ``gate_act="sigmoid"``: the gate behind the norm is a
+    sigmoid, in the ``gate_norm`` closure everywhere (``gated_rms_norm``
+    knows ``silu``). Both are counted where they are not the default.
 
     The call site counts itself here (``linear_attn.lowerings``,
     ``gate_norm.lowerings``, ``causal_taps.lowerings`` once a convolved
-    array); the
-    block itself is ``_gated_delta_block``, one ``jax.jit`` for every node
-    of one signature."""
+    array); the block is ONE ``jax.jit`` for every node of a signature."""
     from . import kernels
 
     key_dim, value_dim = (x.shape[2] // num_heads for x in (query, value))
@@ -872,8 +865,15 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     # the norm's kernel reads o where the rule's kernel left it, head-major
     norm_kernel = _gate_norm_site(
         "gated_delta_net", "norm_first", num_heads, value_dim, gate,
-        core=kernel and query.shape[1] % chunk_size == 0
+        core=kernel and not channel and query.shape[1] % chunk_size == 0
         and gate_act == "silu")
+    if channel and kernel:  # the rule's pair from the taps' outputs on
+        return _channel_delta_block(
+            query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
+            norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
+            eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
+            remat=bool(remat), taps_kernel=taps_kernel,
+            interpret=kernels.common.INTERPRET, gate_act=gate_act)
     return _gated_delta_block(
         query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
         norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
@@ -1368,8 +1368,13 @@ def channel_delta_rule(q, k, v, g, beta, chunk):
     Diag(exp(b_C)) S + (exp(b_C - b) * k)^T u``. Decays, their sums, the
     tables, the solve and the state are float32; the products take
     operands of ``v``'s dtype and accumulate in float32. T is padded to
-    whole chunks as ``gated_delta_rule`` pads it. The one form on every
-    platform: ``kernels.gdn_takes`` refuses a decay a channel."""
+    whole chunks as ``gated_delta_rule`` pads it. The form for every
+    platform but the TPU and for the shapes ``kernels.gdn_takes(...,
+    "channel")`` has no tiles for (a head that is not whole lane rows, a
+    chunk no sub-block divides); the others are the pair ``kda_fwd_`` /
+    ``kda_bwd_`` of ``ops/kernels/gdn.py`` where the step is lowered for
+    the TPU (``_channel_delta_block``), held to this form by
+    ``tests/test_gated_delta_kernel.py``."""
     f32 = jnp.float32
     b, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -1465,3 +1470,60 @@ def _decayed_products(q, k, cum, sub, dot):
         blocks = d[..., :, :, None, :] * own          # block-diagonal
         out.append(full + blocks.reshape(lead + (chunk, chunk)))
     return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "chunk", "eps", "beta_scale", "remat", "taps_kernel",
+    "interpret", "gate_act"))
+def _channel_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
+                         dt_bias, norm_gamma, *, heads, chunk, eps,
+                         beta_scale, remat, taps_kernel, interpret,
+                         gate_act):
+    """``gated_delta_net`` with a decay a channel where the rule's kernel
+    pair has tiles (``kernels.gdn_takes(..., "channel")``), one signature:
+    ``_gated_delta_block``'s three scopes with ``delta_rule`` ONE call,
+    ``kernels.channel_delta_net``, from the convolution's outputs to ``o``
+    [B, T, H V]. The unit norms, the decays and their running sums are
+    made in VMEM, a chunk at a time, and again in the backward kernel:
+    under ``remat`` nothing of the scope is computed twice but the write
+    strengths, and no array [B, T, H, K] exists between the taps' pair and
+    the norm (as a reshape of [B, T, H K] it is a move on the TPU: a tile
+    is eight heads of a token there and eight tokens of a head here).
+    Off the TPU the call is the chunk form on the same values. (Down here
+    so that no line of ``_gated_delta_block`` moves: the scalar pair's
+    call sits in it.)"""
+    from . import kernels
+
+    f32 = jnp.float32
+    bsz, t, _ = query.shape
+    dk, dv = query.shape[2] // heads, value.shape[2] // heads
+
+    def again(f):
+        return jax.checkpoint(f) if remat else f
+
+    def conv1d(x, w, takes):
+        if takes:
+            return kernels.causal_conv(x, w, form="silu",
+                                       interpret=interpret)
+        return again(lambda x, w: jax.nn.silu(
+            causal_taps(x, w)).astype(x.dtype))(x, w)
+
+    def gate_norm(o, gate, norm_gamma):
+        o = o.reshape(bsz, t, heads, dv)
+        var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+        normed = o * jax.lax.rsqrt(var + eps) * norm_gamma.astype(f32)
+        gated = normed.reshape(bsz, t, heads * dv) * getattr(
+            jax.nn, gate_act)(gate.astype(f32))
+        return gated.astype(gate.dtype)
+
+    with jax.named_scope("conv1d"):
+        edges = (0, heads * dk, 2 * heads * dk, 2 * heads * dk + heads * dv)
+        q, k, v = (conv1d(x, conv_weight[:, lo:hi], takes)
+                   for x, lo, hi, takes in zip((query, key, value), edges,
+                                               edges[1:], taps_kernel))
+    with jax.named_scope("delta_rule"):
+        beta = again(lambda b: beta_scale * jax.nn.sigmoid(b.astype(f32)))(b)
+        o = kernels.channel_delta_net(q, k, v, a, beta, a_log, dt_bias,
+                                      chunk, interpret=interpret)
+    with jax.named_scope("gate_norm"):
+        return again(gate_norm)(o, gate, norm_gamma)

@@ -50,10 +50,12 @@ def _rms(got, want):
 
 
 def recurrence(q, k, v, g, beta):
-    """The rule, one token after another, in float32."""
+    """The rule, one token after another, in float32: g [B, T, H], a decay
+    a head, or [B, T, H, K], a decay a key channel."""
     def token(state, at):                                     # [B, H, K, V]
         q_t, k_t, v_t, g_t, beta_t = at
-        state = jnp.exp(g_t)[..., None, None] * state
+        state = jnp.exp(g_t if g.ndim == 4 else g_t[..., None])[
+            ..., None] * state
         u_t = beta_t[..., None] * (
             v_t - jnp.sum(state * k_t[..., None], axis=2))
         state = state + k_t[..., None] * u_t[:, :, None, :]
@@ -92,6 +94,10 @@ def _both_ways(form, chunk):
     f = {"kernels": functools.partial(pk.gated_delta_rule, chunk=chunk,
                                       interpret=True),
          "chunked": functools.partial(gated_delta_rule, chunk=chunk),
+         "channel_kernels": functools.partial(
+             pk.gdn.channel_delta_rule, chunk=chunk, interpret=True),
+         "channel_chunked": functools.partial(tr.channel_delta_rule,
+                                              chunk=chunk),
          "recurrence": recurrence}[form]
 
     def loss(*a):
@@ -431,3 +437,265 @@ def test_a_fit_of_three_linear_layers_traces_each_kernel_once():
         telemetry.disable()
         telemetry.reset()
     assert np.isfinite(seen).all() and seen[-1] < seen[0], seen
+
+
+# -- a decay a channel (Kimi Delta Attention): the pair kda_fwd_ / kda_bwd_ ---
+
+def _channel_inputs(seed, batch, t, heads, dk, dv, dtype=jnp.float32,
+                    g_min=-0.5, repeat=0):
+    """``_inputs`` with a log decay a channel, uniform in (``g_min``, 0)."""
+    ins, cot = _inputs(seed, batch, t, heads, dk, dv, dtype, repeat)
+    rng = np.random.RandomState(seed + 100)
+    g = (-1e-3 if repeat else g_min) * rng.rand(batch, t, heads, dk)
+    return ins[:3] + (jnp.asarray(g, jnp.float32), ins[4]), cot
+
+
+def _channel_both_ways(form, chunk):
+    """``_both_ways`` of the forms whose g is [B, T, H, K]."""
+    return _both_ways(form if form == "recurrence" else "channel_" + form,
+                      chunk)
+
+
+# (batch, T, heads, K, V, chunk): the Kimi Linear cell's head (a lane row
+# of keys, one of values) in chunks of 64 over a ragged length; ten heads,
+# so two groups of five a step, in chunks of one sub-block; values of two
+# lane rows in chunks of two sub-blocks, ragged
+CHANNEL_SHAPES = {
+    "the_cells_head_ragged": (1, 130, 3, 128, 128, 64),
+    "two_head_groups": (2, 32, 10, 128, 128, 16),
+    "values_of_two_lane_rows": (1, 70, 2, 128, 256, 32),
+}
+
+
+@pytest.mark.parametrize("shape", list(CHANNEL_SHAPES))
+def test_the_channel_pair_matches_the_chunk_form_and_the_recurrence(shape):
+    """Output and the gradient of q, k, v, g ([B, T, H, K]) and beta in
+    float32, to summation order, against both."""
+    *sizes, chunk = CHANNEL_SHAPES[shape]
+    assert pk.gdn_takes(*sizes[2:], chunk, jnp.float32, "channel")
+    ins, cot = _channel_inputs(0, *sizes)
+    o, grads = _channel_both_ways("kernels", chunk)(ins, cot)
+    for other in ("chunked", "recurrence"):
+        o_w, grads_w = _channel_both_ways(other, chunk)(ins, cot)
+        _close(o, o_w, "o against " + other, ulps=32)
+        for name, got, want in zip(GRADS, grads, grads_w):
+            assert got.shape == want.shape and got.dtype == want.dtype
+            assert float(jnp.abs(want).max()) > 1e-6, name
+            _close(got, want, name + " against " + other, ulps=128)
+
+
+def test_the_channel_pair_in_bf16_is_where_the_chunk_form_is():
+    """bf16 q, k and v at the cell's head: both forms round the same
+    operands to bf16 and keep decays, tables, the system, its solution and
+    the state float32, so each is a small share of a tensor's rms from the
+    float32 recurrence, the kernels no farther than the chunk form."""
+    *sizes, chunk = CHANNEL_SHAPES["the_cells_head_ragged"]
+    assert pk.gdn_takes(*sizes[2:], chunk, jnp.bfloat16, "channel")
+    ins, cot = _channel_inputs(3, *sizes, dtype=jnp.bfloat16)
+    exact = tuple(v.astype(jnp.float32) for v in ins)
+    o, ours = _channel_both_ways("kernels", chunk)(ins, cot)
+    o_c, theirs = _channel_both_ways("chunked", chunk)(ins, cot)
+    o_w, want = _channel_both_ways("recurrence", chunk)(exact, cot)
+    assert o.dtype == jnp.float32
+    assert _rms(o, o_w) < max(1.5 * _rms(o_c, o_w), 0.004)
+    for name, g, c, w in zip(GRADS, ours, theirs, want):
+        assert g.dtype == c.dtype and g.shape == c.shape, name
+        assert _rms(g, w) < max(1.5 * _rms(c, w), 0.004), (
+            name, _rms(g, w), _rms(c, w))
+
+
+@pytest.mark.parametrize("g_min", [-20.0, -3.0])
+def test_the_channel_pair_takes_no_exponential_of_a_positive_number(g_min):
+    """LOG DECAYS DOWN TO -20 A TOKEN (the range of ``tests/
+    test_kimi_linear.py::test_the_factored_form_would_overflow_where_the_
+    chunk_form_is_exact``): over a chunk of 64 the running sum reaches
+    -1,280 and the factored operands ``k exp(-b)`` are inf from the fifth
+    token on. The pair's output and gradients are finite and the chunk
+    form's, and both are the recurrence's."""
+    chunk = 64
+    ins, cot = _channel_inputs(5, 1, 128, 2, 128, 128, g_min=g_min)
+    if g_min < -10:
+        cum = jnp.cumsum(ins[3][:, :chunk], axis=1)
+        assert not np.isfinite(np.asarray(ins[1][:, :chunk]
+                                          * jnp.exp(-cum))).all()
+    o, grads = _channel_both_ways("kernels", chunk)(ins, cot)
+    assert np.isfinite(np.asarray(o)).all()
+    for other in ("chunked", "recurrence"):
+        o_w, grads_w = _channel_both_ways(other, chunk)(ins, cot)
+        _close(o, o_w, "o against " + other, ulps=32)
+        for name, got, want in zip(GRADS, grads, grads_w):
+            assert np.isfinite(np.asarray(got)).all(), name
+            _close(got, want, name + " against " + other, ulps=128)
+
+
+def test_one_decay_a_head_through_the_channel_pair_is_the_scalar_pair():
+    """Every channel of a head given one decay: the channel pair computes
+    what the scalar pair does, output and gradients (g's summed over the
+    channels it was spread to)."""
+    chunk = 32
+    ins, cot = _inputs(6, 1, 70, 2, 128, 128)
+    spread = jax.jit(jax.value_and_grad(
+        lambda q, k, v, g, beta: jnp.sum(pk.gdn.channel_delta_rule(
+            q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta, chunk,
+            interpret=True) * cot), EVERY))
+    (_, got), (o_w, want) = spread(*ins), _both_ways("kernels", chunk)(
+        ins, cot)
+    _close(pk.gdn.channel_delta_rule(
+        *ins[:3], jnp.broadcast_to(ins[3][..., None], ins[0].shape),
+        ins[4], chunk, interpret=True), o_w, "o", ulps=32)
+    for name, g, w in zip(GRADS, got, want):
+        _close(g, w, name, ulps=128)
+
+
+def test_the_channel_pair_solves_repeated_keys_by_substitution():
+    """The repeated-keys case on the channel pair: four directions over
+    and over, strengths near 2, nothing forgotten. As near the recurrence
+    as the chunk form's ``solve_triangular``."""
+    chunk = 64
+    ins, cot = _channel_inputs(7, 1, 128, 2, 128, 128, repeat=4)
+    o_w, want = _channel_both_ways("recurrence", chunk)(ins, cot)
+    o, ours = _channel_both_ways("kernels", chunk)(ins, cot)
+    o_c, theirs = _channel_both_ways("chunked", chunk)(ins, cot)
+    scale = float(jnp.abs(o_w).max())
+    err, err_c = (float(jnp.abs(x - o_w).max()) / scale for x in (o, o_c))
+    assert err <= max(2 * err_c, 1e-4), (err, err_c)
+    for name, g, c, w in zip(GRADS, ours, theirs, want):
+        top = float(jnp.abs(w).max())
+        e, e_c = (float(jnp.abs(x - w).max()) / top for x in (g, c))
+        assert e <= max(2 * e_c, 1e-3), (name, e, e_c)
+
+
+def test_the_channel_pair_takes_the_kimi_cells_shape_and_refuses_the_rest():
+    take = functools.partial(pk.gdn_takes, decay="channel")
+    assert take(32, 128, 128, 64, jnp.bfloat16)             # the cell's
+    assert take(32, 128, 128, 64, jnp.float32)
+    assert take(3, 128, 256, 16, jnp.float32)
+    assert take(16, 256, 128, 128, jnp.bfloat16)
+    assert pk.gdn.kda_group(32) == 8 and pk.gdn.kda_group(10) == 5
+    for heads, dk, dv, chunk, dtype in [
+            (3, 8, 12, 16, jnp.float32),         # the tiny symbol's
+            (32, 96, 192, 64, jnp.bfloat16),     # a head no whole lane rows
+            (32, 128, 192, 64, jnp.bfloat16),
+            (32, 128, 128, 24, jnp.bfloat16),    # no sub-block divides it
+            (32, 128, 128, 8, jnp.bfloat16),
+            (32, 128, 128, 256, jnp.bfloat16),   # a chunk over a lane row
+            (32, 128, 128, 64, jnp.float16),     # not Mosaic's operand
+            (8, 2048, 4096, 128, jnp.float32),   # a step over VMEM
+            (0, 128, 128, 64, jnp.bfloat16)]:
+        assert not take(heads, dk, dv, chunk, dtype), (heads, dk, dv, chunk)
+    assert not pk.gdn_takes(32, 128, 128, 64, jnp.bfloat16, "matrix")
+    assert (pk.gdn.kda_vmem_bytes(128, 8, 8, 2048, 4096, 4)
+            > pk.common.VMEM_RAISED_LIMIT)
+
+
+# the op in its channel form at a shape the pair takes
+CH, CD, CCHUNK, CT = 2, 128, 16, 40
+
+
+def _channel_op_inputs(seed, dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+    step = np.exp(rng.uniform(np.log(0.001), np.log(0.1), CH * CD))
+
+    def draw(*shape, scale=1.0, shift=0.0):
+        return jnp.asarray(shift + scale * rng.randn(*shape), dtype)
+
+    return (draw(BATCH, CT, CH * CD), draw(BATCH, CT, CH * CD),
+            draw(BATCH, CT, CH * CD), draw(BATCH, CT, CH * CD),
+            draw(BATCH, CT, CH * CD, scale=2.0), draw(BATCH, CT, CH),
+            draw(TAPS, 3 * CH * CD, scale=0.3),
+            jnp.asarray(np.log(rng.uniform(1, 16, CH)), dtype),
+            jnp.asarray(step + np.log(-np.expm1(-step)), dtype),
+            draw(CD, scale=0.1, shift=1.0),
+            jnp.asarray(rng.randn(BATCH, CT, CH * CD), jnp.float32))
+
+
+def _channel_block(kernel, interpret, remat, ins, cot):
+    """Output and gradients of the block the op hands such a call to
+    (``kernel``: ``_channel_delta_block``, the pair from the taps' outputs
+    on) or of ``_gated_delta_block`` in the ``jax.numpy`` chunk form."""
+    kw = dict(heads=CH, chunk=CCHUNK, eps=1e-6, beta_scale=1.0, remat=remat,
+              taps_kernel=(False,) * 3, interpret=interpret,
+              gate_act="sigmoid")
+    block = (tr._channel_delta_block if kernel else functools.partial(
+        tr._gated_delta_block, kernel=False, norm_kernel=False))
+
+    def loss(*a):
+        o = block(*a, **kw)
+        return jnp.sum(o * cot), o
+    return jax.jit(jax.value_and_grad(loss, tuple(range(len(ins))),
+                                      has_aux=True))(*ins)
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_the_channel_op_through_the_pair_is_the_op_in_the_chunk_form(remat):
+    """``GatedDeltaNet`` with ``a`` as wide as the keys and a sigmoid gate,
+    the rule's pair through the interpreter (what the TPU's branch
+    computes) against the op in the ``jax.numpy`` chunk form: forward and
+    the gradient of all ten inputs, in training too."""
+    *ins, cot = _channel_op_inputs(0)
+    assert pk.gdn_takes(CH, CD, CD, CCHUNK, jnp.float32, "channel")
+    tr._channel_delta_block.clear_cache()
+    (_, o), got = _channel_block(True, True, remat, ins, cot)
+    (_, o_w), want = _channel_block(False, False, remat, ins, cot)
+    _close(o, o_w, "out", ulps=32)
+    for name, g, w in zip(OP_GRADS, got, want):
+        assert g.shape == w.shape
+        _close(g, w, name, ulps=256)
+    tr._channel_delta_block.clear_cache()
+
+
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_off_the_tpu_the_channel_ops_values_are_the_chunk_forms_bit_for_bit(
+        remat):
+    """A step lowered for the CPU at a shape the channel pair takes
+    computes what the op computed before there was a pair: output and
+    every gradient equal to the bit, as one program."""
+    *ins, cot = _channel_op_inputs(1)
+    tr._channel_delta_block.clear_cache()
+    (_, o), got = _channel_block(True, False, remat, ins, cot)
+    (_, o_w), want = _channel_block(False, False, remat, ins, cot)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(o_w))
+    for name, g, w in zip(OP_GRADS, got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), name)
+
+
+def test_a_channel_training_step_holds_each_kernel_once_never_interpreted():
+    """The gradient's program of the ``GatedDeltaNet`` op in its channel
+    form in training: ONE ``kda_fwd_`` and one ``kda_bwd_`` (the pair keeps
+    its own residuals and makes the unit norms and decays itself: ``_pre``),
+    both for Mosaic and neither the scalar pair;
+    lowered for the CPU it holds no kernel at all and runs, and the call
+    site counted itself ``impl="kernel", decay="channel"``."""
+    tr._channel_delta_block.clear_cache()
+    *ins, cot = _channel_op_inputs(2)
+    attrs = dict(num_heads=CH, chunk_size=CCHUNK, allow_neg_eigval=False,
+                 gate_act="sigmoid")
+
+    def loss(*a):
+        return jnp.sum(tr._gated_delta_net(attrs, list(a), True)[0] * cot)
+
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        grad = jax.jit(jax.grad(loss, tuple(range(len(ins)))))
+        calls = list(_pallas_calls(grad.trace(*ins).jaxpr.jaxpr))
+        sites = telemetry.REGISTRY.get("linear_attn.lowerings")
+        assert sites.value(heads=CH, key_dim=CD, value_dim=CD, chunk=CCHUNK,
+                           conv=TAPS, impl="kernel", decay="channel",
+                           gate="sigmoid") == 1
+        assert telemetry.total("linear_attn.lowerings") == 1
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    names = sorted(str(c.params["name"]) for c in calls
+                   if "taps_" not in str(c.params["name"]))
+    assert names == ["kda_bwd_f32_c16_k128_v128_pre",
+                     "kda_fwd_f32_c16_k128_v128_pre"], names
+    assert not any(c.params["interpret"] for c in calls)
+    lowered = grad.lower(*ins)
+    text = lowered.as_text()
+    assert "tpu_custom_call" not in text and "kda_fwd" not in text
+    got = lowered.compile()(*ins)
+    (_, _), want = _channel_block(False, False, True, ins, cot)
+    for name, g, w in zip(OP_GRADS, got, want):
+        _close(g, w, name, ulps=256)

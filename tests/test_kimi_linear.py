@@ -320,11 +320,18 @@ def test_the_scalar_path_is_bit_equal_to_the_parents(dtype):
 
 
 def test_the_kernel_pairs_refuse_the_channel_form_and_the_sigmoid_gate():
-    """The scalar rule's Pallas pair is never handed a vector decay, and
-    the gate and norm's pair (which knows ``silu``) never a sigmoid; the
-    taps' pair takes the three 4,096-column convolutions as it stands."""
+    """What is true since the channel rule has its own Pallas pair (the
+    name is the test's of PR 53, when neither was taken): at the cell's
+    shape (32 heads of 128 / 128, chunks of 64, bf16) the rule's pair
+    takes a decay a channel (``impl="kernel", decay="channel"``) and
+    refuses what it has no tiles for; the gate and norm's pair (which
+    knows ``silu``) is still never handed a sigmoid; the taps' pair takes
+    the three 4,096-column convolutions as it stands."""
     assert pk.gdn_takes(32, 128, 128, 64, jnp.bfloat16)
-    assert not pk.gdn_takes(32, 128, 128, 64, jnp.bfloat16, "channel")
+    assert pk.gdn_takes(32, 128, 128, 64, jnp.bfloat16, "channel")
+    assert not pk.gdn_takes(32, 96, 192, 64, jnp.bfloat16, "channel")
+    assert not pk.gdn_takes(32, 128, 128, 24, jnp.bfloat16, "channel")
+    assert not pk.gdn_takes(H, D, D, CHUNK, jnp.float32, "channel")
     assert pk.taps_takes(4096, 8192, 4, jnp.bfloat16, "silu", 0, 4096)
     telemetry.reset()
     telemetry.enable()
@@ -341,8 +348,9 @@ def test_the_kernel_pairs_refuse_the_channel_form_and_the_sigmoid_gate():
             jax.ShapeDtypeStruct((128,), jnp.bfloat16))
         rule = telemetry.REGISTRY.get("linear_attn.lowerings")
         assert rule.value(heads=32, key_dim=128, value_dim=128, chunk=64,
-                          conv=4, impl="chunked", decay="channel",
+                          conv=4, impl="kernel", decay="channel",
                           gate="sigmoid") == 1
+        assert telemetry.total("linear_attn.lowerings") == 1
         norm = telemetry.REGISTRY.get("gate_norm.lowerings")
         assert norm.value(site="gated_delta_net", groups=32, width=128,
                           impl="jnp") == 1
@@ -566,11 +574,16 @@ def test_the_model_states_its_own_initialisation_and_counts_its_call_sites():
                                     label=[mx.nd.array(labels)]),
                     is_train=False)
         # one per layer's call site, nothing per step; from_config's
-        # chunk is the program's 64
+        # chunk is the program's 64; the impl is what gdn_takes says of
+        # the tiny heads (8 columns are no lane row: the chunk form)
+        impl = ("kernel" if pk.gdn_takes(H, D, D, 64, jnp.float32, "channel")
+                else "chunked")
+        assert impl == "chunked"
         rule = telemetry.REGISTRY.get("linear_attn.lowerings")
         assert rule.value(heads=H, key_dim=D, value_dim=D, chunk=64,
-                          conv=TAPS, impl="chunked", decay="channel",
+                          conv=TAPS, impl=impl, decay="channel",
                           gate="sigmoid") == 4
+        assert telemetry.total("linear_attn.lowerings") == 4
         latent = telemetry.REGISTRY.get("attention.latent_lowerings")
         assert latent.value(heads=HEADS, latent=LATENT, rope=ROPE,
                             nope=NOPE, dv=DV, impl="composed",

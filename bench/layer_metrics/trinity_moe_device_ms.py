@@ -1,0 +1,21 @@
+"""Busy milliseconds of device 0 per step in the expert layers of a
+Trinity model's share: ops whose scope's class is ``moe`` (the router
+over all 128 experts, the compaction of the rows routed to the held
+experts, three grouped products a pass at 2048 / 1024 over the share's
+buffer, the row moves back) and the shared expert's three
+``FullyConnected`` nodes (``layer<i>_shared_{gate,up,down}_proj``),
+forward and backward together. None for a configuration whose operations
+module counts no gated attention layer."""
+import afmoe_scopes
+import lm_scopes
+import mla_scopes
+
+
+def compute(trace, counters, run):
+    if not afmoe_scopes.afmoe_flops(run):
+        return None
+    routed = lm_scopes.class_ms(trace, run, "moe")
+    shared = mla_scopes.ms(trace, run, "shared")
+    if routed is None or shared is None:
+        return None
+    return routed + shared

@@ -30,8 +30,13 @@ as one of the chips that share a layer by tensor parallelism, with
 Delta Attention, a delta rule whose decay is a vector a head, three to
 one beside latent attention without a rotary embedding, a dense SwiGLU
 then one shared and 256 sigmoid-routed experts), whole or as a share,
-with ``kimi_linear_reference``; ``lm_blocks`` holds what the LM symbols
-share.
+with ``kimi_linear_reference``. ``afmoe`` is Trinity-Mini (attention
+under per-head norms whose output passes a sigmoid gate, a 2,048-key
+window three to one beside full attention without a rotary embedding,
+four norms a block, a dense SwiGLU then one shared and 128
+sigmoid-routed experts, a muP multiplier on the embedding), whole or as
+a share, with ``afmoe_reference``; ``lm_blocks`` holds what the LM
+symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -45,8 +50,8 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
-from . import (falcon_h1, falcon_h1_reference, kanana2, kanana2_reference,
-               kimi_linear, kimi_linear_reference,
+from . import (afmoe, afmoe_reference, falcon_h1, falcon_h1_reference,
+               kanana2, kanana2_reference, kimi_linear, kimi_linear_reference,
                lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
                nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
                olmoe, olmoe_reference)

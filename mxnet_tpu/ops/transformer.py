@@ -170,10 +170,30 @@ def _kv_heads(attrs):
     return int(attrs.get("num_kv_heads", 0)) or int(attrs["num_heads"])
 
 
+_M_GATED_LOWERINGS = _tm.counter(
+    "attention.gated_lowerings", "Traces of an Attention call site whose "
+    "output is gated (with_gate: one per lowering, nothing per step); "
+    "labels: heads, dv (the value width a head)")
+
+
+def gate_output(out, gate):
+    """``out * sigmoid(gate)``, an element each (a gate per head and
+    channel): the sigmoid and the product float32, one rounding to
+    ``out``'s dtype."""
+    return (out.astype(jnp.float32)
+            * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
+
+
+def _optional_inputs(attrs):
+    return [name for name in ("sink", "gate")
+            if bool((attrs or {}).get("with_" + name, False))]
+
+
 def _attention(attrs, ins, is_train):
     from .kernels import attention
 
     q, k, v = ins[:3]
+    optional = dict(zip(_optional_inputs(attrs), ins[3:]))
     heads, kv_heads = int(attrs["num_heads"]), _kv_heads(attrs)
     window = int(attrs.get("window", 0))
     b, t, _ = q.shape
@@ -185,10 +205,13 @@ def _attention(attrs, ins, is_train):
         out = attention(split(q, heads), split(k, kv_heads),
                         split(v, kv_heads),
                         causal=bool(attrs.get("causal", True)),
-                        window=window,
-                        sink=ins[3] if bool(attrs.get("with_sink", False))
-                        else None)
-    return [out.reshape(b, t, -1)]
+                        window=window, sink=optional.get("sink"))
+    out = out.reshape(b, t, -1)
+    if "gate" in optional:
+        _M_GATED_LOWERINGS.inc(heads=heads, dv=out.shape[2] // heads)
+        with jax.named_scope("gate"):
+            out = gate_output(out, optional["gate"])
+    return [out]
 
 
 def _attention_infer(attrs, in_shapes):
@@ -211,22 +234,23 @@ def _attention_infer(attrs, in_shapes):
         raise ValueError(
             "Attention: key %s has head_dim %d over %d heads, query %s "
             "has %d over %d" % (k, dk, kv_heads, q, d, heads))
-    ins = [q, k, v] + [(heads,)] * (len(in_shapes) - 3)
-    return ins, [q[:2] + (heads * dv,)], []
+    out = q[:2] + (heads * dv,)
+    optional = {"sink": (heads,), "gate": out}
+    ins = [q, k, v] + [optional[name] for name in _optional_inputs(attrs)]
+    return ins, [out], []
 
 
 _attn = OpDef(
     "_contrib_Attention",
     _attention,
-    arguments=("query", "key", "value", "sink"),
+    arguments=("query", "key", "value", "sink", "gate"),
     defaults={"num_heads": 1, "num_kv_heads": 0, "causal": True,
-              "window": 0, "with_sink": False},
+              "window": 0, "with_sink": False, "with_gate": False},
     infer_shape=_attention_infer,
     aliases=("Attention",),
 )
 _attn.list_arguments = lambda attrs=None: (
-    ["query", "key", "value", "sink"]
-    if (attrs or {}).get("with_sink") else ["query", "key", "value"])
+    ["query", "key", "value"] + _optional_inputs(attrs))
 register(_attn)
 
 

@@ -108,8 +108,12 @@ def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, window,
 # window): Kanana's latent attention, OLMoE's, MiMo's full and window
 # layers, LFM2's (heads of 64: half a lane row a head, 4 query heads a
 # key/value head), Falcon-H1's share (10 query heads on 2: five a
-# key/value head)
+# key/value head), Trinity-Mini's window layers (a band of 2,048 keys on
+# tiles of 1,024: three k tiles a q tile, one wholly inside) and its
+# full layer, 8 query heads a key/value head in both
 CELL_CALLS = {
+    "trinity_window_8k": (8192, 32, 4, 128, 128, 2048),
+    "trinity_full_8k": (8192, 32, 4, 128, 128, 0),
     "falcon_h1_4k": (4096, 10, 2, 128, 128, 0),
     "kanana2_8k": (8192, 32, 32, 192, 128, 0),
     "lfm2_8k": (8192, 32, 8, 64, 64, 0),

@@ -274,6 +274,14 @@ def test_flash_tiles_at_the_cell_shape_are_large():
     assert (4096 // bq) * (4096 // bk) <= 1024
 
 
+def test_flash_tiles_under_a_window_wider_than_the_cap():
+    # Trinity-Mini's window of 2,048 at T 8,192: twice the window is past
+    # the measured cap, so the band of a q tile is three k tiles wide
+    assert flash_tiles(8192, 128, jnp.bfloat16, window=2048) == (1024, 1024)
+    assert pk.flash._band_steps(8, 8, 1024, 1024, 2048, "k") == 3
+    assert pk.flash._band_steps(8, 8, 1024, 1024, 2048, "q") == 3
+
+
 def test_flash_lowerings_counter_counts_one_per_lowering():
     telemetry.reset()
     telemetry.enable()
@@ -332,6 +340,20 @@ FUSED_BWD_CASES = {
                                  128, 128, jnp.bfloat16),
     "bf16_padded_full": (2, 200, 2, 1, 32, 32, False, 0, False, 64, 128,
                          jnp.bfloat16),
+    # the Trinity-Mini cell's window call scaled down (8 query heads on
+    # 1, no sink, a window of two tiles as 2,048 is of 1,024): the band
+    # of a q tile crosses three k tiles, one wholly inside; a window of a
+    # tile and a half; T a multiple of the tile and not
+    "window_of_two_tiles_8_on_1": (1, 512, 8, 1, 32, 32, True, 128, False,
+                                   64, 64, jnp.float32),
+    "window_of_two_tiles_padded": (1, 450, 8, 1, 32, 32, True, 128, False,
+                                   64, 64, jnp.float32),
+    "window_of_a_tile_and_a_half": (1, 448, 8, 1, 32, 32, True, 96, False,
+                                    64, 64, jnp.float32),
+    "bf16_window_of_two_tiles_8_on_1": (1, 768, 8, 1, 64, 64, True, 256,
+                                        False, 128, 128, jnp.bfloat16),
+    "bf16_window_of_a_tile_and_a_half": (1, 700, 8, 1, 64, 64, True, 192,
+                                         False, 128, 128, jnp.bfloat16),
 }
 
 
@@ -395,6 +417,8 @@ def test_fused_backward_matches_the_reference_gradients(name):
     (4096, 128, 128, 0, jnp.bfloat16, True),
     (4096, 192, 128, 0, jnp.bfloat16, True),
     (4096, 192, 128, 128, jnp.bfloat16, True),
+    (8192, 128, 128, 2048, jnp.bfloat16, True),   # Trinity-Mini's window
+    (8192, 128, 128, 0, jnp.bfloat16, True),      # and its full layer
     (32768, 192, 128, 0, jnp.bfloat16, False),
     (16384, 192, 128, 0, jnp.bfloat16, False),
     (16384, 128, 128, 0, jnp.float32, False),

@@ -23,12 +23,22 @@ pair at other head groups than its own. Prints one JSON line a row and
 writes `chiprun_out/channel_delta_rule_table.json`; PERF.md section 7 holds
 the table (PR 54).
 
-    chiprun -- python3 benchmarks/channel_delta_rule.py
+`--gate-norm` prints what follows the rule instead (PR 56): the per-head
+norm and its sigmoid gate on `o` token-major as the pair wrote it
+(`ops/kernels/gate_norm.py::gated_rms_norm`, form `token_major`), the
+`jax.numpy` closure under its checkpoint against the kernel pair through
+its entry, then the two calls alone by (row tile, column tile) and by the
+rows a loop step takes (`gate_norm._ROWS_TOKEN_MAJOR`;
+`--rows-a-step=64,128,256`), device ms by kernel name. Writes
+`chiprun_out/channel_gate_norm_table.json`.
+
+    chiprun -- python3 benchmarks/channel_delta_rule.py [--gate-norm]
 
 `--rehearse-cpu` runs the same flow at a toy size here (the pairs' branch
 for other platforms, no trace): it proves the script, not a number.
 """
 import collections
+import functools
 import glob
 import json
 import os
@@ -62,9 +72,9 @@ def _time(f, *args, reps=10):
 
 
 def _device_ms(g, *args, reps=5):
-    """Device ms a call of each ``kda_`` / ``gdn_`` kernel and of
-    everything else in the program, from a profiler trace of ``reps``
-    calls."""
+    """Device ms a call of each ``kda_`` / ``gdn_`` / ``gate_norm_``
+    kernel and of everything else in the program, from a profiler trace of
+    ``reps`` calls."""
     from jax.profiler import ProfileData
 
     jax.block_until_ready(g(*args))
@@ -83,7 +93,8 @@ def _device_ms(g, *args, reps=5):
                 continue
             for e in line.events:
                 name = e.name.split(" = ")[0].lstrip("%")
-                ms[name.split(".")[0] if name.startswith(("kda_", "gdn_"))
+                ms[name.split(".")[0]
+                   if name.startswith(("kda_", "gdn_", "gate_norm_"))
                    else "everything else"] += e.duration_ns / 1e6 / reps
     return dict(ms)
 
@@ -151,6 +162,96 @@ def forms():
             "fused": both(fused), "scalar_kernel": both(scalar_kernel)}
 
 
+PEAK_GBS = 819.0  # bench/peaks.json, "TPU v5 lite"
+
+
+def gate_norm_table(row, rehearse, steps, row_tiles=(256, 512, 1024),
+                    column_tiles=(512, 1024, 2048, 4096)):
+    """The gate and norm behind the channel rule alone, one layer at the
+    cell's shape: ``o`` float32 and the gate bf16, both [B, T, H V]."""
+    from mxnet_tpu.ops.kernels import gate_norm as gn
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    rng = np.random.RandomState(3)
+    columns = H * V
+    o = jnp.asarray(rng.randn(B, T, columns), f32)
+    gate, cot = (jnp.asarray(rng.randn(B, T, columns), bf16)
+                 for _ in range(2))
+    gamma = jnp.asarray(1 + 0.1 * rng.randn(V), bf16)
+    static = dict(form="token_major", width=V, eps=1e-5, scale=None,
+                  offset=0, act="sigmoid")
+    # what the op must move: o and the gate in, the result out; then those
+    # two and the cotangent in, two cotangents out
+    fwd_mb = T * columns * (4 + 2 + 2) / 1e6
+    bwd_mb = T * columns * (4 + 2 + 2 + 4 + 2) / 1e6
+
+    def each_way(f):
+        # the result's cotangent an operand: a loss summed here would
+        # fuse into the form
+        def both(o, gate, gamma, cot):
+            out, back = jax.vjp(f, o, gate, gamma)
+            return (out,) + back(cot)
+        return jax.jit(both)
+
+    def ms_of(f, *args):
+        if rehearse:
+            return {"everything else": _time(f, *args, reps=2)}
+        return _device_ms(f, *args)
+
+    def fwd_bwd(ms):
+        return tuple(sum(v for k, v in ms.items()
+                         if k.startswith("gate_norm_" + which))
+                     for which in ("fwd", "bwd"))
+
+    plain = each_way(jax.checkpoint(functools.partial(gn.plain_form,
+                                                      **static)))
+    ms = ms_of(plain, o, gate, gamma, cot)["everything else"]
+    row(gate_norm="jnp", fwd_bwd_ms=ms,
+        bytes_bound_fwd_bwd_ms=(fwd_mb + bwd_mb) / PEAK_GBS)
+    entry = each_way(functools.partial(
+        pk.gated_rms_norm, form="token_major", eps=1e-5, groups=H,
+        act="sigmoid", interpret=rehearse))
+    ms = ms_of(entry, o, gate, gamma, cot)
+    fwd, bwd = fwd_bwd(ms)
+    far = {name: float(jnp.abs(g.astype(f32) - w.astype(f32)).max()
+                       / jnp.abs(w.astype(f32)).max())
+           for name, g, w in zip(("out", "do", "dgate", "dgamma"),
+                                 entry(o, gate, gamma, cot),
+                                 plain(o, gate, gamma, cot))}
+    row(gate_norm="kernel", rows_a_step=gn._ROWS_TOKEN_MAJOR,
+        tiles=gn.gate_norm_tiles("token_major", H, V, T, bf16, 0, columns),
+        fwd_ms=fwd, bwd_ms=bwd, rest_ms=ms.get("everything else"),
+        share_of_bytes_bound=(100 * (fwd_mb + bwd_mb) / PEAK_GBS
+                              / (fwd + bwd) if fwd + bwd else None),
+        far_from_jnp=far,
+        kernels=sorted(k for k in ms if k.startswith("gate_norm_")))
+    own = gn._ROWS_TOKEN_MAJOR
+    for rows in () if rehearse else steps:
+        gn._ROWS_TOKEN_MAJOR = rows
+        gn.gate_norm_fwd_call.clear_cache()
+        gn.gate_norm_bwd_call.clear_cache()
+        for tile in [(r, c) for r in row_tiles for c in column_tiles
+                     if T % r == 0 and columns % c == 0 and r % rows == 0
+                     and gn.gate_norm_vmem_bytes(r, c, V, 2, "token_major")
+                     <= pk.common.VMEM_RAISED_LIMIT]:
+            def both(o, gate, gamma_row, cot, tile=tile):
+                kw = dict(tiles=tile, interpret=False, **static)
+                return (gn.gate_norm_fwd_call(o, gate, gamma_row, **kw),
+                        gn.gate_norm_bwd_call(o, gate, gamma_row, cot, **kw))
+            try:
+                ms = _device_ms(
+                    jax.jit(both), o, gate,
+                    gn._gamma_row(gamma, bf16, "token_major", tile), cot)
+            except Exception as e:  # noqa: BLE001 — Mosaic refused the tile
+                row(gate_norm="calls", rows_a_step=rows, tiles=tile,
+                    refused=str(e)[:300])
+                continue
+            fwd, bwd = fwd_bwd(ms)
+            row(gate_norm="calls", rows_a_step=rows, tiles=tile, fwd_ms=fwd,
+                bwd_ms=bwd, fwd_gbs=fwd_mb / fwd, bwd_gbs=bwd_mb / bwd)
+    gn._ROWS_TOKEN_MAJOR = own
+
+
 def main():
     global T, H
     rehearse = "--rehearse-cpu" in sys.argv
@@ -167,6 +268,14 @@ def main():
         print(json.dumps(kw), flush=True)
         res["rows"].append(kw)
 
+    if "--gate-norm" in sys.argv:
+        gate_norm_table(row, rehearse, [
+            int(r) for a in sys.argv if a.startswith("--rows-a-step=")
+            for r in a.split("=", 1)[1].split(",")] or (64, 128, 256))
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/channel_gate_norm_table.json", "w") as f:
+            json.dump(res, f, indent=1)
+        return
     assert pk.gdn_takes(H, K, V, CHUNK, jnp.bfloat16, "channel")
     both = forms()
     args, cot = inputs(0, jnp.bfloat16, T if rehearse else 2048)

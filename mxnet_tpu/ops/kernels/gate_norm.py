@@ -1,26 +1,37 @@
 """The gate and the grouped RMSNorm between a state-space layer's core and
 its output projection (scope ``gate_norm`` of ``ops/transformer.py``'s
 ``Mamba2`` and ``GatedDeltaNet`` blocks), one pass over the operands each
-way. Two forms, a static flag of the one body pair, since the two callers
+way. Three forms, a static flag of the one body pair, since the callers
 differ in order and in where the core left its output:
 
-  gate_first  ``u = y * silu(m z)``, ``r = rsqrt(mean_group(u^2) + eps)``,
-              ``gamma.astype(dtype) * (u r).astype(dtype)`` (``Mamba2``):
-              ``y`` [B, T, C] float32 as the scan wrote it, ``z`` a window
-              of columns of ``in_proj``'s output, ``m`` a fixed scalar
-              (Falcon-H1's multiplier) or none
-  norm_first  ``(o * rsqrt(mean_head(o^2) + eps) * gamma) * silu(gate)``
-              (``GatedDeltaNet``): ``o`` [B, H, T, V] float32, head-major
-              as the delta rule's kernel wrote it; gate and result
-              token-major [B, T, H V]. The pass is the transpose too.
+  gate_first   ``u = y * act(m z)``, ``r = rsqrt(mean_group(u^2) + eps)``,
+               ``gamma.astype(dtype) * (u r).astype(dtype)`` (``Mamba2``):
+               ``y`` [B, T, C] float32 as the scan wrote it, ``z`` a window
+               of columns of ``in_proj``'s output, ``m`` a fixed scalar
+               (Falcon-H1's multiplier) or none
+  norm_first   ``(o * rsqrt(mean_head(o^2) + eps) * gamma) * act(gate)``
+               (``GatedDeltaNet``): ``o`` [B, H, T, V] float32, head-major
+               as the scalar delta rule's kernel wrote it; gate and result
+               token-major [B, T, H V]. The pass is the transpose too.
+  token_major  ``norm_first``'s order on ``gate_first``'s operands: ``o``
+               [B, T, H V] float32 as the channel delta rule's pair wrote
+               it (Kimi Delta Attention), a head whole lane rows, gamma
+               [V] shared by the heads. Nothing is moved: a head is a
+               128-lane range of a token's row on both sides.
+
+``act`` is the gate's activation, a second static flag of the bodies:
+``silu`` (``Mamba2``, the scalar ``GatedDeltaNet``) or ``sigmoid`` (Kimi's
+gate). A kernel's name carries the form and, where it is not ``silu``, the
+gate (``gate_norm_fwd_bf16_r256_g128_token_major_sigmoid``).
 
   grid      (batch, column tile, row tile), the rows innermost. A column
-            tile holds whole groups (gate_first: as many groups as fit
-            ``_COLUMN_TILE`` columns; norm_first: one head where V is
-            whole lane rows, else the two that make them: 2 x 192 = 384).
-            The window's index map adds its offset, a multiple of the
-            column tile: no slice is made in front of the call.
-  body      a ``fori_loop`` over ``_ROWS`` rows a group: load, cast to
+            tile holds whole groups (gate_first, token_major: as many
+            groups as fit ``_COLUMN_TILE`` columns; norm_first: one head
+            where V is whole lane rows, else the two that make them: 2 x
+            192 = 384). The window's index map adds its offset, a multiple
+            of the column tile: no slice is made in front of the call.
+  body      a ``fori_loop`` over ``_ROWS`` rows a group (``token_major``:
+            ``_ROWS_TOKEN_MAJOR``, its groups one lane row): load, cast to
             float32, the gate, the squares' sum over the group's lanes,
             the scaling, one cast, one store. A step's float32 values
             stay in VMEM between the statistics and the scaling: nothing
@@ -29,10 +40,11 @@ differ in order and in where the core left its output:
             over the block's lanes, so nothing else is cut at lane 192.
   backward  one kernel on the same grid: the op's INPUTS and the result's
             cotangent in, the statistics computed again, ``dy`` (``do``,
-            head-major) float32 and ``dz`` (``dgate``) in the gate's type
-            out. ``dgamma`` a column accumulates in float32 in the loop's
-            carry and, over the row tiles, in its [8, column tile] output
-            block, folded and summed outside.
+            laid out as ``o``) float32 and ``dz`` (``dgate``) in the gate's
+            type out. ``dgamma`` a column accumulates in float32 in the
+            loop's carry and, over the row tiles, in its [8, column tile]
+            output block, folded (over the heads too, where they share
+            gamma) and summed outside.
   set-up    as the taps': ``jax.lax`` primitives in the bodies, each
             ``pallas_call`` behind a ``jax.jit``, the ``jax.numpy`` form
             on every platform but the TPU, inside the ``custom_vjp``
@@ -58,18 +70,30 @@ from .common import (
     LANES, VMEM_RAISED_LIMIT, VMEM_SCOPED_DEFAULT, no_x64, on_tpu,
     operand_label, sum_keepdims, whole_lanes)
 
-FORMS = ("gate_first", "norm_first")
+FORMS = ("gate_first", "norm_first", "token_major")
+ACTS = ("silu", "sigmoid")
 # a loop step of a body: four bf16 sublane tiles of rows. A step's chain
 # (load, gate, sum over lanes, rsqrt, scale, store) is latency the next
 # step cannot hide, so a step holds enough independent rows to fill it:
 # at 16 rows the pair took 1.58 ms a Nemotron block and 2.10 an
 # Olmo-Hybrid layer, at 64 rows 1.01 and 0.81 (PERF.md section 7, PR 52)
 _ROWS = 64
+# ``token_major``'s groups are ONE lane row wide, so 64 rows are 8 vregs an
+# array and the loop's own steps show: the pair at the Kimi Linear cell's
+# shape read 1.39 ms a layer at 64 rows a step, 1.13 at 128, 1.10 at 256
+# and 1.09 at 512 (PERF.md section 7, PR 56). 256 rows of 128 lanes are what
+# 64 rows of Nemotron's groups of 512 are.
+_ROWS_TOKEN_MAJOR = 256
 _ROW_TILES = (1024, 512, 256, 128)
-# the widest column tile of ``gate_first`` and what a backward step's
-# blocks may take of VMEM before a shorter row tile is tried
+# the widest column tile of ``gate_first`` and ``token_major``, and what a
+# backward step's blocks may take of VMEM before a shorter row tile is tried
 _COLUMN_TILE = 2048
 _BLOCK_BUDGET = 24 * 1024 * 1024
+
+
+def _rows_a_step(form, row_tile):
+    """The rows a loop step of a body takes."""
+    return min(row_tile, _ROWS_TOKEN_MAJOR) if form == "token_major" else _ROWS
 
 
 def gate_norm_vmem_bytes(rows, columns, width, itemsize, form):
@@ -77,15 +101,15 @@ def gate_norm_vmem_bytes(rows, columns, width, itemsize, form):
     output and its cotangent float32, a head's V columns padded to lane
     rows; the gate, its cotangent and the result's in the gate's type),
     gamma and its gradient's block, and a loop step's float32 values (a
-    dozen arrays of ``_ROWS`` rows of one group, or of a pair of heads),
+    dozen arrays of a step's rows of one group, or of a pair of heads),
     which Mosaic keeps in VMEM."""
-    if form == "gate_first":
-        core, step = columns, width
-    else:
+    if form == "norm_first":
         core, step = columns // width * whole_lanes(width), columns
+    else:
+        core, step = columns, width
     blocks = rows * (2 * 4 * core + 3 * itemsize * columns)
-    return (2 * blocks + 4 * 8 * 4 * columns + 12 * 4 * _ROWS * step
-            + 2 * 1024 * 1024)
+    return (2 * blocks + 4 * 8 * 4 * columns
+            + 12 * 4 * _rows_a_step(form, rows) * step + 2 * 1024 * 1024)
 
 
 def gate_norm_tiles(form, groups, width, time, dtype, offset=0,
@@ -103,7 +127,7 @@ def gate_norm_tiles(form, groups, width, time, dtype, offset=0,
             or jnp.dtype(dtype).name not in ("bfloat16", "float32")
             or offset < 0 or offset + columns > src_width):
         return None
-    if form == "gate_first":
+    if form != "norm_first":
         if width % LANES:
             return None
         fit = [n for n in range(1, groups + 1)
@@ -136,8 +160,8 @@ def gate_norm_takes(form, groups, width, time, dtype, offset=0,
                            src_width) is not None
 
 
-def _row_step(r):
-    return pl.ds(pl.multiple_of(lax.mul(r, np.int32(_ROWS)), _ROWS), _ROWS)
+def _row_step(r, rows):
+    return pl.ds(pl.multiple_of(lax.mul(r, np.int32(rows)), rows), rows)
 
 
 def _spread(column, like):
@@ -145,17 +169,20 @@ def _spread(column, like):
     return lax.broadcast_in_dim(column, like.shape, (0, 1))
 
 
-def _gate(z, scale):
-    """``m z``, its logistic and ``silu(m z)``, float32."""
+def _gate(z, scale, act):
+    """``m z``, its logistic and ``act(m z)``, float32."""
     g = z if scale is None else lax.mul(z, np.float32(scale))
     sig = lax.logistic(g)
-    return g, sig, lax.mul(g, sig)
+    return g, sig, lax.mul(g, sig) if act == "silu" else sig
 
 
-def _silu_slope(g, sig):
-    """``silu'(g) = sig (1 + g (1 - sig))``."""
+def _gate_slope(g, sig, act):
+    """``silu'(g) = sig (1 + g (1 - sig))``, ``sigmoid'(g) = sig (1 -
+    sig)``."""
     one = np.float32(1)
-    return lax.mul(sig, lax.add(one, lax.mul(g, lax.sub(one, sig))))
+    if act == "silu":
+        return lax.mul(sig, lax.add(one, lax.mul(g, lax.sub(one, sig))))
+    return lax.mul(sig, lax.sub(one, sig))
 
 
 def _by_range(v, ranges, then):
@@ -204,8 +231,8 @@ def _through(v, dtype):
         lax.convert_element_type(v, dtype), jnp.float32)
 
 
-def _rows_of(ref, lo, hi):
-    return lax.broadcast_in_dim(ref[:, lo:hi], (_ROWS, hi - lo), (0, 1))
+def _rows_of(ref, lo, hi, rows):
+    return lax.broadcast_in_dim(ref[:, lo:hi], (rows, hi - lo), (0, 1))
 
 
 def _heads(y_ref, rows):
@@ -216,47 +243,53 @@ def _heads(y_ref, rows):
 
 
 def _gate_norm_fwd_kernel(y_ref, z_ref, gamma_ref, o_ref, *, form, width,
-                          eps, scale):
+                          eps, scale, act):
     """One [row tile, column tile] block. y ([row tile, columns] float32;
     ``norm_first``: [heads, row tile, V]), the gate's block, gamma [1,
     columns] float32 -> the result in the gate's type."""
     tr, tc = o_ref.shape
     cast, f32 = lax.convert_element_type, jnp.float32
     dtype = o_ref.dtype
+    per = _rows_a_step(form, tr)
 
     if form == "norm_first":
-        gamma = _rows_of(gamma_ref, 0, tc)
+        gamma = _rows_of(gamma_ref, 0, tc, per)
 
         def step(r, carry):
-            rows = _row_step(r)
+            rows = _row_step(r, per)
             o = _heads(y_ref, rows)
             normed = lax.mul(lax.mul(o, _scale_of(o, eps, tc // width)),
                              gamma)
-            silu = _gate(cast(z_ref[rows, :], f32), scale)[2]
-            o_ref[rows, :] = cast(lax.mul(normed, silu), dtype)
+            gate = _gate(cast(z_ref[rows, :], f32), scale, act)[2]
+            o_ref[rows, :] = cast(lax.mul(normed, gate), dtype)
             return carry
 
-        lax.fori_loop(0, tr // _ROWS, step, np.int32(0))
+        lax.fori_loop(0, tr // per, step, np.int32(0))
         return
 
     for lo in range(0, tc, width):
         hi = lo + width
-        gamma = _rows_of(gamma_ref, lo, hi)
+        gamma = _rows_of(gamma_ref, lo, hi, per)
 
         def step(r, carry, lo=lo, hi=hi, gamma=gamma):
-            rows = _row_step(r)
-            silu = _gate(cast(z_ref[rows, lo:hi], f32), scale)[2]
-            u = lax.mul(y_ref[rows, lo:hi], silu)
+            rows = _row_step(r, per)
+            gate = _gate(cast(z_ref[rows, lo:hi], f32), scale, act)[2]
+            y = y_ref[rows, lo:hi]
+            if form == "token_major":
+                normed = lax.mul(lax.mul(y, _scale_of(y, eps)), gamma)
+                o_ref[rows, lo:hi] = cast(lax.mul(normed, gate), dtype)
+                return carry
+            u = lax.mul(y, gate)
             n = lax.mul(u, _scale_of(u, eps))
             o_ref[rows, lo:hi] = cast(lax.mul(gamma, _through(n, dtype)),
                                       dtype)
             return carry
 
-        lax.fori_loop(0, tr // _ROWS, step, np.int32(0))
+        lax.fori_loop(0, tr // per, step, np.int32(0))
 
 
 def _gate_norm_bwd_kernel(y_ref, z_ref, gamma_ref, dout_ref, dy_ref, dz_ref,
-                          dgamma_ref, *, form, width, eps, scale):
+                          dgamma_ref, *, form, width, eps, scale, act):
     """The same block with the result's cotangent -> the cotangents of
     ``y`` (float32, laid out as ``y``) and of the gate (its type), and the
     block's rows of gamma's, summed a sublane into [8, column tile]
@@ -265,25 +298,27 @@ def _gate_norm_bwd_kernel(y_ref, z_ref, gamma_ref, dout_ref, dy_ref, dz_ref,
     cast, f32 = lax.convert_element_type, jnp.float32
     mul = lax.mul
     dtype = dz_ref.dtype
+    per = _rows_a_step(form, tr)
 
     @pl.when(lax.eq(pl.program_id(2), np.int32(0)))
     def _():
         dgamma_ref[...] = lax.full(dgamma_ref.shape, 0, f32)
 
     if form == "norm_first":
-        gamma = _rows_of(gamma_ref, 0, tc)
+        gamma = _rows_of(gamma_ref, 0, tc, per)
         heads = tc // width
 
         def step(r, acc):
-            rows = _row_step(r)
+            rows = _row_step(r, per)
             o = _heads(y_ref, rows)
             r_o = _scale_of(o, eps, heads)
             n = mul(o, r_o)
-            g, sig, silu = _gate(cast(z_ref[rows, :], f32), scale)
+            g, sig, gate = _gate(cast(z_ref[rows, :], f32), scale, act)
             dout = cast(dout_ref[rows, :], f32)
             dz_ref[rows, :] = cast(
-                mul(mul(dout, mul(n, gamma)), _silu_slope(g, sig)), dtype)
-            dnormed = mul(dout, silu)
+                mul(mul(dout, mul(n, gamma)), _gate_slope(g, sig, act)),
+                dtype)
+            dnormed = mul(dout, gate)
             do = _norm_back(mul(dnormed, gamma), n, r_o, heads)
             for j in range(heads):
                 dy_ref[j, rows, :] = do if heads == 1 else lax.slice_in_dim(
@@ -291,36 +326,49 @@ def _gate_norm_bwd_kernel(y_ref, z_ref, gamma_ref, dout_ref, dy_ref, dz_ref,
             return lax.add(acc, _fold(mul(dnormed, n)))
 
         dgamma_ref[...] = lax.add(dgamma_ref[...], lax.fori_loop(
-            0, tr // _ROWS, step, lax.full((8, tc), 0, f32)))
+            0, tr // per, step, lax.full((8, tc), 0, f32)))
         return
 
     for lo in range(0, tc, width):
         hi = lo + width
-        gamma = _rows_of(gamma_ref, lo, hi)
+        gamma = _rows_of(gamma_ref, lo, hi, per)
 
         def step(r, acc, lo=lo, hi=hi, gamma=gamma):
-            rows = _row_step(r)
+            rows = _row_step(r, per)
             y = y_ref[rows, lo:hi]
-            g, sig, silu = _gate(cast(z_ref[rows, lo:hi], f32), scale)
-            u = mul(y, silu)
+            g, sig, gate = _gate(cast(z_ref[rows, lo:hi], f32), scale, act)
+            if form == "token_major":
+                r_y = _scale_of(y, eps)
+                n = mul(y, r_y)
+                dout = cast(dout_ref[rows, lo:hi], f32)
+                dz_ref[rows, lo:hi] = cast(
+                    mul(mul(dout, mul(n, gamma)), _gate_slope(g, sig, act)),
+                    dtype)
+                dnormed = mul(dout, gate)
+                dy_ref[rows, lo:hi] = _norm_back(mul(dnormed, gamma), n, r_y)
+                return lax.add(acc, _fold(mul(dnormed, n)))
+            u = mul(y, gate)
             r_u = _scale_of(u, eps)
             n = mul(u, r_u)
             dout = cast(dout_ref[rows, lo:hi], f32)
             acc = lax.add(acc, _fold(mul(dout, _through(n, dtype))))
             du = _norm_back(mul(dout, gamma), n, r_u)
-            dy_ref[rows, lo:hi] = mul(du, silu)
-            dg = mul(mul(du, y), _silu_slope(g, sig))
+            dy_ref[rows, lo:hi] = mul(du, gate)
+            dg = mul(mul(du, y), _gate_slope(g, sig, act))
             dz_ref[rows, lo:hi] = cast(
                 dg if scale is None else mul(dg, np.float32(scale)), dtype)
             return acc
 
         dgamma_ref[:, lo:hi] = lax.add(dgamma_ref[:, lo:hi], lax.fori_loop(
-            0, tr // _ROWS, step, lax.full((8, width), 0, f32)))
+            0, tr // per, step, lax.full((8, width), 0, f32)))
 
 
-def _name(which, dtype, tiles, width, form):
-    return "gate_norm_%s_%s_r%d_g%d_%s" % (
-        which, operand_label(dtype), tiles[0], width, form)
+def _name(which, dtype, tiles, width, form, act):
+    """What a device trace shows: the form and, where it is not ``silu``,
+    the gate."""
+    return "gate_norm_%s_%s_r%d_g%d_%s%s" % (
+        which, operand_label(dtype), tiles[0], width, form,
+        "" if act == "silu" else "_" + act)
 
 
 def _params(tiles, width, dtype, form):
@@ -343,20 +391,22 @@ def _specs(tiles, form, width, offset):
         return lax.add(j, shift) if offset else j
 
     across = pl.BlockSpec((None, tr, tc), lambda b, j, i: (b, i, j))
+    core = across
     if form == "norm_first":
         core = pl.BlockSpec((None, tc // width, tr, width),
                             lambda b, j, i: (b, j, i, 0))
-        gamma = pl.BlockSpec((1, tc), lambda b, j, i: (0, 0))
-    else:
-        core = across
+    if form == "gate_first":
         gamma = pl.BlockSpec((1, tc), lambda b, j, i: (0, j))
+    else:  # a head's, side by side over one column tile
+        gamma = pl.BlockSpec((1, tc), lambda b, j, i: (0, 0))
     return (core,
             pl.BlockSpec((None, tr, tc), lambda b, j, i: (b, i, column(j))),
             gamma, across,
             pl.BlockSpec((None, 8, tc), lambda b, j, i: (b, 0, j)))
 
 
-_STATIC = ("form", "width", "eps", "scale", "offset", "tiles", "interpret")
+_STATIC = ("form", "width", "eps", "scale", "act", "offset", "tiles",
+           "interpret")
 
 
 def _extent(y, form):
@@ -369,29 +419,29 @@ def _extent(y, form):
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def gate_norm_fwd_call(y, src, gamma, *, form, width, eps, scale, offset,
-                       tiles, interpret):
+                       tiles, interpret, act="silu"):
     """y ([B, T, C], ``norm_first``: [B, H, T, V]) float32, src [B, T, W],
-    gamma [1, C] (``norm_first``: [1, column tile]) float32 -> [B, T, C]
+    gamma [1, C] (a head's forms: [1, column tile]) float32 -> [B, T, C]
     in src's type."""
     b, t, columns = _extent(y, form)
     core, gate, gamma_spec, across, _ = _specs(tiles, form, width, offset)
     with no_x64():
         return pl.pallas_call(
             functools.partial(_gate_norm_fwd_kernel, form=form, width=width,
-                              eps=eps, scale=scale),
+                              eps=eps, scale=scale, act=act),
             grid=(b, columns // tiles[1], t // tiles[0]),
             in_specs=[core, gate, gamma_spec],
             out_specs=across,
             out_shape=jax.ShapeDtypeStruct((b, t, columns), src.dtype),
             compiler_params=_params(tiles, width, src.dtype, form),
-            name=_name("fwd", src.dtype, tiles, width, form),
+            name=_name("fwd", src.dtype, tiles, width, form, act),
             interpret=interpret,
         )(y, src, gamma)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def gate_norm_bwd_call(y, src, gamma, dout, *, form, width, eps, scale,
-                       offset, tiles, interpret):
+                       offset, tiles, interpret, act="silu"):
     """-> ``y``'s cotangent float32 (laid out as ``y``), the gate's [B, T,
     C] in src's type, and gamma's a batch row and sublane, [B, 8, C]
     float32."""
@@ -401,7 +451,7 @@ def gate_norm_bwd_call(y, src, gamma, dout, *, form, width, eps, scale,
     with no_x64():
         return pl.pallas_call(
             functools.partial(_gate_norm_bwd_kernel, form=form, width=width,
-                              eps=eps, scale=scale),
+                              eps=eps, scale=scale, act=act),
             grid=(b, columns // tiles[1], t // tiles[0]),
             in_specs=[core, gate, gamma_spec, across],
             out_specs=[core, across, small],
@@ -410,26 +460,33 @@ def gate_norm_bwd_call(y, src, gamma, dout, *, form, width, eps, scale,
                 jax.ShapeDtypeStruct((b, t, columns), src.dtype),
                 jax.ShapeDtypeStruct((b, 8, columns), jnp.float32)],
             compiler_params=_params(tiles, width, src.dtype, form),
-            name=_name("bwd", src.dtype, tiles, width, form),
+            name=_name("bwd", src.dtype, tiles, width, form, act),
             interpret=interpret,
         )(y, src, gamma, dout)
 
 
-def plain_form(y, src, gamma, *, form, width, eps, scale, offset):
+def plain_form(y, src, gamma, *, form, width, eps, scale, offset,
+               act="silu"):
     """The op in ``jax.numpy`` on the kernels' operands, as the
-    ``gate_norm`` closures of ``_mamba2_block`` and ``_gated_delta_block``
-    write it: the branch for every platform but the TPU, and the oracle of
-    the kernels' tests."""
+    ``gate_norm`` closures of ``_mamba2_block``, ``_gated_delta_block``
+    and ``_channel_delta_block`` write it: the branch for every platform
+    but the TPU, and the oracle of the kernels' tests."""
     f32 = jnp.float32
     b, t, columns = _extent(y, form)
+    gate = getattr(jax.nn, act)
     if form == "norm_first":
         o = jnp.moveaxis(y, 1, 2)
         var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
         normed = o * jax.lax.rsqrt(var + eps) * gamma.astype(f32)
-        gated = normed.reshape(b, t, columns) * jax.nn.silu(src.astype(f32))
+        gated = normed.reshape(b, t, columns) * gate(src.astype(f32))
         return gated.astype(src.dtype)
     z = src[..., offset:offset + columns].astype(f32)
-    gated = y * jax.nn.silu(z if scale is None else z * scale)
+    if form == "token_major":
+        o = y.reshape(b, t, columns // width, width)
+        var = jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+        normed = o * jax.lax.rsqrt(var + eps) * gamma.astype(f32)
+        return (normed.reshape(b, t, columns) * gate(z)).astype(src.dtype)
+    gated = y * gate(z if scale is None else z * scale)
     groups = gated.reshape(b, t, columns // width, width)
     var = jnp.mean(jnp.square(groups), axis=-1, keepdims=True)
     normed = (groups * jax.lax.rsqrt(var + eps)).reshape(b, t, columns)
@@ -439,7 +496,7 @@ def plain_form(y, src, gamma, *, form, width, eps, scale, offset):
 def _gamma_row(gamma, dtype, form, tiles):
     """gamma as the kernels hold it, float32 [1, columns]: rounded to the
     result's type first where the form multiplies in it (``gate_first``),
-    a head's side by side over a column tile (``norm_first``)."""
+    a head's side by side over a column tile (the two other forms)."""
     if form == "gate_first":
         gamma = gamma.astype(dtype)
     else:
@@ -447,27 +504,29 @@ def _gamma_row(gamma, dtype, form, tiles):
     return gamma.astype(jnp.float32).reshape(1, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _gate_norm(y, src, gamma, form, width, eps, scale, offset, tiles,
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _gate_norm(y, src, gamma, form, width, eps, scale, act, offset, tiles,
                interpret):
-    return _gate_norm_fwd(y, src, gamma, form, width, eps, scale, offset,
-                          tiles, interpret)[0]
+    return _gate_norm_fwd(y, src, gamma, form, width, eps, scale, act,
+                          offset, tiles, interpret)[0]
 
 
-def _gate_norm_fwd(y, src, gamma, form, width, eps, scale, offset, tiles,
-                   interpret):
+def _gate_norm_fwd(y, src, gamma, form, width, eps, scale, act, offset,
+                   tiles, interpret):
     # one trace for the primal and the rule: see ``ssd._ssd_fwd``
     with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
         out = _gate_norm_forward(y, src, gamma, form=form, width=width,
-                                 eps=eps, scale=scale, offset=offset,
-                                 tiles=tiles, interpret=interpret)
+                                 eps=eps, scale=scale, act=act,
+                                 offset=offset, tiles=tiles,
+                                 interpret=interpret)
     return out, (y, src, gamma)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _gate_norm_forward(y, src, gamma, *, form, width, eps, scale, offset,
-                       tiles, interpret):
-    static = dict(form=form, width=width, eps=eps, scale=scale,
+def _gate_norm_forward(y, src, gamma, *, form, width, eps, scale, act,
+                       offset, tiles, interpret):
+    static = dict(form=form, width=width, eps=eps, scale=scale, act=act,
                   offset=offset)
 
     def kernels(y, src, gamma, interpret):
@@ -479,9 +538,9 @@ def _gate_norm_forward(y, src, gamma, *, form, width, eps, scale, offset,
                   interpret, y, src, gamma)
 
 
-def _gate_norm_bwd(form, width, eps, scale, offset, tiles, interpret, res,
-                   dout):
-    static = dict(form=form, width=width, eps=eps, scale=scale,
+def _gate_norm_bwd(form, width, eps, scale, act, offset, tiles, interpret,
+                   res, dout):
+    static = dict(form=form, width=width, eps=eps, scale=scale, act=act,
                   offset=offset)
 
     def kernels(y, src, gamma, dout, interpret):
@@ -505,19 +564,21 @@ _gate_norm.defvjp(_gate_norm_fwd, _gate_norm_bwd)
 
 
 def gated_rms_norm(y, src, gamma, *, form, eps, groups=None, scale=None,
-                   offset=0, interpret=False):
+                   offset=0, act="silu", interpret=False):
     """The gate and the grouped RMSNorm of a state-space block
     (``FORMS``), as a Pallas kernel pair differentiable in all three, for
     the shapes ``gate_norm_takes`` admits. ``gate_first``: y [B, T, C]
     float32 in ``groups`` groups, the gate the ``C`` columns of src [B, T,
     W] from ``offset``, ``scale`` a fixed scalar inside the gate's
-    ``silu``, gamma [C]. ``norm_first``: y [B, H, T, V] float32
-    (head-major), src the gate [B, T, H V], gamma [V]. -> [B, T, C] in
-    src's type. Mosaic where the computation is lowered for the TPU and
-    ``plain_form`` on every other platform, the choice made inside the
-    ``custom_vjp``; ``interpret=True`` (the kernels' tests) runs the
-    kernels through the Pallas interpreter. No partitioning rule: inside a
-    sharded ``jit``, call under ``shard_map``."""
+    activation, gamma [C]. ``norm_first``: y [B, H, T, V] float32
+    (head-major), src the gate [B, T, H V], gamma [V]. ``token_major``:
+    y [B, T, H V] float32 in ``groups`` heads, the gate as
+    ``gate_first``'s, gamma [V]. ``act``: the gate's activation
+    (``ACTS``). -> [B, T, C] in src's type. Mosaic where the computation
+    is lowered for the TPU and ``plain_form`` on every other platform, the
+    choice made inside the ``custom_vjp``; ``interpret=True`` (the
+    kernels' tests) runs the kernels through the Pallas interpreter. No
+    partitioning rule: inside a sharded ``jit``, call under ``shard_map``."""
     if form == "norm_first":
         groups, width = y.shape[1], y.shape[3]
     else:
@@ -525,13 +586,13 @@ def gated_rms_norm(y, src, gamma, *, form, eps, groups=None, scale=None,
     time = _extent(y, form)[1]
     tiles = gate_norm_tiles(form, groups, width, time, src.dtype, offset,
                             src.shape[2])
-    if (tiles is None or y.dtype != jnp.float32
-            or (form == "norm_first" and scale is not None)):
+    if (tiles is None or y.dtype != jnp.float32 or act not in ACTS
+            or (form != "gate_first" and scale is not None)):
         raise ValueError(
             "gated_rms_norm: no tiles for %d groups of %d columns over %d "
-            "rows (%s) with the gate at %d of %s, form %r (gate_norm_takes "
-            "decides)" % (groups, width, time, y.dtype, offset, src.shape,
-                          form))
+            "rows (%s) with the gate (%s) at %d of %s, form %r "
+            "(gate_norm_takes decides)" % (groups, width, time, y.dtype, act,
+                                           offset, src.shape, form))
     return _gate_norm(y, src, gamma, form, int(width), float(eps),
-                      None if scale is None else float(scale), int(offset),
-                      tiles, bool(interpret))
+                      None if scale is None else float(scale), act,
+                      int(offset), tiles, bool(interpret))

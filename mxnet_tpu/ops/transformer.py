@@ -850,7 +850,7 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     unit norms, ``beta`` and ``g`` only: the kernel pair keeps its own
     residuals and runs once each way. Where ``kernels.gate_norm_takes``
     has tiles the gate and norm are ``gated_rms_norm`` (``norm_first``) on
-    ``o`` head-major as the rule's kernel wrote it.
+    ``o`` head-major as the rule's kernel wrote it (a ``silu`` gate).
 
     **A decay a channel** (Kimi Delta Attention, arXiv:2510.26692), taken
     by the shape of ``a``: [B, T, H K] with ``dt_bias`` [H K] (``a_log``
@@ -859,9 +859,9 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     ``kernels.gdn_takes(..., "channel")`` has tiles (a head whole lane
     rows) the block is ``_channel_delta_block``: the pair ``kda_fwd_`` /
     ``kda_bwd_`` from the taps' outputs on, the unit norms and decays made
-    in VMEM. ``gate_act="sigmoid"``: the gate behind the norm is a
-    sigmoid, in the ``gate_norm`` closure everywhere (``gated_rms_norm``
-    knows ``silu``). Both are counted where they are not the default.
+    in VMEM, and the gate and norm ``gated_rms_norm`` (``token_major``)
+    on ``o`` as that pair wrote it. ``gate_act="sigmoid"``: the gate behind
+    the norm is a sigmoid. Both are counted where they are not the default.
 
     The call site counts itself here (``linear_attn.lowerings``,
     ``gate_norm.lowerings``, ``causal_taps.lowerings`` once a convolved
@@ -876,9 +876,8 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     kernel = kernels.gdn_takes(
         num_heads, key_dim, value_dim, chunk_size, value.dtype,
         "channel" if channel else "scalar")
-    labels = {"decay": "channel"} if channel else {}
-    if gate_act != "silu":
-        labels["gate"] = gate_act
+    gated = {} if gate_act == "silu" else {"gate": gate_act}
+    labels = dict(gated, decay="channel") if channel else gated
     _M_LINEAR_ATTN_LOWERINGS.inc(
         heads=num_heads, key_dim=key_dim, value_dim=value_dim,
         chunk=chunk_size, conv=conv_weight.shape[0],
@@ -886,18 +885,19 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
     taps_kernel = tuple(
         _taps_site("gated_delta_net", x, conv_weight, "silu",
                    channels=x.shape[2]) for x in (query, key, value))
-    # the norm's kernel reads o where the rule's kernel left it, head-major
+    # the norm's kernel reads o where the rule's kernel left it: head-major
+    # from the scalar pair (a silu gate), token-major from the channel pair
     norm_kernel = _gate_norm_site(
-        "gated_delta_net", "norm_first", num_heads, value_dim, gate,
-        core=kernel and not channel and query.shape[1] % chunk_size == 0
-        and gate_act == "silu")
+        "gated_delta_net", "token_major" if channel else "norm_first",
+        num_heads, value_dim, gate, core=kernel and (channel or (
+            query.shape[1] % chunk_size == 0 and gate_act == "silu")), **gated)
     if channel and kernel:  # the rule's pair from the taps' outputs on
         return _channel_delta_block(
             query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
             norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
             eps=float(eps), beta_scale=2.0 if allow_neg_eigval else 1.0,
-            remat=bool(remat), taps_kernel=taps_kernel,
-            interpret=kernels.common.INTERPRET, gate_act=gate_act)
+            remat=bool(remat), taps_kernel=taps_kernel, gate_act=gate_act,
+            interpret=kernels.common.INTERPRET, norm_kernel=norm_kernel)
     return _gated_delta_block(
         query, key, value, gate, a, b, conv_weight, a_log, dt_bias,
         norm_gamma, heads=int(num_heads), chunk=int(chunk_size),
@@ -1291,23 +1291,23 @@ def _taps_site(site, src, conv_weight, form, offset=0, channels=None):
 _M_GATE_NORM_LOWERINGS = _tm.counter(
     "gate_norm.lowerings", "Traces of the gate and grouped RMSNorm of a "
     "Mamba2 or GatedDeltaNet call site (one per node and lowering, nothing "
-    "per step); labels: site, groups, width, impl (kernel: the Pallas pair "
-    "of ops/kernels/gate_norm.py where the step is lowered for the TPU, the "
+    "per step); labels: site, groups, width, gate (where not silu), impl "
+    "(kernel: the pair of ops/kernels/gate_norm.py where lowered for the TPU, "
     "jax.numpy form of the same signature elsewhere; jnp: the block's "
     "gate_norm closure everywhere)")
 
 
-def _gate_norm_site(site, form, groups, width, src, core=True):
+def _gate_norm_site(site, form, groups, width, src, core=True, **gated):
     """Whether ``kernels.gated_rms_norm`` takes ``groups`` groups of
     ``width`` columns gated by the first of src's (``core``: whether what
     feeds it is laid out as the kernels read it); the call site counts
-    itself here, outside its block's ``jax.jit``."""
+    itself here (``gated``: more labels), outside its block's ``jax.jit``."""
     from . import kernels
 
     kernel = bool(core) and kernels.gate_norm_takes(
         form, groups, width, src.shape[1], src.dtype, 0, src.shape[2])
     _M_GATE_NORM_LOWERINGS.inc(site=site, groups=groups, width=width,
-                               impl="kernel" if kernel else "jnp")
+                               impl="kernel" if kernel else "jnp", **gated)
     return kernel
 
 
@@ -1498,11 +1498,11 @@ def _decayed_products(q, k, cum, sub, dot):
 
 @functools.partial(jax.jit, static_argnames=(
     "heads", "chunk", "eps", "beta_scale", "remat", "taps_kernel",
-    "interpret", "gate_act"))
+    "interpret", "gate_act", "norm_kernel"))
 def _channel_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                          dt_bias, norm_gamma, *, heads, chunk, eps,
                          beta_scale, remat, taps_kernel, interpret,
-                         gate_act):
+                         gate_act, norm_kernel):
     """``gated_delta_net`` with a decay a channel where the rule's kernel
     pair has tiles (``kernels.gdn_takes(..., "channel")``), one signature:
     ``_gated_delta_block``'s three scopes with ``delta_rule`` ONE call,
@@ -1513,9 +1513,14 @@ def _channel_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
     strengths, and no array [B, T, H, K] exists between the taps' pair and
     the norm (as a reshape of [B, T, H K] it is a move on the TPU: a tile
     is eight heads of a token there and eight tokens of a head here).
-    Off the TPU the call is the chunk form on the same values. (Down here
-    so that no line of ``_gated_delta_block`` moves: the scalar pair's
-    call sits in it.)"""
+    ``gate_norm`` reads ``o`` where the pair wrote it, token-major, a head
+    a lane row: ``kernels.gated_rms_norm`` (``token_major``, the gate's
+    activation ``gate_act``) where ``norm_kernel`` (``gate_norm_takes``
+    has tiles), with no checkpoint round it (its backward kernel is the
+    recomputation), the closure for the shapes the pair refuses.
+    Off the TPU the calls are the ``jax.numpy`` forms on the same values.
+    (Down here so that no line of ``_gated_delta_block`` moves: the scalar
+    pair's call sits in it.)"""
     from . import kernels
 
     f32 = jnp.float32
@@ -1550,4 +1555,8 @@ def _channel_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
         o = kernels.channel_delta_net(q, k, v, a, beta, a_log, dt_bias,
                                       chunk, interpret=interpret)
     with jax.named_scope("gate_norm"):
+        if norm_kernel:
+            return kernels.gated_rms_norm(o, gate, norm_gamma, eps=eps,
+                                          form="token_major", groups=heads,
+                                          act=gate_act, interpret=interpret)
         return again(gate_norm)(o, gate, norm_gamma)

@@ -242,11 +242,16 @@ def test_the_channel_delta_block_compiles_within_its_memory(one_chip):
     the decays made inside: Mosaic takes every slice, broadcast and
     product of both bodies, once each way, a step's working set under the
     scoped VMEM the calls state) and never the scalar pair, the
-    sigmoid-gated norm is still no kernel, no [B, T, H, K] array is moved
-    in front of the pair, and the compiled block's temporaries stay under
-    2.0 GB (1.48 as written; the ``jax.numpy`` chunk form compiled to
-    4.02): the pair's residuals (the entering states 268 MB, the inverses
-    67, the tables 134) and what crosses between the scopes."""
+    sigmoid-gated norm is the gate and norm's pair in its token-major
+    form (``gate_norm_fwd_`` / ``gate_norm_bwd_bf16_r256_g128_token_major_
+    sigmoid``, once each way: it reads ``o`` straight from the rule's
+    kernel's tuple and the rule's backward reads ``do`` straight from
+    its, with no copy, slice or transpose between them), no [B, T, H, K]
+    array is moved in front of the pair, and the compiled block's
+    temporaries stay under 2.0 GB (1.48 before the norm's pair; the
+    ``jax.numpy`` chunk form compiled to 4.02): the pair's residuals (the
+    entering states 268 MB, the inverses 67, the tables 134) and what
+    crosses between the scopes."""
     from mxnet_tpu.ops import transformer as tr
 
     t, h, d = 8192, 32, 128
@@ -289,7 +294,27 @@ def test_the_channel_delta_block_compiles_within_its_memory(one_chip):
         assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (
             name, used, limit)
     assert "gdn_fwd_" not in text and "gdn_bwd_" not in text
-    assert "gate_norm_fwd_" not in text
+    made_by = {m.group(1): m.group(2) for m in re.finditer(
+        r"%([\w.\-]+) = \S+ ([\w\-]+)\(", text)}
+
+    def operand_kinds(line):
+        operands = re.search(r"custom-call\(([^)]*)\)", line).group(1)
+        return [made_by.get(o.split("%")[-1].strip())
+                for o in operands.split(",")]
+
+    for which in ("fwd", "bwd"):
+        name = "gate_norm_%s_bf16_r256_g128_token_major_sigmoid" % which
+        calls = [line for line in text.splitlines()
+                 if name in line.split(" = ")[0] and "custom-call(" in line]
+        assert len(calls) == 1, name
+        kinds = operand_kinds(calls[0])
+        assert not {"copy", "slice", "transpose", "reshape"} & set(kinds), (
+            name, kinds)
+        assert kinds[0] == "get-tuple-element", (name, kinds)
+    rule_bwd, = [line for line in text.splitlines()
+                 if "kda_bwd_" in line.split(" = ")[0]
+                 and "custom-call(" in line]
+    assert operand_kinds(rule_bwd)[-1] == "get-tuple-element"
     assert "triangular" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
 

@@ -615,9 +615,9 @@ def _channel_block(kernel, interpret, remat, ins, cot):
     on) or of ``_gated_delta_block`` in the ``jax.numpy`` chunk form."""
     kw = dict(heads=CH, chunk=CCHUNK, eps=1e-6, beta_scale=1.0, remat=remat,
               taps_kernel=(False,) * 3, interpret=interpret,
-              gate_act="sigmoid")
+              gate_act="sigmoid", norm_kernel=False)
     block = (tr._channel_delta_block if kernel else functools.partial(
-        tr._gated_delta_block, kernel=False, norm_kernel=False))
+        tr._gated_delta_block, kernel=False))
 
     def loss(*a):
         o = block(*a, **kw)

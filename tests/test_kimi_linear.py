@@ -319,20 +319,79 @@ def test_the_scalar_path_is_bit_equal_to_the_parents(dtype):
                                       np.asarray(w, np.float32))
 
 
-def test_the_kernel_pairs_refuse_the_channel_form_and_the_sigmoid_gate():
-    """What is true since the channel rule has its own Pallas pair (the
-    name is the test's of PR 53, when neither was taken): at the cell's
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+# the gate and norm's calls that stood before the token-major form: the
+# operands' shapes (y, the gate's array, gamma), the arguments, and what
+# the kernels are named, their grid and their blocks of y and of the gate
+SILU_NORMS = {
+    "nemotron3_nano_fit_share_8k": (
+        ((1, 8192, 4096), (1, 8192, 10304), (4096,)),
+        dict(form="gate_first", groups=8), "r256_g512_gate_first",
+        (1, 2, 32), (256, 2048), (256, 2048)),
+    "falcon_h1_fit_share_4k": (
+        ((1, 4096, 2048), (1, 4096, 4624), (2048,)),
+        dict(form="gate_first", groups=1, scale=0.7),
+        "r256_g2048_gate_first", (1, 1, 16), (256, 2048), (256, 2048)),
+    "olmo_hybrid_fit_stage_4k": (
+        ((1, 30, 4096, 192), (1, 4096, 5760), (192,)),
+        dict(form="norm_first"), "r1024_g192_norm_first", (1, 15, 4),
+        (2, 1024, 192), (1024, 384)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SILU_NORMS))
+def test_the_silu_norms_keep_their_kernels(cell):
+    """Nemotron's, Falcon-H1's and Olmo-Hybrid's gate and norm, called
+    with the arguments they always had: the pair's names (no gate in
+    them: ``silu`` is the default), tiles, grids and blocks are what they
+    were before the family learnt the token-major form and the sigmoid."""
+    shapes, kwargs, name, grid, core, gate = SILU_NORMS[cell]
+    ins = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in zip(
+        shapes, (jnp.float32, jnp.bfloat16, jnp.bfloat16))]
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda *a: jnp.sum(pk.gated_rms_norm(
+            *a, eps=1e-5, interpret=True, **kwargs).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(*ins)
+    calls = {str(c.params["name"]): c for c in _pallas_calls(jaxpr.jaxpr)}
+    assert sorted(calls) == ["gate_norm_%s_bf16_%s" % (which, name)
+                             for which in ("bwd", "fwd")]
+    for call in calls.values():
+        mapping = call.params["grid_mapping"]
+        assert tuple(mapping.grid) == grid
+        blocks = [tuple(d.block_size for d in m.block_shape
+                        if hasattr(d, "block_size"))
+                  for m in mapping.block_mappings[:2]]
+        assert blocks == [core, gate], (call.params["name"], blocks)
+
+
+def test_the_kernel_pairs_take_the_channel_form_and_the_sigmoid_gate():
+    """What is true since the channel rule has its own Pallas pair and the
+    gate and norm's pair its token-major form and the sigmoid (the test
+    was PR 53's ``..._refuse_...``, when neither was taken): at the cell's
     shape (32 heads of 128 / 128, chunks of 64, bf16) the rule's pair
     takes a decay a channel (``impl="kernel", decay="channel"``) and
-    refuses what it has no tiles for; the gate and norm's pair (which
-    knows ``silu``) is still never handed a sigmoid; the taps' pair takes
-    the three 4,096-column convolutions as it stands."""
+    refuses what it has no tiles for; the gate and norm's pair reads ``o``
+    token-major where that pair wrote it, under the sigmoid
+    (``impl="kernel", gate="sigmoid"``), and is the closure's for heads
+    that are no whole lane rows; the taps' pair takes the three
+    4,096-column convolutions as it stands."""
     assert pk.gdn_takes(32, 128, 128, 64, jnp.bfloat16)
     assert pk.gdn_takes(32, 128, 128, 64, jnp.bfloat16, "channel")
     assert not pk.gdn_takes(32, 96, 192, 64, jnp.bfloat16, "channel")
     assert not pk.gdn_takes(32, 128, 128, 24, jnp.bfloat16, "channel")
     assert not pk.gdn_takes(H, D, D, CHUNK, jnp.float32, "channel")
     assert pk.taps_takes(4096, 8192, 4, jnp.bfloat16, "silu", 0, 4096)
+    assert pk.gate_norm_takes("token_major", 32, 128, 8192, jnp.bfloat16, 0,
+                              4096)
+    assert not pk.gate_norm_takes("token_major", 32, 96, 8192, jnp.bfloat16,
+                                  0, 3072)
     telemetry.reset()
     telemetry.enable()
     try:
@@ -353,7 +412,8 @@ def test_the_kernel_pairs_refuse_the_channel_form_and_the_sigmoid_gate():
         assert telemetry.total("linear_attn.lowerings") == 1
         norm = telemetry.REGISTRY.get("gate_norm.lowerings")
         assert norm.value(site="gated_delta_net", groups=32, width=128,
-                          impl="jnp") == 1
+                          impl="kernel", gate="sigmoid") == 1
+        assert telemetry.total("gate_norm.lowerings") == 1
         taps = telemetry.REGISTRY.get("causal_taps.lowerings")
         assert taps.value(site="gated_delta_net", channels=4096, taps=4,
                           impl="kernel") == 3

@@ -19,9 +19,8 @@ Also prints how far each form's output and gradients are, on the chip,
 from the chunk form in float32 with every product at the highest precision
 (largest difference over that one's largest magnitude), and each pair's
 VMEM a step by the accounting. `--heads-a-step=1,4,16` times the fused
-pair at other head groups than its own. Prints one JSON line a row and
-writes `chiprun_out/channel_delta_rule_table.json`; PERF.md section 7 holds
-the table (PR 54).
+pair at other head groups than its own. PERF.md section 7 holds the table
+(PR 54).
 
 `--gate-norm` prints what follows the rule instead (PR 56): the per-head
 norm and its sigmoid gate on `o` token-major as the pair wrote it
@@ -29,74 +28,28 @@ norm and its sigmoid gate on `o` token-major as the pair wrote it
 `jax.numpy` closure under its checkpoint against the kernel pair through
 its entry, then the two calls alone by (row tile, column tile) and by the
 rows a loop step takes (`gate_norm._ROWS_TOKEN_MAJOR`;
-`--rows-a-step=64,128,256`), device ms by kernel name. Writes
-`chiprun_out/channel_gate_norm_table.json`.
+`--rows-a-step=64,128,256`), device ms by kernel name (the file's table
+is `gate_norm`).
 
     chiprun -- python3 benchmarks/channel_delta_rule.py [--gate-norm]
+    python3 benchmarks/channel_delta_rule.py --rehearse-cpu [--gate-norm]
 
-`--rehearse-cpu` runs the same flow at a toy size here (the pairs' branch
-for other platforms, no trace): it proves the script, not a number.
+The platform rule, the clocks and the output file are `alone.py`'s.
 """
-import collections
 import functools
-import glob
-import json
-import os
 import sys
-import tempfile
-import time
 
+import alone
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from mxnet_tpu.ops import kernels as pk  # noqa: E402
-from mxnet_tpu.ops.transformer import channel_delta_rule  # noqa: E402
+from mxnet_tpu.ops import kernels as pk
+from mxnet_tpu.ops.transformer import channel_delta_rule
 
 B, T, H, K, V, CHUNK = 1, 8192, 32, 128, 128, 64
 INPUTS = ("q", "k", "v", "a", "b")
-
-
-def _time(f, *args, reps=10):
-    jax.block_until_ready(f(*args))
-    jax.block_until_ready(f(*args))
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        r = f(*args)
-    jax.block_until_ready(r)
-    np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[:1])  # a fetch
-    return (time.perf_counter() - t0) / reps * 1e3
-
-
-def _device_ms(g, *args, reps=5):
-    """Device ms a call of each ``kda_`` / ``gdn_`` / ``gate_norm_``
-    kernel and of everything else in the program, from a profiler trace of
-    ``reps`` calls."""
-    from jax.profiler import ProfileData
-
-    jax.block_until_ready(g(*args))
-    where = tempfile.mkdtemp()
-    with jax.profiler.trace(where):
-        for _ in range(reps):
-            r = g(*args)
-        jax.block_until_ready(r)
-    trace, = glob.glob(where + "/plugins/profile/*/*.xplane.pb")
-    ms = collections.Counter()
-    for plane in ProfileData.from_file(trace).planes:
-        if plane.name != "/device:TPU:0":
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for e in line.events:
-                name = e.name.split(" = ")[0].lstrip("%")
-                ms[name.split(".")[0]
-                   if name.startswith(("kda_", "gdn_", "gate_norm_"))
-                   else "everything else"] += e.duration_ns / 1e6 / reps
-    return dict(ms)
 
 
 def inputs(seed, dtype, t):
@@ -162,10 +115,7 @@ def forms():
             "fused": both(fused), "scalar_kernel": both(scalar_kernel)}
 
 
-PEAK_GBS = 819.0  # bench/peaks.json, "TPU v5 lite"
-
-
-def gate_norm_table(row, rehearse, steps, row_tiles=(256, 512, 1024),
+def gate_norm_table(run, steps, row_tiles=(256, 512, 1024),
                     column_tiles=(512, 1024, 2048, 4096)):
     """The gate and norm behind the channel rule alone, one layer at the
     cell's shape: ``o`` float32 and the gate bf16, both [B, T, H V]."""
@@ -184,6 +134,7 @@ def gate_norm_table(row, rehearse, steps, row_tiles=(256, 512, 1024),
     # two and the cotangent in, two cotangents out
     fwd_mb = T * columns * (4 + 2 + 2) / 1e6
     bwd_mb = T * columns * (4 + 2 + 2 + 4 + 2) / 1e6
+    bound_ms = run.bound(nbytes=1e6 * (fwd_mb + bwd_mb))
 
     def each_way(f):
         # the result's cotangent an operand: a loss summed here would
@@ -194,23 +145,19 @@ def gate_norm_table(row, rehearse, steps, row_tiles=(256, 512, 1024),
         return jax.jit(both)
 
     def ms_of(f, *args):
-        if rehearse:
-            return {"everything else": _time(f, *args, reps=2)}
-        return _device_ms(f, *args)
+        return alone.by_kernel(run.device_ops(f, *args), "gate_norm_")
 
     def fwd_bwd(ms):
-        return tuple(sum(v for k, v in ms.items()
-                         if k.startswith("gate_norm_" + which))
+        return tuple(alone.named(ms, "gate_norm_" + which)
                      for which in ("fwd", "bwd"))
 
     plain = each_way(jax.checkpoint(functools.partial(gn.plain_form,
                                                       **static)))
-    ms = ms_of(plain, o, gate, gamma, cot)["everything else"]
-    row(gate_norm="jnp", fwd_bwd_ms=ms,
-        bytes_bound_fwd_bwd_ms=(fwd_mb + bwd_mb) / PEAK_GBS)
+    ms = ms_of(plain, o, gate, gamma, cot).get(alone.REST)
+    run.row(gate_norm="jnp", fwd_bwd_ms=ms, bytes_bound_fwd_bwd_ms=bound_ms)
     entry = each_way(functools.partial(
         pk.gated_rms_norm, form="token_major", eps=1e-5, groups=H,
-        act="sigmoid", interpret=rehearse))
+        act="sigmoid", interpret=run.rehearse))
     ms = ms_of(entry, o, gate, gamma, cot)
     fwd, bwd = fwd_bwd(ms)
     far = {name: float(jnp.abs(g.astype(f32) - w.astype(f32)).max()
@@ -218,15 +165,15 @@ def gate_norm_table(row, rehearse, steps, row_tiles=(256, 512, 1024),
            for name, g, w in zip(("out", "do", "dgate", "dgamma"),
                                  entry(o, gate, gamma, cot),
                                  plain(o, gate, gamma, cot))}
-    row(gate_norm="kernel", rows_a_step=gn._ROWS_TOKEN_MAJOR,
-        tiles=gn.gate_norm_tiles("token_major", H, V, T, bf16, 0, columns),
-        fwd_ms=fwd, bwd_ms=bwd, rest_ms=ms.get("everything else"),
-        share_of_bytes_bound=(100 * (fwd_mb + bwd_mb) / PEAK_GBS
-                              / (fwd + bwd) if fwd + bwd else None),
-        far_from_jnp=far,
-        kernels=sorted(k for k in ms if k.startswith("gate_norm_")))
+    run.row(gate_norm="kernel", rows_a_step=gn._ROWS_TOKEN_MAJOR,
+            tiles=gn.gate_norm_tiles("token_major", H, V, T, bf16, 0,
+                                     columns),
+            fwd_ms=fwd, bwd_ms=bwd, rest_ms=ms.get(alone.REST),
+            share_of_bytes_bound=alone.ratio(bound_ms, fwd + bwd, 100),
+            far_from_jnp=far,
+            kernels=sorted(k for k in ms if k.startswith("gate_norm_")))
     own = gn._ROWS_TOKEN_MAJOR
-    for rows in () if rehearse else steps:
+    for rows in steps:
         gn._ROWS_TOKEN_MAJOR = rows
         gn.gate_norm_fwd_call.clear_cache()
         gn.gate_norm_bwd_call.clear_cache()
@@ -235,50 +182,40 @@ def gate_norm_table(row, rehearse, steps, row_tiles=(256, 512, 1024),
                      and gn.gate_norm_vmem_bytes(r, c, V, 2, "token_major")
                      <= pk.common.VMEM_RAISED_LIMIT]:
             def both(o, gate, gamma_row, cot, tile=tile):
-                kw = dict(tiles=tile, interpret=False, **static)
+                kw = dict(tiles=tile, interpret=run.rehearse, **static)
                 return (gn.gate_norm_fwd_call(o, gate, gamma_row, **kw),
                         gn.gate_norm_bwd_call(o, gate, gamma_row, cot, **kw))
             try:
-                ms = _device_ms(
+                ms = ms_of(
                     jax.jit(both), o, gate,
                     gn._gamma_row(gamma, bf16, "token_major", tile), cot)
             except Exception as e:  # noqa: BLE001 — Mosaic refused the tile
-                row(gate_norm="calls", rows_a_step=rows, tiles=tile,
-                    refused=str(e)[:300])
+                run.row(gate_norm="calls", rows_a_step=rows, tiles=tile,
+                        refused=str(e)[:300])
                 continue
             fwd, bwd = fwd_bwd(ms)
-            row(gate_norm="calls", rows_a_step=rows, tiles=tile, fwd_ms=fwd,
-                bwd_ms=bwd, fwd_gbs=fwd_mb / fwd, bwd_gbs=bwd_mb / bwd)
+            run.row(gate_norm="calls", rows_a_step=rows, tiles=tile,
+                    fwd_ms=fwd, bwd_ms=bwd, fwd_gbs=alone.ratio(fwd_mb, fwd),
+                    bwd_gbs=alone.ratio(bwd_mb, bwd))
     gn._ROWS_TOKEN_MAJOR = own
 
 
 def main():
     global T, H
-    rehearse = "--rehearse-cpu" in sys.argv
+    run = alone.Run(__file__)
     steps = [a.split("=", 1)[1] for a in sys.argv
              if a.startswith("--heads-a-step=")]
-    if rehearse:
+    if run.rehearse:
         T, H = 128, 2
-    dev = jax.devices()[0]
-    res = {"device": str(dev.device_kind), "platform": dev.platform,
-           "shape": dict(b=B, t=T, heads=H, key_dim=K, value_dim=V,
-                         chunk=CHUNK), "rows": []}
-
-    def row(**kw):
-        print(json.dumps(kw), flush=True)
-        res["rows"].append(kw)
-
+    shape = dict(b=B, t=T, heads=H, key_dim=K, value_dim=V, chunk=CHUNK)
     if "--gate-norm" in sys.argv:
-        gate_norm_table(row, rehearse, [
+        gate_norm_table(run, [
             int(r) for a in sys.argv if a.startswith("--rows-a-step=")
             for r in a.split("=", 1)[1].split(",")] or (64, 128, 256))
-        os.makedirs("chiprun_out", exist_ok=True)
-        with open("chiprun_out/channel_gate_norm_table.json", "w") as f:
-            json.dump(res, f, indent=1)
-        return
+        return run.save("gate_norm", shape=shape)
     assert pk.gdn_takes(H, K, V, CHUNK, jnp.bfloat16, "channel")
     both = forms()
-    args, cot = inputs(0, jnp.bfloat16, T if rehearse else 2048)
+    args, cot = inputs(0, jnp.bfloat16, min(T, 2048))
     outs = {name: (f(*args), g(cot, *args)[1])
             for name, (f, g) in both.items() if name != "scalar_kernel"}
 
@@ -293,22 +230,25 @@ def main():
         exact = tuple(v.astype(jnp.float32) for v in args)
         o_x, g_x = f(*exact), g(cot, *exact)[1]
     for name, (o, grads) in outs.items():
-        row(check=name + "_against_float32_highest", dtype="bfloat16",
-            t=args[0].shape[1], o=rel(o, o_x),
-            **{"d" + n: rel(k, e) for n, k, e in zip(INPUTS, grads, g_x)})
+        run.row(check=name + "_against_float32_highest", dtype="bfloat16",
+                t=args[0].shape[1], o=rel(o, o_x),
+                **{"d" + n: rel(k, e) for n, k, e in zip(INPUTS, grads, g_x)})
 
     args, cot = inputs(1, jnp.bfloat16, T)
-    for _ in range(1 if rehearse else 2):
-        for name, (f, g) in both.items():
-            fwd = _time(f, *args)
-            row(form=name, fwd_ms=fwd, fwd_bwd_ms=_time(g, cot, *args))
+    for name, (f, g) in run.alternate(both, rounds=2):
+        run.row(form=name, fwd_ms=run.host_ms(f, *args, reps=10),
+                fwd_bwd_ms=run.host_ms(g, cot, *args, reps=10))
     item = jnp.dtype(jnp.bfloat16).itemsize
-    row(vmem_by_the_accounting=dict(
+    run.row(vmem_by_the_accounting=dict(
         kda=pk.gdn.kda_vmem_bytes(CHUNK, pk.gdn.kda_group(H), H, K, V, item),
         gdn=pk.gdn.gdn_vmem_bytes(CHUNK, pk.gdn.gdn_group(H), K, V, item)))
-    for name in () if rehearse else ("kernel", "fused", "scalar_kernel"):
-        row(form=name, kernels_device_ms=_device_ms(both[name][1], cot,
-                                                    *args))
+
+    def kernels_ms(g):
+        return alone.by_kernel(run.device_ops(g, cot, *args),
+                               "kda_", "gdn_", "gate_norm_")
+
+    for name in ("kernel", "fused", "scalar_kernel"):
+        run.row(form=name, kernels_device_ms=kernels_ms(both[name][1]))
 
     for per in [int(p) for s in steps for p in s.split(",") if p]:
         own, pk.gdn.KDA_HEADS_A_STEP = pk.gdn.KDA_HEADS_A_STEP, per
@@ -316,13 +256,12 @@ def main():
                   pk.gdn.kda_net_forward):
             f.clear_cache()
         f, g = forms()["fused"]
-        row(heads_a_step=pk.gdn.kda_group(H), fwd_ms=_time(f, *args),
-            fwd_bwd_ms=_time(g, cot, *args),
-            kernels_device_ms=None if rehearse else _device_ms(g, cot, *args))
+        run.row(heads_a_step=pk.gdn.kda_group(H),
+                fwd_ms=run.host_ms(f, *args, reps=10),
+                fwd_bwd_ms=run.host_ms(g, cot, *args, reps=10),
+                kernels_device_ms=kernels_ms(g))
         pk.gdn.KDA_HEADS_A_STEP = own
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/channel_delta_rule_table.json", "w") as f:
-        json.dump(res, f, indent=1)
+    run.save(shape=shape)
 
 
 if __name__ == "__main__":

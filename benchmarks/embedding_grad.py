@@ -6,29 +6,24 @@ the grouped matmul's wgrad kernel over an exact 0 / 1 table), at the seven
 LM cells' shapes (`CELLS`: rows looked up a step, the table's width, its
 rows) and at `PROBES`, which move one of the three at a time to say what a
 scattered row's cost follows. Ids are uniform over the table, as the cells'
-traffic draws them. Device 0's busy ms a call from a profiled run
-(`moe_permute._device_ops`), us a row, and for the rule the kernel's share
-of it; `write_ms` is the table's bytes written once at 819 GB/s.
+traffic draws them. Device 0's busy ms a call from a profiled run, us a
+row, and for the rule the kernel's share of it; `write_ms` is the table's
+bytes written once at the device's HBM peak.
 
-Prints one JSON line a row and writes `chiprun_out/embedding_grad.json`;
 PERF.md section 5 holds the table (PR 50).
 
     chiprun -- python3 benchmarks/embedding_grad.py
-    python3 benchmarks/embedding_grad.py --rehearse-cpu    (proves the script)
-"""
-import json
-import os
-import sys
+    python3 benchmarks/embedding_grad.py --rehearse-cpu
 
+The platform rule, the clock and the output file are `alone.py`'s.
+"""
+import alone
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from moe_permute import _busy_ms, _device_ops  # noqa: E402
-from mxnet_tpu.ops import indexing  # noqa: E402
+from mxnet_tpu.ops import indexing
 
 # (rows, width, vocab): Falcon-H1, Olmo-Hybrid, OLMoE, MiMo-V2-Flash,
 # Kanana-2, Nemotron-3-Nano, LFM2
@@ -71,7 +66,7 @@ def rule_grad(ids, cot, vocab):
                        vocab)
 
 
-def table(name, shape, row, reps=10):
+def table(name, shape, row, run, reps=10):
     m, width, vocab = shape
     rng = np.random.RandomState(0)
     ids = jnp.asarray(rng.randint(0, vocab, m), jnp.int32)
@@ -81,40 +76,29 @@ def table(name, shape, row, reps=10):
         if dtype == jnp.bfloat16:
             forms["segment_product"] = rule_grad
         for by, form in forms.items():
-            ops = _device_ops(jax.jit(form, static_argnums=2), ids, cot,
-                              vocab, reps=reps)
-            ms = _busy_ms(ops, reps)
-            kernel = [(text, s, d) for text, s, d in ops or ()
-                      if "gmm_wgrad" in text]
+            ops = run.device_ops(jax.jit(form, static_argnums=2), ids, cot,
+                                 vocab, reps=reps)
+            ms = alone.busy_ms(ops)
             row(shape=name, rows=m, width=width, vocab=vocab,
                 dtype=jnp.dtype(dtype).name, by=by, device_ms=ms,
-                kernel_ms=_busy_ms(kernel, reps) if kernel else None,
-                us_a_row=None if ms is None else 1e3 * ms / m,
-                write_ms=1e3 * vocab * width * cot.dtype.itemsize / 819e9)
+                kernel_ms=alone.busy_ms(
+                    [op for op in ops if "gmm_wgrad" in op[0]]),
+                us_a_row=alone.ratio(ms, m, 1e3),
+                write_ms=run.bound(
+                    nbytes=vocab * width * cot.dtype.itemsize))
 
 
 def main():
-    if "--rehearse-cpu" in sys.argv:
-        # the script end to end at a toy size; its times mean nothing
-        table("toy", (256, 128, 384),
-              lambda **kw: print(json.dumps(kw), flush=True), reps=1)
+    run = alone.Run(__file__)
+    run.row(device=run.kind, platform=run.platform)
+    if run.rehearse:
+        table("toy", (256, 128, 384), run.row, run)
         return
-    dev = jax.devices()[0]
-    res = {"device": str(dev.device_kind), "platform": dev.platform,
-           "rows": []}
-    print(json.dumps({k: v for k, v in res.items() if k != "rows"}),
-          flush=True)
-
-    def row(**kw):
-        print(json.dumps(kw), flush=True)
-        res["rows"].append(kw)
-
-    table("discarded", CELLS["lfm2"], lambda **kw: None, reps=2)
+    # a process's first executables run slower for their first calls
+    table("discarded", CELLS["lfm2"], lambda **kw: None, run, reps=2)
     for name, shape in list(CELLS.items()) + list(PROBES.items()):
-        table(name, shape, row)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/embedding_grad.json", "w") as f:
-        json.dump(res, f, indent=1)
+        table(name, shape, run.row, run)
+    run.save()
 
 
 if __name__ == "__main__":

@@ -8,45 +8,29 @@ Forward + backward a call, the tilings alternating over three rounds:
 host clock over 10 calls closed by a fetch, and the kernels' own device
 time by their names from a profiler trace of 5 calls. Required operations
 are ``bench/flops/afmoe_symbol``'s count (scores and values over the band
-or the triangle, three forwards) over the bf16 peak of 197 TFLOP/s.
-Prints one JSON line a row and writes
-``chiprun_out/flash_window_tiles.json``; PERF.md section 7 holds the
-table (PR 55).
+or the triangle, three forwards) over the device's bf16 peak. PERF.md
+section 7 holds the table (PR 55).
 
     chiprun -- python3 benchmarks/flash_window_tiles.py
+    python3 benchmarks/flash_window_tiles.py --rehearse-cpu
 
-``--rehearse-cpu`` runs the same flow at a toy size here (the pair's
-branch for other platforms, no trace): it proves the script, not a
-number.
+The platform rule, the clocks and the output file are ``alone.py``'s.
 """
 import collections
-import glob
-import json
-import os
-import sys
-import tempfile
-import time
 
+import alone
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from mxnet_tpu.ops.kernels import flash_attention, flash_tiles  # noqa: E402
-
-PEAK = 197e12
-REHEARSE = "--rehearse-cpu" in sys.argv
-T, H, G, D, WINDOW = (256, 4, 2, 16, 64) if REHEARSE else (8192, 32, 4, 128,
-                                                            2048)
-TILES = (32, 64) if REHEARSE else (256, 512, 1024)
+from mxnet_tpu.ops.kernels import flash_attention, flash_tiles
 
 
-def required_ms(window):
-    w = min(window or T, T)
-    pairs = w * (w + 1) / 2.0 + (T - w) * w
-    return 3 * 2.0 * H * pairs * 2 * D / PEAK * 1e3
+def required_flops(t, h, d, window):
+    w = min(window or t, t)
+    pairs = w * (w + 1) / 2.0 + (t - w) * w
+    return 3 * 2.0 * h * pairs * 2 * d
 
 
 def call(window, block):
@@ -57,71 +41,35 @@ def call(window, block):
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
 
 
-def host_ms(f, *args, reps=10):
-    jax.block_until_ready(f(*args))
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        r = f(*args)
-    np.asarray(r[0].ravel()[:1])  # a fetch closes the last call
-    return (time.perf_counter() - t0) / reps * 1e3
-
-
-def device_ms(f, *args, reps=5):
-    """Device ms a call of each ``flash_`` kernel and of everything else,
-    from a profiler trace of ``reps`` calls."""
-    from jax.profiler import ProfileData
-
-    where = tempfile.mkdtemp()
-    with jax.profiler.trace(where):
-        for _ in range(reps):
-            r = f(*args)
-        jax.block_until_ready(r)
-    trace, = glob.glob(where + "/plugins/profile/*/*.xplane.pb")
-    ms = collections.Counter()
-    for plane in ProfileData.from_file(trace).planes:
-        if plane.name != "/device:TPU:0":
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for e in line.events:
-                name = e.name.split(" = ")[0].lstrip("%")
-                ms[name.split(".")[0] if name.startswith("flash_")
-                   else "everything else"] += e.duration_ns / 1e6 / reps
-    return dict(ms)
-
-
 def main():
+    run = alone.Run(__file__)
+    t, h, g, d, window = ((256, 4, 2, 16, 64) if run.rehearse
+                          else (8192, 32, 4, 128, 2048))
+    tiles = (32, 64) if run.rehearse else (256, 512, 1024)
     rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(1, T, H, D), jnp.bfloat16)
-    k, v = (jnp.asarray(rng.randn(1, T, G, D), jnp.bfloat16)
+    q = jnp.asarray(rng.randn(1, t, h, d), jnp.bfloat16)
+    k, v = (jnp.asarray(rng.randn(1, t, g, d), jnp.bfloat16)
             for _ in range(2))
-    rows = [("window", WINDOW, b) for b in TILES] + [("full", 0, TILES[-1])]
+    rows = [("window", window, b) for b in tiles] + [("full", 0, tiles[-1])]
     fns = {row: call(row[1], row[2]) for row in rows}
     host = collections.defaultdict(list)
-    for _ in range(3):  # the tilings alternate
-        for row in rows:
-            host[row].append(host_ms(fns[row], q, k, v))
-    out = []
+    for row, f in run.alternate(fns):
+        host[row].append(run.host_ms(f, q, k, v, reps=10))
     for row in rows:
         kind, window, block = row
         entry = {"call": kind, "window": window, "block": block,
-                 "chosen": flash_tiles(T, D, jnp.bfloat16, window)[0] == block,
+                 "chosen": flash_tiles(t, d, jnp.bfloat16, window)[0] == block,
                  "host_ms": sorted(host[row]),
-                 "required_ms": required_ms(window)}
-        if not REHEARSE:
-            entry["device_ms"] = device_ms(fns[row], q, k, v)
-            kernels = sum(ms for name, ms in entry["device_ms"].items()
-                          if name.startswith("flash_"))
-            entry["kernels_ms"] = kernels
-            entry["roofline_share"] = 100.0 * entry["required_ms"] / kernels
-        entry["platform"] = jax.devices()[0].platform
-        print(json.dumps(entry), flush=True)
-        out.append(entry)
-    if not REHEARSE:
-        os.makedirs("chiprun_out", exist_ok=True)
-        with open("chiprun_out/flash_window_tiles.json", "w") as f:
-            json.dump(out, f, indent=1)
+                 "required_ms": run.bound(
+                     flops=required_flops(t, h, d, window))}
+        if not run.rehearse:
+            entry["device_ms"] = alone.by_kernel(
+                run.device_ops(fns[row], q, k, v), "flash_")
+            entry["kernels_ms"] = alone.named(entry["device_ms"], "flash_")
+            entry["roofline_share"] = alone.ratio(
+                entry["required_ms"], entry["kernels_ms"], 100.0)
+        run.row(platform=run.platform, **entry)
+    run.save()
 
 
 if __name__ == "__main__":

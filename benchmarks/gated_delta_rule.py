@@ -15,74 +15,30 @@ Also prints how far each form's output and gradients are, on the chip,
 from the chunk form in float32 with every product at the highest
 precision (largest difference over that one's largest magnitude), for
 bf16 and for float32 operands. `--heads-a-step 1,3,6` times the kernel
-pair at other head groups than the rule's own. Prints one JSON line a row
-and writes `chiprun_out/gated_delta_rule_table.json`; PERF.md section 7
-holds the table (PR 42).
+pair at other head groups than the rule's own. PERF.md section 7 holds the
+table (PR 42).
 
     chiprun -- python3 benchmarks/gated_delta_rule.py
+    python3 benchmarks/gated_delta_rule.py --rehearse-cpu
+
+The platform rule, the clocks and the output file are `alone.py`'s.
 """
 import argparse
-import json
-import os
-import sys
-import time
 
+import alone
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from mxnet_tpu.ops import kernels as pk  # noqa: E402
-from mxnet_tpu.ops.transformer import gated_delta_rule  # noqa: E402
+from mxnet_tpu.ops import kernels as pk
+from mxnet_tpu.ops.transformer import gated_delta_rule
 
 B, T, H, K, V, CHUNK = 1, 4096, 30, 96, 192, 64
 INPUTS = ("q", "k", "v", "a", "b")
 
 
-def _time(f, *args, reps=20):
-    jax.block_until_ready(f(*args))
-    jax.block_until_ready(f(*args))
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        r = f(*args)
-    jax.block_until_ready(r)
-    np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[:1])  # a fetch
-    return (time.perf_counter() - t0) / reps * 1e3
-
-
-def _kernel_device_ms(g, *args, reps=10):
-    """Device ms a call of each ``gdn_`` kernel and of everything else in
-    the program, from a profiler trace of ``reps`` calls."""
-    import collections
-    import glob
-    import tempfile
-
-    from jax.profiler import ProfileData
-
-    jax.block_until_ready(g(*args))
-    where = tempfile.mkdtemp()
-    with jax.profiler.trace(where):
-        for _ in range(reps):
-            r = g(*args)
-        jax.block_until_ready(r)
-    trace, = glob.glob(where + "/plugins/profile/*/*.xplane.pb")
-    ms = collections.Counter()
-    for plane in ProfileData.from_file(trace).planes:
-        if plane.name != "/device:TPU:0":
-            continue
-        for line in plane.lines:
-            if line.name != "XLA Ops":
-                continue
-            for e in line.events:
-                name = e.name.split(" = ")[0].lstrip("%")
-                ms[name.split(".")[0] if name.startswith("gdn_")
-                   else "everything else"] += e.duration_ns / 1e6 / reps
-    return dict(ms)
-
-
-def inputs(seed, dtype, t=T):
+def inputs(seed, dtype, t):
     """q, k, v as the op's convolution leaves them (unit scale, heads side
     by side in the last dimension), a and b as their projections do, decay
     rates by the published rule."""
@@ -134,21 +90,17 @@ def forms():
 
 
 def main():
+    global T, H
     ap = argparse.ArgumentParser()
     ap.add_argument("--heads-a-step", default="")
     ap.add_argument("--rounds", type=int, default=3)
+    ap.add_argument("--rehearse-cpu", action="store_true")
     args_ = ap.parse_args()
-    dev = jax.devices()[0]
-    res = {"device": str(dev.device_kind), "platform": dev.platform,
-           "shape": dict(b=B, t=T, heads=H, key_dim=K, value_dim=V,
-                         chunk=CHUNK), "rows": []}
-
-    def row(**kw):
-        print(json.dumps(kw), flush=True)
-        res["rows"].append(kw)
-
+    run = alone.Run(__file__)
+    if run.rehearse:
+        T, H = 128, 2
     both = forms()
-    for dtype, t in ((jnp.bfloat16, T), (jnp.float32, 1024)):
+    for dtype, t in ((jnp.bfloat16, T), (jnp.float32, min(T, 1024))):
         args, cot = inputs(0, dtype, t)
         outs = {name: (f(*args), g(cot, *args)[1])
                 for name, (f, g) in both.items()}
@@ -164,35 +116,36 @@ def main():
             exact = tuple(v.astype(jnp.float32) for v in args)
             o_x, g_x = f(*exact), g(cot, *exact)[1]
         for name, (o, grads) in outs.items():
-            row(check=name + "_against_float32_highest",
-                dtype=jnp.dtype(dtype).name, t=t, o=rel(o, o_x),
-                **{"d" + n: rel(k, e)
-                   for n, k, e in zip(INPUTS, grads, g_x)})
+            run.row(check=name + "_against_float32_highest",
+                    dtype=jnp.dtype(dtype).name, t=t, o=rel(o, o_x),
+                    **{"d" + n: rel(k, e)
+                       for n, k, e in zip(INPUTS, grads, g_x)})
 
-    args, cot = inputs(1, jnp.bfloat16)
-    for _ in range(args_.rounds):
-        for name in ("chunked", "kernel"):
-            f, g = both[name]
-            fwd = _time(f, *args)
-            row(form=name, fwd_ms=fwd, fwd_bwd_ms=_time(g, cot, *args))
-    row(kernels_device_ms=_kernel_device_ms(both["kernel"][1], cot, *args),
-        heads_a_step=pk.gdn.gdn_group(H), steps=B * H // pk.gdn.gdn_group(H)
-        * (T // CHUNK))
-    def retimed(**label):
+    args, cot = inputs(1, jnp.bfloat16, T)
+    for name, (f, g) in run.alternate(both, rounds=args_.rounds):
+        run.row(form=name, fwd_ms=run.host_ms(f, *args),
+                fwd_bwd_ms=run.host_ms(g, cot, *args))
+
+    def kernels_ms(g):
+        return alone.by_kernel(run.device_ops(g, cot, *args, reps=10),
+                               "gdn_")
+
+    run.row(kernels_device_ms=kernels_ms(both["kernel"][1]),
+            heads_a_step=pk.gdn.gdn_group(H),
+            steps=B * H // pk.gdn.gdn_group(H) * (T // CHUNK))
+    for per in [int(p) for p in args_.heads_a_step.split(",") if p]:
+        own, pk.gdn.GDN_HEADS_A_STEP = pk.gdn.GDN_HEADS_A_STEP, per
         for f in (pk.gdn.gdn_fwd_call, pk.gdn.gdn_bwd_call,
                   pk.gdn.gdn_forward):
             f.clear_cache()
         f, g = forms()["kernel"]
-        row(fwd_ms=_time(f, *args), fwd_bwd_ms=_time(g, cot, *args),
-            kernels_device_ms=_kernel_device_ms(g, cot, *args), **label)
-
-    for per in [int(p) for p in args_.heads_a_step.split(",") if p]:
-        own, pk.gdn.GDN_HEADS_A_STEP = pk.gdn.GDN_HEADS_A_STEP, per
-        retimed(heads_a_step=pk.gdn.gdn_group(H))
+        run.row(fwd_ms=run.host_ms(f, *args),
+                fwd_bwd_ms=run.host_ms(g, cot, *args),
+                kernels_device_ms=kernels_ms(g),
+                heads_a_step=pk.gdn.gdn_group(H))
         pk.gdn.GDN_HEADS_A_STEP = own
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/gated_delta_rule_table.json", "w") as f:
-        json.dump(res, f, indent=1)
+    run.save(shape=dict(b=B, t=T, heads=H, key_dim=K, value_dim=V,
+                        chunk=CHUNK))
 
 
 if __name__ == "__main__":

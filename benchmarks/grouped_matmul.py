@@ -8,24 +8,22 @@ group sizes (layers 1 and 0 of a chip run of `olmoe_fit_resident_4k`, seed
 32,768 rows over 64 groups. Host clock over 20 calls closed by a fetch.
 
 The group metadata is a run-time input of the kernels, so one compile a
-tile serves every load. Prints one JSON line a row and writes
-`chiprun_out/gmm_table.json`; PERF.md section 7 holds the table (PR 30).
+tile serves every load. PERF.md section 7 holds the table (PR 30).
 
     chiprun -- python3 benchmarks/grouped_matmul.py [--quick]
-"""
-import json
-import os
-import sys
-import time
+    python3 benchmarks/grouped_matmul.py --rehearse-cpu
 
+The platform rule, the clock and the output file are `alone.py`'s.
+"""
+import sys
+
+import alone
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from mxnet_tpu.ops import kernels as pk  # noqa: E402
+from mxnet_tpu.ops import kernels as pk
 
 M, GROUPS = 32768, 64
 CELL_LAYER1 = [
@@ -48,17 +46,6 @@ LOADS = {
 }
 
 
-def _time(f, *args, reps=20):
-    jax.block_until_ready(f(*args))
-    jax.block_until_ready(f(*args))
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        r = f(*args)
-    jax.block_until_ready(r)
-    np.asarray(jax.tree_util.tree_leaves(r)[0][:1])  # closed by a fetch
-    return (time.perf_counter() - t0) / reps * 1e3
-
-
 def ragged(mode):
     dot = jax.lax.ragged_dot
     if mode == "fwd":
@@ -70,18 +57,18 @@ def ragged(mode):
         lambda r_: dot(l, r_, s), r)[1](d)[0])
 
 
-def kernel(mode, tiles):
+def kernel(mode, tiles, interpret):
     tm = tiles[0]
 
     def f(l, r, d, s):
-        meta = pk.gmm_metadata(s, M, tm)
+        meta = pk.gmm_metadata(s, l.shape[0], tm)
         if mode == "wgrad":
             return pk.gmm.gmm_wgrad_call(
-                meta[0], *meta[4:], l, d, groups=GROUPS, tiles=tiles,
-                interpret=False)
+                meta[0], *meta[4:], l, d, groups=r.shape[0], tiles=tiles,
+                interpret=interpret)
         return pk.gmm.gmm_call(
             *meta[:4], d if mode == "dgrad" else l, r, tiles=tiles,
-            transposed=mode == "dgrad", interpret=False)
+            transposed=mode == "dgrad", interpret=interpret)
     return jax.jit(f)
 
 
@@ -103,48 +90,46 @@ def tile_table(k, n, quick):
 
 
 def main():
+    run = alone.Run(__file__)
     quick = "--quick" in sys.argv
-    dev = jax.devices()[0]
-    res = {"device": str(dev.device_kind), "platform": dev.platform,
-           "rows": []}
-
-    def row(**kw):
-        print(json.dumps(kw), flush=True)
-        res["rows"].append(kw)
-
+    m, groups, shapes, loads = M, GROUPS, ((2048, 2048), (1024, 2048)), LOADS
+    if run.rehearse:
+        m, groups, shapes = 512, 4, ((256, 256),)
+        loads = {"skewed": [300, 0, 200, 12], "uniform128": [128] * 4}
     rng = np.random.RandomState(1)
-    for k, n in ((2048, 2048), (1024, 2048)):
-        lhs = jnp.asarray(rng.randn(M, k), jnp.bfloat16)
-        rhs = jnp.asarray(rng.randn(GROUPS, k, n) * 0.02, jnp.bfloat16)
-        dout = jnp.asarray(rng.randn(M, n), jnp.bfloat16)
-        sizes = {name: jnp.asarray(v, jnp.int32) for name, v in LOADS.items()}
-        gflop = 2.0 * M * k * n / 1e9
+    for k, n in shapes:
+        lhs = jnp.asarray(rng.randn(m, k), jnp.bfloat16)
+        rhs = jnp.asarray(rng.randn(groups, k, n) * 0.02, jnp.bfloat16)
+        dout = jnp.asarray(rng.randn(m, n), jnp.bfloat16)
+        sizes = {name: jnp.asarray(v, jnp.int32) for name, v in loads.items()}
+        gflop = 2.0 * m * k * n / 1e9
+        table = (dict.fromkeys(("fwd", "dgrad", "wgrad"), [(128, 128, 128)])
+                 if run.rehearse else tile_table(k, n, quick))
         for mode in ("fwd", "dgrad", "wgrad"):
             f = ragged(mode)
             want = {name: f(lhs, rhs, dout, s) for name, s in sizes.items()}
-            row(k=k, n=n, mode=mode, kernel="ragged_dot", gflop=gflop,
-                ms={name: _time(f, lhs, rhs, dout, s)
-                    for name, s in sizes.items()})
-            for tiles in tile_table(k, n, quick)[mode]:
+            run.row(k=k, n=n, mode=mode, kernel="ragged_dot", gflop=gflop,
+                    ms={name: run.host_ms(f, lhs, rhs, dout, s)
+                        for name, s in sizes.items()})
+            for tiles in table[mode]:
                 try:
-                    f = kernel(mode, tiles)
+                    f = kernel(mode, tiles, run.rehearse)
                     err = {}
                     for name, s in sizes.items():
                         got = f(lhs, rhs, dout, s).astype(jnp.float32)
                         ref = want[name].astype(jnp.float32)
                         err[name] = float(jnp.abs(got - ref).max()
                                           / jnp.abs(ref).max())
-                    row(k=k, n=n, mode=mode, kernel="pallas", tiles=tiles,
-                        ms={name: _time(f, lhs, rhs, dout, s)
-                            for name, s in sizes.items()},
-                        max_rel_diff_to_ragged=err)
+                    run.row(k=k, n=n, mode=mode, kernel="pallas", tiles=tiles,
+                            ms={name: run.host_ms(f, lhs, rhs, dout, s)
+                                for name, s in sizes.items()},
+                            max_rel_diff_to_ragged=err)
                 except Exception as e:  # noqa: BLE001 — a refused tile
-                    row(k=k, n=n, mode=mode, kernel="pallas", tiles=tiles,
-                        error=str(e)[:300])
+                    run.row(k=k, n=n, mode=mode, kernel="pallas", tiles=tiles,
+                            error=str(e)[:300])
             del want
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/gmm_table.json", "w") as f:
-        json.dump(res, f, indent=1)
+    run.save()
 
 
-main()
+if __name__ == "__main__":
+    main()

@@ -7,8 +7,7 @@ rotation over the whole query, the rotary key broadcast and concatenated,
 (`_latent_kernel_path`: `kernels.latent_flash`, two key operands on
 the arrays the neighbouring matmuls leave), the two alternating.
 
-Three tables, one JSON line a row, all written to
-`chiprun_out/latent_flash_table.json` (PERF.md section 7 holds them):
+Three tables, one JSON line a row (PERF.md section 7 holds them):
 
 - `block`: `q_proj`, `kv_a_proj`, `LatentAttention`, `o_proj` of one layer
   as one jitted program, so that the op's operands are what a matmul
@@ -28,74 +27,40 @@ Three tables, one JSON line a row, all written to
   from the composition's, on the chip, as a share of the largest magnitude.
 
     chiprun -- python3 benchmarks/latent_flash.py
+    python3 benchmarks/latent_flash.py --rehearse-cpu
 
-`--rehearse-cpu` runs the same flow at a toy size here (the kernel path's
-branch for other platforms, no trace): it proves the script, not a number.
+The platform rule, the clocks and the output file are `alone.py`'s.
 """
 import collections
-import glob
-import json
-import os
 import re
-import sys
-import tempfile
-import time
 
+import alone
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path[:0] = [_ROOT, os.path.join(_ROOT, "bench")]
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-import reduce_scopes  # noqa: E402  (bench/: the trace's scopes)
-import reduce_trace  # noqa: E402
-from mxnet_tpu.ops import kernels as pk  # noqa: E402
-from mxnet_tpu.ops import transformer as tr  # noqa: E402
+from mxnet_tpu.ops import kernels as pk
+from mxnet_tpu.ops import transformer as tr
 
 B, T, HIDDEN, H, N, R, DV, L = 1, 8192, 2048, 32, 128, 64, 128, 512
 THETA, EPS = 1e6, 1e-6
-PEAK_TFLOPS = 197.0     # bf16, one v5e chip (Google Cloud documentation)
-
-
-def _time(f, *args, reps=20):
-    jax.block_until_ready(f(*args))
-    jax.block_until_ready(f(*args))
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        r = f(*args)
-    jax.block_until_ready(r)
-    np.asarray(jax.tree_util.tree_leaves(r)[0].ravel()[:1])  # a fetch
-    return (time.perf_counter() - t0) / reps * 1e3
 
 
 _PART = re.compile(r"[/(](latent|full|q_proj|kv_a_proj|o_proj)[/)]")
 
 
-def _device_ms(g, *args, reps=5):
+def _device_ms(run, g, *args):
     """Device ms of one call of ``g`` by scope (an op's first of latent,
     full and the three projections, forward or under ``transpose(``) and
-    op by op, longest first, from a profiler trace of ``reps`` calls."""
-    jax.block_until_ready(g(*args))
-    where = tempfile.mkdtemp()
-    with jax.profiler.trace(where):
-        for _ in range(reps):
-            r = g(*args)
-        jax.block_until_ready(r)
-    trace, = glob.glob(where + "/plugins/profile/*/*.xplane.pb")
-    ops = reduce_trace.load(trace)["devices"][0]["ops"]
-    names = reduce_scopes.scope_names(trace).get(0, {})
+    op by op, longest first, from a profiler trace of 5 calls."""
     by_scope, by_op = collections.Counter(), collections.Counter()
-    for text, own in reduce_scopes.self_times(
-            [(n, s, s + d) for n, s, d in ops]):
-        scope = names.get(text) or ""
+    for text, ms, scope in run.device_ops(g, *args, scopes=True):
         m = _PART.search(scope)
         part = "%s_%s" % (m.group(1) if m else "other",
                           "bwd" if "transpose(" in scope else "fwd")
-        by_scope[part] += own / 1e6 / reps
-        by_op[(text.split(" = ")[0].lstrip("%"), part)] += (
-            own / 1e6 / reps)
+        by_scope[part] += ms
+        by_op[(alone.op_name(text), part)] += ms
     return ({k: round(v, 4) for k, v in sorted(by_scope.items())},
             [(name, part, round(ms, 4))
              for (name, part), ms in by_op.most_common(40)])
@@ -197,18 +162,9 @@ def kernel_inputs(seed):
 
 def main():
     global T, HIDDEN, H, L
-    rehearse = "--rehearse-cpu" in sys.argv
-    if rehearse:
+    run = alone.Run(__file__)
+    if run.rehearse:
         T, HIDDEN, H, L = 256, 64, 2, 32
-    dev = jax.devices()[0]
-    res = {"device": str(dev.device_kind), "platform": dev.platform,
-           "shape": dict(b=B, t=T, hidden=HIDDEN, heads=H, nope=N, rope=R,
-                         dv=DV, latent=L), "rows": []}
-
-    def row(**kw):
-        print(json.dumps(kw), flush=True)
-        res["rows"].append(kw)
-
     p = params(0)
     args = tuple(p[k] for k in ("x", "wq", "wa", "gamma", "wup", "wo"))
     blocks = {form: block(form) for form in ("composed", "kernel")}
@@ -219,42 +175,40 @@ def main():
 
     outs = {form: (f(*args), g(*args, p["cot"]))
             for form, (f, g) in blocks.items()}
-    row(table="check", what="kernel_against_composed",
-        y=rel(outs["kernel"][0], outs["composed"][0]),
-        **{"d" + n: rel(k, c) for n, k, c in zip(
-            ("x", "wq", "wa", "gamma", "wup", "wo"), outs["kernel"][1],
-            outs["composed"][1])})
+    run.row(table="check", what="kernel_against_composed",
+            y=rel(outs["kernel"][0], outs["composed"][0]),
+            **{"d" + n: rel(k, c) for n, k, c in zip(
+                ("x", "wq", "wa", "gamma", "wup", "wo"), outs["kernel"][1],
+                outs["composed"][1])})
     del outs
 
-    for _ in range(3):
-        for form, (f, g) in blocks.items():
-            fwd, fwd_bwd = _time(f, *args), _time(g, *args, p["cot"])
-            row(table="block", form=form, fwd_ms=fwd, fwd_bwd_ms=fwd_bwd,
+    for form, (f, g) in run.alternate(blocks):
+        fwd = run.host_ms(f, *args)
+        fwd_bwd = run.host_ms(g, *args, p["cot"])
+        run.row(table="block", form=form, fwd_ms=fwd, fwd_bwd_ms=fwd_bwd,
                 bwd_ms=fwd_bwd - fwd)
-    for form, (_, g) in () if rehearse else blocks.items():
-        by_scope, top = _device_ms(g, *args, p["cot"])
-        row(table="block", form=form, device_ms_by_scope=by_scope)
-        row(table="block", form=form, ops=top)
+    for form, (_, g) in blocks.items():
+        by_scope, top = _device_ms(run, g, *args, p["cot"])
+        run.row(table="block", form=form, device_ms_by_scope=by_scope)
+        run.row(table="block", form=form, ops=top)
 
     # causal scores and values, forward (2 products) and backward (5)
     pairs = B * H * T * (T + 1) / 2
     fwd_flop, bwd_flop = (2 * pairs * ((N + R) * n + DV * m)
                           for n, m in ((1, 1), (3, 2)))
     calls, ins = kernel_calls(), kernel_inputs(1)
-    for _ in range(3):
-        for form, (f, g) in calls.items():
-            fwd, fwd_bwd = _time(f, *ins[form]), _time(g, *ins[form])
-            row(table="kernels", form=form, fwd_ms=fwd, fwd_bwd_ms=fwd_bwd,
-                bwd_ms=fwd_bwd - fwd,
-                fwd_mxu_ms=fwd_flop / PEAK_TFLOPS / 1e9,
-                bwd_mxu_ms=bwd_flop / PEAK_TFLOPS / 1e9)
-    for form, (_, g) in () if rehearse else calls.items():
-        by_scope, top = _device_ms(g, *ins[form])
-        row(table="kernels", form=form, device_ms=sum(by_scope.values()),
-            ops=top[:6])
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/latent_flash_table.json", "w") as f:
-        json.dump(res, f, indent=1)
+    for form, (f, g) in run.alternate(calls):
+        fwd = run.host_ms(f, *ins[form])
+        fwd_bwd = run.host_ms(g, *ins[form])
+        run.row(table="kernels", form=form, fwd_ms=fwd, fwd_bwd_ms=fwd_bwd,
+                bwd_ms=fwd_bwd - fwd, fwd_mxu_ms=run.bound(flops=fwd_flop),
+                bwd_mxu_ms=run.bound(flops=bwd_flop))
+    for form, (_, g) in calls.items():
+        by_scope, top = _device_ms(run, g, *ins[form])
+        run.row(table="kernels", form=form,
+                device_ms=sum(by_scope.values()), ops=top[:6])
+    run.save(shape=dict(b=B, t=T, hidden=HIDDEN, heads=H, nope=N, rope=R,
+                        dv=DV, latent=L))
 
 
 if __name__ == "__main__":

@@ -12,7 +12,7 @@ the two sorts and the row count (`bincount` against the one-hot sum).
 made beforehand). Host clock over 20 calls closed by a fetch, after one
 discarded pass over the table (a process's first executables ran 50
 times slower for their first calls); `bound_ms` is one move's bytes
-(read and write 134 MB) at the chip's 819 GB/s.
+(read and write 134 MB) at the device's HBM peak.
 
 **A share's moves** (PR 47), at the three share cells' shapes (`SHARES`:
 Kanana 8,192 x 2,048, top-6, 16 of 128 experts held, a buffer of 12,288
@@ -29,27 +29,24 @@ the buffer and `ops.kernels.sorted_segment_sum`), with `_share_weights`
 is applied inside the experts there. `plan` is the index arithmetic
 (compaction, sorts) of the tree's formulation and of this one;
 `bound_ms` of a share row is one move's bytes (the buffer read and the
-tokens written, or the reverse) at 819 GB/s.
+tokens written, or the reverse) at the same peak.
 
-Prints one JSON line a row and writes `chiprun_out/moe_permute.json`;
 PERF.md section 7 holds the tables (PR 32, PR 47).
 
     chiprun -- python3 benchmarks/moe_permute.py [--shares-only]
-    python3 benchmarks/moe_permute.py --rehearse-cpu    (proves the script)
-"""
-import json
-import os
-import sys
-import time
+    python3 benchmarks/moe_permute.py --rehearse-cpu [--shares-only]
 
+The platform rule, the clocks and the output file are `alone.py`'s.
+"""
+import sys
+
+import alone
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from mxnet_tpu.parallel import moe  # noqa: E402
+from mxnet_tpu.parallel import moe
 
 TOKENS, WIDTH, EXPERTS, TOP_K = 4096, 2048, 64, 8
 CELL_LAYER1 = [
@@ -67,60 +64,6 @@ def routing(load, seed):
     rng = np.random.RandomState(seed)
     score = np.log(np.asarray(load)) + rng.gumbel(size=(TOKENS, EXPERTS))
     return np.argsort(-score, axis=1)[:, :TOP_K].astype(np.int32)
-
-
-def _time(f, *args, reps=20):
-    jax.block_until_ready(f(*args))
-    jax.block_until_ready(f(*args))
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        r = f(*args)
-    jax.block_until_ready(r)
-    np.asarray(jax.tree_util.tree_leaves(r)[0][:1])  # closed by a fetch
-    return (time.perf_counter() - t0) / reps * 1e3
-
-
-def _device_ops(f, *args, reps=10):
-    """Device 0's ops over a profiled run of ``reps`` calls, as
-    ``bench/reduce_trace.py`` reads them ((name, start, duration) in ns;
-    the benchmark's readers read a slice the same way); None off the
-    TPU."""
-    import glob
-    import tempfile
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench")
-    if bench not in sys.path:
-        sys.path.insert(0, bench)
-    import reduce_trace
-
-    jax.block_until_ready(f(*args))
-    with tempfile.TemporaryDirectory() as where:
-        with jax.profiler.trace(where):
-            for _ in range(reps):
-                r = f(*args)
-            jax.block_until_ready(r)
-        found = glob.glob(os.path.join(
-            where, "plugins", "profile", "*", "*.xplane.pb"))
-        devices = reduce_trace.load(found[0])["devices"] if found else {}
-    return devices[0]["ops"] if 0 in devices else None
-
-
-def _busy_ms(ops, reps):
-    """The union of ``ops`` (``_device_ops``'s) a call, ms."""
-    import reduce_trace
-
-    if ops is None:
-        return None
-    return reduce_trace.total(reduce_trace.union(
-        [(s, s + d) for _, s, d in ops])) / reps / 1e6
-
-
-def _device_ms(f, *args, reps=10):
-    """Device 0's busy time a call, ms. The host clock of ``_time`` has a
-    floor of about 0.3 ms a call on the chip's host, above most of a
-    share's moves; None off the TPU."""
-    return _busy_ms(_device_ops(f, *args, reps=reps), reps)
 
 
 def sort_pair(experts):
@@ -156,21 +99,22 @@ def argsort_pair(experts):
     return order, jnp.argsort(order)
 
 
-def table(name, load, row):
+def table(name, load, row, run):
     rng = np.random.RandomState(1)
     x = jnp.asarray(rng.randn(TOKENS, WIDTH), jnp.bfloat16)
     rows = jnp.asarray(rng.randn(TOKENS * TOP_K, WIDTH), jnp.bfloat16)
     weights = jnp.asarray(rng.rand(TOKENS, TOP_K), jnp.float32)
     experts = jnp.asarray(routing(load, 0))
     flat = experts.reshape(-1)
+    time = run.host_ms
     rest = jax.jit(sort_pair)(experts)
     row(routing=name, what="sorts", by="argsort",
-        ms=_time(jax.jit(argsort_pair), experts))
+        ms=time(jax.jit(argsort_pair), experts))
     row(routing=name, what="sorts", by="lax_int32",
-        ms=_time(jax.jit(sort_pair), experts))
-    row(routing=name, what="counts", by="bincount", ms=_time(jax.jit(
+        ms=time(jax.jit(sort_pair), experts))
+    row(routing=name, what="counts", by="bincount", ms=time(jax.jit(
         lambda e: jnp.bincount(e, length=EXPERTS)), flat))
-    row(routing=name, what="counts", by="one_hot", ms=_time(jax.jit(
+    row(routing=name, what="counts", by="one_hot", ms=time(jax.jit(
         lambda e: jnp.sum(jax.nn.one_hot(e, EXPERTS, dtype=jnp.int32),
                           axis=0, dtype=jnp.int32)), flat))
     for what, by, f, diff, cotangent in (
@@ -179,8 +123,8 @@ def table(name, load, row):
             ("combine", "take", take_combine, (rows, weights), x),
             ("combine", "pair", moe._combine, (rows, weights), x)):
         row(routing=name, what=what, by=by,
-            fwd_ms=_time(jax.jit(f), *diff, *rest),
-            bwd_ms=_time(*backward(f, diff, rest), cotangent))
+            fwd_ms=time(jax.jit(f), *diff, *rest),
+            bwd_ms=time(*backward(f, diff, rest), cotangent))
 
 
 SHARES = {  # tokens, width, experts, held, top_k, bound
@@ -212,7 +156,7 @@ def segment_plan(experts, held, bound):
     return moe._share_plan(experts, offset=0, held=held, bound=bound)
 
 
-def share_table(name, shape, row, reps=20):
+def share_table(name, shape, row, run, reps=20):
     tokens, width, experts_n, held, top_k, bound = shape
     rng = np.random.RandomState(1)
     x = jnp.asarray(rng.randn(tokens, width), jnp.bfloat16)
@@ -222,6 +166,11 @@ def share_table(name, shape, row, reps=20):
         rng.rand(tokens, experts_n), axis=1)[:, :top_k].astype(np.int32))
     move_bytes = (bound + tokens) * width * 2
 
+    def device_ms(f, *args, reps):
+        """Device 0's busy ms a call: the host clock has a floor of about
+        0.3 ms a call on the chip's host, above most of a share's moves."""
+        return alone.busy_ms(run.device_ops(f, *args, reps=reps))
+
     def put(what, by, fwd, bwd):
         """``fwd`` / ``bwd``: (jitted function, arguments) or None."""
         def ms(call, clock):
@@ -229,9 +178,10 @@ def share_table(name, shape, row, reps=20):
                 call[0], *call[1], reps=reps)
 
         row(share=name, what=what, by=by,
-            fwd_device_ms=ms(fwd, _device_ms), bwd_device_ms=ms(
-                bwd, _device_ms), fwd_ms=ms(fwd, _time),
-            bwd_ms=ms(bwd, _time), bound_ms=1e3 * move_bytes / 819e9)
+            fwd_device_ms=ms(fwd, device_ms), bwd_device_ms=ms(
+                bwd, device_ms), fwd_ms=ms(fwd, run.host_ms),
+            bwd_ms=ms(bwd, run.host_ms),
+            bound_ms=run.bound(nbytes=move_bytes))
 
     for by, plan in (("take", take_plan), ("segment_product", segment_plan)):
         put("plan", by, (jax.jit(
@@ -321,33 +271,32 @@ def share_table(name, shape, row, reps=20):
 
 
 def main():
-    if "--rehearse-cpu" in sys.argv:
-        # the script end to end at a toy size; its times mean nothing
-        share_table("toy", (512, 128, 16, 4, 4, 640),
-                    lambda **kw: print(json.dumps(kw), flush=True), reps=1)
-        return
-    dev = jax.devices()[0]
-    move_bytes = 2 * TOKENS * TOP_K * WIDTH * 2
-    res = {"device": str(dev.device_kind), "platform": dev.platform,
-           "bound_ms": 1e3 * move_bytes / 819e9, "rows": []}
-    print(json.dumps({k: v for k, v in res.items() if k != "rows"}),
-          flush=True)
+    global TOKENS, WIDTH, EXPERTS, TOP_K
+    run = alone.Run(__file__)
+    shares, routings = SHARES, ROUTINGS
+    if run.rehearse:
+        TOKENS, WIDTH, EXPERTS, TOP_K = 256, 128, 8, 2
+        shares = {"toy": (512, 128, 16, 4, 4, 640)}
+        routings = {"toy_skewed": [c + 0.5 for c in CELL_LAYER1[:EXPERTS]]}
+    bound_ms = run.bound(nbytes=2 * TOKENS * TOP_K * WIDTH * 2)
+    run.row(device=run.kind, platform=run.platform, bound_ms=bound_ms)
 
-    def row(**kw):
-        print(json.dumps(kw), flush=True)
-        res["rows"].append(kw)
+    def discard(**kw):
+        pass
 
-    for name, shape in SHARES.items():
-        share_table(name, shape, lambda **kw: None, reps=2)   # discarded
-    for name, shape in SHARES.items():
-        share_table(name, shape, row)
+    # a process's first executables ran 50 times slower for their first
+    # calls: one pass over each table is made and thrown away
+    if not run.rehearse:
+        for name, shape in shares.items():
+            share_table(name, shape, discard, run, reps=2)
+    for name, shape in shares.items():
+        share_table(name, shape, run.row, run)
     if "--shares-only" not in sys.argv:
-        table("discarded", ROUTINGS["multinomial"], lambda **kw: None)
-        for name, load in ROUTINGS.items():
-            table(name, load, row)
-    os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/moe_permute.json", "w") as f:
-        json.dump(res, f, indent=1)
+        if not run.rehearse:
+            table("discarded", routings["multinomial"], discard, run)
+        for name, load in routings.items():
+            table(name, load, run.row, run)
+    run.save(bound_ms=bound_ms)
 
 
 if __name__ == "__main__":

@@ -185,7 +185,7 @@ def _mirror_policy(prim, *_args, **_params):
     safe here: masks come from deterministic per-node fold_in keys.)
 
     MXNET_MIRROR_SAVE tunes the saved set (comma-separated primitive
-    names) — the knob benchmarks/mirror_inception.py sweeps to trade
+    names; tests/test_mirror.py pins the default's remat) to trade
     recompute time against activation memory, e.g. adding
     reduce_window_max,reduce_window_sum (pooling) or concatenate
     (the reference's Concat) cuts the recompute chains at extra pins.

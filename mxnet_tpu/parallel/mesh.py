@@ -13,7 +13,6 @@ key sharding. Mesh axes follow the scaling-book convention:
 """
 from __future__ import annotations
 
-import logging
 import os
 import time as _time_mod
 
@@ -30,31 +29,6 @@ _H_COLLECTIVE_SECONDS = _tm.histogram(
     "parallel.collective_seconds",
     "Host-observed latency of explicit cross-process collectives "
     "(labelled by op: barrier / allreduce_sum / broadcast)")
-
-_INJECT_WARNED = False
-
-
-def _injected_latency_ms():
-    """MXNET_KVSTORE_INJECT_LATENCY_MS (bench/test knob), parsed to a
-    float or 0. Warns ONCE per process when active: a forgotten export
-    injects sleep into EVERY cross-process allreduce and is
-    indistinguishable from a slow interconnect in the telemetry
-    (ADVICE r5)."""
-    global _INJECT_WARNED
-    raw = os.environ.get("MXNET_KVSTORE_INJECT_LATENCY_MS")
-    if not raw:
-        return 0.0
-    try:
-        ms = float(raw)
-    except ValueError:
-        return 0.0
-    if ms > 0.0 and not _INJECT_WARNED:
-        _INJECT_WARNED = True
-        logging.getLogger(__name__).warning(
-            "MXNET_KVSTORE_INJECT_LATENCY_MS=%s: injecting %.1f ms of "
-            "artificial latency into every cross-process allreduce "
-            "(bench/test knob — unset it for real runs)", raw, ms)
-    return ms
 
 
 def device_count():
@@ -191,13 +165,10 @@ _ALL_GATHER_CACHE = {}
 
 
 def _collective_preamble():
-    """Shared guard for explicit host collectives: injected-latency
-    bench knob + fault-injection hook. Collectives are never retried
-    (peers issue them in lockstep), so delay is the only injectable
-    fault — see allreduce_sum for the full rationale."""
-    inj_ms = _injected_latency_ms()  # warns once when the knob is live
-    if inj_ms:
-        _time_mod.sleep(inj_ms / 1000.0)
+    """Shared guard for explicit host collectives: the fault-injection
+    hook. Collectives are never retried (peers issue them in lockstep),
+    so delay is the only injectable fault — see allreduce_sum for the
+    full rationale."""
     if _fault is not None and _fault.configured():
         _fault.fire("collective")
 
@@ -222,12 +193,6 @@ def allreduce_sum(value):
     value = np.asarray(value)
     if jax.process_count() <= 1:
         return value
-    # Bench/test knob: model a high-RTT interconnect by sleeping before
-    # the collective (benchmarks/dist_overlap_worker.py uses it to show
-    # what the comm engine's overlap buys when the network, not the CPU,
-    # is the bottleneck — on the 1-core CI box localhost gloo has ~zero
-    # latency, so without this the collective chain can never be hidden).
-    # The sleep releases the GIL like a real network wait would.
     # MXTPU_FAULT_INJECT delay_collective_ms: the slow/hung-peer class
     # the watchdog's progress staleness signal must catch. Collectives
     # are never retried (peers issue them in lockstep; re-entering one a

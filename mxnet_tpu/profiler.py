@@ -176,8 +176,8 @@ def attribute_trace(trace_dir, hlo_text, top=30):
     start_jax_trace. hlo_text: ``jit(f).lower(...).compile().as_text()``
     of the program that ran inside the trace. Returns rows
     ``{"ms", "op", "source"}`` sorted by total device time, descending —
-    the view that located the 25%-of-step BatchNorm cost this framework's
-    ResNet bench shed (see benchmarks/profile_step.py for the workflow).
+    the view that located the 25%-of-step BatchNorm cost the ResNet step
+    shed (tests/test_profiler.py::test_attribute_trace_end_to_end pins it).
 
     Device lanes are preferred (pid named '/device:...'); if none exist
     (cpu backend) any trace event whose name appears in the HLO is

@@ -9,8 +9,8 @@ the executor's compute dtype (bf16 on TPU). Biases, norms, and
 
 This is a weight-memory/bandwidth optimization (4x smaller resident
 weights on the host side, bf16-equivalent numerics on device); the
-parity gate lives in benchmarks/serving_bench.py — top-1 agreement
-vs the unquantized model must be ≥ 99% on the bench model.
+parity gate is tests/test_serving.py::test_int8_quant_parity — top-1
+agreement with the unquantized model must be ≥ 99% on its model.
 """
 from __future__ import annotations
 

@@ -1,6 +1,6 @@
 """Device cost model: FLOPs/bytes per compiled program + roofline math.
 
-Two independent sources of truth, cross-checked in benchmark_score.py:
+Two independent sources of truth (tests/test_anatomy.py holds each):
 
 - :func:`extract_cost` reads XLA's own accounting
   (``compiled.cost_analysis()``) — exact for whatever XLA actually

@@ -392,35 +392,3 @@ def test_dispatch_fastpath_counters(monkeypatch):
     finally:
         telemetry.reset()
         telemetry.disable()
-
-
-# ---------------------------------------------------------------------
-# satellite: inject-latency warning
-# ---------------------------------------------------------------------
-def test_inject_latency_warns_once(monkeypatch, caplog):
-    from mxnet_tpu.parallel import mesh
-
-    monkeypatch.setenv("MXNET_KVSTORE_INJECT_LATENCY_MS", "5")
-    monkeypatch.setattr(mesh, "_INJECT_WARNED", False)
-    with caplog.at_level(logging.WARNING,
-                         logger="mxnet_tpu.parallel.mesh"):
-        assert mesh._injected_latency_ms() == 5.0
-        assert mesh._injected_latency_ms() == 5.0  # second call silent
-    warns = [r for r in caplog.records
-             if "MXNET_KVSTORE_INJECT_LATENCY_MS" in r.getMessage()]
-    assert len(warns) == 1
-
-
-def test_inject_latency_off_or_garbage_is_silent(monkeypatch, caplog):
-    from mxnet_tpu.parallel import mesh
-
-    monkeypatch.setattr(mesh, "_INJECT_WARNED", False)
-    with caplog.at_level(logging.WARNING,
-                         logger="mxnet_tpu.parallel.mesh"):
-        monkeypatch.delenv("MXNET_KVSTORE_INJECT_LATENCY_MS",
-                           raising=False)
-        assert mesh._injected_latency_ms() == 0.0
-        monkeypatch.setenv("MXNET_KVSTORE_INJECT_LATENCY_MS", "nope")
-        assert mesh._injected_latency_ms() == 0.0
-    assert not [r for r in caplog.records
-                if "INJECT_LATENCY" in r.getMessage()]

@@ -10,9 +10,9 @@ What is pinned here (CPU): the flag actually wires a remat into the
 traced computation (falsifiable: remove the wiring and the jaxpr has no
 remat equation), gradients are bit-compatible with the non-mirrored
 path, and the fused ShardedTrainStep honors the same flag. The MEMORY
-effect is measured on real TPU hardware by benchmarks/mirror_inception.py
-(XLA's CPU pipeline largely undoes rematerialization, so a CPU memory
-assertion would pin XLA internals, not our behavior).
+effect on the chip is not measured (PERF.md); XLA's CPU pipeline largely
+undoes rematerialization, so a CPU memory assertion would pin XLA
+internals, not our behavior.
 """
 import os
 

@@ -4,7 +4,7 @@ Parity: reference python/mxnet/profiler.py (MXSetProfilerConfig/State,
 chrome trace-event dump). The attribution half is TPU-native surface:
 jax.profiler device traces joined back to framework source lines via
 optimized-HLO metadata — the workflow that located the 25%-of-step
-BatchNorm cost in the ResNet bench (benchmarks/profile_step.py).
+BatchNorm cost in the ResNet step.
 """
 import json
 import os

@@ -421,19 +421,3 @@ def test_serve_self_test_subprocess():
         cwd=REPO)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "serve self-test PASSED" in r.stdout
-
-
-@pytest.mark.slow
-@pytest.mark.timeout(300)
-def test_serving_bench_smoke_subprocess():
-    env = _serve_env()
-    env["SERVE_SMOKE"] = "1"
-    r = subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "benchmarks", "serving_bench.py")],
-        capture_output=True, text=True, timeout=280, env=env, cwd=REPO)
-    assert r.returncode == 0, r.stdout + r.stderr
-    out = json.loads(r.stdout)
-    assert out["steady_state_recompiles"] == 0
-    assert out["closed_loop"]["speedup"] >= 3.0
-    assert "latency_p99_ms" in out["open_loop"]

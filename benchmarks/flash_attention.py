@@ -10,9 +10,11 @@ the type it is given: the float32 inputs here stay float32 operands, so
 this script times the float32 caller; the bf16 numbers at the OLMoE
 cell's shape are in PERF.md (PR 28). Measured on the v5e (chip run,
 PR 28): T=2048 flash 0.32 ms vs dense 29.25 ms; T=8192 1.72 ms; T=16384
-5.5 ms. Under "cells" it times the kernels alone at the three LM cells'
+5.5 ms. Under "cells" it times the kernels alone at the LM cells'
 attention calls in bf16: forward, the split backward and the one-pass
-backward (PERF.md section 7, PR 34). Prints ONE JSON line.
+backward (PERF.md section 7, PR 34), each with its cut tiles whole and,
+where `flash.cut_half` takes the call, by quarters (`..._quarters_ms`;
+PR 58). Prints ONE JSON line.
 
     chiprun -- python3 benchmarks/flash_attention.py
     python3 benchmarks/flash_attention.py --rehearse-cpu
@@ -39,10 +41,11 @@ def dense(q, k, v):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
 
-# the benchmark's three LM cells' attention calls (PERF.md section 4):
-# (T, query heads, key/value heads, D, Dv, window), bf16, causal
+# the LM cells' attention calls (PERF.md section 4): (T, query heads,
+# key/value heads, D, Dv, window), bf16, causal
 CELL_CALLS = {
     "kanana2_8k": (8192, 32, 32, 192, 128, 0),
+    "lfm2_8k": (8192, 32, 8, 64, 64, 0),
     "olmoe_4k": (4096, 16, 16, 128, 128, 0),
     "mimo_full_4k": (4096, 8, 1, 192, 128, 0),
     "mimo_window_4k": (4096, 8, 1, 192, 128, 128),
@@ -64,8 +67,8 @@ def live_pairs(t, heads, bq, bk, window):
 def cell_kernels(run):
     """Forward, the split backward (dq and dkv) and the one-pass
     backward, kernel calls alone (no layout change round them), in ms a
-    call at the cells' shapes; the two backward forms' gradients against
-    each other."""
+    call at the cells' shapes, cut tiles whole and by quarters; every
+    backward's gradients against the whole split form's."""
     def ms(f, *args):
         return round(run.host_ms(f, *args), 3)
 
@@ -79,27 +82,34 @@ def cell_kernels(run):
         bq, bk = pk.flash_tiles(t, max(d, dv), q.dtype, window)
         kw = dict(t_real=t, scale=d ** -0.5, causal=True, window=window,
                   block_q=bq, block_k=bk, interpret=run.rehearse)
-        fwd = jax.jit(functools.partial(pk.flash.fwd_call, **kw))
-        o, lse = fwd(q, k, v)
+        # the toy tiles are under the floor: a rehearsal runs their
+        # quarters all the same, where the window's edge allows
+        edge = (bq // 2 if run.rehearse and window % (bq // 2) == 0
+                else pk.flash.cut_half(bq, bk, True, window))
+        o, lse = pk.flash.fwd_call(q, k, v, **kw)
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1, keepdims=True)
-        row = {"tiles": [bq, bk], "fwd_ms": ms(fwd, q, k, v)}
+        row = {"tiles": [bq, bk], "edge": edge}
         pairs = live_pairs(t, h, bq, bk, window)
         ref = None
-        for form, fused in BWD_FORMS.items():
-            bwd = jax.jit(functools.partial(pk.flash.bwd_call, fused=fused,
-                                            **kw))
-            row["bwd_%s_ms" % form] = ms(bwd, q, k, v, do, lse, delta)
-            row["bwd_%s_us_a_pair" % form] = round(
-                row["bwd_%s_ms" % form] * 1000 / pairs, 2)
-            got = [np.asarray(x, np.float32)
-                   for x in bwd(q, k, v, do, lse, delta)]
-            if ref is None:
-                ref = got
-            else:
-                row["bwd_%s_max_rel_diff" % form] = float(max(
-                    np.abs(a - b).max() / (np.abs(b).max() + 1e-9)
-                    for a, b in zip(got, ref)))
+        for cut, e in [("", 0)] + [("quarters_", edge)] * bool(edge):
+            fwd = jax.jit(functools.partial(pk.flash.fwd_call, edge=e, **kw))
+            row["fwd_%sms" % cut] = ms(fwd, q, k, v)
+            for form, fused in BWD_FORMS.items():
+                bwd = jax.jit(functools.partial(
+                    pk.flash.bwd_call, fused=fused, edge=e, **kw))
+                key = "bwd_%s_%s" % (form, cut)
+                row[key + "ms"] = ms(bwd, q, k, v, do, lse, delta)
+                row[key + "us_a_pair"] = round(
+                    row[key + "ms"] * 1000 / pairs, 2)
+                got = [np.asarray(x, np.float32)
+                       for x in bwd(q, k, v, do, lse, delta)]
+                if ref is None:
+                    ref = got
+                else:
+                    row[key + "max_rel_diff"] = float(max(
+                        np.abs(a - b).max() / (np.abs(b).max() + 1e-9)
+                        for a, b in zip(got, ref)))
         out[name] = row
     return out
 

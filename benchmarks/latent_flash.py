@@ -23,6 +23,9 @@ Three tables, one JSON line a row (PERF.md section 7 holds them):
   it: run as layout (a) with the heads folded into the batch, so the
   rotary key is held once a head and its gradient summed outside: 1 MB and
   32 MB at this shape, noise beside the 134 MB of keys and values);
+  since PR 58 layout (a) also with its diagonal tiles whole
+  (`latent_flash_a_whole`: the pair before the quarters of
+  `flash.cut_steps`);
 - `check`: how far the kernel path's output and its four gradients are
   from the composition's, on the chip, as a share of the largest magnitude.
 
@@ -33,6 +36,7 @@ The platform rule, the clocks and the output file are `alone.py`'s.
 """
 import collections
 import re
+from unittest import mock
 
 import alone
 
@@ -123,6 +127,11 @@ def kernel_calls():
     def layout_a(q, kv, kr):        # [B, T, H 256], [B, T, H 256], [B, T, 128]
         return pk.latent_flash(q, kv, kr, H, N, scale)
 
+    def layout_a_whole(q, kv, kr):
+        # the floor is read once, as the call site is traced
+        with mock.patch.object(pk.flash, "FLASH_MIN_EDGE", T):
+            return pk.latent_flash(q, kv, kr, H, N, scale)
+
     def layout_b(q, kv, kr):        # [B H, T, 256] twice, [B H, T, 128]
         return pk.latent_flash(q, kv, kr, 1, N, scale)
 
@@ -131,6 +140,7 @@ def kernel_calls():
             return jnp.sum(f(*a).astype(jnp.float32))
         return jax.jit(f), jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
     return {"parent_flash": both(parent), "latent_flash_a": both(layout_a),
+            "latent_flash_a_whole": both(layout_a_whole),
             "latent_flash_b": both(layout_b)}
 
 
@@ -150,12 +160,12 @@ def kernel_inputs(seed):
     def heads_first(x):
         return jnp.moveaxis(x, 2, 1).reshape(B * H, T, x.shape[3])
 
+    layout_a = (q_pad.reshape(B, T, -1), kv.reshape(B, T, -1), kr_pad)
     return {
         "parent_flash": (q, jnp.concatenate(
             [kn, jnp.broadcast_to(kr[:, :, None], (B, T, H, R))], axis=-1),
             v),
-        "latent_flash_a": (q_pad.reshape(B, T, -1), kv.reshape(B, T, -1),
-                           kr_pad),
+        "latent_flash_a": layout_a, "latent_flash_a_whole": layout_a,
         "latent_flash_b": (heads_first(q_pad), heads_first(kv),
                            jnp.repeat(kr_pad, H, axis=0))}
 

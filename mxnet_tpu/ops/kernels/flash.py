@@ -23,6 +23,22 @@ places, all below:
             ``first live + j``, so tiles below the band are neither
             fetched nor stepped over; the band's lower edge is one more
             compare in the mask.
+  cut tiles a square tile of B rows that the diagonal or the window's
+            edge cuts corner to corner (the window 0 or a multiple of
+            B / 2, B / 2 >= ``FLASH_MIN_EDGE``: tiles of 1,024; no
+            padding key in it)
+            runs by its quarters of B / 2 (``cut_steps``): a dead
+            quarter is no step, a whole one a step without the mask,
+            a cut one a step whose mask is the one compare that cuts
+            it; the forward takes a row half's live quarters as one
+            step. The steps are inside the tile's body, on static
+            slices of the tile's blocks and accumulators: the grid,
+            the walk and the DMA are the whole tile's. Kernels
+            that do are named ``..._e<B / 2>``. Every other masked tile
+            (unequal tiles, another window, padding keys) computes the
+            mask over all of its scores, as before; a call that has no
+            such tile (whole tiles of keys, every masked offset of its
+            band a cut one) holds no whole-tile masked body at all.
   heads     G key/value heads serve H = G * group query heads: q head
             b reads k/v head b // group through the index maps; dkv's
             inner dimension walks the group's q heads one after
@@ -50,6 +66,7 @@ delta_i = sum_d dO_id * O_id.
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -69,9 +86,11 @@ _M_FLASH_LOWERINGS = _tm.counter(
     "(one per lowering, nothing per step); labels: operands (the type "
     "the MXU is fed), block_q, block_k and, where the call has them, "
     "window, kv_heads (fewer than the query heads), dv (a value width "
-    "other than the query's). Where a site's backward is traced it "
-    "counts once more under operands, block_q, block_k, window and bwd: "
-    "fused (dq, dk and dv in one pass) or split (dq and dkv)")
+    "other than the query's), edge (the rows of a quarter, where the "
+    "site's cut tiles run by quarters). Where a site's backward is traced "
+    "it counts once more under operands, block_q, block_k, window, bwd: "
+    "fused (dq, dk and dv in one pass) or split (dq and dkv), and edge "
+    "where the site has it")
 
 # Forward, dq and dkv ask Mosaic for no more than its scoped default
 # (``VMEM_SCOPED_DEFAULT``), and their tiles are chosen under it as
@@ -87,6 +106,13 @@ _M_FLASH_LOWERINGS = _tm.counter(
 FLASH_MAX_BLOCK_Q = 1024
 FLASH_MAX_BLOCK_K = 1024
 FLASH_MIN_BLOCK = 128
+# The smallest quarter of a cut tile that is worth a step of its own, by
+# measurement on the v5e (PERF.md section 7, PR 58): tiles of 1,024 by
+# quarters of 512 take 15.5% off Trinity-Mini's window pair and 5-9% off
+# every full-causal pair; tiles of 512 by quarters of 256 under the same
+# window lose 1% (a step of 256 x 256 costs what it saves), and a
+# 256 x 256 tile under a window of 128 costs 2 us whatever it computes.
+FLASH_MIN_EDGE = 512
 
 
 def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None):
@@ -244,19 +270,118 @@ def _inner_q(ki, j, *, block_q, block_k, window, steps, group):
     return head, j
 
 
+# What of a tile pair one call of a kernel's body computes: ``rows`` and
+# ``cols``, static slices of the q tile's and the k tile's rows, and
+# ``delta``, ``q_pos - k_pos`` at its first row and column where the part
+# is a step inside a cut tile (the tile offset is static there), None for
+# the tile whole.
+Part = collections.namedtuple("Part", "rows cols delta")
+WHOLE = Part(..., ..., None)
+
+
+def _cuts(delta, rows, cols, window):
+    """Which bounds of ``0 <= q_pos - k_pos < window`` cut a rectangle of
+    ``rows`` x ``cols`` scores whose first has ``q_pos - k_pos = delta``:
+    (the diagonal does, the window's edge does); None where no score of
+    it is live."""
+    least, most = delta - (cols - 1), delta + rows - 1
+    if most < 0 or (window and least >= window):
+        return None
+    return least < 0, bool(window) and most >= window
+
+
+def quarter_states(block, window, offset):
+    """The four quarters [row half][column half] of a square tile of
+    ``block`` rows, ``offset`` = qi - ki tiles under the diagonal, of a
+    causal call under ``window``: "dead", "cut" (the mask decides) or
+    "whole". ``_keep``'s predicate on the quarter's corners."""
+    h = block // 2
+
+    def state(a, b):
+        cuts = _cuts(offset * block + (a - b) * h, h, h, window)
+        return "dead" if cuts is None else "cut" if any(cuts) else "whole"
+    return [[state(a, b) for b in range(2)] for a in range(2)]
+
+
+def cut_half(block_q, block_k, causal, window=0):
+    """Half a tile's rows where a causal call's cut tiles run by quarters
+    (``cut_steps``), 0 where they run whole: shapes alone decide. Square
+    tiles whose half is ``FLASH_MIN_EDGE`` at least, and a window whose
+    edge runs through the quarters' corners."""
+    h = block_q // 2
+    if not causal or block_q != block_k or h < FLASH_MIN_EDGE or window % h:
+        return 0
+    return h
+
+
+def cut_steps(block, window, by_rows=False):
+    """{tile offset qi - ki: [(masked, Part), ...]} of the offsets a
+    causal call's band holds at which a tile has a dead quarter, and the
+    steps that cover its live scores: each live quarter one, masked
+    where it is cut; ``by_rows``, a row half's live quarters together
+    (the forward's: a step rescales the rows' softmax state and costs
+    each row its two lane reductions whatever its width, so three steps
+    a tile lost to the whole tile and two win; the backward keeps no
+    state a row and is the same either way: PERF.md section 7, PR 58).
+    An offset with no dead quarter keeps ``tile_cases``' whole-tile
+    cases."""
+    h = block // 2
+    steps = {}
+    for offset in range(window // block + 2):
+        states = quarter_states(block, window, offset)
+        dead = sum(states, []).count("dead")
+        if not 0 < dead < 4:
+            continue
+        steps[offset] = []
+        for a in range(2):
+            live = [b for b in range(2) if states[a][b] != "dead"]
+            # (first column half, column halves) of the row half's steps
+            for b, n in ([(live[0], len(live))] if by_rows and live
+                         else [(b, 1) for b in live]):
+                delta = offset * block + (a - b) * h
+                steps[offset].append(
+                    (any(_cuts(delta, h, n * h, window)),
+                     Part(slice(a * h, (a + 1) * h),
+                          slice(b * h, (b + n) * h), delta)))
+    return steps
+
+
+def part_mask(rows, cols, delta, window):
+    """The keep-mask of a step inside a cut tile, None where nothing cuts
+    it: ``0 <= delta + row - col < window`` by the one compare (or two)
+    that its rectangle needs. The tile holds no padding key."""
+    diagonal, edge = _cuts(delta, rows, cols, window)
+    if not (diagonal or edge):
+        return None
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+
+    def shifted(by):
+        return row + jnp.int32(by) if by else row
+    mask = None
+    if diagonal:
+        mask = shifted(delta) >= col
+    if edge:
+        below = shifted(delta - window) < col
+        mask = below if mask is None else mask & below
+    return mask
+
+
 def _masked_scores(q, k_blk, qi, ki, *, block_q, block_k, t_real, scale,
-                   causal, window=0, masked=True):
+                   causal, window=0, masked=True, part=WHOLE):
     """The shared score/mask invariant of all three kernels:
     s = scale·q@kᵀ on the MXU plus the (padding, causal, window)
-    keep-mask for this (qi, ki) block pair — None for a tile
-    ``tile_cases`` found to need none. Kept in ONE place so forward and
-    backward can never disagree on masking."""
+    keep-mask for this (qi, ki) block pair — None for a tile (or a step
+    inside a cut tile, ``part``) that needs none. Kept in ONE place so
+    forward and backward can never disagree on masking."""
     s = jnp.float32(scale) * jax.lax.dot_general(
         q, k_blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32,
     )  # [bq, bk]
     if not masked:
         return s, None
+    if part.delta is not None:
+        return s, part_mask(*s.shape, part.delta, window)
     q_pos = qi * jnp.int32(block_q) + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
@@ -272,12 +397,14 @@ def _masked_scores(q, k_blk, qi, ki, *, block_q, block_k, t_real, scale,
 
 
 def tile_cases(body, qi, ki, *, block_q, block_k, t_real, t_pad, causal,
-               window=0):
+               window=0, edge=0, by_rows=False):
     """Run ``body(masked)`` for this tile pair at what it holds: not at
     all for a dead tile, with the mask where the diagonal or the
     window's edge crosses it or it holds padding keys, without it
-    everywhere else."""
-    live = needs_mask = None  # None: statically "always" / "never"
+    everywhere else. Under ``edge`` (``cut_half``) a tile with a dead
+    quarter and no padding key runs ``body(masked, part)`` once a step
+    of ``cut_steps(.., by_rows)`` instead."""
+    live = needs_mask = pads = None  # None: statically "always" / "never"
     if causal:
         live = _causal_block_live(qi, ki, block_q, block_k)
         needs_mask = jax.lax.gt(affine(ki, block_k, block_k - 1),
@@ -301,15 +428,35 @@ def tile_cases(body, qi, ki, *, block_q, block_k, t_real, t_pad, causal,
         body(False)
         return
     unmasked = jax.lax.bitwise_not(needs_mask)
+    cut = cut_steps(block_q, window, by_rows) if edge else {}
+    for offset, steps in cut.items():
+        here = jax.lax.eq(jax.lax.sub(qi, ki), np.int32(offset))
+        if pads is not None:
+            here = jax.lax.bitwise_and(here, jax.lax.bitwise_not(pads))
+        needs_mask = jax.lax.bitwise_and(needs_mask,
+                                         jax.lax.bitwise_not(here))
+
+        def by_quarters(steps=steps):
+            for masked, part in steps:
+                body(masked, part)
+        pl.when(jax.lax.bitwise_and(live, here))(by_quarters)
     if live is not None:
         needs_mask = jax.lax.bitwise_and(live, needs_mask)
         unmasked = jax.lax.bitwise_and(live, unmasked)
-    pl.when(needs_mask)(lambda: body(True))
+    # without padding keys, a call whose every masked tile is a cut one
+    # (no offset of its band is cut and has no dead quarter) has no tile
+    # left for the whole-tile mask: the body is not traced
+    if not (cut and pads is None and all(
+            "dead" in states or "cut" not in states
+            for states in (sum(quarter_states(block_q, window, offset), [])
+                           for offset in range(window // block_q + 2)))):
+        pl.when(needs_mask)(lambda: body(True))
     pl.when(unmasked)(lambda: body(False))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
-                *, block_q, block_k, t_real, t_pad, scale, causal, window):
+                *, block_q, block_k, t_real, t_pad, scale, causal, window,
+                edge):
     qi = pl.program_id(1)
     j = pl.program_id(2)
     ki = _inner_k(qi, j, block_q=block_q, block_k=block_k, window=window)
@@ -320,27 +467,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
         m_s[...] = jnp.full_like(m_s, jnp.float32(NEG_INF))
         l_s[...] = jnp.zeros_like(l_s)
 
-    def body(masked):
-        v_blk = v_ref[0]  # [bk, Dv]
+    def body(masked, part=WHOLE):
+        rows, cols = part.rows, part.cols
+        v_blk = v_ref[0, cols]  # [bk, Dv]
         s, mask = _masked_scores(
-            q_ref[0], k_ref[0], qi, ki, block_q=block_q, block_k=block_k,
-            t_real=t_real, scale=scale, causal=causal, window=window,
-            masked=masked)
+            q_ref[0, rows], k_ref[0, cols], qi, ki, block_q=block_q,
+            block_k=block_k, t_real=t_real, scale=scale, causal=causal,
+            window=window, masked=masked, part=part)
         if masked:
             s = jnp.where(mask, s, jnp.float32(NEG_INF))
-        m_prev = m_s[...]  # [bq, 1]
+        m_prev = m_s[rows]  # [bq, 1]
         m_cur = jax.lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jax.lax.exp(m_prev - m_cur)
         p = jax.lax.exp(s - m_cur)
-        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        m_s[...] = m_cur
-        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+        l_s[rows] = l_s[rows] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_s[rows] = m_cur
+        acc[rows] = acc[rows] * alpha + jax.lax.dot_general(
             p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window,
+               edge=edge, by_rows=True)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -351,10 +500,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, l_ref, acc, m_s, l_s,
         l_ref[0] = m_s[...] + jnp.log(safe_l)
 
 
-def _bwd_p_ds(q, k_blk, v_blk, do, lse, delta, qi, ki, masked, **tile):
-    """P and dS of one tile pair, rounded to the operand type: what dq
-    and dkv both rebuild from the residuals."""
-    s, mask = _masked_scores(q, k_blk, qi, ki, masked=masked, **tile)
+def _bwd_p_ds(q, k_blk, v_blk, do, lse, delta, qi, ki, masked, part,
+              **tile):
+    """P and dS of one tile pair (of its ``part``), rounded to the
+    operand type: what dq and dkv both rebuild from the residuals."""
+    s, mask = _masked_scores(q, k_blk, qi, ki, masked=masked, part=part,
+                             **tile)
     p = jax.lax.exp(s - lse)
     if masked:
         p = jnp.where(mask, p, jnp.float32(0.0))
@@ -368,7 +519,7 @@ def _bwd_p_ds(q, k_blk, v_blk, do, lse, delta, qi, ki, masked, **tile):
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
                    dq_acc, *, block_q, block_k, t_real, t_pad, scale,
-                   causal, window):
+                   causal, window, edge):
     qi = pl.program_id(1)
     j = pl.program_id(2)
     ki = _inner_k(qi, j, block_q=block_q, block_k=block_k, window=window)
@@ -377,19 +528,22 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def body(masked):
-        k_blk = k_ref[0]
+    def body(masked, part=WHOLE):
+        rows, cols = part.rows, part.cols
+        k_blk = k_ref[0, cols]
         _, ds = _bwd_p_ds(
-            q_ref[0], k_blk, v_ref[0], do_ref[0], l_ref[0], d_ref[0],
-            qi, ki, masked, block_q=block_q, block_k=block_k,
-            t_real=t_real, scale=scale, causal=causal, window=window)
-        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+            q_ref[0, rows], k_blk, v_ref[0, cols], do_ref[0, rows],
+            l_ref[0, rows], d_ref[0, rows], qi, ki, masked, part,
+            block_q=block_q, block_k=block_k, t_real=t_real, scale=scale,
+            causal=causal, window=window)
+        dq_acc[rows] = dq_acc[rows] + jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window,
+               edge=edge)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -398,7 +552,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                    t_real, t_pad, scale, causal, window, steps, group):
+                    t_real, t_pad, scale, causal, window, edge, steps,
+                    group):
     ki = pl.program_id(1)
     j = pl.program_id(2)
     _, qi = _inner_q(ki, j, block_q=block_q, block_k=block_k,
@@ -409,24 +564,27 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def body(masked):
-        q = q_ref[0]  # [bq, D]
-        do = do_ref[0]
+    def body(masked, part=WHOLE):
+        rows, cols = part.rows, part.cols
+        q = q_ref[0, rows]  # [bq, D]
+        do = do_ref[0, rows]
         p, ds = _bwd_p_ds(
-            q, k_ref[0], v_ref[0], do, l_ref[0], d_ref[0], qi, ki,
-            masked, block_q=block_q, block_k=block_k, t_real=t_real,
-            scale=scale, causal=causal, window=window)
-        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
+            q, k_ref[0, cols], v_ref[0, cols], do, l_ref[0, rows],
+            d_ref[0, rows], qi, ki, masked, part, block_q=block_q,
+            block_k=block_k, t_real=t_real, scale=scale, causal=causal,
+            window=window)
+        dv_acc[cols] = dv_acc[cols] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bk, Dv]
-        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
+        dk_acc[cols] = dk_acc[cols] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window,
+               edge=edge)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -436,7 +594,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref,
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
                       dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, block_q,
-                      block_k, t_real, t_pad, scale, causal, window, group):
+                      block_k, t_real, t_pad, scale, causal, window, edge,
+                      group):
     """dq, dk and dv in one pass: P and dS are built once a tile pair and
     feed all three products. The grid is dq's, (q head, q tile, k step):
     dq accumulates in tile-sized scratch over the inner steps; dk and dv
@@ -479,29 +638,31 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def body(masked):
-        q = q_ref[0]
-        k_blk = k_ref[0]
-        do = do_ref[0]
+    def body(masked, part=WHOLE):
+        rows, cols = part.rows, part.cols
+        q = q_ref[0, rows]
+        k_blk = k_ref[0, cols]
+        do = do_ref[0, rows]
         p, ds = _bwd_p_ds(
-            q, k_blk, v_ref[0], do, l_ref[0], d_ref[0], qi, ki, masked,
-            block_q=block_q, block_k=block_k, t_real=t_real, scale=scale,
-            causal=causal, window=window)
-        dv_acc[ki] = dv_acc[ki] + jax.lax.dot_general(
+            q, k_blk, v_ref[0, cols], do, l_ref[0, rows], d_ref[0, rows],
+            qi, ki, masked, part, block_q=block_q, block_k=block_k,
+            t_real=t_real, scale=scale, causal=causal, window=window)
+        dv_acc[ki, cols] = dv_acc[ki, cols] + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # [bk, Dv]
-        dk_acc[ki] = dk_acc[ki] + jax.lax.dot_general(
+        dk_acc[ki, cols] = dk_acc[ki, cols] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+        dq_acc[rows] = dq_acc[rows] + jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
     tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-               t_real=t_real, t_pad=t_pad, causal=causal, window=window)
+               t_real=t_real, t_pad=t_pad, causal=causal, window=window,
+               edge=edge)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -520,10 +681,10 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_ref,
 # host-side wrappers
 # ---------------------------------------------------------------------------
 
-def _kernel_name(which, dtype, block_q, block_k, window=0):
-    return "flash_%s_%s_q%d_k%d%s" % (
+def _kernel_name(which, dtype, block_q, block_k, window=0, edge=0):
+    return "flash_%s_%s_q%d_k%d%s%s" % (
         which, operand_label(dtype), block_q, block_k,
-        "_w%d" % window if window else "")
+        "_w%d" % window if window else "", "_e%d" % edge if edge else "")
 
 
 _FLASH_PARAMS = pltpu.CompilerParams(
@@ -576,8 +737,17 @@ def _tile_specs(block_q, block_k, d, dv, causal, inner, *, window=0,
             pl.BlockSpec((1, block_q, 1), q_idx))
 
 
+# One trace and one lowering a signature, however many attention sites
+# of a program share it (as the latent pair, the scan and the taps keep
+# theirs): a site's kernels are some hundred equations a body, and a cut
+# tile's steps are bodies of their own.
+_CALL_STATIC = ("t_real", "scale", "causal", "window", "block_q", "block_k",
+                "interpret", "edge")
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATIC)
 def fwd_call(q3, k3, v3, *, t_real, scale, causal, window, block_q,
-             block_k, interpret):
+             block_k, interpret, edge=0):
     bh, t_pad, d = q3.shape
     dv = v3.shape[2]
     group = bh // k3.shape[0]
@@ -585,7 +755,7 @@ def fwd_call(q3, k3, v3, *, t_real, scale, causal, window, block_q,
     nk = t_pad // block_k
     kern = functools.partial(
         _fwd_kernel, block_q=block_q, block_k=block_k, t_real=t_real,
-        t_pad=t_pad, scale=scale, causal=causal, window=window,
+        t_pad=t_pad, scale=scale, causal=causal, window=window, edge=edge,
     )
     q_spec, k_spec, o_spec, v_spec, row_spec = _tile_specs(
         block_q, block_k, d, dv, causal, "k", window=window, group=group)
@@ -607,7 +777,8 @@ def fwd_call(q3, k3, v3, *, t_real, scale, causal, window, block_q,
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
             compiler_params=_FLASH_PARAMS,
-            name=_kernel_name("fwd", q3.dtype, block_q, block_k, window),
+            name=_kernel_name("fwd", q3.dtype, block_q, block_k, window,
+                              edge),
             interpret=interpret,
         )(q3, k3, v3)
     return out, lse
@@ -658,21 +829,24 @@ def _bwd_fused_call(q3, k3, v3, do3, lse, delta, *, interpret, **tile):
             vmem_limit_bytes=flash_vmem_bytes(
                 block_q, block_k, max(d, dv), q3.dtype.itemsize,
                 resident=(t_pad, d, dv))),
-        name=_kernel_name("bwd", q3.dtype, block_q, block_k, window),
+        name=_kernel_name("bwd", q3.dtype, block_q, block_k, window,
+                          tile["edge"]),
         interpret=interpret,
     )(q3, k3, v3, do3, lse, delta)
     return dq, dk.reshape(bg, t_pad, d), dv_.reshape(bg, t_pad, dv)
 
 
+@functools.partial(jax.jit, static_argnames=_CALL_STATIC + ("fused",))
 def bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
-             window, block_q, block_k, interpret, fused=False):
+             window, block_q, block_k, interpret, fused=False, edge=0):
     bh, t_pad, d = q3.shape
     bg, dv = k3.shape[0], v3.shape[2]
     group = bh // bg
     nq = t_pad // block_q
     nk = t_pad // block_k
     tile = dict(block_q=block_q, block_k=block_k, t_real=t_real,
-                t_pad=t_pad, scale=scale, causal=causal, window=window)
+                t_pad=t_pad, scale=scale, causal=causal, window=window,
+                edge=edge)
     with no_x64():
         if fused:
             return _bwd_fused_call(q3, k3, v3, do3, lse, delta,
@@ -690,7 +864,8 @@ def bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
             out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q3.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             compiler_params=_FLASH_PARAMS,
-            name=_kernel_name("dq", q3.dtype, block_q, block_k, window),
+            name=_kernel_name("dq", q3.dtype, block_q, block_k, window,
+                              edge),
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
         steps = (_band_steps(nq, nk, block_q, block_k, window, "q")
@@ -713,7 +888,8 @@ def bwd_call(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
                 pltpu.VMEM((block_k, dv), jnp.float32),
             ],
             compiler_params=_FLASH_PARAMS,
-            name=_kernel_name("dkv", q3.dtype, block_q, block_k, window),
+            name=_kernel_name("dkv", q3.dtype, block_q, block_k, window,
+                              edge),
             interpret=interpret,
         )(q3, k3, v3, do3, lse, delta)
     return dq, dk, dv_
@@ -781,24 +957,24 @@ def plain_bwd(q3, k3, v3, do3, lse, delta, *, t_real, scale, causal,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11)
 )
 def _flash(q3, k3, v3, sink, t_real, scale, causal, window, block_q,
-           block_k, interpret):
+           block_k, edge, interpret):
     out, _ = _flash_fwd(q3, k3, v3, sink, t_real, scale, causal, window,
-                        block_q, block_k, interpret)
+                        block_q, block_k, edge, interpret)
     return out
 
 
-def _static(t_real, scale, causal, window, block_q, block_k):
+def _static(t_real, scale, causal, window, block_q, block_k, edge):
     """A call's static arguments, as both of a pair's forms take them."""
     return dict(t_real=t_real, scale=scale, causal=causal, window=window,
-                block_q=block_q, block_k=block_k)
+                block_q=block_q, block_k=block_k, edge=edge)
 
 
 def _flash_fwd(q3, k3, v3, sink, t_real, scale, causal, window, block_q,
-               block_k, interpret):
-    call = _static(t_real, scale, causal, window, block_q, block_k)
+               block_k, edge, interpret):
+    call = _static(t_real, scale, causal, window, block_q, block_k, edge)
     out, lse = on_tpu(functools.partial(fwd_call, **call),
                       functools.partial(plain_fwd, **call), interpret,
                       q3, k3, v3)
@@ -810,8 +986,8 @@ def _flash_fwd(q3, k3, v3, sink, t_real, scale, causal, window, block_q,
     return out, (q3, k3, v3, sink, out, lse)
 
 
-def _flash_bwd(t_real, scale, causal, window, block_q, block_k, interpret,
-               res, g):
+def _flash_bwd(t_real, scale, causal, window, block_q, block_k, edge,
+               interpret, res, g):
     q3, k3, v3, sink, out, lse = res
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
@@ -821,8 +997,9 @@ def _flash_bwd(t_real, scale, causal, window, block_q, block_k, interpret,
                       v3.shape[2], q3.dtype)
     _M_FLASH_LOWERINGS.inc(
         operands=operand_label(q3.dtype), block_q=block_q,
-        block_k=block_k, window=window, bwd="fused" if fused else "split")
-    call = _static(t_real, scale, causal, window, block_q, block_k)
+        block_k=block_k, window=window, bwd="fused" if fused else "split",
+        **({"edge": edge} if edge else {}))
+    call = _static(t_real, scale, causal, window, block_q, block_k, edge)
     dq, dk, dv = on_tpu(
         functools.partial(bwd_call, fused=fused, **call),
         functools.partial(plain_bwd, **call), interpret,
@@ -894,13 +1071,17 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                   block_k=int(block_k))
     if window or g != h or dv != d:
         labels.update(window=int(window), kv_heads=int(g), dv=int(dv))
+    edge = cut_half(int(block_q), int(block_k), bool(causal), int(window))
+    if edge:
+        labels.update(edge=edge)
     _M_FLASH_LOWERINGS.inc(**labels)
     mult = int(np.lcm(block_q, block_k))
     q3, k3, v3 = (pad_to(_heads_first(x), 1, mult)[0] for x in (q, k, v))
     if sink is not None:
         sink = jnp.tile(sink.astype(jnp.float32), b)  # [B*H], as q3's rows
     out = _flash(q3, k3, v3, sink, t, float(scale), bool(causal),
-                 int(window), int(block_q), int(block_k), bool(interpret))
+                 int(window), int(block_q), int(block_k), edge,
+                 bool(interpret))
     out = out[:, :t]
     return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
 

@@ -1,8 +1,9 @@
 """Latent attention's flash pair (ops/transformer.py::latent_attention; MLA,
 DeepSeek-V2/V3) and the pass over its query. The same algorithm as the
 single-key flash kernels (tiles from ``flash.flash_tiles``, the causal
-live-tile walk, ``flash.tile_cases``' unmasked fast path, the one-pass
-backward with the diagonal first), on the operands where the
+live-tile walk, ``flash.tile_cases``' unmasked fast path and its diagonal
+tiles by quarters, the one-pass backward with the diagonal first), on the
+operands where the
 neighbouring matmuls leave and take them, every one token-major and a
 head a block of whole lane rows of its columns:
 
@@ -68,7 +69,8 @@ _M_LATENT_TRACES = _tm.counter(
     "attention.latent_kernel_traces", "Traces of a latent flash kernel's "
     "pallas_call (one a signature and process, however many "
     "LatentAttention nodes call it; nothing per step); labels: pass "
-    "(fwd / bwd)")
+    "(fwd / bwd) and, where the kernel runs its cut tiles by quarters, "
+    "edge (a quarter's rows)")
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b^T
 _TN = (((0,), (0,)), ((), ()))      # a^T @ b
@@ -99,21 +101,25 @@ def latent_flash_takes(t, nope, rope, dv, dtype):
                       dtype)
 
 
-def _latent_key(kv_ref, kr_ref, nope):
-    """A head's key tile [k_nope | k_rope], put together in VMEM: two
-    lane-aligned column groups of one value, so ONE product over both
-    (the MXU sums the two inside it; two products summed by the vector
-    unit cost a pass over the scores more: PERF.md section 7, PR 44)."""
-    return lax.concatenate([kv_ref[:, :nope], kr_ref[...]], 1)
+def _latent_key(kv_ref, kr_ref, nope, cols):
+    """Rows ``cols`` of a head's key tile [k_nope | k_rope], put together
+    in VMEM: two lane-aligned column groups of one value, so ONE product
+    over both (the MXU sums the two inside it; two products summed by the
+    vector unit cost a pass over the scores more: PERF.md section 7,
+    PR 44)."""
+    return lax.concatenate([kv_ref[cols, :nope], kr_ref[cols]], 1)
 
 
-def _latent_scores(q_ref, k_blk, qi, ki, masked, *, block_q, block_k,
+def _latent_scores(q_ref, k_blk, qi, ki, masked, part, *, block_q, block_k,
                    t_real, scale):
-    """``scale (q_nope k_nope^T + q_rope k_rope^T)`` of one tile pair and,
-    under ``masked``, ``_masked_scores``' keep-mask of a causal call."""
-    s = lax.mul(_f32_dot(q_ref[...], k_blk, _NT), np.float32(scale))
+    """``scale (q_nope k_nope^T + q_rope k_rope^T)`` of one tile pair (of
+    its ``part``) and, under ``masked``, ``_masked_scores``' keep-mask of
+    a causal call."""
+    s = lax.mul(_f32_dot(q_ref[part.rows], k_blk, _NT), np.float32(scale))
     if not masked:
         return s, None
+    if part.delta is not None:
+        return s, flash.part_mask(*s.shape, part.delta, 0)
     shape = (block_q, block_k)
     q_pos = lax.add(lax.broadcasted_iota(jnp.int32, shape, 0),
                     affine(qi, block_q))
@@ -124,7 +130,8 @@ def _latent_scores(q_ref, k_blk, qi, ki, masked, *, block_q, block_k,
 
 
 def _latent_fwd_kernel(q_ref, kv_ref, kr_ref, o_ref, l_ref, acc, m_s, l_s,
-                       *, nope, block_q, block_k, t_real, t_pad, scale):
+                       *, nope, block_q, block_k, t_real, t_pad, scale,
+                       edge):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -134,25 +141,28 @@ def _latent_fwd_kernel(q_ref, kv_ref, kr_ref, o_ref, l_ref, acc, m_s, l_s,
         m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
 
-    def body(masked):
-        v_blk = kv_ref[:, nope:]
+    def body(masked, part=flash.WHOLE):
+        rows, cols = part.rows, part.cols
+        v_blk = kv_ref[cols, nope:]
         s, mask = _latent_scores(
-            q_ref, _latent_key(kv_ref, kr_ref, nope), qi, ki, masked,
-            block_q=block_q, block_k=block_k, t_real=t_real, scale=scale)
+            q_ref, _latent_key(kv_ref, kr_ref, nope, cols), qi, ki, masked,
+            part, block_q=block_q, block_k=block_k, t_real=t_real,
+            scale=scale)
         if masked:
             s = jnp.where(mask, s, jnp.float32(NEG_INF))
-        m_prev = m_s[...]
+        m_prev = m_s[rows]
         m_cur = lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = lax.exp(lax.sub(m_prev, m_cur))
         p = lax.exp(lax.sub(s, m_cur))
-        l_s[...] = lax.add(lax.mul(l_s[...], alpha),
-                           jnp.sum(p, axis=1, keepdims=True))
-        m_s[...] = m_cur
-        acc[...] = lax.add(lax.mul(acc[...], alpha),
-                           _f32_dot(p.astype(v_blk.dtype), v_blk, _NN))
+        l_s[rows] = lax.add(lax.mul(l_s[rows], alpha),
+                            jnp.sum(p, axis=1, keepdims=True))
+        m_s[rows] = m_cur
+        acc[rows] = lax.add(lax.mul(acc[rows], alpha),
+                            _f32_dot(p.astype(v_blk.dtype), v_blk, _NN))
 
     flash.tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-                t_real=t_real, t_pad=t_pad, causal=True)
+                     t_real=t_real, t_pad=t_pad, causal=True, edge=edge,
+                     by_rows=True)
 
     @pl.when(ki == pl.num_programs(3) - 1)
     def _():
@@ -165,7 +175,7 @@ def _latent_fwd_kernel(q_ref, kv_ref, kr_ref, o_ref, l_ref, acc, m_s, l_s,
 def _latent_bwd_kernel(q_ref, kv_ref, kr_ref, o_ref, do_ref, l_ref, dq_ref,
                        dkv_ref, dkr_ref, delta, dq_acc, dk_acc, dv_acc,
                        dkr_acc, *, nope, block_q, block_k, t_real, t_pad,
-                       scale):
+                       scale, edge):
     """dq, dK_nope | dV and dK_rope in one pass, as ``_bwd_fused_kernel``
     makes dq, dk and dv: a row's k tiles from the diagonal down, dq in
     tile-sized scratch over the inner steps, a head's dK_nope and dV in
@@ -211,26 +221,27 @@ def _latent_bwd_kernel(q_ref, kv_ref, kr_ref, o_ref, do_ref, l_ref, dq_ref,
             lax.mul(do_ref[...].astype(jnp.float32),
                     o_ref[...].astype(jnp.float32)), axis=1, keepdims=True)
 
-    def body(masked):
-        do = do_ref[...]
-        k_blk = _latent_key(kv_ref, kr_ref, nope)
+    def body(masked, part=flash.WHOLE):
+        rows, cols = part.rows, part.cols
+        do = do_ref[rows]
+        k_blk = _latent_key(kv_ref, kr_ref, nope, cols)
         s, mask = _latent_scores(
-            q_ref, k_blk, qi, ki, masked, block_q=block_q, block_k=block_k,
-            t_real=t_real, scale=scale)
-        p = lax.exp(lax.sub(s, l_ref[...]))
+            q_ref, k_blk, qi, ki, masked, part, block_q=block_q,
+            block_k=block_k, t_real=t_real, scale=scale)
+        p = lax.exp(lax.sub(s, l_ref[rows]))
         if masked:
             p = jnp.where(mask, p, jnp.float32(0.0))
-        dp = _f32_dot(do, kv_ref[:, nope:], _NT)
-        ds = lax.mul(p, lax.sub(dp, delta[...])).astype(q_ref.dtype)
-        dv_acc[ki] = lax.add(dv_acc[ki], _f32_dot(p.astype(do.dtype), do,
-                                                  _TN))
-        dk = _f32_dot(ds, q_ref[...], _TN)      # [dK_nope | dK_rope]
-        dk_acc[ki] = lax.add(dk_acc[ki], dk[:, :nope])
-        dkr_acc[ki] = lax.add(dkr_acc[ki], dk[:, nope:])
-        dq_acc[...] = lax.add(dq_acc[...], _f32_dot(ds, k_blk, _NN))
+        dp = _f32_dot(do, kv_ref[cols, nope:], _NT)
+        ds = lax.mul(p, lax.sub(dp, delta[rows])).astype(q_ref.dtype)
+        dv_acc[ki, cols] = lax.add(
+            dv_acc[ki, cols], _f32_dot(p.astype(do.dtype), do, _TN))
+        dk = _f32_dot(ds, q_ref[rows], _TN)     # [dK_nope | dK_rope]
+        dk_acc[ki, cols] = lax.add(dk_acc[ki, cols], dk[:, :nope])
+        dkr_acc[ki, cols] = lax.add(dkr_acc[ki, cols], dk[:, nope:])
+        dq_acc[rows] = lax.add(dq_acc[rows], _f32_dot(ds, k_blk, _NN))
 
     flash.tile_cases(body, qi, ki, block_q=block_q, block_k=block_k,
-                t_real=t_real, t_pad=t_pad, causal=True)
+                     t_real=t_real, t_pad=t_pad, causal=True, edge=edge)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -252,9 +263,15 @@ def _latent_bwd_kernel(q_ref, kv_ref, kr_ref, o_ref, do_ref, l_ref, dq_ref,
         each_k_tile(write)
 
 
-def _latent_name(which, dtype, block_q, block_k):
-    return "flash2_%s_%s_q%d_k%d" % (which, operand_label(dtype), block_q,
-                                     block_k)
+def _latent_name(which, dtype, block_q, block_k, edge):
+    return "flash2_%s_%s_q%d_k%d%s" % (
+        which, operand_label(dtype), block_q, block_k,
+        "_e%d" % edge if edge else "")
+
+
+def _count_trace(which, edge):
+    _M_LATENT_TRACES.inc(**{"pass": which},
+                         **({"edge": edge} if edge else {}))
 
 
 def _latent_specs(block_q, block_k, width, kv_width, rope, dv, steps=0):
@@ -282,15 +299,15 @@ def _latent_specs(block_q, block_k, width, kv_width, rope, dv, steps=0):
 
 
 _LATENT_STATIC = ("heads", "nope", "t_real", "scale", "block_q", "block_k",
-                  "interpret")
+                  "edge", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_LATENT_STATIC)
 def latent_fwd_call(q, kv, kr, *, heads, nope, t_real, scale, block_q,
-                    block_k, interpret):
+                    block_k, edge, interpret):
     """q [B, T, H (N + Rp)], kv [B, T, H (N + Dv)], kr [B, T, Rp] -> o
     [B, T, H Dv] and lse [B, H, T, 1] float32."""
-    _M_LATENT_TRACES.inc(**{"pass": "fwd"})
+    _count_trace("fwd", edge)
     b, t_pad, _ = q.shape
     width, kv_width, rope = (x.shape[2] // n for x, n in (
         (q, heads), (kv, heads), (kr, 1)))
@@ -301,7 +318,8 @@ def latent_fwd_call(q, kv, kr, *, heads, nope, t_real, scale, block_q,
         return pl.pallas_call(
             functools.partial(
                 _latent_fwd_kernel, nope=nope, block_q=block_q,
-                block_k=block_k, t_real=t_real, t_pad=t_pad, scale=scale),
+                block_k=block_k, t_real=t_real, t_pad=t_pad, scale=scale,
+                edge=edge),
             grid=(b, heads, t_pad // block_q, t_pad // block_k),
             in_specs=[q_spec, kv_spec, kr_spec],
             out_specs=[o_spec, row_spec],
@@ -314,16 +332,16 @@ def latent_fwd_call(q, kv, kr, *, heads, nope, t_real, scale, block_q,
                 pltpu.VMEM((block_q, 1), jnp.float32)],
             compiler_params=pltpu.CompilerParams(dimension_semantics=(
                 "parallel", "parallel", "parallel", "arbitrary")),
-            name=_latent_name("fwd", q.dtype, block_q, block_k),
+            name=_latent_name("fwd", q.dtype, block_q, block_k, edge),
             interpret=interpret,
         )(q, kv, kr)
 
 
 @functools.partial(jax.jit, static_argnames=_LATENT_STATIC)
 def latent_bwd_call(q, kv, kr, out, do, lse, *, heads, nope, t_real,
-                    scale, block_q, block_k, interpret):
+                    scale, block_q, block_k, edge, interpret):
     """-> dq, dkv and dkr, shaped and typed as q, kv and kr."""
-    _M_LATENT_TRACES.inc(**{"pass": "bwd"})
+    _count_trace("bwd", edge)
     b, t_pad, _ = q.shape
     width, kv_width, rope = (x.shape[2] // n for x, n in (
         (q, heads), (kv, heads), (kr, 1)))
@@ -335,7 +353,8 @@ def latent_bwd_call(q, kv, kr, out, do, lse, *, heads, nope, t_real,
         dq, dkv, dkr = pl.pallas_call(
             functools.partial(
                 _latent_bwd_kernel, nope=nope, block_q=block_q,
-                block_k=block_k, t_real=t_real, t_pad=t_pad, scale=scale),
+                block_k=block_k, t_real=t_real, t_pad=t_pad, scale=scale,
+                edge=edge),
             grid=(b, heads, t_pad // block_q, nk),
             in_specs=[q_spec, kv_spec, kr_spec, o_spec, o_spec, row_spec],
             out_specs=[
@@ -363,7 +382,7 @@ def latent_bwd_call(q, kv, kr, out, do, lse, *, heads, nope, t_real,
                 vmem_limit_bytes=flash.flash_vmem_bytes(
                     block_q, block_k, max(width, dv), q.dtype.itemsize,
                     resident=(t_pad, width, dv))),
-            name=_latent_name("bwd", q.dtype, block_q, block_k),
+            name=_latent_name("bwd", q.dtype, block_q, block_k, edge),
             interpret=interpret,
         )(q, kv, kr, out, do, lse)
     return dq, dkv.reshape(kv.shape), dkr.reshape(kr.shape)
@@ -385,20 +404,22 @@ def latent_composed(q, kv, kr, heads, nope, scale):
     return out.reshape(b, t, -1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _latent(q, kv, kr, heads, nope, t_real, scale, block_q, block_k,
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _latent(q, kv, kr, heads, nope, t_real, scale, block_q, block_k, edge,
             interpret):
     return _latent_fwd(q, kv, kr, heads, nope, t_real, scale, block_q,
-                       block_k, interpret)[0]
+                       block_k, edge, interpret)[0]
 
 
 def _latent_fwd(q, kv, kr, heads, nope, t_real, scale, block_q, block_k,
-                interpret):
+                edge, interpret):
     # one trace of the forward for the primal and the rule: see _ssd_fwd
     with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
         out, lse = latent_forward(
             q, kv, kr, heads=heads, nope=nope, t_real=t_real, scale=scale,
-            block_q=block_q, block_k=block_k, interpret=interpret)
+            block_q=block_q, block_k=block_k, edge=edge,
+            interpret=interpret)
     return out, (q, kv, kr, out, lse)
 
 
@@ -435,11 +456,11 @@ def latent_backward(q, kv, kr, out, lse, g, *, interpret, **call):
     return on_tpu(kernels, plain, interpret, q, kv, kr, out, lse, g)
 
 
-def _latent_bwd(heads, nope, t_real, scale, block_q, block_k, interpret,
-                res, g):
+def _latent_bwd(heads, nope, t_real, scale, block_q, block_k, edge,
+                interpret, res, g):
     return latent_backward(
         *res, g, heads=heads, nope=nope, t_real=t_real, scale=scale,
-        block_q=block_q, block_k=block_k, interpret=interpret)
+        block_q=block_q, block_k=block_k, edge=edge, interpret=interpret)
 
 
 _latent.defvjp(_latent_fwd, _latent_bwd)
@@ -474,7 +495,9 @@ def latent_flash(q, kv, k_rope, heads, nope, scale, block_q=None,
     mult = int(np.lcm(block_q, block_k))
     q, kv, k_rope = (pad_to(x, 1, mult)[0] for x in (q, kv, k_rope))
     out = _latent(q, kv, k_rope, int(heads), int(nope), t, float(scale),
-                  int(block_q), int(block_k), bool(interpret))
+                  int(block_q), int(block_k),
+                  flash.cut_half(int(block_q), int(block_k), True),
+                  bool(interpret))
     return out[:, :t]
 
 

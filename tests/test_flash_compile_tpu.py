@@ -70,11 +70,16 @@ def test_flash_chosen_tiles_compile_for_v5e(one_chip, t, h, d, dtype,
     t_pad = -(-t // max(bq, bk)) * max(bq, bk)
     fuses = pk.flash.bwd_fuses(t_pad, bq, bk, d, d, dtype)
     assert fuses is (t < 16384)
+    # square tiles of 1,024 run a causal call's cut tiles by quarters,
+    # and say so
+    edge = pk.flash.cut_half(bq, bk, causal)
+    assert edge == (512 if causal and bq == bk == 1024 else 0)
     for which, there in (("fwd", True), ("bwd", fuses), ("dq", not fuses),
                          ("dkv", not fuses)):
         # the kernel's name is the device op's name: what a trace shows
-        assert ("flash_%s_%s_q%d_k%d" % (which, operands, bq, bk)
-                in text) is there
+        name = "flash_%s_%s_q%d_k%d" % (which, operands, bq, bk)
+        assert (name + ("_e%d" % edge if edge else "") in text) is there
+        assert (name + "_e" in text) is (there and edge > 0)
 
 
 # the MiMo-V2-Flash share cell's two calls: 8 query heads of 192 on one
@@ -99,8 +104,10 @@ def test_flash_window_and_grouped_heads_compile_for_v5e(one_chip, window,
     bq, bk = flash_tiles(t, d, jnp.bfloat16, window)
     assert (bq, bk) == ((256, 256) if window else (1024, 1024))
     for which in ("fwd", "bwd"):
-        assert "flash_%s_bf16_q%d_k%d%s" % (
-            which, bq, bk, "_w128" if window else "") in text
+        # quarters of 128 are under the floor: the window kernels keep
+        # their names; the full layers' tiles of 1,024 run quarters of 512
+        assert re.search(r"flash_%s_bf16_q%d_k%d%s\b" % (
+            which, bq, bk, "_w128" if window else "_e512"), text)
     assert "flash_dq_" not in text and "flash_dkv_" not in text
 
 
@@ -142,8 +149,11 @@ def test_one_pass_backward_compiles_under_its_stated_limit(one_chip, cell):
         shape(1, t, g, dv)).compile().as_text()
     bq, bk = flash_tiles(t, d, jnp.bfloat16, window)
     assert pk.flash.bwd_fuses(t, bq, bk, d, dv, jnp.bfloat16)
-    name = "flash_bwd_bf16_q%d_k%d%s" % (
-        bq, bk, "_w%d" % window if window else "")
+    edge = pk.flash.cut_half(bq, bk, True, window)
+    assert edge == (0 if cell == "mimo_window_4k" else 512)
+    name = "flash_bwd_bf16_q%d_k%d%s%s" % (
+        bq, bk, "_w%d" % window if window else "",
+        "_e%d" % edge if edge else "")
     calls = [line for line in text.splitlines()
              if name in line and "custom-call(" in line]
     assert len(calls) == 1
@@ -185,7 +195,7 @@ def test_the_latent_pair_compiles_at_the_kanana_cells_shape(one_chip):
     bq, bk = flash_tiles(t, width, jnp.bfloat16)
     assert (bq, bk) == (1024, 1024)
     calls = {which: [line for line in text.splitlines()
-                     if "flash2_%s_bf16_q%d_k%d" % (which, bq, bk) in line
+                     if "flash2_%s_bf16_q%d_k%d_e512" % (which, bq, bk) in line
                      and "custom-call(" in line]
              for which in ("fwd", "bwd")}
     assert [len(c) for c in calls.values()] == [1, 1]
@@ -226,7 +236,7 @@ def test_the_latent_pair_takes_the_call_that_rotates_nothing(one_chip):
         shape(latent), shape(h * (nope + dv), latent)).compile().as_text()
     for which in ("fwd", "bwd"):
         assert len([line for line in text.splitlines()
-                    if "flash2_%s_bf16_q1024_k1024" % which in line
+                    if "flash2_%s_bf16_q1024_k1024_e512" % which in line
                     and "custom-call(" in line]) == 1
     assert "latent_query_" not in text
     assert "flash_fwd_" not in text and "flash_bwd_" not in text

@@ -140,6 +140,51 @@ def test_uneven_tiles_walk_the_same_triangle(block_q, block_k):
         _close(g, w, name, ulps=64)
 
 
+# tiles of 512 pinned on the pair and the floor lowered to their quarters
+# of 256 (the cells' tiles of 1,024 scaled down), so a diagonal tile runs
+# its three live quarters (``flash.cut_steps``); 1,300 positions pad to
+# 1,536 and the last diagonal tile, which holds padding keys, keeps the
+# whole mask
+@pytest.mark.parametrize("t,heads", [(1024, 2), (1300, 1)],
+                         ids=["two_tiles", "padded_last_tile"])
+def test_diagonal_tiles_by_quarters_match_the_composition(t, heads,
+                                                          monkeypatch):
+    monkeypatch.setattr(pk.flash, "FLASH_MIN_EDGE", 256)
+    rng = np.random.RandomState(t)
+    q, kv, kr = (jnp.asarray(rng.randn(1, t, width), jnp.float32)
+                 for width in (heads * 256, heads * (NOPE + DV), 128))
+    zero = jnp.arange(256) < NOPE + ROPE      # the lanes behind the rotary
+    q = (q.reshape(1, t, heads, 256) * zero).reshape(1, t, -1)
+    kr = kr * zero[NOPE:]
+    cot = jnp.asarray(rng.randn(1, t, heads * DV), jnp.float32)
+    scale = (NOPE + ROPE) ** -0.5
+    assert pk.flash.cut_half(512, 512, True) == 256
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        fn = lambda *a: pk.latent_flash(*a, heads, NOPE, scale, block_q=512,
+                                        block_k=512, interpret=True)
+        names = [str(e.params["name"]) for e in _pallas_calls(
+            jax.make_jaxpr(lambda *a: jax.vjp(fn, *a)[1](cot))(
+                q, kv, kr).jaxpr)]
+        assert sorted(names) == ["flash2_bwd_f32_q512_k512_e256",
+                                 "flash2_fwd_f32_q512_k512_e256"]
+        c = telemetry.REGISTRY.get("attention.latent_kernel_traces")
+        assert c.value(**{"pass": "fwd"}, edge=256) == 1
+        assert c.value(**{"pass": "bwd"}, edge=256) == 1
+        assert telemetry.total("attention.latent_kernel_traces") == 2
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    got, got_g = _out_and_grads(fn, (q, kv, kr), cot)
+    want, want_g = _out_and_grads(
+        lambda *a: pk.latent.latent_composed(*a, heads, NOPE, scale),
+        (q, kv, kr), cot)
+    _close(got, want, "out")
+    for name, g, w in zip(("dq", "dkv", "dk_rope"), got_g, want_g):
+        _close(g, w, name, ulps=64)
+
+
 @pytest.mark.parametrize("t,heads", [(128, 4), (384, 1)],
                          ids=["one_tile", "three_tiles"])
 def test_bf16_operands_are_one_rounding_from_the_composition(

@@ -462,6 +462,275 @@ def test_split_backward_is_taken_where_the_rule_says(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# cut tiles by quarters: a square tile that the diagonal or the window's
+# edge cuts corner to corner runs its live quarters (``cut_steps``)
+# ---------------------------------------------------------------------------
+
+# (t, heads, kv heads, d, window) at tiles of 512, scaled down from the
+# cells' 1,024 (``_cut_case`` lowers the floor to quarters of 256 for
+# the test): the diagonal alone; a window of two tiles (Trinity-Mini's: the
+# band's lower edge tile is the diagonal's mirror), of one tile (both of
+# a band's tiles cut) and of half a tile (three cut quarters on the
+# diagonal, one under it); four query heads on one key/value head; a
+# last tile that holds padding keys and falls back to the whole mask
+CUT_CASES = {
+    "full_causal": (1024, 2, 2, 32, 0),
+    "window_of_two_tiles": (2048, 1, 1, 32, 1024),
+    "window_of_a_tile": (1536, 1, 1, 32, 512),
+    "window_of_half_a_tile": (1536, 1, 1, 32, 256),
+    "grouped_heads": (1024, 4, 1, 32, 0),
+    "grouped_heads_window_padded": (1300, 4, 2, 32, 512),
+    "padded_last_tile": (1300, 1, 1, 32, 0),
+}
+
+
+def _cut_case(name, monkeypatch):
+    monkeypatch.setattr(pk.flash, "FLASH_MIN_EDGE", 256)
+    t, h, g, d, window = CUT_CASES[name]
+    rng = np.random.RandomState(sorted(CUT_CASES).index(name))
+    q, k, v, do = (jnp.asarray(rng.randn(*shape), jnp.float32)
+                   for shape in ((1, t, h, d), (1, t, g, d), (1, t, g, d),
+                                 (1, t, h, d)))
+    kw = dict(causal=True, window=window, block_q=512, block_k=512)
+    assert pk.flash.cut_half(512, 512, True, window) == 256
+    return (q, k, v, do), kw
+
+
+def _kernel_names(jaxpr):
+    return sorted(_dots_by_kernel(jaxpr))
+
+
+@pytest.mark.parametrize("name", sorted(CUT_CASES))
+def test_cut_tiles_forward_matches_the_reference_and_the_whole_form(
+        name, monkeypatch):
+    (q, k, v, _), kw = _cut_case(name, monkeypatch)
+    fn = lambda q, k, v: flash_attention(q, k, v, **kw)
+    whole = lambda q, k, v: flash_attention(q, k, v, **kw)
+    window = kw["window"]
+    assert _kernel_names(jax.make_jaxpr(fn)(q, k, v)) == [
+        "flash_fwd_f32_q512_k512%s_e256" % ("_w%d" % window if window
+                                            else "")]
+    got = fn(q, k, v)
+    want = reference_attention(q, k, v, causal=True, window=window)
+    assert _rel(got, want) < 2e-6
+    # the same scores in the same float32 arithmetic: only a row's
+    # online-softmax steps differ
+    monkeypatch.undo()
+    assert pk.flash.FLASH_MIN_EDGE == 512
+    assert _kernel_names(jax.make_jaxpr(whole)(q, k, v)) == [
+        "flash_fwd_f32_q512_k512" + ("_w%d" % window if window else "")]
+    assert _rel(got, whole(q, k, v)) < 2e-6
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["one_pass", "split"])
+@pytest.mark.parametrize("name", sorted(CUT_CASES))
+def test_cut_tiles_backward_matches_the_reference_gradients(
+        name, fused, monkeypatch):
+    (q, k, v, do), kw = _cut_case(name, monkeypatch)
+    monkeypatch.setattr(pk.flash, "bwd_fuses", lambda *a: fused)
+
+    def grads(fn, **kw):
+        out, vjp = jax.vjp(lambda q, k, v: fn(q, k, v, **kw), q, k, v)
+        return vjp(do)
+
+    names = _kernel_names(jax.make_jaxpr(
+        lambda *a: grads(flash_attention, **kw))())
+    assert all(n.endswith("_e256") for n in names), names
+    assert [n.split("_")[1] for n in names] == (
+        ["bwd", "fwd"] if fused else ["dkv", "dq", "fwd"])
+    got = grads(flash_attention, **kw)
+    want = grads(reference_attention, causal=True, window=kw["window"])
+    for which, a, r in zip("dq dk dv".split(), got, want):
+        assert a.shape == r.shape
+        assert _rel(a, r) < 5e-6, (which, _rel(a, r))
+
+
+@pytest.mark.parametrize("window", [0, 2048], ids=["full", "window"])
+def test_the_cells_tiles_run_quarters_of_512_to_the_reference(window):
+    """The floor as it stands: tiles of 1,024 (the cells'), full causal
+    and under Trinity-Mini's window of two tiles, forward and the
+    one-pass backward."""
+    t = 3072 if window else 2048
+    rng = np.random.RandomState(t)
+    q, k, v, do = (jnp.asarray(rng.randn(1, t, 1, 32), jnp.float32)
+                   for _ in range(4))
+    assert flash_tiles(8192, 128, jnp.bfloat16, window) == (1024, 1024)
+
+    def grads(fn, **kw):
+        out, vjp = jax.vjp(lambda q, k, v: fn(q, k, v, **kw), q, k, v)
+        return (out,) + vjp(do)
+
+    kw = dict(causal=True, window=window, block_q=1024, block_k=1024)
+    names = _kernel_names(jax.make_jaxpr(
+        lambda: grads(flash_attention, **kw))())
+    tail = "_f32_q1024_k1024%s_e512" % ("_w2048" if window else "")
+    assert names == ["flash_bwd" + tail, "flash_fwd" + tail]
+    for which, a, r in zip("out dq dk dv".split(),
+                           grads(flash_attention, **kw),
+                           grads(reference_attention, causal=True,
+                                 window=window)):
+        assert _rel(a, r) < 5e-6, (which, _rel(a, r))
+
+
+@pytest.mark.parametrize("by_rows", [False, True],
+                         ids=["quarters", "by_rows"])
+@pytest.mark.parametrize("block", [8, 512, 1024])
+def test_the_classifier_is_the_keep_mask_on_the_quarters_corners(block,
+                                                                 by_rows):
+    """``quarter_states`` and the steps ``cut_steps`` makes of them against
+    ``_keep``'s [T, T] mask, for every window that is 0 or a multiple of
+    half a tile and every tile offset a band can hold: a dead quarter
+    holds no live score, a whole one no dead score, the steps' masks are
+    the mask on their rectangles and together they cover every live
+    score of the tile once; ``by_rows`` (the forward's) a row half's
+    live quarters are one step."""
+    h = block // 2
+    for window in [0] + [n * h for n in range(1, 7)]:
+        offsets = range(window // block + 2) if window else range(2)
+        t = (max(offsets) + 1) * block
+        keep = pk.flash._keep(t, True, window)
+        steps = pk.flash.cut_steps(block, window, by_rows)
+        for offset in offsets:
+            tile = keep[offset * block:(offset + 1) * block, :block]
+            states = pk.flash.quarter_states(block, window, offset)
+            for a in range(2):
+                for b in range(2):
+                    quarter = tile[a * h:(a + 1) * h, b * h:(b + 1) * h]
+                    assert states[a][b] == (
+                        "dead" if not quarter.any() else
+                        "whole" if quarter.all() else "cut"), (
+                            window, offset, a, b)
+            flat = sum(states, [])
+            assert (offset in steps) is (0 < flat.count("dead") < 4)
+            if offset not in steps:
+                continue
+            covered = np.zeros_like(tile, dtype=np.int32)
+            assert len(steps[offset]) == (
+                sum(any(s != "dead" for s in row) for row in states)
+                if by_rows else 4 - flat.count("dead"))
+            for masked, part in steps[offset]:
+                want = tile[part.rows, part.cols]
+                assert masked is (not want.all())
+                covered[part.rows, part.cols] += 1
+                if block > 8 and not masked:
+                    continue        # the mask itself: at the toy size
+                mask = pk.flash.part_mask(*want.shape, part.delta, window)
+                if masked:
+                    np.testing.assert_array_equal(np.asarray(mask), want)
+                else:
+                    assert mask is None
+            np.testing.assert_array_equal(covered > 0, tile | (covered > 0))
+            assert covered.max() == 1 and (covered[tile] == 1).all()
+    assert pk.flash.cut_steps(block, 3 * h).keys() == {0, 2}
+    assert pk.flash.cut_steps(block, 2 * block).keys() == {0, 2}
+
+
+@pytest.mark.parametrize("block_q,block_k,causal,window,half", [
+    (1024, 1024, True, 0, 512), (1024, 1024, True, 2048, 512),
+    (1024, 1024, True, 1536, 512), (1024, 1024, True, 512, 512),
+    (1024, 1024, True, 256, 0), (1024, 1024, True, 1000, 0),
+    (512, 512, True, 0, 0), (512, 512, True, 1024, 0),
+    (256, 256, True, 128, 0), (1024, 512, True, 0, 0),
+    (1024, 1024, False, 0, 0)])
+def test_quarters_are_taken_where_the_shapes_say(block_q, block_k, causal,
+                                                 window, half):
+    assert pk.flash.cut_half(block_q, block_k, causal, window) == half
+
+
+def _pallas_kernels(jaxpr):
+    """(name, kernel jaxpr) of every ``pallas_call`` of a jaxpr, nested
+    ones included, as text."""
+    found = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append((str(eqn.params["name"]),
+                              str(eqn.params["jaxpr"])))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return sorted(found)
+
+
+def test_tiles_under_the_floor_lower_as_they_did(monkeypatch):
+    """MiMo's window call scaled down (tiles of 256 under a window of
+    128, 8 query heads on one): halves of 128 are under the floor, so
+    the kernels keep their names and their bodies are the ones the
+    classifier never touched."""
+    q = jnp.zeros((1, 512, 8, 64), jnp.bfloat16)
+    k = jnp.zeros((1, 512, 1, 64), jnp.bfloat16)
+    assert flash_tiles(4096, 192, jnp.bfloat16, 128) == (256, 256)
+
+    def kernels():
+        for call in (pk.flash.fwd_call, pk.flash.bwd_call):
+            call.clear_cache()      # one trace a signature: trace anew
+        return _pallas_kernels(jax.make_jaxpr(jax.grad(_loss(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, window=128, block_q=256,
+                block_k=256)), argnums=(0, 1, 2)))(q, k, k))
+
+    own = kernels()
+    assert [name for name, _ in own] == ["flash_bwd_bf16_q256_k256_w128",
+                                         "flash_fwd_bf16_q256_k256_w128"]
+    with monkeypatch.context() as m:
+        m.setattr(pk.flash, "cut_steps", None)      # never asked
+        assert kernels() == own
+    # the same call over the floor is another pair of kernels
+    monkeypatch.setattr(pk.flash, "FLASH_MIN_EDGE", 128)
+    assert [name for name, _ in kernels()] == [
+        "flash_bwd_bf16_q256_k256_w128_e128",
+        "flash_fwd_bf16_q256_k256_w128_e128"]
+
+
+def test_a_call_without_padding_traces_no_whole_tile_mask():
+    """Where every masked tile is a cut one (whole tiles of keys, a
+    window that leaves no offset cut without a dead quarter) the
+    whole-tile masked body is not in the kernel: its four iota tables
+    are the quarters'; with padding keys, or under a window of a tile
+    and a half, it stays."""
+    def iotas(t, window):
+        q3 = jnp.zeros((1, -(-t // 1024) * 1024, 32), jnp.float32)
+        (_, body), = _pallas_kernels(jax.make_jaxpr(
+            lambda q3: pk.flash.fwd_call(
+                q3, q3, q3, t_real=t, scale=1.0, causal=True,
+                window=window, block_q=1024, block_k=1024, interpret=True,
+                edge=512))(q3))
+        return body.count(" iota[")
+
+    # two position tables a masked step; the forward steps by row halves
+    assert iotas(2048, 0) == 4          # the diagonal tile's two steps
+    assert iotas(2000, 0) == 6          # ... and the padded tile's mask
+    assert iotas(3072, 2048) == 8       # the diagonal's and the edge's
+    # offset 1 is cut and none of it dead: three steps and the mask
+    assert iotas(3072, 1536) == 6 + 2
+
+
+def test_flash_lowerings_say_which_sites_run_quarters(monkeypatch):
+    monkeypatch.setattr(pk.flash, "FLASH_MIN_EDGE", 256)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        q = jnp.zeros((1, 1024, 1, 32), jnp.float32)
+        jax.make_jaxpr(jax.grad(_loss(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, window=1024, block_q=512, block_k=512)),
+            argnums=(0, 1, 2)))(q, q, q)
+        jax.make_jaxpr(lambda q: flash_attention(
+            q, q, q, block_q=512, block_k=512))(q)      # not causal
+        c = telemetry.REGISTRY.get("attention.flash_lowerings")
+        assert c.value(operands="f32", block_q=512, block_k=512,
+                       window=1024, kv_heads=1, dv=32, edge=256) == 1
+        assert c.value(operands="f32", block_q=512, block_k=512,
+                       window=1024, bwd="fused", edge=256) == 1
+        assert c.value(operands="f32", block_q=512, block_k=512) == 1
+        assert telemetry.total("attention.flash_lowerings") == 3
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+
+
+# ---------------------------------------------------------------------------
 # grouped matmul (the expert layer's products): interpret mode against a
 # per-group dense product
 # ---------------------------------------------------------------------------

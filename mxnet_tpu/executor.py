@@ -123,7 +123,8 @@ _OP_CLASS = {
     "sigmoid": "act", "tanh": "act", "add_n": "act", "clip": "act",
     "MakeLoss": "loss", "softmax_cross_entropy": "loss",
     "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
-    "_contrib_LatentAttention": "attn", "_contrib_Mamba2": "ssm",
+    "_contrib_LatentAttention": "attn", "_contrib_KeyIndexer": "attn",
+    "_contrib_Mamba2": "ssm",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
     "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",

@@ -35,8 +35,13 @@ under per-head norms whose output passes a sigmoid gate, a 2,048-key
 window three to one beside full attention without a rotary embedding,
 four norms a block, a dense SwiGLU then one shared and 128
 sigmoid-routed experts, a muP multiplier on the embedding), whole or as
-a share, with ``afmoe_reference``; ``lm_blocks`` holds what the LM
-symbols share.
+a share, with ``afmoe_reference``. ``dots3`` is dots3-note-prev (latent
+attention with a query latent in two geometries: full layers whose
+``KeyIndexer`` chooses the 2,048 best keys a query, one in four beside
+layers under a 513-key window over a latent of their own, a sigmoid gate
+a head on both, a dense SwiGLU then one shared and 256 sigmoid-routed
+experts), whole or as a share, with ``dots3_reference``; ``lm_blocks``
+holds what the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -50,8 +55,8 @@ from .resnext import get_symbol as resnext
 from .vgg import get_symbol as vgg
 from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
-from . import (afmoe, afmoe_reference, falcon_h1, falcon_h1_reference,
-               kanana2, kanana2_reference, kimi_linear, kimi_linear_reference,
-               lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
+from . import (afmoe, afmoe_reference, dots3, dots3_reference, falcon_h1,
+               falcon_h1_reference, kanana2, kanana2_reference, kimi_linear,
+               kimi_linear_reference, lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
                nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
                olmoe, olmoe_reference)

@@ -129,9 +129,16 @@ def _latent_scores(q_ref, k_blk, qi, ki, masked, part, *, block_q, block_k,
                               lax.ge(q_pos, k_pos))
 
 
+def _kept(keep_ref, mask):
+    """The keep-mask's tile (int8, 0 drops the pair) and the positions'
+    own ``mask`` (None off the diagonal): a pair is live under both."""
+    kept = lax.ne(keep_ref[...].astype(jnp.int32), np.int32(0))
+    return kept if mask is None else lax.bitwise_and(mask, kept)
+
+
 def _latent_fwd_kernel(q_ref, kv_ref, kr_ref, o_ref, l_ref, acc, m_s, l_s,
                        *, nope, block_q, block_k, t_real, t_pad, scale,
-                       edge):
+                       edge, keep_ref=None):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -148,12 +155,18 @@ def _latent_fwd_kernel(q_ref, kv_ref, kr_ref, o_ref, l_ref, acc, m_s, l_s,
             q_ref, _latent_key(kv_ref, kr_ref, nope, cols), qi, ki, masked,
             part, block_q=block_q, block_k=block_k, t_real=t_real,
             scale=scale)
-        if masked:
+        if keep_ref is not None:
+            mask = _kept(keep_ref, mask)
+        if mask is not None:
             s = jnp.where(mask, s, jnp.float32(NEG_INF))
         m_prev = m_s[rows]
         m_cur = lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = lax.exp(lax.sub(m_prev, m_cur))
         p = lax.exp(lax.sub(s, m_cur))
+        if keep_ref is not None:
+            # a row may keep no key of a tile before its first kept one:
+            # its maximum is still NEG_INF there and exp(0) is no weight
+            p = jnp.where(mask, p, jnp.float32(0.0))
         l_s[rows] = lax.add(lax.mul(l_s[rows], alpha),
                             jnp.sum(p, axis=1, keepdims=True))
         m_s[rows] = m_cur
@@ -175,7 +188,7 @@ def _latent_fwd_kernel(q_ref, kv_ref, kr_ref, o_ref, l_ref, acc, m_s, l_s,
 def _latent_bwd_kernel(q_ref, kv_ref, kr_ref, o_ref, do_ref, l_ref, dq_ref,
                        dkv_ref, dkr_ref, delta, dq_acc, dk_acc, dv_acc,
                        dkr_acc, *, nope, block_q, block_k, t_real, t_pad,
-                       scale, edge):
+                       scale, edge, keep_ref=None):
     """dq, dK_nope | dV and dK_rope in one pass, as ``_bwd_fused_kernel``
     makes dq, dk and dv: a row's k tiles from the diagonal down, dq in
     tile-sized scratch over the inner steps, a head's dK_nope and dV in
@@ -229,7 +242,9 @@ def _latent_bwd_kernel(q_ref, kv_ref, kr_ref, o_ref, do_ref, l_ref, dq_ref,
             q_ref, k_blk, qi, ki, masked, part, block_q=block_q,
             block_k=block_k, t_real=t_real, scale=scale)
         p = lax.exp(lax.sub(s, l_ref[rows]))
-        if masked:
+        if keep_ref is not None:
+            mask = _kept(keep_ref, mask)
+        if mask is not None:
             p = jnp.where(mask, p, jnp.float32(0.0))
         dp = _f32_dot(do, kv_ref[cols, nope:], _NT)
         ds = lax.mul(p, lax.sub(dp, delta[rows])).astype(q_ref.dtype)
@@ -263,26 +278,46 @@ def _latent_bwd_kernel(q_ref, kv_ref, kr_ref, o_ref, do_ref, l_ref, dq_ref,
         each_k_tile(write)
 
 
-def _latent_name(which, dtype, block_q, block_k, edge):
-    return "flash2_%s_%s_q%d_k%d%s" % (
-        which, operand_label(dtype), block_q, block_k,
-        "_e%d" % edge if edge else "")
+def _select_fwd_kernel(q_ref, kv_ref, kr_ref, keep_ref, *refs, **static):
+    """``_latent_fwd_kernel`` whose every live tile is also masked by the
+    keep-mask's tile: the selected variant's operand order."""
+    _latent_fwd_kernel(q_ref, kv_ref, kr_ref, *refs, keep_ref=keep_ref,
+                       **static)
 
 
-def _count_trace(which, edge):
+def _select_bwd_kernel(q_ref, kv_ref, kr_ref, keep_ref, *refs, **static):
+    _latent_bwd_kernel(q_ref, kv_ref, kr_ref, *refs, keep_ref=keep_ref,
+                       **static)
+
+
+def _latent_name(which, dtype, block_q, block_k, edge, select=False):
+    return "flash2%s_%s_%s_q%d_k%d%s" % (
+        "sel" if select else "", which, operand_label(dtype), block_q,
+        block_k, "_e%d" % edge if edge else "")
+
+
+def _count_trace(which, edge, select=False):
     _M_LATENT_TRACES.inc(**{"pass": which},
-                         **({"edge": edge} if edge else {}))
+                         **({"edge": edge} if edge else {}),
+                         **({"select": 1} if select else {}))
 
 
-def _latent_specs(block_q, block_k, width, kv_width, rope, dv, steps=0):
-    """Block specs of (q and dq, kv, k_rope, o and dO, lse) at
-    grid step (batch, head, q tile, k step); with ``steps`` the inner
-    steps walk a row's k tiles downwards. A dead step names the row's
-    last live tile, already resident."""
+def _k_tile(block_q, block_k, steps=0):
+    """(q tile, inner step) -> the k tile that step reads; with ``steps``
+    the inner steps walk a row's k tiles downwards. A dead step names
+    the row's last live tile, already resident."""
     def k_tile(i, j):
         if steps:
             j = lax.sub(np.int32(steps - 1), j)
         return lax.min(j, flash.last_live_k(i, block_q, block_k))
+    return k_tile
+
+
+def _latent_specs(block_q, block_k, width, kv_width, rope, dv, steps=0):
+    """Block specs of (q and dq, kv, k_rope, o and dO, lse) at
+    grid step (batch, head, q tile, k step); ``steps`` as ``_k_tile``
+    takes it."""
+    k_tile = _k_tile(block_q, block_k, steps)
 
     def by_row(width):
         return pl.BlockSpec((None, block_q, width),
@@ -298,16 +333,35 @@ def _latent_specs(block_q, block_k, width, kv_width, rope, dv, steps=0):
                          lambda b, h, i, j: (b, h, i, 0)))
 
 
+def _keep_specs(keep, block_q, block_k, steps=0):
+    """[the block spec of the keep-mask's tile of a pair, every head's]
+    for a selected call, [] for one that selects nothing."""
+    if keep is None:
+        return []
+    k_tile = _k_tile(block_q, block_k, steps)
+    return [pl.BlockSpec((None, block_q, block_k),
+                         lambda b, h, i, j: (b, i, k_tile(i, j)))]
+
+
 _LATENT_STATIC = ("heads", "nope", "t_real", "scale", "block_q", "block_k",
                   "edge", "interpret")
 
 
+def _keep_vmem_bytes(block_q, block_k):
+    """What a selected call holds in VMEM on top of
+    ``flash.flash_vmem_bytes``: the keep-mask's two int8 tile buffers and
+    the tile widened to 32 bits."""
+    return 6 * block_q * block_k
+
+
 @functools.partial(jax.jit, static_argnames=_LATENT_STATIC)
-def latent_fwd_call(q, kv, kr, *, heads, nope, t_real, scale, block_q,
-                    block_k, edge, interpret):
-    """q [B, T, H (N + Rp)], kv [B, T, H (N + Dv)], kr [B, T, Rp] -> o
-    [B, T, H Dv] and lse [B, H, T, 1] float32."""
-    _count_trace("fwd", edge)
+def latent_fwd_call(q, kv, kr, keep=None, *, heads, nope, t_real, scale,
+                    block_q, block_k, edge, interpret):
+    """q [B, T, H (N + Rp)], kv [B, T, H (N + Dv)], kr [B, T, Rp] and,
+    for the selected variant, keep [B, T, T] int8 -> o [B, T, H Dv] and
+    lse [B, H, T, 1] float32."""
+    select = keep is not None
+    _count_trace("fwd", edge, select)
     b, t_pad, _ = q.shape
     width, kv_width, rope = (x.shape[2] // n for x, n in (
         (q, heads), (kv, heads), (kr, 1)))
@@ -317,11 +371,13 @@ def latent_fwd_call(q, kv, kr, *, heads, nope, t_real, scale, block_q,
     with no_x64():
         return pl.pallas_call(
             functools.partial(
-                _latent_fwd_kernel, nope=nope, block_q=block_q,
+                _select_fwd_kernel if select else _latent_fwd_kernel,
+                nope=nope, block_q=block_q,
                 block_k=block_k, t_real=t_real, t_pad=t_pad, scale=scale,
                 edge=edge),
             grid=(b, heads, t_pad // block_q, t_pad // block_k),
-            in_specs=[q_spec, kv_spec, kr_spec],
+            in_specs=[q_spec, kv_spec, kr_spec] + _keep_specs(
+                keep, block_q, block_k),
             out_specs=[o_spec, row_spec],
             out_shape=[
                 jax.ShapeDtypeStruct((b, t_pad, heads * dv), q.dtype),
@@ -331,17 +387,23 @@ def latent_fwd_call(q, kv, kr, *, heads, nope, t_real, scale, block_q,
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32)],
             compiler_params=pltpu.CompilerParams(dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary")),
-            name=_latent_name("fwd", q.dtype, block_q, block_k, edge),
+                "parallel", "parallel", "parallel", "arbitrary"), **(
+                    {"vmem_limit_bytes": flash.flash_vmem_bytes(
+                        block_q, block_k, max(width, dv), q.dtype.itemsize)
+                        + _keep_vmem_bytes(block_q, block_k)}
+                    if select else {})),
+            name=_latent_name("fwd", q.dtype, block_q, block_k, edge,
+                              select),
             interpret=interpret,
-        )(q, kv, kr)
+        )(q, kv, kr, *([keep] if select else []))
 
 
 @functools.partial(jax.jit, static_argnames=_LATENT_STATIC)
-def latent_bwd_call(q, kv, kr, out, do, lse, *, heads, nope, t_real,
-                    scale, block_q, block_k, edge, interpret):
+def latent_bwd_call(q, kv, kr, out, do, lse, keep=None, *, heads, nope,
+                    t_real, scale, block_q, block_k, edge, interpret):
     """-> dq, dkv and dkr, shaped and typed as q, kv and kr."""
-    _count_trace("bwd", edge)
+    select = keep is not None
+    _count_trace("bwd", edge, select)
     b, t_pad, _ = q.shape
     width, kv_width, rope = (x.shape[2] // n for x, n in (
         (q, heads), (kv, heads), (kr, 1)))
@@ -352,11 +414,14 @@ def latent_bwd_call(q, kv, kr, out, do, lse, *, heads, nope, t_real,
     with no_x64():
         dq, dkv, dkr = pl.pallas_call(
             functools.partial(
-                _latent_bwd_kernel, nope=nope, block_q=block_q,
+                _select_bwd_kernel if select else _latent_bwd_kernel,
+                nope=nope, block_q=block_q,
                 block_k=block_k, t_real=t_real, t_pad=t_pad, scale=scale,
                 edge=edge),
             grid=(b, heads, t_pad // block_q, nk),
-            in_specs=[q_spec, kv_spec, kr_spec, o_spec, o_spec, row_spec],
+            in_specs=[q_spec, kv_spec, kr_spec] + _keep_specs(
+                keep, block_q, block_k, steps=nk) + [o_spec, o_spec,
+                                                    row_spec],
             out_specs=[
                 q_spec,
                 pl.BlockSpec((None, nk, block_k, kv_width),
@@ -381,93 +446,118 @@ def latent_bwd_call(q, kv, kr, out, do, lse, *, heads, nope, t_real,
                                      "arbitrary"),
                 vmem_limit_bytes=flash.flash_vmem_bytes(
                     block_q, block_k, max(width, dv), q.dtype.itemsize,
-                    resident=(t_pad, width, dv))),
-            name=_latent_name("bwd", q.dtype, block_q, block_k, edge),
+                    resident=(t_pad, width, dv))
+                + (_keep_vmem_bytes(block_q, block_k) if select else 0)),
+            name=_latent_name("bwd", q.dtype, block_q, block_k, edge,
+                              select),
             interpret=interpret,
-        )(q, kv, kr, out, do, lse)
+        )(q, kv, kr, *([keep] if select else []), out, do, lse)
     return dq, dkv.reshape(kv.shape), dkr.reshape(kr.shape)
 
 
-def latent_composed(q, kv, kr, heads, nope, scale):
+def kept_attention(q, k, v, keep, scale):
+    """``flash.reference_attention``'s causal arithmetic under a keep-mask
+    besides: q, k [B, T, H, D], v [B, T, H, Dv], keep [B, T, T] (0 drops
+    the pair) -> [B, T, H, Dv]. Materialised float32 scores; a row that
+    keeps no key gives zeros."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    live = jnp.logical_and(flash._keep(q.shape[1], True, 0)[None],
+                           keep != 0)[:, None]
+    s = jnp.where(live, s, NEG_INF)
+    p = jnp.where(live, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True),
+                        np.float32(1e-30))
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(
+        q.dtype)
+
+
+def latent_composed(q, kv, kr, heads, nope, scale, keep=None):
     """``flash.reference_attention`` over the concatenated key on the kernels'
-    operands, o as they give it: the branch for every platform but the
-    TPU, and what the kernels' tests hold them to."""
+    operands (``kept_attention`` under a keep-mask), o as they give it:
+    the branch for every platform but the TPU, and what the kernels'
+    tests hold them to."""
     b, t, _ = q.shape
     kv = kv.reshape(b, t, heads, -1)
     k = jnp.concatenate(
         [kv[..., :nope],
          jnp.broadcast_to(kr[:, :, None, :], (b, t, heads, kr.shape[2]))],
         axis=-1)
-    out = flash.reference_attention(
-        q.reshape(b, t, heads, -1), k, kv[..., nope:], causal=True,
-        scale=scale)
+    q = q.reshape(b, t, heads, -1)
+    if keep is None:
+        out = flash.reference_attention(q, k, kv[..., nope:], causal=True,
+                                        scale=scale)
+    else:
+        out = kept_attention(q, k, kv[..., nope:], keep, scale)
     return out.reshape(b, t, -1)
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _latent(q, kv, kr, heads, nope, t_real, scale, block_q, block_k, edge,
-            interpret):
-    return _latent_fwd(q, kv, kr, heads, nope, t_real, scale, block_q,
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11))
+def _latent(q, kv, kr, keep, heads, nope, t_real, scale, block_q, block_k,
+            edge, interpret):
+    return _latent_fwd(q, kv, kr, keep, heads, nope, t_real, scale, block_q,
                        block_k, edge, interpret)[0]
 
 
-def _latent_fwd(q, kv, kr, heads, nope, t_real, scale, block_q, block_k,
-                edge, interpret):
+def _latent_fwd(q, kv, kr, keep, heads, nope, t_real, scale, block_q,
+                block_k, edge, interpret):
     # one trace of the forward for the primal and the rule: see _ssd_fwd
     with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
         out, lse = latent_forward(
-            q, kv, kr, heads=heads, nope=nope, t_real=t_real, scale=scale,
-            block_q=block_q, block_k=block_k, edge=edge,
+            q, kv, kr, keep, heads=heads, nope=nope, t_real=t_real,
+            scale=scale, block_q=block_q, block_k=block_k, edge=edge,
             interpret=interpret)
-    return out, (q, kv, kr, out, lse)
+    return out, (q, kv, kr, out, lse, keep)
 
 
 @functools.partial(jax.jit, static_argnames=_LATENT_STATIC)
-def latent_forward(q, kv, kr, *, interpret, **call):
+def latent_forward(q, kv, kr, keep=None, *, interpret, **call):
     """o and lse (zeros off the TPU, where the composed form's own
-    transpose is the backward)."""
-    def kernels(q, kv, kr, interpret):
-        return latent_fwd_call(q, kv, kr, interpret=interpret, **call)
+    transpose is the backward); ``keep`` [B, T, T] int8 or None (a pair
+    that selects no keys: today's kernels under today's names)."""
+    def kernels(q, kv, kr, keep, interpret):
+        return latent_fwd_call(q, kv, kr, keep, interpret=interpret, **call)
 
-    def plain(q, kv, kr):
+    def plain(q, kv, kr, keep):
         return (latent_composed(q, kv, kr, call["heads"], call["nope"],
-                                call["scale"]),
+                                call["scale"], keep),
                 jnp.zeros((q.shape[0], call["heads"], q.shape[1], 1),
                           jnp.float32))
 
-    return on_tpu(kernels, plain, interpret, q, kv, kr)
+    return on_tpu(kernels, plain, interpret, q, kv, kr, keep)
 
 
 @functools.partial(jax.jit, static_argnames=_LATENT_STATIC)
-def latent_backward(q, kv, kr, out, lse, g, *, interpret, **call):
+def latent_backward(q, kv, kr, out, lse, keep, g, *, interpret, **call):
     heads = call["heads"]
 
-    def kernels(q, kv, kr, out, lse, g, interpret):
-        return latent_bwd_call(q, kv, kr, out, g.astype(q.dtype), lse,
+    def kernels(q, kv, kr, out, lse, keep, g, interpret):
+        return latent_bwd_call(q, kv, kr, out, g.astype(q.dtype), lse, keep,
                                interpret=interpret, **call)
 
-    def plain(q, kv, kr, out, lse, g):
+    def plain(q, kv, kr, out, lse, keep, g):
         return jax.vjp(
             lambda *ins: latent_composed(*ins, heads, call["nope"],
-                                         call["scale"]),
+                                         call["scale"], keep),
             q, kv, kr)[1](g)
 
-    return on_tpu(kernels, plain, interpret, q, kv, kr, out, lse, g)
+    return on_tpu(kernels, plain, interpret, q, kv, kr, out, lse, keep, g)
 
 
 def _latent_bwd(heads, nope, t_real, scale, block_q, block_k, edge,
                 interpret, res, g):
+    # the keep-mask is data, not a weight: no cotangent
     return latent_backward(
         *res, g, heads=heads, nope=nope, t_real=t_real, scale=scale,
-        block_q=block_q, block_k=block_k, edge=edge, interpret=interpret)
+        block_q=block_q, block_k=block_k, edge=edge,
+        interpret=interpret) + (None,)
 
 
 _latent.defvjp(_latent_fwd, _latent_bwd)
 
 
 def latent_flash(q, kv, k_rope, heads, nope, scale, block_q=None,
-                 block_k=None, interpret=False):
+                 block_k=None, interpret=False, keep=None):
     """Causal latent attention as a Pallas kernel pair, for the shapes
     ``latent_flash_takes`` admits. Every operand token-major, a head a
     block of columns: q [B, T, H (N + Rp)] (a head's N un-rotated
@@ -485,7 +575,15 @@ def latent_flash(q, kv, k_rope, heads, nope, scale, block_q=None,
     the ``custom_vjp`` as ``ssd_scan`` makes it; ``interpret=True`` (the
     kernels' tests) runs the kernels through the Pallas interpreter
     wherever the computation is lowered. No partitioning rule: inside a
-    sharded ``jit``, call under ``shard_map``."""
+    sharded ``jit``, call under ``shard_map``.
+
+    ``keep`` [B, T, T] (any integer or bool type; read as int8, 0 drops
+    the pair): attention that chooses its keys. Row t's softmax runs over
+    the keys s <= t with ``keep[b, t, s] != 0`` only; the mask is every
+    head's, carries no gradient, and is one more tile operand of a pair
+    named ``flash2sel_*`` (every live tile masked, none by quarters: a
+    selection of half a row's keys leaves no tile to skip). Without it
+    the call lowers to the ``flash2_*`` pair as it always did."""
     t = q.shape[1]
     if block_q is None or block_k is None:
         auto_q, auto_k = flash.flash_tiles(
@@ -494,9 +592,12 @@ def latent_flash(q, kv, k_rope, heads, nope, scale, block_q=None,
         block_q, block_k = block_q or auto_q, block_k or auto_k
     mult = int(np.lcm(block_q, block_k))
     q, kv, k_rope = (pad_to(x, 1, mult)[0] for x in (q, kv, k_rope))
-    out = _latent(q, kv, k_rope, int(heads), int(nope), t, float(scale),
-                  int(block_q), int(block_k),
-                  flash.cut_half(int(block_q), int(block_k), True),
+    edge = flash.cut_half(int(block_q), int(block_k), True)
+    if keep is not None:
+        keep = pad_to(pad_to(keep.astype(jnp.int8), 1, mult)[0], 2, mult)[0]
+        edge = 0
+    out = _latent(q, kv, k_rope, keep, int(heads), int(nope), t,
+                  float(scale), int(block_q), int(block_k), edge,
                   bool(interpret))
     return out[:, :t]
 

@@ -1,6 +1,6 @@
 """Transformer-block operators for the Symbol API: RMSNorm, RoPE,
-Attention, LatentAttention, Mamba2, TopKMoE, GatedDeltaNet, ShortConv and
-ScaledSum.
+Attention, LatentAttention, KeyIndexer, Mamba2, TopKMoE, GatedDeltaNet,
+ShortConv and ScaledSum.
 
 Beyond-reference capability (the 2017 operator set has no attention and
 no sparse-expert layer): what a decoder-only LM with sparse experts
@@ -10,7 +10,8 @@ no sparse-expert layer): what a decoder-only LM with sparse experts
 attention dispatch ``ops/kernels.attention`` (flash kernel on the
 TPU at T >= 128, the materialised reference elsewhere;
 ``LatentAttention`` projects its keys and values up from a latent first,
-for its own flash pair or the same dispatch), ``TopKMoE`` over
+for its own flash pair or the same dispatch, over every causal key, a
+window of them or the keys ``KeyIndexer``'s mask keeps), ``TopKMoE`` over
 ``parallel/moe.topk_moe``; ``Mamba2`` (a state-space mixer's core: the
 convolution, the chunked scan and the gated norm) is ``jax.numpy`` here,
 with no kernel behind it. Exported as ``mx.contrib.sym`` /
@@ -178,14 +179,15 @@ _M_GATED_LOWERINGS = _tm.counter(
 
 def gate_output(out, gate):
     """``out * sigmoid(gate)``, an element each (a gate per head and
-    channel): the sigmoid and the product float32, one rounding to
-    ``out``'s dtype."""
+    channel) or broadcast (``LatentAttention``'s one gate a head over the
+    head's columns): the sigmoid and the product float32, one rounding
+    to ``out``'s dtype."""
     return (out.astype(jnp.float32)
             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(out.dtype)
 
 
-def _optional_inputs(attrs):
-    return [name for name in ("sink", "gate")
+def _optional_inputs(attrs, names=("sink", "gate")):
+    return [name for name in names
             if bool((attrs or {}).get("with_" + name, False))]
 
 
@@ -262,11 +264,16 @@ _M_LATENT_LOWERINGS = _tm.counter(
     "attention.latent_lowerings", "Traces of a LatentAttention call site "
     "(one per lowering, nothing per step); labels: heads, latent (the "
     "width keys and values are projected up from), rope (the rotary key "
-    "every head shares), nope (a head's own key), dv, impl (see below)")
+    "every head shares), nope (a head's own key), dv, impl (see below) "
+    "and, where set, rotary=0, window (the band's keys), select=1 (a "
+    "keep-mask chooses the keys), gate=headwise, query_latent (the width "
+    "the caller projected the query up from)")
 
 
 def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
-                     v_head_dim, theta, eps, interleave=True, rotary=True):
+                     v_head_dim, theta, eps, interleave=True, rotary=True,
+                     window=0, latent_scale=1.0, gate=None, keep=None,
+                     query_latent=0):
     """query [B, T, H * (N + R)] (a head's N un-rotated dimensions, then
     its R rotary ones), latent [B, T, L + R] (the compressed key/value
     latent, then the one rotary key a token), gamma [L], up_weight
@@ -287,39 +294,78 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
     attention dispatch). ``rotary=False`` (NoPE latent attention): neither
     the query's R last dimensions nor the shared key is rotated, ``theta``
     and ``interleave`` are read by nothing; the same two forms on the same
-    shapes (the kernels never rotated), counted with ``rotary=0``."""
+    shapes (the kernels never rotated), counted with ``rotary=0``.
+
+    ``latent_scale``: a fixed scalar on the normed latent (the product
+    float32, one rounding), the rotary key unscaled. ``window`` > 0: row t
+    sees the keys t - window + 1 .. t (``Attention``'s convention), through
+    the composed form whatever the shapes (the pair has no band), under the
+    scope ``window``. ``keep`` [B, T, T] (0 drops the pair; what
+    ``KeyIndexer`` gives): row t's softmax runs over its kept keys s <= t
+    only, the mask every head's and without a gradient; ``kernel`` is then
+    the pair's selected variant (``flash2sel_*``, ``latent_flash(keep=)``),
+    ``composed`` the materialised ``kernels.latent.kept_attention``, both
+    under the scope ``select``. ``gate`` [B, T, H]: head h's output times
+    ``sigmoid(gate[.., h])``, float32, one rounding (scope ``gate``)."""
     from .kernels import latent_flash_takes
 
     width = latent.shape[2] - rope_dim
     nope = query.shape[2] // num_heads - rope_dim
-    kernel = latent_flash_takes(query.shape[1], nope, rope_dim, v_head_dim,
-                                query.dtype)
-    _M_LATENT_LOWERINGS.inc(heads=num_heads, latent=width, rope=rope_dim,
-                            nope=nope, dv=v_head_dim,
-                            impl="kernel" if kernel else "composed",
-                            **({} if rotary else {"rotary": 0}))
+    kernel = not window and latent_flash_takes(
+        query.shape[1], nope, rope_dim, v_head_dim, query.dtype)
+    _M_LATENT_LOWERINGS.inc(
+        heads=num_heads, latent=width, rope=rope_dim, nope=nope,
+        dv=v_head_dim, impl="kernel" if kernel else "composed",
+        **({} if rotary else {"rotary": 0}),
+        **({"window": window} if window else {}),
+        **({} if keep is None else {"select": 1}),
+        **({} if gate is None else {"gate": "headwise"}),
+        **({"query_latent": query_latent} if query_latent else {}))
     with jax.named_scope("latent"):
         c = rms_norm(latent[..., :width], gamma, eps)
+        if latent_scale != 1:
+            c = (c.astype(jnp.float32)
+                 * np.float32(latent_scale)).astype(c.dtype)
         kv = jax.lax.dot_general(
             c, up_weight.astype(c.dtype), (((2,), (1,)), ((), ())),
             preferred_element_type=jnp.float32).astype(c.dtype)
         k_rope = latent[..., width:]
         if rotary:
             k_rope = rope(k_rope, 1, theta, rope_dim, 0, interleave)
-    path = _latent_kernel_path if kernel else _latent_composed_path
-    return path(query, kv, k_rope, num_heads, v_head_dim, theta, interleave,
-                rotary)
+    if keep is not None:
+        keep = jax.lax.stop_gradient(keep)
+    if kernel:
+        out = _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim,
+                                  theta, interleave, rotary, keep=keep)
+    else:
+        out = _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim,
+                                    theta, interleave, rotary, window=window,
+                                    keep=keep)
+    if gate is None:
+        return out
+    with jax.named_scope("gate"):
+        b, t, _ = out.shape
+        return gate_output(out.reshape(b, t, num_heads, v_head_dim),
+                           gate[..., None]).reshape(b, t, -1)
+
+
+_LATENT_OPTIONAL = ("gate", "keep")
 
 
 def _latent_attention(attrs, ins, is_train):
+    optional = dict(zip(_optional_inputs(attrs, _LATENT_OPTIONAL),
+                        ins[4:]))
     return [latent_attention(
-        *ins, num_heads=int(attrs["num_heads"]),
+        *ins[:4], num_heads=int(attrs["num_heads"]),
         rope_dim=int(attrs["rope_dim"]),
         v_head_dim=int(attrs["v_head_dim"]),
         theta=float(attrs.get("theta", 10000.0)),
         eps=float(attrs.get("eps", 1e-6)),
         interleave=bool(attrs.get("interleave", True)),
-        rotary=bool(attrs.get("rotary", True)))]
+        rotary=bool(attrs.get("rotary", True)),
+        window=int(attrs.get("window", 0)),
+        latent_scale=float(attrs.get("latent_scale", 1.0)),
+        query_latent=int(attrs.get("query_latent", 0)), **optional)]
 
 
 def _latent_attention_infer(attrs, in_shapes):
@@ -336,22 +382,50 @@ def _latent_attention_infer(attrs, in_shapes):
             "LatentAttention: latent %s must be query's [batch, time] %s "
             "by the latent width + rope_dim=%d" % (latent, q[:2], r))
     width = latent[2] - r
-    return ([q, latent, (width,), (heads * (d - r + dv), width)],
+    window = int(attrs.get("window", 0))
+    if window < 0 or (window and bool(attrs.get("with_keep", False))):
+        raise ValueError(
+            "LatentAttention: window=%d must be >= 0, and a window beside "
+            "a keep-mask is not implemented" % window)
+    optional = {"gate": q[:2] + (heads,), "keep": q[:2] + (q[1],)}
+    return ([q, latent, (width,), (heads * (d - r + dv), width)]
+            + [optional[name]
+               for name in _optional_inputs(attrs, _LATENT_OPTIONAL)],
             [q[:2] + (heads * dv,)], [])
 
 
-register(
-    OpDef(
-        "_contrib_LatentAttention",
-        _latent_attention,
-        arguments=("query", "latent", "latent_gamma", "up_weight"),
-        defaults={"num_heads": 1, "rope_dim": 0, "v_head_dim": 0,
-                  "theta": 10000.0, "eps": 1e-6, "interleave": True,
-                  "rotary": True},
-        infer_shape=_latent_attention_infer,
-        aliases=("LatentAttention",),
-    )
+def _latent_attention_infer_type(attrs, in_types):
+    """The keep-mask has a type of its own (int8, ``KeyIndexer``'s);
+    every other input and the output share the query's."""
+    names = ["query", "latent", "latent_gamma", "up_weight"
+             ] + _optional_inputs(attrs, _LATENT_OPTIONAL)
+    known = [t for name, t in zip(names, in_types)
+             if t is not None and name != "keep"]
+    if not known:
+        raise MXNetError("LatentAttention: cannot infer type")
+    t = known[0]
+    return ([np.int8 if name == "keep" and x is None
+             else t if x is None else x
+             for name, x in zip(names, in_types)], [t], [])
+
+
+_latent_op = OpDef(
+    "_contrib_LatentAttention",
+    _latent_attention,
+    arguments=("query", "latent", "latent_gamma", "up_weight", "gate",
+               "keep"),
+    defaults={"num_heads": 1, "rope_dim": 0, "v_head_dim": 0,
+              "theta": 10000.0, "eps": 1e-6, "interleave": True,
+              "rotary": True, "window": 0, "latent_scale": 1.0,
+              "query_latent": 0, "with_gate": False, "with_keep": False},
+    infer_shape=_latent_attention_infer,
+    infer_type=_latent_attention_infer_type,
+    aliases=("LatentAttention",),
 )
+_latent_op.list_arguments = lambda attrs=None: (
+    ["query", "latent", "latent_gamma", "up_weight"]
+    + _optional_inputs(attrs, _LATENT_OPTIONAL))
+register(_latent_op)
 
 
 # --------------------------------------------------------------------------
@@ -1134,13 +1208,15 @@ register(
 # chooses; down here so that no line above moves: see GatedDeltaNet's note)
 # --------------------------------------------------------------------------
 def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
-                          interleave, rotary=True):
+                          interleave, rotary=True, window=0, keep=None):
     """Every head's key materialised: the rotation over the whole query,
     the shared rotary key broadcast and concatenated behind each head's
     slice of ``kv`` [B, T, H (N + Dv)], the values sliced out of it, and
     ``kernels.attention`` (the flash kernel on the TPU at T >= 128,
-    the materialised reference elsewhere)."""
+    the materialised reference elsewhere; its band under ``window``), or
+    under ``keep`` the materialised ``kernels.latent.kept_attention``."""
     from .kernels import attention
+    from .kernels.latent import kept_attention
 
     b, t, _ = query.shape
     rope_dim = k_rope.shape[2]
@@ -1153,14 +1229,20 @@ def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
             [kv[..., :nope], jnp.broadcast_to(
                 k_rope[:, :, None, :], (b, t, num_heads, rope_dim))],
             axis=-1)
-    with jax.named_scope("full"):
-        out = attention(q.reshape(b, t, num_heads, nope + rope_dim), k,
-                        kv[..., nope:], causal=True)
+    q = q.reshape(b, t, num_heads, nope + rope_dim)
+    if keep is not None:
+        with jax.named_scope("select"):
+            out = kept_attention(q, k, kv[..., nope:], keep,
+                                 (nope + rope_dim) ** -0.5)
+    else:
+        with jax.named_scope("window" if window else "full"):
+            out = attention(q, k, kv[..., nope:], causal=True,
+                            window=window)
     return out.reshape(b, t, num_heads * v_head_dim)
 
 
 def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
-                        interleave, rotary=True):
+                        interleave, rotary=True, keep=None):
     """Nothing of [T, H, N + R] built for the keys: one pass over the
     query (``_kernel_query``) and ``kernels.latent_flash`` on
     ``kv`` and ``k_rope`` where the up-projection and the rotation left
@@ -1179,9 +1261,10 @@ def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
                         ((0, 0),) * 3 + ((0, -rope_dim % 128),)).reshape(
                             query.shape[:2] + (-1,))
         k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, -rope_dim % 128)))
-    with jax.named_scope("full"):
+    with jax.named_scope("full" if keep is None else "select"):
         return latent_flash(q, kv, k_rope, num_heads, width - rope_dim,
-                            scale=width ** -0.5, interpret=interpret)
+                            scale=width ** -0.5, interpret=interpret,
+                            keep=keep)
 
 
 def _rotated_lanes(x, rope_dim, theta, interleave):
@@ -1560,3 +1643,188 @@ def _channel_delta_block(query, key, value, gate, a, b, conv_weight, a_log,
                                           form="token_major", groups=heads,
                                           act=gate_act, interpret=interpret)
         return again(gate_norm)(o, gate, norm_gamma)
+
+
+# --------------------------------------------------------------------------
+# KeyIndexer — attention that chooses its keys: a light many-head scorer
+# over one key a token and an exact top-k a query row, as a keep-mask for
+# ``LatentAttention(with_keep=True)`` (DeepSeek-V3.2-Exp's lightning indexer;
+# down here so that no line above moves)
+# --------------------------------------------------------------------------
+_M_INDEX_LOWERINGS = _tm.counter(
+    "attention.index_lowerings", "Traces of a KeyIndexer call site (one per "
+    "lowering, nothing per step); labels: heads, width (a head's and the "
+    "one key's), topk, rows (query rows a block of the scores), impl (jnp: "
+    "blocked jax.numpy scores and a bisection on the scores' bits, on "
+    "every platform)")
+
+INDEX_BLOCK_ROWS = 256   # query rows a block of [heads, rows, keys] scores
+
+
+def _sortable_bits(x):
+    """float32 -> uint32 of the same order (negative values reversed
+    under the others, -0.0 under +0.0)."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jnp.where(bits >> np.uint32(31) == 0, bits | np.uint32(1 << 31),
+                     ~bits)
+
+
+def keep_top_k(scores, k):
+    """scores [..., T, S] (read as float32) -> bool of the same shape: in
+    each row its ``k`` largest entries (all of them where S <= k), ties to the
+    lower index: ``jax.lax.top_k``'s choice without its sort. The k-th
+    largest value of a row is found bit by bit (32 counting passes over
+    the scores' order-preserving bits); only a row that holds its k-th
+    value more than once pays the running count that breaks the tie."""
+    if scores.shape[-1] <= k:
+        return jnp.ones(scores.shape, bool)
+    u = _sortable_bits(scores.astype(jnp.float32))
+
+    def count(mask):
+        return jnp.sum(mask, axis=-1, keepdims=True, dtype=jnp.int32)
+
+    def step(i, cur):
+        cand = cur | (np.uint32(1 << 31) >> i.astype(jnp.uint32))
+        return jnp.where(count(u >= cand) >= k, cand, cur)
+
+    kth = jax.lax.fori_loop(0, 32, step,
+                            jnp.zeros(u.shape[:-1] + (1,), jnp.uint32))
+    above = u > kth
+    # entries of the k-th value a row may still take, lowest index first
+    room = k - count(above)
+    tied = u == kth
+
+    def by_index():
+        return above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+                                <= room))
+
+    return jax.lax.cond(jnp.any(count(tied) > room), by_index,
+                        lambda: above | tied)
+
+
+def index_scores(q, k, w, rows=INDEX_BLOCK_ROWS):
+    """``I[b, t, s] = sum_j w[b, t, j] relu(q[b, t, j] . k[b, s])`` for s
+    <= t, -inf past the diagonal: q [B, T, H, D] and k [B, T, D] in the
+    operands' type (the products accumulate in float32), w [B, T, H]
+    float32 -> [B, T, T] float32. A block of ``rows`` query rows at a time
+    against the keys up to its last row: no [H, T, T] array exists."""
+    b, t, h, d = q.shape
+    out = []
+    for lo in range(0, t, rows):
+        hi = min(lo + rows, t)
+        s = jnp.einsum("bthd,bsd->bths", q[:, lo:hi], k[:, :hi],
+                       preferred_element_type=jnp.float32)
+        s = jnp.einsum("bths,bth->bts", jax.nn.relu(s), w[:, lo:hi])
+        live = (np.arange(hi)[None, :] <= np.arange(lo, hi)[:, None])
+        s = jnp.where(live[None], s, -jnp.inf)
+        out.append(jnp.pad(s, ((0, 0), (0, 0), (0, t - hi)),
+                           constant_values=-np.inf))
+    return jnp.concatenate(out, axis=1) if len(out) > 1 else out[0]
+
+
+def layer_norm(x, gamma, beta, eps):
+    """LayerNorm over the last axis: statistics float32, the normalised
+    value cast to ``x``'s dtype before scale and shift (``rms_norm``'s
+    order)."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+    normed = ((x32 - mean) * jax.lax.rsqrt(var + eps)).astype(x.dtype)
+    return gamma.astype(x.dtype) * normed + beta.astype(x.dtype)
+
+
+def key_indexer(query_latent, data, q_weight, k_weight, k_gamma, k_beta,
+                head_weight, num_heads, rope_dim, topk, theta, eps=1e-6):
+    """query_latent [B, T, Lq] (the normed query latent), data [B, T, D]
+    (the block's normed input), q_weight [H W, Lq], k_weight [W, D],
+    k_gamma and k_beta [W], head_weight [H, D] -> (keep [B, T, T] int8,
+    count [B] float32).
+
+    ``qI_j = q_weight_j query_latent`` for H heads of W; ``kI =
+    LayerNorm(k_weight data)``, ONE key a token; RoPE (rotate-half pairs)
+    on the first ``rope_dim`` dimensions of both; ``w = (H^-0.5 W^-0.5)
+    head_weight data``; ``I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])``
+    for s <= t; row t keeps its ``min(t + 1, topk)`` keys of largest I,
+    ties to the lower index. ``count`` is the pairs kept a batch row
+    (``sum_t min(t + 1, topk)``: exact). Nothing here has a gradient:
+    every input is read behind ``stop_gradient`` (the published model
+    trains the scorer by a loss of its own). The two products take
+    operands of ``data``'s type and accumulate in float32; ReLU, the
+    weights, the sum over the heads and the compare are float32."""
+    (query_latent, data, q_weight, k_weight, k_gamma, k_beta,
+     head_weight) = (jax.lax.stop_gradient(x) for x in (
+         query_latent, data, q_weight, k_weight, k_gamma, k_beta,
+         head_weight))
+    b, t, _ = data.shape
+    width = k_weight.shape[0]
+    dtype = data.dtype
+    rows = min(INDEX_BLOCK_ROWS, t)
+    _M_INDEX_LOWERINGS.inc(heads=num_heads, width=width, topk=topk,
+                           rows=rows, impl="jnp")
+
+    def project(x, weight):
+        return jax.lax.dot_general(
+            x, weight.astype(x.dtype), (((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    with jax.named_scope("index"):
+        q = rope(project(query_latent, q_weight).astype(dtype), num_heads,
+                 theta, rope_dim)
+        k = rope(layer_norm(project(data, k_weight).astype(dtype), k_gamma,
+                            k_beta, eps), 1, theta, rope_dim)
+        w = project(data, head_weight) * np.float32(
+            num_heads ** -0.5 * width ** -0.5)
+        scores = index_scores(q.reshape(b, t, num_heads, width), k, w, rows)
+        with jax.named_scope("topk"):
+            keep = keep_top_k(scores, topk) & (scores > -jnp.inf)
+            count = jnp.sum(keep, axis=(1, 2), dtype=jnp.int32)
+    return keep.astype(jnp.int8), count.astype(jnp.float32)
+
+
+def _key_indexer(attrs, ins, is_train):
+    return list(key_indexer(
+        *ins, num_heads=int(attrs["num_heads"]),
+        rope_dim=int(attrs["rope_dim"]), topk=int(attrs["topk"]),
+        theta=float(attrs.get("theta", 10000.0)),
+        eps=float(attrs.get("eps", 1e-6))))
+
+
+def _key_indexer_infer(attrs, in_shapes):
+    heads, width = int(attrs["num_heads"]), int(attrs["head_dim"])
+    latent = _known(in_shapes[0], "KeyIndexer")
+    data = _known(in_shapes[1], "KeyIndexer")
+    if len(data) != 3 or len(latent) != 3 or latent[:2] != data[:2]:
+        raise ValueError(
+            "KeyIndexer: query_latent %s and data %s must be [batch, time, "
+            "width] over the same positions" % (latent, data))
+    if heads <= 0 or width <= 0 or int(attrs["topk"]) <= 0:
+        raise ValueError("KeyIndexer: num_heads, head_dim and topk must be "
+                         "set (> 0)")
+    _check_rotation("KeyIndexer", width, int(attrs["rope_dim"]), 0)
+    b, t, d = data
+    return ([latent, data, (heads * width, latent[2]), (width, d), (width,),
+             (width,), (heads, d)], [(b, t, t), (b,)], [])
+
+
+def _key_indexer_infer_type(attrs, in_types):
+    known = [t for t in in_types if t is not None]
+    if not known:
+        raise MXNetError("KeyIndexer: cannot infer type")
+    return ([known[0] if x is None else x for x in in_types],
+            [np.int8, np.float32], [])
+
+
+register(
+    OpDef(
+        "_contrib_KeyIndexer",
+        _key_indexer,
+        arguments=("query_latent", "data", "q_weight", "k_weight", "k_gamma",
+                   "k_beta", "head_weight"),
+        outputs=("keep", "count"),
+        defaults={"num_heads": 1, "head_dim": 0, "rope_dim": 0, "topk": 0,
+                  "theta": 10000.0, "eps": 1e-6},
+        infer_shape=_key_indexer_infer,
+        infer_type=_key_indexer_infer_type,
+        aliases=("KeyIndexer",),
+    )
+)

@@ -243,6 +243,65 @@ def test_the_latent_pair_takes_the_call_that_rotates_nothing(one_chip):
     assert " cosine(" not in text and " sine(" not in text
 
 
+@pytest.mark.parametrize("layer", ["full", "window"])
+def test_the_dots3_cells_attention_compiles_for_v5e(one_chip, layer):
+    """``LatentAttention`` as the dots3 cell calls it (T 4,096, bf16, a
+    gate a head): a full layer's 16 heads of 128 + 64 / 128 from a latent
+    of 512 under a keep-mask take the latent pair's SELECTED variant,
+    once each way, under the VMEM ``flash_vmem_bytes`` and
+    ``_keep_vmem_bytes`` count, and no
+    kernel of the unselected pair; a window layer's 8 heads of 192 + 64 /
+    128 from a latent of 1,024 (192 is not whole lane rows) take the
+    single-key pair under a band of 513 keys on tiles of 1,024."""
+    from mxnet_tpu.ops.transformer import latent_attention
+
+    t = 4096
+    h, nope, rope, dv, latent = ((16, 128, 64, 128, 512) if layer == "full"
+                                 else (8, 192, 64, 128, 1024))
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(q, c, gamma, up, gate, *keep):
+        return jnp.sum(latent_attention(
+            q, c, gamma, up, num_heads=h, rope_dim=rope, v_head_dim=dv,
+            theta=8e7, eps=1e-5, latent_scale=10 ** 0.5, gate=gate,
+            window=0 if keep else 513,
+            keep=keep[0] if keep else None).astype(jnp.float32))
+
+    ins = [shape(1, t, h * (nope + rope)), shape(1, t, latent + rope),
+           shape(latent), shape(h * (nope + dv), latent), shape(1, t, h)]
+    if layer == "full":
+        ins.append(shape(1, t, t, dtype=jnp.int8))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *ins).compile().as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    if layer == "window":
+        assert pk.latent_flash_takes(t, nope, rope, dv, jnp.bfloat16) is False
+        for which in ("fwd", "bwd"):
+            assert len([c for c in calls if "flash_%s_bf16_q1024_k1024_w513"
+                        % which in c]) == 1
+        assert "flash2" not in text
+        return
+    assert pk.latent_flash_takes(t, nope, rope, dv, jnp.bfloat16)
+    width = nope + 128
+    for which, resident in (("fwd", None), ("bwd", (t, width, dv))):
+        mine = [c for c in calls
+                if "flash2sel_%s_bf16_q1024_k1024" % which in c]
+        assert len(mine) == 1
+        limit, used = (
+            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
+                          r'"size":"(\d+)"' % key, mine[0]).group(1))
+            for key in ("scoped_memory_configs",
+                        "used_scoped_memory_configs"))
+        assert limit == pk.flash.flash_vmem_bytes(
+            1024, 1024, width, 2, resident=resident) \
+            + pk.latent._keep_vmem_bytes(1024, 1024)
+        assert used <= limit <= pk.common.VMEM_RAISED_LIMIT
+    assert "flash2_fwd" not in text and "flash2_bwd" not in text
+    assert "flash_fwd_" not in text and "flash_bwd_" not in text
+
+
 def test_the_channel_delta_block_compiles_within_its_memory(one_chip):
     """``GatedDeltaNet`` in its channel form at the Kimi Linear cell's
     shape (T 8,192, 32 heads of 128 / 128, chunks of 64, bf16), value and

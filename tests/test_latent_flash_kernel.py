@@ -369,3 +369,88 @@ def test_three_layers_trace_each_body_once_over_two_steps(kernels):
         telemetry.reset()
     assert all(np.isfinite(np.asarray(g)).all() for ins in grads
                for g in ins)
+
+
+# -- the selected variant: every live tile also masked by a keep-mask --------
+
+def _select_inputs(t, heads, seed):
+    rng = np.random.RandomState(seed)
+    q, kv, kr = (jnp.asarray(rng.randn(BATCH, t, width), jnp.float32)
+                 for width in (heads * 256, heads * (NOPE + DV), 128))
+    zero = jnp.arange(256) < NOPE + ROPE      # the lanes behind the rotary
+    q = (q.reshape(BATCH, t, heads, 256) * zero).reshape(BATCH, t, -1)
+    kr = kr * zero[NOPE:]
+    keep = rng.rand(BATCH, t, t) < 0.5
+    # a row that drops its own key, and rows that keep nothing of their
+    # first tile but key 3 (the running maximum starts late)
+    keep[:, np.arange(t), np.arange(t)] = False
+    keep[:, :, :128] = False
+    keep[:, :, 3] = True
+    cot = jnp.asarray(rng.randn(BATCH, t, heads * DV), jnp.float32)
+    return (q, kv, kr), jnp.asarray(keep, jnp.int8), cot
+
+
+@pytest.mark.parametrize("t,block_q,block_k", [
+    (512, 128, 256), (600, 256, 128), (384, 128, 128)],
+    ids=["q128_k256", "padded_q256_k128", "square"])
+def test_the_selected_pair_matches_the_composed_form(t, block_q, block_k):
+    """``latent_flash(keep=)`` through the interpreter against
+    ``kept_attention`` over the concatenated key, output and every
+    gradient: pairs past the diagonal stay dead whatever the mask says,
+    padding keys are masked by the padded mask's zeros."""
+    heads = 2
+    ins, keep, cot = _select_inputs(t, heads, t)
+    keep = keep.at[:, 5, 9].set(1)    # past the diagonal: never live
+    scale = (NOPE + ROPE) ** -0.5
+    got, got_g = _out_and_grads(
+        lambda *a: pk.latent_flash(*a, heads, NOPE, scale, block_q=block_q,
+                                   block_k=block_k, interpret=True,
+                                   keep=keep), ins, cot)
+    want, want_g = _out_and_grads(
+        lambda *a: pk.latent.latent_composed(*a, heads, NOPE, scale, keep),
+        ins, cot)
+    _close(got, want, "out")
+    for name, g, w in zip(("dq", "dkv", "dk_rope"), got_g, want_g):
+        _close(g, w, name, ulps=64)
+    # the mask is data: a mask of ones is plain causal attention
+    ones = jnp.ones_like(keep)
+    _close(pk.latent_flash(*ins, heads, NOPE, scale, block_q=block_q,
+                           block_k=block_k, interpret=True, keep=ones),
+           pk.latent.latent_composed(*ins, heads, NOPE, scale), "all kept")
+
+
+def test_a_selection_names_its_own_pair_and_none_keeps_todays_names():
+    """A call with a keep-mask traces ``flash2sel_*`` (no tile by
+    quarters, counted with ``select=1``); the same call without one still
+    traces the ``flash2_*`` pair under its name of before, cut tiles and
+    all."""
+    heads, t = 1, 2048      # traced only: the cells' tiles of 1,024
+    ins, keep, cot = _select_inputs(t, heads, 7)
+    ins = tuple(x.astype(jnp.bfloat16) for x in ins)
+    cot = cot.astype(jnp.bfloat16)
+
+    def names(**extra):
+        fn = lambda *a: pk.latent_flash(*a, heads, NOPE, 192 ** -0.5,
+                                        **extra)
+        return sorted(str(e.params["name"]) for e in _pallas_calls(
+            jax.make_jaxpr(lambda *a: jax.vjp(fn, *a)[1](cot))(*ins).jaxpr))
+
+    for name in _JITTED:
+        getattr(pk.latent, name).clear_cache()
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        bq, bk = pk.flash.flash_tiles(t, 256, jnp.bfloat16)
+        edge = pk.flash.cut_half(bq, bk, True)
+        tiles = "bf16_q%d_k%d" % (bq, bk)
+        assert edge and names(keep=keep) == ["flash2sel_bwd_" + tiles,
+                                             "flash2sel_fwd_" + tiles]
+        c = telemetry.REGISTRY.get("attention.latent_kernel_traces")
+        assert c.value(**{"pass": "fwd"}, select=1) == 1
+        assert c.value(**{"pass": "bwd"}, select=1) == 1
+        assert names() == ["flash2_bwd_%s_e%d" % (tiles, edge),
+                           "flash2_fwd_%s_e%d" % (tiles, edge)]
+        assert telemetry.total("attention.latent_kernel_traces") == 4
+    finally:
+        telemetry.disable()
+        telemetry.reset()

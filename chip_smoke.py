@@ -7,9 +7,8 @@ as shipped, weights random from a seed):
 
   device    platform / device_kind / count, the peak-table entry, the
             native library, the compile-cache directory
-  kernels   flash attention forward+backward and the optimizer slab
-            update, compiled by Mosaic and compared with their jnp
-            references
+  kernels   flash attention forward+backward, compiled by Mosaic and
+            compared with its jnp reference
   train     ``Module(sym, context=mx.tpu(0), mesh=make_mesh(dp=1))
             .fit(..., kvstore='device')`` — the fused ShardedTrainStep,
             bf16 ResNet-50 b256 on a repeated synthetic batch
@@ -20,7 +19,7 @@ as shipped, weights random from a seed):
             and decode, every token checked against a full forward
   train_dpN only with more than one chip: the same ResNet-50 under
             ``MXTPU_AMP=bf16`` over all N chips (flat sharded update,
-            fp32 masters, the slab kernel inside shard_map)
+            fp32 masters, the slab rule inside shard_map)
 
 It exits non-zero if the platform is not ``tpu``, if a leg raises, if a
 request goes unanswered, or if a mechanism a leg asked for did not
@@ -227,44 +226,6 @@ def leg_kernels(smoke):
         check(all(np.isfinite(e) and e <= tol for e in errs),
               "flash attention %s disagrees with the reference: %s"
               % (jnp.dtype(dtype).name, errs))
-
-    kw = dict(wd=1e-4, rescale_grad=1.0 / 32, clip_gradient=None,
-              momentum=0.9, beta1=0.9, beta2=0.999, epsilon=1e-8)
-    for kind in ("sgd_mom", "adam"):
-        # a ragged multi-block slab and one below a single 256-row block
-        for size in (1_000_003, 3000):
-            w = jnp.asarray(rng.randn(size).astype(np.float32))
-            g = jnp.asarray(rng.randn(size) * 4, jnp.bfloat16)
-            states = tuple(
-                jnp.asarray(np.abs(rng.randn(size)).astype(np.float32) * .1)
-                for _ in range(pk.SLAB_STATE_SLOTS[kind]))
-            fused = jax.jit(lambda w, g, st, fin, _k=kind: (
-                pk.fused_slab_update(_k, w, g, st, 0.05, 1.0 / 128, fin,
-                                     interpret=smoke.rehearsal, **kw)))
-            plain = jax.jit(lambda w, g, st, fin, _k=kind: (
-                pk.slab_update_reference(_k, w, g, st, 0.05, 1.0 / 128,
-                                         fin, **kw)))
-            n = smoke.expect_mosaic(
-                fused.lower(w, g, states, 1.0).as_text(),
-                "slab update %s" % kind)
-            worst = 0.0
-            for finite in (1.0, 0.0):
-                got_w, got_st, got_w16 = fused(w, g, states, finite)
-                ref_w, ref_st, ref_w16 = plain(w, g, states, finite)
-                for a, b in zip((got_w,) + tuple(got_st),
-                                (ref_w,) + tuple(ref_st)):
-                    np.testing.assert_allclose(
-                        np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6)
-                    worst = max(worst, float(jnp.max(jnp.abs(a - b))))
-                # the bf16 copy may differ by one rounding step where
-                # the f32 values differ in their last bits
-                np.testing.assert_allclose(
-                    np.asarray(got_w16.astype(jnp.float32)),
-                    np.asarray(ref_w16.astype(jnp.float32)),
-                    rtol=2 ** -7, atol=1e-6)
-            smoke.say("kernels", kernel="fused_slab_update", kind=kind,
-                      size=size, mosaic_calls=n,
-                      max_abs_err="%.2e" % worst)
 
 
 def _resnet(smoke, dtype):
@@ -515,7 +476,7 @@ def leg_generate(smoke):
 def leg_train_dpn(smoke):
     """The same ResNet-50 over all N chips under MXTPU_AMP=bf16: the
     first time the flat sharded update, the fp32 masters and the slab
-    kernel inside shard_map meet real devices."""
+    rule inside shard_map meet real devices."""
     import jax
     import jax.numpy as jnp
 
@@ -531,11 +492,10 @@ def leg_train_dpn(smoke):
         trainer = mod._fused_trainer
         check(trainer is not None, "multi-device kvstore='device' did "
                                    "not reach the fused step")
-        text = _inspect_step(smoke, mod, "train_dp%d" % n)
+        _inspect_step(smoke, mod, "train_dp%d" % n)
     finally:
         os.environ.pop("MXTPU_AMP", None)
     tag = "train_dp%d" % n
-    n_mosaic = smoke.expect_mosaic(text, "slab kernel in the dp=%d step" % n)
     total, resident = trainer.opt_state_shard_info(mod._fused_opt)
     # the invariant of the AMP path, checked on the devices in one
     # program: working params == bf16(fp32 masters), element for element
@@ -546,9 +506,7 @@ def leg_train_dpn(smoke):
     in_use = [int((d.memory_stats() or {}).get("bytes_in_use", 0))
               for d in smoke.devices]
     smoke.say(tag, fused=True, flat_mode=trainer.flat_mode,
-              amp=trainer.amp,
-              update_kernel="pallas x%d" % n_mosaic if n_mosaic
-              else "pallas via the interpreter",
+              amp=trainer.amp, update_calls=len(trainer._flat_plan.buckets),
               opt_state_elements=total, resident_on_device0=resident,
               params_ne_bf16_masters=mismatched,
               bytes_in_use="/".join(str(b) for b in in_use))

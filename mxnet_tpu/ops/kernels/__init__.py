@@ -19,7 +19,6 @@ because they need explicit on-chip (VMEM) accumulation patterns.
                of Mamba2, GatedDeltaNet and ShortConv, with its epilogue
   gate_norm    the gate and the grouped RMSNorm behind the scan (Mamba2)
                and the delta rule (GatedDeltaNet), one pass each way
-  slab_update  the AMP optimizer step over a flat slab
   conv         the conv-backward pair
   common       what they share
 
@@ -49,8 +48,6 @@ from .gmm import (
     sorted_segment_sum)
 from .latent import (
     latent_flash, latent_flash_takes, latent_query, latent_query_takes)
-from .slab_update import (
-    SLAB_STATE_SLOTS, fused_slab_update, slab_update_reference)
 from .ssd import ssd_scan, ssd_takes
 from .taps import causal_conv, taps_takes
 
@@ -58,11 +55,10 @@ __all__ = [
     "attention", "causal_conv", "channel_delta_net", "common",
     "conv_bwd_filter", "conv_bwd_input",
     "conv_bwd_plan", "conv_kernel_enabled", "flash_attention",
-    "flash_tiles", "fused_slab_update", "gate_norm_takes",
+    "flash_tiles", "gate_norm_takes",
     "gated_delta_rule", "gated_rms_norm", "gdn_takes",
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
     "grouped_matmul", "latent_flash", "latent_flash_takes", "latent_query",
-    "latent_query_takes", "reference_attention", "SLAB_STATE_SLOTS",
-    "slab_update_reference", "sorted_segment_sum", "ssd_scan", "ssd_takes",
-    "taps_takes",
+    "latent_query_takes", "reference_attention", "sorted_segment_sum",
+    "ssd_scan", "ssd_takes", "taps_takes",
 ]

@@ -29,9 +29,9 @@ VMEM_SCOPED_DEFAULT = 16 * 1024 * 1024
 VMEM_RAISED_LIMIT = 48 * 1024 * 1024
 
 # The one seam for tests: what an op-level caller (``attention``,
-# ``parallel/moe.py``, ``ops/transformer.py``, ``ops/nn.py``,
-# ``parallel/train_step.py``) passes as a kernel entry's ``interpret``
-# when it traces. A test reaches a kernel through a model with
+# ``parallel/moe.py``, ``ops/transformer.py``, ``ops/nn.py``) passes as
+# a kernel entry's ``interpret`` when it traces. A test reaches a kernel
+# through a model with
 # ``monkeypatch.setattr(common, "INTERPRET", True)``; nothing else sets it.
 INTERPRET = False
 

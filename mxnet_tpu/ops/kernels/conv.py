@@ -158,8 +158,8 @@ def conv_bwd_filter(data, grad, wshape, pad, block_n=None, interpret=False):
         block_n = plan["block_n"] if plan else 1
 
     def kernel(data, grad, interpret):
-        # layout + halo pad happen OUTSIDE no_x64 (see fused_slab_update's
-        # note on i64/i32 subfunction cache keys under global x64)
+        # layout + halo pad happen OUTSIDE no_x64: under the global x64 a
+        # lowered subfunction's cache key would meet i64 and i32 operands
         x_t = jnp.pad(jnp.transpose(data, (0, 2, 3, 1)),
                       ((0, 0), (pad[0], pad[0]), (pad[1], pad[1]), (0, 0)))
         g_t = jnp.transpose(grad, (0, 2, 3, 1))

@@ -144,3 +144,58 @@ register(
         mutate_inputs=(2, 3, 4),
     )
 )
+
+
+# -- the AMP flat update's rule over a slab ---------------------------------
+# State slabs a rule keeps beside the master (``parallel/train_step.py``).
+SLAB_STATE_SLOTS = {"sgd": 0, "sgd_mom": 1, "adam": 2}
+
+
+def slab_update(kind, w, g, states, lr, inv_scale, finite, *, wd,
+                rescale_grad, clip_gradient, momentum=0.0, beta1=0.9,
+                beta2=0.999, epsilon=1e-8):
+    """One AMP optimizer step on a flat slab (a shard of the fused step's
+    float32 masters), as one XLA fusion: ``_prep_grad`` and the sgd /
+    sgd_mom / adam update above with the AMP extras, the gradient's
+    unscale up front, the branchless finite select at the end and the
+    bf16 weight copy out. All math in f32 whatever the gradient's dtype.
+
+    w: (S,) f32 master shard; g: (S,) grad shard (bf16 under AMP);
+    states: tuple of (S,) f32 state slabs (``SLAB_STATE_SLOTS[kind]`` of
+    them); lr / inv_scale / finite: f32 scalars, traced or not (finite:
+    1.0 = apply, 0.0 = skip, every slab bit for bit what it was).
+    Returns (new_w f32, new_states tuple, w16 bf16), each (S,)."""
+    assert len(states) == SLAB_STATE_SLOTS[kind], (kind, len(states))
+    lr, inv_scale, finite = (jnp.asarray(x, jnp.float32)
+                             for x in (lr, inv_scale, finite))
+    w = w.astype(jnp.float32)
+    g = g.astype(jnp.float32) * inv_scale
+    if rescale_grad != 1.0:
+        g = g * jnp.float32(rescale_grad)
+    if clip_gradient is not None and clip_gradient > 0:
+        g = jnp.clip(g, -jnp.float32(clip_gradient),
+                     jnp.float32(clip_gradient))
+    if wd != 0.0:
+        g = g + jnp.float32(wd) * w
+    if kind == "sgd":
+        new_w = w - lr * g
+        new_states = ()
+    elif kind == "sgd_mom":
+        mom = states[0].astype(jnp.float32)
+        new_mom = jnp.float32(momentum) * mom - lr * g
+        new_w = w + new_mom
+        new_states = (new_mom,)
+    elif kind == "adam":
+        mean = states[0].astype(jnp.float32)
+        var = states[1].astype(jnp.float32)
+        new_mean = beta1 * mean + (1.0 - beta1) * g
+        new_var = beta2 * var + (1.0 - beta2) * jnp.square(g)
+        new_w = w - lr * new_mean / (jnp.sqrt(new_var) + epsilon)
+        new_states = (new_mean, new_var)
+    else:
+        raise ValueError("unknown slab kind %r" % (kind,))
+    keep = finite > jnp.float32(0.5)
+    new_w = jnp.where(keep, new_w, w)
+    new_states = tuple(jnp.where(keep, ns, os_.astype(jnp.float32))
+                       for ns, os_ in zip(new_states, states))
+    return new_w, new_states, new_w.astype(jnp.bfloat16)

@@ -141,12 +141,12 @@ class Optimizer:
     # (create_state captures the live weight values) stay False.
     elementwise_update = False
 
-    # Name of the fused Pallas slab-update kernel variant
-    # (ops/kernels.fused_slab_update) the AMP flat-update path may
-    # use for this optimizer: "sgd" (momentum attr picks the mom
-    # variant), "adam", or None to always take the jnp reference path.
+    # Name of the rule of ops/optimizer_ops.slab_update the AMP
+    # flat-update path runs for this optimizer: "sgd" (momentum attr
+    # picks the mom variant), "adam", or None to trace through the
+    # optimizer's own update.
     # Only meaningful when elementwise_update is True.
-    fused_slab_kernel = None
+    slab_rule = None
 
     def create_state(self, index, weight):
         return None
@@ -189,7 +189,7 @@ class SGD(Optimizer):
     """SGD with momentum — fused sgd_update/sgd_mom_update kernels."""
 
     elementwise_update = True
-    fused_slab_kernel = "sgd"
+    slab_rule = "sgd"
 
     def __init__(self, momentum=0.0, **kwargs):
         super().__init__(**kwargs)
@@ -211,7 +211,7 @@ class SGD(Optimizer):
 class NAG(SGD):
     """Nesterov accelerated SGD (reference optimizer.py:413)."""
 
-    fused_slab_kernel = None  # overrides SGD's: no Nesterov slab kernel
+    slab_rule = None  # overrides SGD's: no Nesterov slab rule
 
     def update(self, index, weight, grad, state):
         lr, wd, g = self._begin_update(index, grad)
@@ -277,7 +277,7 @@ class Adam(Optimizer):
     """Adam — fused adam_update kernel with bias correction via lr_t."""
 
     elementwise_update = True
-    fused_slab_kernel = "adam"
+    slab_rule = "adam"
 
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999,
                  epsilon=1e-8, **kwargs):

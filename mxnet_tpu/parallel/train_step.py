@@ -38,26 +38,81 @@ from ..base import bucket_bytes_env as _env_bucket_bytes
 _M_STEPS = _tm.counter(
     "train_step.steps", "Optimizer steps dispatched through the fused "
     "ShardedTrainStep path")
+_M_FLAT_LOWERINGS = _tm.counter(
+    "train_step.flat_update_lowerings", "Traces of the flat AMP update "
+    "(one per trace of a step, nothing per step); labels: form (slab: "
+    "ops/optimizer_ops.slab_update, one XLA fusion a bucket; optimizer: "
+    "traced through the optimizer's own update), calls (updates a step, "
+    "one a bucket), tile_rows (rows of 128 lanes a shard is a multiple "
+    "of)")
 _H_BUCKET_BYTES = _tm.histogram(
     "kvstore.bucket_bytes", "Payload bytes per coalesced gradient bucket "
     "(kvstore GradBucketer flushes and fused flat-update plan buckets)")
+
+
+# A shard of the AMP path's slabs is whole (16, 128) tiles, a bf16 array's
+# on the chip and so every operand's of the update: the float32 masters and
+# state, the bf16 gradient and the bf16 copy that is all-gathered. The plan
+# pads to it once, so a step pads, slices and copies nothing round its
+# update, and the all-gather of a shard needs no re-tiling (the parent's
+# shards, a multiple of dp only, cost a ``reduce`` a gather: PERF.md 7).
+# The float32 path keeps its shards as wide as their elements.
+_LANES = 128
+_AMP_SHARD_ALIGN = 16 * _LANES
+
+
+def _slab_axes(shape):
+    """The order in which a key's axes are laid into its slab, or None
+    for the order they are in. Trailing axes that together are narrower
+    than one row of lanes go first (a conv filter ``[O, I, 3, 3]`` lies in
+    its slab as ``[3, 3, O, I]``): the chip pads a minor dimension to 128
+    lanes, so a row-major ``reshape(-1)`` of such a filter goes through a
+    copy 40 times its size, in and out, every step, while the chip itself
+    holds the filter with the large axes minor and the reordered reshape
+    is a re-tiling. A slab is elementwise to everything that reads it, so
+    the order inside a key is the plan's to choose."""
+    for k in range(1, len(shape)):
+        narrow = int(np.prod(shape[k:]))
+        if narrow < _LANES:
+            if narrow == 1:
+                return None
+            return tuple(range(k, len(shape))) + tuple(range(k))
+    return None
+
+
+def _to_slab(x):
+    """A key's array (jax or numpy) as the 1-D run it is in its slab."""
+    axes = _slab_axes(x.shape)
+    return (x if axes is None else x.transpose(axes)).reshape(-1)
+
+
+def _from_slab(run, shape):
+    """Inverse of ``_to_slab``: a key's 1-D run back in ``shape``."""
+    axes = _slab_axes(shape)
+    if axes is None:
+        return run.reshape(shape)
+    back = tuple(int(a) for a in np.argsort(axes))
+    return run.reshape(tuple(shape[a] for a in axes)).transpose(back)
 
 
 class _FlatBucket:
     """One size-capped flat slab of the parameter space: contiguous
     per-key views carved out of a single (padded) 1-D buffer, all
     sharing one (dtype, lr_mult, wd_mult) signature so a single set of
-    fused-optimizer scalar kwargs is valid for the whole slab."""
+    fused-optimizer scalar kwargs is valid for the whole slab. A view's
+    elements lie in the order ``_to_slab`` gives them."""
 
     __slots__ = ("rep_index", "dtype", "views", "size", "padded")
 
-    def __init__(self, rep_index, dtype, views, dp):
+    def __init__(self, rep_index, dtype, views, dp, shard_align):
         self.rep_index = rep_index  # index whose _fused_kwargs apply
         self.dtype = dtype
         self.views = views  # [(index, name, offset, size, shape)]
         self.size = sum(v[3] for v in views)
-        # pad so the slab splits evenly into dp contiguous shards
-        self.padded = -(-self.size // dp) * dp
+        # pad so the slab splits evenly into dp contiguous shards, each
+        # a multiple of shard_align (the pad is zeros and stays zeros)
+        whole = dp * shard_align
+        self.padded = -(-self.size // whole) * whole
 
 
 class _FlatUpdatePlan:
@@ -69,7 +124,8 @@ class _FlatUpdatePlan:
     differentiating), and packs size-capped buckets."""
 
     def __init__(self, param_names, shapes, dtypes, optimizer, dp,
-                 bucket_bytes, comm_itemsize=None):
+                 bucket_bytes, comm_itemsize=None, shard_align=1):
+        self._shard_align = shard_align
         groups = {}
         order = []
         for i, name in enumerate(param_names):
@@ -115,7 +171,8 @@ class _FlatUpdatePlan:
         for (i, name, size, shape) in pending:
             views.append((i, name, off, size, shape))
             off += size
-        self.buckets.append(_FlatBucket(pending[0][0], dtype, views, dp))
+        self.buckets.append(_FlatBucket(pending[0][0], dtype, views, dp,
+                                        self._shard_align))
 
 
 class _EveryKeyCount(dict):
@@ -400,7 +457,7 @@ class ShardedTrainStep:
             def pack(buckets):
                 slabs = []
                 for parts, pad in zip(buckets, pads):
-                    flats = [p.astype(jnp.float32).reshape(-1)
+                    flats = [_to_slab(p.astype(jnp.float32))
                              for p in parts]
                     if pad:
                         flats.append(jnp.zeros((pad,), jnp.float32))
@@ -415,8 +472,8 @@ class ShardedTrainStep:
                 state[self._master_key(bi)] = slab
         else:
             for bi, bucket in enumerate(names):
-                parts = [np.asarray(params_by_name[name],
-                                    np.float32).reshape(-1)
+                parts = [_to_slab(np.asarray(params_by_name[name],
+                                             np.float32))
                          for name in bucket]
                 if pads[bi]:
                     parts.append(np.zeros((pads[bi],), np.float32))
@@ -431,15 +488,16 @@ class ShardedTrainStep:
         return state
 
     def master_params_named(self, opt_state):
-        """fp32 master weights carved back to per-param shapes (lazy
-        device slices — the fp32 truth for metrics/checkpoints)."""
+        """fp32 master weights carved back to per-param shapes and
+        axis order (lazy device slices — the fp32 truth for
+        metrics/checkpoints)."""
         plan = self._flat_plan
         assert plan is not None, "flat plan not built yet"
         out = {}
         for bi, b in enumerate(plan.buckets):
             m = opt_state[self._master_key(bi)]
             for (_i, name, off, size, shape) in b.views:
-                out[name] = m[off:off + size].reshape(shape)
+                out[name] = _from_slab(m[off:off + size], shape)
         return out
 
     def master_params_placed(self, opt_state):
@@ -464,7 +522,7 @@ class ShardedTrainStep:
         if self._flat_plan is None:
             shapes = {n: tuple(params[n].shape) for n in self.param_names}
             dtypes = {n: str(params[n].dtype) for n in self.param_names}
-            comm_itemsize = None
+            comm_itemsize, shard_align = None, 1
             if self.amp:
                 # the plan describes the fp32 MASTER slabs regardless of
                 # whether it is built from fp32 params (make_state) or
@@ -473,10 +531,11 @@ class ShardedTrainStep:
                 dtypes = {n: ("float32" if d == "bfloat16" else d)
                           for n, d in dtypes.items()}
                 comm_itemsize = 2
+                shard_align = _AMP_SHARD_ALIGN
             self._flat_plan = _FlatUpdatePlan(
                 self.param_names, shapes, dtypes, self.optimizer,
                 self.mesh.shape["dp"], self.flat_bucket_bytes,
-                comm_itemsize=comm_itemsize)
+                comm_itemsize=comm_itemsize, shard_align=shard_align)
         return self._flat_plan
 
     def _flat_state_sharding(self):
@@ -503,7 +562,7 @@ class ShardedTrainStep:
                 return None
             if isinstance(st, tuple):
                 return tuple(_slice(s, off, size, shape) for s in st)
-            return st[off:off + size].reshape(shape)
+            return _from_slab(st[off:off + size], shape)
 
         named = {}
         for bi, b in enumerate(plan.buckets):
@@ -514,9 +573,10 @@ class ShardedTrainStep:
 
     def named_state_to_flat(self, named):
         """Inverse of flat_state_to_named: pack per-param (host) state
-        trees into device-placed flat slabs, zero-padding each slab to a
-        dp multiple (pad lanes stay exactly zero under every
-        elementwise_update optimizer, so they never leak into views)."""
+        trees into device-placed flat slabs, each key in its slab's
+        order and each slab zero-padded to the plan's width (pad lanes
+        stay exactly zero under every elementwise_update optimizer, so
+        they never leak into views)."""
         import jax
 
         plan = self._flat_plan
@@ -530,7 +590,7 @@ class ShardedTrainStep:
                 return tuple(
                     _pack([p[j] for p in parts], pad, dtype)
                     for j in range(len(parts[0])))
-            flats = [np.asarray(p).reshape(-1) for p in parts]
+            flats = [_to_slab(np.asarray(p)) for p in parts]
             leaf_dtype = flats[0].dtype
             if pad:
                 flats.append(np.zeros((pad,), leaf_dtype))
@@ -839,32 +899,36 @@ class ShardedTrainStep:
         return (_keep_dtype(w._data, w_c),
                 _keep_dtype(_unwrap_state(st), st_c))
 
+    def _slab_rule(self):
+        """The ``ops/optimizer_ops.slab_update`` rule the optimizer names
+        for its AMP update, or None for one traced through its own
+        ``update``."""
+        kind = getattr(self.optimizer, "slab_rule", None)
+        if kind == "sgd" and getattr(self.optimizer, "momentum", 0.0):
+            kind = "sgd_mom"
+        return kind
+
     def _flat_body_amp(self, bucket, m_c, g_c, st_c, lr, t, inv_scale,
                        finite):
         """One AMP optimizer step on a width-S chunk: bf16 grad in, fp32
         master + state updated, bf16 weight copy out; non-finite steps
         pass old values through bitwise (branchless select).
 
-        Optimizers that declare a `fused_slab_kernel` go through
-        ops/kernels.fused_slab_update: the Pallas kernel where the step is
-        lowered for the TPU — one VMEM pass for the whole
-        unscale/update/cast chain — and its shared-math jnp reference on
-        every other platform (same `_slab_update_math`, so the platform
-        changes codegen, not formulas). Other elementwise optimizers
-        trace through their own Optimizer.update on the unscaled fp32
-        gradient exactly like `_flat_body`."""
+        Optimizers that declare a `slab_rule` go through
+        ops/optimizer_ops.slab_update, the whole unscale / update / select
+        / cast chain written once and one XLA fusion over the shard. Other
+        elementwise optimizers trace through their own Optimizer.update on
+        the unscaled fp32 gradient exactly like `_flat_body`."""
         import jax
         import jax.numpy as jnp
 
         from ..ndarray import NDArray
-        from ..ops import kernels
+        from ..ops.optimizer_ops import slab_update
 
         opt = self.optimizer
         opt.lr = lr
         opt._index_update_count = _EveryKeyCount(t)
-        kind = getattr(opt, "fused_slab_kernel", None)
-        if kind == "sgd" and getattr(opt, "momentum", 0.0):
-            kind = "sgd_mom"
+        kind = self._slab_rule()
         if kind is not None:
             kwargs = opt._fused_kwargs(bucket.rep_index)
             lr_eff = kwargs["lr"]
@@ -876,15 +940,14 @@ class ShardedTrainStep:
             states = ()
             if st_c is not None:
                 states = st_c if isinstance(st_c, tuple) else (st_c,)
-            nm, nst, w16 = kernels.fused_slab_update(
+            nm, nst, w16 = slab_update(
                 kind, m_c, g_c, states, lr_eff, inv_scale, finite,
                 wd=kwargs["wd"], rescale_grad=kwargs["rescale_grad"],
                 clip_gradient=kwargs["clip_gradient"],
                 momentum=getattr(opt, "momentum", 0.0),
                 beta1=getattr(opt, "beta1", 0.9),
                 beta2=getattr(opt, "beta2", 0.999),
-                epsilon=getattr(opt, "epsilon", 1e-8),
-                interpret=kernels.common.INTERPRET)
+                epsilon=getattr(opt, "epsilon", 1e-8))
             if st_c is None:
                 new_st = None
             elif isinstance(st_c, tuple):
@@ -926,6 +989,9 @@ class ShardedTrainStep:
 
         plan = self._ensure_flat_plan(params)
         dp = self.mesh.shape["dp"]
+        _M_FLAT_LOWERINGS.inc(
+            form="slab" if self._slab_rule() else "optimizer",
+            calls=len(plan.buckets), tile_rows=_AMP_SHARD_ALIGN // _LANES)
         scale = opt_state[self.AMP_SCALE_KEY]
         good = opt_state[self.AMP_GOOD_KEY]
         new_params, new_state = {}, {}
@@ -934,7 +1000,7 @@ class ShardedTrainStep:
         finite = jnp.asarray(True)
         for b in plan.buckets:
             pad = b.padded - b.size
-            g_parts = [grads[name].reshape(-1)
+            g_parts = [_to_slab(grads[name])
                        for (_i, name, _o, _s, _sh) in b.views]
             if pad:
                 g_parts.append(jnp.zeros((pad,), g_parts[0].dtype))
@@ -1002,8 +1068,8 @@ class ShardedTrainStep:
                     w16_full = w16_2.reshape(b.padded)
 
                 for (_i, name, off, size, shape) in b.views:
-                    new_params[name] = (
-                        w16_full[off:off + size].reshape(shape))
+                    new_params[name] = _from_slab(
+                        w16_full[off:off + size], shape)
                 new_state[self._master_key(bi)] = nmaster
                 if nst is not None:
                     new_state[self._flat_key(bi)] = nst
@@ -1056,9 +1122,9 @@ class ShardedTrainStep:
         with self._patched_optimizer(lr, t):
             for bi, b in enumerate(plan.buckets):
                 pad = b.padded - b.size
-                w_parts = [params[name].reshape(-1)
+                w_parts = [_to_slab(params[name])
                            for (_i, name, _o, _s, _sh) in b.views]
-                g_parts = [grads[name].reshape(-1)
+                g_parts = [_to_slab(grads[name])
                            for (_i, name, _o, _s, _sh) in b.views]
                 if pad:
                     zpad = jnp.zeros((pad,), w_parts[0].dtype)
@@ -1120,8 +1186,8 @@ class ShardedTrainStep:
                         lambda a: a.reshape(b.padded), nst2)
 
                 for (_i, name, off, size, shape) in b.views:
-                    new_params[name] = (
-                        flat_nw[off:off + size].reshape(shape))
+                    new_params[name] = _from_slab(
+                        flat_nw[off:off + size], shape)
                 if nst is not None:
                     new_state[self._flat_key(bi)] = nst
         for name in params:

@@ -2,7 +2,7 @@
 
 MXTPU_AMP=bf16 on the flat fused-update path: bf16 forward/backward and
 collectives, fp32 master-weight slabs, dynamic loss scaling, and the
-fused Pallas optimizer-slab kernel. These tests pin the contracts:
+slab rule that updates them. These tests pin the contracts:
 
 - the working params are exactly bf16(masters) at every step boundary;
 - a non-finite gradient skips the step bitwise-cleanly (params, masters,
@@ -10,8 +10,9 @@ fused Pallas optimizer-slab kernel. These tests pin the contracts:
   training continues;
 - the scale doubles after MXTPU_LOSS_SCALE_WINDOW consecutive finite
   steps;
-- the Pallas slab kernel (interpret mode) matches the jnp
-  reference chain across device counts and optimizers;
+- the flat update on the plan's whole-tile shards matches the slab rule
+  on the plain arrays across device counts and optimizers, and its trace
+  pads and slices nothing round the update;
 - kvstore gradient buckets group by dtype, the byte cap counts actual
   itemsize, and MXTPU_BUCKET_REDUCE_DTYPE upcasts only the sum;
 - checkpoints are dtype-portable (AMP <-> fp32 both directions,
@@ -294,71 +295,330 @@ def test_amp_scale_growth(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# fused Pallas slab kernel vs jnp reference
+# the flat update as the step applies it, on the plan's whole-tile shards,
+# against the slab rule on the plain arrays
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kind", ["sgd", "sgd_mom", "adam"])
-@pytest.mark.parametrize("size", [131, 1024, 5000])
-def test_slab_kernel_matches_reference(kind, size):
-    """fused_slab_update (interpret mode) vs slab_update_reference on
-    odd/padded sizes; finite=0 must return the inputs bitwise."""
+_SLAB_OPTS = {
+    "sgd": ("sgd", {}),
+    "sgd_mom": ("sgd", {"momentum": 0.9}),
+    "adam": ("adam", {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8}),
+}
+
+
+def _one_key_trainer(kind, size, ndev):
+    """A fused trainer over one key of ``size`` parameters at dp=ndev
+    (MXTPU_AMP set by the caller), its float32 weight, a bf16 gradient
+    and state slabs that are not zeros."""
+    import jax
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops.kernels import (
-        SLAB_STATE_SLOTS, fused_slab_update, slab_update_reference)
+    from jax.sharding import Mesh
+    from mxnet_tpu import optimizer as opt
+    from mxnet_tpu.ops.optimizer_ops import SLAB_STATE_SLOTS
+    from mxnet_tpu.parallel import ShardedTrainStep
 
-    rng = np.random.RandomState(size + len(kind))
-    w = jnp.asarray(rng.randn(size).astype(np.float32))
-    g = jnp.asarray((rng.randn(size) * 4).astype(np.float32),
+    data = mx.sym.Variable("data")
+    net = mx.sym.LinearRegressionOutput(
+        mx.sym.FullyConnected(data, num_hidden=1, no_bias=True, name="fc"),
+        name="softmax")
+    mesh = Mesh(np.asarray(jax.devices()[:ndev]), ("dp",))
+    name, extra = _SLAB_OPTS[kind]
+    o = opt.create(name, learning_rate=0.05, wd=0.0001,
+                   rescale_grad=1.0 / 32, **extra)
+    trainer = ShardedTrainStep(net, mesh, optimizer=o)
+    assert trainer.amp and trainer.flat_mode == "shard"
+    rng = np.random.RandomState(size + len(kind) + ndev)
+    w = rng.randn(1, size).astype(np.float32)
+    g = jnp.asarray((rng.randn(1, size) * 4).astype(np.float32),
                     jnp.bfloat16)
-    states = tuple(
-        jnp.asarray(rng.randn(size).astype(np.float32) * 0.1)
-        for _ in range(SLAB_STATE_SLOTS[kind]))
-    kw = dict(wd=0.0001, rescale_grad=1.0 / 32, clip_gradient=None,
-              momentum=0.9, beta1=0.9, beta2=0.999, epsilon=1e-8)
-    for finite in (1.0, 0.0):
-        ref_w, ref_st, ref_w16 = slab_update_reference(
-            kind, w, g, states, 0.05, 1.0 / 128, finite, **kw)
-        got_w, got_st, got_w16 = fused_slab_update(
-            kind, w, g, states, 0.05, 1.0 / 128, finite, interpret=True,
-            **kw)
-        np.testing.assert_allclose(np.asarray(got_w), np.asarray(ref_w),
-                                   rtol=1e-6, atol=1e-7)
-        for a, b in zip(got_st, ref_st):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=1e-7)
-        np.testing.assert_array_equal(
-            np.asarray(got_w16.astype(jnp.float32)),
-            np.asarray(ref_w16.astype(jnp.float32)))
-        if finite == 0.0:
-            np.testing.assert_array_equal(np.asarray(got_w),
-                                          np.asarray(w))
+    states = tuple(np.abs(rng.randn(1, size)).astype(np.float32) * 0.1
+                   for _ in range(SLAB_STATE_SLOTS[kind]))
+    placed = {"fc_weight": jax.device_put(
+        w, trainer._sharding_for("fc_weight"))}
+    trainer._ensure_flat_plan(placed)
+    named = {"fc_weight": (None if not states else
+                           states[0] if len(states) == 1 else states)}
+    opt_state = {k: v for k, v in
+                 trainer.named_state_to_flat(named).items()
+                 if v is not None}
+    opt_state.update(trainer.build_amp_master_state(
+        {"fc_weight": w}, scale=128.0))
+    params = trainer.amp_cast_params(placed)
+    return trainer, params, opt_state, w, g, states
 
 
-@pytest.mark.parametrize("ndev,optname", [(2, "sgd"), (4, "adam"),
-                                          (8, "sgd")])
-def test_amp_kernel_vs_reference_fit(monkeypatch, ndev, optname):
-    """End-to-end: the slab kernel through the Pallas interpreter (the
-    kernel layer's one test seam) vs the jnp chain a CPU step runs, across
-    simulated device counts — same masters and working params to float
-    tolerance after a full fit."""
-    from mxnet_tpu.ops.kernels import common
+def _slabs(trainer, opt_state):
+    """Every master and state slab of ``opt_state``, on the host."""
+    import jax
+
+    return {k: jax.tree_util.tree_map(np.asarray, v)
+            for k, v in opt_state.items()
+            if k not in (trainer.AMP_SCALE_KEY, trainer.AMP_GOOD_KEY)}
+
+
+# (kind, dp, size): the parent's nine sizes (a shard under a tile, odd,
+# several tiles with a pad), then a shard of exactly one (16, 128) tile,
+# of many, and of the plan's own padded sizes, at dp 2 / 4 / 8
+_SLAB_CASES = [(kind, 2, size) for size in (131, 1024, 5000)
+               for kind in ("sgd", "sgd_mom", "adam")] + [
+    ("sgd_mom", 2, 2 * 2048), ("adam", 4, 4 * 2048), ("sgd", 8, 8 * 2048),
+    ("sgd_mom", 2, 24 * 2048), ("sgd_mom", 4, 3 * 4 * 2048 - 5),
+    ("adam", 8, 2 * 8 * 2048 + 1), ("sgd", 4, 40000),
+]
+
+
+@pytest.mark.parametrize("kind,ndev,size", _SLAB_CASES)
+def test_slab_kernel_matches_reference(monkeypatch, kind, ndev, size):
+    """The flat AMP update of ``_apply_optimizer_flat_amp`` on the plan's
+    whole-tile shards against ``slab_update`` on the plain arrays: new
+    master, new state and bf16 copy; the pad still zero after a step; a
+    non-finite step leaves every slab bit for bit what it was. (The name
+    is from when a Pallas kernel ran here, PR 60.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops.optimizer_ops import slab_update
+    from mxnet_tpu.parallel.train_step import _AMP_SHARD_ALIGN
 
     monkeypatch.setenv("MXTPU_AMP", "bf16")
     monkeypatch.setenv("MXTPU_SHARD_UPDATE", "1")
+    trainer, params, opt_state, w, g, states = _one_key_trainer(
+        kind, size, ndev)
+    (bucket,) = trainer._flat_plan.buckets
+    assert bucket.size == size
+    assert bucket.padded % (ndev * _AMP_SHARD_ALIGN) == 0
+    assert bucket.padded - size < ndev * _AMP_SHARD_ALIGN
+    before = _slabs(trainer, opt_state)
+    lr, t = jnp.float32(0.05), jnp.float32(3.0)
+    apply = jax.jit(lambda p, gr, st: trainer._apply_optimizer_flat_amp(
+        p, gr, st, lr, t))
+    o = trainer.optimizer
 
-    mod_r, met_r = _fit_mlp(ndev, optname, num_epoch=1)
-    ref = {k: np.asarray(v) for k, v in _masters(mod_r).items()}
+    def rule(w, g, states, finite):
+        lr_eff = lr
+        if kind == "adam":
+            lr_eff = lr * ((1.0 - o.beta2 ** t) ** 0.5
+                           / (1.0 - o.beta1 ** t))
+        return slab_update(
+            kind, w, g, states, lr_eff, jnp.float32(1.0) / 128.0, finite,
+            wd=o.wd, rescale_grad=o.rescale_grad, clip_gradient=-1.0,
+            momentum=getattr(o, "momentum", 0.0))
 
-    monkeypatch.setattr(common, "INTERPRET", True)
-    mod_k, met_k = _fit_mlp(ndev, optname, num_epoch=1)
-    got = {k: np.asarray(v) for k, v in _masters(mod_k).items()}
+    for poison in (False, True):
+        grad = g.at[0, size // 2].set(jnp.inf) if poison else g
+        new_params, new_state = apply(params, {"fc_weight": grad},
+                                      opt_state)
+        want_w, want_st, want_w16 = jax.jit(rule)(
+            w.reshape(-1), grad.reshape(-1),
+            tuple(s.reshape(-1) for s in states),
+            0.0 if poison else 1.0)
+        got = _slabs(trainer, new_state)
+        if poison:
+            for key, old in before.items():
+                for a, b in zip(jax.tree_util.tree_leaves(got[key]),
+                                jax.tree_util.tree_leaves(old)):
+                    np.testing.assert_array_equal(a, b, err_msg=key)
+            np.testing.assert_array_equal(
+                np.asarray(new_params["fc_weight"].astype(jnp.float32)),
+                np.asarray(params["fc_weight"].astype(jnp.float32)))
+            assert float(new_state[trainer.AMP_SCALE_KEY]) == 64.0
+            continue
+        master = got[trainer._master_key(0)]
+        np.testing.assert_array_equal(master[:size], np.asarray(want_w))
+        got_st = got.get(trainer._flat_key(0), ())
+        got_st = got_st if isinstance(got_st, tuple) else (got_st,)
+        assert len(got_st) == len(want_st)
+        for a, b in zip(got_st, want_st):
+            np.testing.assert_array_equal(a[:size], np.asarray(b))
+        np.testing.assert_array_equal(
+            np.asarray(new_params["fc_weight"].astype(jnp.float32))
+            .reshape(-1), np.asarray(want_w16.astype(jnp.float32)))
+        for slab in (master,) + tuple(got_st):   # the pad is still zeros
+            assert slab.shape == (bucket.padded,)
+            np.testing.assert_array_equal(slab[size:], 0)
 
-    assert sorted(got) == sorted(ref)
-    for k in ref:
-        np.testing.assert_allclose(got[k], ref[k], rtol=1e-5, atol=1e-7,
-                                   err_msg="%s drifted" % k)
-    assert abs(met_k.get()[1] - met_r.get()[1]) < 0.05
+
+@pytest.mark.parametrize("ndev,net", [(2, "lenet"), (4, "lenet"),
+                                      (4, "mlp_adam"), (8, "mlp_sgd")])
+def test_amp_replicated_is_bit_equal_to_shard(monkeypatch, ndev, net):
+    """MXTPU_SHARD_UPDATE=0 scans the same body over the same whole-tile
+    shard width on every replica: masters bit-equal to the sharded mode,
+    a 3x3 filter's slab order included."""
+    monkeypatch.setenv("MXTPU_AMP", "bf16")
+    got = {}
+    for mode, want in (("1", "shard"), ("0", "replicated")):
+        monkeypatch.setenv("MXTPU_SHARD_UPDATE", mode)
+        if net == "lenet":
+            mod, _ = _fit_lenet(ndev, num_epoch=2)
+        else:
+            mod, _ = _fit_mlp(ndev, net.split("_")[1], num_epoch=1)
+        assert mod._fused_owner._fused_trainer.flat_mode == want
+        got[mode] = {k: np.asarray(v) for k, v in _masters(mod).items()}
+    assert sorted(got["1"]) == sorted(got["0"])
+    for k, v in got["1"].items():
+        np.testing.assert_array_equal(v, got["0"][k], err_msg=k)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, nested ones included."""
+    import jax
+
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("kind", ["sgd", "sgd_mom", "adam"])
+def test_traced_flat_update_pads_and_slices_nothing(monkeypatch, kind):
+    """The mechanism's gauge, on four simulated devices: the traced
+    ``_apply_optimizer_flat_amp`` holds no ``pad``, no slice as large as a
+    shard (the views out are a key each) and one update a bucket, and it
+    counts itself once a trace and never a step."""
+    import jax
+    import jax.numpy as jnp
+
+    from jax.sharding import Mesh
+    from mxnet_tpu import optimizer as opt
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.parallel import ShardedTrainStep
+    from mxnet_tpu.parallel.train_step import _AMP_SHARD_ALIGN
+
+    monkeypatch.setenv("MXTPU_AMP", "bf16")
+    monkeypatch.setenv("MXTPU_SHARD_UPDATE", "1")
+    monkeypatch.setenv("MXTPU_BUCKET_BYTES", "128")   # several buckets
+    telemetry.enable()
+    try:
+        ndev, batch = 4, 16
+        name, extra = _SLAB_OPTS[kind]
+        net = _mlp_net()
+        mesh = Mesh(np.asarray(jax.devices()[:ndev]), ("dp",))
+        trainer = ShardedTrainStep(net, mesh, optimizer=opt.create(
+            name, learning_rate=0.1, rescale_grad=1.0 / batch,
+            **extra)).compile()
+        arg_shapes, _, _ = net.infer_shape(data=(batch, 8),
+                                           softmax_label=(batch,))
+        params, aux, state = trainer.init(
+            dict(zip(net.list_arguments(), arg_shapes)),
+            mx.initializer.Uniform(0.1))
+        plan = trainer._flat_plan
+        assert len(plan.buckets) > 1
+        shard = min(b.padded for b in plan.buckets) // ndev
+        assert shard % _AMP_SHARD_ALIGN == 0
+        labels = dict(form="slab", calls=len(plan.buckets),
+                      tile_rows=_AMP_SHARD_ALIGN // 128)
+        counter = telemetry.REGISTRY.get("train_step.flat_update_lowerings")
+        traces = counter.value(**labels)
+
+        grads = {k: jnp.ones_like(v) for k, v in params.items()}
+        jaxpr = jax.make_jaxpr(
+            lambda p, g, s, lr, t: trainer._apply_optimizer_flat_amp(
+                p, g, s, lr, t))(params, grads, state, jnp.float32(0.1),
+                                 jnp.float32(1.0))
+        assert counter.value(**labels) == traces + 1
+        eqns = list(_equations(jaxpr.jaxpr))
+        names = [e.primitive.name for e in eqns]
+        assert "pad" not in names
+        assert "pallas_call" not in names and "platform_index" not in names
+        assert names.count("shard_map") == len(plan.buckets)
+        for e in eqns:
+            if e.primitive.name in ("slice", "dynamic_slice", "gather"):
+                assert all(v.aval.size < shard for v in e.outvars), e
+
+        rng = np.random.RandomState(3)
+        X = rng.randn(batch, 8).astype(np.float32)
+        y = rng.randint(0, 4, batch).astype(np.float32)
+        traces = counter.value(**labels)
+        for t in (1, 2, 3):
+            params, aux, state, _ = trainer(
+                params, aux, state, _place_batch(trainer, X, y), t=t)
+        assert counter.value(**labels) == traces + 1   # the step's one trace
+    finally:
+        telemetry.disable()
+
+
+# ---------------------------------------------------------------------------
+# the order a key lies in its slab, and per-key checkpoints through it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,axes", [
+    ((7,), None), ((1000, 2048), None), ((2048, 512, 1, 1), None),
+    ((512, 512, 3, 3), (2, 3, 0, 1)), ((64, 3, 7, 7), (2, 3, 0, 1)),
+    ((8, 1, 3, 3), (1, 2, 3, 0)), ((16, 8), (1, 0)),
+    ((4, 3, 5, 200), None), ((6, 4, 3), (1, 2, 0)), ((512, 64, 3), (2, 0, 1)),
+])
+def test_slab_order_round_trips(shape, axes):
+    """Trailing axes narrower together than a row of lanes lie first in
+    the slab; whatever the order, a key comes back as it went in."""
+    from mxnet_tpu.parallel.train_step import (
+        _from_slab, _slab_axes, _to_slab)
+
+    assert _slab_axes(shape) == axes
+    small = tuple(min(d, 6) for d in shape)
+    if _slab_axes(small) != axes:   # the rule reads sizes: keep them
+        small = shape
+    x = np.arange(int(np.prod(small)), dtype=np.float32).reshape(small)
+    run = _to_slab(x)
+    assert run.shape == (x.size,)
+    if axes is None:
+        np.testing.assert_array_equal(run, x.reshape(-1))
+    else:
+        np.testing.assert_array_equal(run, x.transpose(axes).reshape(-1))
+    np.testing.assert_array_equal(_from_slab(run, small), x)
+
+
+def test_amp_per_key_checkpoint_from_another_layout(monkeypatch):
+    """A checkpoint is per key and row-major whatever wrote it (the
+    parent of PR 60 laid a filter into its slab as [O, I, kh, kw], this
+    tree as [kh, kw, O, I]): a blob built by hand from plain per-key
+    arrays restores at dp=4 into masters, momentum and bf16 copies that
+    read back bit for bit, and captures as the blob it came from."""
+    import jax.numpy as jnp
+
+    monkeypatch.setenv("MXTPU_AMP", "bf16")
+    mod, _ = _fit_lenet(4, num_epoch=1)
+    tr = mod._fused_owner._fused_trainer
+    assert tr.amp and tr.flat_mode == "shard"
+    plan = tr._flat_plan
+    assert any(len(shape) == 4 for b in plan.buckets
+               for (_i, _n, _o, _s, shape) in b.views)
+    rng = np.random.RandomState(11)
+    arg, aux = mod.get_params()
+    blob = {
+        "arg": {k: rng.randn(*v.shape).astype(np.float32)
+                for k, v in arg.items()},
+        "aux": {k: v.asnumpy() for k, v in aux.items()},
+        "opt": {"kind": "fused", "t": 7,
+                "state": {k: rng.randn(*v.shape).astype(np.float32)
+                          for k, v in arg.items()},
+                "amp": {"scale": 64.0, "good": 3.0}},
+    }
+    mod._restore_train_state(blob)
+    opt_state = mod._fused_owner._fused_opt
+    for name, m in tr.master_params_named(opt_state).items():
+        np.testing.assert_array_equal(np.asarray(m), blob["arg"][name])
+    for name, st in tr.flat_state_to_named(opt_state).items():
+        np.testing.assert_array_equal(np.asarray(st),
+                                      blob["opt"]["state"][name])
+    for name, p in mod._fused_owner._fused_params.items():
+        assert p.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(p.astype(jnp.float32)),
+            np.asarray(jnp.asarray(blob["arg"][name], jnp.bfloat16)
+                       .astype(jnp.float32)))
+    for bi, b in enumerate(plan.buckets):   # the pad is zeros
+        for key in (tr._master_key(bi), tr._flat_key(bi)):
+            np.testing.assert_array_equal(
+                np.asarray(opt_state[key])[b.size:], 0)
+    again = mod._capture_train_state()
+    for name in blob["arg"]:
+        np.testing.assert_array_equal(np.asarray(again["arg"][name]),
+                                      blob["arg"][name])
+        np.testing.assert_array_equal(
+            np.asarray(again["opt"]["state"][name]),
+            blob["opt"]["state"][name])
+    assert float(np.asarray(again["opt"]["amp"]["scale"])) == 64.0
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import ndarray as nd
 from mxnet_tpu import telemetry as tm
 from mxnet_tpu.parallel import make_mesh
-from mxnet_tpu.parallel.train_step import ShardedTrainStep
+from mxnet_tpu.parallel.train_step import ShardedTrainStep, _from_slab
 
 
 @pytest.fixture(autouse=True)
@@ -245,7 +245,7 @@ def test_amp_master_slabs_are_packed_on_the_mesh(monkeypatch):
                                       np.asarray(host[key]))
         for (_i, n, off, size, shape) in b.views:
             np.testing.assert_array_equal(
-                np.asarray(state[key])[off:off + size].reshape(shape),
+                _from_slab(np.asarray(state[key])[off:off + size], shape),
                 np.asarray(params[n]))
         np.testing.assert_array_equal(np.asarray(state[key])[b.size:], 0)
 

@@ -1056,20 +1056,6 @@ def _switch_case(name):
                 (draw(b, t, heads * (nope + rp), scale=0.3),
                  draw(b, t, heads * (nope + dv), scale=0.3),
                  draw(b, t, rp, scale=0.3)), (0, 1, 2))
-    if name == "fused_slab_update":
-        size = 3000
-        kw = dict(wd=1e-4, rescale_grad=1.0 / 32, clip_gradient=None,
-                  momentum=0.9)
-        args = (draw(size), draw(size, scale=4, dtype=jnp.bfloat16),
-                (draw(size, scale=0.1),))
-
-        def entry(w, g, states, interpret):
-            return pk.fused_slab_update(
-                "sgd_mom", w, g, states, 0.05, 1.0 / 128, 1.0,
-                interpret=interpret, **kw)
-        return (entry, lambda w, g, states: pk.slab_update_reference(
-                    "sgd_mom", w, g, states, 0.05, 1.0 / 128, 1.0, **kw),
-                args, ())
     dshape, wshape, pad = (2, 8, 10, 10), (16, 8, 3, 3), (1, 1)
     x, w, g = draw(*dshape), draw(*wshape, scale=0.1), draw(2, 16, 10, 10)
 
@@ -1088,7 +1074,7 @@ def _switch_case(name):
 
 
 SWITCH_CASES = ("attention", "conv_bwd_filter", "conv_bwd_input",
-                "flash_attention", "fused_slab_update", "gated_delta_rule",
+                "flash_attention", "gated_delta_rule",
                 "grouped_matmul", "latent_flash", "ssd_scan")
 
 

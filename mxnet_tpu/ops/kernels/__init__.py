@@ -45,7 +45,7 @@ from .gate_norm import gate_norm_takes, gated_rms_norm
 from .gdn import channel_delta_net, gated_delta_rule, gdn_takes
 from .gmm import (
     gmm_metadata, gmm_row_tile, gmm_runs_kernel, gmm_tiles, grouped_matmul,
-    sorted_segment_sum)
+    held_transposed, sorted_segment_sum)
 from .latent import (
     latent_flash, latent_flash_takes, latent_query, latent_query_takes)
 from .ssd import ssd_scan, ssd_takes
@@ -58,7 +58,8 @@ __all__ = [
     "flash_tiles", "gate_norm_takes",
     "gated_delta_rule", "gated_rms_norm", "gdn_takes",
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
-    "grouped_matmul", "latent_flash", "latent_flash_takes", "latent_query",
+    "grouped_matmul", "held_transposed", "latent_flash",
+    "latent_flash_takes", "latent_query",
     "latent_query_takes", "reference_attention", "sorted_segment_sum",
     "ssd_scan", "ssd_takes", "taps_takes",
 ]

@@ -45,7 +45,9 @@ from .common import (
 _M_GMM_LOWERINGS = _tm.counter(
     "moe.gmm_lowerings", "Traces of a grouped_matmul kernel call site "
     "(one per lowering, nothing per step); labels: mode (fwd / dgrad / "
-    "wgrad), operands (the type the MXU is fed), tm, tk, tn")
+    "wgrad), operands (the type the MXU is fed), tm, tk, tn and, on a "
+    "product over a weight, rhs (declared / held_transposed: the order "
+    "the weight reached the kernels in)")
 
 # Row tiles by measurement on the v5e (PERF.md section 7): 128 rows feed
 # the MXU at 61% of its peak, 256 at 67%, 512 at 74%, but a tile of the
@@ -369,48 +371,79 @@ def gmm_wgrad_call(offsets, gids, tids, visits, lhs, dout, *, groups,
         )(offsets, gids, tids, lhs, dout)
 
 
-def _gmm_count(mode, dtype, tiles):
+def _gmm_count(mode, dtype, tiles, rhs_t=None):
+    """``rhs_t``: whether the product's weight came held transposed; None
+    where there is no weight (a segment sum)."""
+    rhs = {} if rhs_t is None else {
+        "rhs": "held_transposed" if rhs_t else "declared"}
     _M_GMM_LOWERINGS.inc(mode=mode, operands=operand_label(dtype),
-                         tm=tiles[0], tk=tiles[1], tn=tiles[2])
+                         tm=tiles[0], tk=tiles[1], tn=tiles[2], **rhs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _gmm(lhs, rhs, group_sizes, meta, plan, interpret):
-    return _gmm_fwd(lhs, rhs, group_sizes, meta, plan, interpret)[0]
+def held_transposed(shape):
+    """Whether the chip holds a ``[g, k, n]`` weight with ``k`` minor, as
+    ``[g, n, k]`` row-major: the TPU's compiler lays an entry parameter
+    whose last dimension is no multiple of a lane row and whose middle
+    one is with the middle one minor (so do the optimizer's states of its
+    shape), and a Mosaic operand, which is row-major, then costs a copy
+    of the whole array each way, every step, unless the kernels take the
+    swap of its last two axes (``grouped_matmul(rhs_transposed=True)``),
+    which is a bitcast of what is held."""
+    _, k, n = shape
+    return n % LANES != 0 and k % LANES == 0
 
 
-def _gmm_fwd(lhs, rhs, group_sizes, meta, plan, interpret):
-    tiles = plan[0]
-    _gmm_count("fwd", lhs.dtype, tiles)
+def _ragged(lhs, rhs, group_sizes, rhs_t):
+    return jax.lax.ragged_dot(
+        lhs, jnp.swapaxes(rhs, 1, 2) if rhs_t else rhs, group_sizes)
+
+
+# ``plan`` holds the tiles of the three kernels over the weight AS IT IS
+# HELD, ``[g, a, b]``: (rows of ``a`` columns times it, rows of ``b``
+# columns times its transposed blocks, the ragged contraction into its
+# shape). ``rhs_t`` says which of the first two is the forward.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _gmm(lhs, rhs, group_sizes, meta, plan, rhs_t, interpret):
+    return _gmm_fwd(lhs, rhs, group_sizes, meta, plan, rhs_t, interpret)[0]
+
+
+def _gmm_fwd(lhs, rhs, group_sizes, meta, plan, rhs_t, interpret):
+    tiles = plan[1 if rhs_t else 0]
+    _gmm_count("fwd", lhs.dtype, tiles, rhs_t)
 
     def kernels(lhs, rhs, group_sizes, meta, interpret):
-        return gmm_call(*meta[:4], lhs, rhs, tiles=tiles, transposed=False,
+        return gmm_call(*meta[:4], lhs, rhs, tiles=tiles, transposed=rhs_t,
                         interpret=interpret)
 
     def ragged(lhs, rhs, group_sizes, meta):
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
+        return _ragged(lhs, rhs, group_sizes, rhs_t)
 
     out = on_tpu(kernels, ragged, interpret, lhs, rhs, group_sizes, meta)
     return out, (lhs, rhs, group_sizes, meta)
 
 
-def _gmm_bwd(plan, interpret, res, dout):
+def _gmm_bwd(plan, rhs_t, interpret, res, dout):
     lhs, rhs, group_sizes, meta = res
-    _, dgrad_tiles, wgrad_tiles = plan
-    _gmm_count("dgrad", lhs.dtype, dgrad_tiles)
-    _gmm_count("wgrad", lhs.dtype, wgrad_tiles)
+    dgrad_tiles, wgrad_tiles = plan[0 if rhs_t else 1], plan[2]
+    _gmm_count("dgrad", lhs.dtype, dgrad_tiles, rhs_t)
+    _gmm_count("wgrad", lhs.dtype, wgrad_tiles, rhs_t)
 
     def kernels(lhs, rhs, dout, group_sizes, meta, interpret):
         offsets, gids, tids, visits, w_gids, w_tids, w_visits = meta
+        # the ragged contraction writes the weight's held shape: rows of
+        # its middle dimension's columns first
+        rows = (dout, lhs) if rhs_t else (lhs, dout)
         return (
             gmm_call(offsets, gids, tids, visits, dout, rhs,
-                     tiles=dgrad_tiles, transposed=True, interpret=interpret),
-            gmm_wgrad_call(offsets, w_gids, w_tids, w_visits, lhs, dout,
+                     tiles=dgrad_tiles, transposed=not rhs_t,
+                     interpret=interpret),
+            gmm_wgrad_call(offsets, w_gids, w_tids, w_visits, *rows,
                            groups=rhs.shape[0], tiles=wgrad_tiles,
                            interpret=interpret))
 
     def ragged(lhs, rhs, dout, group_sizes, meta):
-        return jax.vjp(lambda l, r: jax.lax.ragged_dot(l, r, group_sizes),
+        return jax.vjp(lambda l, r: _ragged(l, r, group_sizes, rhs_t),
                        lhs, rhs)[1](dout)
 
     dlhs, drhs = on_tpu(kernels, ragged, interpret, lhs, rhs,
@@ -429,11 +462,18 @@ def gmm_runs_kernel(m, dtype):
             and jnp.dtype(dtype).name in ("bfloat16", "float32"))
 
 
-def grouped_matmul(lhs, rhs, group_sizes, metadata=None, interpret=False):
+def grouped_matmul(lhs, rhs, group_sizes, metadata=None, interpret=False,
+                   rhs_transposed=False):
     """``lhs[m, k]`` sorted by group, ``rhs[g, k, n]``, ``group_sizes[g]``
     (int32, summing to m) -> ``[m, n]``: row r of group i times
     ``rhs[i]``, what ``jax.lax.ragged_dot`` computes, differentiable in
-    ``lhs`` and ``rhs``.
+    ``lhs`` and ``rhs``. With ``rhs_transposed`` the weight comes held
+    transposed, ``rhs[g, n, k]``, and row r is multiplied by
+    ``rhs[i]^T``: the same three kernels over the array as it is held
+    (the forward indexes the transposed weight block, as dgrad does of a
+    declared one; dgrad the straight one; wgrad writes ``[g, n, k]``),
+    at the tiles a declared ``[g, n, k]`` weight has. For a weight that
+    ``held_transposed`` is true of.
 
     Three Pallas kernels (forward, dgrad over the transposed weight
     block, wgrad with the ragged contraction) with tiles from
@@ -450,19 +490,20 @@ def grouped_matmul(lhs, rhs, group_sizes, metadata=None, interpret=False):
     otherwise. The weight gradient of a group with no rows is exactly
     zero. Like ``flash_attention``, the kernels have no partitioning
     rule: inside a sharded ``jit``, call under ``shard_map``."""
-    m, k = lhs.shape
-    groups, _, n = rhs.shape
+    m = lhs.shape[0]
+    groups, a, b = rhs.shape
+    rhs_t = bool(rhs_transposed)
     dtype = jnp.result_type(lhs.dtype, rhs.dtype)
     if not gmm_runs_kernel(m, dtype):
-        return jax.lax.ragged_dot(lhs, rhs, group_sizes)
-    tiles = gmm_tiles(m, k, n, groups, dtype)
-    tn_d, tk_d = gmm_tiles(m, n, k, groups, dtype)[1:]
+        return _ragged(lhs, rhs, group_sizes, rhs_t)
+    tiles = gmm_tiles(m, a, b, groups, dtype)
+    tn_d, tk_d = gmm_tiles(m, b, a, groups, dtype)[1:]
     if metadata is None:
         metadata = gmm_metadata(group_sizes, m, tiles[0])
     plan = (tiles, (tiles[0], tk_d, tn_d),
-            gmm_tiles(m, k, n, groups, dtype, wgrad=True))
+            gmm_tiles(m, a, b, groups, dtype, wgrad=True))
     return _gmm(lhs.astype(dtype), rhs.astype(dtype), group_sizes,
-                tuple(metadata), plan, bool(interpret))
+                tuple(metadata), plan, rhs_t, bool(interpret))
 
 
 # Tokens a group of ``sorted_segment_sum``: the contraction's ``k``, one

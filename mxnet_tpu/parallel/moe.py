@@ -108,7 +108,12 @@ def _expert_dot(counts, rows, dtype):
     run where the step is lowered for the TPU and ``jax.lax.ragged_dot``
     on every other platform (and at shapes the kernels do not take). The
     kernels' group metadata is made once here and shared by every product
-    (and its two transposes) that the returned function is used for."""
+    (and its two transposes) that the returned function is used for. A
+    weight that the chip holds transposed (``kernels.held_transposed``,
+    from its shape: Nemotron-H's un-gated ``[E, 2688, 1856]``) reaches the
+    kernels as the swap of its last two axes, a bitcast of what is held,
+    where the declared order costs a copy of the weight, of its gradient
+    and of the optimizer's state each step."""
     from ..ops import kernels
 
     groups = counts.shape[0]
@@ -116,9 +121,17 @@ def _expert_dot(counts, rows, dtype):
         return lambda lhs, rhs: jax.lax.ragged_dot(lhs, rhs, counts)
     metadata = kernels.gmm_metadata(
         counts, rows, kernels.gmm_row_tile(rows, groups))
-    return functools.partial(kernels.grouped_matmul, group_sizes=counts,
-                             metadata=metadata,
-                             interpret=kernels.common.INTERPRET)
+    product = functools.partial(kernels.grouped_matmul, group_sizes=counts,
+                                metadata=metadata,
+                                interpret=kernels.common.INTERPRET)
+
+    def dot(lhs, rhs):
+        if kernels.held_transposed(rhs.shape):
+            return product(lhs, jnp.swapaxes(rhs, 1, 2),
+                           rhs_transposed=True)
+        return product(lhs, rhs)
+
+    return dot
 
 
 def _experts(params, rows, counts, activation, scale=None):

@@ -447,6 +447,96 @@ def test_gmm_chosen_tiles_compile_for_v5e(one_chip, m, k, n, groups, dtype):
     assert "ragged_dot_tiling" not in text and "ragged-dot" not in text
 
 
+# (held experts, d_model, columns of the up product, activation, rows):
+# the Nemotron-3-Nano share cell's un-gated experts, whose up weight
+# [16, 2688, 1856] the chip holds with 2,688 minor (1,856 is 14.5 lane
+# rows); OLMoE's and the Kimi Linear share cell's SwiGLU experts, whole
+# lane rows every way
+EXPERT_STEPS = {
+    "nemotron3_nano": (16, 2688, 1856, "relu2", 12288),
+    "olmoe": (64, 2048, 2048, "swiglu", 32768),
+    "kimi_linear": (8, 2304, 2048, "swiglu", 6144),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(EXPERT_STEPS))
+def test_an_expert_layers_step_copies_no_weight_on_v5e(one_chip, cell):
+    """A step shaped like a cell's over one expert layer's products
+    (``parallel/moe.py::_experts`` on a share's path, its gradient, SGD
+    with float32 momentum; both weights and both momenta donated entry
+    parameters written back) as the TPU's compiler leaves it. Nemotron's
+    up weight lies ``{1,2,0}`` in the entry layout, ``[16, 1856, 2688]``
+    row-major, and so does its momentum; handed to the kernels as that
+    (``held_transposed``), nothing the size of the weight is copied or
+    transposed on the way in or out (the declared order costs four copies
+    a block: PERF.md section 7), and the up product runs the three kernel
+    signatures of the down product, roles permuted. The other cells'
+    weights go as they are declared, under the names ``GMM_SHAPES``
+    asserts."""
+    from mxnet_tpu.parallel import moe
+
+    experts, d, columns, activation, rows = EXPERT_STEPS[cell]
+    hidden = columns if activation == "relu2" else columns // 2
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w = {"w_gate_up": spec((experts, d, columns)),
+         "w_down": spec((experts, hidden, d))}
+    mom = {name: spec(v.shape, jnp.float32) for name, v in w.items()}
+
+    def step(w, mom, x, counts, scale):
+        def loss(w, x):
+            return jnp.sum(moe._experts(w, x, counts, activation, scale)
+                           .astype(jnp.float32) ** 2)
+
+        grads, dx = jax.grad(loss, argnums=(0, 1))(w, x)
+        mom = {name: 0.9 * mom[name] + grads[name].astype(jnp.float32)
+               for name in w}
+        w = {name: (w[name].astype(jnp.float32)
+                    - 0.01 * mom[name]).astype(w[name].dtype) for name in w}
+        return w, mom, dx
+
+    text = jax.jit(step, donate_argnums=(0, 1)).lower(
+        w, mom, spec((rows, d)), spec((experts,), jnp.int32),
+        spec((rows,), jnp.float32)).compile().as_text()
+    calls = re.findall(r"= \S+ custom-call\(.*?op_name=\"[^\"]*?/"
+                       r"(gmm_\w+)/pallas_call", text)
+    bf16 = jnp.bfloat16
+    tm = pk.gmm_row_tile(rows, experts)
+
+    def names(k, n):
+        """The three kernels over a weight declared (and held) [g, k, n]."""
+        _, tn_d, tk_d = gmm_tiles(rows, n, k, experts, bf16)
+        return sorted("gmm_%s_bf16_m%d_k%d_n%d" % ((mode,) + tiles)
+                      for mode, tiles in (
+                          ("fwd", gmm_tiles(rows, k, n, experts, bf16)),
+                          ("dgrad", (tm, tk_d, tn_d)),
+                          ("wgrad", gmm_tiles(rows, k, n, experts, bf16,
+                                              wgrad=True))))
+
+    moved = re.findall(r"= (\w+\[[\d,]+\])\S* (?:copy|transpose)\(", text)
+    sized = [m for m in moved if str(columns) in m and str(d) in m
+             and m.count(",") == 2]
+    entry = text.splitlines()[0]
+    if cell == "nemotron3_nano":
+        assert pk.held_transposed((experts, d, columns))
+        # the entry layout: both arrays twice (in, and the donated out)
+        for held in ("bf16[16,2688,1856]{1,2,0:", "f32[16,2688,1856]{1,2,0:"):
+            assert entry.count(held) == 2, held
+        assert not sized, sized
+        # the up product's three ARE the down product's
+        assert sorted(calls) == sorted(2 * names(hidden, d))
+        assert sorted(set(calls)) == [
+            "gmm_dgrad_bf16_m256_k1856_n896", "gmm_fwd_bf16_m256_k1856_n896",
+            "gmm_wgrad_bf16_m256_k1856_n384"]
+    else:
+        assert not pk.held_transposed((experts, d, columns))
+        assert "{1,2,0:" not in entry
+        assert sorted(calls) == sorted(names(d, columns) + names(hidden, d))
+    assert "ragged_dot_tiling" not in text and "ragged-dot" not in text
+
+
 def test_expert_layer_moves_rows_by_gathers_on_v5e(one_chip):
     """One OLMoE expert layer at the cell's shape (4,096 tokens of 2,048,
     top-8 of 64 experts of width 1,024, bf16), forward and backward, as

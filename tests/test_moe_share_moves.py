@@ -355,3 +355,164 @@ def test_a_share_models_step_scatters_nothing_in_its_expert_layers(
         # layers call it: what is counted is shapes, not calls)
         assert {s for s, _ in sums} == set(summed)
         assert all("indices_are_sorted=true" in rest for _, rest in sums)
+
+
+# -- a weight handed to the kernels in the order the chip holds it -----------
+
+def _plain_experts(params, rows, sizes, activation, scale):
+    """``_experts`` in float32 over ``ragged_dot``, the weights as they are
+    declared."""
+    dot = lambda lhs, w: jax.lax.ragged_dot(
+        lhs, w, sizes, precision=jax.lax.Precision.HIGHEST)
+    up = dot(rows, params["w_gate_up"])
+    if activation == "relu2":
+        act = jnp.square(jax.nn.relu(up))
+    else:
+        half = up.shape[1] // 2
+        act = jax.nn.silu(up[:, :half]) * up[:, half:]
+    if scale is not None:
+        act = act * scale[:, None]
+    return dot(act, params["w_down"])
+
+
+# (d_model, columns of the up product, which weights the chip holds
+# transposed): Nemotron's kind (the up columns a lane row and a half), the
+# other cells' (whole lane rows both), and the same rule met by ``w_down``
+EXPERT_WIDTHS = {
+    "up_held_transposed": (256, 192, ["w_gate_up"]),
+    "declared": (256, 128, []),
+    "down_held_transposed": (192, 256, ["w_down"]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("scaled", [False, True], ids=["full", "share"])
+@pytest.mark.parametrize("activation", ["relu2", "swiglu"])
+@pytest.mark.parametrize("widths", sorted(EXPERT_WIDTHS))
+def test_the_experts_take_a_weight_in_the_order_the_chip_holds_it(
+        widths, activation, scaled, dtype, path):
+    """``_experts`` on the full path and on a share's (``scale``) against
+    the plain float32 products over the declared weights: the value and
+    the gradient of every input, to the kernels' own tolerances, whether
+    ``held_transposed`` fires for a weight (it then reaches
+    ``grouped_matmul`` as the swap of its last two axes) or for none; the
+    gradients come back in the declared shapes."""
+    d, columns, held = EXPERT_WIDTHS[widths]
+    hidden = columns if activation == "relu2" else columns // 2
+    sizes = [3, 0, 210, 7, 160, 4]
+    rng = np.random.RandomState(7)
+    params = {
+        "w_gate_up": jnp.asarray(
+            rng.randn(len(sizes), d, columns) / np.sqrt(d), jnp.float32),
+        "w_down": jnp.asarray(
+            rng.randn(len(sizes), hidden, d) / np.sqrt(hidden), jnp.float32)}
+    assert [name for name in sorted(params)
+            if kernels.held_transposed(params[name].shape)] == held
+    rows = jnp.asarray(rng.randn(sum(sizes), d), jnp.float32)
+    scale = (jnp.asarray(rng.rand(sum(sizes)) + 0.1, jnp.float32)
+             if scaled else None)
+    cot = jnp.asarray(rng.randn(sum(sizes), d), jnp.float32)
+    counts = jnp.asarray(sizes, jnp.int32)
+
+    def run(fn, params, rows):
+        def loss(params, rows, scale):
+            out = fn(params, rows, counts, activation, scale)
+            return jnp.sum(out.astype(jnp.float32) * cot), out
+        argnums = (0, 1, 2) if scaled else (0, 1)
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=argnums, has_aux=True)(params, rows, scale)
+        return [out, grads[1], grads[0]["w_gate_up"], grads[0]["w_down"]
+                ] + list(grads[2:])
+
+    want = run(_plain_experts, params, rows)
+    low = jax.tree_util.tree_map(lambda a: a.astype(dtype), (params, rows))
+    got = run(moe._experts, *low)
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    names = ("out", "d rows", "d w_gate_up", "d w_down", "d scale")
+    for name, g, w in zip(names, got, want):
+        assert g.shape == w.shape, name
+        assert g.dtype == (jnp.float32 if name == "d scale" else dtype)
+        assert _off(g, w) < tol, (name, _off(g, w))
+    # an expert with no rows: both its weights' gradients exactly zero
+    assert not np.asarray(got[2][1], np.float32).any()
+    assert not np.asarray(got[3][1], np.float32).any()
+
+
+def _benchmark_expert_layers():
+    """(configuration, w_gate_up shape, w_down shape, rows of the experts'
+    products) of every benchmark configuration that has expert layers, at
+    its published widths, from the symbol its factory builds (shapes
+    only)."""
+    import glob
+    import importlib
+    import json
+    import os
+
+    root = os.path.join(os.path.dirname(__file__), "..", "bench", "configs")
+    out = []
+    for path in sorted(glob.glob(os.path.join(root, "*.json"))):
+        with open(path) as f:
+            cfg = json.load(f)
+        module, _, factory = cfg["factory"].partition(":")
+        if factory != "from_config":
+            continue
+        sym = getattr(importlib.import_module(module), factory)(
+            cfg, **cfg["kwargs"])
+        batch, _, t = cfg["input_shape"]
+        shapes, _, _ = sym.infer_shape(data=(batch, t),
+                                       softmax_label=(batch, t))
+        by_name = dict(zip(sym.list_arguments(), shapes))
+        ups = sorted(n for n in by_name if n.endswith("moe_gate_up_weight"))
+        if not ups:
+            continue
+        share = cfg.get("share") or {}
+        rows = share.get("share_rows_bound") or (
+            batch * t * cfg["num_experts_per_tok"])
+        out.append((os.path.basename(path)[:-len(".json")], by_name[ups[0]],
+                    by_name[ups[0].replace("gate_up", "down")], rows))
+    return out
+
+
+def test_only_nemotrons_up_product_is_handed_over_held_transposed():
+    """``moe.gmm_lowerings{rhs}`` over one expert layer of every benchmark
+    configuration at its published widths, forward and backward, by
+    ``jax.eval_shape`` (shapes only, nothing computed): Nemotron-3-Nano's
+    un-gated up product (2,688 -> 1,856 = 14.5 lane rows) counts its three
+    modes as ``held_transposed`` and its down product as ``declared``;
+    in every other configuration both widths of both weights are whole
+    lane rows and all six are ``declared``."""
+    layers = _benchmark_expert_layers()
+    assert len(layers) == 8, [name for name, *_ in layers]
+    spec = jax.ShapeDtypeStruct
+    for name, up, down, rows in layers:
+        activation = "relu2" if up[2] == down[1] else "swiglu"
+        params = {"w_gate_up": spec(up, jnp.bfloat16),
+                  "w_down": spec(down, jnp.bfloat16)}
+
+        def grads(params, x, counts, scale):
+            return jax.grad(lambda p, x: jnp.sum(moe._experts(
+                p, x, counts, activation, scale).astype(jnp.float32)),
+                argnums=(0, 1))(params, x)
+
+        telemetry.reset()
+        telemetry.enable()
+        try:
+            got = jax.eval_shape(
+                grads, params, spec((rows, up[1]), jnp.bfloat16),
+                spec((up[0],), jnp.int32), spec((rows,), jnp.float32))
+            c = telemetry.REGISTRY.get("moe.gmm_lowerings")
+            by_rhs = {"declared": 0, "held_transposed": 0}
+            for key in c.label_sets():
+                by_rhs[dict(key)["rhs"]] += c.value(**dict(key))
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+        # the gradients keep the declared shapes
+        assert got[0]["w_gate_up"].shape == up, name
+        assert got[0]["w_down"].shape == down, name
+        held = 3 if name == "nemotron_3_nano_30b_a3b" else 0
+        assert by_rhs == {"declared": 6 - held,
+                          "held_transposed": held}, (name, by_rhs)
+        assert kernels.held_transposed(up) == bool(held), name
+        assert not kernels.held_transposed(down), name

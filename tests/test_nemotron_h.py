@@ -60,6 +60,10 @@ CFG = dict(
 # on, a buffer that holds every row
 SHARE = dict(CFG, n_routed_experts=4, share=dict(
     experts_of=16, expert_offset=8, share_rows_bound=BATCH * T * 3))
+# the share at a hidden size of one whole lane row over experts of 24: the
+# published widths' kind (2,688 over 1,856), an up weight the chip holds
+# transposed (``ops.kernels.held_transposed``)
+SHARE_WIDE = dict(SHARE, hidden_size=128)
 EXPERT_LAYERS, MAMBA_LAYERS = 2, 2
 SSM = {k: CFG[k] for k in ("mamba_num_heads", "mamba_head_dim", "n_groups",
                            "ssm_state_size", "layer_norm_epsilon")}
@@ -121,10 +125,15 @@ def _module(sym, params):
 
 # -- the whole model, uncut and as a share -----------------------------------
 
-@pytest.mark.parametrize("cfg", [CFG, SHARE], ids=["whole", "share"])
+@pytest.mark.parametrize("cfg", [CFG, SHARE, SHARE_WIDE],
+                         ids=["whole", "share", "share_held_transposed"])
 def test_logits_loss_and_every_gradient_match_the_reference(cfg):
     sym = nemotron_h.from_config(cfg, seq_len=T)
     params = _params(sym, 1)
+    from mxnet_tpu.ops import kernels
+
+    assert kernels.held_transposed(
+        params["layer1_moe_gate_up_weight"].shape) == (cfg is SHARE_WIDE)
     tokens, labels = _batch(2)
     want = ref.forward(params, tokens, cfg, labels=labels)
     loss, grads = ref.loss_and_grads(params, tokens, labels, cfg)
@@ -563,12 +572,24 @@ def _held(w, offset, held):
                 w_down=w["w_down"][offset:offset + held])
 
 
+@pytest.mark.parametrize("d,interpret", [(48, False), (128, False),
+                                         (128, True)],
+                         ids=["declared", "held_transposed",
+                              "held_transposed_kernels"])
 @pytest.mark.parametrize("held", [16, 4], ids=["whole_layer", "share"])
-def test_relu2_experts_match_the_reference(held):
+def test_relu2_experts_match_the_reference(held, d, interpret, monkeypatch):
     """``activation="relu2"``: ``w_gate_up`` is the up projection alone
     ([E, d, h]), an expert is ``down(relu(up(x))^2)``; output and the
-    gradient of every input, whole and as a share."""
-    x, w = _moe_weights(0)
+    gradient of every input, whole and as a share; at the tiny hidden
+    size and at one of a whole lane row over experts of 24, the
+    published widths' kind (2,688 over 1,856), which the chip holds
+    transposed and the grouped matmul takes so (here its ``ragged_dot``
+    branch over the swap, and its kernels through the interpreter)."""
+    from mxnet_tpu.ops import kernels
+
+    monkeypatch.setattr(kernels.common, "INTERPRET", interpret)
+    x, w = _moe_weights(0, d=d)
+    assert kernels.held_transposed(w["w_gate_up"].shape) == (d == 128)
     offset = 8 if held < 16 else 0
     part = _held(w, offset, held)
 

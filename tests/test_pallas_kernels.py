@@ -801,6 +801,87 @@ def test_grouped_matmul_matches_the_dense_product(load, dtype, tol):
             assert not np.asarray(got[1][g], np.float32).any(), g
 
 
+# (k, n) of the declared weight: 256 x 192 is held transposed (192 is a
+# lane row and a half, 256 two), the other two are not (both whole lane
+# rows; neither)
+HELD_WIDTHS = [(256, 192), (256, 384), (48, 160)]
+
+
+@pytest.mark.parametrize("k,n", HELD_WIDTHS)
+def test_held_transposed_reads_the_shape_alone(k, n):
+    assert pk.held_transposed((4, k, n)) == ((k, n) == (256, 192))
+    # the swap of a held-transposed weight is a declared one
+    assert not pk.held_transposed((4, 192, 256))
+    assert pk.held_transposed((16, 2688, 1856))
+    assert not pk.held_transposed((16, 1856, 2688))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-2)])
+@pytest.mark.parametrize("k,n", HELD_WIDTHS)
+@pytest.mark.parametrize("load", ["cell_skew", "straddles_tiles"])
+def test_held_transposed_product_matches_ragged_dot(load, k, n, dtype, tol):
+    """``grouped_matmul(lhs, swap(rhs), rhs_transposed=True)`` against
+    ``jax.lax.ragged_dot(lhs, rhs)`` in float32: the value, the rows'
+    gradient and the weight's (in the held order, ``[g, n, k]``), whatever
+    ``held_transposed`` says of the widths; an empty group's weight
+    gradient exactly zero."""
+    sizes = GMM_SIZES[load]
+    lhs, rhs, group_sizes = _gmm_inputs(sizes, k, n, dtype, seed=5)
+    held = jnp.swapaxes(rhs, 1, 2)
+    cot = jnp.asarray(
+        np.random.RandomState(6).randn(sum(sizes), n), jnp.float32)
+
+    def loss(fn):
+        return lambda l, r: jnp.sum(fn(l, r).astype(jnp.float32) * cot)
+
+    kernel = lambda l, r: grouped_matmul(l, r, group_sizes,
+                                         rhs_transposed=True)
+    plain = lambda l, r: jax.lax.ragged_dot(
+        l, r, group_sizes, precision=jax.lax.Precision.HIGHEST)
+    wide = lhs.astype(jnp.float32), rhs.astype(jnp.float32)
+    out = kernel(lhs, held)
+    assert out.dtype == dtype and out.shape == (sum(sizes), n)
+    assert _rel(out, plain(*wide)) < tol
+    got = jax.grad(loss(kernel), argnums=(0, 1))(lhs, held)
+    want = jax.grad(loss(plain), argnums=(0, 1))(*wide)
+    assert got[1].shape == held.shape and got[1].dtype == dtype
+    assert _rel(got[0], want[0]) < 2 * tol
+    assert _rel(got[1], jnp.swapaxes(want[1], 1, 2)) < 2 * tol
+    for g, size in enumerate(sizes):
+        if size == 0:
+            assert not np.asarray(got[1][g], np.float32).any(), g
+
+
+def test_held_transposed_product_runs_the_same_kernels_with_roles_swapped():
+    """Over a weight held ``[g, n, k]`` the forward is the kernel that
+    indexes the transposed block (named ``gmm_dgrad_*``: the name says
+    the indexing, not the pass), the rows' gradient the straight one, the
+    weight's the ragged contraction into ``[g, n, k]``: the three
+    signatures, tiles and all, of a declared ``[g, n, k]`` weight; small
+    calls go to ``ragged_dot`` over the swap."""
+    sizes = GMM_SIZES["straddles_tiles"]
+    lhs, rhs, group_sizes = _gmm_inputs(sizes, 256, 192, jnp.bfloat16)
+    held = jnp.swapaxes(rhs, 1, 2)
+
+    def kernels_of(fn, *args):
+        return sorted(_dots_by_kernel(jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+            argnums=(0, 1)))(*args)))
+
+    across = kernels_of(lambda l, r: grouped_matmul(
+        l, r, group_sizes, rhs_transposed=True), lhs, held)
+    straight = kernels_of(
+        lambda l, r: grouped_matmul(l, r, group_sizes),
+        jnp.zeros((sum(sizes), 192), jnp.bfloat16), held)
+    assert across == straight and len(across) == 3
+    small = _gmm_inputs([10, 0, 30], 256, 192, jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda l, r: grouped_matmul(
+        l, r, small[2], rhs_transposed=True))(
+            small[0], jnp.swapaxes(small[1], 1, 2))
+    assert "pallas_call" not in str(jaxpr) and "ragged_dot" in str(jaxpr)
+
+
 def test_grouped_matmul_splits_k_and_n_like_the_whole():
     """A contraction walked in several k steps (the float32 scratch
     accumulator) and several n tiles against the whole-k, whole-n call."""
@@ -940,10 +1021,20 @@ def test_gmm_lowerings_counter_counts_one_per_call_site_and_mode():
         c = telemetry.REGISTRY.get("moe.gmm_lowerings")
         for mode in ("fwd", "dgrad", "wgrad"):
             assert c.value(mode=mode, operands="bf16", tm=128, tk=32,
-                           tn=64) == 1, mode
+                           tn=64, rhs="declared") == 1, mode
         assert c.value(mode="fwd", operands="f32", tm=128, tk=32,
-                       tn=64) == 1
+                       tn=64, rhs="declared") == 1
         assert telemetry.total("moe.gmm_lowerings") == 4
+        # a weight handed over as it is held: the same tiles' labels as
+        # the kernels' names carry, ``mode`` the pass
+        jax.jit(jax.grad(lambda l, r: jnp.sum(grouped_matmul(
+            l, r, group_sizes, rhs_transposed=True).astype(jnp.float32)),
+            argnums=(0, 1))).lower(lhs, jnp.swapaxes(rhs, 1, 2))
+        for mode, tk, tn in (("fwd", 64, 32), ("dgrad", 64, 32),
+                             ("wgrad", 64, 32)):
+            assert c.value(mode=mode, operands="bf16", tm=128, tk=tk,
+                           tn=tn, rhs="held_transposed") == 1, mode
+        assert telemetry.total("moe.gmm_lowerings") == 7
     finally:
         telemetry.disable()
         telemetry.reset()

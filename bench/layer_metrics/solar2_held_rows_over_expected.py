@@ -1,0 +1,18 @@
+"""Rows the held experts of ALL four layers received in the last step of
+the window, summed, over what uniform routing sends them (layers x
+tokens x experts-per-token x held / routed-over; 4 x 1,024 in the cell).
+From the model's count outputs. The step's length follows this sum (a
+row costs time in every grouped product), so it is the quantity the
+cell's run-to-run spread follows; 1.0 is a deployment's balanced routing.
+It describes the traffic and the seeded weights more than the code."""
+import share_scopes
+import solar2_scopes
+
+
+def compute(trace, counters, run):
+    flops = solar2_scopes.solar2_flops(run)
+    held = flops and share_scopes.held_rows(run)
+    if not held:
+        return None
+    return sum(held) / float(len(held) * run["batch"]
+                             * flops.expected_share_rows(run["cfg"]))

@@ -40,8 +40,13 @@ attention with a query latent in two geometries: full layers whose
 ``KeyIndexer`` chooses the 2,048 best keys a query, one in four beside
 layers under a 513-key window over a latent of their own, a sigmoid gate
 a head on both, a dense SwiGLU then one shared and 256 sigmoid-routed
-experts), whole or as a share, with ``dots3_reference``; ``lm_blocks``
-holds what the LM symbols share.
+experts), whole or as a share, with ``dots3_reference``.
+``solar_open2`` is Solar-Open2-250B (Kimi Delta Attention with write
+strengths up to 2, three to one beside gated grouped attention without a
+rotary embedding, one shared and 320 sigmoid-routed experts in every
+layer), whole or as a share of its experts, of both mixers' heads and of
+its vocabulary, with ``solar_open2_reference``; ``lm_blocks`` holds what
+the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -59,4 +64,4 @@ from . import (afmoe, afmoe_reference, dots3, dots3_reference, falcon_h1,
                falcon_h1_reference, kanana2, kanana2_reference, kimi_linear,
                kimi_linear_reference, lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
                nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
-               olmoe, olmoe_reference)
+               olmoe, olmoe_reference, solar_open2, solar_open2_reference)

@@ -75,8 +75,8 @@ no auxiliary loss, no multi-token-prediction layer
 from .. import initializer as init
 from .. import symbol as sym
 from ..contrib import symbol as csym
-from .lm_blocks import (expert_layer, head_and_loss, linear, mixer_block,
-                        swiglu)
+from .lm_blocks import (expert_layer, head_and_loss, kda_mixer, linear,
+                        mixer_block, swiglu)
 
 KDA, FULL = "kda", "full_attention"
 _PUBLISHED_FULL = (4, 8, 12, 16, 20, 24, 27)
@@ -101,35 +101,10 @@ def get_symbol(vocab_size=163840, hidden_size=2304,
     def positions(x, width):  # [B*T, w] -> [B, T, w]
         return sym.Reshape(x, shape=(-1, seq_len, width))
 
-    kda_width = kda_heads * kda_head_dim
-
     def delta_attention(x, p):
-        p += "kda_"
-
-        def wide(name):
-            return positions(linear(x, p + name + "_proj", kda_width),
-                             kda_width)
-
-        def low_rank(name):  # 2304 -> rank -> H K, no bias on either
-            return positions(linear(
-                linear(x, p + name + "_a_proj", kda_rank),
-                p + name + "_b_proj", kda_width), kda_width)
-
-        y = csym.GatedDeltaNet(
-            wide("q"), wide("k"), wide("v"), low_rank("g"), low_rank("f"),
-            positions(linear(x, p + "b_proj", kda_heads), kda_heads),
-            conv_weight=sym.Variable(p + "conv_weight", init=init.Uniform(
-                scale=conv_kernel ** -0.5)),
-            a_log=sym.Variable(p + "a_log", init=init.LogOfUniform(
-                low=1.0, high=16.0)),
-            dt_bias=sym.Variable(p + "dt_bias", init=init.InverseSoftplus(
-                low=0.001, high=0.1, floor=1e-4)),
-            norm_gamma=sym.Variable(p + "norm_gamma", init=init.One()),
-            num_heads=kda_heads, conv_kernel=conv_kernel,
-            chunk_size=chunk_size, eps=rms_eps, allow_neg_eigval=False,
-            gate_act="sigmoid", name=p[:-1])
-        return linear(sym.Reshape(y, shape=(-1, kda_width)), p + "o_proj",
-                      hidden_size)
+        return kda_mixer(x, p, hidden_size, seq_len, kda_heads, kda_head_dim,
+                         kda_rank, conv_kernel, chunk_size, rms_eps,
+                         allow_neg_eigval=False)
 
     q_width = num_heads * (nope_head_dim + rope_head_dim)
 

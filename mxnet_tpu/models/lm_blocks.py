@@ -4,7 +4,8 @@ the tail also ``olmoe``): a bias-free projection, the dense SwiGLU and
 the un-gated relu² feed-forward, the one-mixer residual block, the block
 that norms a sub-layer's output and the block whose mixers read one
 normed input side by side, a fixed scalar on a node's output, the routed
-expert layer's call and the head, untied or reading the embedding's
+expert layer's call, the Kimi Delta Attention mixer (``kimi_linear``,
+``solar_open2``) and the head, untied or reading the embedding's
 matrix, with its loss. Each takes the node-name prefix of its layer, so a
 model's argument and scope names are its own."""
 from .. import initializer as init
@@ -61,6 +62,52 @@ def expert_layer(x, prefix, **attrs):
             prefix + "moe_select_bias", init=init.Zero()),
         name=prefix + "moe", **attrs)
     return moe[0], sym.BlockGrad(moe[1], name=prefix + "expert_count")
+
+
+def kda_mixer(x, prefix, hidden_size, seq_len, heads, head_dim, rank,
+              conv_kernel, chunk_size, rms_eps, allow_neg_eigval):
+    """A Kimi Delta Attention mixer on ``x`` [tokens, hidden] at ``heads``
+    heads of ``head_dim`` keys and values (all of the model's, or the
+    heads one chip holds: a held head is its columns of every projection
+    below, its ``a_log``, its ``dt_bias`` channels and its taps): the four
+    wide projections ``<prefix>kda_{q,k,v,o}_proj``, the two low-rank
+    pairs into the gate and the decay (``_g_a`` / ``_g_b``, ``_f_a`` /
+    ``_f_b``: hidden -> ``rank`` -> heads x head_dim, the first halves
+    whole whatever the heads held, no bias) and the write strength's
+    ``_b_proj`` round ``GatedDeltaNet`` in its channel form with a sigmoid
+    gate (the node ``<prefix>kda``, which owns the taps, ``a_log``,
+    ``dt_bias`` and the gated norm's gamma, each with the rule the model
+    states). ``allow_neg_eigval``: write strengths ``2 sigmoid`` (up to
+    2) where true, ``sigmoid`` where false."""
+    p = prefix + "kda_"
+    width = heads * head_dim
+
+    def positions(y, columns):  # [B*T, w] -> [B, T, w]
+        return sym.Reshape(y, shape=(-1, seq_len, columns))
+
+    def wide(name):
+        return positions(linear(x, p + name + "_proj", width), width)
+
+    def low_rank(name):  # hidden -> rank -> H K, no bias on either
+        return positions(linear(
+            linear(x, p + name + "_a_proj", rank),
+            p + name + "_b_proj", width), width)
+
+    y = csym.GatedDeltaNet(
+        wide("q"), wide("k"), wide("v"), low_rank("g"), low_rank("f"),
+        positions(linear(x, p + "b_proj", heads), heads),
+        conv_weight=sym.Variable(p + "conv_weight", init=init.Uniform(
+            scale=conv_kernel ** -0.5)),
+        a_log=sym.Variable(p + "a_log", init=init.LogOfUniform(
+            low=1.0, high=16.0)),
+        dt_bias=sym.Variable(p + "dt_bias", init=init.InverseSoftplus(
+            low=0.001, high=0.1, floor=1e-4)),
+        norm_gamma=sym.Variable(p + "norm_gamma", init=init.One()),
+        num_heads=heads, conv_kernel=conv_kernel,
+        chunk_size=chunk_size, eps=rms_eps,
+        allow_neg_eigval=allow_neg_eigval, gate_act="sigmoid", name=p[:-1])
+    return linear(sym.Reshape(y, shape=(-1, width)), p + "o_proj",
+                  hidden_size)
 
 
 def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,

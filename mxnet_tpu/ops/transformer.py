@@ -814,7 +814,8 @@ _M_LINEAR_ATTN_LOWERINGS = _tm.counter(
     "head's widths), chunk (tokens a chunk of the delta rule), conv (the "
     "convolution's taps), impl (kernel: the Pallas pair where the step is "
     "lowered for the TPU, the chunk form elsewhere; chunked: the jax.numpy "
-    "chunk form everywhere), decay=channel (gdn.py's kda_ pair), gate")
+    "chunk form everywhere), decay=channel (gdn.py's kda_ pair), gate, "
+    "beta_scale=2 (a channel call whose write strengths are 2 sigmoid)")
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk):
@@ -952,6 +953,8 @@ def gated_delta_net(query, key, value, gate, a, b, conv_weight, a_log,
         "channel" if channel else "scalar")
     gated = {} if gate_act == "silu" else {"gate": gate_act}
     labels = dict(gated, decay="channel") if channel else gated
+    if channel and allow_neg_eigval:  # not the channel form's accepted 1
+        labels["beta_scale"] = 2
     _M_LINEAR_ATTN_LOWERINGS.inc(
         heads=num_heads, key_dim=key_dim, value_dim=value_dim,
         chunk=chunk_size, conv=conv_weight.shape[0],

@@ -242,15 +242,12 @@ class Normal(Initializer):
         self.sigma = sigma
 
     def _init_weight(self, _, arr):
-        # from mx.random's stream, in float32 on the array's own device
-        # (the reference: random.normal(0, sigma, out=arr)): a numpy
-        # float64 draw costs a minute per billion values of a fit's set-up
-        import jax
-        import jax.numpy as jnp
-
-        with jax.default_device(arr._placement):
-            arr[:] = self.sigma * jax.random.normal(
-                random.next_key(), arr.shape, jnp.float32)
+        # from mx.random's stream, in float32 (the reference:
+        # random.normal(0, sigma, out=arr)): a numpy float64 draw costs a
+        # minute per billion values of a fit's set-up. The key is taken
+        # now; an array nobody has read yet is drawn where its first
+        # reader is, the fused step's mesh or the array's own device
+        arr._set_normal(random.next_key(), self.sigma)
 
 
 @register

@@ -208,6 +208,9 @@ class BucketingModule(BaseModule):
         for mod in self._buckets.values():
             if mod is not self._curr_module:
                 mod.borrow_optimizer(self._curr_module)
+        # a fused placement left the truth on the mesh (get_params hands
+        # this flag down)
+        self._params_dirty = self._curr_module._params_dirty
         self.optimizer_initialized = True
 
     def forward(self, data_batch, is_train=None):

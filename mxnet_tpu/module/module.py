@@ -562,8 +562,8 @@ class Module(BaseModule):
             for idx, name in enumerate(self._exec_group.param_names):
                 self._kvstore.pull(idx, out=self._arg_params[name])
         self._fused_params, self._fused_aux = self._fused_trainer.place_params(
-            self._arg_params, self._aux_params
-        )
+            self._arg_params, self._aux_params)
+        self._params_dirty = True  # a draw made on the mesh is only there
         self._fused_opt = self._fused_trainer.make_state(self._fused_params)
         if self._fused_trainer.amp:
             # make_state captured the fp32 params as master slabs; the
@@ -685,9 +685,9 @@ class Module(BaseModule):
                 data_names=self._data_names, label_names=self._label_names,
                 flat_update=False,
             ).compile()
-            # the weight arrays shared with the owner are released or out
-            # of date, and this module's own gradient buffers as unused
+            # the shared weights are released or stale, the gradients unused
             self._release_exec_arrays()
+            self._params_dirty = True  # the truth is the owner's fused state
         self.optimizer_initialized = True
 
     def forward(self, data_batch, is_train=None):

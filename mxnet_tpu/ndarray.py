@@ -169,14 +169,31 @@ def imperative_invoke(opdef, inputs, attrs, out=None):
     return ret
 
 
+def _platform(sharding):
+    return next(iter(sharding.device_set)).platform
+
+
+class _Draw:
+    """A ``_Deferred``'s value when it is not a constant but a draw:
+    ``sigma * normal(key)`` in float32 (``mx.random``'s threefry
+    stream), cast to the array's type."""
+
+    __slots__ = ("key", "sigma")
+
+    def __init__(self, key, sigma):
+        self.key = key
+        self.sigma = sigma
+
+
 class _Deferred:
     """What an NDArray holds in place of a buffer nobody has read or
-    written yet: where a constant of which shape, type and value belongs.
-    The buffer comes into being on the first read of ``NDArray._data``,
-    made on its own device by a program (no host array, no crossing); a
-    whole write replaces it without the constant ever being made. Also
-    what an executor array keeps once the fused step owns the
-    parameters (``NDArray._drop_buffer``): then ``value`` is None, there
+    written yet: where a constant, or a draw (``_Draw``), of which shape
+    and type belongs. The buffer comes into being on the first read of
+    ``NDArray._data``, made on its own device by a program (no host
+    array, no crossing), or on the mesh when the fused step takes the
+    array first (``place``); a whole write replaces it without the value
+    ever being made. Also what an array keeps once the fused step owns
+    its value (``NDArray._drop_buffer``): then ``value`` is None, there
     is nothing to read, and a read says so."""
 
     __slots__ = ("shape", "dtype", "value", "device")
@@ -206,6 +223,10 @@ class _Deferred:
             return self.device
         return SingleDeviceSharding(self.device)
 
+    @property
+    def platform(self):
+        return _platform(self.sharding)
+
 
 @functools.lru_cache(maxsize=1024)
 def _constants_program(specs, shardings):
@@ -221,23 +242,114 @@ def _constants_program(specs, shardings):
     return _jax().jit(make, out_shardings=shardings)
 
 
-def make_constants(deferred):
-    """The buffers of a sequence of ``_Deferred``, made on their devices
-    by ONE program and without a host array: a set-up that needs a
-    state tree pays one cache load, not one per key."""
+@functools.lru_cache(maxsize=1024)
+def _draw_program(shape, dtype, sharding):
+    """One jitted program a signature, kept for the process, that makes
+    ``sigma * normal(key)`` where ``sharding`` says: drawn and scaled in
+    float32 and cast inside, so no float32 copy outlives it. Key and
+    sigma are its arguments: every array of a signature, whatever its
+    seed, runs the one executable. Drawn as rows of the last axis and
+    reshaped (a value of the stream depends on its row-major position
+    alone): the TPU's compiler takes 8 s over a three-axis draw and
+    1-2.6 s over the same rows. On the host's platform a barrier keeps
+    XLA from folding sigma into the normal's own ``sqrt(2)``, so the
+    value is bit for bit what the eager draw, scale and cast gave; an
+    accelerator has no eager value to keep, and the barrier would cost
+    it a float32 pass over HBM and 6-9 s of compile a signature."""
+    jax = _jax()
+    rows = (int(np.prod(shape[:-1], dtype=np.int64)),) + tuple(shape[-1:])
+    on_host = _platform(sharding) == "cpu"
+
+    def draw(key, sigma):
+        x = jax.random.normal(key, rows, np.float32)
+        if on_host:
+            x = jax.lax.optimization_barrier(x)
+        return (sigma * x).astype(dtype).reshape(shape)
+
+    return jax.jit(draw, out_shardings=sharding)
+
+
+def make_deferred(deferred):
+    """The buffers of a sequence of ``_Deferred``, made where each says
+    and without a host array: the constants by ONE program (a set-up
+    that needs a state tree pays one cache load, not one per key), the
+    draws by one program a (shape, type, sharding), a call a key."""
     deferred = tuple(deferred)
     if not deferred:
         return ()
     if any(d.value is None for d in deferred):
         raise MXNetError(
-            "this executor array gave its buffer up to the fused step: "
-            "read Module.get_params(), or run a forward first")
+            "this array gave its buffer up to the fused step: read "
+            "Module.get_params(), or run a forward first")
+    draws = [i for i, d in enumerate(deferred) if type(d.value) is _Draw]
+    consts = [i for i, d in enumerate(deferred) if type(d.value) is not _Draw]
     if _tm.enabled():
-        for d in deferred:
-            _tm.note_const(d.nbytes)
-    return _constants_program(
-        tuple((d.shape, d.dtype, d.value) for d in deferred),
-        tuple(d.sharding for d in deferred))()
+        for i in consts:
+            _tm.note_const(deferred[i].nbytes)
+        for i in draws:
+            _tm.note_drawn(deferred[i].nbytes, deferred[i].platform)
+    made = [None] * len(deferred)
+    if consts:
+        bufs = _constants_program(
+            tuple((deferred[i].shape, deferred[i].dtype, deferred[i].value)
+                  for i in consts),
+            tuple(deferred[i].sharding for i in consts))()
+        for i, buf in zip(consts, bufs):
+            made[i] = buf
+    for i in draws:
+        d = deferred[i]
+        made[i] = _draw_program(d.shape, d.dtype, d.sharding)(
+            d.value.key, np.float32(d.value.sigma))
+    return tuple(made)
+
+
+def place(values, shardings):
+    """Each of ``values`` (NDArrays, jax or numpy arrays) as a jax.Array
+    under its entry of ``shardings``, a fresh buffer each. What nobody
+    has read yet is made there (``make_deferred``); an NDArray that held
+    a draw keeps no record of it, because the placed copy is the only
+    one: drawn again on another device it would differ in the last
+    place. What holds a buffer is put from where it is: from a device
+    of the sharding's directly, else by way of the host. The host's
+    bytes are counted once a call, a call that sent none included
+    (``device.h2d_bytes`` then reads 0, not nothing)."""
+    jax = _jax()
+    out = [None] * len(values)
+    unmade, crossed = [], 0
+    for i, (v, sharding) in enumerate(zip(values, shardings)):
+        buf = getattr(v, "_buf", v)
+        if type(buf) is _Deferred and buf.value is not None:
+            unmade.append(i)
+            continue
+        if isinstance(v, NDArray):
+            v._drain_engine()
+            buf = v._data
+        if (isinstance(buf, jax.Array) and sharding.is_fully_addressable
+                and buf.sharding.device_set <= sharding.device_set):
+            if _platform(buf.sharding) == "cpu":
+                crossed += buf.nbytes  # host memory, as _note_crossing has it
+            # of a copy: a put may alias its source where the devices
+            # meet, and the step donates what it is given
+            out[i] = jax.device_put(jax.numpy.copy(buf), sharding)
+        else:
+            host = np.asarray(buf)
+            crossed += host.nbytes
+            out[i] = jax.device_put(host, sharding)
+    if _tm.enabled() and values:
+        _tm.note_h2d(crossed, next(iter(shardings[0].device_set)))
+    records = [values[i]._buf for i in unmade]
+    made = make_deferred(
+        _Deferred(r.shape, r.dtype, r.value, shardings[i])
+        for i, r in zip(unmade, records))
+    for i, r, buf in zip(unmade, records, made):
+        out[i] = buf
+        if type(r.value) is _Draw:
+            values[i]._drop_buffer()
+    # a fit places once: its programs are not kept for the process,
+    # because a loaded executable holds up to 1 MB of the chip's memory
+    _draw_program.cache_clear()
+    _constants_program.cache_clear()
+    return out
 
 
 class NDArray:
@@ -258,10 +370,11 @@ class NDArray:
     # -- the buffer ---------------------------------------------------------
     @property
     def _data(self):
-        """The jax.Array; a deferred constant is made here, once."""
+        """The jax.Array; a deferred constant or draw is made here,
+        once, on the array's own device."""
         buf = self._buf
         if type(buf) is _Deferred:
-            buf = self._buf = make_constants((buf,))[0]
+            buf = self._buf = make_deferred((buf,))[0]
         return buf
 
     @_data.setter
@@ -279,6 +392,21 @@ class NDArray:
         and has nothing to read until something writes it whole."""
         buf = self._buf
         self._buf = _Deferred(buf.shape, buf.dtype, None, buf.device)
+
+    def _set_normal(self, key, sigma):
+        """A whole write of ``sigma * normal(key)``: recorded while the
+        array holds no buffer (whoever reads first decides where it is
+        made), made now on the array's own device otherwise."""
+        if self._engine_dep is not None:
+            self._drain_engine()  # as __setitem__: an in-flight pull lands first
+        unread = type(self._buf) is _Deferred
+        # the key as host words: a record (the kvstore keeps a copy of
+        # each) then holds nothing on the stream's device
+        self._buf = _Deferred(self.shape, self._buf.dtype,
+                              _Draw(np.asarray(key), float(sigma)),
+                              self._placement)
+        if not unread:
+            self._data  # it held a buffer: it holds the new one at once
 
     # -- basic properties ---------------------------------------------------
     @property
@@ -385,6 +513,12 @@ class NDArray:
                 # _data assignment (not copyto) precisely so this drain
                 # can't self-deadlock the op that holds the var.
                 other._drain_engine()
+            if self._unread_as_on(other):
+                # neither holds a buffer: the record moves, nothing is made
+                buf = self._buf
+                other._buf = _Deferred(buf.shape, buf.dtype, buf.value,
+                                       other._placement)
+                return other
             if _tm.enabled():
                 _note_crossing(self._data, other._placement)
             other._data = jax.device_put(self._data, other._placement)
@@ -396,7 +530,20 @@ class NDArray:
         raise MXNetError("copyto: unsupported target %r" % (other,))
 
     def copy(self):
+        if self._unread_as_on(self):
+            return NDArray(self._buf)  # the same record, not a buffer
         return NDArray(self._data + 0)
+
+    def _unread_as_on(self, other):
+        """Whether this array's value is still a record (``_Deferred``)
+        that reads on ``other``'s device, itself holding no buffer, as
+        it reads here: a constant anywhere, a draw on the same platform
+        (another back end rounds ``erf_inv`` differently)."""
+        buf, there = self._buf, other._buf
+        return (type(buf) is _Deferred and buf.value is not None
+                and type(there) is _Deferred
+                and (type(buf.value) is not _Draw
+                     or buf.platform == there.platform))
 
     def as_in_context(self, context):
         if self.context == context:

@@ -683,21 +683,21 @@ class ShardedTrainStep:
         return host
 
     def place_params(self, arg_arrays_by_name, aux_arrays_by_name):
-        """device_put host/NDArray values onto the mesh by spec.
+        """Parameters and auxiliary states on the mesh by spec: what
+        nobody has read yet is made there, the rest is put there
+        (``ndarray.place``; an NDArray that held a draw holds nothing
+        after). Accepts numpy arrays or NDArrays; returns dicts of
+        jax.Arrays."""
+        from .. import ndarray as ndmod
 
-        Accepts numpy arrays or NDArrays; returns dict of jax.Arrays."""
-        import jax
-
-        def _put(v, name):
-            host = v.asnumpy() if hasattr(v, "asnumpy") else np.asarray(v)
-            return jax.device_put(self.count_h2d(host),
-                                  self._sharding_for(name))
-
+        names = list(self.param_names) + list(self.aux_names)
         with _tm.span("train_step.place_params"):
-            params = {n: _put(arg_arrays_by_name[n], n)
-                      for n in self.param_names}
-            aux = {n: _put(aux_arrays_by_name[n], n) for n in self.aux_names}
-        return params, aux
+            placed = dict(zip(names, ndmod.place(
+                [arg_arrays_by_name[n] for n in self.param_names]
+                + [aux_arrays_by_name[n] for n in self.aux_names],
+                [self._sharding_for(n) for n in names])))
+        return ({n: placed[n] for n in self.param_names},
+                {n: placed[n] for n in self.aux_names})
 
     def make_state(self, params):
         """Build optimizer state via the optimizer's OWN create_state on
@@ -747,7 +747,7 @@ class ShardedTrainStep:
         create_state returns them) on the mesh, each leaf where
         ``sharding_for(key, leaf)`` says. Leaves nobody has read are
         constants: all of them come out of ONE program on the mesh
-        (``ndarray.make_constants``), none crosses from the host."""
+        (``ndarray.make_deferred``), none crosses from the host."""
         import jax
 
         from .. import ndarray as ndmod
@@ -770,7 +770,7 @@ class ShardedTrainStep:
             return jax.device_put(buf, sharding)
 
         placed = {key: _place(key, s) for key, s in tree.items()}
-        made = ndmod.make_constants(consts)
+        made = ndmod.make_deferred(consts)
 
         def _fill(s):
             if isinstance(s, tuple):

@@ -39,7 +39,7 @@ from .tracer import (  # noqa: F401
     span, current_span, under, Span, NULL_SPAN,
 )
 from . import setup  # noqa: F401  (jax's seconds, bytes to the device)
-from .setup import note_const, note_h2d  # noqa: F401
+from .setup import note_const, note_drawn, note_h2d  # noqa: F401
 from .export import (  # noqa: F401
     sample_device_memory, write_prometheus_file, set_prometheus_file,
     jsonl_path,

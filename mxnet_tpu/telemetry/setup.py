@@ -50,7 +50,15 @@ CONST_BYTES = _reg.counter(
     "bytes of constants made on a device by a program, without a host "
     "array or a crossing (a deferred buffer's first read, fresh optimizer "
     "state), by the outermost span open on the calling thread: with "
-    "device.h2d_bytes, what a span allocated")
+    "device.h2d_bytes and device.drawn_bytes, what a span allocated")
+DRAWN_BYTES = _reg.counter(
+    "device.drawn_bytes",
+    "bytes of normal draws made on a device by a program, without a host "
+    "array or a crossing (a deferred draw's first read, or the fused "
+    "step's placement of a parameter nobody had read), by the outermost "
+    "span open on the calling thread and by whether the device was the "
+    "host's (host=1, a cpu device) or not: the share with host=0 is how "
+    "often a parameter was made where the step holds it")
 IMPORT_T0 = _reg.gauge(
     "process.import_t0",
     "time.perf_counter() at the first statement of mxnet_tpu/__init__.py")
@@ -235,6 +243,13 @@ def note_h2d(nbytes, device):
 
 def note_const(nbytes):
     """Count ``nbytes`` of a constant a program made on a device. Same
-    guard (the caller's) and same ``under`` as ``note_h2d``, so a span's
-    allocations are the sum of the two."""
+    guard (the caller's) and same ``under`` as ``note_h2d`` and
+    ``note_drawn``, so a span's allocations are the sum of the three."""
     CONST_BYTES.inc(int(nbytes), under=under())
+
+
+def note_drawn(nbytes, platform):
+    """Count ``nbytes`` of a normal draw a program made on a device of
+    ``platform``. Same guard and same ``under`` as ``note_const``."""
+    DRAWN_BYTES.inc(int(nbytes), under=under(),
+                    host="1" if platform == "cpu" else "0")

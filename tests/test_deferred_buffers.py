@@ -77,11 +77,12 @@ def _batch(seed=0):
 @pytest.mark.parametrize("path", ["per_key", "flat"])
 def test_fused_setup_sends_each_parameter_once_and_nothing_else(path):
     """Bind sends nothing and makes nothing; init_params draws on the
-    host and sends nothing; init_optimizer places each parameter and
-    auxiliary state once (the one crossing that carries information)
-    and makes the momentum on the mesh. The constants an initializer
-    declared (biases, gammas, moving statistics) are made where
-    place_params first reads them, on the host's device."""
+    host (Xavier: numpy's stream) and sends nothing; init_optimizer
+    places each drawn weight once (the one crossing that carries
+    information) and makes the momentum on the mesh. The constants an
+    initializer declared (biases, gammas, moving statistics) nobody has
+    read: place_params makes them on the mesh too (ISSUE 63), and the
+    host arrays stay the records they were."""
     tm.enable()
     if path == "per_key":
         ctx = mx.cpu(1)
@@ -105,7 +106,7 @@ def test_fused_setup_sends_each_parameter_once_and_nothing_else(path):
     trainer = mod._fused_trainer
     assert trainer is not None
     assert (trainer.flat_mode is not None) == (path == "flat")
-    placed = 4 * (_size(PARAMS) + _size(AUX))
+    placed = 4 * _size(PARAMS, DRAWN)
     sent = _by_span("device.h2d_bytes")
     # the flat path's two loss-scaler scalars do not exist without AMP
     assert sent == {"module.init_optimizer": placed}
@@ -115,6 +116,12 @@ def test_fused_setup_sends_each_parameter_once_and_nothing_else(path):
     assert momentum >= 4 * _size(PARAMS)   # the flat slabs are padded
     assert _by_span("device.const_bytes") == {
         "module.init_optimizer": declared + momentum}
+    assert _by_span("device.drawn_bytes") == {}
+    for n, arr in list(mod._arg_params.items()) + list(
+            mod._aux_params.items()):
+        assert _is_deferred(arr) == (n not in DRAWN)
+        assert mod._fused_trainer._sharding_for(n) == (
+            dict(mod._fused_params, **mod._fused_aux)[n].sharding)
     # and still no buffer in the executor group: the fused step owns them
     assert all(_is_deferred(exe.arg_dict[n]) and _is_deferred(exe.grad_dict[n])
                for n in PARAMS)
@@ -188,11 +195,9 @@ def test_make_state_is_the_parents_bit_for_bit(name, dtype):
     before = _by_span("device.h2d_bytes")
     state = step.make_state(params)
     crossed = _by_span("device.h2d_bytes")
-    if name.startswith("dcasgd"):
-        # its kept copy was computed, on the stand-in's device: here a
-        # cpu device, whose buffers the counter takes for host memory
-        assert crossed.pop("train_step.make_state") == 4 * _size(PARAMS)
-    assert crossed == before              # no constant crossed
+    # no constant crossed; DCASGD's kept copy is a copy of a stand-in
+    # that holds no buffer: the same record, made with the rest
+    assert crossed == before
     assert list(state) == list(step.param_names)
     sharding = NamedSharding(step.mesh, P())
     for n in step.param_names:
@@ -259,8 +264,9 @@ def test_make_state_is_one_program(monkeypatch):
         calls.append(len(specs))
         return real(specs, shardings)
 
-    monkeypatch.setattr(nd, "_constants_program", counting)
+    counting.cache_clear = real.cache_clear    # place() drops its programs
     step, params = _trainer(mx.optimizer.Adam(), flat_update=False)
+    monkeypatch.setattr(nd, "_constants_program", counting)
     step.make_state(params)
     assert calls == [2 * len(PARAMS)]
 
@@ -572,3 +578,438 @@ def test_resume_places_the_saved_state_over_the_made_one(tmp_path):
         assert s.dtype == np.float32
         assert s.sharding == NamedSharding(again._fused_trainer.mesh, P())
         np.testing.assert_array_equal(np.asarray(s), saved[n])
+
+
+# -- a draw nobody has read (ISSUE 63) ----------------------------------------
+# ``Normal`` takes its key from ``mx.random``'s stream when it is called
+# and leaves a record; the value is made where its first reader is: the
+# fused step's mesh (``ndarray.place``), else the array's own device.
+
+SIGMA = 0.05
+
+
+def _mixed_net(dtype="float32"):
+    """``Normal`` weights (conv0, fc0), constants (biases, gamma, beta,
+    the moving statistics) and one weight that states a numpy-stream
+    initializer of its own."""
+    net = mx.sym.Variable("data", dtype=dtype)
+    net = mx.sym.Convolution(net, num_filter=4, kernel=(3, 3), pad=(1, 1),
+                             name="conv0")
+    net = mx.sym.BatchNorm(net, name="bn0")
+    net = mx.sym.Activation(net, act_type="relu", name="relu0")
+    net = mx.sym.FullyConnected(mx.sym.Flatten(net), num_hidden=8, name="fc0")
+    net = mx.sym.FullyConnected(
+        net, num_hidden=4, name="fc1", weight=mx.sym.Variable(
+            "fc1_weight", init=mx.init.Uniform(0.1)))
+    return mx.sym.SoftmaxOutput(net, name="softmax")
+
+
+MIXED = dict(PARAMS, fc0_weight=(8, 64), fc0_bias=(8,), fc1_weight=(4, 8),
+             fc1_bias=(4,))
+NORMAL = ("conv0_weight", "fc0_weight")      # in the order they are drawn
+NUMPY = ("fc1_weight",)
+
+
+def _eager_normal(key, shape, dtype, device):
+    """What the parent's ``Normal._init_weight`` wrote: three eager
+    programs on the array's device."""
+    import jax.numpy as jnp
+
+    with jax.default_device(device):
+        return np.asarray(jnp.asarray(
+            SIGMA * jax.random.normal(key, shape, jnp.float32),
+            dtype=mx.base.np_dtype(dtype)))
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).view(np.uint8)
+
+
+def _mixed_module(mesh_devices=(1,), dtype="float32", seed=11, specs=None):
+    ctx = mx.cpu(mesh_devices[0])
+    mesh = make_mesh(dp=len(mesh_devices),
+                     devices=[jax.devices()[i] for i in mesh_devices])
+    mod = mx.mod.Module(_mixed_net(dtype), context=ctx, mesh=mesh,
+                        param_specs=specs)
+    mod.bind([("data", (8, 3, 4, 4))], LABEL)
+    mx.random.seed(seed)
+    np.random.seed(seed)
+    mod.init_params(mx.init.Normal(SIGMA))
+    return mod
+
+
+def _fuse(mod):
+    mod.init_optimizer(kvstore="device", optimizer="sgd", optimizer_params={
+        "learning_rate": 0.1, "momentum": 0.9})
+    assert mod._fused_trainer is not None
+    return mod
+
+
+def _want_normals(seed, dtype="float32", device=None):
+    """name -> the parent's eager value at ``seed``, and the stream's
+    state after the last of them."""
+    mx.random.seed(seed)
+    want = {n: _eager_normal(mx.random.next_key(), MIXED[n], dtype,
+                             device or mx.cpu(0).jax_device)
+            for n in NORMAL}
+    return want, mx.random.get_state()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "float16"])
+@pytest.mark.parametrize("born", ["deferred", "buffer"])
+def test_a_normal_read_on_the_host_is_the_eager_draw_bit_for_bit(born, dtype):
+    shape = (33, 17)
+    if born == "deferred":
+        arr = nd.deferred_full(shape, 0, ctx=mx.cpu(1), dtype=dtype)
+    else:
+        arr = mx.nd.ones(shape, ctx=mx.cpu(1), dtype=dtype)
+    tm.enable()
+    mx.random.seed(7)
+    mx.init.Normal(SIGMA)("x_weight", arr)
+    after = mx.random.get_state()
+    # an array that held a buffer is written at once, as it was
+    assert _is_deferred(arr) == (born == "deferred")
+    nbytes = 33 * 17 * np.dtype(arr.dtype).itemsize
+    assert _by_span("device.drawn_bytes") == (
+        {} if born == "deferred" else {"-": nbytes})
+    got = arr.asnumpy()
+    assert not _is_deferred(arr) and arr.context == mx.cpu(1)
+    mx.random.seed(7)
+    want = _eager_normal(mx.random.next_key(), shape, dtype,
+                         mx.cpu(1).jax_device)
+    np.testing.assert_array_equal(mx.random.get_state(), after)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    arr.asnumpy()                                        # made once
+    assert _by_span("device.drawn_bytes") == {"-": nbytes}
+    streams = tm.snapshot()["device.drawn_bytes"]["streams"]
+    assert [s["labels"]["host"] for s in streams] == ["1"]  # a cpu device
+    assert _by_span("device.h2d_bytes") == {}
+    assert _by_span("device.const_bytes") == {}
+
+
+def test_init_params_leaves_the_stream_where_the_parent_left_it():
+    """One key a ``Normal`` weight, in the symbol's order; constants and
+    numpy-stream initializers take none, and nothing is made."""
+    tm.enable()
+    mod = _mixed_module()
+    want, state = _want_normals(11)
+    np.testing.assert_array_equal(mx.random.get_state(), state)
+    # numpy's stream too: the one Uniform weight, drawn eagerly
+    np.random.seed(11)
+    np.testing.assert_array_equal(
+        mod._arg_params["fc1_weight"].asnumpy(),
+        np.random.uniform(-0.1, 0.1, (4, 8)).astype("f"))
+    assert _by_span("device.drawn_bytes") == {}
+    assert _by_span("device.const_bytes") == {}
+    for n, arr in mod._arg_params.items():
+        assert _is_deferred(arr) == (n not in NUMPY)
+    # read on the host (no fused step): the parent's values
+    got, _ = mod.get_params()
+    for n in NORMAL:
+        np.testing.assert_array_equal(_bits(got[n].asnumpy()),
+                                      _bits(want[n]))
+    assert _by_span("device.drawn_bytes") == {
+        "-": 4 * _size(MIXED, NORMAL)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_fused_fit_makes_its_parameters_on_the_mesh(dtype):
+    """Nothing reads a parameter between init_params and the placement:
+    every draw is made once, under its sharding on the mesh; the only
+    bytes that cross are the numpy-stream weight's; the host arrays give
+    their records up and get_params reads the step's copy."""
+    tm.enable()
+    item = np.dtype(mx.base.np_dtype(dtype)).itemsize
+    mod = _fuse(_mixed_module(dtype=dtype))
+    drawn = item * _size(MIXED, NORMAL)
+    assert _by_span("device.drawn_bytes") == {"module.init_optimizer": drawn}
+    # a fit places once: no executable of the placement stays loaded
+    assert nd._draw_program.cache_info().currsize == 0
+    assert _by_span("device.h2d_bytes") == {
+        "module.init_optimizer": item * _size(MIXED, NUMPY)}
+    for n in NORMAL:
+        arr = mod._arg_params[n]
+        assert _is_deferred(arr) and arr._buf.value is None
+        with pytest.raises(mx.MXNetError, match="fused step"):
+            arr.asnumpy()
+        assert mod._fused_params[n].sharding == (
+            mod._fused_trainer._sharding_for(n))
+    # the mesh is a cpu device here, so the step holds the parent's bits
+    want, _ = _want_normals(11, dtype, mx.cpu(1).jax_device)
+    held = {n: np.asarray(v) for n, v in mod._fused_params.items()}
+    for n in NORMAL:
+        np.testing.assert_array_equal(_bits(held[n]), _bits(want[n]))
+    # the kvstore's copy is the record, not 2 x the parameters
+    assert all(_is_deferred(mod._kvstore._store[i])
+               for i, n in enumerate(mod._param_names) if n in NORMAL)
+    assert mod._params_dirty
+    got, got_aux = mod.get_params()
+    for n in MIXED:
+        np.testing.assert_array_equal(_bits(got[n].asnumpy()),
+                                      _bits(held[n]))
+    np.testing.assert_array_equal(got_aux["bn0_moving_var"].asnumpy(), 1)
+    # drawn once: reading the step's copy draws nothing
+    assert sum(_by_span("device.drawn_bytes").values()) == drawn
+
+    batch = _batch()
+    batch.data[0] = batch.data[0].astype(dtype)
+    mod.forward(batch, is_train=True)
+    mod.update()
+    assert np.isfinite(mod.get_outputs()[0].asnumpy().astype("f")).all()
+    after = {n: np.asarray(v) for n, v in mod._fused_params.items()}
+    assert any(not np.array_equal(after[n], held[n]) for n in NORMAL)
+    got, _ = mod.get_params()
+    for n in MIXED:
+        np.testing.assert_array_equal(_bits(got[n].asnumpy()),
+                                      _bits(after[n]))
+    assert sum(_by_span("device.drawn_bytes").values()) == drawn
+
+
+def test_fit_draws_every_normal_once_and_none_before_the_placement():
+    """``Module.fit`` end to end: a single draw made for a reader other
+    than the step would show as bytes beyond the parameters'."""
+    tm.enable()
+    ctx = mx.cpu(1)
+    mod = mx.mod.Module(_mixed_net(), context=ctx, mesh=make_mesh(
+        dp=1, devices=[ctx.jax_device]))
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.randn(16, 3, 4, 4).astype("f"),
+                           rng.randint(0, 4, (16,)).astype("f"), batch_size=8)
+    mx.random.seed(3)
+    np.random.seed(3)
+    mod.fit(it, num_epoch=1, kvstore="device", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1},
+            initializer=mx.init.Normal(SIGMA))
+    assert mod._fused_trainer is not None
+    assert _by_span("device.drawn_bytes") == {
+        "module.init_optimizer": 4 * _size(MIXED, NORMAL)}
+    got, _ = mod.get_params()
+    want, _ = _want_normals(3)
+    for n in NORMAL:
+        assert np.isfinite(got[n].asnumpy()).all()
+        assert not np.array_equal(got[n].asnumpy(), want[n])   # trained
+
+
+def _read_get_params(mod, tmp_path):
+    return {n: v.asnumpy() for n, v in mod.get_params()[0].items()}
+
+
+def _read_save_checkpoint(mod, tmp_path):
+    prefix = str(tmp_path / "early")
+    mod.save_checkpoint(prefix, 0)
+    _, arg, _ = mx.model.load_checkpoint(prefix, 0)
+    return {n: v.asnumpy() for n, v in arg.items()}
+
+
+@pytest.mark.parametrize("read", [_read_get_params, _read_save_checkpoint],
+                         ids=["get_params", "save_checkpoint"])
+def test_a_read_before_init_optimizer_takes_the_host_path(read, tmp_path):
+    """The reader made the values on the host: those bytes are what the
+    step receives, over the link, and nothing is drawn a second time."""
+    tm.enable()
+    mod = _mixed_module()
+    seen = read(mod, tmp_path)
+    drawn = 4 * _size(MIXED, NORMAL)
+    assert _by_span("device.drawn_bytes") == {"-": drawn}
+    want, _ = _want_normals(11)
+    for n in NORMAL:
+        np.testing.assert_array_equal(_bits(seen[n]), _bits(want[n]))
+    _fuse(mod)
+    assert _by_span("device.drawn_bytes") == {"-": drawn}
+    assert _by_span("device.h2d_bytes")["module.init_optimizer"] >= drawn
+    for n in MIXED:
+        np.testing.assert_array_equal(
+            _bits(np.asarray(mod._fused_params[n])), _bits(seen[n]))
+        assert not _is_deferred(mod._arg_params[n])
+    got, _ = mod.get_params()
+    for n in MIXED:
+        np.testing.assert_array_equal(_bits(got[n].asnumpy()),
+                                      _bits(seen[n]))
+
+
+@pytest.mark.parametrize("devices, spec", [
+    ((1, 2), P("dp")), ((1, 2, 3, 4), P("dp")), ((1, 2, 3, 4), P(None, "dp")),
+    ((2, 3), P())], ids=["rows2", "rows4", "cols4", "replicated2"])
+def test_a_sharded_spec_gives_the_single_device_values(devices, spec):
+    tm.enable()
+    one = _fuse(_mixed_module())
+    want = {n: np.asarray(one._fused_params[n]) for n in MIXED}
+    tm.reset()
+    mod = _fuse(_mixed_module(devices, specs={"fc0_weight": spec}))
+    held = mod._fused_params["fc0_weight"]
+    assert held.sharding == NamedSharding(mod._fused_trainer.mesh, spec)
+    assert len(held.sharding.device_set) == len(devices)
+    if spec != P():
+        assert held.addressable_shards[0].data.size < held.size
+    for n in MIXED:
+        np.testing.assert_array_equal(
+            _bits(np.asarray(mod._fused_params[n])), _bits(want[n]))
+    # counted once however many devices hold a part of it
+    assert _by_span("device.drawn_bytes") == {
+        "module.init_optimizer": 4 * _size(MIXED, NORMAL)}
+    got, _ = mod.get_params()
+    np.testing.assert_array_equal(_bits(got["fc0_weight"].asnumpy()),
+                                  _bits(want["fc0_weight"]))
+
+
+def _drawn(dtype="float32", ctx=None):
+    arr = nd.deferred_full((5, 6), 0, ctx=ctx or mx.cpu(1), dtype=dtype)
+    mx.random.seed(21)
+    mx.init.Normal(SIGMA)("x_weight", arr)
+    mx.random.seed(21)
+    want = _eager_normal(mx.random.next_key(), (5, 6), dtype,
+                         mx.cpu(1).jax_device)
+    assert _is_deferred(arr)
+    return arr, want
+
+
+def _draw_copy(arr, want):
+    twin = arr.copy()
+    assert _is_deferred(twin) and _is_deferred(arr)    # the same record
+    assert twin.context == mx.cpu(1)
+    return twin
+
+
+def _draw_copyto_unread(arr, want):
+    out = nd.deferred_full((5, 6), 0, ctx=mx.cpu(2))
+    assert arr.copyto(out) is out
+    assert _is_deferred(out) and _is_deferred(arr)
+    assert out.context == mx.cpu(2)
+    assert _by_span("device.h2d_bytes") == {}
+    assert _by_span("device.drawn_bytes") == {}
+    np.testing.assert_array_equal(_bits(arr.asnumpy()), _bits(want))
+    return out
+
+
+def _draw_copyto_dropped(arr, want):
+    out = mx.nd.ones((5, 6), ctx=mx.cpu(2))
+    out._drop_buffer()                      # an executor's released weight
+    arr.copyto(out)
+    assert _is_deferred(out) and out.context == mx.cpu(2)
+    return out
+
+
+def _draw_copyto_buffer(arr, want):
+    out = mx.nd.ones((5, 6), ctx=mx.cpu(2))
+    arr.copyto(out)
+    assert not _is_deferred(out) and not _is_deferred(arr)   # a read
+    assert out.context == mx.cpu(2)
+    return out
+
+
+def _draw_copyto_context(arr, want):
+    return arr.copyto(mx.cpu(3))
+
+
+def _draw_pickle(arr, want):
+    back = pickle.loads(pickle.dumps(arr))
+    assert back.shape == (5, 6) and not _is_deferred(back)
+    return back
+
+
+def _draw_overwritten(arr, want):
+    arr[:] = 2.0                            # never made: a constant again
+    assert _is_deferred(arr) and arr._buf.value == 2.0
+    np.testing.assert_array_equal(arr.asnumpy(), 2.0)
+    assert _by_span("device.drawn_bytes") == {}
+    return None
+
+
+def _draw_dropped(arr, want):
+    arr._drop_buffer()
+    assert arr.shape == (5, 6) and arr.context == mx.cpu(1)
+    with pytest.raises(mx.MXNetError, match="fused step"):
+        arr.asnumpy()
+    with pytest.raises(mx.MXNetError, match="fused step"):
+        arr.copy().asnumpy()
+    assert _by_span("device.drawn_bytes") == {}
+    return None
+
+
+def _draw_arithmetic(arr, want):
+    return (arr * 1)
+
+
+DRAW_OPS = [_draw_copy, _draw_copyto_unread, _draw_copyto_dropped,
+            _draw_copyto_buffer, _draw_copyto_context, _draw_pickle,
+            _draw_overwritten, _draw_dropped, _draw_arithmetic]
+
+
+@pytest.mark.parametrize("op", DRAW_OPS,
+                         ids=[f.__name__[6:] for f in DRAW_OPS])
+def test_an_array_holding_a_draw_reads_as_the_draw_everywhere(op):
+    tm.enable()
+    arr, want = _drawn()
+    out = op(arr, want)
+    if out is not None:
+        np.testing.assert_array_equal(_bits(out.asnumpy()), _bits(want))
+        np.testing.assert_array_equal(_bits(arr.asnumpy()), _bits(want))
+
+
+def test_place_puts_from_a_mesh_device_without_the_host(monkeypatch):
+    """What already lies on a device of the sharding's is put from
+    there, a fresh buffer (the step donates its own); what lies
+    elsewhere goes by way of the host as it did."""
+    devs = jax.devices()
+    sharding = NamedSharding(make_mesh(dp=2, devices=devs[1:3]), P())
+    on_mesh = mx.nd.array(np.arange(6, dtype="f").reshape(2, 3),
+                          ctx=mx.cpu(1))
+    off_mesh = mx.nd.array(np.arange(6, dtype="f").reshape(2, 3) + 1,
+                           ctx=mx.cpu(4))
+    put, real = [], jax.device_put
+    monkeypatch.setattr(jax, "device_put", lambda x, *r, **k: (
+        put.append(type(x).__module__.split(".")[0]), real(x, *r, **k))[1])
+    a, b, c = nd.place([on_mesh, off_mesh, np.ones((2, 3), "f")],
+                       [sharding] * 3)
+    monkeypatch.undo()
+    assert put == ["jaxlib", "numpy", "numpy"]
+    for got, want in ((a, on_mesh.asnumpy()), (b, off_mesh.asnumpy()),
+                      (c, np.ones((2, 3), "f"))):
+        assert got.sharding == sharding
+        np.testing.assert_array_equal(np.asarray(got), want)
+    a.delete()                              # as a donation would
+    np.testing.assert_array_equal(on_mesh.asnumpy(),
+                                  np.arange(6, dtype="f").reshape(2, 3))
+
+
+def test_bucketing_and_borrowers_read_the_owners_fused_state():
+    """The host arrays of a fused owner hold nothing: whoever shares
+    them reads the step's copy."""
+    def sym_gen(key):
+        data = mx.sym.Variable("data")
+        fc = mx.sym.FullyConnected(data, num_hidden=4, name="fc")
+        return mx.sym.SoftmaxOutput(fc, name="softmax"), ("data",), (
+            "softmax_label",)
+
+    tm.enable()
+    ctx = mx.cpu(1)
+    mod = mx.mod.BucketingModule(
+        sym_gen, default_bucket_key=8, context=ctx)
+    mod.bind([("data", (8, 5))], [("softmax_label", (8,))])
+    mx.random.seed(2)
+    mod.init_params(mx.init.Normal(SIGMA))
+    mod.switch_bucket(4, [("data", (4, 5))], [("softmax_label", (4,))])
+    mod.switch_bucket(8, [("data", (8, 5))], [("softmax_label", (8,))])
+    for m in mod._buckets.values():
+        m._mesh = make_mesh(dp=1, devices=[ctx.jax_device])
+    mod.init_optimizer(kvstore="device", optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1})
+    owner = mod._curr_module
+    assert owner._fused_trainer is not None
+    assert _is_deferred(owner._arg_params["fc_weight"])
+    # every parameter a draw or a constant: nothing crossed, and the
+    # counter says 0 rather than nothing
+    assert _by_span("device.h2d_bytes") == {"module.init_optimizer": 0}
+    assert _by_span("device.drawn_bytes") == {
+        "module.init_optimizer": 4 * 4 * 5}
+    mx.random.seed(2)
+    want = _eager_normal(mx.random.next_key(), (4, 5), "float32",
+                         ctx.jax_device)
+    held = np.asarray(owner._fused_owner._fused_params["fc_weight"])
+    np.testing.assert_array_equal(_bits(held), _bits(want))
+    borrower = mod._buckets[4]
+    got = borrower.get_params()[0]["fc_weight"].asnumpy()
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    got = mod.get_params()[0]["fc_weight"].asnumpy()
+    np.testing.assert_array_equal(_bits(got), _bits(want))

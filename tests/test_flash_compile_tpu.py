@@ -933,3 +933,34 @@ def test_gate_norm_kernels_compile_inside_their_blocks_for_v5e(one_chip,
     assert len(bwd) == 1
     last = re.search(r"custom-call\(([^)]*)\)", bwd[0]).group(1).split(",")[-1]
     assert made_by[last.strip().lstrip("%")] == "get-tuple-element"
+
+
+# a stacked expert weight of the OLMoE cell's kind (three axes), an
+# attention projection, a router: what a fused fit draws on the mesh
+DRAWS = [((8, 1024, 2048), jnp.bfloat16), ((2048, 2048), jnp.bfloat16),
+         ((2048, 64), jnp.float32)]
+
+
+@pytest.mark.parametrize("shape,dtype", DRAWS)
+def test_a_parameter_is_drawn_on_the_chip_in_one_fusion(one_chip, shape,
+                                                        dtype):
+    """``ndarray._draw_program`` for the TPU: no barrier (the host's
+    bit-for-bit promise is the host's), so bits, normal, scale and cast
+    are one pass with no float32 temporary, and the reshape from rows
+    to the parameter's shape moves nothing."""
+    import numpy as np
+
+    from mxnet_tpu import ndarray as nd
+
+    compiled = nd._draw_program(shape, np.dtype(dtype), one_chip).lower(
+        jax.ShapeDtypeStruct((2,), np.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), np.float32, sharding=one_chip)).compile()
+    nd._draw_program.cache_clear()
+    text = compiled.as_text()
+    assert "opt-barrier" not in text
+    out = compiled.memory_analysis()
+    nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    assert out.output_size_in_bytes == nbytes
+    assert out.temp_size_in_bytes < 1 << 20
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r" copy\(", entry)) <= 1     # the scalar sigma

@@ -808,5 +808,5 @@ CONTRIB_OP_EXPORTS = (
     "dequantize", "count_sketch", "SwitchMoE",
     # ops/transformer.py
     "RMSNorm", "RoPE", "Attention", "LatentAttention", "Mamba2", "TopKMoE",
-    "GatedDeltaNet", "ShortConv", "ScaledSum", "KeyIndexer",
+    "GatedDeltaNet", "ShortConv", "ScaledSum", "KeyIndexer", "ExitMix",
 )

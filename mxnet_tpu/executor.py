@@ -124,7 +124,7 @@ _OP_CLASS = {
     "MakeLoss": "loss", "softmax_cross_entropy": "loss",
     "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
     "_contrib_LatentAttention": "attn", "_contrib_KeyIndexer": "attn",
-    "_contrib_Mamba2": "ssm",
+    "_contrib_Mamba2": "ssm", "_contrib_ExitMix": "loss",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
     "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",
@@ -547,6 +547,25 @@ class _PlacedProgram:
         return ct_env
 
 
+_G_SHARED_USES = _tm.gauge(
+    "lm.shared_argument_uses", "The largest number of nodes that read ONE "
+    "argument of the symbol bound last (a weight a looped stack visits T "
+    "times reads T, a head tied to the embedding 2, an ordinary weight 1): "
+    "set at bind, nothing per step")
+
+
+def _argument_uses(program):
+    """{argument name: how many node inputs of the program's graph read
+    it} (an argument with several readers has ONE gradient, their sum)."""
+    uses = {}
+    for node in program.nodes:
+        n_args = node._extra.get("n_args", len(node.inputs))
+        for c, _ in node.inputs[:n_args]:
+            if c.is_variable:
+                uses[c.name] = uses.get(c.name, 0) + 1
+    return uses
+
+
 def resolve_creation_shapes(symbol, shapes_by_name):
     """For creation ops (_zeros/_ones) whose shape attr has unknown (0)
     dims — MXNet's bind-time-inferred convention, e.g. rnn_cell
@@ -618,6 +637,9 @@ class Executor:
             self._fwd_jit = _instrument_jit(self._make_fwd(), "fwd")
             self._fwdbwd_jit = _instrument_jit(self._make_fwdbwd(), "fwdbwd")
         self._pending_train_step = False
+        if _tm.enabled():
+            _G_SHARED_USES.set(max(_argument_uses(self._program).values(),
+                                   default=0))
 
     def _build_placed(self):
         """ctx_group placement (reference AssignContext/PlaceDevice):

@@ -45,8 +45,12 @@ experts), whole or as a share, with ``dots3_reference``.
 strengths up to 2, three to one beside gated grouped attention without a
 rotary embedding, one shared and 320 sigmoid-routed experts in every
 layer), whole or as a share of its experts, of both mixers' heads and of
-its vocabulary, with ``solar_open2_reference``; ``lm_blocks`` holds what
-the LM symbols share.
+its vocabulary, with ``solar_open2_reference``. ``ouro`` is Ouro-2.6B (a
+looped language model: a Llama-shaped stack under four norms a block run
+four times over ONE set of weights, the final norm, an exit gate and the
+head after every pass, the exit-weighted loss; every weight one
+``Variable`` read at four depths), whole or as a pipeline stage, with
+``ouro_reference``; ``lm_blocks`` holds what the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -64,4 +68,5 @@ from . import (afmoe, afmoe_reference, dots3, dots3_reference, falcon_h1,
                falcon_h1_reference, kanana2, kanana2_reference, kimi_linear,
                kimi_linear_reference, lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
                nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
-               olmoe, olmoe_reference, solar_open2, solar_open2_reference)
+               olmoe, olmoe_reference, ouro, ouro_reference, solar_open2,
+               solar_open2_reference)

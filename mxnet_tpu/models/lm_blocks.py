@@ -1,40 +1,47 @@
 """The pieces the decoder-only LM symbols share (``mimo_v2``,
-``kanana2``, ``nemotron_h``, ``olmo_hybrid``, ``lfm2``, ``falcon_h1``;
-the tail also ``olmoe``): a bias-free projection, the dense SwiGLU and
-the un-gated relu² feed-forward, the one-mixer residual block, the block
-that norms a sub-layer's output and the block whose mixers read one
-normed input side by side, a fixed scalar on a node's output, the routed
-expert layer's call, the Kimi Delta Attention mixer (``kimi_linear``,
-``solar_open2``) and the head, untied or reading the embedding's
-matrix, with its loss. Each takes the node-name prefix of its layer, so a
-model's argument and scope names are its own."""
+``kanana2``, ``nemotron_h``, ``olmo_hybrid``, ``lfm2``, ``falcon_h1``,
+``ouro``; the tail also ``olmoe``): a bias-free projection, the dense
+SwiGLU and the un-gated relu² feed-forward, the one-mixer residual block,
+the block that norms a sub-layer's output and the block whose mixers read
+one normed input side by side, a fixed scalar on a node's output, the
+routed expert layer's call, the Kimi Delta Attention mixer
+(``kimi_linear``, ``solar_open2``) and the head, untied or reading the
+embedding's matrix, with its loss. Each takes the node-name prefix of its
+layer, so a model's argument and scope names are its own; a model whose
+weights are read at several depths (``ouro``) hands in the ``Variable``s
+it made once."""
 from .. import initializer as init
 from .. import symbol as sym
 from ..contrib import symbol as csym
 
 
-def linear(x, name, num_hidden, init=None):
+def linear(x, name, num_hidden, init=None, weight=None):
     """``FullyConnected`` without a bias; ``init`` is the weight's own
-    rule where the model states one."""
-    extra = {} if init is None else {
-        "weight": sym.Variable(name + "_weight", init=init)}
+    rule where the model states one. ``weight``: the matrix's
+    ``Variable`` where the model made it itself, so that several nodes
+    read ONE argument (``init`` is then the ``Variable``'s own)."""
+    if weight is None and init is not None:
+        weight = sym.Variable(name + "_weight", init=init)
+    extra = {} if weight is None else {"weight": weight}
     return sym.FullyConnected(x, num_hidden=num_hidden, no_bias=True,
                               name=name, **extra)
 
 
 def swiglu(x, prefix, width, hidden_size, gate_scale=1.0, out_scale=1.0,
-           inits=(None, None, None)):
+           inits=(None, None, None), weights=(None, None, None)):
     """``<prefix>down_proj(silu(<prefix>gate_proj(x)) *
     <prefix>up_proj(x))`` at ``width`` columns; with the two fixed
     scalars, ``gate_scale`` on the gate's pre-activation and
     ``out_scale`` on the result (``scaled``). ``inits``: the rules of the
-    gate's, the up and the down projection's weights."""
+    gate's, the up and the down projection's weights; ``weights``: their
+    ``Variable``s where the model made them itself (``linear``)."""
     gate = sym.Activation(
-        scaled(linear(x, prefix + "gate_proj", width, inits[0]),
+        scaled(linear(x, prefix + "gate_proj", width, inits[0], weights[0]),
                prefix + "gate_proj_scale", gate_scale), act_type="silu")
     return scaled(
-        linear(gate * linear(x, prefix + "up_proj", width, inits[1]),
-               prefix + "down_proj", hidden_size, inits[2]),
+        linear(gate * linear(x, prefix + "up_proj", width, inits[1],
+                             weights[1]),
+               prefix + "down_proj", hidden_size, inits[2], weights[2]),
         prefix + "down_proj_scale", out_scale)
 
 
@@ -142,12 +149,14 @@ def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
     return sym.Group([loss] + counts)
 
 
-def post_norm_block(h, prefix, norm, rms_eps, sublayer):
+def post_norm_block(h, prefix, norm, rms_eps, sublayer, gamma=None):
     """``h + RMSNorm(sublayer(h, prefix))``, the norm named
     ``<prefix><norm>``: the norm sits on the sub-layer's OUTPUT (the
-    Olmo 2 / Olmo 3 order); ``mixer_block`` norms its input."""
+    Olmo 2 / Olmo 3 order); ``mixer_block`` norms its input. ``gamma``:
+    the norm's scale where the model made the ``Variable`` itself."""
+    extra = {} if gamma is None else {"gamma": gamma}
     return h + csym.RMSNorm(sublayer(h, prefix), eps=rms_eps,
-                            name=prefix + norm)
+                            name=prefix + norm, **extra)
 
 
 def scaled(x, name, scale):

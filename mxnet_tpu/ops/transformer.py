@@ -1831,3 +1831,70 @@ register(
         aliases=("KeyIndexer",),
     )
 )
+
+
+# --------------------------------------------------------------------------
+# ExitMix — a looped language model's exit distribution over its passes and
+# the loss it weights (Zhu et al., "Scaling Latent Reasoning via Looped
+# Language Models", arXiv:2510.25741, stage I): down here so that no line
+# above moves
+# --------------------------------------------------------------------------
+_M_LOOP_VISITS = _tm.counter(
+    "lm.loop_layer_visits", "Layer visits a step of a looped stack (passes "
+    "x layers over ONE set of weights), counted where its ExitMix node is "
+    "traced (one per node and lowering, nothing per step); labels: passes")
+
+
+def exit_mix(gates, nll, beta):
+    """The exit distribution of ``gates`` [N, T] (a token's gate
+    pre-activation after each of T passes) and the loss it gives ``nll``
+    [N, T] (the token's cross-entropy at each exit): ``lambda_t =
+    sigmoid(gates_t)``; ``p_t = lambda_t prod_{j<t} (1 - lambda_j)`` for
+    ``t < T`` and ``p_T = prod_{j<T} (1 - lambda_j)``, so the last column
+    of ``gates`` is read by nothing; a token's loss ``sum_t p_t nll_t -
+    beta H(p)``, ``H(p) = -sum_t p_t log p_t``. Returns (loss [N], p [N,
+    T]), float32 both. ``log p`` is a sum of ``log_sigmoid``s (of the
+    gate where the token exits, of its negative where it stays), so a
+    saturated gate gives ``p log p`` = 0 and no ``log 0``."""
+    gates, nll = gates.astype(jnp.float32), nll.astype(jnp.float32)
+    stayed = jnp.zeros_like(gates[:, 0])  # log prod_{j<t} (1 - lambda_j)
+    log_p = []
+    for t in range(gates.shape[1] - 1):
+        log_p.append(stayed + jax.nn.log_sigmoid(gates[:, t]))
+        stayed = stayed + jax.nn.log_sigmoid(-gates[:, t])
+    log_p = jnp.stack(log_p + [stayed], axis=1)
+    p = jnp.exp(log_p)
+    return jnp.sum(p * (nll + beta * log_p), axis=1), p
+
+
+def _exit_mix(attrs, ins, is_train):
+    gates, nll = ins
+    visits = int(attrs.get("visits", 0))
+    if visits:
+        _M_LOOP_VISITS.inc(visits, passes=gates.shape[1])
+    return list(exit_mix(gates, nll, float(attrs.get("beta", 0.0))))
+
+
+def _exit_mix_infer(attrs, in_shapes):
+    known = [tuple(s) for s in in_shapes if s is not None]
+    if not known:
+        raise MXNetError("ExitMix: data shape required")
+    if len(known[0]) != 2 or any(s != known[0] for s in known):
+        raise ValueError("ExitMix: gates and nll must share one [tokens, "
+                         "passes] shape, got %s" % (in_shapes,))
+    return [known[0]] * 2, [(known[0][0],), known[0]], []
+
+
+register(
+    OpDef(
+        "_contrib_ExitMix",
+        _exit_mix,
+        arguments=("gates", "nll"),
+        outputs=("loss", "prob"),
+        defaults={"beta": 0.0, "visits": 0},
+        infer_shape=_exit_mix_infer,
+        infer_type=lambda attrs, in_types: (
+            [np.float32] * 2, [np.float32] * 2, []),
+        aliases=("ExitMix",),
+    )
+)

@@ -145,10 +145,21 @@ class Symbol:
         return _topo_order([n for n, _ in self._outputs])
 
     def list_arguments(self):
+        """The graph's free variables by name, in DFS order. Two
+        ``Variable`` nodes under ONE name would be two arguments that
+        ``bind`` by name cannot tell apart (only one would ever be fed):
+        that raises. A weight several nodes read is ONE ``Variable``
+        object handed to each of them, and is listed once."""
         args = []
         for n in self._nodes():
             if n.is_variable and not n._extra.get("is_aux"):
                 args.append(n.name)
+        if len(set(args)) != len(args):
+            twice = sorted({a for a in args if args.count(a) > 1})
+            raise MXNetError(
+                "two Variables in one graph are named %s: to let several "
+                "nodes read one argument, make the Variable once and pass "
+                "that object to each" % ", ".join(map(repr, twice)))
         return args
 
     def list_outputs(self):

@@ -121,14 +121,14 @@ def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
                   tied_to=None, logit_scale=1.0, init=None):
     """``final_norm``, the ``lm_head``, float32 logits (``lm_head_f32``)
     and each sequence's mean next-token cross-entropy behind ``MakeLoss``
-    (``loss``), grouped with the layers' counts. The head is untied, its
-    own ``lm_head_weight`` (drawn by ``init`` where given), unless
-    ``tied_to`` is the embedding's ``Variable``: then it reads that matrix
-    (``[vocab, hidden]`` is an ``Embedding``'s table and a
-    ``FullyConnected``'s weight alike), one parameter whose gradient is
-    the sum of both uses. ``logit_scale``: a fixed scalar on the float32
-    logits (the cast is ``lm_head_cast`` then, the scaled logits
-    ``lm_head_f32``)."""
+    (``loss``), grouped with the layers' counts. A token's log-probability
+    is ONE node, ``pick_log_softmax`` (``lm_head_pick``), whose own
+    backward rule keeps the logits, the labels and one number a row: no
+    [tokens, vocab] table, no scatter. The head is untied, its own
+    ``lm_head_weight`` (drawn by ``init``), unless ``tied_to`` is the
+    embedding's ``Variable``: one matrix, table and weight alike, whose
+    gradient sums both uses. ``logit_scale``: a fixed scalar on the float32
+    logits (cast ``lm_head_cast`` then, scaled logits ``lm_head_f32``)."""
     normed = csym.RMSNorm(h, eps=rms_eps, name="final_norm")
     if tied_to is None:
         logits = linear(normed, "lm_head", vocab_size, init)
@@ -140,9 +140,9 @@ def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
         logits, dtype="float32",
         name="lm_head_f32" if logit_scale == 1 else "lm_head_cast"),
         "lm_head_f32", logit_scale)
-    nll = 0 - sym.pick(sym.log_softmax(logits, name="lm_head_logp"),
-                       sym.Reshape(label, shape=(-1,)), axis=1,
-                       name="lm_head_pick")
+    nll = 0 - sym.pick_log_softmax(logits,
+                                   sym.Reshape(label, shape=(-1,)),
+                                   name="lm_head_pick")
     per_sequence = sym.mean(sym.Reshape(nll, shape=(-1, seq_len)), axis=1,
                             name="lm_head_mean")
     loss = sym.MakeLoss(per_sequence, name="loss")

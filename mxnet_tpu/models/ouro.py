@@ -30,10 +30,12 @@ and the model, for ``t = 1..T`` with the SAME weights every pass:
 lambda_t prod_{j<t}(1 - lambda_j)`` and after the last with what is
 left, ``p_T = prod_{j<T}(1 - lambda_j)``. The loss is the paper's stage
 I, the entropy-regularised expected loss: a token's ``sum_t p_t l_t -
-beta H(p)`` with ``l_t`` the next-token cross-entropy of ``z_t`` and
-``H(p) = -sum_t p_t log p_t``; gradients flow through ``p_t`` into the
-gate and the stream (nothing is detached). ``beta`` is no key of
-``config.json`` (``exit_beta``, 0.05 by default).
+beta H(p)`` with ``l_t`` the next-token cross-entropy of ``z_t`` (one
+``pick_log_softmax`` node a pass, ``loop<t>_lm_head_pick``: its backward
+rule keeps ``z_t`` as the head rounded it and one number a token, no
+[tokens, vocab] table) and ``H(p) = -sum_t p_t log p_t``; gradients flow
+through ``p_t`` into the gate and the stream (nothing is detached).
+``beta`` is no key of ``config.json`` (``exit_beta``, 0.05 by default).
 
 **One set of weights.** A layer's seven matrices and four gammas, the
 final norm's gamma, the head and the gate are ``sym.Variable``s made
@@ -173,8 +175,8 @@ def get_symbol(vocab_size=49152, hidden_size=2048, intermediate_size=5632,
         logits = sym.Cast(
             linear(h, p + "lm_head", vocab_size, weight=head),
             dtype="float32", name=p + "lm_head_f32")
-        picked = sym.pick(sym.log_softmax(logits, name=p + "lm_head_logp"),
-                          label, axis=1, name=p + "lm_head_pick")
+        picked = sym.pick_log_softmax(logits, label,
+                                      name=p + "lm_head_pick")
         nll.append(sym.Reshape(0 - picked, shape=(-1, 1)))
         gates.append(sym.FullyConnected(
             h, weight=gate_weight, bias=gate_bias, num_hidden=1,

@@ -348,3 +348,93 @@ _topk_def.list_outputs = lambda attrs=None: (
     else ["output"]
 )
 register(_topk_def)
+
+
+# --------------------------------------------------------------------------
+# pick_log_softmax — a row's log-probability of its label, with a backward
+# rule of its own (down here so that no line above moves: ``_lookup_bwd``
+# is on a kernel's call path)
+# --------------------------------------------------------------------------
+_M_PICKED_LOGP_TRACES = _tm.counter(
+    "lm.picked_logp_traces", "Traces of a pick_log_softmax node (one per "
+    "node and lowering, nothing per step); labels: rows, vocab")
+
+
+def _is_label(logits, labels):
+    """[..., V] bool, true at each row's label: an iota compared with the
+    label, so neither the pick nor its transpose is a gather or a
+    scatter."""
+    return jax.lax.broadcasted_iota(
+        jnp.int32, logits.shape, logits.ndim - 1) == labels[..., None]
+
+
+@jax.custom_vjp
+def picked_log_prob(logits, labels):
+    """``log_softmax(logits)[..., labels]`` along the last axis: logits
+    [..., V], labels [...] int32 in ``[0, V)`` -> [...] in the logits'
+    type, ``logits[label] - logsumexp(logits)``. Autodiff of
+    ``log_softmax`` + ``pick`` gathers from a float32 [..., V] table of
+    log-probabilities (which the chip writes to HBM for the gather's
+    sake: 1.9 ms a step at [4096, 50304], PERF.md section 7) and
+    transposes the gather to a scatter-add; this rule keeps the logits it
+    was given, the labels and the rows' ``lse``, sums the picked logit in
+    the pass that sums the exponentials, and its backward is ONE
+    elementwise expression, ``(onehot(label) - exp(logits - lse)) * g``:
+    the exact derivative, softmax less one-hot. Where the logits are a
+    cast of a narrower product inside one program, XLA fuses the cast into
+    each pass and only the product itself lives from the forward to the
+    backward."""
+    return _picked_fwd(logits, labels)[0]
+
+
+def _picked_fwd(logits, labels):
+    top = jnp.max(logits, axis=-1)
+    lse = top + jnp.log(jnp.sum(jnp.exp(logits - top[..., None]), axis=-1))
+    picked = jnp.sum(jnp.where(_is_label(logits, labels), logits, 0), axis=-1)
+    return picked - lse, (logits, labels, lse)
+
+
+def _picked_bwd(res, g):
+    logits, labels, lse = res
+    onehot = _is_label(logits, labels).astype(logits.dtype)
+    return (onehot - jnp.exp(logits - lse[..., None])) * g[..., None], None
+
+
+picked_log_prob.defvjp(_picked_fwd, _picked_bwd)
+
+
+def _pick_log_softmax(attrs, ins, is_train):
+    data, index = ins
+    vocab = data.shape[-1]
+    _M_PICKED_LOGP_TRACES.inc(rows=data.size // vocab, vocab=vocab)
+    # a label below zero counts from the end, as ``pick`` reads it
+    labels = index.astype(jnp.int32)
+    return [picked_log_prob(data, jnp.where(labels < 0, labels + vocab,
+                                            labels))]
+
+
+register(
+    OpDef(
+        "pick_log_softmax",
+        _pick_log_softmax,
+        arguments=("data", "index"),
+        infer_shape=lambda attrs, in_shapes: (
+            [tuple(in_shapes[0]), tuple(in_shapes[0][:-1])],
+            [tuple(in_shapes[0][:-1])],
+            [],
+        ),
+        doc="""pick(log_softmax(data, axis=-1), index, axis=-1) as one node: data
+[..., V], index [...] (cast to int32; below zero counts from the end) ->
+[...] in data's type, data[index] - logsumexp(data). A language model's
+head reads its float32 logits through it (models/lm_blocks.py
+head_and_loss, node lm_head_pick). The value and the gradient are those
+of the two ops under autodiff (the gradient is softmax less one-hot, times
+the cotangent), but the rule is the op's own: the forward writes nothing
+of [..., V]; kept for the backward are data as it was given, index and one
+number a row (the log of the row's sum of exponentials); the backward is
+one elementwise expression over [..., V], where autodiff of the two ops
+gathers from a [..., V] table of log-probabilities written for the purpose
+and transposes the gather to a scatter-add.
+An index outside [-V, V) picks nothing: the row reads -logsumexp(data).""",
+    )
+)

@@ -964,3 +964,54 @@ def test_a_parameter_is_drawn_on_the_chip_in_one_fusion(one_chip, shape,
     assert out.temp_size_in_bytes < 1 << 20
     entry = text[text.index("ENTRY"):]
     assert len(re.findall(r" copy\(", entry)) <= 1     # the scalar sigma
+
+
+# the OLMoE cell's head; Falcon-H1's, whose float32 logits pass a fixed
+# scalar (``lm_head_cast`` -> ``lm_head_f32``) before the loss reads them
+HEADS = {"olmoe": (4096, 2048, 50304, 1.0),
+         "falcon_h1": (4096, 5120, 32640, 0.0078125)}
+
+
+@pytest.mark.parametrize("cell", sorted(HEADS))
+def test_the_head_and_its_loss_write_no_float32_table_on_v5e(one_chip, cell):
+    """``lm_blocks.head_and_loss`` forward and backward at a cell's
+    shape: the head's bf16 product is the only [tokens, vocab] array an
+    op of the compiled step writes (``pick_log_softmax`` keeps it, the
+    labels and a number a row; the gradient products make the cotangent
+    inside their operand fusions), nothing is gathered or scattered, and
+    the temporaries are that one array."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.executor import _GraphProgram
+    from mxnet_tpu.models import lm_blocks
+
+    tokens, hidden, vocab, logit_scale = HEADS[cell]
+    sym = lm_blocks.head_and_loss(
+        mx.sym.Variable("data"), mx.sym.Variable("softmax_label"), [],
+        vocab, tokens, 1e-5, logit_scale=logit_scale)
+    program = _GraphProgram(sym)
+
+    def loss(params, label):
+        outs, _ = program(dict(params, softmax_label=label), {},
+                          jax.random.PRNGKey(0), True)
+        return jnp.sum(outs[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = {"data": spec((tokens, hidden), jnp.bfloat16),
+              "final_norm_gamma": spec((hidden,), jnp.bfloat16),
+              "lm_head_weight": spec((vocab, hidden), jnp.bfloat16)}
+    compiled = jax.jit(jax.grad(loss)).lower(
+        params, spec((1, tokens), jnp.float32)).compile()
+    text = compiled.as_text()
+    entry = re.sub(r"\{[^{}]*\}", "", text[text.index("ENTRY"):])
+    # what each op of the entry computation writes: the text between
+    # ``=`` and the opcode, a type or a tuple of types
+    written = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%\S+ = (.*?) [\w\-]+\(", entry, re.M)]
+    table = "[%d,%d]" % (tokens, vocab)
+    assert any("bf16" + table in w for w in written)
+    assert not [w for w in written if "f32" + table in w]
+    assert not re.search(r" (scatter|gather)\(", text)
+    logits = tokens * vocab * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * logits

@@ -323,9 +323,9 @@ def test_mimo_and_olmoe_keep_their_node_names_over_the_shared_blocks():
                                  experts_per_token=2, expert_width=16,
                                  seq_len=8)):
         internals = sym.get_internals().list_outputs()
-        for name in ("lm_head_f32_output", "lm_head_logp_output",
-                     "lm_head_pick_output", "lm_head_mean_output",
-                     "loss_output", "final_norm_output"):
+        for name in ("lm_head_f32_output", "lm_head_pick_output",
+                     "lm_head_mean_output", "loss_output",
+                     "final_norm_output"):
             assert name in internals
 
 

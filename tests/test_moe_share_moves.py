@@ -302,10 +302,11 @@ def test_a_share_models_step_scatters_nothing_in_its_expert_layers(
         monkeypatch, seam):
     """The LFM2 share symbol at T 128 (two expert layers, a buffer of 768
     rows each), forward and backward as a step traces it. With the
-    kernels' branch taken (the seam) the program's only scatter is the
-    loss's pick over the vocabulary (the embedding's gradient is a sorted
-    segment sum too, since PR 50): none has the tokens' shape, where the
-    tree's formulation had two a layer (and a scalar one). On the CPU's
+    kernels' branch taken (the seam) the program has no scatter at all
+    (the embedding's gradient is a sorted segment sum since PR 50, and the
+    loss's pick over the vocabulary, the last one, went with PR 65's
+    ``pick_log_softmax``), where the tree's formulation had two a layer
+    of the tokens' shape (and a scalar one). On the CPU's
     own branch the segment sums are the scatters: two a layer of that
     shape, one of [tokens, top_k] and the embedding's over the table, each
     over sorted indices."""
@@ -341,7 +342,7 @@ def test_a_share_models_step_scatters_nothing_in_its_expert_layers(
     text = jax.jit(jax.grad(loss)).lower(args, feeds).compiler_ir(
         dialect="hlo").as_hlo_text()
     scatters = re.findall(r"= (\S+?)\{[^ ]*\} scatter\((.*)", text)
-    others = ["f32[%d,%d]" % (batch * t, vocab)]
+    others = []
     # a token's rows, the weights' cotangents placed the same way, and the
     # table's rows
     summed = ["f32[%d,%d]" % (batch * t, hidden), "f32[%d,3]" % (batch * t),

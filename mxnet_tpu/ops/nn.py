@@ -216,6 +216,60 @@ def _conv_nhwc_dn():
         (1, 1, 1, 1), (1, 1, 1, 1), ("NHWC", "HWIO", "NHWC"))
 
 
+def _conv_named_grads(plain, data, weight, dgrad=None, wgrad=None):
+    """``plain(data, weight)`` with its two gradient convolutions traced
+    under ``jax.named_scope("dgrad")`` and ``("wgrad")``: the one
+    custom_vjp scaffold of the default path and of every lever, so that
+    in a device trace a backward op says which of the two it is
+    (``.../jvp(conv/<node>)/dgrad/...`` inside jax's ``transpose(``) and
+    a fix to either construction lands everywhere at once.
+
+    ``dgrad(d, w, g)`` / ``wgrad(d, w, g)`` replace a gradient; without
+    one it is jax's own transpose of ``plain``: one vjp per gradient
+    conv, so that each carries its own scope (the unused primal convs
+    are dead code). A scope is metadata: the default path compiles to
+    the program the bare differentiated call gives, and a data gradient
+    nobody reads (the first convolution's, of the batch) is dead code
+    that jax drops before it lowers, as its own rule would not have
+    made it."""
+
+    @jax.custom_vjp
+    def conv(d, w):
+        return plain(d, w)
+
+    def fwd(d, w):
+        return plain(d, w), (d, w)
+
+    def bwd(res, g):
+        d, w = res
+        with jax.named_scope("dgrad"):
+            gd = (dgrad(d, w, g) if dgrad is not None
+                  else jax.vjp(lambda dd: plain(dd, w), d)[1](g)[0])
+        with jax.named_scope("wgrad"):
+            gw = (wgrad(d, w, g) if wgrad is not None
+                  else jax.vjp(lambda ww: plain(d, ww), w)[1](g)[0])
+        return gd, gw
+
+    conv.defvjp(fwd, bwd)
+    return conv(data, weight)
+
+
+def _conv_plain(nd, stride, pad, dilate, groups=1):
+    """The NCHW / OIHW convolution every path's forward is.
+
+    NOTE: no preferred_element_type here — the MXU accumulates bf16
+    matmuls in fp32 natively, and an explicit f32 output + cast breaks
+    lax's conv transpose rules under bf16 (mixed-dtype cotangent)."""
+
+    def plain(d, w):
+        return jax.lax.conv_general_dilated(
+            d, w, window_strides=stride,
+            padding=[(p, p) for p in pad], rhs_dilation=dilate,
+            dimension_numbers=_conv_dn(nd), feature_group_count=groups)
+
+    return plain
+
+
 def _conv2d_bwd_nhwc(data, weight, stride, pad, dilate, groups):
     """2-D conv, NCHW interface, with the BACKWARD convs computed in
     explicit NHWC layout (custom_vjp; forward stays the plain NCHW conv
@@ -233,75 +287,41 @@ def _conv2d_bwd_nhwc(data, weight, stride, pad, dilate, groups):
     fuse or cancel. Gated by MXNET_CONV_BWD_LAYOUT=NHWC; numerics
     pinned against the default path in tests/test_conv_bwd_layout.py."""
 
-    @jax.custom_vjp
-    def conv(data, weight):
+    def f_nhwc(dt, wt):
         return jax.lax.conv_general_dilated(
-            data, weight, window_strides=stride,
+            dt, wt, window_strides=stride,
             padding=[(p, p) for p in pad], rhs_dilation=dilate,
-            dimension_numbers=_conv_dn(2), feature_group_count=groups)
+            dimension_numbers=_conv_nhwc_dn(),
+            feature_group_count=groups)
 
-    def fwd(data, weight):
-        return conv(data, weight), (data, weight)
+    def nhwc(a):
+        return jnp.transpose(a, (0, 2, 3, 1))          # NCHW -> NHWC
 
-    def bwd(res, g):
-        data, weight = res
-        data_t = jnp.transpose(data, (0, 2, 3, 1))     # NCHW -> NHWC
-        weight_t = jnp.transpose(weight, (2, 3, 1, 0))  # OIHW -> HWIO
+    def hwio(w):
+        return jnp.transpose(w, (2, 3, 1, 0))          # OIHW -> HWIO
 
-        def f_nhwc(dt, wt):
-            return jax.lax.conv_general_dilated(
-                dt, wt, window_strides=stride,
-                padding=[(p, p) for p in pad], rhs_dilation=dilate,
-                dimension_numbers=_conv_nhwc_dn(),
-                feature_group_count=groups)
+    def dgrad(d, w, g):
+        gd_t, = jax.vjp(lambda dt: f_nhwc(dt, hwio(w)), nhwc(d))[1](nhwc(g))
+        return jnp.transpose(gd_t, (0, 3, 1, 2))
 
-        g_t = jnp.transpose(g, (0, 2, 3, 1))
-        # one vjp per gradient conv, so that each carries its own scope
-        # into a device trace (the unused primal convs are dead code)
-        with jax.named_scope("dgrad"):
-            gd_t, = jax.vjp(lambda dt: f_nhwc(dt, weight_t), data_t)[1](g_t)
-            gd = jnp.transpose(gd_t, (0, 3, 1, 2))
-        with jax.named_scope("wgrad"):
-            gw_t, = jax.vjp(lambda wt: f_nhwc(data_t, wt), weight_t)[1](g_t)
-            gw = jnp.transpose(gw_t, (3, 2, 0, 1))
-        return gd, gw
+    def wgrad(d, w, g):
+        gw_t, = jax.vjp(lambda wt: f_nhwc(nhwc(d), wt), hwio(w))[1](nhwc(g))
+        return jnp.transpose(gw_t, (3, 2, 0, 1))
 
-    conv.defvjp(fwd, bwd)
-    return conv(data, weight)
+    return _conv_named_grads(_conv_plain(2, stride, pad, dilate, groups),
+                             data, weight, dgrad, wgrad)
 
 
 def _conv2d_wgrad_custom(data, weight, stride, pad, dilate, wgrad_fn):
-    """Shared custom_vjp scaffold for the wgrad levers: forward and the
-    DATA gradient stay jax's own lowerings (vjp of the plain conv);
-    only the filter gradient is replaced by wgrad_fn(d, g, w) -> f32
-    array reshapeable to w.shape. Keeping one scaffold means a fix to
-    the dgrad construction or the cotangent dtype cast lands in every
-    lever at once."""
+    """The wgrad levers: forward and the DATA gradient stay jax's own
+    lowerings (vjp of the plain conv); only the filter gradient is
+    replaced by wgrad_fn(d, g, w) -> f32 array reshapeable to w.shape."""
 
-    def plain(d, w):
-        return jax.lax.conv_general_dilated(
-            d, w, window_strides=stride,
-            padding=[(p, p) for p in pad], rhs_dilation=dilate,
-            dimension_numbers=_conv_dn(2))
+    def wgrad(d, w, g):
+        return wgrad_fn(d, g, w).astype(w.dtype).reshape(w.shape)
 
-    @jax.custom_vjp
-    def conv(data, weight):
-        return plain(data, weight)
-
-    def fwd(data, weight):
-        return conv(data, weight), (data, weight)
-
-    def bwd(res, g):
-        d, w = res
-        with jax.named_scope("dgrad"):
-            _, dgrad_vjp = jax.vjp(lambda dd: plain(dd, w), d)
-            gd, = dgrad_vjp(g)
-        with jax.named_scope("wgrad"):
-            gw = wgrad_fn(d, g, w).astype(w.dtype).reshape(w.shape)
-        return gd, gw
-
-    conv.defvjp(fwd, bwd)
-    return conv(data, weight)
+    return _conv_named_grads(_conv_plain(2, stride, pad, dilate),
+                             data, weight, wgrad=wgrad)
 
 
 def _conv2d_wgrad_patches(data, weight, stride, pad, dilate):
@@ -453,31 +473,16 @@ def _conv2d_pallas_bwd(data, weight, pad):
 
     interpret = kernels.common.INTERPRET
 
-    def plain(d, w):
-        return jax.lax.conv_general_dilated(
-            d, w, window_strides=(1, 1),
-            padding=[(p, p) for p in pad],
-            dimension_numbers=_conv_dn(2))
+    def dgrad(d, w, g):
+        return kernels.conv_bwd_input(
+            g, w, d.shape, pad, interpret=interpret).astype(d.dtype)
 
-    @jax.custom_vjp
-    def conv(d, w):
-        return plain(d, w)
+    def wgrad(d, w, g):
+        return kernels.conv_bwd_filter(
+            d, g, w.shape, pad, interpret=interpret).astype(w.dtype)
 
-    def fwd(d, w):
-        return plain(d, w), (d, w)
-
-    def bwd(res, g):
-        d, w = res
-        with jax.named_scope("dgrad"):
-            gd = kernels.conv_bwd_input(
-                g, w, d.shape, pad, interpret=interpret).astype(d.dtype)
-        with jax.named_scope("wgrad"):
-            gw = kernels.conv_bwd_filter(
-                d, g, w.shape, pad, interpret=interpret).astype(w.dtype)
-        return gd, gw
-
-    conv.defvjp(fwd, bwd)
-    return conv(data, weight)
+    return _conv_named_grads(_conv_plain(2, (1, 1), pad, (1, 1)),
+                             data, weight, dgrad, wgrad)
 
 
 def _conv2d_s2d_strided(data, weight, kernel, pad, groups):
@@ -594,19 +599,10 @@ def _convolution(attrs, ins, is_train):
             and groups == 1):
         out = _conv2d_wgrad_taps(data, weight, stride, pad, dilate)
     else:
-        # NOTE: no preferred_element_type here — the MXU accumulates bf16
-        # matmuls in fp32 natively, and an explicit f32 output + cast
-        # breaks lax's conv transpose rules under bf16 (mixed-dtype
-        # cotangent)
-        out = jax.lax.conv_general_dilated(
-            data,
-            weight,
-            window_strides=stride,
-            padding=[(p, p) for p in pad],
-            rhs_dilation=dilate,
-            dimension_numbers=_conv_dn(nd),
-            feature_group_count=groups,
-        )
+        # XLA's own lowering of all three convs, the two gradient
+        # ones under their names
+        out = _conv_named_grads(
+            _conv_plain(nd, stride, pad, dilate, groups), data, weight)
     if not bool(attrs.get("no_bias", False)):
         bias = ins[2].reshape((1, -1) + (1,) * nd)
         out = out + bias

@@ -1015,3 +1015,49 @@ def test_the_head_and_its_loss_write_no_float32_table_on_v5e(one_chip, cell):
     assert not re.search(r" (scatter|gather)\(", text)
     logits = tokens * vocab * 2
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * logits
+
+
+# two of ResNet-50's convolutions at the cell's batch of 256 in bf16: a
+# 3 x 3 of stage 2 and the strided 1 x 1 ``stage2_unit1_sc``
+# (data, filter, stride, pad)
+RESNET_CONVS = {
+    "stage2_unit2_conv2": ((256, 128, 28, 28), (128, 128, 3, 3), (1, 1),
+                           (1, 1)),
+    "stage2_unit1_sc": ((256, 256, 56, 56), (512, 256, 1, 1), (2, 2), (0, 0)),
+}
+
+
+@pytest.mark.parametrize("node", sorted(RESNET_CONVS))
+def test_named_conv_gradients_compile_to_the_parents_program_on_v5e(
+        one_chip, node, monkeypatch):
+    """``ops/nn.py::_conv_named_grads`` is names and nothing else where
+    it counts: for the chip the optimized HLO of a ``Convolution`` node's
+    backward, its scopes ``dgrad`` and ``wgrad`` in it, is the bare
+    differentiated call's once the metadata is stripped."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.executor import _GraphProgram
+    from mxnet_tpu.ops import nn
+    from test_conv_pass_scopes import PARENT_FORM, stripped
+
+    dshape, wshape, stride, pad = RESNET_CONVS[node]
+    program = _GraphProgram(mx.sym.Convolution(
+        mx.sym.Variable("data"), kernel=wshape[2:], stride=stride, pad=pad,
+        num_filter=wshape[0], no_bias=True, name=node))
+
+    def loss(args):
+        out, = program(args, {}, None, True)[0]
+        return jnp.sum((out * out).astype(jnp.float32))
+
+    args = {"data": jax.ShapeDtypeStruct(dshape, jnp.bfloat16,
+                                         sharding=one_chip),
+            node + "_weight": jax.ShapeDtypeStruct(wshape, jnp.bfloat16,
+                                                   sharding=one_chip)}
+    new = jax.jit(jax.grad(loss)).lower(args).compile().as_text()
+    monkeypatch.setattr(nn, "_conv_named_grads", PARENT_FORM)
+    old = jax.jit(jax.grad(loss)).lower(args).compile().as_text()
+    # (alone, the filter gradient's convolution comes out of the TPU's
+    # passes with no metadata at all, in either form; inside a whole step
+    # it keeps its name: PERF.md section 5 item 2)
+    assert "transpose(jvp(conv/%s))/dgrad/" % node in new
+    assert "dgrad" not in old and "wgrad" not in old
+    assert stripped(new) == stripped(old)

@@ -19,6 +19,8 @@ because they need explicit on-chip (VMEM) accumulation patterns.
                of Mamba2, GatedDeltaNet and ShortConv, with its epilogue
   gate_norm    the gate and the grouped RMSNorm behind the scan (Mamba2)
                and the delta rule (GatedDeltaNet), one pass each way
+  rope         the rotary embedding of whole heads of whole lane rows,
+               one pass each way that is Attention's transposition too
   conv         the conv-backward pair
   common       what they share
 
@@ -48,6 +50,7 @@ from .gmm import (
     held_transposed, sorted_segment_sum)
 from .latent import (
     latent_flash, latent_flash_takes, latent_query, latent_query_takes)
+from .rope import rope_rows, rotate_heads
 from .ssd import ssd_scan, ssd_takes
 from .taps import causal_conv, taps_takes
 
@@ -60,6 +63,7 @@ __all__ = [
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
     "grouped_matmul", "held_transposed", "latent_flash",
     "latent_flash_takes", "latent_query",
-    "latent_query_takes", "reference_attention", "sorted_segment_sum",
+    "latent_query_takes", "reference_attention", "rope_rows", "rotate_heads",
+    "sorted_segment_sum",
     "ssd_scan", "ssd_takes", "taps_takes",
 ]

@@ -86,16 +86,16 @@ def rope(x, num_heads, theta, rotary_dim=0, offset=0, interleave=False):
     The pairs are (i, i + R/2) — the ``rotate_half`` convention — or,
     with ``interleave``, (2i, 2i + 1); pair i turns by ``pos *
     theta^(-2i/R)`` either way. Angles, sines and the rotation itself
-    are float32; the result is ``x``'s dtype."""
+    are float32; the result is ``x``'s dtype. Whole heads of whole lane
+    rows under ``rotate_half`` take ONE pass each way and no half is an
+    array (``_takes_one_pass``, at this file's end): results are EQUAL."""
     b, t, hd = x.shape
     d = hd // num_heads
     r = rotary_dim or d - offset
-    inv_freq = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
-    angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
-    if interleave:  # a pair's two lanes share its angle
-        angles = np.repeat(angles, 2, axis=-1)
-    cos = jnp.asarray(np.cos(angles), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(angles), jnp.float32)[None, :, None, :]
+    if _takes_one_pass(x, num_heads, r, offset, interleave):
+        return _rotate_whole_heads(x, num_heads, theta)
+    cos, sin = (jnp.asarray(table, jnp.float32)[None, :, None, :]
+                for table in _rope_tables(t, r, theta, interleave))
     x4 = x.astype(jnp.float32).reshape(b, t, num_heads, d)
     rot = x4[..., offset: offset + r]
     if interleave:
@@ -1898,3 +1898,62 @@ register(
         aliases=("ExitMix",),
     )
 )
+
+
+# --------------------------------------------------------------------------
+# RoPE's one-pass form (``rope`` chooses; down here so that no line above
+# moves)
+# --------------------------------------------------------------------------
+_M_ROPE_LOWERINGS = _tm.counter(
+    "rope.lowerings", "Traces of a rope call site (one per lowering, "
+    "nothing per step); labels: heads, head_dim, form (one_pass: a whole "
+    "head of whole lane rows rotated in one pass each way, "
+    "ops/kernels/rope.py; halves: the two halves computed apart and "
+    "concatenated)")
+
+
+def _rope_tables(t, r, theta, interleave=False):
+    """cos and sin of positions 0..t-1 times pair i's frequency
+    ``theta^(-2i/r)``, float64 [t, r/2]; under ``interleave`` [t, r], a
+    pair's two lanes sharing its angle."""
+    inv_freq = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
+    angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    if interleave:
+        angles = np.repeat(angles, 2, axis=-1)
+    return np.cos(angles), np.sin(angles)
+
+
+def _takes_one_pass(x, num_heads, r, offset, interleave):
+    """Whether a ``rope`` call is a whole head's rotation over whole lane
+    rows that ``kernels.rope_rows`` has a tile for, read off the call's
+    own arguments (and, as ``Embedding``'s rule, not in a program the
+    partitioner splits: the kernels have no partitioning rule); counts
+    the call site."""
+    from . import kernels
+
+    d = x.shape[2] // num_heads
+    one_pass = (not interleave and offset == 0 and r == d
+                and kernels.rope_rows(num_heads, d, x.shape[1],
+                                      x.dtype) is not None
+                and not kernels.common.trace_is_partitioned())
+    _M_ROPE_LOWERINGS.inc(heads=num_heads, head_dim=d,
+                          form="one_pass" if one_pass else "halves")
+    return one_pass
+
+
+@functools.lru_cache(maxsize=None)
+def _whole_head_tables(t, d, theta):
+    """``[cos | cos]`` and ``[-sin | sin]``, float32 [t, d]: one pair of
+    arrays a shape, so that a program's call sites share two constants."""
+    cos, sin = _rope_tables(t, d, theta)
+    return (np.concatenate([cos, cos], axis=-1).astype(np.float32),
+            np.concatenate([-sin, sin], axis=-1).astype(np.float32))
+
+
+def _rotate_whole_heads(x, num_heads, theta):
+    """``rope``'s one-pass form: ``kernels.rotate_heads`` on the tables."""
+    from . import kernels
+
+    c, s = _whole_head_tables(x.shape[1], x.shape[2] // num_heads, theta)
+    return kernels.rotate_heads(x, c, s, num_heads,
+                                interpret=kernels.common.INTERPRET)

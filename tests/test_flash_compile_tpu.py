@@ -1061,3 +1061,57 @@ def test_named_conv_gradients_compile_to_the_parents_program_on_v5e(
     assert "transpose(jvp(conv/%s))/dgrad/" % node in new
     assert "dgrad" not in old and "wgrad" not in old
     assert stripped(new) == stripped(old)
+
+
+# -- the rotation between its neighbours (PR 67) -----------------------------
+
+# tokens, query heads, key/value heads: Ouro's and OLMoE's layer,
+# Trinity-Mini's, Falcon-H1's (hidden 2,048 in front of all three)
+ROPE_LAYERS = {
+    "ouro_olmoe": (4096, 16, 16),
+    "trinity_mini": (8192, 32, 4),
+    "falcon_h1": (4096, 10, 2),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROPE_LAYERS))
+def test_the_rotation_is_two_kernels_and_moves_nothing_on_v5e(one_chip, cell):
+    """Projection, ``rope``, ``attention``, forward and backward, at a
+    cell's shape: the compiled program holds the rotation's kernel pair
+    for ``q`` and for ``k`` and, under the rotation's scope, NOTHING else:
+    no half of a head is an array, no float32 copy of the operand, and the
+    transposition to heads first is the kernels' own (what they write
+    heads first ``Attention`` reads where it lies)."""
+    from mxnet_tpu.ops import transformer
+
+    t, heads, kv_heads = ROPE_LAYERS[cell]
+    d, hidden = 128, 2048
+
+    def loss(h, wq, wk, wv):
+        q, k, v = h @ wq, h @ wk, h @ wv
+        with jax.named_scope("ROTATION"):
+            q = transformer.rope(q, heads, 1e4)
+            k = transformer.rope(k, kv_heads, 1e4)
+        out = pk.attention(q.reshape(1, t, heads, d),
+                           k.reshape(1, t, kv_heads, d),
+                           v.reshape(1, t, kv_heads, d), causal=True)
+        return jnp.sum(jnp.square(out.astype(jnp.float32)))
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        spec(1, t, hidden), spec(hidden, heads * d),
+        spec(hidden, kv_heads * d), spec(hidden, kv_heads * d)
+    ).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    for n in (heads, kv_heads):
+        rows = pk.rope_rows(n, d, t, jnp.bfloat16)
+        for way in ("fwd", "bwd"):
+            assert "rope_%s_bf16_r%d_h%d_d%d" % (way, rows, n, d) in entry
+    scoped = [line for line in entry.splitlines() if "ROTATION" in line]
+    assert len(scoped) >= 4
+    assert all("custom-call(" in line and "rope_" in line
+               for line in scoped), scoped
+    assert not re.search(r"\[1,%d,\d+,64\]" % t, entry)
+    assert not re.search(r"f32\[1,(%d,\d+|\d+,%d),128\]" % (t, t), entry)

@@ -1,8 +1,9 @@
 """Busy milliseconds of device 0 per step in the KDA layers' nine
 projections (the ``FullyConnected`` nodes named
-``layer<i>_kda_{q,k,v,o}_proj``: 2304 -> 4096 three times and back; the
-low-rank pairs ``layer<i>_kda_{f,g}_{a,b}_proj``: 2304 -> 128 -> 4096
-into the decay and into the gate; ``layer<i>_kda_b_proj``: 2304 -> 32),
+``layer<i>_kda_{q,k,v,o}_proj``: hidden -> the heads held three times
+and back; the low-rank pairs ``layer<i>_kda_{f,g}_{a,b}_proj``: hidden
+-> 128 -> the heads held, into the decay and into the gate;
+``layer<i>_kda_b_proj``: hidden -> a column a head), every KDA layer,
 forward and backward together: the part of a KDA layer that is plain
 matrix products."""
 import kda_scopes

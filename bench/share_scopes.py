@@ -93,14 +93,16 @@ def flops_of(run):
 def held_rows(run):
     """Rows each expert layer's held experts received in the last step
     (the whole batch's): from the model's count outputs, over the
-    share's experts. None
-    where the run has no counts or the configuration no share."""
+    share's experts, whose number the configuration spells
+    ``n_routed_experts`` or ``num_experts``. None where the run has no
+    counts or the configuration no share of its experts."""
     cfg = run.get("cfg", {})
     counts, share = run.get("expert_counts"), cfg.get("share")
-    if not counts or not share:
+    held = cfg.get("n_routed_experts", cfg.get("num_experts"))
+    if not counts or not share or not held:
         return None
     lo = share.get("expert_offset", 0)
-    return [sum(layer[lo:lo + cfg["n_routed_experts"]]) for layer in counts]
+    return [sum(layer[lo:lo + held]) for layer in counts]
 
 
 if __name__ == "__main__":

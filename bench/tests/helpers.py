@@ -33,3 +33,24 @@ def check_rehearsal(proc, metric_names):
     for name, m in result["metrics"].items():
         assert set(m) == {"unit"}, (name, m)
     return result
+
+
+PARENT_READERS = os.path.join("tests", "parent_readers")
+
+
+def parent_reader(name):
+    """A reader as the tree before PR 68 had it (``parent_readers/``);
+    its setup readers import that tree's ``setup_phases`` as
+    ``parent_setup_phases``, found beside them."""
+    import lib
+    beside = os.path.join(BENCH, PARENT_READERS)
+    if beside not in sys.path:
+        sys.path.append(beside)
+    return lib.load_module(PARENT_READERS, name)
+
+
+def folded():
+    """old reader -> {kept, how, cells} (``parent_readers/folded.json``)."""
+    import lib
+    return lib.load_json(lib.find(PARENT_READERS, "folded", ".json"))[
+        "readers"]

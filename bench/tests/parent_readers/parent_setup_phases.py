@@ -19,17 +19,15 @@ window opened (``lib.Session.window_open``), which the program keeps
 ``run["open_t"]``. A program without these streams (an older commit)
 reads as ``None``, never as zero and never as a raise.
 
-The six terms of the partition, in seconds::
+The eight terms of the partition, in seconds::
 
-    setup_s = runtime + import + init_params + init_optimizer
-              + first_dispatch + unattributed
+    setup_s = runtime + import + bind + init_params + init_optimizer
+              + first_dispatch + telemetry + unattributed
               + (open_t - first_step_t)        the harness's own interval
 
-``module.bind`` (0.01-0.15 s) and ``telemetry.cost_capture`` (0.02-0.14 s
-on the chip) are spans no entry reads: they lie in ``unattributed``, so
-that the parts sum to ``setup_s``, and ``remainder`` takes them out
-before it judges, because its limits are for what NO span covers. The
-jax seconds of ``telemetry.cost_capture`` carry ``under`` =
+``telemetry.cost_capture`` never nests in ``train_step.first_dispatch``
+(the program opens it after the dispatch has returned), so nothing is
+subtracted from that term; its jax seconds carry ``under`` =
 ``telemetry.cost_capture`` wherever it nests, so ``trace_lower_s``
 leaves them out by label.
 """
@@ -39,13 +37,13 @@ SPAN_SECONDS = "mxtpu.span_seconds"
 COST_CAPTURE = "telemetry.cost_capture"
 # span -> the partition's term it is
 SPAN_TERMS = {
+    "bind": "module.bind",
     "init_params": "module.init_params",
     "init_optimizer": "module.init_optimizer",
     "first_dispatch": "train_step.first_dispatch",
+    "telemetry": COST_CAPTURE,
 }
 TERMS = ("runtime", "import") + tuple(SPAN_TERMS) + ("unattributed",)
-# spans with no entry of their own: in the remainder, not in its verdict
-UNLISTED_SPANS = ("module.bind", COST_CAPTURE)
 H2D_ROOTS = ("module.bind", "module.init_params", "module.init_optimizer")
 # what the remainder may hold, jax's seconds outside every span apart,
 # before the run fails (PERF.md section 5 says what was found in it)
@@ -137,30 +135,24 @@ def outside_jit_s(run):
 
 
 def remainder(run):
-    """``unattributed`` as ``(value, ok, why)``, judged on what no span
-    covers (the value less ``UNLISTED_SPANS``): below the floor two
+    """``unattributed`` as ``(value, ok, why)``: below the floor two
     terms counted one interval twice; above the limit, once jax's
     seconds outside every span are taken out, something of size has no
     span."""
-    snap = registry_at_open(run)
-    found = terms(run, snap)
+    found = terms(run)
     value = found["unattributed"]
     if value is None:
         return None
     limit = max(REMAINDER_MIN_LIMIT_S, REMAINDER_LIMIT_SHARE * run["setup_s"])
     outside = outside_jit_s(run)
-    unlisted = sum(span_seconds(snap, s) or 0.0 for s in UNLISTED_SPANS)
-    bare = value - unlisted
     why = ("setup_s %.3f = %s + harness %.3f + unattributed %.3f, of which "
-           "%s %.3f and jax outside every span %.3f (limits %.1f .. %.3f on "
-           "the rest)"
+           "jax outside every span %.3f (limits %.1f .. %.3f on the rest)"
            % (run["setup_s"],
               " + ".join("%s %.3f" % (t, found[t] or 0.0)
                          for t in TERMS[:-1]),
-              harness_s(run), value, " + ".join(UNLISTED_SPANS), unlisted,
-              outside, REMAINDER_FLOOR_S, limit))
-    return value, (REMAINDER_FLOOR_S <= bare
-                   and bare - outside <= limit), why
+              harness_s(run), value, outside, REMAINDER_FLOOR_S, limit))
+    return value, (REMAINDER_FLOOR_S <= value
+                   and value - outside <= limit), why
 
 
 def trace_lower_s(run):

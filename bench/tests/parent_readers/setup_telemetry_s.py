@@ -2,7 +2,7 @@
 opened: what the traced run's set-up pays that the untraced run's does
 not (the step's second lowering, the symbol's cost table). None where
 the program opens no such span."""
-import setup_phases
+import parent_setup_phases as setup_phases
 
 
 def compute(trace, counters, run):

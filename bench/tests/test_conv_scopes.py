@@ -1,6 +1,6 @@
 """``conv_scopes``: whole steps counted in a slice that cuts one, the
 bounds of two of ResNet-50's convolutions against figures worked by hand,
-the unnamed-gradient check, the four readers over a reduction handed in,
+the unnamed-gradient check, the six readers over a reduction handed in,
 and their manifest entries (found by name)."""
 import pytest
 
@@ -11,10 +11,10 @@ import reduce_trace as rt
 
 CELLS = ["resnet50_fit_resident", "resnet50_fit_dp4",
          "inception_v3_fit_resident"]
-# the manifest holds 128 per-layer metrics at most and had 124: the three
-# passes' milliseconds and the share of the pass furthest from its bound
+# the three passes' milliseconds and each pass's share of its bound (PR 66
+# had room for the data gradient's alone; PR 68 listed the other two)
 METRICS = dict({"conv_%s_device_ms" % p: "ms/step" for p in cs.PASSES},
-               conv_dgrad_roofline_share="%")
+               **{"conv_%s_roofline_share" % p: "%" for p in cs.PASSES})
 PEAK = lib.load_json(lib.BENCH + "/peaks.json")["TPU v5 lite"]
 TRACE = {"devices": {}}  # the harness's own reduction: only its truth is read
 
@@ -207,9 +207,8 @@ def test_the_readers_read_the_reduction(which, ns):
     # 16.3 ms of bound over a hand-made step of nanoseconds
     assert cs.pass_roofline_share(TRACE, run, which) == pytest.approx(
         100 * 16.337 / (ns * 1e-6), rel=1e-3)
-    if which == "dgrad":
-        assert reader("conv_dgrad_roofline_share").compute(
-            TRACE, {}, run) == cs.pass_roofline_share(TRACE, run, which)
+    assert reader("conv_%s_roofline_share" % which).compute(
+        TRACE, {}, run) == cs.pass_roofline_share(TRACE, run, which)
 
 
 @pytest.mark.parametrize("name", sorted(METRICS))

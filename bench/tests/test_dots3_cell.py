@@ -293,19 +293,25 @@ TRACE_READERS = ["dots3_index_device_ms", "dots3_index_topk_device_ms",
                  "dots3_attn_select_roofline_share",
                  "dots3_attn_window_device_ms",
                  "dots3_attn_window_roofline_share",
-                 "dots3_attn_proj_device_ms", "dots3_moe_device_ms"]
-READERS = TRACE_READERS + ["dots3_held_rows_over_expected",
+                 "dots3_attn_proj_device_ms", "moe_share_device_ms",
+                 "shared_expert_device_ms"]
+READERS = TRACE_READERS + ["moe_share_rows_over_expected",
                            "dots3_keys_selected_over_expected"]
+# every share's entries since PR 68 (``dots3_moe_device_ms``, routed +
+# shared, and ``dots3_held_rows_over_expected`` until then)
+SHARED = ["moe_share_device_ms", "shared_expert_device_ms",
+          "moe_share_rows_over_expected"]
 
 
-def test_the_ten_readers_read_what_they_say():
+def test_the_eleven_readers_read_what_they_say():
     run = _run()
     assert _read("dots3_index_device_ms", run) == pytest.approx(6.0)
     assert _read("dots3_index_topk_device_ms", run) == pytest.approx(2.0)
     assert _read("dots3_attn_select_device_ms", run) == pytest.approx(6.0)
     assert _read("dots3_attn_window_device_ms", run) == pytest.approx(3.0)
     assert _read("dots3_attn_proj_device_ms", run) == pytest.approx(18.0)
-    assert _read("dots3_moe_device_ms", run) == pytest.approx(30.0)
+    assert _read("moe_share_device_ms", run) == pytest.approx(22.0)
+    assert _read("shared_expert_device_ms", run) == pytest.approx(8.0)
     # two full layers, three forwards each of the selected pairs x 16
     # heads x 320 multiply-adds at 197 T/s, of 6 ms
     share = _read("dots3_attn_select_roofline_share", run)
@@ -320,9 +326,9 @@ def test_the_ten_readers_read_what_they_say():
     assert 0 < share < 100
     # the held experts are the first eight: (8 x 100 + 8 x 140 + 2 x
     # 1024) rows of 4 x 1024
-    assert _read("dots3_held_rows_over_expected", run) == pytest.approx(
+    assert _read("moe_share_rows_over_expected", run) == pytest.approx(
         (800 + 1120 + 2048) / 4096.0)
-    assert _read("dots3_held_rows_over_expected", run, trace=False) \
+    assert _read("moe_share_rows_over_expected", run, trace=False) \
         == pytest.approx(0.96875)               # a model output, no trace
     value, ok, why = _read("dots3_keys_selected_over_expected", run)
     assert (value, ok) == (1.0, True) and "6292480" in why
@@ -344,10 +350,10 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
     assert _read(name, _run(cfg=kanana, **nothing)) is None
     # another model's run, whatever its scopes hold: only the readers of
     # the dots3 scopes alone would read them
-    assert _read(name, _run(cfg=kanana)) is None or name in (
+    assert _read(name, _run(cfg=kanana)) is None or name in [
         "dots3_index_device_ms", "dots3_index_topk_device_ms",
         "dots3_attn_select_device_ms", "dots3_attn_window_device_ms",
-        "dots3_attn_proj_device_ms")
+        "dots3_attn_proj_device_ms"] + SHARED
     if name in TRACE_READERS:
         assert _read(name, _run(), trace=False) is None
         assert _read(name, _run(trace_steps=0)) is None
@@ -355,7 +361,8 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
         assert _read(name, _run(peak=None)) is None
     entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
              if m["name"] == name][0]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"] if name in SHARED \
+        else entry["workloads"] == [CELL]
     assert entry["moves"] == "train_samples_s"
     assert entry["layer"] == "ops and kernels"
     assert entry["source"] == ("device_trace" if name in TRACE_READERS
@@ -427,7 +434,7 @@ def test_rehearsal_runs_the_cell_end_to_end_with_the_trace_on():
                       "--seconds", "1", "--trace", "1", "--rehearse-cpu"])
     result = check_rehearsal(proc, ["fused_step_share",
                                     "fit_lookahead_share",
-                                    "dots3_held_rows_over_expected",
+                                    "moe_share_rows_over_expected",
                                     "dots3_keys_selected_over_expected"])
     assert "matches_reference ok=True" in proc.stdout
     assert '"within_limits": false' in proc.stdout

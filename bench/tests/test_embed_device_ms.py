@@ -36,7 +36,7 @@ def test_it_finds_nothing_where_there_is_nothing(run):
     assert _read(_run(), trace=False) is None
 
 
-def test_its_entry_lists_the_seven_cells_with_an_embedding():
+def test_its_entry_lists_the_twelve_cells_with_an_embedding():
     manifest = lib.load_json(lib.MANIFEST)
     entry = [m for m in manifest["per_layer"] if m["name"] == NAME]
     assert entry == [{
@@ -47,6 +47,13 @@ def test_its_entry_lists_the_seven_cells_with_an_embedding():
             "olmoe_fit_resident_4k", "mimo_v2_flash_fit_share_4k",
             "kanana2_fit_share_8k", "nemotron3_nano_fit_share_8k",
             "olmo_hybrid_fit_stage_4k", "lfm2_fit_share_8k",
-            "falcon_h1_fit_share_4k"]}]
+            "falcon_h1_fit_share_4k", "kimi_linear_fit_share_8k",
+            "trinity_mini_fit_share_8k", "dots3_note_fit_share_4k",
+            "solar_open2_fit_share_4k", "ouro_fit_loop_4k"]}]
+    # every cell whose configuration is a token model (PR 68 opened the
+    # list to the five newest)
+    conv = ("resnet50", "resnet50_amp", "inception_v3")
+    assert entry[0]["workloads"] == [
+        c["name"] for c in manifest["workloads"] if c["config"] not in conv]
     kinds = {c["name"]: c for c in manifest["workloads"]}
     assert all(cell in kinds for cell in entry[0]["workloads"])

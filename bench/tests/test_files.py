@@ -148,6 +148,94 @@ def test_every_reader_is_in_the_manifest():
     assert found == listed
 
 
+PER_LAYER_LIMIT = 128       # what a manifest may hold
+
+
+def test_the_per_layer_manifest_has_room():
+    used = len(MANIFEST["per_layer"])
+    print("per_layer: %d entries of %d, %d free"
+          % (used, PER_LAYER_LIMIT, PER_LAYER_LIMIT - used))
+    assert used <= PER_LAYER_LIMIT
+
+
+def _model_prefixes():
+    """What an entry named for a model would start with: a
+    configuration's or a cell's name, its first word or its first two,
+    and the short forms in use (``tests/named_for_a_model/``)."""
+    names = [c["name"] for c in MANIFEST["configs"]] + [
+        w["name"].split("_fit")[0] for w in MANIFEST["workloads"]]
+    out = set()
+    for name in names:
+        words = name.split("_")
+        out |= {name, words[0], "_".join(words[:2])}
+    # ``olmo`` is two models' first word and no entry's; a bare class
+    # name (``conv``, ``attn``) is a mechanism
+    return {p + "_" for p in out} | {
+        f["prefix"] for f in _named_for_a_model()}
+
+
+def _named_for_a_model():
+    return [lib.load_json(p) for p in sorted(glob.glob(os.path.join(
+        lib.BENCH, "tests", "named_for_a_model", "*.json")))]
+
+
+def test_an_entry_is_named_for_its_mechanism_not_for_a_model():
+    """One entry a mechanism, listing every cell that runs it (PR 68). An
+    entry may carry a model's name only where its reader reads what only
+    that model traces; each such prefix has a file under
+    ``tests/named_for_a_model/`` with its reason and its entries, and a
+    later PR that must add one adds a file."""
+    allowed = {}
+    for f in _named_for_a_model():
+        assert f["reason"] and f["model"] in [
+            c["name"] for c in MANIFEST["configs"]], f
+        for name in f["entries"]:
+            assert name.startswith(f["prefix"]) and name not in allowed
+            allowed[name] = f["prefix"]
+    prefixes = _model_prefixes()
+    named = [m["name"] for m in MANIFEST["per_layer"]
+             if any(m["name"].startswith(p) for p in prefixes)]
+    assert sorted(named) == sorted(allowed)
+    by_name = {m["name"]: m for m in MANIFEST["per_layer"]}
+    for name in allowed:        # a model's own entry lists its own cells
+        assert len(by_name[name]["workloads"]) == 1, name
+
+
+FLOPS_CALL = re.compile(r"\bflops\.([a-z_0-9]+)\(")
+# entry -> what its reader asks of the configuration's operations module
+# beyond what it calls as ``flops.<name>(`` (it finds these by getattr,
+# or through a ``*_scopes`` helper that answers None without them)
+OPERATIONS = {
+    "moe_share_rows_over_expected": ("expected_share_rows",),
+    "moe_share_roofline_share": ("moe_share_flops",),
+    "mla_kernel_roofline_share": ("mla_kernel_flops",),
+    "kda_device_ms": ("kda_core_flops",),
+    "kda_core_device_ms": ("kda_core_flops",),
+    "kda_core_roofline_share": ("kda_core_flops",),
+    "moe_roofline_share": ("moe_flops",),
+    "attn_roofline_share": ("attn_kernel_flops",),
+}
+
+
+@pytest.mark.parametrize("metric", [
+    m for m in MANIFEST["per_layer"] if "workloads" in m],
+    ids=lambda m: m["name"])
+def test_every_listed_cell_has_the_readers_operations_functions(metric):
+    """A list may name a cell only where the reader finds what it counts
+    with: an entry opened to a cell whose operations module lacks the
+    function reads nothing there, and the traced run is refused."""
+    source = open(lib.find("layer_metrics", metric["name"], ".py")).read()
+    wanted = set(FLOPS_CALL.findall(source)) | set(
+        OPERATIONS.get(metric["name"], ()))
+    cells = {w["name"]: w for w in MANIFEST["workloads"]}
+    for cell in metric["workloads"]:
+        cfg = lib.load_json(lib.find("configs", cells[cell]["config"],
+                                     ".json"))
+        module = lib.load_module("flops", cfg["flops"])
+        missing = [f for f in wanted if not hasattr(module, f)]
+        assert not missing, (cell, cfg["flops"], missing)
+
+
 def test_peaks_name_their_source():
     peaks = lib.load_json(os.path.join(lib.BENCH, "peaks.json"))
     v5e = peaks["TPU v5 lite"]

@@ -241,6 +241,9 @@ def test_the_four_readers_read_what_they_say():
     assert _read("mla_kernel_roofline_share", run) == pytest.approx(
         100 * least_ms / 118.0)
     assert _read("mla_kernel_roofline_share", run) < 100
+    # every layer has the node and the operations module offers no
+    # ``mla_layers``: all six count
+    assert not hasattr(fn, "mla_layers") and CFG["num_hidden_layers"] == 6
 
 
 @pytest.mark.parametrize("name", READERS)
@@ -258,7 +261,10 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
         assert _read(name, _run(cfg=mimo)) is None
     entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
              if m["name"] == name][0]
-    assert entry["workloads"] == [CELL]
+    # the latent node's three are Kimi Linear's too since PR 68, the
+    # shared experts' every share's that has them
+    assert entry["workloads"][0] == CELL
+    assert "kimi_linear_fit_share_8k" in entry["workloads"]
     assert entry["moves"] == "train_samples_s"
     assert entry["layer"] == "ops and kernels"
 

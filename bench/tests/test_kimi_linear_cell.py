@@ -312,6 +312,8 @@ def _run(**over):
                           "gate_norm": 0.100, "other": 0.050, "proj": None,
                           "mlp": 0.100},
            "kda_scopes": {"kda_proj": 0.250, "mla_proj": 0.040},
+           "solar2_scopes": {"kda_fwd": 0.250, "kda_bwd": 0.500,
+                             "flash_fwd": 0.030, "flash_bwd": 0.060},
            "mla_scopes": {"mla": 0.110, "latent": 0.020, "full": 0.090,
                           "shared": 0.030},
            "lm_scopes": {"class_s": {"moe": 0.070, "attn": 0.110}}}
@@ -326,24 +328,44 @@ def _read(name, run, trace=True):
 
 TRACE_READERS = ["kda_device_ms", "kda_core_device_ms",
                  "kda_core_roofline_share", "kda_proj_device_ms",
-                 "kimi_mla_device_ms", "kimi_moe_device_ms"]
-READERS = TRACE_READERS + ["kimi_held_rows_over_expected"]
+                 "mla_device_ms", "mla_latent_device_ms",
+                 "mla_kernel_roofline_share", "moe_share_device_ms",
+                 "shared_expert_device_ms"]
+READERS = TRACE_READERS + ["moe_share_rows_over_expected"]
+# one entry a mechanism since PR 68: the four ``kda_*`` are Solar's too,
+# the latent node's three Kanana's (``kimi_mla_device_ms`` was the node
+# plus its three projections, which no entry reads now), the experts'
+# three every share's (``kimi_moe_device_ms`` was routed + shared)
+KDA = TRACE_READERS[:4]
 
 
-def test_the_seven_readers_read_what_they_say():
+def test_the_ten_readers_read_what_they_say():
     run = _run()
     assert _read("kda_device_ms", run) == pytest.approx(200.0)
     assert _read("kda_core_device_ms", run) == pytest.approx(160.0)
     assert _read("kda_proj_device_ms", run) == pytest.approx(50.0)
-    assert _read("kimi_mla_device_ms", run) == pytest.approx(22.0 + 8.0)
-    assert _read("kimi_moe_device_ms", run) == pytest.approx(14.0 + 6.0)
+    assert _read("mla_device_ms", run) == pytest.approx(22.0)
+    assert _read("mla_latent_device_ms", run) == pytest.approx(4.0)
+    assert _read("moe_share_device_ms", run) == pytest.approx(14.0)
+    assert _read("shared_expert_device_ms", run) == pytest.approx(6.0)
     # four layers, three forwards each, bound by bytes: 12 x 0.4103 ms
-    # of 160
-    assert _read("kda_core_roofline_share", run) == pytest.approx(
-        100 * 12 * 0.41034 / 160.0, rel=1e-3)
-    assert _read("kda_core_roofline_share", run) < 100
+    # of 160; the kernel pair holds 150 of the scope's 160 ms
+    share, ok, why = _read("kda_core_roofline_share", run)
+    assert share == pytest.approx(100 * 12 * 0.41034 / 160.0, rel=1e-3)
+    assert share < 100 and ok, why
+    # the chunk form in place of the kernels: the share is not theirs
+    _, ok, why = _read("kda_core_roofline_share", _run(
+        solar2_scopes={"kda_fwd": 0.1, "kda_bwd": 0.2, "flash_fwd": 0,
+                       "flash_bwd": 0}))
+    assert not ok and "want 0.5" in why
+    # ONE latent layer of the five has the node: its kernel's operations
+    # count once (``mla_layers``), not ``num_hidden_layers`` times
+    flops = lib.load_module("flops", CFG["flops"])
+    assert flops.mla_layers(CFG) == 1
+    assert _read("mla_kernel_roofline_share", run) == pytest.approx(
+        100 * (3 * flops.mla_kernel_flops(CFG) / 197e12 * 1e3) / 18.0)
     held = CFG["num_experts"]
-    assert _read("kimi_held_rows_over_expected", run) == pytest.approx(
+    assert _read("moe_share_rows_over_expected", run) == pytest.approx(
         4 * 40 * min(held, 8) / (4.0 * 8192 * 8 * held / 256))
 
 
@@ -363,15 +385,17 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
     if name in TRACE_READERS:
         assert _read(name, _run(), trace=False) is None
         assert _read(name, _run(trace_steps=0)) is None
-    if name not in ("kda_proj_device_ms", "kimi_mla_device_ms"):
+    if name in KDA and name != "kda_proj_device_ms":
         # another model's operations module counts no KDA core
         assert _read(name, _run(cfg=olmo)) is None
         assert _read(name, _run(cfg=kanana)) is None
-    if name == "kda_core_roofline_share":
+    if name.endswith("roofline_share"):
         assert _read(name, _run(peak=None)) is None
     entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
              if m["name"] == name][0]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
+    if name in KDA:
+        assert entry["workloads"] == [CELL, "solar_open2_fit_share_4k"]
     assert entry["moves"] == "train_samples_s"
     assert entry["layer"] == "ops and kernels"
     assert entry["source"] == ("device_trace" if name in TRACE_READERS
@@ -465,10 +489,14 @@ def test_the_cell_the_mix_and_the_manifest():
                      "traffic": cell["traffic"], "chips": 1,
                      "why": cell["why"]}
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
-    # no existing entry's list gained the cell
-    for m in manifest["per_layer"]:
-        if m["name"] not in READERS:
-            assert CELL not in m.get("workloads", [])
+    # since PR 68 every entry whose reader finds something in the cell
+    # lists it; none names the model
+    listed = [m["name"] for m in manifest["per_layer"]
+              if CELL in m.get("workloads", [])]
+    assert set(READERS) | {"embed_device_ms", "head_loss_device_ms",
+                           "moe_permute_device_ms",
+                           "moe_share_roofline_share"} == set(listed)
+    assert not [n for n in listed if n.startswith("kimi")]
 
 
 def test_rehearsal_runs_the_cell_end_to_end_with_the_trace_on():
@@ -482,7 +510,7 @@ def test_rehearsal_runs_the_cell_end_to_end_with_the_trace_on():
                       "--seconds", "1", "--trace", "1", "--rehearse-cpu"])
     result = check_rehearsal(proc, ["fused_step_share",
                                     "fit_lookahead_share",
-                                    "kimi_held_rows_over_expected"])
+                                    "moe_share_rows_over_expected"])
     assert "matches_reference ok=True" in proc.stdout
     assert '"within_limits": false' in proc.stdout
     assert "experts_routed_over_all ok=True" in proc.stdout

@@ -275,8 +275,11 @@ def _read(name, run, trace=True):
 
 TRACE_READERS = ["sconv_device_ms", "sconv_proj_device_ms",
                  "sconv_roofline_share", "attn64_device_ms",
-                 "attn64_roofline_share", "lfm2_moe_device_ms"]
-READERS = TRACE_READERS + ["lfm2_held_rows_over_expected"]
+                 "attn64_roofline_share", "moe_share_device_ms"]
+READERS = TRACE_READERS + ["moe_share_rows_over_expected"]
+# every share's entries since PR 68 (``lfm2_moe_device_ms`` and
+# ``lfm2_held_rows_over_expected`` until then)
+SHARED = READERS[-2:]
 
 
 def test_the_seven_readers_read_what_they_say():
@@ -284,7 +287,7 @@ def test_the_seven_readers_read_what_they_say():
     assert _read("sconv_device_ms", run) == pytest.approx(20.0)
     assert _read("sconv_proj_device_ms", run) == pytest.approx(80.0)
     assert _read("attn64_device_ms", run) == pytest.approx(30.0)
-    assert _read("lfm2_moe_device_ms", run) == pytest.approx(40.0)
+    assert _read("moe_share_device_ms", run) == pytest.approx(40.0)
     # seven layers, bound by bytes: 7 x (0.1639 + 0.2868) ms of 20
     assert _read("sconv_roofline_share", run) == pytest.approx(
         100 * 7 * 0.45067 / 20.0, rel=1e-3)
@@ -296,9 +299,9 @@ def test_the_seven_readers_read_what_they_say():
         assert 0 < _read(name, run) < 100
     # the held experts are the first eight: (8 x 500 + 8 x 520 + 6 x
     # 4096) rows of 8 x 4096
-    assert _read("lfm2_held_rows_over_expected", run) == pytest.approx(
+    assert _read("moe_share_rows_over_expected", run) == pytest.approx(
         (4000 + 4160 + 6 * 4096) / 32768.0)
-    assert _read("lfm2_held_rows_over_expected", run, trace=False) \
+    assert _read("moe_share_rows_over_expected", run, trace=False) \
         == pytest.approx(0.99902, abs=1e-5)      # a model output, no trace
 
 
@@ -316,8 +319,8 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
     assert _read(name, _run(cfg=nemotron, **nothing)) is None
     # another model's run, whatever its scopes hold: only the two
     # readers of the sconv scopes alone would read them
-    assert _read(name, _run(cfg=nemotron)) is None or name in (
-        "sconv_device_ms", "sconv_proj_device_ms")
+    assert _read(name, _run(cfg=nemotron)) is None or name in [
+        "sconv_device_ms", "sconv_proj_device_ms"] + SHARED
     if name in TRACE_READERS:
         assert _read(name, _run(), trace=False) is None
         assert _read(name, _run(trace_steps=0)) is None
@@ -325,7 +328,8 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
         assert _read(name, _run(peak=None)) is None
     entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
              if m["name"] == name][0]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"] if name in SHARED \
+        else entry["workloads"] == [CELL]
     assert entry["moves"] == "train_samples_s"
     assert entry["layer"] == "ops and kernels"
     assert entry["source"] == ("device_trace" if name in TRACE_READERS
@@ -380,7 +384,7 @@ def test_rehearsal_runs_the_cell_end_to_end_with_the_trace_on():
                       "--seconds", "1", "--trace", "1", "--rehearse-cpu"])
     result = check_rehearsal(proc, ["fused_step_share",
                                     "fit_lookahead_share",
-                                    "lfm2_held_rows_over_expected"])
+                                    "moe_share_rows_over_expected"])
     assert "matches_reference ok=True" in proc.stdout
     assert '"within_limits": false' in proc.stdout
     assert "experts_routed_over_all ok=True" in proc.stdout

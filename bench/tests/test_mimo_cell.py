@@ -174,7 +174,10 @@ def test_the_six_readers_read_what_they_say():
     least_ms = 1e3 * 3 * sum(fn.moe_share_flops(CFG, r) for r in rows) / 197e12
     assert _read("moe_share_roofline_share", run) == pytest.approx(
         100 * least_ms / 10.0)
-    assert _read("moe_share_rows_over_expected", run) == 2.0
+    # the sum over the six layers since PR 68 (the busiest layer's 2.0
+    # until then): five at the expected 1,024 rows and one at twice
+    assert _read("moe_share_rows_over_expected", run) == pytest.approx(
+        7.0 / 6.0)
 
 
 @pytest.mark.parametrize("name", [
@@ -193,7 +196,8 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
         assert _read(name, _run(), trace=False) is None
     entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
              if m["name"] == name][0]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"] if name.startswith("moe_share") \
+        else entry["workloads"] == [CELL]
     assert entry["moves"] == "train_samples_s"
 
 

@@ -47,13 +47,17 @@ def test_it_finds_nothing_where_there_is_nothing(run):
     assert _read(_run(), trace=False) is None
 
 
-def test_its_entry_lists_the_two_cells_with_an_expert_layer():
-    entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
-             if m["name"] == NAME]
+def test_its_entry_lists_the_cells_with_an_expert_layer():
+    """Found by name, wherever it stands: OLMoE's cell and, since PR 68,
+    every cell that holds a share of its experts (``topk_moe`` scopes the
+    same two stages in all of them)."""
+    manifest = lib.load_json(lib.MANIFEST)
+    entry = [m for m in manifest["per_layer"] if m["name"] == NAME]
+    share = [m for m in manifest["per_layer"]
+             if m["name"] == "moe_share_device_ms"][0]["workloads"]
+    assert len(share) == 8
     assert entry == [{
         "name": NAME, "unit": "ms/step", "better": "lower",
         "source": "device_trace", "layer": "ops and kernels",
         "moves": "train_samples_s",
-        "workloads": ["olmoe_fit_resident_4k",
-                      "mimo_v2_flash_fit_share_4k"]}]
-    assert lib.load_json(lib.MANIFEST)["per_layer"][-1] == entry[0]
+        "workloads": ["olmoe_fit_resident_4k"] + share}]

@@ -248,8 +248,11 @@ def _read(name, run, trace=True):
         {"devices": {}} if trace else None, {"telemetry": {}}, run)
 
 
-READERS = ["ssm_device_ms", "ssm_scan_device_ms", "ssm_scan_roofline_share",
-           "ssm_proj_device_ms", "moe_relu2_device_ms"]
+OWN = ["ssm_device_ms", "ssm_scan_device_ms", "ssm_scan_roofline_share",
+       "ssm_proj_device_ms"]
+# the un-gated experts' class ``moe`` is every share's entry since PR 68
+# (``moe_relu2_device_ms`` until then)
+READERS = OWN + ["moe_share_device_ms"]
 
 
 def test_the_five_readers_read_what_they_say():
@@ -257,7 +260,7 @@ def test_the_five_readers_read_what_they_say():
     assert _read("ssm_device_ms", run) == pytest.approx(100.0)
     assert _read("ssm_scan_device_ms", run) == pytest.approx(80.0)
     assert _read("ssm_proj_device_ms", run) == pytest.approx(30.0)
-    assert _read("moe_relu2_device_ms", run) == pytest.approx(40.0)
+    assert _read("moe_share_device_ms", run) == pytest.approx(40.0)
     # four blocks, bound by bytes: 4 x 0.618 ms of 80
     assert _read("ssm_scan_roofline_share", run) == pytest.approx(
         100 * 4 * 0.6183 / 80.0, rel=1e-3)
@@ -274,13 +277,15 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
     nothing = _run(ssm_scopes=None, lm_scopes=None)
     assert _read(name, nothing) is None
     assert _read(name, _run(), trace=False) is None
-    assert _read(name, _run(cfg=kanana, ssm_scopes=None)) is None
+    if name in OWN:
+        assert _read(name, _run(cfg=kanana, ssm_scopes=None)) is None
     if name == "ssm_scan_roofline_share":
         assert _read(name, _run(cfg=kanana)) is None
         assert _read(name, _run(peak=None)) is None
     entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
              if m["name"] == name][0]
-    assert entry["workloads"] == [CELL]
+    assert entry["workloads"] == [CELL] if name in OWN \
+        else CELL in entry["workloads"]
     assert entry["moves"] == "train_samples_s"
     assert entry["layer"] == "ops and kernels"
     assert entry["source"] == "device_trace"
@@ -297,7 +302,7 @@ def test_the_cell_is_the_existing_mix_and_kind_unchanged():
     assert set(cell["expect"]["reference"]) == set(
         kanana["expect"]["reference"])
     manifest = lib.load_json(lib.MANIFEST)
-    assert len(manifest["workloads"]) == 7
+    assert CELL in [w["name"] for w in manifest["workloads"]]
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
 
 

@@ -23,7 +23,7 @@ def test_rehearse_traced(workload):
     proc = run_bench(["--workload", workload, "--seed", "4", "--seconds",
                       "1", "--trace", "1", "--rehearse-cpu"])
     result = check_rehearsal(proc, ["fused_step_share", "compile_s",
-                                    "fit_host_ms_step"])
+                                    "fit_lookahead_share"])
     # the CPU has no device plane: nothing read from a trace is reported
     assert "device_idle_share" not in result["metrics"]
     assert "train_samples_s" not in result["metrics"]

@@ -282,18 +282,22 @@ TRACE_READERS = ["trinity_attn_window_device_ms",
                  "trinity_attn_full_device_ms",
                  "trinity_attn_gate_device_ms",
                  "trinity_attn_proj_device_ms", "trinity_norm_device_ms",
-                 "trinity_moe_device_ms"]
-READERS = TRACE_READERS + ["trinity_held_rows_over_expected"]
+                 "moe_share_device_ms", "shared_expert_device_ms"]
+READERS = TRACE_READERS + ["moe_share_rows_over_expected"]
+# every share's entries since PR 68 (``trinity_moe_device_ms``, routed +
+# shared, and ``trinity_held_rows_over_expected`` until then)
+SHARED = READERS[-3:]
 
 
-def test_the_eight_readers_read_what_they_say():
+def test_the_nine_readers_read_what_they_say():
     run = _run()
     assert _read("trinity_attn_window_device_ms", run) == pytest.approx(40.0)
     assert _read("trinity_attn_full_device_ms", run) == pytest.approx(18.0)
     assert _read("trinity_attn_gate_device_ms", run) == pytest.approx(5.0)
     assert _read("trinity_attn_proj_device_ms", run) == pytest.approx(45.0)
     assert _read("trinity_norm_device_ms", run) == pytest.approx(12.0)
-    assert _read("trinity_moe_device_ms", run) == pytest.approx(30.0)
+    assert _read("moe_share_device_ms", run) == pytest.approx(22.0)
+    assert _read("shared_expert_device_ms", run) == pytest.approx(8.0)
     # four layers, three forwards each of 0.2405 T at 197 T/s, of 40 ms
     share = _read("trinity_attn_window_roofline_share", run)
     assert share == pytest.approx(
@@ -302,9 +306,9 @@ def test_the_eight_readers_read_what_they_say():
     assert share == pytest.approx(36.63, abs=0.01) and 0 < share < 100
     # the held experts are the first sixteen: (16 x 500 + 16 x 520 + 2 x
     # 8192) rows of 4 x 8192
-    assert _read("trinity_held_rows_over_expected", run) == pytest.approx(
+    assert _read("moe_share_rows_over_expected", run) == pytest.approx(
         (8000 + 8320 + 2 * 8192) / 32768.0)
-    assert _read("trinity_held_rows_over_expected", run, trace=False) \
+    assert _read("moe_share_rows_over_expected", run, trace=False) \
         == pytest.approx(0.99805, abs=1e-5)      # a model output, no trace
 
 
@@ -322,9 +326,9 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
     # another model's run, whatever its scopes hold: only the readers of
     # the afmoe scopes alone would read them (MiMo's window and full
     # kernels are its own metrics')
-    assert _read(name, _run(cfg=mimo)) is None or name in (
+    assert _read(name, _run(cfg=mimo)) is None or name in [
         "trinity_attn_gate_device_ms", "trinity_attn_proj_device_ms",
-        "trinity_norm_device_ms")
+        "trinity_norm_device_ms"] + SHARED
     if name in TRACE_READERS:
         assert _read(name, _run(), trace=False) is None
         assert _read(name, _run(trace_steps=0)) is None
@@ -332,7 +336,8 @@ def test_a_reader_finds_nothing_where_there_is_nothing(name):
         assert _read(name, _run(peak=None)) is None
     entry = [m for m in lib.load_json(lib.MANIFEST)["per_layer"]
              if m["name"] == name][0]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"] if name in SHARED \
+        else entry["workloads"] == [CELL]
     assert entry["moves"] == "train_samples_s"
     assert entry["layer"] == "ops and kernels"
     assert entry["source"] == ("device_trace" if name in TRACE_READERS
@@ -370,7 +375,6 @@ def test_the_cell_shares_the_lfm2_cells_mix_letter_for_letter():
                      "traffic": cell["traffic"], "chips": 1,
                      "why": cell["why"]}
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
-    assert len(manifest["workloads"]) == 12
 
 
 def test_rehearsal_runs_the_cell_end_to_end_with_the_trace_on():
@@ -384,7 +388,7 @@ def test_rehearsal_runs_the_cell_end_to_end_with_the_trace_on():
                       "--seconds", "1", "--trace", "1", "--rehearse-cpu"])
     result = check_rehearsal(proc, ["fused_step_share",
                                     "fit_lookahead_share",
-                                    "trinity_held_rows_over_expected"])
+                                    "moe_share_rows_over_expected"])
     assert "matches_reference ok=True" in proc.stdout
     assert '"within_limits": false' in proc.stdout
     assert "experts_routed_over_all ok=True" in proc.stdout

@@ -809,4 +809,5 @@ CONTRIB_OP_EXPORTS = (
     # ops/transformer.py
     "RMSNorm", "RoPE", "Attention", "LatentAttention", "Mamba2", "TopKMoE",
     "GatedDeltaNet", "ShortConv", "ScaledSum", "KeyIndexer", "ExitMix",
+    "HyperCoeff", "HyperMix",
 )

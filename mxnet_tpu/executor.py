@@ -128,12 +128,13 @@ _OP_CLASS = {
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
     "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",
+    "_contrib_HyperCoeff": "hc", "_contrib_HyperMix": "hc",
 }
 
 
 def op_class(op_name):
     """conv | fc | bn | pool | act | loss | attn | ssm | gdn | sconv | moe |
-    norm | embed | other: the class a node's device ops are filed under
+    norm | embed | hc | other: the class a node's device ops are filed under
     (the first part of its named scope)."""
     cls = _OP_CLASS.get(op_name)
     if cls is not None:
@@ -554,6 +555,12 @@ _G_SHARED_USES = _tm.gauge(
     "set at bind, nothing per step")
 
 
+_G_STREAMS = _tm.gauge(
+    "lm.residual_streams", "Residual streams a token of the symbol bound "
+    "last (a HyperCoeff node's streams; 1 for a symbol with none): set at "
+    "bind, nothing per step")
+
+
 def _argument_uses(program):
     """{argument name: how many node inputs of the program's graph read
     it} (an argument with several readers has ONE gradient, their sum)."""
@@ -640,6 +647,11 @@ class Executor:
         if _tm.enabled():
             _G_SHARED_USES.set(max(_argument_uses(self._program).values(),
                                    default=0))
+            _G_STREAMS.set(max(
+                [int(n.canon_attrs().get("streams", 1))
+                 for n in self._program.nodes
+                 if not n.is_variable
+                 and n.op.name == "_contrib_HyperCoeff"], default=1))
 
     def _build_placed(self):
         """ctx_group placement (reference AssignContext/PlaceDevice):

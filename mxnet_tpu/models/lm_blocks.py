@@ -5,8 +5,9 @@ SwiGLU and the un-gated relu² feed-forward, the one-mixer residual block,
 the block that norms a sub-layer's output and the block whose mixers read
 one normed input side by side, a fixed scalar on a node's output, the
 routed expert layer's call, the Kimi Delta Attention mixer
-(``kimi_linear``, ``solar_open2``) and the head, untied or reading the
-embedding's matrix, with its loss. Each takes the node-name prefix of its
+(``kimi_linear``, ``solar_open2``), the sub-layer wrapped in hyper-connections
+over several residual streams (``xing4``) and the head, untied or reading
+the embedding's matrix, with its loss, one stream's or several's. Each takes the node-name prefix of its
 layer, so a model's argument and scope names are its own; a model whose
 weights are read at several depths (``ouro``) hands in the ``Variable``s
 it made once."""
@@ -129,24 +130,103 @@ def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
     embedding's ``Variable``: one matrix, table and weight alike, whose
     gradient sums both uses. ``logit_scale``: a fixed scalar on the float32
     logits (cast ``lm_head_cast`` then, scaled logits ``lm_head_f32``)."""
-    normed = csym.RMSNorm(h, eps=rms_eps, name="final_norm")
-    if tied_to is None:
-        logits = linear(normed, "lm_head", vocab_size, init)
-    else:
-        logits = sym.FullyConnected(normed, weight=tied_to,
-                                    num_hidden=vocab_size, no_bias=True,
-                                    name="lm_head")
-    logits = scaled(sym.Cast(
-        logits, dtype="float32",
-        name="lm_head_f32" if logit_scale == 1 else "lm_head_cast"),
-        "lm_head_f32", logit_scale)
-    nll = 0 - sym.pick_log_softmax(logits,
-                                   sym.Reshape(label, shape=(-1,)),
-                                   name="lm_head_pick")
-    per_sequence = sym.mean(sym.Reshape(nll, shape=(-1, seq_len)), axis=1,
-                            name="lm_head_mean")
+    per_sequence = head_loss(h, label, "", vocab_size, seq_len, rms_eps,
+                             tied_to, logit_scale, init)
     loss = sym.MakeLoss(per_sequence, name="loss")
     return sym.Group([loss] + counts)
+
+
+def head_loss(h, label, prefix, vocab_size, seq_len, rms_eps, weight=None,
+              logit_scale=1.0, init=None, targets=None):
+    """One read of the head: ``<prefix>final_norm``, ``<prefix>lm_head``
+    (its own ``<prefix>lm_head_weight`` drawn by ``init``, or ``weight``,
+    a ``Variable`` the model made: the embedding's for a tied head, the
+    ONE head's where several streams read it), float32 logits
+    (``<prefix>lm_head_f32``), ``pick_log_softmax`` and each sequence's
+    mean cross-entropy (``<prefix>lm_head_mean``) over its first
+    ``targets`` positions (all of them by default: a stream that predicts
+    further ahead has no label for its last ones). ``head_and_loss``'s
+    nodes, which it builds through this."""
+    normed = csym.RMSNorm(h, eps=rms_eps, name=prefix + "final_norm")
+    if weight is None:
+        logits = linear(normed, prefix + "lm_head", vocab_size, init)
+    else:
+        logits = sym.FullyConnected(normed, weight=weight,
+                                    num_hidden=vocab_size, no_bias=True,
+                                    name=prefix + "lm_head")
+    logits = scaled(sym.Cast(
+        logits, dtype="float32",
+        name=prefix + ("lm_head_f32" if logit_scale == 1
+                       else "lm_head_cast")),
+        prefix + "lm_head_f32", logit_scale)
+    ahead = {} if targets in (None, seq_len) else {
+        "ahead": 1 + seq_len - targets}
+    nll = 0 - sym.pick_log_softmax(logits,
+                                   sym.Reshape(label, shape=(-1,)),
+                                   name=prefix + "lm_head_pick", **ahead)
+    nll = sym.Reshape(nll, shape=(-1, seq_len))
+    if ahead:
+        nll = sym.slice_axis(nll, axis=1, begin=0, end=targets)
+    return sym.mean(nll, axis=1, name=prefix + "lm_head_mean")
+
+
+def heads_and_loss(heads, counts, extras, vocab_size, seq_len, rms_eps,
+                   weight):
+    """Several streams through ONE head (``weight``, the model's
+    ``Variable``) into ONE loss: ``heads`` is ``[(prefix, stream, labels,
+    targets, loss weight)]``, each read as ``head_loss`` reads it; the
+    weighted sum of their per-sequence means is behind ``MakeLoss``
+    (``loss``), and each apart behind ``BlockGrad`` (``<prefix>loss_part``)
+    after the layers' counts, then ``extras`` (nodes that carry no
+    gradient already). The multi-token-prediction objective (DeepSeek-V3,
+    arXiv:2412.19437 section 2.2)."""
+    parts = [head_loss(h, label, prefix, vocab_size, seq_len, rms_eps,
+                       weight, targets=targets)
+             for prefix, h, label, targets, _ in heads]
+    total = None
+    for part, (_, _, _, _, scale) in zip(parts, heads):
+        term = part if scale == 1 else part * float(scale)
+        total = term if total is None else total + term
+    return sym.Group(
+        [sym.MakeLoss(total, name="loss")] + counts
+        + [sym.BlockGrad(part, name=head[0] + "loss_part")
+           for part, head in zip(parts, heads)] + extras)
+
+
+def hyper_block(stream, prefix, part, rms_eps, sublayer, streams, iters, eps,
+                clamp, sigma=0.02, alpha=0.01, carry_bias=4.0):
+    """One sub-layer (``part``: ``attn`` or ``ffn``) of the block
+    ``prefix`` wrapped in manifold-constrained hyper-connections
+    (``ops/transformer.py``, ``HyperCoeff`` / ``HyperMix``): ``stream``
+    [tokens, n hidden] -> (the stream after it, the largest distance of a
+    carry matrix's row or column sum from 1 behind ``BlockGrad``). With
+    ``<p>`` = ``<prefix><part>_``:
+
+        pre, post, res, err = HyperCoeff(stream)       # <p>hc
+        u = sum_j pre[j] stream[j]                     # <p>hc_read
+        y = sublayer(RMSNorm(u), prefix)               # <p>norm
+        stream'[i] = sum_j res[i, j] stream[j] + post[i] y   # <p>hc_write
+
+    The node owns ``<p>hc_phi`` [n (n + 2), n hidden] (Normal ``sigma``),
+    ``<p>hc_bias`` (zeros for the read and the write, ``carry_bias`` on
+    the carry's diagonal: the streams do not start as one average) and
+    ``<p>hc_alpha`` (three scalars, ``alpha``), each stated through
+    ``sym.Variable(init=)``."""
+    n, p = streams, "%s%s_hc" % (prefix, part)
+    bias = [0.0] * (2 * n) + [carry_bias * (i == j)
+                              for i in range(n) for j in range(n)]
+    coeff = csym.HyperCoeff(
+        stream, phi=sym.Variable(p + "_phi", init=init.Normal(sigma=sigma)),
+        bias=sym.Variable(p + "_bias", init=init.Constant(value=bias)),
+        alpha=sym.Variable(p + "_alpha", init=init.Constant(value=alpha)),
+        streams=n, iters=iters, eps=eps, clamp=tuple(clamp),
+        norm_eps=rms_eps, name=p)
+    read = csym.HyperMix(stream, coeff[0], name=p + "_read")
+    y = sublayer(csym.RMSNorm(read, eps=rms_eps,
+                              name="%s%s_norm" % (prefix, part)), prefix)
+    out = csym.HyperMix(stream, coeff[2], y, coeff[1], with_add=True,
+                        name=p + "_write")
+    return out, sym.BlockGrad(coeff[3], name=p + "_err")
 
 
 def post_norm_block(h, prefix, norm, rms_eps, sublayer, gamma=None):

@@ -358,6 +358,11 @@ register(_topk_def)
 _M_PICKED_LOGP_TRACES = _tm.counter(
     "lm.picked_logp_traces", "Traces of a pick_log_softmax node (one per "
     "node and lowering, nothing per step); labels: rows, vocab")
+_M_MTP_MODULES = _tm.counter(
+    "lm.mtp_modules", "Traces of a pick_log_softmax node that reads a "
+    "multi-token-prediction module's stream (ahead > 1: the label it picks "
+    "lies that many positions ahead; one per node and lowering, nothing "
+    "per step); labels: ahead")
 
 
 def _is_label(logits, labels):
@@ -407,6 +412,9 @@ def _pick_log_softmax(attrs, ins, is_train):
     data, index = ins
     vocab = data.shape[-1]
     _M_PICKED_LOGP_TRACES.inc(rows=data.size // vocab, vocab=vocab)
+    ahead = int(attrs.get("ahead", 1))
+    if ahead > 1:
+        _M_MTP_MODULES.inc(ahead=ahead)
     # a label below zero counts from the end, as ``pick`` reads it
     labels = index.astype(jnp.int32)
     return [picked_log_prob(data, jnp.where(labels < 0, labels + vocab,
@@ -418,6 +426,7 @@ register(
         "pick_log_softmax",
         _pick_log_softmax,
         arguments=("data", "index"),
+        defaults={"ahead": 1},
         infer_shape=lambda attrs, in_shapes: (
             [tuple(in_shapes[0]), tuple(in_shapes[0][:-1])],
             [tuple(in_shapes[0][:-1])],
@@ -435,6 +444,9 @@ number a row (the log of the row's sum of exponentials); the backward is
 one elementwise expression over [..., V], where autodiff of the two ops
 gathers from a [..., V] table of log-probabilities written for the purpose
 and transposes the gather to a scatter-add.
-An index outside [-V, V) picks nothing: the row reads -logsumexp(data).""",
+An index outside [-V, V) picks nothing: the row reads -logsumexp(data).
+ahead (default 1) changes no number: it says how many positions ahead of
+its row the label lies, and a node with ahead > 1 (a multi-token-prediction
+module's head) counts in lm.mtp_modules where it is traced.""",
     )
 )

@@ -97,6 +97,33 @@ def whole_lanes(width):
     return -(-width // LANES) * LANES
 
 
+def rope_inv_freq(theta, r):
+    """Pair i's turn a position of an R-wide rotation, float64 [R / 2]:
+    ``theta^(-2i/R)`` where ``theta`` is a number. Where it is a tuple
+    ``(theta, factor, beta_fast, beta_slow, original_max_position)``, YaRN's
+    blend (arXiv:2309.00071, as ``deepseek_v3`` applies it): that frequency
+    where the pair turns more than ``beta_fast`` times over the original
+    context, the frequency over ``factor`` where it turns fewer than
+    ``beta_slow`` times, the linear ramp between the two pairs in between; a
+    static table at any length."""
+    if not isinstance(theta, tuple):
+        return 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
+    theta, factor, beta_fast, beta_slow, original = theta
+    plain = rope_inv_freq(theta, r)
+
+    def pair_turning(rotations):  # the pair that turns so often, a real
+        return (r * np.log(original / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(pair_turning(beta_fast)), 0)
+    high = min(np.ceil(pair_turning(beta_slow)), r - 1)
+    if low == high:
+        high += 0.001
+    slowed = np.clip((np.arange(r // 2, dtype=np.float64) - low)
+                     / (high - low), 0, 1)
+    return plain / factor * slowed + plain * (1 - slowed)
+
+
 def operand_label(dtype):
     return {"bfloat16": "bf16", "float16": "f16",
             "float32": "f32"}.get(jnp.dtype(dtype).name,

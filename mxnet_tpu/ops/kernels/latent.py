@@ -63,7 +63,7 @@ from ... import telemetry as _tm
 from . import flash
 from .common import (
     LANES, NEG_INF, affine, no_x64, on_tpu, operand_label, pad_to,
-    whole_lanes)
+    rope_inv_freq, whole_lanes)
 
 _M_LATENT_TRACES = _tm.counter(
     "attention.latent_kernel_traces", "Traces of a latent flash kernel's "
@@ -704,9 +704,8 @@ def latent_query(x, *, heads, nope, rope, theta, interleave, inverse=False,
     rope_p = whole_lanes(rope)
     per = _query_heads_a_step(nope + rope)
     wide, narrow = per * (nope + rope_p), per * (nope + rope)
-    inv_freq = 1.0 / (theta ** (np.arange(0, rope, 2, dtype=np.float64)
-                                / rope))
-    angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    angles = (np.arange(t, dtype=np.float64)[:, None]
+              * rope_inv_freq(theta, rope)[None, :])
     angles = (np.repeat(angles, 2, axis=-1) if interleave
               else np.concatenate([angles, angles], axis=-1))
     cos, sin = (jnp.asarray(np.pad(f(angles), ((0, 0), (0, rope_p - rope))),

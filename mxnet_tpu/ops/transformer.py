@@ -267,13 +267,15 @@ _M_LATENT_LOWERINGS = _tm.counter(
     "every head shares), nope (a head's own key), dv, impl (see below) "
     "and, where set, rotary=0, window (the band's keys), select=1 (a "
     "keep-mask chooses the keys), gate=headwise, query_latent (the width "
-    "the caller projected the query up from)")
+    "the caller projected the query up from), rope_factor (YaRN's, where "
+    "the frequencies are blended), score_scale (where the scores' scale "
+    "is not 1 / sqrt(nope + rope))")
 
 
 def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
                      v_head_dim, theta, eps, interleave=True, rotary=True,
                      window=0, latent_scale=1.0, gate=None, keep=None,
-                     query_latent=0):
+                     query_latent=0, score_scale=0.0, rope_scaling=()):
     """query [B, T, H * (N + R)] (a head's N un-rotated dimensions, then
     its R rotary ones), latent [B, T, L + R] (the compressed key/value
     latent, then the one rotary key a token), gamma [L], up_weight
@@ -306,8 +308,19 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
     the pair's selected variant (``flash2sel_*``, ``latent_flash(keep=)``),
     ``composed`` the materialised ``kernels.latent.kept_attention``, both
     under the scope ``select``. ``gate`` [B, T, H]: head h's output times
-    ``sigmoid(gate[.., h])``, float32, one rounding (scope ``gate``)."""
+    ``sigmoid(gate[.., h])``, float32, one rounding (scope ``gate``).
+
+    ``score_scale`` > 0 takes the place of ``1 / sqrt(N + R)`` on the
+    scores (a scaled RoPE's ``mscale^2`` folded in by the model), through
+    both forms. ``rope_scaling`` = ``(factor, beta_fast, beta_slow,
+    original_max_position)``: both rotations turn by YaRN's blended
+    frequencies (``kernels.common.rope_inv_freq``), a static table that
+    takes ``theta``'s place wherever it goes; cos and sin are not scaled.
+    At the defaults neither is read and the program is what it was."""
     from .kernels import latent_flash_takes
+
+    if rope_scaling:
+        theta = (theta,) + tuple(float(v) for v in rope_scaling)
 
     width = latent.shape[2] - rope_dim
     nope = query.shape[2] // num_heads - rope_dim
@@ -320,7 +333,9 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
         **({"window": window} if window else {}),
         **({} if keep is None else {"select": 1}),
         **({} if gate is None else {"gate": "headwise"}),
-        **({"query_latent": query_latent} if query_latent else {}))
+        **({"query_latent": query_latent} if query_latent else {}),
+        **({"rope_factor": rope_scaling[0]} if rope_scaling else {}),
+        **({"score_scale": "%.6g" % score_scale} if score_scale else {}))
     with jax.named_scope("latent"):
         c = rms_norm(latent[..., :width], gamma, eps)
         if latent_scale != 1:
@@ -336,11 +351,12 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
         keep = jax.lax.stop_gradient(keep)
     if kernel:
         out = _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim,
-                                  theta, interleave, rotary, keep=keep)
+                                  theta, interleave, rotary, keep=keep,
+                                  scale=score_scale)
     else:
         out = _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim,
                                     theta, interleave, rotary, window=window,
-                                    keep=keep)
+                                    keep=keep, scale=score_scale)
     if gate is None:
         return out
     with jax.named_scope("gate"):
@@ -365,7 +381,9 @@ def _latent_attention(attrs, ins, is_train):
         rotary=bool(attrs.get("rotary", True)),
         window=int(attrs.get("window", 0)),
         latent_scale=float(attrs.get("latent_scale", 1.0)),
-        query_latent=int(attrs.get("query_latent", 0)), **optional)]
+        query_latent=int(attrs.get("query_latent", 0)),
+        score_scale=float(attrs.get("score_scale", 0.0)),
+        rope_scaling=tuple(attrs.get("rope_scaling") or ()), **optional)]
 
 
 def _latent_attention_infer(attrs, in_shapes):
@@ -417,7 +435,8 @@ _latent_op = OpDef(
     defaults={"num_heads": 1, "rope_dim": 0, "v_head_dim": 0,
               "theta": 10000.0, "eps": 1e-6, "interleave": True,
               "rotary": True, "window": 0, "latent_scale": 1.0,
-              "query_latent": 0, "with_gate": False, "with_keep": False},
+              "query_latent": 0, "score_scale": 0.0, "rope_scaling": (),
+              "with_gate": False, "with_keep": False},
     infer_shape=_latent_attention_infer,
     infer_type=_latent_attention_infer_type,
     aliases=("LatentAttention",),
@@ -1211,7 +1230,8 @@ register(
 # chooses; down here so that no line above moves: see GatedDeltaNet's note)
 # --------------------------------------------------------------------------
 def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
-                          interleave, rotary=True, window=0, keep=None):
+                          interleave, rotary=True, window=0, keep=None,
+                          scale=0.0):
     """Every head's key materialised: the rotation over the whole query,
     the shared rotary key broadcast and concatenated behind each head's
     slice of ``kv`` [B, T, H (N + Dv)], the values sliced out of it, and
@@ -1236,16 +1256,16 @@ def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
     if keep is not None:
         with jax.named_scope("select"):
             out = kept_attention(q, k, kv[..., nope:], keep,
-                                 (nope + rope_dim) ** -0.5)
+                                 scale or (nope + rope_dim) ** -0.5)
     else:
         with jax.named_scope("window" if window else "full"):
             out = attention(q, k, kv[..., nope:], causal=True,
-                            window=window)
+                            window=window, scale=scale or None)
     return out.reshape(b, t, num_heads * v_head_dim)
 
 
 def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
-                        interleave, rotary=True, keep=None):
+                        interleave, rotary=True, keep=None, scale=0.0):
     """Nothing of [T, H, N + R] built for the keys: one pass over the
     query (``_kernel_query``) and ``kernels.latent_flash`` on
     ``kv`` and ``k_rope`` where the up-projection and the rotation left
@@ -1266,7 +1286,8 @@ def _latent_kernel_path(query, kv, k_rope, num_heads, v_head_dim, theta,
         k_rope = jnp.pad(k_rope, ((0, 0), (0, 0), (0, -rope_dim % 128)))
     with jax.named_scope("full" if keep is None else "select"):
         return latent_flash(q, kv, k_rope, num_heads, width - rope_dim,
-                            scale=width ** -0.5, interpret=interpret,
+                            scale=scale or width ** -0.5,
+                            interpret=interpret,
                             keep=keep)
 
 
@@ -1914,10 +1935,13 @@ _M_ROPE_LOWERINGS = _tm.counter(
 
 def _rope_tables(t, r, theta, interleave=False):
     """cos and sin of positions 0..t-1 times pair i's frequency
-    ``theta^(-2i/r)``, float64 [t, r/2]; under ``interleave`` [t, r], a
-    pair's two lanes sharing its angle."""
-    inv_freq = 1.0 / (theta ** (np.arange(0, r, 2, dtype=np.float64) / r))
-    angles = np.arange(t, dtype=np.float64)[:, None] * inv_freq[None, :]
+    ``theta^(-2i/r)`` (a tuple ``theta``: YaRN's blended frequencies,
+    ``kernels.common.rope_inv_freq``), float64 [t, r/2]; under
+    ``interleave`` [t, r], a pair's two lanes sharing its angle."""
+    from .kernels.common import rope_inv_freq
+
+    angles = (np.arange(t, dtype=np.float64)[:, None]
+              * rope_inv_freq(theta, r)[None, :])
     if interleave:
         angles = np.repeat(angles, 2, axis=-1)
     return np.cos(angles), np.sin(angles)
@@ -1957,3 +1981,203 @@ def _rotate_whole_heads(x, num_heads, theta):
     c, s = _whole_head_tables(x.shape[1], x.shape[2] // num_heads, theta)
     return kernels.rotate_heads(x, c, s, num_heads,
                                 interpret=kernels.common.INTERPRET)
+
+
+# --------------------------------------------------------------------------
+# HyperCoeff / HyperMix — manifold-constrained hyper-connections (Xie et
+# al., mHC, arXiv:2512.24880, on Zhu et al., Hyper-Connections,
+# arXiv:2409.19606): n residual streams a token, read through a learned
+# row, written through a learned column and carried through a learned,
+# doubly stochastic matrix, all three a function of the token (down here so
+# that no line above moves)
+#
+# Layout, which is what decides the cost on the chip: the stream is
+# [tokens, n C], stream j the lane-aligned columns j C .. (j + 1) C - 1, so
+# that no array has the n streams as a minor dimension (a bf16 [.., 4, C]
+# pads 4 to a 16-row tile); every coefficient array has the TOKENS on its
+# last axis ([n, tokens], [n, n, tokens]: a [tokens, 4] pads 4 lanes to
+# 128).
+# --------------------------------------------------------------------------
+_M_HC_SUBLAYERS = _tm.counter(
+    "lm.hc_sublayers", "Traces of a HyperCoeff node: one sub-layer wrapped "
+    "in hyper-connections (one per node and lowering, nothing per step); "
+    "labels: streams, iters (the Sinkhorn iterations)")
+
+
+def sinkhorn(m, iters, eps):
+    """``m`` [n, n, tokens] positive -> doubly stochastic a token: ``iters``
+    times the columns (axis 0 summed) then the rows (axis 1 summed) divided
+    by their sum + ``eps``; the rows are exact at exit. The sums are
+    written out stream by stream, so an iteration is elementwise over the
+    tokens and nothing is reduced; the iterations are ONE loop body (a
+    program of six blocks would else carry 12 x 20 copies of it, three
+    times over with the backward)."""
+    def total(parts):
+        return functools.reduce(jnp.add, parts)
+
+    n = m.shape[0]
+
+    def iteration(_, m):
+        m = m / (total([m[i] for i in range(n)])[None] + eps)
+        return m / (total([m[:, j] for j in range(n)])[:, None] + eps)
+
+    return jax.lax.fori_loop(0, iters, iteration, m)
+
+
+def hyper_coeff(x, phi, bias, alpha, streams, iters, eps, clamp,
+                norm_eps=1e-6):
+    """The three mixings of one sub-layer from its stream ``x`` [tokens, n
+    C]: ``xbar = x / sqrt(mean(x^2) + norm_eps)`` over all n C lanes (no
+    learned scale), and with ``phi`` [n (n + 2), n C] (rows: n of the read,
+    n of the write, n n of the carry, row-major), ``bias`` [n (n + 2)] and
+    ``alpha`` [3],
+
+        pre  = sigmoid(alpha_0 (phi_pre xbar) + b_pre)            [1, n, tokens]
+        post = 2 sigmoid(alpha_1 (phi_post xbar) + b_post)        [n, tokens]
+        res  = sinkhorn(exp(clip(alpha_2 (phi_res xbar) + b_res)))  [n, n, tokens]
+
+    and ``err`` [1], the largest ``|rowsum - 1|``, ``|colsum - 1|`` of any
+    token's ``res``: what the iterations left. ONE pass over the stream
+    gives the n (n + 2) products and the mean square (``phi xbar = (phi x)
+    / rms``); everything is float32; the iterations are recomputed in the
+    backward (``jax.checkpoint``: the residuals are the products and the
+    mean square, not 2 x iters small arrays)."""
+    n = streams
+    f32 = jnp.float32
+    with jax.named_scope("hc_coeff"):
+        raw = jax.lax.dot_general(
+            phi.astype(x.dtype), x, (((1,), (1,)), ((), ())),
+            precision=(jax.lax.Precision.HIGHEST if x.dtype == f32
+                       else None),
+            preferred_element_type=f32)                     # [n(n+2), N]
+        x32 = x.astype(f32)
+        mean_sq = jnp.mean(x32 * x32, axis=1)               # [N]
+
+    @jax.checkpoint
+    def coefficients(raw, mean_sq, bias, alpha):
+        with jax.named_scope("hc_coeff"):
+            bias, alpha = bias.astype(f32), alpha.astype(f32)
+            z = raw * jax.lax.rsqrt(mean_sq + norm_eps)[None]
+            z = (z * jnp.repeat(alpha, np.array([n, n, n * n]),
+                                total_repeat_length=n * (n + 2))[:, None]
+                 + bias[:, None])
+            pre = jax.nn.sigmoid(z[:n])[None]
+            post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+            m = jnp.exp(jnp.clip(z[2 * n:], clamp[0], clamp[1])).reshape(
+                n, n, -1)
+        with jax.named_scope("hc_sinkhorn"):
+            res = sinkhorn(m, iters, eps)
+            err = jnp.maximum(
+                jnp.max(jnp.abs(jnp.sum(res, axis=1) - 1.0)),
+                jnp.max(jnp.abs(jnp.sum(res, axis=0) - 1.0)))
+        return pre, post, res, err.reshape(1)
+
+    return coefficients(raw, mean_sq, bias, alpha)
+
+
+def hyper_mix(x, mix, add=None, add_mix=None):
+    """``out[t, i] = sum_j mix[i, j, t] x[t, j] (+ add_mix[i, t] add[t])``:
+    x [tokens, n C], mix [m, n, tokens] float32, add [tokens, C], add_mix
+    [m, tokens] -> [tokens, m C] in ``x``'s dtype. m = 1 reads a sub-layer's
+    input off the streams, m = n writes its output back beside the carried
+    streams. Products and sums float32, one rounding."""
+    m, n = mix.shape[0], mix.shape[1]
+    c = x.shape[1] // n
+    with jax.named_scope("hc_mix"):
+        x32 = [x[:, j * c:(j + 1) * c].astype(jnp.float32) for j in range(n)]
+        add32 = None if add is None else add.astype(jnp.float32)
+        out = []
+        for i in range(m):
+            acc = functools.reduce(jnp.add, [
+                mix[i, j][:, None] * x32[j] for j in range(n)])
+            if add32 is not None:
+                acc = acc + add_mix[i][:, None] * add32
+            out.append(acc.astype(x.dtype))
+        return out[0] if m == 1 else jnp.concatenate(out, axis=1)
+
+
+def _hyper_coeff(attrs, ins, is_train):
+    n, iters = int(attrs["streams"]), int(attrs.get("iters", 20))
+    _M_HC_SUBLAYERS.inc(streams=n, iters=iters)
+    return list(hyper_coeff(
+        *ins, streams=n, iters=iters, eps=float(attrs.get("eps", 1e-6)),
+        clamp=tuple(float(v) for v in attrs.get("clamp", (-30.0, 30.0))),
+        norm_eps=float(attrs.get("norm_eps", 1e-6))))
+
+
+def _hyper_coeff_infer(attrs, in_shapes):
+    n = int(attrs["streams"])
+    data = _known(in_shapes[0], "HyperCoeff")
+    if len(data) != 2 or data[1] % n:
+        raise ValueError("HyperCoeff: data %s must be [tokens, streams=%d x "
+                         "hidden]" % (data, n))
+    rows = n * (n + 2)
+    return ([data, (rows, data[1]), (rows,), (3,)],
+            [(1, n, data[0]), (n, data[0]), (n, n, data[0]), (1,)], [])
+
+
+def _hyper_coeff_infer_type(attrs, in_types):
+    """The coefficients are float32 whatever the stream is; ``phi`` is the
+    stream's type (a matrix product's operand), bias and alpha float32."""
+    known = [t for t in in_types[:2] if t is not None]
+    if not known:
+        raise MXNetError("HyperCoeff: cannot infer type")
+    return ([known[0], known[0]] + [
+        np.float32 if t is None else t for t in in_types[2:]],
+        [np.float32] * 4, [])
+
+
+register(
+    OpDef(
+        "_contrib_HyperCoeff",
+        _hyper_coeff,
+        arguments=("data", "phi", "bias", "alpha"),
+        outputs=("pre", "post", "res", "err"),
+        defaults={"streams": 4, "iters": 20, "eps": 1e-6,
+                  "clamp": (-30.0, 30.0), "norm_eps": 1e-6},
+        infer_shape=_hyper_coeff_infer,
+        infer_type=_hyper_coeff_infer_type,
+        aliases=("HyperCoeff",),
+    )
+)
+
+
+def _hyper_mix(attrs, ins, is_train):
+    return [hyper_mix(*ins)]
+
+
+def _hyper_mix_infer(attrs, in_shapes):
+    data = _known(in_shapes[0], "HyperMix")
+    mix = _known(in_shapes[1], "HyperMix")
+    if (len(data) != 2 or len(mix) != 3 or mix[2] != data[0]
+            or data[1] % mix[1]):
+        raise ValueError("HyperMix: data %s [tokens, n x hidden] under mix "
+                         "%s [m, n, tokens]" % (data, mix))
+    c = data[1] // mix[1]
+    added = [(data[0], c), (mix[0], data[0])] if len(in_shapes) > 2 else []
+    return [data, mix] + added, [(data[0], mix[0] * c)], []
+
+
+def _hyper_mix_infer_type(attrs, in_types):
+    known = [t for t in (in_types[0],) + tuple(in_types[2:3])
+             if t is not None]
+    if not known:
+        raise MXNetError("HyperMix: cannot infer type")
+    t = known[0]
+    return ([t, np.float32] + ([t, np.float32] if len(in_types) > 2
+                               else []), [t], [])
+
+
+_hyper_mix_op = OpDef(
+    "_contrib_HyperMix",
+    _hyper_mix,
+    arguments=("data", "mix", "add", "add_mix"),
+    defaults={"with_add": False},
+    infer_shape=_hyper_mix_infer,
+    infer_type=_hyper_mix_infer_type,
+    aliases=("HyperMix",),
+)
+_hyper_mix_op.list_arguments = lambda attrs=None: (
+    ["data", "mix"] + (["add", "add_mix"]
+                       if attrs and attrs.get("with_add") else []))
+register(_hyper_mix_op)

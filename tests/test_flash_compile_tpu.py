@@ -1115,3 +1115,74 @@ def test_the_rotation_is_two_kernels_and_moves_nothing_on_v5e(one_chip, cell):
                for line in scoped), scoped
     assert not re.search(r"\[1,%d,\d+,64\]" % t, entry)
     assert not re.search(r"f32\[1,(%d,\d+|\d+,%d),128\]" % (t, t), entry)
+
+
+def test_the_scaled_latent_pair_compiles_at_the_xing4_cells_shape(one_chip):
+    """``LatentAttention(rope_scaling=, score_scale=)`` as the Xing4.0
+    cell calls it (T 4,096, 32 heads of 128 + 64 / 128 from a latent of
+    512, bf16; YaRN factor 64 over 4,096 positions, the scores times
+    ``192^-0.5 x 2.0048``), forward and backward: the scale and the
+    blended table change no kernel, the op takes the latent pair and the
+    query pass once each way, and no single-key flash kernel is left."""
+    from mxnet_tpu.models.xing4 import score_scale
+    from mxnet_tpu.ops.transformer import latent_attention
+
+    t, h, nope, rope, dv, latent = 4096, 32, 128, 64, 128, 512
+    assert pk.latent_flash_takes(t, nope, rope, dv, jnp.bfloat16)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(*ins):
+        return jnp.sum(latent_attention(
+            *ins, num_heads=h, rope_dim=rope, v_head_dim=dv, theta=1e4,
+            eps=1e-6, interleave=True, query_latent=768,
+            rope_scaling=(64.0, 32.0, 1.0, 4096.0),
+            score_scale=score_scale(192, 64.0, 1.0)).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shape(1, t, h * (nope + rope)), shape(1, t, latent + rope),
+        shape(latent), shape(h * (nope + dv), latent)).compile().as_text()
+    for which in ("fwd", "bwd"):
+        assert len([line for line in text.splitlines()
+                    if "flash2_%s_bf16_" % which in line
+                    and "custom-call(" in line]) == 1, which
+        assert len(re.findall(r"(?m)^\s*%%latent_query_%s_bf16[.\d]* = "
+                              % which, text)) == 1
+    assert "flash_fwd_" not in text and "flash_bwd_" not in text
+
+
+def test_the_streams_mixing_writes_no_float32_stream_on_v5e(one_chip):
+    """One sub-layer's ``HyperCoeff``, read and write at the Xing4.0
+    cell's shape (4,096 tokens, 4 streams of 3,584, bf16), forward and
+    backward: float32 is inside the fusions only — no op of the compiled
+    program writes a float32 array as large as the stream — and the
+    iterations stay ONE loop (a ``while``), not 20 copies of their body."""
+    from mxnet_tpu.ops.transformer import hyper_coeff, hyper_mix
+
+    tokens, n, c = 4096, 4, 3584
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, phi, bias, alpha, y):
+        pre, post, res, _ = hyper_coeff(x, phi, bias, alpha, n, 20, 1e-6,
+                                        (-30.0, 30.0))
+        out = hyper_mix(x, res, y + hyper_mix(x, pre), post)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        spec((tokens, n * c), jnp.bfloat16),
+        spec((n * (n + 2), n * c), jnp.bfloat16),
+        spec((n * (n + 2),), jnp.float32), spec((3,), jnp.float32),
+        spec((tokens, c), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    entry = re.sub(r"\{[^{}]*\}", "", text[text.index("ENTRY"):])
+    written = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%\S+ = (.*?) [\w\-]+\(", entry, re.M)]
+    assert any("bf16[%d,%d]" % (tokens, n * c) in w for w in written)
+    assert not [w for w in written if "f32[%d,%d]" % (tokens, n * c) in w]
+    assert re.search(r" while\(", text)
+    stream = tokens * n * c * 2
+    # the cotangent of the stream and one more stream-sized temporary
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * stream

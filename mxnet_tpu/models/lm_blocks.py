@@ -202,11 +202,12 @@ def hyper_block(stream, prefix, part, rms_eps, sublayer, streams, iters, eps,
     carry matrix's row or column sum from 1 behind ``BlockGrad``). With
     ``<p>`` = ``<prefix><part>_``:
 
-        pre, post, res, err = HyperCoeff(stream)       # <p>hc
-        u = sum_j pre[j] stream[j]                     # <p>hc_read
+        pre, post, res, err, u, stream = HyperCoeff(stream)   # <p>hc
         y = sublayer(RMSNorm(u), prefix)               # <p>norm
         stream'[i] = sum_j res[i, j] stream[j] + post[i] y   # <p>hc_write
 
+    (``u = sum_j pre[j] stream[j]``, the read, is the node's own result and
+    the write reads the stream off it: ``ops/kernels/hyper.py``, PR 70.)
     The node owns ``<p>hc_phi`` [n (n + 2), n hidden] (Normal ``sigma``),
     ``<p>hc_bias`` (zeros for the read and the write, ``carry_bias`` on
     the carry's diagonal: the streams do not start as one average) and
@@ -221,10 +222,9 @@ def hyper_block(stream, prefix, part, rms_eps, sublayer, streams, iters, eps,
         alpha=sym.Variable(p + "_alpha", init=init.Constant(value=alpha)),
         streams=n, iters=iters, eps=eps, clamp=tuple(clamp),
         norm_eps=rms_eps, name=p)
-    read = csym.HyperMix(stream, coeff[0], name=p + "_read")
-    y = sublayer(csym.RMSNorm(read, eps=rms_eps,
+    y = sublayer(csym.RMSNorm(coeff[4], eps=rms_eps,
                               name="%s%s_norm" % (prefix, part)), prefix)
-    out = csym.HyperMix(stream, coeff[2], y, coeff[1], with_add=True,
+    out = csym.HyperMix(coeff[5], coeff[2], y, coeff[1], with_add=True,
                         name=p + "_write")
     return out, sym.BlockGrad(coeff[3], name=p + "_err")
 

@@ -21,6 +21,11 @@ because they need explicit on-chip (VMEM) accumulation patterns.
                and the delta rule (GatedDeltaNet), one pass each way
   rope         the rotary embedding of whole heads of whole lane rows,
                one pass each way that is Attention's transposition too
+  hyper        the residual streams' passes (hyper-connections): the
+               coefficient products, the mean square and the read in one
+               pass over a token block each way, the stream's cotangents
+               summed in the backward's; the write of the next stream,
+               whole rows, one pass each way
   conv         the conv-backward pair
   common       what they share
 
@@ -48,6 +53,8 @@ from .gdn import channel_delta_net, gated_delta_rule, gdn_takes
 from .gmm import (
     gmm_metadata, gmm_row_tile, gmm_runs_kernel, gmm_tiles, grouped_matmul,
     held_transposed, sorted_segment_sum)
+from .hyper import (
+    hyper_takes, stream_mix, stream_products, stream_read, stream_write)
 from .latent import (
     latent_flash, latent_flash_takes, latent_query, latent_query_takes)
 from .rope import rope_rows, rotate_heads
@@ -61,9 +68,11 @@ __all__ = [
     "flash_tiles", "gate_norm_takes",
     "gated_delta_rule", "gated_rms_norm", "gdn_takes",
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
-    "grouped_matmul", "held_transposed", "latent_flash",
+    "grouped_matmul", "held_transposed", "hyper_takes", "latent_flash",
     "latent_flash_takes", "latent_query",
     "latent_query_takes", "reference_attention", "rope_rows", "rotate_heads",
     "sorted_segment_sum",
-    "ssd_scan", "ssd_takes", "taps_takes",
+    "ssd_scan", "ssd_takes", "stream_mix", "stream_products", "stream_read",
+    "stream_write",
+    "taps_takes",
 ]

@@ -2024,34 +2024,13 @@ def sinkhorn(m, iters, eps):
     return jax.lax.fori_loop(0, iters, iteration, m)
 
 
-def hyper_coeff(x, phi, bias, alpha, streams, iters, eps, clamp,
-                norm_eps=1e-6):
-    """The three mixings of one sub-layer from its stream ``x`` [tokens, n
-    C]: ``xbar = x / sqrt(mean(x^2) + norm_eps)`` over all n C lanes (no
-    learned scale), and with ``phi`` [n (n + 2), n C] (rows: n of the read,
-    n of the write, n n of the carry, row-major), ``bias`` [n (n + 2)] and
-    ``alpha`` [3],
-
-        pre  = sigmoid(alpha_0 (phi_pre xbar) + b_pre)            [1, n, tokens]
-        post = 2 sigmoid(alpha_1 (phi_post xbar) + b_post)        [n, tokens]
-        res  = sinkhorn(exp(clip(alpha_2 (phi_res xbar) + b_res)))  [n, n, tokens]
-
-    and ``err`` [1], the largest ``|rowsum - 1|``, ``|colsum - 1|`` of any
-    token's ``res``: what the iterations left. ONE pass over the stream
-    gives the n (n + 2) products and the mean square (``phi xbar = (phi x)
-    / rms``); everything is float32; the iterations are recomputed in the
-    backward (``jax.checkpoint``: the residuals are the products and the
-    mean square, not 2 x iters small arrays)."""
-    n = streams
+def _coefficients(raw, mean_sq, bias, alpha, n, iters, eps, clamp, norm_eps):
+    """``hyper_coeff``'s three mixings and ``err`` from the products ``raw``
+    [n (n + 2), tokens] and the mean square [tokens]: float32, elementwise
+    over the tokens, the iterations recomputed in the backward
+    (``jax.checkpoint``: the residuals are the products and the mean
+    square, not 2 x iters small arrays)."""
     f32 = jnp.float32
-    with jax.named_scope("hc_coeff"):
-        raw = jax.lax.dot_general(
-            phi.astype(x.dtype), x, (((1,), (1,)), ((), ())),
-            precision=(jax.lax.Precision.HIGHEST if x.dtype == f32
-                       else None),
-            preferred_element_type=f32)                     # [n(n+2), N]
-        x32 = x.astype(f32)
-        mean_sq = jnp.mean(x32 * x32, axis=1)               # [N]
 
     @jax.checkpoint
     def coefficients(raw, mean_sq, bias, alpha):
@@ -2075,34 +2054,104 @@ def hyper_coeff(x, phi, bias, alpha, streams, iters, eps, clamp,
     return coefficients(raw, mean_sq, bias, alpha)
 
 
+def hyper_coeff(x, phi, bias, alpha, streams, iters, eps, clamp,
+                norm_eps=1e-6):
+    """The three mixings of one sub-layer from its stream ``x`` [tokens, n
+    C]: ``xbar = x / sqrt(mean(x^2) + norm_eps)`` over all n C lanes (no
+    learned scale), and with ``phi`` [n (n + 2), n C] (rows: n of the read,
+    n of the write, n n of the carry, row-major), ``bias`` [n (n + 2)] and
+    ``alpha`` [3],
+
+        pre  = sigmoid(alpha_0 (phi_pre xbar) + b_pre)            [1, n, tokens]
+        post = 2 sigmoid(alpha_1 (phi_post xbar) + b_post)        [n, tokens]
+        res  = sinkhorn(exp(clip(alpha_2 (phi_res xbar) + b_res)))  [n, n, tokens]
+
+    and ``err`` [1], the largest ``|rowsum - 1|``, ``|colsum - 1|`` of any
+    token's ``res``: what the iterations left. ONE pass over the stream
+    gives the n (n + 2) products and the mean square (``phi xbar = (phi x)
+    / rms``); everything is float32; the iterations are recomputed in the
+    backward (``_coefficients``)."""
+    from . import kernels
+
+    with jax.named_scope("hc_coeff"):
+        raw, mean_sq = kernels.stream_products(x, phi)    # [n(n+2), N], [N]
+    return _coefficients(raw, mean_sq, bias, alpha, streams, iters, eps,
+                         clamp, norm_eps)
+
+
+_M_HC_LOWERINGS = _tm.counter(
+    "lm.hc_lowerings", "Traces of a HyperCoeff node, or of a HyperMix node "
+    "that writes all n streams back, by the form its pass over the stream "
+    "takes (one per node and lowering, nothing per step); labels: node "
+    "(coeff: HyperCoeff; write: HyperMix with an addend and a square mix), "
+    "form (one_pass: ops/kernels/hyper.py, one kernel pass over a token "
+    "block each way: the products, the mean square and the read with the "
+    "stream's cotangents summed in the backward's, or the write; plain: "
+    "the jax.numpy forms)")
+
+
+def _takes_one_stream_pass(node, x, streams):
+    """Whether a node's pass over the stream ``x`` is a kernel pair's:
+    ``kernels.hyper_takes`` has a token block for the stream, and the
+    program is not one the partitioner splits (the kernels have no
+    partitioning rule; ``rope``'s rule); counts the node."""
+    from . import kernels
+
+    one_pass = bool(
+        x.ndim == 2 and x.shape[1] % streams == 0
+        and kernels.hyper_takes(x.shape[0], streams, x.shape[1] // streams,
+                                x.dtype) is not None
+        and not kernels.common.trace_is_partitioned())
+    _M_HC_LOWERINGS.inc(node=node, form="one_pass" if one_pass else "plain")
+    return one_pass
+
+
+def hyper_coeff_read(x, phi, bias, alpha, streams, iters, eps, clamp,
+                     norm_eps=1e-6):
+    """``hyper_coeff`` on the kernel pair: its four results, the read
+    ``hyper_mix(x, pre)`` [tokens, C] and the stream (the same array: the
+    write reads it off this node, so that its cotangent arrives IN the
+    backward's one pass), for the shapes ``kernels.hyper_takes`` admits.
+    The products, the mean square and the read are one pass over a token
+    block; the mixings stay ``_coefficients``. Results within the float32
+    rounding of the ``jax.numpy`` forms' sums."""
+    from . import kernels
+
+    with jax.named_scope("hc_coeff"):
+        raw, mean_sq, read, stream = kernels.stream_read(
+            x, phi, bias, alpha, streams, norm_eps,
+            interpret=kernels.common.INTERPRET)
+    return _coefficients(raw, mean_sq, bias, alpha, streams, iters, eps,
+                         clamp, norm_eps) + (read, stream)
+
+
 def hyper_mix(x, mix, add=None, add_mix=None):
     """``out[t, i] = sum_j mix[i, j, t] x[t, j] (+ add_mix[i, t] add[t])``:
     x [tokens, n C], mix [m, n, tokens] float32, add [tokens, C], add_mix
     [m, tokens] -> [tokens, m C] in ``x``'s dtype. m = 1 reads a sub-layer's
     input off the streams, m = n writes its output back beside the carried
     streams. Products and sums float32, one rounding."""
-    m, n = mix.shape[0], mix.shape[1]
-    c = x.shape[1] // n
+    from . import kernels
+
     with jax.named_scope("hc_mix"):
-        x32 = [x[:, j * c:(j + 1) * c].astype(jnp.float32) for j in range(n)]
-        add32 = None if add is None else add.astype(jnp.float32)
-        out = []
-        for i in range(m):
-            acc = functools.reduce(jnp.add, [
-                mix[i, j][:, None] * x32[j] for j in range(n)])
-            if add32 is not None:
-                acc = acc + add_mix[i][:, None] * add32
-            out.append(acc.astype(x.dtype))
-        return out[0] if m == 1 else jnp.concatenate(out, axis=1)
+        return kernels.stream_mix(x, mix, add, add_mix)
 
 
 def _hyper_coeff(attrs, ins, is_train):
+    """The mixings, the read ``HyperMix(data, pre)`` and the stream for the
+    write to read; one pass over the stream where
+    ``_takes_one_stream_pass`` says so, the ``jax.numpy`` forms
+    elsewhere."""
     n, iters = int(attrs["streams"]), int(attrs.get("iters", 20))
     _M_HC_SUBLAYERS.inc(streams=n, iters=iters)
-    return list(hyper_coeff(
-        *ins, streams=n, iters=iters, eps=float(attrs.get("eps", 1e-6)),
+    options = dict(
+        streams=n, iters=iters, eps=float(attrs.get("eps", 1e-6)),
         clamp=tuple(float(v) for v in attrs.get("clamp", (-30.0, 30.0))),
-        norm_eps=float(attrs.get("norm_eps", 1e-6))))
+        norm_eps=float(attrs.get("norm_eps", 1e-6)))
+    if _takes_one_stream_pass("coeff", ins[0], n):
+        return list(hyper_coeff_read(*ins, **options))
+    outs = hyper_coeff(*ins, **options)
+    return list(outs) + [hyper_mix(ins[0], outs[0]), ins[0]]
 
 
 def _hyper_coeff_infer(attrs, in_shapes):
@@ -2113,18 +2162,21 @@ def _hyper_coeff_infer(attrs, in_shapes):
                          "hidden]" % (data, n))
     rows = n * (n + 2)
     return ([data, (rows, data[1]), (rows,), (3,)],
-            [(1, n, data[0]), (n, data[0]), (n, n, data[0]), (1,)], [])
+            [(1, n, data[0]), (n, data[0]), (n, n, data[0]), (1,),
+             (data[0], data[1] // n), data],
+            [])
 
 
 def _hyper_coeff_infer_type(attrs, in_types):
-    """The coefficients are float32 whatever the stream is; ``phi`` is the
-    stream's type (a matrix product's operand), bias and alpha float32."""
+    """The coefficients are float32 whatever the stream is; ``phi``, the
+    read and the stream handed on are the stream's type (a matrix product's
+    operand), bias and alpha float32."""
     known = [t for t in in_types[:2] if t is not None]
     if not known:
         raise MXNetError("HyperCoeff: cannot infer type")
     return ([known[0], known[0]] + [
         np.float32 if t is None else t for t in in_types[2:]],
-        [np.float32] * 4, [])
+        [np.float32] * 4 + [known[0]] * 2, [])
 
 
 register(
@@ -2132,7 +2184,7 @@ register(
         "_contrib_HyperCoeff",
         _hyper_coeff,
         arguments=("data", "phi", "bias", "alpha"),
-        outputs=("pre", "post", "res", "err"),
+        outputs=("pre", "post", "res", "err", "read", "stream"),
         defaults={"streams": 4, "iters": 20, "eps": 1e-6,
                   "clamp": (-30.0, 30.0), "norm_eps": 1e-6},
         infer_shape=_hyper_coeff_infer,
@@ -2143,6 +2195,16 @@ register(
 
 
 def _hyper_mix(attrs, ins, is_train):
+    """The write of all n streams is one kernel pass each way where
+    ``_takes_one_stream_pass`` says so; every other mixing ``hyper_mix``."""
+    from . import kernels
+
+    if (len(ins) == 4 and ins[1].shape[0] == ins[1].shape[1]
+            and _takes_one_stream_pass("write", ins[0], ins[1].shape[1])):
+        x, res, y, post = ins
+        with jax.named_scope("hc_mix"):
+            return [kernels.stream_write(
+                x, res, y, post, interpret=kernels.common.INTERPRET)]
     return [hyper_mix(*ins)]
 
 

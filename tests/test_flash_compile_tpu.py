@@ -1186,3 +1186,92 @@ def test_the_streams_mixing_writes_no_float32_stream_on_v5e(one_chip):
     stream = tokens * n * c * 2
     # the cotangent of the stream and one more stream-sized temporary
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * stream
+
+
+def test_the_streams_passes_are_four_kernels_on_v5e(one_chip):
+    """The same sub-layer through ``hyper_coeff_read`` and
+    ``kernels.stream_write`` (PR 70): the coefficient pass with the read,
+    and the write, are ONE kernel each each way at the block
+    ``hyper_takes`` chose, each inside its own VMEM count, named for what
+    they run and filed under the node's scope and ``hc_coeff`` /
+    ``hc_mix``; the write reads the stream off the node, so the read's
+    backward kernel's result is the stream's ONLY cotangent (no
+    ``add_any`` of stream-sized arrays behind it), no float32 stream is
+    written, and no fusion concatenates one."""
+    from mxnet_tpu.ops.kernels import hyper
+    from mxnet_tpu.ops.transformer import hyper_coeff_read
+
+    tokens, n, c = 4096, 4, 3584
+    block = pk.hyper_takes(tokens, n, c, jnp.bfloat16)
+    assert block == 128
+    for kernel in hyper.KERNELS:    # each is compiled under its own count
+        assert hyper.hyper_vmem_bytes(
+            block, n, c, 2, kernel) <= pk.common.VMEM_RAISED_LIMIT
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(x, phi, bias, alpha, y):
+        with jax.named_scope("hc/layer0_attn_hc"):
+            _, post, res, _, read, stream = hyper_coeff_read(
+                x, phi, bias, alpha, n, 20, 1e-6, (-30.0, 30.0))
+        with jax.named_scope("hc/layer0_attn_hc_write/hc_mix"):
+            out = pk.stream_write(stream, res, y + read, post)
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        spec((tokens, n * c), jnp.bfloat16),
+        spec((n * (n + 2), n * c), jnp.bfloat16),
+        spec((n * (n + 2),), jnp.float32), spec((3,), jnp.float32),
+        spec((tokens, c), jnp.bfloat16)).compile().as_text()
+    for kernel, under in (
+            ("read_fwd", "jvp(hc/layer0_attn_hc)/hc_coeff/"),
+            ("read_bwd", "transpose(jvp(hc/layer0_attn_hc))/hc_coeff/"),
+            ("write_fwd", "jvp(hc/layer0_attn_hc_write/hc_mix)/"),
+            ("write_bwd", "transpose(jvp(hc/layer0_attn_hc_write/hc_mix))/")):
+        calls = re.findall(
+            r"(?m)^\s*(?:ROOT )?%%hc_%s_bf16_n4_c3584[.\d]* = .*" % kernel,
+            text)
+        assert len(calls) == 1, kernel
+        assert under in calls[0], calls[0][-400:]
+    entry = re.sub(r"\{[^{}]*\}", "", text[text.index("ENTRY"):])
+    written = [m.group(1) for m in re.finditer(
+        r"^\s*(?:ROOT )?%\S+ = (.*?) [\w\-]+\(", entry, re.M)]
+    assert not [w for w in written if "f32[%d,%d]" % (tokens, n * c) in w]
+    assert not re.search(r"(?m)^\s*%%\S*(pad|concatenate)\S* = bf16\[%d,%d\]"
+                         % (tokens, n * c), entry)
+    # the root's second result, the stream's cotangent, is the kernel's own
+    root = re.search(r"(?m)^\s*ROOT %\S+ = .*? tuple\(%[\w.\-]+, (%[\w.\-]+)",
+                     entry)
+    assert re.search(
+        r"(?m)^\s*%s = .*get-tuple-element\(%%hc_read_bwd_bf16_n4_c3584"
+        % re.escape(root.group(1)), entry), root.group(0)
+
+
+def test_the_streams_products_cotangents_go_at_the_default_precision():
+    """What the read's backward kernel is held to (PR 70): autodiff of
+    ``hyper_coeff``'s products ``phi x^T`` on a bf16 stream hands the
+    products' float32 cotangent to BOTH backward products (``dphi`` and
+    ``dx``) at the DEFAULT precision, which on the TPU is one bf16 pass:
+    the float32 operand is rounded to bf16 in front of the MXU
+    (``benchmarks/hyper_mix.py``'s row ``operand`` reads that on the
+    chip). ``hc_read_bwd`` casts the same cotangents to the stream's type
+    itself; a float32 stream multiplies at the highest, both ways."""
+    tokens, n, c = 256, 4, 128
+    rows = n * (n + 2)
+    g = jax.ShapeDtypeStruct((rows, tokens), jnp.float32)
+    for dtype, precision in ((jnp.bfloat16, "DEFAULT"),
+                             (jnp.float32, "HIGHEST")):
+        x = jax.ShapeDtypeStruct((tokens, n * c), dtype)
+        phi = jax.ShapeDtypeStruct((rows, n * c), dtype)
+
+        def pulled(x, phi, g):
+            (_, pull) = jax.vjp(lambda x, phi: pk.stream_products(x, phi)[0],
+                                x, phi)
+            return pull(g)
+        dots = re.findall(r"stablehlo\.dot_general.*", jax.jit(pulled).lower(
+            x, phi, g).as_text())
+        assert len(dots) == 2
+        for dot in dots:
+            assert "precision = [%s, %s]" % (precision, precision) in dot
+            assert "tensor<%dx%dxf32>" % (rows, tokens) in dot

@@ -475,11 +475,12 @@ def test_the_ops_check_their_inputs_and_say_their_types():
     coeff = mx.contrib.sym.HyperCoeff(data, streams=4, name="hc")
     args, outs, _ = coeff.infer_shape(data=(48, 64))
     assert args == [(48, 64), (24, 64), (24,), (3,)]
-    assert outs == [(1, 4, 48), (4, 48), (4, 4, 48), (1,)]
+    assert outs == [(1, 4, 48), (4, 48), (4, 4, 48), (1,), (48, 16),
+                    (48, 64)]
     assert coeff.list_arguments() == ["data", "hc_phi", "hc_bias", "hc_alpha"]
     arg_types, out_types, _ = coeff.infer_type(data=jnp.bfloat16)
     assert arg_types == [jnp.bfloat16, jnp.bfloat16, np.float32, np.float32]
-    assert out_types == [np.float32] * 4
+    assert out_types == [np.float32] * 4 + [jnp.bfloat16] * 2
     with pytest.raises(Exception, match="streams"):
         coeff.infer_shape(data=(48, 66))
     read = mx.contrib.sym.HyperMix(data, coeff[0], name="read")
@@ -740,23 +741,30 @@ def test_the_model_states_its_initialisation_and_counts_what_it_traces():
 def test_the_scopes_name_the_mixing_the_query_latent_and_the_module():
     """What a trace files the new device ops under. A node's ops are traced
     under ``<op class>/<node name>``: ``hc/layer<i>_attn_hc`` and
-    ``_ffn_hc`` (the coefficients; ``_read`` / ``_write`` the mixing's two
-    nodes), the query latent's three nodes ``*_q_latent_*``, every node of
-    the module ``mtp0_*``; inside the ``hc`` nodes the ops' own scopes
+    ``_ffn_hc`` (the coefficients and, since PR 70, the read, one pass over
+    the stream; ``_write`` the mixing's other node, which reads the stream
+    off the first), the query latent's three nodes ``*_q_latent_*``, every
+    node of the module ``mtp0_*``; inside the ``hc`` nodes the ops' own scopes
     ``hc_coeff``, ``hc_sinkhorn`` and ``hc_mix``."""
     from mxnet_tpu.executor import op_class
 
     nodes = json.loads(xing4.from_config(SHARE, seq_len=T).tojson())["nodes"]
     scopes = {"%s/%s" % (op_class(n["op"]), n["name"])
               for n in nodes if n["op"] != "null"}
-    assert {"hc/layer0_attn_hc", "hc/layer1_ffn_hc", "hc/layer1_attn_hc_read",
+    assert {"hc/layer0_attn_hc", "hc/layer1_ffn_hc", "hc/layer1_attn_hc_write",
             "hc/mtp0_ffn_hc_write", "fc/layer0_q_latent_a_proj",
             "norm/layer1_q_latent_norm", "fc/mtp0_q_latent_b_proj",
             "fc/mtp0_proj", "norm/mtp0_embed_norm", "norm/mtp0_hidden_norm",
             "embed/mtp0_embed", "fc/mtp0_lm_head", "norm/mtp0_final_norm",
             "moe/mtp0_moe", "attn/mtp0_attn", "other/mtp0_lm_head_pick",
             "act/mtp0_stream_sum", "act/stream_sum", "loss/loss"} <= scopes
-    assert len([s for s in scopes if s.startswith("hc/")]) == 3 * 2 * 3
+    assert len([s for s in scopes if s.startswith("hc/")]) == 3 * 2 * 2
+    # the norm in front of the sub-layer reads the coefficient node's
+    # fifth result (the read), the write its sixth (the stream)
+    by_name = {n["name"]: n for n in nodes}
+    coeff = nodes.index(by_name["layer1_attn_hc"])
+    assert by_name["layer1_attn_norm"]["inputs"][0][:2] == [coeff, 4]
+    assert by_name["layer1_attn_hc_write"]["inputs"][0][:2] == [coeff, 5]
     # every node of the module says so but those ``lm_blocks`` leaves
     # unnamed in every LM symbol: the shape-only ones of the head's loss,
     # and the shared SwiGLU's activation and product and its sum with the

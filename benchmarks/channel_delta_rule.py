@@ -1,5 +1,5 @@
 """The delta rule with a decay a channel (Kimi Delta Attention) on the chip:
-`ops/transformer.py::channel_delta_rule` (the `jax.numpy` chunk form, under
+`ops/transformer/delta.py::channel_delta_rule` (the `jax.numpy` chunk form, under
 `jax.checkpoint` with the unit norms, write strengths and decays before it,
 as the GatedDeltaNet op ran its `delta_rule` stage before the kernels)
 against `ops/kernels/gdn.py::channel_delta_rule` (the `kda_fwd_` /
@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mxnet_tpu.ops import kernels as pk
-from mxnet_tpu.ops.transformer import channel_delta_rule
+from mxnet_tpu.ops.transformer.delta import channel_delta_rule
 
 B, T, H, K, V, CHUNK = 1, 8192, 32, 128, 128, 64
 INPUTS = ("q", "k", "v", "a", "b")

@@ -1,4 +1,4 @@
-"""The gated delta rule's chunk core on the chip: `ops/transformer.py::
+"""The gated delta rule's chunk core on the chip: `ops/transformer/delta.py::
 gated_delta_rule` (the `jax.numpy` chunk form, under `jax.checkpoint` with
 the unit norms, write strengths and decays before it, as the GatedDeltaNet
 op ran its `delta_rule` stage before the kernels) against
@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mxnet_tpu.ops import kernels as pk
-from mxnet_tpu.ops.transformer import gated_delta_rule
+from mxnet_tpu.ops.transformer.delta import gated_delta_rule
 
 B, T, H, K, V, CHUNK = 1, 4096, 30, 96, 192, 64
 INPUTS = ("q", "k", "v", "a", "b")

@@ -1,7 +1,7 @@
 """The residual streams' mixing on the chip: ONE sub-layer's pass over the
 stream at the shape the cell of `BENCHMARK.json` that has one calls it with
 (`CELLS`: Xing4.0's 4,096 tokens of 4 streams of 3,584), in the forms
-`ops/transformer.py`'s `HyperCoeff` and `HyperMix` nodes choose between,
+`ops/transformer/hyper.py`'s `HyperCoeff` and `HyperMix` nodes choose between,
 and in a third that no node runs.
 
   plain     `hyper_coeff` (the products `phi x^T`, the mean square, the
@@ -58,7 +58,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mxnet_tpu.ops import kernels, transformer
+from mxnet_tpu.ops import kernels
+from mxnet_tpu.ops.transformer import hyper
 from mxnet_tpu.ops.kernels.common import dot_highest
 
 ITERS, EPS, CLAMP, NORM_EPS = 20, 1e-6, (-30.0, 30.0), 1e-6
@@ -72,19 +73,19 @@ def _rows(x, phi, bias, alpha, n):
         raw = dot_highest(x, phi.astype(x.dtype), (1, 1)).T
         x32 = x.astype(jnp.float32)
         mean_sq = jnp.mean(x32 * x32, axis=1)
-    return transformer._coefficients(raw, mean_sq, bias, alpha, n, ITERS,
+    return hyper._coefficients(raw, mean_sq, bias, alpha, n, ITERS,
                                      EPS, CLAMP, NORM_EPS)
 
 
 def _form(by, n):
     def parts(x, phi, bias, alpha):
         if by in ("one_pass", "read"):
-            return transformer.hyper_coeff_read(
+            return hyper.hyper_coeff_read(
                 x, phi, bias, alpha, n, ITERS, EPS, CLAMP, NORM_EPS)
         outs = (_rows(x, phi, bias, alpha, n) if by == "rows"
-                else transformer.hyper_coeff(x, phi, bias, alpha, n, ITERS,
+                else hyper.hyper_coeff(x, phi, bias, alpha, n, ITERS,
                                              EPS, CLAMP, NORM_EPS))
-        return outs + (transformer.hyper_mix(x, outs[0]), x)
+        return outs + (hyper.hyper_mix(x, outs[0]), x)
     return parts
 
 
@@ -97,7 +98,7 @@ def passes(by, n, write):
             return read, post, res
         if by == "one_pass":
             return kernels.stream_write(stream, res, read, post)
-        return transformer.hyper_mix(stream, res, read, post)
+        return hyper.hyper_mix(stream, res, read, post)
     return f
 
 

@@ -1,7 +1,7 @@
 """Latent attention on the chip at the Kanana cell's shape (one sequence of
 8,192 tokens, hidden 2,048, 32 heads of 128 + 64 query/key and 128 value
 dimensions from a latent of 512, interleaved RoPE, bf16): the composition
-the op ran before PR 44 (`ops/transformer.py::_latent_composed_path`: the
+the op ran before PR 44 (`ops/transformer/latent.py::_latent_composed_path`: the
 rotation over the whole query, the rotary key broadcast and concatenated,
 `flash_attention` on head-major copies) against the kernel path
 (`_latent_kernel_path`: `kernels.latent_flash`, two key operands on
@@ -46,6 +46,7 @@ import numpy as np
 
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import latent
 
 B, T, HIDDEN, H, N, R, DV, L = 1, 8192, 2048, 32, 128, 64, 128, 512
 THETA, EPS = 1e6, 1e-6
@@ -96,8 +97,8 @@ def _linear(x, w, scope):
 def block(form):
     """(forward, forward + backward) of one layer's attention block with
     the op's attention in ``form``: "composed" or "kernel"."""
-    path = {"composed": tr._latent_composed_path,
-            "kernel": tr._latent_kernel_path}[form]
+    path = {"composed": latent._latent_composed_path,
+            "kernel": latent._latent_kernel_path}[form]
 
     def fwd(x, wq, wa, gamma, wup, wo):
         query = _linear(x, wq, "q_proj")

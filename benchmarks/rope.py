@@ -1,4 +1,4 @@
-"""The rotary embedding on the chip: `ops/transformer.py::rope`'s two forms
+"""The rotary embedding on the chip: `ops/transformer/rotary.py::rope`'s two forms
 at the shapes the cells of `BENCHMARK.json` call it with (`CELLS`: Ouro's
 and OLMoE's `q` and `k`, Trinity-Mini's and Falcon-H1's `q` and `k`: heads
 of 128, the rule's; LFM2's heads of 64 under `halves` alone, the form its
@@ -44,7 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from mxnet_tpu.ops import kernels, transformer
+from mxnet_tpu.ops import kernels
+from mxnet_tpu.ops.transformer import rotary
 
 THETA = 10000.0
 HIDDEN = 2048
@@ -61,13 +62,13 @@ CELLS = {
 
 def halves(x, heads):
     """`rope` with the rule switched off: the halves' lines."""
-    with mock.patch.object(transformer, "_takes_one_pass",
+    with mock.patch.object(rotary, "_takes_one_pass",
                            lambda *args: False):
-        return transformer.rope(x, heads, THETA)
+        return rotary.rope(x, heads, THETA)
 
 
 def one_pass(x, heads):
-    return transformer._rotate_whole_heads(x, heads, THETA)
+    return rotary._rotate_whole_heads(x, heads, THETA)
 
 
 FORMS = {"halves": halves, "one_pass": one_pass}

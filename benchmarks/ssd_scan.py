@@ -1,4 +1,4 @@
-"""The chunked state-space scan on the chip: `ops/transformer.py::ssd_scan`
+"""The chunked state-space scan on the chip: `ops/transformer/ssm.py::ssd_scan`
 (the `jnp.einsum` form, under `jax.checkpoint(policy=dots_saveable)` as
 the Mamba2 op ran it before the kernels, plus the skip) against
 `ops/kernels/ssd.py::ssd_scan` (the `ssd_fwd_` / `ssd_bwd_` kernel pair)
@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from mxnet_tpu.ops import kernels as pk
-from mxnet_tpu.ops.transformer import ssd_scan
+from mxnet_tpu.ops.transformer.ssm import ssd_scan
 
 B, CHUNK = 1, 128
 # (t, heads, head_dim, groups, state) of the cells that run the pair

@@ -7,9 +7,9 @@ from ..ops import registry as _registry
 import sys as _sys
 
 _mod = _sys.modules[__name__]
-from .ops import CONTRIB_OP_EXPORTS
+from .ops import contrib_op_exports as _exports
 
-for _name in CONTRIB_OP_EXPORTS:
+for _name in _exports():
     if _registry.exists(_name):
         _opdef = _registry.get(_name)
 

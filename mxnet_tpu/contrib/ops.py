@@ -800,14 +800,14 @@ register(
 )
 
 
-# Single source of truth for the names contrib/{symbol,ndarray}.py expose
-# (keeps the two frontends from drifting when an op is added).
-CONTRIB_OP_EXPORTS = (
-    "MultiBoxPrior", "MultiBoxTarget", "MultiBoxDetection", "Proposal",
-    "ROIPooling", "CTCLoss", "ctc_loss", "fft", "ifft", "quantize",
-    "dequantize", "count_sketch", "SwitchMoE",
-    # ops/transformer.py
-    "RMSNorm", "RoPE", "Attention", "LatentAttention", "Mamba2", "TopKMoE",
-    "GatedDeltaNet", "ShortConv", "ScaledSum", "KeyIndexer", "ExitMix",
-    "HyperCoeff", "HyperMix",
-)
+def contrib_op_exports():
+    """What contrib/{symbol,ndarray}.py expose: ``X`` for every op registered
+    as ``_contrib_X`` with the alias ``X`` (exported by being registered so),
+    and the eight registered under the reference's other conventions."""
+    from ..ops import registry
+
+    return sorted({"ROIPooling", "CTCLoss", "ctc_loss", "fft", "ifft",
+                   "quantize", "dequantize", "count_sketch"}.union(
+        op.name[len("_contrib_"):] for op in registry.primary_ops()
+        if op.name.startswith("_contrib_")
+        and op.name[len("_contrib_"):] in op.aliases))

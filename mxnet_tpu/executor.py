@@ -35,6 +35,7 @@ from . import telemetry as _tm
 from .base import MXNetError, np_dtype
 from .context import Context
 from .ndarray import NDArray
+from .ops import registry as _registry
 from .symbol import Symbol, _topo_order
 
 __all__ = ["Executor"]
@@ -116,27 +117,13 @@ def _force_mirrored(node):
     return node.attrs.get("__force_mirroring__") in ("True", "true", "1")
 
 
-_OP_CLASS = {
-    "Convolution": "conv", "Deconvolution": "conv",
-    "FullyConnected": "fc", "BatchNorm": "bn", "Pooling": "pool",
-    "Activation": "act", "LeakyReLU": "act", "relu": "act",
-    "sigmoid": "act", "tanh": "act", "add_n": "act", "clip": "act",
-    "MakeLoss": "loss", "softmax_cross_entropy": "loss",
-    "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
-    "_contrib_LatentAttention": "attn", "_contrib_KeyIndexer": "attn",
-    "_contrib_Mamba2": "ssm", "_contrib_ExitMix": "loss",
-    "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
-    "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
-    "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",
-    "_contrib_HyperCoeff": "hc", "_contrib_HyperMix": "hc",
-}
-
-
 def op_class(op_name):
     """conv | fc | bn | pool | act | loss | attn | ssm | gdn | sconv | moe |
     norm | embed | hc | other: the class a node's device ops are filed under
-    (the first part of its named scope)."""
-    cls = _OP_CLASS.get(op_name)
+    (the first part of its named scope). An op states its class where it is
+    registered (``OpDef(op_class=)``); the rest go by two rules of name."""
+    cls = (_registry.get(op_name).op_class if _registry.exists(op_name)
+           else None)
     if cls is not None:
         return cls
     if op_name.endswith("Output"):
@@ -555,12 +542,6 @@ _G_SHARED_USES = _tm.gauge(
     "set at bind, nothing per step")
 
 
-_G_STREAMS = _tm.gauge(
-    "lm.residual_streams", "Residual streams a token of the symbol bound "
-    "last (a HyperCoeff node's streams; 1 for a symbol with none): set at "
-    "bind, nothing per step")
-
-
 def _argument_uses(program):
     """{argument name: how many node inputs of the program's graph read
     it} (an argument with several readers has ONE gradient, their sum)."""
@@ -647,11 +628,6 @@ class Executor:
         if _tm.enabled():
             _G_SHARED_USES.set(max(_argument_uses(self._program).values(),
                                    default=0))
-            _G_STREAMS.set(max(
-                [int(n.canon_attrs().get("streams", 1))
-                 for n in self._program.nodes
-                 if not n.is_variable
-                 and n.op.name == "_contrib_HyperCoeff"], default=1))
 
     def _build_placed(self):
         """ctx_group placement (reference AssignContext/PlaceDevice):

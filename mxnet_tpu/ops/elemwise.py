@@ -27,7 +27,7 @@ def elemwise_backward_infer(attrs, in_shapes, out_shapes):
     return [merged] * len(in_shapes)
 
 
-def _unary(name, fn, aliases=()):
+def _unary(name, fn, aliases=(), op_class=None):
     register(
         OpDef(
             name,
@@ -36,6 +36,7 @@ def _unary(name, fn, aliases=()):
             infer_shape=same_shape_infer(1),
             backward_infer_shape=elemwise_backward_infer,
             aliases=aliases,
+            op_class=op_class,
         )
     )
 
@@ -101,8 +102,8 @@ def _relu(x):
     return jnp.where(x > 0, x, jnp.zeros_like(x))  # exact subgradient parity
 
 
-_unary("relu", _relu)
-_unary("sigmoid", jax.nn.sigmoid)
+_unary("relu", _relu, op_class="act")
+_unary("sigmoid", jax.nn.sigmoid, op_class="act")
 _unary("_copy", lambda x: x, aliases=("identity",))
 _unary("BlockGrad", jax.lax.stop_gradient, aliases=("stop_gradient",))
 _unary("make_loss", lambda x: x)
@@ -135,7 +136,7 @@ _unary("arccos", jnp.arccos)
 _unary("arctan", jnp.arctan)
 _unary("sinh", jnp.sinh)
 _unary("cosh", jnp.cosh)
-_unary("tanh", jnp.tanh)
+_unary("tanh", jnp.tanh, op_class="act")
 _unary("arcsinh", jnp.arcsinh)
 _unary("arccosh", jnp.arccosh)
 _unary("arctanh", jnp.arctanh)
@@ -280,5 +281,6 @@ register(
             attrs, in_shapes
         ),
         aliases=("ElementWiseSum", "_sum"),
+        op_class="act",
     )
 )

@@ -166,6 +166,7 @@ register(
         defaults={"input_dim": 0, "output_dim": 0, "dtype": "float32"},
         infer_shape=_embedding_infer,
         infer_type=_embedding_infer_type,
+        op_class="embed",
         doc="""weight[data] along the first axis: data (any shape, cast to int32)
 -> data.shape + (output_dim,) in the table's type. An id below zero counts
 from the table's end; one outside the table reads NaN and has no gradient.

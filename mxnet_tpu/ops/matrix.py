@@ -353,6 +353,7 @@ register(
         ],
         arguments=("data",),
         defaults={"a_min": 0.0, "a_max": 1.0},
+        op_class="act",
     )
 )
 

@@ -56,6 +56,7 @@ register(
         defaults={"act_type": "relu"},
         infer_shape=same_shape_infer(1),
         backward_infer_shape=elemwise_backward_infer,
+        op_class="act",
     )
 )
 
@@ -101,6 +102,7 @@ _lrelu = OpDef(
     },
     infer_shape=_leaky_relu_infer,
     needs_rng=True,
+    op_class="act",
 )
 _lrelu.list_arguments = lambda attrs=None: (
     ["data", "gamma"] if (attrs or {}).get("act_type") == "prelu" else ["data"]
@@ -179,6 +181,7 @@ _fc = OpDef(
     defaults={"num_hidden": 0, "no_bias": False},
     infer_shape=_fc_infer,
     backward_infer_shape=_fc_backward_infer,
+    op_class="fc",
 )
 _fc.list_arguments = lambda attrs=None: (
     ["data", "weight"]
@@ -650,6 +653,7 @@ _conv = OpDef(
         "layout": None,
     },
     infer_shape=_conv_infer,
+    op_class="conv",
 )
 _conv.list_arguments = lambda attrs=None: (
     ["data", "weight"]
@@ -737,6 +741,7 @@ _deconv = OpDef(
         "workspace": 512,
     },
     infer_shape=_deconv_infer,
+    op_class="conv",
 )
 _deconv.list_arguments = lambda attrs=None: (
     ["data", "weight"]
@@ -851,6 +856,7 @@ register(
         },
         infer_shape=_pooling_infer,
         aliases=("Pooling_v1",),
+        op_class="pool",
     )
 )
 
@@ -1009,6 +1015,7 @@ _bn = OpDef(
     },
     infer_shape=_bn_infer,
     aliases=("CuDNNBatchNorm",),
+    op_class="bn",
 )
 _bn._num_visible_outputs = 1
 register(_bn)
@@ -1387,6 +1394,7 @@ register(
         defaults={"grad_scale": 1.0, "valid_thresh": 0.0, "normalization": "null"},
         infer_shape=same_shape_infer(1),
         need_top_grad=False,
+        op_class="loss",
     )
 )
 
@@ -1410,6 +1418,7 @@ register(
             [(1,)],
             [],
         ),
+        op_class="loss",
     )
 )
 

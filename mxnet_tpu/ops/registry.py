@@ -45,6 +45,7 @@ class OpDef:
         mutate_inputs=(),
         open_attrs=False,
         doc=None,
+        op_class=None,
     ):
         self.name = name
         self.fcompute = fcompute
@@ -81,6 +82,10 @@ class OpDef:
         self.open_attrs = open_attrs
         # what the generated docstring says of the op beyond its signature
         self.doc = doc
+        # the class a node's device ops are filed under in a trace (the
+        # first part of its named scope: conv, fc, attn, ...); None: by
+        # the rules of ``executor.op_class``
+        self.op_class = op_class
 
     # -- attr handling ------------------------------------------------------
     def canon_attrs(self, raw_attrs):
@@ -240,10 +245,9 @@ class OpDef:
 
         if self._infer_type is not None:
             return self._infer_type(attrs, in_types)
-        known = [t for t in in_types if t is not None]
-        if not known:
-            raise MXNetError("%s: cannot infer type" % self.name)
-        t = known[0]
+        from .utils import first_type
+
+        t = first_type(self.name, in_types)
         completed = [t if x is None else x for x in in_types]
         return completed, [t] * len(self._outputs), [np.float32] * len(self._aux)
 

@@ -1,0 +1,285 @@
+"""``Mamba2``: the state-space mixer's core between its two projections
+(Mamba-2 / SSD, Dao & Gu, arXiv:2405.21060): the causal taps, the chunked
+scan and the gated grouped norm, one ``jax.jit`` a signature. Kernel
+families, where lowered for the TPU and the shapes have tiles:
+``ops/kernels/taps.py``, ``ops/kernels/ssd.py`` (``ssd_scan`` here is the
+pair's einsum form and oracle) and ``ops/kernels/gate_norm.py``."""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ... import telemetry as _tm
+from ..registry import OpDef, register
+from ..utils import required_shape
+from .taps import _gate_norm_site, _taps_site, again, causal_taps
+
+
+_M_SCAN_LOWERINGS = _tm.counter(
+    "ssm.scan_lowerings", "Traces of a Mamba2 call site (one per "
+    "lowering, nothing per step); labels: heads, head_dim, state, groups, "
+    "chunk, conv (the convolution's taps), impl (kernel / einsum) and, "
+    "where the projection's five segments are scaled, scaled=1")
+
+
+def ssd_scan(x, bmat, cmat, dt, a, chunk):
+    """The state-space recurrence ``S_t = exp(dt_t a) S_{t-1} + dt_t x_t
+    B_t^T``, ``y_t = S_t C_t`` (``S`` [H, P, N], zero before the first
+    token) in its chunked (SSD) form. x [B, T, H, P], bmat and cmat
+    [B, T, G, N] (head h reads group ``h // (H / G)``), dt [B, T, H]
+    float32 and positive, a [H] float32 and negative -> y [B, T, H, P]
+    float32.
+
+    Inside a chunk of ``chunk`` tokens the masked ``(C B^T) * decay``
+    product against ``dt x``; a chunk's end state; the recurrence over
+    the chunks (a ``lax.scan``, the state entering each chunk kept); and
+    the carried state read through ``C``. Log decays, their running sums
+    and the carried state are float32; the four products take operands of
+    ``x``'s dtype and accumulate in float32. T is padded to whole chunks
+    with ``dt`` 0 (no decay, no input) and the padding cut off. The form
+    for the shapes ``kernels.ssd_scan`` has no tiles for, and what
+    its tests hold it to."""
+    f32 = jnp.float32
+    b, t, h, p = x.shape
+    g, n = bmat.shape[2:]
+    e = h // g                                    # heads a group
+    pad = -t % chunk
+    if pad:
+        x, bmat, cmat, dt = (
+            jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2))
+            for v in (x, bmat, cmat, dt))
+    nc = (t + pad) // chunk
+    x = x.reshape(b, nc, chunk, g, e, p)
+    bmat = bmat.reshape(b, nc, chunk, g, n)
+    cmat = cmat.reshape(b, nc, chunk, g, n)
+    dt = dt.reshape(b, nc, chunk, g, e)
+    cum = jnp.cumsum(dt * a.reshape(g, e), axis=2)    # log decay to here
+    total = cum[:, :, -1]                             # [B, nc, G, E]
+    x32 = x.astype(f32)
+
+    def dot(spec, lhs, rhs):
+        return jnp.einsum(spec, lhs, rhs, preferred_element_type=f32)
+
+    # inside a chunk: token i reads token j <= i through C_i . B_j,
+    # decayed by exp(cum_i - cum_j)
+    cb = dot("bcign,bcjgn->bcgij", cmat, bmat)
+    at = jnp.moveaxis(cum, 2, -1)                     # [B, nc, G, E, Q]
+    causal = np.tril(np.ones((chunk, chunk), bool))
+    decay = jnp.exp(jnp.where(causal, at[..., :, None] - at[..., None, :],
+                              -jnp.inf))
+    mixed = (cb[:, :, :, None] * decay).astype(x.dtype)
+    y = dot("bcgeij,bcjgep->bcigep", mixed,
+            (x32 * dt[..., None]).astype(x.dtype))
+    # a chunk's end state, had it started from zero
+    to_end = jnp.exp(total[:, :, None] - cum) * dt
+    states = dot("bcjgep,bcjgn->cbgepn",
+                 (x32 * to_end[..., None]).astype(x.dtype), bmat)
+
+    def carry(state, chunk_in):
+        ended, decayed = chunk_in
+        return state * jnp.exp(decayed)[..., None, None] + ended, state
+
+    _, entering = jax.lax.scan(
+        carry, jnp.zeros(states.shape[1:], f32),
+        (states, jnp.moveaxis(total, 1, 0)))
+    # the state a chunk entered with, read through C and decayed to here
+    y = y + dot("bcign,cbgepn->bcigep", cmat,
+                entering.astype(x.dtype)) * jnp.exp(cum)[..., None]
+    return y.reshape(b, t + pad, h, p)[:, :t]
+
+
+def mamba2(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
+           num_heads, head_dim, state_size, num_groups, chunk_size, eps,
+           remat=False, multipliers=None):
+    """proj [B, T, 2 H P + 2 G N + H] (``in_proj``'s output: the gate
+    ``z``, then ``x | B | C``, then a step size a head), conv_weight
+    [taps, H P + 2 G N] (tap ``taps - 1`` meets the current token),
+    conv_bias [H P + 2 G N], dt_bias, a_log and d_skip [H], norm_gamma
+    [H P] -> [B, T, H P] (``out_proj``'s input).
+
+    ``x | B | C = silu(conv(.))``, a causal depthwise convolution over
+    time (scope ``conv1d``: ``conv`` is the class of the ``Convolution``
+    nodes in a trace): one Pallas kernel each way over that window of
+    ``proj``'s columns where the taps' family has tiles for the shapes
+    and the step is lowered for the TPU (``kernels.taps_takes`` /
+    ``causal_conv``), ``causal_taps``' shifted multiply-adds elsewhere;
+    ``dt =
+    softplus(dt + dt_bias)``, ``a = -exp(a_log)``, ``y = ssd_scan(...) +
+    d_skip x`` (scope ``scan``); ``RMSNorm(y * silu(z))`` with the
+    statistics over each of the G groups of columns, times ``norm_gamma``
+    (scope ``gate_norm``: the gate first, then the norm). The
+    convolution's sum, step sizes, decays, the carried state, the gate
+    and the norm's statistics are float32 whatever ``proj``'s dtype. The
+    scan is the Pallas kernel pair where the shapes have tiles for it and
+    the step is lowered for the TPU (``kernels.ssd_takes`` /
+    ``ssd_scan``; the skip inside it), the ``jnp.einsum`` form elsewhere.
+    The gate and norm are one Pallas kernel each way where
+    ``kernels.gate_norm_takes`` has tiles (``gated_rms_norm``,
+    ``gate_first``), the block's ``gate_norm`` closure elsewhere.
+    ``remat`` (training): the float32 tables of the ``jax.numpy`` forms
+    are computed again in the backward pass, not kept (``jax.checkpoint``
+    round each; plain autodiff kept 9.6 GB of them at the Nemotron
+    cell's shape); the scan's kernel pair keeps its own residuals (its
+    output and the states), the taps' and the gate and norm's their
+    inputs (the backward kernel computes the sums again in VMEM), and
+    each runs once each way.
+
+    ``multipliers`` (Falcon-H1's ``ssm_multipliers``, with whatever
+    scalar the projection's input carried folded in): five fixed scalars
+    over ``proj``'s segments ``z | x | B | C | dt``, ``proj * m`` a
+    segment in the mathematics. No scaled copy of ``proj`` is made (the
+    taps' kernel reads its window where ``in_proj`` left it): ``x``'s,
+    ``B``'s and ``C``'s scale the taps' float32 weights a column (a
+    depthwise tap is linear in its column; the bias is not scaled),
+    ``z``'s sits inside the gate's ``silu`` and ``dt``'s in front of
+    ``dt_bias``, each float32.
+
+    The call site counts itself here (``ssm.scan_lowerings``,
+    ``causal_taps.lowerings`` and ``gate_norm.lowerings``); the block
+    itself is ``_mamba2_block``, one ``jax.jit`` for every node of one
+    signature: a model's layers trace, differentiate and lower it once
+    (XLA inlines the calls, each under its own node's scope)."""
+    from .. import kernels
+
+    kernel = bool(kernels.ssd_takes(
+        num_heads, head_dim, state_size, num_groups, chunk_size, proj.dtype))
+    d_in = num_heads * head_dim
+    taps_kernel = _taps_site("mamba2", proj, conv_weight, "bias_silu",
+                             offset=d_in)
+    norm_kernel = _gate_norm_site("mamba2", "gate_first", num_groups,
+                                  d_in // num_groups, proj)
+    if multipliers is not None:
+        multipliers = tuple(float(m) for m in multipliers)
+        if len(multipliers) != 5:
+            raise ValueError("Mamba2: multipliers=%r must be five scalars, "
+                             "one a segment of z | x | B | C | dt"
+                             % (multipliers,))
+    _M_SCAN_LOWERINGS.inc(heads=num_heads, head_dim=head_dim,
+                          state=state_size, groups=num_groups,
+                          chunk=chunk_size, conv=conv_weight.shape[0],
+                          impl="kernel" if kernel else "einsum",
+                          **({} if multipliers is None else {"scaled": 1}))
+    return _mamba2_block(
+        proj, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
+        sizes=(num_heads, head_dim, state_size, num_groups, chunk_size),
+        eps=float(eps), remat=bool(remat), kernel=kernel,
+        taps_kernel=taps_kernel, interpret=kernels.common.INTERPRET,
+        multipliers=multipliers, norm_kernel=norm_kernel)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sizes", "eps", "remat", "kernel", "taps_kernel", "interpret",
+    "multipliers", "norm_kernel"))
+def _mamba2_block(proj, conv_weight, conv_bias, dt_bias, a_log, d_skip,
+                  norm_gamma, *, sizes, eps, remat, kernel, taps_kernel,
+                  interpret, multipliers=None, norm_kernel=False):
+    """``mamba2`` for one signature (``sizes``: heads, head width, state,
+    groups, chunk)."""
+    from .. import kernels
+
+    f32 = jnp.float32
+    b, t, _ = proj.shape
+    h, p, n, g, chunk = sizes
+    d_in = h * p
+    conv_dim = d_in + 2 * g * n
+    m_z = m_dt = None
+    if multipliers is not None:
+        m_z, m_x, m_b, m_c, m_dt = multipliers
+        conv_weight = conv_weight.astype(f32) * np.repeat(
+            np.asarray([m_x, m_b, m_c], np.float32), (d_in, g * n, g * n))
+
+    def conv1d(proj, conv_weight, conv_bias):
+        acc = causal_taps(proj[..., d_in:d_in + conv_dim], conv_weight,
+                          conv_bias)
+        return jax.nn.silu(acc).astype(proj.dtype)
+
+    def gate_norm(y, proj, norm_gamma):
+        z = proj[..., :d_in].astype(f32)
+        gated = y.reshape(b, t, d_in) * jax.nn.silu(
+            z if m_z is None else z * m_z)
+        groups = gated.reshape(b, t, g, d_in // g)
+        var = jnp.mean(jnp.square(groups), axis=-1, keepdims=True)
+        normed = (groups * jax.lax.rsqrt(var + eps)).reshape(b, t, d_in)
+        return norm_gamma.astype(proj.dtype) * normed.astype(proj.dtype)
+
+    with jax.named_scope("conv1d"):
+        if taps_kernel:
+            xbc = kernels.causal_conv(
+                proj, conv_weight, conv_bias, form="bias_silu", offset=d_in,
+                channels=conv_dim, interpret=interpret)
+        else:
+            xbc = again(conv1d, remat)(proj, conv_weight, conv_bias)
+    with jax.named_scope("scan"):
+        x = xbc[..., :d_in].reshape(b, t, h, p)
+        bc = (xbc[..., d_in:d_in + g * n].reshape(b, t, g, n),
+              xbc[..., d_in + g * n:].reshape(b, t, g, n))
+        dt = proj[..., d_in + conv_dim:].astype(f32)
+        dt = jax.nn.softplus((dt if m_dt is None else dt * m_dt)
+                             + dt_bias.astype(f32))
+        a = -jnp.exp(a_log.astype(f32))
+        if kernel:
+            y = kernels.ssd_scan(x, *bc, dt, a, d_skip, chunk,
+                                 interpret=interpret)
+        else:
+            y = again(functools.partial(ssd_scan, chunk=chunk), remat,
+                      policy=jax.checkpoint_policies.dots_saveable)(
+                          x, *bc, dt, a)
+            y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
+    with jax.named_scope("gate_norm"):
+        if norm_kernel:
+            return kernels.gated_rms_norm(
+                y.reshape(b, t, d_in), proj, norm_gamma, form="gate_first",
+                groups=g, eps=eps, scale=m_z, interpret=interpret)
+        return again(gate_norm, remat)(y, proj, norm_gamma)
+
+
+def _mamba2_sizes(attrs):
+    return tuple(int(attrs[k]) for k in (
+        "num_heads", "head_dim", "state_size", "num_groups"))
+
+
+def _mamba2(attrs, ins, is_train):
+    h, p, n, g = _mamba2_sizes(attrs)
+    return [mamba2(*ins, num_heads=h, head_dim=p, state_size=n, num_groups=g,
+                   chunk_size=int(attrs["chunk_size"]),
+                   eps=float(attrs.get("eps", 1e-5)), remat=is_train,
+                   multipliers=attrs.get("multipliers"))]
+
+
+def _mamba2_infer(attrs, in_shapes):
+    h, p, n, g = _mamba2_sizes(attrs)
+    taps, chunk = int(attrs["conv_kernel"]), int(attrs["chunk_size"])
+    if min(h, p, n, g, taps, chunk) <= 0 or h % g:
+        raise ValueError(
+            "Mamba2: num_heads=%d, head_dim=%d, state_size=%d, "
+            "num_groups=%d, conv_kernel=%d and chunk_size=%d must be "
+            "positive and the groups divide the heads"
+            % (h, p, n, g, taps, chunk))
+    data = required_shape(in_shapes[0], "Mamba2")
+    d_in, conv_dim = h * p, h * p + 2 * g * n
+    if len(data) != 3 or data[2] != d_in + conv_dim + h:
+        raise ValueError(
+            "Mamba2: data must be [batch, time, %d] (z %d | x B C %d | "
+            "dt %d), got %s" % (d_in + conv_dim + h, d_in, conv_dim, h,
+                                data))
+    return ([data, (taps, conv_dim), (conv_dim,), (h,), (h,), (h,),
+             (d_in,)], [data[:2] + (d_in,)], [])
+
+
+register(
+    OpDef(
+        "_contrib_Mamba2",
+        _mamba2,
+        arguments=("data", "conv_weight", "conv_bias", "dt_bias", "a_log",
+                   "d", "norm_gamma"),
+        defaults={"num_heads": 1, "head_dim": 0, "state_size": 0,
+                  "num_groups": 1, "conv_kernel": 4, "chunk_size": 128,
+                  "eps": 1e-5, "multipliers": None},
+        infer_shape=_mamba2_infer,
+        aliases=("Mamba2",),
+        op_class="ssm",
+    )
+)

@@ -105,3 +105,42 @@ def reduce_out_shape(ishape, axis, keepdims, exclude=False):
 
 def known(shape):
     return shape is not None and all(d is not None and d > 0 for d in shape)
+
+
+# -- what the transformer families' inference shares -------------------------
+def required_shape(shape, what):
+    if shape is None:
+        raise MXNetError("%s: data shape required" % what)  # resolvable later
+    return tuple(shape)
+
+
+def first_type(what, types):
+    """The first of ``types`` that is known: what an op's others follow."""
+    known = [t for t in types if t is not None]
+    if not known:
+        raise MXNetError("%s: cannot infer type" % what)
+    return known[0]
+
+
+def head_width(what, name, shape, heads):
+    """``shape`` [batch, time, heads * head_dim] -> head_dim. A
+    ValueError: a known-but-wrong shape must survive the infer fixpoint
+    loop (see SwitchMoE)."""
+    if len(shape) != 3 or heads <= 0 or shape[2] % heads:
+        raise ValueError(
+            "%s: %s must be [batch, time, %d heads * head_dim], got %s"
+            % (what, name, heads, shape))
+    return shape[2] // heads
+
+
+def check_rotation(what, d, r, offset):
+    if r % 2 or r <= 0 or offset < 0 or offset + r > d:
+        raise ValueError(
+            "%s: the rotated dimensions must be an even count inside the "
+            "head_dim %d, got %d from %d on" % (what, d, r, offset))
+
+
+def optional_inputs(attrs, names):
+    """The inputs an op takes only under ``with_<name>=True``, in order."""
+    return [name for name in names
+            if bool((attrs or {}).get("with_" + name, False))]

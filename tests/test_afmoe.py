@@ -32,6 +32,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.models import afmoe, afmoe_reference as ref
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import attention as attention_ops
 from mxnet_tpu.ops.kernels import flash_tiles, reference_attention
 from mxnet_tpu.parallel import make_mesh
 from mxnet_tpu.parallel.moe import topk_moe
@@ -82,7 +83,7 @@ def _attention_inputs(seed, dtype):
 def _op(window=0, **more):
     attrs = dict(num_heads=HEADS, num_kv_heads=KV, causal=True,
                  window=window, **more)
-    return lambda *ins: tr._attention(attrs, list(ins), True)[0]
+    return lambda *ins: attention_ops._attention(attrs, list(ins), True)[0]
 
 
 def _plain(window, dtype):
@@ -145,7 +146,7 @@ def _parent_attention(attrs, ins):
     from mxnet_tpu.ops.kernels import attention
 
     q, k, v = ins[:3]
-    heads, kv_heads = int(attrs["num_heads"]), tr._kv_heads(attrs)
+    heads, kv_heads = int(attrs["num_heads"]), attention_ops._kv_heads(attrs)
     window = int(attrs.get("window", 0))
     b, t, _ = q.shape
 
@@ -174,7 +175,7 @@ def test_the_gateless_call_traces_what_the_parent_traced(with_sink):
                  window=WINDOW, with_sink=with_sink)
 
     def ours(*a):
-        return tr._attention(attrs, list(a), True)[0]
+        return attention_ops._attention(attrs, list(a), True)[0]
 
     def parents(*a):
         return _parent_attention(attrs, list(a))[0]

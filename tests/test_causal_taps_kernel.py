@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import delta, ssm
 from mxnet_tpu.ops.kernels import taps
 from mxnet_tpu.ops.transformer import short_conv
 
@@ -285,8 +286,8 @@ def test_a_call_site_counts_each_convolved_array_once_a_lowering(
     width, the taps and which form runs; a time length no tile divides is
     the ``jax.numpy`` form's; nothing a step."""
     op, arrays = SITES[site]
-    tr._mamba2_block.clear_cache()
-    tr._gated_delta_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
     compiled = jax.jit(op).lower(*_site_inputs(site, 256)).compile()
     count = registry.get("causal_taps.lowerings")
     assert telemetry.total("causal_taps.lowerings") == len(arrays)
@@ -334,7 +335,7 @@ def test_a_training_step_holds_each_kernel_once_and_never_interpreted(site):
     VMEM: no second forward under a checkpoint), both for Mosaic; a step
     lowered for the CPU holds no kernel at all, runs, and has the
     ``jax.numpy`` form's gradients."""
-    tr._mamba2_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
     op = SITES[site][0]
     ins = _site_inputs(site, 256)
 

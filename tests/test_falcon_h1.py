@@ -40,6 +40,7 @@ from mxnet_tpu.models import falcon_h1, falcon_h1_reference as ref
 from mxnet_tpu.models import lm_blocks
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import ssm, sums
 from mxnet_tpu.parallel import make_mesh
 
 T, BATCH, CHUNK, TAPS = 30, 2, 8, 4
@@ -383,11 +384,11 @@ def scan_path(request, monkeypatch):
     (``jax.numpy`` inside the ``custom_vjp``s), and with both kernel
     pairs put through the Pallas interpreter (what the TPU's branch
     computes)."""
-    tr._mamba2_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
     if request.param == "kernels_interpreted":
         monkeypatch.setattr(pk.common, "INTERPRET", True)
     yield request.param
-    tr._mamba2_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
 
 
 def _op_inputs(seed, t):
@@ -463,7 +464,7 @@ def test_scaled_sum_multiplies_in_float32_and_rounds_once():
     data = mx.sym.Variable("data")
     assert lm_blocks.scaled(data, "same", 1.0) is data
     with pytest.raises(ValueError, match="2 inputs under scales"):
-        tr._scaled_sum({"scales": (0.5,)}, [x, y], False)
+        sums._scaled_sum({"scales": (0.5,)}, [x, y], False)
 
 
 # -- the model through the fused step ------------------------------------------
@@ -538,7 +539,7 @@ def test_the_blocks_count_themselves_where_they_are_traced():
         losses.append(float(loss))
         want, moms = ref.sgd_momentum_step(want, moms, grads, lr, momentum)
 
-    for jitted in (tr._mamba2_block, pk.ssd.ssd_fwd_call,
+    for jitted in (ssm._mamba2_block, pk.ssd.ssd_fwd_call,
                    pk.ssd.ssd_bwd_call):
         jitted.clear_cache()    # another test's trace is not this one's
     telemetry.reset()

@@ -322,6 +322,7 @@ def test_the_channel_delta_block_compiles_within_its_memory(one_chip):
     entering states 268 MB, the inverses 67, the tables 134) and what
     crosses between the scopes."""
     from mxnet_tpu.ops import transformer as tr
+    from mxnet_tpu.ops.transformer import delta
 
     t, h, d = 8192, 32, 128
     assert pk.gdn_takes(h, d, d, 64, jnp.bfloat16, "channel")
@@ -339,7 +340,7 @@ def test_the_channel_delta_block_compiles_within_its_memory(one_chip):
             q * 2, k * 2, v * 2, gate * 2, a * 2, *rest, h, 64, 1e-5,
             allow_neg_eigval=False, remat=True, gate_act="sigmoid")
 
-    tr._gated_delta_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
     compiled = jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(op(*a).astype(jnp.float32)),
         argnums=tuple(range(len(ins))))).lower(*ins).compile()
@@ -873,6 +874,7 @@ def test_gate_norm_kernels_compile_inside_their_blocks_for_v5e(one_chip,
     either, and ``do`` goes to the rule's backward as written), and a
     step's blocks fit the scoped VMEM the calls state."""
     from mxnet_tpu.ops import transformer as tr
+    from mxnet_tpu.ops.transformer import delta, ssm
 
     form, t, rows, width, dims = GATE_NORM_BLOCKS[site]
     f32 = jnp.float32
@@ -902,8 +904,8 @@ def test_gate_norm_kernels_compile_inside_their_blocks_for_v5e(one_chip,
             return tr.gated_delta_net(q * 2, k * 2, v * 2, gate * 2, *rest,
                                       h, 64, 1e-6, remat=True)
 
-    tr._mamba2_block.clear_cache()
-    tr._gated_delta_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
     text = jax.jit(jax.value_and_grad(
         lambda *a: jnp.sum(op(*a).astype(f32)),
         argnums=tuple(range(len(ins))))).lower(*ins).compile().as_text()

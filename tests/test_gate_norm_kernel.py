@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import delta, ssm
 from mxnet_tpu.ops.kernels import gate_norm
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -327,8 +328,8 @@ SITES = {"mamba2": (_mamba2, dict(site="mamba2", groups=2, width=128),
 
 
 def _clear_blocks():
-    for block in (tr._mamba2_block, tr._gated_delta_block,
-                  tr._channel_delta_block):
+    for block in (ssm._mamba2_block, delta._gated_delta_block,
+                  delta._channel_delta_block):
         block.clear_cache()
 
 
@@ -421,7 +422,7 @@ def test_falcon_h1s_multiplier_sits_inside_the_gates_silu(monkeypatch):
     multipliers = (0.7, 1.1, 0.9, 1.2, 0.8)
 
     def grads():
-        tr._mamba2_block.clear_cache()
+        ssm._mamba2_block.clear_cache()
         return jax.jit(jax.value_and_grad(
             lambda *a: jnp.sum(_mamba2(
                 *a, remat=True, multipliers=multipliers) ** 2),

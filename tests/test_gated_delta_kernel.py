@@ -30,6 +30,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.models import olmo_hybrid, olmo_hybrid_reference as ref
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import delta
 from mxnet_tpu.ops.transformer import gated_delta_net, gated_delta_rule
 from mxnet_tpu.parallel import make_mesh
 
@@ -285,11 +286,11 @@ def rule_path(request, monkeypatch):
     put through the Pallas interpreter (what the TPU's branch computes).
     The block is one ``jax.jit`` a signature, so its cache is emptied
     round the switch."""
-    tr._gated_delta_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
     if request.param == "kernels_interpreted":
         monkeypatch.setattr(pk.common, "INTERPRET", True)
     yield request.param
-    tr._gated_delta_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
@@ -323,13 +324,13 @@ def test_off_the_tpu_the_ops_values_are_the_chunk_forms_bit_for_bit(remat):
     one program, which a step is: dispatched piece by piece the two
     autodiffs fuse the decay's gradient differently, to an ulp)."""
     *ins, cot = _op_inputs(1)
-    tr._gated_delta_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
     kw = dict(heads=H, chunk=CHUNK, eps=1e-6, beta_scale=2.0, remat=remat,
               taps_kernel=(False,) * 3, norm_kernel=False)
 
     def grads(kernel):
         def loss(*a):
-            o = tr._gated_delta_block(*a, kernel=kernel, interpret=False,
+            o = delta._gated_delta_block(*a, kernel=kernel, interpret=False,
                                       **kw)
             return jnp.sum(o * cot), o
         return jax.jit(jax.value_and_grad(loss, tuple(range(len(ins))),
@@ -357,12 +358,12 @@ def test_a_training_step_holds_each_kernel_once_and_never_interpreted():
     second forward under the checkpoint that recomputes the unit norms),
     both for Mosaic; lowered for the CPU it holds no kernel at all and
     runs."""
-    tr._gated_delta_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
     *ins, cot = _op_inputs(2)
     attrs = dict(num_heads=H, chunk_size=CHUNK)
 
     def loss(*a):
-        return jnp.sum(tr._gated_delta_net(attrs, list(a), True)[0] * cot)
+        return jnp.sum(delta._gated_delta_net(attrs, list(a), True)[0] * cot)
 
     grad = jax.jit(jax.grad(loss, tuple(range(len(ins)))))
     calls = list(_pallas_calls(grad.trace(*ins).jaxpr.jaxpr))
@@ -401,7 +402,7 @@ def test_a_fit_of_three_linear_layers_traces_each_kernel_once():
     rng = np.random.RandomState(3)
     tokens = rng.randint(0, cfg["vocab_size"], (1, t + 1))
     steps = 4
-    for jitted in (tr._gated_delta_block, pk.gdn.gdn_fwd_call,
+    for jitted in (delta._gated_delta_block, pk.gdn.gdn_fwd_call,
                    pk.gdn.gdn_bwd_call, pk.gdn.gdn_forward):
         jitted.clear_cache()    # another test's trace is not this one's
     telemetry.reset()
@@ -616,8 +617,8 @@ def _channel_block(kernel, interpret, remat, ins, cot):
     kw = dict(heads=CH, chunk=CCHUNK, eps=1e-6, beta_scale=1.0, remat=remat,
               taps_kernel=(False,) * 3, interpret=interpret,
               gate_act="sigmoid", norm_kernel=False)
-    block = (tr._channel_delta_block if kernel else functools.partial(
-        tr._gated_delta_block, kernel=False))
+    block = (delta._channel_delta_block if kernel else functools.partial(
+        delta._gated_delta_block, kernel=False))
 
     def loss(*a):
         o = block(*a, **kw)
@@ -634,14 +635,14 @@ def test_the_channel_op_through_the_pair_is_the_op_in_the_chunk_form(remat):
     the gradient of all ten inputs, in training too."""
     *ins, cot = _channel_op_inputs(0)
     assert pk.gdn_takes(CH, CD, CD, CCHUNK, jnp.float32, "channel")
-    tr._channel_delta_block.clear_cache()
+    delta._channel_delta_block.clear_cache()
     (_, o), got = _channel_block(True, True, remat, ins, cot)
     (_, o_w), want = _channel_block(False, False, remat, ins, cot)
     _close(o, o_w, "out", ulps=32)
     for name, g, w in zip(OP_GRADS, got, want):
         assert g.shape == w.shape
         _close(g, w, name, ulps=256)
-    tr._channel_delta_block.clear_cache()
+    delta._channel_delta_block.clear_cache()
 
 
 @pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
@@ -651,7 +652,7 @@ def test_off_the_tpu_the_channel_ops_values_are_the_chunk_forms_bit_for_bit(
     computes what the op computed before there was a pair: output and
     every gradient equal to the bit, as one program."""
     *ins, cot = _channel_op_inputs(1)
-    tr._channel_delta_block.clear_cache()
+    delta._channel_delta_block.clear_cache()
     (_, o), got = _channel_block(True, False, remat, ins, cot)
     (_, o_w), want = _channel_block(False, False, remat, ins, cot)
     np.testing.assert_array_equal(np.asarray(o), np.asarray(o_w))
@@ -666,13 +667,13 @@ def test_a_channel_training_step_holds_each_kernel_once_never_interpreted():
     both for Mosaic and neither the scalar pair;
     lowered for the CPU it holds no kernel at all and runs, and the call
     site counted itself ``impl="kernel", decay="channel"``."""
-    tr._channel_delta_block.clear_cache()
+    delta._channel_delta_block.clear_cache()
     *ins, cot = _channel_op_inputs(2)
     attrs = dict(num_heads=CH, chunk_size=CCHUNK, allow_neg_eigval=False,
                  gate_act="sigmoid")
 
     def loss(*a):
-        return jnp.sum(tr._gated_delta_net(attrs, list(a), True)[0] * cot)
+        return jnp.sum(delta._gated_delta_net(attrs, list(a), True)[0] * cot)
 
     telemetry.reset()
     telemetry.enable()

@@ -288,7 +288,7 @@ def test_the_taps_kernel_leaves_the_op_its_outputs_and_gradients(
     under its checkpoints (``taps_takes`` made to refuse): the output to
     the last bit, every gradient to summation order."""
     from mxnet_tpu.ops import kernels as pk
-    from mxnet_tpu.ops import transformer as tr
+    from mxnet_tpu.ops.transformer import delta
 
     heads, t = 2, 256
     rng = np.random.RandomState(9)
@@ -311,7 +311,7 @@ def test_the_taps_kernel_leaves_the_op_its_outputs_and_gradients(
                                remat=True)
 
     def run():
-        tr._gated_delta_block.clear_cache()
+        delta._gated_delta_block.clear_cache()
         return op(*ins), jax.grad(lambda *a: jnp.sum(op(*a) * cot),
                                   every)(*ins)
 
@@ -321,7 +321,7 @@ def test_the_taps_kernel_leaves_the_op_its_outputs_and_gradients(
     out, grads = run()
     monkeypatch.setattr(pk, "taps_takes", lambda *a, **k: False)
     was, were = run()
-    tr._gated_delta_block.clear_cache()
+    delta._gated_delta_block.clear_cache()
     np.testing.assert_array_equal(np.asarray(out), np.asarray(was))
     for name, g, w in zip(NAMES, grads, were):
         assert g.dtype == w.dtype and g.shape == w.shape, name

@@ -22,6 +22,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.models import xing4, xing4_reference as ref
 from mxnet_tpu.ops import kernels
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import hyper as hyper_ops
 from mxnet_tpu.ops.kernels import hyper
 
 ITERS, EPS, CLAMP, NORM_EPS = 20, 1e-6, (-30.0, 30.0), 1e-6
@@ -315,8 +316,8 @@ def test_a_node_takes_the_form_its_call_admits_and_counts_it(
 def test_a_partitioned_program_takes_the_plain_form(lowerings):
     x, phi, bias, alpha, _ = _operands(128, jnp.bfloat16, 5)
     with kernels.common.partitioned_trace(4):
-        assert not tr._takes_one_stream_pass("coeff", x, N)
-    assert tr._takes_one_stream_pass("coeff", x, N)
+        assert not hyper_ops._takes_one_stream_pass("coeff", x, N)
+    assert hyper_ops._takes_one_stream_pass("coeff", x, N)
     assert lowerings() == {"one_pass": 1, "plain": 1}
 
 

@@ -38,6 +38,7 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.models import kimi_linear, kimi_linear_reference as ref
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import delta
 from mxnet_tpu.parallel import make_mesh
 from mxnet_tpu.parallel.moe import topk_moe
 
@@ -833,7 +834,7 @@ ASSUMED = {
         "BEFORE the gate" in text and "sigmoid" in text
         and str(_kda_node("gate_act")) == "sigmoid"),
     "unit_norm": lambda text: (
-        "1e-6" in text and "1e-6" in open(tr.__file__).read().split(
+        "1e-6" in text and "1e-6" in open(delta.__file__).read().split(
             "def unit(x)")[1][:300]),
     "router": lambda text: "sum + 1e-20" in text and "2.446" in text,
     "block": lambda text: "h += mixer(RMSNorm(h)); h += ffn(RMSNorm(h))"

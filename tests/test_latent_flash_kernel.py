@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import latent
 
 BATCH, NOPE, ROPE, DV, LATENT = 2, 128, 64, 128, 32
 THETA, EPS = 1e6, 1e-6
@@ -233,7 +234,7 @@ def test_the_query_pass_is_the_rotation_and_its_transpose(
                       jnp.float32)
 
     def both(x, cot):
-        out, vjp = jax.vjp(lambda x: tr._kernel_query(
+        out, vjp = jax.vjp(lambda x: latent._kernel_query(
             x, heads, rope, THETA, interleave, True), x)
         return out, vjp(cot)[0]
 

@@ -313,7 +313,7 @@ def test_three_blocks_of_one_shape_trace_the_block_and_the_kernels_once():
     a node nor once a branch; and the step (off the TPU: the einsum
     branch inside the kernels' ``custom_vjp``) follows the reference."""
     from mxnet_tpu.ops import kernels as pk
-    from mxnet_tpu.ops import transformer as tr
+    from mxnet_tpu.ops.transformer import ssm
 
     t, heads, p, n, chunk = 256, 4, 32, 128, 128
     cfg = dict(CFG, num_hidden_layers=4, hybrid_override_pattern="MMME",
@@ -335,7 +335,7 @@ def test_three_blocks_of_one_shape_trace_the_block_and_the_kernels_once():
         losses.append(float(loss))
         want, moms = ref.sgd_momentum_step(want, moms, grads, lr, momentum)
 
-    for jitted in (tr._mamba2_block, pk.ssd.ssd_fwd_call,
+    for jitted in (ssm._mamba2_block, pk.ssd.ssd_fwd_call,
                    pk.ssd.ssd_bwd_call):
         jitted.clear_cache()    # another test's trace is not this one's
     telemetry.reset()

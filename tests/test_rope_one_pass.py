@@ -20,6 +20,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import telemetry
 from mxnet_tpu.ops import kernels, registry
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import rotary
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 THETA = 10000.0
@@ -28,7 +29,7 @@ THETA = 10000.0
 def halves_form(x, heads, theta=THETA):
     """``rope`` with the rule switched off: the lines every call ran
     before the one-pass form."""
-    with mock.patch.object(tr, "_takes_one_pass", lambda *args: False):
+    with mock.patch.object(rotary, "_takes_one_pass", lambda *args: False):
         return tr.rope(x, heads, theta)
 
 
@@ -131,7 +132,7 @@ def test_the_kernel_pair_is_the_plain_form(dtype, t, heads, d):
     rng = np.random.RandomState(t + heads)
     x = jnp.asarray(rng.randn(2, t, heads * d), dtype)
     g = jnp.asarray(rng.randn(2, t, heads * d), dtype)
-    c, s = tr._whole_head_tables(t, d, THETA)
+    c, s = rotary._whole_head_tables(t, d, THETA)
     assert kernels.rope_rows(heads, d, t, dtype) == min(t, 512)
 
     def both(interpret):

@@ -37,6 +37,7 @@ from mxnet_tpu.models import kimi_linear, solar_open2
 from mxnet_tpu.models import solar_open2_reference as ref
 from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import attention, delta
 from mxnet_tpu.parallel import make_mesh
 from mxnet_tpu.parallel.moe import topk_moe
 
@@ -377,7 +378,7 @@ def test_the_shares_add_up_to_the_uncut_layer(kind):
     total = 0.0
     for j in range(0, 8, 2):                # four head shares of two
         if kind == "gqa":                   # 2 query heads on 1 of 4
-            part = tr._attention(
+            part = attention._attention(
                 dict(num_heads=2, num_kv_heads=1, causal=True,
                      with_gate=True),
                 [x @ rows("q", 8, j, 2).T, x @ rows("k", 4, j // 2, 1).T,
@@ -565,11 +566,11 @@ def test_the_channel_op_at_beta_scale_2_is_the_chunk_form_and_the_reference(
                 block(*a, **kw, **more)),
             tuple(range(len(ins))), has_aux=True))(*ins)
 
-    tr._channel_delta_block.clear_cache()
-    (_, o), got = both(tr._channel_delta_block, interpret=True)
-    (_, o_c), want = both(tr._gated_delta_block, kernel=False,
+    delta._channel_delta_block.clear_cache()
+    (_, o), got = both(delta._channel_delta_block, interpret=True)
+    (_, o_c), want = both(delta._gated_delta_block, kernel=False,
                           interpret=False)
-    tr._channel_delta_block.clear_cache()
+    delta._channel_delta_block.clear_cache()
     # the inputs are the worst case: strengths over 1.9, a sub-block's
     # keys within a part in a hundred of one direction
     k = jax.nn.silu(ref.causal_conv(ins[1], ins[6][:, heads * d:2 * heads * d]

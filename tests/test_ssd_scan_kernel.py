@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from mxnet_tpu.models import nemotron_h_reference as ref
 from mxnet_tpu.ops import kernels as pk
-from mxnet_tpu.ops import transformer as tr
+from mxnet_tpu.ops.transformer import ssm
 from mxnet_tpu.ops.transformer import mamba2, ssd_scan
 
 BATCH, N, CHUNK, TAPS = 2, 128, 128, 4
@@ -186,11 +186,11 @@ def scan_path(request, monkeypatch):
     inside the ``custom_vjp``), and with the kernel pair put through the
     Pallas interpreter (what the TPU's branch computes). The block is one
     ``jax.jit`` a signature, so its cache is emptied round the switch."""
-    tr._mamba2_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
     if request.param == "kernels_interpreted":
         monkeypatch.setattr(pk.common, "INTERPRET", True)
     yield request.param
-    tr._mamba2_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
 
 
 def _op_inputs(seed, t, dtype):
@@ -264,7 +264,7 @@ def test_the_taps_kernel_leaves_mamba2_its_outputs_and_gradients(
     every = tuple(range(7))
 
     def run():
-        tr._mamba2_block.clear_cache()
+        ssm._mamba2_block.clear_cache()
         out = _op(*ins, remat=True)
         return out, jax.grad(lambda *a: jnp.sum(
             _op(*a, remat=True).astype(jnp.float32) ** 2), every)(*ins)
@@ -275,7 +275,7 @@ def test_the_taps_kernel_leaves_mamba2_its_outputs_and_gradients(
     out, grads = run()
     monkeypatch.setattr(pk, "taps_takes", lambda *a, **k: False)
     was, were = run()
-    tr._mamba2_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
     np.testing.assert_array_equal(np.asarray(out, np.float32),
                                   np.asarray(was, np.float32))
     for name, g, w in zip(OP_GRADS, grads, were):
@@ -307,13 +307,13 @@ def test_a_training_step_holds_each_kernel_once_and_never_interpreted():
     ``causal_taps``, so a step lowered for the TPU traces no interpreter
     copy of the bodies, and one lowered for the CPU holds no kernel at
     all and runs."""
-    tr._mamba2_block.clear_cache()
+    ssm._mamba2_block.clear_cache()
     ins = _op_inputs(2, 256, jnp.float32)
     attrs = dict(num_heads=HEADS, head_dim=P, state_size=N,
                  num_groups=GROUPS, chunk_size=CHUNK)
 
     def loss(*a):
-        return jnp.sum(tr._mamba2(attrs, list(a), True)[0] ** 2)
+        return jnp.sum(ssm._mamba2(attrs, list(a), True)[0] ** 2)
 
     grad = jax.jit(jax.grad(loss, tuple(range(7))))
     calls = list(_pallas_calls(grad.trace(*ins).jaxpr.jaxpr))

@@ -116,6 +116,32 @@ def test_op_docs_fresh():
     assert r.returncode == 0, r.stdout + r.stderr
 
 
+def test_lowered_step_prints_a_cell_by_what_the_compilers_read(tmp_path):
+    """tools/lowered_step.py on a cell's rehearsal sizes: the step lowered
+    for the TPU here, the one Mosaic body decoded, no location left in the
+    text, and the same line from a second run (what two trees are compared
+    by)."""
+    import gzip
+    import json
+
+    def line(*more):
+        r = subprocess.run(
+            [sys.executable, os.path.join(TOOLS, "lowered_step.py"),
+             "olmo_hybrid_fit_stage_4k", "--tiny", *more],
+            capture_output=True, text=True, timeout=240)
+        assert r.returncode == 0, r.stdout + r.stderr
+        return json.loads(r.stdout.splitlines()[-1])
+
+    first = line("--out", str(tmp_path))
+    assert first["cell"] == "olmo_hybrid_fit_stage_4k.tiny"
+    assert first["mosaic_bodies"] == 1 and len(first["kernels"]) == 1
+    assert first["blocks"] == ["_gated_delta_block"]
+    with gzip.open(tmp_path / "olmo_hybrid_fit_stage_4k.mlir.gz", "rt") as f:
+        text = f.read()
+    assert "mosaic:" in text and "loc(" not in text and "TUzvUg" not in text
+    assert line() == first
+
+
 def test_launch_tracker_modes_dry_run(tmp_path, capsys, monkeypatch):
     """mpi/sge/yarn trackers (reference dmlc tracker parity): --dry-run
     emits a submission command wrapping the rank shim; the shim itself

@@ -633,6 +633,41 @@ def test_op_class(op, cls):
     assert executor.op_class(op) == cls
 
 
+# what ``executor._OP_CLASS`` held before an op's registration said it (PR 71)
+REGISTERED_CLASSES = {
+    "Convolution": "conv", "Deconvolution": "conv",
+    "FullyConnected": "fc", "BatchNorm": "bn", "Pooling": "pool",
+    "Activation": "act", "LeakyReLU": "act", "relu": "act",
+    "sigmoid": "act", "tanh": "act", "add_n": "act", "clip": "act",
+    "MakeLoss": "loss", "softmax_cross_entropy": "loss",
+    "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
+    "_contrib_LatentAttention": "attn", "_contrib_KeyIndexer": "attn",
+    "_contrib_Mamba2": "ssm", "_contrib_ExitMix": "loss",
+    "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
+    "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
+    "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",
+    "_contrib_HyperCoeff": "hc", "_contrib_HyperMix": "hc",
+}
+
+
+def test_op_class_is_what_each_registration_says():
+    from mxnet_tpu.ops import registry
+
+    said = {op.name: op.op_class for op in registry.primary_ops()
+            if op.op_class is not None}
+    assert said == REGISTERED_CLASSES
+    for name, cls in said.items():
+        assert executor.op_class(name) == cls, name
+
+
+def test_op_class_of_an_unregistered_name_is_other():
+    from mxnet_tpu.ops import registry
+
+    assert not registry.exists("NoSuchOp")
+    assert executor.op_class("NoSuchOp") == "other"
+    assert executor.op_class("NoSuchOutput") == "loss"  # the rule by name
+
+
 def _lowered_step(monkeypatch):
     """Lower the toy fit's fused step from what the trainer hands to the
     cost capture (the abstract arguments of its first dispatch)."""

@@ -707,7 +707,6 @@ def test_the_model_states_its_initialisation_and_counts_what_it_traces():
         assert telemetry.REGISTRY.get("lm.hc_sublayers").value(
             streams=4, iters=20) == 6
         assert telemetry.REGISTRY.get("lm.mtp_modules").value(ahead=2) == 1
-        assert telemetry.REGISTRY.get("lm.residual_streams").value() == 4
         # the embedding and the head have two readers each; the label has
         # four (both heads' picks, the module's embedding, the shift)
         assert telemetry.REGISTRY.get("lm.shared_argument_uses").value() == 4

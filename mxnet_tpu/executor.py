@@ -189,6 +189,56 @@ def _mirror_save_set(names):
     return frozenset(n.strip() for n in names.split(",") if n.strip())
 
 
+_M_SHIFT_GRADS = _tm.counter(
+    "conv.shift_grad_lowerings", "Traces of a Convolution node that carries "
+    "the gradient of the per-channel shift inside its data (an input "
+    "BatchNorm's beta, _shift_grad_plan) and takes it from the batch's "
+    "summed cotangent and one forward convolution at batch C "
+    "(ops/nn.py::_carry_shift_grad), one per node and lowering, nothing "
+    "per step; labels: node")
+
+
+def _shift_grad_plan(nodes, output_entries):
+    """{id(node): (node, index) of a beta} for the two ends of every input
+    BatchNorm whose beta takes its gradient through the convolution that
+    reads it, from the graph alone: a ``Convolution`` whose data is,
+    through ``Cast`` nodes only, the first output of a ``BatchNorm`` with
+    ``fix_gamma`` that normalises a variable of the symbol by batch
+    statistics, each value on the way having that one reader.
+
+    Such a BatchNorm's ``dgamma`` and ``dx`` have no reader (the batch
+    wants none unless a caller asks), so of the convolution's data
+    gradient of the whole batch only ``dbeta = sum(dy)`` is read:
+    ``_GraphProgram.__call__`` hands the convolution beta (``__shift__``)
+    and BatchNorm a beta under ``stop_gradient``. A BatchNorm in
+    mid-network needs the whole ``dy`` for its ``dx``, one that learns
+    gamma for ``sum(dy * xhat)``, and a second reader's cotangent is not
+    the convolution's to sum: all of them keep the plain form."""
+    readers = {}
+    for node in nodes:
+        for c, i in node.inputs:
+            readers[(id(c), i)] = readers.get((id(c), i), 0) + 1
+    for n, i in output_entries:
+        readers[(id(n), i)] = readers.get((id(n), i), 0) + 1
+    plan = {}
+    for node in nodes:
+        if node.is_variable or node.op.name != "Convolution":
+            continue
+        src, out = node.inputs[0]
+        while (not src.is_variable and src.op.name == "Cast"
+               and readers[(id(src), out)] == 1):
+            src, out = src.inputs[0]
+        if (src.is_variable or src.op.name != "BatchNorm" or out != 0
+                or readers[(id(src), 0)] != 1
+                or not src.inputs[0][0].is_variable):
+            continue
+        attrs = src.canon_attrs()
+        if (bool(attrs.get("fix_gamma", True))
+                and not bool(attrs.get("use_global_stats", False))):
+            plan[id(node)] = plan[id(src)] = src.inputs[2]
+    return plan
+
+
 def _node_attrs(program, node, rng):
     """Execution-time attrs for one node — the ONE place where per-node
     execution semantics (shape overrides, CustomOp scoping keys, rng
@@ -234,6 +284,7 @@ class _GraphProgram:
         }
         # stable per-node ids for rng folding
         self._node_ids = {id(n): i for i, n in enumerate(self.nodes)}
+        self._shift_grads = _shift_grad_plan(self.nodes, self.output_entries)
         # (shape, dtype, sharding) input signature -> canonicalized
         # per-signature dispatch state (see dispatch_plan)
         self._dispatch_plans = {}
@@ -285,6 +336,19 @@ class _GraphProgram:
                 continue
             attrs = _node_attrs(self, node, rng)
             in_vals = [env[(id(c), i)] for (c, i) in node.inputs]
+            if (is_train and id(node) in self._shift_grads
+                    and in_vals[0].shape[1] < in_vals[0].shape[0]):
+                # an input BatchNorm and its convolution
+                # (_shift_grad_plan): beta's gradient moves from the one
+                # to the other, a forward convolution at batch C; with no
+                # fewer channels than samples (both nodes see the same
+                # two) that would cost what the data gradient does
+                if node.op.name == "BatchNorm":
+                    in_vals[2] = jax.lax.stop_gradient(in_vals[2])
+                else:
+                    beta, i = self._shift_grads[id(node)]
+                    attrs["__shift__"] = env[(id(beta), i)]
+                    _M_SHIFT_GRADS.inc(node=node.name)
             results = _compute_node(node, attrs, in_vals, is_train)
             n_outs = node.num_outputs()
             for i, v in enumerate(results[:n_outs]):

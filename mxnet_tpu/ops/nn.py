@@ -257,6 +257,45 @@ def _conv_named_grads(plain, data, weight, dgrad=None, wgrad=None):
     return conv(data, weight)
 
 
+def _carry_shift_grad(out, plain, image, weight, shift):
+    """``out = conv(data, weight)``, by whichever path, handed on with the
+    gradient of ``shift`` ``[C]`` hung on it: ``data``, of shape ``image``,
+    is something plus a per-channel shift that is already inside it (an
+    input BatchNorm's beta: ``executor._shift_grad_plan`` finds the pair and
+    cuts BatchNorm's own path to it). The forward reads nothing.
+
+    The shift's gradient is the data gradient summed over batch and
+    positions, and ``plain`` is linear in the data and the same map for
+    every sample, so channel c's number is ``<sum_n g, plain(e_c, w)>``,
+    ``e_c`` the image that is one in channel c: ONE forward convolution at
+    batch ``C`` (float32 operands at full precision) against the batch's
+    summed cotangent (float32), traced under ``dgrad``. The data gradient of
+    the batch, from which BatchNorm would have summed the same ``C`` numbers
+    (a transposed convolution into 3 of the MXU's 128 lanes behind
+    ResNet-50's ``bn_data``: 3.88 ms of a 95.9 ms step, and 1.6 ms still at
+    batch 1), is then read only where a caller differentiates the data."""
+    channels = image[1]
+
+    @jax.custom_vjp
+    def carry(out, b, w):
+        return out
+
+    def bwd(w, g):
+        with jax.named_scope("dgrad"), \
+                jax.default_matmul_precision("float32"):
+            each = jnp.eye(channels, dtype=jnp.float32).reshape(
+                (channels, channels) + (1,) * len(image[2:]))
+            response = plain(
+                jnp.broadcast_to(each, each.shape[:2] + image[2:]),
+                w.astype(jnp.float32))
+            gb = jnp.sum(response * jnp.sum(g.astype(jnp.float32), axis=0),
+                         axis=tuple(range(1, len(image))))
+        return g, gb.astype(shift.dtype), None
+
+    carry.defvjp(lambda out, b, w: (out, w), bwd)
+    return carry(out, shift, jax.lax.stop_gradient(weight))
+
+
 def _conv_plain(nd, stride, pad, dilate, groups=1):
     """The NCHW / OIHW convolution every path's forward is.
 
@@ -606,6 +645,12 @@ def _convolution(attrs, ins, is_train):
         # ones under their names
         out = _conv_named_grads(
             _conv_plain(nd, stride, pad, dilate, groups), data, weight)
+    if attrs.get("__shift__") is not None:
+        # the node carries the gradient of the per-channel shift inside its
+        # data (executor._shift_grad_plan), whichever path it took
+        out = _carry_shift_grad(
+            out, _conv_plain(nd, stride, pad, dilate, groups),
+            tuple(data.shape), weight, attrs["__shift__"])
     if not bool(attrs.get("no_bias", False)):
         bias = ins[2].reshape((1, -1) + (1,) * nd)
         out = out + bias

@@ -172,13 +172,17 @@ def test_inside_the_steps_scope_the_names_stay_under_the_node():
         assert re.search(r"[/(]conv/c\)*/%s/" % grad, mine), mine
 
 
-def block(first_reads_batch):
+def block(first_reads):
     """conv + BatchNorm + ReLU + conv, the pattern of both conv cells; the
-    first convolution reads the batch itself or a BatchNorm of it (as
-    ResNet-50's ``conv0`` reads ``bn_data``, whose beta is trained)."""
+    first convolution reads the ``batch`` itself, a BatchNorm of it whose
+    beta is trained (``input_bn``: ResNet-50's ``conv0`` reads ``bn_data``;
+    the node then carries beta's gradient, tests/test_conv_shift_grad.py)
+    or one that learns its gamma too and so needs the whole data gradient
+    (``input_bn_gamma``)."""
     data = mx.sym.Variable("data")
-    if not first_reads_batch:
-        data = mx.sym.BatchNorm(data, fix_gamma=True, name="bn_data")
+    if first_reads != "batch":
+        data = mx.sym.BatchNorm(data, fix_gamma=first_reads == "input_bn",
+                                name="bn_data")
     body = mx.sym.Convolution(data, kernel=(3, 3), pad=(1, 1), num_filter=8,
                               no_bias=True, name="c1")
     body = mx.sym.BatchNorm(body, fix_gamma=False, name="bn1")
@@ -201,23 +205,24 @@ def block(first_reads_batch):
     return loss, args
 
 
-@pytest.mark.parametrize("first_reads_batch", [True, False])
-def test_a_block_compiles_to_the_parents_program(first_reads_batch,
-                                                 monkeypatch):
-    loss, params = block(first_reads_batch)
+@pytest.mark.parametrize("first_reads", ["batch", "input_bn_gamma"])
+def test_a_block_compiles_to_the_parents_program(first_reads, monkeypatch):
+    loss, params = block(first_reads)
     new = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
     monkeypatch.setattr(nn, "_conv_named_grads", PARENT_FORM)
     old = jax.jit(jax.grad(loss)).lower(params).compile().as_text()
     assert stripped(new) == stripped(old)
 
 
-@pytest.mark.parametrize("first_reads_batch,dgrads", [(True, 1), (False, 2)])
-def test_a_data_gradient_nobody_reads_is_not_compiled(first_reads_batch,
-                                                      dgrads):
+@pytest.mark.parametrize("first_reads,dgrads", [
+    ("batch", 1), ("input_bn", 2), ("input_bn_gamma", 2)])
+def test_a_data_gradient_nobody_reads_is_not_compiled(first_reads, dgrads):
     """The rule hands back both gradients; the batch's is dead code (jax
     drops it from the jaxpr before it lowers, and XLA would), as jax's own
-    rule would never have made it."""
-    loss, params = block(first_reads_batch)
+    rule would never have made it. Behind an input BatchNorm that trains
+    its beta alone the second one under ``dgrad`` is the shift's: a forward
+    convolution at batch ``C``."""
+    loss, params = block(first_reads)
     lowered = jax.jit(jax.grad(loss)).lower(params)
     assert sum("/dgrad/" in n for n in conv_names(lowered)) == dgrads
     assert sum("/wgrad/" in n for n in conv_names(lowered)) == 2

@@ -6,6 +6,7 @@ at, the stated one of the one-pass backward). The
 topology is described inside a fixture, never at import: only the worker
 that is handed this file loads the TPU's library. Keep every such test
 in THIS file."""
+import functools
 import os
 import re
 
@@ -1063,6 +1064,45 @@ def test_named_conv_gradients_compile_to_the_parents_program_on_v5e(
     assert "transpose(jvp(conv/%s))/dgrad/" % node in new
     assert "dgrad" not in old and "wgrad" not in old
     assert stripped(new) == stripped(old)
+
+
+def test_the_stems_beta_takes_its_gradient_from_a_forward_on_v5e(one_chip):
+    """ResNet-50's stem at the cell's size (``bn_data`` -> ``cast_in`` ->
+    ``conv0``, batch 256), the batch not differentiated: for the chip no
+    convolution writes the image's gradient, whole or summed; the one under
+    ``dgrad`` is the float32 forward response to the three channels'
+    indicator images, which meets the batch's summed cotangent (PR 72;
+    tests/test_conv_shift_grad.py has the numbers)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.executor import _GraphProgram
+
+    body = mx.sym.BatchNorm(mx.sym.Variable("data"), fix_gamma=True,
+                            eps=2e-5, name="bn_data")
+    body = mx.sym.Cast(body, dtype="bfloat16", name="cast_in")
+    program = _GraphProgram(mx.sym.Convolution(
+        body, kernel=(7, 7), stride=(2, 2), pad=(3, 3), num_filter=64,
+        no_bias=True, name="conv0"))
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    batch = sds((256, 3, 224, 224), jnp.float32)
+    stats = {"bn_data_moving_mean": sds((3,), jnp.float32),
+             "bn_data_moving_var": sds((3,), jnp.float32)}
+    params = {"bn_data_gamma": sds((3,), jnp.float32),
+              "bn_data_beta": sds((3,), jnp.float32),
+              "conv0_weight": sds((64, 3, 7, 7), jnp.bfloat16)}
+
+    def loss(p, data, aux):
+        out, = program(dict(p, data=data), aux, None, True)[0]
+        return jnp.sum((out * out).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss)).lower(params, batch, stats).compile(
+        ).as_text()
+    convs = re.findall(r"= (\w+)\[([\d,]+)\]\S* convolution\(([^\n]*)", text)
+    assert len(convs) == 3, convs
+    (dtype, dims), = [(dtype, dims) for dtype, dims, rest in convs
+                      if "/dgrad/" in rest]
+    # (the TPU's passes fold the map's rows into the batch: 112,24,15,64)
+    assert dtype == "f32" and not dims.startswith("256,"), convs
+    assert not [dims for _, dims, _ in convs if "224" in dims.split(",")]
 
 
 # -- the rotation between its neighbours (PR 67) -----------------------------

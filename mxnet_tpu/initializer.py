@@ -215,6 +215,20 @@ class LogOfUniform(Initializer):
 
 
 @register
+class LogOfIndex(Initializer):
+    """``log(1), log(2), .., log(N)`` along the last axis, the same in
+    every row: Mamba-1's ``A_log`` [channels, N] (the S4D-real rule, Gu &
+    Dao, arXiv:2312.00752: state index ``n`` of every channel decays at
+    rate ``n + 1``)."""
+
+    def _init_weight(self, _, arr):
+        arr[:] = np.broadcast_to(np.log(np.arange(
+            1, arr.shape[-1] + 1, dtype=np.float32)), arr.shape)
+
+    _init_default = _init_weight
+
+
+@register
 class InverseSoftplus(Initializer):
     """``softplus^-1(v) = v + log(1 - exp(-v))``, ``v`` log-uniform in
     [low, high] and not under ``floor``: a state-space layer's

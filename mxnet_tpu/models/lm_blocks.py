@@ -1,6 +1,6 @@
 """The pieces the decoder-only LM symbols share (``mimo_v2``,
 ``kanana2``, ``nemotron_h``, ``olmo_hybrid``, ``lfm2``, ``falcon_h1``,
-``ouro``; the tail also ``olmoe``): a bias-free projection, the dense
+``ouro``, ``phi4_flash``; the tail also ``olmoe``): a bias-free projection, the dense
 SwiGLU and the un-gated relu² feed-forward, the one-mixer residual block,
 the block that norms a sub-layer's output and the block whose mixers read
 one normed input side by side, a fixed scalar on a node's output, the
@@ -119,7 +119,7 @@ def kda_mixer(x, prefix, hidden_size, seq_len, heads, head_dim, rank,
 
 
 def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
-                  tied_to=None, logit_scale=1.0, init=None):
+                  tied_to=None, logit_scale=1.0, init=None, norm=None):
     """``final_norm``, the ``lm_head``, float32 logits (``lm_head_f32``)
     and each sequence's mean next-token cross-entropy behind ``MakeLoss``
     (``loss``), grouped with the layers' counts. A token's log-probability
@@ -129,15 +129,16 @@ def head_and_loss(h, label, counts, vocab_size, seq_len, rms_eps,
     ``lm_head_weight`` (drawn by ``init``), unless ``tied_to`` is the
     embedding's ``Variable``: one matrix, table and weight alike, whose
     gradient sums both uses. ``logit_scale``: a fixed scalar on the float32
-    logits (cast ``lm_head_cast`` then, scaled logits ``lm_head_f32``)."""
+    logits (cast ``lm_head_cast`` then, scaled logits ``lm_head_f32``).
+    ``norm``: the final norm where it is no ``RMSNorm`` (``head_loss``)."""
     per_sequence = head_loss(h, label, "", vocab_size, seq_len, rms_eps,
-                             tied_to, logit_scale, init)
+                             tied_to, logit_scale, init, norm=norm)
     loss = sym.MakeLoss(per_sequence, name="loss")
     return sym.Group([loss] + counts)
 
 
 def head_loss(h, label, prefix, vocab_size, seq_len, rms_eps, weight=None,
-              logit_scale=1.0, init=None, targets=None):
+              logit_scale=1.0, init=None, targets=None, norm=None):
     """One read of the head: ``<prefix>final_norm``, ``<prefix>lm_head``
     (its own ``<prefix>lm_head_weight`` drawn by ``init``, or ``weight``,
     a ``Variable`` the model made: the embedding's for a tied head, the
@@ -146,8 +147,12 @@ def head_loss(h, label, prefix, vocab_size, seq_len, rms_eps, weight=None,
     mean cross-entropy (``<prefix>lm_head_mean``) over its first
     ``targets`` positions (all of them by default: a stream that predicts
     further ahead has no label for its last ones). ``head_and_loss``'s
-    nodes, which it builds through this."""
-    normed = csym.RMSNorm(h, eps=rms_eps, name=prefix + "final_norm")
+    nodes, which it builds through this. ``norm(h, name)`` builds the final
+    norm where the model's is another (``phi4_flash``'s LayerNorm)."""
+    if norm is None:
+        normed = csym.RMSNorm(h, eps=rms_eps, name=prefix + "final_norm")
+    else:
+        normed = norm(h, prefix + "final_norm")
     if weight is None:
         logits = linear(normed, prefix + "lm_head", vocab_size, init)
     else:

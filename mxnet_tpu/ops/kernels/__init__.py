@@ -13,6 +13,8 @@ because they need explicit on-chip (VMEM) accumulation patterns.
   gmm          the expert layer's grouped matmul, and the sorted
                segment sum that is its wgrad at another shape
   ssd          the chunked state-space scan (Mamba-2)
+  sscan        the selective scan (Mamba-1): a decay a channel and a state
+               index, the [state, channels-tile] state carried in VMEM
   gdn          the gated delta rule's chunk core, its decay a head's
                (gdn_) or a key channel's (kda_, Kimi Delta Attention)
   taps         the causal taps: the short depthwise convolution over time
@@ -59,6 +61,7 @@ from .latent import (
     latent_flash, latent_flash_takes, latent_query, latent_query_takes)
 from .rope import rope_rows, rotate_heads
 from .ssd import ssd_scan, ssd_takes
+from .sscan import selective_scan, sscan_takes
 from .taps import causal_conv, taps_takes
 
 __all__ = [
@@ -71,7 +74,7 @@ __all__ = [
     "grouped_matmul", "held_transposed", "hyper_takes", "latent_flash",
     "latent_flash_takes", "latent_query",
     "latent_query_takes", "reference_attention", "rope_rows", "rotate_heads",
-    "sorted_segment_sum",
+    "selective_scan", "sorted_segment_sum", "sscan_takes",
     "ssd_scan", "ssd_takes", "stream_mix", "stream_products", "stream_read",
     "stream_write",
     "taps_takes",

@@ -5,12 +5,12 @@ plain functions, its ``OpDef``s (``_contrib_<Name>`` with the alias ``<Name>``:
 that exports them as ``mx.contrib.sym`` / ``mx.contrib.nd`` functions), their
 inference and counters, and names its kernel family:
 
-  norm       ``RMSNorm``; ``rms_norm``, ``layer_norm``
+  norm       ``RMSNorm``, ``LayerNorm``; ``rms_norm``, ``layer_norm``
   rotary     ``RoPE``; ``rope`` with its tables and its one-pass form
   taps       ``ShortConv``; the causal taps, what ``ssm`` and ``delta`` share
-  attention  ``Attention``; ``gate_output``
+  attention  ``Attention``, ``DiffAttention``; ``gate_output``
   latent     ``LatentAttention`` (both forms, the query pass), ``KeyIndexer``
-  ssm        ``Mamba2``; ``ssd_scan``
+  ssm        ``Mamba2``, ``Mamba1``; ``ssd_scan``, ``selective_scan``
   delta      ``GatedDeltaNet``; the scalar and the channel delta rule
   moe        ``TopKMoE``
   hyper      ``HyperCoeff``, ``HyperMix``; the Sinkhorn mixings
@@ -24,14 +24,14 @@ the cells that run that family, no others. Activations are ``[batch, time,
 heads * head_dim]`` between ops, ``[tokens, width]`` for experts and streams.
 """
 from . import moe  # noqa: F401  (registers TopKMoE)
-from .attention import gate_output
+from .attention import diff_attention, gate_output
 from .delta import (
     KDA_SUB_BLOCK, channel_delta_rule, gated_delta_net, gated_delta_rule)
 from .hyper import hyper_coeff, hyper_coeff_read, hyper_mix, sinkhorn
 from .latent import keep_top_k, key_indexer, latent_attention
 from .norm import rms_norm
 from .rotary import rope
-from .ssm import mamba2, ssd_scan
+from .ssm import mamba1, mamba2, selective_scan, ssd_scan
 from .sums import exit_mix, scaled_sum
 from .taps import causal_taps, gated_taps, short_conv
 
@@ -39,6 +39,7 @@ __all__ = [
     "KDA_SUB_BLOCK", "causal_taps", "channel_delta_rule", "exit_mix",
     "gate_output", "gated_delta_net", "gated_delta_rule", "gated_taps",
     "hyper_coeff", "hyper_coeff_read", "hyper_mix", "keep_top_k",
-    "key_indexer", "latent_attention", "mamba2", "rms_norm", "rope",
-    "scaled_sum", "short_conv", "sinkhorn", "ssd_scan",
+    "diff_attention", "key_indexer", "latent_attention", "mamba1", "mamba2",
+    "rms_norm", "rope", "scaled_sum", "selective_scan", "short_conv",
+    "sinkhorn", "ssd_scan",
 ]

@@ -1,6 +1,6 @@
 """The norms the families share: ``rms_norm`` (the op ``RMSNorm``, the latent's
-norm inside ``LatentAttention``) and ``layer_norm`` (``KeyIndexer``'s one key
-a token). Plain ``jax.numpy`` on every platform, no kernel family."""
+norm inside ``LatentAttention``) and ``layer_norm`` (the op ``LayerNorm``,
+``KeyIndexer``'s one key a token). Plain ``jax.numpy`` on every platform, no kernel family."""
 from __future__ import annotations
 
 import jax
@@ -49,6 +49,29 @@ register(
         defaults={"eps": 1e-5},
         infer_shape=_rms_norm_infer,
         aliases=("RMSNorm",),
+        op_class="norm",
+    )
+)
+
+
+def _layer_norm(attrs, ins, is_train):
+    data, gamma, beta = ins
+    return [layer_norm(data, gamma, beta, float(attrs.get("eps", 1e-5)))]
+
+
+def _layer_norm_infer(attrs, in_shapes):
+    data = required_shape(in_shapes[0], "LayerNorm")
+    return [data, (data[-1],), (data[-1],)], [data], []
+
+
+register(
+    OpDef(
+        "_contrib_LayerNorm",
+        _layer_norm,
+        arguments=("data", "gamma", "beta"),
+        defaults={"eps": 1e-5},
+        infer_shape=_layer_norm_infer,
+        aliases=("LayerNorm",),
         op_class="norm",
     )
 )

@@ -3,7 +3,12 @@
 scan and the gated grouped norm, one ``jax.jit`` a signature. Kernel
 families, where lowered for the TPU and the shapes have tiles:
 ``ops/kernels/taps.py``, ``ops/kernels/ssd.py`` (``ssd_scan`` here is the
-pair's einsum form and oracle) and ``ops/kernels/gate_norm.py``."""
+pair's einsum form and oracle) and ``ops/kernels/gate_norm.py``.
+``Mamba1`` (Mamba, Gu & Dao, arXiv:2312.00752): the taps, the two small
+projections, the selective scan (a decay a channel AND a state index:
+``selective_scan`` here is the ``jax.numpy`` form and oracle of
+``ops/kernels/sscan.py``) and the gate, with the scan's output before the
+gate as a second result."""
 from __future__ import annotations
 
 import functools
@@ -280,6 +285,199 @@ register(
                   "eps": 1e-5, "multipliers": None},
         infer_shape=_mamba2_infer,
         aliases=("Mamba2",),
+        op_class="ssm",
+    )
+)
+
+
+_M_SELECTIVE_LOWERINGS = _tm.counter(
+    "ssm.selective_lowerings", "Traces of a Mamba1 call site (one per "
+    "lowering, nothing per step); labels: channels, state, dt_rank, conv "
+    "(the convolution's taps), impl (kernel: the pair of "
+    "ops/kernels/sscan.py where the step is lowered for the TPU, the "
+    "jax.numpy form of the same signature elsewhere; scan: the jax.numpy "
+    "form everywhere)")
+
+SSCAN_CHUNK = 64       # tokens a step of the jax.numpy form's carried scan
+
+
+def selective_scan(x, dt, bmat, cmat, a, skip, chunk=SSCAN_CHUNK,
+                   remat=False):
+    """The selective recurrence ``S_t[c, n] = exp(dt_t[c] a[c, n])
+    S_{t-1}[c, n] + dt_t[c] x_t[c] B_t[n]``, ``y_t[c] = sum_n C_t[n]
+    S_t[c, n] + skip[c] x_t[c]`` (``S`` zero before the first token).
+    x [B, T, D], dt [B, T, D] positive, bmat and cmat [B, T, N], a [D, N]
+    negative, skip [D] -> y [B, T, D] float32, everything computed in
+    float32.
+
+    A ``lax.scan`` over chunks of ``chunk`` tokens carrying the state
+    [B, D, N]; inside a chunk the recurrence is an associative scan over
+    the pairs (decay, input), which holds the chunk's [chunk, D, N]
+    states at once and no more (the whole sequence's would be 1.3 GB at
+    4,096 tokens of 5,120 channels); under ``remat`` a chunk is computed
+    again in the backward pass and only the carried states are kept. T is
+    padded to whole chunks with ``dt`` 0 (no decay, no input). The form
+    for every platform but the TPU and for the shapes
+    ``kernels.sscan_takes`` refuses, and what the kernel pair's tests hold
+    it to."""
+    f32 = jnp.float32
+    b, t, d = x.shape
+    n = a.shape[1]
+    pad = -t % chunk
+    x32 = x.astype(f32)
+    chunks = tuple(
+        jnp.moveaxis(jnp.pad(v.astype(f32), ((0, 0), (0, pad), (0, 0)))
+                     .reshape(b, (t + pad) // chunk, chunk, v.shape[2]), 1, 0)
+        for v in (x32, dt, bmat, cmat))
+    a = a.astype(f32)
+
+    def join(left, right):
+        return left[0] * right[0], right[0] * left[1] + right[1]
+
+    def one(state, chunk_in):
+        x_c, dt_c, b_c, c_c = chunk_in
+        decay = jnp.exp(dt_c[..., None] * a)               # [B, Q, D, N]
+        wrote = (dt_c * x_c)[..., None] * b_c[:, :, None, :]
+        decayed, summed = jax.lax.associative_scan(
+            join, (decay, wrote), axis=1)
+        states = decayed * state[:, None] + summed
+        return states[:, -1], jnp.sum(states * c_c[:, :, None, :], axis=-1)
+
+    _, y = jax.lax.scan(again(one, remat), jnp.zeros((b, d, n), f32), chunks)
+    y = jnp.moveaxis(y, 0, 1).reshape(b, t + pad, d)[:, :t]
+    return y + skip.astype(f32) * x32
+
+
+def mamba1(proj, conv_weight, conv_bias, x_proj_weight, dt_proj_weight,
+           dt_bias, a_log, d_skip, remat=False):
+    """proj [B, T, 2 D] (``in_proj``'s output, ``x | z``), conv_weight
+    [taps, D] (tap ``taps - 1`` meets the current token), conv_bias [D],
+    x_proj_weight [R + 2 N, D], dt_proj_weight [D, R], dt_bias [D], a_log
+    [D, N], d_skip [D] -> (``m * silu(z)``, ``m``), both [B, T, D] in
+    proj's type: ``out_proj``'s input, and the scan's output before the
+    gate (the memory a later layer's gated unit reads).
+
+    ``x = silu(conv(x))``, the causal depthwise convolution with bias
+    (scope ``conv1d``; ``kernels.causal_conv`` where the taps' family has
+    tiles and the step is lowered for the TPU, ``causal_taps``
+    elsewhere); ``dt_low | B | C = x_proj_weight x`` (scope ``x_proj``);
+    ``dt = softplus(dt_proj_weight dt_low + dt_bias)`` (scope
+    ``dt_proj``); ``a = -exp(a_log)``; ``m = selective_scan(x, dt, B, C, a,
+    d_skip)`` (scope ``sscan``: the Pallas pair of
+    ``ops/kernels/sscan.py`` where ``kernels.sscan_takes`` has tiles and
+    the step is lowered for the TPU, the ``jax.numpy`` form elsewhere);
+    the gate (scope ``gate``). The two projections take operands of
+    proj's type and accumulate in float32; ``dt``, the decays, the state,
+    the sum over the state index and the gate are float32; ``m`` is
+    rounded to proj's type once, before the gate and the second result
+    read it. ``remat`` (training): the float32 tables of the ``jax.numpy``
+    forms are computed again in the backward pass (``again``); the kernel
+    pair keeps its operands and the states its chunks entered with.
+
+    The call site counts itself here (``ssm.selective_lowerings``,
+    ``causal_taps.lowerings``); the block is ``_mamba1_block``, one
+    ``jax.jit`` for every node of one signature."""
+    from .. import kernels
+
+    d_in, state = a_log.shape
+    kernel = bool(kernels.sscan_takes(d_in, state, proj.dtype))
+    taps_kernel = _taps_site("mamba1", proj, conv_weight, "bias_silu",
+                             channels=d_in)
+    _M_SELECTIVE_LOWERINGS.inc(
+        channels=d_in, state=state, dt_rank=dt_proj_weight.shape[1],
+        conv=conv_weight.shape[0], impl="kernel" if kernel else "scan")
+    return _mamba1_block(
+        proj, conv_weight, conv_bias, x_proj_weight, dt_proj_weight, dt_bias,
+        a_log, d_skip, remat=bool(remat), kernel=kernel,
+        taps_kernel=taps_kernel, interpret=kernels.common.INTERPRET)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "remat", "kernel", "taps_kernel", "interpret"))
+def _mamba1_block(proj, conv_weight, conv_bias, x_proj_weight,
+                  dt_proj_weight, dt_bias, a_log, d_skip, *, remat, kernel,
+                  taps_kernel, interpret):
+    """``mamba1`` for one signature."""
+    from .. import kernels
+
+    f32 = jnp.float32
+    d_in, n = a_log.shape
+    rank = dt_proj_weight.shape[1]
+
+    def conv1d(proj, conv_weight, conv_bias):
+        acc = causal_taps(proj[..., :d_in], conv_weight, conv_bias)
+        return jax.nn.silu(acc).astype(proj.dtype)
+
+    def step_sizes(low, dt_proj_weight, dt_bias):
+        dt = jnp.einsum("btr,dr->btd", low, dt_proj_weight,
+                        preferred_element_type=f32)
+        return jax.nn.softplus(dt + dt_bias.astype(f32))
+
+    def gate(m, proj):
+        return (m.astype(f32) * jax.nn.silu(proj[..., d_in:].astype(f32))
+                ).astype(proj.dtype)
+
+    with jax.named_scope("conv1d"):
+        if taps_kernel:
+            x = kernels.causal_conv(
+                proj, conv_weight, conv_bias, form="bias_silu",
+                channels=d_in, interpret=interpret)
+        else:
+            x = again(conv1d, remat)(proj, conv_weight, conv_bias)
+    with jax.named_scope("x_proj"):
+        low = jnp.einsum("btd,rd->btr", x, x_proj_weight,
+                         preferred_element_type=f32).astype(proj.dtype)
+    with jax.named_scope("dt_proj"):
+        dt = step_sizes(low[..., :rank], dt_proj_weight, dt_bias)
+    with jax.named_scope("sscan"):
+        a = -jnp.exp(a_log.astype(f32))
+        bc = low[..., rank:rank + n], low[..., rank + n:]
+        if kernel:
+            m = kernels.selective_scan(x, dt, *bc, a, d_skip,
+                                       interpret=interpret)
+        else:
+            m = selective_scan(x, dt, *bc, a, d_skip,
+                               remat=remat).astype(proj.dtype)
+    with jax.named_scope("gate"):
+        return again(gate, remat)(m, proj), m
+
+
+def _mamba1_sizes(attrs):
+    return tuple(int(attrs[k]) for k in (
+        "channels", "state_size", "dt_rank", "conv_kernel"))
+
+
+def _mamba1(attrs, ins, is_train):
+    return list(mamba1(*ins, remat=is_train))
+
+
+def _mamba1_infer(attrs, in_shapes):
+    d_in, n, rank, taps = _mamba1_sizes(attrs)
+    if min(d_in, n, rank, taps) <= 0:
+        raise ValueError(
+            "Mamba1: channels=%d, state_size=%d, dt_rank=%d and "
+            "conv_kernel=%d must be positive" % (d_in, n, rank, taps))
+    data = required_shape(in_shapes[0], "Mamba1")
+    if len(data) != 3 or data[2] != 2 * d_in:
+        raise ValueError(
+            "Mamba1: data must be [batch, time, %d] (x %d | z %d), got %s"
+            % (2 * d_in, d_in, d_in, data))
+    out = data[:2] + (d_in,)
+    return ([data, (taps, d_in), (d_in,), (rank + 2 * n, d_in),
+             (d_in, rank), (d_in,), (d_in, n), (d_in,)], [out, out], [])
+
+
+register(
+    OpDef(
+        "_contrib_Mamba1",
+        _mamba1,
+        arguments=("data", "conv_weight", "conv_bias", "x_proj_weight",
+                   "dt_proj_weight", "dt_bias", "a_log", "d"),
+        outputs=("output", "memory"),
+        defaults={"channels": 0, "state_size": 16, "dt_rank": 0,
+                  "conv_kernel": 4},
+        infer_shape=_mamba1_infer,
+        aliases=("Mamba1",),
         op_class="ssm",
     )
 )

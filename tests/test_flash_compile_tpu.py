@@ -734,6 +734,79 @@ def test_ssd_scan_kernels_compile_for_v5e(one_chip, t, heads, p, groups, n,
             name, used, limit)
 
 
+# the Phi-4-mini-flash cell's selective scan (one sequence of 4,096 tokens,
+# 5,120 channels of state 16, bf16: ten tiles of 512 channels by 32 chunks
+# of 128 tokens); a float32 caller of three tiles of 128 over a ragged
+# length
+SSCAN_SHAPES = [
+    (4096, 5120, jnp.bfloat16),
+    (1000, 384, jnp.float32),
+]
+
+
+@pytest.mark.parametrize("t,channels,dtype", SSCAN_SHAPES)
+def test_selective_scan_kernels_compile_for_v5e(one_chip, t, channels, dtype):
+    """The selective scan's forward and backward kernels at real widths:
+    Mosaic takes every slice, lane compare and reduction of both bodies
+    and a step's working set (a chunk's states among it) is under the
+    scoped VMEM the calls state."""
+    n = 16
+    assert pk.sscan_takes(channels, n, dtype)
+    chunk, width = pk.sscan.sscan_tiles(channels, n, dtype)
+
+    def spec(*shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*ins):
+        return jnp.sum(pk.selective_scan(*ins).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))).lower(
+        spec(1, t, channels), spec(1, t, channels, dtype=jnp.float32),
+        spec(1, t, n), spec(1, t, n), spec(channels, n, dtype=jnp.float32),
+        spec(channels, dtype=jnp.float32)).compile().as_text()
+    operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    for which in ("fwd", "bwd"):
+        name = "sscan_%s_%s_q%d_w%d_n%d" % (which, operands, chunk, width, n)
+        calls = [line for line in text.splitlines()
+                 if name in line and "custom-call(" in line]
+        assert len(calls) == 1, name
+        limit, used = (
+            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
+                          r'"size":"(\d+)"' % key, calls[0]).group(1))
+            for key in ("scoped_memory_configs",
+                        "used_scoped_memory_configs"))
+        assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (
+            name, used, limit)
+
+
+@pytest.mark.parametrize("window", [512, 0])
+def test_differential_attentions_two_maps_compile_at_the_cells_shape(
+        one_chip, window):
+    """40 query heads on 20 key/value heads of 64 as 20 pairs on 10 groups:
+    two flash calls with a value of 128 on a query of 64, under the window
+    of 512 and full, forward and backward."""
+    from mxnet_tpu.ops.transformer import diff_attention
+
+    t, heads, kv, d = 4096, 40, 20, 64
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def loss(*ins):
+        return jnp.sum(diff_attention(
+            *ins, num_heads=heads, num_kv_heads=kv, lambda_init=0.5,
+            window=window).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(8)))).lower(
+        spec(1, t, heads * d), spec(1, t, kv * d), spec(1, t, kv * d),
+        *(spec(d) for _ in range(4)), spec(2 * d)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "flash_" in line]
+    # two maps, each a forward and a one-pass backward
+    assert len([c for c in calls if "flash_fwd_" in c]) == 2
+    assert len([c for c in calls if "flash_bwd_" in c]) == 2, calls
+
+
 # the Olmo-Hybrid cell's delta rule (one sequence of 4,096 tokens, 30
 # heads with keys of 96 and values of 192: neither a whole number of lane
 # rows; chunks of 64, bf16, fifteen heads a step); a float32 caller whose

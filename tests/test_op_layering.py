@@ -81,7 +81,10 @@ def test_the_transformer_package_is_no_longer_than_the_file_was():
     assert not os.path.exists(os.path.join(OPS, "transformer.py"))
     total = sum(open(os.path.join(FAMILIES, m)).read().count("\n")
                 for m in _modules(FAMILIES))
-    assert total <= 2300, total
+    # 2,300 when PR 71 cut the file up; PR 73's three ops (``Mamba1`` with
+    # the selective scan's ``jax.numpy`` form, ``DiffAttention``,
+    # ``LayerNorm``) are 354 lines in ``ssm``, ``attention`` and ``norm``
+    assert total <= 2300 + 360, total
 
 
 def test_the_executor_names_no_op_above_it():
@@ -95,14 +98,15 @@ EXPORTED = {
     "dequantize", "count_sketch", "SwitchMoE",
     "RMSNorm", "RoPE", "Attention", "LatentAttention", "Mamba2", "TopKMoE",
     "GatedDeltaNet", "ShortConv", "ScaledSum", "KeyIndexer", "ExitMix",
-    "HyperCoeff", "HyperMix",
+    "HyperCoeff", "HyperMix", "Mamba1", "DiffAttention", "LayerNorm",
 }
 
 
 @pytest.mark.parametrize("namespace", ["sym", "nd"])
-def test_contrib_exports_exactly_the_26_names(namespace):
-    """What ``CONTRIB_OP_EXPORTS`` listed by hand before PR 71."""
-    assert set(contrib_op_exports()) == EXPORTED and len(EXPORTED) == 26
+def test_contrib_exports_exactly_the_29_names(namespace):
+    """What ``CONTRIB_OP_EXPORTS`` listed by hand before PR 71, and the
+    three ops PR 73 registered."""
+    assert set(contrib_op_exports()) == EXPORTED and len(EXPORTED) == 29
     space = getattr(mx.contrib, namespace)
     ops = {name for name in dir(space) if registry.exists(name)
            and callable(getattr(space, name))

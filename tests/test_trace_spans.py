@@ -643,6 +643,8 @@ REGISTERED_CLASSES = {
     "_contrib_Attention": "attn", "_contrib_RoPE": "attn",
     "_contrib_LatentAttention": "attn", "_contrib_KeyIndexer": "attn",
     "_contrib_Mamba2": "ssm", "_contrib_ExitMix": "loss",
+    "_contrib_Mamba1": "ssm", "_contrib_DiffAttention": "attn",
+    "_contrib_LayerNorm": "norm",
     "_contrib_TopKMoE": "moe", "_contrib_RMSNorm": "norm",
     "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
     "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",

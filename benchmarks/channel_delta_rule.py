@@ -18,9 +18,19 @@ time is read from a profiler trace by their names.
 Also prints how far each form's output and gradients are, on the chip,
 from the chunk form in float32 with every product at the highest precision
 (largest difference over that one's largest magnitude), and each pair's
-VMEM a step by the accounting. `--heads-a-step=1,4,16` times the fused
-pair at other head groups than its own. PERF.md section 7 holds the table
-(PR 54).
+VMEM a step by the accounting. `--heads-a-step=2,4,8,16` times the fused
+pair at other head groups than its own (1 / 2 / 4 / 8 pairs of heads a
+step). `--shape=solar` takes the Solar-Open2 cell's length (4,096 tokens,
+the same 32 heads of 128 / 128). PERF.md section 7 holds the tables (PR 54,
+PR 74).
+
+`--passes` prints the KNOCK-OUT table and nothing else: the fused pair's
+device ms a call with one part of the kernels' work taken out at a time
+(`gated_delta_rule.knocked_out`: the substitution replaced by the identity,
+every float32 product at one MXU pass instead of six, the diagonal
+sub-blocks' 8-row tiles not walked), and the pair without its prologue
+(the form `kernel`, whose kernels are `kda_*` without `_pre`). The copies
+are built in this process; the package has no such switch.
 
 `--gate-norm` prints what follows the rule instead (PR 56): the per-head
 norm and its sigmoid gate on `o` token-major as the pair wrote it
@@ -31,8 +41,10 @@ rows a loop step takes (`gate_norm._ROWS_TOKEN_MAJOR`;
 `--rows-a-step=64,128,256`), device ms by kernel name (the file's table
 is `gate_norm`).
 
-    chiprun -- python3 benchmarks/channel_delta_rule.py [--gate-norm]
-    python3 benchmarks/channel_delta_rule.py --rehearse-cpu [--gate-norm]
+    chiprun -- python3 benchmarks/channel_delta_rule.py \
+        [--gate-norm | --passes] [--shape=solar]
+    python3 benchmarks/channel_delta_rule.py --rehearse-cpu \
+        [--gate-norm | --passes]
 
 The platform rule, the clocks and the output file are `alone.py`'s.
 """
@@ -40,6 +52,7 @@ import functools
 import sys
 
 import alone
+import gated_delta_rule as scalar_rule  # its knock-outs of gdn.py
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +62,7 @@ from mxnet_tpu.ops import kernels as pk
 from mxnet_tpu.ops.transformer.delta import channel_delta_rule
 
 B, T, H, K, V, CHUNK = 1, 8192, 32, 128, 128, 64
+LENGTHS = {"kimi": 8192, "solar": 4096}   # the cells' tokens a sequence
 INPUTS = ("q", "k", "v", "a", "b")
 
 
@@ -205,9 +219,27 @@ def main():
     run = alone.Run(__file__)
     steps = [a.split("=", 1)[1] for a in sys.argv
              if a.startswith("--heads-a-step=")]
+    cell = ([a.split("=", 1)[1] for a in sys.argv
+             if a.startswith("--shape=")] or ["kimi"])[0]
+    T = LENGTHS[cell]
     if run.rehearse:
         T, H = 128, 2
-    shape = dict(b=B, t=T, heads=H, key_dim=K, value_dim=V, chunk=CHUNK)
+    shape = dict(cell=cell, b=B, t=T, heads=H, key_dim=K, value_dim=V,
+                 chunk=CHUNK)
+    if "--passes" in sys.argv:
+        args, cot = inputs(1, jnp.bfloat16, T)
+
+        def kernels_ms(form):
+            return alone.by_kernel(
+                run.device_ops(forms()[form][1], cot, *args), "kda_")
+
+        for part in ("whole", "substitution", "float32_products_one_pass",
+                     "diagonal_tiles"):
+            with scalar_rule.knocked_out(part):
+                run.row(knocked_out=part,
+                        kernels_device_ms=kernels_ms("fused"))
+        run.row(knocked_out="prologue", kernels_device_ms=kernels_ms("kernel"))
+        return run.save("passes_" + cell, shape=shape)
     if "--gate-norm" in sys.argv:
         gate_norm_table(run, [
             int(r) for a in sys.argv if a.startswith("--rows-a-step=")
@@ -252,16 +284,15 @@ def main():
 
     for per in [int(p) for s in steps for p in s.split(",") if p]:
         own, pk.gdn.KDA_HEADS_A_STEP = pk.gdn.KDA_HEADS_A_STEP, per
-        for f in (pk.gdn.kda_fwd_call, pk.gdn.kda_bwd_call,
-                  pk.gdn.kda_net_forward):
-            f.clear_cache()
+        scalar_rule.clear_traces()
         f, g = forms()["fused"]
         run.row(heads_a_step=pk.gdn.kda_group(H),
                 fwd_ms=run.host_ms(f, *args, reps=10),
                 fwd_bwd_ms=run.host_ms(g, cot, *args, reps=10),
                 kernels_device_ms=kernels_ms(g))
         pk.gdn.KDA_HEADS_A_STEP = own
-    run.save(shape=shape)
+        scalar_rule.clear_traces()
+    run.save(None if cell == "kimi" else cell, shape=shape)
 
 
 if __name__ == "__main__":

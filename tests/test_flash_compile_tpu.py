@@ -809,13 +809,26 @@ def test_differential_attentions_two_maps_compile_at_the_cells_shape(
 
 # the Olmo-Hybrid cell's delta rule (one sequence of 4,096 tokens, 30
 # heads with keys of 96 and values of 192: neither a whole number of lane
-# rows; chunks of 64, bf16, fifteen heads a step); a float32 caller whose
-# heads are whole lane rows, seven heads a step, chunks of 128 over a
-# ragged length
+# rows; chunks of 64, bf16, ten heads a step, five pairs); a float32
+# caller whose heads are whole lane rows, seven heads a step (three pairs
+# and a head beside zeros, its outputs behind a ``pl.when``), chunks of
+# 128 over a ragged length (a pair's tables two lane rows wide)
 GDN_SHAPES = [
     (4096, 30, 96, 192, 64, jnp.bfloat16),
     (300, 7, 128, 128, 128, jnp.float32),
 ]
+
+
+def _scoped_vmem(text, name):
+    """(the limit a kernel's call states, what Mosaic says it uses) of the
+    one custom call named ``name`` in a compiled program's text."""
+    calls = [line for line in text.splitlines()
+             if name in line.split(" = ")[0] and "custom-call(" in line]
+    assert len(calls) == 1, name
+    return tuple(
+        int(re.search(r'"%s":\[\{"memory_space":"1","offset":"0",'
+                      r'"size":"(\d+)"' % key, calls[0]).group(1))
+        for key in ("scoped_memory_configs", "used_scoped_memory_configs"))
 
 
 @pytest.mark.parametrize("t,heads,dk,dv,chunk,dtype", GDN_SHAPES)
@@ -839,18 +852,67 @@ def test_gated_delta_rule_kernels_compile_for_v5e(one_chip, t, heads, dk, dv,
         spec(1, t, heads, dtype=jnp.float32),
         spec(1, t, heads, dtype=jnp.float32)).compile().as_text()
     operands = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    stated = pk.gdn.gdn_vmem_bytes(chunk, pk.gdn.gdn_group(heads), dk, dv,
+                                   jnp.dtype(dtype).itemsize)
     for which in ("fwd", "bwd"):
         name = "gdn_%s_%s_c%d_k%d_v%d" % (which, operands, chunk, dk, dv)
-        calls = [line for line in text.splitlines()
-                 if name in line and "custom-call(" in line]
-        assert len(calls) == 1, name
-        limit, used = (
-            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"0",'
-                          r'"size":"(\d+)"' % key, calls[0]).group(1))
-            for key in ("scoped_memory_configs",
-                        "used_scoped_memory_configs"))
+        limit, used = _scoped_vmem(text, name)
         assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (
             name, used, limit)
+        assert used <= stated, (name, used, stated)
+
+
+# the Kimi Linear cell's channel rule (8,192 tokens) and Solar-Open2's
+# (4,096), both 32 heads of 128 / 128 in chunks of 64, bf16, four pairs a
+# step, the prologue made inside; three float32 heads (a pair and a head
+# beside zeros) without it
+KDA_SHAPES = {
+    "kimi_linear": (8192, 32, jnp.bfloat16, True),
+    "solar_open2": (4096, 32, jnp.bfloat16, True),
+    "three_heads": (200, 3, jnp.float32, False),
+}
+
+
+@pytest.mark.parametrize("cell", list(KDA_SHAPES))
+def test_channel_delta_rule_kernels_compile_for_v5e(one_chip, cell):
+    """The channel pair alone at the cells' shapes: Mosaic takes every
+    slice, select and product of the two bodies a PAIR of heads wide (the
+    tables side by side in whole lane rows, the substitution's two column
+    reads a tile, the stacked rows against the block diagonals) and a
+    step's working set is under what ``kda_vmem_bytes`` states."""
+    t, heads, dtype, fused = KDA_SHAPES[cell]
+    d, chunk = 128, 64
+    assert pk.gdn_takes(heads, d, d, chunk, dtype, "channel")
+
+    def spec(*shape, dtype=dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    f32 = jnp.float32
+    wide = spec(1, t, heads * d)
+    if fused:
+        def loss(*ins):
+            return jnp.sum(pk.channel_delta_net(*ins, chunk))
+        ins = (wide,) * 4 + (spec(1, t, heads, dtype=f32),
+                             spec(heads, dtype=f32),
+                             spec(heads * d, dtype=f32))
+    else:
+        def loss(*ins):
+            return jnp.sum(pk.gdn.channel_delta_rule(*ins, chunk))
+        by_head = spec(1, t, heads, d)
+        ins = (by_head,) * 3 + (spec(1, t, heads, d, dtype=f32),
+                                spec(1, t, heads, dtype=f32))
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(len(ins))))).lower(
+        *ins).compile().as_text()
+    stated = pk.gdn.kda_vmem_bytes(chunk, pk.gdn.kda_group(heads), heads, d,
+                                   d, jnp.dtype(dtype).itemsize)
+    for which in ("fwd", "bwd"):
+        name = "kda_%s_%s_c%d_k%d_v%d%s" % (
+            which, {"bfloat16": "bf16", "float32": "f32"}[
+                jnp.dtype(dtype).name], chunk, d, d, "_pre" * fused)
+        limit, used = _scoped_vmem(text, name)
+        assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (
+            name, used, limit)
+        assert used <= stated, (name, used, stated)
 
 
 # the three cells' convolved arrays: Nemotron's Mamba-2 window (columns

@@ -112,15 +112,19 @@ def _both_ways(form, chunk):
     return run
 
 
-# (batch, T, heads, K, V, chunk): a ragged last chunk of 16; the cell's
-# head (keys not a lane row, values one and a half) on three heads in
-# chunks of 64, ragged; eighteen heads, so two groups of nine a step; a
-# chunk of a whole lane row
+# (batch, T, heads, K, V, chunk): a ragged last chunk of 16 (three heads:
+# a pair and a head beside zeros); the cell's head (keys not a lane row,
+# values one and a half) on three heads in chunks of 64, ragged; eighteen
+# heads, so three groups of six a step; a chunk of a whole lane row (two
+# heads: one pair); fifteen heads, which have no even group (seven pairs
+# and a head beside zeros a step); ten, five pairs a step
 SHAPES = {
     "ragged_small": (2, 70, 3, 32, 64, 16),
     "the_cells_head": (1, 130, 3, 96, 192, 64),
     "two_head_groups": (2, 48, 18, 32, 32, 16),
     "a_lane_row_of_tokens": (1, 150, 2, 32, 32, 128),
+    "fifteen_heads": (1, 40, 15, 32, 32, 16),
+    "ten_heads": (1, 40, 10, 32, 32, 16),
 }
 
 
@@ -236,7 +240,7 @@ def test_the_kernels_take_the_cells_shape_and_refuse_what_has_no_tiles():
     assert take(30, 96, 192, 64, jnp.float32)
     assert take(3, 32, 64, 16, jnp.float32)
     assert take(16, 128, 128, 128, jnp.bfloat16)
-    assert pk.gdn.gdn_group(30) == 15 and pk.gdn.gdn_group(34) == 2
+    assert pk.gdn.gdn_group(30) == 10 and pk.gdn.gdn_group(34) == 2
     for heads, dk, dv, chunk, dtype in [
             (3, 8, 16, 8, jnp.float32),          # the tiny symbol's
             (30, 8, 16, 64, jnp.float32),
@@ -249,6 +253,57 @@ def test_the_kernels_take_the_cells_shape_and_refuse_what_has_no_tiles():
         assert not take(heads, dk, dv, chunk, dtype), (heads, dk, dv, chunk)
     assert (pk.gdn.gdn_vmem_bytes(128, 6, 2048, 4096, 4)
             > pk.common.VMEM_RAISED_LIMIT)
+
+
+def _one_head(form, seed, t, dk, dv, key_norm=1.0, decay=1.0):
+    """One head's inputs and cotangent [1, T, 1, .], its keys ``key_norm``
+    long and its log decays ``decay`` times ``_inputs``'s."""
+    make = _channel_inputs if form == "channel_kernels" else _inputs
+    (q, k, v, g, beta), cot = make(seed, 1, t, 1, dk, dv)
+    return (q, k * key_norm, v, g * decay, beta), cot
+
+
+@pytest.mark.parametrize("form,dk,dv", [("kernels", 32, 64),
+                                        ("channel_kernels", 128, 128)])
+def test_nothing_crosses_between_the_two_heads_of_a_trip(form, dk, dv):
+    """A PAIR OF HEADS A TRIP SHARES LANE ROWS AND MXU PASSES AND NOTHING
+    ELSE. A head beside one whose keys are ten times as long and which
+    forgets twenty times as fast, beside one whose keys are a hundredth
+    and which forgets nothing, beside a head of zeros, in the pair's first
+    half and in its second, and alone (the group of one head: the body's
+    own zeros beside it): its output and every gradient are the same BIT
+    FOR BIT, and the chunk form's of that head alone at the file's
+    tolerances."""
+    chunk, t = 16, 40
+    run = _both_ways(form, chunk)
+    mine, cot = _one_head(form, 11, t, dk, dv)
+    others = [_one_head(form, 12, t, dk, dv, key_norm=10.0, decay=20.0),
+              _one_head(form, 13, t, dk, dv, key_norm=0.01, decay=1e-4),
+              (tuple(jnp.zeros_like(x) for x in mine), jnp.zeros_like(cot))]
+
+    def beside(other, first):
+        """The head's output and gradients from a step of two heads."""
+        def two(a, b):
+            return jnp.concatenate((a, b) if first else (b, a), axis=2)
+
+        o, grads = run(tuple(two(a, b) for a, b in zip(mine, other[0])),
+                       two(cot, other[1]))
+        at = 0 if first else 1
+        return [np.asarray(x[:, :, at:at + 1]) for x in (o,) + tuple(grads)]
+
+    o, grads = run(mine, cot)
+    alone = [np.asarray(x) for x in (o,) + tuple(grads)]
+    for first in (True, False):
+        seen = [beside(other, first) for other in others]
+        for got in seen[1:] + ([alone] if first else []):
+            for name, a, b in zip(("o",) + GRADS, seen[0], got):
+                np.testing.assert_array_equal(a, b, name)
+        assert np.isfinite(seen[0][0]).all()
+    o_w, grads_w = _both_ways(form.replace("kernels", "chunked"), chunk)(
+        mine, cot)
+    _close(alone[0], o_w, "o", ulps=32)
+    for name, got, want in zip(GRADS, alone[1:], grads_w):
+        _close(got, want, name, ulps=128)
 
 
 # -- the op at a shape the kernels take ---------------------------------------
@@ -428,7 +483,9 @@ def test_a_fit_of_three_linear_layers_traces_each_kernel_once():
                            conv=4, impl="kernel") == 3
         assert telemetry.total("linear_attn.lowerings") == 3
         traces = telemetry.REGISTRY.get("linear_attn.kernel_traces")
-        assert (traces.value(mode="fwd"), traces.value(mode="bwd")) == (1, 1)
+        assert [traces.value(mode=mode, heads_a_trip=2)
+                for mode in ("fwd", "bwd")] == [1, 1]
+        assert telemetry.total("linear_attn.kernel_traces") == 2
         # 32 tokens: no row tile of the gate and norm's kernel divides them
         norm = telemetry.REGISTRY.get("gate_norm.lowerings")
         assert norm.value(site="gated_delta_net", groups=2, width=32,
@@ -438,6 +495,92 @@ def test_a_fit_of_three_linear_layers_traces_each_kernel_once():
         telemetry.disable()
         telemetry.reset()
     assert np.isfinite(seen).all() and seen[-1] < seen[0], seen
+
+
+def _kimi_linear(t):
+    from mxnet_tpu.models import kimi_linear
+    return kimi_linear.from_config(dict(
+        model_type="kimi_linear", hidden_size=48, num_hidden_layers=2,
+        first_k_dense_replace=1, moe_layer_freq=1,
+        linear_attn_config=dict(kda_layers=[1, 2], full_attn_layers=[],
+                                num_heads=2, head_dim=128,
+                                short_conv_kernel_size=4),
+        num_attention_heads=4, num_key_value_heads=4, head_dim=12,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        kv_lora_rank=32, q_lora_rank=None, mla_use_nope=True,
+        rope_theta=10000, rope_scaling=None, intermediate_size=96,
+        moe_intermediate_size=32, num_experts=4, num_shared_experts=1,
+        num_experts_per_token=2, moe_renormalize=True,
+        moe_router_activation_func="sigmoid", num_expert_group=1,
+        topk_group=1, use_grouped_topk=True, routed_scaling_factor=2.446,
+        rms_norm_eps=1e-5, vocab_size=256, hidden_act="silu",
+        tie_word_embeddings=False, num_nextn_predict_layers=0,
+        model_max_length=t), seq_len=t, chunk_size=CHUNK)
+
+
+def _solar_open2(t):
+    from mxnet_tpu.models import solar_open2
+    return solar_open2.from_config(dict(
+        model_type="solar_open2", hidden_size=48, num_hidden_layers=3,
+        first_k_dense_replace=0, gqa_interval=3, gqa_layers=[0],
+        linear_attn_config=dict(num_heads=2, head_dim=128, num_kv_heads=None,
+                                short_conv_kernel_size=4),
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        use_rope=False, use_gqa_gate=True, kda_use_full_proj=False,
+        kda_allow_neg_eigval=True, partial_rotary_factor=1, rope_theta=10000,
+        intermediate_size=96, moe_intermediate_size=32, n_routed_experts=4,
+        n_shared_experts=1, num_experts_per_tok=2, norm_topk_prob=True,
+        routed_scaling_factor=1, rms_norm_eps=1e-5, vocab_size=256,
+        tie_word_embeddings=False, max_position_embeddings=t),
+        seq_len=t, chunk_size=CHUNK)
+
+
+@pytest.mark.parametrize("model", [_kimi_linear, _solar_open2],
+                         ids=["kimi_linear", "solar_open2"])
+def test_a_fit_of_a_channel_model_says_its_body_takes_two_heads_a_trip(model):
+    """``Module.fit`` of two KDA layers of Kimi Linear's and of
+    Solar-Open2's symbol at heads the channel pair takes (two of 128 /
+    128): both call sites count themselves ``impl="kernel",
+    decay="channel"``, ``kda_fwd_`` and ``kda_bwd_`` are traced once each
+    and ``linear_attn.kernel_traces`` says which body that was:
+    ``heads_a_trip=2`` (Olmo-Hybrid's fit is the test above)."""
+    t, steps = 32, 3
+    sym = model(t)
+    tokens = np.random.RandomState(5).randint(0, 256, (1, t + 1))
+    for jitted in (delta._channel_delta_block, pk.gdn.kda_fwd_call,
+                   pk.gdn.kda_bwd_call, pk.gdn.kda_net_forward):
+        jitted.clear_cache()    # another test's trace is not this one's
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        seen = []
+        mx.random.seed(4)
+        np.random.seed(4)
+        mod = mx.mod.Module(sym, context=mx.cpu(0), mesh=make_mesh(dp=1))
+        mod.fit(mx.io.NDArrayIter(
+            np.tile(tokens[:, :-1].astype(np.float32), (steps, 1)),
+            np.tile(tokens[:, 1:].astype(np.float32), (steps, 1)),
+            batch_size=1),
+            num_epoch=1, eval_metric="loss", optimizer="sgd",
+            optimizer_params={"learning_rate": 0.05, "momentum": 0.9},
+            kvstore="device", initializer=mx.init.Normal(sigma=0.05),
+            batch_end_callback=lambda b: (
+                seen.append(b.eval_metric.get()[1]),
+                b.eval_metric.reset()))
+        assert mod._fused_trainer is not None
+        sites = telemetry.REGISTRY.get("linear_attn.lowerings")
+        assert sum(v for k, v in sites._values.items()
+                   if ("impl", "kernel") in k
+                   and ("decay", "channel") in k) == 2
+        assert telemetry.total("linear_attn.lowerings") == 2
+        traces = telemetry.REGISTRY.get("linear_attn.kernel_traces")
+        assert [traces.value(mode=mode, heads_a_trip=2)
+                for mode in ("fwd", "bwd")] == [1, 1]
+        assert telemetry.total("linear_attn.kernel_traces") == 2
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    assert np.isfinite(seen).all(), seen
 
 
 # -- a decay a channel (Kimi Delta Attention): the pair kda_fwd_ / kda_bwd_ ---
@@ -458,13 +601,16 @@ def _channel_both_ways(form, chunk):
 
 
 # (batch, T, heads, K, V, chunk): the Kimi Linear cell's head (a lane row
-# of keys, one of values) in chunks of 64 over a ragged length; ten heads,
-# so two groups of five a step, in chunks of one sub-block; values of two
-# lane rows in chunks of two sub-blocks, ragged
+# of keys, one of values) in chunks of 64 over a ragged length (three
+# heads: a pair and a head beside zeros); ten heads, so five groups of a
+# pair a step, in chunks of one sub-block; values of two lane rows in
+# chunks of two sub-blocks, ragged (two heads: one pair); fifteen heads,
+# three groups of two pairs and a head beside zeros
 CHANNEL_SHAPES = {
     "the_cells_head_ragged": (1, 130, 3, 128, 128, 64),
     "two_head_groups": (2, 32, 10, 128, 128, 16),
     "values_of_two_lane_rows": (1, 70, 2, 128, 256, 32),
+    "fifteen_heads": (1, 24, 15, 128, 128, 16),
 }
 
 
@@ -572,7 +718,7 @@ def test_the_channel_pair_takes_the_kimi_cells_shape_and_refuses_the_rest():
     assert take(32, 128, 128, 64, jnp.float32)
     assert take(3, 128, 256, 16, jnp.float32)
     assert take(16, 256, 128, 128, jnp.bfloat16)
-    assert pk.gdn.kda_group(32) == 8 and pk.gdn.kda_group(10) == 5
+    assert pk.gdn.kda_group(32) == 8 and pk.gdn.kda_group(10) == 2
     for heads, dk, dv, chunk, dtype in [
             (3, 8, 12, 16, jnp.float32),         # the tiny symbol's
             (32, 96, 192, 64, jnp.bfloat16),     # a head no whole lane rows

@@ -50,7 +50,11 @@ looped language model: a Llama-shaped stack under four norms a block run
 four times over ONE set of weights, the final norm, an exit gate and the
 head after every pass, the exit-weighted loss; every weight one
 ``Variable`` read at four depths), whole or as a pipeline stage, with
-``ouro_reference``; ``lm_blocks`` holds what the LM symbols share.
+``ouro_reference``. ``keye_vl2`` is Keye-VL-2.0-30B-A3B's language model
+(grouped attention under per-head norms that reads only the 2,048 keys a
+16-head ``KeyIndexer`` picks, in every layer, over 128 softmax-routed
+experts), whole or as a share, with ``keye_vl2_reference``; ``lm_blocks``
+holds what the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -66,7 +70,8 @@ from .lstm import lstm_unroll, BucketingLSTMModel
 from .transformer import transformer_lm
 from . import (afmoe, afmoe_reference, dots3, dots3_reference, falcon_h1,
                falcon_h1_reference, kanana2, kanana2_reference, kimi_linear,
-               kimi_linear_reference, lfm2, lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
+               keye_vl2, keye_vl2_reference, kimi_linear_reference, lfm2,
+               lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
                nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
                olmoe, olmoe_reference, ouro, ouro_reference, solar_open2,
                solar_open2_reference)

@@ -6,8 +6,10 @@ equivalent is Pallas: kernels that XLA cannot produce from jnp alone
 because they need explicit on-chip (VMEM) accumulation patterns.
 
   flash        single-key flash attention (forward, dq / dkv, the
-               one-pass backward), ``attention`` (the ops' one dispatch)
-               and ``reference_attention`` (the oracle)
+               one-pass backward), its selected pair under a keep-mask
+               (``flash_select``), ``attention`` (the ops' one dispatch)
+               and ``reference_attention`` / ``kept_attention`` (the
+               oracles)
   latent       latent attention's two-key flash pair and the pass over
                its query
   gmm          the expert layer's grouped matmul, and the sorted
@@ -49,7 +51,8 @@ from . import common
 from .conv import (
     conv_bwd_filter, conv_bwd_input, conv_bwd_plan, conv_kernel_enabled)
 from .flash import (
-    attention, flash_attention, flash_tiles, reference_attention)
+    attention, flash_attention, flash_select, flash_select_takes,
+    flash_tiles, kept_attention, reference_attention)
 from .gate_norm import gate_norm_takes, gated_rms_norm
 from .gdn import channel_delta_net, gated_delta_rule, gdn_takes
 from .gmm import (
@@ -68,10 +71,11 @@ __all__ = [
     "attention", "causal_conv", "channel_delta_net", "common",
     "conv_bwd_filter", "conv_bwd_input",
     "conv_bwd_plan", "conv_kernel_enabled", "flash_attention",
-    "flash_tiles", "gate_norm_takes",
+    "flash_select", "flash_select_takes", "flash_tiles", "gate_norm_takes",
     "gated_delta_rule", "gated_rms_norm", "gdn_takes",
     "gmm_metadata", "gmm_row_tile", "gmm_runs_kernel", "gmm_tiles",
-    "grouped_matmul", "held_transposed", "hyper_takes", "latent_flash",
+    "grouped_matmul", "held_transposed", "hyper_takes", "kept_attention",
+    "latent_flash",
     "latent_flash_takes", "latent_query",
     "latent_query_takes", "reference_attention", "rope_rows", "rotate_heads",
     "selective_scan", "sorted_segment_sum", "sscan_takes",

@@ -53,6 +53,15 @@ places, all below:
             no value: applied to the forward kernel's (out, lse) by
             ``_apply_sink`` outside it; the backward kernels rebuild P
             from the lse that holds it and need nothing else.
+  select    attention that is told its keys (``flash_select``: an int8
+            keep-mask [B, T, T], 0 drops the pair) is a pair of its own,
+            ``flashsel_fwd_`` / ``flashsel_bwd_``: a q tile holds the
+            SAME ``rows`` positions of every query head of a key/value
+            head's group one under another, so a step's one K / V tile
+            and one [rows, block_k] mask tile serve the whole group, and
+            dK / dV sum over the group inside the products. Every live
+            causal tile is computed and masked: a scattered selection
+            leaves no tile to skip.
 
 forward / dq / the one-pass backward: grid (B*H, nq, nk), k innermost;
 dkv: grid (B*G, nk, group * nq).
@@ -115,13 +124,17 @@ FLASH_MIN_BLOCK = 128
 FLASH_MIN_EDGE = 512
 
 
-def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None):
+def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None,
+                     select_rows=0):
     """Upper bound on the VMEM one grid step of the widest kernel holds:
     double-buffered operand and result tiles, the float32 accumulators
     and two float32 score-shaped temporaries, as dkv has them; with
     ``resident`` = (t_pad, d, dv), plus what the one-pass backward keeps
     for a whole key/value head: dK and dV in float32 scratch and their
-    double-buffered output blocks, and a third score-shaped temporary.
+    double-buffered output blocks, and a third score-shaped temporary;
+    with ``select_rows`` (the selected pair: a head's rows of a q tile),
+    plus the keep-mask's two int8 tile buffers of [select_rows, block_k]
+    and the tile widened to 32 bits over the whole group's rows.
     Against the smallest limit Mosaic compiles each shape under (v5e,
     1-2 MiB steps), counted / needed:
 
@@ -135,7 +148,11 @@ def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None):
     one pass, T 4096, 192 / 128, bf16, 8 heads on 1 (MiMo)  32       18
     one pass, the same, 256 x 256 under a window of 128     14.75    9
     one pass, T 4096, 128 / 128, float32                    31       27
+    selected one pass, T 8192, 128 / 128, bf16, 8 on 1     36.25    (*)
     ====================================================  =======  ======
+
+    (*) compiled for a described v5e under its own count
+    (``tests/test_flash_compile_tpu.py``).
     """
     lanes = whole_lanes(d)
     row_tiles = 2 * 2 * block_q * lanes * itemsize      # q, dO
@@ -143,6 +160,8 @@ def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None):
     acc = 2 * block_k * lanes * 4
     scores = 2 * block_q * block_k * 4
     step = row_tiles + col_tiles + acc + scores
+    if select_rows:
+        step += 2 * select_rows * block_k + 4 * block_q * block_k
     if resident is None:
         return step
     t_pad, d, dv = resident
@@ -150,13 +169,14 @@ def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None):
             + t_pad * (whole_lanes(d) + whole_lanes(dv)) * (4 + 2 * itemsize))
 
 
-def bwd_fuses(t_pad, block_q, block_k, d, dv, dtype):
+def bwd_fuses(t_pad, block_q, block_k, d, dv, dtype, select_rows=0):
     """Whether the backward of a call runs as one pass: decided by the
     call's shapes and operand type alone, through what the pass would
     hold in VMEM."""
     return flash_vmem_bytes(
         block_q, block_k, max(d, dv), jnp.dtype(dtype).itemsize,
-        resident=(t_pad, d, dv)) <= VMEM_RAISED_LIMIT
+        resident=(t_pad, d, dv),
+        select_rows=select_rows) <= VMEM_RAISED_LIMIT
 
 
 def _one_tile(t):
@@ -1084,6 +1104,442 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                  bool(interpret))
     out = out[:, :t]
     return out.reshape(b, h, t, dv).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# the selected pair: causal attention over the keys a keep-mask names
+# ---------------------------------------------------------------------------
+
+_M_SELECT_TRACES = _tm.counter(
+    "attention.select_kernel_traces", "Traces of a selected flash kernel's "
+    "pallas_call (one a signature and process, however many Attention "
+    "nodes call it; nothing per step); labels: pass (fwd / bwd), group "
+    "(query heads a key/value head), rows (a head's rows of a q tile)")
+
+# the fewest rows of an int8 tile (its sublanes pack by 32)
+_KEEP_MIN_ROWS = 32
+
+
+def select_tiles(t, group, d, dv, dtype):
+    """(rows, block_k, t_pad) of the selected pair for ``t`` positions and
+    ``group`` query heads a key/value head, or None where it has no tiles
+    for the shapes: ``flash_tiles``' q tile is shared out among the
+    group's heads, ``rows`` = block_q / group positions each (whole int8
+    tiles of the mask), against ``flash_tiles``' k tile; T a tile at
+    least, an operand type Mosaic takes, and a key/value head's dK / dV
+    resident for the one-pass backward (the only one there is)."""
+    if (t < FLASH_MIN_BLOCK
+            or jnp.dtype(dtype).name not in ("bfloat16", "float32")):
+        return None
+    block_q, block_k = flash_tiles(t, max(d, dv), dtype)
+    rows = block_q // group
+    if block_q % group or rows % _KEEP_MIN_ROWS:
+        return None
+    mult = int(np.lcm(rows, block_k))
+    t_pad = -(-t // mult) * mult
+    if not bwd_fuses(t_pad, block_q, block_k, d, dv, dtype,
+                     select_rows=rows):
+        return None
+    return rows, block_k, t_pad
+
+
+def flash_select_takes(t, heads, kv_heads, d, dv, dtype):
+    """Whether ``flash_select`` has kernels for the shapes (``Attention``
+    asks; shapes and operand type alone decide)."""
+    return (heads % kv_heads == 0
+            and select_tiles(t, heads // kv_heads, d, dv, dtype) is not None)
+
+
+def _select_mask(keep_ref, qi, ki, *, rows, group, block_k):
+    """The pairs of a tile that are live, [group * rows, block_k] bool:
+    the keep-mask's tile (int8, 0 drops the pair) under the causal
+    compare of its positions, once for the ``rows`` positions and laid
+    under itself for each of the group's heads."""
+    shape = (rows, block_k)
+    q_pos = jax.lax.add(jax.lax.broadcasted_iota(jnp.int32, shape, 0),
+                        affine(qi, rows))
+    k_pos = jax.lax.add(jax.lax.broadcasted_iota(jnp.int32, shape, 1),
+                        affine(ki, block_k))
+    kept = jnp.where(jax.lax.ge(q_pos, k_pos),
+                     keep_ref[0].astype(jnp.int32), np.int32(0))
+    if group > 1:
+        kept = jax.lax.concatenate([kept] * group, 0)
+    return jax.lax.ne(kept, np.int32(0))
+
+
+def _select_live(qi, ki, rows, block_k):
+    """Whether k tile ki holds a key at or before q tile qi's last
+    position."""
+    return jax.lax.le(affine(ki, block_k), affine(qi, rows, rows - 1))
+
+
+def _select_fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, l_ref, acc,
+                       m_s, l_s, *, rows, group, block_k, scale):
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+
+    @pl.when(ki == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, jnp.float32(NEG_INF))
+        l_s[...] = jnp.zeros_like(l_s)
+
+    @pl.when(_select_live(qi, ki, rows, block_k))
+    def _():
+        v_blk = v_ref[0]
+        s = jnp.float32(scale) * jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        mask = _select_mask(keep_ref, qi, ki, rows=rows, group=group,
+                            block_k=block_k)
+        s = jnp.where(mask, s, jnp.float32(NEG_INF))
+        m_prev = m_s[...]
+        m_cur = jax.lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jax.lax.exp(m_prev - m_cur)
+        # a row may keep no key of a tile before its first kept one: its
+        # maximum is still NEG_INF there and exp(0) is no weight
+        p = jnp.where(mask, jax.lax.exp(s - m_cur), jnp.float32(0.0))
+        l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_s[...] = m_cur
+        acc[...] = acc[...] * alpha + jax.lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _():
+        l_fin = l_s[...]
+        safe_l = jnp.where(l_fin > 0, l_fin, jnp.float32(1.0))
+        o_ref[0] = (acc[...] / safe_l).astype(o_ref.dtype)
+        l_ref[0] = m_s[...] + jnp.log(safe_l)
+
+
+def _select_bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, l_ref, d_ref,
+                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                       rows, group, block_k, scale):
+    """dq, dk and dv in one pass, as ``_bwd_fused_kernel`` makes them: a q
+    tile's k tiles from the diagonal down, dq in tile-sized scratch over
+    the inner steps, the key/value head's dK and dV whole in float32
+    scratch over its q tiles. A q tile's rows are those of every head of
+    the group, so the two products into dK and dV sum over the group."""
+    qi = pl.program_id(1)
+    j = pl.program_id(2)
+    ki = jax.lax.sub(pl.num_programs(2) - 1, j)
+    first = jax.lax.bitwise_and(jax.lax.eq(qi, np.int32(0)),
+                                jax.lax.eq(j, np.int32(0)))
+    last = jax.lax.bitwise_and(
+        jax.lax.eq(qi, pl.num_programs(1) - 1),
+        jax.lax.eq(j, pl.num_programs(2) - 1))
+
+    def each_k_tile(fn):
+        def step(i, carry):
+            fn(i)
+            return carry
+        jax.lax.fori_loop(0, dk_acc.shape[0], step, 0)
+
+    @pl.when(first)
+    def _():
+        def zero(i):
+            dk_acc[i] = jnp.zeros(dk_acc.shape[1:], jnp.float32)
+            dv_acc[i] = jnp.zeros(dv_acc.shape[1:], jnp.float32)
+        each_k_tile(zero)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(_select_live(qi, ki, rows, block_k))
+    def _():
+        q, k_blk, do = q_ref[0], k_ref[0], do_ref[0]
+        s = jnp.float32(scale) * jax.lax.dot_general(
+            q, k_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        mask = _select_mask(keep_ref, qi, ki, rows=rows, group=group,
+                            block_k=block_k)
+        p = jnp.where(mask, jax.lax.exp(s - l_ref[0]), jnp.float32(0.0))
+        dp = jax.lax.dot_general(
+            do, v_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - d_ref[0])).astype(q.dtype)
+        dv_acc[ki] = dv_acc[ki] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[ki] = dk_acc[ki] + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+            ds, k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = (jnp.float32(scale) * dq_acc[...]).astype(dq_ref.dtype)
+
+    @pl.when(last)
+    def _():
+        def write(i):
+            dk_ref[0, i] = (jnp.float32(scale) * dk_acc[i]).astype(
+                dk_ref.dtype)
+            dv_ref[0, i] = dv_acc[i].astype(dv_ref.dtype)
+        each_k_tile(write)
+
+
+def _select_name(which, dtype, block_q, block_k, group):
+    return "flashsel_%s_%s_q%d_k%d_g%d" % (
+        which, operand_label(dtype), block_q, block_k, group)
+
+
+def _select_specs(rows, group, block_k, d, dv, kv_heads, steps=0):
+    """Block specs of (a q-shaped tile, a k tile, their value-width twins,
+    a statistic a row, the keep-mask's tile) at grid step (batch x
+    key/value head, q tile, k step); with ``steps`` the inner steps walk a
+    q tile's k tiles downwards. A dead step names the q tile's last live k
+    tile, already resident, and fetches nothing."""
+    block_q = rows * group
+
+    def k_tile(i, j):
+        if steps:
+            j = jax.lax.sub(np.int32(steps - 1), j)
+        return jax.lax.min(j, jax.lax.div(affine(i, rows, rows - 1),
+                                          np.int32(block_k)))
+
+    def q_idx(b, i, j):
+        return (b, i, 0)
+
+    def k_idx(b, i, j):
+        return (b, k_tile(i, j), 0)
+
+    def keep_idx(b, i, j):
+        return (jax.lax.div(b, np.int32(kv_heads)), i, k_tile(i, j))
+
+    return (pl.BlockSpec((1, block_q, d), q_idx),
+            pl.BlockSpec((1, block_k, d), k_idx),
+            pl.BlockSpec((1, block_q, dv), q_idx),
+            pl.BlockSpec((1, block_k, dv), k_idx),
+            pl.BlockSpec((1, block_q, 1), q_idx),
+            pl.BlockSpec((1, rows, block_k), keep_idx))
+
+
+_SELECT_STATIC = ("rows", "group", "block_k", "scale", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_SELECT_STATIC)
+def select_fwd_call(q3, k3, v3, keep, *, rows, group, block_k, scale,
+                    interpret):
+    """q3 [B G, group T, D] (``_group_rows``), k3 [B G, T, D], v3 [B G, T,
+    Dv], keep [B, T, T] int8 -> o [B G, group T, Dv] and lse [B G, group
+    T, 1] float32, rows as q3's."""
+    _M_SELECT_TRACES.inc(**{"pass": "fwd"}, group=group, rows=rows)
+    bg, t_pad, d = k3.shape
+    dv = v3.shape[2]
+    block_q = rows * group
+    q_spec, k_spec, o_spec, v_spec, row_spec, keep_spec = _select_specs(
+        rows, group, block_k, d, dv, bg // keep.shape[0])
+    with no_x64():
+        return pl.pallas_call(
+            functools.partial(_select_fwd_kernel, rows=rows, group=group,
+                              block_k=block_k, scale=scale),
+            grid=(bg, t_pad // rows, t_pad // block_k),
+            in_specs=[q_spec, k_spec, v_spec, keep_spec],
+            out_specs=[o_spec, row_spec],
+            out_shape=[
+                jax.ShapeDtypeStruct((bg, group * t_pad, dv), q3.dtype),
+                jax.ShapeDtypeStruct((bg, group * t_pad, 1), jnp.float32)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, dv), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, 1), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=max(VMEM_SCOPED_DEFAULT, flash_vmem_bytes(
+                    block_q, block_k, max(d, dv), q3.dtype.itemsize,
+                    select_rows=rows))),
+            name=_select_name("fwd", q3.dtype, block_q, block_k, group),
+            interpret=interpret,
+        )(q3, k3, v3, keep)
+
+
+@functools.partial(jax.jit, static_argnames=_SELECT_STATIC)
+def select_bwd_call(q3, k3, v3, keep, do3, lse, delta, *, rows, group,
+                    block_k, scale, interpret):
+    """-> dq, dk and dv, shaped and typed as q3, k3 and v3."""
+    _M_SELECT_TRACES.inc(**{"pass": "bwd"}, group=group, rows=rows)
+    bg, t_pad, d = k3.shape
+    dv = v3.shape[2]
+    block_q = rows * group
+    nk = t_pad // block_k
+    q_spec, k_spec, o_spec, v_spec, row_spec, keep_spec = _select_specs(
+        rows, group, block_k, d, dv, bg // keep.shape[0], steps=nk)
+
+    def whole_head(width):
+        return pl.BlockSpec((1, nk, block_k, width),
+                            lambda b, i, j: (b, 0, 0, 0))
+
+    with no_x64():
+        dq, dk, dv_ = pl.pallas_call(
+            functools.partial(_select_bwd_kernel, rows=rows, group=group,
+                              block_k=block_k, scale=scale),
+            grid=(bg, t_pad // rows, nk),
+            in_specs=[q_spec, k_spec, v_spec, keep_spec, o_spec, row_spec,
+                      row_spec],
+            out_specs=[q_spec, whole_head(d), whole_head(dv)],
+            out_shape=[
+                jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+                jax.ShapeDtypeStruct((bg, nk, block_k, d), q3.dtype),
+                jax.ShapeDtypeStruct((bg, nk, block_k, dv), q3.dtype)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((nk, block_k, d), jnp.float32),
+                pltpu.VMEM((nk, block_k, dv), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                # dk / dv accumulate over a key/value head's q tiles
+                dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+                vmem_limit_bytes=flash_vmem_bytes(
+                    block_q, block_k, max(d, dv), q3.dtype.itemsize,
+                    resident=(t_pad, d, dv), select_rows=rows)),
+            name=_select_name("bwd", q3.dtype, block_q, block_k, group),
+            interpret=interpret,
+        )(q3, k3, v3, keep, do3, lse, delta)
+    return dq, dk.reshape(bg, t_pad, d), dv_.reshape(bg, t_pad, dv)
+
+
+def _group_rows(x, kv_heads, rows):
+    """x [B, T, H, D] -> [B G, (T / rows) group rows, D]: the ``rows``
+    positions of a q tile of each of a group's heads one under another,
+    the order the selected pair's q-shaped operands have."""
+    b, t, h, d = x.shape
+    group = h // kv_heads
+    x = x.reshape(b, t // rows, rows, kv_heads, group, d)
+    return x.transpose(0, 3, 1, 4, 2, 5).reshape(b * kv_heads, group * t, d)
+
+
+def _ungroup_rows(x, batch, rows, group):
+    """``_group_rows``' inverse: [B G, group T, D] -> [B, T, H, D]."""
+    bg, gt, d = x.shape
+    kv_heads, t = bg // batch, gt // group
+    x = x.reshape(batch, kv_heads, t // rows, group, rows, d)
+    return x.transpose(0, 2, 4, 1, 3, 5).reshape(batch, t, kv_heads * group,
+                                                 d)
+
+
+def kept_attention(q, k, v, keep, scale):
+    """``reference_attention``'s causal arithmetic under a keep-mask
+    besides: q [B, T, H, D], k [B, T, G, D], v [B, T, G, Dv] (H a multiple
+    of G), keep [B, T, T] (0 drops the pair) -> [B, T, H, Dv].
+    Materialised float32 scores; a row that keeps no key gives zeros."""
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    live = jnp.logical_and(_keep(q.shape[1], True, 0)[None],
+                           keep != 0)[:, None]
+    s = jnp.where(live, s, NEG_INF)
+    p = jnp.where(live, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True),
+                        np.float32(1e-30))
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(
+        q.dtype)
+
+
+def _select_plain(q3, k3, v3, keep, rows, group, scale):
+    """``kept_attention`` on the selected pair's operands, o as the pair
+    gives it: the branch for every platform but the TPU."""
+    b = keep.shape[0]
+    kv_heads = k3.shape[0] // b
+
+    def heads_last(x):
+        return x.reshape(b, kv_heads, *x.shape[1:]).transpose(0, 2, 1, 3)
+
+    out = kept_attention(_ungroup_rows(q3, b, rows, group), heads_last(k3),
+                         heads_last(v3), keep, scale)
+    return _group_rows(out, kv_heads, rows)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _select(q3, k3, v3, keep, rows, group, block_k, scale, interpret):
+    return _select_fwd(q3, k3, v3, keep, rows, group, block_k, scale,
+                       interpret)[0]
+
+
+def _select_fwd(q3, k3, v3, keep, rows, group, block_k, scale, interpret):
+    def plain(q3, k3, v3, keep):
+        # lse is the kernels' own: the plain form's transpose is its
+        # backward
+        return (_select_plain(q3, k3, v3, keep, rows, group, scale),
+                jnp.zeros(q3.shape[:2] + (1,), jnp.float32))
+
+    out, lse = on_tpu(
+        functools.partial(select_fwd_call, rows=rows, group=group,
+                          block_k=block_k, scale=scale),
+        plain, interpret, q3, k3, v3, keep)
+    return out, (q3, k3, v3, keep, out, lse)
+
+
+def _select_bwd(rows, group, block_k, scale, interpret, res, g):
+    q3, k3, v3, keep, out, lse = res
+
+    def kernels(q3, k3, v3, keep, out, lse, g, interpret):
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        return select_bwd_call(
+            q3, k3, v3, keep, g.astype(q3.dtype), lse, delta, rows=rows,
+            group=group, block_k=block_k, scale=scale, interpret=interpret)
+
+    def plain(q3, k3, v3, keep, out, lse, g):
+        return jax.vjp(
+            lambda *ins: _select_plain(*ins, keep, rows, group, scale),
+            q3, k3, v3)[1](g)
+
+    # the keep-mask is data, not a weight: no cotangent
+    return on_tpu(kernels, plain, interpret, q3, k3, v3, keep, out, lse,
+                  g) + (None,)
+
+
+_select.defvjp(_select_fwd, _select_bwd)
+
+
+def flash_select(q, k, v, keep, scale=None, interpret=False):
+    """Causal attention over the keys a keep-mask names, as a Pallas kernel
+    pair, for the shapes ``flash_select_takes`` admits. q [B, T, H, D], k
+    [B, T, G, D], v [B, T, G, Dv] as ``flash_attention`` takes them, keep
+    [B, T, T] (any integer or bool type; read as int8, 0 drops the pair)
+    -> [B, T, H, Dv]: row t's softmax runs over the keys s <= t with
+    ``keep[b, t, s] != 0`` only, a row that keeps none gives zeros. The
+    mask is every head's and carries no gradient; q, k and v are
+    differentiable.
+
+    A q tile of ``flash_tiles``' rows is ``rows`` = block_q / (H / G)
+    positions of EVERY query head of one key/value head's group
+    (``_group_rows``: the one transposition ``flash_attention`` makes
+    too), so a grid step loads one K tile, one V tile and one [rows,
+    block_k] tile of the mask for the whole group, its two products into
+    dK and dV sum over the group, and the kernels are ``flash_attention``'s
+    arithmetic on [block_q, block_k] scores (``flashsel_fwd_`` /
+    ``flashsel_bwd_<operands>_q<block_q>_k<block_k>_g<group>``; the
+    backward is one pass, dK and dV of a key/value head resident). Every
+    live causal tile is computed and masked, none by quarters: a selection
+    scattered over a row's keys leaves no tile to skip. T is padded to
+    whole tiles with rows and keys the mask drops. Mosaic where the
+    computation is lowered for the TPU and ``kept_attention`` on every
+    other platform, the choice made inside the ``custom_vjp``;
+    ``interpret=True`` (the kernels' tests) runs the kernels through the
+    Pallas interpreter. No partitioning rule: inside a sharded ``jit``,
+    call under ``shard_map``."""
+    b, t, h, d = q.shape
+    g, dv = k.shape[2], v.shape[3]
+    tiles = select_tiles(t, h // g, d, dv, q.dtype) if h % g == 0 else None
+    if tiles is None:
+        raise ValueError(
+            "flash_select: no tiles for query %s, key %s, value %s of %s "
+            "(flash_select_takes)" % (q.shape, k.shape, v.shape, q.dtype))
+    rows, block_k, t_pad = tiles
+    if scale is None:
+        scale = 1.0 / float(np.sqrt(d))
+    q, k, v = (pad_to(x, 1, t_pad)[0] for x in (q, k, v))
+    keep = pad_to(pad_to(keep.astype(jnp.int8), 1, t_pad)[0], 2, t_pad)[0]
+    out = _select(_group_rows(q, g, rows), _heads_first(k), _heads_first(v),
+                  keep, rows, h // g, block_k, float(scale),
+                  bool(interpret))
+    return _ungroup_rows(out, b, rows, h // g)[:, :t]
 
 
 def reference_attention(q, k, v, causal=False, scale=None, window=0,

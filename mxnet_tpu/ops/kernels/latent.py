@@ -455,22 +455,6 @@ def latent_bwd_call(q, kv, kr, out, do, lse, keep=None, *, heads, nope,
     return dq, dkv.reshape(kv.shape), dkr.reshape(kr.shape)
 
 
-def kept_attention(q, k, v, keep, scale):
-    """``flash.reference_attention``'s causal arithmetic under a keep-mask
-    besides: q, k [B, T, H, D], v [B, T, H, Dv], keep [B, T, T] (0 drops
-    the pair) -> [B, T, H, Dv]. Materialised float32 scores; a row that
-    keeps no key gives zeros."""
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    live = jnp.logical_and(flash._keep(q.shape[1], True, 0)[None],
-                           keep != 0)[:, None]
-    s = jnp.where(live, s, NEG_INF)
-    p = jnp.where(live, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
-    p = p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True),
-                        np.float32(1e-30))
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32)).astype(
-        q.dtype)
-
-
 def latent_composed(q, kv, kr, heads, nope, scale, keep=None):
     """``flash.reference_attention`` over the concatenated key on the kernels'
     operands (``kept_attention`` under a keep-mask), o as they give it:
@@ -487,7 +471,7 @@ def latent_composed(q, kv, kr, heads, nope, scale, keep=None):
         out = flash.reference_attention(q, k, kv[..., nope:], causal=True,
                                         scale=scale)
     else:
-        out = kept_attention(q, k, kv[..., nope:], keep, scale)
+        out = flash.kept_attention(q, k, kv[..., nope:], keep, scale)
     return out.reshape(b, t, -1)
 
 

@@ -1,8 +1,11 @@
 """``Attention``: multi-head scaled-dot-product attention, grouped
-key/value heads, causal or under a window, a learned sink a head and an
-output gate its optional inputs. A thin op over the one attention dispatch
+key/value heads, causal or under a window, a learned sink a head, an
+output gate and a keep-mask that names each query's keys its optional
+inputs. A thin op over the one attention dispatch
 ``kernels.attention`` (``ops/kernels/flash.py``: the flash kernels where
-lowered for the TPU at T >= 128, the materialised reference elsewhere).
+lowered for the TPU at T >= 128, the materialised reference elsewhere)
+and, under a keep-mask, over ``kernels.flash_select`` (the selected pair
+where it has tiles for the shapes, ``kernels.kept_attention`` elsewhere).
 ``gate_output`` is the gate ``LatentAttention`` shares.
 ``DiffAttention`` (differential attention, Ye et al., arXiv:2410.05258):
 two softmax maps a head pair read against the pair's two value heads side
@@ -18,10 +21,11 @@ import jax.numpy as jnp
 
 from ... import telemetry as _tm
 from ..registry import OpDef, register
-from ..utils import head_width, optional_inputs, required_shape
+from ..utils import (
+    head_width, optional_inputs, required_shape, types_beside_keep)
 
 
-_OPTIONAL = ("sink", "gate")
+_OPTIONAL = ("sink", "gate", "keep")
 
 
 def _kv_heads(attrs):
@@ -32,6 +36,14 @@ _M_GATED_LOWERINGS = _tm.counter(
     "attention.gated_lowerings", "Traces of an Attention call site whose "
     "output is gated (with_gate: one per lowering, nothing per step); "
     "labels: heads, dv (the value width a head)")
+
+
+_M_SELECT_LOWERINGS = _tm.counter(
+    "attention.select_lowerings", "Traces of an Attention call site whose "
+    "keys a keep-mask names (with_keep: one per lowering, nothing per "
+    "step); labels: select=1, heads, group (query heads a key/value "
+    "head), impl (kernel: the selected flash pair where the step is "
+    "lowered for the TPU; composed: the materialised kept_attention)")
 
 
 def gate_output(out, gate):
@@ -55,17 +67,42 @@ def _attention(attrs, ins, is_train):
     def split(x, n):
         return x.reshape(b, t, n, x.shape[2] // n)
 
-    with jax.named_scope("window" if window else "full"):
-        out = attention(split(q, heads), split(k, kv_heads),
-                        split(v, kv_heads),
-                        causal=bool(attrs.get("causal", True)),
-                        window=window, sink=optional.get("sink"))
+    if "keep" in optional:
+        out = _selected(split(q, heads), split(k, kv_heads),
+                        split(v, kv_heads), optional["keep"])
+    else:
+        with jax.named_scope("window" if window else "full"):
+            out = attention(split(q, heads), split(k, kv_heads),
+                            split(v, kv_heads),
+                            causal=bool(attrs.get("causal", True)),
+                            window=window, sink=optional.get("sink"))
     out = out.reshape(b, t, -1)
     if "gate" in optional:
         _M_GATED_LOWERINGS.inc(heads=heads, dv=out.shape[2] // heads)
         with jax.named_scope("gate"):
             out = gate_output(out, optional["gate"])
     return [out]
+
+
+def _selected(q, k, v, keep):
+    """Causal attention of q [B, T, H, D] on k, v [B, T, G, .] over the
+    keys ``keep`` [B, T, T] names (0 drops the pair; no gradient), under
+    the scope ``select``: ``kernels.flash_select`` where it has tiles for
+    the shapes, the materialised ``kernels.kept_attention`` elsewhere;
+    the shapes alone choose."""
+    from .. import kernels
+
+    heads, kv_heads, d = q.shape[2], k.shape[2], q.shape[3]
+    kernel = kernels.flash_select_takes(q.shape[1], heads, kv_heads, d,
+                                        v.shape[3], q.dtype)
+    _M_SELECT_LOWERINGS.inc(select=1, heads=heads, group=heads // kv_heads,
+                            impl="kernel" if kernel else "composed")
+    keep = jax.lax.stop_gradient(keep)
+    with jax.named_scope("select"):
+        if kernel:
+            return kernels.flash_select(q, k, v, keep,
+                                        interpret=kernels.common.INTERPRET)
+        return kernels.kept_attention(q, k, v, keep, d ** -0.5)
 
 
 def _attention_infer(attrs, in_shapes):
@@ -75,6 +112,12 @@ def _attention_infer(attrs, in_shapes):
                          "num_heads=%d" % (kv_heads, heads))
     if int(attrs.get("window", 0)) and not bool(attrs.get("causal", True)):
         raise ValueError("Attention: a window needs causal=True")
+    if bool(attrs.get("with_keep", False)) and (
+            int(attrs.get("window", 0)) or bool(attrs.get("with_sink", False))
+            or not bool(attrs.get("causal", True))):
+        raise ValueError(
+            "Attention: a keep-mask beside a window, a sink or causal=False "
+            "is not implemented")
     q, k, v = (required_shape(shape, "Attention") for shape in in_shapes[:3])
     d = head_width("Attention", "query", q, heads)
     dk = head_width("Attention", "key", k, kv_heads)
@@ -89,18 +132,30 @@ def _attention_infer(attrs, in_shapes):
             "Attention: key %s has head_dim %d over %d heads, query %s "
             "has %d over %d" % (k, dk, kv_heads, q, d, heads))
     out = q[:2] + (heads * dv,)
-    optional = {"sink": (heads,), "gate": out}
+    optional = {"sink": (heads,), "gate": out, "keep": q[:2] + (q[1],)}
     ins = [q, k, v] + [optional[name] for name in optional_inputs(attrs, _OPTIONAL)]
     return ins, [out], []
+
+
+def _attention_infer_type(attrs, in_types):
+    """The keep-mask has a type of its own (int8, ``KeyIndexer``'s);
+    every other input and the output share the query's."""
+    types, t = types_beside_keep(
+        "Attention",
+        ["query", "key", "value"] + optional_inputs(attrs, _OPTIONAL),
+        in_types)
+    return types, [t], []
 
 
 _attn = OpDef(
     "_contrib_Attention",
     _attention,
-    arguments=("query", "key", "value", "sink", "gate"),
+    arguments=("query", "key", "value", "sink", "gate", "keep"),
     defaults={"num_heads": 1, "num_kv_heads": 0, "causal": True,
-              "window": 0, "with_sink": False, "with_gate": False},
+              "window": 0, "with_sink": False, "with_gate": False,
+              "with_keep": False},
     infer_shape=_attention_infer,
+    infer_type=_attention_infer_type,
     aliases=("Attention",),
     op_class="attn",
 )

@@ -18,7 +18,8 @@ import numpy as np
 from ... import telemetry as _tm
 from ..registry import OpDef, register
 from ..utils import (
-    check_rotation, first_type, head_width, optional_inputs, required_shape)
+    check_rotation, first_type, head_width, optional_inputs, required_shape,
+    types_beside_keep)
 from .attention import gate_output
 from .norm import layer_norm, rms_norm
 from .rotary import rope
@@ -119,9 +120,8 @@ def _latent_composed_path(query, kv, k_rope, num_heads, v_head_dim, theta,
     slice of ``kv`` [B, T, H (N + Dv)], the values sliced out of it, and
     ``kernels.attention`` (the flash kernel on the TPU at T >= 128,
     the materialised reference elsewhere; its band under ``window``), or
-    under ``keep`` the materialised ``kernels.latent.kept_attention``."""
-    from ..kernels import attention
-    from ..kernels.latent import kept_attention
+    under ``keep`` the materialised ``kernels.kept_attention``."""
+    from ..kernels import attention, kept_attention
 
     b, t, _ = query.shape
     rope_dim = k_rope.shape[2]
@@ -207,7 +207,7 @@ def latent_attention(query, latent, gamma, up_weight, num_heads, rope_dim,
     ``KeyIndexer`` gives): row t's softmax runs over its kept keys s <= t
     only, the mask every head's and without a gradient; ``kernel`` is then
     the pair's selected variant (``flash2sel_*``, ``latent_flash(keep=)``),
-    ``composed`` the materialised ``kernels.latent.kept_attention``, both
+    ``composed`` the materialised ``kernels.kept_attention``, both
     under the scope ``select``. ``gate`` [B, T, H]: head h's output times
     ``sigmoid(gate[.., h])``, float32, one rounding (scope ``gate``).
 
@@ -316,13 +316,11 @@ def _latent_attention_infer(attrs, in_shapes):
 def _latent_attention_infer_type(attrs, in_types):
     """The keep-mask has a type of its own (int8, ``KeyIndexer``'s);
     every other input and the output share the query's."""
-    names = ["query", "latent", "latent_gamma", "up_weight"
-             ] + optional_inputs(attrs, _LATENT_OPTIONAL)
-    t = first_type("LatentAttention", [
-        t for name, t in zip(names, in_types) if name != "keep"])
-    return ([np.int8 if name == "keep" and x is None
-             else t if x is None else x
-             for name, x in zip(names, in_types)], [t], [])
+    types, t = types_beside_keep(
+        "LatentAttention",
+        ["query", "latent", "latent_gamma", "up_weight"]
+        + optional_inputs(attrs, _LATENT_OPTIONAL), in_types)
+    return types, [t], []
 
 
 _latent_op = OpDef(

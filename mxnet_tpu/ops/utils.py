@@ -122,6 +122,17 @@ def first_type(what, types):
     return known[0]
 
 
+def types_beside_keep(what, names, in_types):
+    """(input types, the others' type) of an op whose input ``keep`` has a
+    type of its own (int8, ``KeyIndexer``'s keep-mask) while every other
+    input, ``names`` in order, shares the first known one."""
+    t = first_type(what, [
+        t for name, t in zip(names, in_types) if name != "keep"])
+    return [np.int8 if name == "keep" and x is None
+            else t if x is None else x
+            for name, x in zip(names, in_types)], t
+
+
 def head_width(what, name, shape, heads):
     """``shape`` [batch, time, heads * head_dim] -> head_dim. A
     ValueError: a known-but-wrong shape must survive the infer fixpoint

@@ -244,6 +244,52 @@ def test_the_latent_pair_takes_the_call_that_rotates_nothing(one_chip):
     assert " cosine(" not in text and " sine(" not in text
 
 
+def test_the_selected_pair_compiles_at_the_keye_cells_shape(one_chip):
+    """``Attention(keep=)`` as the Keye-VL-2.0 cell calls it (T 8,192,
+    bf16, 32 query heads on 4 key/value heads of 128 under an int8
+    keep-mask): the selected pair ``flashsel_*``, once each way, a q tile
+    of 1,024 rows = 128 positions of each of a group's 8 heads against k
+    tiles of 1,024, under the VMEM ``flash_vmem_bytes(select_rows=)``
+    counts (36.25 MiB for the one-pass backward), and no kernel of the
+    unselected pair."""
+    from mxnet_tpu.ops.transformer import attention as attention_ops
+
+    t, h, g, d = 8192, 32, 4, 128
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(q, k, v, keep):
+        return jnp.sum(attention_ops._attention(
+            dict(num_heads=h, num_kv_heads=g, causal=True, with_keep=True),
+            [q, k, v, keep], True)[0].astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(1, t, h * d), shape(1, t, g * d), shape(1, t, g * d),
+        shape(1, t, t, dtype=jnp.int8)).compile().as_text()
+    assert pk.flash.select_tiles(t, h // g, d, d, jnp.bfloat16) == (
+        128, 1024, t)
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    for which, resident in (("fwd", None), ("bwd", (t, d, d))):
+        mine = [c for c in calls
+                if "flashsel_%s_bf16_q1024_k1024_g8" % which in c]
+        assert len(mine) == 1
+        limit, used = (
+            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
+                          r'"size":"(\d+)"' % key, mine[0]).group(1))
+            for key in ("scoped_memory_configs",
+                        "used_scoped_memory_configs"))
+        count = pk.flash.flash_vmem_bytes(1024, 1024, d, 2,
+                                          resident=resident, select_rows=128)
+        assert limit == max(count, pk.common.VMEM_SCOPED_DEFAULT)
+        assert used <= limit <= pk.common.VMEM_RAISED_LIMIT
+    assert pk.flash.flash_vmem_bytes(
+        1024, 1024, d, 2, resident=(t, d, d),
+        select_rows=128) == int(36.25 * 2 ** 20)
+    assert "flash_fwd_" not in text and "flash_bwd_" not in text
+    assert "flash2" not in text
+
+
 @pytest.mark.parametrize("layer", ["full", "window"])
 def test_the_dots3_cells_attention_compiles_for_v5e(one_chip, layer):
     """``LatentAttention`` as the dots3 cell calls it (T 4,096, bf16, a

@@ -484,7 +484,8 @@ def test_only_nemotrons_up_product_is_handed_over_held_transposed():
     in every other configuration both widths of both weights are whole
     lane rows and all six are ``declared``."""
     layers = _benchmark_expert_layers()
-    assert len(layers) == 10, [name for name, *_ in layers]
+    # the eleventh since PR 75: Keye-VL-2.0's experts of 768, six lane rows
+    assert len(layers) == 11, [name for name, *_ in layers]
     spec = jax.ShapeDtypeStruct
     for name, up, down, rows in layers:
         activation = "relu2" if up[2] == down[1] else "swiglu"

@@ -176,6 +176,117 @@ def test_flash_bf16_backward_matches_f32_reference(b, t, h, d, causal, bq,
         assert _rel(a, r) < BF16_BWD_TOL, (name, _rel(a, r))
 
 
+# -- the selected pair: Attention's keep-mask ---------------------------------
+
+def _select_case(seed, t, heads, kv_heads, d, dv, dtype, topk):
+    """q, k, v, an int8 keep-mask of ``topk`` keys a row (fewer above the
+    diagonal's reach) whose late rows keep NO key of the first quarter of
+    the keys: their first live tile holds none of theirs."""
+    rng = np.random.RandomState(seed)
+    draw = lambda *s: jnp.asarray(rng.randn(*s), dtype)
+    scores = rng.randn(1, t, t)
+    causal = np.tril(np.ones((t, t), bool))
+    scores = np.where(causal[None], scores, -np.inf)
+    kth = np.sort(scores, axis=-1)[..., -topk][..., None]
+    keep = (scores >= kth) & causal[None]
+    keep[:, t // 2:, : t // 4] = False
+    return (draw(1, t, heads, d), draw(1, t, kv_heads, d),
+            draw(1, t, kv_heads, dv), jnp.asarray(keep, jnp.int8),
+            jnp.asarray(rng.randn(1, t, heads, dv), jnp.float32))
+
+
+SELECT_CASES = {
+    # 8 query heads on ONE key/value head of 128, whole tiles of 256
+    "8_on_1": (256, 8, 1, 128, 128, jnp.float32, 48, 1e-5),
+    # T no multiple of the tile (200 -> 256: padded rows and keys the
+    # mask drops), 4 on 1 twice over, a value narrower than the key
+    "padded": (200, 8, 2, 64, 32, jnp.float32, 48, 1e-5),
+    # bf16 operands on tiles of 512: p and dS round to 2^-9 once each
+    "bf16": (512, 16, 2, 128, 128, jnp.bfloat16, 100, 2.0 ** -6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+def test_the_selected_pair_matches_kept_attention(case):
+    """``flash_select`` through the interpreter against the materialised
+    ``kept_attention`` (what ``Attention(keep=)`` runs where the pair has
+    no tiles): the output's weighted sum and dq, dk, dv. A q tile holds a
+    group's heads one under another, so dk and dv here are sums over the
+    group made inside the kernel's products."""
+    t, heads, kv_heads, d, dv, dtype, topk, tol = SELECT_CASES[case]
+    q, k, v, keep, w = _select_case(3, t, heads, kv_heads, d, dv, dtype,
+                                    topk)
+    assert pk.flash_select_takes(t, heads, kv_heads, d, dv, dtype)
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+            argnums=(0, 1, 2))(q, k, v)
+
+    got = run(lambda q, k, v: pk.flash_select(q, k, v, keep,
+                                              interpret=True))
+    want = run(lambda q, k, v: pk.kept_attention(q, k, v, keep, d ** -0.5))
+    plain = run(lambda q, k, v: pk.flash_select(q, k, v, keep))
+    for g, p, x in zip(*(jax.tree_util.tree_leaves(r)
+                         for r in (got, plain, want))):
+        g, p, x = (np.asarray(a, np.float32) for a in (g, p, x))
+        scale = max(np.abs(x).max(), 1.0)
+        assert np.abs(g - x).max() <= tol * scale, np.abs(g - x).max()
+        # off the TPU the entry IS kept_attention on re-ordered rows
+        assert np.abs(p - x).max() <= 1e-5 * scale
+
+
+def test_a_row_that_keeps_nothing_reads_zeros_and_moves_nothing():
+    """Rows whose mask is empty (and the padding rows are such rows):
+    zeros out, a zero dq, and nothing added to dk or dv."""
+    t, heads, d = 256, 8, 128
+    q, k, v, keep, w = _select_case(5, t, heads, 1, d, d, jnp.float32, 32)
+    keep = keep.at[:, 100:140].set(0)
+
+    def loss(q, k, v):
+        return jnp.sum(pk.flash_select(q, k, v, keep, interpret=True) * w)
+
+    out = pk.flash_select(q, k, v, keep, interpret=True)
+    assert not np.asarray(out[:, 100:140]).any()
+    assert np.isfinite(np.asarray(out)).all()
+    dq, dk, dv = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert not np.asarray(dq[:, 100:140]).any()
+    for g in (dq, dk, dv):
+        assert np.isfinite(np.asarray(g)).all()
+
+
+@pytest.mark.parametrize("t,heads,kv_heads,d,dtype,takes", [
+    (8192, 32, 4, 128, jnp.bfloat16, True),    # the Keye cell
+    (4096, 16, 16, 128, jnp.bfloat16, True),   # no group: a 1 MB mask tile
+    (8192, 32, 4, 128, jnp.float16, False),    # no type Mosaic takes
+    (100, 8, 1, 128, jnp.float32, False),      # shorter than a tile
+    (384, 16, 2, 128, jnp.float32, False),     # 16 rows a head: no int8 tile
+    (32768, 32, 4, 128, jnp.bfloat16, False),  # dK / dV do not stay in VMEM
+])
+def test_the_selected_pair_takes_what_the_shapes_say(t, heads, kv_heads, d,
+                                                     dtype, takes):
+    assert pk.flash_select_takes(t, heads, kv_heads, d, d, dtype) is takes
+    if not takes:
+        q = jnp.zeros((1, t, heads, d), dtype)
+        kv = jnp.zeros((1, t, kv_heads, d), dtype)
+        with pytest.raises(ValueError, match="flash_select_takes"):
+            jax.eval_shape(lambda: pk.flash_select(
+                q, kv, kv, jnp.zeros((1, t, t), jnp.int8)))
+
+
+def test_the_vmem_count_holds_the_keep_masks_tile():
+    """``flash_vmem_bytes(select_rows=)``: two int8 buffers of [rows,
+    block_k] and the mask widened to 32 bits over the group's rows."""
+    base = pk.flash.flash_vmem_bytes(1024, 1024, 128, 2,
+                                     resident=(8192, 128, 128))
+    got = pk.flash.flash_vmem_bytes(1024, 1024, 128, 2,
+                                    resident=(8192, 128, 128),
+                                    select_rows=128)
+    assert got - base == 2 * 128 * 1024 + 4 * 1024 * 1024
+    assert pk.flash.flash_vmem_bytes(1024, 1024, 128, 2) == \
+        pk.flash.flash_vmem_bytes(1024, 1024, 128, 2, select_rows=0)
+
+
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
                                        (jnp.bfloat16, 1e-2)])
 def test_flash_default_tiles_match_explicit_128(dtype, tol):

@@ -30,6 +30,9 @@ because they need explicit on-chip (VMEM) accumulation patterns.
                pass over a token block each way, the stream's cotangents
                summed in the backward's; the write of the next stream,
                whole rows, one pass each way
+  topk         the choice of a row's k largest scores (KeyIndexer's
+               keep-mask): the 32 counting passes, the tie count and the
+               mask on a row block held in VMEM, one read of the scores
   conv         the conv-backward pair
   common       what they share
 
@@ -66,6 +69,7 @@ from .rope import rope_rows, rotate_heads
 from .ssd import ssd_scan, ssd_takes
 from .sscan import selective_scan, sscan_takes
 from .taps import causal_conv, taps_takes
+from .topk import top_k_mask, top_k_rows
 
 __all__ = [
     "attention", "causal_conv", "channel_delta_net", "common",
@@ -81,5 +85,5 @@ __all__ = [
     "selective_scan", "sorted_segment_sum", "sscan_takes",
     "ssd_scan", "ssd_takes", "stream_mix", "stream_products", "stream_read",
     "stream_write",
-    "taps_takes",
+    "taps_takes", "top_k_mask", "top_k_rows",
 ]

@@ -2,8 +2,9 @@
 keys and values are projected up from one normalised latent a token, over
 every causal key, a window of them or the keys a keep-mask chooses; and
 ``KeyIndexer`` (DeepSeek-V3.2-Exp's lightning indexer), the light
-many-head scorer whose exact top-k a query row is that mask (``jax.numpy``
-on every platform). The attention is the kernel family
+many-head scorer whose exact top-k a query row is that mask (the scores
+``jax.numpy`` on every platform, the choice ``kernels.top_k_mask``). The
+attention is the kernel family
 ``ops/kernels/latent.py`` (a flash pair of two key operands, a pass over the
 query) where ``kernels.latent_flash_takes`` admits the shapes, the
 concatenated key through ``kernels.attention`` elsewhere."""
@@ -347,52 +348,32 @@ register(_latent_op)
 _M_INDEX_LOWERINGS = _tm.counter(
     "attention.index_lowerings", "Traces of a KeyIndexer call site (one per "
     "lowering, nothing per step); labels: heads, width (a head's and the "
-    "one key's), topk, rows (query rows a block of the scores), impl (jnp: "
-    "blocked jax.numpy scores and a bisection on the scores' bits, on "
-    "every platform)")
+    "one key's), topk, rows (query rows a block of the scores), impl (how "
+    "the keys are chosen among the blocked jax.numpy scores; pallas: the "
+    "shapes have a row block in kernels.topk, the kernel where the step is "
+    "lowered for the TPU; jnp: the bisection on the scores' bits in "
+    "jax.numpy on every platform)")
 
 INDEX_BLOCK_ROWS = 256   # query rows a block of [heads, rows, keys] scores
 
 
-def _sortable_bits(x):
-    """float32 -> uint32 of the same order (negative values reversed
-    under the others, -0.0 under +0.0)."""
-    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
-    return jnp.where(bits >> np.uint32(31) == 0, bits | np.uint32(1 << 31),
-                     ~bits)
-
-
-def keep_top_k(scores, k):
+def keep_top_k(scores, k, live=False, causal=False):
     """scores [..., T, S] (read as float32) -> bool of the same shape: in
     each row its ``k`` largest entries (all of them where S <= k), ties to the
     lower index: ``jax.lax.top_k``'s choice without its sort. The k-th
     largest value of a row is found bit by bit (32 counting passes over
     the scores' order-preserving bits); only a row that holds its k-th
-    value more than once pays the running count that breaks the tie."""
-    if scores.shape[-1] <= k:
-        return jnp.ones(scores.shape, bool)
-    u = _sortable_bits(scores.astype(jnp.float32))
+    value more than once pays the running count that breaks the tie.
+    Under ``live`` an -inf is never kept (a row keeps at most its entries
+    over -inf); ``causal`` is the caller's word that row t holds -inf past
+    column t, which lets the kernel stop a row block's passes there.
+    ``kernels.top_k_mask`` makes the choice: on a row block held in VMEM
+    where it has one for the shape and the step is lowered for the TPU."""
+    from .. import kernels
 
-    def count(mask):
-        return jnp.sum(mask, axis=-1, keepdims=True, dtype=jnp.int32)
-
-    def step(i, cur):
-        cand = cur | (np.uint32(1 << 31) >> i.astype(jnp.uint32))
-        return jnp.where(count(u >= cand) >= k, cand, cur)
-
-    kth = jax.lax.fori_loop(0, 32, step,
-                            jnp.zeros(u.shape[:-1] + (1,), jnp.uint32))
-    above = u > kth
-    # entries of the k-th value a row may still take, lowest index first
-    room = k - count(above)
-    tied = u == kth
-
-    def by_index():
-        return above | (tied & (jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
-                                <= room))
-
-    return jax.lax.cond(jnp.any(count(tied) > room), by_index,
-                        lambda: above | tied)
+    keep, _ = kernels.top_k_mask(scores.astype(jnp.float32), k, live, causal,
+                                 interpret=kernels.common.INTERPRET)
+    return keep.astype(bool)
 
 
 def index_scores(q, k, w, rows=INDEX_BLOCK_ROWS):
@@ -433,6 +414,8 @@ def key_indexer(query_latent, data, q_weight, k_weight, k_gamma, k_beta,
     trains the scorer by a loss of its own). The two products take
     operands of ``data``'s type and accumulate in float32; ReLU, the
     weights, the sum over the heads and the compare are float32."""
+    from .. import kernels
+
     (query_latent, data, q_weight, k_weight, k_gamma, k_beta,
      head_weight) = (jax.lax.stop_gradient(x) for x in (
          query_latent, data, q_weight, k_weight, k_gamma, k_beta,
@@ -441,8 +424,9 @@ def key_indexer(query_latent, data, q_weight, k_weight, k_gamma, k_beta,
     width = k_weight.shape[0]
     dtype = data.dtype
     rows = min(INDEX_BLOCK_ROWS, t)
-    _M_INDEX_LOWERINGS.inc(heads=num_heads, width=width, topk=topk,
-                           rows=rows, impl="jnp")
+    _M_INDEX_LOWERINGS.inc(
+        heads=num_heads, width=width, topk=topk, rows=rows,
+        impl="pallas" if kernels.top_k_rows((b, t, t), topk) else "jnp")
 
     def project(x, weight):
         return jax.lax.dot_general(
@@ -458,9 +442,11 @@ def key_indexer(query_latent, data, q_weight, k_weight, k_gamma, k_beta,
             num_heads ** -0.5 * width ** -0.5)
         scores = index_scores(q.reshape(b, t, num_heads, width), k, w, rows)
         with jax.named_scope("topk"):
-            keep = keep_top_k(scores, topk) & (scores > -jnp.inf)
-            count = jnp.sum(keep, axis=(1, 2), dtype=jnp.int32)
-    return keep.astype(jnp.int8), count.astype(jnp.float32)
+            keep, kept = kernels.top_k_mask(
+                scores, topk, live=True, causal=True,
+                interpret=kernels.common.INTERPRET)
+            count = jnp.sum(kept, axis=(1, 2), dtype=jnp.int32)
+    return keep, count.astype(jnp.float32)
 
 
 def _key_indexer(attrs, ins, is_train):

@@ -390,6 +390,40 @@ def test_keep_top_k_is_lax_top_k_with_ties_to_the_lower_index(t, k):
     np.testing.assert_array_equal(got.sum(-1), k)
 
 
+def test_a_length_of_whole_lane_rows_chooses_by_the_kernel(monkeypatch):
+    """At T 256 the full layer's ``KeyIndexer`` has a row block
+    (``kernels.top_k_rows``): its call site counts ``impl="pallas"`` and,
+    by the one seam, the kernel of ``ops/kernels/topk.py`` chooses the 48
+    keys through the Pallas interpreter; the loss and the pairs kept are
+    the reference's."""
+    from mxnet_tpu.ops import kernels
+
+    t = 256
+    cfg = dict(CFG, num_hidden_layers=2, layer_types=[F, S], index_topk=48,
+               max_position_embeddings=t)
+    sym = dots3.from_config(cfg, seq_len=t)
+    params = _params(sym, 31, t=t)
+    tokens, labels = _batch(32, t=t)
+    want = ref.forward(params, tokens, cfg, labels=labels)
+    monkeypatch.setattr(kernels.common, "INTERPRET", True)
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        mod = _module(sym, params, t=t)
+        mod.forward(mx.io.DataBatch(data=[mx.nd.array(tokens)],
+                                    label=[mx.nd.array(labels)]),
+                    is_train=False)
+        outs = [o.asnumpy() for o in mod.get_outputs()]
+        assert telemetry.REGISTRY.get("attention.index_lowerings").value(
+            heads=4, width=16, topk=48, rows=256, impl="pallas") == 1
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    _close(outs[0], want["per_sequence"], "per-sequence loss", rtol=1e-4)
+    np.testing.assert_array_equal(
+        outs[-1], [sum(min(i + 1, 48) for i in range(t))] * BATCH)
+
+
 def _latent_inputs(seed, t, heads=2, nope=16, rope=8, dv=16, latent=32):
     rng = np.random.RandomState(seed)
     draw = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)

@@ -244,6 +244,54 @@ def test_the_latent_pair_takes_the_call_that_rotates_nothing(one_chip):
     assert " cosine(" not in text and " sine(" not in text
 
 
+@pytest.mark.parametrize("cell,t,heads,width,d", [
+    ("keye_vl2", 8192, 16, 128, 2048), ("dots3", 4096, 64, 128, 1536)])
+def test_the_indexers_choice_is_one_kernel_on_v5e(one_chip, cell, t, heads,
+                                                  width, d):
+    """``KeyIndexer`` as the two cells that have one call it (k 2,048 of
+    8,192 / 4,096 keys): the choice is ONE ``topk_mask_*`` kernel at a
+    block of 128 rows with the causal stop, inside the scoped default as
+    it counts; no ``while`` (the bisection's 32 passes through HBM), no
+    [T, T] array of 32-bit integers (its bits, its running count) and no
+    pass of ``key_indexer``'s own over the mask."""
+    from mxnet_tpu.ops.transformer import latent as latent_ops
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def index(x, q_w, k_w, gamma, beta, head_w):
+        return latent_ops.key_indexer(
+            x, x, q_w, k_w, gamma, beta, head_w, num_heads=heads,
+            rope_dim=64, topk=2048, theta=1e6)
+
+    text = jax.jit(index).lower(
+        shape(1, t, d), shape(heads * width, d), shape(width, d),
+        shape(width, dtype=jnp.float32), shape(width, dtype=jnp.float32),
+        shape(heads, d)).compile().as_text()
+    assert pk.top_k_rows((1, t, t), 2048) == 128
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    mine = [c for c in calls
+            if "topk_mask_f32_r128_s%d_k2048_causal" % t in c]
+    assert len(mine) == len(calls) == 1
+    # the kernel alone: what it holds of VMEM is what it counts (in the
+    # indexer's small program XLA lends the call's neighbours VMEM too)
+    alone = jax.jit(lambda x: pk.top_k_mask(x, 2048, live=True, causal=True)
+                    ).lower(shape(1, t, t, dtype=jnp.float32)
+                            ).compile().as_text()
+    used = int(re.search(
+        r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":'
+        r'"\d+","size":"(\d+)"', [line for line in alone.splitlines()
+                                 if "topk_mask_" in line
+                                 and "custom-call(" in line][0]).group(1))
+    assert used <= pk.topk.top_k_vmem_bytes(128, t) + 2 ** 20
+    assert pk.topk.top_k_vmem_bytes(128, t) <= pk.common.VMEM_SCOPED_DEFAULT
+    assert " while(" not in text
+    assert not re.search(r"[su]32\[1,%d,%d\]" % (t, t), text)
+    assert not [line for line in text.splitlines()
+                if "fusion(" in line and "s8[1,%d,%d]" % (t, t) in line]
+
+
 def test_the_selected_pair_compiles_at_the_keye_cells_shape(one_chip):
     """``Attention(keep=)`` as the Keye-VL-2.0 cell calls it (T 8,192,
     bf16, 32 query heads on 4 key/value heads of 128 under an int8

@@ -19,7 +19,8 @@ _OF_A_PEAK = re.compile(
     r"share_of|roofline|bound_|_bound|mxu|required_ms|write_ms|gbs")
 SCRIPTS = ("channel_delta_rule", "embedding_grad", "flash_attention",
            "flash_window_tiles", "gated_delta_rule", "grouped_matmul",
-           "hyper_mix", "latent_flash", "moe_permute", "rope", "ssd_scan")
+           "hyper_mix", "keep_top_k", "latent_flash", "moe_permute", "rope",
+           "ssd_scan")
 
 
 @pytest.mark.timeout(60)

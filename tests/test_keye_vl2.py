@@ -532,6 +532,10 @@ def test_the_flash_path_runs_the_selected_pair_through_the_model(
         outs = [o.asnumpy() for o in mod.get_outputs()]
         assert telemetry.REGISTRY.get("attention.select_lowerings").value(
             select=1, heads=8, group=8, impl="kernel") == 1
+        # 256 keys are whole lane rows: the indexer's choice is the
+        # kernel's (``ops/kernels/topk.py``), through the interpreter
+        assert telemetry.REGISTRY.get("attention.index_lowerings").value(
+            heads=4, width=8, topk=48, rows=256, impl="pallas") == 1
     finally:
         telemetry.disable()
         telemetry.reset()

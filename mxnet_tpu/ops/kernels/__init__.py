@@ -7,7 +7,9 @@ because they need explicit on-chip (VMEM) accumulation patterns.
 
   flash        single-key flash attention (forward, dq / dkv, the
                one-pass backward), its selected pair under a keep-mask
-               (``flash_select``), ``attention`` (the ops' one dispatch)
+               (``flash_select``: the mask's tile one bias under a group's
+               heads, the diagonal tile by the column blocks its rows
+               see), ``attention`` (the ops' one dispatch)
                and ``reference_attention`` / ``kept_attention`` (the
                oracles)
   latent       latent attention's two-key flash pair and the pass over

@@ -59,9 +59,20 @@ places, all below:
             SAME ``rows`` positions of every query head of a key/value
             head's group one under another, so a step's one K / V tile
             and one [rows, block_k] mask tile serve the whole group, and
-            dK / dV sum over the group inside the products. Every live
-            causal tile is computed and masked: a scattered selection
-            leaves no tile to skip.
+            dK / dV sum over the group inside the products. A scattered
+            selection leaves no tile to skip, so every live causal tile
+            is computed, and masked at the mask's own size: the tile
+            becomes ONE float32 bias of [rows, block_k] (0 kept,
+            ``NEG_INF`` dropped) that is added under each head's scores,
+            with no select behind it (a row's maximum starts at a finite
+            floor). A k tile wholly before the q tile's positions
+            compares none; the tile that holds them (``rows`` positions
+            against ``block_k`` keys: lopsided) has the causal compare
+            in its bias and, in the backward, runs ONE step over its
+            first column blocks of ``select_edge`` that a row can see
+            (a kernel that does is named ``..._e<width>``). Both
+            passes walk a q tile's k tiles from the diagonal down, so
+            its dead steps come first.
 
 forward / dq / the one-pass backward: grid (B*H, nq, nk), k innermost;
 dkv: grid (B*G, nk, group * nq).
@@ -134,7 +145,7 @@ def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None,
     double-buffered output blocks, and a third score-shaped temporary;
     with ``select_rows`` (the selected pair: a head's rows of a q tile),
     plus the keep-mask's two int8 tile buffers of [select_rows, block_k]
-    and the tile widened to 32 bits over the whole group's rows.
+    and the tile's float32 bias.
     Against the smallest limit Mosaic compiles each shape under (v5e,
     1-2 MiB steps), counted / needed:
 
@@ -148,11 +159,11 @@ def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None,
     one pass, T 4096, 192 / 128, bf16, 8 heads on 1 (MiMo)  32       18
     one pass, the same, 256 x 256 under a window of 128     14.75    9
     one pass, T 4096, 128 / 128, float32                    31       27
-    selected one pass, T 8192, 128 / 128, bf16, 8 on 1     36.25    (*)
+    selected one pass, T 8192, 128 / 128, bf16, 8 on 1     32.75    27.3 (*)
     ====================================================  =======  ======
 
-    (*) compiled for a described v5e under its own count
-    (``tests/test_flash_compile_tpu.py``).
+    (*) what Mosaic used of its own count when compiled for a described
+    v5e (``tests/test_flash_compile_tpu.py``).
     """
     lanes = whole_lanes(d)
     row_tiles = 2 * 2 * block_q * lanes * itemsize      # q, dO
@@ -161,7 +172,7 @@ def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None,
     scores = 2 * block_q * block_k * 4
     step = row_tiles + col_tiles + acc + scores
     if select_rows:
-        step += 2 * select_rows * block_k + 4 * block_q * block_k
+        step += (2 + 4) * select_rows * block_k
     if resident is None:
         return step
     t_pad, d, dv = resident
@@ -1114,10 +1125,19 @@ _M_SELECT_TRACES = _tm.counter(
     "attention.select_kernel_traces", "Traces of a selected flash kernel's "
     "pallas_call (one a signature and process, however many Attention "
     "nodes call it; nothing per step); labels: pass (fwd / bwd), group "
-    "(query heads a key/value head), rows (a head's rows of a q tile)")
+    "(query heads a key/value head), rows (a head's rows of a q tile), edge "
+    "(the width of the column blocks a q tile's diagonal k tile runs by, 0 "
+    "where it runs whole)")
 
 # the fewest rows of an int8 tile (its sublanes pack by 32)
 _KEEP_MIN_ROWS = 32
+# Where a row's running maximum starts: far above NEG_INF, so that a dropped
+# pair's exp(NEG_INF - m) is 0 whatever m the row has, and far below any
+# score, so that a row's first kept key takes the maximum over
+SELECT_FLOOR = -1e20
+# The narrowest column block of a diagonal tile that is worth a case of its
+# own, by measurement on the v5e (PERF.md section 7, PR 77)
+SELECT_EDGE = 256
 
 
 def select_tiles(t, group, d, dv, dtype):
@@ -1150,62 +1170,117 @@ def flash_select_takes(t, heads, kv_heads, d, dv, dtype):
             and select_tiles(t, heads // kv_heads, d, dv, dtype) is not None)
 
 
-def _select_mask(keep_ref, qi, ki, *, rows, group, block_k):
-    """The pairs of a tile that are live, [group * rows, block_k] bool:
-    the keep-mask's tile (int8, 0 drops the pair) under the causal
-    compare of its positions, once for the ``rows`` positions and laid
-    under itself for each of the group's heads."""
-    shape = (rows, block_k)
-    q_pos = jax.lax.add(jax.lax.broadcasted_iota(jnp.int32, shape, 0),
-                        affine(qi, rows))
-    k_pos = jax.lax.add(jax.lax.broadcasted_iota(jnp.int32, shape, 1),
-                        affine(ki, block_k))
-    kept = jnp.where(jax.lax.ge(q_pos, k_pos),
-                     keep_ref[0].astype(jnp.int32), np.int32(0))
-    if group > 1:
-        kept = jax.lax.concatenate([kept] * group, 0)
-    return jax.lax.ne(kept, np.int32(0))
+def select_edge(which, rows, block_k):
+    """The width of the column blocks a q tile's diagonal k tile runs by in
+    pass ``which`` ("fwd" / "bwd"), 0 where that tile runs whole: the pass
+    and the shapes alone decide, as ``cut_half`` does for the plain pair. A
+    q tile holds ``rows`` positions and its diagonal tile ``block_k`` keys,
+    of which a row of it can see the first ``rows (i % (block_k / rows) +
+    1)`` at the most. The backward, whose five products bound it, runs ONE
+    step over the first blocks of ``SELECT_EDGE`` columns (``rows`` where
+    that is more) that hold them; the forward's step costs every row its
+    two lane reductions and its accumulator's rescaling whatever its
+    width, and a narrower one saved nothing (PERF.md section 7, PR 77): it
+    runs the tile whole. ``rows == block_k`` (no group) leaves nothing to
+    cut."""
+    edge = max(rows, SELECT_EDGE)
+    return edge if which == "bwd" and edge < block_k else 0
 
 
-def _select_live(qi, ki, rows, block_k):
-    """Whether k tile ki holds a key at or before q tile qi's last
-    position."""
-    return jax.lax.le(affine(ki, block_k), affine(qi, rows, rows - 1))
+def _select_bias(keep, offset=None):
+    """What a tile step adds to its scores, float32 at the keep-mask's own
+    [rows, width]: 0 where the pair is kept and ``NEG_INF`` where it is
+    dropped, so the sum is the score itself or ``NEG_INF`` itself. ``keep``
+    is the int8 tile (0 drops the pair); ``offset`` the q tile's first
+    position less the k tile's first key where the diagonal runs through
+    the tile (the causal compare folded in), None for a tile wholly before
+    the q tile."""
+    kept = jax.lax.ne(keep.astype(jnp.int32), np.int32(0))
+    if offset is not None:
+        row = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, keep.shape, 1)
+        kept = jax.lax.bitwise_and(
+            kept, jax.lax.ge(jax.lax.add(row, offset), col))
+    return jnp.where(kept, jnp.float32(0.0), jnp.float32(NEG_INF))
+
+
+def _select_scores(q, k_blk, bias, *, group, scale):
+    """[group * rows, width] float32 scores of a q tile against a k tile's
+    first ``width`` keys, the dropped pairs at ``NEG_INF``: the one bias of
+    [rows, width] under each of the group's heads (the split of the leading
+    dimension at whole sublane tiles moves nothing)."""
+    s = jnp.float32(scale) * jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    if group == 1:
+        return s + bias
+    return (s.reshape((group,) + bias.shape) + bias).reshape(s.shape)
+
+
+def _select_steps(step, keep_ref, qi, ki, *, rows, block_k, edge):
+    """Run ``step(width, bias)`` for k tile ki of q tile qi at what it
+    holds: nothing for a tile past the q tile's positions; the keep-mask
+    alone, no position compared, for a tile wholly before them; and the
+    tile that holds them (``rows`` divides ``block_k``: there is one) over
+    its first column blocks of ``edge`` that a row can see, one ``pl.when``
+    case a width (``select_edge``; whole under ``edge`` 0), the causal
+    compare in that step's bias."""
+    first = affine(qi, rows)
+    diagonal = jax.lax.div(first, np.int32(block_k))
+    offset = jax.lax.sub(first, affine(ki, block_k))
+
+    @pl.when(jax.lax.lt(ki, diagonal))
+    def _():
+        step(block_k, _select_bias(keep_ref[0]))
+
+    edge = edge or block_k
+    # rows divides edge: a q tile's positions lie in ONE column block
+    blocks = jax.lax.add(jax.lax.div(offset, np.int32(edge)), np.int32(1))
+    for n in range(1, block_k // edge + 1):
+        @pl.when(jax.lax.bitwise_and(jax.lax.eq(ki, diagonal),
+                                     jax.lax.eq(blocks, np.int32(n))))
+        def _(width=n * edge):
+            step(width, _select_bias(keep_ref[0, :, :width], offset))
 
 
 def _select_fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, l_ref, acc,
-                       m_s, l_s, *, rows, group, block_k, scale):
+                       m_s, l_s, *, rows, group, block_k, scale, edge):
+    """The softmax of a q tile over its k tiles from the diagonal down, as
+    the backward walks them: a q tile's dead steps come first and its last
+    step computes, so the next q tile's operands arrive under it (a walk
+    upwards left that wait bare, 0.36 ms of a 5.3 ms call: PERF.md section
+    7, PR 77)."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    j = pl.program_id(2)
+    ki = jax.lax.sub(pl.num_programs(2) - 1, j)
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _():
         acc[...] = jnp.zeros_like(acc)
-        m_s[...] = jnp.full_like(m_s, jnp.float32(NEG_INF))
+        # a row's maximum starts at, and so stays above, a finite floor:
+        # before its first kept key exp(NEG_INF - floor) is 0 and no
+        # dropped pair weighs anything, with no select on p
+        m_s[...] = jnp.full_like(m_s, jnp.float32(SELECT_FLOOR))
         l_s[...] = jnp.zeros_like(l_s)
 
-    @pl.when(_select_live(qi, ki, rows, block_k))
-    def _():
-        v_blk = v_ref[0]
-        s = jnp.float32(scale) * jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        mask = _select_mask(keep_ref, qi, ki, rows=rows, group=group,
-                            block_k=block_k)
-        s = jnp.where(mask, s, jnp.float32(NEG_INF))
+    def step(width, bias):
+        v_blk = v_ref[0, :width]
+        s = _select_scores(q_ref[0], k_ref[0, :width], bias, group=group,
+                           scale=scale)
         m_prev = m_s[...]
         m_cur = jax.lax.max(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jax.lax.exp(m_prev - m_cur)
-        # a row may keep no key of a tile before its first kept one: its
-        # maximum is still NEG_INF there and exp(0) is no weight
-        p = jnp.where(mask, jax.lax.exp(s - m_cur), jnp.float32(0.0))
+        p = jax.lax.exp(s - m_cur)
         l_s[...] = l_s[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         m_s[...] = m_cur
         acc[...] = acc[...] * alpha + jax.lax.dot_general(
             p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    _select_steps(step, keep_ref, qi, ki, rows=rows, block_k=block_k,
+                  edge=edge)
+
+    @pl.when(j == pl.num_programs(2) - 1)
     def _():
         l_fin = l_s[...]
         safe_l = jnp.where(l_fin > 0, l_fin, jnp.float32(1.0))
@@ -1215,7 +1290,7 @@ def _select_fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, l_ref, acc,
 
 def _select_bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, l_ref, d_ref,
                        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                       rows, group, block_k, scale):
+                       rows, group, block_k, scale, edge):
     """dq, dk and dv in one pass, as ``_bwd_fused_kernel`` makes them: a q
     tile's k tiles from the diagonal down, dq in tile-sized scratch over
     the inner steps, the key/value head's dK and dV whole in float32
@@ -1247,28 +1322,28 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, l_ref, d_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(_select_live(qi, ki, rows, block_k))
-    def _():
-        q, k_blk, do = q_ref[0], k_ref[0], do_ref[0]
-        s = jnp.float32(scale) * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        mask = _select_mask(keep_ref, qi, ki, rows=rows, group=group,
-                            block_k=block_k)
-        p = jnp.where(mask, jax.lax.exp(s - l_ref[0]), jnp.float32(0.0))
+    def step(width, bias):
+        q, k_blk, do = q_ref[0], k_ref[0, :width], do_ref[0]
+        s = _select_scores(q, k_blk, bias, group=group, scale=scale)
+        # a dropped pair's NEG_INF less any row's lse (the floor at the
+        # least) is no weight
+        p = jax.lax.exp(s - l_ref[0])
         dp = jax.lax.dot_general(
-            do, v_ref[0], (((1,), (1,)), ((), ())),
+            do, v_ref[0, :width], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = (p * (dp - d_ref[0])).astype(q.dtype)
-        dv_acc[ki] = dv_acc[ki] + jax.lax.dot_general(
+        dv_acc[ki, :width] = dv_acc[ki, :width] + jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dk_acc[ki] = dk_acc[ki] + jax.lax.dot_general(
+        dk_acc[ki, :width] = dk_acc[ki, :width] + jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
             ds, k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _select_steps(step, keep_ref, qi, ki, rows=rows, block_k=block_k,
+                  edge=edge)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
@@ -1283,24 +1358,24 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, l_ref, d_ref,
         each_k_tile(write)
 
 
-def _select_name(which, dtype, block_q, block_k, group):
-    return "flashsel_%s_%s_q%d_k%d_g%d" % (
-        which, operand_label(dtype), block_q, block_k, group)
+def _select_name(which, dtype, block_q, block_k, group, edge):
+    return "flashsel_%s_%s_q%d_k%d_g%d%s" % (
+        which, operand_label(dtype), block_q, block_k, group,
+        "_e%d" % edge if edge else "")
 
 
-def _select_specs(rows, group, block_k, d, dv, kv_heads, steps=0):
+def _select_specs(rows, group, block_k, d, dv, kv_heads, steps):
     """Block specs of (a q-shaped tile, a k tile, their value-width twins,
     a statistic a row, the keep-mask's tile) at grid step (batch x
-    key/value head, q tile, k step); with ``steps`` the inner steps walk a
-    q tile's k tiles downwards. A dead step names the q tile's last live k
-    tile, already resident, and fetches nothing."""
+    key/value head, q tile, k step): the ``steps`` inner steps walk a q
+    tile's k tiles downwards. A dead step names the q tile's last live k
+    tile, the one its first live step takes, and fetches nothing more."""
     block_q = rows * group
 
     def k_tile(i, j):
-        if steps:
-            j = jax.lax.sub(np.int32(steps - 1), j)
-        return jax.lax.min(j, jax.lax.div(affine(i, rows, rows - 1),
-                                          np.int32(block_k)))
+        return jax.lax.min(jax.lax.sub(np.int32(steps - 1), j),
+                           jax.lax.div(affine(i, rows, rows - 1),
+                                       np.int32(block_k)))
 
     def q_idx(b, i, j):
         return (b, i, 0)
@@ -1319,25 +1394,27 @@ def _select_specs(rows, group, block_k, d, dv, kv_heads, steps=0):
             pl.BlockSpec((1, rows, block_k), keep_idx))
 
 
-_SELECT_STATIC = ("rows", "group", "block_k", "scale", "interpret")
+_SELECT_STATIC = ("rows", "group", "block_k", "scale", "edge", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=_SELECT_STATIC)
 def select_fwd_call(q3, k3, v3, keep, *, rows, group, block_k, scale,
-                    interpret):
+                    edge, interpret):
     """q3 [B G, group T, D] (``_group_rows``), k3 [B G, T, D], v3 [B G, T,
     Dv], keep [B, T, T] int8 -> o [B G, group T, Dv] and lse [B G, group
-    T, 1] float32, rows as q3's."""
-    _M_SELECT_TRACES.inc(**{"pass": "fwd"}, group=group, rows=rows)
+    T, 1] float32, rows as q3's; ``edge`` as ``select_edge`` gives it a
+    pass."""
+    _M_SELECT_TRACES.inc(**{"pass": "fwd"}, group=group, rows=rows,
+                         edge=edge)
     bg, t_pad, d = k3.shape
     dv = v3.shape[2]
     block_q = rows * group
     q_spec, k_spec, o_spec, v_spec, row_spec, keep_spec = _select_specs(
-        rows, group, block_k, d, dv, bg // keep.shape[0])
+        rows, group, block_k, d, dv, bg // keep.shape[0], t_pad // block_k)
     with no_x64():
         return pl.pallas_call(
             functools.partial(_select_fwd_kernel, rows=rows, group=group,
-                              block_k=block_k, scale=scale),
+                              block_k=block_k, scale=scale, edge=edge),
             grid=(bg, t_pad // rows, t_pad // block_k),
             in_specs=[q_spec, k_spec, v_spec, keep_spec],
             out_specs=[o_spec, row_spec],
@@ -1353,22 +1430,24 @@ def select_fwd_call(q3, k3, v3, keep, *, rows, group, block_k, scale,
                 vmem_limit_bytes=max(VMEM_SCOPED_DEFAULT, flash_vmem_bytes(
                     block_q, block_k, max(d, dv), q3.dtype.itemsize,
                     select_rows=rows))),
-            name=_select_name("fwd", q3.dtype, block_q, block_k, group),
+            name=_select_name("fwd", q3.dtype, block_q, block_k, group,
+                              edge),
             interpret=interpret,
         )(q3, k3, v3, keep)
 
 
 @functools.partial(jax.jit, static_argnames=_SELECT_STATIC)
 def select_bwd_call(q3, k3, v3, keep, do3, lse, delta, *, rows, group,
-                    block_k, scale, interpret):
+                    block_k, scale, edge, interpret):
     """-> dq, dk and dv, shaped and typed as q3, k3 and v3."""
-    _M_SELECT_TRACES.inc(**{"pass": "bwd"}, group=group, rows=rows)
+    _M_SELECT_TRACES.inc(**{"pass": "bwd"}, group=group, rows=rows,
+                         edge=edge)
     bg, t_pad, d = k3.shape
     dv = v3.shape[2]
     block_q = rows * group
     nk = t_pad // block_k
     q_spec, k_spec, o_spec, v_spec, row_spec, keep_spec = _select_specs(
-        rows, group, block_k, d, dv, bg // keep.shape[0], steps=nk)
+        rows, group, block_k, d, dv, bg // keep.shape[0], nk)
 
     def whole_head(width):
         return pl.BlockSpec((1, nk, block_k, width),
@@ -1377,7 +1456,7 @@ def select_bwd_call(q3, k3, v3, keep, do3, lse, delta, *, rows, group,
     with no_x64():
         dq, dk, dv_ = pl.pallas_call(
             functools.partial(_select_bwd_kernel, rows=rows, group=group,
-                              block_k=block_k, scale=scale),
+                              block_k=block_k, scale=scale, edge=edge),
             grid=(bg, t_pad // rows, nk),
             in_specs=[q_spec, k_spec, v_spec, keep_spec, o_spec, row_spec,
                       row_spec],
@@ -1396,7 +1475,8 @@ def select_bwd_call(q3, k3, v3, keep, do3, lse, delta, *, rows, group,
                 vmem_limit_bytes=flash_vmem_bytes(
                     block_q, block_k, max(d, dv), q3.dtype.itemsize,
                     resident=(t_pad, d, dv), select_rows=rows)),
-            name=_select_name("bwd", q3.dtype, block_q, block_k, group),
+            name=_select_name("bwd", q3.dtype, block_q, block_k, group,
+                              edge),
             interpret=interpret,
         )(q3, k3, v3, keep, do3, lse, delta)
     return dq, dk.reshape(bg, t_pad, d), dv_.reshape(bg, t_pad, dv)
@@ -1469,7 +1549,8 @@ def _select_fwd(q3, k3, v3, keep, rows, group, block_k, scale, interpret):
 
     out, lse = on_tpu(
         functools.partial(select_fwd_call, rows=rows, group=group,
-                          block_k=block_k, scale=scale),
+                          block_k=block_k, scale=scale,
+                          edge=select_edge("fwd", rows, block_k)),
         plain, interpret, q3, k3, v3, keep)
     return out, (q3, k3, v3, keep, out, lse)
 
@@ -1482,7 +1563,8 @@ def _select_bwd(rows, group, block_k, scale, interpret, res, g):
                         axis=-1, keepdims=True)
         return select_bwd_call(
             q3, k3, v3, keep, g.astype(q3.dtype), lse, delta, rows=rows,
-            group=group, block_k=block_k, scale=scale, interpret=interpret)
+            group=group, block_k=block_k, scale=scale,
+            edge=select_edge("bwd", rows, block_k), interpret=interpret)
 
     def plain(q3, k3, v3, keep, out, lse, g):
         return jax.vjp(
@@ -1514,11 +1596,15 @@ def flash_select(q, k, v, keep, scale=None, interpret=False):
     block_k] tile of the mask for the whole group, its two products into
     dK and dV sum over the group, and the kernels are ``flash_attention``'s
     arithmetic on [block_q, block_k] scores (``flashsel_fwd_`` /
-    ``flashsel_bwd_<operands>_q<block_q>_k<block_k>_g<group>``; the
-    backward is one pass, dK and dV of a key/value head resident). Every
-    live causal tile is computed and masked, none by quarters: a selection
-    scattered over a row's keys leaves no tile to skip. T is padded to
-    whole tiles with rows and keys the mask drops. Mosaic where the
+    ``flashsel_bwd_<operands>_q<block_q>_k<block_k>_g<group>[_e<width>]``;
+    the backward is one pass, dK and dV of a key/value head resident). A
+    selection scattered over a row's keys leaves no tile to skip: every
+    live causal tile is computed, under the mask's tile as ONE float32
+    bias of [rows, block_k] added beneath each head's scores
+    (``_select_bias``), and the backward runs the q tile's diagonal k tile
+    over the column blocks of ``select_edge`` its rows can see
+    (``_select_steps``). T is padded to whole tiles with rows and keys the
+    mask drops. Mosaic where the
     computation is lowered for the TPU and ``kept_attention`` on every
     other platform, the choice made inside the ``custom_vjp``;
     ``interpret=True`` (the kernels' tests) runs the kernels through the

@@ -297,9 +297,11 @@ def test_the_selected_pair_compiles_at_the_keye_cells_shape(one_chip):
     bf16, 32 query heads on 4 key/value heads of 128 under an int8
     keep-mask): the selected pair ``flashsel_*``, once each way, a q tile
     of 1,024 rows = 128 positions of each of a group's 8 heads against k
-    tiles of 1,024, under the VMEM ``flash_vmem_bytes(select_rows=)``
-    counts (36.25 MiB for the one-pass backward), and no kernel of the
-    unselected pair."""
+    tiles of 1,024 whose diagonal tile the backward runs by the column
+    blocks of ``select_edge``, under the VMEM
+    ``flash_vmem_bytes(select_rows=)`` counts (the keep-mask's tile at its
+    own size: nothing of the group's), and no kernel of the unselected
+    pair."""
     from mxnet_tpu.ops.transformer import attention as attention_ops
 
     t, h, g, d = 8192, 32, 4, 128
@@ -317,10 +319,15 @@ def test_the_selected_pair_compiles_at_the_keye_cells_shape(one_chip):
         shape(1, t, t, dtype=jnp.int8)).compile().as_text()
     assert pk.flash.select_tiles(t, h // g, d, d, jnp.bfloat16) == (
         128, 1024, t)
+    assert pk.flash.select_edge("fwd", 128, 1024) == 0
+    assert 128 <= pk.flash.select_edge("bwd", 128, 1024) < 1024
     calls = [line for line in text.splitlines() if "custom-call(" in line]
     for which, resident in (("fwd", None), ("bwd", (t, d, d))):
-        mine = [c for c in calls
-                if "flashsel_%s_bf16_q1024_k1024_g8" % which in c]
+        name = pk.flash._select_name(
+            which, jnp.bfloat16, 1024, 1024, 8,
+            pk.flash.select_edge(which, 128, 1024))
+        assert name.startswith("flashsel_%s_bf16_q1024_k1024_g8" % which)
+        mine = [c for c in calls if name + "/" in c]
         assert len(mine) == 1
         limit, used = (
             int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
@@ -331,9 +338,6 @@ def test_the_selected_pair_compiles_at_the_keye_cells_shape(one_chip):
                                           resident=resident, select_rows=128)
         assert limit == max(count, pk.common.VMEM_SCOPED_DEFAULT)
         assert used <= limit <= pk.common.VMEM_RAISED_LIMIT
-    assert pk.flash.flash_vmem_bytes(
-        1024, 1024, d, 2, resident=(t, d, d),
-        select_rows=128) == int(36.25 * 2 ** 20)
     assert "flash_fwd_" not in text and "flash_bwd_" not in text
     assert "flash2" not in text
 

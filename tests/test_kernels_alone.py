@@ -18,14 +18,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _OF_A_PEAK = re.compile(
     r"share_of|roofline|bound_|_bound|mxu|required_ms|write_ms|gbs")
 SCRIPTS = ("channel_delta_rule", "embedding_grad", "flash_attention",
-           "flash_window_tiles", "gated_delta_rule", "grouped_matmul",
-           "hyper_mix", "keep_top_k", "latent_flash", "moe_permute", "rope",
-           "ssd_scan")
+           "flash_select", "flash_window_tiles", "gated_delta_rule",
+           "grouped_matmul", "hyper_mix", "keep_top_k", "latent_flash",
+           "moe_permute", "rope", "ssd_scan")
 
 
 @pytest.mark.timeout(60)
 @pytest.mark.parametrize(
-    "script,rehearse", [(s, True) for s in SCRIPTS] + [(SCRIPTS[3], False)],
+    "script,rehearse", [(s, True) for s in SCRIPTS] + [(SCRIPTS[4], False)],
     ids=list(SCRIPTS) + ["off_the_chip_exits_2"])
 def test_script_keeps_the_platform_rule(script, rehearse, tmp_path):
     r = subprocess.run(

@@ -236,6 +236,159 @@ def test_the_selected_pair_matches_kept_attention(case):
         assert np.abs(p - x).max() <= 1e-5 * scale
 
 
+# What a tile step masks and which columns of a q tile's diagonal k tile it
+# computes (PR 77). T 1,024 in float32 is ONE k tile of 1,024 keys under q
+# tiles of 1,024 / group positions: a q tile at every position of its
+# diagonal tile; T 2,000 pads to two k tiles: an interior tile, which takes
+# the keep-mask alone, before the diagonal one.
+# name: (t, heads, kv_heads, dtype, SELECT_EDGE, (rows, edge) the traces
+# must say, what ``_mask_case`` does to the mask, tolerance)
+MASK_CASES = {
+    "edge128": (1024, 8, 1, jnp.float32, 128, (128, 128), None, 2e-5),
+    "edge256": (1024, 8, 1, jnp.float32, 256, (128, 256), None, 2e-5),
+    "edge512": (1024, 8, 1, jnp.float32, 512, (128, 512), None, 2e-5),
+    "bf16_edge256": (1024, 8, 1, jnp.bfloat16, 256, (128, 256), None,
+                     2.0 ** -6),
+    # four heads a group: a q tile's 256 positions are one column block
+    "group4": (1024, 8, 2, jnp.float32, 256, (256, 256), None, 2e-5),
+    # no group: square tiles, nothing lopsided, the tile whole
+    "group1": (1024, 2, 2, jnp.float32, 256, (1024, 0), None, 2e-5),
+    # T no multiple of a tile: padded rows and keys the mask drops
+    "padded": (2000, 8, 1, jnp.float32, 256, (128, 256), None, 2e-5),
+    "no_key_rows": (1024, 8, 1, jnp.float32, 256, (128, 256), "empty_rows",
+                    2e-5),
+    # the finite floor's case: every earlier tile of the row all dropped
+    "first_key_in_last_tile": (2000, 8, 1, jnp.float32, 256, (128, 256),
+                               "late_first_key", 2e-5),
+}
+
+
+def _mask_case(keep, how):
+    keep = np.array(keep)
+    t = keep.shape[1]
+    if how == "empty_rows":
+        keep[:, 300:340] = 0
+        keep[:, 0] = 0
+    elif how == "late_first_key":
+        # the second k tile's rows keep nothing of the first k tile, and
+        # some of them only their own position
+        keep[:, 1024:, :1024] = 0
+        keep[:, 1500:1600] = np.eye(t, dtype=keep.dtype)[1500:1600]
+    return jnp.asarray(keep)
+
+
+@pytest.mark.parametrize("case", sorted(MASK_CASES))
+def test_a_tile_step_masks_at_the_masks_size_and_cuts_the_diagonal(
+        case, monkeypatch):
+    """``flash_select`` through the interpreter against ``kept_attention``,
+    output and dq, dk, dv, where a tile step's mask and columns differ: q
+    tiles at every position ``i % (block_k / rows)`` of their diagonal k
+    tile at each width of its column blocks (``select_edge`` under another
+    ``SELECT_EDGE``; ``attention.select_kernel_traces`` says which width
+    each kernel was traced at: the backward's, the forward's tile whole),
+    groups of 1, 4 and 8, bf16 operands, a
+    padded T, rows that keep no key at all, and rows whose first kept key
+    lies in their last k tile."""
+    t, heads, kv_heads, dtype, min_edge, said, how, tol = MASK_CASES[case]
+    d = 64
+    q, k, v, keep, w = _select_case(11, t, heads, kv_heads, d, d, dtype, 96)
+    keep = _mask_case(keep, how)
+    monkeypatch.setattr(pk.flash, "SELECT_EDGE", min_edge)
+    rows, edge = said
+    group = heads // kv_heads
+    assert pk.flash.select_tiles(t, group, d, d, dtype)[:2] == (rows, 1024)
+    assert pk.flash.select_edge("bwd", rows, 1024) == edge
+    assert pk.flash.select_edge("fwd", rows, 1024) == 0
+
+    def run(fn):
+        def loss(*a):
+            out = fn(*a).astype(jnp.float32)
+            return jnp.sum(out * w), out
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out,) + grads
+
+    for jitted in (pk.flash.select_fwd_call, pk.flash.select_bwd_call):
+        jitted.clear_cache()    # another case's trace is not this one's
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        got = run(lambda q, k, v: pk.flash_select(q, k, v, keep,
+                                                  interpret=True))
+        traces = telemetry.REGISTRY.get("attention.select_kernel_traces")
+        assert [traces.value(**{"pass": which}, group=group, rows=rows,
+                             edge=by)
+                for which, by in (("fwd", 0), ("bwd", edge))] == [1, 1]
+        assert telemetry.total("attention.select_kernel_traces") == 2
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    want = run(lambda q, k, v: pk.kept_attention(q, k, v, keep, d ** -0.5))
+    for g, x in zip(got, want):
+        g, x = (np.asarray(a, np.float32) for a in (g, x))
+        assert np.isfinite(g).all()
+        assert np.abs(g - x).max() <= tol * max(np.abs(x).max(), 1.0), \
+            np.abs(g - x).max()
+    if how == "empty_rows":
+        out, dq = got[:2]
+        assert not np.asarray(out[:, 300:340], np.float32).any()
+        assert not np.asarray(dq[:, 300:340], np.float32).any()
+
+
+def _inner_jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr inside its equations' parameters."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (tuple, list))
+                        else (param,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _inner_jaxprs(sub)
+
+
+@pytest.mark.parametrize("which", ["fwd", "bwd"])
+def test_the_selected_bodies_build_nothing_integer_of_a_tiles_size(which):
+    """The traced body of each selected kernel at the Keye cell's tiles (a
+    q tile of 8 x 128 rows against 1,024 keys, column blocks of 256): no
+    integer or bool value larger than the keep-mask's own [rows, block_k]
+    tile (the mask widened over the group's rows cannot come back unseen),
+    and the diagonal tile's four widths are four products."""
+    t, g, group, rows, block_k, d = 2048, 1, 8, 128, 1024, 128
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    q3, k3, row = shape(g, group * t, d), shape(g, t, d), shape(
+        g, group * t, 1, dtype=jnp.float32)
+    kw = dict(rows=rows, group=group, block_k=block_k, scale=d ** -0.5,
+              edge=256, interpret=False)
+    call, args = {
+        "fwd": (pk.flash.select_fwd_call,
+                (q3, k3, k3, shape(1, t, t, dtype=jnp.int8))),
+        "bwd": (pk.flash.select_bwd_call,
+                (q3, k3, k3, shape(1, t, t, dtype=jnp.int8), q3, row, row)),
+    }[which]
+    kernel, = [eqn.params["jaxpr"]
+               for jaxpr in _inner_jaxprs(
+                   jax.make_jaxpr(lambda *a: call(*a, **kw))(*args).jaxpr)
+               for eqn in jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    widths = set()
+    for jaxpr in _inner_jaxprs(kernel):
+        for eqn in jaxpr.eqns:
+            for var in eqn.outvars:
+                aval = var.aval
+                if not hasattr(aval, "shape"):   # a ref
+                    continue
+                if not jnp.issubdtype(aval.dtype, jnp.floating):
+                    assert np.prod(aval.shape) <= rows * block_k, eqn
+                if (eqn.primitive.name == "dot_general"
+                        and aval.shape[0] == group * rows):
+                    widths.add(aval.shape[1])
+    # [block_q, width] scores (and dP) and the [block_q, d] products
+    assert widths == {256, 512, 768, 1024, d}
+
+
 def test_a_row_that_keeps_nothing_reads_zeros_and_moves_nothing():
     """Rows whose mask is empty (and the padding rows are such rows):
     zeros out, a zero dq, and nothing added to dk or dv."""
@@ -276,13 +429,13 @@ def test_the_selected_pair_takes_what_the_shapes_say(t, heads, kv_heads, d,
 
 def test_the_vmem_count_holds_the_keep_masks_tile():
     """``flash_vmem_bytes(select_rows=)``: two int8 buffers of [rows,
-    block_k] and the mask widened to 32 bits over the group's rows."""
+    block_k] and the tile's float32 bias, nothing of the group's size."""
     base = pk.flash.flash_vmem_bytes(1024, 1024, 128, 2,
                                      resident=(8192, 128, 128))
     got = pk.flash.flash_vmem_bytes(1024, 1024, 128, 2,
                                     resident=(8192, 128, 128),
                                     select_rows=128)
-    assert got - base == 2 * 128 * 1024 + 4 * 1024 * 1024
+    assert got - base == (2 + 4) * 128 * 1024
     assert pk.flash.flash_vmem_bytes(1024, 1024, 128, 2) == \
         pk.flash.flash_vmem_bytes(1024, 1024, 128, 2, select_rows=0)
 

@@ -118,10 +118,11 @@ def _force_mirrored(node):
 
 
 def op_class(op_name):
-    """conv | fc | bn | pool | act | loss | attn | ssm | gdn | sconv | moe |
-    norm | embed | hc | other: the class a node's device ops are filed under
-    (the first part of its named scope). An op states its class where it is
-    registered (``OpDef(op_class=)``); the rest go by two rules of name."""
+    """conv | fc | bn | pool | act | loss | attn | ssm | linattn | gdn |
+    sconv | moe | norm | embed | hc | other: the class a node's device ops
+    are filed under (the first part of its named scope). An op states its
+    class where it is registered (``OpDef(op_class=)``); the rest go by two
+    rules of name."""
     cls = (_registry.get(op_name).op_class if _registry.exists(op_name)
            else None)
     if cls is not None:

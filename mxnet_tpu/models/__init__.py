@@ -53,8 +53,12 @@ head after every pass, the exit-weighted loss; every weight one
 ``ouro_reference``. ``keye_vl2`` is Keye-VL-2.0-30B-A3B's language model
 (grouped attention under per-head norms that reads only the 2,048 keys a
 16-head ``KeyIndexer`` picks, in every layer, over 128 softmax-routed
-experts), whole or as a share, with ``keye_vl2_reference``; ``lm_blocks``
-holds what the LM symbols share.
+experts), whole or as a share, with ``keye_vl2_reference``.
+``minicpm_sala`` is MiniCPM-SALA (Lightning linear attention with a fixed
+decay a head in three layers of four, InfLLM-V2 block-sparse attention that
+chooses 64 blocks of 64 keys from mean-pooled keys in the fourth, MiniCPM's
+scaling), whole or as one of two chips sharing a layer, with
+``minicpm_sala_reference``; ``lm_blocks`` holds what the LM symbols share.
 """
 from .mlp import get_symbol as mlp
 from .lenet import get_symbol as lenet
@@ -71,7 +75,8 @@ from .transformer import transformer_lm
 from . import (afmoe, afmoe_reference, dots3, dots3_reference, falcon_h1,
                falcon_h1_reference, kanana2, kanana2_reference, kimi_linear,
                keye_vl2, keye_vl2_reference, kimi_linear_reference, lfm2,
-               lfm2_reference, mimo_v2, mimo_v2_reference, nemotron_h,
+               lfm2_reference, mimo_v2, mimo_v2_reference, minicpm_sala,
+               minicpm_sala_reference, nemotron_h,
                nemotron_h_reference, olmo_hybrid, olmo_hybrid_reference,
                olmoe, olmoe_reference, ouro, ouro_reference, solar_open2,
                solar_open2_reference)

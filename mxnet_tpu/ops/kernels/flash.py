@@ -72,7 +72,12 @@ places, all below:
             first column blocks of ``select_edge`` that a row can see
             (a kernel that does is named ``..._e<width>``). Both
             passes walk a q tile's k tiles from the diagonal down, so
-            its dead steps come first.
+            its dead steps come first. Where a key/value head's dK / dV
+            do not fit VMEM whole (``select_range``: T 16,384 at a group
+            of 16), the backward keeps them a RANGE of keys at a time
+            (``flashsel_bwd_..._r<keys>``): the same five products, the
+            q tiles walked once a range, dq a float32 partial sum a
+            range that XLA adds up, so its VMEM does not grow with T.
 
 forward / dq / the one-pass backward: grid (B*H, nq, nk), k innermost;
 dkv: grid (B*G, nk, group * nq).
@@ -160,6 +165,7 @@ def flash_vmem_bytes(block_q, block_k, d, itemsize, resident=None,
     one pass, the same, 256 x 256 under a window of 128     14.75    9
     one pass, T 4096, 128 / 128, float32                    31       27
     selected one pass, T 8192, 128 / 128, bf16, 8 on 1     32.75    27.3 (*)
+    selected by ranges of 8192, T 16384, bf16, 16 on 1     33.4     27.7 (*)
     ====================================================  =======  ======
 
     (*) what Mosaic used of its own count when compiled for a described
@@ -1124,7 +1130,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 _M_SELECT_TRACES = _tm.counter(
     "attention.select_kernel_traces", "Traces of a selected flash kernel's "
     "pallas_call (one a signature and process, however many Attention "
-    "nodes call it; nothing per step); labels: pass (fwd / bwd), group "
+    "nodes call it; nothing per step); labels: pass (fwd / bwd, or "
+    "bwd_ranges: the backward that keeps dK / dV a range of keys at a "
+    "time, with its label range, the keys of one), group "
     "(query heads a key/value head), rows (a head's rows of a q tile), edge "
     "(the width of the column blocks a q tile's diagonal k tile runs by, 0 "
     "where it runs whole)")
@@ -1146,8 +1154,9 @@ def select_tiles(t, group, d, dv, dtype):
     for the shapes: ``flash_tiles``' q tile is shared out among the
     group's heads, ``rows`` = block_q / group positions each (whole int8
     tiles of the mask), against ``flash_tiles``' k tile; T a tile at
-    least, an operand type Mosaic takes, and a key/value head's dK / dV
-    resident for the one-pass backward (the only one there is)."""
+    least, an operand type Mosaic takes, and a backward whose dK / dV fit
+    VMEM: a key/value head's whole (one pass) or a range of its keys at a
+    time (``select_range``)."""
     if (t < FLASH_MIN_BLOCK
             or jnp.dtype(dtype).name not in ("bfloat16", "float32")):
         return None
@@ -1157,10 +1166,37 @@ def select_tiles(t, group, d, dv, dtype):
         return None
     mult = int(np.lcm(rows, block_k))
     t_pad = -(-t // mult) * mult
-    if not bwd_fuses(t_pad, block_q, block_k, d, dv, dtype,
-                     select_rows=rows):
+    if not select_range(t_pad, rows, group, block_k, d, dv, dtype):
         return None
     return rows, block_k, t_pad
+
+
+def select_range(t_pad, rows, group, block_k, d, dv, dtype):
+    """The keys whose dK / dV the selected backward keeps in VMEM at a time:
+    ``t_pad`` where a key/value head's fit whole beside a step's tiles (the
+    one-pass backward, ``bwd_fuses``: every sequence it admitted before
+    there were ranges), else the most k tiles that divide the sequence and
+    fit beside a step's tiles and the float32 block of dq's partial sum;
+    0 where not even one tile does. Shapes and operand type alone decide."""
+    block_q, nk = rows * group, t_pad // block_k
+    if bwd_fuses(t_pad, block_q, block_k, d, dv, dtype, select_rows=rows):
+        return t_pad
+    for tiles in range(nk - 1, 0, -1):
+        if nk % tiles == 0 and select_range_vmem_bytes(
+                tiles * block_k, rows, group, block_k, d, dv,
+                jnp.dtype(dtype).itemsize) <= VMEM_RAISED_LIMIT:
+            return tiles * block_k
+    return 0
+
+
+def select_range_vmem_bytes(keys, rows, group, block_k, d, dv, itemsize):
+    """What a step of the ranged backward holds: ``flash_vmem_bytes`` with
+    ``keys`` keys of dK / dV resident, and the two float32 buffers of dq's
+    partial-sum block."""
+    block_q = rows * group
+    return 2 * block_q * whole_lanes(d) * 4 + flash_vmem_bytes(
+        block_q, block_k, max(d, dv), itemsize, resident=(keys, d, dv),
+        select_rows=rows)
 
 
 def flash_select_takes(t, heads, kv_heads, d, dv, dtype):
@@ -1288,6 +1324,33 @@ def _select_fwd_kernel(q_ref, k_ref, v_ref, keep_ref, o_ref, l_ref, acc,
         l_ref[0] = m_s[...] + jnp.log(safe_l)
 
 
+def _select_bwd_step(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_acc,
+                     dk_acc, dv_acc, at, *, group, scale):
+    """``step(width, bias)`` of a backward tile step, both backwards': P and
+    dS once, then the three products into dv_acc[at] and dk_acc[at] (the k
+    tile's place in the resident scratch) and dq_acc."""
+    def step(width, bias):
+        q, k_blk, do = q_ref[0], k_ref[0, :width], do_ref[0]
+        s = _select_scores(q, k_blk, bias, group=group, scale=scale)
+        # a dropped pair's NEG_INF less any row's lse (the floor at the
+        # least) is no weight
+        p = jax.lax.exp(s - l_ref[0])
+        dp = jax.lax.dot_general(
+            do, v_ref[0, :width], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = (p * (dp - d_ref[0])).astype(q.dtype)
+        dv_acc[at, :width] = dv_acc[at, :width] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_acc[at, :width] = dk_acc[at, :width] + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
+            ds, k_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return step
+
+
 def _select_bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, l_ref, d_ref,
                        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
                        rows, group, block_k, scale, edge):
@@ -1322,26 +1385,8 @@ def _select_bwd_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, l_ref, d_ref,
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def step(width, bias):
-        q, k_blk, do = q_ref[0], k_ref[0, :width], do_ref[0]
-        s = _select_scores(q, k_blk, bias, group=group, scale=scale)
-        # a dropped pair's NEG_INF less any row's lse (the floor at the
-        # least) is no weight
-        p = jax.lax.exp(s - l_ref[0])
-        dp = jax.lax.dot_general(
-            do, v_ref[0, :width], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - d_ref[0])).astype(q.dtype)
-        dv_acc[ki, :width] = dv_acc[ki, :width] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_acc[ki, :width] = dk_acc[ki, :width] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dq_acc[...] = dq_acc[...] + jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
+    step = _select_bwd_step(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_acc,
+                            dk_acc, dv_acc, ki, group=group, scale=scale)
     _select_steps(step, keep_ref, qi, ki, rows=rows, block_k=block_k,
                   edge=edge)
 
@@ -1482,6 +1527,139 @@ def select_bwd_call(q3, k3, v3, keep, do3, lse, delta, *, rows, group,
     return dq, dk.reshape(bg, t_pad, d), dv_.reshape(bg, t_pad, dv)
 
 
+def _select_bwd_range_kernel(q_ref, k_ref, v_ref, keep_ref, do_ref, l_ref,
+                             d_ref, dq_ref, dk_ref, dv_ref, dq_acc, dk_acc,
+                             dv_acc, *, rows, group, block_k, scale, edge):
+    """``_select_bwd_kernel`` for a sequence whose dK / dV do not fit VMEM
+    whole: grid (batch x key/value head, key range, q tile, k step). A
+    range's dK and dV stay in float32 scratch while EVERY q tile walks the
+    range's k tiles from its diagonal (or the range's last tile) down; dq is
+    a range's partial sum, written float32 a (range, q tile) and summed
+    outside. A q tile wholly before the range computes nothing and writes
+    zeros."""
+    r = pl.program_id(1)
+    qi = pl.program_id(2)
+    j = pl.program_id(3)
+    tiles = dk_acc.shape[0]
+    local = jax.lax.sub(np.int32(tiles - 1), j)
+    ki = jax.lax.add(affine(r, tiles), local)
+    first = jax.lax.bitwise_and(jax.lax.eq(qi, np.int32(0)),
+                                jax.lax.eq(j, np.int32(0)))
+    last = jax.lax.bitwise_and(
+        jax.lax.eq(qi, pl.num_programs(2) - 1),
+        jax.lax.eq(j, np.int32(tiles - 1)))
+
+    def each_k_tile(fn):
+        def step(i, carry):
+            fn(i)
+            return carry
+        jax.lax.fori_loop(0, tiles, step, 0)
+
+    @pl.when(first)
+    def _():
+        def zero(i):
+            dk_acc[i] = jnp.zeros(dk_acc.shape[1:], jnp.float32)
+            dv_acc[i] = jnp.zeros(dv_acc.shape[1:], jnp.float32)
+        each_k_tile(zero)
+
+    @pl.when(j == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    step = _select_bwd_step(q_ref, k_ref, v_ref, do_ref, l_ref, d_ref, dq_acc,
+                            dk_acc, dv_acc, local, group=group, scale=scale)
+    _select_steps(step, keep_ref, qi, ki, rows=rows, block_k=block_k,
+                  edge=edge)
+
+    @pl.when(j == tiles - 1)
+    def _():
+        dq_ref[0, 0] = jnp.float32(scale) * dq_acc[...]
+
+    @pl.when(last)
+    def _():
+        def write(i):
+            dk_ref[0, i] = (jnp.float32(scale) * dk_acc[i]).astype(
+                dk_ref.dtype)
+            dv_ref[0, i] = dv_acc[i].astype(dv_ref.dtype)
+        each_k_tile(write)
+
+
+@functools.partial(jax.jit, static_argnames=_SELECT_STATIC + ("keys",))
+def select_bwd_range_call(q3, k3, v3, keep, do3, lse, delta, *, rows, group,
+                          block_k, scale, edge, keys, interpret):
+    """``select_bwd_call`` with dK / dV resident ``keys`` keys at a time
+    (``select_range``; whole k tiles that divide the sequence) -> dq, dk and
+    dv, shaped and typed as q3, k3 and v3."""
+    _M_SELECT_TRACES.inc(**{"pass": "bwd_ranges"}, group=group, rows=rows,
+                         edge=edge, range=keys)
+    bg, t_pad, d = k3.shape
+    dv = v3.shape[2]
+    block_q = rows * group
+    nk, tiles = t_pad // block_k, keys // block_k
+    ranges = nk // tiles
+    kv_heads = bg // keep.shape[0]
+
+    def q_tile(r, i):  # a q tile before the range names the range's first
+        return jax.lax.max(i, affine(r, keys // rows))
+
+    def k_tile(r, i, j):  # the walk down from the diagonal, inside the range
+        top = jax.lax.min(
+            jax.lax.add(affine(r, tiles), jax.lax.sub(np.int32(tiles - 1), j)),
+            jax.lax.div(affine(i, rows, rows - 1), np.int32(block_k)))
+        return jax.lax.max(top, affine(r, tiles))
+
+    def q_idx(b, r, i, j):
+        return (b, q_tile(r, i), 0)
+
+    def k_idx(b, r, i, j):
+        return (b, k_tile(r, i, j), 0)
+
+    def keep_idx(b, r, i, j):
+        return (jax.lax.div(b, np.int32(kv_heads)), q_tile(r, i),
+                k_tile(r, i, j))
+
+    def a_range(width):
+        return pl.BlockSpec((1, tiles, block_k, width),
+                            lambda b, r, i, j: (b, r, 0, 0))
+
+    with no_x64():
+        dq, dk, dv_ = pl.pallas_call(
+            functools.partial(_select_bwd_range_kernel, rows=rows,
+                              group=group, block_k=block_k, scale=scale,
+                              edge=edge),
+            grid=(bg, ranges, t_pad // rows, tiles),
+            in_specs=[pl.BlockSpec((1, block_q, d), q_idx),
+                      pl.BlockSpec((1, block_k, d), k_idx),
+                      pl.BlockSpec((1, block_k, dv), k_idx),
+                      pl.BlockSpec((1, rows, block_k), keep_idx),
+                      pl.BlockSpec((1, block_q, dv), q_idx),
+                      pl.BlockSpec((1, block_q, 1), q_idx),
+                      pl.BlockSpec((1, block_q, 1), q_idx)],
+            out_specs=[pl.BlockSpec((1, 1, block_q, d),
+                                    lambda b, r, i, j: (r, b, i, 0)),
+                       a_range(d), a_range(dv)],
+            out_shape=[
+                jax.ShapeDtypeStruct((ranges,) + q3.shape, jnp.float32),
+                jax.ShapeDtypeStruct((bg, nk, block_k, d), q3.dtype),
+                jax.ShapeDtypeStruct((bg, nk, block_k, dv), q3.dtype)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, d), jnp.float32),
+                pltpu.VMEM((tiles, block_k, d), jnp.float32),
+                pltpu.VMEM((tiles, block_k, dv), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                # dk / dv accumulate over a range's q tiles
+                dimension_semantics=("parallel", "arbitrary", "arbitrary",
+                                     "arbitrary"),
+                vmem_limit_bytes=select_range_vmem_bytes(
+                    keys, rows, group, block_k, d, dv, q3.dtype.itemsize)),
+            name=_select_name("bwd", q3.dtype, block_q, block_k, group,
+                              edge) + "_r%d" % keys,
+            interpret=interpret,
+        )(q3, k3, v3, keep, do3, lse, delta)
+    return (jnp.sum(dq, axis=0).astype(q3.dtype), dk.reshape(bg, t_pad, d),
+            dv_.reshape(bg, t_pad, dv))
+
+
 def _group_rows(x, kv_heads, rows):
     """x [B, T, H, D] -> [B G, (T / rows) group rows, D]: the ``rows``
     positions of a q tile of each of a group's heads one under another,
@@ -1501,11 +1679,32 @@ def _ungroup_rows(x, batch, rows, group):
                                                  d)
 
 
+# The most bytes of float32 scores [B, H, T, T] ``kept_attention`` builds
+# when it is called by name: 16 heads at T 8,192. Past it a call site that
+# has no selected kernels for its shapes is told so, where it used to ask
+# the device for the scores (17 GB at 16 heads and T 16,384).
+KEPT_SCORES_LIMIT = 1 << 32
+
+
 def kept_attention(q, k, v, keep, scale):
     """``reference_attention``'s causal arithmetic under a keep-mask
     besides: q [B, T, H, D], k [B, T, G, D], v [B, T, G, Dv] (H a multiple
     of G), keep [B, T, T] (0 drops the pair) -> [B, T, H, Dv].
-    Materialised float32 scores; a row that keeps no key gives zeros."""
+    Materialised float32 scores; a row that keeps no key gives zeros.
+    Raises where the scores would pass ``KEPT_SCORES_LIMIT`` bytes."""
+    b, t, h = q.shape[:3]
+    if 4 * b * h * t * t > KEPT_SCORES_LIMIT:
+        raise ValueError(
+            "kept_attention: float32 scores [%d, %d, %d, %d] are %.1f GB, "
+            "over KEPT_SCORES_LIMIT (%.1f GB): these shapes need the "
+            "selected flash pair (flash_select_takes)"
+            % (b, h, t, t, 4e-9 * b * h * t * t, 1e-9 * KEPT_SCORES_LIMIT))
+    return _kept_attention(q, k, v, keep, scale)
+
+
+def _kept_attention(q, k, v, keep, scale):
+    """``kept_attention`` at any size: the selected pair's branch off the
+    TPU, which a step lowered for the TPU traces and never runs."""
     group = q.shape[2] // k.shape[2]
     if group > 1:
         k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
@@ -1529,8 +1728,8 @@ def _select_plain(q3, k3, v3, keep, rows, group, scale):
     def heads_last(x):
         return x.reshape(b, kv_heads, *x.shape[1:]).transpose(0, 2, 1, 3)
 
-    out = kept_attention(_ungroup_rows(q3, b, rows, group), heads_last(k3),
-                         heads_last(v3), keep, scale)
+    out = _kept_attention(_ungroup_rows(q3, b, rows, group), heads_last(k3),
+                          heads_last(v3), keep, scale)
     return _group_rows(out, kv_heads, rows)
 
 
@@ -1561,10 +1760,17 @@ def _select_bwd(rows, group, block_k, scale, interpret, res, g):
     def kernels(q3, k3, v3, keep, out, lse, g, interpret):
         delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1, keepdims=True)
+        static = dict(rows=rows, group=group, block_k=block_k, scale=scale,
+                      edge=select_edge("bwd", rows, block_k),
+                      interpret=interpret)
+        keys = select_range(k3.shape[1], rows, group, block_k, q3.shape[2],
+                            v3.shape[2], q3.dtype)
+        if keys < k3.shape[1]:
+            return select_bwd_range_call(
+                q3, k3, v3, keep, g.astype(q3.dtype), lse, delta, keys=keys,
+                **static)
         return select_bwd_call(
-            q3, k3, v3, keep, g.astype(q3.dtype), lse, delta, rows=rows,
-            group=group, block_k=block_k, scale=scale,
-            edge=select_edge("bwd", rows, block_k), interpret=interpret)
+            q3, k3, v3, keep, g.astype(q3.dtype), lse, delta, **static)
 
     def plain(q3, k3, v3, keep, out, lse, g):
         return jax.vjp(
@@ -1596,8 +1802,17 @@ def flash_select(q, k, v, keep, scale=None, interpret=False):
     block_k] tile of the mask for the whole group, its two products into
     dK and dV sum over the group, and the kernels are ``flash_attention``'s
     arithmetic on [block_q, block_k] scores (``flashsel_fwd_`` /
-    ``flashsel_bwd_<operands>_q<block_q>_k<block_k>_g<group>[_e<width>]``;
-    the backward is one pass, dK and dV of a key/value head resident). A
+    ``flashsel_bwd_<operands>_q<block_q>_k<block_k>_g<group>[_e<width>]``).
+    Which backward runs is ``select_range``'s word, from the shapes alone:
+    one pass with dK and dV of a key/value head resident in VMEM wherever
+    they fit beside a step's tiles (``bwd_fuses``: at heads of 128 / 128 in
+    bf16, T 8,192 at a group of 8 and up to 12,288 at a group of 16; every
+    sequence the pair admitted before PR 79), and past that the same five
+    products with dK and dV resident a RANGE of keys at a time
+    (``select_bwd_range_call``, named ``..._r<keys>``: ranges of 8,192 keys
+    at T 16,384 and a group of 16, and at any longer T: its VMEM does not
+    grow with the sequence), each range walking every q tile at or after it
+    and writing dq as a float32 partial sum that XLA adds up. A
     selection scattered over a row's keys leaves no tile to skip: every
     live causal tile is computed, under the mask's tile as ONE float32
     bias of [rows, block_k] added beneath each head's scores

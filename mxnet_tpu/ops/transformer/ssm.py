@@ -8,7 +8,14 @@ pair's einsum form and oracle) and ``ops/kernels/gate_norm.py``.
 projections, the selective scan (a decay a channel AND a state index:
 ``selective_scan`` here is the ``jax.numpy`` form and oracle of
 ``ops/kernels/sscan.py``) and the gate, with the scan's output before the
-gate as a second result."""
+gate as a second result.
+``LinearAttention`` (Lightning Attention, Qin et al., arXiv:2401.04658, as
+MiniMax-01 runs it, arXiv:2501.08313): linear attention with a FIXED decay a
+head, ``S_t = lambda S_{t-1} + k_t v_t^T``, ``o_t = scale S_t^T q_t``. That is
+``Mamba2``'s scan read with other names (``x`` = values, ``B`` = keys, ``C`` =
+queries, a step size of 1, ``a`` = ``-slope``, no skip, as many groups as
+heads), so it runs ``ops/kernels/ssd.py`` and ``ssd_scan`` here as they
+stand, with the norm a head and the sigmoid gate that follow it."""
 from __future__ import annotations
 
 import functools
@@ -19,7 +26,8 @@ import numpy as np
 
 from ... import telemetry as _tm
 from ..registry import OpDef, register
-from ..utils import required_shape
+from ..utils import head_width, required_shape
+from .attention import gate_output
 from .taps import _gate_norm_site, _taps_site, again, causal_taps
 
 
@@ -479,5 +487,142 @@ register(
         infer_shape=_mamba1_infer,
         aliases=("Mamba1",),
         op_class="ssm",
+    )
+)
+
+
+_M_LINATTN_LOWERINGS = _tm.counter(
+    "linattn.lowerings", "Traces of a LinearAttention call site (one per "
+    "lowering, nothing per step); labels: impl (kernel: the ssd pair where "
+    "the step is lowered for the TPU; einsum: the chunked jax.numpy form), "
+    "heads, chunk")
+
+
+def lightning_slopes(heads_of, layer, layers_of, first=0, held=None):
+    """``slope(h, l) = 2^(-8 (h + 1) / H) * (1 - l / (L - 1) + 1e-5)`` for
+    the PUBLISHED head h of H = ``heads_of`` and the published layer l of L
+    = ``layers_of`` (MiniMax-01's modelling code: ALiBi's geometric slopes
+    under a factor that falls with depth): the ``held`` heads from ``first``
+    on, as a tuple of floats."""
+    held = heads_of - first if held is None else held
+    factor = 1.0 - layer / max(layers_of - 1, 1) + 1e-5
+    return tuple(2.0 ** (-8.0 * (h + 1) / heads_of) * factor
+                 for h in range(first, first + held))
+
+
+def linear_attention(query, key, value, slopes, num_heads, chunk_size=128,
+                     norm_gamma=None, gate=None, eps=1e-6, remat=False):
+    """query and key [B, T, H D], value [B, T, H P] (after their norms and
+    rotation), ``slopes`` H positive floats -> [B, T, H P].
+
+    Scope ``core``: the recurrence above, float32 state and result, the
+    products' operands in ``value``'s type; ``D ** -0.5`` multiplies the
+    float32 result. Scope ``norm`` (``norm_gamma`` [P], optional): an
+    RMSNorm over each head's own P columns, one gamma shared by the heads,
+    statistics float32, one rounding before the scale. Scope ``gate`` (``gate`` [B, T, H P], optional): ``o *
+    sigmoid(gate)``, ``attention.gate_output``. ``remat`` (training): norm
+    and gate are computed again in the backward pass from the core's
+    float32 result, which the kernel pair keeps anyway."""
+    from .. import kernels
+
+    d = query.shape[2] // num_heads
+    p = value.shape[2] // num_heads
+    kernel = bool(kernels.ssd_takes(num_heads, p, d, num_heads, chunk_size,
+                                    value.dtype))
+    _M_LINATTN_LOWERINGS.inc(impl="kernel" if kernel else "einsum",
+                             heads=num_heads, chunk=chunk_size)
+    slopes = jnp.asarray(slopes, jnp.float32)
+    if slopes.shape != (num_heads,):
+        raise ValueError("LinearAttention: slopes=%r must be %d floats, one "
+                         "a head" % (slopes.shape, num_heads))
+    extra = {"norm_gamma": norm_gamma, "gate": gate}
+    return _linattn_block(
+        query, key, value, slopes,
+        {name: x for name, x in extra.items() if x is not None},
+        sizes=(num_heads, d, p, int(chunk_size)), scale=float(d) ** -0.5,
+        eps=float(eps), remat=bool(remat), kernel=kernel,
+        interpret=kernels.common.INTERPRET)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "sizes", "scale", "eps", "remat", "kernel", "interpret"))
+def _linattn_block(query, key, value, slopes, extra, *, sizes, scale, eps,
+                   remat, kernel, interpret):
+    """``linear_attention`` for one signature (``sizes``: heads, key width,
+    value width, chunk): a model's layers differ in their slopes alone,
+    which are an operand, so they trace and lower this once."""
+    from .. import kernels
+
+    f32 = jnp.float32
+    h, d, p, chunk = sizes
+    b, t, _ = query.shape
+
+    def heads(x, width):
+        return x.reshape(b, t, h, width)
+
+    with jax.named_scope("core"):
+        q, k, v = heads(query, d), heads(key, d), heads(value, p)
+        dt = jnp.ones((b, t, h), f32)
+        a = -slopes
+        if kernel:
+            y = kernels.ssd_scan(v, k, q, dt, a, jnp.zeros((h,), f32), chunk,
+                                 interpret=interpret)
+        else:
+            y = again(functools.partial(ssd_scan, chunk=chunk), remat,
+                      policy=jax.checkpoint_policies.dots_saveable)(
+                          v, k, q, dt, a)
+        y = y * scale
+
+    def finish(y, extra):
+        out = y
+        if "norm_gamma" in extra:
+            with jax.named_scope("norm"):
+                var = jnp.mean(jnp.square(y), axis=-1, keepdims=True)
+                out = (extra["norm_gamma"].astype(value.dtype)
+                       * (y * jax.lax.rsqrt(var + eps)).astype(value.dtype))
+        out = out.astype(value.dtype).reshape(b, t, h * p)
+        if "gate" in extra:
+            with jax.named_scope("gate"):
+                out = gate_output(out, extra["gate"])
+        return out
+
+    return again(finish, remat)(y, extra)
+
+
+def _linear_attention(attrs, ins, is_train):
+    query, key, value, norm_gamma, gate = ins
+    return [linear_attention(
+        query, key, value, slopes=tuple(attrs["slopes"]),
+        num_heads=int(attrs["num_heads"]), norm_gamma=norm_gamma, gate=gate,
+        eps=float(attrs.get("eps", 1e-6)), remat=is_train)]
+
+
+def _linear_attention_infer(attrs, in_shapes):
+    heads = int(attrs["num_heads"])
+    slopes = tuple(attrs.get("slopes") or ())
+    if heads <= 0 or len(slopes) != heads or min(slopes) <= 0:
+        raise ValueError(
+            "LinearAttention: slopes=%r must be num_heads=%d positive "
+            "floats, a head's fixed log decay a token" % (slopes, heads))
+    q, k, v = (required_shape(shape, "LinearAttention")
+               for shape in in_shapes[:3])
+    head_width("LinearAttention", "query", q, heads)
+    p = head_width("LinearAttention", "value", v, heads)
+    if k != q or v[:2] != q[:2]:
+        raise ValueError(
+            "LinearAttention: key %s must be query's shape %s and value %s "
+            "share its batch and time" % (k, q, v))
+    return [q, k, v, (p,), v], [v], []
+
+
+register(
+    OpDef(
+        "_contrib_LinearAttention",
+        _linear_attention,
+        arguments=("query", "key", "value", "norm", "gate"),
+        defaults={"num_heads": 1, "slopes": (), "eps": 1e-6},
+        infer_shape=_linear_attention_infer,
+        aliases=("LinearAttention",),
+        op_class="linattn",
     )
 )

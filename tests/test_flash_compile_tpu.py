@@ -342,6 +342,56 @@ def test_the_selected_pair_compiles_at_the_keye_cells_shape(one_chip):
     assert "flash2" not in text
 
 
+def test_the_selected_pair_compiles_at_16k_with_a_ranged_backward(one_chip):
+    """``Attention(keep=)`` as the MiniCPM-SALA cell calls it (T 16,384,
+    bf16, 16 query heads on ONE key/value head of 128): a q tile of 1,024
+    rows = 64 positions of each of the 16 heads. A key/value head's dK / dV
+    (33.5 MB in float32 scratch and bf16 blocks) do not fit VMEM beside a
+    step, so the backward is the one that keeps them 8,192 keys at a time
+    (``select_range``; ``flashsel_bwd_..._r8192``), under the VMEM its call
+    states, and the one-pass backward is not in the program."""
+    from mxnet_tpu.ops.transformer import attention as attention_ops
+
+    t, h, g, d = 16384, 16, 1, 128
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(q, k, v, keep):
+        return jnp.sum(attention_ops._attention(
+            dict(num_heads=h, num_kv_heads=g, causal=True, with_keep=True),
+            [q, k, v, keep], True)[0].astype(jnp.float32))
+
+    assert pk.flash_select_takes(t, h, g, d, d, jnp.bfloat16)
+    assert pk.flash.select_tiles(t, h // g, d, d, jnp.bfloat16) == (
+        64, 1024, t)
+    assert not pk.flash.bwd_fuses(t, 1024, 1024, d, d, jnp.bfloat16,
+                                  select_rows=64)
+    assert pk.flash.select_range(t, 64, 16, 1024, d, d, jnp.bfloat16) == 8192
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(1, t, h * d), shape(1, t, g * d), shape(1, t, g * d),
+        shape(1, t, t, dtype=jnp.int8)).compile().as_text()
+    calls = [line for line in text.splitlines() if "custom-call(" in line]
+    counts = {"fwd": pk.flash.flash_vmem_bytes(1024, 1024, d, 2,
+                                               select_rows=64),
+              "bwd": pk.flash.select_range_vmem_bytes(8192, 64, 16, 1024, d,
+                                                      d, 2)}
+    for which, name in (("fwd", "flashsel_fwd_bf16_q1024_k1024_g16/"),
+                        ("bwd", "flashsel_bwd_bf16_q1024_k1024_g16_e256"
+                                "_r8192/")):
+        mine = [c for c in calls if name in c]
+        assert len(mine) == 1, name
+        limit, used = (
+            int(re.search(r'"%s":\[\{"memory_space":"1","offset":"\d+",'
+                          r'"size":"(\d+)"' % key, mine[0]).group(1))
+            for key in ("scoped_memory_configs",
+                        "used_scoped_memory_configs"))
+        assert limit == max(counts[which], pk.common.VMEM_SCOPED_DEFAULT)
+        assert used <= limit <= pk.common.VMEM_RAISED_LIMIT, (used, limit)
+    assert len([c for c in calls if "flashsel_" in c]) == 2
+    assert "flash_fwd_" not in text and "flash_bwd_" not in text
+
+
 @pytest.mark.parametrize("layer", ["full", "window"])
 def test_the_dots3_cells_attention_compiles_for_v5e(one_chip, layer):
     """``LatentAttention`` as the dots3 cell calls it (T 4,096, bf16, a
@@ -790,10 +840,13 @@ def test_a_share_layer_moves_rows_without_a_scatter_on_v5e(one_chip):
 # of 64 on 8 groups, state 128, chunks of 128, bf16); the Falcon-H1
 # share's (4,096 tokens, 16 heads of 128 in ONE group, state 256: a step
 # is sixteen lane tiles and a [256, 2048] float32 state); a float32 caller
-# whose heads are whole lane rows, in chunks of 256 over a ragged length
+# whose heads are whole lane rows, in chunks of 256 over a ragged length;
+# the MiniCPM-SALA share's linear core (16,384 tokens, 16 heads of 128 EACH
+# its own group, state 128: ``LinearAttention``'s keys and queries)
 SSD_SHAPES = [
     (8192, 64, 64, 8, 128, 128, jnp.bfloat16),
     (4096, 16, 128, 1, 256, 128, jnp.bfloat16),
+    (16384, 16, 128, 16, 128, 128, jnp.bfloat16),
     (1000, 4, 128, 2, 256, 256, jnp.float32),
 ]
 

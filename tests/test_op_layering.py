@@ -86,8 +86,12 @@ def test_the_transformer_package_is_no_longer_than_the_file_was():
     # ``LayerNorm``) are 354 lines in ``ssm``, ``attention`` and ``norm``;
     # PR 75's keep-mask on ``Attention`` (the input, its type rule, the
     # dispatch between the selected pair and ``kept_attention``, its
-    # counter) is 50 in ``attention``
-    assert total <= 2300 + 360 + 50, total
+    # counter) is 50 in ``attention``; PR 79's two ops are 400:
+    # ``LinearAttention`` in ``ssm`` (145: its slopes, the block on the
+    # scan both ways, the norm a head and the gate) and the family
+    # ``blocks`` (``BlockSelect``: pooled keys, window and block scores,
+    # the choice, its closed form and its count a step)
+    assert total <= 2300 + 360 + 50 + 400, total
 
 
 def test_the_executor_names_no_op_above_it():
@@ -102,14 +106,15 @@ EXPORTED = {
     "RMSNorm", "RoPE", "Attention", "LatentAttention", "Mamba2", "TopKMoE",
     "GatedDeltaNet", "ShortConv", "ScaledSum", "KeyIndexer", "ExitMix",
     "HyperCoeff", "HyperMix", "Mamba1", "DiffAttention", "LayerNorm",
+    "LinearAttention", "BlockSelect",
 }
 
 
 @pytest.mark.parametrize("namespace", ["sym", "nd"])
-def test_contrib_exports_exactly_the_29_names(namespace):
-    """What ``CONTRIB_OP_EXPORTS`` listed by hand before PR 71, and the
-    three ops PR 73 registered."""
-    assert set(contrib_op_exports()) == EXPORTED and len(EXPORTED) == 29
+def test_contrib_exports_exactly_the_31_names(namespace):
+    """What ``CONTRIB_OP_EXPORTS`` listed by hand before PR 71, the three
+    ops PR 73 registered and the two of PR 79."""
+    assert set(contrib_op_exports()) == EXPORTED and len(EXPORTED) == 31
     space = getattr(mx.contrib, namespace)
     ops = {name for name in dir(space) if registry.exists(name)
            and callable(getattr(space, name))

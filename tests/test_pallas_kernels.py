@@ -335,6 +335,74 @@ def test_a_tile_step_masks_at_the_masks_size_and_cuts_the_diagonal(
         assert not np.asarray(dq[:, 300:340], np.float32).any()
 
 
+# The backward that keeps dK / dV a range of keys at a time (PR 79), at
+# sizes where the one-pass backward exists too: ``VMEM_RAISED_LIMIT`` is
+# lowered so that ``select_range`` finds ``keys`` resident and no more.
+# name: (t, heads, kv_heads, dtype, keys a range, tolerance: the one-pass
+# backward's, ``MASK_CASES``)
+RANGE_CASES = {
+    # three ranges of one k tile; q tiles before a range write zeros
+    "three_ranges": (3000, 8, 1, jnp.float32, 1024, 2e-5),
+    # two k tiles a range: the walk down from the diagonal inside a range
+    "two_tiles_a_range": (4096, 4, 1, jnp.float32, 2048, 2e-5),
+    # the MiniCPM-SALA cell's group: 64 positions of 16 heads a q tile
+    "bf16_group16": (2048, 16, 1, jnp.bfloat16, 1024, 2.0 ** -6),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RANGE_CASES))
+def test_the_ranged_backward_matches_kept_attention(case, monkeypatch):
+    """``flash_select`` through the interpreter where a key/value head's dK
+    / dV do not fit VMEM whole: dq (the ranges' float32 partial sums), dk
+    and dv against ``kept_attention`` at the one-pass backward's tolerance,
+    and against the one-pass backward itself on the same operands."""
+    t, heads, kv_heads, dtype, keys, tol = RANGE_CASES[case]
+    d, group = 64, heads // kv_heads
+    q, k, v, keep, w = _select_case(17, t, heads, kv_heads, d, d, dtype, 96)
+    rows, block_k, t_pad = pk.flash.select_tiles(t, group, d, d, dtype)
+    assert pk.flash.select_range(t_pad, rows, group, block_k, d, d,
+                                 dtype) == t_pad
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) * w),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    for jitted in (pk.flash.select_bwd_call, pk.flash.select_bwd_range_call):
+        jitted.clear_cache()
+    one_pass = grads(lambda q, k, v: pk.flash_select(q, k, v, keep,
+                                                     interpret=True))
+    itemsize = jnp.dtype(dtype).itemsize
+    monkeypatch.setattr(
+        pk.flash, "VMEM_RAISED_LIMIT", pk.flash.select_range_vmem_bytes(
+            keys, rows, group, block_k, d, d, itemsize))
+    assert pk.flash.select_tiles(t, group, d, d, dtype) == (rows, block_k,
+                                                            t_pad)
+    assert pk.flash.select_range(t_pad, rows, group, block_k, d, d,
+                                 dtype) == keys
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        got = grads(lambda q, k, v: pk.flash_select(q, k, v, keep,
+                                                    interpret=True))
+        traces = telemetry.REGISTRY.get("attention.select_kernel_traces")
+        assert traces.value(
+            **{"pass": "bwd_ranges"}, group=group, rows=rows, range=keys,
+            edge=pk.flash.select_edge("bwd", rows, block_k)) == 1
+        assert traces.value(
+            **{"pass": "bwd"}, group=group, rows=rows,
+            edge=pk.flash.select_edge("bwd", rows, block_k)) == 0
+    finally:
+        telemetry.disable()
+        telemetry.reset()
+    want = grads(lambda q, k, v: pk.kept_attention(q, k, v, keep, d ** -0.5))
+    for g, o, x in zip(got, one_pass, want):
+        g, o, x = (np.asarray(a, np.float32) for a in (g, o, x))
+        assert np.isfinite(g).all()
+        scale = max(np.abs(x).max(), 1.0)
+        assert np.abs(g - x).max() <= tol * scale, np.abs(g - x).max()
+        assert np.abs(g - o).max() <= tol * scale, np.abs(g - o).max()
+
+
 def _inner_jaxprs(jaxpr):
     """``jaxpr`` and every jaxpr inside its equations' parameters."""
     yield jaxpr
@@ -414,7 +482,8 @@ def test_a_row_that_keeps_nothing_reads_zeros_and_moves_nothing():
     (8192, 32, 4, 128, jnp.float16, False),    # no type Mosaic takes
     (100, 8, 1, 128, jnp.float32, False),      # shorter than a tile
     (384, 16, 2, 128, jnp.float32, False),     # 16 rows a head: no int8 tile
-    (32768, 32, 4, 128, jnp.bfloat16, False),  # dK / dV do not stay in VMEM
+    (32768, 32, 4, 128, jnp.bfloat16, True),   # dK / dV by ranges of keys
+    (16384, 16, 1, 128, jnp.bfloat16, True),   # the MiniCPM-SALA cell
 ])
 def test_the_selected_pair_takes_what_the_shapes_say(t, heads, kv_heads, d,
                                                      dtype, takes):

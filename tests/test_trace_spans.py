@@ -649,6 +649,7 @@ REGISTERED_CLASSES = {
     "Embedding": "embed", "_contrib_GatedDeltaNet": "gdn",
     "_contrib_ShortConv": "sconv", "_contrib_ScaledSum": "act",
     "_contrib_HyperCoeff": "hc", "_contrib_HyperMix": "hc",
+    "_contrib_LinearAttention": "linattn", "_contrib_BlockSelect": "attn",
 }
 
 
